@@ -20,7 +20,7 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ._compat import shard_map
+from jax import shard_map
 
 from .. import observability as _obs
 from .. import resilience as _res

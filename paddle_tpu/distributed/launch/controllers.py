@@ -40,6 +40,38 @@ class _Proc:
         self.log_file = log_file
 
 
+def _visible_chips_env(devices: str, local_rank: int, nproc: int) -> dict:
+    """Per-rank chip visibility from ``--devices``.  The installed libtpu
+    takes its chips from ``TPU_VISIBLE_CHIPS`` (the variable jax's own
+    multi-process TPU tests set); each local rank gets its own equal
+    share of the list, so ranks on one host never all claim chip 0.
+    Ranks that share a host also tell libtpu the process grid — the
+    one-chip-per-process recipe of the same tests (a 2x2 host for four
+    ranks, a row otherwise)."""
+    ids = [d for d in devices.split(",") if d]
+    if len(ids) % nproc:
+        raise ValueError(f"--devices names {len(ids)} chips, which "
+                         f"{nproc} local ranks cannot share equally")
+    k = len(ids) // nproc
+    env = {"TPU_VISIBLE_CHIPS":
+           ",".join(ids[local_rank * k:(local_rank + 1) * k])}
+    if nproc > 1:
+        if k != 1:
+            raise ValueError("several ranks on one host take one chip "
+                             "each: give --devices one id per rank")
+        ports = [8476 + r for r in range(nproc)]
+        env.update({
+            "CLOUD_TPU_TASK_ID": str(local_rank),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "2,2,1" if nproc == 4
+            else f"{nproc},1,1",
+            "TPU_PROCESS_ADDRESSES":
+                ",".join(f"localhost:{p}" for p in ports),
+            "TPU_PROCESS_PORT": str(ports[local_rank]),
+        })
+    return env
+
+
 class CollectiveController:
     """Spawn + watch the local ranks of a collective job."""
 
@@ -118,7 +150,8 @@ class CollectiveController:
             "PADDLE_LOG_DIR": os.path.abspath(self.args.log_dir),
         })
         if self.args.devices:
-            env["TPU_VISIBLE_DEVICES"] = self.args.devices
+            env.update(_visible_chips_env(self.args.devices, local_rank,
+                                          self.nproc))
         return env
 
     # -- spawn / watch -------------------------------------------------------

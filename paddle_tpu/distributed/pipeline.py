@@ -28,11 +28,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ._compat import pvary as _pvary
-
 __all__ = ["spmd_pipeline", "stack_layer_params", "PP_AXIS"]
 
 PP_AXIS = "pp"
+
+
+def _pvary(x, axis):
+    return jax.lax.pcast(x, axis, to="varying")
 
 
 def _pp_shard_map(f, mesh, in_specs, out_specs):
@@ -40,10 +42,9 @@ def _pp_shard_map(f, mesh, in_specs, out_specs):
     'auto' so GSPMD keeps tensor/data parallelism inside each stage body."""
     # check_vma=True is load-bearing: jax 0.9's eager partial-manual path
     # (_unmatch) mis-builds an all-axes dst spec when check_vma=False
-    from ._compat import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs,
-                     axis_names=frozenset({PP_AXIS}), check_vma=True)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         axis_names=frozenset({PP_AXIS}), check_vma=True)
 
 
 @jax.custom_vjp

@@ -217,17 +217,9 @@ def scheduled_pipeline_loss(schedule: Schedule, stage_fn: Callable,
 
     # with_sharding_constraint inside the pp-manual shard_map needs the
     # pp axis TYPED Manual on the sharding's mesh (vma axes must be
-    # Manual); the auto axes keep their Auto type. Legacy jax has no
-    # AxisType, and its partitioner CHECK-crashes on any wsc inside a
-    # partial-manual region (hlo_sharding_util: sharding.IsManualSubgroup)
-    # — there the pins become identity and GSPMD infers the auto-axes
-    # sharding on its own.
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        AxisType = None
-    if mb_auto_spec is not None and AxisType is not None:
-        from jax.sharding import NamedSharding
+    # Manual); the auto axes keep their Auto type.
+    if mb_auto_spec is not None:
+        from jax.sharding import AxisType, NamedSharding
         _mesh_mpp = Mesh(
             mesh.devices, mesh.axis_names,
             axis_types=tuple(AxisType.Manual if n == PP_AXIS
@@ -290,8 +282,8 @@ def scheduled_pipeline_loss(schedule: Schedule, stage_fn: Callable,
 
         def pv(a):
             """pvary, idempotent: no-op when already device-varying."""
-            from ._compat import pvary, vma_of
-            return a if PP_AXIS in vma_of(a) else pvary(a, PP_AXIS)
+            return a if PP_AXIS in jax.typeof(a).vma \
+                else jax.lax.pcast(a, PP_AXIS, to="varying")
         # CRITICAL: vjp w.r.t. a pp-INVARIANT value makes shard_map insert
         # a psum_invariant collective to re-invariant the cotangent — and
         # a collective inside one lax.switch branch deadlocks devices that

@@ -22,7 +22,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.dispatch import apply

@@ -30,8 +30,17 @@ class Generator:
     def manual_seed(self, s: int) -> "Generator":
         self._seed = int(s)
         self._counter = 0
-        self._key = jax.random.key(int(s))
+        # the key is built on first draw: making one here would
+        # initialise the XLA backend at package import, and a launcher
+        # parent that imports the package must stay off the device
+        self._key_cache = None
         return self
+
+    @property
+    def _key(self):
+        if self._key_cache is None:
+            self._key_cache = jax.random.key(self._seed)
+        return self._key_cache
 
     def next_key(self):
         self._counter += 1
@@ -42,7 +51,7 @@ class Generator:
 
     def set_state(self, state) -> None:
         self._seed, self._counter = int(state[0]), int(state[1])
-        self._key = jax.random.key(self._seed)
+        self._key_cache = None
 
 
 default_generator = Generator(0)

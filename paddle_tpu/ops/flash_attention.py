@@ -182,12 +182,17 @@ def sdpa(q, k, v, mask=None, causal: bool = False, dropout_p: float = 0.0,
         _count_kernel("flash_bundled")
         from jax.experimental.pallas.ops.tpu.flash_attention import (
             flash_attention as _pallas_flash)
-        qh = jnp.swapaxes(q, 1, 2)  # [B,H,S,D]
-        kh = jnp.swapaxes(k, 1, 2)
-        vh = jnp.swapaxes(v, 1, 2)
-        out = _pallas_flash(qh, kh, vh, causal=causal, sm_scale=scale,
-                            block_sizes=_flash_block_sizes(Sq, Sk))
-        return jnp.swapaxes(out, 1, 2)
+        from .on_mesh import on_mesh
+
+        def bundled(q, k, v):
+            out = _pallas_flash(
+                jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),  # [B,H,S,D]
+                jnp.swapaxes(v, 1, 2), causal=causal, sm_scale=scale,
+                block_sizes=_flash_block_sizes(Sq, Sk))
+            return jnp.swapaxes(out, 1, 2)
+        # the bundled kernel owns its custom_vjp, so autodiff transposes
+        # this shard_map: fine under GSPMD, not inside the pp region
+        return on_mesh(bundled, (q, k, v), ("bshd",) * 3, "bshd")
     if path == "flash_segmented":
         _count_kernel("flash_segmented")
         pad = _as_key_padding(mask, B, Sq, Sk)

@@ -33,10 +33,6 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _row_block(n_rows: int) -> int:
     for b in (256, 128, 64, 32, 16, 8):
@@ -361,13 +357,25 @@ def fused_rope(q, k, cos, sin):
 # rope + paged-cache append (serving decode path; no VJP — inference only)
 # ---------------------------------------------------------------------------
 
+def _put_row(page, off, row):
+    """``page`` [psz, D] with row ``off`` replaced by ``row`` [1, D].
+    Mosaic cannot lower a one-row store at a dynamic sublane offset it
+    cannot prove tile-aligned, so the append lands through a full-block
+    select (psz*D elements — one or two vregs per KV head); the f32
+    round trip is exact for every pool dtype and keeps the select off
+    packed-bf16 mask layouts."""
+    hit = jax.lax.broadcasted_iota(jnp.int32, page.shape, 0) == off
+    return jnp.where(hit, row.astype(jnp.float32),
+                     page.astype(jnp.float32)).astype(page.dtype)
+
+
 def _rope_append_kernel(pg_ref, off_ref,              # scalar prefetch
                         q_ref, k_ref, v_ref, c_ref, s_ref,
                         kin_ref, vin_ref,
                         qo_ref, kp_ref, vp_ref):
     t = pl.program_id(0)
-    c = c_ref[:].astype(jnp.float32)                   # [1, D/2]
-    s = s_ref[:].astype(jnp.float32)
+    c = c_ref[0].astype(jnp.float32)                   # [1, D/2]
+    s = s_ref[0].astype(jnp.float32)
 
     def rot(x):                                        # [h, D] f32
         d2 = x.shape[-1] // 2
@@ -387,10 +395,11 @@ def _rope_append_kernel(pg_ref, off_ref,              # scalar prefetch
         vp_ref[:] = vin_ref[:]
 
     off = off_ref[t]
-    kr = rot(k_ref[0].astype(jnp.float32)).astype(kp_ref.dtype)
-    kp_ref[:, 0, pl.dslice(off, 1), :] = kr[:, None, :]
-    vp_ref[:, 0, pl.dslice(off, 1), :] = \
-        v_ref[0].astype(vp_ref.dtype)[:, None, :]
+    kr = rot(k_ref[0].astype(jnp.float32))
+    v = v_ref[0]
+    for h in range(kr.shape[0]):
+        kp_ref[h, 0] = _put_row(kp_ref[h, 0], off, kr[h:h + 1])
+        vp_ref[h, 0] = _put_row(vp_ref[h, 0], off, v[h:h + 1])
 
 
 def fused_rope_append(q, k, v, cos, sin, k_pages, v_pages,
@@ -423,7 +432,7 @@ def fused_rope_append(q, k, v, cos, sin, k_pages, v_pages,
         return (t, 0, 0)
 
     def cs_map(t, pg, off):
-        return (t, 0)
+        return (t, 0, 0)
 
     def page_map(t, pg, off):
         return (0, jnp.clip(pg[t], 0, total - 1), 0, 0)
@@ -436,8 +445,10 @@ def fused_rope_append(q, k, v, cos, sin, k_pages, v_pages,
             pl.BlockSpec((1, Hq, D), tok_map),
             pl.BlockSpec((1, KV, D), tok_map),
             pl.BlockSpec((1, KV, D), tok_map),
-            pl.BlockSpec((1, d2), cs_map),
-            pl.BlockSpec((1, d2), cs_map),
+            # one-token blocks need their last two dims equal to the
+            # array's: the trig rows ride as [T, 1, d2]
+            pl.BlockSpec((1, 1, d2), cs_map),
+            pl.BlockSpec((1, 1, d2), cs_map),
             page_spec,
             page_spec,
         ],
@@ -452,11 +463,11 @@ def fused_rope_append(q, k, v, cos, sin, k_pages, v_pages,
                    jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
         # flat-input indices INCLUDE the scalar-prefetch operands
         input_output_aliases={7: 1, 8: 2},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(page_idx.astype(jnp.int32), page_off.astype(jnp.int32),
-      q, k, v, cos, sin, k_pages, v_pages)
+      q, k, v, cos[:, None, :], sin[:, None, :], k_pages, v_pages)
 
 
 def _append_rows_kernel(pg_ref, off_ref, r_ref, pin_ref, po_ref):
@@ -467,8 +478,9 @@ def _append_rows_kernel(pg_ref, off_ref, r_ref, pin_ref, po_ref):
     def _seed():
         po_ref[:] = pin_ref[:]
 
-    po_ref[:, 0, pl.dslice(off_ref[t], 1), :] = \
-        r_ref[0].astype(po_ref.dtype)[:, None, :]
+    r = r_ref[0]
+    for h in range(r.shape[0]):
+        po_ref[h, 0] = _put_row(po_ref[h, 0], off_ref[t], r[h:h + 1])
 
 
 def fused_append_rows(pages, rows, page_idx, page_off):
@@ -496,7 +508,7 @@ def fused_append_rows(pages, rows, page_idx, page_off):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pages.shape, pages.dtype),
         input_output_aliases={3: 0},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(page_idx.astype(jnp.int32), page_off.astype(jnp.int32),

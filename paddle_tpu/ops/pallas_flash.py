@@ -37,9 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from .on_mesh import on_mesh
 
 __all__ = ["flash_sdpa", "flash_kernel_eligible"]
 
@@ -48,7 +46,7 @@ _NEG = -1e30
 # B/H/outer-block grid dims are independent; only the innermost dim
 # carries the online-softmax / accumulator state. Marking them parallel
 # lets Mosaic split them across TensorCores (megacore parts)
-_CPARAMS = _CompilerParams(
+_CPARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
 
@@ -236,10 +234,29 @@ def _specs(bq, bk, D, order: str):
              pl.BlockSpec((1, 1, bk, D), kmap)], qmap, kmap)
 
 
+def _out(shape, dtype, *like):
+    """out_shape entry varying over the manual mesh axes its inputs vary
+    over — inside a vma-checked shard_map (on_mesh under the pipeline's
+    pp region) pallas_call has to be told."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in like))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
+# the kernel launches run per shard of the step's mesh, INSIDE the
+# custom_vjp rules (see ops/on_mesh.py)
+_QKV = ("bhsd", "bhsd", "bhsd", "b1s", "b1s")
+
+
+def _fwd_on_mesh(q, k, v, seg_q, seg_kv, scale, causal, bq, bk, use_seg):
+    fwd = functools.partial(_flash_fwd_impl, scale=scale, causal=causal,
+                            bq=bq, bk=bk, use_seg=use_seg)
+    return on_mesh(fwd, (q, k, v, seg_q, seg_kv), _QKV, ("bhsd", "bhs1"))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def _flash_core(q, k, v, seg_q, seg_kv, scale, causal, bq, bk, use_seg):
-    o, _ = _flash_fwd_impl(q, k, v, seg_q, seg_kv, scale, causal, bq, bk,
-                           use_seg)
+    o, _ = _fwd_on_mesh(q, k, v, seg_q, seg_kv, scale, causal, bq, bk,
+                        use_seg)
     return o
 
 
@@ -258,8 +275,8 @@ def _flash_fwd_impl(q, k, v, seg_q, seg_kv, scale, causal, bq, bk,
         out_specs=[pl.BlockSpec((1, 1, bq, D), qmap),
                    pl.BlockSpec((1, 1, bq, 1),
                                 lambda b, h, i, j: (b, h, i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
-                   jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32)],
+        out_shape=[_out((B, H, Sq, D), q.dtype, q, k, v),
+                   _out((B, H, Sq, 1), jnp.float32, q, k, v)],
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32)],
@@ -271,13 +288,21 @@ def _flash_fwd_impl(q, k, v, seg_q, seg_kv, scale, causal, bq, bk,
 
 def _flash_vjp_fwd(q, k, v, seg_q, seg_kv, scale, causal, bq, bk,
                    use_seg):
-    o, lse = _flash_fwd_impl(q, k, v, seg_q, seg_kv, scale, causal, bq,
-                             bk, use_seg)
+    o, lse = _fwd_on_mesh(q, k, v, seg_q, seg_kv, scale, causal, bq, bk,
+                          use_seg)
     return o, (q, k, v, seg_q, seg_kv, o, lse)
 
 
 def _flash_vjp_bwd(scale, causal, bq, bk, use_seg, res, do):
-    q, k, v, seg_q, seg_kv, o, lse = res
+    bwd = functools.partial(_flash_bwd_impl, scale=scale, causal=causal,
+                            bq=bq, bk=bk, use_seg=use_seg)
+    dq, dk, dv = on_mesh(bwd, (*res, do),
+                         _QKV + ("bhsd", "bhs1", "bhsd"), ("bhsd",) * 3)
+    return dq, dk, dv, None, None
+
+
+def _flash_bwd_impl(q, k, v, seg_q, seg_kv, o, lse, do, scale, causal,
+                    bq, bk, use_seg):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     off = Sk - Sq
@@ -295,7 +320,7 @@ def _flash_vjp_bwd(scale, causal, bq, bk, use_seg, res, do):
         in_specs=in_specs + [pl.BlockSpec((1, 1, bq, D), qmap),
                              row_spec, row_spec],
         out_specs=pl.BlockSpec((1, 1, bq, D), qmap),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
+        out_shape=_out((B, H, Sq, D), q.dtype, q, k, v, do),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=_CPARAMS,
         interpret=_interpret(),
@@ -312,14 +337,14 @@ def _flash_vjp_bwd(scale, causal, bq, bk, use_seg, res, do):
                               row_spec2, row_spec2],
         out_specs=[pl.BlockSpec((1, 1, bk, D), kmap2),
                    pl.BlockSpec((1, 1, bk, D), kmap2)],
-        out_shape=[jax.ShapeDtypeStruct((B, H, Sk, D), k.dtype),
-                   jax.ShapeDtypeStruct((B, H, Sk, D), v.dtype)],
+        out_shape=[_out((B, H, Sk, D), k.dtype, q, k, v, do),
+                   _out((B, H, Sk, D), v.dtype, q, k, v, do)],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
         compiler_params=_CPARAMS,
         interpret=_interpret(),
     )(seg_q, seg_kv, q, k, v, do, lse, di)
-    return dq, dk, dv, None, None
+    return dq, dk, dv
 
 
 _flash_core.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

@@ -43,17 +43,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flashmask_sdpa", "flashmask_block_kinds", "bands_from_startend"]
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 _NEG = -1e30
 
 # B/H/outer-block dims are independent; only the innermost dim carries
 # the online-softmax / accumulator state (paddlelint PE501: every
 # revisited output axis must be declared). Parallel outer dims let
 # Mosaic split them across TensorCores (megacore parts), same as flash.
-_CPARAMS = _CompilerParams(
+_CPARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
 

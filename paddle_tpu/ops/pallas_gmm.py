@@ -33,10 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 __all__ = ["gmm", "gmm_kernel_eligible"]
 
 
@@ -152,7 +148,7 @@ def _gmm_fwd_impl(lhs, rhs, group_sizes, bm, bn):
         functools.partial(_gmm_kernel, bm=bm),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mp, N), lhs.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(offs, blk_lo, blk_hi, lhs, rhs)
@@ -194,7 +190,7 @@ def _tgmm_impl(lhs, dout, group_sizes, bm, bn):
         functools.partial(_tgmm_kernel, bm=bm),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((G, K, N), lhs.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(offs, i_lo.astype(jnp.int32), i_hi.astype(jnp.int32), lhs, dout)

@@ -52,14 +52,14 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
-#: Pallas VMEM budget per TensorCore (v4/v5: ~16 MiB); the eligibility
-#: check keeps each kernel's resident weight set under a safety margin
-#: of it so the token blocks + scratch still fit.
-_VMEM_BYTES = 16 * 1024 * 1024
+#: Mosaic's default scoped-VMEM limit on a v4/v5 TensorCore.  The
+#: kernels here set no ``vmem_limit_bytes``, so this is what the
+#: compiler holds one launch to; the eligibility check models each
+#: kernel's resident bytes against it (tests/test_tpu_aot_compile.py
+#: holds the model to the compiler's own answer).
+_VMEM_LIMIT = 16 * 1024 * 1024
+#: compiler-internal scratch plus the scale / bias / norm rows
+_VMEM_SLACK = 1024 * 1024
 
 
 def _row_block(n_rows: int) -> int:
@@ -280,7 +280,7 @@ def _ffn_forward(h2, x2, wg, sg, wu, su, wd, sd, b1, b2, act):
 
 def _ffn_int4_kernel(he_ref, ho_ref, x_ref, qg_ref, sg_ref, qu_ref,
                      su_ref, qd_ref, sd_ref, b1_ref, b2_ref, xo_ref,
-                     acc_ref):
+                     acc_ref, wd_ref):
     def planes(q_ref, s_ref):
         s = s_ref[0].astype(jnp.float32)[None, :]
         q = q_ref[:].astype(jnp.int32)
@@ -298,17 +298,25 @@ def _ffn_int4_kernel(he_ref, ho_ref, x_ref, qg_ref, sg_ref, qu_ref,
     u = (jnp.dot(he, ulo, preferred_element_type=jnp.float32)
          + jnp.dot(ho, uhi, preferred_element_type=jnp.float32))
     acc_ref[:] = g * jax.lax.logistic(g) * u
-    t = acc_ref[:]
-    bt, I = t.shape
-    # the down-proj's even/odd split happens IN VMEM on the scratch
-    # activation (lane dim untouched — the reshape merges sublanes),
-    # mirroring how _wol_int4_fwd_impl splits its host input
-    ts = t.reshape(bt, I // 2, 2)
+    # the down-proj contracts over I, whose even/odd rows sit in the
+    # two nibble planes.  De-interleaving the [bt, I] activation would
+    # stride LANES, which Mosaic does not lower; instead the planes
+    # interleave back into an f32 VMEM scratch by sublane-strided
+    # stores (Mosaic wants a 128-lane base for those, so the scratch is
+    # [H/128, I, 128] column chunks) and the activation contracts
+    # against each chunk whole
+    I2 = qd_ref.shape[0]
+    nc, _, cw = wd_ref.shape
     dlo, dhi = planes(qd_ref, sd_ref)
-    d = (jnp.dot(ts[:, :, 0], dlo, preferred_element_type=jnp.float32)
-         + jnp.dot(ts[:, :, 1], dhi, preferred_element_type=jnp.float32)) \
-        + b2_ref[0].astype(jnp.float32)[None, :]
-    xo_ref[:] = (x_ref[:].astype(jnp.float32) + d).astype(xo_ref.dtype)
+    t = acc_ref[:]
+    for c in range(nc):
+        cols = slice(c * cw, (c + 1) * cw)
+        wd_ref[c, pl.ds(0, I2, stride=2), :] = dlo[:, cols]
+        wd_ref[c, pl.ds(1, I2, stride=2), :] = dhi[:, cols]
+        d = jnp.dot(t, wd_ref[c], preferred_element_type=jnp.float32) \
+            + b2_ref[0, cols].astype(jnp.float32)[None, :]
+        xo_ref[:, cols] = (x_ref[:, cols].astype(jnp.float32)
+                           + d).astype(xo_ref.dtype)
 
 
 def _ffn_int4(he, ho, x2, qg, sg, qu, su, qd, sd, b1, b2):
@@ -317,6 +325,7 @@ def _ffn_int4(he, ho, x2, qg, sg, qu, su, qd, sd, b1, b2):
     I = qg.shape[1]
     I2 = qd.shape[0]
     bt = _row_block(T)
+    cw = 128 if H % 128 == 0 else H     # interpret mode takes any H
     return pl.pallas_call(
         _ffn_int4_kernel,
         grid=(T // bt,),
@@ -333,7 +342,8 @@ def _ffn_int4(he, ho, x2, qg, sg, qu, su, qd, sd, b1, b2):
                   pl.BlockSpec((1, H), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((bt, H), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((T, H), x2.dtype),
-        scratch_shapes=[pltpu.VMEM((bt, I), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bt, I), jnp.float32),
+                        pltpu.VMEM((H // cw, I, cw), jnp.float32)],
         interpret=_interpret(),
     )(he, ho, x2, qg, sg, qu, su, qd, sd, b1, b2)
 
@@ -392,26 +402,39 @@ def fused_ffn(h, x, wg, sg=None, wu=None, su=None, wd=None, sd=None,
 # ---------------------------------------------------------------------------
 
 def megadecode_eligible(hidden: int, intermediate: int, o_width: int, *,
-                        int4: bool = False,
-                        dtype_bytes: int = 2) -> bool:
+                        int4: bool = False, dtype_bytes: int = 2,
+                        tokens: int = 8) -> bool:
     """True when the fused back-half tiling is launchable: interpret
     mode always (blocks are virtual); on a real TPU the lane dims must
     be 128-aligned (the packed-int4 layouts additionally halve their
     contraction dims, so those must stay even) and the larger kernel's
-    resident weight set must fit a 3/4 VMEM budget (the remainder
-    covers token blocks, scales and the f32 scratch accumulator).
-    Callers fall back to the split per-kernel chain when this is
-    False — same math, more HBM round-trips."""
+    resident set must fit the scoped-VMEM limit.  Resident, as the
+    compiler counts it: the weight slabs as stored (constant index_map
+    — single-buffered), the double-buffered ``_row_block(tokens)`` token
+    blocks, the f32 scratch, and for packed int4 the one 4-byte plane
+    Mosaic materializes while unpacking the largest slab plus
+    fused_ffn's [I, H] f32 down-proj scratch.  ``dtype_bytes`` is the
+    stored width of an fp/int8 weight element.  Callers fall back to
+    the split per-kernel chain when this is False — same math, more
+    HBM round-trips."""
     if _interpret():
         return True
     if hidden % 128 or intermediate % 128 or o_width % 128:
         return False
     if int4 and (o_width % 2 or hidden % 2 or intermediate % 2):
         return False
-    wb = dtype_bytes if not int4 else 0.5
-    w1 = o_width * hidden * wb
-    w2 = (2 * hidden * intermediate + intermediate * hidden) * wb
-    return max(w1, w2) <= _VMEM_BYTES * 3 // 4
+    H, I, Ko = hidden, intermediate, o_width
+    bt = _row_block(tokens)
+    wb = 0.5 if int4 else dtype_bytes
+    # o-proj + norm: o, x in; x, h out (2-byte activations, two buffers
+    # each) + the [bt, H] f32 accumulator
+    k1 = Ko * H * wb + 2 * 2 * bt * (Ko + 3 * H) + 4 * bt * H
+    # ffn: h, x in; x out + the [bt, I] f32 activation scratch
+    k2 = 3 * H * I * wb + 2 * 2 * bt * 3 * H + 4 * bt * I
+    if int4:
+        k1 += 4 * (Ko // 2) * H
+        k2 += 4 * (H // 2) * I + 4 * I * H
+    return max(k1, k2) <= _VMEM_LIMIT - _VMEM_SLACK
 
 
 # ---------------------------------------------------------------------------

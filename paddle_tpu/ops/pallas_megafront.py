@@ -41,10 +41,12 @@ pallas_call sites below is a literal grid/BlockSpec launch owned by one
 function (`_qkv_rope_append_fwd`, `_qkv_rope_append_int4`,
 `_mla_qkv_rope_append_fwd`) with a CANONICAL binding in
 analysis/vmemmodel.py; the cost registry carries matching byte formulas
-(PF406/PE506 exact); the aliased page pools keep the fused.py scatter
-contract (adjacent same-page tokens, width-1 per-step-table dslice
-stores, `arbitrary` grid semantics) so PE501-PE504 certify the scatter
-exactly as they do the PR-7 kernel.  Inference-only: no VJPs.
+(PF406/PE506 exact); the aliased page pools keep the fused.py append
+contract (adjacent same-page tokens, one row per grid step at the
+per-step table offset — written through `fused._put_row`'s page-block
+select, since Mosaic lowers no one-row store at a dynamic sublane
+offset — and `arbitrary` grid semantics) so PE501-PE503 certify the
+append exactly as they do the PR-7 kernel.  Inference-only: no VJPs.
 """
 
 from __future__ import annotations
@@ -57,6 +59,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .fused import _put_row
+
 __all__ = ["fused_qkv_rope_append", "megafront_eligible"]
 
 
@@ -64,14 +68,7 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
-#: Pallas VMEM budget per TensorCore (v4/v5: ~16 MiB); the eligibility
-#: check keeps the resident qkv slab under a safety margin of it so the
-#: token row, trig rows and the two page blocks still fit.
-_VMEM_BYTES = 16 * 1024 * 1024
+from .pallas_megadecode import _VMEM_LIMIT, _VMEM_SLACK
 
 
 # ---------------------------------------------------------------------------
@@ -88,24 +85,18 @@ def _qkv_rope_append_kernel(pg_ref, off_ref,          # scalar prefetch
     # the fp path stays bitwise-equal to the plain dot); int8 weights
     # dequantize here exactly like quant._wol_kernel
     w = w_ref[:].astype(jnp.float32) * s_ref[0].astype(jnp.float32)[None, :]
-    p = jnp.dot(h_ref[:].astype(jnp.float32), w,
+    p = jnp.dot(h_ref[0].astype(jnp.float32), w,
                 preferred_element_type=jnp.float32) \
         + b_ref[0].astype(jnp.float32)[None, :]        # [1, (Hq+2KV)*D]
     D = qo_ref.shape[-1]
-    c = c_ref[:].astype(jnp.float32)                   # [1, D/2]
-    sn = sn_ref[:].astype(jnp.float32)
+    c = c_ref[0].astype(jnp.float32)                   # [1, D/2]
+    sn = sn_ref[0].astype(jnp.float32)
 
     def rot(x):                                        # [h, D] f32
         d2 = x.shape[-1] // 2
         x1, x2 = x[:, :d2], x[:, d2:]
         return jnp.concatenate([x1 * c - x2 * sn, x2 * c + x1 * sn], -1)
 
-    # column split of the fused projection — the in-VMEM retile stage:
-    # q rows leave at the consumer's one-token granularity
-    q = p[0, :heads * D].reshape(heads, D)
-    k = p[0, heads * D:(heads + kv_heads) * D].reshape(kv_heads, D)
-    v = p[0, (heads + kv_heads) * D:].reshape(kv_heads, D)
-    qo_ref[0] = rot(q).astype(qo_ref.dtype)
     # first visit of a page seeds the resident output block from the
     # aliased input fetch; consecutive same-page tokens keep the block
     # resident, so their earlier row writes survive (re-seeding would
@@ -117,9 +108,19 @@ def _qkv_rope_append_kernel(pg_ref, off_ref,          # scalar prefetch
         kp_ref[:] = kin_ref[:]
         vp_ref[:] = vin_ref[:]
 
+    # column split of the fused projection row — the in-VMEM retile
+    # stage.  Each head is a static lane window of p (a [1, N] ->
+    # [heads, D] reshape moves lanes into sublanes, which Mosaic does
+    # not lower): q heads leave at the consumer's one-token
+    # granularity, k/v heads land in their page rows
     off = off_ref[t]
-    kp_ref[:, 0, pl.dslice(off, 1), :] = rot(k).astype(kp_ref.dtype)[:, None, :]
-    vp_ref[:, 0, pl.dslice(off, 1), :] = v.astype(vp_ref.dtype)[:, None, :]
+    for i in range(heads):
+        qo_ref[0, i:i + 1, :] = \
+            rot(p[:, i * D:(i + 1) * D]).astype(qo_ref.dtype)
+    for i in range(kv_heads):
+        ko, vo = (heads + i) * D, (heads + kv_heads + i) * D
+        kp_ref[i, 0] = _put_row(kp_ref[i, 0], off, rot(p[:, ko:ko + D]))
+        vp_ref[i, 0] = _put_row(vp_ref[i, 0], off, p[:, vo:vo + D])
 
 
 def _qkv_rope_append_fwd(h, w, s, b, cos, sin, k_pages, v_pages,
@@ -138,14 +139,14 @@ def _qkv_rope_append_fwd(h, w, s, b, cos, sin, k_pages, v_pages,
         num_scalar_prefetch=2,                 # page_idx, page_off
         grid=(T,),
         in_specs=[
-            pl.BlockSpec((1, H), lambda t, pg, off: (t, 0)),
+            pl.BlockSpec((1, 1, H), lambda t, pg, off: (t, 0, 0)),
             # weight/scale/bias index_maps reference no grid dim:
             # fetched ONCE, VMEM-resident across the token sweep
             pl.BlockSpec((H, N), lambda t, pg, off: (0, 0)),
             pl.BlockSpec((1, N), lambda t, pg, off: (0, 0)),
             pl.BlockSpec((1, N), lambda t, pg, off: (0, 0)),
-            pl.BlockSpec((1, d2), lambda t, pg, off: (t, 0)),
-            pl.BlockSpec((1, d2), lambda t, pg, off: (t, 0)),
+            pl.BlockSpec((1, 1, d2), lambda t, pg, off: (t, 0, 0)),
+            pl.BlockSpec((1, 1, d2), lambda t, pg, off: (t, 0, 0)),
             page_spec,
             page_spec,
         ],
@@ -161,11 +162,12 @@ def _qkv_rope_append_fwd(h, w, s, b, cos, sin, k_pages, v_pages,
                    jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
         # flat-input indices INCLUDE the scalar-prefetch operands
         input_output_aliases={8: 1, 9: 2},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(page_idx.astype(jnp.int32), page_off.astype(jnp.int32),
-      h, w, s, b, cos, sin, k_pages, v_pages)
+      h[:, None, :], w, s, b, cos[:, None, :], sin[:, None, :],
+      k_pages, v_pages)
 
 
 # ---------------------------------------------------------------------------
@@ -185,16 +187,16 @@ def _qkv_rope_append_int4_kernel(pg_ref, off_ref,     # scalar prefetch
     qw = qw_ref[:].astype(jnp.int32)
     lo = (((qw & 0xF) ^ 8) - 8).astype(jnp.float32) * s
     hi = (qw >> 4).astype(jnp.float32) * s
-    p = (jnp.dot(he_ref[:].astype(jnp.float32), lo,
+    p = (jnp.dot(he_ref[0].astype(jnp.float32), lo,
                  preferred_element_type=jnp.float32)
-         + jnp.dot(ho_ref[:].astype(jnp.float32), hi,
+         + jnp.dot(ho_ref[0].astype(jnp.float32), hi,
                    preferred_element_type=jnp.float32)) \
         + b_ref[0].astype(jnp.float32)[None, :]
     D = qo_ref.shape[-1]
     # trig rides as one [1, D] (cos | sin) row here: the packed-int4
     # lane rule (PF403) requires every block lane be 1 or a
     # 128-multiple, which the D/2-wide trig halves would break
-    cs = cs_ref[:].astype(jnp.float32)
+    cs = cs_ref[0].astype(jnp.float32)
     c, sn = cs[:, :D // 2], cs[:, D // 2:]
 
     def rot(x):                                        # [h, D] f32
@@ -202,10 +204,6 @@ def _qkv_rope_append_int4_kernel(pg_ref, off_ref,     # scalar prefetch
         x1, x2 = x[:, :d2], x[:, d2:]
         return jnp.concatenate([x1 * c - x2 * sn, x2 * c + x1 * sn], -1)
 
-    q = p[0, :heads * D].reshape(heads, D)
-    k = p[0, heads * D:(heads + kv_heads) * D].reshape(kv_heads, D)
-    v = p[0, (heads + kv_heads) * D:].reshape(kv_heads, D)
-    qo_ref[0] = rot(q).astype(qo_ref.dtype)
     prev = pg_ref[jnp.maximum(t - 1, 0)]
 
     @pl.when((t == 0) | (pg_ref[t] != prev))
@@ -213,9 +211,15 @@ def _qkv_rope_append_int4_kernel(pg_ref, off_ref,     # scalar prefetch
         kp_ref[:] = kin_ref[:]
         vp_ref[:] = vin_ref[:]
 
+    # column split by static lane windows, as in the fp/int8 kernel
     off = off_ref[t]
-    kp_ref[:, 0, pl.dslice(off, 1), :] = rot(k).astype(kp_ref.dtype)[:, None, :]
-    vp_ref[:, 0, pl.dslice(off, 1), :] = v.astype(vp_ref.dtype)[:, None, :]
+    for i in range(heads):
+        qo_ref[0, i:i + 1, :] = \
+            rot(p[:, i * D:(i + 1) * D]).astype(qo_ref.dtype)
+    for i in range(kv_heads):
+        ko, vo = (heads + i) * D, (heads + kv_heads + i) * D
+        kp_ref[i, 0] = _put_row(kp_ref[i, 0], off, rot(p[:, ko:ko + D]))
+        vp_ref[i, 0] = _put_row(vp_ref[i, 0], off, p[:, vo:vo + D])
 
 
 def _qkv_rope_append_int4(he, ho, qw, s, b, trig, k_pages, v_pages,
@@ -233,12 +237,12 @@ def _qkv_rope_append_int4(he, ho, qw, s, b, trig, k_pages, v_pages,
         num_scalar_prefetch=2,
         grid=(T,),
         in_specs=[
-            pl.BlockSpec((1, H2), lambda t, pg, off: (t, 0)),
-            pl.BlockSpec((1, H2), lambda t, pg, off: (t, 0)),
+            pl.BlockSpec((1, 1, H2), lambda t, pg, off: (t, 0, 0)),
+            pl.BlockSpec((1, 1, H2), lambda t, pg, off: (t, 0, 0)),
             pl.BlockSpec((H2, N), lambda t, pg, off: (0, 0)),
             pl.BlockSpec((1, N), lambda t, pg, off: (0, 0)),
             pl.BlockSpec((1, N), lambda t, pg, off: (0, 0)),
-            pl.BlockSpec((1, D), lambda t, pg, off: (t, 0)),
+            pl.BlockSpec((1, 1, D), lambda t, pg, off: (t, 0, 0)),
             page_spec,
             page_spec,
         ],
@@ -253,11 +257,12 @@ def _qkv_rope_append_int4(he, ho, qw, s, b, trig, k_pages, v_pages,
                    jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
                    jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
         input_output_aliases={8: 1, 9: 2},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(page_idx.astype(jnp.int32), page_off.astype(jnp.int32),
-      he, ho, qw, s, b, trig, k_pages, v_pages)
+      he[:, None, :], ho[:, None, :], qw, s, b, trig[:, None, :],
+      k_pages, v_pages)
 
 
 # ---------------------------------------------------------------------------
@@ -272,10 +277,10 @@ def _mla_qkv_rope_append_kernel(pg_ref, off_ref,      # scalar prefetch
                                 lora_rank: int, eps: float):
     t = pl.program_id(0)
     w = w_ref[:].astype(jnp.float32) * s_ref[0].astype(jnp.float32)[None, :]
-    p = jnp.dot(h_ref[:].astype(jnp.float32), w,
+    p = jnp.dot(h_ref[0].astype(jnp.float32), w,
                 preferred_element_type=jnp.float32)    # [1, Nq + r + dr]
-    c = c_ref[:].astype(jnp.float32)                   # [1, dr/2]
-    sn = sn_ref[:].astype(jnp.float32)
+    c = c_ref[0].astype(jnp.float32)                   # [1, dr/2]
+    sn = sn_ref[0].astype(jnp.float32)
 
     def rot(x):                                        # [h, dr] f32
         d2 = x.shape[-1] // 2
@@ -284,9 +289,13 @@ def _mla_qkv_rope_append_kernel(pg_ref, off_ref,      # scalar prefetch
 
     dh = qo_ref.shape[-1]                              # dn + dr
     nq = heads * dh
-    q = p[0, :nq].reshape(heads, dh)
-    q = jnp.concatenate([q[:, :nope_dim], rot(q[:, nope_dim:])], -1)
-    qo_ref[0] = q.astype(qo_ref.dtype)
+    # each head is a static lane window of the row (a [1, N] ->
+    # [heads, dh] reshape moves lanes into sublanes, which Mosaic does
+    # not lower)
+    for i in range(heads):
+        q = p[:, i * dh:(i + 1) * dh]
+        q = jnp.concatenate([q[:, :nope_dim], rot(q[:, nope_dim:])], -1)
+        qo_ref[0, i:i + 1, :] = q.astype(qo_ref.dtype)
     # latent rms norm — the _rms_kernel op order ((x * rsqrt) * w) so
     # the fused latent bitwise-matches the unfused fused_rms_norm row
     lat = p[:, nq:nq + lora_rank]                      # [1, r]
@@ -301,8 +310,7 @@ def _mla_qkv_rope_append_kernel(pg_ref, off_ref,      # scalar prefetch
     def _seed():
         pp_ref[:] = pin_ref[:]
 
-    off = off_ref[t]
-    pp_ref[:, 0, pl.dslice(off, 1), :] = row.astype(pp_ref.dtype)[:, None, :]
+    pp_ref[0, 0] = _put_row(pp_ref[0, 0], off_ref[t], row)
 
 
 def _mla_qkv_rope_append_fwd(h, w, s, g, cos, sin, pool, page_idx,
@@ -323,12 +331,12 @@ def _mla_qkv_rope_append_fwd(h, w, s, g, cos, sin, pool, page_idx,
         num_scalar_prefetch=2,
         grid=(T,),
         in_specs=[
-            pl.BlockSpec((1, H), lambda t, pg, off: (t, 0)),
+            pl.BlockSpec((1, 1, H), lambda t, pg, off: (t, 0, 0)),
             pl.BlockSpec((H, N), lambda t, pg, off: (0, 0)),
             pl.BlockSpec((1, N), lambda t, pg, off: (0, 0)),
             pl.BlockSpec((1, r), lambda t, pg, off: (0, 0)),
-            pl.BlockSpec((1, dd2), lambda t, pg, off: (t, 0)),
-            pl.BlockSpec((1, dd2), lambda t, pg, off: (t, 0)),
+            pl.BlockSpec((1, 1, dd2), lambda t, pg, off: (t, 0, 0)),
+            pl.BlockSpec((1, 1, dd2), lambda t, pg, off: (t, 0, 0)),
             page_spec,
         ],
         out_specs=[pl.BlockSpec((1, heads, dh), lambda t, pg, off: (t, 0, 0)),
@@ -342,11 +350,11 @@ def _mla_qkv_rope_append_fwd(h, w, s, g, cos, sin, pool, page_idx,
         out_shape=[jax.ShapeDtypeStruct((T, heads, dh), h.dtype),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
         input_output_aliases={8: 1},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(page_idx.astype(jnp.int32), page_off.astype(jnp.int32),
-      h, w, s, g, cos, sin, pool)
+      h[:, None, :], w, s, g, cos[:, None, :], sin[:, None, :], pool)
 
 
 # ---------------------------------------------------------------------------
@@ -432,20 +440,26 @@ def megafront_eligible(hidden: int, out_cols: int, head_dim: int, *,
                        dtype_bytes: int = 2) -> bool:
     """True when the fused front-half tiling is launchable: interpret
     mode always (blocks are virtual); on a real TPU the matmul lane
-    dims must be 128-aligned and the packed-int4 layout needs an even
-    contraction dim, and the VMEM-resident qkv slab must fit a 3/4
-    VMEM budget (the remainder covers the token row, trig rows, the
-    two page blocks and the q output block).  Callers fall back to the
-    split norm/dots/rope-append chain when this is False — same math,
-    more HBM round-trips."""
+    dims must be 128-aligned, the packed-int4 layout needs an even
+    contraction dim, and the resident qkv slab must fit the
+    scoped-VMEM limit: the slab as stored (``dtype_bytes`` per fp/int8
+    element; constant index_map — single-buffered) plus, for packed
+    int4, the 4-byte plane Mosaic materializes while unpacking it.
+    The per-token blocks (one hidden row, trig rows, two page blocks,
+    the q row) ride in the slack.  Callers fall back to the split
+    norm/dots/rope-append chain when this is False — same math, more
+    HBM round-trips."""
     if _interpret():
         return True
     if hidden % 128 or out_cols % 128:
         return False
     if int4 and hidden % 2:
         return False
-    wb = dtype_bytes if not int4 else 0.5
-    return hidden * out_cols * wb <= _VMEM_BYTES * 3 // 4
+    if int4:
+        need = (1 + 4) * (hidden // 2) * out_cols
+    else:
+        need = hidden * out_cols * dtype_bytes
+    return need <= _VMEM_LIMIT - _VMEM_SLACK
 
 
 # ---------------------------------------------------------------------------

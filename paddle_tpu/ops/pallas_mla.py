@@ -144,9 +144,7 @@ def mla_decode_attention(q_eff, q_pe, c_lat, c_pe, lengths, *,
                 pltpu.VMEM((nh, 1), jnp.float32),
             ]),
         out_shape=jax.ShapeDtypeStruct((B, nh, r), c_lat.dtype),
-        # jax renamed TPUCompilerParams -> CompilerParams; accept both
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
     )(lens, q_eff, q_pe, c_lat, c_pe)

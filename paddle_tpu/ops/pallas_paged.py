@@ -34,10 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 __all__ = ["paged_decode_attention", "paged_decode_attention_v2",
            "paged_kernel_eligible", "default_pages_per_group"]
 
@@ -253,7 +249,7 @@ def paged_decode_attention_v2(q, k_pages, v_pages, lengths, page_indices,
                     total_pages=total),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, rep, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=_interpret(),
     )(lengths.astype(jnp.int32), tab, qg, k_pages, v_pages)
@@ -295,7 +291,7 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
                         pltpu.VMEM((rep, 1), jnp.float32),
                         pltpu.VMEM((rep, 1), jnp.float32)],
     )
-    cparams = _CompilerParams(
+    cparams = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     out = pl.pallas_call(
         functools.partial(_kernel, page_size=page_size,

@@ -41,10 +41,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_paged import paged_kernel_eligible
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 __all__ = ["ragged_paged_attention", "ragged_attention_reference",
            "ragged_kernel_eligible"]
 
@@ -180,7 +176,7 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     )
     # i is sequential ("arbitrary"): every sequence read-modify-writes
     # the same resident output block
-    cparams = _CompilerParams(
+    cparams = pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary", "arbitrary"))
     out = pl.pallas_call(
         functools.partial(_ragged_kernel, page_size=psz, rep=rep,

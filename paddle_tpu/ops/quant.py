@@ -22,6 +22,10 @@ __all__ = ["weight_quantize", "weight_dequantize", "weight_only_linear",
            "int4_planes", "int4_dequantize"]
 
 
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
 def weight_quantize(w, algo: str = "weight_only_int8"):
     """w [K, N] -> (quantized weight, per-channel scale [N]).
     int8: symmetric absmax; int4: packed two nibbles per int8 byte."""
@@ -102,7 +106,7 @@ def int4_dequantize(qw, scale):
                   pl.BlockSpec((1, bn), lambda j: (0, j))],
         out_specs=pl.BlockSpec((K2 * 2, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((K2 * 2, Np), jnp.float32),
-        interpret=jax.default_backend() != "tpu",
+        interpret=_interpret(),
     )(qw, scale.reshape(1, Np).astype(jnp.float32))
     return out[:, :N]
 
@@ -131,7 +135,7 @@ def _wol_int8_fwd_impl(x2, qw, scale):
                   pl.BlockSpec((N,), lambda i: (0,))],
         out_specs=pl.BlockSpec((bm, N), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, N), x2.dtype),
-        interpret=jax.default_backend() != "tpu",
+        interpret=_interpret(),
     )(x2, qw, scale)
 
 
@@ -200,7 +204,7 @@ def _wol_int4_fwd_impl(x2, qw, scale):
                   pl.BlockSpec((1, bn), lambda i, j: (0, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x2.dtype),
-        interpret=jax.default_backend() != "tpu",
+        interpret=_interpret(),
     )(xe, xo, qw, scale.reshape(1, Np))
     return out[:M, :N]
 

@@ -48,6 +48,7 @@ greedy property; sampling strategies belong to the batch APIs.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, List, Optional, Tuple
 
@@ -319,15 +320,14 @@ class ServingEngine:
               if self._family == "mla"
               else cfg.num_attention_heads * cfg.head_dim)
         int4 = any(k.endswith("_q4") for L in p["layers"] for k in L)
-        self.megadecode = bool(
-            (True if megadecode is None else megadecode)
-            and self.ragged
-            and megadecode_eligible(cfg.hidden_size,
-                                    cfg.intermediate_size, ow,
-                                    int4=int4))
-        #: pallas launches after attention, per layer per decode step —
-        #: the bench A/B row reads this (2 fused vs the 6-stage chain)
-        self.back_half_launches = 2 if self.megadecode else 6
+        int8 = any(k.endswith("_q") for L in p["layers"] for k in L)
+        # stored bytes of one fp/int8 weight element, for the VMEM gates
+        wbytes = 1 if int8 else int(jnp.dtype(dt).itemsize)
+        self._megadecode_gate = functools.partial(
+            megadecode_eligible, cfg.hidden_size, cfg.intermediate_size,
+            ow, int4=int4, dtype_bytes=wbytes)
+        self._megadecode_pin = True if megadecode is None else megadecode
+        self._gate_megadecode()
         # mega-kernel front half (ISSUE 20): the qkv projection matmuls,
         # rope and the paged K/V append collapse to ONE pallas_call
         # after the norm, so the decode layer body is <=5 launches with
@@ -340,7 +340,7 @@ class ServingEngine:
         self.megafront = bool(
             (True if megafront is None else megafront)
             and self.ragged
-            and self._megafront_family_ok(cfg, int4))
+            and self._megafront_family_ok(cfg, int4, wbytes))
         if self.megafront:
             self._concat_qkv_weights()
         #: pallas/XLA launches before attention, per layer per decode
@@ -389,11 +389,28 @@ class ServingEngine:
         else:
             self.controller = None
 
-    def _megafront_family_ok(self, cfg, int4: bool) -> bool:
+    def _flat_rows(self) -> int:
+        """Flat token rows of one unified launch."""
+        return self.max_slots * (1 + self.spec_k) + self.prefill_chunk
+
+    def _gate_megadecode(self) -> None:
+        """(Re)decide the fused back half for the CURRENT flat row count
+        — the kernels' token blocks follow it, so `reconfigure()` asks
+        again before it rebuilds the programs."""
+        self.megadecode = bool(
+            self._megadecode_pin and self.ragged
+            and self._megadecode_gate(tokens=self._flat_rows()))
+        #: pallas launches after attention, per layer per decode step —
+        #: the bench A/B row reads this (2 fused vs the 6-stage chain)
+        self.back_half_launches = 2 if self.megadecode else 6
+
+    def _megafront_family_ok(self, cfg, int4: bool, wbytes: int) -> bool:
         """Per-family tiling/layout gate for the fused front half."""
+        eligible = functools.partial(megafront_eligible,
+                                     dtype_bytes=wbytes)
         if self._family == "gpt":
             # wqkv ships concatenated already; identity trig
-            return megafront_eligible(
+            return eligible(
                 cfg.hidden_size,
                 3 * cfg.num_attention_heads * cfg.head_dim,
                 cfg.head_dim)
@@ -406,11 +423,10 @@ class ServingEngine:
             dh = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
             n = (cfg.num_attention_heads * dh
                  + cfg.kv_lora_rank + cfg.qk_rope_head_dim)
-            return megafront_eligible(cfg.hidden_size, n, dh)
+            return eligible(cfg.hidden_size, n, dh)
         n = (cfg.num_attention_heads
              + 2 * cfg.num_key_value_heads) * cfg.head_dim
-        return megafront_eligible(cfg.hidden_size, n, cfg.head_dim,
-                                  int4=int4)
+        return eligible(cfg.hidden_size, n, cfg.head_dim, int4=int4)
 
     def _split_front_launches(self) -> int:
         """Launches before attention on the SPLIT front path, per layer
@@ -496,6 +512,7 @@ class ServingEngine:
             return False
         self.prefill_chunk = new_chunk
         self.spec_k = new_k
+        self._gate_megadecode()
         self._build_programs()
         self.rebuilds += 1
         if _obs.enabled():

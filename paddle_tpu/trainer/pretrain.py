@@ -28,6 +28,7 @@ from ..amp import decorate_tree
 from ..core.tensor import Tensor
 from ..distributed.mesh import (build_hybrid_mesh, global_device_put,
                                 mesh_context)
+from ..ops.on_mesh import kernel_mesh
 from ..distributed.pipeline import (PP_AXIS, spmd_pipeline,
                                     spmd_pipeline_interleaved,
                                     stack_layer_params,
@@ -437,7 +438,9 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
         def cast_loss(master_params):
             return loss_fn(decorate_tree(master_params, param_dtype),
                            ids, labels)
-        loss, grads = jax.value_and_grad(cast_loss)(state.master)
+        # Pallas kernels in the step run per-shard on this mesh
+        with kernel_mesh(mesh):
+            loss, grads = jax.value_and_grad(cast_loss)(state.master)
         new_master, new_opt, gnorm = tx.update(grads, state.opt_state,
                                                state.master)
         new_params = decorate_tree(new_master, param_dtype)
@@ -450,6 +453,10 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
     data_spec = NamedSharding(mesh, P(("dp", "sharding"), None))
     jstep = jax.jit(train_step, donate_argnums=(0,))
 
-    meta = {"model": model, "mesh": mesh, "data_sharding": data_spec,
+    # the init model is NOT kept: its f32 parameters are a second copy
+    # of the state on the device (1.3 GiB at the 8B shard), stale after
+    # the first step, and at the flagship recipe they were what made a
+    # resumed run miss the step program's scratch reservation
+    meta = {"mesh": mesh, "data_sharding": data_spec,
             "flops_per_token": flops_per_token(mc)}
     return state, jstep, meta

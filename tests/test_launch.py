@@ -3,6 +3,8 @@
 import os
 import textwrap
 
+import pytest
+
 from paddle_tpu.distributed.launch import launch
 
 
@@ -258,3 +260,71 @@ def test_fault_injection_sigkill_worker_recovers(tmp_path):
     assert "rank 1 finished" in (tmp_path / "log" / "workerlog.1").read_text()
     # both ranks completed the retry attempt
     assert (done / "0").exists() and (done / "1").exists()
+
+
+# -- the chip belongs to the workers (ISSUE 21) ------------------------------
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.mark.parametrize("module", ["paddle_tpu.distributed.launch.main",
+                                    "paddle_tpu.distributed.auto_tuner"])
+def test_parent_import_leaves_backend_uninitialised(module):
+    """A launcher / auto-tuner parent imports the package and then spawns
+    workers that need the chip: a parent that initialised the XLA backend
+    would hold it.  Checked in a fresh interpreter."""
+    import subprocess
+    import sys
+    code = (f"import {module}\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, list(xla_bridge._backends)\n")
+    r = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                       capture_output=True, text=True,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert r.returncode == 0, r.stderr[-2000:]
+
+
+def test_devices_are_split_across_local_ranks():
+    from paddle_tpu.distributed.launch.controllers import _visible_chips_env
+    # one rank: every listed chip, no process grid
+    assert _visible_chips_env("0,1,2,3", 0, 1) == {
+        "TPU_VISIBLE_CHIPS": "0,1,2,3"}
+    # four ranks on a four-chip host: one chip each, never all chip 0
+    envs = [_visible_chips_env("0,1,2,3", r, 4) for r in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert {e["TPU_PROCESS_BOUNDS"] for e in envs} == {"2,2,1"}
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    assert [e["CLOUD_TPU_TASK_ID"] for e in envs] == ["0", "1", "2", "3"]
+    with pytest.raises(ValueError):
+        _visible_chips_env("0,1,2", 0, 2)
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch):
+    import jax
+    from paddle_tpu._bootstrap import configure_compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        # set: jax reads the variable itself, code sets no directory
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert configure_compile_cache() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir is None
+        # unset: one fixed path inside the checkout, the same every call
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert configure_compile_cache() == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir \
+            == os.path.join(REPO, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_peak_table_has_no_default():
+    import types
+    from paddle_tpu.device import peaks
+    v5e = types.SimpleNamespace(device_kind="TPU v5 lite", platform="tpu")
+    assert peaks.require_peak(v5e).bf16_flops == 197e12
+    assert peaks.peak(v5e).hbm_bytes_per_s == 819e9
+    cpu = types.SimpleNamespace(device_kind="cpu", platform="cpu")
+    assert peaks.peak(cpu) is None
+    with pytest.raises(RuntimeError, match="cpu"):
+        peaks.require_peak(cpu)

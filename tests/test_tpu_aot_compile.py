@@ -1,0 +1,289 @@
+"""Compile the Pallas kernels of the llama train and serve paths for a
+DESCRIBED TPU v5e, at the widths chip_smoke.py runs — the one file of
+its kind (on-chip-measurement guide §2 step 3).
+
+The TPU compiler is installed off-chip and compiles for a chip that is
+described, not attached; interpret mode never sees what it refuses (a
+block the tiling forbids, a store at an unaligned dynamic sublane
+offset, a lane->sublane reshape, too much VMEM).  Nothing runs here: a
+compile that passes is not a chip run.
+
+Rules this file keeps: the topology is described inside a module-scoped,
+non-autouse fixture (never at import, in a skipif, in parametrize args
+or in conftest.py — only one process may load libtpu and every xdist
+worker imports every test file); compiles happen in the test's own
+process; the persistent compile cache is off around them; and the
+kernels' ``_interpret`` switches are steered from here, not through a
+program option.
+"""
+
+import functools
+import os
+import types
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+# the llama3_8b_shard_config(mp=8, pp=4) geometry under the smoke's
+# engine: hidden 4096, 4 q / 1 kv heads x 128, FFN 1792, vocab slice
+# 16032; T = 8 slots + 32 prefill-chunk rows, page 16, 8*128+1 pages
+T, H, HQ, KV, D, I = 40, 4096, 4, 1, 128, 1792
+PSZ, NP, S, NJ = 16, 8 * 128 + 1, 9, 128
+N = (HQ + 2 * KV) * D
+F32, I8, I32 = jnp.float32, jnp.int8, jnp.int32
+ALGOS = {"bf16": None, "int8": "weight_only_int8",
+         "int4": "weight_only_int4"}
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e chip: ``shape(dims, dtype)`` builds an argument
+    placed on it, ``compiles(fn, *shapes)`` says whether the TPU compiler
+    accepts ``jit(fn)`` (and keeps the reason when it does not)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.ops import (fused, pallas_flash, pallas_megadecode,
+                                pallas_megafront, pallas_ragged, quant)
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    one = SingleDeviceSharding(topo.devices[0])
+    mp = pytest.MonkeyPatch()
+    for mod in (fused, pallas_flash, pallas_megadecode, pallas_megafront,
+                pallas_ragged, quant):
+        mp.setattr(mod, "_interpret", lambda: False)
+    # a described-device executable is written to the persistent cache
+    # but cannot be read back without a chip: keep it off around these
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    # conftest pins "highest" for the CPU numerics tests; the chip runs
+    # the default, and that is what must compile
+    precision_was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", None)
+
+    verdicts, refusals = {}, {}
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def compiles(fn, *shapes):
+        key = (fn, shapes)      # each kernel variant compiles once
+        if key not in verdicts:
+            try:
+                jax.jit(fn).lower(*shapes).compile()
+                verdicts[key] = True
+            except Exception as e:  # noqa: BLE001 - the compiler's refusal
+                refusals[fn] = str(e)[:400]
+                verdicts[key] = False
+        return verdicts[key]
+
+    yield types.SimpleNamespace(shape=shape, compiles=compiles,
+                                refusals=refusals)
+    mp.undo()
+    jax.config.update("jax_default_matmul_precision", precision_was)
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    cc.reset_cache()
+
+
+def _pools(chip, kv=KV, d=D):
+    p = chip.shape((kv, NP, PSZ, d))
+    return p, p
+
+
+def _quant_w(chip, algo, k, n):
+    """(weight, scale) shapes of a [k, n] projection in deploy layout."""
+    if algo == "bf16":
+        return chip.shape((k, n)), None
+    rows = k // 2 if algo == "int4" else k
+    return chip.shape((rows, n), I8), chip.shape((n,), F32)
+
+
+# -- the kernels, as module-level callables so verdict keys are stable --
+
+def _front(algo):
+    from paddle_tpu.ops.pallas_megafront import fused_qkv_rope_append
+
+    def f(h, w, sc, c, s, kp, vp, pg, off):
+        return fused_qkv_rope_append(h, w, sc, None, c, s, kp, vp, pg, off,
+                                     heads=HQ, kv_heads=KV, head_dim=D,
+                                     algo=ALGOS[algo])
+    return f
+
+
+def _oproj(algo):
+    from paddle_tpu.ops.pallas_megadecode import fused_oproj_norm
+    return lambda o, x, w, sc, nw: fused_oproj_norm(
+        o, x, w, sc, None, nw, None, algo=ALGOS[algo])
+
+
+def _ffn(algo):
+    from paddle_tpu.ops.pallas_megadecode import fused_ffn
+    return lambda h, x, wg, sg, wu, su, wd, sd: fused_ffn(
+        h, x, wg, sg, wu, su, wd, sd, algo=ALGOS[algo])
+
+
+_FRONT = {a: _front(a) for a in ALGOS}
+_OPROJ = {a: _oproj(a) for a in ALGOS}
+_FFN = {a: _ffn(a) for a in ALGOS}
+
+
+def _front_args(chip, algo):
+    w, sc = _quant_w(chip, algo, H, N)
+    tok = chip.shape((T,), I32)
+    trig = chip.shape((T, D // 2), F32)
+    return (chip.shape((T, H)), w, sc, trig, trig, *_pools(chip), tok, tok)
+
+
+def _back_compiles(chip, algo, hidden, inter, ow):
+    """Both back-half kernels at one geometry."""
+    act = chip.shape((T, hidden))
+    w, sc = _quant_w(chip, algo, ow, hidden)
+    ok1 = chip.compiles(_OPROJ[algo], chip.shape((T, ow)), act, w, sc,
+                        chip.shape((hidden,)))
+    wg, sg = _quant_w(chip, algo, hidden, inter)
+    wd, sd = _quant_w(chip, algo, inter, hidden)
+    ok2 = chip.compiles(_FFN[algo], act, act, wg, sg, wg, sg, wd, sd)
+    return ok1 and ok2
+
+
+# ---------------------------------------------------------------------------
+# serve path
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("algo", list(ALGOS))
+def test_fused_qkv_rope_append_compiles(chip, algo):
+    """The default front half (one-token blocks, page-row append)."""
+    f = _FRONT[algo]
+    assert chip.compiles(f, *_front_args(chip, algo)), chip.refusals.get(f)
+
+
+def _rope_append(q, k, v, c, s, kp, vp, pg, off):
+    from paddle_tpu.ops.fused import fused_rope_append
+    return fused_rope_append(q, k, v, c, s, kp, vp, pg, off)
+
+
+def test_fused_rope_append_compiles(chip):
+    """The split front half (megafront off / ineligible)."""
+    tok = chip.shape((T,), I32)
+    trig = chip.shape((T, D // 2), F32)
+    assert chip.compiles(
+        _rope_append, chip.shape((T, HQ, D)), chip.shape((T, KV, D)),
+        chip.shape((T, KV, D)), trig, trig, *_pools(chip), tok, tok), \
+        chip.refusals.get(_rope_append)
+
+
+def _append_rows(pages, rows, pg, off):
+    from paddle_tpu.ops.fused import fused_append_rows
+    return fused_append_rows(pages, rows, pg, off)
+
+
+def test_fused_append_rows_compiles(chip):
+    """The MLA split front's latent-row append ([latent 512 | rope 64]
+    rows — same page-row write as the llama kernels)."""
+    tok = chip.shape((T,), I32)
+    assert chip.compiles(_append_rows, chip.shape((1, NP, PSZ, 576)),
+                         chip.shape((T, 1, 576)), tok, tok), \
+        chip.refusals.get(_append_rows)
+
+
+def _ragged(q, kp, vp, ss, nt, kvl, tab):
+    from paddle_tpu.ops.pallas_ragged import ragged_paged_attention
+    return ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab)
+
+
+def test_ragged_paged_attention_compiles_where_gate_says(chip):
+    from paddle_tpu.ops.pallas_ragged import ragged_kernel_eligible
+    seq = chip.shape((S,), I32)
+    assert ragged_kernel_eligible(HQ, KV, D, PSZ)
+    assert chip.compiles(_ragged, chip.shape((T, HQ, D)), *_pools(chip),
+                         seq, seq, seq, chip.shape((S, NJ), I32)), \
+        chip.refusals.get(_ragged)
+
+
+def _serve_norm_and_linears(x, nw, w8, s8, w4, s4):
+    """What the engine's split chain adds around the kernels above:
+    the rms norm and, on quantized deploys, the weight-only linears."""
+    from paddle_tpu.ops.fused import fused_rms_norm
+    from paddle_tpu.ops.quant import weight_only_linear
+    h = fused_rms_norm(x, nw, 1e-6)
+    return (weight_only_linear(h, w8, s8, algo="weight_only_int8"),
+            weight_only_linear(h, w4, s4, algo="weight_only_int4"))
+
+
+def test_norm_and_weight_only_linears_compile(chip):
+    w8, s8 = _quant_w(chip, "int8", H, I)
+    w4, s4 = _quant_w(chip, "int4", H, I)
+    assert chip.compiles(_serve_norm_and_linears, chip.shape((T, H)),
+                         chip.shape((H,)), w8, s8, w4, s4), \
+        chip.refusals.get(_serve_norm_and_linears)
+
+
+# ---------------------------------------------------------------------------
+# the gates agree with the compiler
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("algo", list(ALGOS))
+def test_megafront_gate_matches_compiler(chip, algo):
+    from paddle_tpu.ops.pallas_megafront import megafront_eligible
+    wbytes = 1 if algo == "int8" else 2
+    says = megafront_eligible(H, N, D, int4=algo == "int4",
+                              dtype_bytes=wbytes)
+    assert says == chip.compiles(_FRONT[algo], *_front_args(chip, algo))
+    assert says     # the smoke's engine takes the fused front
+
+
+@pytest.mark.parametrize("algo", list(ALGOS))
+def test_megadecode_gate_matches_compiler(chip, algo):
+    """At the smoke widths the resident FFN slabs do not fit VMEM in any
+    layout: the gate says so, the compiler agrees, and the engine's back
+    half is the split chain (re-tiling fused_ffn is a later perf_opt).
+    At the 8-way-shard hidden the same kernels fit, and compile."""
+    from paddle_tpu.ops.pallas_megadecode import megadecode_eligible
+    gate = functools.partial(megadecode_eligible, int4=algo == "int4",
+                             dtype_bytes=1 if algo == "int8" else 2,
+                             tokens=T)
+    assert not gate(H, I, HQ * D)
+    assert not _back_compiles(chip, algo, H, I, HQ * D)
+    assert gate(512, I, 512)
+    assert _back_compiles(chip, algo, 512, I, 512)
+
+
+# ---------------------------------------------------------------------------
+# train path (run_pretrain's llama3_8b_shard recipe: batch 3, seq 8192)
+# ---------------------------------------------------------------------------
+
+def _flash_loss(q, k, v):
+    from paddle_tpu.ops.pallas_flash import flash_sdpa
+    return flash_sdpa(q, k, v, causal=True).astype(F32).sum()
+
+
+def test_flash_attention_fwd_and_grad_compile(chip):
+    qkv = chip.shape((3, 8192, HQ, D))   # sdpa repeats kv heads first
+    f = jax.grad(_flash_loss, argnums=(0, 1, 2))
+    assert chip.compiles(f, qkv, qkv, qkv), chip.refusals.get(f)
+
+
+def _train_elementwise_loss(x, nw, q, k, cos, sin, g, u):
+    from paddle_tpu.ops.fused import fused_rms_norm, fused_rope, swiglu
+    qr, kr = fused_rope(q, k, cos, sin)
+    return (fused_rms_norm(x, nw, 1e-6).astype(F32).sum()
+            + qr.astype(F32).sum() + kr.astype(F32).sum()
+            + swiglu(g, u).astype(F32).sum())
+
+
+def test_train_norm_rope_swiglu_fwd_and_grad_compile(chip):
+    B, Sq = 3, 8192
+    trig = chip.shape((Sq, D // 2), F32)
+    act = chip.shape((B, Sq, I))
+    f = jax.grad(_train_elementwise_loss, argnums=(0, 1, 2, 3, 6, 7))
+    assert chip.compiles(
+        f, chip.shape((B, Sq, H)), chip.shape((H,)),
+        chip.shape((B, Sq, HQ, D)), chip.shape((B, Sq, KV, D)),
+        trig, trig, act, act), chip.refusals.get(f)
