@@ -1,0 +1,8 @@
+"""Mean ``prefill_rows`` of the step records over the window's steps:
+rows of the launch that belong to the one prefill chunk."""
+
+from benchmarks.lib.program_spans import count_mean
+
+
+def read(h):
+    return count_mean(h, "prefill_rows")

@@ -20,11 +20,18 @@ writer (`StepLogger`) whose records carry span ids minted by `span()` —
 the same ids are embedded in the chrome-trace event names the host
 profiler exports, so step rows and trace spans correlate.
 
+`span(name, **args)` is the one way the program opens a host span: a
+`jax.profiler.TraceAnnotation` under the plain name (on the device
+trace's clock whenever a profiler trace is on), an in-memory record in
+`tracing.recorder()`, and the legacy native `RecordEvent`
+(docs/OBSERVABILITY.md, "Host spans, the step key and the profiler's
+clock").
+
 Overhead contract: every mutation checks `FLAGS_metrics` FIRST via a
 cached flag-object attribute read, so with the flag off an instrumented
 call is one function call + one attribute test (no locks, no dict
-lookups). `tests/test_observability.py` gates this at <5% on a tight
-instrumented loop.
+lookups). `tests/test_observability.py::TestOverhead` gates what a
+disabled entry point does: calls made, locks taken, objects kept.
 """
 
 from __future__ import annotations
@@ -441,29 +448,96 @@ def sample_values(reg: Optional[Registry] = None) -> Dict[str, float]:
 # ---------------------------------------------------------------------------
 
 _span_seq = itertools.count(1)
+# the pid half of a span id, kept: os.getpid() is a system call on every
+# span (5 us on the chip's sandboxed host, my chip run, PR 24)
+_pid = os.getpid()
+
+
+def _pid_after_fork() -> None:
+    global _pid
+    _pid = os.getpid()
+
+
+os.register_at_fork(after_in_child=_pid_after_fork)
+
+
+def _new_span_id() -> str:
+    """A process-unique id ``<pid>-<seq>``: host spans and request
+    traces draw from one sequence."""
+    return f"{_pid}-{next(_span_seq)}"
+
+
+# per-thread stack of the open spans: the top is the next span's parent
+_open_spans = threading.local()
+# jax.profiler.TraceAnnotation and native.RecordEvent, imported at the
+# first span (importing this package must not load the profiler, let
+# alone start a backend)
+_TraceAnnotation = _RecordEvent = None
 
 
 class _Span:
-    """Context manager wrapping a host-profiler RecordEvent whose name
-    embeds a unique span id; `StepLogger.log(..., span_id=sp.span_id)`
-    writes the same id, so JSONL rows join chrome-trace events on it."""
+    """One host span, the single way the program opens one.
 
-    def __init__(self, name: str):
+    Sinks: (1) a `jax.profiler.TraceAnnotation` under the PLAIN name
+    with `args` as its arguments — whenever anyone's profiler trace is
+    on, the span is a host event in the same ``.xplane.pb`` and on the
+    same clock as the device's operations, and costs a fraction of a
+    microsecond otherwise; (2) with FLAGS_request_tracing on, one
+    ``(name, start_ns, end_ns, parent, step)`` record in the process
+    `tracing.recorder()`, in memory; (3) the legacy sink, a native
+    host-profiler `RecordEvent` whose name embeds a unique span id —
+    `StepLogger.log(..., span_id=sp.span_id)` writes the same id, so
+    JSONL rows join `paddle.profiler.Profiler`'s chrome export on it.
+
+    `step` is the argument of that name, or else the enclosing span's:
+    the key that joins spans, step records and request stamps."""
+
+    __slots__ = ("name", "span_id", "step", "_ev", "_ann", "_t0",
+                 "_parent")
+
+    def __init__(self, name: str, args: Mapping[str, Any]):
+        global _TraceAnnotation, _RecordEvent
+        if _TraceAnnotation is None:
+            from jax.profiler import TraceAnnotation as _TraceAnnotation
+            from ..native import RecordEvent as _RecordEvent
         self.name = name
-        self.span_id = f"{os.getpid()}-{next(_span_seq)}"
-        from ..native import RecordEvent
-        self._ev = RecordEvent(f"{name}[span={self.span_id}]")
+        self.span_id = _new_span_id()
+        self.step = args.get("step")
+        self._ev = _RecordEvent(f"{name}[span={self.span_id}]")
+        self._ann = _TraceAnnotation(name, **args)
+        self._t0 = 0
+        self._parent: Optional[str] = None
 
     def __enter__(self):
+        self._ann.__enter__()
         self._ev.__enter__()
+        if tracing._FLAG.value:
+            stack = getattr(_open_spans, "stack", None)
+            if stack is None:
+                stack = _open_spans.stack = []
+            if stack:
+                self._parent = stack[-1].name
+                if self.step is None:
+                    self.step = stack[-1].step
+            stack.append(self)
+            self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        return self._ev.__exit__(*exc)
+        if self._t0:
+            t1 = time.perf_counter_ns()
+            _open_spans.stack.pop()
+            tracing._default_recorder._span_done(
+                self.name, self._t0, t1, self._parent, self.step)
+        self._ev.__exit__(*exc)
+        self._ann.__exit__(*exc)
+        return False
 
 
-def span(name: str) -> _Span:
-    return _Span(name)
+def span(name: str, **args: Any) -> _Span:
+    """Open host span `name`; `args` (ids, counts — never pasted into
+    the name) become the profiler event's arguments."""
+    return _Span(name, args)
 
 
 class StepLogger:

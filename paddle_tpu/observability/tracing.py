@@ -21,16 +21,24 @@ and observe them into registry histograms:
 bucket counts (linear interpolation within the landing bucket — exact
 whenever observations sit on bucket bounds), and `slo_summary()` renders
 the standard serving table. `TraceRecorder.export_chrome_trace` writes
-the timelines as chrome-trace JSON whose span ids share the namespace
-(and the ``name[span=<pid>-<seq>]`` convention) of the host-profiler
-events `observability.span` emits, so request rows and host-profiler
-spans correlate in one viewer; each stamp taken inside an engine step
-additionally carries the step's host span id in its args.
+the timelines as chrome-trace JSON whose lifetime spans share the
+``name[span=<pid>-<seq>]`` convention of the legacy host-profiler events
+`observability.span` emits.
+
+The recorder also keeps what `observability.span` records — every host
+span as ``(name, start_ns, end_ns, parent, step)`` — and one STEP RECORD
+per `ServingEngine.step()` (`open_step` / `close_step`): the step's
+phases and the counts taken where the work happens (`STEP_COUNTS`), in
+a ring of their own. The step is the one join key: each stamp made
+inside a step carries ``step=<seq>``, the step's spans carry it in
+memory, and its profiler event (`jax.profiler.TraceAnnotation`, on the
+device trace's clock) carries it as an argument.
 
 Overhead contract (same as the metrics layer): every entry point checks
 the cached ``FLAGS_request_tracing`` flag object FIRST, so with tracing
-off a stamp costs one function call + one attribute test. Gated at <5%
-alongside the metrics gate in tests/test_observability.py::TestOverhead.
+off a stamp costs one function call + one attribute test. Gated
+alongside the metrics gate in tests/test_observability.py::TestOverhead
+(calls made, locks taken, objects kept).
 
 Thread discipline (paddlelint PT006): all recorder state — the live
 table, the finished-trace ring, the exporter file handle — is touched
@@ -48,11 +56,11 @@ from collections import deque
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
 from .. import flags as _flags
-from . import DEFAULT_BUCKETS, Histogram, _span_seq, registry
+from . import DEFAULT_BUCKETS, Histogram, _new_span_id, registry
 
 __all__ = ["TraceEvent", "RequestTrace", "TraceRecorder", "recorder",
            "enabled", "set_enabled", "percentile", "percentiles",
-           "slo_summary", "SLO_METRICS"]
+           "slo_summary", "SLO_METRICS", "STEP_COUNTS"]
 
 _FLAG = _flags._registry["FLAGS_request_tracing"]
 
@@ -96,9 +104,18 @@ _H_E2E = registry().histogram(
     "enqueue -> completion per finished request", buckets=DEFAULT_BUCKETS)
 
 
+#: the counts of one step record, taken by the engine where the work
+#: happens (docs/OBSERVABILITY.md says what each one is)
+STEP_COUNTS: Tuple[str, ...] = (
+    "decode_rows", "prefill_rows", "live", "waiting", "admitted",
+    "finished", "preempted", "cow_pages", "pages_live", "pages_visited",
+    "pool_pages_used", "pool_pages_total")
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
 class TraceEvent:
     """One monotonic stamp: name, microsecond timestamp, optional meta
-    (token index, chunk size, host-profiler span id, explicit dur_us)."""
+    (token index, chunk size, engine step, explicit dur_us)."""
 
     __slots__ = ("name", "t_us", "meta")
 
@@ -134,7 +151,7 @@ class RequestTrace:
         self.request_id = request_id
         self.kind = kind
         # same namespace + format as observability.span host spans
-        self.span_id = f"{os.getpid()}-{next(_span_seq)}"
+        self.span_id = _new_span_id()
         self.outcome: Optional[str] = None
         self.meta = dict(meta) if meta else {}
         self._events: List[TraceEvent] = []
@@ -168,6 +185,18 @@ class RequestTrace:
     def queue_wait_s(self) -> Optional[float]:
         return self._gap_s(self.first("enqueue"), self.first("admit"))
 
+    def prefill_wait_s(self) -> Optional[float]:
+        """First admit -> first prefill chunk: the wait, holding a slot,
+        behind the chunks of the prompts admitted earlier."""
+        return self._gap_s(self.first("admit"),
+                           self.first("prefill_chunk"))
+
+    def prefill_run_s(self) -> Optional[float]:
+        """First prefill chunk -> first token. With `queue_wait_s` and
+        `prefill_wait_s` it sums to `ttft_s` by construction."""
+        return self._gap_s(self.first("prefill_chunk"),
+                           self.first("token"))
+
     def ttft_s(self) -> Optional[float]:
         return self._gap_s(self.first("enqueue"), self.first("token"))
 
@@ -188,6 +217,8 @@ class RequestTrace:
                 "span_id": self.span_id, "outcome": self.outcome,
                 "meta": self.meta,
                 "queue_wait_s": self.queue_wait_s(),
+                "prefill_wait_s": self.prefill_wait_s(),
+                "prefill_run_s": self.prefill_run_s(),
                 "ttft_s": self.ttft_s(), "tpot_s": self.tpot_s(),
                 "e2e_s": self.e2e_s(),
                 "events": [e.to_dict() for e in self._events]}
@@ -206,9 +237,10 @@ class TraceRecorder:
     All mutation goes through `begin` / `stamp` / `finish`, each gated on
     FLAGS_request_tracing first. Finished traces move to a bounded ring
     (FLAGS_trace_ring_size, oldest evicted) so a long-lived serving
-    process cannot grow without bound. An optional background exporter
-    thread drains finished traces to JSONL; it shares the same lock as
-    every other accessor (paddlelint PT006 discipline).
+    process cannot grow without bound; host spans and step records
+    each have a ring of the same capacity. An optional background
+    exporter thread drains finished traces to JSONL; it shares the same
+    lock as every other accessor (paddlelint PT006 discipline).
     """
 
     def __init__(self, capacity: Optional[int] = None):
@@ -219,7 +251,11 @@ class TraceRecorder:
         self._done: deque = deque(maxlen=int(capacity))
         self._capacity = int(capacity)
         self._counters: Dict[str, deque] = {}
-        self._host_span: Optional[str] = None
+        self._spans: deque = deque(maxlen=int(capacity))
+        self._steps: deque = deque(maxlen=int(capacity))
+        self._open_step: Optional[Dict[str, Any]] = None
+        self._compiles = 0
+        self._listening = False
         self._replica: Optional[str] = None
         self._export_f = None
         self._export_thread: Optional[threading.Thread] = None
@@ -249,9 +285,9 @@ class TraceRecorder:
             tr = self._live.get(request_id)
             if tr is None:
                 return
-            hs = self._host_span
-            if hs is not None and "host_span" not in meta:
-                meta["host_span"] = hs
+            st = self._open_step
+            if st is not None and "step" not in meta:
+                meta["step"] = st["seq"]
             rp = self._replica
             if rp is not None and "replica" not in meta:
                 meta["replica"] = rp
@@ -287,13 +323,55 @@ class TraceRecorder:
             if e2e is not None:
                 _H_E2E.observe(e2e)
 
-    def set_host_span(self, span_id: Optional[str]) -> None:
-        """Record the host-profiler span id of the engine step currently
-        executing; subsequent stamps carry it for trace correlation."""
+    # ------------------------------------------------- spans and steps
+    def _span_done(self, name: str, start_ns: int, end_ns: int,
+                   parent: Optional[str], step: Optional[int]) -> None:
+        """`observability.span`'s in-memory sink (the span checked the
+        flag when it opened). A span of the open step also lands in that
+        step's record: the step's own span gives its start and end, its
+        direct children are its phases."""
+        with self._lock:
+            self._spans.append((name, start_ns, end_ns, parent, step))
+            st = self._open_step
+            if st is not None and step == st["seq"]:
+                if parent == st["name"]:
+                    st["phases"].append((name, start_ns, end_ns))
+                elif name == st["name"]:
+                    st["start_ns"], st["end_ns"] = start_ns, end_ns
+
+    def _on_compile(self, event: str, secs: float, **kw) -> None:
+        if event == _COMPILE_EVENT:
+            with self._lock:
+                self._compiles += 1
+
+    def open_step(self, seq: int, name: str) -> None:
+        """Open the record of engine step `seq`, whose span is `name`.
+        Until `close_step`, stamps carry ``step=seq`` and the step's
+        spans land in the record. The first call registers the one
+        `jax.monitoring` listener that counts compiles."""
         if not _FLAG.value:
             return
+        if not self._listening:
+            import jax.monitoring
+            jax.monitoring.register_event_duration_secs_listener(
+                self._on_compile)
+            self._listening = True
         with self._lock:
-            self._host_span = span_id
+            self._open_step = {
+                "seq": int(seq), "name": name, "replica": self._replica,
+                "start_ns": None, "end_ns": None, "phases": [],
+                "compiles": self._compiles}
+
+    def close_step(self, counts: Mapping[str, int]) -> None:
+        """Close the open step record with the engine's `counts`
+        (`STEP_COUNTS`) and move it to the step ring."""
+        with self._lock:
+            st, self._open_step = self._open_step, None
+            if st is None:
+                return
+            st.update(counts)
+            st["compiles"] = self._compiles - st["compiles"]
+            self._steps.append(st)
 
     def set_replica_context(self, name: Optional[str]) -> None:
         """Record which fleet replica is currently stamping; subsequent
@@ -388,6 +466,23 @@ class TraceRecorder:
         with self._lock:
             return {k: list(v) for k, v in self._counters.items()}
 
+    def spans(self) -> List[Tuple[str, int, int, Optional[str],
+                                  Optional[int]]]:
+        """The newest host spans, oldest first:
+        ``(name, start_ns, end_ns, parent, step)`` on the
+        `time.perf_counter_ns` clock."""
+        with self._lock:
+            return list(self._spans)
+
+    def steps(self) -> List[Dict[str, Any]]:
+        """Copies of the newest step records, oldest first: ``seq``,
+        ``name``, ``replica``, ``start_ns`` / ``end_ns``, ``phases``
+        (``[(span name, start_ns, end_ns)]``, disjoint, inside the
+        step), ``compiles`` and the `STEP_COUNTS`."""
+        with self._lock:
+            return [dict(st, phases=list(st["phases"]))
+                    for st in self._steps]
+
     def trace(self, request_id) -> Optional[RequestTrace]:
         """Most recent trace for `request_id`: live first, then the
         newest matching finished one."""
@@ -419,7 +514,9 @@ class TraceRecorder:
             self._done.clear()
             self._counters.clear()
             self._pending_export.clear()
-            self._host_span = None
+            self._spans.clear()
+            self._steps.clear()
+            self._open_step = None
             self._replica = None
 
     # ------------------------------------------------------- chrome export
@@ -433,14 +530,30 @@ class TraceRecorder:
         instant per point event, and one ``ph:"C"`` counter event per
         counter-track sample (gauge series — page-pool utilization,
         HBM accounting — rendered by Perfetto as value-over-time tracks
-        on the same clock). Returns the event count; the file
+        on the same clock). The step records are one more row
+        (``tid`` 0): a span per step with its counts as args, its
+        phases nested inside. Returns the event count; the file
         round-trips through `profiler.load_profiler_result`."""
         with self._lock:
             traces = list(self._done) + \
                 (list(self._live.values()) if include_live else [])
             counters = {k: list(v) for k, v in self._counters.items()}
+            steps = list(self._steps)
         pid = os.getpid()
         events: List[Dict[str, Any]] = []
+        for st in steps:
+            if st["start_ns"] is None:
+                continue    # the step's span never closed
+            counts = {k: v for k, v in st.items()
+                      if k not in ("name", "phases", "start_ns", "end_ns")}
+            rows = [(st["name"], st["start_ns"], st["end_ns"], counts)]
+            rows += [(n, a, b, {"step": st["seq"]})
+                     for n, a, b in st["phases"]]
+            for name, a, b, args in rows:
+                events.append({
+                    "name": name, "ph": "X", "pid": pid, "tid": 0,
+                    "ts": a // 1000, "dur": max((b - a) // 1000, 1),
+                    "cat": "step", "args": args})
         for tid, tr in enumerate(traces, start=1):
             evs = tr.timeline()
             if not evs:

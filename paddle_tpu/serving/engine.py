@@ -349,6 +349,10 @@ class ServingEngine:
         self.front_half_launches = 2 if self.megafront \
             else self._split_front_launches()
         self.launches = 0      # device program launches by THIS engine
+        self.steps = 0         # step() calls: the step timeline's `seq`
+        # the open step's counts, taken where the work happens and
+        # closed into the recorder's step record (tracing.STEP_COUNTS)
+        self._counts = dict.fromkeys(_tracing.STEP_COUNTS, 0)
 
         # live HBM accounting (ISSUE 11): static residency is published
         # once; a cumulative analytical ledger turns each launch into
@@ -572,25 +576,44 @@ class ServingEngine:
         for observability/benching."""
         out = {"admitted": 0, "prefill_tokens": 0, "decoded": 0,
                "finished": 0}
+        self.steps += 1
+        self._counts = dict.fromkeys(_tracing.STEP_COUNTS, 0)
         _TRACE.set_replica_context(self.replica)
-        for req in self.scheduler.expire_waiting():
-            # a PREEMPTED request expiring in the queue still owns its
-            # allocator sequence (pages kept for the resume that never
-            # came) — free it here or the pool leaks
-            if self.allocator.has_seq(req.request_id):
-                self.allocator.free(req.request_id)
-            if _obs.enabled():
-                _REQS.labels(outcome="overloaded"
-                             if isinstance(req.result, _res.Overloaded)
-                             else "timeout").inc()
-            out["finished"] += 1
-        # deadline sweep over in-flight requests: partial result, pages
-        # freed immediately
-        for _, req in list(self.scheduler.active()):
-            if req.deadline_expired():
-                self._finish(req)
+        _TRACE.open_step(self.steps, "serving.engine.step")
+        try:
+            with _obs.span("serving.engine.step", step=self.steps):
+                self._step_phases(out)
+        finally:
+            self._counts["admitted"] = out["admitted"]
+            self._counts["finished"] = out["finished"]
+            _TRACE.close_step(self._counts)
+        return out
+
+    def _step_phases(self, out: Dict[str, int]) -> None:
+        """The step timeline: disjoint child spans of
+        ``serving.engine.step`` — admit, then build / launch / sync /
+        sample inside the step function(s), then account."""
+        with _obs.span("serving.engine.admit"):
+            for req in self.scheduler.expire_waiting():
+                # a PREEMPTED request expiring in the queue still owns
+                # its allocator sequence (pages kept for the resume
+                # that never came) — free it here or the pool leaks
+                if self.allocator.has_seq(req.request_id):
+                    self.allocator.free(req.request_id)
+                if _obs.enabled():
+                    _REQS.labels(outcome="overloaded"
+                                 if isinstance(req.result, _res.Overloaded)
+                                 else "timeout").inc()
                 out["finished"] += 1
-        out["admitted"] = self._admit()
+            # deadline sweep over in-flight requests: partial result,
+            # pages freed immediately
+            for _, req in list(self.scheduler.active()):
+                if req.deadline_expired():
+                    self._finish(req)
+                    out["finished"] += 1
+            out["admitted"] = self._admit()
+            self._counts["live"] = self.scheduler.inflight
+            self._counts["waiting"] = len(self.scheduler.waiting)
         if self.ragged:
             pf, dec, fin = self._unified_step()
             out["prefill_tokens"] = pf
@@ -601,17 +624,21 @@ class ServingEngine:
             out["finished"] += fin
             out["decoded"], fin = self._decode()
             out["finished"] += fin
-        if _obs.enabled():
-            _ACTIVE.set(self.scheduler.inflight)
-            _WAITING.set(len(self.scheduler.waiting))
-            self._account_step(out)
-        self.allocator.publish_gauges()
-        if _obs.enabled():
-            # counter tracks move in lockstep with the step spans
-            _TRACE.sample_gauges(_COUNTER_GAUGES)
-        if self.controller is not None:
-            self.controller.on_step(out)
-        return out
+        with _obs.span("serving.engine.account"):
+            # what the observability itself costs, measured by itself
+            if _obs.enabled():
+                _ACTIVE.set(self.scheduler.inflight)
+                _WAITING.set(len(self.scheduler.waiting))
+                self._account_step(out)
+            self.allocator.publish_gauges()
+            if _obs.enabled():
+                # counter tracks move in lockstep with the step spans
+                _TRACE.sample_gauges(_COUNTER_GAUGES)
+            if self.controller is not None:
+                self.controller.on_step(out)
+            self._counts["pool_pages_total"] = self.allocator.num_pages - 1
+            self._counts["pool_pages_used"] = \
+                self.allocator.num_pages - 1 - self.allocator.free_pages
 
     # ------------------------------------------------- HBM accounting
     def _account_step(self, out: Dict[str, int]) -> None:
@@ -1031,6 +1058,7 @@ class ServingEngine:
             if need > spare:
                 return None
         self.scheduler.preempt(victim)
+        self._counts["preempted"] += 1
         if _obs.enabled():
             _PREEMPTIONS.inc()
         return cand
@@ -1046,34 +1074,35 @@ class ServingEngine:
         if not self._prefill_fifo:
             return 0, 0
         req = self._prefill_fifo[0]
-        n = min(self.prefill_chunk, int(req.prompt.size) - req.prefill_pos)
-        start = req.prefill_pos
-        self._apply_copies(self.allocator.extend(req.request_id, n), req)
-        ids = np.zeros((1, self.prefill_chunk), np.int32)
-        ids[0, :n] = req.prompt[start:start + n]
-        table = self.allocator.table(req.request_id)[None]
-        if _tracing.enabled():
-            # the host span's id rides along on every stamp taken inside
-            # this launch, so request timelines join the profiler trace
-            with _obs.span("serving.engine.prefill_chunk") as sp:
-                logits, self._pools = self._jit_prefill(
-                    self._w, jnp.asarray(ids), self._pools,
-                    jnp.asarray(table), np.int32(start), np.int32(n))
-            _TRACE.set_host_span(sp.span_id)
-            _TRACE.stamp(req.request_id, "prefill_chunk", tokens=n,
-                         start=start)
-        else:
+        with _obs.span("serving.engine.build"):
+            n = min(self.prefill_chunk,
+                    int(req.prompt.size) - req.prefill_pos)
+            start = req.prefill_pos
+            self._apply_copies(self.allocator.extend(req.request_id, n),
+                               req)
+            ids = np.zeros((1, self.prefill_chunk), np.int32)
+            ids[0, :n] = req.prompt[start:start + n]
+            table = self.allocator.table(req.request_id)[None]
+            self._counts["prefill_rows"] += n
+            self._counts["pages_live"] += -(-(start + n) // self.page_size)
+            self._counts["pages_visited"] += self.pages_per_seq
+        with _obs.span("serving.engine.launch"):
             logits, self._pools = self._jit_prefill(
                 self._w, jnp.asarray(ids), self._pools, jnp.asarray(table),
                 np.int32(start), np.int32(n))
-        req.prefill_pos += n
-        self.launches += 1
-        if _obs.enabled():
-            _LAUNCHES.labels(path="split").inc()
-            _STEPS.labels(phase="prefill").inc()
-            _TOKENS.labels(phase="prefill").inc(n)
-        finished = 0
-        if req.prefill_pos == int(req.prompt.size):
+            _TRACE.stamp(req.request_id, "prefill_chunk", tokens=n,
+                         start=start)
+            req.prefill_pos += n
+            self.launches += 1
+            if _obs.enabled():
+                _LAUNCHES.labels(path="split").inc()
+                _STEPS.labels(phase="prefill").inc()
+                _TOKENS.labels(phase="prefill").inc(n)
+        if req.prefill_pos < int(req.prompt.size):
+            return n, 0     # mid-prompt: the logits are never read
+        with _obs.span("serving.engine.sync"):
+            row = np.asarray(logits[0])
+        with _obs.span("serving.engine.sample"):
             self._prefill_fifo.pop(0)
             req.state = DECODE
             # cache the full prompt pages BEFORE _emit can finish the
@@ -1081,12 +1110,9 @@ class ServingEngine:
             if self.prefix_cache is not None and self.prefix_cache_admit:
                 self.prefix_cache.insert(
                     req.prompt, self.allocator.seq_pages(req.request_id))
-            tok = int(np.argmax(np.asarray(logits[0])))
-            fin = self._emit(req, tok)
-            finished += fin
-            if not fin and self.role == "prefill":
+            finished = self._emit(req, int(np.argmax(row)))
+            if not finished and self.role == "prefill":
                 self._stage_handoff(req)
-        _TRACE.set_host_span(None)
         return n, finished
 
     # ------------------------------------------------------------- decode
@@ -1094,36 +1120,36 @@ class ServingEngine:
         active = self.scheduler.active(DECODE)
         if not active:
             return 0, 0
-        B = self.max_slots
-        tok = np.zeros(B, np.int32)
-        lengths = np.zeros(B, np.int32)
-        tables = np.zeros((B, self.pages_per_seq), np.int32)
-        for slot, req in active:
-            tok[slot] = req.pending
-            lengths[slot] = self.allocator.seq_length(req.request_id)
-            self._apply_copies(self.allocator.extend(req.request_id, 1),
-                               req)
-            tables[slot] = self.allocator.table(req.request_id)
-        if _tracing.enabled():
-            with _obs.span("serving.engine.decode_step") as sp:
-                logits, self._pools = self._jit_decode(
-                    self._w, jnp.asarray(tok), self._pools,
-                    jnp.asarray(lengths), jnp.asarray(tables))
-            _TRACE.set_host_span(sp.span_id)
-        else:
+        with _obs.span("serving.engine.build"):
+            B = self.max_slots
+            tok = np.zeros(B, np.int32)
+            lengths = np.zeros(B, np.int32)
+            tables = np.zeros((B, self.pages_per_seq), np.int32)
+            for slot, req in active:
+                tok[slot] = req.pending
+                lengths[slot] = self.allocator.seq_length(req.request_id)
+                self._apply_copies(
+                    self.allocator.extend(req.request_id, 1), req)
+                tables[slot] = self.allocator.table(req.request_id)
+            self._counts["decode_rows"] += len(active)
+            self._counts["pages_live"] += int(np.sum(
+                -(-(lengths[lengths > 0] + 1) // self.page_size)))
+            self._counts["pages_visited"] += B * self.pages_per_seq
+        with _obs.span("serving.engine.launch"):
             logits, self._pools = self._jit_decode(
                 self._w, jnp.asarray(tok), self._pools,
                 jnp.asarray(lengths), jnp.asarray(tables))
-        logits = np.asarray(logits)
-        self.launches += 1
-        if _obs.enabled():
-            _LAUNCHES.labels(path="split").inc()
-            _STEPS.labels(phase="decode").inc()
-            _TOKENS.labels(phase="decode").inc(len(active))
-        finished = 0
-        for slot, req in active:
-            finished += self._emit(req, int(np.argmax(logits[slot])))
-        _TRACE.set_host_span(None)
+            self.launches += 1
+            if _obs.enabled():
+                _LAUNCHES.labels(path="split").inc()
+                _STEPS.labels(phase="decode").inc()
+                _TOKENS.labels(phase="decode").inc(len(active))
+        with _obs.span("serving.engine.sync"):
+            logits = np.asarray(logits)
+        with _obs.span("serving.engine.sample"):
+            finished = 0
+            for slot, req in active:
+                finished += self._emit(req, int(np.argmax(logits[slot])))
         return len(active), finished
 
     # ------------------------------------------------------------ unified
@@ -1156,6 +1182,36 @@ class ServingEngine:
         active = self.scheduler.active(DECODE)
         if preq is None and not active:
             return 0, 0, 0
+        with _obs.span("serving.engine.build"):
+            host, drafts, n, start = self._build_unified(preq, active)
+        with _obs.span("serving.engine.launch"):
+            logits, self._pools = self._jit_unified(
+                self._w, jnp.asarray(host[0]), self._pools,
+                *(jnp.asarray(t) for t in host[1:]))
+            if preq is not None:
+                _TRACE.stamp(preq.request_id, "prefill_chunk", tokens=n,
+                             start=start)
+            self.launches += 1
+            if _obs.enabled():
+                _LAUNCHES.labels(
+                    path="unified_megafront" if self.megafront
+                    else "unified").inc()
+                _STEPS.labels(phase="unified").inc()
+                if n:
+                    _TOKENS.labels(phase="prefill").inc(n)
+        with _obs.span("serving.engine.sync"):
+            # the wait for the device and the copy back
+            logits = np.asarray(logits)     # [S, vocab]; [T, vocab] K>0
+        with _obs.span("serving.engine.sample"):
+            decoded, finished = self._sample_unified(
+                logits, preq, active, drafts, n)
+        return n, decoded, finished
+
+    def _build_unified(self, preq: Optional[Request], active):
+        """The host half of the unified launch: extend every sequence
+        (applying copy-on-write copies) and fill the row tables.
+        Returns ((tok, positions, num_tokens, kv_lengths, tables,
+        tok_page, tok_off), drafts by slot, prefill rows, their start)."""
         B, C, K = self.max_slots, self.prefill_chunk, self.spec_k
         R = 1 + K
         base = B * R
@@ -1211,28 +1267,24 @@ class ServingEngine:
             tables[S - 1] = tbl
             tok_page[base:base + n] = tbl[(start + rows) // ps]
             tok_off[base:base + n] = (start + rows) % ps
-        args = (self._w, jnp.asarray(tok), self._pools,
-                jnp.asarray(positions), jnp.asarray(num_tokens),
-                jnp.asarray(kv_lengths), jnp.asarray(tables),
-                jnp.asarray(tok_page), jnp.asarray(tok_off))
-        if _tracing.enabled():
-            with _obs.span("serving.engine.unified_step") as sp:
-                logits, self._pools = self._jit_unified(*args)
-            _TRACE.set_host_span(sp.span_id)
-            if preq is not None:
-                _TRACE.stamp(preq.request_id, "prefill_chunk", tokens=n,
-                             start=start)
-        else:
-            logits, self._pools = self._jit_unified(*args)
-        logits = np.asarray(logits)         # [S, vocab]; [T, vocab] K>0
-        self.launches += 1
-        if _obs.enabled():
-            _LAUNCHES.labels(
-                path="unified_megafront" if self.megafront
-                else "unified").inc()
-            _STEPS.labels(phase="unified").inc()
-            if n:
-                _TOKENS.labels(phase="prefill").inc(n)
+        self._counts["decode_rows"] = int(num_tokens[:B].sum())
+        self._counts["prefill_rows"] = n
+        # pages that hold this launch's tokens, against the page-table
+        # entries the ragged kernel's grid walks for each KV head
+        self._counts["pages_live"] = int(np.sum(-(-kv_lengths // ps)))
+        self._counts["pages_visited"] = S * nj
+        return ((tok, positions, num_tokens, kv_lengths, tables, tok_page,
+                 tok_off), drafts, n, start)
+
+    def _sample_unified(self, logits: np.ndarray, preq: Optional[Request],
+                        active, drafts: Dict[int, List[int]],
+                        n: int) -> Tuple[int, int]:
+        """Greedy argmax over the launch's logits: the prefill chunk's
+        first token when the prompt is done, one token per decode slot,
+        drafts verified. Returns (decoded, finished)."""
+        B, K = self.max_slots, self.spec_k
+        R = 1 + K
+        base = B * R
         finished = 0
         if preq is not None:
             preq.prefill_pos += n
@@ -1247,7 +1299,7 @@ class ServingEngine:
                     self.prefix_cache.insert(
                         preq.prompt,
                         self.allocator.seq_pages(preq.request_id))
-                row = logits[base + n - 1] if K else logits[S - 1]
+                row = logits[base + n - 1] if K else logits[B]
                 fin = self._emit(preq, int(np.argmax(row)))
                 finished += fin
                 if not fin and self.role == "prefill":
@@ -1283,8 +1335,7 @@ class ServingEngine:
                          drafted=len(d), accepted=m)
         if _obs.enabled() and decoded:
             _TOKENS.labels(phase="decode").inc(decoded)
-        _TRACE.set_host_span(None)
-        return n, decoded, finished
+        return decoded, finished
 
     def _emit(self, req: Request, tok: int) -> int:
         """Record one sampled token; finish on EOS/max-tokens (pages
@@ -1315,6 +1366,7 @@ class ServingEngine:
         pools before the write that triggered them."""
         if not copies:
             return
+        self._counts["cow_pages"] += len(copies)
         if req is not None:
             _TRACE.stamp(req.request_id, "cow", pages=len(copies))
         src = np.asarray([c[0] for c in copies])

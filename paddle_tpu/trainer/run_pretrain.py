@@ -346,16 +346,21 @@ def run(cfg: dict) -> int:
                 yield b
             epoch += 1
 
+    # host spans for an operator's profiler trace and the recorder:
+    # waiting for data, the step (dispatch to the loss on the host), save
+    from ..observability import span
     it = batches()
     t_last = time.time()
     for step in range(start_step, cfg["max_steps"]):
-        ids_np, labels_np = next(it)
-        ids = global_device_put(jnp.asarray(ids_np),
-                                meta["data_sharding"])
-        labels = global_device_put(jnp.asarray(labels_np),
-                                   meta["data_sharding"])
-        state, m = jstep(state, ids, labels)
-        loss = float(jax.device_get(m["loss"]))
+        with span("trainer.data_wait", step=step + 1):
+            ids_np, labels_np = next(it)
+        with span("trainer.step", step=step + 1):
+            ids = global_device_put(jnp.asarray(ids_np),
+                                    meta["data_sharding"])
+            labels = global_device_put(jnp.asarray(labels_np),
+                                       meta["data_sharding"])
+            state, m = jstep(state, ids, labels)
+            loss = float(jax.device_get(m["loss"]))
         now = time.time()
         tok_s = tokens_per_step / max(now - t_last, 1e-9)
         t_last = now
@@ -372,7 +377,8 @@ def run(cfg: dict) -> int:
         if cfg["save_interval"] > 0 and (
                 (step + 1) % cfg["save_interval"] == 0
                 or (step + 1) == cfg["max_steps"] or stop["sig"]):
-            save(step + 1)
+            with span("trainer.save", step=step + 1):
+                save(step + 1)
         if stop["sig"]:
             print("[run_pretrain] SIGTERM: emergency checkpoint done"
                   if cfg["save_interval"] > 0 else

@@ -325,93 +325,167 @@ class TestSubsystems:
 # overhead gate: disabled metrics must not tax the hot loop
 # ---------------------------------------------------------------------------
 
+class _CountingLock:
+    """Stands in for a `threading.Lock`; counts what takes it."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.taken = 0
+
+    def acquire(self, *a, **kw):
+        self.taken += 1
+        return self._lock.acquire(*a, **kw)
+
+    def release(self):
+        self._lock.release()
+
+    def __enter__(self):
+        self.acquire()
+        return self
+
+    def __exit__(self, *exc):
+        self.release()
+        return False
+
+
+def _what_it_does(body, n=200):
+    """Run `body(i)` n times and report what it DID inside paddle_tpu:
+    Python functions entered and C functions called per iteration
+    (`sys.setprofile`), and the blocks still allocated afterwards from
+    paddle_tpu's own files (`tracemalloc`).  All three repeat exactly
+    under any load, which a ratio of two wall-clock loops does not (the
+    gate this replaces failed in the driver's run under six workers)."""
+    import os
+    import sys
+    import tracemalloc
+    pkg = os.path.dirname(os.path.abspath(obs.__file__))
+    pkg = os.path.dirname(pkg) + os.sep
+    seen = {"py": 0, "c": 0}
+
+    def profile(frame, event, arg):
+        if frame.f_code.co_filename.startswith(pkg):
+            if event == "call":
+                seen["py"] += 1
+            elif event == "c_call":
+                seen["c"] += 1
+
+    body(-1)        # lazy imports and first-call caches are not the path
+    prev = sys.getprofile()
+    sys.setprofile(profile)
+    try:
+        for i in range(n):
+            body(i)
+    finally:
+        sys.setprofile(prev)
+    tracemalloc.start()
+    try:
+        before = tracemalloc.take_snapshot()
+        for i in range(n):
+            body(i)
+        after = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    only = [tracemalloc.Filter(True, pkg + "*")]
+    kept = sum(d.count_diff for d in after.filter_traces(only).compare_to(
+        before.filter_traces(only), "filename") if d.count_diff > 0)
+    return {"py_calls": seen["py"] / n, "c_calls": seen["c"] / n,
+            "blocks_kept": kept}
+
+
 class TestOverhead:
+    """What a DISABLED entry point does: how many Python functions it
+    enters, whether it calls into C, takes a lock or keeps an object.
+    (Until ISSUE 24 these compared two wall-clock loops at 5 %; the
+    names stay, the judgement is now a count.)"""
+
+    def _off(self):
+        from paddle_tpu.observability import tracing as tr
+        obs.set_enabled(False)
+        tr.set_enabled(False)
+
+    def _on(self):
+        from paddle_tpu.observability import tracing as tr
+        obs.set_enabled(True)
+        tr.set_enabled(True)
+
     def test_disabled_overhead_under_5pct(self):
         from paddle_tpu.observability import tracing as tr
         r = Registry()
         c = r.counter("ov_total")
         h = r.histogram("ov_seconds")
         rec = tr.TraceRecorder(capacity=8)
-        a = np.random.RandomState(0).randn(160, 160).astype(np.float32)
-        n = 600
+        locks = [_CountingLock() for _ in range(3)]
+        c._lock, h._lock, rec._lock = locks
 
-        def plain():
-            t0 = time.perf_counter()
-            for _ in range(n):
-                a.dot(a)
-            return time.perf_counter() - t0
+        def body(i):
+            c.inc()
+            h.observe(1.0)
+            rec.stamp(i, "token", index=i)
 
-        def instrumented():
-            t0 = time.perf_counter()
-            for i in range(n):
-                a.dot(a)
-                c.inc()
-                h.observe(1.0)
-                rec.stamp(i, "token", index=i)
-            return time.perf_counter() - t0
-
-        obs.set_enabled(False)
-        tr.set_enabled(False)
+        self._off()
         try:
-            # warm both paths, then interleave rounds and compare the best
-            # observation of each (min filters scheduler noise)
-            plain()
-            instrumented()
-            tp, ti = [], []
-            for _ in range(7):
-                tp.append(plain())
-                ti.append(instrumented())
+            did = _what_it_does(body)
         finally:
-            obs.set_enabled(True)
-            tr.set_enabled(True)
-        assert c.value == 0  # the flag really gated recording
-        assert not rec.live() and not rec.finished()  # stamps gated too
-        assert min(ti) < min(tp) * 1.05, (
-            f"disabled-metrics loop {min(ti):.4f}s vs plain {min(tp):.4f}s "
-            f"(+{(min(ti) / min(tp) - 1) * 100:.1f}%)")
+            self._on()
+        # each entry point is ONE Python function that tests the flag
+        # and returns: no C call, no lock, nothing kept
+        assert did == {"py_calls": 3, "c_calls": 0, "blocks_kept": 0}
+        assert [lk.taken for lk in locks] == [0, 0, 0]
+        assert c.value == 0 and h.count == 0   # the flag really gated
+        assert not rec.live() and not rec.finished()
 
     def test_disabled_counter_tracks_under_5pct(self):
         # ISSUE 11: the per-step attribution stamps the engine adds —
         # counter-track points and gauge sampling — must also vanish
-        # under the metrics-off gate
+        # under the off flags; ISSUE 24: so must the step records and
+        # the in-memory sink of `observability.span`
         from paddle_tpu.observability import tracing as tr
         r = Registry()
         g = r.gauge("ov_gauge")
         g.set(1.0)
         rec = tr.TraceRecorder(capacity=8)
-        a = np.random.RandomState(0).randn(160, 160).astype(np.float32)
-        n = 600
+        rec._lock = lock = _CountingLock()
 
-        def plain():
-            t0 = time.perf_counter()
-            for _ in range(n):
-                a.dot(a)
-            return time.perf_counter() - t0
+        def body(i):
+            rec.counter("ov.track", float(i))
+            rec.sample_gauges(("ov_gauge",), reg=r)
+            rec.open_step(i, "ov.step")
+            rec.close_step({"decode_rows": i})
 
-        def instrumented():
-            t0 = time.perf_counter()
-            for i in range(n):
-                a.dot(a)
-                rec.counter("ov.track", float(i))
-                rec.sample_gauges(("ov_gauge",), reg=r)
-            return time.perf_counter() - t0
-
-        obs.set_enabled(False)
-        tr.set_enabled(False)
+        self._off()
         try:
-            plain()
-            instrumented()
-            tp, ti = [], []
-            for _ in range(7):
-                tp.append(plain())
-                ti.append(instrumented())
+            did = _what_it_does(body)
         finally:
-            obs.set_enabled(True)
-            tr.set_enabled(True)
-        assert rec.counters() == {}  # the flag really gated sampling
-        assert min(ti) < min(tp) * 1.05, (
-            f"disabled counter-track loop {min(ti):.4f}s vs plain "
-            f"{min(tp):.4f}s (+{(min(ti) / min(tp) - 1) * 100:.1f}%)")
+            self._on()
+        assert rec.counters() == {} and rec.steps() == []
+        assert not rec._listening       # no jax.monitoring listener
+        # close_step has to look (a step may have opened before the
+        # flag went off): one lock, nothing else
+        assert did == {"py_calls": 4, "c_calls": 0, "blocks_kept": 0}
+        assert lock.taken == 2 * 200 + 1 + 2    # 2 loops, warm-up, reads
+
+        # the span primitive with the flag off: its profiler annotation
+        # and the native host event have switches of their own (no trace
+        # is on), and the recorder is never reached
+        default = tr.recorder()
+        n_spans = len(default.spans())
+        real, default._lock = default._lock, _CountingLock()
+        self._off()
+        try:
+            def spans(i):
+                with span("ov.span", step=i):
+                    pass
+            did = _what_it_does(spans)
+            taken = default._lock.taken
+        finally:
+            default._lock = real
+            self._on()
+        assert taken == 0 and len(default.spans()) == n_spans
+        assert did["c_calls"] <= 8      # the two sinks' own enter / exit
+        assert did["blocks_kept"] <= 2  # of 200 spans: none keeps one
+        # span(), _Span.__init__/__enter__/__exit__ and RecordEvent's
+        # three: a fixed handful, whatever the load
+        assert did["py_calls"] <= 8
 
     def test_disabled_fleet_paths_under_5pct(self):
         # ISSUE 16: the fleet plane's hot-path hooks — the router's SLO
@@ -420,47 +494,26 @@ class TestOverhead:
         from paddle_tpu.observability import fleet as fleet_mod
         from paddle_tpu.observability import tracing as tr
         rec = tr.TraceRecorder(capacity=8)
-        # four gated calls ride each iteration (vs three in the tests
-        # above), so give them a bigger work unit to hide under
-        a = np.random.RandomState(0).randn(256, 256).astype(np.float32)
-        n = 300
+        rec._lock = lock = _CountingLock()
 
-        def plain():
-            t0 = time.perf_counter()
-            for _ in range(n):
-                a.dot(a)
-            return time.perf_counter() - t0
-
-        def instrumented():
-            t0 = time.perf_counter()
-            for i in range(n):
-                a.dot(a)
-                fleet_mod.observe_ttft(0.1)
-                fleet_mod.observe_handoff(0.01)
-                rec.set_replica_context("pf0")
-                rec.adopt(i, rec.export_context(i))
-            return time.perf_counter() - t0
+        def body(i):
+            fleet_mod.observe_ttft(0.1)
+            fleet_mod.observe_handoff(0.01)
+            rec.set_replica_context("pf0")
+            rec.adopt(i, rec.export_context(i))
 
         before = obs.snapshot()["serving.fleet.ttft_seconds"]
-        obs.set_enabled(False)
-        tr.set_enabled(False)
+        self._off()
         try:
-            plain()
-            instrumented()
-            tp, ti = [], []
-            for _ in range(7):
-                tp.append(plain())
-                ti.append(instrumented())
+            did = _what_it_does(body)
         finally:
-            obs.set_enabled(True)
-            tr.set_enabled(True)
+            self._on()
         after = obs.snapshot()["serving.fleet.ttft_seconds"]
         assert after["series"][0]["count"] \
             == before["series"][0]["count"]  # observes really gated
         assert not rec.live() and not rec.finished()
-        assert min(ti) < min(tp) * 1.05, (
-            f"disabled fleet-path loop {min(ti):.4f}s vs plain "
-            f"{min(tp):.4f}s (+{(min(ti) / min(tp) - 1) * 100:.1f}%)")
+        assert did == {"py_calls": 5, "c_calls": 0, "blocks_kept": 0}
+        assert lock.taken == 2              # the two reads above
 
 
 class TestReplicaPrefixMetrics:

@@ -298,10 +298,14 @@ class TestEngineTracing:
         # phase rows nest inside the lifetime span
         phases = {e["name"] for e in events if e.get("cat") == "phase"}
         assert {"queue", "prefill", "decode"} <= phases
-        # token stamps carry the host-profiler span id of their engine
-        # step -> joinable against the host chrome trace
+        # token stamps carry the engine step that produced them: the
+        # one key that joins them to the step row and to the profiler's
+        # `serving.engine.step` events (argument `step`)
         toks = [e for e in events if e["name"] == "token"]
-        assert toks and all("host_span" in e["args"] for e in toks)
+        assert toks and all("step" in e["args"] for e in toks)
+        rows = {e["args"]["seq"] for e in events
+                if e["name"] == "serving.engine.step"}
+        assert {e["args"]["step"] for e in toks} <= rows
 
     def test_refused_request_appears_in_timeline(self):
         from paddle_tpu import resilience as res
@@ -361,6 +365,314 @@ class TestEngineTracing:
             assert tr.recorder().trace(0) is None
         finally:
             tr.set_enabled(True)
+
+
+PHASES = ["serving.engine." + p for p in
+          ("admit", "build", "launch", "sync", "sample", "account")]
+
+
+def _inside_and_disjoint(step, phases):
+    """`phases` [(start, end)] in order: inside `step`, none overlapping."""
+    edge = step[0]
+    for a, b in phases:
+        assert edge <= a <= b
+        edge = b
+    assert edge <= step[1]
+
+
+class TestStepTimeline:
+    """ISSUE 24: one step record per `ServingEngine.step()`, its phase
+    spans on the profiler's clock, request stamps keyed by the step."""
+
+    def _script(self, eng, cfg, ragged_seed=0):
+        """Seeded join/leave: three requests at once into two slots (one
+        waits), a long prompt ahead of a short one. Returns what every
+        `step()` returned and the allocator's page count after it."""
+        rng = np.random.RandomState(ragged_seed)
+        for rid, n in (("long", 14), ("short", 3), ("late", 6)):
+            eng.add_request(rng.randint(0, cfg.vocab_size, n).astype(
+                np.int32), max_new_tokens=3, request_id=rid)
+        outs = []
+        while eng.has_work():
+            out = eng.step()
+            outs.append((out, eng.allocator.stats()["pages_used"]))
+        return outs
+
+    @pytest.mark.parametrize("ragged", [True, False])
+    def test_one_record_per_step_with_counts(self, ragged):
+        eng, cfg = _tiny_engine(ragged=ragged, prefix_sharing=False,
+                                enable_prefix_cache=False)
+        outs = self._script(eng, cfg)
+        steps = tr.recorder().steps()
+        assert [s["seq"] for s in steps] == list(range(1, len(outs) + 1))
+        for s, (out, pages_used) in zip(steps, outs):
+            assert s["name"] == "serving.engine.step"
+            _inside_and_disjoint((s["start_ns"], s["end_ns"]),
+                                 [(a, b) for _, a, b in s["phases"]])
+            names = [n for n, _, _ in s["phases"]]
+            assert names[0] == PHASES[0] and names[-1] == PHASES[-1]
+            assert set(names) <= set(PHASES)
+            if ragged:
+                assert names == PHASES
+            assert s["decode_rows"] == out["decoded"]
+            assert s["prefill_rows"] == out["prefill_tokens"]
+            assert s["admitted"] == out["admitted"]
+            assert s["finished"] == out["finished"]
+            assert s["pool_pages_used"] == pages_used
+            assert s["pool_pages_total"] == eng.num_pages - 1
+            if ragged:
+                if not out["finished"]:
+                    # nothing shared, nothing freed: the launch's live
+                    # pages are the allocator's own count
+                    assert s["pages_live"] == pages_used
+                assert s["pages_visited"] == \
+                    (eng.max_slots + 1) * eng.pages_per_seq
+            else:
+                # two launches a step, each walks its own sequences
+                if not out["finished"]:
+                    assert pages_used <= s["pages_live"] <= 2 * pages_used
+                assert s["pages_visited"] == eng.pages_per_seq * (
+                    bool(out["prefill_tokens"])
+                    + eng.max_slots * bool(out["decoded"]))
+            assert s["preempted"] == s["cow_pages"] == 0
+        # two slots, three requests: one waited, then everyone left
+        assert steps[0]["live"] == 2 and steps[0]["waiting"] == 1
+        assert sum(s["admitted"] for s in steps) == 3
+        assert sum(s["finished"] for s in steps) == 3
+        # steps() hands out copies
+        steps[0]["phases"].clear()
+        assert tr.recorder().steps()[0]["phases"]
+
+    def test_request_phases_sum_to_ttft(self):
+        eng, cfg = _tiny_engine(prefix_sharing=False)
+        self._script(eng, cfg)
+        done = {t.request_id: t for t in tr.recorder().finished("request")}
+        assert set(done) == {"long", "short", "late"}
+        for t in done.values():
+            parts = [t.queue_wait_s(), t.prefill_wait_s(),
+                     t.prefill_run_s()]
+            assert None not in parts
+            # exact on the stamps' own integer microseconds
+            marks = [t.first(n).t_us for n in
+                     ("enqueue", "admit", "prefill_chunk", "token")]
+            assert marks == sorted(marks)
+            assert sum(b - a for a, b in zip(marks, marks[1:])) == \
+                marks[-1] - marks[0]
+            assert sum(parts) == pytest.approx(t.ttft_s(), abs=1e-9)
+            # every chunk is stamped, each with the step that ran it
+            chunks = [e for e in t.timeline() if e.name == "prefill_chunk"]
+            assert sum(e.meta["tokens"] for e in chunks) == \
+                t.meta["prompt_len"]
+            assert all("step" in e.meta for e in t.timeline()
+                       if e.name != "enqueue")
+            assert "prefill_wait_s" in t.to_dict()
+        # admitted in the same step as the 14-token prompt (4 chunks of
+        # 4), "short" holds a slot and waits behind them
+        long_chunks = [e.meta["step"] for e in done["long"].timeline()
+                       if e.name == "prefill_chunk"]
+        first_short = done["short"].first("prefill_chunk")
+        assert first_short.meta["step"] == long_chunks[-1] + 1
+        assert done["short"].first("admit").meta["step"] == long_chunks[0]
+        assert done["short"].prefill_wait_s() > 0
+        assert done["short"].prefill_wait_s() > \
+            done["long"].prefill_wait_s()
+
+    def test_compiles_counted_in_the_step_that_compiled(self):
+        eng, cfg = _tiny_engine()
+        rng = np.random.RandomState(0)
+        eng.add_request(rng.randint(0, cfg.vocab_size, 5).astype(np.int32),
+                        max_new_tokens=12, request_id="c")
+        for _ in range(4):
+            eng.step()
+        warm = tr.recorder().steps()
+        assert warm[0]["compiles"] >= 1          # the first launch
+        assert [s["compiles"] for s in warm[1:]] == [0, 0, 0]
+        assert eng.reconfigure(prefill_chunk=8)
+        eng.step()
+        eng.step()
+        after = tr.recorder().steps()[len(warm):]
+        assert after[0]["compiles"] >= 1 and after[1]["compiles"] == 0
+
+    def test_flags_off_records_nothing_tokens_unchanged(self):
+        from paddle_tpu import observability as obs
+
+        def tokens():
+            import paddle_tpu as paddle
+            paddle.seed(11)             # the same weights both times
+            eng, cfg = _tiny_engine()
+            rng = np.random.RandomState(3)
+            for i in range(3):
+                eng.add_request(rng.randint(0, cfg.vocab_size, 6).astype(
+                    np.int32), max_new_tokens=4, request_id=i)
+            res = eng.run_to_completion()
+            return [res[i].tolist() for i in range(3)]
+
+        want = tokens()
+        rec = tr.recorder()
+        assert rec.steps() and rec.spans()
+        rec.clear()
+        obs.set_enabled(False)
+        tr.set_enabled(False)
+        try:
+            got = tokens()
+        finally:
+            obs.set_enabled(True)
+            tr.set_enabled(True)
+        assert got == want
+        assert rec.steps() == [] and rec.spans() == []
+        assert rec.finished() == [] and rec.live() == []
+        assert rec.counters() == {}
+
+    def test_profiler_trace_holds_the_step_timeline(self, tmp_path):
+        """A `jax.profiler` trace on the CPU: the step and its six
+        phases are host events under their plain names, nested and
+        disjoint, and agree with the in-memory records."""
+        import glob
+        import jax
+        from jax.profiler import ProfileData
+        eng, cfg = _tiny_engine()
+        rng = np.random.RandomState(0)
+        eng.add_request(rng.randint(0, cfg.vocab_size, 6).astype(np.int32),
+                        max_new_tokens=6, request_id="p")
+        eng.step()                      # compile outside the trace
+        n0 = len(tr.recorder().steps())
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            for _ in range(3):
+                eng.step()
+        finally:
+            jax.profiler.stop_trace()
+        records = tr.recorder().steps()[n0:]
+        (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                                / "*.xplane.pb"))
+        names = {"serving.engine.step", *PHASES}
+        events = []
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in names:
+                        events.append((e.start_ns, e.start_ns
+                                       + e.duration_ns, e.name,
+                                       dict(e.stats)))
+        events.sort()
+        parents = [e for e in events if e[2] == "serving.engine.step"]
+        assert [int(e[3]["step"]) for e in parents] == \
+            [r["seq"] for r in records]
+        for (a, b, _, _), rec in zip(parents, records):
+            inner = [e for e in events if a <= e[0] and e[1] <= b
+                     and e[2] != "serving.engine.step"]
+            assert [e[2] for e in inner] == PHASES == \
+                [n for n, _, _ in rec["phases"]]
+            _inside_and_disjoint((a, b), [(e[0], e[1]) for e in inner])
+        assert len(events) == len(parents) * (1 + len(PHASES))
+
+
+class TestSpanPrimitive:
+    def test_span_records_parent_and_step(self):
+        from paddle_tpu.observability import span
+        rec = tr.recorder()
+        with span("outer", step=7, note="x"):
+            with span("inner"):
+                pass
+        with span("alone"):
+            pass
+        got = rec.spans()
+        assert [(n, p, s) for n, _, _, p, s in got] == [
+            ("inner", "outer", 7), ("outer", None, 7),
+            ("alone", None, None)]
+        (_, a, b, _, _), (_, c, d, _, _) = got[0], got[1]
+        assert c <= a <= b <= d
+        assert rec.steps() == []        # no step was opened
+
+    def test_spans_of_another_thread_do_not_nest(self):
+        from paddle_tpu.observability import span
+        seen = []
+
+        def other():
+            with span("other.thread"):
+                pass
+            seen.append(True)
+
+        with span("main.thread", step=1):
+            th = threading.Thread(target=other)
+            th.start()
+            th.join(timeout=10)
+        assert seen and not th.is_alive()
+        by_name = {n: (p, s) for n, _, _, p, s in tr.recorder().spans()}
+        assert by_name["other.thread"] == (None, None)
+
+    def test_step_ring_is_bounded_and_separate(self):
+        rec = tr.TraceRecorder(capacity=3)
+        for seq in range(1, 6):
+            rec.open_step(seq, "s")
+            rec.begin(seq)
+            rec.stamp(seq, "token")
+            rec.close_step({"decode_rows": seq})
+        assert [s["seq"] for s in rec.steps()] == [3, 4, 5]
+        assert rec.steps()[-1]["decode_rows"] == 5
+        # stamps inside an open step carry it; outside they do not
+        assert rec.trace(5).first("token").meta == {"step": 5}
+        rec.stamp(5, "late")
+        assert rec.trace(5).first("late").meta is None
+        assert len(rec.live()) == 5     # the request table is its own
+
+    def test_chrome_export_has_the_step_row(self, tmp_path):
+        rec = tr.TraceRecorder(capacity=4)
+        rec.open_step(9, "eng.step")
+        rec._span_done("eng.a", 2_000, 5_000, "eng.step", 9)
+        rec._span_done("eng.b", 6_000, 9_000, "eng.step", 9)
+        rec._span_done("eng.deeper", 6_500, 7_000, "eng.b", 9)
+        rec._span_done("eng.step", 1_000, 10_000, None, 9)
+        rec.close_step({"decode_rows": 3})
+        (st,) = rec.steps()
+        assert (st["start_ns"], st["end_ns"]) == (1_000, 10_000)
+        assert st["phases"] == [("eng.a", 2_000, 5_000),
+                                ("eng.b", 6_000, 9_000)]   # direct children
+        path = str(tmp_path / "steps.json")
+        assert rec.export_chrome_trace(path) == 3
+        events = load_profiler_result(path)
+        assert [(e["name"], e["ts"], e["dur"], e["tid"]) for e in events] \
+            == [("eng.step", 1, 9, 0), ("eng.a", 2, 3, 0),
+                ("eng.b", 6, 3, 0)]
+        assert events[0]["args"]["decode_rows"] == 3
+        assert events[0]["args"]["seq"] == events[1]["args"]["step"] == 9
+
+    def test_trainer_loop_spans(self, tmp_path):
+        """`run_pretrain.run` opens data-wait, step and save spans
+        through the one primitive, keyed by the optimizer step."""
+        import random
+        import signal
+        from paddle_tpu.trainer import run_pretrain
+        rng = random.Random(0)
+        corpus = tmp_path / "corpus.txt"
+        corpus.write_text(" ".join(rng.choice(["a", "bb", "ccc", "dd"])
+                                   for _ in range(3000)))
+        cfg_path = tmp_path / "cfg.json"
+        cfg_path.write_text(json.dumps({
+            "model": {"preset": "tiny", "num_hidden_layers": 1},
+            "data": {"corpus": str(corpus), "vocab_size": 270},
+            "seq_len": 16, "global_batch": 8, "max_steps": 3,
+            "parallel": {"dp": 8},      # conftest's eight CPU devices
+            "save_interval": 2, "remat": "none",
+            "output_dir": str(tmp_path / "out")}))
+        prev = signal.getsignal(signal.SIGTERM)
+        try:
+            assert run_pretrain.run(
+                run_pretrain._load_config(str(cfg_path))) == 0
+        finally:
+            signal.signal(signal.SIGTERM, prev)
+        got = [(n, p, s) for n, _, _, p, s in tr.recorder().spans()
+               if n.startswith("trainer.")]
+        assert got == [
+            ("trainer.data_wait", None, 1), ("trainer.step", None, 1),
+            ("trainer.data_wait", None, 2), ("trainer.step", None, 2),
+            ("trainer.save", None, 2),
+            ("trainer.data_wait", None, 3), ("trainer.step", None, 3),
+            ("trainer.save", None, 3)]
 
 
 class TestServingStampRoundTrip:
