@@ -558,7 +558,9 @@ def accumulator_hazards(eff: KernelEffects) -> List[Dict[str, Any]]:
         carried = any(a.guard == "last" for a in ref.loads)
         first_init = any(s.guard == "first" for s in ref.stores)
         first_load = min(a.line for a in ref.loads)
-        uncond_init = any(s.guard is None and s.line <= first_load
+        # a store on the first load's own line is that statement's
+        # read-modify-write (the right side is evaluated first)
+        uncond_init = any(s.guard is None and s.line < first_load
                           for s in ref.stores)
         if carried and not first_init:
             out.append({

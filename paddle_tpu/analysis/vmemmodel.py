@@ -241,14 +241,18 @@ CANONICAL: Dict[str, Dict[str, Any]] = {
         any_inputs=(1, 2),
         token_tiled=False,
     ),
+    # one tile of TQ = 8 tokens (rows = TQ * rep); K/V stay in HBM
+    # behind the kernel's own page DMAs, as in paged v2
     "ragged_paged_attention": dict(
         kernel="ragged_paged_attention",
-        bindings=dict(T=8, rep=4, D=128, KV=8, S=8, nj=8, psz=32),
+        bindings=dict(KV=8, n_tiles=1, rows=32, D=128, psz=32),
         in_widths=[2, 2, 2], out_widths=[2],
         cost_kwargs=dict(T=8, H=32, KV=8, D=128, S=8, pages_per_seq=8,
                          page_size=32),
-        token_tiled=False,
-        families={"llama": dict(KV=8, rep=4, D=128)},
+        mode="activations",
+        any_inputs=(1, 2),
+        token_tiled=True,
+        families={"llama": dict(KV=8, rows=32, D=128)},
     ),
     "mla_decode_attention": dict(
         kernel="mla_decode_attention",

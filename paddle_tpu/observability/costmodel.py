@@ -334,17 +334,32 @@ def _c_paged_v2(*, B: int, H: int, KV: int, D: int, context: int,
                        dtype_bytes)
 
 
+def _ragged_tile_tokens(T: int, rep: int, dtype_bytes: int) -> int:
+    """ops/pallas_ragged.ragged_tile_tokens, restated (this module is
+    loaded without jax; tests/test_costmodel.py holds the two equal)."""
+    pack = 32 // dtype_bytes
+    unit = pack // math.gcd(rep, pack)
+    tq = max(unit, 128 // rep // unit * unit)
+    return min(tq, _ceil_div(T, unit) * unit)
+
+
 @register_cost("ragged_paged_attention")
 def _c_ragged(*, T: int, H: int, KV: int, D: int, S: int,
               pages_per_seq: int, page_size: int,
               dtype_bytes: int = 2) -> CostEstimate:
-    """Ragged mixed prefill+decode, grid (KV, S, pages): the whole
-    [T*rep, D] query group of one KV head stays VMEM-resident across the
-    head's page sweep (read once per head), K/V pages fetched once per
-    (kv head, sequence, page)."""
+    """Ragged mixed prefill+decode, grid (KV, tiles of TQ tokens): each
+    cell reads one [TQ*rep, D] query tile and writes one output tile
+    (the rows that pad T up to whole tiles are not counted); the pools
+    stay in HBM and a cell DMAs, for every sequence with rows in its
+    tile, the pages up to the tile's causal limit. Stated for the
+    heaviest launch of these shapes: every table full and the T rows
+    spread evenly over the S sequences, so a sequence's pages cross once
+    for each tile its rows span (once for a decode batch, T == S)."""
     rep = H // KV
+    spans = _ceil_div(_ceil_div(T, S),
+                      _ragged_tile_tokens(T, rep, dtype_bytes))
     q = KV * T * rep * D * dtype_bytes
-    kv = 2 * KV * S * pages_per_seq * page_size * D * dtype_bytes
+    kv = 2 * KV * S * spans * pages_per_seq * page_size * D * dtype_bytes
     out = KV * T * rep * D * dtype_bytes
     ctx = pages_per_seq * page_size
     return CostEstimate(bytes_read=q + kv, bytes_written=out,

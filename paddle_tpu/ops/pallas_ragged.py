@@ -23,28 +23,55 @@ row (num_tokens=1) therefore sees its whole context; a prefill chunk is
 causal within the chunk and sees everything before it (shared-prefix
 pages included).
 
-Same machinery family as pallas_paged.py: grid (KV, S, pages), page
-gather through the BlockSpec index_map (never materialized), GQA-native
-[T*rep, D] query groups per KV head, online-softmax f32 scratch,
-pl.when skips for dead pages/slots, interpret mode off-TPU.
+Blocking: the flat buffer is cut into static TILES of TQ tokens
+(`ragged_tile_tokens`: TQ*rep query rows of one KV head, a multiple of
+the dtype's sublane packing; T is padded up to whole tiles). The work
+is a list of (tile, sequence) PAIRS — a sequence with rows in the tile
+— each with the number of pages the tile walks for it: the pages up to
+the causal limit of the sequence's LAST row inside the tile, never more
+than its live pages. `_tile_pages` computes that table for the kernel
+(in XLA, once a step: the per-layer calls are identical and merge) and
+for the engine's `pages_visited` counter (`ragged_pages_visited`), so
+the two cannot drift. Grid (KV, tiles): a cell owns one [TQ*rep, D]
+query tile and its output tile; the K/V pools stay in HBM and the cell
+walks its pairs' pages, each page one K and one V DMA into a ring of
+`_page_buffers` slots. The DMAs run ahead of the compute along the
+head's FLAT walk, across pair and tile boundaries (the read-ahead
+cursor is carried from cell to cell in SMEM). No grid step, DMA or
+branch exists for a dead (sequence, page) entry, and a page meets only
+the TQ*rep rows of a tile that holds rows of its sequence. Rows of
+OTHER sequences in that tile are masked (s = _MASKED -> p = 0, and m,
+l, acc untouched), so the per-row online-softmax state lets sequences
+share a tile. GQA-native, f32 scores / softmax state / accumulator,
+interpret mode off-TPU.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_paged import paged_kernel_eligible
 
 __all__ = ["ragged_paged_attention", "ragged_attention_reference",
-           "ragged_kernel_eligible"]
+           "ragged_kernel_eligible", "ragged_tile_tokens",
+           "ragged_pages_visited"]
 
 _NEG = -1e30
+_MASKED = -3e38
+#: query rows (tokens x rep) of one tile: the MXU's height on the chips
+#: this runs on. PERF.md (PR 25) has the sweep on a v5e: fewer rows make a
+#: decode page cheaper, more rows refetch a prefill chunk's pages less.
+_TILE_ROWS = 128
+#: K+V bytes the page DMAs keep in flight ahead of the compute
+_BYTES_IN_FLIGHT = 256 * 1024
 
 
 def _interpret() -> bool:
@@ -59,77 +86,183 @@ def ragged_kernel_eligible(H: int, KV: int, D: int,
     return paged_kernel_eligible(H, KV, D, page_size)
 
 
-def _ragged_page_map(h, i, j, ss, nt, kvl, tab, *, page_size,
-                     total_pages):
-    # clamp j to the last LIVE page of sequence i and the table value to
-    # a real physical page: dead pages then re-reference the previous
-    # block (Pallas elides the copy) and sentinel/-1 entries never emit
-    # an out-of-range DMA, even though compute is pl.when-skipped
-    jmax = jnp.maximum(kvl[i] - 1, 0) // page_size
-    phys = jnp.clip(tab[i, jnp.minimum(j, jmax)], 0, total_pages - 1)
-    return (h, phys, 0, 0)
+def ragged_tile_tokens(T: int, rep: int, dtype) -> int:
+    """TQ, the tokens of one query tile: about _TILE_ROWS / rep, in
+    units that keep TQ*rep a multiple of the dtype's sublane packing
+    (8 rows of 32 bits), and no more than T rounded up to a unit."""
+    pack = 32 // jnp.dtype(dtype).itemsize
+    unit = pack // math.gcd(rep, pack)
+    tq = max(unit, _TILE_ROWS // rep // unit * unit)
+    return min(tq, -(-T // unit) * unit)
+
+
+def _page_buffers(page_bytes: int) -> int:
+    """K (and V) page buffers of a grid cell: all but one are in flight
+    while one is computed, _BYTES_IN_FLIGHT of K+V between them."""
+    return 1 + min(7, max(1, _BYTES_IN_FLIGHT // (2 * page_bytes)))
+
+
+def _tile_pages(xp, seq_start, num_tokens, kv_lengths, *, tq, n_tiles,
+                page_size, pages_per_seq):
+    """[n_tiles, S] int32: the K/V pages tile t walks for sequence i —
+    pages 0 .. the causal limit of the sequence's last row in the tile;
+    0 where the sequence has no row there. `xp` is numpy (the counter)
+    or jax.numpy (the kernel's work list)."""
+    lo = (xp.arange(n_tiles, dtype=xp.int32) * tq)[:, None]
+    first = xp.maximum(seq_start[None, :], lo)
+    last = xp.minimum((seq_start + num_tokens)[None, :], lo + tq) - 1
+    limit = (kv_lengths - num_tokens - seq_start)[None, :] + last
+    pages = xp.clip(limit // page_size + 1, 0, pages_per_seq)
+    return xp.where(last >= first, pages, 0).astype(xp.int32)
+
+
+def ragged_pages_visited(seq_start, num_tokens, kv_lengths, *, T: int,
+                         rep: int, dtype, page_size: int,
+                         pages_per_seq: int) -> int:
+    """K/V page fetches PER KV HEAD that `ragged_paged_attention` makes
+    for this launch (host-side numpy, the engine's `pages_visited`): the
+    sum over tiles of the pages each tile walks."""
+    tq = ragged_tile_tokens(T, rep, dtype)
+    return int(_tile_pages(
+        np, np.asarray(seq_start, np.int32),
+        np.asarray(num_tokens, np.int32), np.asarray(kv_lengths, np.int32),
+        tq=tq, n_tiles=-(-T // tq), page_size=page_size,
+        pages_per_seq=pages_per_seq).sum())
+
+
+def _work_list(seq_start, num_tokens, kv_lengths, **tiling):
+    """The kernel's scalar-prefetched work: (tile, sequence) pairs with
+    pages to walk, compacted tile-major. Returns tile_first [n_tiles+1]
+    (pairs of tile t are tile_first[t] .. tile_first[t+1]), pair_seq
+    [n_tiles + S] and pair_first [n_tiles + S + 1], the running page
+    count (pair p walks pair_first[p+1] - pair_first[p] pages; the count
+    also picks the DMA slot). Disjoint row ranges give at most
+    n_tiles + S - 1 pairs; more — overlapping ranges — are dropped."""
+    pages = _tile_pages(jnp, seq_start, num_tokens, kv_lengths, **tiling)
+    n_tiles, S = pages.shape
+    cap = n_tiles + S
+    flat = pages.reshape(-1)
+    idx = jnp.nonzero(flat > 0, size=cap, fill_value=0)[0]
+    zero = jnp.zeros(1, jnp.int32)
+    per_tile = jnp.sum(pages > 0, axis=1, dtype=jnp.int32)
+    tile_first = jnp.minimum(
+        jnp.concatenate([zero, jnp.cumsum(per_tile)]), cap)
+    pair_first = jnp.concatenate([zero, jnp.cumsum(flat[idx])])
+    return (tile_first.astype(jnp.int32), (idx % S).astype(jnp.int32),
+            pair_first.astype(jnp.int32))
+
+
+def _in_hbm(x):
+    """Pin a kernel operand to HBM (no op is emitted). Left free, XLA's
+    memory-space assignment prefetches the small row tables into its
+    alternate memory before 15 of a 16-layer step's calls: copies that
+    gain nothing, and the kernel's operands lose their names in the
+    trace (`benchmarks/layer_metrics/ragged_attn_roofline.py` finds the
+    kernel by its `kv_lengths` operand)."""
+    return pltpu.with_memory_space_constraint(x, pltpu.HBM)
+
+
+def _tile_map(h, t, ss, nt, kvl, tab, tile_first, pair_seq, pair_first):
+    return (h, t, 0)
 
 
 def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
-                   q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, page_size, rep, scale):
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+                   first_ref, pseq_ref, pfirst_ref,
+                   q_ref, k_hbm, v_hbm, o_ref,
+                   kbuf, vbuf, acc_ref, m_ref, l_ref, ahead_ref, sem,
+                   *, page_size, rep, tq, total_pages, scale):
+    h = pl.program_id(0)
+    t = pl.program_id(1)
+    n_pairs = first_ref[pl.num_programs(1)]
+    depth = kbuf.shape[0]
 
-    # the whole [T*rep, D] output block stays resident for one KV head's
-    # full (i, j) sweep; zero it once so inactive rows read as zeros and
-    # each sequence's emit only merges its own rows
-    @pl.when((i == 0) & (j == 0))
-    def _zero_out():
-        o_ref[:] = jnp.zeros_like(o_ref)
+    def page_dma(pi, j):
+        # page j of pair pi lands in the slot its running count picks;
+        # sentinel / -1 table entries never emit an out-of-range DMA
+        slot = jax.lax.rem(pfirst_ref[pi] + j, depth)
+        phys = jnp.clip(tab_ref[pseq_ref[pi], j], 0, total_pages - 1)
+        return slot, (
+            pltpu.make_async_copy(k_hbm.at[h, phys], kbuf.at[slot],
+                                  sem.at[0, slot]),
+            pltpu.make_async_copy(v_hbm.at[h, phys], vbuf.at[slot],
+                                  sem.at[1, slot]))
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def fetch_ahead(pi, j):
+        """Start the DMAs of page (pi, j) of the head's flat walk if
+        there is one; return the page after it."""
+        @pl.when(pi < n_pairs)
+        def _start():
+            for dma in page_dma(pi, j)[1]:
+                dma.start()
 
-    start = ss_ref[i]
-    nt = nt_ref[i]
-    kvl = kvl_ref[i]
-    rows = q_ref.shape[1]
-    # flat token index of each query row ([T*rep, 1]: rep query heads of
-    # one token are adjacent rows of the same KV head's group)
-    tok = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // rep
-    row_valid = (tok >= start) & (tok < start + nt)
+        # (past the last pair the clamped read is never used)
+        c = jnp.minimum(pi, jnp.maximum(n_pairs - 1, 0))
+        wrap = j + 1 >= pfirst_ref[c + 1] - pfirst_ref[c]
+        return jnp.where(wrap, pi + 1, pi), jnp.where(wrap, 0, j + 1)
 
-    @pl.when((nt > 0) & (j * page_size < kvl))
-    def _compute():
-        q = q_ref[0]                                     # [T*rep, D]
-        k = k_ref[0, 0]                                  # [psz, D]
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [T*rep, psz]
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        # local token t of this sequence attends positions <= limit
-        limit = kvl - nt + (tok - start)
-        masked = jnp.logical_not(row_valid & (pos <= limit))
-        s = jnp.where(masked, _NEG, s)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(masked, 0.0, p)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, -1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+    # the walk's read-ahead cursor lives across the head's grid cells:
+    # depth - 1 pages fly ahead of the one being computed, whichever
+    # pair or tile they belong to
+    @pl.when(t == 0)
+    def _warmup():
+        ahead = (jnp.int32(0), jnp.int32(0))
+        for _ in range(depth - 1):
+            ahead = fetch_ahead(*ahead)
+        ahead_ref[0], ahead_ref[1] = ahead
 
-    @pl.when(j == nj - 1)
-    def _emit():
-        l = l_ref[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        vals = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-        o_ref[0] = jnp.where(row_valid, vals, o_ref[0])
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, _NEG)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    q = q_ref[0]                                         # [TQ*rep, D]
+    # flat token of each query row ([TQ*rep, 1]: the rep query heads of
+    # one token are adjacent rows of the KV head's group)
+    tok = t * tq + jax.lax.broadcasted_iota(
+        jnp.int32, (q.shape[0], 1), 0) // rep
+    in_page = jax.lax.broadcasted_iota(
+        jnp.int32, (q.shape[0], page_size), 1)
+
+    def pair(pi, ahead):
+        i = pseq_ref[pi]
+        first_row, nt = ss_ref[i], nt_ref[i]
+        # local token t of this sequence attends positions <= limit;
+        # the rows of other sequences attend nothing
+        limit = jnp.where((tok >= first_row) & (tok < first_row + nt),
+                          kvl_ref[i] - nt + (tok - first_row), -1)
+
+        def page(j, ahead):
+            ahead = fetch_ahead(*ahead)
+            slot, dmas = page_dma(pi, j)
+            for dma in dmas:
+                dma.wait()
+            k = kbuf[slot]                               # [psz, D]
+            v = vbuf[slot]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            # _MASKED is so far below any m (>= _NEG) that exp gives an
+            # exact 0: a row with nothing to attend here keeps m, l, acc
+            s = jnp.where(in_page <= limit - j * page_size, s, _MASKED)
+            m_prev = m_ref[:]
+            m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[:] = l_ref[:] * alpha + jnp.sum(p, -1, keepdims=True)
+            acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[:] = m_new
+            return ahead
+
+        return jax.lax.fori_loop(
+            0, pfirst_ref[pi + 1] - pfirst_ref[pi], page, ahead)
+
+    ahead = jax.lax.fori_loop(first_ref[t], first_ref[t + 1], pair,
+                              (ahead_ref[0], ahead_ref[1]))
+    ahead_ref[0], ahead_ref[1] = ahead
+    # rows of no sequence kept l == 0 and acc == 0: they emit zeros
+    l = l_ref[:]
+    o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(
+        o_ref.dtype)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
@@ -138,58 +271,74 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     """q [T, H, D] flat new-token buffer; k/v_pages [KV, total_pages,
     page_size, D]; seq_start/num_tokens/kv_lengths [S] int32;
     page_tables [S, pages_per_seq] int32. Sequences own DISJOINT row
-    ranges [seq_start[i], seq_start[i]+num_tokens[i]); rows covered by
-    no sequence return zeros. Returns [T, H, D].
+    ranges [seq_start[i], seq_start[i]+num_tokens[i]); seq_start is
+    non-decreasing in every caller (the work list is built tile-major
+    from the ranges themselves and does not lean on the order). Rows
+    covered by no sequence return zeros. Returns [T, H, D].
 
-    VMEM residency note: the whole [T*rep, D] query group and output
-    block of one KV head stay resident across that head's page sweep —
-    T is an engine-step batch (max_slots + prefill_chunk), not a full
-    sequence, so the block is small by construction."""
+    VMEM: one [TQ*rep, D] query tile and output tile (double-buffered by
+    the pipeline), that much f32 state, and `_page_buffers` K and V
+    pages."""
     T, H, D = q.shape
     KV, total, psz, _ = k_pages.shape
     rep = H // KV
     S, nj = page_tables.shape
     if scale is None:
         scale = D ** -0.5
-    # [T, H, D] -> [KV, T*rep, D]: one grid cell owns one KV head's
-    # whole flat query group (rep rows per token, token-major)
-    qg = (q.reshape(T, KV, rep, D).transpose(1, 0, 2, 3)
-          .reshape(KV, T * rep, D))
+    interpret = _interpret()
+    if not interpret and not isinstance(q, jax.core.Tracer):
+        # the operands' memory-space pins below exist only under a trace
+        return jax.jit(functools.partial(
+            ragged_paged_attention, scale=scale))(
+                q, k_pages, v_pages, seq_start, num_tokens, kv_lengths,
+                page_tables)
+    tq = ragged_tile_tokens(T, rep, q.dtype)
+    n_tiles = -(-T // tq)
+    Tp, rows = n_tiles * tq, tq * rep
+    depth = _page_buffers(psz * D * k_pages.dtype.itemsize)
+    ss = seq_start.astype(jnp.int32)
+    nt = num_tokens.astype(jnp.int32)
+    kvl = kv_lengths.astype(jnp.int32)
+    work = _work_list(ss, nt, kvl, tq=tq, n_tiles=n_tiles, page_size=psz,
+                      pages_per_seq=nj)
+    # [T, H, D] -> [KV, Tp*rep, D]: a KV head's flat query group (rep
+    # rows per token, token-major), cut into tiles of TQ tokens
+    qg = (jnp.pad(q, ((0, Tp - T), (0, 0), (0, 0)))
+          .reshape(Tp, KV, rep, D).transpose(1, 0, 2, 3)
+          .reshape(KV, Tp * rep, D))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,      # seq_start, num_tokens, kv_lengths,
-        grid=(KV, S, nj),           # page tables
+        num_scalar_prefetch=7,      # row tables, page tables, work list
+        grid=(KV, n_tiles),
         in_specs=[
-            pl.BlockSpec((1, T * rep, D),
-                         lambda h, i, j, ss, nt, kvl, tab: (h, 0, 0)),
-            pl.BlockSpec((1, 1, psz, D), functools.partial(
-                _ragged_page_map, page_size=psz, total_pages=total)),
-            pl.BlockSpec((1, 1, psz, D), functools.partial(
-                _ragged_page_map, page_size=psz, total_pages=total)),
+            pl.BlockSpec((1, rows, D), _tile_map),
+            pl.BlockSpec(memory_space=pltpu.HBM),    # the pools stay put
+            pl.BlockSpec(memory_space=pltpu.HBM),
         ],
-        out_specs=pl.BlockSpec(
-            (1, T * rep, D),
-            lambda h, i, j, ss, nt, kvl, tab: (h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((T * rep, D), jnp.float32),
-                        pltpu.VMEM((T * rep, 1), jnp.float32),
-                        pltpu.VMEM((T * rep, 1), jnp.float32)],
+        out_specs=pl.BlockSpec((1, rows, D), _tile_map),
+        scratch_shapes=[pltpu.VMEM((depth, psz, D), k_pages.dtype),
+                        pltpu.VMEM((depth, psz, D), v_pages.dtype),
+                        pltpu.VMEM((rows, D), jnp.float32),
+                        pltpu.VMEM((rows, 1), jnp.float32),
+                        pltpu.VMEM((rows, 1), jnp.float32),
+                        pltpu.SMEM((2,), jnp.int32),
+                        pltpu.SemaphoreType.DMA((2, depth))],
     )
-    # i is sequential ("arbitrary"): every sequence read-modify-writes
-    # the same resident output block
-    cparams = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary", "arbitrary"))
+    # the tile axis is sequential: a head's page DMAs run ahead from one
+    # tile into the next
     out = pl.pallas_call(
-        functools.partial(_ragged_kernel, page_size=psz, rep=rep,
-                          scale=float(scale)),
+        functools.partial(_ragged_kernel, page_size=psz, rep=rep, tq=tq,
+                          total_pages=total, scale=float(scale)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((KV, T * rep, D), q.dtype),
-        compiler_params=cparams,
-        interpret=_interpret(),
-    )(seq_start.astype(jnp.int32), num_tokens.astype(jnp.int32),
-      kv_lengths.astype(jnp.int32), page_tables.astype(jnp.int32),
-      qg, k_pages, v_pages)
-    return (out.reshape(KV, T, rep, D).transpose(1, 0, 2, 3)
-            .reshape(T, H, D))
+        out_shape=jax.ShapeDtypeStruct((KV, Tp * rep, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(*(x if interpret else _in_hbm(x) for x in (
+        ss, nt, kvl, page_tables.astype(jnp.int32), *work,
+        qg, k_pages, v_pages)))
+    return (out.reshape(KV, Tp, rep, D).transpose(1, 0, 2, 3)
+            .reshape(Tp, H, D)[:T])
 
 
 def ragged_attention_reference(q, k_pages, v_pages, seq_start,

@@ -70,7 +70,8 @@ from ..ops.pallas_megadecode import (fused_ffn, fused_oproj_norm,
 from ..ops.pallas_megafront import (fused_qkv_rope_append,
                                     megafront_eligible)
 from ..ops.pallas_ragged import (ragged_kernel_eligible,
-                                 ragged_paged_attention)
+                                 ragged_paged_attention,
+                                 ragged_pages_visited)
 from .block_allocator import PageBlockAllocator
 from .handoff import (HANDOFF_BYTES, HANDOFF_PAGES, HANDOFFS,
                       KVPageHandoff)
@@ -287,6 +288,8 @@ class ServingEngine:
         else:
             kv, d = cfg.num_key_value_heads, cfg.head_dim
         shape = (kv, self.num_pages, self.page_size, d)
+        # what the ragged kernel's tiling follows (pages_visited)
+        self._q_rep, self._q_dtype = cfg.num_attention_heads // kv, dt
         if self._family == "mla":
             # one pool per layer: each row is [latent | rope-key], read
             # as both K and V by the concat-dot absorbed decode
@@ -1269,10 +1272,14 @@ class ServingEngine:
             tok_off[base:base + n] = (start + rows) % ps
         self._counts["decode_rows"] = int(num_tokens[:B].sum())
         self._counts["prefill_rows"] = n
-        # pages that hold this launch's tokens, against the page-table
-        # entries the ragged kernel's grid walks for each KV head
+        # pages that hold this launch's tokens, against the K/V page
+        # fetches the ragged kernel makes for each KV head (a sequence's
+        # pages once for every query tile that holds rows of it)
         self._counts["pages_live"] = int(np.sum(-(-kv_lengths // ps)))
-        self._counts["pages_visited"] = S * nj
+        self._counts["pages_visited"] = ragged_pages_visited(
+            np.append(np.arange(B) * R, base), num_tokens, kv_lengths,
+            T=T, rep=self._q_rep, dtype=self._q_dtype, page_size=ps,
+            pages_per_seq=nj)
         return ((tok, positions, num_tokens, kv_lengths, tables, tok_page,
                  tok_off), drafts, n, start)
 
@@ -1405,7 +1412,12 @@ class ServingEngine:
     # One fused launch per engine step: T = max_slots + prefill_chunk
     # flat token rows, S = max_slots + 1 sequences with BAKED seq_start
     # [0..B-1, B] (decode slot i owns row i; the prefill chunk owns rows
-    # B..B+n-1). The per-layer body is the fused decode chain:
+    # B..B+n-1). The ragged kernel cuts those rows into tiles of TQ
+    # tokens and walks, for each tile, only the live pages of the
+    # sequences with rows in it (decode slots share a tile; the chunk
+    # spans several and refetches its pages once a tile); its work list
+    # is built from the row tables in XLA, once a step (the layers'
+    # identical copies merge). The per-layer body is the fused decode chain:
     # fused_rms_norm -> fused_qkv_rope_append (the ISSUE-20 mega-kernel
     # front half: qkv projection with in-kernel dequant, rope, and the
     # paged K/V scatter in one launch; `self.megafront` False falls

@@ -19,6 +19,7 @@ import ast
 import json
 import os
 
+import numpy as np
 import pytest
 
 import paddle_tpu.analysis.kernelmodel as km
@@ -148,17 +149,35 @@ class TestBlockSpecConsistency:
         assert None in got["in"]
 
     def test_ragged_bytes_match_block_specs(self, sites):
+        # grid (KV, tiles): the q / out tiles ride BlockSpecs, the pools
+        # stay in HBM behind the kernel's page DMAs (specs opt out, as
+        # paged v2); their bytes are the pages the kernel's own count
+        # visits for the launch the cost states
+        from paddle_tpu.ops import pallas_ragged as pr
         _, ss = sites
         site = _one(ss, "ragged_paged_attention")
-        b = dict(KV=1, S=4, nj=8, T=8, rep=4, psz=16, D=128, total=64)
+        T, S, rep, psz, nj, D = 8, 4, 4, 16, 8, 128
+        tq = pr.ragged_tile_tokens(T, rep, "bfloat16")
+        assert all(pr.ragged_tile_tokens(t, r, dt)
+                   == cm._ragged_tile_tokens(t, r, w)
+                   for t in (8, 9, 40, 288, 2048) for r in (1, 4, 8, 16)
+                   for dt, w in (("float32", 4), ("bfloat16", 2),
+                                 ("int8", 1)))
+        b = dict(KV=1, n_tiles=-(-T // tq), rows=tq * rep, psz=psz, D=D)
         got = km.transfer_bytes(site, b, [BF16] * 3, [BF16])
-        assert got is not None and None not in got["in"] + got["out"]
-        est = cm.cost("ragged_paged_attention", T=8, H=4, KV=1, D=128,
-                      S=4, pages_per_seq=8, page_size=16)
-        q, k, v = got["in"]
-        assert q + k + v == est.bytes_read
+        assert got is not None and got["in"][1:] == [None, None]
+        est = cm.cost("ragged_paged_attention", T=T, H=4, KV=1, D=D,
+                      S=S, pages_per_seq=nj, page_size=psz)
+        q = got["in"][0]
+        assert q + got["out"][0] == est.breakdown["activations"]
         assert got["out"][0] == est.bytes_written
-        assert k + v == est.breakdown["kv"]
+        rows = np.arange(S, dtype=np.int32) * (T // S)
+        visits = pr.ragged_pages_visited(
+            rows, np.full(S, T // S), np.full(S, nj * psz), T=T, rep=rep,
+            dtype="bfloat16", page_size=psz, pages_per_seq=nj)
+        assert visits == S * nj
+        assert est.breakdown["kv"] == 2 * visits * psz * D * BF16
+        assert q + est.breakdown["kv"] == est.bytes_read
 
     def test_flash_fwd_bytes_match_block_specs(self, sites):
         idx, ss = sites
@@ -212,7 +231,7 @@ class TestBlockSpecConsistency:
             v1, dict(B=2, KV=1, nj=8)) == [2, 1, 8]
         rag = _one(ss, "ragged_paged_attention")
         assert km.grid_values(
-            rag, dict(KV=1, S=4, nj=8)) == [1, 4, 8]
+            rag, dict(KV=1, n_tiles=3)) == [1, 3]
         fwd = _one(ss, "_flash_fwd_impl")
         assert km.grid_values(
             fwd, dict(B=2, H=3, nq=2, nk=4)) == [2, 3, 2, 4]

@@ -1278,6 +1278,7 @@ class TestSeededKernelDefects:
 
     RAGGED = "paddle_tpu/ops/pallas_ragged.py"
     FUSED = "paddle_tpu/ops/fused.py"
+    PAGED = "paddle_tpu/ops/pallas_paged.py"
 
     def _analyze(self, tmp_path, rel, tag, old="", new="", append=""):
         src = open(os.path.join(REPO, rel)).read()
@@ -1304,11 +1305,13 @@ class TestSeededKernelDefects:
                 == [], rel
 
     def test_pk101_catches_unclamped_page_table_read(self, tmp_path):
+        # the decode kernel's page map (the ragged kernel reads its
+        # table inside the kernel, for its own page DMAs)
         fresh = self._seed(
-            tmp_path, self.RAGGED,
-            old="phys = jnp.clip(tab[i, jnp.minimum(j, jmax)], 0, "
+            tmp_path, self.PAGED,
+            old="phys = jnp.clip(tab[b, jnp.minimum(j, jmax)], 0, "
                 "total_pages - 1)",
-            new="phys = tab[i, jnp.minimum(j, jmax)]")
+            new="phys = tab[b, jnp.minimum(j, jmax)]")
         assert fresh and {f.rule for f in fresh} == {"PK101"}
         assert all("tab" in f.detail for f in fresh)
 
@@ -1324,8 +1327,8 @@ class TestSeededKernelDefects:
     def test_pk104_catches_bf16_accumulator(self, tmp_path):
         fresh = self._seed(
             tmp_path, self.RAGGED,
-            old="scratch_shapes=[pltpu.VMEM((T * rep, D), jnp.float32),",
-            new="scratch_shapes=[pltpu.VMEM((T * rep, D), jnp.bfloat16),")
+            old="pltpu.VMEM((rows, D), jnp.float32),",
+            new="pltpu.VMEM((rows, D), jnp.bfloat16),")
         assert fresh and {f.rule for f in fresh} == {"PK104"}
         assert fresh[0].detail.startswith("acc:")
 
@@ -2163,8 +2166,8 @@ class TestSeededMemoryDefects:
         # per-core budget
         fresh = self._seed(
             tmp_path, self.RAGGED,
-            old="pltpu.VMEM((T * rep, D), jnp.float32),",
-            new="pltpu.VMEM((T * rep * 4096, D), jnp.float32),")
+            old="pltpu.VMEM((rows, D), jnp.float32),",
+            new="pltpu.VMEM((rows * 4096, D), jnp.float32),")
         assert fresh and {f.rule for f in fresh} == {"PF401"}
         assert fresh[0].detail == "vmem:ragged_paged_attention"
         assert "MiB" in fresh[0].message
@@ -2415,13 +2418,15 @@ class TestSeededEffectsDefects:
         assert pe.qualname == "fused_rope_append"
 
     def test_pe503_catches_dropped_accumulator_guard(self, tmp_path):
-        # delete the @pl.when(j == 0) decorator: _init becomes dead
-        # code (never called), so the online-softmax state is read by
-        # the last-step emit with no first-step seed
+        # delete the seed of the online-softmax state at the top of a
+        # grid cell: the page walk and the emit then read scratch that
+        # no store of this launch has written
         fresh = self._seed(
             tmp_path, self.RAGGED,
-            old="    @pl.when(j == 0)\n    def _init():",
-            new="    def _init():")
+            old="    acc_ref[:] = jnp.zeros_like(acc_ref)\n"
+                "    m_ref[:] = jnp.full_like(m_ref, _NEG)\n"
+                "    l_ref[:] = jnp.zeros_like(l_ref)\n",
+            new="")
         assert fresh and {f.rule for f in fresh} == {"PE503"}
         assert {f.detail for f in fresh} \
             == {"acc:acc_ref", "acc:m_ref", "acc:l_ref"}

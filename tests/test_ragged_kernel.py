@@ -10,9 +10,12 @@ import numpy as np
 import pytest
 
 from paddle_tpu.ops.fused import fused_append_rows, fused_rope_append
-from paddle_tpu.ops.pallas_ragged import (ragged_attention_reference,
+from paddle_tpu.ops.pallas_ragged import (_work_list,
+                                          ragged_attention_reference,
                                           ragged_kernel_eligible,
-                                          ragged_paged_attention)
+                                          ragged_paged_attention,
+                                          ragged_pages_visited,
+                                          ragged_tile_tokens)
 
 
 def _setup(T, S, H, KV, D, psz, pps, seed=0, dtype=jnp.float32):
@@ -48,7 +51,88 @@ def _check(q, kp, vp, ss, nt, kvl, tab, atol=2e-5, rtol=2e-5):
     return out
 
 
+def _engine_layout(kv_dec, chunk, kv_chunk, T=None, chunk_row=None,
+                   share=False, H=8, KV=2, D=64, psz=8, pps=8, seed=0):
+    """The engine's row tables in small: decode slot i owns row i (one
+    token, or none where kv_dec[i] == 0), the prefill chunk owns `chunk`
+    rows from `chunk_row` (default: right after the slots). rep 4 in
+    float32 gives tiles of 32 tokens, so T > 32 spans several."""
+    B = len(kv_dec)
+    chunk_row = B if chunk_row is None else chunk_row
+    T = chunk_row + chunk if T is None else T
+    S = B + 1
+    rng = np.random.RandomState(seed)
+    total = S * pps + 1
+    q = jnp.asarray(rng.randn(T, H, D), jnp.float32)
+    kp = jnp.asarray(rng.randn(KV, total, psz, D), jnp.float32)
+    vp = jnp.asarray(rng.randn(KV, total, psz, D), jnp.float32)
+    tab = 1 + rng.permutation(total - 1)[:S * pps].reshape(S, pps)
+    if share:
+        # a shared prefix: slot 1 and the chunk read slot 0's first pages
+        tab[1, :2] = tab[0, :2]
+        tab[S - 1, :1] = tab[0, :1]
+    ss = np.append(np.arange(B), chunk_row)
+    nt = np.append([int(k > 0) for k in kv_dec], chunk)
+    kvl = np.append(kv_dec, kv_chunk)
+    return (q, kp, vp, jnp.asarray(ss, jnp.int32),
+            jnp.asarray(nt, jnp.int32), jnp.asarray(kvl, jnp.int32),
+            jnp.asarray(tab, jnp.int32))
+
+
+_LAYOUTS = {
+    # 8 decode rows then a chunk that crosses the tile boundary at row
+    # 32; contexts over several pages; T = 48 is not whole tiles
+    "engine": dict(kv_dec=[17, 33, 9, 60, 1, 25, 40, 8], chunk=40,
+                   kv_chunk=24 + 40),
+    # the chunk starts and ends inside tile 0; tile 1 holds no row
+    "chunk_inside_a_tile": dict(kv_dec=[5, 12, 30, 2], chunk=10,
+                                kv_chunk=23, chunk_row=8, T=40),
+    "T_not_whole_tiles": dict(kv_dec=[9, 3], chunk=35, kv_chunk=35,
+                              T=44),
+    # lengths and the chunk's start exactly on page boundaries
+    "on_page_boundaries": dict(kv_dec=[8, 16, 64, 24], chunk=32,
+                               kv_chunk=16 + 32),
+    "idle_between_live": dict(kv_dec=[7, 0, 19, 0, 0, 33, 0, 4], chunk=30,
+                              kv_chunk=41),
+    "no_chunk_decode_only": dict(kv_dec=[7, 19, 0, 64, 33], chunk=0,
+                                 kv_chunk=0, T=37),
+    "shared_physical_pages": dict(kv_dec=[20, 27, 6], chunk=34,
+                                  kv_chunk=50, share=True),
+    # every context is pages_per_seq full pages
+    "full_tables": dict(kv_dec=[64, 64, 64], chunk=36, kv_chunk=64),
+}
+
+
 class TestRaggedKernelParity:
+    @pytest.mark.parametrize("name", list(_LAYOUTS))
+    def test_engine_layouts(self, name):
+        _check(*_engine_layout(**_LAYOUTS[name]))
+
+    @pytest.mark.parametrize("name", list(_LAYOUTS))
+    def test_visit_count_follows_live_pages(self, name):
+        # the exported count (the engine's pages_visited) is the kernel's
+        # own work list; a decode-only launch fetches exactly its live
+        # pages, any launch at most once for every tile
+        q, kp, _, ss, nt, kvl, tab = _engine_layout(**_LAYOUTS[name])
+        T, psz, pps = q.shape[0], kp.shape[2], tab.shape[1]
+        rep = q.shape[1] // kp.shape[0]
+        tq = ragged_tile_tokens(T, rep, q.dtype)
+        tiling = dict(page_size=psz, pages_per_seq=pps)
+        visited = ragged_pages_visited(ss, nt, kvl, T=T, rep=rep,
+                                       dtype=q.dtype, **tiling)
+        tile_first, _, pair_first = _work_list(
+            ss, nt, kvl, tq=tq, n_tiles=-(-T // tq), **tiling)
+        assert int(pair_first[tile_first[-1]]) == visited
+        live = int(np.sum(-(-np.asarray(kvl)[np.asarray(nt) > 0] // psz)))
+        assert live <= visited <= -(-T // tq) * live
+        if int(nt[-1]) == 0:
+            assert visited == live
+        else:
+            # without the chunk nothing is fetched twice
+            dec = ragged_pages_visited(ss[:-1], nt[:-1], kvl[:-1], T=T,
+                                       rep=rep, dtype=q.dtype, **tiling)
+            assert dec == live - -(-int(kvl[-1]) // psz)
+
     @pytest.mark.parametrize("T,S,H,KV,D,psz,pps", [
         (12, 3, 8, 2, 128, 16, 4),   # GQA rep=4, mixed spans
         (9, 4, 4, 1, 64, 16, 2),     # MQA, D=64, non-128-multiple T

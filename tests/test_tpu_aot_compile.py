@@ -207,6 +207,18 @@ def test_ragged_paged_attention_compiles_where_gate_says(chip):
         chip.refusals.get(_ragged)
 
 
+def test_ragged_paged_attention_compiles_at_the_serving_cells_shapes(chip):
+    """`mistral-7b-v0.3-serve-d16` as BENCHMARK.json's serving cells run
+    it: T = 32 slots + a 256-row chunk, 32 q / 8 kv heads x 128, page
+    256, 187 pages, 33 sequences of 16 pages."""
+    t, hq, kv, psz, n_pages, s, nj = 288, 32, 8, 256, 187, 33, 16
+    seq = chip.shape((s,), I32)
+    pool = chip.shape((kv, n_pages, psz, D))
+    assert chip.compiles(_ragged, chip.shape((t, hq, D)), pool, pool,
+                         seq, seq, seq, chip.shape((s, nj), I32)), \
+        chip.refusals.get(_ragged)
+
+
 def _serve_norm_and_linears(x, nw, w8, s8, w4, s4):
     """What the engine's split chain adds around the kernels above:
     the rms norm and, on quantized deploys, the weight-only linears."""

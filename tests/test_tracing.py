@@ -14,6 +14,7 @@ import pytest
 from paddle_tpu import serving as srv
 from paddle_tpu.observability import Histogram, Registry
 from paddle_tpu.observability import tracing as tr
+from paddle_tpu.ops.pallas_ragged import ragged_tile_tokens
 from paddle_tpu.profiler import load_profiler_result
 
 
@@ -405,6 +406,9 @@ class TestStepTimeline:
         outs = self._script(eng, cfg)
         steps = tr.recorder().steps()
         assert [s["seq"] for s in steps] == list(range(1, len(outs) + 1))
+        rows = eng.max_slots + eng.prefill_chunk
+        tiles = -(-rows // ragged_tile_tokens(rows, eng._q_rep,
+                                              eng._q_dtype))
         for s, (out, pages_used) in zip(steps, outs):
             assert s["name"] == "serving.engine.step"
             _inside_and_disjoint((s["start_ns"], s["end_ns"]),
@@ -425,8 +429,13 @@ class TestStepTimeline:
                     # nothing shared, nothing freed: the launch's live
                     # pages are the allocator's own count
                     assert s["pages_live"] == pages_used
-                assert s["pages_visited"] == \
-                    (eng.max_slots + 1) * eng.pages_per_seq
+                # the kernel fetches a sequence's pages once for each
+                # query tile that holds rows of it: decode slots sit in
+                # one tile each, the chunk may span several
+                assert s["pages_live"] <= s["pages_visited"] \
+                    <= tiles * s["pages_live"]
+                if not out["prefill_tokens"]:
+                    assert s["pages_visited"] == s["pages_live"]
             else:
                 # two launches a step, each walks its own sequences
                 if not out["finished"]:
