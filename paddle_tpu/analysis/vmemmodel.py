@@ -252,7 +252,11 @@ CANONICAL: Dict[str, Dict[str, Any]] = {
         mode="activations",
         any_inputs=(1, 2),
         token_tiled=True,
-        families={"llama": dict(KV=8, rows=32, D=128)},
+        # laguna: the widest tile of its two layer kinds (9 query rows a
+        # KV head x 16 tokens, window layers) at the serving page size;
+        # a window changes which pages a tile walks, not what it holds
+        families={"llama": dict(KV=8, rows=32, D=128),
+                  "laguna": dict(KV=8, rows=144, D=128, psz=256)},
     ),
     "mla_decode_attention": dict(
         kernel="mla_decode_attention",

@@ -347,7 +347,12 @@ def _mlp_params(lyr, weight_only_int8: bool = False,
             for k in ("sg", "su", "sd"):
                 _q8(sh, k, weight_only_int8, algo)
             mo["shared"] = sh
-        return dict(moe=mo), dict(top_k=m.top_k, renorm=m.renormalize)
+        st = dict(top_k=m.top_k, renorm=m.renormalize)
+        if m.experts_held is not None or m.routed_scale != 1.0:
+            # one chip's share of an expert-parallel layer (and the
+            # routed scaling factor): static, like the knobs above
+            st.update(held=m.experts_held, scale=m.routed_scale)
+        return dict(moe=mo), st
     d = dict(wg=m.gate_proj.weight._data, wu=m.up_proj.weight._data,
              wd=m.down_proj.weight._data)
     for k in ("wg", "wu", "wd"):
@@ -389,6 +394,67 @@ def _moe_decode_params(model, weight_only_int8: bool = False,
         _q8(p, "head", True, algo)
         p["head"] = None
     return p
+
+
+def _laguna_decode_params(model):
+    """LagunaForCausalLM: a llama-layout weight tree plus, OUTSIDE it
+    like ``moe_static``, one STATIC record a layer (``attn_static``):
+    query heads, window or None, which rope table (a key suffix of the
+    tree's cos / sin).  The serving engine's llama body reads the
+    record where other families read ``cfg``; the head gate is there
+    where a layer has a ``wgate`` leaf.
+
+    Partial rotary: the engine's rope kernels pair dims (i, i + D/2)
+    over the whole head; a rotary width r < D pairs (i, i + r/2).  The
+    SAME column permutation is applied here to ``wq`` and ``wk`` inside
+    every head — rotary first halves, then pass-through dims, in each
+    half of the head — which leaves every q.k unchanged, and the table
+    carries identity (cos 1, sin 0) for the pass-through dims
+    (`models.laguna.rope_table(kernel_layout=True)`).  V is untouched.
+
+    The rope tables are built by ``p["rope_fn"](positions)`` once the
+    caller knows how many positions it serves."""
+    from .models.laguna import FULL, SLIDING, rope_inv_freq
+    inner, cfg = model.model, model.config
+    D = cfg.head_dim
+    suffix = {FULL: "", SLIDING: "_local"}  # of a kind's cos / sin keys
+    layers, attn_static, moe_static = [], [], []
+    for i, lyr in enumerate(inner.layers):
+        a = lyr.self_attn
+        wq, wk = a.q_proj.weight._data, a.k_proj.weight._data
+        r = rope_inv_freq(cfg.rope_parameters[a.kind], D)[2]
+        if r < D:
+            half, rest = r // 2, (D - r) // 2
+            perm = np.concatenate([
+                np.arange(half), r + np.arange(rest),
+                half + np.arange(half), r + rest + np.arange(rest)])
+            wq = wq.reshape(-1, a.heads, D)[..., perm].reshape(wq.shape)
+            wk = wk.reshape(-1, cfg.num_key_value_heads, D)[
+                ..., perm].reshape(wk.shape)
+        d = dict(ln1=lyr.input_layernorm.weight._data, wq=wq, wk=wk,
+                 wv=a.v_proj.weight._data, wgate=a.g_proj.weight._data,
+                 wo=a.o_proj.weight._data,
+                 ln2=lyr.post_attention_layernorm.weight._data)
+        mlp_w, mlp_st = _mlp_params(lyr)
+        d.update(mlp_w)
+        layers.append(d)
+        moe_static.append(mlp_st)
+        attn_static.append(dict(heads=a.heads, window=a.window,
+                                rope=suffix[a.kind]))
+
+    def rope_fn(positions: int):
+        out = {}
+        for kind, (cos, sin) in inner.rope_tables(
+                positions, kernel_layout=True).items():
+            out["cos" + suffix[kind]], out["sin" + suffix[kind]] = cos, sin
+        return out
+
+    return dict(cfg=cfg, family="laguna",
+                embed=inner.embed_tokens.weight._data, layers=layers,
+                norm=inner.norm.weight._data,
+                head=model.lm_head.weight._data,
+                moe_static=tuple(moe_static),
+                attn_static=tuple(attn_static), rope_fn=rope_fn)
 
 
 def _mla_decode_params(model, weight_only_int8: bool = False,
@@ -463,7 +529,14 @@ def _decode_params(model, weight_only_int8: bool = False,
     inner = getattr(model, "model", None)
     if inner is not None:
         from .models.deepseek import DeepSeekV2Model
+        from .models.laguna import LagunaModel
         from .models.moe_llm import MoEModel
+        if isinstance(inner, LagunaModel):
+            if enabled:
+                raise NotImplementedError(
+                    "weight-only quantisation is not wired for the "
+                    "Laguna family")
+            return _laguna_decode_params(model)
         if isinstance(inner, DeepSeekV2Model):
             return _mla_decode_params(model, enabled, algo)
         if isinstance(inner, MoEModel):
@@ -479,7 +552,8 @@ def _llama_weights(p):
     scale (~0.5 GB) that makes XLA chew through the weights at compile
     time."""
     return {k: v for k, v in p.items()
-            if k not in ("cfg", "family", "moe_static")}
+            if k not in ("cfg", "family", "moe_static", "attn_static",
+                         "rope_fn")}
 
 
 def _dq(d, key, dtype):
@@ -546,12 +620,15 @@ def _mm_w(h, L, key):
     return h @ _dq(L, key, h.dtype)
 
 
-def _ffn_apply(L, h2, st=None):
+def _ffn_apply(L, h2, st=None, stats=None, live=None):
     """Per-layer FFN on [B, S, H]: dense SwiGLU (fp or weight-only int8)
     or routed-MoE (dropless per-token top-k — numerics match
     MoELayer._dropless exactly so the cached path exact-matches a
     moe_dropless buffer model). ``st`` holds the layer's STATIC routing
-    knobs (top_k, renorm) from _mlp_params."""
+    knobs (top_k, renorm; held, scale where the layer is one chip's
+    share of an expert-parallel one) from _mlp_params. A routed layer
+    appends its `moe.routing_stats` to the list ``stats``, counted over
+    the rows that ``live`` [B * S] marks (all, if None)."""
     if "moe" not in L:
         return _mm_w(jax.nn.silu(_mm_w(h2, L, "wg"))
                      * _mm_w(h2, L, "wu"), L, "wd")
@@ -559,22 +636,32 @@ def _ffn_apply(L, h2, st=None):
     B, S, H = h2.shape
     T = B * S
     xt = h2.reshape(T, H)
-    gates = jax.nn.softmax(
-        xt.astype(jnp.float32) @ mo["gate"].astype(jnp.float32), axis=-1)
-    from .incubate.moe import dense_expert_ffn, dropless_expert_ffn
+    from .incubate.moe import (dense_expert_ffn, dropless_expert_ffn,
+                               routing_stats)
     # decode steps (tiny T): every-expert dense compute beats the
     # sort+grouped-GEMM path (128-row tile padding) and is bitwise-equal
     ffn = dense_expert_ffn if T <= 32 else dropless_expert_ffn
     dt = h2.dtype
-    y, _ = ffn(xt, gates, _dq(mo, "wge", dt),
-               _dq(mo, "wup", dt), _dq(mo, "wdn", dt),
-               top_k=st["top_k"], renormalize=st["renorm"],
-               activation="swiglu")
-    y = y.reshape(B, S, H).astype(h2.dtype)
+    share = {k: st[k] for k in ("held", "scale") if k in st}
+    # stable scopes in the ops' metadata, for whoever reads a trace
+    with jax.named_scope("routed_ffn"):
+        gates = jax.nn.softmax(
+            xt.astype(jnp.float32) @ mo["gate"].astype(jnp.float32),
+            axis=-1)
+        y, topi = ffn(xt, gates, _dq(mo, "wge", dt),
+                      _dq(mo, "wup", dt), _dq(mo, "wdn", dt),
+                      top_k=st["top_k"], renormalize=st["renorm"],
+                      activation="swiglu", **share)
+        if stats is not None:
+            stats.append(routing_stats(topi, st.get("held"),
+                                       gates.shape[-1], live))
+        y = y.reshape(B, S, H).astype(h2.dtype)
     if "shared" in mo:
         sh = mo["shared"]
-        s = jax.nn.silu(h2 @ _dq(sh, "sg", dt)) * (h2 @ _dq(sh, "su", dt))
-        y = y + s @ _dq(sh, "sd", dt)
+        with jax.named_scope("shared_expert"):
+            s = jax.nn.silu(h2 @ _dq(sh, "sg", dt)) \
+                * (h2 @ _dq(sh, "su", dt))
+            y = y + s @ _dq(sh, "sd", dt)
     return y
 
 
@@ -857,6 +944,12 @@ def _mla_cached_step_body(cfg, max_len: int, moe_static=None):
 
 
 def _cached_step_body(p, max_len: int):
+    if p["family"] == "laguna":
+        raise NotImplementedError(
+            "the Laguna family (per-layer heads, windows, two rope "
+            "tables) decodes through serving.ServingEngine; the "
+            "contiguous-cache generate_cached / generate_compiled "
+            "bodies read one head count and one table")
     if p["family"] == "gpt":
         return _gpt_cached_step_body(p["cfg"], max_len)
     if p["family"] == "mla":

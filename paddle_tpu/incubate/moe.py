@@ -135,17 +135,70 @@ def global_gather(expert_out, combine, expert_axis: Optional[str] = None):
 # layers
 # ---------------------------------------------------------------------------
 
-def dense_expert_ffn(xt, gates, wg, wu, wd, *, top_k: int,
-                     renormalize: bool, activation: str = "swiglu"):
-    """Decode-sized routed FFN: run EVERY expert on every token and
-    weighted-select. At serving token counts (T <= ~32) this beats the
-    sort+grouped-GEMM path, whose per-expert tiles pad to 128 rows — and
-    it is bitwise-identical to it (same per-row matmuls, same combine),
-    so the cached-decode exact-match contract is preserved."""
+def _held_ids(topi, held):
+    """(mask of the pairs whose expert lies in `held = (first, count)`,
+    their ids local to the held stack — `count`, one past the held
+    groups, for the others)."""
+    first, count = held
+    mine = (topi >= first) & (topi < first + count)
+    return mine, jnp.where(mine, topi - first, count)
+
+
+def _route(gates, top_k: int, renormalize: bool, held, scale: float):
+    """Top-k routing over ALL experts of `gates` [T, E]: (weights [T, k],
+    expert ids [T, k], local ids, held mask). `held = (first, count)`
+    names the contiguous experts whose weights this program holds: a
+    pair whose expert lies outside gets weight 0 and the local id
+    `count` (one past the held groups), so it sorts behind them and
+    meets no expert. `scale` is the routed scaling factor. With
+    `held=None` and scale 1 local ids are the ids and the mask is
+    None: the numerics of the uncut layer, bit for bit."""
     topv, topi = jax.lax.top_k(gates, top_k)
     gv = topv
     if renormalize:
         gv = gv / jnp.maximum(jnp.sum(gv, -1, keepdims=True), 1e-9)
+    if scale != 1.0:
+        gv = gv * scale
+    if held is None:
+        return gv, topi, topi, None
+    mine, local = _held_ids(topi, held)
+    return jnp.where(mine, gv, 0.0), topi, local, mine
+
+
+def routing_stats(topi, held, num_experts: int, live=None):
+    """[5] float32 of one routed layer: pairs routed, pairs that met a
+    held expert, the most rows any held expert received, the mean rows
+    a held expert, held experts that received a row (the engine's
+    `moe_*` step counts). `topi` [T, k] is
+    the routing over all `num_experts`; `held = (first, count)` or None
+    for all. `live` [T] bool names the rows a request owns: the padding
+    rows of a fixed-shape launch are routed and computed like any
+    other, but count for nothing here."""
+    held = held if held is not None else (0, num_experts)
+    count = held[1]
+    mine, local = _held_ids(topi, held)
+    n_routed = jnp.asarray(topi.size, jnp.float32)
+    if live is not None:
+        mine = mine & live[:, None]
+        local = jnp.where(live[:, None], local, count)
+        n_routed = jnp.sum(live).astype(jnp.float32) * topi.shape[1]
+    sizes = jnp.bincount(local.reshape(-1), length=count + 1)[:count]
+    n_held = jnp.sum(mine).astype(jnp.float32)
+    return jnp.stack([n_routed, n_held,
+                      jnp.max(sizes).astype(jnp.float32), n_held / count,
+                      jnp.sum(sizes > 0).astype(jnp.float32)])
+
+
+def dense_expert_ffn(xt, gates, wg, wu, wd, *, top_k: int,
+                     renormalize: bool, activation: str = "swiglu",
+                     held=None, scale: float = 1.0):
+    """Decode-sized routed FFN: run EVERY (held) expert on every token
+    and weighted-select. At serving token counts (T <= ~32) this beats
+    the sort+grouped-GEMM path, whose per-expert tiles pad to 128 rows —
+    and it is bitwise-identical to it (same per-row matmuls, same
+    combine), so the cached-decode exact-match contract is preserved.
+    `held` / `scale`: see `dropless_expert_ffn`."""
+    gv, topi, local, mine = _route(gates, top_k, renormalize, held, scale)
     up = jnp.einsum("th,ehi->eti", xt, wu)
     if activation == "swiglu":
         g = jnp.einsum("th,ehi->eti", xt, wg)
@@ -158,28 +211,39 @@ def dense_expert_ffn(xt, gates, wg, wu, wd, *, top_k: int,
     # summation order would argmax-flip near-tied logits vs the
     # buffer/grouped path and break the exact-match contract)
     T = xt.shape[0]
-    sel = down[topi, jnp.arange(T)[:, None]]            # [T, k, H]
+    if mine is not None:
+        local = jnp.minimum(local, wu.shape[0] - 1)     # weight 0 there
+    sel = down[local, jnp.arange(T)[:, None]]           # [T, k, H]
     y = jnp.einsum("tk,tkh->th", gv.astype(sel.dtype), sel)
     return y, topi
 
 
 def dropless_expert_ffn(xt, gates, wg, wu, wd, *, top_k: int,
-                        renormalize: bool, activation: str = "swiglu"):
+                        renormalize: bool, activation: str = "swiglu",
+                        held=None, scale: float = 1.0):
     """Per-token top-k routed expert FFN, dropless (megablocks pattern:
     flatten (token, choice) rows, sort by expert, one ragged grouped GEMM,
     unsort, weighted-combine). SINGLE SOURCE OF TRUTH for the routing
     numerics — MoELayer's training forward and the cached-decode serving
     path (generation._ffn_apply) both call this, so the serving exact-match
-    contract cannot drift. Returns (y [T, H], topi [T, k])."""
+    contract cannot drift. Returns (y [T, H], topi [T, k]).
+
+    One chip's share of an expert-parallel layer: `gates` [T, E] covers
+    ALL experts, `wg` / `wu` / `wd` stack only the `held = (first,
+    count)` contiguous ones. Routing (top-k, renormalise, `scale`) is
+    that of the whole layer; the pairs of other chips' experts sort
+    BEHIND the held groups, where the grouped GEMM owns no row of them
+    (rows past the last group cost no tile and come back zero), and
+    weigh nothing in the combine. The result is this chip's addend of
+    the layer's routed sum."""
     E = wu.shape[0]
     T = xt.shape[0]
-    topv, topi = jax.lax.top_k(gates, top_k)                # [T, k]
-    gv = topv
-    if renormalize:
-        gv = gv / jnp.maximum(jnp.sum(gv, -1, keepdims=True), 1e-9)
+    gv, topi, local, mine = _route(gates, top_k, renormalize, held, scale)
     rows = jnp.repeat(xt, top_k, axis=0)                    # [T*k, H]
-    eids = topi.reshape(-1)                                 # [T*k]
-    srt, sizes, inv = sort_by_group(rows, eids, E)
+    eids = local.reshape(-1)                                # [T*k]
+    srt, sizes, inv = sort_by_group(rows, eids,
+                                    E if mine is None else E + 1)
+    sizes = sizes[:E]
     up = grouped_gemm(srt, wu, sizes)
     if activation == "swiglu":
         g = grouped_gemm(srt, wg, sizes)
@@ -188,6 +252,9 @@ def dropless_expert_ffn(xt, gates, wg, wu, wd, *, top_k: int,
         act = jax.nn.gelu(up)
     down = grouped_gemm(act, wd, sizes)
     down = unsort_by_group(down, inv).reshape(T, top_k, -1)
+    if mine is not None:
+        # whatever a kernel leaves in the rows it does not own
+        down = jnp.where(mine[..., None], down, 0)
     y = jnp.einsum("tk,tkh->th", gv.astype(down.dtype), down)
     return y, topi
 
@@ -206,10 +273,28 @@ class MoELayer(nn.Layer):
                  activation: str = "swiglu", dropless: bool = False,
                  renormalize: bool = True, expert_axis: Optional[str] = None,
                  shared_expert_hidden: int = 0, z_loss_weight: float = 0.0,
-                 name=None):
+                 name=None, experts_held=None, routed_scale: float = 1.0):
         super().__init__()
         if activation not in ("swiglu", "gelu"):
             raise ValueError(f"unsupported activation: {activation}")
+        # one chip's share of an expert-parallel layer: the router
+        # covers all `num_experts`, the stacks hold `experts_held =
+        # (first, count)` of them (dropless_expert_ffn)
+        if experts_held is not None:
+            first, count = (int(v) for v in experts_held)
+            if not (0 <= first and count >= 1
+                    and first + count <= num_experts):
+                raise ValueError(
+                    f"experts_held {experts_held} outside 0..{num_experts}")
+            if not dropless:
+                raise NotImplementedError(
+                    "experts_held needs dropless=True: the capacity "
+                    "dispatch einsums run over every expert")
+            experts_held = (first, count)
+        if routed_scale != 1.0 and not dropless:
+            raise NotImplementedError("routed_scale needs dropless=True")
+        self.experts_held = experts_held
+        self.routed_scale = float(routed_scale)
         self.d_model, self.d_hidden = d_model, d_hidden
         self.num_experts, self.top_k = num_experts, top_k
         self.capacity_factor = capacity_factor
@@ -220,12 +305,14 @@ class MoELayer(nn.Layer):
         self.z_loss_weight = z_loss_weight
         self.l_aux = None
 
-        E, H, Iw = num_experts, d_model, d_hidden
+        H, Iw = d_model, d_hidden
+        Eg = num_experts                # the router's outputs
+        E = experts_held[1] if experts_held else num_experts
         init = I.XavierNormal()
         espec = lambda *rest: P("ep" if expert_axis is None else expert_axis,
                                 *rest)  # noqa: E731
         self.gate_weight = self.create_parameter(
-            [H, E], default_initializer=I.Normal(0.0, 0.02))
+            [H, Eg], default_initializer=I.Normal(0.0, 0.02))
         self.w_up = self.create_parameter([E, H, Iw], default_initializer=init)
         self.w_up._sharding_spec = espec(None, None)
         if activation == "swiglu":
@@ -310,7 +397,9 @@ class MoELayer(nn.Layer):
         k, E = self.top_k, self.num_experts
         y, topi = dropless_expert_ffn(xt, gates, wg, wu, wd, top_k=k,
                                       renormalize=self.renormalize,
-                                      activation=self.activation)
+                                      activation=self.activation,
+                                      held=self.experts_held,
+                                      scale=self.routed_scale)
         mask1 = jax.nn.one_hot(topi[:, 0], E, dtype=gates.dtype)
         return y, load_balance_loss(gates, mask1)
 

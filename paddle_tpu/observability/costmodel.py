@@ -346,7 +346,8 @@ def _ragged_tile_tokens(T: int, rep: int, dtype_bytes: int) -> int:
 @register_cost("ragged_paged_attention")
 def _c_ragged(*, T: int, H: int, KV: int, D: int, S: int,
               pages_per_seq: int, page_size: int,
-              dtype_bytes: int = 2) -> CostEstimate:
+              dtype_bytes: int = 2,
+              window: Optional[int] = None) -> CostEstimate:
     """Ragged mixed prefill+decode, grid (KV, tiles of TQ tokens): each
     cell reads one [TQ*rep, D] query tile and writes one output tile
     (the rows that pad T up to whole tiles are not counted); the pools
@@ -354,14 +355,22 @@ def _c_ragged(*, T: int, H: int, KV: int, D: int, S: int,
     tile, the pages up to the tile's causal limit. Stated for the
     heaviest launch of these shapes: every table full and the T rows
     spread evenly over the S sequences, so a sequence's pages cross once
-    for each tile its rows span (once for a decode batch, T == S)."""
+    for each tile its rows span (once for a decode batch, T == S). With
+    a sliding `window` a tile walks only the pages between the oldest
+    key its first row sees and its last row: at most those spanned by
+    `window - 1` old positions and the tile's own, on any page grid."""
     rep = H // KV
-    spans = _ceil_div(_ceil_div(T, S),
-                      _ragged_tile_tokens(T, rep, dtype_bytes))
+    tq = _ragged_tile_tokens(T, rep, dtype_bytes)
+    per_seq = _ceil_div(T, S)
+    spans = _ceil_div(per_seq, tq)
+    pages, ctx = pages_per_seq, pages_per_seq * page_size
+    if window is not None:
+        pages = min(pages, _ceil_div(window - 1 + min(tq, per_seq),
+                                     page_size) + 1)
+        ctx = min(ctx, window)
     q = KV * T * rep * D * dtype_bytes
-    kv = 2 * KV * S * spans * pages_per_seq * page_size * D * dtype_bytes
+    kv = 2 * KV * S * spans * pages * page_size * D * dtype_bytes
     out = KV * T * rep * D * dtype_bytes
-    ctx = pages_per_seq * page_size
     return CostEstimate(bytes_read=q + kv, bytes_written=out,
                         flops=4 * T * H * ctx * D + 6 * T * H * ctx,
                         breakdown={"kv": kv, "activations": q + out})

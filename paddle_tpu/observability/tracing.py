@@ -60,7 +60,8 @@ from . import DEFAULT_BUCKETS, Histogram, _new_span_id, registry
 
 __all__ = ["TraceEvent", "RequestTrace", "TraceRecorder", "recorder",
            "enabled", "set_enabled", "percentile", "percentiles",
-           "slo_summary", "SLO_METRICS", "STEP_COUNTS"]
+           "slo_summary", "SLO_METRICS", "STEP_COUNTS",
+           "STEP_COUNTS_BY_KIND", "STEP_COUNTS_MOE"]
 
 _FLAG = _flags._registry["FLAGS_request_tracing"]
 
@@ -110,6 +111,19 @@ STEP_COUNTS: Tuple[str, ...] = (
     "decode_rows", "prefill_rows", "live", "waiting", "admitted",
     "finished", "preempted", "cow_pages", "pages_live", "pages_visited",
     "pool_pages_used", "pool_pages_total")
+#: more counts where a model keeps two kinds of cache (full layers and
+#: sliding-window layers; the plain `pages_*` / `pool_pages_*` are then
+#: the sum of both kinds) ...
+STEP_COUNTS_BY_KIND: Tuple[str, ...] = (
+    "pages_live.full", "pages_live.window", "pages_visited.full",
+    "pages_visited.window", "window_pages_freed", "pool_pages_used.full",
+    "pool_pages_used.window", "pool_pages_total.full",
+    "pool_pages_total.window")
+#: ... and where its routed layers hold a share of their experts: taken
+#: on the device, returned with the step's logits
+STEP_COUNTS_MOE: Tuple[str, ...] = (
+    "moe_pairs_routed", "moe_pairs_held", "moe_expert_rows_max",
+    "moe_expert_rows_mean", "moe_experts_hit")
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 

@@ -103,31 +103,42 @@ def _page_buffers(page_bytes: int) -> int:
 
 
 def _tile_pages(xp, seq_start, num_tokens, kv_lengths, *, tq, n_tiles,
-                page_size, pages_per_seq):
+                page_size, pages_per_seq, window=None):
     """[n_tiles, S] int32: the K/V pages tile t walks for sequence i —
     pages 0 .. the causal limit of the sequence's last row in the tile;
     0 where the sequence has no row there. `xp` is numpy (the counter)
-    or jax.numpy (the kernel's work list)."""
+    or jax.numpy (the kernel's work list). With a `window` the walk
+    starts at the page of the oldest key the tile's FIRST row of the
+    sequence still sees (position - window + 1), and the result is the
+    pair (pages walked, first page)."""
     lo = (xp.arange(n_tiles, dtype=xp.int32) * tq)[:, None]
     first = xp.maximum(seq_start[None, :], lo)
     last = xp.minimum((seq_start + num_tokens)[None, :], lo + tq) - 1
-    limit = (kv_lengths - num_tokens - seq_start)[None, :] + last
-    pages = xp.clip(limit // page_size + 1, 0, pages_per_seq)
-    return xp.where(last >= first, pages, 0).astype(xp.int32)
+    base = (kv_lengths - num_tokens - seq_start)[None, :]
+    pages = xp.clip((base + last) // page_size + 1, 0, pages_per_seq)
+    if window is None:
+        return xp.where(last >= first, pages, 0).astype(xp.int32)
+    start = xp.clip((base + first - (window - 1)) // page_size, 0,
+                    pages_per_seq)
+    live = last >= first
+    return (xp.where(live, xp.maximum(pages - start, 0), 0).astype(xp.int32),
+            xp.where(live, start, 0).astype(xp.int32))
 
 
 def ragged_pages_visited(seq_start, num_tokens, kv_lengths, *, T: int,
                          rep: int, dtype, page_size: int,
-                         pages_per_seq: int) -> int:
+                         pages_per_seq: int,
+                         window: Optional[int] = None) -> int:
     """K/V page fetches PER KV HEAD that `ragged_paged_attention` makes
     for this launch (host-side numpy, the engine's `pages_visited`): the
     sum over tiles of the pages each tile walks."""
     tq = ragged_tile_tokens(T, rep, dtype)
-    return int(_tile_pages(
+    pages = _tile_pages(
         np, np.asarray(seq_start, np.int32),
         np.asarray(num_tokens, np.int32), np.asarray(kv_lengths, np.int32),
         tq=tq, n_tiles=-(-T // tq), page_size=page_size,
-        pages_per_seq=pages_per_seq).sum())
+        pages_per_seq=pages_per_seq, window=window)
+    return int((pages if window is None else pages[0]).sum())
 
 
 def _work_list(seq_start, num_tokens, kv_lengths, **tiling):
@@ -137,8 +148,13 @@ def _work_list(seq_start, num_tokens, kv_lengths, **tiling):
     [n_tiles + S] and pair_first [n_tiles + S + 1], the running page
     count (pair p walks pair_first[p+1] - pair_first[p] pages; the count
     also picks the DMA slot). Disjoint row ranges give at most
-    n_tiles + S - 1 pairs; more — overlapping ranges — are dropped."""
+    n_tiles + S - 1 pairs; more — overlapping ranges — are dropped.
+    With a `window` in the tiling pair_seq is twice as long: its second
+    half is the first page each pair walks."""
     pages = _tile_pages(jnp, seq_start, num_tokens, kv_lengths, **tiling)
+    start = None
+    if tiling.get("window") is not None:
+        pages, start = pages
     n_tiles, S = pages.shape
     cap = n_tiles + S
     flat = pages.reshape(-1)
@@ -148,7 +164,10 @@ def _work_list(seq_start, num_tokens, kv_lengths, **tiling):
     tile_first = jnp.minimum(
         jnp.concatenate([zero, jnp.cumsum(per_tile)]), cap)
     pair_first = jnp.concatenate([zero, jnp.cumsum(flat[idx])])
-    return (tile_first.astype(jnp.int32), (idx % S).astype(jnp.int32),
+    pair_seq = idx % S
+    if start is not None:
+        pair_seq = jnp.concatenate([pair_seq, start.reshape(-1)[idx]])
+    return (tile_first.astype(jnp.int32), pair_seq.astype(jnp.int32),
             pair_first.astype(jnp.int32))
 
 
@@ -170,16 +189,21 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
                    first_ref, pseq_ref, pfirst_ref,
                    q_ref, k_hbm, v_hbm, o_ref,
                    kbuf, vbuf, acc_ref, m_ref, l_ref, ahead_ref, sem,
-                   *, page_size, rep, tq, total_pages, scale):
+                   *, page_size, rep, tq, total_pages, scale, window):
     h = pl.program_id(0)
     t = pl.program_id(1)
     n_pairs = first_ref[pl.num_programs(1)]
     depth = kbuf.shape[0]
+    # a windowed launch's pair table is twice as long: pair pi's first
+    # page sits `pairs` entries after its sequence
+    pairs = pseq_ref.shape[0] // 2
 
     def page_dma(pi, j):
         # page j of pair pi lands in the slot its running count picks;
         # sentinel / -1 table entries never emit an out-of-range DMA
         slot = jax.lax.rem(pfirst_ref[pi] + j, depth)
+        if window is not None:
+            j = j + pseq_ref[pairs + pi]
         phys = jnp.clip(tab_ref[pseq_ref[pi], j], 0, total_pages - 1)
         return slot, (
             pltpu.make_async_copy(k_hbm.at[h, phys], kbuf.at[slot],
@@ -228,6 +252,7 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
         # the rows of other sequences attend nothing
         limit = jnp.where((tok >= first_row) & (tok < first_row + nt),
                           kvl_ref[i] - nt + (tok - first_row), -1)
+        page0 = 0 if window is None else pseq_ref[pairs + pi]
 
         def page(j, ahead):
             ahead = fetch_ahead(*ahead)
@@ -241,7 +266,12 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
                 preferred_element_type=jnp.float32) * scale
             # _MASKED is so far below any m (>= _NEG) that exp gives an
             # exact 0: a row with nothing to attend here keeps m, l, acc
-            s = jnp.where(in_page <= limit - j * page_size, s, _MASKED)
+            rel = limit - (page0 + j) * page_size
+            seen = in_page <= rel
+            if window is not None:
+                # each row's own lower bound: keys older than its window
+                seen &= in_page > rel - window
+            s = jnp.where(seen, s, _MASKED)
             m_prev = m_ref[:]
             m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -267,7 +297,8 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
 
 def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
                            kv_lengths, page_tables,
-                           scale: Optional[float] = None):
+                           scale: Optional[float] = None,
+                           window: Optional[int] = None):
     """q [T, H, D] flat new-token buffer; k/v_pages [KV, total_pages,
     page_size, D]; seq_start/num_tokens/kv_lengths [S] int32;
     page_tables [S, pages_per_seq] int32. Sequences own DISJOINT row
@@ -275,6 +306,13 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     non-decreasing in every caller (the work list is built tile-major
     from the ranges themselves and does not lean on the order). Rows
     covered by no sequence return zeros. Returns [T, H, D].
+
+    `window` (static) is a sliding window: the query at position i sees
+    keys j with i - window < j <= i. Pages wholly below a tile's oldest
+    visible key are neither fetched nor read from the page table, so
+    their table entries may be dead. `window=None` is full causal
+    attention and compiles the program it did before the argument
+    existed.
 
     VMEM: one [TQ*rep, D] query tile and output tile (double-buffered by
     the pipeline), that much f32 state, and `_page_buffers` K and V
@@ -289,7 +327,7 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     if not interpret and not isinstance(q, jax.core.Tracer):
         # the operands' memory-space pins below exist only under a trace
         return jax.jit(functools.partial(
-            ragged_paged_attention, scale=scale))(
+            ragged_paged_attention, scale=scale, window=window))(
                 q, k_pages, v_pages, seq_start, num_tokens, kv_lengths,
                 page_tables)
     tq = ragged_tile_tokens(T, rep, q.dtype)
@@ -299,8 +337,9 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     ss = seq_start.astype(jnp.int32)
     nt = num_tokens.astype(jnp.int32)
     kvl = kv_lengths.astype(jnp.int32)
+    tiling = {} if window is None else dict(window=window)
     work = _work_list(ss, nt, kvl, tq=tq, n_tiles=n_tiles, page_size=psz,
-                      pages_per_seq=nj)
+                      pages_per_seq=nj, **tiling)
     # [T, H, D] -> [KV, Tp*rep, D]: a KV head's flat query group (rep
     # rows per token, token-major), cut into tiles of TQ tokens
     qg = (jnp.pad(q, ((0, Tp - T), (0, 0), (0, 0)))
@@ -328,7 +367,8 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     # tile into the next
     out = pl.pallas_call(
         functools.partial(_ragged_kernel, page_size=psz, rep=rep, tq=tq,
-                          total_pages=total, scale=float(scale)),
+                          total_pages=total, scale=float(scale),
+                          window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((KV, Tp * rep, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -343,7 +383,8 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
 
 def ragged_attention_reference(q, k_pages, v_pages, seq_start,
                                num_tokens, kv_lengths, page_tables,
-                               scale: Optional[float] = None):
+                               scale: Optional[float] = None,
+                               window: Optional[int] = None):
     """Plain-XLA oracle with the same ragged semantics (full-softmax,
     gathered pages, jnp.repeat GQA — everything the kernel avoids)."""
     T, H, D = q.shape
@@ -370,6 +411,8 @@ def ragged_attention_reference(q, k_pages, v_pages, seq_start,
     pos = jnp.arange(Tk)
     mask = rv[:, None, :, None] & \
         (pos[None, None, None, :] <= limit[:, None, :, None])
+    if window is not None:
+        mask &= pos[None, None, None, :] > limit[:, None, :, None] - window
     logits = jnp.where(mask, logits, _NEG)
     m = jnp.max(logits, -1, keepdims=True)
     p = jnp.where(mask, jnp.exp(logits - m), 0.0)

@@ -23,6 +23,19 @@ Attention"):
     the trailing partial page); the first write into a shared page
     copies it (COW), so donors and forks never observe each other's
     tokens.
+  - TWO PAGE LIFETIMES (`window=`): a model whose layers are of two
+    kinds keeps two pools under this one manager. The FULL kind is
+    everything above: a sequence keeps every page until it ends. The
+    WINDOW kind (sliding-window layers) has its own pool, free list and
+    page table per sequence; `extend` hands out its pages at the same
+    positions, and `release_window` returns to the free list the pages
+    that lie wholly below `length - window + 1` — the oldest key the
+    NEXT step's oldest query still sees — and points their table
+    entries back at the trash page. A sequence reserves in that pool
+    only what it can hold at once: the pages spanned by `window - 1`
+    old positions plus one step's new ones (`window_span`). Window
+    pages are never shared, so `fork` / `adopt` / `export_seq` /
+    `import_seq` refuse an allocator with a window.
 """
 
 from __future__ import annotations
@@ -55,18 +68,31 @@ _SHARED_TOK = _obs.registry().counter(
 
 
 class _Seq:
-    __slots__ = ("pages", "length", "reserved")
+    __slots__ = ("pages", "length", "reserved", "wpages", "wfirst",
+                 "wreserved")
 
     def __init__(self, pages: List[int], length: int, reserved: int):
         self.pages = pages          # physical page ids, in position order
         self.length = length        # tokens logically present
         self.reserved = reserved    # pages still owed from the free list
+        # the window kind: physical page by logical index (0 = released
+        # or not yet written), the first index still held, pages owed
+        self.wpages: List[int] = []
+        self.wfirst = 0
+        self.wreserved = 0
 
 
 class PageBlockAllocator:
     """Fixed pool of KV pages with refcounted copy-on-write sharing."""
 
-    def __init__(self, num_pages: int, page_size: int, pages_per_seq: int):
+    def __init__(self, num_pages: int, page_size: int, pages_per_seq: int,
+                 window: Optional[int] = None,
+                 window_pages: Optional[int] = None,
+                 window_span: int = 1):
+        """`window` (tokens) switches the second page lifetime on:
+        `window_pages` is that pool's size (its page 0 is its trash
+        page too), `window_span` the most tokens one `extend` adds (the
+        engine's prefill chunk)."""
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is reserved "
                              "as the inactive-slot trash page)")
@@ -85,6 +111,75 @@ class PageBlockAllocator:
         # pins: refcounts held by parties that are not sequences (the
         # prefix-cache trie). A pin keeps a page alive across free().
         self._pinned = np.zeros(self.num_pages, np.int64)
+        self.window = None if window is None else int(window)
+        self.window_pages = 0
+        self._wfree: List[int] = []
+        self._wreserved_total = 0
+        if self.window is not None:
+            if self.window < 1 or window_span < 1:
+                raise ValueError("window and window_span must be >= 1")
+            self.window_span = int(window_span)
+            if window_pages is None or window_pages < 1 + self.window_cap:
+                raise ValueError(
+                    f"window_pages {window_pages} cannot hold one sequence "
+                    f"({self.window_cap} pages + the trash page)")
+            self.window_pages = int(window_pages)
+            self._wfree = list(range(self.window_pages - 1, 0, -1))
+
+    # ------------------------------------------------- the window kind
+    @property
+    def window_cap(self) -> int:
+        """Window-pool pages one sequence can hold at once: `window - 1`
+        old positions and one step's new ones, wherever they fall on
+        the page grid."""
+        return -(-(self.window - 1 + self.window_span)
+                 // self.page_size) + 1
+
+    def _wneed(self, total_tokens: int) -> int:
+        if self.window is None:
+            return 0
+        return min(-(-total_tokens // self.page_size), self.window_cap)
+
+    @property
+    def free_window_pages(self) -> int:
+        return len(self._wfree)
+
+    @property
+    def available_window_pages(self) -> int:
+        return len(self._wfree) - self._wreserved_total
+
+    def release_window(self, seq_id) -> int:
+        """Return to the window pool the pages of `seq_id` that no
+        future query can see: those wholly below `length - window + 1`
+        (the next step's oldest query sits at `length`). Returns how
+        many were freed."""
+        seq = self._seqs[seq_id]
+        keep_from = max(seq.length - self.window + 1, 0) // self.page_size
+        freed = 0
+        for idx in range(seq.wfirst, min(keep_from, len(seq.wpages))):
+            if seq.wpages[idx]:
+                self._wfree.append(seq.wpages[idx])
+                seq.wpages[idx] = 0
+                seq.wreserved += 1      # it may be needed again ahead
+                self._wreserved_total += 1
+                freed += 1
+        seq.wfirst = max(seq.wfirst, min(keep_from, len(seq.wpages)))
+        return freed
+
+    def window_table(self, seq_id) -> np.ndarray:
+        """[pages_per_seq] int32 page table of the window kind: trash
+        where a page was released or is not written yet."""
+        t = np.zeros(self.pages_per_seq, np.int32)
+        pages = self._seqs[seq_id].wpages
+        t[:len(pages)] = pages
+        return t
+
+    def _no_window(self, what: str) -> None:
+        if self.window is not None:
+            raise NotImplementedError(
+                f"{what} shares or moves a sequence's pages; pages of the "
+                f"window kind are released as the window passes them and "
+                f"are never shared")
 
     # ---------------------------------------------------------------- pool
     @property
@@ -143,8 +238,10 @@ class PageBlockAllocator:
         return self._need_pages(total_tokens, share_tokens)
 
     def can_admit(self, total_tokens: int, share_tokens: int = 0) -> bool:
-        return self._need_pages(total_tokens, share_tokens) \
-            <= self.available_pages
+        return (self._need_pages(total_tokens, share_tokens)
+                <= self.available_pages
+                and self._wneed(total_tokens)
+                <= self.available_window_pages)
 
     # ------------------------------------------------------------ lifecycle
     def allocate(self, seq_id, total_tokens: int) -> None:
@@ -159,8 +256,16 @@ class PageBlockAllocator:
                 f"page pool exhausted: sequence needs {need} pages, "
                 f"{self.available_pages} available "
                 f"({self.num_pages - 1} usable)")
-        self._seqs[seq_id] = _Seq([], 0, need)
+        wneed = self._wneed(total_tokens)
+        if wneed > self.available_window_pages:
+            raise _res.Overloaded(
+                f"window page pool exhausted: sequence needs {wneed} "
+                f"pages, {self.available_window_pages} available "
+                f"({self.window_pages - 1} usable)")
+        seq = self._seqs[seq_id] = _Seq([], 0, need)
+        seq.wreserved = wneed
         self._reserved_total += need
+        self._wreserved_total += wneed
         self.publish_gauges()
 
     def fork(self, parent_id, child_id, share_tokens: int,
@@ -169,6 +274,7 @@ class PageBlockAllocator:
         `parent_id`'s cache by refcount. The child starts at
         length == share_tokens; its first write into the trailing
         partially-shared page copies it (COW)."""
+        self._no_window("fork")
         parent = self._seqs[parent_id]
         if share_tokens < 0 or share_tokens > parent.length:
             raise ValueError(
@@ -213,6 +319,7 @@ class PageBlockAllocator:
         page_size), so the adopter's first write lands on a fresh page —
         no COW and no donor_extra charge. Raises `resilience.Overloaded`
         pre-mutation when the pool cannot cover the tail."""
+        self._no_window("adopt")
         self._check_new(seq_id, total_tokens)
         ps = self.page_size
         if share_tokens != len(pages) * ps:
@@ -263,6 +370,11 @@ class PageBlockAllocator:
                 copies.append((src, dst))
                 if _obs.enabled():
                     _COW.inc()
+            if self.window is not None:
+                while len(seq.wpages) <= idx:
+                    seq.wpages.append(0)
+                if not seq.wpages[idx]:
+                    seq.wpages[idx] = self._pop_window_page(seq)
         seq.length += n_tokens
         return copies
 
@@ -296,6 +408,7 @@ class PageBlockAllocator:
         speculative-decode `shrink` a sequence may keep a trailing page
         whose KV beyond `length` is stale-but-unobservable, and the
         importer materializes exactly `ceil(length / page_size)` pages."""
+        self._no_window("export_seq")
         seq = self._seqs[seq_id]
         n_pages = -(-seq.length // self.page_size)
         pages = list(seq.pages[:n_pages])
@@ -325,6 +438,7 @@ class PageBlockAllocator:
         the handoff payload into exactly these pages. Raises
         `resilience.Overloaded` pre-mutation when the pool cannot cover
         the sequence."""
+        self._no_window("import_seq")
         if length < 1 or length > total_tokens:
             raise ValueError(
                 f"import length {length} outside [1, {total_tokens}]")
@@ -345,6 +459,8 @@ class PageBlockAllocator:
             if self._ref[pg] == 0:
                 self._free.append(pg)
         self._reserved_total -= seq.reserved
+        self._wfree.extend(pg for pg in seq.wpages if pg)
+        self._wreserved_total -= seq.wreserved
         self.publish_gauges()
 
     # -------------------------------------------------------------- queries
@@ -391,6 +507,9 @@ class PageBlockAllocator:
             "reserved": self._reserved_total,
             "sequences": len(self._seqs),
             "pinned_pages": int((self._pinned > 0).sum()),
+            "window_pages_used": max(self.window_pages - 1, 0)
+            - len(self._wfree),
+            "window_pages_free": len(self._wfree),
         }
 
     def publish_gauges(self) -> None:
@@ -426,3 +545,11 @@ class PageBlockAllocator:
             self._reserved_total -= 1
         self._ref[pg] = 1
         return pg
+
+    def _pop_window_page(self, seq: _Seq) -> int:
+        if not self._wfree or seq.wreserved < 1:
+            # as above: the reservation (window_cap) is the guarantee
+            raise _res.Overloaded("window page pool exhausted mid-flight")
+        seq.wreserved -= 1
+        self._wreserved_total -= 1
+        return self._wfree.pop()

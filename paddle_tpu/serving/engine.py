@@ -237,8 +237,6 @@ class ServingEngine:
         self._handoff_counts = {"export": 0, "import": 0}
         p = _decode_params(model, weight_only_int8, weight_only_quant)
         cfg = p["cfg"]
-        self._p = p
-        self._w = _llama_weights(p)
         self._family = p["family"]
         self.max_slots = int(max_slots)
         self.page_size = int(page_size)
@@ -247,6 +245,12 @@ class ServingEngine:
             raise ValueError(
                 f"max_context {self.max_context} exceeds "
                 f"max_position_embeddings {cfg.max_position_embeddings}")
+        if "rope_fn" in p:
+            # rope tables to the positions THIS engine serves, not to
+            # the model's published maximum
+            p.update(p.pop("rope_fn")(self.max_context))
+        self._p = p
+        self._w = _llama_weights(p)
         self.prefill_chunk = int(prefill_chunk)
         if self.prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
@@ -254,9 +258,55 @@ class ServingEngine:
         if num_pages is None:
             num_pages = self.max_slots * self.pages_per_seq + 1
         self.num_pages = int(num_pages)
+        # per-layer geometry: query heads, window and rope table of each
+        # layer, the model's own where it has them (Laguna), else cfg's
+        # one head count. A model with sliding-window layers keeps TWO
+        # kinds of cache — pools, page tables and page lifetimes by
+        # layer kind, one allocator.
+        self._attn_static = p.get("attn_static") or (dict(
+            heads=cfg.num_attention_heads, window=None,
+            rope=""),) * len(p["layers"])
+        windows = sorted({st["window"] for st in self._attn_static
+                          if st["window"] is not None})
+        if len(windows) > 1:
+            raise NotImplementedError(
+                f"one window size a model, got {windows}")
+        self._window = windows[0] if windows else None
+        if self._window is not None:
+            why = (f"this model has sliding-window layers (window "
+                   f"{self._window}): their pages are released as the "
+                   f"window passes them, so ")
+            if ragged is False:
+                raise ValueError(why + "only the unified ragged step "
+                                 "serves it (the split path has one page "
+                                 "table and no window)")
+            if enable_prefix_cache:
+                raise ValueError(why + "a cached prefix cannot be "
+                                 "adopted; enable_prefix_cache must be off")
+            if spec_decode:
+                raise ValueError(why + "a rejected draft cannot roll the "
+                                 "cache back; spec_decode must be 0")
+            if role != "colocated":
+                raise ValueError(why + "export_request / import_request "
+                                 "are not supported; role must be "
+                                 "'colocated'")
+            enable_prefix_cache = False
+            # a live donor's early pages are gone from the window pool
+            prefix_sharing = False
+            # the window kind's pool follows from the slots and the
+            # chunk: what every slot and one more sequence (one being
+            # admitted, or preempted with its pages kept) can hold
+            cap = -(-(self._window - 1 + self.prefill_chunk)
+                    // self.page_size) + 1
+            self.num_window_pages = (self.max_slots + 1) * cap + 1
+        else:
+            self.num_window_pages = 0
         self.prefix_sharing = bool(prefix_sharing)
         self.allocator = PageBlockAllocator(
-            self.num_pages, self.page_size, self.pages_per_seq)
+            self.num_pages, self.page_size, self.pages_per_seq,
+            window=self._window,
+            window_pages=self.num_window_pages or None,
+            window_span=self.prefill_chunk)
         admission = getattr(config, "_admission", None)
         self._default_deadline_s = getattr(config, "_deadline_s", None)
         self.scheduler = Scheduler(
@@ -288,23 +338,42 @@ class ServingEngine:
         else:
             kv, d = cfg.num_key_value_heads, cfg.head_dim
         shape = (kv, self.num_pages, self.page_size, d)
-        # what the ragged kernel's tiling follows (pages_visited)
-        self._q_rep, self._q_dtype = cfg.num_attention_heads // kv, dt
+        wshape = (kv, self.num_window_pages, self.page_size, d)
+        # each layer's kind: 0 keeps every page, 1 is the window kind
+        self._layer_kind = [int(st["window"] is not None)
+                            for st in self._attn_static]
+        heads = sorted({st["heads"] for st in self._attn_static})
+        # what the ragged kernel's tiling follows (pages_visited): the
+        # query rows a KV head of each layer kind's layers
+        self._q_rep, self._q_dtype = heads[0] // kv, dt
+        self._kind_rep = {
+            k: sorted({st["heads"] // kv for st, kk in zip(
+                self._attn_static, self._layer_kind) if kk == k})
+            for k in set(self._layer_kind)}
         if self._family == "mla":
             # one pool per layer: each row is [latent | rope-key], read
             # as both K and V by the concat-dot absorbed decode
             self._pools = [jnp.zeros(shape, dt) for _ in range(n_layers)]
         else:
-            self._pools = [(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
-                           for _ in range(n_layers)]
+            self._pools = [(jnp.zeros(sh, dt), jnp.zeros(sh, dt))
+                           for sh in (wshape if k else shape
+                                      for k in self._layer_kind)]
 
         # dispatch path: the unified ragged launch by default, unless
         # the ragged kernel's tiling constraints don't hold on a real
         # TPU (interpret mode has none) or the caller pins the path
+        eligible = (jax.default_backend() != "tpu"
+                    or all(ragged_kernel_eligible(h, kv, d, self.page_size)
+                           for h in heads))
         if ragged is None:
-            ragged = (jax.default_backend() != "tpu"
-                      or ragged_kernel_eligible(
-                          cfg.num_attention_heads, kv, d, self.page_size))
+            ragged = eligible
+        if "attn_static" in p and not (ragged and eligible):
+            raise ValueError(
+                f"family {self._family!r} (per-layer heads {heads}, window "
+                f"{self._window}) is served by the unified ragged step "
+                f"only" + (f", and the ragged kernel is not eligible here: "
+                           f"{kv} KV heads x {d}, page {self.page_size}"
+                           if ragged else " (ragged=False asked for)"))
         self.ragged = bool(ragged)
         if spec_decode < 0:
             raise ValueError("spec_decode must be >= 0")
@@ -321,7 +390,7 @@ class ServingEngine:
         # data-dependent — but still take the fused o-proj+norm kernel)
         ow = (cfg.num_attention_heads * cfg.v_head_dim
               if self._family == "mla"
-              else cfg.num_attention_heads * cfg.head_dim)
+              else heads[-1] * cfg.head_dim)     # the widest layer's
         int4 = any(k.endswith("_q4") for L in p["layers"] for k in L)
         int8 = any(k.endswith("_q") for L in p["layers"] for k in L)
         # stored bytes of one fp/int8 weight element, for the VMEM gates
@@ -353,9 +422,18 @@ class ServingEngine:
             else self._split_front_launches()
         self.launches = 0      # device program launches by THIS engine
         self.steps = 0         # step() calls: the step timeline's `seq`
+        #: set to a callable (request, logits row [vocab]) to be handed
+        #: the host copy of the row each emitted token was sampled from
+        #: (a check against a reference in logits); None costs nothing
+        self.on_logits = None
         # the open step's counts, taken where the work happens and
         # closed into the recorder's step record (tracing.STEP_COUNTS)
-        self._counts = dict.fromkeys(_tracing.STEP_COUNTS, 0)
+        self._count_names = _tracing.STEP_COUNTS
+        if self._window is not None:
+            self._count_names += _tracing.STEP_COUNTS_BY_KIND
+        if any(st and "held" in st for st in p.get("moe_static") or ()):
+            self._count_names += _tracing.STEP_COUNTS_MOE
+        self._counts = dict.fromkeys(self._count_names, 0)
 
         # live HBM accounting (ISSUE 11): static residency is published
         # once; a cumulative analytical ledger turns each launch into
@@ -366,8 +444,10 @@ class ServingEngine:
         self._kv_itemsize = int(jnp.dtype(dt).itemsize)
         planes = 1 if self._family == "mla" else 2
         self._hbm_weights_bytes = _costmodel.tree_bytes(self._w)
-        self._hbm_pool_bytes = (n_layers * planes * kv * self.num_pages
-                                * self.page_size * d * self._kv_itemsize)
+        self._hbm_pool_bytes = sum(
+            planes * kv * (self.num_window_pages if k else self.num_pages)
+            * self.page_size * d * self._kv_itemsize
+            for k in self._layer_kind)
         self._ledger_bytes = 0.0
         self._ledger_model_bytes = 0.0
         self._ledger_tokens = 0
@@ -431,9 +511,11 @@ class ServingEngine:
             n = (cfg.num_attention_heads * dh
                  + cfg.kv_lora_rank + cfg.qk_rope_head_dim)
             return eligible(cfg.hidden_size, n, dh)
-        n = (cfg.num_attention_heads
-             + 2 * cfg.num_key_value_heads) * cfg.head_dim
-        return eligible(cfg.hidden_size, n, cfg.head_dim, int4=int4)
+        return all(
+            eligible(cfg.hidden_size,
+                     (h + 2 * cfg.num_key_value_heads) * cfg.head_dim,
+                     cfg.head_dim, int4=int4)
+            for h in {st["heads"] for st in self._attn_static})
 
     def _split_front_launches(self) -> int:
         """Launches before attention on the SPLIT front path, per layer
@@ -517,6 +599,12 @@ class ServingEngine:
             new_k = 0   # the split path has no multi-row slots
         if (new_chunk, new_k) == (self.prefill_chunk, self.spec_k):
             return False
+        if self._window is not None and (
+                new_k or new_chunk > self.allocator.window_span):
+            raise ValueError(
+                "a model with sliding-window layers reserves its window "
+                "pages for the prefill chunk it was built with: the chunk "
+                "can only shrink, and spec_decode stays 0")
         self.prefill_chunk = new_chunk
         self.spec_k = new_k
         self._gate_megadecode()
@@ -580,7 +668,7 @@ class ServingEngine:
         out = {"admitted": 0, "prefill_tokens": 0, "decoded": 0,
                "finished": 0}
         self.steps += 1
-        self._counts = dict.fromkeys(_tracing.STEP_COUNTS, 0)
+        self._counts = dict.fromkeys(self._count_names, 0)
         _TRACE.set_replica_context(self.replica)
         _TRACE.open_step(self.steps, "serving.engine.step")
         try:
@@ -639,9 +727,26 @@ class ServingEngine:
                 _TRACE.sample_gauges(_COUNTER_GAUGES)
             if self.controller is not None:
                 self.controller.on_step(out)
-            self._counts["pool_pages_total"] = self.allocator.num_pages - 1
-            self._counts["pool_pages_used"] = \
-                self.allocator.num_pages - 1 - self.allocator.free_pages
+            full = (self.allocator.num_pages - 1,
+                    self.allocator.num_pages - 1 - self.allocator.free_pages)
+            win = (0, 0)
+            if self._window is not None:
+                # the window kind's lifetime: pages no future query sees
+                # go back to their pool before the next step admits
+                freed = sum(self.allocator.release_window(req.request_id)
+                            for _, req in self.scheduler.active()
+                            if self.allocator.has_seq(req.request_id))
+                win = (self.allocator.window_pages - 1,
+                       self.allocator.window_pages - 1
+                       - self.allocator.free_window_pages)
+                self._counts.update({
+                    "window_pages_freed": freed,
+                    "pool_pages_total.full": full[0],
+                    "pool_pages_used.full": full[1],
+                    "pool_pages_total.window": win[0],
+                    "pool_pages_used.window": win[1]})
+            self._counts["pool_pages_total"] = full[0] + win[0]
+            self._counts["pool_pages_used"] = full[1] + win[1]
 
     # ------------------------------------------------- HBM accounting
     def _account_step(self, out: Dict[str, int]) -> None:
@@ -672,9 +777,17 @@ class ServingEngine:
                                 + int(out["prefill_tokens"]))
         if dl:
             pages = sum(-(-ln // self.page_size) for ln in lens)
+            layer_pages = pages * n_layers
+            if self._window is not None:
+                # a window layer reads the pages its window spans
+                wcap = -(-self._window // self.page_size) + 1
+                layer_pages = (
+                    pages * self._layer_kind.count(0)
+                    + sum(min(-(-ln // self.page_size), wcap)
+                          for ln in lens) * self._layer_kind.count(1))
             self._ledger_bytes += (
                 dl * self._hbm_weights_bytes
-                + dl * pages * self.page_size * per_tok * n_layers
+                + dl * layer_pages * self.page_size * per_tok
                 * spec_rows)
             if lens:
                 # the budget's view of the SAME step: one weight pass +
@@ -827,6 +940,7 @@ class ServingEngine:
         pages readable until the importer's `release()`, and trie pins
         keep shared prompt pages warm on this replica regardless."""
         rid = req.request_id
+        self._no_handoff("export_request")
         _TRACE.set_replica_context(self.replica)
         if req.pending is None or req.prefill_pos < int(req.prompt.size):
             raise ValueError(
@@ -877,6 +991,13 @@ class ServingEngine:
         handoff.trace = _TRACE.export_context(rid)
         return handoff
 
+    def _no_handoff(self, what: str) -> None:
+        if self._window is not None:
+            raise NotImplementedError(
+                f"{what}: this model has sliding-window layers, whose "
+                f"pages are released as the window passes them; a "
+                f"KV-page handoff of two page kinds is not implemented")
+
     def import_request(self, handoff: KVPageHandoff) -> Request:
         """Receive side of the handoff: allocate destination pages,
         copy the block payload into this replica's pools, and submit
@@ -885,6 +1006,7 @@ class ServingEngine:
         re-prefill. Raises `resilience.Overloaded` (allocator or
         admission gate) with this replica unchanged, so the router can
         retry the same handoff elsewhere."""
+        self._no_handoff("import_request")
         if self.role == "prefill":
             raise ValueError("prefill-role replica cannot decode an "
                              "imported request")
@@ -1058,7 +1180,8 @@ class ServingEngine:
             spare = self.allocator.available_pages + (
                 self.prefix_cache.evictable_pages()
                 if self.prefix_cache is not None else 0)
-            if need > spare:
+            if need > spare or (self._window is not None and not
+                                self.allocator.can_admit(cand.total_tokens)):
                 return None
         self.scheduler.preempt(victim)
         self._counts["preempted"] += 1
@@ -1113,7 +1236,7 @@ class ServingEngine:
             if self.prefix_cache is not None and self.prefix_cache_admit:
                 self.prefix_cache.insert(
                     req.prompt, self.allocator.seq_pages(req.request_id))
-            finished = self._emit(req, int(np.argmax(row)))
+            finished = self._emit(req, int(np.argmax(row)), row)
             if not finished and self.role == "prefill":
                 self._stage_handoff(req)
         return n, finished
@@ -1152,7 +1275,8 @@ class ServingEngine:
         with _obs.span("serving.engine.sample"):
             finished = 0
             for slot, req in active:
-                finished += self._emit(req, int(np.argmax(logits[slot])))
+                finished += self._emit(req, int(np.argmax(logits[slot])),
+                                       logits[slot])
         return len(active), finished
 
     # ------------------------------------------------------------ unified
@@ -1188,9 +1312,10 @@ class ServingEngine:
         with _obs.span("serving.engine.build"):
             host, drafts, n, start = self._build_unified(preq, active)
         with _obs.span("serving.engine.launch"):
-            logits, self._pools = self._jit_unified(
+            logits, self._pools, *moe = self._jit_unified(
                 self._w, jnp.asarray(host[0]), self._pools,
-                *(jnp.asarray(t) for t in host[1:]))
+                *(jax.tree_util.tree_map(jnp.asarray, t)
+                  for t in host[1:]))
             if preq is not None:
                 _TRACE.stamp(preq.request_id, "prefill_chunk", tokens=n,
                              start=start)
@@ -1205,6 +1330,11 @@ class ServingEngine:
         with _obs.span("serving.engine.sync"):
             # the wait for the device and the copy back
             logits = np.asarray(logits)     # [S, vocab]; [T, vocab] K>0
+            if moe:
+                # the routed layers' counts came back with the logits
+                self._counts.update(zip(
+                    _tracing.STEP_COUNTS_MOE,
+                    (float(v) for v in np.asarray(moe[0]))))
         with _obs.span("serving.engine.sample"):
             decoded, finished = self._sample_unified(
                 logits, preq, active, drafts, n)
@@ -1214,7 +1344,9 @@ class ServingEngine:
         """The host half of the unified launch: extend every sequence
         (applying copy-on-write copies) and fill the row tables.
         Returns ((tok, positions, num_tokens, kv_lengths, tables,
-        tok_page, tok_off), drafts by slot, prefill rows, their start)."""
+        tok_page, tok_off), drafts by slot, prefill rows, their start).
+        With sliding-window layers `tables` and `tok_page` are pairs:
+        (the full kind's, the window kind's)."""
         B, C, K = self.max_slots, self.prefill_chunk, self.spec_k
         R = 1 + K
         base = B * R
@@ -1227,6 +1359,10 @@ class ServingEngine:
         tables = np.zeros((S, nj), np.int32)   # idle -> trash page 0
         tok_page = np.zeros(T, np.int32)
         tok_off = np.zeros(T, np.int32)
+        windowed = self._window is not None
+        if windowed:
+            wtables = np.zeros((S, nj), np.int32)
+            wtok_page = np.zeros(T, np.int32)
         drafts: Dict[int, List[int]] = {}
         for slot, req in active:
             ln = self.allocator.seq_length(req.request_id)
@@ -1253,6 +1389,10 @@ class ServingEngine:
             tables[slot] = tbl
             tok_page[r0:r0 + nt] = tbl[pos // ps]
             tok_off[r0:r0 + nt] = pos % ps
+            if windowed:
+                wtables[slot] = wt = self.allocator.window_table(
+                    req.request_id)
+                wtok_page[r0:r0 + nt] = wt[pos // ps]
             if d:
                 _TRACE.stamp(req.request_id, "draft", tokens=len(d))
         n, start = 0, 0
@@ -1270,18 +1410,46 @@ class ServingEngine:
             tables[S - 1] = tbl
             tok_page[base:base + n] = tbl[(start + rows) // ps]
             tok_off[base:base + n] = (start + rows) % ps
+            if windowed:
+                wtables[S - 1] = wt = self.allocator.window_table(
+                    preq.request_id)
+                wtok_page[base:base + n] = wt[(start + rows) // ps]
         self._counts["decode_rows"] = int(num_tokens[:B].sum())
         self._counts["prefill_rows"] = n
         # pages that hold this launch's tokens, against the K/V page
         # fetches the ragged kernel makes for each KV head (a sequence's
         # pages once for every query tile that holds rows of it)
-        self._counts["pages_live"] = int(np.sum(-(-kv_lengths // ps)))
-        self._counts["pages_visited"] = ragged_pages_visited(
-            np.append(np.arange(B) * R, base), num_tokens, kv_lengths,
-            T=T, rep=self._q_rep, dtype=self._q_dtype, page_size=ps,
-            pages_per_seq=nj)
-        return ((tok, positions, num_tokens, kv_lengths, tables, tok_page,
-                 tok_off), drafts, n, start)
+        seq_start = np.append(np.arange(B) * R, base)
+
+        def visited(kind, window=None):
+            # one layer of each head count of the kind, summed
+            return sum(ragged_pages_visited(
+                seq_start, num_tokens, kv_lengths, T=T, rep=r,
+                dtype=self._q_dtype, page_size=ps, pages_per_seq=nj,
+                window=window) for r in self._kind_rep[kind])
+
+        live = int(np.sum(-(-kv_lengths // ps)))
+        self._counts["pages_live"] = live
+        self._counts["pages_visited"] = visited(0)
+        if not windowed:
+            return ((tok, positions, num_tokens, kv_lengths, tables,
+                     tok_page, tok_off), drafts, n, start)
+        # the window kind: the pages between each sequence's oldest
+        # visible key and its newest
+        W = self._window
+        oldest = np.maximum(kv_lengths - num_tokens - W + 1, 0)
+        wlive = int(np.sum(np.where(
+            num_tokens > 0, (kv_lengths - 1) // ps - oldest // ps + 1, 0)))
+        wvisited = visited(1, W)
+        self._counts.update({
+            "pages_live.full": live, "pages_live.window": wlive,
+            "pages_visited.full": self._counts["pages_visited"],
+            "pages_visited.window": wvisited})
+        self._counts["pages_live"] += wlive
+        self._counts["pages_visited"] += wvisited
+        return ((tok, positions, num_tokens, kv_lengths,
+                 (tables, wtables), (tok_page, wtok_page), tok_off),
+                drafts, n, start)
 
     def _sample_unified(self, logits: np.ndarray, preq: Optional[Request],
                         active, drafts: Dict[int, List[int]],
@@ -1307,7 +1475,7 @@ class ServingEngine:
                         preq.prompt,
                         self.allocator.seq_pages(preq.request_id))
                 row = logits[base + n - 1] if K else logits[B]
-                fin = self._emit(preq, int(np.argmax(row)))
+                fin = self._emit(preq, int(np.argmax(row)), row)
                 finished += fin
                 if not fin and self.role == "prefill":
                     self._stage_handoff(preq)
@@ -1316,7 +1484,7 @@ class ServingEngine:
             d = drafts[slot]
             if not d:
                 row = logits[slot * R] if K else logits[slot]
-                finished += self._emit(req, int(np.argmax(row)))
+                finished += self._emit(req, int(np.argmax(row)), row)
                 decoded += 1
                 continue
             r0 = slot * R
@@ -1326,7 +1494,7 @@ class ServingEngine:
             fin = 0
             for j in range(m + 1):
                 decoded += 1
-                fin = self._emit(req, greedy[j])
+                fin = self._emit(req, greedy[j], logits[r0 + j])
                 if fin:
                     break   # EOS/max_new: _finish already freed the seq
             finished += fin
@@ -1344,9 +1512,14 @@ class ServingEngine:
             _TOKENS.labels(phase="decode").inc(decoded)
         return decoded, finished
 
-    def _emit(self, req: Request, tok: int) -> int:
+    def _emit(self, req: Request, tok: int,
+              row: Optional[np.ndarray] = None) -> int:
         """Record one sampled token; finish on EOS/max-tokens (pages
-        freed the same step), else stage it for the next decode step."""
+        freed the same step), else stage it for the next decode step.
+        `row` is the logits row the token was taken from, for whoever
+        set `on_logits`."""
+        if self.on_logits is not None and row is not None:
+            self.on_logits(req, row)
         req.tokens.append(tok)
         _TRACE.stamp(req.request_id, "token", index=len(req.tokens) - 1)
         done = (req.eos_token_id is not None and tok == req.eos_token_id) \
@@ -1373,6 +1546,8 @@ class ServingEngine:
         pools before the write that triggered them."""
         if not copies:
             return
+        # (a copied page is a shared page: never under a window, whose
+        # allocator refuses fork and adopt)
         self._counts["cow_pages"] += len(copies)
         if req is not None:
             _TRACE.stamp(req.request_id, "cow", pages=len(copies))
@@ -1431,10 +1606,11 @@ class ServingEngine:
 
     def _llama_unified_body(self):
         cfg = self._p["cfg"]
-        Hh, KV, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                     cfg.head_dim)
+        KV, D = cfg.num_key_value_heads, cfg.head_dim
         eps = cfg.rms_norm_eps
         moe_static = self._p.get("moe_static")
+        attn_static = self._attn_static
+        count_moe = _tracing.STEP_COUNTS_MOE[0] in self._count_names
         mega = self.megadecode
         megafront = self.megafront
         B, C, K = self.max_slots, self.prefill_chunk, self.spec_k
@@ -1449,11 +1625,28 @@ class ServingEngine:
         def step(w, tok, pools, positions, num_tokens, kv_lengths,
                  tables, tok_page, tok_off):
             x = w["embed"][tok][None]                    # [1, T, H]
-            c = w["cos"][positions]                      # [T, D/2]
-            s = w["sin"][positions]
+            # [T, D/2] trig rows of each rope table the layers name
+            trig = {sfx: (w["cos" + sfx][positions],
+                          w["sin" + sfx][positions])
+                    for sfx in sorted({a["rope"] for a in attn_static})}
+            if not isinstance(tables, tuple):
+                tables, tok_page = (tables,), (tok_page,)
             new_pools = []
+            moe_stats = [] if count_moe else None
+            # the rows a sequence owns this step: the routed layers'
+            # counts leave the idle rows of the flat buffer out
+            row = jnp.arange(T, dtype=jnp.int32)[:, None]
+            live = jnp.any((row >= seq_start) & (
+                row < seq_start + num_tokens), -1) if count_moe else None
             sts = moe_static or (None,) * len(w["layers"])
-            for L, (kp, vp), st in zip(w["layers"], pools, sts):
+            for L, (kp, vp), st, ast in zip(w["layers"], pools, sts,
+                                            attn_static):
+                Hh, window = ast["heads"], ast["window"]
+                c, s = trig[ast["rope"]]
+                # the layer's kind of cache: its page table and the
+                # physical pages its new rows land in
+                kind = int(window is not None)
+                table, page = tables[kind], tok_page[kind]
                 h = fused_rms_norm(x, L["ln1"], eps)
                 if megafront:
                     # ISSUE 20 front half: qkv projection (in-kernel
@@ -1462,7 +1655,7 @@ class ServingEngine:
                     wp, ws = _wq2(L, "wqkv")
                     q, kp, vp = fused_qkv_rope_append(
                         h[0], wp, ws, L.get("bqkv"), c, s, kp, vp,
-                        tok_page, tok_off, heads=Hh, kv_heads=KV,
+                        page, tok_off, heads=Hh, kv_heads=KV,
                         head_dim=D, algo=_walgo(L, "wqkv"))
                 else:
                     q, k, v = (_mm_w(h, L, "wq"), _mm_w(h, L, "wk"),
@@ -1471,19 +1664,25 @@ class ServingEngine:
                         q, k, v = q + L["bq"], k + L["bk"], v + L["bv"]
                     q, kp, vp = fused_rope_append(
                         q.reshape(T, Hh, D), k.reshape(T, KV, D),
-                        v.reshape(T, KV, D), c, s, kp, vp, tok_page,
+                        v.reshape(T, KV, D), c, s, kp, vp, page,
                         tok_off)
                 new_pools.append((kp, vp))
                 o = ragged_paged_attention(q, kp, vp, seq_start,
                                            num_tokens, kv_lengths,
-                                           tables, scale=D ** -0.5)
+                                           table, scale=D ** -0.5,
+                                           window=window)
+                if "wgate" in L:
+                    # one sigmoid scalar a head, from the normed input
+                    g = jax.nn.sigmoid(_mm_w(h, L, "wgate"))[0]
+                    o = o * g[..., None].astype(o.dtype)
                 if mega:
                     wp, ws = _wq2(L, "wo")
                     xn, h2 = fused_oproj_norm(
                         o.reshape(T, Hh * D), x[0], wp, ws, None,
                         L["ln2"], None, eps=eps, algo=_walgo(L, "wo"))
                     if "moe" in L:
-                        x = xn[None] + _ffn_apply(L, h2[None], st)
+                        x = xn[None] + _ffn_apply(L, h2[None], st,
+                                                  moe_stats, live)
                     else:
                         gp, gs = _wq2(L, "wg")
                         up, us = _wq2(L, "wu")
@@ -1493,7 +1692,7 @@ class ServingEngine:
                 else:
                     x = x + _mm_w(o.reshape(1, T, Hh * D), L, "wo")
                     h2 = fused_rms_norm(x, L["ln2"], eps)
-                    x = x + _ffn_apply(L, h2, st)
+                    x = x + _ffn_apply(L, h2, st, moe_stats, live)
             x = fused_rms_norm(x, w["norm"], eps)
             # each sequence's logits come from its LAST flat row; idle
             # slots (num_tokens 0) index garbage the host ignores. With
@@ -1509,6 +1708,15 @@ class ServingEngine:
             else:
                 logits = last @ (w["head"] if w["head"] is not None
                                  else w["embed"].T)
+            if moe_stats:
+                # one [5] array beside the logits: pairs routed and held
+                # summed over the routed layers, the fullest expert's
+                # rows, the mean rows a held expert, experts hit summed
+                # (STEP_COUNTS_MOE)
+                ms = jnp.stack(moe_stats)
+                return logits, new_pools, jnp.stack([
+                    ms[:, 0].sum(), ms[:, 1].sum(), ms[:, 2].max(),
+                    ms[:, 3].mean(), ms[:, 4].sum()])
             return logits, new_pools
 
         return step

@@ -178,6 +178,23 @@ class TestBlockSpecConsistency:
         assert visits == S * nj
         assert est.breakdown["kv"] == 2 * visits * psz * D * BF16
         assert q + est.breakdown["kv"] == est.bytes_read
+        # a sliding window bounds the walk: the count the kernel's work
+        # list makes never passes the cost's, and meets it where the
+        # window's span straddles a page more than its length needs
+        win = cm.cost("ragged_paged_attention", T=T, H=4, KV=1, D=D,
+                      S=S, pages_per_seq=nj, page_size=psz, window=20)
+        assert win.bytes_written == est.bytes_written
+        worst = 0
+        for kvl in range(nj * psz - psz, nj * psz + 1):
+            v = pr.ragged_pages_visited(
+                rows, np.full(S, T // S), np.full(S, kvl), T=T, rep=rep,
+                dtype="bfloat16", page_size=psz, pages_per_seq=nj,
+                window=20)
+            worst = max(worst, v)
+            assert 2 * v * psz * D * BF16 <= win.breakdown["kv"]
+        assert 2 * worst * psz * D * BF16 == win.breakdown["kv"] \
+            < est.breakdown["kv"]
+        assert win.flops < est.flops
 
     def test_flash_fwd_bytes_match_block_specs(self, sites):
         idx, ss = sites

@@ -43,9 +43,10 @@ def _setup(T, S, H, KV, D, psz, pps, seed=0, dtype=jnp.float32):
             jnp.asarray(kvl), tab)
 
 
-def _check(q, kp, vp, ss, nt, kvl, tab, atol=2e-5, rtol=2e-5):
-    out = ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab)
-    ref = ragged_attention_reference(q, kp, vp, ss, nt, kvl, tab)
+def _check(q, kp, vp, ss, nt, kvl, tab, atol=2e-5, rtol=2e-5, window=None):
+    out = ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab, window=window)
+    ref = ragged_attention_reference(q, kp, vp, ss, nt, kvl, tab,
+                                     window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=atol, rtol=rtol)
     return out
@@ -100,29 +101,96 @@ _LAYOUTS = {
                                   kv_chunk=50, share=True),
     # every context is pages_per_seq full pages
     "full_tables": dict(kv_dec=[64, 64, 64], chunk=36, kv_chunk=64),
+    # a sliding window (the `window` key goes to the kernel, the rest to
+    # the layout). rep 6 (tiles of 20 tokens in float32): the window is
+    # not whole pages, the chunk straddles it (its first rows still see
+    # keys from before the chunk, its last rows only the chunk)
+    "window_rep6_chunk_straddles": dict(
+        kv_dec=[17, 33, 9, 60, 1, 25], chunk=30, kv_chunk=22 + 30,
+        H=12, window=21),
+    # rep 9 (tiles of 8 tokens): contexts far past the window, so whole
+    # leading pages are never walked; their table entries are dead
+    "window_rep9_dead_pages": dict(
+        kv_dec=[64, 40, 0, 57, 3], chunk=19, kv_chunk=64, H=18,
+        window=13, dead=True),
+    # window of one page exactly, starts on page boundaries
+    "window_one_page": dict(kv_dec=[8, 16, 64, 24], chunk=32,
+                            kv_chunk=16 + 32, window=8),
+    # a window wider than every context is full causal attention
+    "window_wider_than_context": dict(kv_dec=[20, 27, 6], chunk=34,
+                                      kv_chunk=50, window=100),
 }
+
+
+def _layout(name):
+    """(_engine_layout arrays, window) of a named case. With `dead` the
+    table entries of pages wholly below every row's window point at a
+    page of NaNs: the kernel must neither fetch nor read them."""
+    spec = dict(_LAYOUTS[name])
+    window, dead = spec.pop("window", None), spec.pop("dead", False)
+    q, kp, vp, ss, nt, kvl, tab = _engine_layout(**spec)
+    if dead:
+        psz = kp.shape[2]
+        oldest = np.asarray(kvl) - np.asarray(nt) - window + 1
+        tab = np.array(tab)
+        for i, o in enumerate(oldest):
+            tab[i, :max(int(o), 0) // psz] = 0
+        kp, vp = kp.at[:, 0].set(jnp.nan), vp.at[:, 0].set(jnp.nan)
+        tab = jnp.asarray(tab)
+    return (q, kp, vp, ss, nt, kvl, tab), window
 
 
 class TestRaggedKernelParity:
     @pytest.mark.parametrize("name", list(_LAYOUTS))
     def test_engine_layouts(self, name):
-        _check(*_engine_layout(**_LAYOUTS[name]))
+        arrays, window = _layout(name)
+        out = ragged_paged_attention(*arrays, window=window)
+        assert np.isfinite(np.asarray(out)).all()
+        q, kp, vp, ss, nt, kvl, tab = arrays
+        # the oracle gathers every table entry: give it finite pages
+        _check(q, jnp.nan_to_num(kp), jnp.nan_to_num(vp), ss, nt, kvl,
+               tab, window=window)
+        if window is not None:
+            full = ragged_attention_reference(*arrays[:3], ss, nt, kvl, tab)
+            same = np.allclose(np.asarray(out), np.asarray(full), atol=1e-4)
+            assert same == (window >= int(np.max(np.asarray(kvl))))
 
     @pytest.mark.parametrize("name", list(_LAYOUTS))
     def test_visit_count_follows_live_pages(self, name):
         # the exported count (the engine's pages_visited) is the kernel's
         # own work list; a decode-only launch fetches exactly its live
         # pages, any launch at most once for every tile
-        q, kp, _, ss, nt, kvl, tab = _engine_layout(**_LAYOUTS[name])
+        (q, kp, _, ss, nt, kvl, tab), window = _layout(name)
         T, psz, pps = q.shape[0], kp.shape[2], tab.shape[1]
         rep = q.shape[1] // kp.shape[0]
         tq = ragged_tile_tokens(T, rep, q.dtype)
         tiling = dict(page_size=psz, pages_per_seq=pps)
         visited = ragged_pages_visited(ss, nt, kvl, T=T, rep=rep,
-                                       dtype=q.dtype, **tiling)
+                                       dtype=q.dtype, window=window,
+                                       **tiling)
+        if window is not None:
+            tiling["window"] = window
         tile_first, _, pair_first = _work_list(
             ss, nt, kvl, tq=tq, n_tiles=-(-T // tq), **tiling)
         assert int(pair_first[tile_first[-1]]) == visited
+        if window is not None:
+            # the walk is the pages that hold a key some row of the
+            # (tile, sequence) pair sees, counted row by row
+            want = 0
+            for t0 in range(0, T, tq):
+                for i in range(len(ss)):
+                    rows = [r for r in range(t0, min(t0 + tq, T))
+                            if int(ss[i]) <= r < int(ss[i]) + int(nt[i])]
+                    pos = [int(kvl[i]) - int(nt[i]) + r - int(ss[i])
+                           for r in rows]
+                    seen = {k // psz for p_ in pos
+                            for k in range(max(p_ - window + 1, 0), p_ + 1)}
+                    want += len(seen)
+            assert visited == want
+            assert visited <= ragged_pages_visited(
+                ss, nt, kvl, T=T, rep=rep, dtype=q.dtype, page_size=psz,
+                pages_per_seq=pps)
+            return
         live = int(np.sum(-(-np.asarray(kvl)[np.asarray(nt) > 0] // psz)))
         assert live <= visited <= -(-T // tq) * live
         if int(nt[-1]) == 0:

@@ -219,6 +219,34 @@ def test_ragged_paged_attention_compiles_at_the_serving_cells_shapes(chip):
         chip.refusals.get(_ragged)
 
 
+def _ragged_windowed(q, kp, vp, ss, nt, kvl, tab):
+    from paddle_tpu.ops.pallas_ragged import ragged_paged_attention
+    return ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab, window=512)
+
+
+@pytest.mark.parametrize("hq,fn,n_pages", [
+    (48, _ragged, 1280), (72, _ragged_windowed, 160)],
+    ids=["full_rep6", "window512_rep9"])
+def test_ragged_paged_attention_compiles_at_the_laguna_cell_shapes(
+        chip, hq, fn, n_pages):
+    """`laguna-s-2.1-serve-ep8-d8` as its cell runs it: T = 32 slots + a
+    256-row chunk; full layers 48 q heads (6 a KV head: tiles of 16
+    tokens, 96 rows) over the 1,280-page pool, sliding layers 72 (9 a
+    KV head: tiles of 16 tokens, 144 rows) with window 512 over the
+    160-page pool; 8 KV heads x 128, page 256, 33 sequences of 128
+    pages.  The eligibility gate has to say what the compiler says."""
+    from paddle_tpu.ops.pallas_ragged import (ragged_kernel_eligible,
+                                              ragged_tile_tokens)
+    t, kv, psz, s, nj = 288, 8, 256, 33, 128
+    assert ragged_kernel_eligible(hq, kv, D, psz)
+    assert ragged_tile_tokens(t, hq // kv, jnp.bfloat16) == 16
+    seq = chip.shape((s,), I32)
+    pool = chip.shape((kv, n_pages, psz, D))
+    assert chip.compiles(fn, chip.shape((t, hq, D)), pool, pool,
+                         seq, seq, seq, chip.shape((s, nj), I32)), \
+        chip.refusals.get(fn)
+
+
 def _serve_norm_and_linears(x, nw, w8, s8, w4, s4):
     """What the engine's split chain adds around the kernels above:
     the rms norm and, on quantized deploys, the weight-only linears."""
