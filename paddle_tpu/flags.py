@@ -178,8 +178,9 @@ define_flag("FLAGS_request_tracing", True,
             "TTFT/TPOT/e2e SLO histograms. Off = every stamp degrades "
             "to one attribute test (near-zero overhead)")
 define_flag("FLAGS_trace_ring_size", 2048,
-            "finished request/step traces kept in the in-memory ring "
-            "buffer for export (oldest evicted first)",
+            "finished request traces kept in the in-memory ring buffer "
+            "for export (oldest evicted first); the step records' ring "
+            "holds tracing.STEPS_PER_SLOT times as many",
             validator=lambda v: v >= 1)
 define_flag("FLAGS_eager_op_cache_size", 4096,
             "max entries in the per-op jitted computation cache")

@@ -60,7 +60,7 @@ from . import DEFAULT_BUCKETS, Histogram, _new_span_id, registry
 
 __all__ = ["TraceEvent", "RequestTrace", "TraceRecorder", "recorder",
            "enabled", "set_enabled", "percentile", "percentiles",
-           "slo_summary", "SLO_METRICS", "STEP_COUNTS",
+           "slo_summary", "SLO_METRICS", "STEP_COUNTS", "STEPS_PER_SLOT",
            "STEP_COUNTS_BY_KIND", "STEP_COUNTS_MOE"]
 
 _FLAG = _flags._registry["FLAGS_request_tracing"]
@@ -109,8 +109,8 @@ _H_E2E = registry().histogram(
 #: happens (docs/OBSERVABILITY.md says what each one is)
 STEP_COUNTS: Tuple[str, ...] = (
     "decode_rows", "prefill_rows", "live", "waiting", "admitted",
-    "finished", "preempted", "cow_pages", "pages_live", "pages_visited",
-    "pool_pages_used", "pool_pages_total")
+    "finished", "preempted", "cow_pages", "pools_in_place", "pages_live",
+    "pages_visited", "pool_pages_used", "pool_pages_total")
 #: more counts where a model keeps two kinds of cache (full layers and
 #: sliding-window layers; the plain `pages_*` / `pool_pages_*` are then
 #: the sum of both kinds) ...
@@ -124,6 +124,12 @@ STEP_COUNTS_BY_KIND: Tuple[str, ...] = (
 STEP_COUNTS_MOE: Tuple[str, ...] = (
     "moe_pairs_routed", "moe_pairs_held", "moe_expert_rows_max",
     "moe_expert_rows_mean", "moe_experts_hit")
+#: step records kept for each slot of the request ring. A request lives
+#: through tens to hundreds of steps, and whoever reads a whole measured
+#: window from the records (`benchmarks/lib/program_spans.py`: 50-56 s
+#: of steps plus the warm-up) needs every one: 2048 records stopped
+#: holding the chat cell's window once a step took under 27 ms (PR 29)
+STEPS_PER_SLOT = 4
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
@@ -251,10 +257,11 @@ class TraceRecorder:
     All mutation goes through `begin` / `stamp` / `finish`, each gated on
     FLAGS_request_tracing first. Finished traces move to a bounded ring
     (FLAGS_trace_ring_size, oldest evicted) so a long-lived serving
-    process cannot grow without bound; host spans and step records
-    each have a ring of the same capacity. An optional background
-    exporter thread drains finished traces to JSONL; it shares the same
-    lock as every other accessor (paddlelint PT006 discipline).
+    process cannot grow without bound; host spans have a ring of the
+    same capacity and step records one of `STEPS_PER_SLOT` times it.
+    An optional background exporter thread drains finished traces to
+    JSONL; it shares the same lock as every other accessor (paddlelint
+    PT006 discipline).
     """
 
     def __init__(self, capacity: Optional[int] = None):
@@ -266,7 +273,7 @@ class TraceRecorder:
         self._capacity = int(capacity)
         self._counters: Dict[str, deque] = {}
         self._spans: deque = deque(maxlen=int(capacity))
-        self._steps: deque = deque(maxlen=int(capacity))
+        self._steps: deque = deque(maxlen=STEPS_PER_SLOT * int(capacity))
         self._open_step: Optional[Dict[str, Any]] = None
         self._compiles = 0
         self._listening = False

@@ -416,7 +416,12 @@ def fused_rope_append(q, k, v, cos, sin, k_pages, v_pages,
     pools, never re-read the donated arguments; paddlelint's PF402
     checks the caller side statically, and PE502 proves the kernel
     itself only reads each donated input before its first aliased
-    write, so no defensive copy is ever needed here).
+    write, so no defensive copy is ever needed here). The contract
+    reaches the serving engine's own handle: `ServingEngine` builds its
+    jitted programs with the pools donated, so XLA hands this kernel
+    the live buffers and not a copy of them — the engine's pools are
+    dead after the launch, and `ServingEngine._launch` rebinds them to
+    the returned ones in the same statement.
 
     Contract: tokens that share a page are ADJACENT in t (the engine's
     prefill chunk); non-adjacent revisits only happen on the trash page

@@ -434,6 +434,9 @@ class ServingEngine:
         if any(st and "held" in st for st in p.get("moe_static") or ()):
             self._count_names += _tracing.STEP_COUNTS_MOE
         self._counts = dict.fromkeys(self._count_names, 0)
+        # the pool handles this step's launches were handed (dead
+        # arrays, no buffers): `pools_in_place` asks them at account
+        self._launched: List[object] = []
 
         # live HBM accounting (ISSUE 11): static residency is published
         # once; a cumulative analytical ledger turns each launch into
@@ -569,15 +572,58 @@ class ServingEngine:
         """(Re)build the fixed-shape jitted programs for the CURRENT
         max_slots/prefill_chunk/spec_k. Called once from __init__ and
         again from `reconfigure()` — fresh `jax.jit` objects each time,
-        so `program_cache_sizes()` stays at 1 per program (PT002)."""
+        so `program_cache_sizes()` stays at 1 per program (PT002).
+
+        Every program takes the page pools as argument 2, returns the
+        pools that replace them, and OWNS the ones it is handed
+        (``donate_argnums``): XLA pairs each pool parameter with the
+        output of its shape, in order, and the kernels' in-place row
+        writes (`ops.fused.fused_rope_append`) land in the live buffer
+        instead of a copy of it. So the caller's pools are dead after
+        the launch — `_launch` is the one caller."""
         if self.ragged:
-            self._jit_unified = jax.jit(self._make_unified_body())
+            self._jit_unified = jax.jit(self._make_unified_body(),
+                                        donate_argnums=2)
             self._programs = {"unified": self._jit_unified}
         else:
-            self._jit_decode = jax.jit(self._make_decode_body())
-            self._jit_prefill = jax.jit(self._make_prefill_body())
+            self._jit_decode = jax.jit(self._make_decode_body(),
+                                       donate_argnums=2)
+            self._jit_prefill = jax.jit(self._make_prefill_body(),
+                                        donate_argnums=2)
             self._programs = {"decode": self._jit_decode,
                               "prefill": self._jit_prefill}
+
+    def _live_pools(self):
+        """The page pools, for whoever reads or replaces them between
+        launches (handoff, copy-on-write). A launch that failed AFTER
+        it took the pools leaves nothing to serve from: the engine
+        says so here rather than hand out deleted buffers."""
+        if self._pools is None:
+            raise RuntimeError(
+                "the KV page pools were lost to a launch that raised "
+                "after it had taken them (they are donated to the "
+                "jitted step); this engine cannot run again — build a "
+                "new ServingEngine and resubmit its requests")
+        return self._pools
+
+    def _launch(self, program, tok, *tables):
+        """Call ``program(w, tok, pools, *tables)`` and rebind the pools
+        to the ones it returns, in one statement: the pools handed in
+        are dead once the call is dispatched. Returns what the program
+        returned without the pools: (logits, *more)."""
+        pools = self._live_pools()
+        try:
+            logits, self._pools, *more = program(self._w, tok, pools,
+                                                 *tables)
+        except BaseException:
+            # before the dispatch (tracing, compiling) the pools are
+            # untouched and the engine goes on; after it they are gone
+            if any(a.is_deleted() for a in jax.tree_util.tree_leaves(pools)):
+                self._pools = None
+            raise
+        self._launched.append(pools)   # handles only: `pools_in_place`
+        self.launches += 1
+        return (logits, *more)
 
     def reconfigure(self, prefill_chunk: Optional[int] = None,
                     spec_decode: Optional[int] = None) -> bool:
@@ -669,6 +715,7 @@ class ServingEngine:
                "finished": 0}
         self.steps += 1
         self._counts = dict.fromkeys(self._count_names, 0)
+        self._launched = []
         _TRACE.set_replica_context(self.replica)
         _TRACE.open_step(self.steps, "serving.engine.step")
         try:
@@ -747,6 +794,11 @@ class ServingEngine:
                     "pool_pages_used.window": win[1]})
             self._counts["pool_pages_total"] = full[0] + win[0]
             self._counts["pool_pages_used"] = full[1] + win[1]
+            # did every launch of the step write its pools in place?
+            # (a backend that declines the donation leaves them alive)
+            handed = jax.tree_util.tree_leaves(self._launched)
+            self._counts["pools_in_place"] = int(
+                bool(handed) and all(a.is_deleted() for a in handed))
 
     # ------------------------------------------------- HBM accounting
     def _account_step(self, out: Dict[str, int]) -> None:
@@ -953,10 +1005,11 @@ class ServingEngine:
         exp = self.allocator.export_seq(rid)
         pages = np.asarray(exp["pages"], np.int32)
         if self._family == "mla":
-            blocks = [np.asarray(pool[:, pages]) for pool in self._pools]
+            blocks = [np.asarray(pool[:, pages])
+                      for pool in self._live_pools()]
         else:
             blocks = [(np.asarray(kp[:, pages]), np.asarray(vp[:, pages]))
-                      for kp, vp in self._pools]
+                      for kp, vp in self._live_pools()]
         # remaining deadline travels with the request (the importer's
         # submit() restarts the clock)
         dl = req.deadline_s
@@ -1031,13 +1084,13 @@ class ServingEngine:
         dst = np.asarray(pages, np.int32)
         if self._family == "mla":
             self._pools = [pool.at[:, dst].set(jnp.asarray(blk))
-                           for pool, blk in zip(self._pools,
+                           for pool, blk in zip(self._live_pools(),
                                                 handoff.blocks)]
         else:
             self._pools = [(kp.at[:, dst].set(jnp.asarray(kb)),
                             vp.at[:, dst].set(jnp.asarray(vb)))
                            for (kp, vp), (kb, vb)
-                           in zip(self._pools, handoff.blocks)]
+                           in zip(self._live_pools(), handoff.blocks)]
         req.tokens = list(handoff.tokens)
         req.pending = handoff.pending
         req.prefill_pos = int(req.prompt.size)
@@ -1213,13 +1266,12 @@ class ServingEngine:
             self._counts["pages_live"] += -(-(start + n) // self.page_size)
             self._counts["pages_visited"] += self.pages_per_seq
         with _obs.span("serving.engine.launch"):
-            logits, self._pools = self._jit_prefill(
-                self._w, jnp.asarray(ids), self._pools, jnp.asarray(table),
+            logits, = self._launch(
+                self._jit_prefill, jnp.asarray(ids), jnp.asarray(table),
                 np.int32(start), np.int32(n))
             _TRACE.stamp(req.request_id, "prefill_chunk", tokens=n,
                          start=start)
             req.prefill_pos += n
-            self.launches += 1
             if _obs.enabled():
                 _LAUNCHES.labels(path="split").inc()
                 _STEPS.labels(phase="prefill").inc()
@@ -1262,10 +1314,9 @@ class ServingEngine:
                 -(-(lengths[lengths > 0] + 1) // self.page_size)))
             self._counts["pages_visited"] += B * self.pages_per_seq
         with _obs.span("serving.engine.launch"):
-            logits, self._pools = self._jit_decode(
-                self._w, jnp.asarray(tok), self._pools,
+            logits, = self._launch(
+                self._jit_decode, jnp.asarray(tok),
                 jnp.asarray(lengths), jnp.asarray(tables))
-            self.launches += 1
             if _obs.enabled():
                 _LAUNCHES.labels(path="split").inc()
                 _STEPS.labels(phase="decode").inc()
@@ -1312,14 +1363,13 @@ class ServingEngine:
         with _obs.span("serving.engine.build"):
             host, drafts, n, start = self._build_unified(preq, active)
         with _obs.span("serving.engine.launch"):
-            logits, self._pools, *moe = self._jit_unified(
-                self._w, jnp.asarray(host[0]), self._pools,
+            logits, *moe = self._launch(
+                self._jit_unified, jnp.asarray(host[0]),
                 *(jax.tree_util.tree_map(jnp.asarray, t)
                   for t in host[1:]))
             if preq is not None:
                 _TRACE.stamp(preq.request_id, "prefill_chunk", tokens=n,
                              start=start)
-            self.launches += 1
             if _obs.enabled():
                 _LAUNCHES.labels(
                     path="unified_megafront" if self.megafront
@@ -1555,11 +1605,11 @@ class ServingEngine:
         dst = np.asarray([c[1] for c in copies])
         if self._family == "mla":
             self._pools = [pool.at[:, dst].set(pool[:, src])
-                           for pool in self._pools]
+                           for pool in self._live_pools()]
         else:
             self._pools = [(kp.at[:, dst].set(kp[:, src]),
                             vp.at[:, dst].set(vp[:, src]))
-                           for kp, vp in self._pools]
+                           for kp, vp in self._live_pools()]
 
     # ----------------------------------------------------- jitted bodies
     def _make_decode_body(self):

@@ -1,0 +1,216 @@
+"""The serving step owns its KV page pools (ISSUE 29): the jitted
+programs are built with the pools donated, so the arrays a launch was
+handed are dead once it is dispatched and `ServingEngine._pools` are the
+ones it returned.  Pinned here, on the CPU (jax honours donation there):
+the ownership itself and the step record's `pools_in_place`; that every
+reader of the pools between launches (copy-on-write, handoff export and
+import, preemption, a rolled-back draft, `reconfigure()`) goes through
+the live handle, by token sequences bit-identical to the same engine
+with programs that do NOT own their pools (the behaviour before); and
+what the engine does after a launch that raised."""
+
+import functools
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.observability import tracing
+from paddle_tpu.serving import ServingEngine
+from paddle_tpu.serving.scheduler import DECODE
+
+FAMILIES = ("llama", "gpt", "mla")
+PATHS = ("unified", "split")
+CASES = [(f, p) for f in FAMILIES for p in PATHS]
+IDS = [f"{f}-{p}" for f, p in CASES]
+
+
+def _build(family):
+    paddle.seed(0)
+    if family == "gpt":
+        from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny_config
+        m = GPTForCausalLM(gpt_tiny_config(max_position_embeddings=64))
+    elif family == "mla":
+        from paddle_tpu.models.deepseek import (DeepSeekV2ForCausalLM,
+                                                deepseek_v2_tiny_config)
+        m = DeepSeekV2ForCausalLM(deepseek_v2_tiny_config(
+            moe_dropless=True, num_hidden_layers=2))
+    else:
+        from paddle_tpu.models.llama import (LlamaForCausalLM,
+                                             llama_tiny_config)
+        m = LlamaForCausalLM(llama_tiny_config(num_hidden_layers=2))
+    m.eval()
+    return m
+
+
+@pytest.fixture(scope="module")
+def models():
+    return functools.cache(_build)
+
+
+def _engine(model, path, **kw):
+    args = dict(max_slots=2, page_size=4, prefill_chunk=4,
+                ragged=(path == "unified"))
+    args.update(kw)
+    return ServingEngine(model, **args)
+
+
+def _without_ownership(eng):
+    """The same engine with programs that do not own their pools — the
+    programs `_build_programs` built before ISSUE 29 — now and after
+    every `reconfigure()`."""
+    def build():
+        if eng.ragged:
+            eng._jit_unified = jax.jit(eng._make_unified_body())
+            eng._programs = {"unified": eng._jit_unified}
+        else:
+            eng._jit_decode = jax.jit(eng._make_decode_body())
+            eng._jit_prefill = jax.jit(eng._make_prefill_body())
+            eng._programs = {"decode": eng._jit_decode,
+                             "prefill": eng._jit_prefill}
+    eng._build_programs = build
+    build()
+    return eng
+
+
+def _records(eng):
+    return tracing.recorder().steps()[-eng.steps:]
+
+
+@pytest.mark.parametrize("family,path", CASES, ids=IDS)
+def test_a_step_consumes_the_pools_it_was_handed(models, family, path):
+    m = models(family)
+    eng = _engine(m, path)
+    eng.add_request(np.arange(1, 10, dtype=np.int32), max_new_tokens=4)
+    while eng.has_work():
+        handed = jax.tree.leaves(eng._pools)
+        eng.step()
+        assert all(a.is_deleted() for a in handed)
+        now = jax.tree.leaves(eng._pools)
+        assert len(now) == len(handed)
+        assert not any(a.is_deleted() for a in now)
+        assert {a.shape for a in now} == {a.shape for a in handed}
+    recs = _records(eng)
+    assert recs and all(r["pools_in_place"] == 1 for r in recs)
+    # the pools are readable through the live handle, and hold the run
+    assert float(abs(np.asarray(now[0], np.float32)).sum()) > 0
+    # an idle step launches nothing, so nothing was updated in place
+    eng.step()
+    assert _records(eng)[-1]["pools_in_place"] == 0
+
+
+def _scenario(eng, vocab):
+    """A seeded run through every reader and writer of the pools between
+    launches; returns ({request: tokens}, what happened on the way)."""
+    rng = np.random.RandomState(29)
+    out = {}
+
+    def steps(n=1):
+        for _ in range(n):
+            eng.step()
+            out.update(eng.collect())
+
+    # a periodic prompt, so the n-gram drafter proposes and the model,
+    # which knows nothing of the period, rejects: drafts are rolled back
+    base = np.tile(np.asarray([7, 11, 3], np.int32), 4)[:10]
+    a = eng.add_request(base, max_new_tokens=12, request_id="a")
+    while a.state != DECODE or len(a.tokens) < 2:   # 3 pages and on
+        steps()
+    # forks "a"'s first 6 tokens: its second page is shared in part, so
+    # "b"'s first own row copies it on write (`_apply_copies`)
+    eng.add_request(
+        np.concatenate([base[:6], rng.randint(0, vocab, 4)]).astype(
+            np.int32), max_new_tokens=10, request_id="b")
+    steps(2)
+    # between two steps "a" leaves through a handoff and comes back
+    eng.import_request(eng.export_request(a))
+    steps()
+    eng.reconfigure(prefill_chunk=8, spec_decode=1 if eng.ragged else 0)
+    steps()
+    # both slots are taken: the high-priority arrival preempts one
+    eng.add_request(rng.randint(0, vocab, 5).astype(np.int32),
+                    max_new_tokens=4, request_id="c", priority=2)
+    while eng.has_work():
+        steps()
+    recs = _records(eng)
+    happened = {
+        "cow_pages": sum(r["cow_pages"] for r in recs),
+        "preempted": sum(r["preempted"] for r in recs),
+        "handoffs": dict(eng._handoff_counts), "rebuilds": eng.rebuilds,
+        "drafts_rolled_back": eng.spec_drafted - eng.spec_accepted,
+        "in_place": {r["pools_in_place"] for r in recs
+                     if r["decode_rows"] + r["prefill_rows"]}}
+    return {k: np.asarray(v) for k, v in out.items()}, happened
+
+
+@pytest.mark.parametrize("family,path", CASES, ids=IDS)
+def test_tokens_are_those_of_programs_that_do_not_own_the_pools(
+        models, family, path):
+    m = models(family)
+    V = m.config.vocab_size
+    kw = dict(spec_decode=2) if path == "unified" else {}
+    got, did = _scenario(_engine(m, path, **kw), V)
+    want, before = _scenario(_without_ownership(_engine(m, path, **kw)), V)
+    assert set(got) == {"a", "b", "c"} == set(want)
+    for rid in want:
+        np.testing.assert_array_equal(got[rid], want[rid])
+    # the run did what it says, on both sides alike
+    assert did["cow_pages"] >= 1 and did["preempted"] >= 1
+    assert did["handoffs"] == {"export": 1, "import": 1}
+    assert did["rebuilds"] == 1
+    if path == "unified":
+        assert did["drafts_rolled_back"] >= 1
+    assert did["in_place"] == {1} and before["in_place"] == {0}
+    did.pop("in_place"), before.pop("in_place")
+    assert did == before
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_a_launch_that_took_the_pools_and_raised_ends_the_engine(
+        models, path):
+    """Chosen behaviour (CHANGES.md, PR 29): the pools are not rebuilt —
+    their contents, every live request's cache, are gone with them — so
+    the engine says that it cannot run again, at the next step and at
+    every other reader of the pools."""
+    eng = _engine(models("llama"), path)
+    req = eng.add_request(np.arange(1, 8, dtype=np.int32), max_new_tokens=6)
+    while req.state != DECODE:
+        eng.step()
+    name = "_jit_unified" if eng.ragged else "_jit_decode"
+    program = getattr(eng, name)
+
+    def took_them_then_raised(w, tok, pools, *tables):
+        for a in jax.tree.leaves(pools):
+            a.delete()
+        raise RuntimeError("device fault")
+
+    setattr(eng, name, took_them_then_raised)
+    with pytest.raises(RuntimeError, match="device fault"):
+        eng.step()
+    setattr(eng, name, program)
+    for use in (eng.step, lambda: eng.export_request(req)):
+        with pytest.raises(RuntimeError, match="pools were lost"):
+            use()
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_a_launch_that_raised_before_it_took_the_pools_keeps_them(
+        models, path):
+    eng = _engine(models("llama"), path)
+    eng.add_request(np.arange(1, 8, dtype=np.int32), max_new_tokens=3)
+    name = "_jit_unified" if eng.ragged else "_jit_prefill"
+    program = getattr(eng, name)
+
+    def refused(*args):
+        raise ValueError("refused before dispatch")
+
+    setattr(eng, name, refused)
+    handed = jax.tree.leaves(eng._pools)
+    with pytest.raises(ValueError, match="refused before dispatch"):
+        eng.step()
+    setattr(eng, name, program)
+    kept = jax.tree.leaves(eng._live_pools())
+    assert len(kept) == len(handed)
+    assert all(a is b for a, b in zip(kept, handed))
+    assert not any(a.is_deleted() for a in handed)

@@ -615,19 +615,21 @@ class TestSpanPrimitive:
         assert by_name["other.thread"] == (None, None)
 
     def test_step_ring_is_bounded_and_separate(self):
-        rec = tr.TraceRecorder(capacity=3)
-        for seq in range(1, 6):
+        rec = tr.TraceRecorder(capacity=1)
+        kept = tr.STEPS_PER_SLOT        # step records a request-ring slot
+        for seq in range(1, kept + 3):
             rec.open_step(seq, "s")
             rec.begin(seq)
             rec.stamp(seq, "token")
             rec.close_step({"decode_rows": seq})
-        assert [s["seq"] for s in rec.steps()] == [3, 4, 5]
-        assert rec.steps()[-1]["decode_rows"] == 5
+        last = kept + 2
+        assert [s["seq"] for s in rec.steps()] == list(range(3, last + 1))
+        assert rec.steps()[-1]["decode_rows"] == last
         # stamps inside an open step carry it; outside they do not
-        assert rec.trace(5).first("token").meta == {"step": 5}
-        rec.stamp(5, "late")
-        assert rec.trace(5).first("late").meta is None
-        assert len(rec.live()) == 5     # the request table is its own
+        assert rec.trace(last).first("token").meta == {"step": last}
+        rec.stamp(last, "late")
+        assert rec.trace(last).first("late").meta is None
+        assert len(rec.live()) == last  # the request table is its own
 
     def test_chrome_export_has_the_step_row(self, tmp_path):
         rec = tr.TraceRecorder(capacity=4)
