@@ -278,10 +278,9 @@ def phase3_serve(clog: CompileLog, seed: int) -> None:
     done, t0 = clog.window(), time.time()
     eng = ServingEngine(model, max_slots=8, page_size=16, max_context=ctx)
     say(f"phase 3 engine paths: ragged={eng.ragged} "
-        f"megafront={eng.megafront} megadecode={eng.megadecode} "
         f"front_half_launches={eng.front_half_launches} "
         f"back_half_launches={eng.back_half_launches}")
-    assert eng.ragged and eng.megafront
+    assert eng.ragged
     got, steps = _run_engine(eng, prompts, new, stagger=3)
     s = done()
     say(f"phase 3 engine: 8 requests (prompts {lens}, {new} new tokens, "

@@ -622,11 +622,12 @@ def decode_layer_kernels(family: str = "llama", *, batch: int,
     """Per-kernel decomposition of one decode layer body:
     {kernel: (launches_per_layer, CostEstimate at this shape)}.
 
-    ``megadecode=True`` (the engine default since ISSUE 14) models the
+    ``megadecode=True`` (NOT what ServingEngine runs: its one chain is
+    ``megadecode=False, megafront=False``) models the
     mega-kernel back half: after attention only ``fused_oproj_norm``
     and ``fused_ffn`` launch (2 pallas_calls; their weight slabs are
     carved out of ``weight_bytes_per_layer``).  ``megafront=True``
-    (the engine default since ISSUE 20) models the mega-kernel front
+    models the mega-kernel front
     half: the qkv matmuls, rope and paged K/V scatter collapse into
     one ``fused_qkv_rope_append`` launch, so with both flags on NO
     projection pseudo-kernel remains and the body is 5 launches

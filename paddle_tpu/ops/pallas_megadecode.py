@@ -398,7 +398,8 @@ def fused_ffn(h, x, wg, sg=None, wu=None, su=None, wd=None, sd=None,
 
 
 # ---------------------------------------------------------------------------
-# eligibility: the engine's per-family gate for the fused default path
+# eligibility: whether the kernels tile and their weight slabs fit VMEM
+# (no caller: ServingEngine runs o-proj, norm and _ffn_apply)
 # ---------------------------------------------------------------------------
 
 def megadecode_eligible(hidden: int, intermediate: int, o_width: int, *,

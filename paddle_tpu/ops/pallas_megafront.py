@@ -28,8 +28,9 @@ the only remaining front seam is norm->fused itself, re-registered as a
 PF404 'retile' candidate for the <=4-launch follow-on.
 
 The qkv weight slabs ride as ONE concatenated [H, (Hq+2KV)*D] operand
-(the engine concatenates per-out-channel payloads AND scales once at
-deploy time — column-wise identical math, zero extra HBM) with an
+(a caller concatenates per-out-channel payloads AND scales once at
+deploy time — column-wise identical math, zero extra HBM; ServingEngine
+does not call this kernel since ISSUE 30, ROADMAP D11 deletes it) with an
 index_map referencing no grid dim: fetched once, VMEM-resident across
 the token sweep.  fp weights ride a ones scale (f32 * 1.0 is the
 identity) so the fp path stays bitwise-equal to the plain dots, and the
@@ -375,9 +376,9 @@ def fused_qkv_rope_append(h, w, scale, bias, cos, sin, k_pages, v_pages,
     qkv projection slab in any deploy layout: fp [H, N] (``algo`` None,
     scale ignored), int8 [H, N] + per-out-channel f32 scale [N], or
     packed int4 [H/2, N] + scale [N] — column order [q | k | v] (the
-    GPT fused-qkv weight is already this layout; the engine
-    concatenates the llama/moe per-projection slabs and scales at
-    deploy time, which is column-wise identical math).  ``bias`` [N]
+    GPT fused-qkv weight is already this layout; for llama/moe the
+    caller concatenates the per-projection slabs and scales, which is
+    column-wise identical math).  ``bias`` [N]
     or None rides a zeros row so the launch arity stays fixed.
 
     Standard layout (``lora_rank`` 0): N = (heads + 2*kv_heads) *
@@ -432,7 +433,8 @@ def fused_qkv_rope_append(h, w, scale, bias, cos, sin, k_pages, v_pages,
 
 
 # ---------------------------------------------------------------------------
-# eligibility: the engine's per-family gate for the fused default path
+# eligibility: whether the kernel tiles and its weight slab fits VMEM
+# (no caller: ServingEngine runs the projections + fused_rope_append)
 # ---------------------------------------------------------------------------
 
 def megafront_eligible(hidden: int, out_cols: int, head_dim: int, *,

@@ -8,34 +8,24 @@ leave the instant they hit EOS/max-tokens (their pages return to the
 pool immediately), and never retrace — one compile per (model-config,
 slot-count) pair, checked by the PT002-gated tests.
 
-Two dispatch paths:
+The engine chooses its programs itself, once, at construction
+(`_ragged_step_eligible`; no constructor argument selects a path):
 
-- **ragged (default)**: ONE unified launch per step. Every decode
-  slot's token and the oldest prefill request's chunk ride a single
-  flat token buffer through a fused per-layer body
-  (fused_rms_norm → fused_qkv_rope_append → ragged_paged_attention →
-  fused_oproj_norm → fused_ffn, ≤5 launches), so a step that has both
-  prefill and decode work issues ONE device program instead of two
-  (`serving.engine.launches` counts the difference). Per-sequence row
-  tables (seq_start / num_tokens / kv_lengths / page table) make joins
-  and leaves pure data changes. The front half rides the ISSUE-20
-  mega-kernel — qkv projection (with in-kernel int4/int8 dequant),
-  rope and the paged K/V append in one pallas_call — when
-  `megafront_eligible` holds for the family geometry (`megafront=False`
-  or an ineligible tiling falls back to the split
-  qkv→fused_rope_append front, 5 launches instead of 2; MLA with
-  q-lora or int4 always splits). The back half rides the ISSUE-14
-  mega-kernels — o-proj + residual + norm in one pallas_call, the
-  whole FFN in a second — when `megadecode_eligible` holds
-  (`megadecode=False` or an ineligible tiling falls back to the split
-  o-proj/norm/ffn chain; routed MoE layers always keep the
-  `_ffn_apply` combine — data-dependent routing can't fuse — but
-  still take the fused o-proj+norm kernel).
-- **split (legacy, `ragged=False`)**: the PR-5 alternating
-  `_prefill_chunk` / `_decode` dispatches over
-  `paged_attention`/`append_to_cache`. Kept as the reference path and
-  the fallback when the ragged kernel's tiling constraints don't hold
-  on TPU (`ragged_kernel_eligible`).
+- **unified**: ONE launch per step. Every decode slot's token and the
+  oldest prefill request's chunk ride a single flat token buffer
+  through ONE per-layer chain, the same at every width and on every
+  backend: norm -> q / k / v projections -> `fused_rope_append` (MLA:
+  `fused_append_rows`) -> `ragged_paged_attention` -> o-proj -> norm ->
+  `_ffn_apply`. A step that has both prefill and decode work issues
+  ONE device program instead of two (`serving.engine.launches` counts
+  the difference). Per-sequence row tables (seq_start / num_tokens /
+  kv_lengths / page table) make joins and leaves pure data changes.
+- **split**: the PR-5 alternating `_prefill_chunk` / `_decode`
+  dispatches over `paged_attention`/`append_to_cache`, built only
+  where the ragged kernel's tiling constraints do not hold on a TPU
+  (`ragged_kernel_eligible`: today every MLA model's 576-wide cache
+  row). It leaves when latent attention goes through the ragged
+  kernel (ROADMAP R1).
 
 Inactive slots point their whole page table at the allocator's trash
 page 0 with length/num_tokens 0: both paths write their (garbage) K/V
@@ -48,7 +38,6 @@ greedy property; sampling strategies belong to the batch APIs.
 
 from __future__ import annotations
 
-import functools
 import math
 from typing import Dict, List, Optional, Tuple
 
@@ -65,10 +54,6 @@ from ..generation import (_decode_params, _dq, _ffn_apply, _llama_weights,
 from ..ops.fused import (fused_append_rows, fused_layer_norm,
                          fused_rms_norm, fused_rope_append)
 from ..ops.paged_attention import append_to_cache, paged_attention
-from ..ops.pallas_megadecode import (fused_ffn, fused_oproj_norm,
-                                     megadecode_eligible)
-from ..ops.pallas_megafront import (fused_qkv_rope_append,
-                                    megafront_eligible)
 from ..ops.pallas_ragged import (ragged_kernel_eligible,
                                  ragged_paged_attention,
                                  ragged_pages_visited)
@@ -138,34 +123,51 @@ _COUNTER_GAUGES = (
 )
 
 
-def _walgo(L, key):
-    """Static quant algo of a deploy-layout weight leaf. Kept separate
-    from _wq2 (string literal out, never a tracer) so the fused-kernel
-    dispatchers branch on a host value."""
-    if key + "_q4" in L:
-        return "weight_only_int4"
-    if key + "_q" in L:
-        return "weight_only_int8"
-    return None
-
-
-def _wq2(L, key):
-    """(payload, scale) of a deploy-layout weight leaf — the three
-    layouts fused_oproj_norm / fused_ffn read natively (fp, int8 + f32
-    scale, packed int4 + f32 scale)."""
-    if key + "_q4" in L:
-        return L[key + "_q4"], L[key + "_s"]
-    if key + "_q" in L:
-        return L[key + "_q"], L[key + "_s"]
-    return L[key], None
-
-
 def _lcp(a: np.ndarray, b: np.ndarray) -> int:
     n = min(a.size, b.size)
     if n == 0:
         return 0
     neq = np.nonzero(a[:n] != b[:n])[0]
     return int(neq[0]) if neq.size else n
+
+
+def _ragged_step_eligible(heads, kv: int, d: int, page_size: int) -> bool:
+    """The ONE question `ServingEngine` asks, once, when it chooses its
+    programs: does the ragged kernel tile every layer's head count
+    here? On a TPU that is `ragged_kernel_eligible`; anywhere else the
+    kernel runs interpreted and has no tiling constraint. A test that
+    needs the other answer patches THIS function."""
+    return (jax.default_backend() != "tpu"
+            or all(ragged_kernel_eligible(h, kv, d, page_size)
+                   for h in heads))
+
+
+# -- the step's entry and exit, shared by the jitted bodies ------------
+def _seq_starts(B: int, R: int):
+    """[B + 1] baked row starts of the unified step: decode slot s owns
+    rows [s*R, (s+1)*R), the prefill chunk the rows from B*R on. R == 1
+    reduces to arange(B + 1)."""
+    return jnp.concatenate(
+        [jnp.arange(B, dtype=jnp.int32) * R,
+         jnp.asarray([B * R], jnp.int32)])
+
+
+def _logit_rows(x, seq_start, num_tokens, K: int):
+    """The rows of the unified step's x [1, T, H] whose logits go back
+    to the host: each sequence's LAST flat row (idle slots, num_tokens
+    0, index garbage the host ignores); with spec decoding every row —
+    each drafted position is a verify point."""
+    if K:
+        return x[0]
+    return x[0, jnp.clip(seq_start + num_tokens - 1, 0, x.shape[1] - 1)]
+
+
+def _head_logits(w, last):
+    """Logits of the rows `last`: the quantized head through `_mm_w`,
+    else the model's head or its tied embedding."""
+    if "head_q" in w or "head_q4" in w:
+        return _mm_w(last, w, "head")
+    return last @ (w["head"] if w["head"] is not None else w["embed"].T)
 
 
 class ServingEngine:
@@ -207,13 +209,10 @@ class ServingEngine:
                  weight_only_quant=None,
                  config=None,
                  prefix_sharing: bool = True,
-                 ragged: Optional[bool] = None,
                  enable_prefix_cache: Optional[bool] = None,
                  spec_decode: int = 0,
                  preemption: bool = True,
                  tenant_budgets: Optional[dict] = None,
-                 megadecode: Optional[bool] = None,
-                 megafront: Optional[bool] = None,
                  role: str = "colocated",
                  replica: Optional[str] = None,
                  prefix_cache_admit: bool = True,
@@ -276,10 +275,6 @@ class ServingEngine:
             why = (f"this model has sliding-window layers (window "
                    f"{self._window}): their pages are released as the "
                    f"window passes them, so ")
-            if ragged is False:
-                raise ValueError(why + "only the unified ragged step "
-                                 "serves it (the split path has one page "
-                                 "table and no window)")
             if enable_prefix_cache:
                 raise ValueError(why + "a cached prefix cannot be "
                                  "adopted; enable_prefix_cache must be off")
@@ -359,22 +354,16 @@ class ServingEngine:
                            for sh in (wshape if k else shape
                                       for k in self._layer_kind)]
 
-        # dispatch path: the unified ragged launch by default, unless
-        # the ragged kernel's tiling constraints don't hold on a real
-        # TPU (interpret mode has none) or the caller pins the path
-        eligible = (jax.default_backend() != "tpu"
-                    or all(ragged_kernel_eligible(h, kv, d, self.page_size)
-                           for h in heads))
-        if ragged is None:
-            ragged = eligible
-        if "attn_static" in p and not (ragged and eligible):
+        # the programs: the unified step wherever the ragged kernel
+        # tiles every layer's head count, else the split pair
+        self.ragged = _ragged_step_eligible(heads, kv, d, self.page_size)
+        if "attn_static" in p and not self.ragged:
             raise ValueError(
                 f"family {self._family!r} (per-layer heads {heads}, window "
                 f"{self._window}) is served by the unified ragged step "
-                f"only" + (f", and the ragged kernel is not eligible here: "
-                           f"{kv} KV heads x {d}, page {self.page_size}"
-                           if ragged else " (ragged=False asked for)"))
-        self.ragged = bool(ragged)
+                f"only (the split path has one page table and no window), "
+                f"and the ragged kernel is not eligible here: "
+                f"{kv} KV heads x {d}, page {self.page_size}")
         if spec_decode < 0:
             raise ValueError("spec_decode must be >= 0")
         # speculative decoding: each decode slot owns 1 + spec_k flat
@@ -382,44 +371,6 @@ class ServingEngine:
         # ragged launch). The split path has no multi-row slots, so
         # spec decoding rides the ragged path only.
         self.spec_k = int(spec_decode) if self.ragged else 0
-        # mega-kernel back half (ISSUE 14): o-proj -> residual -> norm
-        # and the whole FFN collapse to TWO pallas_calls per layer when
-        # the family geometry tiles; default on, per-family fallback to
-        # the split chain via the megadecode_eligible gate (routed MoE
-        # layers keep the _ffn_apply combine either way — routing is
-        # data-dependent — but still take the fused o-proj+norm kernel)
-        ow = (cfg.num_attention_heads * cfg.v_head_dim
-              if self._family == "mla"
-              else heads[-1] * cfg.head_dim)     # the widest layer's
-        int4 = any(k.endswith("_q4") for L in p["layers"] for k in L)
-        int8 = any(k.endswith("_q") for L in p["layers"] for k in L)
-        # stored bytes of one fp/int8 weight element, for the VMEM gates
-        wbytes = 1 if int8 else int(jnp.dtype(dt).itemsize)
-        self._megadecode_gate = functools.partial(
-            megadecode_eligible, cfg.hidden_size, cfg.intermediate_size,
-            ow, int4=int4, dtype_bytes=wbytes)
-        self._megadecode_pin = True if megadecode is None else megadecode
-        self._gate_megadecode()
-        # mega-kernel front half (ISSUE 20): the qkv projection matmuls,
-        # rope and the paged K/V append collapse to ONE pallas_call
-        # after the norm, so the decode layer body is <=5 launches with
-        # both mega flags on.  Default on, per-family fallback via the
-        # megafront_eligible tiling gate; MLA's two-stage q-lora
-        # projection and the (unpacked) MLA int4 layout keep the split
-        # front.  The gate rewrites the weight tree (per-projection
-        # slabs -> one concatenated slab per layer), so it must run
-        # BEFORE tree_bytes below.
-        self.megafront = bool(
-            (True if megafront is None else megafront)
-            and self.ragged
-            and self._megafront_family_ok(cfg, int4, wbytes))
-        if self.megafront:
-            self._concat_qkv_weights()
-        #: pallas/XLA launches before attention, per layer per decode
-        #: step — the bench A/B row reads this (2 fused vs the split
-        #: norm / projection dots / rope-append front)
-        self.front_half_launches = 2 if self.megafront \
-            else self._split_front_launches()
         self.launches = 0      # device program launches by THIS engine
         self.steps = 0         # step() calls: the step timeline's `seq`
         #: set to a callable (request, logits row [vocab]) to be handed
@@ -479,50 +430,15 @@ class ServingEngine:
         else:
             self.controller = None
 
-    def _flat_rows(self) -> int:
-        """Flat token rows of one unified launch."""
-        return self.max_slots * (1 + self.spec_k) + self.prefill_chunk
+    # Read by benchmarks/systems/*_serving.py (the `paths` line of every
+    # run) and by nothing else: two constants since the fused halves
+    # left the engine, and the one chain's launches a layer after and
+    # before attention. They go with the `benchmark` PR of ROADMAP R0 (c).
+    megafront = megadecode = False
+    back_half_launches = 6      # o-proj, add, norm; gate/up, act, down
 
-    def _gate_megadecode(self) -> None:
-        """(Re)decide the fused back half for the CURRENT flat row count
-        — the kernels' token blocks follow it, so `reconfigure()` asks
-        again before it rebuilds the programs."""
-        self.megadecode = bool(
-            self._megadecode_pin and self.ragged
-            and self._megadecode_gate(tokens=self._flat_rows()))
-        #: pallas launches after attention, per layer per decode step —
-        #: the bench A/B row reads this (2 fused vs the 6-stage chain)
-        self.back_half_launches = 2 if self.megadecode else 6
-
-    def _megafront_family_ok(self, cfg, int4: bool, wbytes: int) -> bool:
-        """Per-family tiling/layout gate for the fused front half."""
-        eligible = functools.partial(megafront_eligible,
-                                     dtype_bytes=wbytes)
-        if self._family == "gpt":
-            # wqkv ships concatenated already; identity trig
-            return eligible(
-                cfg.hidden_size,
-                3 * cfg.num_attention_heads * cfg.head_dim,
-                cfg.head_dim)
-        if self._family == "mla":
-            if int4:
-                return False    # no packed-int4 MLA front site
-            if any("wqa" in L or "wqa_q" in L or "wqa_q4" in L
-                   for L in self._p["layers"]):
-                return False    # two-stage q-lora can't ride one slab
-            dh = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-            n = (cfg.num_attention_heads * dh
-                 + cfg.kv_lora_rank + cfg.qk_rope_head_dim)
-            return eligible(cfg.hidden_size, n, dh)
-        return all(
-            eligible(cfg.hidden_size,
-                     (h + 2 * cfg.num_key_value_heads) * cfg.head_dim,
-                     cfg.head_dim, int4=int4)
-            for h in {st["heads"] for st in self._attn_static})
-
-    def _split_front_launches(self) -> int:
-        """Launches before attention on the SPLIT front path, per layer
-        (norm + projection dots + the rope/append kernel)."""
+    @property
+    def front_half_launches(self) -> int:
         if self._family == "gpt":
             return 3            # norm + wqkv dot + rope-append
         if self._family == "mla":
@@ -532,41 +448,6 @@ class ServingEngine:
             # + row append
             return 7 if qlora else 5
         return 5                # norm + q/k/v dots + rope-append
-
-    def _concat_qkv_weights(self) -> None:
-        """Deploy-layout transform behind the megafront gate: replace
-        each layer's per-projection slabs with ONE concatenated
-        out-channel slab — the layout `fused_qkv_rope_append` reads.
-        Column-wise identical math (every output column depends only on
-        its own weight column; int4 packs along the contraction axis,
-        so out-channel concat is layout-safe), applied to payloads,
-        scales and biases alike.  llama/moe: wq|wk|wv -> wqkv; MLA:
-        wq|wkva -> wqkva; GPT already ships wqkv.  Consumed leaves are
-        popped so `tree_bytes` stays the honest residency total (concat
-        preserves bytes), which is safe because ragged engines only
-        ever build the unified program."""
-        if self._family == "gpt":
-            return
-        mla = self._family == "mla"
-        keys = ("wq", "wkva") if mla else ("wq", "wk", "wv")
-        new = "wqkva" if mla else "wqkv"
-        layers = []
-        for L in self._p["layers"]:
-            L = dict(L)
-            suffix = {"weight_only_int4": "_q4",
-                      "weight_only_int8": "_q"}.get(
-                          _walgo(L, keys[0]), "")
-            L[new + suffix] = jnp.concatenate(
-                [L.pop(k + suffix) for k in keys], axis=-1)
-            if suffix:
-                L[new + "_s"] = jnp.concatenate(
-                    [L.pop(k + "_s") for k in keys], axis=-1)
-            if "bq" in L:
-                L["bqkv"] = jnp.concatenate(
-                    [L.pop("bq"), L.pop("bk"), L.pop("bv")], axis=-1)
-            layers.append(L)
-        self._p = dict(self._p, layers=layers)
-        self._w = dict(self._w, layers=layers)
 
     def _build_programs(self) -> None:
         """(Re)build the fixed-shape jitted programs for the CURRENT
@@ -653,7 +534,6 @@ class ServingEngine:
                 "can only shrink, and spec_decode stays 0")
         self.prefill_chunk = new_chunk
         self.spec_k = new_k
-        self._gate_megadecode()
         self._build_programs()
         self.rebuilds += 1
         if _obs.enabled():
@@ -877,18 +757,6 @@ class ServingEngine:
             "bytes_per_token_model": (
                 self._ledger_model_bytes / self._ledger_tokens
                 if self._ledger_tokens else 0.0),
-            # launch decomposition of one decode layer body — the live
-            # A/B the bench reads.  The byte ledger above is fusion-
-            # INVARIANT by construction (weights cross once per launch
-            # and cache reads are page-granular on both paths; the
-            # fused front elides only intermediate activation
-            # crossings, which the ledger never counted), so the
-            # front-half win shows up here and in tokens/s, not as a
-            # measured-bytes discontinuity.
-            "front_half_launches": int(self.front_half_launches),
-            "back_half_launches": int(self.back_half_launches),
-            "layer_body_launches": int(self.front_half_launches + 1
-                                       + self.back_half_launches),
         }
 
     def program_cache_sizes(self) -> Dict[str, int]:
@@ -941,12 +809,6 @@ class ServingEngine:
                           self.prefix_cache.pages)
         reg.counter("serving.replica.launches",
                     "device program launches").inc(self.launches)
-        reg.gauge("serving.replica.front_half_launches",
-                  "per-layer launches before attention "
-                  "(2 = fused megafront)").set(self.front_half_launches)
-        reg.gauge("serving.replica.back_half_launches",
-                  "per-layer launches after attention "
-                  "(2 = fused megadecode)").set(self.back_half_launches)
         hc = reg.counter("serving.replica.handoffs",
                          "KV-page handoffs by direction",
                          labels=("direction",))
@@ -1371,9 +1233,7 @@ class ServingEngine:
                 _TRACE.stamp(preq.request_id, "prefill_chunk", tokens=n,
                              start=start)
             if _obs.enabled():
-                _LAUNCHES.labels(
-                    path="unified_megafront" if self.megafront
-                    else "unified").inc()
+                _LAUNCHES.labels(path="unified").inc()
                 _STEPS.labels(phase="unified").inc()
                 if n:
                     _TOKENS.labels(phase="prefill").inc(n)
@@ -1642,16 +1502,11 @@ class ServingEngine:
     # sequences with rows in it (decode slots share a tile; the chunk
     # spans several and refetches its pages once a tile); its work list
     # is built from the row tables in XLA, once a step (the layers'
-    # identical copies merge). The per-layer body is the fused decode chain:
-    # fused_rms_norm -> fused_qkv_rope_append (the ISSUE-20 mega-kernel
-    # front half: qkv projection with in-kernel dequant, rope, and the
-    # paged K/V scatter in one launch; `self.megafront` False falls
-    # back to the split qkv -> fused_rope_append front, same math) ->
-    # ragged_paged_attention -> fused_oproj_norm -> fused_ffn (the
-    # ISSUE-14 mega-kernel back half: o-proj + residual + norm emit
-    # from one f32 VMEM accumulator, the whole FFN from a second —
-    # `self.megadecode` False falls back to the split o-proj/norm/ffn
-    # chain, same math, more HBM round-trips).
+    # identical copies merge). The per-layer body is ONE chain: norm ->
+    # q / k / v projections -> fused_rope_append (MLA:
+    # fused_append_rows) -> ragged_paged_attention -> o-proj -> norm ->
+    # _ffn_apply. Entry (_seq_starts) and exit (_logit_rows,
+    # _head_logits) are shared by the three families.
     # No flags_guard: nothing in the chain is flag-routed.
 
     def _llama_unified_body(self):
@@ -1661,16 +1516,10 @@ class ServingEngine:
         moe_static = self._p.get("moe_static")
         attn_static = self._attn_static
         count_moe = _tracing.STEP_COUNTS_MOE[0] in self._count_names
-        mega = self.megadecode
-        megafront = self.megafront
         B, C, K = self.max_slots, self.prefill_chunk, self.spec_k
         R = 1 + K
         T = B * R + C
-        # decode slot s owns rows [s*R, (s+1)*R); the prefill chunk owns
-        # rows [B*R, B*R+C). R == 1 reduces to arange(B + 1).
-        seq_start = jnp.concatenate(
-            [jnp.arange(B, dtype=jnp.int32) * R,
-             jnp.asarray([B * R], jnp.int32)])
+        seq_start = _seq_starts(B, R)
 
         def step(w, tok, pools, positions, num_tokens, kv_lengths,
                  tables, tok_page, tok_off):
@@ -1698,24 +1547,13 @@ class ServingEngine:
                 kind = int(window is not None)
                 table, page = tables[kind], tok_page[kind]
                 h = fused_rms_norm(x, L["ln1"], eps)
-                if megafront:
-                    # ISSUE 20 front half: qkv projection (in-kernel
-                    # dequant of the concatenated deploy slab), rope
-                    # and the paged K/V scatter in ONE launch
-                    wp, ws = _wq2(L, "wqkv")
-                    q, kp, vp = fused_qkv_rope_append(
-                        h[0], wp, ws, L.get("bqkv"), c, s, kp, vp,
-                        page, tok_off, heads=Hh, kv_heads=KV,
-                        head_dim=D, algo=_walgo(L, "wqkv"))
-                else:
-                    q, k, v = (_mm_w(h, L, "wq"), _mm_w(h, L, "wk"),
-                               _mm_w(h, L, "wv"))
-                    if "bq" in L:
-                        q, k, v = q + L["bq"], k + L["bk"], v + L["bv"]
-                    q, kp, vp = fused_rope_append(
-                        q.reshape(T, Hh, D), k.reshape(T, KV, D),
-                        v.reshape(T, KV, D), c, s, kp, vp, page,
-                        tok_off)
+                q, k, v = (_mm_w(h, L, "wq"), _mm_w(h, L, "wk"),
+                           _mm_w(h, L, "wv"))
+                if "bq" in L:
+                    q, k, v = q + L["bq"], k + L["bk"], v + L["bv"]
+                q, kp, vp = fused_rope_append(
+                    q.reshape(T, Hh, D), k.reshape(T, KV, D),
+                    v.reshape(T, KV, D), c, s, kp, vp, page, tok_off)
                 new_pools.append((kp, vp))
                 o = ragged_paged_attention(q, kp, vp, seq_start,
                                            num_tokens, kv_lengths,
@@ -1725,39 +1563,12 @@ class ServingEngine:
                     # one sigmoid scalar a head, from the normed input
                     g = jax.nn.sigmoid(_mm_w(h, L, "wgate"))[0]
                     o = o * g[..., None].astype(o.dtype)
-                if mega:
-                    wp, ws = _wq2(L, "wo")
-                    xn, h2 = fused_oproj_norm(
-                        o.reshape(T, Hh * D), x[0], wp, ws, None,
-                        L["ln2"], None, eps=eps, algo=_walgo(L, "wo"))
-                    if "moe" in L:
-                        x = xn[None] + _ffn_apply(L, h2[None], st,
-                                                  moe_stats, live)
-                    else:
-                        gp, gs = _wq2(L, "wg")
-                        up, us = _wq2(L, "wu")
-                        dp, ds = _wq2(L, "wd")
-                        x = fused_ffn(h2, xn, gp, gs, up, us, dp, ds,
-                                      algo=_walgo(L, "wg"))[None]
-                else:
-                    x = x + _mm_w(o.reshape(1, T, Hh * D), L, "wo")
-                    h2 = fused_rms_norm(x, L["ln2"], eps)
-                    x = x + _ffn_apply(L, h2, st, moe_stats, live)
+                x = x + _mm_w(o.reshape(1, T, Hh * D), L, "wo")
+                h2 = fused_rms_norm(x, L["ln2"], eps)
+                x = x + _ffn_apply(L, h2, st, moe_stats, live)
             x = fused_rms_norm(x, w["norm"], eps)
-            # each sequence's logits come from its LAST flat row; idle
-            # slots (num_tokens 0) index garbage the host ignores. With
-            # spec decoding every row's logits come back — each drafted
-            # position is a verify point.
-            if K:
-                last = x[0]
-            else:
-                last = x[0, jnp.clip(seq_start + num_tokens - 1,
-                                     0, T - 1)]
-            if "head_q" in w or "head_q4" in w:
-                logits = _mm_w(last, w, "head")
-            else:
-                logits = last @ (w["head"] if w["head"] is not None
-                                 else w["embed"].T)
+            logits = _head_logits(
+                w, _logit_rows(x, seq_start, num_tokens, K))
             if moe_stats:
                 # one [5] array beside the logits: pairs routed and held
                 # summed over the routed layers, the fullest expert's
@@ -1775,16 +1586,10 @@ class ServingEngine:
         cfg = self._p["cfg"]
         nh, hd = cfg.num_attention_heads, cfg.head_dim
         eps = cfg.layer_norm_eps
-        mega = self.megadecode
-        megafront = self.megafront
         B, C, K = self.max_slots, self.prefill_chunk, self.spec_k
         R = 1 + K
         T = B * R + C
-        # decode slot s owns rows [s*R, (s+1)*R); the prefill chunk owns
-        # rows [B*R, B*R+C). R == 1 reduces to arange(B + 1).
-        seq_start = jnp.concatenate(
-            [jnp.arange(B, dtype=jnp.int32) * R,
-             jnp.asarray([B * R], jnp.int32)])
+        seq_start = _seq_starts(B, R)
 
         def step(w, tok, pools, positions, num_tokens, kv_lengths,
                  tables, tok_page, tok_off):
@@ -1796,50 +1601,24 @@ class ServingEngine:
             new_pools = []
             for L, (kp, vp) in zip(w["layers"], pools):
                 h = fused_layer_norm(x, L["ln1w"], L["ln1b"], eps)
-                if megafront:
-                    # the deploy wqkv slab is already the fused
-                    # kernel's [q | k | v] column layout; identity
-                    # trig makes rope a no-op on q/k
-                    q, kp, vp = fused_qkv_rope_append(
-                        h[0], L["wqkv"], None, L["bqkv"], c, s, kp,
-                        vp, tok_page, tok_off, heads=nh, kv_heads=nh,
-                        head_dim=hd)
-                else:
-                    qkv = h @ L["wqkv"] + L["bqkv"]
-                    q, k, v = jnp.split(qkv, 3, axis=-1)
-                    q, kp, vp = fused_rope_append(
-                        q.reshape(T, nh, hd), k.reshape(T, nh, hd),
-                        v.reshape(T, nh, hd), c, s, kp, vp,
-                        tok_page, tok_off)
+                qkv = h @ L["wqkv"] + L["bqkv"]
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                q, kp, vp = fused_rope_append(
+                    q.reshape(T, nh, hd), k.reshape(T, nh, hd),
+                    v.reshape(T, nh, hd), c, s, kp, vp,
+                    tok_page, tok_off)
                 new_pools.append((kp, vp))
                 o = ragged_paged_attention(q, kp, vp, seq_start,
                                            num_tokens, kv_lengths,
                                            tables, scale=hd ** -0.5)
-                if mega:
-                    # GPT family is fp (no quantized leaves): biases and
-                    # the layer norm ride the same two mega-kernels
-                    xn, h2 = fused_oproj_norm(
-                        o.reshape(T, nh * hd), x[0], L["wo"], None,
-                        L["bo"], L["ln2w"], L["ln2b"], eps=eps,
-                        norm="layer")
-                    x = fused_ffn(h2, xn, L["wi"], None, None, None,
-                                  L["wf"], None, L["bi"], L["bf"],
-                                  act="gelu")[None]
-                else:
-                    x = x + (o.reshape(1, T, nh * hd) @ L["wo"]
-                             + L["bo"])
-                    h2 = fused_layer_norm(x, L["ln2w"], L["ln2b"], eps)
-                    x = x + (jax.nn.gelu(h2 @ L["wi"] + L["bi"],
-                                         approximate=True) @ L["wf"]
-                             + L["bf"])
+                x = x + (o.reshape(1, T, nh * hd) @ L["wo"] + L["bo"])
+                h2 = fused_layer_norm(x, L["ln2w"], L["ln2b"], eps)
+                x = x + (jax.nn.gelu(h2 @ L["wi"] + L["bi"],
+                                     approximate=True) @ L["wf"]
+                         + L["bf"])
             x = fused_layer_norm(x, w["normw"], w["normb"], eps)
-            if K:
-                last = x[0]
-            else:
-                last = x[0, jnp.clip(seq_start + num_tokens - 1,
-                                     0, T - 1)]
-            logits = last @ (w["head"] if w["head"] is not None
-                             else w["embed"].T)
+            logits = _head_logits(
+                w, _logit_rows(x, seq_start, num_tokens, K))
             return logits, new_pools
 
         return step
@@ -1853,16 +1632,10 @@ class ServingEngine:
         eps = cfg.rms_norm_eps
         scale = 1.0 / float(math.sqrt(dn + dr))
         moe_static = self._p.get("moe_static")
-        mega = self.megadecode
-        megafront = self.megafront
         B, C, K = self.max_slots, self.prefill_chunk, self.spec_k
         R = 1 + K
         T = B * R + C
-        # decode slot s owns rows [s*R, (s+1)*R); the prefill chunk owns
-        # rows [B*R, B*R+C). R == 1 reduces to arange(B + 1).
-        seq_start = jnp.concatenate(
-            [jnp.arange(B, dtype=jnp.int32) * R,
-             jnp.asarray([B * R], jnp.int32)])
+        seq_start = _seq_starts(B, R)
 
         def step(w, tok, pools, positions, num_tokens, kv_lengths,
                  tables, tok_page, tok_off):
@@ -1884,77 +1657,36 @@ class ServingEngine:
                 h = fused_rms_norm(x, L["ln1"], eps)
                 wkb = _dq(L, "wkvb", x.dtype).reshape(r, nh, dn + dv)
                 w_k, w_v = wkb[..., :dn], wkb[..., dn:]
-                if megafront:
-                    # ISSUE 20 front half: the [q | kv_a] slab
-                    # projects, the q tail and k_pe rope, the latent
-                    # rms-norms and the [latent | rope-key] pool row
-                    # lands — one launch, q already at the attention
-                    # granularity [T, nh, dn+dr]
-                    wp, ws = _wq2(L, "wqkva")
-                    q, pool = fused_qkv_rope_append(
-                        h[0], wp, ws, None, c, s, pool, None,
-                        tok_page, tok_off, heads=nh,
-                        algo=_walgo(L, "wqkva"), norm_weight=L["gkv"],
-                        eps=eps, nope_dim=dn, rope_dim=dr,
-                        lora_rank=r)
-                    q_eff = jnp.einsum("tnd,rnd->tnr", q[..., :dn],
-                                       w_k)
-                    q_cat = jnp.concatenate([q_eff, q[..., dn:]], -1)
+                if "wqa" in L or "wqa_q" in L or "wqa_q4" in L:
+                    q = _mm_w(fused_rms_norm(_mm_w(h, L, "wqa"),
+                                             L["gq"], eps),
+                              L, "wqb")
                 else:
-                    if "wqa" in L or "wqa_q" in L or "wqa_q4" in L:
-                        q = _mm_w(fused_rms_norm(_mm_w(h, L, "wqa"),
-                                                 L["gq"], eps),
-                                  L, "wqb")
-                    else:
-                        q = _mm_w(h, L, "wq")
-                    q = q.reshape(1, T, nh, dn + dr)
-                    q_nope, q_pe = q[..., :dn], q[..., dn:]
-                    # rope runs on the split q_pe/k_pe shapes (not
-                    # D-halved cache rows), so the append is the
-                    # row-scatter kernel
-                    q_pe = rope(q_pe)
-                    kv_a = _mm_w(h, L, "wkva")           # [1, T, r+dr]
-                    lat = fused_rms_norm(kv_a[..., :r], L["gkv"], eps)
-                    k_pe = rope(kv_a[..., r:][:, :, None, :])[:, :, 0]
-                    rows = jnp.concatenate([lat, k_pe], -1)[0][:, None]
-                    pool = fused_append_rows(pool, rows, tok_page,
-                                             tok_off)
-                    q_eff = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_k)
-                    q_cat = jnp.concatenate([q_eff, q_pe], -1)[0]
+                    q = _mm_w(h, L, "wq")
+                q = q.reshape(1, T, nh, dn + dr)
+                q_nope, q_pe = q[..., :dn], q[..., dn:]
+                # rope runs on the split q_pe/k_pe shapes (not D-halved
+                # cache rows), so the append is the row-scatter kernel
+                q_pe = rope(q_pe)
+                kv_a = _mm_w(h, L, "wkva")               # [1, T, r+dr]
+                lat = fused_rms_norm(kv_a[..., :r], L["gkv"], eps)
+                k_pe = rope(kv_a[..., r:][:, :, None, :])[:, :, 0]
+                rows = jnp.concatenate([lat, k_pe], -1)[0][:, None]
+                pool = fused_append_rows(pool, rows, tok_page, tok_off)
+                q_eff = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_k)
+                q_cat = jnp.concatenate([q_eff, q_pe], -1)[0]
                 new_pools.append(pool)
                 o_cat = ragged_paged_attention(q_cat, pool, pool,
                                                seq_start, num_tokens,
                                                kv_lengths, tables,
                                                scale=scale)
                 o = jnp.einsum("tnr,rnv->tnv", o_cat[..., :r], w_v)
-                if mega:
-                    wp, ws = _wq2(L, "wo")
-                    xn, h2 = fused_oproj_norm(
-                        o.reshape(T, nh * dv), x[0], wp, ws, None,
-                        L["ln2"], None, eps=eps, algo=_walgo(L, "wo"))
-                    if "moe" in L:
-                        x = xn[None] + _ffn_apply(L, h2[None], st)
-                    else:
-                        gp, gs = _wq2(L, "wg")
-                        up, us = _wq2(L, "wu")
-                        dp, ds = _wq2(L, "wd")
-                        x = fused_ffn(h2, xn, gp, gs, up, us, dp, ds,
-                                      algo=_walgo(L, "wg"))[None]
-                else:
-                    x = x + _mm_w(o.reshape(1, T, nh * dv), L, "wo")
-                    h2 = fused_rms_norm(x, L["ln2"], eps)
-                    x = x + _ffn_apply(L, h2, st)
+                x = x + _mm_w(o.reshape(1, T, nh * dv), L, "wo")
+                h2 = fused_rms_norm(x, L["ln2"], eps)
+                x = x + _ffn_apply(L, h2, st)
             x = fused_rms_norm(x, w["norm"], eps)
-            if K:
-                last = x[0]
-            else:
-                last = x[0, jnp.clip(seq_start + num_tokens - 1,
-                                     0, T - 1)]
-            if "head_q" in w or "head_q4" in w:
-                logits = _mm_w(last, w, "head")
-            else:
-                logits = last @ (w["head"] if w["head"] is not None
-                                 else w["embed"].T)
+            logits = _head_logits(
+                w, _logit_rows(x, seq_start, num_tokens, K))
             return logits, new_pools
 
         return step
@@ -2013,12 +1745,7 @@ class ServingEngine:
                     x = x + _ffn_apply(L, h2, st)
             x = rms(x, w["norm"])
             last = x[:, -1]
-            if "head_q" in w or "head_q4" in w:
-                logits = _mm_w(last, w, "head")
-            else:
-                logits = last @ (w["head"] if w["head"] is not None
-                                 else w["embed"].T)
-            return logits, new_pools
+            return _head_logits(w, last), new_pools
 
         return step
 
@@ -2097,12 +1824,7 @@ class ServingEngine:
             x = rms(x, w["norm"])
             last = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0,
                                                 keepdims=False)[None]
-            if "head_q" in w or "head_q4" in w:
-                logits = _mm_w(last, w, "head")
-            else:
-                logits = last @ (w["head"] if w["head"] is not None
-                                 else w["embed"].T)
-            return logits, new_pools
+            return _head_logits(w, last), new_pools
 
         return prefill
 
@@ -2143,9 +1865,7 @@ class ServingEngine:
                              + L["bf"])
             x = ln(x, w["normw"], w["normb"])
             last = x[:, -1]
-            logits = last @ (w["head"] if w["head"] is not None
-                             else w["embed"].T)
-            return logits, new_pools
+            return _head_logits(w, last), new_pools
 
         return step
 
@@ -2207,9 +1927,7 @@ class ServingEngine:
             x = ln(x, w["normw"], w["normb"])
             last = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0,
                                                 keepdims=False)[None]
-            logits = last @ (w["head"] if w["head"] is not None
-                             else w["embed"].T)
-            return logits, new_pools
+            return _head_logits(w, last), new_pools
 
         return prefill
 
@@ -2282,12 +2000,7 @@ class ServingEngine:
                     x = x + _ffn_apply(L, h2, st)
             x = rms(x, w["norm"])
             last = x[:, -1]
-            if "head_q" in w or "head_q4" in w:
-                logits = _mm_w(last, w, "head")
-            else:
-                logits = last @ (w["head"] if w["head"] is not None
-                                 else w["embed"].T)
-            return logits, new_pools
+            return _head_logits(w, last), new_pools
 
         return step
 
@@ -2370,11 +2083,6 @@ class ServingEngine:
             x = rms(x, w["norm"])
             last = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0,
                                                 keepdims=False)[None]
-            if "head_q" in w or "head_q4" in w:
-                logits = _mm_w(last, w, "head")
-            else:
-                logits = last @ (w["head"] if w["head"] is not None
-                                 else w["embed"].T)
-            return logits, new_pools
+            return _head_logits(w, last), new_pools
 
         return prefill

@@ -1,0 +1,250 @@
+"""`ServingEngine` chooses its programs itself and runs ONE layer chain
+(ISSUE 30): no constructor argument selects an implementation; the
+unified ragged step is built where `_ragged_step_eligible` says the
+kernel tiles and the split pair where it does not (a test answers for
+that one function); what is lowered calls neither fused half and reads
+the model's own projections; and the names `benchmarks/` reads of the
+engine are there."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.generation import generate_cached
+from paddle_tpu.serving import ServingEngine
+from paddle_tpu.serving import engine as engine_mod
+from test_serving_engine import _run_trace
+
+
+def _tiny(family):
+    """A seeded toy model of one of the engine's families."""
+    paddle.seed(0)
+    if family == "gpt":
+        from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny_config
+        m = GPTForCausalLM(gpt_tiny_config(max_position_embeddings=64))
+    elif family in ("mla", "mla_no_q_lora"):
+        from paddle_tpu.models.deepseek import (DeepSeekV2ForCausalLM,
+                                                deepseek_v2_tiny_config)
+        kw = dict(q_lora_rank=None) if family == "mla_no_q_lora" else {}
+        m = DeepSeekV2ForCausalLM(deepseek_v2_tiny_config(
+            moe_dropless=True, num_hidden_layers=2, **kw))
+    elif family == "moe":
+        from paddle_tpu.models.moe_llm import (MoEForCausalLM,
+                                               qwen2_moe_tiny_config)
+        m = MoEForCausalLM(qwen2_moe_tiny_config(
+            moe_dropless=True, first_k_dense_replace=1,
+            max_position_embeddings=64))
+    else:
+        from paddle_tpu.models.llama import (LlamaForCausalLM,
+                                             llama_tiny_config)
+        m = LlamaForCausalLM(llama_tiny_config(num_hidden_layers=2))
+    m.eval()
+    return m
+
+
+def _quantized_trace_exact(m, seed, quant, n=3):
+    """Engine greedy tokens under weight-only `quant` equal the solo
+    quantized run's, request by request."""
+    rng = np.random.RandomState(seed)
+    prompts = [rng.randint(0, m.config.vocab_size, rng.randint(3, 9))
+               .astype(np.int32) for _ in range(n)]
+    eng = ServingEngine(m, max_slots=2, page_size=4, prefill_chunk=4,
+                        weight_only_quant=quant)
+    assert eng.ragged
+    for i, p in enumerate(prompts):
+        eng.add_request(p, max_new_tokens=4, request_id=i)
+    out = eng.run_to_completion()
+    for i, p in enumerate(prompts):
+        want, _ = generate_cached(m, paddle.to_tensor(p[None]),
+                                  max_new_tokens=4,
+                                  decode_strategy="greedy_search",
+                                  weight_only_quant=quant)
+        np.testing.assert_array_equal(out[i], want.numpy()[0])
+
+
+class TestRaggedPath:
+    """The engine chooses its programs itself (`_ragged_step_eligible`,
+    asked once at construction; no constructor argument): the unified
+    ragged step where the kernel tiles, the split pair where it does
+    not. Split-path parity, strictly fewer launches, and the quantized
+    families on the one chain."""
+
+    ARGS = dict(max_slots=2, page_size=4, prefill_chunk=4)
+
+    @pytest.fixture(scope="class")
+    def model(self):
+        return _tiny("llama")
+
+    @pytest.fixture
+    def no_ragged_kernel(self, monkeypatch):
+        """What a chip answers for a head width that is not 64 or a
+        multiple of 128: the test steers the one function the engine
+        consults, not an option of the program."""
+        monkeypatch.setattr(engine_mod, "_ragged_step_eligible",
+                            lambda *a: False)
+
+    def test_split_path_still_exact(self, model, no_ragged_kernel):
+        # the alternating prefill/decode pair, where the kernel is out
+        V = model.config.vocab_size
+        results, ref, eng = _run_trace(model, V, 4, seed=6, **self.ARGS)
+        assert not eng.ragged
+        assert set(eng.program_cache_sizes()) == {"decode", "prefill"}
+        for rid in ref:
+            np.testing.assert_array_equal(results[rid], ref[rid])
+        assert all(v == 1 for v in eng.program_cache_sizes().values())
+
+    @pytest.mark.parametrize("family", ["llama", "gpt", "mla", "moe"])
+    def test_ragged_matches_split(self, family, monkeypatch):
+        m = _tiny(family)
+        V = m.config.vocab_size
+        r1, _, e1 = _run_trace(m, V, 5, seed=7, **self.ARGS)
+        monkeypatch.setattr(engine_mod, "_ragged_step_eligible",
+                            lambda *a: False)
+        r2, _, e2 = _run_trace(m, V, 5, seed=7, spec_decode=2, **self.ARGS)
+        assert e1.ragged and not e2.ragged
+        assert set(e1.program_cache_sizes()) == {"unified"}
+        assert e2.spec_k == 0       # the split pair has no multi-row slots
+        assert set(r1) == set(r2)
+        for rid in r1:
+            np.testing.assert_array_equal(r1[rid], r2[rid])
+
+    def test_unified_strictly_fewer_launches(self, model, monkeypatch):
+        # a trace with overlapping prefill+decode work: the split path
+        # pays two launches on every such step, the unified path one
+        V = model.config.vocab_size
+        _, _, e1 = _run_trace(model, V, 6, seed=8, **self.ARGS)
+        monkeypatch.setattr(engine_mod, "_ragged_step_eligible",
+                            lambda *a: False)
+        _, _, e2 = _run_trace(model, V, 6, seed=8, **self.ARGS)
+        assert e1.launches < e2.launches
+
+    def test_launches_metric_series(self, model):
+        from paddle_tpu import serving as srv
+        V = model.config.vocab_size
+        _run_trace(model, V, 3, seed=10, **self.ARGS)
+        m = srv.metrics()
+        paths = {s["labels"]["path"]: s["value"]
+                 for s in m["serving.engine.launches"]["series"]}
+        assert paths.get("unified", 0) >= 1
+        assert set(paths) <= {"unified", "split"}
+
+    @pytest.mark.parametrize("switch", ["ragged", "megafront",
+                                        "megadecode"])
+    def test_no_implementation_switch_on_the_constructor(self, model,
+                                                         switch):
+        # a removed argument is refused, not ignored
+        with pytest.raises(TypeError, match=switch):
+            ServingEngine(model, **{switch: False}, **self.ARGS)
+
+    @pytest.mark.parametrize("quant", ["int8", "int4"])
+    def test_llama_quantized_seeded_trace(self, model, quant):
+        _quantized_trace_exact(model, 35, quant, n=2)
+
+    def test_moe_int4_seeded_trace(self):
+        # int4 end to end INCLUDING the 3-D packed expert stacks
+        _quantized_trace_exact(_tiny("moe"), 22, "int4")
+
+    def test_mla_no_q_lora_seeded_trace(self):
+        # the one-stage q projection (`wq`, no `wqa` / `wqb`)
+        m = _tiny("mla_no_q_lora")
+        results, ref, eng = _run_trace(m, m.config.vocab_size, 3, seed=34,
+                                       **self.ARGS)
+        assert eng.ragged and eng.front_half_launches == 5
+        for rid in ref:
+            np.testing.assert_array_equal(results[rid], ref[rid])
+
+    def test_mla_int4_seeded_trace(self):
+        # VERDICT item 6 tail: packed-int4 absorbed projections inside
+        # the engine's MLA body exact-match the int4 solo run
+        _quantized_trace_exact(_tiny("mla"), 12, "int4")
+
+
+def _laguna():
+    from paddle_tpu.models.laguna import (LagunaForCausalLM,
+                                          laguna_tiny_config)
+    paddle.seed(0)
+    m = LagunaForCausalLM(laguna_tiny_config(experts_held=(4, 4)))
+    m.eval()
+    return m
+
+
+def _lower_unified(eng):
+    """Lower the unified step from shapes, with the nine positional
+    arguments `benchmarks/tests` pass it: (w, tok, pools, positions,
+    num_tokens, kv_lengths, tables, tok_page, tok_off)."""
+    B, C = eng.max_slots, eng.prefill_chunk
+
+    def i32(*d):
+        return jax.ShapeDtypeStruct(d, jnp.int32)
+
+    table, page = i32(B + 1, eng.pages_per_seq), i32(B + C)
+    if eng.num_window_pages:    # a table and a page column a layer kind
+        table, page = (table, table), (page, page)
+    return eng._jit_unified.lower(
+        eng._w, i32(B + C), eng._pools, i32(B + C), i32(B + 1), i32(B + 1),
+        table, page, i32(B + C))
+
+
+#: family -> (the model's own projections of layer 0, the append its
+#: front half ends in)
+CHAIN = {
+    "llama": ({"wq", "wk", "wv", "wo", "wg", "wu", "wd"},
+              "fused_rope_append"),
+    "moe": ({"wq", "wk", "wv", "wo"}, "fused_rope_append"),
+    "mla": ({"wqa", "wqb", "wkva", "wkvb", "wo"}, "fused_append_rows"),
+    "gpt": ({"wqkv", "wo", "wi", "wf"}, "fused_rope_append"),
+    "laguna": ({"wq", "wk", "wv", "wgate", "wo"}, "fused_rope_append"),
+}
+
+
+class TestOneChain:
+    """By what was lowered: every family's unified step is norm ->
+    projections -> rope/append -> ragged attention -> o-proj -> norm ->
+    FFN, over the model's own weight tree."""
+
+    @pytest.mark.parametrize("family", sorted(CHAIN))
+    def test_lowered_step_calls_no_fused_half(self, family):
+        m = _laguna() if family == "laguna" else _tiny(family)
+        eng = ServingEngine(m, max_slots=2, page_size=8, max_context=64,
+                            prefill_chunk=8)
+        assert eng.ragged
+        own, append = CHAIN[family]
+        keys = set(eng._w["layers"][0])
+        assert own <= keys, sorted(keys)
+        # GPT's checkpoint ships `wqkv`; nobody else has a slab
+        assert ("wqkv" in keys) == (family == "gpt")
+        assert "wqkva" not in keys
+        # locations name the python functions the step was traced through
+        text = _lower_unified(eng).as_text(debug_info=True)
+        for gone in ("fused_qkv_rope_append", "fused_oproj_norm",
+                     "fused_ffn", "pallas_megafront", "pallas_megadecode"):
+            assert gone not in text, gone
+        for here in (append, "ragged_paged_attention"):
+            assert here in text, here
+        # launches before attention: GPT's one qkv dot, MLA's q-lora pair
+        assert eng.front_half_launches == {"gpt": 3, "mla": 7}.get(family, 5)
+
+
+class TestTheNamesTheBenchmarkReads:
+    """`benchmarks/systems/*_serving.py` print `eng.ragged / .megafront /
+    .megadecode / .front_half_launches / .back_half_launches` in every
+    run's `paths` line, and `benchmarks/tests` lower `_jit_unified` from
+    `_w`, `_pools`, `_p` (ROADMAP D11 / D12 retire both)."""
+
+    @pytest.mark.parametrize("family", ["llama", "laguna"])
+    def test_the_names_the_benchmark_reads(self, family):
+        m = _laguna() if family == "laguna" else _tiny(family)
+        eng = ServingEngine(m, max_slots=2, page_size=8, max_context=64,
+                            prefill_chunk=8)
+        assert eng.ragged is True
+        assert eng.megafront is False and eng.megadecode is False
+        assert (eng.front_half_launches, eng.back_half_launches) == (5, 6)
+        assert eng.spec_k == 0 and eng.on_logits is None
+        assert bool(eng.num_window_pages) == (family == "laguna")
+        assert len(eng._attn_static) == len(eng._p["layers"]) \
+            == len(eng._w["layers"]) == len(eng._pools)
+        logits, pools, *_ = _lower_unified(eng).out_info
+        assert logits.shape == (eng.max_slots + 1, m.config.vocab_size)
+        assert jax.tree.structure(pools) == jax.tree.structure(eng._pools)

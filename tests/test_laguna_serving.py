@@ -75,8 +75,8 @@ def _reference_rows(m, prompt, tokens, **kw):
 
 @pytest.fixture(scope="module")
 def served(model):
-    """One run on the paths the engine chooses here (both fused halves):
-    prompts past the window (24), past several chunks (16) and pages
+    """One run on the engine's one chain, the one the chip runs: prompts
+    past the window (24), past several chunks (16) and pages
     (8), one shorter than the window; (prompts, requests, logits rows,
     engine, the run's step records)."""
     from paddle_tpu.observability import tracing
@@ -88,19 +88,10 @@ def served(model):
 
 
 class TestEngineAgainstReference:
-    @pytest.mark.parametrize("mega", [True, False],
-                             ids=["fused_halves", "split_chain"])
     def test_prefill_in_chunks_then_decode_matches_in_logits(
-            self, model, served, mega):
-        if mega:
-            prompts, reqs, rows, eng, _ = served
-        else:               # the chain the chip takes at published widths
-            prompts = _prompts(model, (70, 20, 41))
-            reqs, rows, eng = _serve(model, prompts, 6, megafront=False,
-                                     megadecode=False)
-            eng.run_to_completion()
+            self, model, served):
+        prompts, reqs, rows, eng, _ = served
         assert eng.ragged and eng._window == 24
-        assert (eng.megafront, eng.megadecode) == (mega, mega)
         assert eng.program_cache_sizes() == {"unified": 1}
         for r, p in zip(reqs, prompts):
             got = np.stack(rows[r.request_id])
@@ -135,7 +126,7 @@ class TestEngineAgainstReference:
         for r in recs:
             # the rows that requests own, not the flat buffer's padding
             live = r["prefill_rows"] + r["decode_rows"]
-            assert live < eng._flat_rows()
+            assert live < eng.max_slots + eng.prefill_chunk
             assert r["moe_pairs_routed"] == live * k * sparse
             assert 0 < r["moe_pairs_held"] < r["moe_pairs_routed"]
             assert r["moe_expert_rows_max"] >= r["moe_expert_rows_mean"] > 0
@@ -179,11 +170,16 @@ class TestEngineAgainstReference:
 
 class TestWhatAWindowedModelRefuses:
     @pytest.mark.parametrize("kw,word", [
-        (dict(ragged=False), "unified ragged step"),
+        (dict(), "unified ragged step"),
         (dict(enable_prefix_cache=True), "enable_prefix_cache"),
         (dict(spec_decode=2), "spec_decode"),
         (dict(role="prefill"), "export_request")])
-    def test_construction_names_the_reason(self, model, kw, word):
+    def test_construction_names_the_reason(self, model, kw, word,
+                                           monkeypatch):
+        if not kw:      # where the ragged kernel does not tile
+            from paddle_tpu.serving import engine as engine_mod
+            monkeypatch.setattr(engine_mod, "_ragged_step_eligible",
+                                lambda *a: False)
         with pytest.raises(ValueError, match=word):
             ServingEngine(model, max_slots=2, page_size=8, max_context=64,
                           **kw)

@@ -3,25 +3,22 @@
 Interpret-mode parity of the two fused launches against their XLA
 oracles (ops/references.py) across the four family geometries — fp
 (bitwise), int8 and packed int4 (split-contraction reordering only) —
-plus the engine-level contracts: megadecode vs split-chain exactness,
-the eligibility gate's TPU tiling rules, int4-MoE end-to-end, and the
-costmodel launch accounting (5 launches/layer with both mega halves,
-8 with either alone, 11 split; 2 pallas_calls after attention)."""
+plus the eligibility gate's TPU tiling rules and the costmodel launch
+accounting (5 launches/layer with both mega halves, 8 with either
+alone, 11 split; 2 pallas_calls after attention).  Kernel-level only:
+ServingEngine does not call these kernels (ROADMAP D11 deletes them
+with these tests)."""
 
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
-import paddle_tpu as paddle
-from paddle_tpu.generation import generate_cached
 from paddle_tpu.ops.pallas_megadecode import (fused_ffn, fused_oproj_norm,
                                               megadecode_eligible)
 from paddle_tpu.ops.quant import weight_quantize
 from paddle_tpu.ops.references import (megadecode_ffn_reference,
                                        oproj_norm_reference)
-from paddle_tpu.serving import ServingEngine
 
 
 def _rand(rng, *shape):
@@ -219,93 +216,6 @@ class TestEligibility:
         assert not md.megadecode_eligible(512, 1792, 520)
         # unsharded llama3-8B blows the VMEM weight budget
         assert not md.megadecode_eligible(4096, 14336, 4096)
-
-
-class TestEngineMegadecode:
-    """Engine wiring: default-on fused back half, split-chain fallback
-    parity, int4-MoE end-to-end, launch accounting."""
-
-    @pytest.fixture(scope="class")
-    def model(self):
-        from paddle_tpu.models.llama import (LlamaForCausalLM,
-                                             llama_tiny_config)
-        paddle.seed(0)
-        m = LlamaForCausalLM(llama_tiny_config(num_hidden_layers=2))
-        m.eval()
-        return m
-
-    def _run(self, model, prompts, max_new=4, **kw):
-        eng = ServingEngine(model, max_slots=2, page_size=4,
-                            prefill_chunk=4, **kw)
-        for i, p in enumerate(prompts):
-            eng.add_request(p, max_new_tokens=max_new, request_id=i)
-        return eng.run_to_completion(), eng
-
-    def test_default_on_and_back_half_launches(self, model):
-        eng = ServingEngine(model, max_slots=2, page_size=4)
-        assert eng.megadecode
-        assert eng.back_half_launches == 2
-        off = ServingEngine(model, max_slots=2, page_size=4,
-                            megadecode=False)
-        assert not off.megadecode
-        assert off.back_half_launches == 6
-
-    def test_megadecode_matches_split_chain(self, model):
-        V = model.config.vocab_size
-        rng = np.random.RandomState(21)
-        prompts = [rng.randint(0, V, rng.randint(3, 9)).astype(np.int32)
-                   for _ in range(3)]
-        on, e1 = self._run(model, prompts)
-        off, e2 = self._run(model, prompts, megadecode=False)
-        assert e1.megadecode and not e2.megadecode
-        assert set(on) == set(off)
-        for i in on:
-            np.testing.assert_array_equal(on[i], off[i])
-        # and both match solo generate_cached (greedy exactness)
-        for i, p in enumerate(prompts):
-            want, _ = generate_cached(model, paddle.to_tensor(p[None]),
-                                      max_new_tokens=4,
-                                      decode_strategy="greedy_search")
-            np.testing.assert_array_equal(on[i], want.numpy()[0])
-
-    def test_moe_int4_seeded_trace(self):
-        # ISSUE 14 tentpole tail: int4 end-to-end through the fused
-        # back half INCLUDING the 3-D packed expert stacks — engine
-        # greedy tokens equal the solo int4 run exactly
-        from paddle_tpu.models.moe_llm import (MoEForCausalLM,
-                                               qwen2_moe_tiny_config)
-        paddle.seed(0)
-        c = qwen2_moe_tiny_config(moe_dropless=True,
-                                  first_k_dense_replace=1,
-                                  max_position_embeddings=64)
-        m = MoEForCausalLM(c)
-        m.eval()
-        rng = np.random.RandomState(22)
-        prompts = [rng.randint(0, c.vocab_size, rng.randint(3, 9))
-                   .astype(np.int32) for _ in range(3)]
-        out, eng = self._run(m, prompts, weight_only_quant="int4")
-        assert eng.megadecode
-        for i, p in enumerate(prompts):
-            want, _ = generate_cached(m, paddle.to_tensor(p[None]),
-                                      max_new_tokens=4,
-                                      decode_strategy="greedy_search",
-                                      weight_only_quant="int4")
-            np.testing.assert_array_equal(out[i], want.numpy()[0])
-
-    def test_gpt_megadecode_matches_split(self):
-        from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny_config
-        paddle.seed(0)
-        c = gpt_tiny_config(max_position_embeddings=64)
-        m = GPTForCausalLM(c)
-        m.eval()
-        rng = np.random.RandomState(23)
-        prompts = [rng.randint(0, c.vocab_size, rng.randint(3, 7))
-                   .astype(np.int32) for _ in range(2)]
-        on, e1 = self._run(m, prompts)
-        off, e2 = self._run(m, prompts, megadecode=False)
-        assert e1.megadecode and not e2.megadecode
-        for i in on:
-            np.testing.assert_array_equal(on[i], off[i])
 
 
 class TestLaunchAccounting:

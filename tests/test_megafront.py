@@ -4,25 +4,19 @@ Interpret-mode parity of fused_qkv_rope_append against its XLA oracle
 (ops/references.py qkv_rope_append_reference) across fp / int8 /
 packed-int4 and the MLA layout — including non-128 dims and
 trash-page sentinel table rows — plus the paged-append seeding
-contract (partial-page walk across launches), the eligibility gate's
-TPU tiling rules, and the engine wiring: megafront vs split-front
-greedy exactness for all four families (fused-on/off and vs solo
-generate_cached, including an all-features trace with prefix cache +
-spec decode + preemption) and the 2-vs-5 front-half launch
-accounting."""
+contract (partial-page walk across launches) and the eligibility
+gate's TPU tiling rules.  Kernel-level only: ServingEngine does not
+call this kernel (ROADMAP D11 deletes it with these tests)."""
 
 import numpy as np
 import pytest
 
 import jax.numpy as jnp
 
-import paddle_tpu as paddle
-from paddle_tpu.generation import generate_cached
 from paddle_tpu.ops.pallas_megafront import (fused_qkv_rope_append,
                                              megafront_eligible)
 from paddle_tpu.ops.quant import weight_quantize
 from paddle_tpu.ops.references import qkv_rope_append_reference
-from paddle_tpu.serving import ServingEngine
 
 
 def _rand(rng, *shape):
@@ -239,193 +233,3 @@ class TestEligibility:
         assert not mf.megafront_eligible(640, 3648, 192)
         # unsharded llama3-8B qkv slab blows the VMEM weight budget
         assert not mf.megafront_eligible(4096, 6144, 128)
-
-
-def _solo(model, prompt, max_new, **kw):
-    out, _ = generate_cached(model, paddle.to_tensor(prompt[None]),
-                             max_new_tokens=max_new,
-                             decode_strategy="greedy_search", **kw)
-    return out.numpy()[0]
-
-
-class TestEngineMegafront:
-    """Engine wiring: default-on fused front half, split-front
-    fallback parity, per-family and quantized exactness vs solo
-    generate_cached, MLA fallbacks, launch accounting."""
-
-    @pytest.fixture(scope="class")
-    def model(self):
-        from paddle_tpu.models.llama import (LlamaForCausalLM,
-                                             llama_tiny_config)
-        paddle.seed(0)
-        m = LlamaForCausalLM(llama_tiny_config(num_hidden_layers=2))
-        m.eval()
-        return m
-
-    def _run(self, model, prompts, max_new=4, **kw):
-        eng = ServingEngine(model, max_slots=2, page_size=4,
-                            prefill_chunk=4, **kw)
-        for i, p in enumerate(prompts):
-            eng.add_request(p, max_new_tokens=max_new, request_id=i)
-        return eng.run_to_completion(), eng
-
-    def test_default_on_and_front_half_launches(self, model):
-        eng = ServingEngine(model, max_slots=2, page_size=4)
-        assert eng.megafront
-        assert eng.front_half_launches == 2
-        # ISSUE 20 acceptance: the whole decode layer body is <=5
-        assert eng.hbm_accounting()["layer_body_launches"] <= 5
-        off = ServingEngine(model, max_slots=2, page_size=4,
-                            megafront=False)
-        assert not off.megafront
-        assert off.front_half_launches == 5
-        assert off.hbm_accounting()["layer_body_launches"] == 8
-
-    def test_megafront_matches_split_front_and_solo(self, model):
-        V = model.config.vocab_size
-        rng = np.random.RandomState(31)
-        prompts = [rng.randint(0, V, rng.randint(3, 9)).astype(np.int32)
-                   for _ in range(3)]
-        on, e1 = self._run(model, prompts)
-        off, e2 = self._run(model, prompts, megafront=False)
-        assert e1.megafront and not e2.megafront
-        assert set(on) == set(off)
-        for i in on:
-            np.testing.assert_array_equal(on[i], off[i])
-        for i, p in enumerate(prompts):
-            np.testing.assert_array_equal(on[i], _solo(model, p, 4))
-        assert all(v == 1 for v in e1.program_cache_sizes().values())
-        assert all(v == 1 for v in e2.program_cache_sizes().values())
-
-    def test_gpt_megafront_matches_split_front(self):
-        from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny_config
-        paddle.seed(0)
-        c = gpt_tiny_config(max_position_embeddings=64)
-        m = GPTForCausalLM(c)
-        m.eval()
-        rng = np.random.RandomState(32)
-        prompts = [rng.randint(0, c.vocab_size, rng.randint(3, 7))
-                   .astype(np.int32) for _ in range(2)]
-        on, e1 = self._run(m, prompts)
-        off, e2 = self._run(m, prompts, megafront=False)
-        assert e1.megafront and not e2.megafront
-        # gpt's native fused-qkv weight needs no deploy concat: the
-        # split front is only 3 launches (norm + qkv dot + rope-append)
-        assert e1.front_half_launches == 2
-        assert e2.front_half_launches == 3
-        for i in on:
-            np.testing.assert_array_equal(on[i], off[i])
-            np.testing.assert_array_equal(on[i], _solo(m, prompts[i], 4))
-
-    def test_moe_megafront_matches_solo(self):
-        from paddle_tpu.models.moe_llm import (MoEForCausalLM,
-                                               qwen2_moe_tiny_config)
-        paddle.seed(0)
-        c = qwen2_moe_tiny_config(moe_dropless=True,
-                                  first_k_dense_replace=1,
-                                  max_position_embeddings=64)
-        m = MoEForCausalLM(c)
-        m.eval()
-        rng = np.random.RandomState(33)
-        prompts = [rng.randint(0, c.vocab_size, rng.randint(3, 9))
-                   .astype(np.int32) for _ in range(2)]
-        out, eng = self._run(m, prompts)
-        assert eng.megafront and eng.front_half_launches == 2
-        for i, p in enumerate(prompts):
-            np.testing.assert_array_equal(out[i], _solo(m, p, 4))
-
-    def test_mla_fused_when_no_q_lora(self):
-        from paddle_tpu.models.deepseek import (DeepSeekV2ForCausalLM,
-                                                deepseek_v2_tiny_config)
-        paddle.seed(0)
-        c = deepseek_v2_tiny_config(moe_dropless=True,
-                                    num_hidden_layers=2,
-                                    q_lora_rank=None)
-        m = DeepSeekV2ForCausalLM(c)
-        m.eval()
-        rng = np.random.RandomState(34)
-        prompts = [rng.randint(0, c.vocab_size, rng.randint(3, 9))
-                   .astype(np.int32) for _ in range(2)]
-        on, e1 = self._run(m, prompts)
-        off, e2 = self._run(m, prompts, megafront=False)
-        assert e1.megafront and e1.front_half_launches == 2
-        assert not e2.megafront
-        for i, p in enumerate(prompts):
-            np.testing.assert_array_equal(on[i], off[i])
-            np.testing.assert_array_equal(on[i], _solo(m, p, 4))
-
-    def test_mla_q_lora_falls_back(self):
-        # the two-stage q compression contracts against an
-        # intermediate normed activation — not the hidden stream — so
-        # the fused front can't absorb it; the gate must fall back
-        from paddle_tpu.models.deepseek import (DeepSeekV2ForCausalLM,
-                                                deepseek_v2_tiny_config)
-        paddle.seed(0)
-        c = deepseek_v2_tiny_config(moe_dropless=True,
-                                    num_hidden_layers=2)
-        m = DeepSeekV2ForCausalLM(c)
-        m.eval()
-        eng = ServingEngine(m, max_slots=2, page_size=4)
-        assert not eng.megafront
-        assert eng.front_half_launches == 7
-        i4 = ServingEngine(m, max_slots=2, page_size=4,
-                           weight_only_quant="int4")
-        assert not i4.megafront      # packed-int4 MLA also splits
-
-    @pytest.mark.parametrize("quant", ["int8", "int4"])
-    def test_quantized_fused_front_exact(self, model, quant):
-        # in-kernel dequant paths: greedy tokens equal the solo
-        # quantized run exactly, fused front on
-        V = model.config.vocab_size
-        rng = np.random.RandomState(35)
-        prompts = [rng.randint(0, V, rng.randint(3, 9)).astype(np.int32)
-                   for _ in range(2)]
-        out, eng = self._run(model, prompts, weight_only_quant=quant)
-        assert eng.megafront and eng.front_half_launches == 2
-        for i, p in enumerate(prompts):
-            np.testing.assert_array_equal(
-                out[i], _solo(model, p, 4, weight_only_quant=quant))
-
-    def test_all_features_trace_exact(self, model):
-        # prefix cache + speculative decoding + oversubscription
-        # (queueing/preemption path): fused-on and fused-off runs both
-        # reproduce the solo greedy stream for every request
-        V = model.config.vocab_size
-        rng = np.random.RandomState(36)
-        base = rng.randint(0, V, 6).astype(np.int32)
-        prompts = [base,                                  # shared
-                   np.concatenate([base, base[:3]]),      # prefix
-                   np.concatenate([base[:4], base[:4]]),  # repetitive
-                   rng.randint(0, V, 5).astype(np.int32),
-                   rng.randint(0, V, 7).astype(np.int32)]
-        kw = dict(max_new=6, spec_decode=3)
-        on, e1 = self._run(model, prompts, **kw)
-        off, e2 = self._run(model, prompts, megafront=False, **kw)
-        assert e1.megafront and not e2.megafront
-        assert e1.prefix_cache is not None and e1.spec_k == 3
-        for i, p in enumerate(prompts):
-            want = _solo(model, p, 6)
-            np.testing.assert_array_equal(on[i], want)
-            np.testing.assert_array_equal(off[i], want)
-        assert all(v == 1 for v in e1.program_cache_sizes().values())
-
-    def test_launch_metric_path_label(self, model):
-        from paddle_tpu import serving as srv
-        V = model.config.vocab_size
-        rng = np.random.RandomState(37)
-        prompts = [rng.randint(0, V, 5).astype(np.int32)]
-        self._run(model, prompts)
-        m = srv.metrics()
-        paths = {s["labels"]["path"]: s["value"]
-                 for s in m["serving.engine.launches"]["series"]}
-        assert paths.get("unified_megafront", 0) >= 1
-
-    def test_accounting_and_scrape_fields(self, model):
-        eng = ServingEngine(model, max_slots=2, page_size=4)
-        acc = eng.hbm_accounting()
-        assert acc["front_half_launches"] == 2
-        assert acc["back_half_launches"] == 2
-        assert acc["layer_body_launches"] == 5
-        snap = eng.scrape()
-        assert "serving.replica.front_half_launches" in snap
-        assert "serving.replica.back_half_launches" in snap

@@ -18,6 +18,7 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.observability import tracing
 from paddle_tpu.serving import ServingEngine
+from paddle_tpu.serving import engine as engine_mod
 from paddle_tpu.serving.scheduler import DECODE
 
 FAMILIES = ("llama", "gpt", "mla")
@@ -50,10 +51,14 @@ def models():
 
 
 def _engine(model, path, **kw):
-    args = dict(max_slots=2, page_size=4, prefill_chunk=4,
-                ragged=(path == "unified"))
+    args = dict(max_slots=2, page_size=4, prefill_chunk=4)
     args.update(kw)
-    return ServingEngine(model, **args)
+    with pytest.MonkeyPatch.context() as mp:
+        # the engine asks once, at construction, whether the ragged
+        # kernel tiles here: the test answers for it
+        mp.setattr(engine_mod, "_ragged_step_eligible",
+                   lambda *a: path == "unified")
+        return ServingEngine(model, **args)
 
 
 def _without_ownership(eng):

@@ -97,7 +97,8 @@ class TestPTQAccuracyGate:
         quantized accuracy within 2 points of the fp32 model's."""
         from paddle_tpu.models.bert import (BertForSequenceClassification,
                                             bert_tiny_config)
-        from tests.test_quality_gates import _sentiment_corpus
+        from tests.test_quality_gate_classification import (
+            _sentiment_corpus)
         paddle.seed(0)
         cfg = bert_tiny_config(vocab_size=64, hidden_size=64,
                                num_hidden_layers=2, num_attention_heads=4,

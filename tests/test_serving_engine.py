@@ -488,90 +488,6 @@ class TestPriorityScheduling:
         assert finish_order == [r1.request_id, r2.request_id]
 
 
-class TestRaggedPath:
-    """The unified ragged dispatch path (PR 7): split-path parity,
-    strictly fewer launches, and int4-MLA exactness."""
-
-    @pytest.fixture(scope="class")
-    def model(self):
-        from paddle_tpu.models.llama import (LlamaForCausalLM,
-                                             llama_tiny_config)
-        paddle.seed(0)
-        m = LlamaForCausalLM(llama_tiny_config(num_hidden_layers=2))
-        m.eval()
-        return m
-
-    def test_split_path_still_exact(self, model):
-        # the legacy alternating prefill/decode path stays the reference
-        V = model.config.vocab_size
-        results, ref, eng = _run_trace(model, V, 4, seed=6, max_slots=2,
-                                       page_size=4, prefill_chunk=4,
-                                       ragged=False)
-        assert not eng.ragged
-        assert set(eng.program_cache_sizes()) == {"decode", "prefill"}
-        for rid in ref:
-            np.testing.assert_array_equal(results[rid], ref[rid])
-        assert all(v == 1 for v in eng.program_cache_sizes().values())
-
-    def test_ragged_matches_split(self, model):
-        V = model.config.vocab_size
-        r1, _, e1 = _run_trace(model, V, 5, seed=7, max_slots=2,
-                               page_size=4, prefill_chunk=4, ragged=True)
-        r2, _, e2 = _run_trace(model, V, 5, seed=7, max_slots=2,
-                               page_size=4, prefill_chunk=4, ragged=False)
-        assert e1.ragged and not e2.ragged
-        assert set(r1) == set(r2)
-        for rid in r1:
-            np.testing.assert_array_equal(r1[rid], r2[rid])
-
-    def test_unified_strictly_fewer_launches(self, model):
-        # a trace with overlapping prefill+decode work: the split path
-        # pays two launches on every such step, the unified path one
-        V = model.config.vocab_size
-        _, _, e1 = _run_trace(model, V, 6, seed=8, max_slots=2,
-                              page_size=4, prefill_chunk=4, ragged=True)
-        _, _, e2 = _run_trace(model, V, 6, seed=8, max_slots=2,
-                              page_size=4, prefill_chunk=4, ragged=False)
-        assert e1.launches < e2.launches
-
-    def test_launches_metric_series(self, model):
-        from paddle_tpu import serving as srv
-        V = model.config.vocab_size
-        _run_trace(model, V, 3, seed=10, max_slots=2, page_size=4,
-                   prefill_chunk=4, ragged=True)
-        m = srv.metrics()
-        paths = {s["labels"]["path"]: s["value"]
-                 for s in m["serving.engine.launches"]["series"]}
-        # the default unified path labels itself by its front half
-        assert paths.get("unified_megafront", 0) >= 1 \
-            or paths.get("unified", 0) >= 1
-
-    def test_mla_int4_seeded_trace(self):
-        # VERDICT item 6 tail: packed-int4 absorbed projections inside
-        # the engine's MLA body exact-match the int4 solo run
-        from paddle_tpu.models.deepseek import (DeepSeekV2ForCausalLM,
-                                                deepseek_v2_tiny_config)
-        paddle.seed(0)
-        c = deepseek_v2_tiny_config(moe_dropless=True,
-                                    num_hidden_layers=2)
-        m = DeepSeekV2ForCausalLM(c)
-        m.eval()
-        rng = np.random.RandomState(12)
-        prompts = [rng.randint(0, c.vocab_size, rng.randint(3, 9))
-                   .astype(np.int32) for _ in range(3)]
-        eng = ServingEngine(m, max_slots=2, page_size=4,
-                            prefill_chunk=4, weight_only_quant="int4")
-        for i, p in enumerate(prompts):
-            eng.add_request(p, max_new_tokens=4, request_id=i)
-        out = eng.run_to_completion()
-        for i, p in enumerate(prompts):
-            want, _ = generate_cached(m, paddle.to_tensor(p[None]),
-                                      max_new_tokens=4,
-                                      decode_strategy="greedy_search",
-                                      weight_only_quant="int4")
-            np.testing.assert_array_equal(out[i], want.numpy()[0])
-
-
 def _run_fleet_trace(model, V, n, seed, roles, **engine_kw):
     """The `_run_trace` join/leave trace driven through a FleetRouter
     over role-split replicas; returns ({rid: result},

@@ -172,7 +172,7 @@ def _rope_append(q, k, v, c, s, kp, vp, pg, off):
 
 
 def test_fused_rope_append_compiles(chip):
-    """The split front half (megafront off / ineligible)."""
+    """The engine's front half: projections, then rope + append."""
     tok = chip.shape((T,), I32)
     trig = chip.shape((T, D // 2), F32)
     assert chip.compiles(
@@ -304,7 +304,7 @@ def test_megadecode_gate_matches_compiler(chip, algo):
 # CPU test cannot see the copy come back; this one can.
 # ---------------------------------------------------------------------------
 
-def _small_engine(family, **paths):
+def _small_engine(family):
     """A ragged engine at toy widths the chip's tiling accepts (heads x
     128, page 16, the smoke's 8 slots + a 32-row chunk), weights in
     bfloat16 as the serving cells hold them."""
@@ -326,7 +326,7 @@ def _small_engine(family, **paths):
     for _, prm in model.named_parameters():
         prm._data = prm._data.astype(jnp.bfloat16)
     return ServingEngine(model, max_slots=8, page_size=16, max_context=128,
-                         prefill_chunk=32, num_pages=65, **paths)
+                         prefill_chunk=32, num_pages=65)
 
 
 def _pool_copies(text, shapes):
@@ -338,14 +338,10 @@ def _pool_copies(text, shapes):
     return [ln.strip() for ln in text.splitlines() if pat.search(ln)]
 
 
-@pytest.mark.parametrize("family,paths,n_shapes", [
-    ("llama", dict(megafront=False, megadecode=False), 1),
-    ("llama", {}, 1),
-    ("window", dict(megafront=False, megadecode=False), 2)],
-    ids=["llama_split_front", "llama_engines_choice", "window_two_pools"])
-def test_unified_step_updates_its_pools_in_place(chip, family, paths,
-                                                 n_shapes):
-    eng = _small_engine(family, **paths)
+@pytest.mark.parametrize("family,n_shapes", [("llama", 1), ("window", 2)],
+                         ids=["llama_engines_choice", "window_two_pools"])
+def test_unified_step_updates_its_pools_in_place(chip, family, n_shapes):
+    eng = _small_engine(family)
     assert eng.ragged
     B, C = eng.max_slots, eng.prefill_chunk
     rows, seqs = chip.shape((B + C,), I32), chip.shape((B + 1,), I32)

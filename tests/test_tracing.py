@@ -400,9 +400,13 @@ class TestStepTimeline:
         return outs
 
     @pytest.mark.parametrize("ragged", [True, False])
-    def test_one_record_per_step_with_counts(self, ragged):
-        eng, cfg = _tiny_engine(ragged=ragged, prefix_sharing=False,
+    def test_one_record_per_step_with_counts(self, ragged, monkeypatch):
+        # the engine asks once whether the ragged kernel tiles here
+        monkeypatch.setattr(srv.engine, "_ragged_step_eligible",
+                            lambda *a: ragged)
+        eng, cfg = _tiny_engine(prefix_sharing=False,
                                 enable_prefix_cache=False)
+        assert eng.ragged is ragged
         outs = self._script(eng, cfg)
         steps = tr.recorder().steps()
         assert [s["seq"] for s in steps] == list(range(1, len(outs) + 1))

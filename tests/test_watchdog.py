@@ -7,6 +7,7 @@ watchdog-off overhead gate."""
 
 import json
 import os
+import threading
 import time
 
 import numpy as np
@@ -417,34 +418,54 @@ def test_chaos_hang_emergency_checkpoint_and_resume(tmp_path, monkeypatch):
 # overhead gate: watchdog off must not tax the collective hot path
 # ---------------------------------------------------------------------------
 class TestOverhead:
-    def test_disabled_overhead_under_5pct(self):
+    def test_disabled_overhead_under_5pct(self, monkeypatch):
+        """With the watchdog off, a collective's two hooks return before
+        they reach the recorder: no record is built and no lock taken.
+        (Until ISSUE 30 this compared two wall-clock loops at 5 %, which
+        failed under six workers; the name stays, the judgement is a
+        count, as in test_observability.py since ISSUE 24.)"""
+        reached = {"start": 0, "finish": 0, "lock": 0, "record": 0}
+
+        def counted(name, fn):
+            def call(*a, **kw):
+                reached[name] += 1
+                return fn(*a, **kw)
+            return call
+
+        class CountingLock:
+            def __init__(self):
+                self._lock = threading.Lock()
+
+            def __enter__(self):
+                reached["lock"] += 1
+                return self._lock.__enter__()
+
+            def __exit__(self, *exc):
+                return self._lock.__exit__(*exc)
+
+        monkeypatch.setattr(wd.FlightRecorder, "start",
+                            counted("start", wd.FlightRecorder.start))
+        monkeypatch.setattr(wd.FlightRecorder, "finish",
+                            counted("finish", wd.FlightRecorder.finish))
+        monkeypatch.setattr(wd.FlightRecord, "__init__",
+                            counted("record", wd.FlightRecord.__init__))
+        wd.recorder()._lock = CountingLock()
+
+        def hooks(n=50):
+            for _ in range(n):
+                wd.end_record(wd.start_record("all_reduce"))
+
         assert not wd.enabled()
-        a = np.random.RandomState(0).randn(160, 160).astype(np.float32)
-        n = 600
-
-        def plain():
-            t0 = time.perf_counter()
-            for _ in range(n):
-                a.dot(a)
-            return time.perf_counter() - t0
-
-        def instrumented():
-            t0 = time.perf_counter()
-            for _ in range(n):
-                a.dot(a)
-                rec = wd.start_record("all_reduce")
-                wd.end_record(rec)
-            return time.perf_counter() - t0
-
-        # warm both paths, then interleave rounds and compare the best
-        # observation of each (min filters scheduler noise)
-        plain()
-        instrumented()
-        tp, ti = [], []
-        for _ in range(7):
-            tp.append(plain())
-            ti.append(instrumented())
-        assert wd.recorder().records() == []    # the gate really gated
-        assert min(ti) < min(tp) * 1.05, (
-            f"disabled-watchdog loop {min(ti):.4f}s vs plain {min(tp):.4f}s "
-            f"(+{(min(ti) / min(tp) - 1) * 100:.1f}%)")
+        hooks()
+        assert reached == {"start": 0, "finish": 0, "lock": 0, "record": 0}
+        assert wd.recorder().records() == []
+        reached["lock"] = 0             # records() itself took it
+        # the same loop with recording on reaches all four: the counters
+        # read what the hooks do
+        wd.set_recording(True)
+        try:
+            hooks()
+        finally:
+            wd.set_recording(False)
+        assert reached == {"start": 50, "finish": 50, "lock": 100,
+                           "record": 50}

@@ -695,272 +695,6 @@ def _serving_engine_row(model, cfg, reqs, max_slots, page_size, rounds):
     return row
 
 
-def bench_serving_engine_ragged(n=16, max_slots=8, page_size=16, rounds=3,
-                                smin=64, smax=513, mmin=32, mmax=257,
-                                seed=0, dtype="bfloat16"):
-    """Unified ragged dispatch vs the legacy split prefill/decode
-    dispatch on the SAME mixed-length trace and engine geometry: the
-    ragged path launches ONE fused program per engine step (the ragged
-    paged-attention kernel covers the prefill chunk and every decode row
-    in a single pallas_call) where the split path launches a prefill
-    program AND a decode program whenever both phases are in flight."""
-    from bench_util import ratio_band
-    from paddle_tpu.serving import ServingEngine
-
-    total = 1024
-    _log(f"serving_engine_ragged: init model n={n} slots={max_slots}")
-    cfg, model = _llama_bench_raw_model(total, dtype)
-    rng = np.random.RandomState(seed)
-    reqs = [(rng.randint(0, cfg.vocab_size,
-                         int(rng.randint(smin, smax))).astype(np.int32),
-             int(rng.randint(mmin, mmax)))
-            for _ in range(n)]
-    engines = {"ragged": ServingEngine(model, max_slots=max_slots,
-                                       page_size=page_size, ragged=True),
-               "split": ServingEngine(model, max_slots=max_slots,
-                                      page_size=page_size, ragged=False)}
-
-    def run(eng):
-        for p, m in reqs:
-            eng.add_request(p, max_new_tokens=m)
-        eng.run_to_completion()
-
-    useful = sum(m for _, m in reqs)
-    launches = {}
-    for name, eng in engines.items():
-        _log(f"serving_engine_ragged: warm {name}")
-        run(eng)                       # compiles the path's programs
-        eng.launches = 0
-        run(eng)
-        launches[name] = eng.launches  # steady-state launches per trace
-    ts = {"ragged": [], "split": []}
-    for _ in range(rounds):            # same-run interleaved A/B
-        for name, eng in engines.items():
-            t0 = time.time()
-            run(eng)
-            ts[name].append(time.time() - t0)
-    return dict(
-        requests=len(reqs), max_slots=max_slots, page_size=page_size,
-        prompt_tokens=int(sum(p.size for p, _ in reqs)),
-        useful_new_tokens=int(useful),
-        ragged_tokens_per_s=round(useful * rounds / sum(ts["ragged"]), 1),
-        split_tokens_per_s=round(useful * rounds / sum(ts["split"]), 1),
-        # per-round split_time/ragged_time: >1 means unified dispatch wins
-        ragged_vs_split=ratio_band(ts["split"], ts["ragged"]),
-        launches_per_trace=launches,
-        programs_compiled={name: eng.program_cache_sizes()
-                           for name, eng in engines.items()},
-        note="same trace, same model, same slots both ways; "
-             "launches_per_trace records the dispatch-count gap the "
-             "fusion removes (the unified step also skips the dead "
-             "launch a phase-empty step would pay). tokens/s counts "
-             "only the requested new tokens. CPU-host numbers are not "
-             "the record — the host-side step loop dominates tiny steps")
-
-
-def bench_megadecode(n=12, max_slots=8, page_size=16, rounds=3,
-                     smin=64, smax=257, mmin=32, mmax=129, seed=0,
-                     dtype="bfloat16", hbm_gb=16):
-    """Mega-kernel fused back half (ISSUE 14) vs the split chain on the
-    SAME ragged trace and engine geometry: megadecode=True runs o-proj
-    + residual + norm + FFN in TWO pallas_calls per layer after
-    attention (fused_oproj_norm -> fused_ffn, 5 launches/layer total
-    with the ISSUE-20 fused front both engines keep, so the A/B
-    isolates the back half); megadecode=False keeps the six-dispatch
-    split back half (8/layer). Also records the int4 density pairing:
-    slots-per-chip at the shard shapes, because int4's recorded win is
-    capacity, not tok/s (see int4_note on the decode_int4 row)."""
-    from bench_util import ratio_band
-    from paddle_tpu.observability import costmodel as cm
-    from paddle_tpu.serving import ServingEngine
-
-    total = 1024
-    _log(f"megadecode: init model n={n} slots={max_slots}")
-    cfg, model = _llama_bench_raw_model(total, dtype)
-    rng = np.random.RandomState(seed)
-    reqs = [(rng.randint(0, cfg.vocab_size,
-                         int(rng.randint(smin, smax))).astype(np.int32),
-             int(rng.randint(mmin, mmax)))
-            for _ in range(n)]
-    engines = {"mega": ServingEngine(model, max_slots=max_slots,
-                                     page_size=page_size, ragged=True),
-               "split_back_half": ServingEngine(
-                   model, max_slots=max_slots, page_size=page_size,
-                   ragged=True, megadecode=False)}
-    assert engines["mega"].megadecode
-    assert not engines["split_back_half"].megadecode
-
-    def run(eng):
-        for p, m in reqs:
-            eng.add_request(p, max_new_tokens=m)
-        eng.run_to_completion()
-
-    useful = sum(m for _, m in reqs)
-    for name, eng in engines.items():
-        _log(f"megadecode: warm {name}")
-        run(eng)                       # compiles the path's programs
-    ts = {name: [] for name in engines}
-    for _ in range(rounds):            # same-run interleaved A/B
-        for name, eng in engines.items():
-            t0 = time.time()
-            run(eng)
-            ts[name].append(time.time() - t0)
-    acct = engines["mega"].hbm_accounting()
-
-    # model-side launch/byte ledger at the engine's own geometry
-    n_layers = cfg.num_hidden_layers
-    w_layer = acct["weights_bytes"] / n_layers
-    kw = dict(batch=max_slots, context=total // 2,
-              hidden=cfg.hidden_size, heads=cfg.num_attention_heads,
-              kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
-              intermediate=cfg.intermediate_size, page_size=page_size,
-              weight_bytes_per_layer=int(w_layer))
-    mega_m = cm.decode_layer_kernels(**kw)
-    split_m = cm.decode_layer_kernels(megadecode=False, **kw)
-
-    def _layer_bytes(d):
-        return sum(c.hbm_bytes * k for k, c in d["kernels"].values())
-
-    # density pairing: KV slots that fit beside the weights on one chip
-    kv_slot = (2 * total * cfg.num_key_value_heads * cfg.head_dim
-               * 2 * n_layers)
-    wb = acct["weights_bytes"]
-    hbm = hbm_gb * 1024 ** 3
-    _, p4 = _llama_bench_model(total, dtype, weight_only_quant="int4")
-    wb4 = _tree_bytes(p4)
-    return dict(
-        requests=len(reqs), max_slots=max_slots, page_size=page_size,
-        useful_new_tokens=int(useful),
-        mega_tokens_per_s=round(useful * rounds / sum(ts["mega"]), 1),
-        split_tokens_per_s=round(
-            useful * rounds / sum(ts["split_back_half"]), 1),
-        # per-round split_time/mega_time: >1 means the fusion wins
-        mega_vs_split=ratio_band(ts["split_back_half"], ts["mega"]),
-        launches_per_layer={"mega": mega_m["launches_per_layer"],
-                            "split": split_m["launches_per_layer"]},
-        back_half_launches={
-            name: eng.back_half_launches
-            for name, eng in engines.items()},
-        model_layer_hbm_bytes={"mega": int(_layer_bytes(mega_m)),
-                               "split": int(_layer_bytes(split_m))},
-        bytes_per_token_measured=round(
-            acct["bytes_per_token_measured"]),
-        bytes_per_token_model=round(acct["bytes_per_token_model"]),
-        int4_slots_per_chip={
-            "weight_bytes_bf16": int(wb),
-            "weight_bytes_int4": int(wb4),
-            "kv_bytes_per_slot": int(kv_slot),
-            "slots_bf16": int(max(0, hbm - wb) // kv_slot),
-            "slots_int4": int(max(0, hbm - wb4) // kv_slot),
-            "note": f"KV slots at {total}-token context beside the "
-                    f"resident weights on a {hbm_gb} GiB chip — int4's "
-                    "win is this density column, not the tok/s column"},
-        note="same trace, same model, same slots both ways; both "
-             "engines keep the ISSUE-20 fused front half, so the A/B "
-             "isolates the back half. launches_per_layer is the "
-             "costmodel ledger at the engine's geometry (5 fused vs 8 "
-             "split back half), back_half_launches the engine's own "
-             "count of pallas_calls after attention (2 vs 6). CPU-host "
-             "tok/s is not the record — the host step loop dominates "
-             "tiny steps; the committed record pairs this row with the "
-             "measured roofline fractions")
-
-
-def bench_front_half(n=12, max_slots=8, page_size=16, rounds=3,
-                     smin=64, smax=257, mmin=32, mmax=129, seed=0,
-                     dtype="bfloat16"):
-    """Megafront fused front half (ISSUE 20) vs the split front on the
-    SAME ragged trace and engine geometry: megafront=True runs
-    norm -> fused_qkv_rope_append in TWO pallas_calls before attention
-    (the single fused launch covers the qkv projection with in-kernel
-    dequant, rope, and the paged K/V append scatter); megafront=False
-    keeps the five-dispatch split front. Both engines keep
-    megadecode=True, so the A/B isolates the front half: layer body 5
-    launches fused vs 8 split. Greedy-output exactness between the two
-    paths is the test-suite contract
-    (tests/test_megafront.py::TestEngineMegafront)."""
-    from bench_util import ratio_band
-    from paddle_tpu.observability import costmodel as cm
-    from paddle_tpu.serving import ServingEngine
-
-    total = 1024
-    _log(f"front_half: init model n={n} slots={max_slots}")
-    cfg, model = _llama_bench_raw_model(total, dtype)
-    rng = np.random.RandomState(seed)
-    reqs = [(rng.randint(0, cfg.vocab_size,
-                         int(rng.randint(smin, smax))).astype(np.int32),
-             int(rng.randint(mmin, mmax)))
-            for _ in range(n)]
-    engines = {"megafront": ServingEngine(model, max_slots=max_slots,
-                                          page_size=page_size,
-                                          ragged=True),
-               "split_front": ServingEngine(
-                   model, max_slots=max_slots, page_size=page_size,
-                   ragged=True, megafront=False)}
-    assert engines["megafront"].megafront
-    assert not engines["split_front"].megafront
-
-    def run(eng):
-        for p, m in reqs:
-            eng.add_request(p, max_new_tokens=m)
-        eng.run_to_completion()
-
-    useful = sum(m for _, m in reqs)
-    for name, eng in engines.items():
-        _log(f"front_half: warm {name}")
-        run(eng)                       # compiles the path's programs
-    ts = {name: [] for name in engines}
-    for _ in range(rounds):            # same-run interleaved A/B
-        for name, eng in engines.items():
-            t0 = time.time()
-            run(eng)
-            ts[name].append(time.time() - t0)
-    acct = engines["megafront"].hbm_accounting()
-
-    # model-side launch ledger at the engine's own geometry
-    n_layers = cfg.num_hidden_layers
-    kw = dict(batch=max_slots, context=total // 2,
-              hidden=cfg.hidden_size, heads=cfg.num_attention_heads,
-              kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
-              intermediate=cfg.intermediate_size, page_size=page_size,
-              weight_bytes_per_layer=int(
-                  acct["weights_bytes"] / n_layers))
-    mega_m = cm.decode_layer_kernels(**kw)
-    split_m = cm.decode_layer_kernels(megafront=False, **kw)
-    return dict(
-        requests=len(reqs), max_slots=max_slots, page_size=page_size,
-        useful_new_tokens=int(useful),
-        fused_tokens_per_s=round(
-            useful * rounds / sum(ts["megafront"]), 1),
-        split_tokens_per_s=round(
-            useful * rounds / sum(ts["split_front"]), 1),
-        # per-round split_time/fused_time: >1 means the fusion wins
-        fused_vs_split=ratio_band(ts["split_front"], ts["megafront"]),
-        launches_per_layer={"megafront": mega_m["launches_per_layer"],
-                            "split_front": split_m["launches_per_layer"]},
-        front_half_launches={
-            name: eng.front_half_launches
-            for name, eng in engines.items()},
-        layer_body_launches={
-            name: eng.front_half_launches + 1 + eng.back_half_launches
-            for name, eng in engines.items()},
-        bytes_per_token_measured=round(
-            acct["bytes_per_token_measured"]),
-        bytes_per_token_model=round(acct["bytes_per_token_model"]),
-        programs_compiled={name: eng.program_cache_sizes()
-                           for name, eng in engines.items()},
-        note="same trace, same model, same slots both ways; both "
-             "engines keep the ISSUE-14 fused back half, so the A/B "
-             "isolates the front half. launches_per_layer is the "
-             "costmodel ledger at the engine's geometry (5 fused vs 8 "
-             "split front), front_half_launches the engine's own count "
-             "of pallas_calls before attention (2 vs 5). The byte "
-             "ledger is fusion-invariant by construction — the fused "
-             "kernel reads the same weight slabs and writes the same "
-             "pages. CPU-host tok/s is not the record — the host step "
-             "loop dominates tiny steps")
-
-
 def bench_serving_engine(n=16, max_slots=8, page_size=16, rounds=3,
                          smin=64, smax=513, mmin=32, mmax=257, seed=0,
                          dtype="bfloat16"):
@@ -1300,9 +1034,6 @@ ROWS = {
     "prefill_8k_llama": lambda: bench_prefill_long("llama"),
     "prefill_8k_mla": lambda: bench_prefill_long("mla"),
     "serving_engine": lambda: bench_serving_engine(),
-    "serving_engine_ragged": lambda: bench_serving_engine_ragged(),
-    "megadecode": lambda: bench_megadecode(),
-    "front_half": lambda: bench_front_half(),
     "prefix_cache_multitenant": lambda: bench_prefix_cache_multitenant(),
     "spec_decode_b1": lambda: bench_spec_decode_b1(),
     "disaggregated": lambda: bench_disaggregated(),
