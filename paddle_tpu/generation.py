@@ -352,6 +352,10 @@ def _mlp_params(lyr, weight_only_int8: bool = False,
             # one chip's share of an expert-parallel layer (and the
             # routed scaling factor): static, like the knobs above
             st.update(held=m.experts_held, scale=m.routed_scale)
+        if m.score != "softmax":
+            st["score"] = m.score
+        if m.route_group is not None:
+            st["group"] = m.route_group
         return dict(moe=mo), st
     d = dict(wg=m.gate_proj.weight._data, wu=m.up_proj.weight._data,
              wd=m.down_proj.weight._data)
@@ -503,6 +507,11 @@ def _mla_decode_params(model, weight_only_int8: bool = False,
              layers=layers, norm=inner.norm.weight._data, head=head,
              cos=inner.rope_cos._data, sin=inner.rope_sin._data,
              moe_static=tuple(moe_static))
+    if cfg.rope_positions < cfg.max_position_embeddings:
+        # the model's own table stops at rope_positions; a serving
+        # engine builds one to the positions it serves
+        p["rope_fn"] = lambda n: dict(zip(("cos", "sin"),
+                                          cfg.rope_table(n)))
     if weight_only_int8 and head is not None:
         _q8(p, "head", True, algo)
         p["head"] = None
@@ -626,7 +635,8 @@ def _ffn_apply(L, h2, st=None, stats=None, live=None):
     MoELayer._dropless exactly so the cached path exact-matches a
     moe_dropless buffer model). ``st`` holds the layer's STATIC routing
     knobs (top_k, renorm; held, scale where the layer is one chip's
-    share of an expert-parallel one) from _mlp_params. A routed layer
+    share of an expert-parallel one; score, group where the router is
+    not a softmax top-k) from _mlp_params. A routed layer
     appends its `moe.routing_stats` to the list ``stats``, counted over
     the rows that ``live`` [B * S] marks (all, if None)."""
     if "moe" not in L:
@@ -642,12 +652,12 @@ def _ffn_apply(L, h2, st=None, stats=None, live=None):
     # sort+grouped-GEMM path (128-row tile padding) and is bitwise-equal
     ffn = dense_expert_ffn if T <= 32 else dropless_expert_ffn
     dt = h2.dtype
-    share = {k: st[k] for k in ("held", "scale") if k in st}
+    share = {k: st[k] for k in ("held", "scale", "group") if k in st}
     # stable scopes in the ops' metadata, for whoever reads a trace
     with jax.named_scope("routed_ffn"):
-        gates = jax.nn.softmax(
-            xt.astype(jnp.float32) @ mo["gate"].astype(jnp.float32),
-            axis=-1)
+        gates = xt.astype(jnp.float32) @ mo["gate"].astype(jnp.float32)
+        gates = jax.nn.sigmoid(gates) if st.get("score") == "sigmoid" \
+            else jax.nn.softmax(gates, axis=-1)
         y, topi = ffn(xt, gates, _dq(mo, "wge", dt),
                       _dq(mo, "wup", dt), _dq(mo, "wdn", dt),
                       top_k=st["top_k"], renormalize=st["renorm"],
@@ -858,7 +868,7 @@ def _mla_cached_step_body(cfg, max_len: int, moe_static=None):
         pos_k = jnp.arange(max_len)
         q_pos = start + jnp.arange(S)
         vis = pos_k[None, :] <= q_pos[:, None]            # [S, max_len]
-        scale = 1.0 / float(np.sqrt(dn + dr))
+        scale = cfg.softmax_scale
         use_fused = False
         if S == 1 and impl != "xla":
             from .ops import pallas_mla

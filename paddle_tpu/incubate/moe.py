@@ -144,15 +144,40 @@ def _held_ids(topi, held):
     return mine, jnp.where(mine, topi - first, count)
 
 
-def _route(gates, top_k: int, renormalize: bool, held, scale: float):
+def _group_limited(gates, n_group: int, topk_group: int):
+    """`gates` [T, E] with every expert outside the `topk_group` best
+    of the `n_group` groups of E / n_group CONSECUTIVE experts set
+    below any score (scores are probabilities or sigmoids, >= 0). A
+    group's score is the sum of its two largest gates (DeepSeek-V3's
+    rule, the one `n_group` / `topk_group` name)."""
+    T, E = gates.shape
+    per = E // n_group
+    best2 = jax.lax.top_k(gates.reshape(T, n_group, per), min(2, per))[0]
+    keep = jax.lax.top_k(jnp.sum(best2, -1), topk_group)[1]  # [T, groups]
+    kept = jnp.any(keep[..., None] == jnp.arange(n_group), 1)
+    return jnp.where(jnp.repeat(kept, per, axis=1), gates, -1.0)
+
+
+def _route(gates, top_k: int, renormalize: bool, held, scale: float,
+           group=None):
     """Top-k routing over ALL experts of `gates` [T, E]: (weights [T, k],
     expert ids [T, k], local ids, held mask). `held = (first, count)`
     names the contiguous experts whose weights this program holds: a
     pair whose expert lies outside gets weight 0 and the local id
     `count` (one past the held groups), so it sorts behind them and
-    meets no expert. `scale` is the routed scaling factor. With
-    `held=None` and scale 1 local ids are the ids and the mask is
-    None: the numerics of the uncut layer, bit for bit."""
+    meets no expert. `scale` is the routed scaling factor. `group =
+    (n_group, topk_group)` limits the choice to the best groups
+    (`_group_limited`); the weights are still the gates' own. With
+    `held=None`, scale 1 and no group local ids are the ids and the
+    mask is None: the numerics of the uncut layer, bit for bit."""
+    if group is not None:
+        n_group, topk_group = group
+        if gates.shape[-1] % n_group or \
+                topk_group * (gates.shape[-1] // n_group) < top_k:
+            raise ValueError(
+                f"{topk_group} of {n_group} groups over "
+                f"{gates.shape[-1]} experts cannot give top-{top_k}")
+        gates = _group_limited(gates, n_group, topk_group)
     topv, topi = jax.lax.top_k(gates, top_k)
     gv = topv
     if renormalize:
@@ -191,14 +216,15 @@ def routing_stats(topi, held, num_experts: int, live=None):
 
 def dense_expert_ffn(xt, gates, wg, wu, wd, *, top_k: int,
                      renormalize: bool, activation: str = "swiglu",
-                     held=None, scale: float = 1.0):
+                     held=None, scale: float = 1.0, group=None):
     """Decode-sized routed FFN: run EVERY (held) expert on every token
     and weighted-select. At serving token counts (T <= ~32) this beats
     the sort+grouped-GEMM path, whose per-expert tiles pad to 128 rows —
     and it is bitwise-identical to it (same per-row matmuls, same
     combine), so the cached-decode exact-match contract is preserved.
-    `held` / `scale`: see `dropless_expert_ffn`."""
-    gv, topi, local, mine = _route(gates, top_k, renormalize, held, scale)
+    `held` / `scale` / `group`: see `dropless_expert_ffn`."""
+    gv, topi, local, mine = _route(gates, top_k, renormalize, held, scale,
+                                   group)
     up = jnp.einsum("th,ehi->eti", xt, wu)
     if activation == "swiglu":
         g = jnp.einsum("th,ehi->eti", xt, wg)
@@ -220,7 +246,7 @@ def dense_expert_ffn(xt, gates, wg, wu, wd, *, top_k: int,
 
 def dropless_expert_ffn(xt, gates, wg, wu, wd, *, top_k: int,
                         renormalize: bool, activation: str = "swiglu",
-                        held=None, scale: float = 1.0):
+                        held=None, scale: float = 1.0, group=None):
     """Per-token top-k routed expert FFN, dropless (megablocks pattern:
     flatten (token, choice) rows, sort by expert, one ragged grouped GEMM,
     unsort, weighted-combine). SINGLE SOURCE OF TRUTH for the routing
@@ -235,10 +261,13 @@ def dropless_expert_ffn(xt, gates, wg, wu, wd, *, top_k: int,
     BEHIND the held groups, where the grouped GEMM owns no row of them
     (rows past the last group cost no tile and come back zero), and
     weigh nothing in the combine. The result is this chip's addend of
-    the layer's routed sum."""
+    the layer's routed sum. `gates` are whatever scores the router
+    gives (a softmax, or sigmoids); `group` limits the choice to the
+    best groups of experts (`_route`)."""
     E = wu.shape[0]
     T = xt.shape[0]
-    gv, topi, local, mine = _route(gates, top_k, renormalize, held, scale)
+    gv, topi, local, mine = _route(gates, top_k, renormalize, held, scale,
+                                   group)
     rows = jnp.repeat(xt, top_k, axis=0)                    # [T*k, H]
     eids = local.reshape(-1)                                # [T*k]
     srt, sizes, inv = sort_by_group(rows, eids,
@@ -273,10 +302,23 @@ class MoELayer(nn.Layer):
                  activation: str = "swiglu", dropless: bool = False,
                  renormalize: bool = True, expert_axis: Optional[str] = None,
                  shared_expert_hidden: int = 0, z_loss_weight: float = 0.0,
-                 name=None, experts_held=None, routed_scale: float = 1.0):
+                 name=None, experts_held=None, routed_scale: float = 1.0,
+                 score: str = "softmax", n_group: int = 1,
+                 topk_group: int = 1):
         super().__init__()
         if activation not in ("swiglu", "gelu"):
             raise ValueError(f"unsupported activation: {activation}")
+        # the router's scores (a softmax over the experts, or one
+        # sigmoid an expert) and the group limit of its top-k (`_route`)
+        if score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unsupported router score: {score}")
+        if (score != "softmax" or n_group > 1) and not dropless:
+            raise NotImplementedError(
+                "sigmoid scores and group-limited routing need "
+                "dropless=True")
+        self.score = score
+        self.route_group = (int(n_group), int(topk_group)) \
+            if n_group > 1 else None
         # one chip's share of an expert-parallel layer: the router
         # covers all `num_experts`, the stacks hold `experts_held =
         # (first, count)` of them (dropless_expert_ffn)
@@ -366,7 +408,8 @@ class MoELayer(nn.Layer):
             xt = xa.reshape(T, shape[-1])
             logits = (xt.astype(jnp.float32)
                       @ gw.astype(jnp.float32))            # [T, E] f32 router
-            gates = jax.nn.softmax(logits, axis=-1)
+            gates = jax.nn.sigmoid(logits) if self.score == "sigmoid" \
+                else jax.nn.softmax(logits, axis=-1)
             if self.dropless:
                 y, aux = self._dropless(xt, logits, gates, wg, wu, wd)
             else:
@@ -399,7 +442,8 @@ class MoELayer(nn.Layer):
                                       renormalize=self.renormalize,
                                       activation=self.activation,
                                       held=self.experts_held,
-                                      scale=self.routed_scale)
+                                      scale=self.routed_scale,
+                                      group=self.route_group)
         mask1 = jax.nn.one_hot(topi[:, 0], E, dtype=gates.dtype)
         return y, load_balance_loss(gates, mask1)
 

@@ -17,6 +17,8 @@ kernel path applies when they match.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -36,9 +38,23 @@ __all__ = ["DeepSeekV2Config", "MLAttention", "DeepSeekV2DecoderLayer",
 
 
 class DeepSeekV2Config(MoEConfig):
+    """MoEConfig + the latent-attention ranks, and what the family's
+    later members add (each default is the V2 behaviour): yarn
+    ``rope_scaling`` (the published group: factor,
+    original_max_position_embeddings, beta_fast / beta_slow, mscale,
+    mscale_all_dim), a router that is not softmax top-k
+    (``scoring_func`` sigmoid, ``n_group`` / ``topk_group``,
+    ``norm_topk_prob``, ``routed_scaling_factor``), one chip's share of
+    an expert-parallel layer (``experts_held = (first, count)``) and
+    ``rope_positions`` (rows of the rotary table the model builds; the
+    published maximum if None)."""
+
     def __init__(self, q_lora_rank=None, kv_lora_rank=512,
                  qk_nope_head_dim=128, qk_rope_head_dim=64,
-                 v_head_dim=128, **kw):
+                 v_head_dim=128, rope_scaling=None,
+                 scoring_func="softmax", n_group=1, topk_group=1,
+                 norm_topk_prob=True, routed_scaling_factor=1.0,
+                 experts_held=None, rope_positions=None, **kw):
         super().__init__(**kw)
         self.q_lora_rank = q_lora_rank
         self.kv_lora_rank = kv_lora_rank
@@ -46,6 +62,57 @@ class DeepSeekV2Config(MoEConfig):
         self.qk_rope_head_dim = qk_rope_head_dim
         self.v_head_dim = v_head_dim
         self.qk_head_dim = qk_nope_head_dim + qk_rope_head_dim
+        if rope_scaling is not None and \
+                rope_scaling.get("type", rope_scaling.get("rope_type")) \
+                != "yarn":
+            raise NotImplementedError(f"rope_scaling {rope_scaling}")
+        self.rope_scaling = rope_scaling
+        self.scoring_func = scoring_func
+        self.n_group, self.topk_group = n_group, topk_group
+        self.norm_topk_prob = norm_topk_prob
+        self.routed_scaling_factor = routed_scaling_factor
+        self.experts_held = tuple(experts_held) if experts_held else None
+        self.rope_positions = min(
+            int(rope_positions or self.max_position_embeddings),
+            self.max_position_embeddings)
+
+    @property
+    def softmax_scale(self) -> float:
+        """The attention scores' scale: qk_head_dim^-1/2, times yarn's
+        mscale^2 where the rope is scaled (m = 0.1 mscale_all_dim
+        ln(factor) + 1, DeepSeek-V2/V3's reading of the key)."""
+        scale = 1.0 / math.sqrt(self.qk_head_dim)
+        rs = self.rope_scaling
+        if rs and rs.get("mscale_all_dim"):
+            m = yarn_mscale(rs["factor"], rs["mscale_all_dim"])
+            scale *= m * m
+        return scale
+
+    def rope_table(self, n: int):
+        """(cos, sin) float32 [n, qk_rope_head_dim / 2]: the default
+        table, or yarn's blended frequencies with cos and sin times
+        mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
+        rs = self.rope_scaling
+        if rs is None:
+            return precompute_rope(self.qk_rope_head_dim, n,
+                                   self.rope_theta)
+        from .laguna import rope_table
+        af = yarn_mscale(rs["factor"], rs.get("mscale", 1)) \
+            / yarn_mscale(rs["factor"], rs.get("mscale_all_dim", 0))
+        return rope_table(
+            dict(rope_type="yarn", rope_theta=self.rope_theta,
+                 factor=rs["factor"], attention_factor=af,
+                 original_max_position_embeddings=rs[
+                     "original_max_position_embeddings"],
+                 beta_fast=rs.get("beta_fast", 32),
+                 beta_slow=rs.get("beta_slow", 1)),
+            self.qk_rope_head_dim, n)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    if factor <= 1 or not mscale:
+        return 1.0
+    return 0.1 * float(mscale) * math.log(float(factor)) + 1.0
 
 
 def deepseek_v2_tiny_config(**kw) -> DeepSeekV2Config:
@@ -139,7 +206,7 @@ class MLAttention(nn.Layer):
             kh = jnp.concatenate([k_nope, k_pe], -1)
 
             if c.use_flash_attention and mask is None:
-                if dv == dn + dr:
+                if dv == dn + dr and c.rope_scaling is None:
                     from ..ops.flash_attention import sdpa
                     o = sdpa(qh, kh, v, causal=True)
                 else:
@@ -149,11 +216,10 @@ class MLAttention(nn.Layer):
                     # long-context prefill on [B,nh,S,S] f32 scores
                     from ..ops.flash_attention import sdpa_padded_heads
                     o = sdpa_padded_heads(
-                        qh, kh, v, causal=True,
-                        scale=float(dn + dr) ** -0.5)
+                        qh, kh, v, causal=True, scale=c.softmax_scale)
             else:
-                scale = 1.0 / float(jnp.sqrt(jnp.float32(dn + dr)))
-                scores = jnp.einsum("bsnd,btnd->bnst", qh, kh) * scale
+                scores = jnp.einsum("bsnd,btnd->bnst", qh, kh) \
+                    * c.softmax_scale
                 scores = scores.astype(jnp.float32)
                 causal = jnp.tril(jnp.ones((S, S), bool))
                 neg = jnp.asarray(-1e30, scores.dtype)
@@ -195,7 +261,12 @@ class DeepSeekV2DecoderLayer(nn.Layer):
                 top_k=c.top_k, capacity_factor=c.capacity_factor,
                 activation="swiglu", dropless=c.moe_dropless,
                 shared_expert_hidden=c.shared_expert_intermediate_size,
-                z_loss_weight=c.router_z_loss_weight)
+                z_loss_weight=c.router_z_loss_weight,
+                renormalize=c.norm_topk_prob,
+                experts_held=c.experts_held,
+                routed_scale=c.routed_scaling_factor,
+                score=c.scoring_func, n_group=c.n_group,
+                topk_group=c.topk_group)
 
     def forward(self, x, cos, sin, attn_mask=None):
         h = x + self.self_attn(self.input_layernorm(x), cos, sin, attn_mask)
@@ -209,16 +280,16 @@ class DeepSeekV2Model(nn.Layer):
         init = I.Normal(0.0, config.initializer_range)
         self.embed_tokens = nn.Embedding(config.vocab_size,
                                          config.hidden_size)
-        self.embed_tokens.weight._data = init(
-            [config.vocab_size, config.hidden_size], "float32")
+        from ..framework.lazy import lazy_enabled
+        if not lazy_enabled():      # else the caller binds the weights
+            self.embed_tokens.weight._data = init(
+                [config.vocab_size, config.hidden_size], "float32")
         self.embed_tokens.weight._sharding_spec = P(MP_AXIS, None)
         self.layers = nn.LayerList(
             [DeepSeekV2DecoderLayer(config, i)
              for i in range(config.num_hidden_layers)])
         self.norm = nn.RMSNorm(config.hidden_size, config.rms_norm_eps)
-        cos, sin = precompute_rope(config.qk_rope_head_dim,
-                                   config.max_position_embeddings,
-                                   config.rope_theta)
+        cos, sin = config.rope_table(config.rope_positions)
         self.register_buffer("rope_cos", Tensor(cos), persistable=False)
         self.register_buffer("rope_sin", Tensor(sin), persistable=False)
 

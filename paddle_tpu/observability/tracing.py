@@ -61,7 +61,7 @@ from . import DEFAULT_BUCKETS, Histogram, _new_span_id, registry
 __all__ = ["TraceEvent", "RequestTrace", "TraceRecorder", "recorder",
            "enabled", "set_enabled", "percentile", "percentiles",
            "slo_summary", "SLO_METRICS", "STEP_COUNTS", "STEPS_PER_SLOT",
-           "STEP_COUNTS_BY_KIND", "STEP_COUNTS_MOE"]
+           "STEP_COUNTS_BY_KIND", "STEP_COUNTS_MOE", "STEP_COUNTS_LATENT"]
 
 _FLAG = _flags._registry["FLAGS_request_tracing"]
 
@@ -124,6 +124,11 @@ STEP_COUNTS_BY_KIND: Tuple[str, ...] = (
 STEP_COUNTS_MOE: Tuple[str, ...] = (
     "moe_pairs_routed", "moe_pairs_held", "moe_expert_rows_max",
     "moe_expert_rows_mean", "moe_experts_hit")
+#: ... and where the cache holds latent attention's rows: the prefill
+#: chunk's KV length after the step (0 without a chunk: what the chunk's
+#: query tiles each walk), and the bytes a token a layer as STORED (the
+#: row's lanes, padding included)
+STEP_COUNTS_LATENT: Tuple[str, ...] = ("chunk_kv_len", "latent_row_bytes")
 #: step records kept for each slot of the request ring. A request lives
 #: through tens to hundreds of steps, and whoever reads a whole measured
 #: window from the records (`benchmarks/lib/program_spans.py`: 50-56 s

@@ -81,8 +81,13 @@ def _interpret() -> bool:
 def ragged_kernel_eligible(H: int, KV: int, D: int,
                            page_size: int) -> bool:
     """Same tiling constraints as the decode kernel: the [rows, D] query
-    group wants MXU-friendly D; any page_size >= 8 works (masks handle
-    partial pages and ragged chunk tails)."""
+    group wants MXU-friendly D (64, or a multiple of 128); any page_size
+    >= 8 works (masks handle partial pages and ragged chunk tails). D is
+    the row AS STORED: latent attention's 512 + 64 values are refused
+    at 576 and taken at the 640 columns the engine stores them in
+    (`serving.engine._latent_row_width`), so every family the engine
+    serves takes the unified step on a TPU at published widths; what is
+    refused today is toy presets."""
     return paged_kernel_eligible(H, KV, D, page_size)
 
 
@@ -185,6 +190,17 @@ def _tile_map(h, t, ss, nt, kvl, tab, tile_first, pair_seq, pair_first):
     return (h, t, 0)
 
 
+def _latent_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
+                   first_ref, pseq_ref, pfirst_ref,
+                   q_ref, k_hbm, o_ref,
+                   kbuf, acc_ref, m_ref, l_ref, ahead_ref, sem, **static):
+    """The launch whose pages hold K and V in ONE row (latent
+    attention): no V pool, no V buffers, one DMA a page."""
+    _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref, first_ref, pseq_ref,
+                   pfirst_ref, q_ref, k_hbm, None, o_ref, kbuf, None,
+                   acc_ref, m_ref, l_ref, ahead_ref, sem, **static)
+
+
 def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
                    first_ref, pseq_ref, pfirst_ref,
                    q_ref, k_hbm, v_hbm, o_ref,
@@ -205,9 +221,12 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
         if window is not None:
             j = j + pseq_ref[pairs + pi]
         phys = jnp.clip(tab_ref[pseq_ref[pi], j], 0, total_pages - 1)
+        k_dma = pltpu.make_async_copy(k_hbm.at[h, phys], kbuf.at[slot],
+                                      sem.at[0, slot])
+        if v_hbm is None:       # V is the page's first columns
+            return slot, (k_dma,)
         return slot, (
-            pltpu.make_async_copy(k_hbm.at[h, phys], kbuf.at[slot],
-                                  sem.at[0, slot]),
+            k_dma,
             pltpu.make_async_copy(v_hbm.at[h, phys], vbuf.at[slot],
                                   sem.at[1, slot]))
 
@@ -260,7 +279,7 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
             for dma in dmas:
                 dma.wait()
             k = kbuf[slot]                               # [psz, D]
-            v = vbuf[slot]
+            v = k[:, :acc_ref.shape[1]] if vbuf is None else vbuf[slot]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
@@ -298,7 +317,8 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
 def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
                            kv_lengths, page_tables,
                            scale: Optional[float] = None,
-                           window: Optional[int] = None):
+                           window: Optional[int] = None,
+                           v_dim: Optional[int] = None):
     """q [T, H, D] flat new-token buffer; k/v_pages [KV, total_pages,
     page_size, D]; seq_start/num_tokens/kv_lengths [S] int32;
     page_tables [S, pages_per_seq] int32. Sequences own DISJOINT row
@@ -306,6 +326,11 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     non-decreasing in every caller (the work list is built tile-major
     from the ranges themselves and does not lean on the order). Rows
     covered by no sequence return zeros. Returns [T, H, D].
+
+    `v_pages=None` with `v_dim` (static, a multiple of 128 on a TPU) is
+    latent attention's cache: a row of `k_pages` is the key whole and
+    the value in its first `v_dim` columns, so a page is fetched ONCE
+    and serves both matmuls. Returns [T, H, v_dim].
 
     `window` (static) is a sliding window: the query at position i sees
     keys j with i - window < j <= i. Pages wholly below a tile's oldest
@@ -324,10 +349,14 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     if scale is None:
         scale = D ** -0.5
     interpret = _interpret()
+    if (v_pages is None) != (v_dim is not None):
+        raise ValueError("give v_pages, or v_dim for rows that hold K "
+                         "and V together; not both, not neither")
     if not interpret and not isinstance(q, jax.core.Tracer):
         # the operands' memory-space pins below exist only under a trace
         return jax.jit(functools.partial(
-            ragged_paged_attention, scale=scale, window=window))(
+            ragged_paged_attention, scale=scale, window=window,
+            v_dim=v_dim))(
                 q, k_pages, v_pages, seq_start, num_tokens, kv_lengths,
                 page_tables)
     tq = ragged_tile_tokens(T, rep, q.dtype)
@@ -345,6 +374,13 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     qg = (jnp.pad(q, ((0, Tp - T), (0, 0), (0, 0)))
           .reshape(Tp, KV, rep, D).transpose(1, 0, 2, 3)
           .reshape(KV, Tp * rep, D))
+    tables = (ss, nt, kvl, page_tables.astype(jnp.int32), *work)
+    static = dict(page_size=psz, rep=rep, tq=tq, total_pages=total,
+                  scale=float(scale), window=window)
+    if v_pages is None:
+        out = _latent_call(qg, k_pages, tables, v_dim, rows, depth,
+                           n_tiles, interpret, **static)
+        return _ungroup(out, T, H)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,      # row tables, page tables, work list
@@ -366,27 +402,64 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     # the tile axis is sequential: a head's page DMAs run ahead from one
     # tile into the next
     out = pl.pallas_call(
-        functools.partial(_ragged_kernel, page_size=psz, rep=rep, tq=tq,
-                          total_pages=total, scale=float(scale),
-                          window=window),
+        functools.partial(_ragged_kernel, **static),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((KV, Tp * rep, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*(x if interpret else _in_hbm(x) for x in (
-        ss, nt, kvl, page_tables.astype(jnp.int32), *work,
-        qg, k_pages, v_pages)))
-    return (out.reshape(KV, Tp, rep, D).transpose(1, 0, 2, 3)
-            .reshape(Tp, H, D)[:T])
+        *tables, qg, k_pages, v_pages)))
+    return _ungroup(out, T, H)
+
+
+def _ungroup(out, T: int, H: int):
+    """[KV, Tp*rep, Dv] (a KV head's flat query group) -> [T, H, Dv]."""
+    KV, flat, Dv = out.shape
+    Tp = flat * KV // H
+    return (out.reshape(KV, Tp, H // KV, Dv).transpose(1, 0, 2, 3)
+            .reshape(Tp, H, Dv)[:T])
+
+
+def _latent_call(qg, pages, tables, v_dim, rows, depth, n_tiles, interpret,
+                 **static):
+    """`ragged_paged_attention`'s launch for pages that hold K and V in
+    one row: the same grid, work list and tile maps; one pool operand,
+    one ring of page buffers, a [rows, v_dim] accumulator and output."""
+    KV, flat, D = qg.shape
+    psz = pages.shape[2]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=7,
+        grid=(KV, n_tiles),
+        in_specs=[pl.BlockSpec((1, rows, D), _tile_map),
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=pl.BlockSpec((1, rows, v_dim), _tile_map),
+        scratch_shapes=[pltpu.VMEM((depth, psz, D), pages.dtype),
+                        pltpu.VMEM((rows, v_dim), jnp.float32),
+                        pltpu.VMEM((rows, 1), jnp.float32),
+                        pltpu.VMEM((rows, 1), jnp.float32),
+                        pltpu.SMEM((2,), jnp.int32),
+                        pltpu.SemaphoreType.DMA((1, depth))],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, **static),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((KV, flat, v_dim), qg.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(*(x if interpret else _in_hbm(x) for x in (*tables, qg, pages)))
 
 
 def ragged_attention_reference(q, k_pages, v_pages, seq_start,
                                num_tokens, kv_lengths, page_tables,
                                scale: Optional[float] = None,
-                               window: Optional[int] = None):
+                               window: Optional[int] = None,
+                               v_dim: Optional[int] = None):
     """Plain-XLA oracle with the same ragged semantics (full-softmax,
     gathered pages, jnp.repeat GQA — everything the kernel avoids)."""
+    if v_pages is None:
+        v_pages = k_pages[..., :v_dim]
     T, H, D = q.shape
     KV, total, psz, _ = k_pages.shape
     rep = H // KV
@@ -399,7 +472,7 @@ def ragged_attention_reference(q, k_pages, v_pages, seq_start,
     tabs = jnp.clip(page_tables.astype(jnp.int32), 0, total - 1)
     Tk = nj * psz
     ks = k_pages[:, tabs].transpose(1, 0, 2, 3, 4).reshape(S, KV, Tk, D)
-    vs = v_pages[:, tabs].transpose(1, 0, 2, 3, 4).reshape(S, KV, Tk, D)
+    vs = v_pages[:, tabs].transpose(1, 0, 2, 3, 4).reshape(S, KV, Tk, -1)
     kr = jnp.repeat(ks, rep, axis=1)                      # [S, H, Tk, D]
     vr = jnp.repeat(vs, rep, axis=1)
     logits = jnp.einsum("thd,shld->shtl", q.astype(jnp.float32),

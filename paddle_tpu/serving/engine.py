@@ -20,12 +20,18 @@ The engine chooses its programs itself, once, at construction
   ONE device program instead of two (`serving.engine.launches` counts
   the difference). Per-sequence row tables (seq_start / num_tokens /
   kv_lengths / page table) make joins and leaves pure data changes.
+  Every family takes it on a TPU at published widths: llama / MoE /
+  Laguna / GPT heads of 64 or a multiple of 128, and latent attention
+  (MLA) whose latent rank is a multiple of 128 — its cache row
+  (latent | rope key) is stored padded to whole 128-lane registers
+  (`_latent_row_width`: 512 + 64 -> 640), K is the row, V its first
+  `kv_lora_rank` columns, one page fetch for both.
 - **split**: the PR-5 alternating `_prefill_chunk` / `_decode`
   dispatches over `paged_attention`/`append_to_cache`, built only
   where the ragged kernel's tiling constraints do not hold on a TPU
-  (`ragged_kernel_eligible`: today every MLA model's 576-wide cache
-  row). It leaves when latent attention goes through the ragged
-  kernel (ROADMAP R1).
+  (`ragged_kernel_eligible`): head sizes that are neither 64 nor a
+  multiple of 128, which today means toy presets on a chip
+  (`chip_smoke.py` runs none). No benchmark cell builds it.
 
 Inactive slots point their whole page table at the allocator's trash
 page 0 with length/num_tokens 0: both paths write their (garbage) K/V
@@ -38,7 +44,6 @@ greedy property; sampling strategies belong to the batch APIs.
 
 from __future__ import annotations
 
-import math
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -142,6 +147,29 @@ def _ragged_step_eligible(heads, kv: int, d: int, page_size: int) -> bool:
                    for h in heads))
 
 
+def _latent_row_width(r: int, dr: int) -> int:
+    """Columns of latent attention's cache row AS STORED: the latent
+    [r] and the shared rope key [dr], then zeros up to whole 128-lane
+    registers where the latent itself is lane-aligned (the published
+    512 + 64 -> 640). A 576-wide minor dimension has no row-major
+    tiled layout without padding: XLA lays such a pool out transposed
+    and a Mosaic call then copies the WHOLE pool to relayout it, every
+    layer. Padding once, in the shape, keeps the pool in place, every
+    DMA and lane slice aligned, and costs 64 dead columns a token
+    (`latent_row_bytes` says what is stored). Toy ranks (r % 128 != 0)
+    run interpreted only and keep r + dr."""
+    w = r + dr
+    return w if r % 128 else -(-w // 128) * 128
+
+
+def _pad_lanes(x, width: int):
+    """x [..., w] with zero columns up to `width`."""
+    pad = width - x.shape[-1]
+    if not pad:
+        return x
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
+
+
 # -- the step's entry and exit, shared by the jitted bodies ------------
 def _seq_starts(B: int, R: int):
     """[B + 1] baked row starts of the unified step: decode slot s owns
@@ -160,6 +188,23 @@ def _logit_rows(x, seq_start, num_tokens, K: int):
     if K:
         return x[0]
     return x[0, jnp.clip(seq_start + num_tokens - 1, 0, x.shape[1] - 1)]
+
+
+def _owned_rows(T: int, seq_start, num_tokens):
+    """[T] bool: the rows of the flat buffer that a sequence owns this
+    step. The routed layers' counts leave the idle rows out."""
+    row = jnp.arange(T, dtype=jnp.int32)[:, None]
+    return jnp.any((row >= seq_start) & (row < seq_start + num_tokens), -1)
+
+
+def _moe_step_counts(moe_stats):
+    """One [5] array beside the logits (STEP_COUNTS_MOE) from the
+    routed layers' `routing_stats`: pairs routed and held summed over
+    the layers, the fullest expert's rows, the mean rows a held
+    expert, experts hit summed."""
+    ms = jnp.stack(moe_stats)
+    return jnp.stack([ms[:, 0].sum(), ms[:, 1].sum(), ms[:, 2].max(),
+                      ms[:, 3].mean(), ms[:, 4].sum()])
 
 
 def _head_logits(w, last):
@@ -329,7 +374,8 @@ class ServingEngine:
         if self._family == "gpt":
             kv, d = cfg.num_attention_heads, cfg.head_dim
         elif self._family == "mla":
-            kv, d = 1, cfg.kv_lora_rank + cfg.qk_rope_head_dim
+            kv, d = 1, _latent_row_width(cfg.kv_lora_rank,
+                                         cfg.qk_rope_head_dim)
         else:
             kv, d = cfg.num_key_value_heads, cfg.head_dim
         shape = (kv, self.num_pages, self.page_size, d)
@@ -346,8 +392,8 @@ class ServingEngine:
                 self._attn_static, self._layer_kind) if kk == k})
             for k in set(self._layer_kind)}
         if self._family == "mla":
-            # one pool per layer: each row is [latent | rope-key], read
-            # as both K and V by the concat-dot absorbed decode
+            # one pool per layer: each row is [latent | rope-key | pad],
+            # K whole and V in its first kv_lora_rank columns
             self._pools = [jnp.zeros(shape, dt) for _ in range(n_layers)]
         else:
             self._pools = [(jnp.zeros(sh, dt), jnp.zeros(sh, dt))
@@ -384,6 +430,8 @@ class ServingEngine:
             self._count_names += _tracing.STEP_COUNTS_BY_KIND
         if any(st and "held" in st for st in p.get("moe_static") or ()):
             self._count_names += _tracing.STEP_COUNTS_MOE
+        if self._family == "mla":
+            self._count_names += _tracing.STEP_COUNTS_LATENT
         self._counts = dict.fromkeys(self._count_names, 0)
         # the pool handles this step's launches were handed (dead
         # arrays, no buffers): `pools_in_place` asks them at account
@@ -473,6 +521,18 @@ class ServingEngine:
                                         donate_argnums=2)
             self._programs = {"decode": self._jit_decode,
                               "prefill": self._jit_prefill}
+        # the copy-on-write program (`_apply_copies`): as many pairs as
+        # one sequence's new rows of one step can touch shared pages,
+        # compiled and run ONCE here (trash page onto itself) so that
+        # no copy ever compiles inside a serving loop
+        rows = max(self.prefill_chunk, 1 + self.spec_k)
+        self._copy_slots = -(-rows // self.page_size) + 1
+        self._jit_copy = jax.jit(
+            lambda pools, src, dst: jax.tree_util.tree_map(
+                lambda p: p.at[:, dst].set(p[:, src]), pools),
+            donate_argnums=0)
+        idle = np.zeros(self._copy_slots, np.int32)
+        self._pools = self._jit_copy(self._live_pools(), idle, idle)
 
     def _live_pools(self):
         """The page pools, for whoever reads or replaces them between
@@ -1326,6 +1386,10 @@ class ServingEngine:
                 wtok_page[base:base + n] = wt[(start + rows) // ps]
         self._counts["decode_rows"] = int(num_tokens[:B].sum())
         self._counts["prefill_rows"] = n
+        if self._family == "mla":
+            self._counts["chunk_kv_len"] = int(kv_lengths[S - 1])
+            self._counts["latent_row_bytes"] = \
+                self._kv_geom[1] * self._kv_itemsize
         # pages that hold this launch's tokens, against the K/V page
         # fetches the ragged kernel makes for each KV head (a sequence's
         # pages once for every query tile that holds rows of it)
@@ -1453,7 +1517,12 @@ class ServingEngine:
 
     def _apply_copies(self, copies, req: Optional[Request] = None) -> None:
         """Apply the allocator's copy-on-write page copies to the device
-        pools before the write that triggered them."""
+        pools before the write that triggered them: the ONE fixed-shape
+        program `_build_programs` built and ran once (`_jit_copy`), so
+        a copy compiles nothing when it comes — a shared first token of
+        two random prompts is enough to bring one — `_copy_slots`
+        (source, destination) pairs a call, padded with the trash page
+        onto itself."""
         if not copies:
             return
         # (a copied page is a shared page: never under a window, whose
@@ -1461,15 +1530,12 @@ class ServingEngine:
         self._counts["cow_pages"] += len(copies)
         if req is not None:
             _TRACE.stamp(req.request_id, "cow", pages=len(copies))
-        src = np.asarray([c[0] for c in copies])
-        dst = np.asarray([c[1] for c in copies])
-        if self._family == "mla":
-            self._pools = [pool.at[:, dst].set(pool[:, src])
-                           for pool in self._live_pools()]
-        else:
-            self._pools = [(kp.at[:, dst].set(kp[:, src]),
-                            vp.at[:, dst].set(vp[:, src]))
-                           for kp, vp in self._live_pools()]
+        n = self._copy_slots
+        for i in range(0, len(copies), n):
+            pairs = np.zeros((2, n), np.int32)
+            part = np.asarray(copies[i:i + n], np.int32).T
+            pairs[:, :part.shape[1]] = part
+            self._pools = self._jit_copy(self._live_pools(), *pairs)
 
     # ----------------------------------------------------- jitted bodies
     def _make_decode_body(self):
@@ -1532,11 +1598,8 @@ class ServingEngine:
                 tables, tok_page = (tables,), (tok_page,)
             new_pools = []
             moe_stats = [] if count_moe else None
-            # the rows a sequence owns this step: the routed layers'
-            # counts leave the idle rows of the flat buffer out
-            row = jnp.arange(T, dtype=jnp.int32)[:, None]
-            live = jnp.any((row >= seq_start) & (
-                row < seq_start + num_tokens), -1) if count_moe else None
+            live = _owned_rows(T, seq_start, num_tokens) \
+                if count_moe else None
             sts = moe_static or (None,) * len(w["layers"])
             for L, (kp, vp), st, ast in zip(w["layers"], pools, sts,
                                             attn_static):
@@ -1570,14 +1633,7 @@ class ServingEngine:
             logits = _head_logits(
                 w, _logit_rows(x, seq_start, num_tokens, K))
             if moe_stats:
-                # one [5] array beside the logits: pairs routed and held
-                # summed over the routed layers, the fullest expert's
-                # rows, the mean rows a held expert, experts hit summed
-                # (STEP_COUNTS_MOE)
-                ms = jnp.stack(moe_stats)
-                return logits, new_pools, jnp.stack([
-                    ms[:, 0].sum(), ms[:, 1].sum(), ms[:, 2].max(),
-                    ms[:, 3].mean(), ms[:, 4].sum()])
+                return logits, new_pools, _moe_step_counts(moe_stats)
             return logits, new_pools
 
         return step
@@ -1624,14 +1680,22 @@ class ServingEngine:
         return step
 
     def _mla_unified_body(self):
+        """Latent attention in the ABSORBED form on the one chain: the
+        cache row is (RMSNorm(latent) | RoPE(k_pe) | pad), the query of
+        head a is (q_nope_a W_kvb^K_a | RoPE(q_pe_a) | 0), the kernel's
+        output the weighted sum of the rows' latent columns, and
+        W_kvb^V_a comes after. The prefill chunk rides the same form as
+        the decode rows."""
         cfg = self._p["cfg"]
         nh = cfg.num_attention_heads
         dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
         r = cfg.kv_lora_rank
+        width = self._kv_geom[1]
         eps = cfg.rms_norm_eps
-        scale = 1.0 / float(math.sqrt(dn + dr))
+        scale = cfg.softmax_scale       # yarn's mscale^2 included
         moe_static = self._p.get("moe_static")
+        count_moe = _tracing.STEP_COUNTS_MOE[0] in self._count_names
         B, C, K = self.max_slots, self.prefill_chunk, self.spec_k
         R = 1 + K
         T = B * R + C
@@ -1652,41 +1716,55 @@ class ServingEngine:
                     [t1 * cc - t2 * ss, t2 * cc + t1 * ss], -1)
 
             new_pools = []
+            moe_stats = [] if count_moe else None
+            live = _owned_rows(T, seq_start, num_tokens) \
+                if count_moe else None
             sts = moe_static or (None,) * len(w["layers"])
             for L, pool, st in zip(w["layers"], pools, sts):
                 h = fused_rms_norm(x, L["ln1"], eps)
                 wkb = _dq(L, "wkvb", x.dtype).reshape(r, nh, dn + dv)
                 w_k, w_v = wkb[..., :dn], wkb[..., dn:]
-                if "wqa" in L or "wqa_q" in L or "wqa_q4" in L:
-                    q = _mm_w(fused_rms_norm(_mm_w(h, L, "wqa"),
-                                             L["gq"], eps),
-                              L, "wqb")
-                else:
-                    q = _mm_w(h, L, "wq")
-                q = q.reshape(1, T, nh, dn + dr)
-                q_nope, q_pe = q[..., :dn], q[..., dn:]
-                # rope runs on the split q_pe/k_pe shapes (not D-halved
-                # cache rows), so the append is the row-scatter kernel
-                q_pe = rope(q_pe)
-                kv_a = _mm_w(h, L, "wkva")               # [1, T, r+dr]
-                lat = fused_rms_norm(kv_a[..., :r], L["gkv"], eps)
-                k_pe = rope(kv_a[..., r:][:, :, None, :])[:, :, 0]
-                rows = jnp.concatenate([lat, k_pe], -1)[0][:, None]
-                pool = fused_append_rows(pool, rows, tok_page, tok_off)
-                q_eff = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_k)
-                q_cat = jnp.concatenate([q_eff, q_pe], -1)[0]
+                with jax.named_scope("mla_q"):
+                    if "wqa" in L or "wqa_q" in L or "wqa_q4" in L:
+                        q = _mm_w(fused_rms_norm(_mm_w(h, L, "wqa"),
+                                                 L["gq"], eps),
+                                  L, "wqb")
+                    else:
+                        q = _mm_w(h, L, "wq")
+                    q = q.reshape(1, T, nh, dn + dr)
+                    q_nope, q_pe = q[..., :dn], q[..., dn:]
+                    # rope runs on the split q_pe/k_pe shapes (not
+                    # D-halved cache rows), so the append is the
+                    # row-scatter kernel
+                    q_pe = rope(q_pe)
+                    q_eff = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_k)
+                    q_cat = _pad_lanes(
+                        jnp.concatenate([q_eff, q_pe], -1)[0], width)
+                with jax.named_scope("mla_kv"):
+                    kv_a = _mm_w(h, L, "wkva")           # [1, T, r+dr]
+                    lat = fused_rms_norm(kv_a[..., :r], L["gkv"], eps)
+                    k_pe = rope(kv_a[..., r:][:, :, None, :])[:, :, 0]
+                    rows = _pad_lanes(
+                        jnp.concatenate([lat, k_pe], -1)[0], width)
+                    pool = fused_append_rows(pool, rows[:, None],
+                                             tok_page, tok_off)
                 new_pools.append(pool)
-                o_cat = ragged_paged_attention(q_cat, pool, pool,
-                                               seq_start, num_tokens,
-                                               kv_lengths, tables,
-                                               scale=scale)
-                o = jnp.einsum("tnr,rnv->tnv", o_cat[..., :r], w_v)
-                x = x + _mm_w(o.reshape(1, T, nh * dv), L, "wo")
+                with jax.named_scope("mla_attention"):
+                    # K is the row, V its latent columns: one page
+                    # fetch serves both matmuls
+                    o_lat = ragged_paged_attention(
+                        q_cat, pool, None, seq_start, num_tokens,
+                        kv_lengths, tables, scale=scale, v_dim=r)
+                with jax.named_scope("mla_out"):
+                    o = jnp.einsum("tnr,rnv->tnv", o_lat, w_v)
+                    x = x + _mm_w(o.reshape(1, T, nh * dv), L, "wo")
                 h2 = fused_rms_norm(x, L["ln2"], eps)
-                x = x + _ffn_apply(L, h2, st)
+                x = x + _ffn_apply(L, h2, st, moe_stats, live)
             x = fused_rms_norm(x, w["norm"], eps)
             logits = _head_logits(
                 w, _logit_rows(x, seq_start, num_tokens, K))
+            if moe_stats:
+                return logits, new_pools, _moe_step_counts(moe_stats)
             return logits, new_pools
 
         return step
@@ -1939,7 +2017,8 @@ class ServingEngine:
                       cfg.v_head_dim)
         r = cfg.kv_lora_rank
         eps = cfg.rms_norm_eps
-        scale = 1.0 / float(math.sqrt(dn + dr))
+        width = self._kv_geom[1]
+        scale = cfg.softmax_scale
         moe_static = self._p.get("moe_static")
         from ..flags import flag, flags_guard
         paged_impl = flag("FLAGS_paged_impl")
@@ -1979,7 +2058,8 @@ class ServingEngine:
                     kv_a = _mm_w(h, L, "wkva")           # [B, 1, r+dr]
                     lat = rms(kv_a[..., :r], L["gkv"])
                     k_pe = rope(kv_a[..., r:][:, :, None, :])[:, :, 0]
-                    row = jnp.concatenate([lat, k_pe], -1)[:, 0]
+                    row = _pad_lanes(
+                        jnp.concatenate([lat, k_pe], -1)[:, 0], width)
                     pool = append_to_cache(pool, pool, row[:, None],
                                            row[:, None], lengths,
                                            tables)[0]
@@ -1990,7 +2070,8 @@ class ServingEngine:
                     # rows [lat|k_pe]; the weighted row sum sliced to the
                     # latent part IS the latent attention output
                     q_eff = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_k)
-                    q_cat = jnp.concatenate([q_eff, q_pe], -1)[:, 0]
+                    q_cat = _pad_lanes(
+                        jnp.concatenate([q_eff, q_pe], -1)[:, 0], width)
                     o_cat = paged_attention(q_cat, pool, pool,
                                             lengths + 1, tables,
                                             scale=scale)
@@ -2011,7 +2092,8 @@ class ServingEngine:
                       cfg.v_head_dim)
         r = cfg.kv_lora_rank
         eps = cfg.rms_norm_eps
-        scale = 1.0 / float(math.sqrt(dn + dr))
+        width = self._kv_geom[1]
+        scale = cfg.softmax_scale
         moe_static = self._p.get("moe_static")
         C = self.prefill_chunk
         ps, nj = self.page_size, self.pages_per_seq
@@ -2063,14 +2145,16 @@ class ServingEngine:
                 kv_a = _mm_w(h, L, "wkva")               # [1, C, r+dr]
                 lat = rms(kv_a[..., :r], L["gkv"])
                 k_pe = rope(kv_a[..., r:][:, :, None, :])[:, :, 0]
-                rows_new = jnp.concatenate([lat, k_pe], -1)  # [1, C, Dc]
+                rows_new = _pad_lanes(
+                    jnp.concatenate([lat, k_pe], -1), width)  # [1, C, Dc]
                 pool = write(pool, rows_new[0][:, None])
                 new_pools.append(pool)
                 wkb = _dq(L, "wkvb", x.dtype).reshape(r, nh, dn + dv)
                 w_k, w_v = wkb[..., :dn], wkb[..., dn:]
                 q_eff = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_k)
-                q_cat = jnp.concatenate([q_eff, q_pe], -1)  # [1,C,nh,Dc]
-                rows = pool[0, table[0]].reshape(T, r + dr)
+                q_cat = _pad_lanes(
+                    jnp.concatenate([q_eff, q_pe], -1), width)
+                rows = pool[0, table[0]].reshape(T, width)
                 scores = jnp.einsum("bsnd,td->bnst", q_cat, rows) * scale
                 scores = jnp.where(vis[None, None],
                                    scores.astype(jnp.float32), -1e30)

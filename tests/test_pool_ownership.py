@@ -219,3 +219,31 @@ def test_a_launch_that_raised_before_it_took_the_pools_keeps_them(
     assert len(kept) == len(handed)
     assert all(a is b for a, b in zip(kept, handed))
     assert not any(a.is_deleted() for a in handed)
+
+
+def test_a_copy_on_write_compiles_nothing_when_it_comes(models):
+    """Two random prompts that share their FIRST token fork a page, and
+    the second one's first row copies it on write. The copy is the one
+    fixed-shape program `_build_programs` built and ran once: when it
+    comes, mid-serving, nothing compiles (a compile inside a measured
+    window makes a benchmark run incorrect: PERF.md, PR 33)."""
+    m = models("llama")
+    eng = _engine(m, "unified")
+    rng = np.random.RandomState(5)
+    V = m.config.vocab_size
+    first = rng.randint(0, V, 12).astype(np.int32)
+    eng.add_request(first, max_new_tokens=8, request_id="donor")
+    for _ in range(3):              # the unified step is compiled here
+        eng.step()
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, *_a, **_k: compiles.append(name)
+        if name == "/jax/core/compile/backend_compile_duration" else None)
+    rider = np.concatenate([first[:1], rng.randint(0, V, 6)]).astype(np.int32)
+    assert rider[1] != first[1]
+    eng.add_request(rider, max_new_tokens=4, request_id="rider")
+    while eng.has_work():
+        eng.step()
+    eng.collect()
+    assert sum(r["cow_pages"] for r in _records(eng)) >= 1
+    assert compiles == []

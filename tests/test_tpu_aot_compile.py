@@ -249,6 +249,36 @@ def test_ragged_paged_attention_compiles_at_the_laguna_cell_shapes(
         chip.refusals.get(fn)
 
 
+def _ragged_latent(q, pool, rows, ss, nt, kvl, tab, pg, off):
+    from paddle_tpu.ops.fused import fused_append_rows
+    from paddle_tpu.ops.pallas_ragged import ragged_paged_attention
+    pool = fused_append_rows(pool, rows, pg, off)
+    return ragged_paged_attention(q, pool, None, ss, nt, kvl, tab,
+                                  v_dim=512), pool
+
+
+def test_latent_append_and_attention_compile_at_the_axk1_cell_shapes(chip):
+    """`a.x-k1-serve-ep16-d6` as its cell runs it: T = 32 slots + a
+    256-row chunk, 64 query heads over ONE cache row of 576 values
+    stored in 640 columns (tiles of 2 tokens, 128 rows), K the row and
+    V its first 512 columns, page 256, 2,049 pages, 33 sequences of 128
+    pages.  The unpadded 576 is what the gate refuses."""
+    from paddle_tpu.ops.pallas_ragged import (ragged_kernel_eligible,
+                                              ragged_tile_tokens)
+    from paddle_tpu.serving.engine import _latent_row_width
+    t, hq, psz, n_pages, s, nj = 288, 64, 256, 2049, 33, 128
+    width = _latent_row_width(512, 64)
+    assert width == 640 and ragged_kernel_eligible(hq, 1, width, psz)
+    assert not ragged_kernel_eligible(hq, 1, 576, psz)
+    assert ragged_tile_tokens(t, hq, jnp.bfloat16) == 2
+    seq, row = chip.shape((s,), I32), chip.shape((t,), I32)
+    assert chip.compiles(
+        _ragged_latent, chip.shape((t, hq, width)),
+        chip.shape((1, n_pages, psz, width)), chip.shape((t, 1, width)),
+        seq, seq, seq, chip.shape((s, nj), I32), row, row), \
+        chip.refusals.get(_ragged_latent)
+
+
 def _serve_norm_and_linears(x, nw, w8, s8, w4, s4):
     """What the engine's split chain adds around the kernels above:
     the rms norm and, on quantized deploys, the weight-only linears."""
@@ -316,6 +346,10 @@ def _small_engine(family):
                                               laguna_tiny_config)
         model = LagunaForCausalLM(laguna_tiny_config(
             head_dim=128, experts_held=(4, 4)))
+    elif family == "latent":
+        from paddle_tpu.models.axk1 import (AXK1ForCausalLM,
+                                            axk1_tiny_config)
+        model = AXK1ForCausalLM(axk1_tiny_config(experts_held=(4, 4)))
     else:
         from paddle_tpu.models.llama import (LlamaForCausalLM,
                                              llama_tiny_config)
@@ -338,8 +372,10 @@ def _pool_copies(text, shapes):
     return [ln.strip() for ln in text.splitlines() if pat.search(ln)]
 
 
-@pytest.mark.parametrize("family,n_shapes", [("llama", 1), ("window", 2)],
-                         ids=["llama_engines_choice", "window_two_pools"])
+@pytest.mark.parametrize("family,n_shapes", [("llama", 1), ("window", 2),
+                                             ("latent", 1)],
+                         ids=["llama_engines_choice", "window_two_pools",
+                              "latent_one_pool_a_layer"])
 def test_unified_step_updates_its_pools_in_place(chip, family, n_shapes):
     eng = _small_engine(family)
     assert eng.ragged
