@@ -110,7 +110,8 @@ _H_E2E = registry().histogram(
 STEP_COUNTS: Tuple[str, ...] = (
     "decode_rows", "prefill_rows", "live", "waiting", "admitted",
     "finished", "preempted", "cow_pages", "pools_in_place", "pages_live",
-    "pages_visited", "pool_pages_used", "pool_pages_total")
+    "pages_visited", "pool_pages_used", "pool_pages_total",
+    "launch_ahead", "rows_dropped")
 #: more counts where a model keeps two kinds of cache (full layers and
 #: sliding-window layers; the plain `pages_*` / `pool_pages_*` are then
 #: the sum of both kinds) ...

@@ -37,6 +37,17 @@ Inactive slots point their whole page table at the allocator's trash
 page 0 with length/num_tokens 0: both paths write their (garbage) K/V
 into the trash page and their logits are ignored on the host.
 
+The unified step keeps ONE launch queued ahead (`_unified_step`):
+call k of `step()` builds and dispatches launch k BEFORE it reads the
+result of launch k-1, so the device finds its next program queued the
+moment the last one ends and the host's admit / build / launch /
+sample / account run beside a device step instead of between two. The
+greedy token of every logits row is taken on the device and a decode
+row of launch k is fed from there (`_jit_feed`); the host reads launch
+k-1's tokens after it has queued launch k. What `step()` returns and
+what a `Request` shows (`tokens`, `prefill_pos`) is always RETIRED
+work: results the host holds.
+
 Greedy decoding only: the exactness contract (engine tokens ==
 solo `generate_cached` tokens per request, the acceptance test) is a
 greedy property; sampling strategies belong to the batch APIs.
@@ -213,6 +224,46 @@ def _head_logits(w, last):
     if "head_q" in w or "head_q4" in w:
         return _mm_w(last, w, "head")
     return last @ (w["head"] if w["head"] is not None else w["embed"].T)
+
+
+def _greedy(logits):
+    """[rows] int32: the greedy token of each logits row, taken on the
+    device from the rows the host would take it from (the first index
+    on ties, as `np.argmax`)."""
+    return jnp.argmax(logits, -1).astype(jnp.int32)
+
+
+class _Launch:
+    """One dispatched unified launch whose result the host has not read
+    yet: the device arrays it returns, and what the host knew when it
+    built it — who owns which row — which is all that retiring it
+    (`ServingEngine.retire`) needs."""
+
+    __slots__ = ("logits", "tokens", "moe", "preq", "n", "rows", "drafts",
+                 "row_of", "counts")
+
+    def __init__(self, logits, tokens, moe, preq, n, rows, drafts, row_of,
+                 counts):
+        self.logits = logits    # [S, vocab] on the device ([T, ..] K > 0)
+        self.tokens = tokens    # [S] int32 on the device: _greedy(logits)
+        self.moe = moe          # [5] routed-layer counts, or None
+        self.preq = preq        # the request whose chunk rides it, or None
+        self.n = n              # that chunk's rows
+        self.rows = rows        # [(slot, request)] of its decode rows
+        self.drafts = drafts    # {slot: drafted tokens} (spec decoding)
+        #: {id(request): row of `tokens`} of every request whose NEXT
+        #: input token this launch produces: its decode rows, and the
+        #: chunk's row when the chunk ends its prompt
+        self.row_of = row_of
+        self.counts = counts    # its part of the step record
+
+
+#: counts of a launch that add up when one step record retires two
+#: launches (a retire forced between two steps, then the step's own);
+#: of the others the record keeps the later launch's
+_ADDITIVE = frozenset(
+    ("decode_rows", "prefill_rows", "rows_dropped", "pages_live",
+     "pages_visited") + _tracing.STEP_COUNTS_BY_KIND[:4])
 
 
 class ServingEngine:
@@ -436,6 +487,12 @@ class ServingEngine:
         # the pool handles this step's launches were handed (dead
         # arrays, no buffers): `pools_in_place` asks them at account
         self._launched: List[object] = []
+        # the launch queue, depth one: the unified launch that is
+        # dispatched and not yet retired, and the counts of what was
+        # retired since `step()` last returned
+        self._inflight: Optional[_Launch] = None
+        self._retired = {"prefill_tokens": 0, "decoded": 0, "finished": 0}
+        self._retired_counts: Dict[str, float] = {}
 
         # live HBM accounting (ISSUE 11): static residency is published
         # once; a cumulative analytical ledger turns each launch into
@@ -503,8 +560,8 @@ class ServingEngine:
         again from `reconfigure()` — fresh `jax.jit` objects each time,
         so `program_cache_sizes()` stays at 1 per program (PT002).
 
-        Every program takes the page pools as argument 2, returns the
-        pools that replace them, and OWNS the ones it is handed
+        Every step program takes the page pools as argument 2, returns
+        the pools that replace them, and OWNS the ones it is handed
         (``donate_argnums``): XLA pairs each pool parameter with the
         output of its shape, in order, and the kernels' in-place row
         writes (`ops.fused.fused_rope_append`) land in the live buffer
@@ -513,7 +570,23 @@ class ServingEngine:
         if self.ragged:
             self._jit_unified = jax.jit(self._make_unified_body(),
                                         donate_argnums=2)
-            self._programs = {"unified": self._jit_unified}
+            # the token feed: EVERY `tok` the unified step sees comes
+            # out of this one program (one type, one sharding, one
+            # committed-ness: `_jit_unified` keeps one cache entry) —
+            # row r takes the host's token where src[r] < 0, else row
+            # src[r] of the launch in flight's greedy tokens, which
+            # never leave the device. Compiled and run ONCE here, on
+            # the tokens of no launch
+            self._jit_feed = jax.jit(
+                lambda prev, tok, src: jnp.where(
+                    src < 0, tok, prev[jnp.maximum(src, 0)]))
+            T = self.max_slots * (1 + self.spec_k) + self.prefill_chunk
+            self._no_tokens = jax.jit(lambda: jnp.zeros(
+                T if self.spec_k else self.max_slots + 1, jnp.int32))()
+            self._jit_feed(self._no_tokens, np.zeros(T, np.int32),
+                           np.full(T, -1, np.int32))
+            self._programs = {"unified": self._jit_unified,
+                              "feed": self._jit_feed}
         else:
             self._jit_decode = jax.jit(self._make_decode_body(),
                                        donate_argnums=2)
@@ -586,6 +659,8 @@ class ServingEngine:
             new_k = 0   # the split path has no multi-row slots
         if (new_chunk, new_k) == (self.prefill_chunk, self.spec_k):
             return False
+        # the launch in flight was built by the programs that go
+        self.retire()
         if self._window is not None and (
                 new_k or new_chunk > self.allocator.window_span):
             raise ValueError(
@@ -642,15 +717,32 @@ class ServingEngine:
         return req
 
     def has_work(self) -> bool:
-        return self.scheduler.has_work()
+        # a launch in flight is work: its requests are unfinished until
+        # it retires (so the scheduler says so too), and a row computed
+        # for a request that has ended since is still to be dropped
+        return self._inflight is not None or self.scheduler.has_work()
+
+    def retire(self) -> None:
+        """Read back the launch in flight, if there is one, and emit its
+        tokens: afterwards every `Request` shows all the work that was
+        dispatched for it. Whatever reads or edits a sequence between
+        two launches calls this first (preemption, the deadline sweep,
+        export / import, `reconfigure`, a router's drain); the counts
+        go into the next `step()` return."""
+        fl = self._inflight
+        if fl is not None:
+            self._sample_unified(fl, *self._await_launch(fl))
 
     def step(self) -> Dict[str, int]:
         """One engine iteration: cull expired requests, admit waiting
         ones into free slots, then run the step's device work — ONE
         unified ragged launch carrying every decode slot's token plus
-        one prefill chunk (ragged path), or the legacy alternating
+        one prefill chunk, dispatched before the previous launch is
+        read back (ragged path), or the legacy alternating
         prefill-chunk / decode-step pair (split path). Returns counts
-        for observability/benching."""
+        for observability/benching; `prefill_tokens`, `decoded` and
+        `finished` are those of work RETIRED in this call (results the
+        host holds), not of the launch it queued."""
         out = {"admitted": 0, "prefill_tokens": 0, "decoded": 0,
                "finished": 0}
         self.steps += 1
@@ -662,10 +754,20 @@ class ServingEngine:
             with _obs.span("serving.engine.step", step=self.steps):
                 self._step_phases(out)
         finally:
+            self._take_retired(out)     # a retire in the account phase
+            self._counts.update(self._retired_counts)
+            self._retired_counts = {}
             self._counts["admitted"] = out["admitted"]
             self._counts["finished"] = out["finished"]
             _TRACE.close_step(self._counts)
         return out
+
+    def _take_retired(self, out: Dict[str, int]) -> None:
+        """Move the counts of what was retired since they were last
+        taken into a `step()` return."""
+        for k, v in self._retired.items():
+            out[k] += v
+            self._retired[k] = 0
 
     def _step_phases(self, out: Dict[str, int]) -> None:
         """The step timeline: disjoint child spans of
@@ -685,18 +787,20 @@ class ServingEngine:
                 out["finished"] += 1
             # deadline sweep over in-flight requests: partial result,
             # pages freed immediately
-            for _, req in list(self.scheduler.active()):
-                if req.deadline_expired():
+            late = [req for _, req in self.scheduler.active()
+                    if req.deadline_expired()]
+            if late:
+                self.retire()       # their partial result is what ran
+            for req in late:
+                if req.slot is not None:    # not ended or staged by it
                     self._finish(req)
                     out["finished"] += 1
             out["admitted"] = self._admit()
             self._counts["live"] = self.scheduler.inflight
             self._counts["waiting"] = len(self.scheduler.waiting)
         if self.ragged:
-            pf, dec, fin = self._unified_step()
-            out["prefill_tokens"] = pf
-            out["decoded"] = dec
-            out["finished"] += fin
+            self._unified_step()
+            self._take_retired(out)
         else:
             out["prefill_tokens"], fin = self._prefill_chunk()
             out["finished"] += fin
@@ -916,6 +1020,7 @@ class ServingEngine:
         rid = req.request_id
         self._no_handoff("export_request")
         _TRACE.set_replica_context(self.replica)
+        self.retire()   # the payload is what every dispatched row wrote
         if req.pending is None or req.prefill_pos < int(req.prompt.size):
             raise ValueError(
                 f"request {rid} is not exportable mid-prefill "
@@ -994,6 +1099,7 @@ class ServingEngine:
                 f"page_size mismatch: handoff {handoff.page_size} vs "
                 f"engine {self.page_size}")
         _TRACE.set_replica_context(self.replica)
+        self.retire()
         _TRACE.adopt(handoff.request_id, handoff.trace)
         req = Request(handoff.prompt, handoff.max_new_tokens,
                       eos_token_id=handoff.eos_token_id,
@@ -1081,10 +1187,14 @@ class ServingEngine:
         if self.prefix_sharing:
             for _, cand in self.scheduler.active():
                 # only the donor's PREFILLED prompt tokens are
-                # reusable; cap at len(prompt)-1 so the last prompt
-                # token is always re-run for this request's logits
+                # reusable — those whose chunk is dispatched: the rider
+                # reads them in a later launch, which the device runs
+                # after the one that writes them; cap at len(prompt)-1
+                # so the last prompt token is always re-run for this
+                # request's logits
                 s = min(_lcp(req.prompt, cand.prompt),
-                        cand.prefill_pos, int(req.prompt.size) - 1)
+                        self._sent_pos(cand, self._inflight),
+                        int(req.prompt.size) - 1)
                 if s > share:
                     share, donor = s, cand
         match = self.prefix_cache.lookup(req.prompt) \
@@ -1158,6 +1268,11 @@ class ServingEngine:
             if need > spare or (self._window is not None and not
                                 self.allocator.can_admit(cand.total_tokens)):
                 return None
+        if self._inflight is not None:
+            # the victim's next token is on the device: read it back,
+            # then ask again (a slot may have come free by itself)
+            self.retire()
+            return cand
         self.scheduler.preempt(victim)
         self._counts["preempted"] += 1
         if _obs.enabled():
@@ -1253,7 +1368,7 @@ class ServingEngine:
         return len(active), finished
 
     # ------------------------------------------------------------ unified
-    def _unified_step(self) -> Tuple[int, int, int]:
+    def _unified_step(self) -> None:
         """ONE ragged launch for the whole step: decode slot `s` owns
         flat rows [s*R, s*R + 1 + k) with R = 1 + spec_k — its pending
         token plus k n-gram-drafted tokens verified in the SAME launch
@@ -1262,59 +1377,127 @@ class ServingEngine:
         kv_lengths / page tables, seq_start baked into the jitted body)
         tell the ragged kernel who owns which rows; idle rows write to
         the trash page and emit garbage logits the host never reads.
-        Returns (prefill_tokens, decoded, finished).
 
-        Speculative accept/rollback is greedy-exact: position j's argmax
-        depends only on rows 0..j of the slot (per-row causality), so
-        drafted tokens are accepted while they match the argmax chain
-        and the KV length is shrunk past the first mismatch — engine
-        output is bit-identical to plain decode, just fewer launches.
+        A launch queue of depth one: this call builds and DISPATCHES
+        its launch first and only then reads back the launch the call
+        before dispatched (`_await_launch` in the sync phase,
+        `_sample_unified` in the sample phase), so the device runs one
+        launch while the host retires the one before and builds the
+        next. Building needs no token VALUE, only what is known at
+        dispatch: a decode row's position is the sequence's length (the
+        allocator is extended at dispatch), its input token is row
+        `src` of the launch in flight's greedy tokens and is fed on the
+        device (`_jit_feed`), a request whose token in flight is its
+        `max_new_tokens`-th gets no row, and a prompt advances by
+        `_sent_pos`. What cannot be known a launch ahead is seen a
+        launch late: an EOS finish leaves one row in the next launch,
+        computed and dropped (`rows_dropped`). Its pages, like a
+        window's passed pages in `account`, go back to the pool at
+        once: every device program takes the pools the one before it
+        returned, so the device runs them in dispatch order — a freed
+        page is next WRITTEN by a later launch (or copy), after the
+        launch that still names it has run, and no `kv_lengths` reaches
+        a row its owner has not written.
+
+        Speculative decoding drafts from the last token on the host,
+        so such an engine retires its own launch before it returns
+        (depth 0: the same code, the queue empty at every build).
+        Accept/rollback is greedy-exact: position j's argmax depends
+        only on rows 0..j of the slot (per-row causality), so drafted
+        tokens are accepted while they match the argmax chain and the
+        KV length is shrunk past the first mismatch — engine output is
+        bit-identical to plain decode, just fewer launches.
 
         Vs the split path: a request that completes its prefill emits
-        its first token from THIS launch and takes its first decode
-        step in the NEXT one (the split path decodes it the same
+        its first token from its chunk's launch and takes its first
+        decode step in the NEXT one (the split path decodes it the same
         engine step) — per-request token sequences are identical, the
-        step count shifts by at most one."""
-        while self._prefill_fifo and \
-                self._prefill_fifo[0].state != PREFILL:
-            self._prefill_fifo.pop(0)
-        preq = self._prefill_fifo[0] if self._prefill_fifo else None
-        active = self.scheduler.active(DECODE)
-        if preq is None and not active:
-            return 0, 0, 0
+        step count shifts."""
+        prev = self._inflight
+        preq = self._next_chunk_request(prev)
+        rows = self._decode_rows(prev)
+        if prev is None and preq is None and not rows:
+            return
+        new, work = None, preq is not None or bool(rows)
         with _obs.span("serving.engine.build"):
-            host, drafts, n, start = self._build_unified(preq, active)
+            if work:
+                host, src, drafts, n, start, counts = \
+                    self._build_unified(preq, rows, prev)
         with _obs.span("serving.engine.launch"):
-            logits, *moe = self._launch(
-                self._jit_unified, jnp.asarray(host[0]),
-                *(jax.tree_util.tree_map(jnp.asarray, t)
-                  for t in host[1:]))
-            if preq is not None:
-                _TRACE.stamp(preq.request_id, "prefill_chunk", tokens=n,
-                             start=start)
-            if _obs.enabled():
-                _LAUNCHES.labels(path="unified").inc()
-                _STEPS.labels(phase="unified").inc()
-                if n:
-                    _TOKENS.labels(phase="prefill").inc(n)
+            if work:
+                tok = self._jit_feed(
+                    self._no_tokens if prev is None else prev.tokens,
+                    host[0], src)
+                logits, tokens, *moe = self._launch(
+                    self._jit_unified, tok,
+                    *(jax.tree_util.tree_map(jnp.asarray, t)
+                      for t in host[1:]))
+                row_of = {id(req): slot for slot, req, _ in rows}
+                if preq is not None:
+                    if start + n == int(preq.prompt.size):
+                        row_of[id(preq)] = self.max_slots
+                    _TRACE.stamp(preq.request_id, "prefill_chunk",
+                                 tokens=n, start=start)
+                new = self._inflight = _Launch(
+                    logits, tokens, moe[0] if moe else None, preq, n,
+                    [(slot, req) for slot, req, _ in rows], drafts,
+                    row_of, counts)
+                self._counts["launch_ahead"] = int(prev is not None)
+                if _obs.enabled():
+                    _LAUNCHES.labels(path="unified").inc()
+                    _STEPS.labels(phase="unified").inc()
+                    if n:
+                        _TOKENS.labels(phase="prefill").inc(n)
+        # retire the launch before this one; a drafting engine its own
+        due = prev if prev is not None else new if self.spec_k else None
         with _obs.span("serving.engine.sync"):
-            # the wait for the device and the copy back
-            logits = np.asarray(logits)     # [S, vocab]; [T, vocab] K>0
-            if moe:
-                # the routed layers' counts came back with the logits
-                self._counts.update(zip(
-                    _tracing.STEP_COUNTS_MOE,
-                    (float(v) for v in np.asarray(moe[0]))))
+            back = self._await_launch(due) if due is not None else None
         with _obs.span("serving.engine.sample"):
-            decoded, finished = self._sample_unified(
-                logits, preq, active, drafts, n)
-        return n, decoded, finished
+            if due is not None:
+                self._sample_unified(due, *back)
 
-    def _build_unified(self, preq: Optional[Request], active):
+    def _sent_pos(self, req: Request, fl: Optional[_Launch]) -> int:
+        """Prompt tokens of `req` whose chunk has been DISPATCHED:
+        `Request.prefill_pos` counts the chunks that have retired, the
+        launch in flight `fl` may carry one more."""
+        return req.prefill_pos + (
+            fl.n if fl is not None and fl.preq is req else 0)
+
+    def _next_chunk_request(self, fl: Optional[_Launch]) \
+            -> Optional[Request]:
+        """The oldest request with prompt left to dispatch."""
+        fifo = self._prefill_fifo
+        while fifo and (fifo[0].state != PREFILL
+                        or self._sent_pos(fifo[0], fl)
+                        >= int(fifo[0].prompt.size)):
+            fifo.pop(0)
+        return fifo[0] if fifo else None
+
+    def _decode_rows(self, fl: Optional[_Launch]):
+        """[(slot, request, src)] of the next launch's decode rows:
+        every request in a slot whose prompt is dispatched in full and
+        that has a token left to make. `src` is the row of the launch
+        in flight `fl` that produces the request's input token, None
+        when the host holds it (`Request.pending`)."""
+        rows = []
+        for slot, req in self.scheduler.active():
+            src = fl.row_of.get(id(req)) if fl is not None else None
+            if req.state != DECODE and (src is None
+                                        or self.role == "prefill"):
+                continue    # mid-prompt, or staged for export next
+            if len(req.tokens) + (src is not None) < req.max_new_tokens:
+                rows.append((slot, req, src))
+        return rows
+
+    def _build_unified(self, preq: Optional[Request], rows,
+                       fl: Optional[_Launch]):
         """The host half of the unified launch: extend every sequence
         (applying copy-on-write copies) and fill the row tables.
         Returns ((tok, positions, num_tokens, kv_lengths, tables,
-        tok_page, tok_off), drafts by slot, prefill rows, their start).
+        tok_page, tok_off), src, drafts by slot, prefill rows, their
+        start, the launch's counts for the step record that retires
+        it). `src` [T] says where each row's token comes from: -1 the
+        host's `tok`, else that row of the launch in flight's tokens.
         With sliding-window layers `tables` and `tok_page` are pairs:
         (the full kind's, the window kind's)."""
         B, C, K = self.max_slots, self.prefill_chunk, self.spec_k
@@ -1323,6 +1506,7 @@ class ServingEngine:
         T, S = base + C, B + 1
         ps, nj = self.page_size, self.pages_per_seq
         tok = np.zeros(T, np.int32)
+        src = np.full(T, -1, np.int32)
         positions = np.zeros(T, np.int32)
         num_tokens = np.zeros(S, np.int32)
         kv_lengths = np.zeros(S, np.int32)
@@ -1334,7 +1518,7 @@ class ServingEngine:
             wtables = np.zeros((S, nj), np.int32)
             wtok_page = np.zeros(T, np.int32)
         drafts: Dict[int, List[int]] = {}
-        for slot, req in active:
+        for slot, req, feed in rows:
             ln = self.allocator.seq_length(req.request_id)
             d: List[int] = []
             if K:
@@ -1352,7 +1536,10 @@ class ServingEngine:
             tbl = self.allocator.table(req.request_id)
             r0 = slot * R
             pos = ln + np.arange(nt)
-            tok[r0:r0 + nt] = [req.pending] + d
+            if feed is None:
+                tok[r0:r0 + nt] = [req.pending] + d
+            else:
+                src[r0] = feed      # its token is still on the device
             positions[r0:r0 + nt] = pos
             num_tokens[slot] = nt
             kv_lengths[slot] = ln + nt
@@ -1367,28 +1554,28 @@ class ServingEngine:
                 _TRACE.stamp(req.request_id, "draft", tokens=len(d))
         n, start = 0, 0
         if preq is not None:
-            start = preq.prefill_pos
+            start = self._sent_pos(preq, fl)
             n = min(C, int(preq.prompt.size) - start)
             self._apply_copies(self.allocator.extend(preq.request_id, n),
                                preq)
             tbl = self.allocator.table(preq.request_id)
-            rows = np.arange(n)
+            chunk = np.arange(n)
             tok[base:base + n] = preq.prompt[start:start + n]
-            positions[base:base + n] = start + rows
+            positions[base:base + n] = start + chunk
             num_tokens[S - 1] = n
             kv_lengths[S - 1] = start + n
             tables[S - 1] = tbl
-            tok_page[base:base + n] = tbl[(start + rows) // ps]
-            tok_off[base:base + n] = (start + rows) % ps
+            tok_page[base:base + n] = tbl[(start + chunk) // ps]
+            tok_off[base:base + n] = (start + chunk) % ps
             if windowed:
                 wtables[S - 1] = wt = self.allocator.window_table(
                     preq.request_id)
-                wtok_page[base:base + n] = wt[(start + rows) // ps]
-        self._counts["decode_rows"] = int(num_tokens[:B].sum())
-        self._counts["prefill_rows"] = n
+                wtok_page[base:base + n] = wt[(start + chunk) // ps]
+        counts = {"decode_rows": int(num_tokens[:B].sum()),
+                  "prefill_rows": n}
         if self._family == "mla":
-            self._counts["chunk_kv_len"] = int(kv_lengths[S - 1])
-            self._counts["latent_row_bytes"] = \
+            counts["chunk_kv_len"] = int(kv_lengths[S - 1])
+            counts["latent_row_bytes"] = \
                 self._kv_geom[1] * self._kv_itemsize
         # pages that hold this launch's tokens, against the K/V page
         # fetches the ragged kernel makes for each KV head (a sequence's
@@ -1403,11 +1590,11 @@ class ServingEngine:
                 window=window) for r in self._kind_rep[kind])
 
         live = int(np.sum(-(-kv_lengths // ps)))
-        self._counts["pages_live"] = live
-        self._counts["pages_visited"] = visited(0)
+        counts["pages_live"] = live
+        counts["pages_visited"] = visited(0)
         if not windowed:
             return ((tok, positions, num_tokens, kv_lengths, tables,
-                     tok_page, tok_off), drafts, n, start)
+                     tok_page, tok_off), src, drafts, n, start, counts)
         # the window kind: the pages between each sequence's oldest
         # visible key and its newest
         W = self._window
@@ -1415,30 +1602,55 @@ class ServingEngine:
         wlive = int(np.sum(np.where(
             num_tokens > 0, (kv_lengths - 1) // ps - oldest // ps + 1, 0)))
         wvisited = visited(1, W)
-        self._counts.update({
+        counts.update({
             "pages_live.full": live, "pages_live.window": wlive,
-            "pages_visited.full": self._counts["pages_visited"],
+            "pages_visited.full": counts["pages_visited"],
             "pages_visited.window": wvisited})
-        self._counts["pages_live"] += wlive
-        self._counts["pages_visited"] += wvisited
+        counts["pages_live"] += wlive
+        counts["pages_visited"] += wvisited
         return ((tok, positions, num_tokens, kv_lengths,
                  (tables, wtables), (tok_page, wtok_page), tok_off),
-                drafts, n, start)
+                src, drafts, n, start, counts)
 
-    def _sample_unified(self, logits: np.ndarray, preq: Optional[Request],
-                        active, drafts: Dict[int, List[int]],
-                        n: int) -> Tuple[int, int]:
-        """Greedy argmax over the launch's logits: the prefill chunk's
-        first token when the prompt is done, one token per decode slot,
-        drafts verified. Returns (decoded, finished)."""
+    def _await_launch(self, fl: _Launch):
+        """The wait for launch `fl` and the copy back: its greedy tokens
+        ([S] int32; [T] with drafts), the routed layers' counts, and
+        the logits rows themselves only where someone asked for them
+        (`on_logits`) — from the same launch of the same program."""
+        tokens = np.asarray(fl.tokens)
+        logits = np.asarray(fl.logits) if self.on_logits is not None \
+            else None
+        if fl.moe is not None:
+            # the routed layers' counts came back with the tokens
+            fl.counts.update(zip(_tracing.STEP_COUNTS_MOE,
+                                 (float(v) for v in np.asarray(fl.moe))))
+        return tokens, logits
+
+    def _sample_unified(self, fl: _Launch, tokens: np.ndarray,
+                        logits: Optional[np.ndarray]) -> None:
+        """Retire launch `fl`: emit the prefill chunk's first token when
+        it ended its prompt and one token per decode row (drafts
+        verified), and add what it did to `_retired` — the counts
+        `step()` returns and the launch's part of the step record. A
+        row of a request that has ended since the launch was built (an
+        EOS seen one launch late) is dropped."""
+        if fl is self._inflight:
+            self._inflight = None
         B, K = self.max_slots, self.spec_k
         R = 1 + K
         base = B * R
-        finished = 0
+        done = self._retired
+        counts = fl.counts
+        counts["rows_dropped"] = 0
+
+        def row(i):
+            return None if logits is None else logits[i]
+
+        preq, n = fl.preq, fl.n
         if preq is not None:
             preq.prefill_pos += n
+            done["prefill_tokens"] += n
             if preq.prefill_pos == int(preq.prompt.size):
-                self._prefill_fifo.pop(0)
                 preq.state = DECODE
                 # cache the full prompt pages BEFORE _emit can finish
                 # the request and return its pages — trie pins keep
@@ -1448,30 +1660,32 @@ class ServingEngine:
                     self.prefix_cache.insert(
                         preq.prompt,
                         self.allocator.seq_pages(preq.request_id))
-                row = logits[base + n - 1] if K else logits[B]
-                fin = self._emit(preq, int(np.argmax(row)), row)
-                finished += fin
+                i = base + n - 1 if K else B
+                fin = self._emit(preq, int(tokens[i]), row(i))
+                done["finished"] += fin
                 if not fin and self.role == "prefill":
                     self._stage_handoff(preq)
         decoded = 0
-        for slot, req in active:
-            d = drafts[slot]
+        for slot, req in fl.rows:
+            if req.state != DECODE:
+                counts["rows_dropped"] += 1
+                continue
+            d = fl.drafts[slot]
+            r0 = slot * R
             if not d:
-                row = logits[slot * R] if K else logits[slot]
-                finished += self._emit(req, int(np.argmax(row)), row)
+                done["finished"] += self._emit(req, int(tokens[r0]),
+                                               row(r0))
                 decoded += 1
                 continue
-            r0 = slot * R
-            greedy = [int(np.argmax(logits[r0 + j]))
-                      for j in range(len(d) + 1)]
+            greedy = [int(tokens[r0 + j]) for j in range(len(d) + 1)]
             m = accept_length(d, greedy)
             fin = 0
             for j in range(m + 1):
                 decoded += 1
-                fin = self._emit(req, greedy[j], logits[r0 + j])
+                fin = self._emit(req, greedy[j], row(r0 + j))
                 if fin:
                     break   # EOS/max_new: _finish already freed the seq
-            finished += fin
+            done["finished"] += fin
             if not fin:
                 # reject the tail: pure length rollback — stale KV past
                 # the new length is never readable (kv_lengths caps the
@@ -1482,9 +1696,15 @@ class ServingEngine:
             self.spec_accepted += m
             _TRACE.stamp(req.request_id, "verify_accept",
                          drafted=len(d), accepted=m)
+        done["decoded"] += decoded
         if _obs.enabled() and decoded:
             _TOKENS.labels(phase="decode").inc(decoded)
-        return decoded, finished
+        # the record of the step that retires a launch describes THAT
+        # launch: its rows and pages beside the tokens `step()` returns
+        # and the device time its span mostly holds
+        for k, v in counts.items():
+            self._retired_counts[k] = v + (
+                self._retired_counts.get(k, 0) if k in _ADDITIVE else 0)
 
     def _emit(self, req: Request, tok: int,
               row: Optional[np.ndarray] = None) -> int:
@@ -1572,7 +1792,9 @@ class ServingEngine:
     # q / k / v projections -> fused_rope_append (MLA:
     # fused_append_rows) -> ragged_paged_attention -> o-proj -> norm ->
     # _ffn_apply. Entry (_seq_starts) and exit (_logit_rows,
-    # _head_logits) are shared by the three families.
+    # _head_logits, _greedy) are shared by the three families: a step
+    # returns (logits rows, the pools, their greedy tokens[, the routed
+    # layers' counts]).
     # No flags_guard: nothing in the chain is flag-routed.
 
     def _llama_unified_body(self):
@@ -1633,8 +1855,9 @@ class ServingEngine:
             logits = _head_logits(
                 w, _logit_rows(x, seq_start, num_tokens, K))
             if moe_stats:
-                return logits, new_pools, _moe_step_counts(moe_stats)
-            return logits, new_pools
+                return (logits, new_pools, _greedy(logits),
+                        _moe_step_counts(moe_stats))
+            return logits, new_pools, _greedy(logits)
 
         return step
 
@@ -1675,7 +1898,7 @@ class ServingEngine:
             x = fused_layer_norm(x, w["normw"], w["normb"], eps)
             logits = _head_logits(
                 w, _logit_rows(x, seq_start, num_tokens, K))
-            return logits, new_pools
+            return logits, new_pools, _greedy(logits)
 
         return step
 
@@ -1764,8 +1987,9 @@ class ServingEngine:
             logits = _head_logits(
                 w, _logit_rows(x, seq_start, num_tokens, K))
             if moe_stats:
-                return logits, new_pools, _moe_step_counts(moe_stats)
-            return logits, new_pools
+                return (logits, new_pools, _greedy(logits),
+                        _moe_step_counts(moe_stats))
+            return logits, new_pools, _greedy(logits)
 
         return step
 

@@ -462,6 +462,9 @@ class FleetRouter:
         if _obs.enabled():
             _DRAINS.labels(replica=name).inc()
             _UP.set(len(self._live()))
+        # what the replica had dispatched is read back first: its
+        # requests are sorted below by what they show once it retired
+        eng.retire()
         # results finished before the fault survive the drain
         self._absorb(eng.collect())
         moved = resubmitted = 0

@@ -24,6 +24,15 @@ like, shrunk to tiny models so tier-1 (CPU) replays it exactly:
                        re-admitted; the scenario asserts zero request
                        loss and exact greedy outputs anyway.
 
+The step-indexed targets (`ttft_p90_steps`, `e2e_p90_steps`) count
+router steps, and a step is a call of `ServingEngine.step()`: since the
+engine keeps one launch queued ahead (PR 34) a token surfaces in the
+call AFTER the one that dispatched its launch, so a request's first
+token shows one call later on its prefill replica and its last one call
+later on its decode replica. Every scenario's targets moved by that
+(+1 / +2 calls; a call itself got shorter), and `replica_kill`'s chaos
+by one step so that the kill still lands on a running decode.
+
 `run_scenario` drives a fresh two/three-replica fleet through a plan
 and emits one flat SERVING_BENCH-style row: fleet tokens/s, TTFT/e2e
 percentiles (from the before/after delta of the router-measured
@@ -135,7 +144,7 @@ def make_plan(name: str, seed: int = 0, vocab: int = 128) -> Plan:
                                    tenant="burst"))
         return Plan(name, seed, arr, two,
                     slo=SLOTargets(ttft_p90_ms=500.0, e2e_p90_ms=2000.0,
-                                   ttft_p90_steps=12, e2e_p90_steps=18,
+                                   ttft_p90_steps=13, e2e_p90_steps=20,
                                    queue_depth=4))
     if name == "agentic":
         # 3 agents x 3 turns; turns 2..3 extend the previous turn
@@ -149,7 +158,7 @@ def make_plan(name: str, seed: int = 0, vocab: int = 128) -> Plan:
                     tenant=f"agent{a}", after=f"agent{a}-t{t - 1}"))
         return Plan(name, seed, arr, two,
                     slo=SLOTargets(ttft_p90_ms=500.0, e2e_p90_ms=3000.0,
-                                   ttft_p90_steps=8, e2e_p90_steps=10,
+                                   ttft_p90_steps=9, e2e_p90_steps=12,
                                    queue_depth=4))
     if name == "mixed":
         # two long-context jobs up front, six short chats trickling in
@@ -163,7 +172,7 @@ def make_plan(name: str, seed: int = 0, vocab: int = 128) -> Plan:
                                tenant="chat"))
         return Plan(name, seed, arr, two,
                     slo=SLOTargets(ttft_p90_ms=800.0, e2e_p90_ms=3000.0,
-                                   ttft_p90_steps=13, e2e_p90_steps=15,
+                                   ttft_p90_steps=14, e2e_p90_steps=17,
                                    queue_depth=4))
     if name == "thrash":
         # a good tenant re-using one prefix vs an adversary streaming
@@ -181,7 +190,7 @@ def make_plan(name: str, seed: int = 0, vocab: int = 128) -> Plan:
         return Plan(name, seed, arr, two,
                     replica_kw={"pf0": {"num_pages": 24}},
                     slo=SLOTargets(ttft_p90_ms=800.0, e2e_p90_ms=3000.0,
-                                   ttft_p90_steps=15, e2e_p90_steps=16,
+                                   ttft_p90_steps=16, e2e_p90_steps=18,
                                    queue_depth=3, pool_high=0.7,
                                    pool_low=0.4))
     if name == "replica_kill":
@@ -192,10 +201,10 @@ def make_plan(name: str, seed: int = 0, vocab: int = 128) -> Plan:
                                int(rng.integers(3, 6)),
                                at_step=i // 2, tenant="burst"))
         return Plan(name, seed, arr, roles,
-                    chaos=Chaos("dec0", at_step=6, readmit_after=4),
+                    chaos=Chaos("dec0", at_step=7, readmit_after=3),
                     check_exact=True,
                     slo=SLOTargets(ttft_p90_ms=800.0, e2e_p90_ms=4000.0,
-                                   ttft_p90_steps=10, e2e_p90_steps=14,
+                                   ttft_p90_steps=11, e2e_p90_steps=17,
                                    queue_depth=4))
     raise ValueError(f"unknown scenario {name!r} (one of {SCENARIOS})")
 

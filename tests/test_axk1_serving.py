@@ -103,7 +103,7 @@ class TestEngineAgainstReference:
         assert eng.ragged and eng._family == "mla"
         # the row is stored padded to whole 128-lane registers
         assert eng._pools[0].shape == (1, 40, 8, 256)
-        assert eng.program_cache_sizes() == {"unified": 1}
+        assert eng.program_cache_sizes() == {"unified": 1, "feed": 1}
         for r, p in zip(reqs, prompts):
             got = np.stack(rows[r.request_id])
             want = _reference_rows(model, p, np.asarray(r.tokens))

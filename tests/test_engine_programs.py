@@ -104,7 +104,7 @@ class TestRaggedPath:
                             lambda *a: False)
         r2, _, e2 = _run_trace(m, V, 5, seed=7, spec_decode=2, **self.ARGS)
         assert e1.ragged and not e2.ragged
-        assert set(e1.program_cache_sizes()) == {"unified"}
+        assert set(e1.program_cache_sizes()) == {"unified", "feed"}
         assert e2.spec_k == 0       # the split pair has no multi-row slots
         assert set(r1) == set(r2)
         for rid in r1:

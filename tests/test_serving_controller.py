@@ -615,8 +615,8 @@ class TestArtifactPlumbing:
     def test_rows_declare_their_slo_targets(self, burst_pair):
         off, on = burst_pair
         for row in (off, on):
-            assert row["slo"]["ttft_p90_steps"] == 12
-            assert row["slo"]["e2e_p90_steps"] == 18
+            assert row["slo"]["ttft_p90_steps"] == 13
+            assert row["slo"]["e2e_p90_steps"] == 20
         assert off["autopilot"] == 0 and on["autopilot"] == 1
 
     def test_committed_artifact_has_paired_autopilot_rows(self):

@@ -407,6 +407,15 @@ def test_unified_step_updates_its_pools_in_place(chip, family, n_shapes):
         2 * math.prod(p.shape) for p in jax.tree.leaves(pools))
     copies = _pool_copies(compiled.as_text(), shapes)
     assert not copies, copies[:3]
+    # the step hands back the greedy token of each logits row, and the
+    # token feed (ISSUE 34: a decode row's input stays on the device)
+    # compiles for the chip at the step's own shapes, to the `tok` the
+    # step takes
+    logits, _, tokens, *_ = compiled.out_info
+    assert (tokens.shape, tokens.dtype) == (logits.shape[:1], I32)
+    feed = eng._jit_feed.lower(chip.shape(tokens.shape, I32), rows,
+                               rows).compile()
+    assert (feed.out_info.shape, feed.out_info.dtype) == (rows.shape, I32)
     # the same body without ownership: the copies this test looks for
     # are there, so the pattern still reads what the compiler prints
     plain = jax.jit(eng._make_unified_body()).lower(*args).compile()

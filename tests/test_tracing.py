@@ -413,6 +413,7 @@ class TestStepTimeline:
         rows = eng.max_slots + eng.prefill_chunk
         tiles = -(-rows // ragged_tile_tokens(rows, eng._q_rep,
                                               eng._q_dtype))
+        queued_on = 0   # pages in use when the call before returned
         for s, (out, pages_used) in zip(steps, outs):
             assert s["name"] == "serving.engine.step"
             _inside_and_disjoint((s["start_ns"], s["end_ns"]),
@@ -429,10 +430,13 @@ class TestStepTimeline:
             assert s["pool_pages_used"] == pages_used
             assert s["pool_pages_total"] == eng.num_pages - 1
             if ragged:
-                if not out["finished"]:
-                    # nothing shared, nothing freed: the launch's live
-                    # pages are the allocator's own count
-                    assert s["pages_live"] == pages_used
+                # the record describes the launch this call RETIRED,
+                # queued by the call before (ISSUE 34), like the counts
+                # `step()` returns. Nothing shared: its live pages are
+                # the allocator's own count when it was queued (a
+                # request that ended in that call had no row in it)
+                assert s["pages_live"] == queued_on
+                queued_on = pages_used
                 # the kernel fetches a sequence's pages once for each
                 # query tile that holds rows of it: decode slots sit in
                 # one tile each, the chunk may span several
