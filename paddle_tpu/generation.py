@@ -461,6 +461,40 @@ def _laguna_decode_params(model):
                 attn_static=tuple(attn_static), rope_fn=rope_fn)
 
 
+def _eva_decode_params(model):
+    """EvaByteForCausalLM: a llama-layout weight tree whose norm gains
+    are ``1 + g`` in float32 (``norm_add_unit_offset``, folded here once)
+    and whose layers carry the chunk pooling's per-head ``phi`` and
+    ``mu`` [heads, head_dim]; ``head`` is the byte heads side by side.
+    ``attn_static`` marks the family as served by the unified ragged
+    step only."""
+    from .models.evabyte import rope_table
+    inner, cfg = model.model, model.config
+
+    def gain(norm):
+        return 1.0 + norm.weight._data.astype(jnp.float32)
+
+    layers = []
+    for lyr in inner.layers:
+        a, m = lyr.self_attn, lyr.mlp
+        layers.append(dict(
+            ln1=gain(lyr.input_layernorm),
+            wq=a.q_proj.weight._data, wk=a.k_proj.weight._data,
+            wv=a.v_proj.weight._data, wo=a.o_proj.weight._data,
+            phi=a.adaptive_phi._data, mu=a.adaptive_mu_k._data,
+            ln2=gain(lyr.post_attention_layernorm),
+            wg=m.gate_proj.weight._data, wu=m.up_proj.weight._data,
+            wd=m.down_proj.weight._data))
+    return dict(
+        cfg=cfg, family="eva", embed=inner.embed_tokens.weight._data,
+        layers=layers, norm=gain(inner.norm),
+        head=model.lm_head.weight._data,
+        attn_static=(dict(heads=cfg.num_attention_heads, window=None,
+                          rope=""),) * len(layers),
+        rope_fn=lambda n: dict(zip(("cos", "sin"), rope_table(
+            cfg.rope_theta, cfg.head_dim, n))))
+
+
 def _mla_decode_params(model, weight_only_int8: bool = False,
                        algo: str = "weight_only_int8"):
     """DeepSeekV2ForCausalLM: multi-head latent attention with the
@@ -538,8 +572,15 @@ def _decode_params(model, weight_only_int8: bool = False,
     inner = getattr(model, "model", None)
     if inner is not None:
         from .models.deepseek import DeepSeekV2Model
+        from .models.evabyte import EvaByteModel
         from .models.laguna import LagunaModel
         from .models.moe_llm import MoEModel
+        if isinstance(inner, EvaByteModel):
+            if enabled:
+                raise NotImplementedError(
+                    "weight-only quantisation is not wired for the "
+                    "EvaByte family")
+            return _eva_decode_params(model)
         if isinstance(inner, LagunaModel):
             if enabled:
                 raise NotImplementedError(
@@ -960,6 +1001,12 @@ def _cached_step_body(p, max_len: int):
             "tables) decodes through serving.ServingEngine; the "
             "contiguous-cache generate_cached / generate_compiled "
             "bodies read one head count and one table")
+    if p["family"] == "eva":
+        raise NotImplementedError(
+            "the EvaByte family (chunk-summary attention: pooled rows "
+            "beside a tumbling window) decodes through "
+            "serving.ServingEngine; the contiguous-cache bodies keep "
+            "one row a token")
     if p["family"] == "gpt":
         return _gpt_cached_step_body(p["cfg"], max_len)
     if p["family"] == "mla":

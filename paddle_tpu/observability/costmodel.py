@@ -221,6 +221,18 @@ def _c_fused_append_rows(*, T: int, KV: int, D: int, page_size: int,
                                    "activations": T * KV * D * dtype_bytes})
 
 
+@register_cost("fused_chunk_pool")
+def _c_fused_chunk_pool(*, P: int, KV: int, D: int, chunk: int,
+                        dtype_bytes: int = 2) -> CostEstimate:
+    """P chunks of `chunk` cached K and V rows [KV, D] pooled to one K
+    and one V row each: scores 2D a row, two weighted sums 2D a row."""
+    rows = 2 * P * KV * chunk * D * dtype_bytes
+    out = 2 * P * KV * D * dtype_bytes
+    return CostEstimate(bytes_read=rows + 2 * KV * D * 4,
+                        bytes_written=out, flops=6 * P * KV * chunk * D,
+                        breakdown={"kv": rows, "activations": out})
+
+
 @register_cost("swiglu")
 def _c_swiglu(*, T: int, H: int, dtype_bytes: int = 2) -> CostEstimate:
     """gate/up [T, H] -> silu(gate) * up [T, H]."""

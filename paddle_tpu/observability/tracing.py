@@ -61,7 +61,8 @@ from . import DEFAULT_BUCKETS, Histogram, _new_span_id, registry
 __all__ = ["TraceEvent", "RequestTrace", "TraceRecorder", "recorder",
            "enabled", "set_enabled", "percentile", "percentiles",
            "slo_summary", "SLO_METRICS", "STEP_COUNTS", "STEPS_PER_SLOT",
-           "STEP_COUNTS_BY_KIND", "STEP_COUNTS_MOE", "STEP_COUNTS_LATENT"]
+           "STEP_COUNTS_BY_KIND", "STEP_COUNTS_MOE", "STEP_COUNTS_LATENT",
+           "STEP_COUNTS_EVA"]
 
 _FLAG = _flags._registry["FLAGS_request_tracing"]
 
@@ -130,6 +131,18 @@ STEP_COUNTS_MOE: Tuple[str, ...] = (
 #: query tiles each walk), and the bytes a token a layer as STORED (the
 #: row's lanes, padding included)
 STEP_COUNTS_LATENT: Tuple[str, ...] = ("chunk_kv_len", "latent_row_bytes")
+#: ... and where every layer is chunk-summary (EVA) attention, whose
+#: cache is two lists of rows from one pool. Of the launch (the first
+#: four add up): the pooled and the exact rows its queries' sequences
+#: read in ONE layer, the pooled rows it wrote, the windows its new
+#: tokens closed; the bytes of one row of either list in one layer.
+#: Of the call: the pages its closes returned, and the pool's pages by
+#: list (the total is the one pool's, the same under both names)
+STEP_COUNTS_EVA: Tuple[str, ...] = (
+    "summary_rows_live", "window_rows_live", "summaries_written",
+    "windows_closed", "cache_row_bytes", "window_pages_freed",
+    "pool_pages_used.summary", "pool_pages_used.exact",
+    "pool_pages_total.summary", "pool_pages_total.exact")
 #: step records kept for each slot of the request ring. A request lives
 #: through tens to hundreds of steps, and whoever reads a whole measured
 #: window from the records (`benchmarks/lib/program_spans.py`: 50-56 s

@@ -26,7 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_rms_norm", "fused_rope", "swiglu", "fused_layer_norm",
            "fused_bias_residual_layer_norm", "fused_moe_dispatch_combine",
-           "fused_rope_append", "fused_append_rows"]
+           "fused_rope_append", "fused_append_rows", "fused_chunk_pool"]
 
 
 def _interpret() -> bool:
@@ -443,6 +443,13 @@ def fused_rope_append(q, k, v, cos, sin, k_pages, v_pages,
         return (0, jnp.clip(pg[t], 0, total - 1), 0, 0)
 
     page_spec = pl.BlockSpec((KV, 1, psz, D), page_map)
+    # a K and a V page of every KV head, in and out, double-buffered by
+    # the pipeline: with many KV heads (32 x 256 x 128 bf16 = 2 MiB a
+    # page) that passes the compiler's default scoped VMEM, and the
+    # kernel asks for what it needs; below it, nothing is asked
+    resident = 8 * KV * psz * D * k_pages.dtype.itemsize
+    vmem = {"vmem_limit_bytes": resident + (8 << 20)} \
+        if resident > (12 << 20) else {}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                 # page_idx, page_off
         grid=(T,),
@@ -469,7 +476,7 @@ def fused_rope_append(q, k, v, cos, sin, k_pages, v_pages,
         # flat-input indices INCLUDE the scalar-prefetch operands
         input_output_aliases={7: 1, 8: 2},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",), **vmem),
         interpret=_interpret(),
     )(page_idx.astype(jnp.int32), page_off.astype(jnp.int32),
       q, k, v, cos[:, None, :], sin[:, None, :], k_pages, v_pages)
@@ -518,6 +525,62 @@ def fused_append_rows(pages, rows, page_idx, page_off):
         interpret=_interpret(),
     )(page_idx.astype(jnp.int32), page_off.astype(jnp.int32),
       rows, pages)
+
+
+# ---------------------------------------------------------------------------
+# chunk pooling (chunk-summary attention's cache rows; inference only)
+# ---------------------------------------------------------------------------
+
+def _chunk_pool_kernel(pg_ref, ck_ref, k_ref, v_ref, phi_ref, mu_ref,
+                       ko_ref, vo_ref, *, scale: float):
+    k = k_ref[:, 0].astype(jnp.float32)                # [KV, c, D]
+    v = v_ref[:, 0].astype(jnp.float32)
+    # every reduction keeps its axis: the weights stay [KV, c, 1], one a
+    # sublane, and nothing moves between lanes and sublanes
+    sc = jnp.sum(k * phi_ref[:], -1, keepdims=True) * scale
+    e = jnp.exp(sc - jnp.max(sc, 1, keepdims=True))
+    a = e / jnp.sum(e, 1, keepdims=True)
+    ko_ref[0] = (jnp.sum(a * k, 1) + mu_ref[:, 0]).astype(ko_ref.dtype)
+    vo_ref[0] = jnp.sum(a * v, 1).astype(vo_ref.dtype)
+
+
+def fused_chunk_pool(k_pages, v_pages, phi, mu, page_idx, chunk_idx, *,
+                     chunk: int, scale: float):
+    """One pooled K and V row for each of P closed chunks of `chunk`
+    cached tokens (EVA, arXiv:2302.04542): slot p reads rows
+    [chunk_idx[p] * chunk, + chunk) of page page_idx[p] of every head,
+    weights them by a = softmax_m(scale * phi_h . k_m) and returns
+    (sum a_m k_m + mu_h, sum a_m v_m), each [P, KV, D] in the pools'
+    type (float32 inside). k/v_pages [KV, total_pages, page_size, D];
+    phi, mu [KV, D]; page_idx, chunk_idx [P] int32. A chunk never
+    straddles a page (page_size % chunk == 0). On a TPU `chunk` is whole
+    sublane tiles of the pools' type (16 rows of bfloat16). The pools
+    are only read: the rows are written by `fused_append_rows`."""
+    KV, total, psz, D = k_pages.shape
+    P = page_idx.shape[0]
+    if psz % chunk:
+        raise ValueError(f"page_size {psz} is not whole chunks of {chunk}")
+
+    def src_map(p, pg, ck):
+        return (0, jnp.clip(pg[p], 0, total - 1),
+                jnp.clip(ck[p], 0, psz // chunk - 1), 0)
+
+    src = pl.BlockSpec((KV, 1, chunk, D), src_map)
+    vec = pl.BlockSpec((KV, 1, D), lambda p, pg, ck: (0, 0, 0))
+    row = pl.BlockSpec((1, KV, D), lambda p, pg, ck: (p, 0, 0))
+    f32 = jnp.float32
+    return pl.pallas_call(
+        functools.partial(_chunk_pool_kernel, scale=float(scale)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(P,),
+            in_specs=[src, src, vec, vec], out_specs=[row, row]),
+        out_shape=[jax.ShapeDtypeStruct((P, KV, D), k_pages.dtype),
+                   jax.ShapeDtypeStruct((P, KV, D), v_pages.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+    )(page_idx.astype(jnp.int32), chunk_idx.astype(jnp.int32),
+      k_pages, v_pages, phi.astype(f32)[:, None], mu.astype(f32)[:, None])
 
 
 # ---------------------------------------------------------------------------
@@ -615,6 +678,10 @@ register_oracle(
     "fused_append_rows", kernel=fused_append_rows,
     reference="paddle_tpu.ops.references:append_rows_reference",
     parity_test="tests/test_oracles.py::TestOracleParity")
+register_oracle(
+    "fused_chunk_pool", kernel=fused_chunk_pool,
+    reference="paddle_tpu.ops.references:chunk_pool_reference",
+    parity_test="tests/test_evabyte_serving.py::TestChunkPool")
 register_oracle(
     "swiglu", kernel=swiglu,
     reference="paddle_tpu.ops.references:swiglu_reference",

@@ -205,7 +205,8 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
                    first_ref, pseq_ref, pfirst_ref,
                    q_ref, k_hbm, v_hbm, o_ref,
                    kbuf, vbuf, acc_ref, m_ref, l_ref, ahead_ref, sem,
-                   *, page_size, rep, tq, total_pages, scale, window):
+                   *, page_size, rep, tq, total_pages, scale, window,
+                   summary=False):
     h = pl.program_id(0)
     t = pl.program_id(1)
     n_pairs = first_ref[pl.num_programs(1)]
@@ -272,6 +273,11 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
         limit = jnp.where((tok >= first_row) & (tok < first_row + nt),
                           kvl_ref[i] - nt + (tok - first_row), -1)
         page0 = 0 if window is None else pseq_ref[pairs + pi]
+        if summary:
+            # the sequence's KV starts with `lo` pooled rows; the rest
+            # of their last page, up to `hi`, is a hole nobody sees
+            lo = kvl_ref[kvl_ref.shape[0] // 2 + i]
+            hi = (lo + page_size - 1) // page_size * page_size
 
         def page(j, ahead):
             ahead = fetch_ahead(*ahead)
@@ -290,6 +296,9 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
             if window is not None:
                 # each row's own lower bound: keys older than its window
                 seen &= in_page > rel - window
+            if summary:
+                at = (page0 + j) * page_size
+                seen &= (in_page < lo - at) | (in_page >= hi - at)
             s = jnp.where(seen, s, _MASKED)
             m_prev = m_ref[:]
             m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
@@ -318,7 +327,8 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
                            kv_lengths, page_tables,
                            scale: Optional[float] = None,
                            window: Optional[int] = None,
-                           v_dim: Optional[int] = None):
+                           v_dim: Optional[int] = None,
+                           summary_rows=None):
     """q [T, H, D] flat new-token buffer; k/v_pages [KV, total_pages,
     page_size, D]; seq_start/num_tokens/kv_lengths [S] int32;
     page_tables [S, pages_per_seq] int32. Sequences own DISJOINT row
@@ -339,6 +349,16 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     attention and compiles the program it did before the argument
     existed.
 
+    `summary_rows` [S] int32 is chunk-summary attention's cache: a
+    sequence's KV, as its page table lists it, STARTS with that many
+    pooled rows, every one visible to every query of the sequence, and
+    the exact rows follow from the next page boundary on (causal as
+    ever, `kv_lengths` counting from the table's first row); the rows
+    between, the unfilled tail of the last pooled page, are seen by
+    nobody. It rides behind `kv_lengths` in that operand, so the launch
+    has the operands it had; `None` compiles the program it did before
+    the argument existed.
+
     VMEM: one [TQ*rep, D] query tile and output tile (double-buffered by
     the pipeline), that much f32 state, and `_page_buffers` K and V
     pages."""
@@ -358,7 +378,7 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
             ragged_paged_attention, scale=scale, window=window,
             v_dim=v_dim))(
                 q, k_pages, v_pages, seq_start, num_tokens, kv_lengths,
-                page_tables)
+                page_tables, summary_rows=summary_rows)
     tq = ragged_tile_tokens(T, rep, q.dtype)
     n_tiles = -(-T // tq)
     Tp, rows = n_tiles * tq, tq * rep
@@ -374,9 +394,12 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     qg = (jnp.pad(q, ((0, Tp - T), (0, 0), (0, 0)))
           .reshape(Tp, KV, rep, D).transpose(1, 0, 2, 3)
           .reshape(KV, Tp * rep, D))
-    tables = (ss, nt, kvl, page_tables.astype(jnp.int32), *work)
     static = dict(page_size=psz, rep=rep, tq=tq, total_pages=total,
                   scale=float(scale), window=window)
+    if summary_rows is not None:
+        kvl = jnp.concatenate([kvl, summary_rows.astype(jnp.int32)])
+        static["summary"] = True
+    tables = (ss, nt, kvl, page_tables.astype(jnp.int32), *work)
     if v_pages is None:
         out = _latent_call(qg, k_pages, tables, v_dim, rows, depth,
                            n_tiles, interpret, **static)
@@ -455,7 +478,8 @@ def ragged_attention_reference(q, k_pages, v_pages, seq_start,
                                num_tokens, kv_lengths, page_tables,
                                scale: Optional[float] = None,
                                window: Optional[int] = None,
-                               v_dim: Optional[int] = None):
+                               v_dim: Optional[int] = None,
+                               summary_rows=None):
     """Plain-XLA oracle with the same ragged semantics (full-softmax,
     gathered pages, jnp.repeat GQA — everything the kernel avoids)."""
     if v_pages is None:
@@ -486,6 +510,10 @@ def ragged_attention_reference(q, k_pages, v_pages, seq_start,
         (pos[None, None, None, :] <= limit[:, None, :, None])
     if window is not None:
         mask &= pos[None, None, None, :] > limit[:, None, :, None] - window
+    if summary_rows is not None:
+        lo = summary_rows.astype(jnp.int32)[:, None]
+        hole = (pos[None, :] >= lo) & (pos[None, :] < -(-lo // psz) * psz)
+        mask &= ~hole[:, None, None, :]
     logits = jnp.where(mask, logits, _NEG)
     m = jnp.max(logits, -1, keepdims=True)
     p = jnp.where(mask, jnp.exp(logits - m), 0.0)

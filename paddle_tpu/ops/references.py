@@ -17,6 +17,7 @@ __all__ = ["rms_norm_reference", "layer_norm_reference",
            "bias_residual_layer_norm_reference",
            "moe_dispatch_combine_reference", "rope_reference",
            "rope_append_reference", "append_rows_reference",
+           "chunk_pool_reference",
            "swiglu_reference", "mla_decode_reference", "gmm_reference",
            "oproj_norm_reference", "megadecode_ffn_reference",
            "qkv_rope_append_reference"]
@@ -89,6 +90,19 @@ def rope_append_reference(q, k, v, cos, sin, k_pages, v_pages,
 def append_rows_reference(pages, rows, page_idx, page_off):
     return pages.at[:, page_idx, page_off, :].set(
         rows.astype(pages.dtype).swapaxes(0, 1))
+
+
+def chunk_pool_reference(k_pages, v_pages, phi, mu, page_idx, chunk_idx, *,
+                         chunk: int, scale: float):
+    f32 = jnp.float32
+    rows = chunk_idx[:, None] * chunk + jnp.arange(chunk)[None, :]
+    k = k_pages[:, page_idx[:, None], rows].astype(f32)    # [KV, P, c, D]
+    v = v_pages[:, page_idx[:, None], rows].astype(f32)
+    a = jax.nn.softmax(
+        scale * jnp.einsum("hpcd,hd->hpc", k, phi.astype(f32)), -1)
+    kt = jnp.einsum("hpc,hpcd->phd", a, k) + mu.astype(f32)
+    vt = jnp.einsum("hpc,hpcd->phd", a, v)
+    return kt.astype(k_pages.dtype), vt.astype(v_pages.dtype)
 
 
 def swiglu_reference(gate, up=None):

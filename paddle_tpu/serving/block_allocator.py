@@ -36,6 +36,10 @@ Attention"):
     old positions plus one step's new ones (`window_span`). Window
     pages are never shared, so `fork` / `adopt` / `export_seq` /
     `import_seq` refuse an allocator with a window.
+  - TWO PAGE LISTS IN ONE LAYER (`ChunkSummaryAllocator`): chunk-summary
+    attention keeps, in EVERY layer, the exact rows of the current
+    tumbling window and one pooled row for each closed chunk. Both
+    lists draw from the ONE pool; they differ in growth and lifetime.
 """
 
 from __future__ import annotations
@@ -47,7 +51,7 @@ import numpy as np
 from .. import observability as _obs
 from .. import resilience as _res
 
-__all__ = ["PageBlockAllocator"]
+__all__ = ["PageBlockAllocator", "ChunkSummaryAllocator"]
 
 _PAGES_USED = _obs.registry().gauge(
     "serving.engine.pages_used", "pool pages currently allocated to "
@@ -553,3 +557,195 @@ class PageBlockAllocator:
         seq.wreserved -= 1
         self._wreserved_total -= 1
         return self._wfree.pop()
+
+
+class ChunkSummaryAllocator(PageBlockAllocator):
+    """Page manager of chunk-summary (EVA) attention: a sequence's cache
+    in every layer is TWO lists of rows of one shape, from ONE pool.
+
+      - the WINDOW list: the exact K/V rows of the current window of
+        `window` tokens, one row a token. The window tumbles: when the
+        sequence's length reaches a multiple of `window` all its pages
+        go back to the pool together (`release_window`) and the next
+        token starts a new list.
+      - the SUMMARY list: one pooled row for each chunk of `chunk`
+        tokens, written when the chunk's last token is (`extend` hands
+        out its page then), visible to attention only from the close of
+        the chunk's window on, kept until the sequence ends.
+
+    A sequence of `total` tokens reserves min(window pages, ceil(total /
+    page_size)) + ceil(total / (chunk * page_size)) pages; a window's
+    close returns its pages to the free list and re-reserves what the
+    next window can need. Rows of neither list are shared or moved:
+    `fork` / `adopt` / `export_seq` / `import_seq` / `shrink` refuse.
+
+    `attention_view` is the sequence as attention reads it: the pages of
+    the VISIBLE summary rows, then the window's pages — one page table,
+    one KV length counted from its first row, and the count of summary
+    rows (the rest of their last page is a hole that
+    `ragged_paged_attention(summary_rows=)` masks)."""
+
+    def __init__(self, num_pages: int, page_size: int, window: int,
+                 chunk: int, max_tokens: int):
+        if window % page_size or page_size % chunk:
+            raise ValueError(
+                f"a window ({window}) is whole pages ({page_size}) and a "
+                f"page whole chunks ({chunk})")
+        self.span = int(window)         # (`window` is the sliding kind's)
+        self.chunk = int(chunk)
+        self.window_list_pages = window // page_size
+        super().__init__(num_pages, page_size, self.table_pages(
+            page_size, window, chunk, max_tokens))
+        self.max_tokens = int(max_tokens)
+        self._total: Dict[object, int] = {}
+
+    @staticmethod
+    def table_pages(page_size: int, window: int, chunk: int,
+                    max_tokens: int) -> int:
+        """Entries of a sequence's page table as attention reads it:
+        the pooled pages of `max_tokens` tokens, then a window's."""
+        return -(-max_tokens // (chunk * page_size)) + window // page_size
+
+    # ---------------------------------------------------- reservation
+    def _check_new(self, seq_id, total_tokens: int) -> None:
+        if seq_id in self._seqs:
+            raise ValueError(f"sequence {seq_id!r} already allocated")
+        if not 1 <= total_tokens <= self.max_tokens:
+            raise ValueError(f"{total_tokens} tokens outside "
+                             f"[1, {self.max_tokens}]")
+
+    def _window_need(self, tokens_left: int) -> int:
+        return min(self.window_list_pages, -(-tokens_left // self.page_size))
+
+    def _need_pages(self, total_tokens: int, share_tokens: int = 0) -> int:
+        return (self._window_need(total_tokens)
+                + -(-total_tokens // (self.chunk * self.page_size)))
+
+    def _refuse(self, what: str):
+        raise NotImplementedError(
+            f"{what}: pooled rows and a tumbling window's pages are never "
+            f"shared, moved or rolled back (chunk-summary attention)")
+
+    def fork(self, *a, **k):
+        self._refuse("fork")
+
+    def adopt(self, *a, **k):
+        self._refuse("adopt")
+
+    def export_seq(self, *a, **k):
+        self._refuse("export_seq")
+
+    def import_seq(self, *a, **k):
+        self._refuse("import_seq")
+
+    def shrink(self, *a, **k):
+        self._refuse("shrink")
+
+    # ------------------------------------------------------ lifecycle
+    def allocate(self, seq_id, total_tokens: int) -> None:
+        super().allocate(seq_id, total_tokens)
+        self._total[seq_id] = int(total_tokens)
+
+    def extend(self, seq_id, n_tokens: int = 1) -> List[Tuple[int, int]]:
+        """Make the next `n_tokens` rows writable: a window page at each
+        page boundary, and the summary page of each chunk that closes.
+        The new tokens may not straddle a window, and the window before
+        must have been released. Never copies."""
+        seq = self._seqs[seq_id]
+        ps, W, c = self.page_size, self.span, self.chunk
+        first, last = seq.length, seq.length + n_tokens - 1
+        if n_tokens < 1 or first // W != last // W:
+            raise ValueError(
+                f"sequence {seq_id!r}: tokens {first}..{last} straddle a "
+                f"window of {W}")
+        if first % W == 0 and seq.wpages:
+            raise RuntimeError(
+                f"sequence {seq_id!r} starts a window at {first} with the "
+                f"last one's pages not released")
+        if last >= self._total[seq_id]:
+            raise ValueError(
+                f"sequence {seq_id!r} overflows its {self._total[seq_id]} "
+                f"reserved tokens at token {last}")
+        while len(seq.wpages) <= last % W // ps:
+            seq.wpages.append(self._pop_page(seq))
+        while len(seq.pages) < -(-((last + 1) // c) // ps):
+            seq.pages.append(self._pop_page(seq))
+        seq.length += n_tokens
+        return []
+
+    def release_window(self, seq_id) -> int:
+        """If the sequence stands at a window's close, return the
+        window's pages to the pool, all together, and reserve what the
+        next window can need. Returns how many were freed. A launch in
+        flight may still read them: every later write goes through a
+        later launch, which the device runs after it."""
+        seq = self._seqs[seq_id]
+        if not seq.wpages or seq.length % self.span:
+            return 0
+        freed = len(seq.wpages)
+        for pg in seq.wpages:
+            self._ref[pg] -= 1
+            self._free.append(pg)
+        seq.wpages = []
+        owed = self._window_need(self._total[seq_id] - seq.length)
+        seq.reserved += owed
+        self._reserved_total += owed
+        return freed
+
+    def free(self, seq_id) -> None:
+        seq = self._seqs[seq_id]
+        seq.pages, seq.wpages = seq.pages + seq.wpages, []
+        del self._total[seq_id]
+        super().free(seq_id)
+
+    # -------------------------------------------------------- queries
+    def attention_view(self, seq_id) -> Tuple[np.ndarray, int, int]:
+        """(page table [pages_per_seq], summary rows, KV length) of the
+        sequence as its NEWEST tokens' queries read it: the summaries
+        of every closed window, then the current window's rows."""
+        seq = self._seqs[seq_id]
+        closed = max(seq.length - 1, 0) // self.span
+        rows = closed * (self.span // self.chunk)
+        n_sp = -(-rows // self.page_size)
+        t = np.zeros(self.pages_per_seq, np.int32)
+        t[:n_sp] = seq.pages[:n_sp]
+        t[n_sp:n_sp + len(seq.wpages)] = seq.wpages
+        return t, rows, (n_sp * self.page_size
+                         + seq.length - closed * self.span)
+
+    def token_pages(self, seq_id, positions: np.ndarray) -> np.ndarray:
+        """Physical page of each position of the CURRENT window (its
+        row is `position % page_size`)."""
+        wp = np.asarray(self._seqs[seq_id].wpages, np.int32)
+        return wp[positions % self.span // self.page_size]
+
+    def closing_chunks(self, seq_id, start: int, n: int) -> np.ndarray:
+        """[k, 4] int32 (source page, chunk within it, summary page,
+        summary row) of each chunk whose last token is among positions
+        [start, start + n) of the current window."""
+        seq = self._seqs[seq_id]
+        ps, c = self.page_size, self.chunk
+        ends = np.arange(-(-(start + 1) // c) * c - 1, start + n, c)
+        j = ends // c
+        return np.stack([self.token_pages(seq_id, ends), ends % ps // c,
+                         np.asarray(seq.pages, np.int32)[j // ps],
+                         j % ps], 1).astype(np.int32).reshape(-1, 4)
+
+    def table(self, seq_id) -> np.ndarray:
+        return self.attention_view(seq_id)[0]
+
+    def pages_by_list(self) -> Tuple[int, int]:
+        """Pool pages held by (summary lists, window lists)."""
+        return (sum(len(s.pages) for s in self._seqs.values()),
+                sum(len(s.wpages) for s in self._seqs.values()))
+
+    def stats(self) -> Dict[str, float]:
+        st = super().stats()
+        live = sum(
+            s.length // self.chunk + (s.length - max(s.length - 1, 0)
+                                      // self.span * self.span
+                                      if s.wpages else 0)
+            for s in self._seqs.values())
+        cap = st["pages_used"] * self.page_size
+        st["fragmentation"] = 1.0 - live / cap if cap else 0.0
+        return st

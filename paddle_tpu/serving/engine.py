@@ -25,7 +25,10 @@ The engine chooses its programs itself, once, at construction
   (MLA) whose latent rank is a multiple of 128 — its cache row
   (latent | rope key) is stored padded to whole 128-lane registers
   (`_latent_row_width`: 512 + 64 -> 640), K is the row, V its first
-  `kv_lora_rank` columns, one page fetch for both.
+  `kv_lora_rank` columns, one page fetch for both. Chunk-summary
+  (EVA) attention takes it too and nothing else: its sequence is one
+  page table of pooled rows followed by the current window's exact
+  rows (`ChunkSummaryAllocator`, `_eva_unified_body`).
 - **split**: the PR-5 alternating `_prefill_chunk` / `_decode`
   dispatches over `paged_attention`/`append_to_cache`, built only
   where the ragged kernel's tiling constraints do not hold on a TPU
@@ -67,13 +70,14 @@ from ..observability import costmodel as _costmodel
 from ..observability import tracing as _tracing
 from ..generation import (_decode_params, _dq, _ffn_apply, _llama_weights,
                           _mm_w)
-from ..ops.fused import (fused_append_rows, fused_layer_norm,
-                         fused_rms_norm, fused_rope_append)
+from ..ops.fused import (fused_append_rows, fused_chunk_pool,
+                         fused_layer_norm, fused_rms_norm,
+                         fused_rope_append)
 from ..ops.paged_attention import append_to_cache, paged_attention
 from ..ops.pallas_ragged import (ragged_kernel_eligible,
                                  ragged_paged_attention,
                                  ragged_pages_visited)
-from .block_allocator import PageBlockAllocator
+from .block_allocator import ChunkSummaryAllocator, PageBlockAllocator
 from .handoff import (HANDOFF_BYTES, HANDOFF_PAGES, HANDOFFS,
                       KVPageHandoff)
 from .prefix_cache import PrefixCache
@@ -156,6 +160,21 @@ def _ragged_step_eligible(heads, kv: int, d: int, page_size: int) -> bool:
     return (jax.default_backend() != "tpu"
             or all(ragged_kernel_eligible(h, kv, d, page_size)
                    for h in heads))
+
+
+def _refuse_shared_cache(why: str, enable_prefix_cache, spec_decode: int,
+                         role: str) -> None:
+    """What a cache whose pages are released mid-sequence cannot serve,
+    refused at construction; `why` says which cache."""
+    if enable_prefix_cache:
+        raise ValueError(why + "a cached prefix cannot be adopted; "
+                         "enable_prefix_cache must be off")
+    if spec_decode:
+        raise ValueError(why + "a rejected draft cannot roll the cache "
+                         "back; spec_decode must be 0")
+    if role != "colocated":
+        raise ValueError(why + "export_request / import_request are not "
+                         "supported; role must be 'colocated'")
 
 
 def _latent_row_width(r: int, dr: int) -> int:
@@ -263,7 +282,8 @@ class _Launch:
 #: of the others the record keeps the later launch's
 _ADDITIVE = frozenset(
     ("decode_rows", "prefill_rows", "rows_dropped", "pages_live",
-     "pages_visited") + _tracing.STEP_COUNTS_BY_KIND[:4])
+     "pages_visited") + _tracing.STEP_COUNTS_BY_KIND[:4]
+    + _tracing.STEP_COUNTS_EVA[:4])
 
 
 class ServingEngine:
@@ -350,6 +370,14 @@ class ServingEngine:
         if self.prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
         self.pages_per_seq = -(-self.max_context // self.page_size)
+        # chunk-summary (EVA) attention: every layer keeps the exact
+        # rows of a tumbling window beside one pooled row a chunk — two
+        # page lists a sequence from ONE pool, read through one table
+        self._eva = self._family == "eva"
+        if self._eva:
+            self.pages_per_seq = ChunkSummaryAllocator.table_pages(
+                self.page_size, cfg.window_size, cfg.chunk_size,
+                self.max_context)
         if num_pages is None:
             num_pages = self.max_slots * self.pages_per_seq + 1
         self.num_pages = int(num_pages)
@@ -368,19 +396,11 @@ class ServingEngine:
                 f"one window size a model, got {windows}")
         self._window = windows[0] if windows else None
         if self._window is not None:
-            why = (f"this model has sliding-window layers (window "
-                   f"{self._window}): their pages are released as the "
-                   f"window passes them, so ")
-            if enable_prefix_cache:
-                raise ValueError(why + "a cached prefix cannot be "
-                                 "adopted; enable_prefix_cache must be off")
-            if spec_decode:
-                raise ValueError(why + "a rejected draft cannot roll the "
-                                 "cache back; spec_decode must be 0")
-            if role != "colocated":
-                raise ValueError(why + "export_request / import_request "
-                                 "are not supported; role must be "
-                                 "'colocated'")
+            _refuse_shared_cache(
+                f"this model has sliding-window layers (window "
+                f"{self._window}): their pages are released as the "
+                f"window passes them, so ", enable_prefix_cache,
+                spec_decode, role)
             enable_prefix_cache = False
             # a live donor's early pages are gone from the window pool
             prefix_sharing = False
@@ -392,12 +412,30 @@ class ServingEngine:
             self.num_window_pages = (self.max_slots + 1) * cap + 1
         else:
             self.num_window_pages = 0
+        if self._eva:
+            _refuse_shared_cache(
+                "this model's layers are chunk-summary attention (pooled "
+                "rows beside a tumbling window's pages, which are released "
+                "together at its close), so ", enable_prefix_cache,
+                spec_decode, role)
+            if self.prefill_chunk % cfg.chunk_size:
+                raise ValueError(
+                    f"prefill_chunk {self.prefill_chunk} must be whole "
+                    f"chunks of {cfg.chunk_size}: a chunk is pooled in "
+                    f"the launch that writes its last token")
+            # neither list's rows are shared, and a preempted sequence's
+            # snapshot over two lists is not built (ROADMAP R4)
+            enable_prefix_cache = prefix_sharing = preemption = False
+            self.allocator = ChunkSummaryAllocator(
+                self.num_pages, self.page_size, cfg.window_size,
+                cfg.chunk_size, self.max_context)
+        else:
+            self.allocator = PageBlockAllocator(
+                self.num_pages, self.page_size, self.pages_per_seq,
+                window=self._window,
+                window_pages=self.num_window_pages or None,
+                window_span=self.prefill_chunk)
         self.prefix_sharing = bool(prefix_sharing)
-        self.allocator = PageBlockAllocator(
-            self.num_pages, self.page_size, self.pages_per_seq,
-            window=self._window,
-            window_pages=self.num_window_pages or None,
-            window_span=self.prefill_chunk)
         admission = getattr(config, "_admission", None)
         self._default_deadline_s = getattr(config, "_deadline_s", None)
         self.scheduler = Scheduler(
@@ -463,6 +501,12 @@ class ServingEngine:
                 f"{kv} KV heads x {d}, page {self.page_size}")
         if spec_decode < 0:
             raise ValueError("spec_decode must be >= 0")
+        if self._eva and jax.default_backend() == "tpu" and \
+                cfg.chunk_size % (32 // jnp.dtype(dt).itemsize):
+            raise ValueError(
+                f"chunk_size {cfg.chunk_size} is not whole sublane tiles "
+                f"of {jnp.dtype(dt).name}: fused_chunk_pool reads a chunk "
+                f"as one block")
         # speculative decoding: each decode slot owns 1 + spec_k flat
         # rows of the unified step (n-gram drafts verified in the SAME
         # ragged launch). The split path has no multi-row slots, so
@@ -483,6 +527,8 @@ class ServingEngine:
             self._count_names += _tracing.STEP_COUNTS_MOE
         if self._family == "mla":
             self._count_names += _tracing.STEP_COUNTS_LATENT
+        if self._eva:
+            self._count_names += _tracing.STEP_COUNTS_EVA
         self._counts = dict.fromkeys(self._count_names, 0)
         # the pool handles this step's launches were handed (dead
         # arrays, no buffers): `pools_in_place` asks them at account
@@ -667,6 +713,11 @@ class ServingEngine:
                 "a model with sliding-window layers reserves its window "
                 "pages for the prefill chunk it was built with: the chunk "
                 "can only shrink, and spec_decode stays 0")
+        if self._eva and (new_k or new_chunk
+                          % self._p["cfg"].chunk_size):
+            raise ValueError(
+                "a model with chunk-summary layers takes prefill chunks of "
+                "whole pooling chunks, and spec_decode stays 0")
         self.prefill_chunk = new_chunk
         self.spec_k = new_k
         self._build_programs()
@@ -818,6 +869,13 @@ class ServingEngine:
                 _TRACE.sample_gauges(_COUNTER_GAUGES)
             if self.controller is not None:
                 self.controller.on_step(out)
+            if self._eva:
+                # a window that closed in this call's launch: its pages
+                # go back to the pool now, all together (the launch in
+                # flight reads them before any later launch writes them)
+                freed = sum(self.allocator.release_window(req.request_id)
+                            for _, req in self.scheduler.active()
+                            if self.allocator.has_seq(req.request_id))
             full = (self.allocator.num_pages - 1,
                     self.allocator.num_pages - 1 - self.allocator.free_pages)
             win = (0, 0)
@@ -836,6 +894,14 @@ class ServingEngine:
                     "pool_pages_used.full": full[1],
                     "pool_pages_total.window": win[0],
                     "pool_pages_used.window": win[1]})
+            if self._eva:
+                summ, exact = self.allocator.pages_by_list()
+                self._counts.update({
+                    "window_pages_freed": freed,
+                    "pool_pages_total.summary": full[0],
+                    "pool_pages_total.exact": full[0],
+                    "pool_pages_used.summary": summ,
+                    "pool_pages_used.exact": exact})
             self._counts["pool_pages_total"] = full[0] + win[0]
             self._counts["pool_pages_used"] = full[1] + win[1]
             # did every launch of the step write its pools in place?
@@ -863,7 +929,11 @@ class ServingEngine:
             self._family, kv_heads=kv, head_dim=d,
             kv_latent_dim=(d if self._family == "mla" else 0),
             kv_dtype_bytes=self._kv_itemsize)
-        lens = [self.allocator.seq_length(req.request_id)
+        # (chunk-summary layers read a sequence's visible pooled rows and
+        # its window's, not its length)
+        length = (lambda rid: self.allocator.attention_view(rid)[2]) \
+            if self._eva else self.allocator.seq_length
+        lens = [length(req.request_id)
                 for _, req in self.scheduler.active()
                 if self.allocator.has_seq(req.request_id)]
         spec_rows = 1 + self.spec_k
@@ -1072,6 +1142,11 @@ class ServingEngine:
         return handoff
 
     def _no_handoff(self, what: str) -> None:
+        if self._eva:
+            raise NotImplementedError(
+                f"{what}: this model's layers are chunk-summary attention; "
+                f"a KV-page handoff of pooled rows and a tumbling "
+                f"window's pages is not implemented")
         if self._window is not None:
             raise NotImplementedError(
                 f"{what}: this model has sliding-window layers, whose "
@@ -1499,7 +1574,13 @@ class ServingEngine:
         it). `src` [T] says where each row's token comes from: -1 the
         host's `tok`, else that row of the launch in flight's tokens.
         With sliding-window layers `tables` and `tok_page` are pairs:
-        (the full kind's, the window kind's)."""
+        (the full kind's, the window kind's). With chunk-summary layers
+        a sequence's table is its visible pooled pages then its
+        window's, `kv_lengths` counts from the table's first row and
+        three operands are pairs: (`kv_lengths`, the pooled rows
+        visible), (`tok_page`, [2, P] pages: where each closing chunk's
+        tokens lie and where its pooled row goes), (`tok_off`, [2, P]:
+        the chunk within that page, the row within that one)."""
         B, C, K = self.max_slots, self.prefill_chunk, self.spec_k
         R = 1 + K
         base = B * R
@@ -1517,6 +1598,42 @@ class ServingEngine:
         if windowed:
             wtables = np.zeros((S, nj), np.int32)
             wtok_page = np.zeros(T, np.int32)
+        eva = self._eva
+        if eva:
+            # one pooling slot a decode row, then one for each chunk
+            # the prefill rows can close; idle ones read and write the
+            # trash page
+            P = B + C // self.allocator.chunk
+            summary_rows = np.zeros(S, np.int32)
+            pool_page = np.zeros((2, P), np.int32)
+            pool_off = np.zeros((2, P), np.int32)
+
+        def place(rid, seq, r0, pos, p0):
+            """Sequence `seq`'s new rows [r0, r0 + pos.size) at
+            positions `pos` (the allocator extended): its page table and
+            KV length as attention reads them and the page each row
+            lands in; with chunk-summary layers also the chunks the
+            rows close, in pooling slots from p0."""
+            rows = slice(r0, r0 + pos.size)
+            positions[rows] = pos
+            num_tokens[seq] = pos.size
+            tok_off[rows] = pos % ps
+            if eva:
+                (tables[seq], summary_rows[seq],
+                 kv_lengths[seq]) = self.allocator.attention_view(rid)
+                tok_page[rows] = self.allocator.token_pages(rid, pos)
+                ck = self.allocator.closing_chunks(rid, int(pos[0]),
+                                                   pos.size)
+                pool_page[:, p0:p0 + len(ck)] = ck[:, (0, 2)].T
+                pool_off[:, p0:p0 + len(ck)] = ck[:, (1, 3)].T
+                return
+            kv_lengths[seq] = pos[-1] + 1
+            tables[seq] = tbl = self.allocator.table(rid)
+            tok_page[rows] = tbl[pos // ps]
+            if windowed:
+                wtables[seq] = wt = self.allocator.window_table(rid)
+                wtok_page[rows] = wt[pos // ps]
+
         drafts: Dict[int, List[int]] = {}
         for slot, req, feed in rows:
             ln = self.allocator.seq_length(req.request_id)
@@ -1533,44 +1650,25 @@ class ServingEngine:
             nt = 1 + len(d)
             self._apply_copies(self.allocator.extend(req.request_id, nt),
                                req)
-            tbl = self.allocator.table(req.request_id)
             r0 = slot * R
-            pos = ln + np.arange(nt)
             if feed is None:
                 tok[r0:r0 + nt] = [req.pending] + d
             else:
                 src[r0] = feed      # its token is still on the device
-            positions[r0:r0 + nt] = pos
-            num_tokens[slot] = nt
-            kv_lengths[slot] = ln + nt
-            tables[slot] = tbl
-            tok_page[r0:r0 + nt] = tbl[pos // ps]
-            tok_off[r0:r0 + nt] = pos % ps
-            if windowed:
-                wtables[slot] = wt = self.allocator.window_table(
-                    req.request_id)
-                wtok_page[r0:r0 + nt] = wt[pos // ps]
+            place(req.request_id, slot, r0, ln + np.arange(nt), slot)
             if d:
                 _TRACE.stamp(req.request_id, "draft", tokens=len(d))
         n, start = 0, 0
         if preq is not None:
             start = self._sent_pos(preq, fl)
             n = min(C, int(preq.prompt.size) - start)
+            if eva:     # a chunk may not straddle a window
+                n = min(n, self.allocator.span
+                        - start % self.allocator.span)
             self._apply_copies(self.allocator.extend(preq.request_id, n),
                                preq)
-            tbl = self.allocator.table(preq.request_id)
-            chunk = np.arange(n)
             tok[base:base + n] = preq.prompt[start:start + n]
-            positions[base:base + n] = start + chunk
-            num_tokens[S - 1] = n
-            kv_lengths[S - 1] = start + n
-            tables[S - 1] = tbl
-            tok_page[base:base + n] = tbl[(start + chunk) // ps]
-            tok_off[base:base + n] = (start + chunk) % ps
-            if windowed:
-                wtables[S - 1] = wt = self.allocator.window_table(
-                    preq.request_id)
-                wtok_page[base:base + n] = wt[(start + chunk) // ps]
+            place(preq.request_id, S - 1, base, start + np.arange(n), B)
         counts = {"decode_rows": int(num_tokens[:B].sum()),
                   "prefill_rows": n}
         if self._family == "mla":
@@ -1592,6 +1690,21 @@ class ServingEngine:
         live = int(np.sum(-(-kv_lengths // ps)))
         counts["pages_live"] = live
         counts["pages_visited"] = visited(0)
+        if eva:
+            seen = num_tokens > 0
+            ends = positions[(seq_start + num_tokens - 1)[seen]] + 1
+            counts.update({
+                "summary_rows_live": int(summary_rows[seen].sum()),
+                "window_rows_live": int(
+                    (kv_lengths - -(-summary_rows // ps) * ps)[seen].sum()),
+                "summaries_written": int((pool_page[1] > 0).sum()),
+                "windows_closed": int(
+                    (ends % self.allocator.span == 0).sum()),
+                "cache_row_bytes": 2 * self._kv_geom[0] * self._kv_geom[1]
+                * self._kv_itemsize})
+            return ((tok, positions, num_tokens, (kv_lengths, summary_rows),
+                     tables, (tok_page, pool_page), (tok_off, pool_off)),
+                    src, drafts, n, start, counts)
         if not windowed:
             return ((tok, positions, num_tokens, kv_lengths, tables,
                      tok_page, tok_off), src, drafts, n, start, counts)
@@ -1773,6 +1886,8 @@ class ServingEngine:
         return self._llama_prefill_body()
 
     def _make_unified_body(self):
+        if self._family == "eva":
+            return self._eva_unified_body()
         if self._family == "gpt":
             return self._gpt_unified_body()
         if self._family == "mla":
@@ -1857,6 +1972,72 @@ class ServingEngine:
             if moe_stats:
                 return (logits, new_pools, _greedy(logits),
                         _moe_step_counts(moe_stats))
+            return logits, new_pools, _greedy(logits)
+
+        return step
+
+    def _eva_unified_body(self):
+        """Chunk-summary (EVA) attention on the one chain, a float32
+        residual stream. Per layer: norm (gain 1 + g) -> q / k / v ->
+        `fused_rope_append` into the window's pages -> `eva_pool`: every
+        chunk whose last token this launch wrote is pooled from the
+        cache (`fused_chunk_pool`) and its row appended to the
+        sequence's summary page, where every later window's queries
+        read it -> `eva_attention`: ONE softmax over the visible pooled
+        rows and the window's exact rows (`summary_rows=`) -> o-proj and
+        SwiGLU accumulated and added in float32. Head 0 of the byte
+        heads is the next byte: the logits rows returned and sampled."""
+        cfg = self._p["cfg"]
+        H, D, V = cfg.num_attention_heads, cfg.head_dim, cfg.vocab_size
+        eps, ck = cfg.rms_norm_eps, cfg.chunk_size
+        scale = D ** -0.5
+        B, C = self.max_slots, self.prefill_chunk
+        T = B + C
+        seq_start = _seq_starts(B, 1)
+        f32 = jnp.float32
+
+        def step(w, tok, pools, positions, num_tokens, kv_lengths,
+                 tables, tok_page, tok_off):
+            kv_lengths, summary_rows = kv_lengths
+            tok_page, pool_page = tok_page
+            tok_off, pool_off = tok_off
+            dt = w["embed"].dtype
+
+            def norm(x, gain):      # float32 in, the weights' type out
+                y = x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps)
+                return (y * gain).astype(dt)
+
+            x = w["embed"][tok][None].astype(f32)        # [1, T, H*D]
+            c, s = w["cos"][positions], w["sin"][positions]
+            new_pools = []
+            for L, (kp, vp) in zip(w["layers"], pools):
+                h = norm(x, L["ln1"])
+                q, kp, vp = fused_rope_append(
+                    (h @ L["wq"]).reshape(T, H, D),
+                    (h @ L["wk"]).reshape(T, H, D),
+                    (h @ L["wv"]).reshape(T, H, D), c, s, kp, vp,
+                    tok_page, tok_off)
+                with jax.named_scope("eva_pool"):
+                    kt, vt = fused_chunk_pool(
+                        kp, vp, L["phi"], L["mu"], pool_page[0],
+                        pool_off[0], chunk=ck, scale=scale)
+                    kp = fused_append_rows(kp, kt, pool_page[1],
+                                           pool_off[1])
+                    vp = fused_append_rows(vp, vt, pool_page[1],
+                                           pool_off[1])
+                new_pools.append((kp, vp))
+                with jax.named_scope("eva_attention"):
+                    o = ragged_paged_attention(
+                        q, kp, vp, seq_start, num_tokens, kv_lengths,
+                        tables, scale=scale, summary_rows=summary_rows)
+                x = x + jnp.dot(o.reshape(1, T, H * D), L["wo"],
+                                preferred_element_type=f32)
+                h2 = norm(x, L["ln2"])
+                x = x + jnp.dot(jax.nn.silu(h2 @ L["wg"]) * (h2 @ L["wu"]),
+                                L["wd"], preferred_element_type=f32)
+            last = _logit_rows(norm(x, w["norm"]), seq_start, num_tokens, 0)
+            logits = jnp.dot(last, w["head"][:, :V],
+                             preferred_element_type=f32)
             return logits, new_pools, _greedy(logits)
 
         return step

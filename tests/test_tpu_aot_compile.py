@@ -279,6 +279,49 @@ def test_latent_append_and_attention_compile_at_the_axk1_cell_shapes(chip):
         chip.refusals.get(_ragged_latent)
 
 
+def _eva_layer_kernels(q, k, v, c, s, kp, vp, phi, mu, ss, nt, kvl, sr, tab,
+                       pg, off, pool_pg, pool_off):
+    """One EvaByte layer's kernels in the engine's order: rope + append
+    into the window's pages, the pooling of the chunks that closed, the
+    two appends of their pooled rows, ONE softmax over pooled and exact
+    rows."""
+    from paddle_tpu.ops.fused import (fused_append_rows, fused_chunk_pool,
+                                      fused_rope_append)
+    from paddle_tpu.ops.pallas_ragged import ragged_paged_attention
+    q, kp, vp = fused_rope_append(q, k, v, c, s, kp, vp, pg, off)
+    kt, vt = fused_chunk_pool(kp, vp, phi, mu, pool_pg[0], pool_off[0],
+                              chunk=16, scale=D ** -0.5)
+    kp = fused_append_rows(kp, kt, pool_pg[1], pool_off[1])
+    vp = fused_append_rows(vp, vt, pool_pg[1], pool_off[1])
+    return ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab,
+                                  summary_rows=sr), kp, vp
+
+
+def test_chunk_summary_kernels_compile_at_the_evabyte_cell_shapes(chip):
+    """`evabyte-6.5b-serve-pp4-d8` as its cell runs it: T = 32 slots + a
+    256-row chunk, 32 query heads over 32 KV heads x 128 (tiles of 128
+    tokens), page 256, ONE pool of 272 pages, 33 sequences whose table
+    is 8 pooled + 8 window pages, 48 pooling slots of 16 rows. A K and a
+    V page of all 32 heads in and out is 16 MiB of VMEM blocks: the
+    rope + append asks for its own limit (the default 16 MiB refused it
+    off-chip, PR 35)."""
+    from paddle_tpu.ops.pallas_ragged import (ragged_kernel_eligible,
+                                              ragged_tile_tokens)
+    t, hq, psz, n_pages, s, nj, p = 288, 32, 256, 272, 33, 16, 48
+    assert ragged_kernel_eligible(hq, hq, D, psz)
+    assert ragged_tile_tokens(t, 1, jnp.bfloat16) == 128
+    seq, row = chip.shape((s,), I32), chip.shape((t,), I32)
+    tok = chip.shape((t, hq, D))
+    pool = chip.shape((hq, n_pages, psz, D))
+    trig = chip.shape((t, D // 2), F32)
+    vec = chip.shape((hq, D))
+    slots = chip.shape((2, p), I32)
+    assert chip.compiles(
+        _eva_layer_kernels, tok, tok, tok, trig, trig, pool, pool, vec, vec,
+        seq, seq, seq, seq, chip.shape((s, nj), I32), row, row, slots,
+        slots), chip.refusals.get(_eva_layer_kernels)
+
+
 def _serve_norm_and_linears(x, nw, w8, s8, w4, s4):
     """What the engine's split chain adds around the kernels above:
     the rms norm and, on quantized deploys, the weight-only linears."""
