@@ -266,8 +266,12 @@ def _offset_stepped(offset: ast.AST, kenv: Dict[str, ast.AST],
                     prefetch: Set[str]) -> bool:
     """True when the scatter offset is a per-grid-step scalar-prefetch
     table read (``off_ref[t]`` with ``t = pl.program_id(k)``) — the
-    engine's append contract makes those destinations disjoint."""
+    engine's append contract makes those destinations disjoint — or
+    such a read plus a term (``base + j``: row j of the step's run)."""
     offset = _resolve_local(offset, kenv)
+    if isinstance(offset, ast.BinOp) and isinstance(offset.op, ast.Add):
+        return _offset_stepped(offset.left, kenv, prefetch) \
+            or _offset_stepped(offset.right, kenv, prefetch)
     if isinstance(offset, ast.Subscript) \
             and km._subscript_root(offset) in prefetch:
         idx = offset.slice

@@ -173,14 +173,19 @@ CANONICAL: Dict[str, Dict[str, Any]] = {
         token_tiled=False,
         families={"llama": dict(H=40, D=128)},
     ),
+    # the site is the append by cache-tile runs (the rope before it is
+    # `_rope_forward`'s, above; the cost with rope=False leaves it out):
+    # G = 8 runs of one row each over the resident [T, KV, D] roped-K
+    # and V rows, one (KV, 1, tile, D) block a plane a run
     "fused_rope_append": dict(
         kernel="fused_rope_append",
-        bindings=dict(T=8, Hq=32, KV=8, D=128, psz=32, d2=64),
-        in_widths=[2, 2, 2, 2, 2, 2, 2], out_widths=[2, 2, 2],
-        cost_kwargs=dict(T=8, Hq=32, KV=8, D=128, page_size=32),
-        token_tiled=True,
-        families={"llama": dict(Hq=32, KV=8, D=128),
-                  "gpt": dict(Hq=32, KV=32, D=128)},
+        bindings=dict(T=8, KV=8, D=128, tile=16, G=8),
+        in_widths=[2, 2, 2, 2], out_widths=[2, 2],
+        cost_kwargs=dict(T=8, Hq=32, KV=8, D=128, page_size=32, runs=8,
+                         tile=16, rope=False),
+        token_tiled=False,
+        families={"llama": dict(KV=8, D=128),
+                  "gpt": dict(KV=32, D=128)},
     ),
     "fused_append_rows": dict(
         kernel="fused_append_rows",
@@ -189,6 +194,14 @@ CANONICAL: Dict[str, Dict[str, Any]] = {
         cost_kwargs=dict(T=8, KV=8, D=128, page_size=32),
         token_tiled=True,
         families={"mla": dict(KV=1, D=576)},
+    ),
+    # EvaByte's pooling: 6 closed chunks of 16 rows, 32 KV heads
+    "fused_chunk_pool": dict(
+        kernel="fused_chunk_pool",
+        bindings=dict(P=6, KV=32, D=128, chunk=16),
+        in_widths=[2, 2, 4, 4], out_widths=[2, 2],
+        cost_kwargs=dict(P=6, KV=32, D=128, chunk=16),
+        token_tiled=False,
     ),
     "_swiglu_forward": dict(
         kernel="swiglu",

@@ -194,19 +194,37 @@ def _c_fused_rope(*, B: int, S: int, H: int, D: int, Hk: int = 0,
 
 @register_cost("fused_rope_append")
 def _c_fused_rope_append(*, T: int, Hq: int, KV: int, D: int,
-                         page_size: int, dtype_bytes: int = 2
-                         ) -> CostEstimate:
-    """Rope(q,k) + paged K/V row scatter in one launch, grid (T,): q/k/v
-    token rows + cos/sin, plus the aliased page blocks — each token
-    read-modify-writes one (KV, page_size, D) block per cache plane."""
-    rows = T * (Hq + 2 * KV) * D * dtype_bytes
-    trig = T * D * dtype_bytes
-    pages = 2 * T * KV * page_size * D * dtype_bytes   # k_pages + v_pages
+                         page_size: int, dtype_bytes: int = 2,
+                         runs: Optional[int] = None,
+                         tile: Optional[int] = None,
+                         rope: bool = True) -> CostEstimate:
+    """Rope(q, k) over all T rows (`fused_rope`'s dense row blocks),
+    then the paged K/V append by RUNS, grid (runs,): the roped K rows
+    and the V rows resident once, and ONE (KV, tile, D) block of each
+    cache plane read and written a run. `tile` defaults to the sublane
+    tile of the dtype (the whole page where a page is not whole tiles),
+    `runs` to T: a decode row is a run of its own, a prefill chunk makes
+    one for each `tile` rows. `rope=False` is the append launch alone
+    (the site `analysis/vmemmodel.py` checks this entry against)."""
+    if tile is None:
+        tile = 32 // dtype_bytes
+        tile = tile if page_size % tile == 0 else page_size
+    runs = T if runs is None else runs
+    rows = 2 * T * KV * D * dtype_bytes                # roped K, V
+    tiles = 2 * runs * KV * tile * D * dtype_bytes     # k_pages + v_pages
+    est = CostEstimate(bytes_read=rows + tiles, bytes_written=tiles,
+                       flops=0, breakdown={"activations": rows,
+                                           "kv": 2 * tiles})
+    if not rope:
+        return est
+    front = cost("fused_rope", B=1, S=T, H=Hq, Hk=KV, D=D,
+                 dtype_bytes=dtype_bytes)
     return CostEstimate(
-        bytes_read=rows + trig + pages,
-        bytes_written=(T * Hq * D * dtype_bytes) + pages,
-        flops=3 * T * (Hq + KV) * D,
-        breakdown={"activations": rows + trig, "kv": 2 * pages})
+        bytes_read=front.bytes_read + est.bytes_read,
+        bytes_written=front.bytes_written + est.bytes_written,
+        flops=front.flops,
+        breakdown={"activations": front.hbm_bytes + rows,
+                   "kv": 2 * tiles})
 
 
 @register_cost("fused_append_rows")

@@ -21,12 +21,14 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_rms_norm", "fused_rope", "swiglu", "fused_layer_norm",
            "fused_bias_residual_layer_norm", "fused_moe_dispatch_combine",
-           "fused_rope_append", "fused_append_rows", "fused_chunk_pool"]
+           "fused_rope_append", "fused_append_rows", "fused_chunk_pool",
+           "append_tile", "append_run_table", "append_run_count"]
 
 
 def _interpret() -> bool:
@@ -369,117 +371,168 @@ def _put_row(page, off, row):
                      page.astype(jnp.float32)).astype(page.dtype)
 
 
-def _rope_append_kernel(pg_ref, off_ref,              # scalar prefetch
-                        q_ref, k_ref, v_ref, c_ref, s_ref,
-                        kin_ref, vin_ref,
-                        qo_ref, kp_ref, vp_ref):
-    t = pl.program_id(0)
-    c = c_ref[0].astype(jnp.float32)                   # [1, D/2]
-    s = s_ref[0].astype(jnp.float32)
+def append_tile(dtype, page_size: int) -> int:
+    """Rows of the cache tile a run of `fused_rope_append` fills: one
+    sublane tile of the pools' dtype (16 rows of bfloat16, 8 of
+    float32), or the whole page where a page is not whole tiles (the
+    tier-1 engines' pages of 8 under bfloat16)."""
+    rows = 32 // jnp.dtype(dtype).itemsize
+    return rows if page_size % rows == 0 else page_size
 
-    def rot(x):                                        # [h, D] f32
-        d2 = x.shape[-1] // 2
-        x1, x2 = x[:, :d2], x[:, d2:]
-        return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
 
-    qo_ref[0] = rot(q_ref[0].astype(jnp.float32)).astype(qo_ref.dtype)
-    # first visit of a page seeds the resident output block from the
-    # aliased input fetch; consecutive same-page tokens keep the block
-    # resident, so their earlier row writes survive (re-seeding would
-    # clobber them with the stale pre-launch page)
-    prev = pg_ref[jnp.maximum(t - 1, 0)]
+def _run_starts(xp, live, first, page_idx, page_off, tile: int):
+    """[T] bool over `xp` (numpy on the host, jnp inside the step): the
+    rows that open a run — a live row that is its sequence's first or
+    whose (page, tile of the page) differs from the row before."""
+    part = page_off // tile
+    moved = xp.concatenate([
+        xp.ones(1, bool), (page_idx[1:] != page_idx[:-1])
+        | (part[1:] != part[:-1])])
+    return live & (first | moved)
 
-    @pl.when((t == 0) | (pg_ref[t] != prev))
-    def _seed():
+
+def append_run_count(live, first, page_idx, page_off, tile: int) -> int:
+    """The host's count of the runs `append_run_table` makes of the same
+    row tables (numpy; `live` / `first` [T] bool: the rows a sequence
+    owns, and each sequence's first): the step record's `append_runs`."""
+    return int(_run_starts(np, live, first, page_idx, page_off,
+                           tile).sum())
+
+
+def append_run_table(seq_start, num_tokens, page_idx, page_off, *,
+                     tile: int, max_runs: int):
+    """The work list of `fused_rope_append`, on the device: [5 * G]
+    int32 (G = max_runs), five columns of G laid end to end — a run's
+    first row of the flat buffer, its rows, its page, the tile of that
+    page, the first row's offset inside the tile. A RUN is the
+    consecutive live rows that land in one tile of one cache page
+    (`_run_starts`); idle rows make none. Runs past the last live one
+    have no rows and name the last live run's tile (the trash page's
+    first when nothing is live), so the kernel's block does not move.
+    `max_runs` bounds the runs the row tables can make (the engine:
+    every decode row its own, a chunk of C rows ceil(C / tile) + 1);
+    compare-and-sum over [G, T], no scatter."""
+    G = max_runs
+    T = page_idx.shape[0]
+    i32 = jnp.int32
+    page_idx, page_off = page_idx.astype(i32), page_off.astype(i32)
+    row = jnp.arange(T, dtype=i32)[:, None]
+    owned = (row >= seq_start) & (row < seq_start + num_tokens)
+    live = owned.any(-1)
+    start = _run_starts(jnp, live, (owned & (row == seq_start)).any(-1),
+                        page_idx, page_off, tile)
+    run_of = jnp.cumsum(start.astype(i32)) - 1          # [T]
+    g = jnp.arange(G, dtype=i32)[:, None]
+    mine = run_of[None] == g                            # [G, T]
+    opens = mine & start[None]
+
+    def at_first(x):                                    # [T] -> [G]
+        return jnp.sum(jnp.where(opens, x[None], 0), -1, dtype=i32)
+
+    rows = jnp.sum(mine & live[None], -1, dtype=i32)
+    last = jnp.maximum(jnp.sum(start, dtype=i32) - 1, 0)
+    keep = jnp.minimum(g[:, 0], last)                   # padded -> last
+    return jnp.concatenate([
+        at_first(jnp.arange(T, dtype=i32)), rows,
+        at_first(page_idx)[keep], at_first(page_off // tile)[keep],
+        at_first(page_off % tile)])
+
+
+def _append_runs_kernel(runs_ref,                     # scalar prefetch
+                        k_ref, v_ref, kin_ref, vin_ref, kp_ref, vp_ref,
+                        *, G: int):
+    g = pl.program_id(0)
+    n = runs_ref[G + g]
+
+    # every live run is a tile of its own: read it, put the run's rows,
+    # write it back. A run without rows repeats the last live run's
+    # block, which stays resident and is not touched; only where nothing
+    # is live does step 0 carry its (trash) tile through unchanged
+    @pl.when((n > 0) | (g == 0))
+    def _run():
         kp_ref[:] = kin_ref[:]
         vp_ref[:] = vin_ref[:]
+        first = runs_ref[g]
+        base = runs_ref[4 * G + g]
 
-    off = off_ref[t]
-    kr = rot(k_ref[0].astype(jnp.float32))
-    v = v_ref[0]
-    for h in range(kr.shape[0]):
-        kp_ref[h, 0] = _put_row(kp_ref[h, 0], off, kr[h:h + 1])
-        vp_ref[h, 0] = _put_row(vp_ref[h, 0], off, v[h:h + 1])
+        def put(j, carry):
+            kr = k_ref[first + j].astype(jnp.float32)  # [KV, D]
+            v = v_ref[first + j]
+            off = base + j
+            for h in range(kr.shape[0]):
+                kp_ref[h, 0] = _put_row(kp_ref[h, 0], off, kr[h:h + 1])
+                vp_ref[h, 0] = _put_row(vp_ref[h, 0], off, v[h:h + 1])
+            return carry
+
+        jax.lax.fori_loop(0, n, put, 0)
 
 
-def fused_rope_append(q, k, v, cos, sin, k_pages, v_pages,
-                      page_idx, page_off):
+def fused_rope_append(q, k, v, cos, sin, k_pages, v_pages, runs):
     """Rotary embedding (per-TOKEN cos/sin rows) on q and k plus the
-    paged-cache K/V row scatter in ONE pallas_call — the serving
-    engine's fused rope+append step.
+    paged-cache K/V append of the LIVE rows — the serving engine's rope
+    + append step. The rope is dense row-block work over all T rows
+    (`_rope_forward`, float32 inside); the append works by RUNS, the
+    consecutive rows that land in one sublane tile of one cache page
+    (`append_run_table`): grid (G,), each step holding one
+    (KV, 1, tile, D) block of K and of V.
 
     q [T, Hq, D]; k/v [T, KV, D]; cos/sin [T, D/2]; k/v_pages
-    [KV, total_pages, page_size, D]; page_idx/page_off [T] int32 name
-    where token t's K/V row lands. Returns (q_roped, k_pages, v_pages)
-    with the page pools donated through input_output_aliases (the HBM
-    buffers update in place on TPU — callers must use the RETURNED
-    pools, never re-read the donated arguments; paddlelint's PF402
-    checks the caller side statically, and PE502 proves the kernel
-    itself only reads each donated input before its first aliased
-    write, so no defensive copy is ever needed here). The contract
-    reaches the serving engine's own handle: `ServingEngine` builds its
-    jitted programs with the pools donated, so XLA hands this kernel
-    the live buffers and not a copy of them — the engine's pools are
-    dead after the launch, and `ServingEngine._launch` rebinds them to
-    the returned ones in the same statement.
+    [KV, total_pages, page_size, D]; runs [5 * G] int32 from
+    `append_run_table` over the same row tables, with `append_tile` of
+    these pools. Returns (q_roped, k_pages, v_pages) with the page
+    pools donated through input_output_aliases (the HBM buffers update
+    in place on TPU — callers must use the RETURNED pools, never
+    re-read the donated arguments; paddlelint's PF402 checks the caller
+    side statically, and PE502 proves the kernel itself only reads each
+    donated input before its first aliased write, so no defensive copy
+    is ever needed here). The contract reaches the serving engine's own
+    handle: `ServingEngine` builds its jitted programs with the pools
+    donated, so XLA hands this kernel the live buffers and not a copy
+    of them — the engine's pools are dead after the launch, and
+    `ServingEngine._launch` rebinds them to the returned ones in the
+    same statement.
 
-    Contract: tokens that share a page are ADJACENT in t (the engine's
-    prefill chunk); non-adjacent revisits only happen on the trash page
-    (inactive slots), whose content is garbage by design. Identity rope
-    (cos=1, sin=0) turns this into a pure fused append for the GPT
-    family."""
-    T, Hq, D = q.shape
-    KV = k.shape[1]
+    Idle rows write nothing: a tile no run names — the trash page's
+    too — comes back bit for bit. Contract: no two runs name one tile
+    (a sequence's rows are consecutive positions, and no two sequences
+    write one page). Identity rope (cos=1, sin=0) turns this into a
+    pure append for the GPT family."""
+    T, KV, D = k.shape
     total, psz = k_pages.shape[1], k_pages.shape[2]
-    d2 = D // 2
+    tile = append_tile(k_pages.dtype, psz)
+    G = runs.shape[0] // 5
+    q = _rope_forward(q[None], cos, sin)[0]
+    k = _rope_forward(k[None], cos, sin)[0]
 
-    def tok_map(t, pg, off):
-        return (t, 0, 0)
+    def row_map(g, runs):
+        return (0, 0, 0)
 
-    def cs_map(t, pg, off):
-        return (t, 0, 0)
+    def tile_map(g, runs):
+        return (0, jnp.clip(runs[2 * G + g], 0, total - 1),
+                jnp.clip(runs[3 * G + g], 0, psz // tile - 1), 0)
 
-    def page_map(t, pg, off):
-        return (0, jnp.clip(pg[t], 0, total - 1), 0, 0)
-
-    page_spec = pl.BlockSpec((KV, 1, psz, D), page_map)
-    # a K and a V page of every KV head, in and out, double-buffered by
-    # the pipeline: with many KV heads (32 x 256 x 128 bf16 = 2 MiB a
-    # page) that passes the compiler's default scoped VMEM, and the
-    # kernel asks for what it needs; below it, nothing is asked
-    resident = 8 * KV * psz * D * k_pages.dtype.itemsize
-    vmem = {"vmem_limit_bytes": resident + (8 << 20)} \
-        if resident > (12 << 20) else {}
+    # the roped K rows and the V rows of the whole launch stay resident
+    # (fetched once); a run reads its rows at a dynamic LEADING index
+    row_spec = pl.BlockSpec((T, KV, D), row_map)
+    tile_spec = pl.BlockSpec((KV, 1, tile, D), tile_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                 # page_idx, page_off
-        grid=(T,),
-        in_specs=[
-            pl.BlockSpec((1, Hq, D), tok_map),
-            pl.BlockSpec((1, KV, D), tok_map),
-            pl.BlockSpec((1, KV, D), tok_map),
-            # one-token blocks need their last two dims equal to the
-            # array's: the trig rows ride as [T, 1, d2]
-            pl.BlockSpec((1, 1, d2), cs_map),
-            pl.BlockSpec((1, 1, d2), cs_map),
-            page_spec,
-            page_spec,
-        ],
-        out_specs=[pl.BlockSpec((1, Hq, D), tok_map),
-                   page_spec, page_spec],
+        num_scalar_prefetch=1,                 # the run table
+        grid=(G,),
+        in_specs=[row_spec, row_spec, tile_spec, tile_spec],
+        out_specs=[tile_spec, tile_spec],
     )
-    return pl.pallas_call(
-        _rope_append_kernel,
+    kp, vp = pl.pallas_call(
+        functools.partial(_append_runs_kernel, G=G),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((T, Hq, D), q.dtype),
-                   jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+        out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
                    jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
-        # flat-input indices INCLUDE the scalar-prefetch operands
-        input_output_aliases={7: 1, 8: 2},
+        # flat-input indices INCLUDE the scalar-prefetch operand
+        input_output_aliases={3: 0, 4: 1},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",), **vmem),
+            dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
-    )(page_idx.astype(jnp.int32), page_off.astype(jnp.int32),
-      q, k, v, cos[:, None, :], sin[:, None, :], k_pages, v_pages)
+    )(runs.astype(jnp.int32), k, v, k_pages, v_pages)
+    return q, kp, vp
 
 
 def _append_rows_kernel(pg_ref, off_ref, r_ref, pin_ref, po_ref):
@@ -499,8 +552,12 @@ def fused_append_rows(pages, rows, page_idx, page_off):
     """Scatter per-token cache rows [T, KV, D] into paged pools
     [KV, total_pages, page_size, D] at (page_idx[t], page_off[t]) in one
     pallas_call — the MLA engine's latent-row append (its rope runs on
-    split q_pe/k_pe shapes before the rows are concatenated). Same
-    adjacency contract as fused_rope_append."""
+    split q_pe/k_pe shapes before the rows are concatenated) and the
+    chunk-summary engine's pooled rows. grid (T,), one row and one whole
+    page block a step. Contract: tokens that share a page are ADJACENT
+    in t (the engine's prefill chunk); non-adjacent revisits only happen
+    on the trash page (inactive slots), whose content is garbage by
+    design."""
     T, KV, D = rows.shape
     total, psz = pages.shape[1], pages.shape[2]
 

@@ -397,7 +397,7 @@ def fused_qkv_rope_append(h, w, scale, bias, cos, sin, k_pages, v_pages,
     nope_dim+rope_dim] with its rope tail rotated, pool)`` — the
     absorbed kv_b einsums stay outside.
 
-    Same adjacency contract as fused_rope_append: tokens sharing a page
+    Same adjacency contract as fused_append_rows: tokens sharing a page
     are adjacent in t; callers must use the RETURNED pools, never
     re-read the donated arguments."""
     T, H = h.shape

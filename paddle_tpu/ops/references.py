@@ -75,15 +75,21 @@ def rope_reference(q, k, cos, sin):
 
 
 def rope_append_reference(q, k, v, cos, sin, k_pages, v_pages,
-                          page_idx, page_off):
+                          page_idx, page_off, live):
+    """Every row of q roped; the roped K row and the V row of each LIVE
+    row t at (page_idx[t], page_off[t]) of every head. An idle row
+    writes nothing (its page index is sent out of range and dropped).
+    `fused_rope_append` takes the same rows as runs
+    (`ops.fused.append_run_table`)."""
     c = cos.astype(jnp.float32)[:, None, :]           # [T, 1, D/2]
     s = sin.astype(jnp.float32)[:, None, :]
     qr = _rotate_half(q.astype(jnp.float32), c, s).astype(q.dtype)
     kr = _rotate_half(k.astype(jnp.float32), c, s)
-    kp = k_pages.at[:, page_idx, page_off, :].set(
-        kr.astype(k_pages.dtype).swapaxes(0, 1))
-    vp = v_pages.at[:, page_idx, page_off, :].set(
-        v.astype(v_pages.dtype).swapaxes(0, 1))
+    page = jnp.where(live, page_idx, k_pages.shape[1])
+    kp = k_pages.at[:, page, page_off, :].set(
+        kr.astype(k_pages.dtype).swapaxes(0, 1), mode="drop")
+    vp = v_pages.at[:, page, page_off, :].set(
+        v.astype(v_pages.dtype).swapaxes(0, 1), mode="drop")
     return qr, kp, vp
 
 

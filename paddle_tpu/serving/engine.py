@@ -70,7 +70,8 @@ from ..observability import costmodel as _costmodel
 from ..observability import tracing as _tracing
 from ..generation import (_decode_params, _dq, _ffn_apply, _llama_weights,
                           _mm_w)
-from ..ops.fused import (fused_append_rows, fused_chunk_pool,
+from ..ops.fused import (append_run_count, append_run_table, append_tile,
+                         fused_append_rows, fused_chunk_pool,
                          fused_layer_norm, fused_rms_norm,
                          fused_rope_append)
 from ..ops.paged_attention import append_to_cache, paged_attention
@@ -281,8 +282,8 @@ class _Launch:
 #: launches (a retire forced between two steps, then the step's own);
 #: of the others the record keeps the later launch's
 _ADDITIVE = frozenset(
-    ("decode_rows", "prefill_rows", "rows_dropped", "pages_live",
-     "pages_visited") + _tracing.STEP_COUNTS_BY_KIND[:4]
+    ("decode_rows", "prefill_rows", "append_runs", "rows_dropped",
+     "pages_live", "pages_visited") + _tracing.STEP_COUNTS_BY_KIND[:4]
     + _tracing.STEP_COUNTS_EVA[:4])
 
 
@@ -547,6 +548,9 @@ class ServingEngine:
         # costmodel budget
         self._kv_geom = (kv, d)
         self._kv_itemsize = int(jnp.dtype(dt).itemsize)
+        # the unit of work of the rope + append kernel
+        # (`ops.fused.append_run_table`): the rows of one cache tile
+        self._append_tile = append_tile(dt, self.page_size)
         planes = 1 if self._family == "mla" else 2
         self._hbm_weights_bytes = _costmodel.tree_bytes(self._w)
         self._hbm_pool_bytes = sum(
@@ -1594,6 +1598,8 @@ class ServingEngine:
         tables = np.zeros((S, nj), np.int32)   # idle -> trash page 0
         tok_page = np.zeros(T, np.int32)
         tok_off = np.zeros(T, np.int32)
+        row_live = np.zeros(T, bool)    # rows a sequence owns ...
+        row_first = np.zeros(T, bool)   # ... and each sequence's first
         windowed = self._window is not None
         if windowed:
             wtables = np.zeros((S, nj), np.int32)
@@ -1617,6 +1623,7 @@ class ServingEngine:
             rows = slice(r0, r0 + pos.size)
             positions[rows] = pos
             num_tokens[seq] = pos.size
+            row_live[rows], row_first[r0] = True, True
             tok_off[rows] = pos % ps
             if eva:
                 (tables[seq], summary_rows[seq],
@@ -1671,14 +1678,20 @@ class ServingEngine:
             place(preq.request_id, S - 1, base, start + np.arange(n), B)
         counts = {"decode_rows": int(num_tokens[:B].sum()),
                   "prefill_rows": n}
-        if self._family == "mla":
+        seq_start = np.append(np.arange(B) * R, base)
+        if self._family == "mla":       # its rows go in one by one
             counts["chunk_kv_len"] = int(kv_lengths[S - 1])
             counts["latent_row_bytes"] = \
                 self._kv_geom[1] * self._kv_itemsize
+        else:
+            # the runs the launch's rope + append makes of these rows,
+            # one layer of each kind (the kinds' pages turn together)
+            counts["append_runs"] = (1 + windowed) * append_run_count(
+                row_live, row_first, tok_page, tok_off,
+                self._append_tile)
         # pages that hold this launch's tokens, against the K/V page
         # fetches the ragged kernel makes for each KV head (a sequence's
         # pages once for every query tile that holds rows of it)
-        seq_start = np.append(np.arange(B) * R, base)
 
         def visited(kind, window=None):
             # one layer of each head count of the kind, summed
@@ -1912,6 +1925,22 @@ class ServingEngine:
     # layers' counts]).
     # No flags_guard: nothing in the chain is flag-routed.
 
+    def _run_table(self, seq_start):
+        """(num_tokens, tok_page, tok_off) -> the work list of the
+        step's `fused_rope_append` calls, made on the device from the
+        row tables the step already takes. Its length is the most runs
+        a launch can make: every decode row its own, the chunk's one
+        for each tile it touches."""
+        tile = self._append_tile
+        bound = (self.max_slots * (1 + self.spec_k)
+                 + -(-self.prefill_chunk // tile) + 1)
+
+        def run_table(num_tokens, tok_page, tok_off):
+            return append_run_table(seq_start, num_tokens, tok_page,
+                                    tok_off, tile=tile, max_runs=bound)
+
+        return run_table
+
     def _llama_unified_body(self):
         cfg = self._p["cfg"]
         KV, D = cfg.num_key_value_heads, cfg.head_dim
@@ -1923,6 +1952,7 @@ class ServingEngine:
         R = 1 + K
         T = B * R + C
         seq_start = _seq_starts(B, R)
+        run_table = self._run_table(seq_start)
 
         def step(w, tok, pools, positions, num_tokens, kv_lengths,
                  tables, tok_page, tok_off):
@@ -1933,6 +1963,9 @@ class ServingEngine:
                     for sfx in sorted({a["rope"] for a in attn_static})}
             if not isinstance(tables, tuple):
                 tables, tok_page = (tables,), (tok_page,)
+            # the append's runs, once a kind of cache
+            runs = [run_table(num_tokens, page, tok_off)
+                    for page in tok_page]
             new_pools = []
             moe_stats = [] if count_moe else None
             live = _owned_rows(T, seq_start, num_tokens) \
@@ -1945,7 +1978,7 @@ class ServingEngine:
                 # the layer's kind of cache: its page table and the
                 # physical pages its new rows land in
                 kind = int(window is not None)
-                table, page = tables[kind], tok_page[kind]
+                table = tables[kind]
                 h = fused_rms_norm(x, L["ln1"], eps)
                 q, k, v = (_mm_w(h, L, "wq"), _mm_w(h, L, "wk"),
                            _mm_w(h, L, "wv"))
@@ -1953,7 +1986,7 @@ class ServingEngine:
                     q, k, v = q + L["bq"], k + L["bk"], v + L["bv"]
                 q, kp, vp = fused_rope_append(
                     q.reshape(T, Hh, D), k.reshape(T, KV, D),
-                    v.reshape(T, KV, D), c, s, kp, vp, page, tok_off)
+                    v.reshape(T, KV, D), c, s, kp, vp, runs[kind])
                 new_pools.append((kp, vp))
                 o = ragged_paged_attention(q, kp, vp, seq_start,
                                            num_tokens, kv_lengths,
@@ -1994,6 +2027,7 @@ class ServingEngine:
         B, C = self.max_slots, self.prefill_chunk
         T = B + C
         seq_start = _seq_starts(B, 1)
+        run_table = self._run_table(seq_start)
         f32 = jnp.float32
 
         def step(w, tok, pools, positions, num_tokens, kv_lengths,
@@ -2009,14 +2043,14 @@ class ServingEngine:
 
             x = w["embed"][tok][None].astype(f32)        # [1, T, H*D]
             c, s = w["cos"][positions], w["sin"][positions]
+            runs = run_table(num_tokens, tok_page, tok_off)
             new_pools = []
             for L, (kp, vp) in zip(w["layers"], pools):
                 h = norm(x, L["ln1"])
                 q, kp, vp = fused_rope_append(
                     (h @ L["wq"]).reshape(T, H, D),
                     (h @ L["wk"]).reshape(T, H, D),
-                    (h @ L["wv"]).reshape(T, H, D), c, s, kp, vp,
-                    tok_page, tok_off)
+                    (h @ L["wv"]).reshape(T, H, D), c, s, kp, vp, runs)
                 with jax.named_scope("eva_pool"):
                     kt, vt = fused_chunk_pool(
                         kp, vp, L["phi"], L["mu"], pool_page[0],
@@ -2050,6 +2084,7 @@ class ServingEngine:
         R = 1 + K
         T = B * R + C
         seq_start = _seq_starts(B, R)
+        run_table = self._run_table(seq_start)
 
         def step(w, tok, pools, positions, num_tokens, kv_lengths,
                  tables, tok_page, tok_off):
@@ -2058,6 +2093,7 @@ class ServingEngine:
             # pure fused K/V append, bitwise-exact on q/k
             c = jnp.ones((T, hd // 2), x.dtype)
             s = jnp.zeros((T, hd // 2), x.dtype)
+            runs = run_table(num_tokens, tok_page, tok_off)
             new_pools = []
             for L, (kp, vp) in zip(w["layers"], pools):
                 h = fused_layer_norm(x, L["ln1w"], L["ln1b"], eps)
@@ -2065,8 +2101,7 @@ class ServingEngine:
                 q, k, v = jnp.split(qkv, 3, axis=-1)
                 q, kp, vp = fused_rope_append(
                     q.reshape(T, nh, hd), k.reshape(T, nh, hd),
-                    v.reshape(T, nh, hd), c, s, kp, vp,
-                    tok_page, tok_off)
+                    v.reshape(T, nh, hd), c, s, kp, vp, runs)
                 new_pools.append((kp, vp))
                 o = ragged_paged_attention(q, kp, vp, seq_start,
                                            num_tokens, kv_lengths,

@@ -431,17 +431,20 @@ class TestSummaryMask:
 #: (1d73225), toy widths, on the CPU under the suite's matmul precision:
 #: chunk-summary attention came in beside these programs, not through
 #: them. A PR that means to change one records the new text here.
+#: PR 36 (rope + append by cache-tile runs) re-recorded the four that
+#: call `fused_rope_append`; `mla` (`fused_append_rows`) is PR 35's
+#: parent's text still.
 LOWERED_AT_PARENT = {
-    "llama": "b7cab2b6d51f3f7d912a0eda8470daf3786b7c4fe1b972b327dc5470ffa4"
-             "2718",
-    "moe": "d2214d4cea1c2882a8469b6392e2a36103fe9df5a97e811777e755618b5b22"
-           "cd",
+    "llama": "796a58c1dfb7e68b1df0482ead04c086fa984da24e4b682670e8b54c8533"
+             "7e22",
+    "moe": "4faec1b74aa7a77f2da610cb075890537d69df83938fa03bd04650c8d83ddc"
+           "77",
     "mla": "95716f117ba052bcd4cb7ab773eb7723b3cea776fb15ead50b69783698625c"
            "6c",
-    "gpt": "4d453b8a8fed29de3bd063a4a0e15ffbb1e0c77998b999a834a801e03b0da9"
-           "e1",
-    "laguna": "298ae97f63b6b40424ea3a185664004331c67edfc9e7c59392382bfea89"
-              "ce1d8",
+    "gpt": "2a8c5ea9067a9806f3e646b554493aec66a3d7f4ce0ba416b4dd42c85d182e"
+           "ea",
+    "laguna": "0884e3e52935f0f89c1420aebe50b30f0427df3011f1a368736f06fbb34"
+              "1055c",
 }
 
 

@@ -9,13 +9,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.ops.fused import fused_append_rows, fused_rope_append
+from paddle_tpu.ops.fused import (append_run_count, append_run_table,
+                                  append_tile, fused_append_rows,
+                                  fused_rope_append)
 from paddle_tpu.ops.pallas_ragged import (_work_list,
                                           ragged_attention_reference,
                                           ragged_kernel_eligible,
                                           ragged_paged_attention,
                                           ragged_pages_visited,
                                           ragged_tile_tokens)
+from paddle_tpu.ops.references import rope_append_reference
 
 
 def _setup(T, S, H, KV, D, psz, pps, seed=0, dtype=jnp.float32):
@@ -296,71 +299,166 @@ class TestRaggedKernelParity:
         assert not ragged_kernel_eligible(3, 2, 128, 16)  # H % KV != 0
 
 
+def _row_tables(B, R, C, psz, dec, chunk, pps=4):
+    """The engine's row tables in small: decode slot s owns rows
+    [s*R, (s+1)*R), the prefill chunk the rows from B*R; sequence s
+    holds pages 1 + s*pps .. (page 0 is the trash page idle rows name).
+    `dec` {slot: (first position, rows)}, `chunk` (first position, rows)
+    or None. Returns seq_start, num_tokens, page, off, positions."""
+    S, T = B + 1, B * R + C
+    seq_start = np.append(np.arange(B) * R, B * R).astype(np.int32)
+    num_tokens = np.zeros(S, np.int32)
+    page, off = np.zeros(T, np.int32), np.zeros(T, np.int32)
+    positions = np.zeros(T, np.int32)
+    seqs = dict(dec)
+    if chunk is not None:
+        seqs[B] = chunk
+    for s_, (p0, n) in seqs.items():
+        pos = p0 + np.arange(n)
+        assert pos[-1] < pps * psz
+        rows = slice(seq_start[s_], seq_start[s_] + n)
+        num_tokens[s_] = n
+        positions[rows] = pos
+        page[rows] = 1 + s_ * pps + pos // psz
+        off[rows] = pos % psz
+    return seq_start, num_tokens, page, off, positions
+
+
+def _runs_by_hand(seq_start, num_tokens, page, off, tile):
+    """The run rule as a loop: [(first row, rows, page, tile, offset)]."""
+    runs = []
+    for s0, n in zip(seq_start, num_tokens):
+        for t in range(s0, s0 + n):
+            key = (page[t], off[t] // tile)
+            if t > s0 and key == (page[t - 1], off[t - 1] // tile):
+                runs[-1][1] += 1
+            else:
+                runs.append([t, 1, *key, off[t] % tile])
+    return sorted(map(tuple, runs))
+
+
+#: name -> (B, R, C, page_size, dec, chunk); bfloat16 tiles hold 16
+#: rows, float32 tiles 8
+LAYOUTS = {
+    "decode_rows_only": (6, 1, 16, 32,
+                         {0: (5, 1), 2: (31, 1), 3: (32, 1), 5: (0, 1)},
+                         None),
+    "chunk_from_a_tile_boundary": (2, 1, 32, 32, {1: (9, 1)}, (32, 32)),
+    "chunk_from_mid_tile": (2, 1, 32, 32, {0: (3, 1)}, (5, 30)),
+    "chunk_across_a_page": (2, 1, 32, 32, {}, (20, 32)),
+    "spec_rows_across_a_tile": (3, 4, 16, 32,
+                                {0: (14, 4), 2: (30, 3)}, (0, 7)),
+    "all_rows_idle": (4, 1, 16, 32, {}, None),
+    # every decode row live, the chunk one tile more than its rows fill
+    "run_table_full": (4, 1, 32, 32,
+                       {0: (1, 1), 1: (2, 1), 2: (3, 1), 3: (4, 1)},
+                       (7, 32)),
+    "page_of_8_under_a_tile_of_16": (3, 1, 16, 8, {0: (7, 1), 1: (8, 1)},
+                                     (12, 14)),
+}
+
+
 class TestFusedRopeAppend:
-    def _setup(self, T, Hq, KV, D, psz, total, seed=0):
-        rng = np.random.RandomState(seed)
-        q = jnp.asarray(rng.randn(T, Hq, D), jnp.float32)
-        k = jnp.asarray(rng.randn(T, KV, D), jnp.float32)
-        v = jnp.asarray(rng.randn(T, KV, D), jnp.float32)
-        cos = jnp.asarray(rng.randn(T, D // 2), jnp.float32)
-        sin = jnp.asarray(rng.randn(T, D // 2), jnp.float32)
-        kp = jnp.asarray(rng.randn(KV, total, psz, D), jnp.float32)
-        vp = jnp.asarray(rng.randn(KV, total, psz, D), jnp.float32)
-        return q, k, v, cos, sin, kp, vp
+    """`fused_rope_append` against the plain `rope_append_reference`,
+    bit for bit on q and on EVERY page of both pools: the pages and rows
+    no live row names, the trash page's too, come back untouched."""
 
     @staticmethod
-    def _rot(x, c, s):
-        d2 = x.shape[-1] // 2
-        x1, x2 = x[..., :d2], x[..., d2:]
-        cc, ss = c[:, None, :], s[:, None, :]
-        return jnp.concatenate([x1 * cc - x2 * ss,
-                                x2 * cc + x1 * ss], -1)
+    def _check(layout, dtype, KV=2, Hq=4, D=64, identity=False, seed=0):
+        B, R, C, psz, dec, chunk = layout
+        seq_start, num_tokens, page, off, _ = _row_tables(
+            B, R, C, psz, dec, chunk)
+        T, total = B * R + C, 1 + (B + 1) * 4
+        rng = np.random.RandomState(seed)
 
-    def test_rope_and_scatter(self):
-        # engine-shaped page walk: decode rows on distinct pages, then
-        # an adjacent prefill run sharing pages, idle rows on trash 0
-        T, KV, D, psz, total = 7, 2, 64, 4, 9
-        q, k, v, cos, sin, kp, vp = self._setup(T, 4, KV, D, psz, total)
-        pg = jnp.asarray([3, 5, 0, 7, 7, 7, 8], jnp.int32)
-        off = jnp.asarray([1, 3, 0, 0, 1, 2, 0], jnp.int32)
-        qo, kp2, vp2 = fused_rope_append(q, k, v, cos, sin, kp, vp,
-                                         pg, off)
-        np.testing.assert_allclose(np.asarray(qo),
-                                   np.asarray(self._rot(q, cos, sin)),
-                                   atol=1e-6)
-        kref, vref = np.array(kp), np.array(vp)
-        kr = np.asarray(self._rot(k, cos, sin))
-        vr = np.asarray(v)
-        for t in range(T):
-            kref[:, int(pg[t]), int(off[t])] = kr[t]
-            vref[:, int(pg[t]), int(off[t])] = vr[t]
-        # every page except trash 0 must match exactly (V bitwise; K is
-        # roped in f32 in both paths)
-        np.testing.assert_array_equal(np.asarray(vp2)[:, 1:],
-                                      vref[:, 1:])
-        np.testing.assert_allclose(np.asarray(kp2)[:, 1:], kref[:, 1:],
-                                   atol=1e-6)
+        def arr(*shape):
+            return jnp.asarray(rng.randn(*shape), dtype)
 
-    def test_identity_rope_bitwise(self):
-        # cos=1/sin=0 (the GPT family's pure append): bitwise passthrough
-        T, KV, D, psz, total = 4, 2, 64, 4, 5
-        q, k, v, _, _, kp, vp = self._setup(T, 4, KV, D, psz, total,
-                                            seed=1)
-        cos = jnp.ones((T, D // 2), jnp.float32)
-        sin = jnp.zeros((T, D // 2), jnp.float32)
-        pg = jnp.asarray([1, 2, 3, 4], jnp.int32)
-        off = jnp.asarray([0, 1, 2, 3], jnp.int32)
-        qo, kp2, vp2 = fused_rope_append(q, k, v, cos, sin, kp, vp,
-                                         pg, off)
-        np.testing.assert_array_equal(np.asarray(qo), np.asarray(q))
-        kref, vref = np.array(kp), np.array(vp)
-        for t in range(T):
-            kref[:, int(pg[t]), int(off[t])] = np.asarray(k)[t]
-            vref[:, int(pg[t]), int(off[t])] = np.asarray(v)[t]
-        np.testing.assert_array_equal(np.asarray(kp2)[:, 1:],
-                                      kref[:, 1:])
-        np.testing.assert_array_equal(np.asarray(vp2)[:, 1:],
-                                      vref[:, 1:])
+        q, k, v = arr(T, Hq, D), arr(T, KV, D), arr(T, KV, D)
+        if identity:
+            cos = jnp.ones((T, D // 2), dtype)
+            sin = jnp.zeros((T, D // 2), dtype)
+        else:
+            cos, sin = arr(T, D // 2), arr(T, D // 2)
+        kp, vp = arr(KV, total, psz, D), arr(KV, total, psz, D)
+        tile = append_tile(dtype, psz)
+        G = B * R + -(-C // tile) + 1
+        runs = append_run_table(
+            jnp.asarray(seq_start), jnp.asarray(num_tokens),
+            jnp.asarray(page), jnp.asarray(off), tile=tile, max_runs=G)
+        live = np.zeros(T, bool)
+        for s0, n in zip(seq_start, num_tokens):
+            live[s0:s0 + n] = True
+        # jitted as the kernel is: the compiler then rounds the rope of
+        # both the same way (op by op it keeps a product it would fuse)
+        want = jax.jit(rope_append_reference)(
+            q, k, v, cos, sin, kp, vp, jnp.asarray(page),
+            jnp.asarray(off), jnp.asarray(live))
+        got = fused_rope_append(q, k, v, cos, sin, kp, vp, runs)
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype
+            np.testing.assert_array_equal(
+                np.asarray(g.astype(jnp.float32)),
+                np.asarray(w.astype(jnp.float32)))
+        # the reference itself wrote the live rows and nothing else
+        changed = np.any(np.asarray(want[2] != vp), (0, 3))
+        assert changed.sum() <= live.sum()
+        assert not changed[0].any()
+        return runs, G
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bfloat16"])
+    @pytest.mark.parametrize("name", sorted(LAYOUTS))
+    def test_equals_the_reference_bit_for_bit(self, name, dtype):
+        runs, G = self._check(LAYOUTS[name], dtype)
+        n_runs = int((np.asarray(runs)[G:2 * G] > 0).sum())
+        if name == "all_rows_idle":
+            assert n_runs == 0
+        if name == "run_table_full" and dtype == jnp.float32:
+            assert n_runs == G          # no padded run at all
+
+    @pytest.mark.parametrize("KV", [1, 8, 32])
+    def test_kv_heads(self, KV):
+        self._check(LAYOUTS["chunk_from_mid_tile"], jnp.bfloat16, KV=KV,
+                    Hq=max(KV, 4), D=128, seed=KV)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bfloat16"])
+    def test_identity_rope_is_a_pure_append(self, dtype):
+        # cos=1 / sin=0 (the GPT family): q comes back as it went in
+        self._check(LAYOUTS["spec_rows_across_a_tile"], dtype,
+                    identity=True, seed=1)
+
+    @pytest.mark.parametrize("seed", range(6))
+    def test_run_table_is_the_hosts_rule(self, seed):
+        """The device's table, the host's count of it (the step record's
+        `append_runs`) and the rule written as a loop agree on seeded
+        row tables."""
+        rng = np.random.RandomState(seed)
+        B, R, C, psz = 5, 1 + seed % 3, 24, 32
+        tile = (8, 16)[seed % 2]
+        dec = {s_: (int(rng.randint(0, 100)), int(rng.randint(1, R + 1)))
+               for s_ in range(B) if rng.rand() < 0.7}
+        chunk = (int(rng.randint(0, 100)), int(rng.randint(1, C + 1))) \
+            if seed != 3 else None
+        seq_start, num_tokens, page, off, _ = _row_tables(
+            B, R, C, psz, dec, chunk)
+        G = B * R + -(-C // tile) + 1
+        table = np.asarray(append_run_table(
+            jnp.asarray(seq_start), jnp.asarray(num_tokens),
+            jnp.asarray(page), jnp.asarray(off), tile=tile,
+            max_runs=G)).reshape(5, G)
+        want = _runs_by_hand(seq_start, num_tokens, page, off, tile)
+        n = len(want)
+        assert n <= G
+        assert sorted(map(tuple, table[:, :n].T)) == want
+        live, first = np.zeros(len(page), bool), np.zeros(len(page), bool)
+        for s0, k in zip(seq_start, num_tokens):
+            live[s0:s0 + k], first[s0] = True, k > 0
+        assert append_run_count(live, first, page, off, tile) == n
+        # a run past the last live one: no rows, the last live tile
+        assert not table[1, n:].any()
+        assert (table[2:4, n:] == table[2:4, n - 1:n]).all()
 
     def test_append_rows(self):
         # the MLA latent-row scatter (KV=1 single pool)

@@ -166,18 +166,45 @@ def test_fused_qkv_rope_append_compiles(chip, algo):
     assert chip.compiles(f, *_front_args(chip, algo)), chip.refusals.get(f)
 
 
-def _rope_append(q, k, v, c, s, kp, vp, pg, off):
-    from paddle_tpu.ops.fused import fused_rope_append
-    return fused_rope_append(q, k, v, c, s, kp, vp, pg, off)
+def _rope_append(q, k, v, c, s, kp, vp, ss, nt, pg, off):
+    """The step's two pieces: the run table from the row tables, once,
+    and the kernel that works by it (32 slots + a 256-row chunk where T
+    is 288, the smoke's 8 + 32 else)."""
+    from paddle_tpu.ops.fused import (append_run_table, append_tile,
+                                      fused_rope_append)
+    tile = append_tile(kp.dtype, kp.shape[2])
+    slots = ss.shape[0] - 1
+    bound = slots + -(-(q.shape[0] - slots) // tile) + 1
+    runs = append_run_table(ss, nt, pg, off, tile=tile, max_runs=bound)
+    return fused_rope_append(q, k, v, c, s, kp, vp, runs)
 
 
-def test_fused_rope_append_compiles(chip):
-    """The engine's front half: projections, then rope + append."""
-    tok = chip.shape((T,), I32)
-    trig = chip.shape((T, D // 2), F32)
+def _rope_append_args(chip, t, hq, kv, s, n_pages, psz):
+    tok, seq = chip.shape((t,), I32), chip.shape((s,), I32)
+    trig = chip.shape((t, D // 2), F32)
+    pool = chip.shape((kv, n_pages, psz, D))
+    return (chip.shape((t, hq, D)), chip.shape((t, kv, D)),
+            chip.shape((t, kv, D)), trig, trig, pool, pool, seq, seq,
+            tok, tok)
+
+
+@pytest.mark.parametrize("t,hq,kv,s,n_pages,psz", [
+    (T, HQ, KV, S, NP, PSZ), (288, 32, 8, 33, 187, 256),
+    (288, 32, 32, 33, 272, 256), (288, 72, 8, 33, 160, 256),
+    (288, 48, 8, 33, 1280, 256)],
+    ids=["smoke", "mistral", "evabyte_kv32", "laguna_hq72", "laguna_hq48"])
+def test_fused_rope_append_compiles(chip, t, hq, kv, s, n_pages, psz):
+    """The engine's front half: projections, then rope + append by
+    cache-tile runs — one (KV, 1, 16, 128) block of K and of V a grid
+    step, at the smoke's widths and at the serving cells' (Mistral,
+    EvaByte's 32 KV heads, Laguna's two head counts). The compiler's
+    default VMEM holds each: nothing asks for more."""
+    import inspect
+    from paddle_tpu.ops import fused
+    assert "vmem_limit_bytes" not in inspect.getsource(
+        fused.fused_rope_append)
     assert chip.compiles(
-        _rope_append, chip.shape((T, HQ, D)), chip.shape((T, KV, D)),
-        chip.shape((T, KV, D)), trig, trig, *_pools(chip), tok, tok), \
+        _rope_append, *_rope_append_args(chip, t, hq, kv, s, n_pages, psz)),\
         chip.refusals.get(_rope_append)
 
 
@@ -285,10 +312,9 @@ def _eva_layer_kernels(q, k, v, c, s, kp, vp, phi, mu, ss, nt, kvl, sr, tab,
     into the window's pages, the pooling of the chunks that closed, the
     two appends of their pooled rows, ONE softmax over pooled and exact
     rows."""
-    from paddle_tpu.ops.fused import (fused_append_rows, fused_chunk_pool,
-                                      fused_rope_append)
+    from paddle_tpu.ops.fused import fused_append_rows, fused_chunk_pool
     from paddle_tpu.ops.pallas_ragged import ragged_paged_attention
-    q, kp, vp = fused_rope_append(q, k, v, c, s, kp, vp, pg, off)
+    q, kp, vp = _rope_append(q, k, v, c, s, kp, vp, ss, nt, pg, off)
     kt, vt = fused_chunk_pool(kp, vp, phi, mu, pool_pg[0], pool_off[0],
                               chunk=16, scale=D ** -0.5)
     kp = fused_append_rows(kp, kt, pool_pg[1], pool_off[1])
@@ -301,10 +327,7 @@ def test_chunk_summary_kernels_compile_at_the_evabyte_cell_shapes(chip):
     """`evabyte-6.5b-serve-pp4-d8` as its cell runs it: T = 32 slots + a
     256-row chunk, 32 query heads over 32 KV heads x 128 (tiles of 128
     tokens), page 256, ONE pool of 272 pages, 33 sequences whose table
-    is 8 pooled + 8 window pages, 48 pooling slots of 16 rows. A K and a
-    V page of all 32 heads in and out is 16 MiB of VMEM blocks: the
-    rope + append asks for its own limit (the default 16 MiB refused it
-    off-chip, PR 35)."""
+    is 8 pooled + 8 window pages, 48 pooling slots of 16 rows."""
     from paddle_tpu.ops.pallas_ragged import (ragged_kernel_eligible,
                                               ragged_tile_tokens)
     t, hq, psz, n_pages, s, nj, p = 288, 32, 256, 272, 33, 16, 48

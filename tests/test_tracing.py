@@ -460,6 +460,49 @@ class TestStepTimeline:
         steps[0]["phases"].clear()
         assert tr.recorder().steps()[0]["phases"]
 
+    @pytest.mark.parametrize("kw", [
+        {}, {"spec_decode": 2}, {"page_size": 8, "prefill_chunk": 12}],
+        ids=["rows_of_one", "spec_rows", "chunk_of_12_in_pages_of_8"])
+    def test_append_runs_is_the_devices_table(self, kw, monkeypatch):
+        """The step record's `append_runs` is taken on the host by the
+        rule the device makes its run table by: every launch's count
+        equals the live runs of the table the jitted step derives from
+        the same row tables, and the record of the call that retires
+        the launch carries it."""
+        import jax.numpy as jnp
+        from paddle_tpu.ops.fused import append_run_table
+        eng, cfg = _tiny_engine(prefix_sharing=False, **kw)
+        B, R, C = eng.max_slots, 1 + eng.spec_k, eng.prefill_chunk
+        tile = eng._append_tile
+        bound = B * R + -(-C // tile) + 1
+        seq_start = jnp.asarray(np.append(np.arange(B) * R, B * R),
+                                jnp.int32)
+        counted, rows = [], []
+        build = eng._build_unified
+
+        def spy(*a):
+            out = build(*a)
+            _, _, num_tokens, _, _, tok_page, tok_off = out[0]
+            table = np.asarray(append_run_table(
+                seq_start, jnp.asarray(num_tokens), jnp.asarray(tok_page),
+                jnp.asarray(tok_off), tile=tile,
+                max_runs=bound)).reshape(5, bound)
+            assert out[-1]["append_runs"] == (table[1] > 0).sum()
+            assert table[1].sum() == num_tokens.sum()
+            counted.append(out[-1]["append_runs"])
+            rows.append(int(num_tokens.sum()))
+            return out
+
+        monkeypatch.setattr(eng, "_build_unified", spy)
+        self._script(eng, cfg)
+        steps = tr.recorder().steps()
+        # every launch retires into one record (the call after the one
+        # that built it, or a retire forced in between: the count adds)
+        assert sum(s["append_runs"] for s in steps) == sum(counted)
+        # a run holds a row at least and a tile of rows at most
+        assert all(c <= n <= c * tile for c, n in zip(counted, rows))
+        assert any(c < n for c, n in zip(counted, rows))
+
     def test_request_phases_sum_to_ttft(self):
         eng, cfg = _tiny_engine(prefix_sharing=False)
         self._script(eng, cfg)
