@@ -681,8 +681,9 @@ def _ffn_apply(L, h2, st=None, stats=None, live=None):
     appends its `moe.routing_stats` to the list ``stats``, counted over
     the rows that ``live`` [B * S] marks (all, if None)."""
     if "moe" not in L:
-        return _mm_w(jax.nn.silu(_mm_w(h2, L, "wg"))
-                     * _mm_w(h2, L, "wu"), L, "wd")
+        with jax.named_scope("ffn"):
+            return _mm_w(jax.nn.silu(_mm_w(h2, L, "wg"))
+                         * _mm_w(h2, L, "wu"), L, "wd")
     mo = L["moe"]
     B, S, H = h2.shape
     T = B * S

@@ -27,6 +27,7 @@ from .. import nn
 from ..nn import functional as F
 from ..nn import initializer as I
 from ..distributed.parallel_layers import MP_AXIS
+from ..observability.attribution import scope as _scope
 
 __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM",
            "llama3_8b_config", "llama_tiny_config", "apply_rope",
@@ -208,23 +209,26 @@ class LlamaAttention(nn.Layer):
 
         def finish(q, k, v, wo):
             """rope → attention → output projection (shared tail)."""
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            rep = H // KV
-            if rep > 1:
-                k = jnp.repeat(k, rep, axis=2)
-                v = jnp.repeat(v, rep, axis=2)
-            from ..ops.flash_attention import sdpa, sdpa_reference
-            if c.context_parallel:
-                # ring attention over the sep axis (P9): seq stays sharded,
-                # KV blocks rotate via collective-permute
-                from ..distributed.ring_attention import ring_attention_raw
-                o = ring_attention_raw(q, k, v, axis="sep", causal=True)
-            elif c.use_flash_attention:
-                o = sdpa(q, k, v, mask=mask_arr, causal=True)
-            else:
-                o = sdpa_reference(q, k, v, mask=mask_arr, causal=True)
-            return o.reshape(B, S, -1) @ wo
+            with _scope("attention"):
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
+                rep = H // KV
+                if rep > 1:
+                    k = jnp.repeat(k, rep, axis=2)
+                    v = jnp.repeat(v, rep, axis=2)
+                from ..ops.flash_attention import sdpa, sdpa_reference
+                if c.context_parallel:
+                    # ring attention over the sep axis (P9): seq stays
+                    # sharded, KV blocks rotate via collective-permute
+                    from ..distributed.ring_attention import \
+                        ring_attention_raw
+                    o = ring_attention_raw(q, k, v, axis="sep", causal=True)
+                elif c.use_flash_attention:
+                    o = sdpa(q, k, v, mask=mask_arr, causal=True)
+                else:
+                    o = sdpa_reference(q, k, v, mask=mask_arr, causal=True)
+            with _scope("attn_out"):
+                return o.reshape(B, S, -1) @ wo
 
         if c.fuse_attention_qkv:
             g = self._qkv_groups
@@ -233,10 +237,11 @@ class LlamaAttention(nn.Layer):
             def impl(h, wqkv, wo):
                 # [B,S,g,(Hg+2KVg),D]: dim 2 is the shard (rank) dim, so
                 # the q|k|v slices below are shard-local under mp
-                qkv = (h @ wqkv).reshape(B, S, g, Hg + 2 * KVg, D)
-                q = qkv[:, :, :, :Hg].reshape(B, S, H, D)
-                k = qkv[:, :, :, Hg:Hg + KVg].reshape(B, S, KV, D)
-                v = qkv[:, :, :, Hg + KVg:].reshape(B, S, KV, D)
+                with _scope("qkv_proj"):
+                    qkv = (h @ wqkv).reshape(B, S, g, Hg + 2 * KVg, D)
+                    q = qkv[:, :, :, :Hg].reshape(B, S, H, D)
+                    k = qkv[:, :, :, Hg:Hg + KVg].reshape(B, S, KV, D)
+                    v = qkv[:, :, :, Hg + KVg:].reshape(B, S, KV, D)
                 # head order is group-major for q AND kv consistently, and
                 # jnp.repeat on the flat kv axis maps q head (g_i, h_j) to
                 # kv head (g_i, h_j // (Hg/KVg)) — GQA grouping preserved
@@ -245,9 +250,10 @@ class LlamaAttention(nn.Layer):
                           [x, self.qkv_proj.weight, self.o_proj.weight])
 
         def impl(h, wq, wk, wv, wo):
-            q = (h @ wq).reshape(B, S, H, D)
-            k = (h @ wk).reshape(B, S, KV, D)
-            v = (h @ wv).reshape(B, S, KV, D)
+            with _scope("qkv_proj"):
+                q = (h @ wq).reshape(B, S, H, D)
+                k = (h @ wk).reshape(B, S, KV, D)
+                v = (h @ wv).reshape(B, S, KV, D)
             return finish(q, k, v, wo)
         return _apply("llama_attention", impl,
                       [x, self.q_proj.weight, self.k_proj.weight,
@@ -315,10 +321,17 @@ class LlamaDecoderLayer(nn.Layer):
 
     def forward(self, x, cos, sin, attn_mask=None):
         from ..distributed.parallel_layers import annotate_sequence_parallel
-        h = x + self.self_attn(self.input_layernorm(x), cos, sin, attn_mask)
+        # the step scopes of `observability.attribution`: the attention
+        # layer names its own three parts
+        with _scope("attn_norm"):
+            hn = self.input_layernorm(x)
+        h = x + self.self_attn(hn, cos, sin, attn_mask)
         if self.c.sequence_parallel:
             h = annotate_sequence_parallel(h)
-        out = h + self.mlp(self.post_attention_layernorm(h))
+        with _scope("ffn_norm"):
+            hn = self.post_attention_layernorm(h)
+        with _scope("ffn"):
+            out = h + self.mlp(hn)
         if self.c.sequence_parallel:
             out = annotate_sequence_parallel(out)
         return out

@@ -24,13 +24,16 @@ the observatory artifact unchanged.
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple, \
-    Union
+import re
+from typing import Any, Callable, Dict, List, Mapping, NamedTuple, \
+    Optional, Sequence, Tuple, Union
 
 from . import costmodel
 
 __all__ = ["attribute", "render_roofline_table",
-           "train_step_attribution", "render_flagship_table"]
+           "train_step_attribution", "render_flagship_table",
+           "SCOPES", "SCOPE_ALIASES", "scope", "OpScope", "op_key",
+           "op_scopes", "compile_named"]
 
 _TRAIN_PHASES = ("data", "fwd", "bwd", "opt")
 
@@ -196,3 +199,293 @@ def render_flagship_table(d: Mapping[str, Any]) -> str:
     out.append(f"| **wall per step** | **{d['wall_ms_per_step']:.1f}** "
                f"| 100% |")
     return "\n".join(out)
+
+
+# --------------------------------------------------------------------------
+# measured time x the program's own names for the parts of a step
+# (ISSUE 37).  The serving step bodies and the trainer step run their
+# parts under `scope(<name>)`; the compiled program carries the name in
+# every instruction's ``op_name``; `op_scopes` reads it back, keyed as
+# the device trace names the instruction's events.
+# --------------------------------------------------------------------------
+
+#: the ONE vocabulary, the same in every model family and in the trainer
+SCOPES = ("embed", "attn_norm", "qkv_proj", "cache_write", "attention",
+          "attn_out", "ffn_norm", "ffn", "routed_ffn", "shared_expert",
+          "head", "head_loss", "update")
+
+#: scopes that name a kernel in the device trace (a Pallas call's
+#: instruction takes its INNERMOST scope's name, and trace readers find
+#: `mla_attention`, `eva_attention`, `eva_pool` so): they stay as they
+#: are and answer to the vocabulary through this table.  `mla_kv` is
+#: the latent row's projection, norm and rope; its row append sits in a
+#: `cache_write` of its own, one scope further in.
+SCOPE_ALIASES = {"mla_q": "qkv_proj", "mla_kv": "qkv_proj",
+                 "mla_attention": "attention", "mla_out": "attn_out",
+                 "eva_attention": "attention", "eva_pool": "cache_write"}
+
+
+def scope(name: str):
+    """`jax.named_scope(name)` for a name of the vocabulary: metadata
+    of the operations traced under it, no operation of its own."""
+    if name not in SCOPES:
+        raise ValueError(f"{name!r} is not one of the step scopes {SCOPES}")
+    import jax
+    return jax.named_scope(name)
+
+
+class OpScope(NamedTuple):
+    """What the program says of one compiled instruction."""
+    scope: Optional[str]        # a name of SCOPES; None under no scope
+    direction: str              # "fwd" | "bwd" | "remat" | "-"
+    kind: str                   # "compute" | "collective" | "copy" | "control"
+    opcode: str
+    shape: str                  # the (first) result, `bf16[288,4096]`
+    scopes: Tuple[str, ...]     # a fusion's names, where it spans several
+    inherited: bool             # no name of its own: its reader's, else
+    #                             its operand's (layout copies, prefetches)
+    reads: str                  # a copy's source parameter (`w__layers__..`)
+    program: str
+
+
+_HLO_HEAD = re.compile(r"^\s*(?:ROOT\s+)?%?([\w\-.]+) = (\(?\w+\[[\d,]*\])")
+_HLO_OPCODE = re.compile(r" ([\w\-]+)\(")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w\-.]+) \(.*\{\s*$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w\-.]+)")
+#: computations whose instructions run inside their caller's one event
+_INLINED = re.compile(r"\b(?:to_apply|called_computations)=\{?%?([\w\-.]+)")
+_OPERAND = re.compile(r"%([\w\-.]+)")
+_JIT_PART = re.compile(r"\bp?jit\([^()]*\)")
+_WORD = re.compile(r"[A-Za-z_][\w.\-]*")
+_COLLECTIVE = re.compile(
+    r"^(all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all"
+    r"|collective-broadcast|ragged-all-to-all|send|recv)(-start|-done)?$")
+#: instructions the device runs nothing for
+_NO_EVENT = {"parameter", "constant", "get-tuple-element", "tuple", "bitcast",
+             "after-all", "partition-id", "replica-id", "iota"}
+
+
+def _head(line: str) -> Optional[Tuple[str, str, str]]:
+    """(instruction name, first result shape, opcode) of an HLO line,
+    as `Compiled.as_text()` or a device trace event prints it."""
+    head = _HLO_HEAD.match(line)
+    if head is None:
+        return None
+    op = _HLO_OPCODE.search(line, head.end())
+    return (head.group(1), head.group(2).lstrip("("),
+            op.group(1) if op else "?")
+
+
+def op_key(line: str) -> Optional[str]:
+    """The key of an instruction: ``%<name> <first result shape>``,
+    from its line in `Compiled.as_text()` and from its event's name in
+    the device trace alike.  (The two print one instruction differently
+    — the trace adds operand types and tilings — so whole lines never
+    compare equal; the name is unique in a program and the shape keeps
+    two programs' ``%fusion.3`` apart.)"""
+    head = _head(line)
+    return None if head is None else f"%{head[0]} {head[1]}"
+
+
+def _path_scope(op_name: str) -> Tuple[Optional[str], str]:
+    """(innermost vocabulary name, direction) of one ``op_name`` path,
+    e.g. ``jit(step)/transpose(jvp(ffn))/checkpoint/mul``.  A scope
+    entered outside a transformation shows inside its parentheses, so
+    the path is read word by word, outermost first."""
+    words = _WORD.findall(_JIT_PART.sub("", op_name))
+    name = None
+    for w in words[:-1]:            # the last word is the primitive
+        w = SCOPE_ALIASES.get(w, w)
+        if w in SCOPES:
+            name = w
+    if "rematted_computation" in words:
+        direction = "remat"
+    elif "transpose" in words:
+        direction = "bwd"
+    elif "jvp" in words:
+        direction = "fwd"
+    else:
+        direction = "-"
+    return name, direction
+
+
+def _kind(opcode: str) -> str:
+    if _COLLECTIVE.match(opcode):
+        return "collective"
+    if opcode in ("copy", "copy-start", "copy-done"):
+        return "copy"
+    # its event spans the events of the computations it calls
+    return "control" if opcode in ("while", "conditional", "call") \
+        else "compute"
+
+
+class _Inst:
+    """One parsed instruction, while its computation is resolved."""
+    __slots__ = ("name", "shape", "opcode", "scope", "direction", "kind",
+                 "inside", "operands", "inherited")
+
+    def __init__(self, name, shape, opcode, scope, direction, kind, inside,
+                 operands):
+        self.name, self.shape, self.opcode = name, shape, opcode
+        self.scope, self.direction, self.kind = scope, direction, kind
+        self.inside, self.operands, self.inherited = inside, operands, False
+
+
+def _inherit(insts: List[_Inst], by_name: Mapping[str, _Inst]) -> None:
+    """Instructions with no ``op_name`` of their own (the compiler's
+    layout copies, prefetches into fast memory, reshapes, loops): each
+    takes the (scope, direction) of the first named instruction that
+    reads it, else of the first it reads, through any number of such."""
+    users: Dict[str, List[str]] = {}
+    for i in insts:
+        for o in i.operands:
+            users.setdefault(o, []).append(i.name)
+    pending = [i for i in insts if i.scope is None
+               and i.opcode not in ("parameter", "constant")]
+    for edges in (users.get, lambda n: by_name[n].operands):
+        changed = True
+        while changed:
+            changed = False
+            for i in pending:
+                if i.scope is None:
+                    src = next((by_name[n] for n in edges(i.name) or ()
+                                if n in by_name and by_name[n].scope), None)
+                    if src is not None:
+                        i.scope, i.direction = src.scope, src.direction
+                        i.inherited = changed = True
+
+
+def _source_parameter(inst: _Inst, by_name: Mapping[str, _Inst]) -> str:
+    """The parameter a copy reads, through bitcasts and the like."""
+    for _ in range(8):
+        if not inst.operands or inst.operands[0] not in by_name:
+            return ""
+        inst = by_name[inst.operands[0]]
+        if inst.opcode == "parameter":
+            return inst.name
+    return ""
+
+
+def _program_scopes(text: str, program: str) -> Dict[str, OpScope]:
+    comps: Dict[str, List[str]] = {}
+    cur: Optional[List[str]] = None
+    for line in text.splitlines():
+        if cur is None:
+            m = _COMPUTATION.match(line)
+            if m:
+                cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        else:
+            cur.append(line)
+    inlined = {m.group(1) for lines in comps.values() for ln in lines
+               for m in [_CALLS.search(ln) if " fusion(" in ln
+                         else _INLINED.search(ln)] if m}
+    out: Dict[str, OpScope] = {}
+    for comp, lines in comps.items():
+        if comp in inlined:
+            continue
+        insts: List[_Inst] = []
+        for ln in lines:
+            head = _head(ln)
+            if head is None:
+                continue
+            name, shape, opcode = head
+            m = _OP_NAME.search(ln)
+            own, direction = _path_scope(m.group(1)) if m else (None, "-")
+            kind, inside = _kind(opcode), ()
+            calls = _CALLS.search(ln) if opcode == "fusion" else None
+            if calls:
+                # a fusion answers to its root's scope (XLA gives it
+                # the root's metadata); where the root has none — a
+                # residual add outside every scope — to the last scoped
+                # instruction it fused
+                names, inner_ops = [], set()
+                for inner in comps.get(calls.group(1), ()):
+                    ih = _head(inner)
+                    if ih:
+                        inner_ops.add(_kind(ih[2]) if ih[2] not in
+                                      ("convolution", "dot") else "matmul")
+                    im = _OP_NAME.search(inner)
+                    nm, d = _path_scope(im.group(1)) if im else (None, "-")
+                    if nm is not None:
+                        names.append(nm)
+                        if own is None and direction == "-":
+                            direction = d
+                if own is None and names:
+                    own = names[-1]
+                # a collective the compiler hid inside a matmul's fusion
+                # (`async_collective_fusion`) leaves it a matmul
+                if "collective" in inner_ops and "matmul" not in inner_ops:
+                    kind = "collective"
+                distinct = tuple(dict.fromkeys(names))
+                inside = distinct if len(distinct) > 1 else ()
+            operands = _OPERAND.findall(ln[ln.index(opcode + "("):])
+            insts.append(_Inst(name, shape, opcode, own, direction, kind,
+                               inside, operands))
+        by_name = {i.name: i for i in insts}
+        _inherit(insts, by_name)
+        for i in insts:
+            if i.opcode in _NO_EVENT:
+                continue
+            reads = _source_parameter(i, by_name) if i.kind == "copy" else ""
+            out[f"%{i.name} {i.shape}"] = OpScope(
+                i.scope, i.direction, i.kind, i.opcode, i.shape, i.inside,
+                i.inherited, reads, program)
+    return out
+
+
+def op_scopes(compiled: Any) -> Dict[str, OpScope]:
+    """{`op_key`: `OpScope`} of every instruction the device runs an
+    event for, of one compiled program (anything with ``as_text()``, or
+    its text) or of a mapping ``{program name: compiled}``.  Built by
+    whoever reads a trace, after the run: nothing in the program calls
+    it.  A key that two programs give different answers for keeps
+    neither scope (``scope`` None, both programs named)."""
+    programs = compiled if isinstance(compiled, Mapping) \
+        else {"program": compiled}
+    table: Dict[str, OpScope] = {}
+    for program, c in programs.items():
+        text = c if isinstance(c, str) else c.as_text()
+        for key, rec in _program_scopes(text, program).items():
+            old = table.get(key)
+            if old is not None and old[:3] != rec[:3]:
+                rec = rec._replace(scope=None, scopes=(),
+                                   program=f"{old.program}+{program}")
+            table[key] = rec
+    return table
+
+
+_NAMED = re.compile(r"[/(\"](%s)(?=[/)\"])"
+                    % "|".join(SCOPES + tuple(SCOPE_ALIASES)))
+
+
+def compile_named(jitted: Any, args: Sequence[Any],
+                  fresh: Callable[[], Any]) -> Any:
+    """``jitted.lower(*args).compile()``, answered by this process's
+    own compile of the program or by the compile cache — unless the
+    answer has lost the program's names.  The cache's key leaves names
+    out, so an entry an older program wrote (before it named its parts,
+    or under other names) is what the new one finds, names and all.
+    Then the program is traced again from ``fresh()``, a new `jax.jit`
+    of the same function, and compiled with the cache off: a whole
+    compile, paid once by the reader that asked."""
+    lowered = jitted.lower(*args)
+    compiled = lowered.compile()
+    wants = set(_NAMED.findall(lowered.as_text(debug_info=True)))
+    has = {n for path in _OP_NAME.findall(compiled.as_text())
+           for n in _NAMED.findall("/" + path)}
+    # (the compiler may fuse a small part away whole: most names, not all)
+    if 2 * len(has & wants) >= len(wants):
+        return compiled
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        return fresh().lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()

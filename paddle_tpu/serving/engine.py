@@ -58,6 +58,7 @@ greedy property; sampling strategies belong to the batch APIs.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -67,6 +68,7 @@ import numpy as np
 from .. import observability as _obs
 from .. import resilience as _res
 from ..observability import costmodel as _costmodel
+from ..observability.attribution import compile_named, scope as _scope
 from ..observability import tracing as _tracing
 from ..generation import (_decode_params, _dq, _ffn_apply, _llama_weights,
                           _mm_w)
@@ -617,9 +619,9 @@ class ServingEngine:
         writes (`ops.fused.fused_rope_append`) land in the live buffer
         instead of a copy of it. So the caller's pools are dead after
         the launch — `_launch` is the one caller."""
+        self._programs = self._step_programs()
         if self.ragged:
-            self._jit_unified = jax.jit(self._make_unified_body(),
-                                        donate_argnums=2)
+            self._jit_unified = self._programs["unified"]
             # the token feed: EVERY `tok` the unified step sees comes
             # out of this one program (one type, one sharding, one
             # committed-ness: `_jit_unified` keeps one cache entry) —
@@ -627,23 +629,15 @@ class ServingEngine:
             # src[r] of the launch in flight's greedy tokens, which
             # never leave the device. Compiled and run ONCE here, on
             # the tokens of no launch
-            self._jit_feed = jax.jit(
-                lambda prev, tok, src: jnp.where(
-                    src < 0, tok, prev[jnp.maximum(src, 0)]))
+            self._jit_feed = self._programs["feed"]
             T = self.max_slots * (1 + self.spec_k) + self.prefill_chunk
             self._no_tokens = jax.jit(lambda: jnp.zeros(
                 T if self.spec_k else self.max_slots + 1, jnp.int32))()
             self._jit_feed(self._no_tokens, np.zeros(T, np.int32),
                            np.full(T, -1, np.int32))
-            self._programs = {"unified": self._jit_unified,
-                              "feed": self._jit_feed}
         else:
-            self._jit_decode = jax.jit(self._make_decode_body(),
-                                       donate_argnums=2)
-            self._jit_prefill = jax.jit(self._make_prefill_body(),
-                                        donate_argnums=2)
-            self._programs = {"decode": self._jit_decode,
-                              "prefill": self._jit_prefill}
+            self._jit_decode = self._programs["decode"]
+            self._jit_prefill = self._programs["prefill"]
         # the copy-on-write program (`_apply_copies`): as many pairs as
         # one sequence's new rows of one step can touch shared pages,
         # compiled and run ONCE here (trash page onto itself) so that
@@ -656,6 +650,19 @@ class ServingEngine:
             donate_argnums=0)
         idle = np.zeros(self._copy_slots, np.int32)
         self._pools = self._jit_copy(self._live_pools(), idle, idle)
+
+    def _step_programs(self) -> Dict[str, object]:
+        """{name: a FRESH `jax.jit`} of the step programs at the current
+        max_slots/prefill_chunk/spec_k."""
+        if self.ragged:
+            return {"unified": jax.jit(self._make_unified_body(),
+                                       donate_argnums=2),
+                    "feed": jax.jit(lambda prev, tok, src: jnp.where(
+                        src < 0, tok, prev[jnp.maximum(src, 0)]))}
+        return {"decode": jax.jit(self._make_decode_body(),
+                                  donate_argnums=2),
+                "prefill": jax.jit(self._make_prefill_body(),
+                                   donate_argnums=2)}
 
     def _live_pools(self):
         """The page pools, for whoever reads or replaces them between
@@ -1004,6 +1011,36 @@ class ServingEngine:
         "prefill": n}. Every count must stay at 1 after any join/leave
         pattern."""
         return {name: fn._cache_size()
+                for name, fn in self._programs.items()}
+
+    def compiled_programs(self) -> Dict[str, object]:
+        """{program name: its `jax.stages.Compiled`} of this engine's
+        step programs, for whoever reads a trace of them afterwards
+        (`observability.attribution.op_scopes`). Each is lowered again
+        at the shapes its launches have — an idle launch's row tables,
+        the weights and pools as they stand — and compiled, which the
+        compile cache answers where it answered the step; nothing is
+        launched and no pool is taken. Called by no part of the engine:
+        a run that does not ask pays nothing."""
+        def shaped(a):
+            return jax.ShapeDtypeStruct(np.shape(a), a.dtype)
+
+        pools = self._live_pools()
+        if self.ragged:
+            host, src, *_ = self._build_unified(None, [], None)
+            rest = jax.tree_util.tree_map(shaped, host[1:])
+            args = {"unified": (self._w, shaped(host[0]), pools, *rest),
+                    "feed": (shaped(self._no_tokens), shaped(host[0]),
+                             shaped(src))}
+        else:
+            B, nj = self.max_slots, self.pages_per_seq
+            i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+            args = {"decode": (self._w, i32((B,)), pools, i32((B,)),
+                               i32((B, nj))),
+                    "prefill": (self._w, i32((1, self.prefill_chunk)),
+                                pools, i32((1, nj)), i32(()), i32(()))}
+        return {name: compile_named(
+                    fn, args[name], lambda n=name: self._step_programs()[n])
                 for name, fn in self._programs.items()}
 
     def collect(self) -> Dict[object, object]:
@@ -1956,16 +1993,19 @@ class ServingEngine:
 
         def step(w, tok, pools, positions, num_tokens, kv_lengths,
                  tables, tok_page, tok_off):
-            x = w["embed"][tok][None]                    # [1, T, H]
-            # [T, D/2] trig rows of each rope table the layers name
-            trig = {sfx: (w["cos" + sfx][positions],
-                          w["sin" + sfx][positions])
-                    for sfx in sorted({a["rope"] for a in attn_static})}
+            with _scope("embed"):
+                x = w["embed"][tok][None]                # [1, T, H]
+                # [T, D/2] trig rows of each rope table the layers name
+                trig = {sfx: (w["cos" + sfx][positions],
+                              w["sin" + sfx][positions])
+                        for sfx in sorted({a["rope"]
+                                           for a in attn_static})}
             if not isinstance(tables, tuple):
                 tables, tok_page = (tables,), (tok_page,)
             # the append's runs, once a kind of cache
-            runs = [run_table(num_tokens, page, tok_off)
-                    for page in tok_page]
+            with _scope("cache_write"):
+                runs = [run_table(num_tokens, page, tok_off)
+                        for page in tok_page]
             new_pools = []
             moe_stats = [] if count_moe else None
             live = _owned_rows(T, seq_start, num_tokens) \
@@ -1979,33 +2019,41 @@ class ServingEngine:
                 # physical pages its new rows land in
                 kind = int(window is not None)
                 table = tables[kind]
-                h = fused_rms_norm(x, L["ln1"], eps)
-                q, k, v = (_mm_w(h, L, "wq"), _mm_w(h, L, "wk"),
-                           _mm_w(h, L, "wv"))
-                if "bq" in L:
-                    q, k, v = q + L["bq"], k + L["bk"], v + L["bv"]
-                q, kp, vp = fused_rope_append(
-                    q.reshape(T, Hh, D), k.reshape(T, KV, D),
-                    v.reshape(T, KV, D), c, s, kp, vp, runs[kind])
+                with _scope("attn_norm"):
+                    h = fused_rms_norm(x, L["ln1"], eps)
+                with _scope("qkv_proj"):
+                    q, k, v = (_mm_w(h, L, "wq"), _mm_w(h, L, "wk"),
+                               _mm_w(h, L, "wv"))
+                    if "bq" in L:
+                        q, k, v = q + L["bq"], k + L["bk"], v + L["bv"]
+                with _scope("cache_write"):
+                    q, kp, vp = fused_rope_append(
+                        q.reshape(T, Hh, D), k.reshape(T, KV, D),
+                        v.reshape(T, KV, D), c, s, kp, vp, runs[kind])
                 new_pools.append((kp, vp))
-                o = ragged_paged_attention(q, kp, vp, seq_start,
-                                           num_tokens, kv_lengths,
-                                           table, scale=D ** -0.5,
-                                           window=window)
-                if "wgate" in L:
-                    # one sigmoid scalar a head, from the normed input
-                    g = jax.nn.sigmoid(_mm_w(h, L, "wgate"))[0]
-                    o = o * g[..., None].astype(o.dtype)
-                x = x + _mm_w(o.reshape(1, T, Hh * D), L, "wo")
-                h2 = fused_rms_norm(x, L["ln2"], eps)
+                with _scope("attention"):
+                    o = ragged_paged_attention(q, kp, vp, seq_start,
+                                               num_tokens, kv_lengths,
+                                               table, scale=D ** -0.5,
+                                               window=window)
+                with _scope("attn_out"):
+                    if "wgate" in L:
+                        # one sigmoid scalar a head, from the normed input
+                        g = jax.nn.sigmoid(_mm_w(h, L, "wgate"))[0]
+                        o = o * g[..., None].astype(o.dtype)
+                    x = x + _mm_w(o.reshape(1, T, Hh * D), L, "wo")
+                with _scope("ffn_norm"):
+                    h2 = fused_rms_norm(x, L["ln2"], eps)
                 x = x + _ffn_apply(L, h2, st, moe_stats, live)
-            x = fused_rms_norm(x, w["norm"], eps)
-            logits = _head_logits(
-                w, _logit_rows(x, seq_start, num_tokens, K))
-            if moe_stats:
-                return (logits, new_pools, _greedy(logits),
-                        _moe_step_counts(moe_stats))
-            return logits, new_pools, _greedy(logits)
+            with _scope("head"):
+                x = fused_rms_norm(x, w["norm"], eps)
+                logits = _head_logits(
+                    w, _logit_rows(x, seq_start, num_tokens, K))
+                tokens = _greedy(logits)
+                if moe_stats:
+                    return (logits, new_pools, tokens,
+                            _moe_step_counts(moe_stats))
+            return logits, new_pools, tokens
 
         return step
 
@@ -2041,38 +2089,53 @@ class ServingEngine:
                 y = x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps)
                 return (y * gain).astype(dt)
 
-            x = w["embed"][tok][None].astype(f32)        # [1, T, H*D]
-            c, s = w["cos"][positions], w["sin"][positions]
-            runs = run_table(num_tokens, tok_page, tok_off)
+            with _scope("embed"):
+                x = w["embed"][tok][None].astype(f32)    # [1, T, H*D]
+                c, s = w["cos"][positions], w["sin"][positions]
+            with _scope("cache_write"):
+                runs = run_table(num_tokens, tok_page, tok_off)
             new_pools = []
             for L, (kp, vp) in zip(w["layers"], pools):
-                h = norm(x, L["ln1"])
-                q, kp, vp = fused_rope_append(
-                    (h @ L["wq"]).reshape(T, H, D),
-                    (h @ L["wk"]).reshape(T, H, D),
-                    (h @ L["wv"]).reshape(T, H, D), c, s, kp, vp, runs)
-                with jax.named_scope("eva_pool"):
-                    kt, vt = fused_chunk_pool(
-                        kp, vp, L["phi"], L["mu"], pool_page[0],
-                        pool_off[0], chunk=ck, scale=scale)
-                    kp = fused_append_rows(kp, kt, pool_page[1],
-                                           pool_off[1])
-                    vp = fused_append_rows(vp, vt, pool_page[1],
-                                           pool_off[1])
+                with _scope("attn_norm"):
+                    h = norm(x, L["ln1"])
+                with _scope("qkv_proj"):
+                    q, k, v = ((h @ L["wq"]).reshape(T, H, D),
+                               (h @ L["wk"]).reshape(T, H, D),
+                               (h @ L["wv"]).reshape(T, H, D))
+                # `eva_pool` / `eva_attention` stay the kernels' own
+                # (innermost) names: the trace's readers find them so
+                with _scope("cache_write"):
+                    q, kp, vp = fused_rope_append(q, k, v, c, s, kp, vp,
+                                                  runs)
+                    with jax.named_scope("eva_pool"):
+                        kt, vt = fused_chunk_pool(
+                            kp, vp, L["phi"], L["mu"], pool_page[0],
+                            pool_off[0], chunk=ck, scale=scale)
+                        kp = fused_append_rows(kp, kt, pool_page[1],
+                                               pool_off[1])
+                        vp = fused_append_rows(vp, vt, pool_page[1],
+                                               pool_off[1])
                 new_pools.append((kp, vp))
-                with jax.named_scope("eva_attention"):
+                with _scope("attention"), jax.named_scope("eva_attention"):
                     o = ragged_paged_attention(
                         q, kp, vp, seq_start, num_tokens, kv_lengths,
                         tables, scale=scale, summary_rows=summary_rows)
-                x = x + jnp.dot(o.reshape(1, T, H * D), L["wo"],
-                                preferred_element_type=f32)
-                h2 = norm(x, L["ln2"])
-                x = x + jnp.dot(jax.nn.silu(h2 @ L["wg"]) * (h2 @ L["wu"]),
-                                L["wd"], preferred_element_type=f32)
-            last = _logit_rows(norm(x, w["norm"]), seq_start, num_tokens, 0)
-            logits = jnp.dot(last, w["head"][:, :V],
-                             preferred_element_type=f32)
-            return logits, new_pools, _greedy(logits)
+                with _scope("attn_out"):
+                    x = x + jnp.dot(o.reshape(1, T, H * D), L["wo"],
+                                    preferred_element_type=f32)
+                with _scope("ffn_norm"):
+                    h2 = norm(x, L["ln2"])
+                with _scope("ffn"):
+                    x = x + jnp.dot(
+                        jax.nn.silu(h2 @ L["wg"]) * (h2 @ L["wu"]),
+                        L["wd"], preferred_element_type=f32)
+            with _scope("head"):
+                last = _logit_rows(norm(x, w["norm"]), seq_start,
+                                   num_tokens, 0)
+                logits = jnp.dot(last, w["head"][:, :V],
+                                 preferred_element_type=f32)
+                tokens = _greedy(logits)
+            return logits, new_pools, tokens
 
         return step
 
@@ -2088,33 +2151,44 @@ class ServingEngine:
 
         def step(w, tok, pools, positions, num_tokens, kv_lengths,
                  tables, tok_page, tok_off):
-            x = (w["embed"][tok] + w["pos"][positions])[None]
+            with _scope("embed"):
+                x = (w["embed"][tok] + w["pos"][positions])[None]
             # identity rope (cos=1, sin=0): fused_rope_append becomes a
             # pure fused K/V append, bitwise-exact on q/k
             c = jnp.ones((T, hd // 2), x.dtype)
             s = jnp.zeros((T, hd // 2), x.dtype)
-            runs = run_table(num_tokens, tok_page, tok_off)
+            with _scope("cache_write"):
+                runs = run_table(num_tokens, tok_page, tok_off)
             new_pools = []
             for L, (kp, vp) in zip(w["layers"], pools):
-                h = fused_layer_norm(x, L["ln1w"], L["ln1b"], eps)
-                qkv = h @ L["wqkv"] + L["bqkv"]
-                q, k, v = jnp.split(qkv, 3, axis=-1)
-                q, kp, vp = fused_rope_append(
-                    q.reshape(T, nh, hd), k.reshape(T, nh, hd),
-                    v.reshape(T, nh, hd), c, s, kp, vp, runs)
+                with _scope("attn_norm"):
+                    h = fused_layer_norm(x, L["ln1w"], L["ln1b"], eps)
+                with _scope("qkv_proj"):
+                    qkv = h @ L["wqkv"] + L["bqkv"]
+                    q, k, v = jnp.split(qkv, 3, axis=-1)
+                with _scope("cache_write"):
+                    q, kp, vp = fused_rope_append(
+                        q.reshape(T, nh, hd), k.reshape(T, nh, hd),
+                        v.reshape(T, nh, hd), c, s, kp, vp, runs)
                 new_pools.append((kp, vp))
-                o = ragged_paged_attention(q, kp, vp, seq_start,
-                                           num_tokens, kv_lengths,
-                                           tables, scale=hd ** -0.5)
-                x = x + (o.reshape(1, T, nh * hd) @ L["wo"] + L["bo"])
-                h2 = fused_layer_norm(x, L["ln2w"], L["ln2b"], eps)
-                x = x + (jax.nn.gelu(h2 @ L["wi"] + L["bi"],
-                                     approximate=True) @ L["wf"]
-                         + L["bf"])
-            x = fused_layer_norm(x, w["normw"], w["normb"], eps)
-            logits = _head_logits(
-                w, _logit_rows(x, seq_start, num_tokens, K))
-            return logits, new_pools, _greedy(logits)
+                with _scope("attention"):
+                    o = ragged_paged_attention(q, kp, vp, seq_start,
+                                               num_tokens, kv_lengths,
+                                               tables, scale=hd ** -0.5)
+                with _scope("attn_out"):
+                    x = x + (o.reshape(1, T, nh * hd) @ L["wo"] + L["bo"])
+                with _scope("ffn_norm"):
+                    h2 = fused_layer_norm(x, L["ln2w"], L["ln2b"], eps)
+                with _scope("ffn"):
+                    x = x + (jax.nn.gelu(h2 @ L["wi"] + L["bi"],
+                                         approximate=True) @ L["wf"]
+                             + L["bf"])
+            with _scope("head"):
+                x = fused_layer_norm(x, w["normw"], w["normb"], eps)
+                logits = _head_logits(
+                    w, _logit_rows(x, seq_start, num_tokens, K))
+                tokens = _greedy(logits)
+            return logits, new_pools, tokens
 
         return step
 
@@ -2142,9 +2216,10 @@ class ServingEngine:
 
         def step(w, tok, pools, positions, num_tokens, kv_lengths,
                  tables, tok_page, tok_off):
-            x = w["embed"][tok][None]                    # [1, T, H]
-            c = w["cos"][positions]                      # [T, dr/2]
-            s = w["sin"][positions]
+            with _scope("embed"):
+                x = w["embed"][tok][None]                # [1, T, H]
+                c = w["cos"][positions]                  # [T, dr/2]
+                s = w["sin"][positions]
 
             def rope(t):                                 # [1, T, h, dr]
                 d2 = t.shape[-1] // 2
@@ -2160,7 +2235,8 @@ class ServingEngine:
                 if count_moe else None
             sts = moe_static or (None,) * len(w["layers"])
             for L, pool, st in zip(w["layers"], pools, sts):
-                h = fused_rms_norm(x, L["ln1"], eps)
+                with _scope("attn_norm"):
+                    h = fused_rms_norm(x, L["ln1"], eps)
                 wkb = _dq(L, "wkvb", x.dtype).reshape(r, nh, dn + dv)
                 w_k, w_v = wkb[..., :dn], wkb[..., dn:]
                 with jax.named_scope("mla_q"):
@@ -2185,8 +2261,9 @@ class ServingEngine:
                     k_pe = rope(kv_a[..., r:][:, :, None, :])[:, :, 0]
                     rows = _pad_lanes(
                         jnp.concatenate([lat, k_pe], -1)[0], width)
-                    pool = fused_append_rows(pool, rows[:, None],
-                                             tok_page, tok_off)
+                    with _scope("cache_write"):
+                        pool = fused_append_rows(pool, rows[:, None],
+                                                 tok_page, tok_off)
                 new_pools.append(pool)
                 with jax.named_scope("mla_attention"):
                     # K is the row, V its latent columns: one page
@@ -2197,15 +2274,18 @@ class ServingEngine:
                 with jax.named_scope("mla_out"):
                     o = jnp.einsum("tnr,rnv->tnv", o_lat, w_v)
                     x = x + _mm_w(o.reshape(1, T, nh * dv), L, "wo")
-                h2 = fused_rms_norm(x, L["ln2"], eps)
+                with _scope("ffn_norm"):
+                    h2 = fused_rms_norm(x, L["ln2"], eps)
                 x = x + _ffn_apply(L, h2, st, moe_stats, live)
-            x = fused_rms_norm(x, w["norm"], eps)
-            logits = _head_logits(
-                w, _logit_rows(x, seq_start, num_tokens, K))
-            if moe_stats:
-                return (logits, new_pools, _greedy(logits),
-                        _moe_step_counts(moe_stats))
-            return logits, new_pools, _greedy(logits)
+            with _scope("head"):
+                x = fused_rms_norm(x, w["norm"], eps)
+                logits = _head_logits(
+                    w, _logit_rows(x, seq_start, num_tokens, K))
+                tokens = _greedy(logits)
+                if moe_stats:
+                    return (logits, new_pools, tokens,
+                            _moe_step_counts(moe_stats))
+            return logits, new_pools, tokens
 
         return step
 

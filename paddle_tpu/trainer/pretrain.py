@@ -28,6 +28,7 @@ from ..amp import decorate_tree
 from ..core.tensor import Tensor
 from ..distributed.mesh import (build_hybrid_mesh, global_device_put,
                                 mesh_context)
+from ..observability.attribution import compile_named, scope as _scope
 from ..ops.on_mesh import kernel_mesh
 from ..distributed.pipeline import (PP_AXIS, spmd_pipeline,
                                     spmd_pipeline_interleaved,
@@ -311,6 +312,7 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
             pp_timetable = generate_schedule(cfg.pp_schedule, n_stages, M)
         pp_timetable.validate()
 
+    @_scope("head_loss")
     def _rms_head_loss(norm_w, w_head, h, labels_h, constrain=False,
                        onehot_pick=False):
         """final RMSNorm + chunked-CE SUM over h [.., S, H]. constrain
@@ -355,20 +357,22 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
 
     def loss_fn(compute_params, ids, labels):
         emb = compute_params["outer"][embed_key]
-        if mesh.shape.get("mp", 1) > 1:
-            # vocab-parallel lookup as a one-hot CONTRACTION: a gather
-            # over the vocab-sharded table forces GSPMD into involuntary
-            # full rematerialization (replicate the table, then reshard —
-            # the r2-flagged SPMD warnings); the contraction partitions
-            # cleanly (batch-sharded one-hot x vocab-sharded table =
-            # local matmul + psum over mp, the GSPMD analog of Megatron's
-            # range-mask + allreduce) and rides the MXU
-            oh = jax.nn.one_hot(ids, emb.shape[0], dtype=emb.dtype)
-            x = oh @ emb                # [B,S,H]
-        else:
-            x = jnp.take(emb, ids, axis=0)  # [B,S,H]
-        x = jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, P(("dp", "sharding"), "sep", None)))
+        with _scope("embed"):
+            if mesh.shape.get("mp", 1) > 1:
+                # vocab-parallel lookup as a one-hot CONTRACTION: a
+                # gather over the vocab-sharded table forces GSPMD into
+                # involuntary full rematerialization (replicate the
+                # table, then reshard — the r2-flagged SPMD warnings);
+                # the contraction partitions cleanly (batch-sharded
+                # one-hot x vocab-sharded table = local matmul + psum
+                # over mp, the GSPMD analog of Megatron's range-mask +
+                # allreduce) and rides the MXU
+                oh = jax.nn.one_hot(ids, emb.shape[0], dtype=emb.dtype)
+                x = oh @ emb                # [B,S,H]
+            else:
+                x = jnp.take(emb, ids, axis=0)  # [B,S,H]
+            x = jax.lax.with_sharding_constraint(
+                x, NamedSharding(mesh, P(("dp", "sharding"), "sep", None)))
         if use_timetable:
             # 1F1B/ZBH1/FThenB: the loss head runs ON the last stage
             # inside the executor (the cotangent seeds the interleaved
@@ -441,9 +445,12 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
         # Pallas kernels in the step run per-shard on this mesh
         with kernel_mesh(mesh):
             loss, grads = jax.value_and_grad(cast_loss)(state.master)
-        new_master, new_opt, gnorm = tx.update(grads, state.opt_state,
-                                               state.master)
-        new_params = decorate_tree(new_master, param_dtype)
+        # gradient clip, AdamW and the cast back; the ZeRO gather and
+        # scatter the partitioner puts around them take the scope too
+        with _scope("update"):
+            new_master, new_opt, gnorm = tx.update(grads, state.opt_state,
+                                                   state.master)
+            new_params = decorate_tree(new_master, param_dtype)
         return TrainState(new_params, new_master, new_opt,
                           state.step + 1), {"loss": loss,
                                             "grad_norm": gnorm}
@@ -457,6 +464,21 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
     # of the state on the device (1.3 GiB at the 8B shard), stale after
     # the first step, and at the flagship recipe they were what made a
     # resumed run miss the step program's scratch reservation
+    def compiled_programs(state: TrainState) -> Dict[str, Any]:
+        """{"train_step": the step compiled for `state`'s shapes and
+        shardings}, for whoever reads a trace of it afterwards
+        (`observability.attribution.op_scopes`).  Lowered from shapes
+        and answered by this process's own compile of the step or by
+        the compile cache (`attribution.compile_named`); nothing runs
+        on the devices and nothing here is called by the step."""
+        shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=a.sharding), state)
+        batch = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=data_spec)
+        return {"train_step": compile_named(
+            jstep, (shapes, batch, batch),
+            lambda: jax.jit(lambda *a: train_step(*a), donate_argnums=(0,)))}
+
     meta = {"mesh": mesh, "data_sharding": data_spec,
-            "flops_per_token": flops_per_token(mc)}
+            "flops_per_token": flops_per_token(mc),
+            "compiled_programs": compiled_programs}
     return state, jstep, meta

@@ -515,6 +515,37 @@ class TestOverhead:
         assert did == {"py_calls": 5, "c_calls": 0, "blocks_kept": 0}
         assert lock.taken == 2              # the two reads above
 
+    def test_a_serving_run_never_reads_its_own_program(self, monkeypatch):
+        # ISSUE 37: the step scopes are metadata of the one tracing;
+        # construction and `step()` compile nothing twice, lower nothing
+        # again and build no table — only a reader that asks does
+        import numpy as np
+        from test_engine_programs import _tiny
+        from paddle_tpu.observability import attribution as at
+        from paddle_tpu.serving import ServingEngine
+        from paddle_tpu.serving import engine as engine_mod
+        called = []
+        for name in ("op_scopes", "compile_named"):
+            monkeypatch.setattr(
+                at, name, lambda *a, _n=name, **k: called.append(_n))
+        monkeypatch.setattr(engine_mod, "compile_named",
+                            lambda *a, **k: called.append("compile_named"))
+        monkeypatch.setattr(
+            ServingEngine, "compiled_programs",
+            lambda self: called.append("compiled_programs"))
+        fresh = []
+        real = ServingEngine._step_programs
+        monkeypatch.setattr(
+            ServingEngine, "_step_programs",
+            lambda self: fresh.append(1) or real(self))
+        eng = ServingEngine(_tiny("llama"), max_slots=2, page_size=8,
+                            max_context=64, prefill_chunk=8)
+        for n in (5, 9):
+            eng.add_request(np.arange(n, dtype=np.int32), max_new_tokens=4)
+        assert len(eng.run_to_completion()) == 2
+        assert called == [] and fresh == [1]    # the constructor's one
+        assert all(n == 1 for n in eng.program_cache_sizes().values())
+
 
 class TestReplicaPrefixMetrics:
     """ISSUE 15 satellite: the fleet router's locality signal is visible
