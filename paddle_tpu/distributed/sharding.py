@@ -19,6 +19,13 @@ TPU-native rework: every stage is a SHARDING-SPEC CHOICE, not an engine.
   sharding axis (fleet.distributed_model(shard_params_on="sharding")); the
   forward all-gather + post-use release the reference implements by hand is
   XLA's all-gather + live-range analysis.
+
+The trainer's stacked decoder parameters (`[stage, (chunk,) layer, ...]`)
+take the axis on a dim of the PARAMETER, never on the stack dims
+(`compose_sharding_spec(..., n_lead=)`): every rank then holds a shard of
+every layer, a layer's weights are an all-gather of shards and its
+gradient a reduce-scatter, where whole layers on one rank would be
+broadcast and their gradients all-reduced whole.
 """
 
 from __future__ import annotations
@@ -39,20 +46,26 @@ __all__ = ["compose_sharding_spec", "DygraphShardingOptimizer",
 SHARDING_AXIS = "sharding"
 
 
-def compose_sharding_spec(spec: Optional[P], shape, axis: str, size: int) -> P:
+def compose_sharding_spec(spec: Optional[P], shape, axis: str, size: int,
+                          n_lead: int = 0) -> P:
     """Add ZeRO sharding on the first free dim divisible by the axis size
-    (mirrors the reference's rank-partition of flattened state)."""
+    (mirrors the reference's rank-partition of flattened state).
+
+    `n_lead` leading dims are never taken: the trainer's stacked decoder
+    parameters are `[stage, (chunk,) layer, ...param dims]`, and the axis
+    has to split each layer's weights (an all-gather of shards brings a
+    layer together), not hand whole layers to one rank (a broadcast)."""
+    spec = spec or P()
     if size <= 1:
-        return spec or P()
-    entries = list(spec or P()) + [None] * (len(shape) - len(spec or P()))
-    for d, s in enumerate(shape):
-        e = entries[d]
-        used = () if e is None else (e if isinstance(e, tuple) else (e,))
-        if axis in used:
-            return P(*entries)
-        if e is None and s % size == 0:
+        return spec
+    entries = list(spec) + [None] * (len(shape) - len(spec))
+    if any(axis in (e if isinstance(e, tuple) else (e,))
+           for e in entries if e is not None):
+        return P(*entries)
+    for d in range(n_lead, len(shape)):
+        if entries[d] is None and shape[d] % size == 0:
             entries[d] = axis
-            return P(*entries)
+            break
     return P(*entries)
 
 

@@ -4,7 +4,9 @@
 One jitted SPMD program composes every axis:
   pp  — compiled microbatch pipeline (distributed.pipeline)
   dp  — batch dim sharded (grad psum by GSPMD)
-  sharding — ZeRO: params+opt-state dim-0 sharded
+  sharding — ZeRO: params+opt-state sharded on a parameter dim (each
+        layer's weights split, all-gathered once a step; the weight
+        gradients reduce-scattered)
   sep — sequence dim sharded (context parallelism via GSPMD resharding
         around attention; ring-attention kernel lands at L6)
   mp  — Megatron TP (weight specs) + vocab-parallel CE
@@ -34,6 +36,7 @@ from ..distributed.pipeline import (PP_AXIS, spmd_pipeline,
                                     spmd_pipeline_interleaved,
                                     stack_layer_params,
                                     stack_layer_params_interleaved)
+from ..distributed.sharding import compose_sharding_spec
 from ..models.llama import (LlamaConfig, LlamaDecoderLayer, LlamaForCausalLM,
                             precompute_rope)
 from ..optimizer.functional import FunctionalAdamW
@@ -154,37 +157,17 @@ def flops_per_token_hw(c: LlamaConfig, seq_len: int) -> float:
     return 6.0 * _n_params(c) + attn
 
 
-def _param_spec_tree(state: Dict[str, jnp.ndarray], model) -> Dict[str, P]:
-    """Collect each param's sharding spec (TP specs from the layers; the
-    sharding (ZeRO) axis composes on dim 0 when divisible)."""
-    sd = model.state_dict()
-    specs = {}
-    from ..distributed.mesh import get_mesh, sanitize_spec
-    mesh = get_mesh()
-    for k, v in state.items():
-        spec = getattr(sd[k], "_sharding_spec", None)
-        if mesh is not None:
-            spec = sanitize_spec(mesh, spec)
-        specs[k] = spec if spec is not None else P()
-    return specs
+#: state-dict key prefix of a decoder layer's parameter -> the step scope
+#: (`observability.attribution.SCOPES`) of the part that uses it
+_WEIGHT_SCOPES = (("input_layernorm", "attn_norm"),
+                  ("post_attention_layernorm", "ffn_norm"),
+                  ("mlp.", "ffn"),
+                  ("self_attn.o_proj", "attn_out"),
+                  ("self_attn.", "qkv_proj"))
 
 
-def _compose_zero(spec: P, shape, axis: str, size: int) -> P:
-    """Add ZeRO sharding on the first free dim divisible by the axis size."""
-    if size <= 1:
-        return spec
-    entries = list(spec) + [None] * (len(shape) - len(spec))
-    for d, (e, s) in enumerate(zip(entries, shape)):
-        used = () if e is None else (e if isinstance(e, tuple) else (e,))
-        if axis in used:
-            return P(*entries)
-        if s % size == 0 and e is None:
-            entries[d] = axis
-            return P(*entries)
-        if s % size == 0 and not isinstance(e, tuple):
-            # compose with existing axis on same dim if still divisible
-            continue
-    return P(*entries)
+def _weight_scope(key: str) -> str:
+    return next(s for prefix, s in _WEIGHT_SCOPES if key.startswith(prefix))
 
 
 class TrainState(NamedTuple):
@@ -237,13 +220,16 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
     outer_specs = {k: (getattr(model_sd[k], "_sharding_spec", None) or P())
                    for k in outer}
 
-    # ZeRO composition on the sharding axis
+    # ZeRO composition on the sharding axis: inside each layer's weights,
+    # never on the stage / chunk / layer-count dims (whole layers on one
+    # rank would be broadcast to the others, not gathered from shards)
     zdeg = mesh.shape.get("sharding", 1)
-    stacked_specs = {k: _compose_zero(stacked_specs[k], stacked[k].shape,
-                                      "sharding", zdeg)
-                     for k in stacked}
-    outer_specs = {k: _compose_zero(outer_specs[k], outer[k].shape,
-                                    "sharding", zdeg) for k in outer}
+    gathered_specs = stacked_specs
+    stacked_specs = {k: compose_sharding_spec(
+        stacked_specs[k], stacked[k].shape, "sharding", zdeg, n_lead)
+        for k in stacked}
+    outer_specs = {k: compose_sharding_spec(
+        outer_specs[k], outer[k].shape, "sharding", zdeg) for k in outer}
 
     params = {"stacked": stacked, "outer": outer}
     specs = {"stacked": stacked_specs, "outer": outer_specs}
@@ -266,6 +252,31 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
     opt_state = tx.init(master)
 
     cos, sin = precompute_rope(mc.head_dim, cfg.seq_len, mc.rope_theta)
+
+    def zero_gather(stacked_bf16):
+        """Each layer's weights brought together over the sharding axis
+        ONCE a step: an all-gather of shards on the bf16 copy, before the
+        pipeline (outside its `shard_map`, whose stage body runs every
+        tick) and outside the rematerialised layer bodies, whose saved
+        inputs the gathered weights are, so that neither a recomputed
+        forward nor the backward gathers them again.  The cotangent is
+        constrained back to the shards: that is what lets the partitioner
+        reduce-scatter a weight's gradient where it would otherwise
+        all-reduce it whole."""
+        def gather(k):
+            def to(spec):
+                return lambda v: jax.lax.with_sharding_constraint(
+                    v, NamedSharding(mesh, spec))
+            whole, shard = to(gathered_specs[k]), to(stacked_specs[k])
+            f = jax.custom_vjp(whole)
+            f.defvjp(lambda v: (whole(v), None),
+                     lambda _, ct: (shard(ct),))
+            return f
+        out = {}
+        for k, v in stacked_bf16.items():
+            with _scope(_weight_scope(k)):    # the part that uses it
+                out[k] = gather(k)(v)
+        return out
 
     # stage body: apply L/S decoder layers via scan over the local slice;
     # per-layer remat (ref: fleet recompute intervals) keeps scan residuals
@@ -356,6 +367,9 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
         return total
 
     def loss_fn(compute_params, ids, labels):
+        if zdeg > 1:
+            compute_params = dict(compute_params, stacked=zero_gather(
+                compute_params["stacked"]))
         emb = compute_params["outer"][embed_key]
         with _scope("embed"):
             if mesh.shape.get("mp", 1) > 1:
@@ -445,8 +459,7 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
         # Pallas kernels in the step run per-shard on this mesh
         with kernel_mesh(mesh):
             loss, grads = jax.value_and_grad(cast_loss)(state.master)
-        # gradient clip, AdamW and the cast back; the ZeRO gather and
-        # scatter the partitioner puts around them take the scope too
+        # gradient clip, AdamW and the cast back, on each rank's shards
         with _scope("update"):
             new_master, new_opt, gnorm = tx.update(grads, state.opt_state,
                                                    state.master)

@@ -334,12 +334,23 @@ def test_every_op_of_the_trainer_step_answers_to_a_name():
     scoped = sum(r.scope is not None for r in table.values())
     assert scoped >= 0.9 * len(table), (scoped, len(table))
     # the mp all-reduce of a row-parallel matmul's output takes the
-    # matmul's name, forward and recomputed; ZeRO's traffic the update's
+    # matmul's name, forward and recomputed
     coll = {(r.scope, r.direction) for r in table.values()
             if r.kind == "collective"}
     assert {("attn_out", "fwd"), ("attn_out", "remat"),
             ("ffn", "fwd")} <= coll
-    assert any(s == "update" for s, _ in coll)
+    # ZeRO's traffic: a layer's weights are gathered under the name of
+    # the part that uses them, forward only (never recomputed, never in
+    # the backward); a weight gradient's sum over the axis answers to
+    # its matmul, backward; the update keeps the norm's scalars
+    gathers = {(r.scope, r.direction) for r in table.values()
+               if r.kind == "collective" and r.opcode == "all-gather"
+               and r.scope not in ("embed", "head_loss", "update")}
+    assert gathers == {(s, "fwd") for s in (
+        "attn_norm", "qkv_proj", "attn_out", "ffn_norm", "ffn")}, gathers
+    assert {("qkv_proj", "bwd"), ("attn_out", "bwd"), ("ffn", "bwd")} <= coll
+    assert {r.shape for r in table.values() if r.kind == "collective"
+            and r.scope == "update"} == {"f32[]"}
     # the state the step was handed is still the caller's
     state, m = step(state, ids, ids)
     assert np.isfinite(float(m["loss"]))

@@ -1,0 +1,311 @@
+"""ZeRO on the trainer's stacked decoder parameters (ISSUE 38): the
+`sharding` axis splits each layer's weights, never the stack of layers;
+a layer's weights cross the axis once a step, as an all-gather of
+shards in the forward direction; a checkpoint written under the old
+placement (whole layers on one rank) still resumes."""
+
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+import paddle_tpu as paddle
+from paddle_tpu.distributed.mesh import global_device_put
+from paddle_tpu.distributed.sharding import compose_sharding_spec
+from paddle_tpu.models.llama import llama_tiny_config
+from paddle_tpu.trainer.pretrain import (PretrainConfig,
+                                         build_llama_pretrain_step,
+                                         make_hybrid_mesh_for)
+
+STACKED_KEYS = ("input_layernorm.weight", "self_attn.qkv_proj.weight",
+                "self_attn.o_proj.weight", "post_attention_layernorm.weight",
+                "mlp.gate_up_proj.weight", "mlp.down_proj.weight")
+#: parallel degrees, number of leading stack dims [stage, (chunk,) layer]
+LAYOUTS = {"zero2-mp2": (dict(sharding=2, mp=2), 2),
+           "pp2-zero2": (dict(pp=2, sharding=2, n_microbatches=2), 2),
+           "pp2-vpp2-zero2": (dict(pp=2, sharding=2, vpp=2,
+                                   n_microbatches=2), 3)}
+
+needs_4 = pytest.mark.skipif(len(jax.devices()) < 4,
+                             reason="needs 4 (virtual) devices")
+
+
+def _build(seed=5, layers=2, **kw):
+    paddle.seed(seed)
+    mc = llama_tiny_config(num_hidden_layers=layers,
+                           max_position_embeddings=64,
+                           fuse_attention_qkv=True, fuse_attention_ffn=True,
+                           fuse_pack_groups=2)
+    base = dict(global_batch=4, seq_len=16, remat="full", scan_layers=False,
+                ce_chunks=2)
+    base.update(kw)
+    cfg = PretrainConfig(mc, **base)
+    n = cfg.dp * cfg.mp * cfg.pp * cfg.sharding * cfg.sep
+    mesh = make_hybrid_mesh_for(cfg, devices=jax.devices()[:n])
+    state, step, meta = build_llama_pretrain_step(cfg, mesh)
+    ids = global_device_put(jnp.asarray(np.random.RandomState(0).randint(
+        0, mc.vocab_size, (4, 16)), jnp.int32), meta["data_sharding"])
+    return state, step, meta, ids
+
+
+_built = {}
+
+
+def _layout(name):
+    if name not in _built:
+        kw, n_lead = LAYOUTS[name]
+        state = _build(layers=4 if "vpp" in kw else 2, **kw)[0]
+        _built[name] = ({k: (v.shape, v.sharding.spec)
+                         for k, v in state.master["stacked"].items()},
+                        n_lead, state)
+    return _built[name]
+
+
+def _axes(entry):
+    return () if entry is None else \
+        entry if isinstance(entry, tuple) else (entry,)
+
+
+def test_compose_skips_the_leading_stack_dims():
+    # [stage, layer, in, out]: 8 layers divide by 2, and are not taken
+    assert compose_sharding_spec(P("pp", None, None, "mp"), (1, 8, 128, 512),
+                                 "sharding", 2, n_lead=2) == \
+        P("pp", None, "sharding", "mp")
+    assert compose_sharding_spec(P("pp", None, "mp", None), (1, 8, 256, 128),
+                                 "sharding", 2, n_lead=2) == \
+        P("pp", None, "mp", "sharding")
+    # [stage, chunk, layer, hidden]
+    assert compose_sharding_spec(P("pp"), (2, 2, 2, 128), "sharding", 2,
+                                 n_lead=3) == P("pp", None, None, "sharding")
+    # no parameter dim divides: the parameter stays whole on that axis
+    assert compose_sharding_spec(P("pp"), (1, 8, 3), "sharding", 2,
+                                 n_lead=2) == P("pp", None, None)
+    # an axis of 1 changes nothing
+    assert compose_sharding_spec(P("pp", None, None, "mp"), (1, 8, 128, 512),
+                                 "sharding", 1, n_lead=2) == \
+        P("pp", None, None, "mp")
+
+
+@needs_4
+@pytest.mark.parametrize("key", STACKED_KEYS)
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_the_axis_lies_inside_each_layers_weights(layout, key):
+    specs, n_lead, state = _layout(layout)
+    shape, spec = specs[key]
+    entries = list(spec) + [None] * (len(shape) - len(spec))
+    assert entries[0] == "pp"
+    for d in range(1, n_lead):
+        assert entries[d] is None, (key, spec)
+    on = [d for d, e in enumerate(entries) if "sharding" in _axes(e)]
+    assert len(on) == 1 and on[0] >= n_lead, (key, spec)
+    # tensor parallelism is where the layer put it
+    want_mp = {"self_attn.qkv_proj.weight": len(shape) - 1,
+               "mlp.gate_up_proj.weight": len(shape) - 1,
+               "self_attn.o_proj.weight": len(shape) - 2,
+               "mlp.down_proj.weight": len(shape) - 2}.get(key)
+    has_mp = [d for d, e in enumerate(entries) if "mp" in _axes(e)]
+    assert has_mp == ([] if want_mp is None else [want_mp]), (key, spec)
+    # master weights, both moments and the bf16 copy follow the one spec
+    for tree in (state.params, state.opt_state.moment1,
+                 state.opt_state.moment2):
+        assert tree["stacked"][key].sharding.spec == spec
+
+
+#: the step's other users of the placement, each against itself with the
+#: sharding axis at 1 (what stays in `kw` is common to both sides)
+EQUIVALENT = {"zero2-mp2": dict(mp=2),
+              "zero2-mp2-scan": dict(mp=2, scan_layers=True),
+              "dp2-zero2-mp2": dict(dp=2, mp=2),
+              "pp2-zero2": dict(pp=2, n_microbatches=2),
+              "pp2-zero2-1f1b": dict(pp=2, n_microbatches=2,
+                                     pp_schedule="1F1B"),
+              "pp2-vpp2-zero2": dict(pp=2, vpp=2, n_microbatches=2,
+                                     layers=4)}
+
+
+@pytest.mark.parametrize("case", sorted(EQUIVALENT))
+def test_one_step_equals_the_unsharded_axis(case):
+    """The sharding axis at 2 against the same model without it, float32
+    throughout so that only the order of the sums differs."""
+    kw = EQUIVALENT[case]
+    if len(jax.devices()) < 2 * kw.get("dp", 1) * kw.get("mp", 1) \
+            * kw.get("pp", 1):
+        pytest.skip("needs more (virtual) devices")
+    got, want = {}, {}
+    for out, zero in ((got, dict(sharding=2)), (want, {})):
+        state, step, _, ids = _build(param_dtype="float32", **kw, **zero)
+        state, m = step(state, ids, ids)
+        out.update(loss=float(m["loss"]), gnorm=float(m["grad_norm"]),
+                   master=jax.tree.map(np.asarray, state.master),
+                   grad=jax.tree.map(np.asarray, state.opt_state.moment1))
+    np.testing.assert_allclose(got["loss"], want["loss"], rtol=1e-6)
+    np.testing.assert_allclose(got["gnorm"], want["gnorm"], rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(got["grad"]),
+                    jax.tree.leaves(want["grad"])):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)
+    # AdamW's first step moves every weight by lr x sign(gradient): a
+    # gradient within rounding of zero may take either sign
+    lr = 3e-4
+    for a, b in zip(jax.tree.leaves(got["master"]),
+                    jax.tree.leaves(want["master"])):
+        off = np.abs(a - b) > 1e-6
+        assert off.mean() < 1e-3 and np.abs(a - b).max() <= 2.01 * lr
+
+
+_COLL = re.compile(
+    r"= (\S+) (all-gather|all-reduce|collective-permute|reduce-scatter)"
+    r"(?:-start)?\(")
+
+
+def _dims(shape):
+    """`bf16[1,8,64,128]{...}` -> (1, 8, 64, 128)"""
+    return tuple(int(d) for d in re.search(
+        r"\[([\d,]*)\]", shape).group(1).split(",") if d)
+
+
+def _collectives(text):
+    """(opcode, result dims, op_name) of the collectives over the ZeRO
+    groups {{0,2},{1,3}} of the 2 x 2 mesh (sharding major, mp minor)."""
+    out = []
+    for line in text.splitlines():
+        m = _COLL.search(line)
+        if not m:
+            continue
+        groups = re.search(r"replica_groups=(\S+?),? ", line)
+        pairs = re.search(r"source_target_pairs=(\S+?),? ", line)
+        where = (groups or pairs).group(1)
+        if where.startswith(("{{0,2},{1,3}}", "[2,2]<=[2,2]T(1,0)",
+                             "{{0,2},{2,0}", "{{2,0},{3,1}}")):
+            name = re.search(r'op_name="([^"]*)"', line)
+            out.append((m.group(2), _dims(m.group(1)),
+                        name.group(1) if name else ""))
+    return out
+
+
+@needs_4
+def test_each_layers_weights_are_gathered_once_from_halves():
+    state, step, meta, ids = _build(sharding=2, mp=2)
+    state, _ = step(state, ids, ids)
+    text = meta["compiled_programs"](state)["train_step"].as_text()
+    colls = _collectives(text)
+    layers = 2
+    # the per-chip shape of one layer's weight, whole over the ZeRO axis
+    whole = {k: tuple(s // (2 if "mp" in _axes(e) else 1)
+                      for s, e in zip(v.shape[2:], list(v.sharding.spec)[2:]
+                                      + [None] * v.ndim))
+             for k, v in state.master["stacked"].items()}
+    gathers = [c for c in colls if c[0] == "all-gather"
+               and "head_loss" not in c[2] and "embed" not in c[2]
+               and "update" not in c[2]]
+    for g in gathers:
+        assert "rematted_computation" not in g[2] \
+            and "checkpoint" not in g[2] and "transpose" not in g[2], g
+    # one gather a key for the whole stack, or one a layer: either way
+    # every layer of every key crosses the axis exactly once
+    want = {}
+    for shape in whole.values():
+        want[shape] = want.get(shape, 0) + layers
+    by_shape = {}
+    for _, dims, _ in gathers:
+        dims = tuple(d for d in dims if d != 1) or (1,)
+        if dims[1:] in want and dims[0] == layers:
+            dims, n = dims[1:], layers
+        else:
+            n = 1
+        by_shape[dims] = by_shape.get(dims, 0) + n
+    assert by_shape == want, (by_shape, want)
+    # no layer travels whole from an owner to the other rank
+    for op, dims, name in colls:
+        if op == "collective-permute":
+            assert tuple(d for d in dims if d != 1) not in want, (dims, name)
+
+
+@needs_4
+def test_a_pipelines_stack_is_gathered_before_the_pipeline():
+    """pp 2 x sharding 2: a stage's body runs every tick (and again when
+    rematerialised), so its weights are gathered ONCE, ahead of the
+    pipeline's `shard_map`: in the entry computation, not in the loop."""
+    state, step, meta, ids = _build(pp=2, sharding=2, n_microbatches=2)
+    state, _ = step(state, ids, ids)
+    text = meta["compiled_programs"](state)["train_step"].as_text()
+    cut = text.index("\nENTRY ")
+    want = {}
+    for v in state.master["stacked"].values():
+        want[tuple(v.shape[2:])] = want.get(tuple(v.shape[2:]), 0) + 1
+
+    def weight_gathers(part):
+        got = {}
+        for line in part.splitlines():
+            m = _COLL.search(line)
+            if m and m.group(2) == "all-gather" \
+                    and "head_loss" not in line:
+                dims = tuple(d for d in _dims(m.group(1)) if d != 1)
+                if dims in want:
+                    got[dims] = got.get(dims, 0) + 1
+        return got
+    assert weight_gathers(text[cut:]) == want
+    assert weight_gathers(text[:cut]) == {}
+
+
+#: sha256 of the lowered step's text with the ZeRO axis at 1 (mp 2,
+#: toy widths, this file's `_build`), recorded at PR 38's parent
+LOWERED_AT_PARENT_WITHOUT_ZERO = \
+    "65f046752feb570514b4541d1b89f94de1adc53eb0aa90fadf7633aa19f71bb7"
+
+
+def test_without_the_axis_the_step_lowers_to_the_parents_text():
+    if len(jax.devices()) < 2:
+        pytest.skip("needs 2 (virtual) devices")
+    state, step, meta, ids = _build(mp=2)
+    shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=NamedSharding(
+            meta["mesh"], a.sharding.spec if hasattr(a.sharding, "spec")
+            else P())), state)
+    text = step.lower(shapes, ids, ids).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        LOWERED_AT_PARENT_WITHOUT_ZERO
+
+
+@needs_4
+def test_a_checkpoint_of_the_old_placement_resumes(tmp_path):
+    """Arrays saved while whole layers lived on one rank
+    (`('pp', 'sharding', ...)`) restore into the step's own placement,
+    and the next loss is the uninterrupted run's."""
+    from paddle_tpu.distributed import checkpoint as dck
+    from paddle_tpu.trainer.run_pretrain import (_flatten_state,
+                                                 _restore_state)
+    state, step, meta, ids = _build(sharding=2, mp=2)
+    state, _ = step(state, ids, ids)
+    mesh = meta["mesh"]
+
+    def old_way(path, leaf):
+        keys = [getattr(p, "key", None) for p in path]
+        if "stacked" not in keys:
+            return leaf
+        spec = list(leaf.sharding.spec) + [None] * leaf.ndim
+        rest = [None if "sharding" in _axes(e) else e
+                for e in spec[2:leaf.ndim]]
+        return jax.device_put(leaf, NamedSharding(
+            mesh, P("pp", "sharding", *rest)))
+    old = state._replace(
+        master=jax.tree_util.tree_map_with_path(old_way, state.master),
+        opt_state=jax.tree_util.tree_map_with_path(old_way,
+                                                   state.opt_state))
+    assert old.master["stacked"]["mlp.gate_up_proj.weight"].sharding.spec \
+        == P("pp", "sharding", None, "mp")
+    dck.save_state_dict(_flatten_state(old), str(tmp_path / "ck"))
+    _, m_next = step(state, ids, ids)
+
+    fresh, step2, _, _ = _build(seed=11, sharding=2, mp=2)
+    flat = _flatten_state(fresh)
+    dck.load_state_dict(flat, str(tmp_path / "ck"))
+    resumed = _restore_state(fresh, flat, jnp.bfloat16)
+    for k, v in resumed.master["stacked"].items():
+        assert v.sharding.spec == fresh.master["stacked"][k].sharding.spec
+    _, m_resumed = step2(resumed, ids, ids)
+    assert float(m_resumed["loss"]) == float(m_next["loss"])
+    assert float(m_resumed["grad_norm"]) == float(m_next["grad_norm"])
