@@ -495,6 +495,39 @@ def _eva_decode_params(model):
             cfg.rope_theta, cfg.head_dim, n))))
 
 
+def _looped_decode_params(model):
+    """OuroForCausalLM: a llama-layout weight tree run ``total_ut_steps``
+    times a token. Each layer carries four gains (``ln1_out`` and
+    ``ln2_out`` norm a sublayer's OUTPUT before the add); ``norm`` closes
+    every pass; ``gate_w`` [hidden] and ``gate_b`` [] are the exit gate.
+    ``attn_static`` marks the family as served by the unified ragged
+    step only."""
+    from .models.evabyte import rope_table
+    inner, cfg = model.model, model.config
+    layers = []
+    for lyr in inner.layers:
+        a, m = lyr.self_attn, lyr.mlp
+        layers.append(dict(
+            ln1=lyr.input_layernorm.weight._data,
+            wq=a.q_proj.weight._data, wk=a.k_proj.weight._data,
+            wv=a.v_proj.weight._data, wo=a.o_proj.weight._data,
+            ln1_out=lyr.input_layernorm_2.weight._data,
+            ln2=lyr.post_attention_layernorm.weight._data,
+            wg=m.gate_proj.weight._data, wu=m.up_proj.weight._data,
+            wd=m.down_proj.weight._data,
+            ln2_out=lyr.post_attention_layernorm_2.weight._data))
+    gate = inner.early_exit_gate
+    return dict(
+        cfg=cfg, family="looped", embed=inner.embed_tokens.weight._data,
+        layers=layers, norm=inner.norm.weight._data,
+        gate_w=gate.weight._data[:, 0], gate_b=gate.bias._data[0],
+        head=model.lm_head.weight._data,
+        attn_static=(dict(heads=cfg.num_attention_heads, window=None,
+                          rope=""),) * len(layers),
+        rope_fn=lambda n: dict(zip(("cos", "sin"), rope_table(
+            cfg.rope_theta, cfg.head_dim, n))))
+
+
 def _mla_decode_params(model, weight_only_int8: bool = False,
                        algo: str = "weight_only_int8"):
     """DeepSeekV2ForCausalLM: multi-head latent attention with the
@@ -575,6 +608,13 @@ def _decode_params(model, weight_only_int8: bool = False,
         from .models.evabyte import EvaByteModel
         from .models.laguna import LagunaModel
         from .models.moe_llm import MoEModel
+        from .models.ouro import OuroModel
+        if isinstance(inner, OuroModel):
+            if enabled:
+                raise NotImplementedError(
+                    "weight-only quantisation is not wired for the "
+                    "Ouro family")
+            return _looped_decode_params(model)
         if isinstance(inner, EvaByteModel):
             if enabled:
                 raise NotImplementedError(
@@ -1008,6 +1048,12 @@ def _cached_step_body(p, max_len: int):
             "beside a tumbling window) decodes through "
             "serving.ServingEngine; the contiguous-cache bodies keep "
             "one row a token")
+    if p["family"] == "looped":
+        raise NotImplementedError(
+            "the Ouro family (the layer list run total_ut_steps times, a "
+            "cache row for every pass of every layer) decodes through "
+            "serving.ServingEngine; the contiguous-cache bodies keep "
+            "one row a layer")
     if p["family"] == "gpt":
         return _gpt_cached_step_body(p["cfg"], max_len)
     if p["family"] == "mla":

@@ -586,18 +586,21 @@ def _c_fused_qkv_rope_append(*, T: int, H: int, Hq: int, KV: int = 0,
 
 def kv_bytes_per_token_layer(family: str, *, kv_heads: int = 0,
                              head_dim: int = 0, kv_latent_dim: int = 0,
-                             kv_dtype_bytes: int = 2) -> int:
+                             kv_dtype_bytes: int = 2,
+                             passes: int = 1) -> int:
     """HBM bytes of cache READ per context token per layer at decode:
     K+V rows for the attention families, the single [latent|rope] row
-    for mla (read once — the absorbed decode's whole advantage)."""
+    for mla (read once — the absorbed decode's whole advantage).
+    ``passes``: a looped decoder applies a layer that many times a
+    token and every pass keeps, and reads, its own rows."""
     if family == "mla":
         if not kv_latent_dim:
             raise ValueError("mla needs kv_latent_dim "
                              "(kv_lora_rank + qk_rope_head_dim)")
-        return kv_latent_dim * kv_dtype_bytes
+        return passes * kv_latent_dim * kv_dtype_bytes
     if not (kv_heads and head_dim):
         raise ValueError(f"{family} needs kv_heads and head_dim")
-    return 2 * kv_heads * head_dim * kv_dtype_bytes
+    return passes * 2 * kv_heads * head_dim * kv_dtype_bytes
 
 
 def decode_step_budget(family: str = "llama", *, batch: int,
@@ -605,9 +608,12 @@ def decode_step_budget(family: str = "llama", *, batch: int,
                        kv_heads: int = 0, head_dim: int = 0,
                        kv_latent_dim: int = 0, kv_dtype_bytes: int = 2,
                        page_size: Optional[int] = None,
-                       spec_rows: int = 1) -> Dict[str, Any]:
+                       spec_rows: int = 1,
+                       passes: int = 1) -> Dict[str, Any]:
     """HBM budget of ONE decode step (every weight byte + every live
     cache byte crosses once): the serving roofline's denominator.
+    A looped decoder (``passes`` > 1) hands in as ``weight_bytes`` what
+    a step READS: its layers once a pass.
 
     ``page_size=None`` counts cache rows exactly (the naive roofline
     SERVING_BENCH committed); an int rounds each sequence up to whole
@@ -616,7 +622,8 @@ def decode_step_budget(family: str = "llama", *, batch: int,
     """
     per_tok = kv_bytes_per_token_layer(
         family, kv_heads=kv_heads, head_dim=head_dim,
-        kv_latent_dim=kv_latent_dim, kv_dtype_bytes=kv_dtype_bytes)
+        kv_latent_dim=kv_latent_dim, kv_dtype_bytes=kv_dtype_bytes,
+        passes=passes)
     if page_size is None:
         kv_seq = per_tok * float(context) * layers
     else:

@@ -62,7 +62,7 @@ __all__ = ["TraceEvent", "RequestTrace", "TraceRecorder", "recorder",
            "enabled", "set_enabled", "percentile", "percentiles",
            "slo_summary", "SLO_METRICS", "STEP_COUNTS", "STEPS_PER_SLOT",
            "STEP_COUNTS_BY_KIND", "STEP_COUNTS_MOE", "STEP_COUNTS_LATENT",
-           "STEP_COUNTS_EVA"]
+           "STEP_COUNTS_EVA", "STEP_COUNTS_LOOP"]
 
 _FLAG = _flags._registry["FLAGS_request_tracing"]
 
@@ -143,6 +143,14 @@ STEP_COUNTS_EVA: Tuple[str, ...] = (
     "windows_closed", "cache_row_bytes", "window_pages_freed",
     "pool_pages_used.summary", "pool_pages_used.exact",
     "pool_pages_total.summary", "pool_pages_total.exact")
+#: ... and where the layer list runs several times a token (a looped
+#: decoder): the passes of a launch, the layer applications they make,
+#: the bytes ALL of a token's cache rows take (passes x layers x K + V),
+#: and — taken on the device, returned with the step's logits — the
+#: mean exit distribution p(u) of the rows a request owned, a tuple of
+#: `ut_steps` floats that sums to 1: what adaptive exit would save
+STEP_COUNTS_LOOP: Tuple[str, ...] = (
+    "ut_steps", "layer_applications", "cache_row_bytes", "ut_exit_mass")
 #: step records kept for each slot of the request ring. A request lives
 #: through tens to hundreds of steps, and whoever reads a whole measured
 #: window from the records (`benchmarks/lib/program_spans.py`: 50-56 s

@@ -76,6 +76,7 @@ from ..ops.fused import (append_run_count, append_run_table, append_tile,
                          fused_append_rows, fused_chunk_pool,
                          fused_layer_norm, fused_rms_norm,
                          fused_rope_append)
+from ..models.ouro import exit_distribution as _exit_distribution
 from ..ops.paged_attention import append_to_cache, paged_attention
 from ..ops.pallas_ragged import (ragged_kernel_eligible,
                                  ragged_paged_attention,
@@ -268,7 +269,9 @@ class _Launch:
                  counts):
         self.logits = logits    # [S, vocab] on the device ([T, ..] K > 0)
         self.tokens = tokens    # [S] int32 on the device: _greedy(logits)
-        self.moe = moe          # [5] routed-layer counts, or None
+        self.moe = moe          # counts taken on the device, or None:
+        #                         [5] of the routed layers, [passes] the
+        #                         looped decoder's exit distribution
         self.preq = preq        # the request whose chunk rides it, or None
         self.n = n              # that chunk's rows
         self.rows = rows        # [(slot, request)] of its decode rows
@@ -415,6 +418,29 @@ class ServingEngine:
             self.num_window_pages = (self.max_slots + 1) * cap + 1
         else:
             self.num_window_pages = 0
+        # a looped decoder runs its layer list `total_ut_steps` times a
+        # token over ONE set of weights, and each pass attends to its OWN
+        # rows: a token holds passes x layers cache rows. Each layer's
+        # pool holds `passes` x num_pages pages and pass u reads and
+        # writes page p as page p + u * num_pages, so the allocator, the
+        # row tables and the kernels see one page id a token
+        self._passes = 1
+        if self._family == "looped":
+            self._passes = int(cfg.total_ut_steps)
+            if cfg.early_exit_threshold < 1.0:
+                raise NotImplementedError(
+                    f"early_exit_threshold {cfg.early_exit_threshold} < 1: "
+                    f"rows that leave a launch after an early pass still "
+                    f"owe the later passes their cache rows; adaptive exit "
+                    f"is not built (ROADMAP R13) and every token takes "
+                    f"all {self._passes} passes")
+            _refuse_shared_cache(
+                f"this model runs its layers {self._passes} times a token "
+                f"and a page id names {self._passes} pages of every "
+                f"layer's pool, which a page copy does not move, so ",
+                enable_prefix_cache, spec_decode, role)
+            # a forked page's copy-on-write would copy pass 0 only
+            enable_prefix_cache = prefix_sharing = False
         if self._eva:
             _refuse_shared_cache(
                 "this model's layers are chunk-summary attention (pooled "
@@ -470,7 +496,7 @@ class ServingEngine:
                                          cfg.qk_rope_head_dim)
         else:
             kv, d = cfg.num_key_value_heads, cfg.head_dim
-        shape = (kv, self.num_pages, self.page_size, d)
+        shape = (kv, self._passes * self.num_pages, self.page_size, d)
         wshape = (kv, self.num_window_pages, self.page_size, d)
         # each layer's kind: 0 keeps every page, 1 is the window kind
         self._layer_kind = [int(st["window"] is not None)
@@ -532,6 +558,8 @@ class ServingEngine:
             self._count_names += _tracing.STEP_COUNTS_LATENT
         if self._eva:
             self._count_names += _tracing.STEP_COUNTS_EVA
+        if self._family == "looped":
+            self._count_names += _tracing.STEP_COUNTS_LOOP
         self._counts = dict.fromkeys(self._count_names, 0)
         # the pool handles this step's launches were handed (dead
         # arrays, no buffers): `pools_in_place` asks them at account
@@ -555,10 +583,13 @@ class ServingEngine:
         self._append_tile = append_tile(dt, self.page_size)
         planes = 1 if self._family == "mla" else 2
         self._hbm_weights_bytes = _costmodel.tree_bytes(self._w)
-        self._hbm_pool_bytes = sum(
+        self._hbm_pool_bytes = self._passes * sum(
             planes * kv * (self.num_window_pages if k else self.num_pages)
             * self.page_size * d * self._kv_itemsize
             for k in self._layer_kind)
+        # what ONE launch reads of the weights: the layers once a pass
+        self._hbm_weight_read_bytes = self._hbm_weights_bytes + (
+            self._passes - 1) * _costmodel.tree_bytes(self._w["layers"])
         self._ledger_bytes = 0.0
         self._ledger_model_bytes = 0.0
         self._ledger_tokens = 0
@@ -939,7 +970,7 @@ class ServingEngine:
         per_tok = _costmodel.kv_bytes_per_token_layer(
             self._family, kv_heads=kv, head_dim=d,
             kv_latent_dim=(d if self._family == "mla" else 0),
-            kv_dtype_bytes=self._kv_itemsize)
+            kv_dtype_bytes=self._kv_itemsize, passes=self._passes)
         # (chunk-summary layers read a sequence's visible pooled rows and
         # its window's, not its length)
         length = (lambda rid: self.allocator.attention_view(rid)[2]) \
@@ -963,7 +994,7 @@ class ServingEngine:
                     + sum(min(-(-ln // self.page_size), wcap)
                           for ln in lens) * self._layer_kind.count(1))
             self._ledger_bytes += (
-                dl * self._hbm_weights_bytes
+                dl * self._hbm_weight_read_bytes
                 + dl * layer_pages * self.page_size * per_tok
                 * spec_rows)
             if lens:
@@ -972,11 +1003,12 @@ class ServingEngine:
                 budget = _costmodel.decode_step_budget(
                     self._family, batch=len(lens),
                     context=sum(lens) / len(lens), layers=n_layers,
-                    weight_bytes=self._hbm_weights_bytes,
+                    weight_bytes=self._hbm_weight_read_bytes,
                     kv_heads=kv, head_dim=d,
                     kv_latent_dim=(d if self._family == "mla" else 0),
                     kv_dtype_bytes=self._kv_itemsize,
-                    page_size=self.page_size, spec_rows=spec_rows)
+                    page_size=self.page_size, spec_rows=spec_rows,
+                    passes=self._passes)
                 self._ledger_model_bytes += budget["bytes_per_step"]
         if self._ledger_tokens:
             _G_BPT_MEASURED.set(self._ledger_bytes
@@ -1183,6 +1215,11 @@ class ServingEngine:
         return handoff
 
     def _no_handoff(self, what: str) -> None:
+        if self._passes > 1:
+            raise NotImplementedError(
+                f"{what}: this model runs its layers {self._passes} times "
+                f"a token; a KV-page handoff of {self._passes} pages a "
+                f"page id is not implemented")
         if self._eva:
             raise NotImplementedError(
                 f"{what}: this model's layers are chunk-summary attention; "
@@ -1737,6 +1774,13 @@ class ServingEngine:
                 dtype=self._q_dtype, page_size=ps, pages_per_seq=nj,
                 window=window) for r in self._kind_rep[kind])
 
+        if self._family == "looped":
+            n_layers = len(self._p["layers"])
+            counts.update({
+                "ut_steps": self._passes,
+                "layer_applications": self._passes * n_layers,
+                "cache_row_bytes": self._passes * n_layers * 2
+                * self._kv_geom[0] * self._kv_geom[1] * self._kv_itemsize})
         live = int(np.sum(-(-kv_lengths // ps)))
         counts["pages_live"] = live
         counts["pages_visited"] = visited(0)
@@ -1783,7 +1827,11 @@ class ServingEngine:
         tokens = np.asarray(fl.tokens)
         logits = np.asarray(fl.logits) if self.on_logits is not None \
             else None
-        if fl.moe is not None:
+        if fl.moe is not None and self._family == "looped":
+            # the mean exit distribution of the rows a request owned
+            fl.counts["ut_exit_mass"] = tuple(
+                float(v) for v in np.asarray(fl.moe))
+        elif fl.moe is not None:
             # the routed layers' counts came back with the tokens
             fl.counts.update(zip(_tracing.STEP_COUNTS_MOE,
                                  (float(v) for v in np.asarray(fl.moe))))
@@ -1866,8 +1914,8 @@ class ServingEngine:
         # launch: its rows and pages beside the tokens `step()` returns
         # and the device time its span mostly holds
         for k, v in counts.items():
-            self._retired_counts[k] = v + (
-                self._retired_counts.get(k, 0) if k in _ADDITIVE else 0)
+            self._retired_counts[k] = v + self._retired_counts.get(k, 0) \
+                if k in _ADDITIVE else v
 
     def _emit(self, req: Request, tok: int,
               row: Optional[np.ndarray] = None) -> int:
@@ -1938,6 +1986,8 @@ class ServingEngine:
     def _make_unified_body(self):
         if self._family == "eva":
             return self._eva_unified_body()
+        if self._family == "looped":
+            return self._looped_unified_body()
         if self._family == "gpt":
             return self._gpt_unified_body()
         if self._family == "mla":
@@ -2136,6 +2186,104 @@ class ServingEngine:
                                  preferred_element_type=f32)
                 tokens = _greedy(logits)
             return logits, new_pools, tokens
+
+        return step
+
+    def _looped_unified_body(self):
+        """A looped decoder on the one chain: the layer list runs
+        `total_ut_steps` times inside the launch over weights held once.
+        Pass u of layer l keeps its own cache rows: page p of the row
+        tables is page p + u * num_pages of the layer's pool (the table,
+        and the page column of the append's run table, shifted). A layer
+        is a sandwich — norm -> q / k / v -> `fused_rope_append` ->
+        `ragged_paged_attention` -> o-proj -> NORM -> add; norm ->
+        SwiGLU -> NORM -> add — and every pass ends with the model's
+        last norm, whose output feeds the next pass and the exit gate
+        (`loop_norm`). The logits are the last pass's; the mean exit
+        distribution of the owned rows goes back beside them
+        (`ut_exit_mass`). The passes are a compiled loop, not unrolled:
+        both forms were measured (PERF.md section 6, PR 39: the same
+        step, half the compile, a third of the text)."""
+        cfg = self._p["cfg"]
+        H, KV, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                    cfg.head_dim)
+        eps, U, N = cfg.rms_norm_eps, self._passes, self.num_pages
+        B, C = self.max_slots, self.prefill_chunk
+        T = B + C
+        seq_start = _seq_starts(B, 1)
+        run_table = self._run_table(seq_start)
+
+        def step(w, tok, pools, positions, num_tokens, kv_lengths,
+                 tables, tok_page, tok_off):
+            with _scope("embed"):
+                x = w["embed"][tok][None]                # [1, T, hidden]
+                c, s = w["cos"][positions], w["sin"][positions]
+            with _scope("cache_write"):
+                runs = run_table(num_tokens, tok_page, tok_off)
+                # the run table's page column (`append_run_table`)
+                G = runs.shape[0] // 5
+                page_col = (jnp.arange(5 * G) // G == 2).astype(jnp.int32)
+
+            # ONE jitted layer, called once a layer: the program's text
+            # holds its kernels once, not once a layer, and lowering
+            # them is most of a warm start (XLA inlines the calls)
+            @jax.jit
+            def layer(L, x, kp, vp, table, runs_u, c, s, num_tokens,
+                      kv_lengths):
+                with _scope("attn_norm"):
+                    h = fused_rms_norm(x, L["ln1"], eps)
+                with _scope("qkv_proj"):
+                    q, k, v = h @ L["wq"], h @ L["wk"], h @ L["wv"]
+                with _scope("cache_write"):
+                    q, kp, vp = fused_rope_append(
+                        q.reshape(T, H, D), k.reshape(T, KV, D),
+                        v.reshape(T, KV, D), c, s, kp, vp, runs_u)
+                with _scope("attention"):
+                    o = ragged_paged_attention(
+                        q, kp, vp, seq_start, num_tokens, kv_lengths,
+                        table, scale=D ** -0.5)
+                with _scope("attn_out"):
+                    x = x + fused_rms_norm(
+                        o.reshape(1, T, H * D) @ L["wo"], L["ln1_out"], eps)
+                with _scope("ffn_norm"):
+                    h2 = fused_rms_norm(x, L["ln2"], eps)
+                with _scope("ffn"):
+                    x = x + fused_rms_norm(
+                        (jax.nn.silu(h2 @ L["wg"]) * (h2 @ L["wu"]))
+                        @ L["wd"], L["ln2_out"], eps)
+                return x, kp, vp
+
+            def one_pass(u, carry):
+                x, pools, lam = carry
+                off = u * N
+                table, runs_u = tables + off, runs + page_col * off
+                new_pools = []
+                for L, (kp, vp) in zip(w["layers"], pools):
+                    x, kp, vp = layer(L, x, kp, vp, table, runs_u, c, s,
+                                      num_tokens, kv_lengths)
+                    new_pools.append((kp, vp))
+                with _scope("loop_norm"):
+                    x = fused_rms_norm(x, w["norm"], eps)
+                    gate = jax.nn.sigmoid(
+                        jnp.dot(x[0], w["gate_w"],
+                                preferred_element_type=jnp.float32)
+                        + w["gate_b"].astype(jnp.float32))
+                    lam = jax.lax.dynamic_update_index_in_dim(
+                        lam, gate, u, 0)
+                return x, new_pools, lam
+
+            x, new_pools, lam = jax.lax.fori_loop(
+                0, U, one_pass,
+                (x, list(pools), jnp.zeros((U, T), jnp.float32)))
+            with _scope("head"):
+                logits = _head_logits(
+                    w, _logit_rows(x, seq_start, num_tokens, 0))
+                tokens = _greedy(logits)
+            with _scope("loop_norm"):
+                own = _owned_rows(T, seq_start, num_tokens)
+                mass = jnp.where(own[:, None], _exit_distribution(lam.T), 0)
+                mass = mass.sum(0) / jnp.maximum(own.sum(), 1)
+            return logits, new_pools, tokens, mass
 
         return step
 

@@ -262,11 +262,21 @@ def _eva():
     return m
 
 
+def _ouro():
+    from paddle_tpu.models.ouro import OuroForCausalLM, ouro_tiny_config
+    paddle.seed(0)
+    m = OuroForCausalLM(ouro_tiny_config(max_position_embeddings=64,
+                                         rope_positions=64))
+    m.eval()
+    return m
+
+
 #: family -> names its unified step must show
 FAMILIES = {
     "llama": {"ffn"}, "moe": {"routed_ffn", "shared_expert"},
     "mla": {"routed_ffn"}, "gpt": {"ffn"},
     "laguna": {"routed_ffn", "shared_expert"}, "eva": {"ffn"},
+    "looped": {"ffn", "loop_norm"},
 }
 EVERY_STEP = {"embed", "attn_norm", "qkv_proj", "cache_write", "attention",
               "attn_out", "ffn_norm", "head"}
@@ -275,7 +285,7 @@ EVERY_STEP = {"embed", "attn_norm", "qkv_proj", "cache_write", "attention",
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_every_op_of_a_serving_step_answers_to_a_name(family):
     m = _laguna() if family == "laguna" else _eva() if family == "eva" \
-        else _tiny(family)
+        else _ouro() if family == "looped" else _tiny(family)
     kw = dict(max_slots=3, page_size=8, max_context=256, prefill_chunk=8,
               num_pages=64) if family == "eva" else \
         dict(max_slots=2, page_size=8, max_context=64, prefill_chunk=8)
