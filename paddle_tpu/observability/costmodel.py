@@ -378,11 +378,14 @@ def _c_ragged(*, T: int, H: int, KV: int, D: int, S: int,
               pages_per_seq: int, page_size: int,
               dtype_bytes: int = 2,
               window: Optional[int] = None) -> CostEstimate:
-    """Ragged mixed prefill+decode, grid (KV, tiles of TQ tokens): each
-    cell reads one [TQ*rep, D] query tile and writes one output tile
-    (the rows that pad T up to whole tiles are not counted); the pools
-    stay in HBM and a cell DMAs, for every sequence with rows in its
-    tile, the pages up to the tile's causal limit. Stated for the
+    """Ragged mixed prefill+decode, grid (KV / hb, tiles of TQ tokens):
+    each cell reads the [TQ*rep, D] query tile of each of its hb KV
+    heads and writes their output tiles (the rows that pad T up to
+    whole tiles are not counted); the pools stay in HBM and a cell DMAs,
+    for every sequence with rows in its tile, the pages up to the tile's
+    causal limit, each page for its hb heads at once. The head block
+    (`ops/pallas_ragged.ragged_head_block`) changes how many DMAs bring
+    the bytes, not the bytes: nothing below depends on it. Stated for the
     heaviest launch of these shapes: every table full and the T rows
     spread evenly over the S sequences, so a sequence's pages cross once
     for each tile its rows span (once for a decode batch, T == S). With

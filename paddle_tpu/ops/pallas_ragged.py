@@ -32,11 +32,17 @@ the causal limit of the sequence's LAST row inside the tile, never more
 than its live pages. `_tile_pages` computes that table for the kernel
 (in XLA, once a step: the per-layer calls are identical and merge) and
 for the engine's `pages_visited` counter (`ragged_pages_visited`), so
-the two cannot drift. Grid (KV, tiles): a cell owns one [TQ*rep, D]
-query tile and its output tile; the K/V pools stay in HBM and the cell
-walks its pairs' pages, each page one K and one V DMA into a ring of
-`_page_buffers` slots. The DMAs run ahead of the compute along the
-head's FLAT walk, across pair and tile boundaries (the read-ahead
+the two cannot drift. Grid (KV / hb, tiles): a cell owns the
+[TQ*rep, D] query tiles of a BLOCK of hb KV heads (`ragged_head_block`,
+read from the shapes) and their output tiles; the K/V pools stay in HBM
+and the cell walks its pairs' pages, each page visit ONE K and ONE V DMA
+of the page as all hb heads hold it (a strided copy out of the
+[KV, pages, psz, D] pools) into a ring of `_page_buffers` slots. What a
+visit costs whatever the page holds — the work-list reads, the slot
+arithmetic, the read-ahead cursor, the DMA starts and waits, the mask —
+is paid once a visit; the hb heads' softmax updates are hb independent
+chains in the one loop body. The DMAs run ahead of the compute along
+the block's FLAT walk, across pair and tile boundaries (the read-ahead
 cursor is carried from cell to cell in SMEM). No grid step, DMA or
 branch exists for a dead (sequence, page) entry, and a page meets only
 the TQ*rep rows of a tile that holds rows of its sequence. Rows of
@@ -48,6 +54,7 @@ interpret mode off-TPU.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Optional
@@ -62,7 +69,7 @@ from .pallas_paged import paged_kernel_eligible
 
 __all__ = ["ragged_paged_attention", "ragged_attention_reference",
            "ragged_kernel_eligible", "ragged_tile_tokens",
-           "ragged_pages_visited"]
+           "ragged_head_block", "ragged_pages_visited"]
 
 _NEG = -1e30
 _MASKED = -3e38
@@ -72,6 +79,14 @@ _MASKED = -3e38
 _TILE_ROWS = 128
 #: K+V bytes the page DMAs keep in flight ahead of the compute
 _BYTES_IN_FLIGHT = 256 * 1024
+#: VMEM a grid cell's blocks may take: what `ragged_head_block` sums,
+#: under the compiler's default scoped limit (16 MiB on the chips this
+#: runs on; no `vmem_limit_bytes`) with room for the scores and
+#: probabilities of the head being computed
+_VMEM_BUDGET = 10 * 1024 * 1024
+#: KV heads a page visit serves at most: PERF.md (PR 42) has the sweep
+#: on a v5e
+_HEAD_BLOCK_MAX = 16
 
 
 def _interpret() -> bool:
@@ -101,10 +116,36 @@ def ragged_tile_tokens(T: int, rep: int, dtype) -> int:
     return min(tq, -(-T // unit) * unit)
 
 
-def _page_buffers(page_bytes: int) -> int:
-    """K (and V) page buffers of a grid cell: all but one are in flight
-    while one is computed, _BYTES_IN_FLIGHT of K+V between them."""
-    return 1 + min(7, max(1, _BYTES_IN_FLIGHT // (2 * page_bytes)))
+def _page_buffers(block_bytes: int) -> int:
+    """K (and V) buffers of a grid cell, each a page as the cell's head
+    block holds it: all but one are in flight while one is computed,
+    _BYTES_IN_FLIGHT of K+V between them (one, where a block alone is
+    more)."""
+    return 1 + min(7, max(1, _BYTES_IN_FLIGHT // (2 * block_bytes)))
+
+
+def _block_vmem(hb: int, rows: int, D: int, psz: int, itemsize: int) -> int:
+    """VMEM bytes of a grid cell that serves `hb` KV heads: the query
+    and output tiles (double-buffered by the pipeline), the f32
+    accumulator, m and l (a [rows, 1] column takes whole 128-lane
+    tiles), the K and the V ring."""
+    tile = hb * rows * D
+    state = tile * 4 + 2 * hb * rows * 128 * 4
+    block = hb * psz * D * itemsize
+    return 4 * tile * itemsize + state + 2 * _page_buffers(block) * block
+
+
+def ragged_head_block(KV: int, rows: int, D: int, psz: int, itemsize: int,
+                      latent: bool = False) -> int:
+    """hb, the KV heads one page visit serves: the largest divisor of
+    KV, no more than _HEAD_BLOCK_MAX, whose cell (`_block_vmem`) fits
+    _VMEM_BUDGET; 1 where none does, and for a `latent` cache (one row
+    serves every query head: there is one KV head to visit)."""
+    if latent:
+        return 1
+    return max([1] + [hb for hb in range(2, min(KV, _HEAD_BLOCK_MAX) + 1)
+                      if KV % hb == 0 and _block_vmem(
+                          hb, rows, D, psz, itemsize) <= _VMEM_BUDGET])
 
 
 def _tile_pages(xp, seq_start, num_tokens, kv_lengths, *, tq, n_tiles,
@@ -211,28 +252,38 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
     t = pl.program_id(1)
     n_pairs = first_ref[pl.num_programs(1)]
     depth = kbuf.shape[0]
+    # the cell's block of KV heads: an axis of the ring and of the f32
+    # state, which the launch of one-row pages (one KV head) goes without
+    hb = q_ref.shape[0]
+    blocked = acc_ref.ndim == 3
+    heads = pl.ds(h * hb, hb) if blocked else h
     # a windowed launch's pair table is twice as long: pair pi's first
     # page sits `pairs` entries after its sequence
     pairs = pseq_ref.shape[0] // 2
 
+    def part(g):
+        """The index of head g's part of a ring slot or of the state."""
+        return (g, ...) if blocked else (...,)
+
     def page_dma(pi, j):
-        # page j of pair pi lands in the slot its running count picks;
-        # sentinel / -1 table entries never emit an out-of-range DMA
+        # page j of pair pi, as the block's heads hold it, lands in the
+        # slot its running count picks; sentinel / -1 table entries
+        # never emit an out-of-range DMA
         slot = jax.lax.rem(pfirst_ref[pi] + j, depth)
         if window is not None:
             j = j + pseq_ref[pairs + pi]
         phys = jnp.clip(tab_ref[pseq_ref[pi], j], 0, total_pages - 1)
-        k_dma = pltpu.make_async_copy(k_hbm.at[h, phys], kbuf.at[slot],
+        k_dma = pltpu.make_async_copy(k_hbm.at[heads, phys], kbuf.at[slot],
                                       sem.at[0, slot])
         if v_hbm is None:       # V is the page's first columns
             return slot, (k_dma,)
         return slot, (
             k_dma,
-            pltpu.make_async_copy(v_hbm.at[h, phys], vbuf.at[slot],
+            pltpu.make_async_copy(v_hbm.at[heads, phys], vbuf.at[slot],
                                   sem.at[1, slot]))
 
     def fetch_ahead(pi, j):
-        """Start the DMAs of page (pi, j) of the head's flat walk if
+        """Start the DMAs of page (pi, j) of the block's flat walk if
         there is one; return the page after it."""
         @pl.when(pi < n_pairs)
         def _start():
@@ -244,7 +295,7 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
         wrap = j + 1 >= pfirst_ref[c + 1] - pfirst_ref[c]
         return jnp.where(wrap, pi + 1, pi), jnp.where(wrap, 0, j + 1)
 
-    # the walk's read-ahead cursor lives across the head's grid cells:
+    # the walk's read-ahead cursor lives across the block's grid cells:
     # depth - 1 pages fly ahead of the one being computed, whichever
     # pair or tile they belong to
     @pl.when(t == 0)
@@ -257,13 +308,14 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
     acc_ref[:] = jnp.zeros_like(acc_ref)
     m_ref[:] = jnp.full_like(m_ref, _NEG)
     l_ref[:] = jnp.zeros_like(l_ref)
-    q = q_ref[0]                                         # [TQ*rep, D]
+    q = [q_ref[g] for g in range(hb)]                    # [TQ*rep, D] each
+    rows = q_ref.shape[1]
     # flat token of each query row ([TQ*rep, 1]: the rep query heads of
     # one token are adjacent rows of the KV head's group)
     tok = t * tq + jax.lax.broadcasted_iota(
-        jnp.int32, (q.shape[0], 1), 0) // rep
+        jnp.int32, (rows, 1), 0) // rep
     in_page = jax.lax.broadcasted_iota(
-        jnp.int32, (q.shape[0], page_size), 1)
+        jnp.int32, (rows, page_size), 1)
 
     def pair(pi, ahead):
         i = pseq_ref[pi]
@@ -279,18 +331,9 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
             lo = kvl_ref[kvl_ref.shape[0] // 2 + i]
             hi = (lo + page_size - 1) // page_size * page_size
 
-        def page(j, ahead):
-            ahead = fetch_ahead(*ahead)
-            slot, dmas = page_dma(pi, j)
-            for dma in dmas:
-                dma.wait()
-            k = kbuf[slot]                               # [psz, D]
-            v = k[:, :acc_ref.shape[1]] if vbuf is None else vbuf[slot]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            # _MASKED is so far below any m (>= _NEG) that exp gives an
-            # exact 0: a row with nothing to attend here keeps m, l, acc
+        def visible(j):
+            """The keys of page j each row sees: the same for every
+            head, so one mask a visit."""
             rel = limit - (page0 + j) * page_size
             seen = in_page <= rel
             if window is not None:
@@ -299,16 +342,37 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
             if summary:
                 at = (page0 + j) * page_size
                 seen &= (in_page < lo - at) | (in_page >= hi - at)
-            s = jnp.where(seen, s, _MASKED)
-            m_prev = m_ref[:]
-            m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[:] = l_ref[:] * alpha + jnp.sum(p, -1, keepdims=True)
-            acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[:] = m_new
+            return seen
+
+        def page(j, ahead):
+            ahead = fetch_ahead(*ahead)
+            slot, dmas = page_dma(pi, j)
+            for dma in dmas:
+                dma.wait()
+            seen = None
+            for g in range(hb):
+                at = part(g)
+                k = kbuf[(slot, *at)]                    # [psz, D]
+                v = k[:, :acc_ref.shape[-1]] if vbuf is None \
+                    else vbuf[(slot, *at)]
+                s = jax.lax.dot_general(
+                    q[g], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if seen is None:    # after the first head's scores,
+                    seen = visible(j)   # where one head a cell builds it
+                # _MASKED is so far below any m (>= _NEG) that exp gives
+                # an exact 0: a row with nothing to attend here keeps m,
+                # l, acc
+                s = jnp.where(seen, s, _MASKED)
+                m_prev = m_ref[at]
+                m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_ref[at] = l_ref[at] * alpha + jnp.sum(p, -1, keepdims=True)
+                acc_ref[at] = acc_ref[at] * alpha + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_ref[at] = m_new
             return ahead
 
         return jax.lax.fori_loop(
@@ -318,9 +382,10 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
                               (ahead_ref[0], ahead_ref[1]))
     ahead_ref[0], ahead_ref[1] = ahead
     # rows of no sequence kept l == 0 and acc == 0: they emit zeros
-    l = l_ref[:]
-    o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(
-        o_ref.dtype)
+    for g in range(hb):
+        l = l_ref[part(g)]
+        o_ref[g] = (acc_ref[part(g)] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
@@ -328,7 +393,8 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
                            scale: Optional[float] = None,
                            window: Optional[int] = None,
                            v_dim: Optional[int] = None,
-                           summary_rows=None):
+                           summary_rows=None, scope: Optional[str] = None,
+                           _launch: bool = False):
     """q [T, H, D] flat new-token buffer; k/v_pages [KV, total_pages,
     page_size, D]; seq_start/num_tokens/kv_lengths [S] int32;
     page_tables [S, pages_per_seq] int32. Sequences own DISJOINT row
@@ -359,8 +425,15 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     has the operands it had; `None` compiles the program it did before
     the argument existed.
 
-    VMEM: one [TQ*rep, D] query tile and output tile (double-buffered by
-    the pipeline), that much f32 state, and `_page_buffers` K and V
+    `scope` (static) names the launch in the compiled program (the
+    caller's own `jax.named_scope`, said again: the launch is traced
+    and lowered ONCE for equal shapes inside a jitted copy of this
+    function, which a step's layers then share, and the instruction
+    takes the innermost name).
+
+    VMEM (`_block_vmem`): for each of the cell's `ragged_head_block`
+    KV heads one [TQ*rep, D] query tile and output tile (double-buffered
+    by the pipeline), that much f32 state, and `_page_buffers` K and V
     pages."""
     T, H, D = q.shape
     KV, total, psz, _ = k_pages.shape
@@ -372,17 +445,23 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     if (v_pages is None) != (v_dim is not None):
         raise ValueError("give v_pages, or v_dim for rows that hold K "
                          "and V together; not both, not neither")
-    if not interpret and not isinstance(q, jax.core.Tracer):
-        # the operands' memory-space pins below exist only under a trace
-        return jax.jit(functools.partial(
-            ragged_paged_attention, scale=scale, window=window,
-            v_dim=v_dim))(
-                q, k_pages, v_pages, seq_start, num_tokens, kv_lengths,
-                page_tables, summary_rows=summary_rows)
+    latent = v_pages is None
+    if not _launch and not (latent and isinstance(q, jax.core.Tracer)):
+        # one trace and one lowering of the launch (the heads' chains
+        # are unrolled in it) for all the layers of a step that make it,
+        # and the trace the operands' memory-space pins below need. A
+        # traced launch of one-row pages stays in line: the program it
+        # was
+        return _launch_jit(
+            q, k_pages, v_pages, seq_start, num_tokens, kv_lengths,
+            page_tables, scale=float(scale), window=window, v_dim=v_dim,
+            summary_rows=summary_rows, scope=scope)
     tq = ragged_tile_tokens(T, rep, q.dtype)
     n_tiles = -(-T // tq)
     Tp, rows = n_tiles * tq, tq * rep
-    depth = _page_buffers(psz * D * k_pages.dtype.itemsize)
+    itemsize = k_pages.dtype.itemsize
+    hb = ragged_head_block(KV, rows, D, psz, itemsize, latent=latent)
+    depth = _page_buffers(hb * psz * D * itemsize)
     ss = seq_start.astype(jnp.int32)
     nt = num_tokens.astype(jnp.int32)
     kvl = kv_lengths.astype(jnp.int32)
@@ -400,40 +479,48 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
         kvl = jnp.concatenate([kvl, summary_rows.astype(jnp.int32)])
         static["summary"] = True
     tables = (ss, nt, kvl, page_tables.astype(jnp.int32), *work)
-    if v_pages is None:
-        out = _latent_call(qg, k_pages, tables, v_dim, rows, depth,
-                           n_tiles, interpret, **static)
+    if latent:
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
+            out = _latent_call(qg, k_pages, tables, v_dim, rows, depth,
+                               n_tiles, interpret, **static)
         return _ungroup(out, T, H)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,      # row tables, page tables, work list
-        grid=(KV, n_tiles),
+        grid=(KV // hb, n_tiles),
         in_specs=[
-            pl.BlockSpec((1, rows, D), _tile_map),
+            pl.BlockSpec((hb, rows, D), _tile_map),
             pl.BlockSpec(memory_space=pltpu.HBM),    # the pools stay put
             pl.BlockSpec(memory_space=pltpu.HBM),
         ],
-        out_specs=pl.BlockSpec((1, rows, D), _tile_map),
-        scratch_shapes=[pltpu.VMEM((depth, psz, D), k_pages.dtype),
-                        pltpu.VMEM((depth, psz, D), v_pages.dtype),
-                        pltpu.VMEM((rows, D), jnp.float32),
-                        pltpu.VMEM((rows, 1), jnp.float32),
-                        pltpu.VMEM((rows, 1), jnp.float32),
+        out_specs=pl.BlockSpec((hb, rows, D), _tile_map),
+        scratch_shapes=[pltpu.VMEM((depth, hb, psz, D), k_pages.dtype),
+                        pltpu.VMEM((depth, hb, psz, D), v_pages.dtype),
+                        pltpu.VMEM((hb, rows, D), jnp.float32),
+                        pltpu.VMEM((hb, rows, 1), jnp.float32),
+                        pltpu.VMEM((hb, rows, 1), jnp.float32),
                         pltpu.SMEM((2,), jnp.int32),
                         pltpu.SemaphoreType.DMA((2, depth))],
     )
-    # the tile axis is sequential: a head's page DMAs run ahead from one
-    # tile into the next
-    out = pl.pallas_call(
-        functools.partial(_ragged_kernel, **static),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((KV, Tp * rep, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(*(x if interpret else _in_hbm(x) for x in (
-        *tables, qg, k_pages, v_pages)))
+    # the tile axis is sequential: a head block's page DMAs run ahead
+    # from one tile into the next
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        out = pl.pallas_call(
+            functools.partial(_ragged_kernel, **static),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((KV, Tp * rep, D), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+        )(*(x if interpret else _in_hbm(x) for x in (
+            *tables, qg, k_pages, v_pages)))
     return _ungroup(out, T, H)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "window", "v_dim", "scope"))
+def _launch_jit(*operands, **options):
+    return ragged_paged_attention(*operands, _launch=True, **options)
 
 
 def _ungroup(out, T: int, H: int):
@@ -447,8 +534,9 @@ def _ungroup(out, T: int, H: int):
 def _latent_call(qg, pages, tables, v_dim, rows, depth, n_tiles, interpret,
                  **static):
     """`ragged_paged_attention`'s launch for pages that hold K and V in
-    one row: the same grid, work list and tile maps; one pool operand,
-    one ring of page buffers, a [rows, v_dim] accumulator and output."""
+    one row: the same grid, work list and tile maps at a head block of
+    one, so without that axis; one pool operand, one ring of page
+    buffers, a [rows, v_dim] accumulator and output."""
     KV, flat, D = qg.shape
     psz = pages.shape[2]
     grid_spec = pltpu.PrefetchScalarGridSpec(

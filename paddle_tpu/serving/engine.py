@@ -78,9 +78,10 @@ from ..ops.fused import (append_run_count, append_run_table, append_tile,
                          fused_rope_append)
 from ..models.ouro import exit_distribution as _exit_distribution
 from ..ops.paged_attention import append_to_cache, paged_attention
-from ..ops.pallas_ragged import (ragged_kernel_eligible,
+from ..ops.pallas_ragged import (ragged_head_block,
+                                 ragged_kernel_eligible,
                                  ragged_paged_attention,
-                                 ragged_pages_visited)
+                                 ragged_pages_visited, ragged_tile_tokens)
 from .block_allocator import ChunkSummaryAllocator, PageBlockAllocator
 from .handoff import (HANDOFF_BYTES, HANDOFF_PAGES, HANDOFFS,
                       KVPageHandoff)
@@ -288,7 +289,8 @@ class _Launch:
 #: of the others the record keeps the later launch's
 _ADDITIVE = frozenset(
     ("decode_rows", "prefill_rows", "append_runs", "rows_dropped",
-     "pages_live", "pages_visited") + _tracing.STEP_COUNTS_BY_KIND[:4]
+     "pages_live", "pages_visited", "attn_block_visits")
+    + _tracing.STEP_COUNTS_BY_KIND[:4]
     + _tracing.STEP_COUNTS_EVA[:4])
 
 
@@ -578,6 +580,15 @@ class ServingEngine:
         # costmodel budget
         self._kv_geom = (kv, d)
         self._kv_itemsize = int(jnp.dtype(dt).itemsize)
+        # the KV heads one page visit of the ragged kernel serves, for
+        # each query group size: the kernel's own choice at the unified
+        # step's row count (`attn_block_visits`)
+        T = self.max_slots * (1 + self.spec_k) + self.prefill_chunk
+        self._head_block = {
+            r: ragged_head_block(
+                kv, ragged_tile_tokens(T, r, dt) * r, d, self.page_size,
+                self._kv_itemsize, latent=self._family == "mla")
+            for reps in self._kind_rep.values() for r in reps}
         # the unit of work of the rope + append kernel
         # (`ops.fused.append_run_table`): the rows of one cache tile
         self._append_tile = append_tile(dt, self.page_size)
@@ -1022,7 +1033,7 @@ class ServingEngine:
         static residency (weights, page pool, draft state) plus the
         measured and model bytes-per-token the 25% acceptance check
         compares."""
-        return {
+        acct = {
             "weights_bytes": float(self._hbm_weights_bytes),
             "page_pool_bytes": float(self._hbm_pool_bytes),
             "draft_bytes": float(_G_HBM_DRAFT.value),
@@ -1035,6 +1046,13 @@ class ServingEngine:
                 self._ledger_model_bytes / self._ledger_tokens
                 if self._ledger_tokens else 0.0),
         }
+        if self.ragged:
+            # KV heads a page visit of the ragged kernel serves, by
+            # layer kind (the fewest over the kind's head counts)
+            for k, reps in self._kind_rep.items():
+                acct["attn_head_block" + (".window" if k else "")] = \
+                    float(min(self._head_block[r] for r in reps))
+        return acct
 
     def program_cache_sizes(self) -> Dict[str, int]:
         """{program name: compiled-variant count} for this engine's
@@ -1765,14 +1783,23 @@ class ServingEngine:
                 self._append_tile)
         # pages that hold this launch's tokens, against the K/V page
         # fetches the ragged kernel makes for each KV head (a sequence's
-        # pages once for every query tile that holds rows of it)
+        # pages once for every query tile that holds rows of it); a
+        # visit brings the page for a block of KV heads at once, so the
+        # visits it makes are the fetches of all heads over the block
+        counts["attn_block_visits"] = 0
 
         def visited(kind, window=None):
             # one layer of each head count of the kind, summed
-            return sum(ragged_pages_visited(
-                seq_start, num_tokens, kv_lengths, T=T, rep=r,
-                dtype=self._q_dtype, page_size=ps, pages_per_seq=nj,
-                window=window) for r in self._kind_rep[kind])
+            total = 0
+            for r in self._kind_rep[kind]:
+                pages = ragged_pages_visited(
+                    seq_start, num_tokens, kv_lengths, T=T, rep=r,
+                    dtype=self._q_dtype, page_size=ps, pages_per_seq=nj,
+                    window=window)
+                total += pages
+                counts["attn_block_visits"] += \
+                    pages * self._kv_geom[0] // self._head_block[r]
+            return total
 
         if self._family == "looped":
             n_layers = len(self._p["layers"])
@@ -2085,7 +2112,8 @@ class ServingEngine:
                     o = ragged_paged_attention(q, kp, vp, seq_start,
                                                num_tokens, kv_lengths,
                                                table, scale=D ** -0.5,
-                                               window=window)
+                                               window=window,
+                                               scope="attention")
                 with _scope("attn_out"):
                     if "wgate" in L:
                         # one sigmoid scalar a head, from the normed input
@@ -2169,7 +2197,8 @@ class ServingEngine:
                 with _scope("attention"), jax.named_scope("eva_attention"):
                     o = ragged_paged_attention(
                         q, kp, vp, seq_start, num_tokens, kv_lengths,
-                        tables, scale=scale, summary_rows=summary_rows)
+                        tables, scale=scale, summary_rows=summary_rows,
+                        scope="eva_attention")
                 with _scope("attn_out"):
                     x = x + jnp.dot(o.reshape(1, T, H * D), L["wo"],
                                     preferred_element_type=f32)
@@ -2241,7 +2270,7 @@ class ServingEngine:
                 with _scope("attention"):
                     o = ragged_paged_attention(
                         q, kp, vp, seq_start, num_tokens, kv_lengths,
-                        table, scale=D ** -0.5)
+                        table, scale=D ** -0.5, scope="attention")
                 with _scope("attn_out"):
                     x = x + fused_rms_norm(
                         o.reshape(1, T, H * D) @ L["wo"], L["ln1_out"], eps)
@@ -2322,7 +2351,8 @@ class ServingEngine:
                 with _scope("attention"):
                     o = ragged_paged_attention(q, kp, vp, seq_start,
                                                num_tokens, kv_lengths,
-                                               tables, scale=hd ** -0.5)
+                                               tables, scale=hd ** -0.5,
+                                               scope="attention")
                 with _scope("attn_out"):
                     x = x + (o.reshape(1, T, nh * hd) @ L["wo"] + L["bo"])
                 with _scope("ffn_norm"):

@@ -155,6 +155,9 @@ class TestEngineAgainstReference:
             assert r["latent_row_bytes"] == 256 * 4         # float32
             assert (r["chunk_kv_len"] > 0) == (r["prefill_rows"] > 0)
             assert r["pages_visited"] >= r["pages_live"] > 0
+            # a latent cache is ONE KV head: a visit serves it alone
+            assert r["attn_block_visits"] == r["pages_visited"]
+        assert eng.hbm_accounting()["attn_head_block"] == 1
         # a chunk's context grows by the chunk until the prompt ends
         ctx = [r["chunk_kv_len"] for r in recs if r["prefill_rows"]]
         assert ctx[:4] == [16, 32, 48, 61]
@@ -188,6 +191,11 @@ class TestLatentKernel:
                                           scale=0.05, v_dim=V)
         assert got.shape == (T, rep, V)
         np.testing.assert_allclose(got, want, atol=2e-6, rtol=0)
+        # one row serves every query head: one KV head a page visit,
+        # whatever a cell of that size could hold
+        from paddle_tpu.ops.pallas_ragged import ragged_head_block
+        assert ragged_head_block(1, 8 * rep, D, psz, 4, latent=True) == 1 \
+            == ragged_head_block(8, 8 * rep, D, psz, 4, latent=True)
         # the same numbers as two pools, V a copy of K's first columns
         two = ragged_paged_attention(
             q, pool, jnp.pad(pool[..., :V], ((0, 0),) * 3 + ((0, D - V),)),

@@ -150,7 +150,7 @@ class TestBlockSpecConsistency:
         assert None in got["in"]
 
     def test_ragged_bytes_match_block_specs(self, sites):
-        # grid (KV, tiles): the q / out tiles ride BlockSpecs, the pools
+        # grid (KV / hb, tiles): the q / out tiles ride BlockSpecs, the pools
         # stay in HBM behind the kernel's page DMAs (specs opt out, as
         # paged v2); their bytes are the pages the kernel's own count
         # visits for the launch the cost states
@@ -164,9 +164,17 @@ class TestBlockSpecConsistency:
                    for t in (8, 9, 40, 288, 2048) for r in (1, 4, 8, 16)
                    for dt, w in (("float32", 4), ("bfloat16", 2),
                                  ("int8", 1)))
-        b = dict(KV=1, n_tiles=-(-T // tq), rows=tq * rep, psz=psz, D=D)
+        b = dict(KV=1, hb=1, n_tiles=-(-T // tq), rows=tq * rep, psz=psz,
+                 D=D)
         got = km.transfer_bytes(site, b, [BF16] * 3, [BF16])
         assert got is not None and got["in"][1:] == [None, None]
+        # the head block moves whole tiles of the same rows: a launch's
+        # q and out bytes do not depend on it
+        for hb in (1, 2, 4, 8):
+            blocked = km.transfer_bytes(site, dict(b, KV=8, hb=hb),
+                                        [BF16] * 3, [BF16])
+            assert blocked["in"][0] == 8 * got["in"][0]
+            assert blocked["out"] == [8 * got["out"][0]]
         est = cm.cost("ragged_paged_attention", T=T, H=4, KV=1, D=D,
                       S=S, pages_per_seq=nj, page_size=psz)
         q = got["in"][0]
@@ -249,7 +257,9 @@ class TestBlockSpecConsistency:
             v1, dict(B=2, KV=1, nj=8)) == [2, 1, 8]
         rag = _one(ss, "ragged_paged_attention")
         assert km.grid_values(
-            rag, dict(KV=1, n_tiles=3)) == [1, 3]
+            rag, dict(KV=1, hb=1, n_tiles=3)) == [1, 3]
+        assert km.grid_values(
+            rag, dict(KV=16, hb=8, n_tiles=3)) == [2, 3]
         fwd = _one(ss, "_flash_fwd_impl")
         assert km.grid_values(
             fwd, dict(B=2, H=3, nq=2, nk=4)) == [2, 3, 2, 4]

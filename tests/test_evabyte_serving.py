@@ -395,11 +395,21 @@ class TestSummaryMask:
         return q, kp, vp, ss, nt, kvl, tab, sr
 
     @pytest.mark.parametrize("seed", [0, 1])
-    def test_kernel_matches_oracle(self, seed):
+    def test_kernel_matches_oracle(self, seed, monkeypatch):
         *args, sr = self._case(np.random.default_rng(seed))
         got = ragged_paged_attention(*args, summary_rows=sr)
         want = ragged_attention_reference(*args, summary_rows=sr)
         np.testing.assert_allclose(got, want, atol=2e-5)
+        if seed:
+            # a page visit served both KV heads: one head a visit gives
+            # the same bits
+            from paddle_tpu.ops import pallas_ragged
+            monkeypatch.setattr(pallas_ragged, "ragged_head_block",
+                                lambda *a, **k: 1)
+            pallas_ragged._launch_jit.clear_cache()     # trace it again
+            np.testing.assert_array_equal(
+                got, ragged_paged_attention(*args, summary_rows=sr))
+            pallas_ragged._launch_jit.clear_cache()
         # and the hole matters: without the mask the answer differs
         plain = ragged_attention_reference(*args)
         assert np.abs(np.asarray(plain - want))[0].max() > 1e-2
@@ -432,19 +442,22 @@ class TestSummaryMask:
 #: chunk-summary attention came in beside these programs, not through
 #: them. A PR that means to change one records the new text here.
 #: PR 36 (rope + append by cache-tile runs) re-recorded the four that
-#: call `fused_rope_append`; `mla` (`fused_append_rows`) is PR 35's
-#: parent's text still.
+#: call `fused_rope_append`, PR 42 (a page visit of the ragged kernel
+#: serves a block of KV heads; one jitted launch a step's layers share)
+#: the same four; `mla` (`fused_append_rows`,
+#: ONE KV head: a block of one without the axis) is PR 35's parent's
+#: text still.
 LOWERED_AT_PARENT = {
-    "llama": "796a58c1dfb7e68b1df0482ead04c086fa984da24e4b682670e8b54c8533"
-             "7e22",
-    "moe": "4faec1b74aa7a77f2da610cb075890537d69df83938fa03bd04650c8d83ddc"
-           "77",
+    "llama": "986cc43c9b47e3f14971f0fe0c3f210dd2dc22265c81c4ed897c7ed7faa1"
+             "ae7b",
+    "moe": "c33d0d6ae5d0be6cf546453b86a3c7853e365ad818968696a84089b4d88919"
+           "98",
     "mla": "95716f117ba052bcd4cb7ab773eb7723b3cea776fb15ead50b69783698625c"
            "6c",
-    "gpt": "2a8c5ea9067a9806f3e646b554493aec66a3d7f4ce0ba416b4dd42c85d182e"
-           "ea",
-    "laguna": "0884e3e52935f0f89c1420aebe50b30f0427df3011f1a368736f06fbb34"
-              "1055c",
+    "gpt": "f92873aa0b8da9418f9152b8cc776f57a3aae5975758b7c46a949665448a6c"
+           "2d",
+    "laguna": "69d5c90d27c571b0f35f8827269ad4e2f773120fd1780d26692f07d15ea"
+              "9264f",
 }
 
 

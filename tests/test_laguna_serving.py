@@ -135,6 +135,12 @@ class TestEngineAgainstReference:
                 + r["pages_live.window"]
             assert r["pages_visited"] == r["pages_visited.full"] \
                 + r["pages_visited.window"]
+            # both kinds' visits bring a page for all KV heads at once
+            acct = eng.hbm_accounting()
+            kv = eng._kv_geom[0]
+            assert acct["attn_head_block"] == kv \
+                == acct["attn_head_block.window"]
+            assert r["attn_block_visits"] == r["pages_visited"]
             assert r["pages_live.window"] <= r["pages_live.full"]
             assert r["pool_pages_total.full"] == eng.num_pages - 1
             assert r["pool_pages_total.window"] == eng.num_window_pages - 1

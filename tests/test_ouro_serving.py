@@ -132,13 +132,15 @@ def test_a_slot_is_reused_and_a_request_waits_on_pages(tiny):
 
 def test_the_text_holds_one_layer_in_one_loop(tiny):
     """The passes are a compiled loop around ONE jitted layer: 7 matmuls
-    (and the interpreted kernel's 2), the gate, the head — and staggered
-    arrivals still match."""
+    (and the interpreted kernel's 2 for each KV head of a page visit's
+    block: both heads here), the gate, the head — and staggered arrivals
+    still match."""
     m, w, c = tiny
     prompts = _prompts(5, [13, 4])
     eng = _engine(m)
+    assert eng.hbm_accounting()["attn_head_block"] == 2
     assert _lower_unified(eng).as_text().count("stablehlo.dot_general") \
-        == 9 + 1 + 1
+        == 7 + 2 * 2 + 1 + 1
     for p, (tokens, got) in zip(prompts, _run(eng, prompts, [6, 9], 2)):
         np.testing.assert_allclose(got, _reference(w, c, p, tokens),
                                    atol=2e-4)
@@ -269,9 +271,10 @@ def test_the_split_programs_refuse_the_family(tiny, monkeypatch):
 #: (4851f97), toy widths, on the CPU under the suite's matmul precision:
 #: chunk-summary attention, beside the five of `test_evabyte_serving`
 #: (unchanged there). The looped decoder came in beside these programs,
-#: not through them.
+#: not through them. PR 42 (a page visit of the ragged kernel serves a
+#: block of KV heads) re-recorded it with four of the five.
 EVA_LOWERED_AT_PARENT = \
-    "ab1e2460af029f3ea8d964386ad88804acbe5d1f6db0f43b69b642506fff56a4"
+    "ad74a30170541461cbcf1366bcc60e5b69e0f18b221a2ec15216012a21436d2f"
 
 
 def _lower_eva():

@@ -1327,8 +1327,8 @@ class TestSeededKernelDefects:
     def test_pk104_catches_bf16_accumulator(self, tmp_path):
         fresh = self._seed(
             tmp_path, self.RAGGED,
-            old="pltpu.VMEM((rows, D), jnp.float32),",
-            new="pltpu.VMEM((rows, D), jnp.bfloat16),")
+            old="pltpu.VMEM((hb, rows, D), jnp.float32),",
+            new="pltpu.VMEM((hb, rows, D), jnp.bfloat16),")
         assert fresh and {f.rule for f in fresh} == {"PK104"}
         assert fresh[0].detail.startswith("acc:")
 
@@ -2166,8 +2166,8 @@ class TestSeededMemoryDefects:
         # per-core budget
         fresh = self._seed(
             tmp_path, self.RAGGED,
-            old="pltpu.VMEM((rows, D), jnp.float32),",
-            new="pltpu.VMEM((rows * 4096, D), jnp.float32),")
+            old="pltpu.VMEM((hb, rows, D), jnp.float32),",
+            new="pltpu.VMEM((hb, rows * 4096, D), jnp.float32),")
         assert fresh and {f.rule for f in fresh} == {"PF401"}
         assert fresh[0].detail == "vmem:ragged_paged_attention"
         assert "MiB" in fresh[0].message
@@ -2189,8 +2189,8 @@ class TestSeededMemoryDefects:
         # truncates — the break PK104's declaration-side check misses
         fresh = self._seed(
             tmp_path, self.RAGGED,
-            old="m_ref[:] = m_new",
-            new="m_ref[:] = m_new.astype(jnp.bfloat16)")
+            old="m_ref[at] = m_new",
+            new="m_ref[at] = m_new.astype(jnp.bfloat16)")
         assert fresh and {f.rule for f in fresh} == {"PF403"}
         assert fresh[0].detail == "accum:m_ref"
 

@@ -12,8 +12,10 @@ import pytest
 from paddle_tpu.ops.fused import (append_run_count, append_run_table,
                                   append_tile, fused_append_rows,
                                   fused_rope_append)
+from paddle_tpu.ops import pallas_ragged
 from paddle_tpu.ops.pallas_ragged import (_work_list,
                                           ragged_attention_reference,
+                                          ragged_head_block,
                                           ragged_kernel_eligible,
                                           ragged_paged_attention,
                                           ragged_pages_visited,
@@ -143,7 +145,87 @@ def _layout(name):
     return (q, kp, vp, ss, nt, kvl, tab), window
 
 
+#: launches whose page visits serve a BLOCK of KV heads: the KV heads,
+#: the block the kernel takes of them, the query heads a KV head, and
+#: what else the launch has (`_engine_layout` keys; `idle` empties a
+#: slot, `sentinel` marks its table and every dead tail -1, `summary`
+#: gives pooled rows a sequence: chunk-summary attention)
+_HEAD_BLOCKS = {
+    "kv2_rep9_window": dict(KV=2, hb=2, rep=9, window=13),
+    "kv8_rep4_idle_slot_sentinel": dict(KV=8, hb=8, rep=4, idle=True,
+                                        sentinel=True),
+    "kv16_rep6": dict(KV=16, hb=16, rep=6),
+    # 32 heads go as two blocks of 16, the most a visit serves
+    "kv32_rep1_summary": dict(KV=32, hb=16, rep=1,
+                              summary=[8, 0, 13, 16]),
+}
+
+
 class TestRaggedKernelParity:
+    @pytest.mark.parametrize("name", list(_HEAD_BLOCKS))
+    def test_a_head_block_is_one_head_a_visit_bit_for_bit(
+            self, name, monkeypatch):
+        spec = dict(_HEAD_BLOCKS[name])
+        KV, hb, rep = spec.pop("KV"), spec.pop("hb"), spec.pop("rep")
+        kv_dec = [17, 0 if spec.pop("idle", False) else 9, 30]
+        q, kp, vp, ss, nt, kvl, tab = _engine_layout(
+            kv_dec=kv_dec, chunk=11, kv_chunk=16 + 11, H=KV * rep, KV=KV,
+            D=32, pps=4)
+        if spec.pop("sentinel", False):
+            live = -(-np.asarray(kvl) // kp.shape[2])
+            tab = jnp.where(np.arange(tab.shape[1])[None] < live[:, None],
+                            tab, -1)
+        kw = dict(window=spec.pop("window", None))
+        if "summary" in spec:
+            kw["summary_rows"] = jnp.asarray(spec.pop("summary"), jnp.int32)
+        tq = ragged_tile_tokens(q.shape[0], rep, q.dtype)
+        assert ragged_head_block(KV, tq * rep, 32, kp.shape[2], 4) == hb
+        out = ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab, **kw)
+        ref = ragged_attention_reference(q, kp, vp, ss, nt, kvl, tab, **kw)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+        monkeypatch.setattr(pallas_ragged, "ragged_head_block",
+                            lambda *a, **k: 1)
+        # (the launch is traced once for equal shapes: trace it again)
+        pallas_ragged._launch_jit.clear_cache()
+        one = ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab, **kw)
+        pallas_ragged._launch_jit.clear_cache()
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(one))
+
+    @pytest.mark.parametrize("name,KV,rows,D,psz,latent,want", [
+        # the six configurations' launches in bfloat16: T = 288 (Ouro
+        # 272) rows, tiles of `ragged_tile_tokens` x rep query rows
+        ("mistral", 8, 128, 128, 256, False, 8),
+        ("laguna_full_rep6", 8, 96, 128, 256, False, 8),
+        ("laguna_window_rep9", 8, 144, 128, 256, False, 8),
+        ("axk1_latent", 1, 128, 640, 256, True, 1),
+        ("evabyte", 32, 128, 128, 256, False, 16),
+        ("ouro", 16, 128, 128, 64, False, 16),
+        # no divisor of 7 but 7 and 1, and 7 heads of this tile do not
+        # fit: one head a visit
+        ("no_fitting_divisor", 7, 1024, 128, 256, False, 1),
+        ("a_latent_cache_is_one_head", 8, 128, 128, 256, True, 1),
+    ])
+    def test_head_block_follows_the_shapes(self, name, KV, rows, D, psz,
+                                           latent, want):
+        hb = ragged_head_block(KV, rows, D, psz, 2, latent=latent)
+        assert hb == want and KV % hb == 0
+        assert hb <= pallas_ragged._HEAD_BLOCK_MAX
+        # the cell's VMEM by hand: q and out tiles twice, f32 state (m
+        # and l a 128-lane column each), two rings of K and V blocks
+        block = hb * psz * D * 2
+        slots = pallas_ragged._page_buffers(block)
+        vmem = (4 * hb * rows * D * 2 + hb * rows * (D + 256) * 4
+                + 2 * slots * block)
+        assert vmem == pallas_ragged._block_vmem(hb, rows, D, psz, 2)
+        if hb > 1:
+            assert vmem <= pallas_ragged._VMEM_BUDGET < 16 * 2 ** 20
+        bigger = [n for n in range(hb + 1, min(
+            KV, pallas_ragged._HEAD_BLOCK_MAX) + 1) if KV % n == 0]
+        assert latent or all(
+            pallas_ragged._block_vmem(n, rows, D, psz, 2)
+            > pallas_ragged._VMEM_BUDGET for n in bigger)
+
     @pytest.mark.parametrize("name", list(_LAYOUTS))
     def test_engine_layouts(self, name):
         arrays, window = _layout(name)

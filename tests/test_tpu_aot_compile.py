@@ -248,6 +248,24 @@ def test_ragged_paged_attention_compiles_at_the_serving_cells_shapes(chip):
         chip.refusals.get(_ragged)
 
 
+def test_ragged_paged_attention_compiles_at_the_ouro_cell_shapes(chip):
+    """`ouro-2.6b-serve-whole` as its cell runs it: T = 16 slots + a
+    256-row chunk, 16 query heads over 16 KV heads x 128 (tiles of 128
+    tokens), pages of 64, one pass's 80 pages of a layer's pool of 320,
+    17 sequences of 64 pages. A page visit serves all 16 heads: the
+    widest block of the cells (a strided DMA of 16 x 16 KB)."""
+    from paddle_tpu.ops.pallas_ragged import (ragged_head_block,
+                                              ragged_tile_tokens)
+    t, hq, psz, n_pages, s, nj = 272, 16, 64, 320, 17, 64
+    assert ragged_tile_tokens(t, 1, jnp.bfloat16) == 128
+    assert ragged_head_block(hq, 128, D, psz, 2) == 16
+    seq = chip.shape((s,), I32)
+    pool = chip.shape((hq, n_pages, psz, D))
+    assert chip.compiles(_ragged, chip.shape((t, hq, D)), pool, pool,
+                         seq, seq, seq, chip.shape((s, nj), I32)), \
+        chip.refusals.get(_ragged)
+
+
 def _ragged_windowed(q, kp, vp, ss, nt, kvl, tab):
     from paddle_tpu.ops.pallas_ragged import ragged_paged_attention
     return ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab, window=512)

@@ -444,7 +444,16 @@ class TestStepTimeline:
                     <= tiles * s["pages_live"]
                 if not out["prefill_tokens"]:
                     assert s["pages_visited"] == s["pages_live"]
+                # a visit brings the page for a block of KV heads (here
+                # all of them: `hbm_accounting` says the kernel's choice)
+                kv = eng._kv_geom[0]
+                hb = int(eng.hbm_accounting()["attn_head_block"])
+                assert hb == kv > 1
+                assert s["attn_block_visits"] \
+                    == s["pages_visited"] * kv // hb
             else:
+                assert s["attn_block_visits"] == 0
+                assert "attn_head_block" not in eng.hbm_accounting()
                 # two launches a step, each walks its own sequences
                 if not out["finished"]:
                     assert pages_used <= s["pages_live"] <= 2 * pages_used

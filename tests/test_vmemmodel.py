@@ -105,6 +105,30 @@ class TestFootprints:
         assert fp["bytes"] == expected
         assert fp["unresolved"] == 0
 
+    @pytest.mark.parametrize(
+        "family", ["llama", "laguna", "looped", "chunk_summary"])
+    def test_the_ragged_kernels_head_blocks_fit_vmem(self, sites, family):
+        # the serving cells' cells of VMEM: what the model resolves (the
+        # q and out tiles of a block of heads, double-buffered; the f32
+        # accumulator, m and l, the read-ahead cursor), the two rings it
+        # counts unresolved beside the two pools in HBM (their dtype is
+        # the pools') added by hand, and the head block in the bindings
+        # is the one the kernel takes at those shapes
+        from paddle_tpu.ops import pallas_ragged as pr
+        entry = vm.CANONICAL["ragged_paged_attention"]
+        b = vm.site_bindings(entry, family)
+        hb, rows, D, psz = b["hb"], b["rows"], b["D"], b["psz"]
+        fp = vm.site_footprint(sites["ragged_paged_attention"], entry, b)
+        assert fp["unresolved"] == 4
+        tile = hb * rows * D
+        assert fp["bytes"] == (4 * tile * 2 + tile * 4 + 2 * hb * rows * 4
+                               + 2 * 4)
+        assert pr.ragged_head_block(b["KV"], rows, D, psz, 2) == hb
+        assert pr._page_buffers(hb * psz * D * 2) == b["depth"]
+        rings = 2 * b["depth"] * hb * psz * D * 2
+        assert fp["bytes"] + rings <= pr._block_vmem(hb, rows, D, psz, 2) \
+            <= pr._VMEM_BUDGET < vm.VMEM_BYTES_PER_CORE
+
     def test_unresolved_blocks_are_counted_not_guessed(self, sites):
         # paged_decode_attention_v2 declares two data-dtype scratch
         # buffers the static model cannot size; they must surface in

@@ -1,0 +1,185 @@
+"""The ragged attention kernel ALONE on the local chip, at the serving
+cells' launches, over the head block `hb` (PERF.md section 6, PR 42):
+
+    chiprun -- python tools/ragged_head_sweep.py [--hb 1 2 4 8 16]
+
+One launch shape a cell (`LAUNCHES`), its row tables drawn from a seed;
+for every `hb` that divides the cell's KV heads, `--chain` launches in
+ONE jitted program (each launch's output is the next one's query, so
+nothing merges) timed on the host's clock around `block_until_ready`,
+the least of `--repeats`. A line a (launch, hb): ms a launch, us a
+(KV head, page) visit, and whether the output equals `hb` 1's bit for
+bit. `hb` is forced by replacing `pallas_ragged.ragged_head_block` for
+the sweep only; `0` leaves the kernel's own choice. `--depth` forces
+the ring's slots the same way. Appends its lines to
+chiprun_out/ragged_head_sweep.jsonl.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+#: name -> the launch: T rows, q / KV heads, page size, pool pages, page
+#: table width, decode contexts (lo, hi, how many of the slots are live),
+#: the chunk's (rows, context before it), window, summary (EvaByte's
+#: pooled rows: one a 16 tokens before the open 2,048-token window)
+LAUNCHES = {
+    "mistral_decode": dict(T=288, H=32, KV=8, psz=256, pages=187, nj=16,
+                           slots=32, live=32, ctx=(300, 750), chunk=None),
+    "mistral_chunk": dict(T=288, H=32, KV=8, psz=256, pages=187, nj=16,
+                          slots=32, live=32, ctx=(300, 750),
+                          chunk=(256, 1024)),
+    "ouro": dict(T=272, H=16, KV=16, psz=64, pages=320, nj=64, slots=16,
+                 live=6, ctx=(150, 700), chunk=None),
+    "evabyte": dict(T=288, H=32, KV=32, psz=256, pages=272, nj=16,
+                    slots=32, live=10, ctx=(2048, 30000),
+                    chunk=(256, 9216), summary=True),
+    "laguna_full": dict(T=288, H=48, KV=8, psz=256, pages=1280, nj=128,
+                        slots=32, live=32, ctx=(256, 4096),
+                        chunk=(256, 20000)),
+    "laguna_window": dict(T=288, H=72, KV=8, psz=256, pages=160, nj=128,
+                          slots=32, live=32, ctx=(256, 4096),
+                          chunk=(256, 20000), window=512),
+}
+
+
+def _tables(spec, rng):
+    """seq_start, num_tokens, kv_lengths, page table, summary rows of
+    one launch: slot i owns row i, the chunk the rows behind them."""
+    B, psz, nj = spec["slots"], spec["psz"], spec["nj"]
+    S = B + 1
+    ss = np.append(np.arange(B), B).astype(np.int32)
+    nt = np.zeros(S, np.int32)
+    ctx = np.zeros(S, np.int64)
+    live = rng.permutation(B)[:spec["live"]]
+    nt[live] = 1
+    ctx[live] = rng.randint(*spec["ctx"], size=len(live))
+    if spec["chunk"] is not None:
+        nt[B], ctx[B] = spec["chunk"][0], sum(spec["chunk"])
+    window = spec.get("window")
+    sr = np.zeros(S, np.int32)
+    if spec.get("summary"):
+        # pooled rows of the closed windows' chunks, then the open
+        # window's exact rows from the next page boundary on
+        sr = (ctx // 2048 * 2048 // 16).astype(np.int32)
+        kvl = -(-sr // psz) * psz + ctx % 2048
+        kvl = np.where(nt > 0, np.maximum(kvl, nt), 0)
+    else:
+        kvl = ctx
+    tab = np.zeros((S, nj), np.int32)
+    free = 1 + rng.permutation(spec["pages"] - 1)
+    at = 0
+    for i in range(S):
+        n = -(-int(kvl[i]) // psz)
+        first = 0 if window is None else \
+            max(int(kvl[i]) - int(nt[i]) - window + 1, 0) // psz
+        assert n <= nj, (n, nj)
+        take = free[np.arange(at, at + n - first) % len(free)]
+        tab[i, first:n] = take
+        at += n - first
+    return ss, nt, kvl.astype(np.int32), tab, sr
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--hb", type=int, nargs="+", default=[1, 2, 4, 8, 16, 0])
+    ap.add_argument("--depth", type=int, default=0)
+    ap.add_argument("--launch", nargs="+", default=list(LAUNCHES))
+    ap.add_argument("--chain", type=int, default=48)
+    ap.add_argument("--repeats", type=int, default=5)
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_ragged as pr
+
+    if jax.default_backend() != "tpu":
+        print("WARNING: not on a TPU; the times mean nothing",
+              file=sys.stderr)
+    choose, buffers = pr.ragged_head_block, pr._page_buffers
+    if args.depth:
+        pr._page_buffers = lambda _bytes: args.depth
+    out = []
+    for name in args.launch:
+        spec = LAUNCHES[name]
+        rng = np.random.RandomState(args.seed)
+        T, H, KV, D, psz = spec["T"], spec["H"], spec["KV"], 128, spec["psz"]
+        ss, nt, kvl, tab, sr = _tables(spec, rng)
+        window = spec.get("window")
+        visits = pr.ragged_pages_visited(
+            ss, nt, kvl, T=T, rep=H // KV, dtype=jnp.bfloat16,
+            page_size=psz, pages_per_seq=spec["nj"], window=window)
+        key = jax.random.PRNGKey(args.seed)
+        kq, kk, kv_ = jax.random.split(key, 3)
+        q = jax.random.normal(kq, (T, H, D), jnp.bfloat16)
+        pool = (KV, spec["pages"], psz, D)
+        kp = jax.random.normal(kk, pool, jnp.bfloat16)
+        vp = jax.random.normal(kv_, pool, jnp.bfloat16)
+        tables = [jnp.asarray(x) for x in (ss, nt, kvl, tab)]
+        summary = jnp.asarray(sr) if spec.get("summary") else None
+        first = None
+        for hb in args.hb:
+            if hb and KV % hb:
+                continue
+            pr.ragged_head_block = choose if not hb else \
+                (lambda *a, _hb=hb, **k: _hb)
+            pr._launch_jit.clear_cache()    # equal shapes: trace again
+
+            # (the pools and tables are ARGUMENTS: closed over, they
+            # would be constants of the program, 0.3-2.6 GB to compile)
+            def launch(q, kp, vp, tables, summary):
+                return pr.ragged_paged_attention(
+                    q, kp, vp, *tables, window=window,
+                    summary_rows=summary)
+
+            def chain(q, *rest):
+                return jax.lax.fori_loop(
+                    0, args.chain, lambda _, x: launch(x, *rest), q)
+
+            rest = (kp, vp, tables, summary)
+            try:
+                one = np.asarray(
+                    jax.jit(launch)(q, *rest).astype(jnp.float32))
+                run = jax.jit(chain)
+                run(q, *rest).block_until_ready()
+                times = []
+                for _ in range(args.repeats):
+                    t0 = time.perf_counter()
+                    run(q, *rest).block_until_ready()
+                    times.append(time.perf_counter() - t0)
+            except Exception as e:  # noqa: BLE001 - the compiler's refusal
+                print(f"{name} hb {hb}: REFUSED {str(e)[:300]!r}", flush=True)
+                continue
+            if first is None:
+                first = one
+            used = hb or choose(
+                KV, pr.ragged_tile_tokens(T, H // KV, jnp.bfloat16)
+                * (H // KV), D, psz, 2)
+            ms = min(times) / args.chain * 1e3
+            rec = dict(launch=name, hb=used, forced=bool(hb),
+                       depth=args.depth, ms_a_launch=ms,
+                       visits_a_head=visits,
+                       us_a_head_visit=ms * 1e3 / (visits * KV),
+                       us_a_block_visit=ms * 1e3 / (visits * KV // used),
+                       equal_to_first=bool(np.array_equal(one, first)),
+                       finite=bool(np.isfinite(one).all()),
+                       device=jax.devices()[0].device_kind)
+            out.append(rec)
+            print(json.dumps(rec), flush=True)
+    pr.ragged_head_block, pr._page_buffers = choose, buffers
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/ragged_head_sweep.jsonl", "a") as f:
+        f.writelines(json.dumps(rec) + "\n" for rec in out)
+
+
+if __name__ == "__main__":
+    main()
