@@ -399,6 +399,23 @@ CANONICAL: Dict[str, Dict[str, Any]] = {
         token_tiled=True,
         families={"mla": dict(H=640, N=3648, r=512, heads=16)},
     ),
+    # -- ops/pallas_ssm.py (a slot-indexed state pool, heads minor) --------
+    # 8 live slots of [P = 64, N, H] float32 in J = 4 blocks of PB rows:
+    # each slot's state once in and once out, its row's operands
+    "ssm_state_update": dict(
+        kernel="ssm_state_update",
+        bindings=dict(B=8, J=4, PB=16, N=128, H=128),
+        in_widths=[4, 4, 2, 2, 4], out_widths=[4, 4],
+        cost_kwargs=dict(live=8, P=64, N=128, H=128),
+        token_tiled=False,
+    ),
+    "ssm_state_put": dict(
+        kernel="ssm_state_put",
+        bindings=dict(J=4, PB=16, N=128, H=128),
+        in_widths=[4, 4], out_widths=[4],
+        cost_kwargs=dict(P=64, N=128, H=128),
+        token_tiled=False,
+    ),
 }
 
 #: The decode-layer kernel chain in launch order (PF404 walks adjacent

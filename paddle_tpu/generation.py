@@ -528,6 +528,36 @@ def _looped_decode_params(model):
             cfg.rope_theta, cfg.head_dim, n))))
 
 
+def _hybrid_decode_params(model):
+    """NemotronHForCausalLM: ONE mixer a block, of the kind its letter of
+    ``pattern`` names (static, outside the tree): ``M`` a Mamba-2
+    state-space layer, ``*`` attention without rotary, ``E`` a latent
+    routed FFN in `_ffn_apply`'s layout.  ``attn_static`` has one record
+    for each ATTENTION block (the only ones with pages) and marks the
+    family as served by the unified ragged step only; ``moe_static``
+    one for each ``E`` block."""
+    from .models.nemotron_h import arrays
+    inner, cfg = model.model, model.config
+    layers, moe_static = [], []
+    for blk in inner.layers:
+        mix = blk.mixer
+        d = dict(norm=blk.norm.weight._data)
+        if blk.kind == "E":
+            d["moe"] = arrays(mix.weights())
+            moe_static.append(mix.static())
+        else:
+            d.update(arrays(mix.weights()))
+        layers.append(d)
+    n_attn = cfg.hybrid_override_pattern.count("*")
+    return dict(
+        cfg=cfg, family="hybrid", pattern=cfg.hybrid_override_pattern,
+        embed=inner.embed_tokens.weight._data, layers=layers,
+        norm=inner.norm_f.weight._data, head=model.lm_head.weight._data,
+        moe_static=tuple(moe_static),
+        attn_static=(dict(heads=cfg.num_attention_heads, window=None,
+                          rope=""),) * n_attn)
+
+
 def _mla_decode_params(model, weight_only_int8: bool = False,
                        algo: str = "weight_only_int8"):
     """DeepSeekV2ForCausalLM: multi-head latent attention with the
@@ -608,7 +638,14 @@ def _decode_params(model, weight_only_int8: bool = False,
         from .models.evabyte import EvaByteModel
         from .models.laguna import LagunaModel
         from .models.moe_llm import MoEModel
+        from .models.nemotron_h import NemotronHModel
         from .models.ouro import OuroModel
+        if isinstance(inner, NemotronHModel):
+            if enabled:
+                raise NotImplementedError(
+                    "weight-only quantisation is not wired for the "
+                    "Nemotron-H family")
+            return _hybrid_decode_params(model)
         if isinstance(inner, OuroModel):
             if enabled:
                 raise NotImplementedError(
@@ -643,7 +680,7 @@ def _llama_weights(p):
     time."""
     return {k: v for k, v in p.items()
             if k not in ("cfg", "family", "moe_static", "attn_static",
-                         "rope_fn")}
+                         "rope_fn", "pattern")}
 
 
 def _dq(d, key, dtype):
@@ -717,7 +754,10 @@ def _ffn_apply(L, h2, st=None, stats=None, live=None):
     moe_dropless buffer model). ``st`` holds the layer's STATIC routing
     knobs (top_k, renorm; held, scale where the layer is one chip's
     share of an expert-parallel one; score, group where the router is
-    not a softmax top-k) from _mlp_params. A routed layer
+    not a softmax top-k; act "relu2" where the experts are two matrices)
+    from _mlp_params. ``L["moe"]`` may hold a correction ``bias`` [E]
+    of the choice and the latent projections ``lat_dn`` / ``lat_up``
+    around the experts (Nemotron-H). A routed layer
     appends its `moe.routing_stats` to the list ``stats``, counted over
     the rows that ``live`` [B * S] marks (all, if None)."""
     if "moe" not in L:
@@ -735,24 +775,40 @@ def _ffn_apply(L, h2, st=None, stats=None, live=None):
     ffn = dense_expert_ffn if T <= 32 else dropless_expert_ffn
     dt = h2.dtype
     share = {k: st[k] for k in ("held", "scale", "group") if k in st}
+    act = st.get("act", "swiglu")
+    if "bias" in mo:
+        # a per-expert correction of the CHOICE (`moe._route`)
+        share["bias"] = mo["bias"].astype(jnp.float32)
     # stable scopes in the ops' metadata, for whoever reads a trace
     with jax.named_scope("routed_ffn"):
         gates = xt.astype(jnp.float32) @ mo["gate"].astype(jnp.float32)
         gates = jax.nn.sigmoid(gates) if st.get("score") == "sigmoid" \
             else jax.nn.softmax(gates, axis=-1)
-        y, topi = ffn(xt, gates, _dq(mo, "wge", dt),
+        if "lat_dn" in mo:
+            # experts in a latent: the router read the full width, the
+            # experts read (and write) the down-projected row
+            with jax.named_scope("latent_proj"):
+                xt = xt @ _dq(mo, "lat_dn", dt)
+        y, topi = ffn(xt, gates,
+                      _dq(mo, "wge", dt) if act == "swiglu" else None,
                       _dq(mo, "wup", dt), _dq(mo, "wdn", dt),
                       top_k=st["top_k"], renormalize=st["renorm"],
-                      activation="swiglu", **share)
+                      activation=act, **share)
         if stats is not None:
             stats.append(routing_stats(topi, st.get("held"),
                                        gates.shape[-1], live))
+        if "lat_up" in mo:
+            with jax.named_scope("latent_proj"):
+                y = y.astype(dt) @ _dq(mo, "lat_up", dt)
         y = y.reshape(B, S, H).astype(h2.dtype)
     if "shared" in mo:
         sh = mo["shared"]
         with jax.named_scope("shared_expert"):
-            s = jax.nn.silu(h2 @ _dq(sh, "sg", dt)) \
-                * (h2 @ _dq(sh, "su", dt))
+            if "sg" in sh or "sg_q" in sh or "sg_q4" in sh:
+                s = jax.nn.silu(h2 @ _dq(sh, "sg", dt)) \
+                    * (h2 @ _dq(sh, "su", dt))
+            else:       # two matrices: relu(x U)^2 V
+                s = jnp.square(jax.nn.relu(h2 @ _dq(sh, "su", dt)))
             y = y + s @ _dq(sh, "sd", dt)
     return y
 
@@ -1054,6 +1110,12 @@ def _cached_step_body(p, max_len: int):
             "cache row for every pass of every layer) decodes through "
             "serving.ServingEngine; the contiguous-cache bodies keep "
             "one row a layer")
+    if p["family"] == "hybrid":
+        raise NotImplementedError(
+            "the Nemotron-H family (state-space mixers whose memory of a "
+            "sequence is a slot of recurrent state, beside the attention "
+            "blocks' pages) decodes through serving.ServingEngine; the "
+            "contiguous-cache bodies keep rows only")
     if p["family"] == "gpt":
         return _gpt_cached_step_body(p["cfg"], max_len)
     if p["family"] == "mla":

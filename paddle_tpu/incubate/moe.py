@@ -159,7 +159,7 @@ def _group_limited(gates, n_group: int, topk_group: int):
 
 
 def _route(gates, top_k: int, renormalize: bool, held, scale: float,
-           group=None):
+           group=None, bias=None):
     """Top-k routing over ALL experts of `gates` [T, E]: (weights [T, k],
     expert ids [T, k], local ids, held mask). `held = (first, count)`
     names the contiguous experts whose weights this program holds: a
@@ -167,9 +167,15 @@ def _route(gates, top_k: int, renormalize: bool, held, scale: float,
     `count` (one past the held groups), so it sorts behind them and
     meets no expert. `scale` is the routed scaling factor. `group =
     (n_group, topk_group)` limits the choice to the best groups
-    (`_group_limited`); the weights are still the gates' own. With
-    `held=None`, scale 1 and no group local ids are the ids and the
-    mask is None: the numerics of the uncut layer, bit for bit."""
+    (`_group_limited`); the weights are still the gates' own. `bias`
+    [E] is a per-expert correction added to the scores for the CHOICE
+    (the groups' and the top-k's): it picks, it does not weigh — the
+    weights are the chosen experts' own gates. With `held=None`, scale
+    1, no group and no bias local ids are the ids and the mask is None:
+    the numerics of the uncut layer, bit for bit."""
+    scores = gates
+    if bias is not None:
+        gates = gates + bias
     if group is not None:
         n_group, topk_group = group
         if gates.shape[-1] % n_group or \
@@ -179,7 +185,7 @@ def _route(gates, top_k: int, renormalize: bool, held, scale: float,
                 f"{gates.shape[-1]} experts cannot give top-{top_k}")
         gates = _group_limited(gates, n_group, topk_group)
     topv, topi = jax.lax.top_k(gates, top_k)
-    gv = topv
+    gv = topv if bias is None else jnp.take_along_axis(scores, topi, -1)
     if renormalize:
         gv = gv / jnp.maximum(jnp.sum(gv, -1, keepdims=True), 1e-9)
     if scale != 1.0:
@@ -214,23 +220,33 @@ def routing_stats(topi, held, num_experts: int, live=None):
                       jnp.sum(sizes > 0).astype(jnp.float32)])
 
 
+def _expert_act(activation: str, up, gate):
+    """The experts' nonlinearity on the up-projection `up`: `swiglu`
+    gates it with `gate()` (the third matrix's product, made only
+    here), `relu2` squares its positive part (two matrices an expert),
+    anything else is gelu."""
+    if activation == "swiglu":
+        return jax.nn.silu(gate()) * up
+    if activation == "relu2":
+        return jnp.square(jax.nn.relu(up))
+    return jax.nn.gelu(up)
+
+
 def dense_expert_ffn(xt, gates, wg, wu, wd, *, top_k: int,
                      renormalize: bool, activation: str = "swiglu",
-                     held=None, scale: float = 1.0, group=None):
+                     held=None, scale: float = 1.0, group=None,
+                     bias=None):
     """Decode-sized routed FFN: run EVERY (held) expert on every token
     and weighted-select. At serving token counts (T <= ~32) this beats
     the sort+grouped-GEMM path, whose per-expert tiles pad to 128 rows —
     and it is bitwise-identical to it (same per-row matmuls, same
     combine), so the cached-decode exact-match contract is preserved.
-    `held` / `scale` / `group`: see `dropless_expert_ffn`."""
+    `held` / `scale` / `group` / `bias`: see `dropless_expert_ffn`."""
     gv, topi, local, mine = _route(gates, top_k, renormalize, held, scale,
-                                   group)
+                                   group, bias)
     up = jnp.einsum("th,ehi->eti", xt, wu)
-    if activation == "swiglu":
-        g = jnp.einsum("th,ehi->eti", xt, wg)
-        act = jax.nn.silu(g) * up
-    else:
-        act = jax.nn.gelu(up)
+    act = _expert_act(activation, up,
+                      lambda: jnp.einsum("th,ehi->eti", xt, wg))
     down = jnp.einsum("eti,eih->eth", act, wd)          # [E, T, H]
     # combine EXACTLY like the grouped path: gather the k selected expert
     # outputs per token and reduce over k in rank order (a different
@@ -246,7 +262,8 @@ def dense_expert_ffn(xt, gates, wg, wu, wd, *, top_k: int,
 
 def dropless_expert_ffn(xt, gates, wg, wu, wd, *, top_k: int,
                         renormalize: bool, activation: str = "swiglu",
-                        held=None, scale: float = 1.0, group=None):
+                        held=None, scale: float = 1.0, group=None,
+                        bias=None):
     """Per-token top-k routed expert FFN, dropless (megablocks pattern:
     flatten (token, choice) rows, sort by expert, one ragged grouped GEMM,
     unsort, weighted-combine). SINGLE SOURCE OF TRUTH for the routing
@@ -263,22 +280,21 @@ def dropless_expert_ffn(xt, gates, wg, wu, wd, *, top_k: int,
     weigh nothing in the combine. The result is this chip's addend of
     the layer's routed sum. `gates` are whatever scores the router
     gives (a softmax, or sigmoids); `group` limits the choice to the
-    best groups of experts (`_route`)."""
+    best groups of experts and `bias` corrects it (`_route`).
+    `activation` "relu2" is the non-gated expert of two matrices
+    (`wg` None): relu(x U)^2 V."""
     E = wu.shape[0]
     T = xt.shape[0]
     gv, topi, local, mine = _route(gates, top_k, renormalize, held, scale,
-                                   group)
+                                   group, bias)
     rows = jnp.repeat(xt, top_k, axis=0)                    # [T*k, H]
     eids = local.reshape(-1)                                # [T*k]
     srt, sizes, inv = sort_by_group(rows, eids,
                                     E if mine is None else E + 1)
     sizes = sizes[:E]
     up = grouped_gemm(srt, wu, sizes)
-    if activation == "swiglu":
-        g = grouped_gemm(srt, wg, sizes)
-        act = jax.nn.silu(g) * up
-    else:
-        act = jax.nn.gelu(up)
+    act = _expert_act(activation, up,
+                      lambda: grouped_gemm(srt, wg, sizes))
     down = grouped_gemm(act, wd, sizes)
     down = unsort_by_group(down, inv).reshape(T, top_k, -1)
     if mine is not None:

@@ -219,10 +219,18 @@ SCOPES = ("embed", "attn_norm", "qkv_proj", "cache_write", "attention",
 #: `mla_attention`, `eva_attention`, `eva_pool` so): they stay as they
 #: are and answer to the vocabulary through this table.  `mla_kv` is
 #: the latent row's projection, norm and rope; its row append sits in a
-#: `cache_write` of its own, one scope further in.
+#: `cache_write` of its own, one scope further in.  A state-space
+#: mixer's parts answer as the attention chain's do: its in-projection
+#: as `qkv_proj`, the convolution (which keeps the sequence's last
+#: rows) as `cache_write`, the state update and the chunk's scan as
+#: `attention`, the gated norm and out-projection as `attn_out`; the
+#: latent projections around the routed experts are `routed_ffn`.
 SCOPE_ALIASES = {"mla_q": "qkv_proj", "mla_kv": "qkv_proj",
                  "mla_attention": "attention", "mla_out": "attn_out",
-                 "eva_attention": "attention", "eva_pool": "cache_write"}
+                 "eva_attention": "attention", "eva_pool": "cache_write",
+                 "ssm_in_proj": "qkv_proj", "ssm_conv": "cache_write",
+                 "ssm_scan": "attention", "ssm_out": "attn_out",
+                 "latent_proj": "routed_ffn"}
 
 
 def scope(name: str):
@@ -246,6 +254,9 @@ class OpScope(NamedTuple):
     #                             its operand's (layout copies, prefetches)
     reads: str                  # a copy's source parameter (`w__layers__..`)
     program: str
+    #: the innermost name AS THE PROGRAM WROTE IT: `scope` itself, or
+    #: the name of SCOPE_ALIASES that answered for it (`ssm_scan`)
+    own: str = ""
 
 
 _HLO_HEAD = re.compile(r"^\s*(?:ROOT\s+)?%?([\w\-.]+) = (\(?\w+\[[\d,]*\])")
@@ -294,11 +305,8 @@ def _path_scope(op_name: str) -> Tuple[Optional[str], str]:
     entered outside a transformation shows inside its parentheses, so
     the path is read word by word, outermost first."""
     words = _WORD.findall(_JIT_PART.sub("", op_name))
-    name = None
-    for w in words[:-1]:            # the last word is the primitive
-        w = SCOPE_ALIASES.get(w, w)
-        if w in SCOPES:
-            name = w
+    own = _own(words)
+    name = SCOPE_ALIASES.get(own, own) or None
     if "rematted_computation" in words:
         direction = "remat"
     elif "transpose" in words:
@@ -308,6 +316,21 @@ def _path_scope(op_name: str) -> Tuple[Optional[str], str]:
     else:
         direction = "-"
     return name, direction
+
+
+def _own(words) -> str:
+    """The innermost of a path's words that is a name of the vocabulary
+    or one of its aliases, as written ("" without one)."""
+    own = ""
+    for w in words[:-1]:            # the last word is the primitive
+        if w in SCOPES or w in SCOPE_ALIASES:
+            own = w
+    return own
+
+
+def _path_own(op_name: str) -> str:
+    """`_own` of one ``op_name`` path."""
+    return _own(_WORD.findall(_JIT_PART.sub("", op_name)))
 
 
 def _kind(opcode: str) -> str:
@@ -323,13 +346,14 @@ def _kind(opcode: str) -> str:
 class _Inst:
     """One parsed instruction, while its computation is resolved."""
     __slots__ = ("name", "shape", "opcode", "scope", "direction", "kind",
-                 "inside", "operands", "inherited")
+                 "inside", "operands", "inherited", "own")
 
     def __init__(self, name, shape, opcode, scope, direction, kind, inside,
-                 operands):
+                 operands, own=""):
         self.name, self.shape, self.opcode = name, shape, opcode
         self.scope, self.direction, self.kind = scope, direction, kind
         self.inside, self.operands, self.inherited = inside, operands, False
+        self.own = own
 
 
 def _inherit(insts: List[_Inst], by_name: Mapping[str, _Inst]) -> None:
@@ -353,6 +377,7 @@ def _inherit(insts: List[_Inst], by_name: Mapping[str, _Inst]) -> None:
                                 if n in by_name and by_name[n].scope), None)
                     if src is not None:
                         i.scope, i.direction = src.scope, src.direction
+                        i.own = src.own
                         i.inherited = changed = True
 
 
@@ -394,6 +419,7 @@ def _program_scopes(text: str, program: str) -> Dict[str, OpScope]:
             name, shape, opcode = head
             m = _OP_NAME.search(ln)
             own, direction = _path_scope(m.group(1)) if m else (None, "-")
+            raw = _path_own(m.group(1)) if m else ""
             kind, inside = _kind(opcode), ()
             calls = _CALLS.search(ln) if opcode == "fusion" else None
             if calls:
@@ -411,8 +437,10 @@ def _program_scopes(text: str, program: str) -> Dict[str, OpScope]:
                     nm, d = _path_scope(im.group(1)) if im else (None, "-")
                     if nm is not None:
                         names.append(nm)
-                        if own is None and direction == "-":
-                            direction = d
+                        if own is None:
+                            raw = _path_own(im.group(1))
+                            if direction == "-":
+                                direction = d
                 if own is None and names:
                     own = names[-1]
                 # a collective the compiler hid inside a matmul's fusion
@@ -423,7 +451,7 @@ def _program_scopes(text: str, program: str) -> Dict[str, OpScope]:
                 inside = distinct if len(distinct) > 1 else ()
             operands = _OPERAND.findall(ln[ln.index(opcode + "("):])
             insts.append(_Inst(name, shape, opcode, own, direction, kind,
-                               inside, operands))
+                               inside, operands, raw))
         by_name = {i.name: i for i in insts}
         _inherit(insts, by_name)
         for i in insts:
@@ -432,7 +460,7 @@ def _program_scopes(text: str, program: str) -> Dict[str, OpScope]:
             reads = _source_parameter(i, by_name) if i.kind == "copy" else ""
             out[f"%{i.name} {i.shape}"] = OpScope(
                 i.scope, i.direction, i.kind, i.opcode, i.shape, i.inside,
-                i.inherited, reads, program)
+                i.inherited, reads, program, i.own)
     return out
 
 

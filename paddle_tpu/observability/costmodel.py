@@ -251,6 +251,35 @@ def _c_fused_chunk_pool(*, P: int, KV: int, D: int, chunk: int,
                         breakdown={"kv": rows, "activations": out})
 
 
+@register_cost("ssm_state_update")
+def _c_ssm_state_update(*, live: int, P: int, N: int, H: int,
+                        dtype_bytes: int = 2) -> CostEstimate:
+    """One step of the Mamba-2 recurrence for `live` slots of a
+    slot-indexed state pool [slots, P, N, H] float32 (aliased in+out):
+    each live slot's state once in and once out, its row's dt x [P, H]
+    and decay [H] in float32, B and C [N, H]; y [P, H] out. An idle
+    slot is neither read nor written. 5 FLOPs an element of the state
+    (decay, the outer product's multiply-add, the read-out's)."""
+    state = live * P * N * H * 4
+    rows_in = live * ((P * H + H) * 4 + 2 * N * H * dtype_bytes)
+    rows_out = live * P * H * 4
+    return CostEstimate(bytes_read=state + rows_in,
+                        bytes_written=state + rows_out,
+                        flops=5 * live * P * N * H,
+                        breakdown={"state": 2 * state,
+                                   "activations": rows_in + rows_out})
+
+
+@register_cost("ssm_state_put")
+def _c_ssm_state_put(*, P: int, N: int, H: int) -> CostEstimate:
+    """One slot [P, N, H] float32 of the state pool replaced in place:
+    the new state read, the slot written (its old content rides in with
+    the aliased block and is dropped)."""
+    state = P * N * H * 4
+    return CostEstimate(bytes_read=2 * state, bytes_written=state, flops=0,
+                        breakdown={"state": 3 * state})
+
+
 @register_cost("swiglu")
 def _c_swiglu(*, T: int, H: int, dtype_bytes: int = 2) -> CostEstimate:
     """gate/up [T, H] -> silu(gate) * up [T, H]."""
@@ -586,6 +615,19 @@ def _c_fused_qkv_rope_append(*, T: int, H: int, Hq: int, KV: int = 0,
 # ---------------------------------------------------------------------------
 # composite budgets — the shared cost vocabulary
 # ---------------------------------------------------------------------------
+
+def ssm_state_bytes_per_seq_layer(*, heads: int, head_dim: int,
+                                  state_size: int, conv_dim: int,
+                                  conv_kernel: int,
+                                  state_dtype_bytes: int = 4,
+                                  conv_dtype_bytes: int = 2) -> int:
+    """HBM bytes a SEQUENCE holds in one state-space (Mamba-2) layer,
+    whatever its length: the recurrent state heads x head_dim x
+    state_size and the convolution's tail, the last conv_kernel - 1
+    rows of its input."""
+    return (heads * head_dim * state_size * state_dtype_bytes
+            + (conv_kernel - 1) * conv_dim * conv_dtype_bytes)
+
 
 def kv_bytes_per_token_layer(family: str, *, kv_heads: int = 0,
                              head_dim: int = 0, kv_latent_dim: int = 0,

@@ -62,7 +62,7 @@ __all__ = ["TraceEvent", "RequestTrace", "TraceRecorder", "recorder",
            "enabled", "set_enabled", "percentile", "percentiles",
            "slo_summary", "SLO_METRICS", "STEP_COUNTS", "STEPS_PER_SLOT",
            "STEP_COUNTS_BY_KIND", "STEP_COUNTS_MOE", "STEP_COUNTS_LATENT",
-           "STEP_COUNTS_EVA", "STEP_COUNTS_LOOP"]
+           "STEP_COUNTS_EVA", "STEP_COUNTS_LOOP", "STEP_COUNTS_SSM"]
 
 _FLAG = _flags._registry["FLAGS_request_tracing"]
 
@@ -151,6 +151,19 @@ STEP_COUNTS_EVA: Tuple[str, ...] = (
 #: `ut_steps` floats that sums to 1: what adaptive exit would save
 STEP_COUNTS_LOOP: Tuple[str, ...] = (
     "ut_steps", "layer_applications", "cache_row_bytes", "ut_exit_mass")
+#: ... and where some layers are state-space mixers, whose memory of a
+#: sequence is a FIXED-SIZE state in a slot of a pool, not pages: the
+#: slots a launch's rows name (its decode rows' and its chunk's), the
+#: bytes a slot holds in ONE such layer as stored (the recurrent state
+#: and the convolution's tail), the bytes of recurrent state the launch
+#: moved over all such layers (each named slot's once in and once out;
+#: a slot that starts its sequence is not read), the chunk's rows, the
+#: slots the launch started from zero state, and the pool's slots that
+#: hold a request against all of them
+STEP_COUNTS_SSM: Tuple[str, ...] = (
+    "ssm_slots_live", "ssm_state_bytes", "ssm_state_bytes_moved",
+    "ssm_scan_rows", "ssm_state_resets", "state_pool_slots_used",
+    "state_pool_slots_total")
 #: step records kept for each slot of the request ring. A request lives
 #: through tens to hundreds of steps, and whoever reads a whole measured
 #: window from the records (`benchmarks/lib/program_spans.py`: 50-56 s

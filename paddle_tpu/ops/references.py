@@ -20,7 +20,8 @@ __all__ = ["rms_norm_reference", "layer_norm_reference",
            "chunk_pool_reference",
            "swiglu_reference", "mla_decode_reference", "gmm_reference",
            "oproj_norm_reference", "megadecode_ffn_reference",
-           "qkv_rope_append_reference"]
+           "qkv_rope_append_reference", "ssm_state_update_reference",
+           "ssm_state_put_reference", "ssm_recurrence_reference"]
 
 
 def rms_norm_reference(x, weight, eps: float = 1e-6):
@@ -260,3 +261,44 @@ def gmm_reference(lhs, rhs, group_sizes, block_m: int = 128,
                        rhs.astype(jnp.float32))
     out = jnp.einsum("mgn,mg->mn", per_g, member)
     return out.astype(lhs.dtype)
+
+
+def ssm_state_update_reference(pool, slots, n_live, xdt, dec, bh, ch):
+    """One step of the Mamba-2 recurrence for the live slots (the first
+    ``n_live`` of ``slots``); every other slot of the pool unchanged."""
+    NS = pool.shape[0]
+    f32 = jnp.float32
+    live = jnp.zeros(NS, bool).at[slots].max(
+        jnp.arange(slots.shape[0]) < n_live[0])
+    new = (dec[:NS, 0][:, None, None, :] * pool
+           + xdt[:NS, :, None, :] * bh[:NS].astype(f32)[:, None])
+    y = jnp.sum(new * ch[:NS].astype(f32)[:, None], axis=2)
+    keep = live[:, None, None, None]
+    return jnp.where(live[:, None, None], y, 0), jnp.where(keep, new, pool)
+
+
+def ssm_state_put_reference(pool, slot, state):
+    return jnp.where(slot[1] > 0,
+                     pool.at[slot[0]].set(state.astype(pool.dtype)), pool)
+
+
+def ssm_recurrence_reference(xdt, dA, bm, cm, state):
+    """The Mamba-2 recurrence token by token (what
+    `pallas_ssm.ssm_chunk_scan` and `ssm_state_update` are tested
+    against): the operands of `ssm_chunk_scan`."""
+    G = bm.shape[1]
+    H = xdt.shape[1]
+    K = H // G
+
+    def step(s, row):
+        x, a, b, c = row                    # [H, P], [H], [G, N], [G, N]
+        bh, ch = jnp.repeat(b, K, 0), jnp.repeat(c, K, 0)   # [H, N]
+        s = jnp.exp(a)[None, None, :] * s + jnp.einsum("hp,hn->pnh", x, bh)
+        return s, jnp.einsum("pnh,hn->hp", s, ch,
+                             precision=jax.lax.Precision.HIGHEST)
+
+    f32 = jnp.float32
+    state, y = jax.lax.scan(
+        step, state.astype(f32),
+        (xdt.astype(f32), dA.astype(f32), bm.astype(f32), cm.astype(f32)))
+    return y, state

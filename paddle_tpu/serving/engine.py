@@ -76,8 +76,12 @@ from ..ops.fused import (append_run_count, append_run_table, append_tile,
                          fused_append_rows, fused_chunk_pool,
                          fused_layer_norm, fused_rms_norm,
                          fused_rope_append)
+from ..models.nemotron_h import (ssm_conv, ssm_gated_norm, ssm_operands,
+                                 ssm_split)
 from ..models.ouro import exit_distribution as _exit_distribution
 from ..ops.paged_attention import append_to_cache, paged_attention
+from ..ops.pallas_ssm import (ssm_chunk_scan, ssm_state_put,
+                              ssm_state_update)
 from ..ops.pallas_ragged import (ragged_head_block,
                                  ragged_kernel_eligible,
                                  ragged_paged_attention,
@@ -291,7 +295,8 @@ _ADDITIVE = frozenset(
     ("decode_rows", "prefill_rows", "append_runs", "rows_dropped",
      "pages_live", "pages_visited", "attn_block_visits")
     + _tracing.STEP_COUNTS_BY_KIND[:4]
-    + _tracing.STEP_COUNTS_EVA[:4])
+    + _tracing.STEP_COUNTS_EVA[:4]
+    + ("ssm_state_bytes_moved", "ssm_scan_rows", "ssm_state_resets"))
 
 
 class ServingEngine:
@@ -443,6 +448,23 @@ class ServingEngine:
                 enable_prefix_cache, spec_decode, role)
             # a forked page's copy-on-write would copy pass 0 only
             enable_prefix_cache = prefix_sharing = False
+        # a hybrid's state-space blocks keep a FIXED-SIZE state a
+        # sequence, in the slot the scheduler gave it, beside the pages
+        # of its attention blocks: two kinds of cache, one engine. A
+        # state cannot be cut at a token: nothing can adopt a prefix of
+        # it, roll it back or move it, and a slot that is given away
+        # takes the state with it
+        self._ssm_layers = p["pattern"].count("M") \
+            if self._family == "hybrid" else 0
+        if self._ssm_layers:
+            _refuse_shared_cache(
+                f"this model has {self._ssm_layers} state-space blocks, "
+                f"whose memory of a sequence is one recurrent state in "
+                f"its slot, not rows that a snapshot could cut, so ",
+                enable_prefix_cache, spec_decode, role)
+            # neither is a preempted sequence's state kept once its slot
+            # is handed on (ROADMAP R4 b): the engine never preempts
+            enable_prefix_cache = prefix_sharing = preemption = False
         if self._eva:
             _refuse_shared_cache(
                 "this model's layers are chunk-summary attention (pooled "
@@ -515,6 +537,23 @@ class ServingEngine:
             # one pool per layer: each row is [latent | rope-key | pad],
             # K whole and V in its first kv_lora_rank columns
             self._pools = [jnp.zeros(shape, dt) for _ in range(n_layers)]
+        elif self._ssm_layers:
+            # the attention blocks' pages, and for each state-space
+            # block the slot-indexed state pool (heads-minor float32,
+            # `ops.pallas_ssm`) and the convolution's tail, one slot
+            # more than the scheduler's: the spare takes what idle rows
+            # and an absent chunk write
+            self._state_shape = (
+                self.max_slots + 1, cfg.mamba_head_dim, cfg.ssm_state_size,
+                cfg.mamba_num_heads)
+            self._tail_shape = (self.max_slots + 1, cfg.conv_kernel - 1,
+                                cfg.conv_dim)
+            self._pools = {
+                "kv": [(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
+                       for _ in self._layer_kind],
+                "ssm": [(jnp.zeros(self._state_shape, jnp.float32),
+                         jnp.zeros(self._tail_shape, dt))
+                        for _ in range(self._ssm_layers)]}
         else:
             self._pools = [(jnp.zeros(sh, dt), jnp.zeros(sh, dt))
                            for sh in (wshape if k else shape
@@ -562,6 +601,8 @@ class ServingEngine:
             self._count_names += _tracing.STEP_COUNTS_EVA
         if self._family == "looped":
             self._count_names += _tracing.STEP_COUNTS_LOOP
+        if self._ssm_layers:
+            self._count_names += _tracing.STEP_COUNTS_SSM
         self._counts = dict.fromkeys(self._count_names, 0)
         # the pool handles this step's launches were handed (dead
         # arrays, no buffers): `pools_in_place` asks them at account
@@ -598,6 +639,17 @@ class ServingEngine:
             planes * kv * (self.num_window_pages if k else self.num_pages)
             * self.page_size * d * self._kv_itemsize
             for k in self._layer_kind)
+        # a state-space block's pool: every slot's state and tail
+        self._ssm_state_bytes = self._ssm_slot_bytes = 0
+        if self._ssm_layers:
+            self._ssm_state_bytes = 4 * int(np.prod(self._state_shape[1:]))
+            self._ssm_slot_bytes = _costmodel.ssm_state_bytes_per_seq_layer(
+                heads=cfg.mamba_num_heads, head_dim=cfg.mamba_head_dim,
+                state_size=cfg.ssm_state_size, conv_dim=cfg.conv_dim,
+                conv_kernel=cfg.conv_kernel,
+                conv_dtype_bytes=self._kv_itemsize)
+            self._hbm_pool_bytes += self._ssm_layers * (
+                self.max_slots + 1) * self._ssm_slot_bytes
         # what ONE launch reads of the weights: the layers once a pass
         self._hbm_weight_read_bytes = self._hbm_weights_bytes + (
             self._passes - 1) * _costmodel.tree_bytes(self._w["layers"])
@@ -691,7 +743,17 @@ class ServingEngine:
                 lambda p: p.at[:, dst].set(p[:, src]), pools),
             donate_argnums=0)
         idle = np.zeros(self._copy_slots, np.int32)
-        self._pools = self._jit_copy(self._live_pools(), idle, idle)
+        self._copy_pages(idle, idle)
+
+    def _copy_pages(self, src, dst) -> None:
+        """Pages `src` copied onto pages `dst` in every page pool (a
+        state-space block has no pages: only the attention blocks')."""
+        pools = self._live_pools()
+        if isinstance(pools, dict):
+            self._pools = dict(pools, kv=self._jit_copy(pools["kv"], src,
+                                                        dst))
+        else:
+            self._pools = self._jit_copy(pools, src, dst)
 
     def _step_programs(self) -> Dict[str, object]:
         """{name: a FRESH `jax.jit`} of the step programs at the current
@@ -766,6 +828,10 @@ class ServingEngine:
                 "a model with sliding-window layers reserves its window "
                 "pages for the prefill chunk it was built with: the chunk "
                 "can only shrink, and spec_decode stays 0")
+        if self._ssm_layers and new_k:
+            raise ValueError(
+                "a model with state-space blocks cannot roll a rejected "
+                "draft's state back: spec_decode stays 0")
         if self._eva and (new_k or new_chunk
                           % self._p["cfg"].chunk_size):
             raise ValueError(
@@ -955,6 +1021,10 @@ class ServingEngine:
                     "pool_pages_total.exact": full[0],
                     "pool_pages_used.summary": summ,
                     "pool_pages_used.exact": exact})
+            if self._ssm_layers:
+                self._counts["state_pool_slots_used"] = \
+                    self.scheduler.inflight
+                self._counts["state_pool_slots_total"] = self.max_slots
             self._counts["pool_pages_total"] = full[0] + win[0]
             self._counts["pool_pages_used"] = full[1] + win[1]
             # did every launch of the step write its pools in place?
@@ -977,7 +1047,7 @@ class ServingEngine:
         riding the unified launch — the slack the observatory's 25%
         gate allows."""
         kv, d = self._kv_geom
-        n_layers = len(self._p["layers"])
+        n_layers = len(self._layer_kind)    # the layers that keep pages
         per_tok = _costmodel.kv_bytes_per_token_layer(
             self._family, kv_heads=kv, head_dim=d,
             kv_latent_dim=(d if self._family == "mla" else 0),
@@ -1036,6 +1106,8 @@ class ServingEngine:
         acct = {
             "weights_bytes": float(self._hbm_weights_bytes),
             "page_pool_bytes": float(self._hbm_pool_bytes),
+            "state_pool_bytes": float(self._ssm_layers * (
+                self.max_slots + 1) * self._ssm_slot_bytes),
             "draft_bytes": float(_G_HBM_DRAFT.value),
             "ledger_bytes": float(self._ledger_bytes),
             "ledger_tokens": int(self._ledger_tokens),
@@ -1233,6 +1305,11 @@ class ServingEngine:
         return handoff
 
     def _no_handoff(self, what: str) -> None:
+        if self._ssm_layers:
+            raise NotImplementedError(
+                f"{what}: this model has state-space blocks; a handoff of "
+                f"a sequence's recurrent state beside its KV pages is not "
+                f"implemented")
         if self._passes > 1:
             raise NotImplementedError(
                 f"{what}: this model runs its layers {self._passes} times "
@@ -1811,6 +1888,30 @@ class ServingEngine:
         live = int(np.sum(-(-kv_lengths // ps)))
         counts["pages_live"] = live
         counts["pages_visited"] = visited(0)
+        if self._ssm_layers:
+            # whose state the launch's rows name: the live decode slots
+            # first (the spare slot B pads the list), how many, the
+            # chunk's slot (the spare without a chunk) and whether this
+            # launch STARTS its sequence — from zero state, by this
+            # flag, on the device
+            named = [slot for slot, _, _ in rows]
+            tab = np.full(B + 3, B, np.int32)
+            tab[:len(named)] = named
+            tab[B] = len(named)
+            starts = int(preq is not None and start == 0)
+            tab[B + 2] = starts
+            if preq is not None:
+                tab[B + 1] = preq.slot
+            slots = len(named) + (preq is not None)
+            counts.update({
+                "ssm_slots_live": slots,
+                "ssm_state_bytes": self._ssm_slot_bytes,
+                # (a Python int: 5.4e9 at the benchmark's sizes)
+                "ssm_state_bytes_moved": (2 * slots - starts)
+                * self._ssm_layers * self._ssm_state_bytes,
+                "ssm_scan_rows": n, "ssm_state_resets": starts})
+            return ((tok, positions, num_tokens, (kv_lengths, tab), tables,
+                     tok_page, tok_off), src, drafts, n, start, counts)
         if eva:
             seen = num_tokens > 0
             ends = positions[(seq_start + num_tokens - 1)[seen]] + 1
@@ -1993,7 +2094,7 @@ class ServingEngine:
             pairs = np.zeros((2, n), np.int32)
             part = np.asarray(copies[i:i + n], np.int32).T
             pairs[:, :part.shape[1]] = part
-            self._pools = self._jit_copy(self._live_pools(), *pairs)
+            self._copy_pages(*pairs)
 
     # ----------------------------------------------------- jitted bodies
     def _make_decode_body(self):
@@ -2013,6 +2114,8 @@ class ServingEngine:
     def _make_unified_body(self):
         if self._family == "eva":
             return self._eva_unified_body()
+        if self._family == "hybrid":
+            return self._hybrid_unified_body()
         if self._family == "looped":
             return self._looped_unified_body()
         if self._family == "gpt":
@@ -2313,6 +2416,169 @@ class ServingEngine:
                 mass = jnp.where(own[:, None], _exit_distribution(lam.T), 0)
                 mass = mass.sum(0) / jnp.maximum(own.sum(), 1)
             return logits, new_pools, tokens, mass
+
+        return step
+
+    def _hybrid_unified_body(self):
+        """A hybrid (Nemotron-H) on the one launch: block l is `x +
+        mixer_l(RMSNorm(x))` with ONE mixer, of the kind the model's
+        pattern names.
+
+        ``*``, attention without rotary: q / k / v -> `fused_rope_append`
+        under an identity table (a plain append) -> `ragged_paged_attention`
+        -> o-proj, over the pages of the attention blocks alone.
+
+        ``M``, a Mamba-2 state-space mixer, whose memory of a sequence is
+        its slot of the block's state pool and of its convolution tail:
+        in-projection (`ssm_in_proj`) -> the causal convolution, a decode
+        row from its slot's tail, the chunk's rows from its slot's tail
+        (zeros where the launch starts the sequence) and from each other,
+        the tails of the last valid rows written back (`ssm_conv`) -> the
+        state: every live decode slot ONE step of the recurrence, in place
+        (`ssm_state_update`), the chunk's rows a scan in the config's
+        chunks from its slot's state (`ssm_chunk_scan`; rows past the
+        chunk's length carry dt 0, the identity), its last state put back
+        in place (`ssm_state_put`) (`ssm_scan`) -> the gated group norm
+        and the out-projection (`ssm_out`). A launch without a chunk skips
+        the scan and moves no state for it.
+
+        ``E``, a latent routed FFN: `_ffn_apply` (`routed_ffn`,
+        `latent_proj`, `shared_expert`).
+
+        ``kv_lengths`` is a pair: (the attention blocks' lengths, the
+        state table [B + 3]: the live decode slots then the spare, their
+        count, the chunk's slot, whether the launch starts it)."""
+        cfg, pattern = self._p["cfg"], self._p["pattern"]
+        Hq, KV, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                     cfg.head_dim)
+        Hm, P, G, N = (cfg.mamba_num_heads, cfg.mamba_head_dim,
+                       cfg.n_groups, cfg.ssm_state_size)
+        eps, K = cfg.layer_norm_epsilon, cfg.conv_kernel
+        moe_static = self._p["moe_static"]
+        B, C = self.max_slots, self.prefill_chunk
+        T = B + C
+        seq_start = _seq_starts(B, 1)
+        run_table = self._run_table(seq_start)
+        f32 = jnp.float32
+
+        def ssm(L, a, z_pool, t_pool, num_tokens, tab):
+            """The state-space mixer of a [T, hidden] -> (its output [T,
+            hidden], the state pool, the tail pool)."""
+            dt_w = a.dtype
+            slots, n_live = tab[:B], tab[B:B + 1]
+            cslot, starts = tab[B + 1], tab[B + 2] > 0
+            n_c = num_tokens[B]
+            live = num_tokens[:B] > 0
+            with jax.named_scope("ssm_in_proj"):
+                z, u, dt = ssm_split(a @ L["w_in"], cfg)
+            with jax.named_scope("ssm_conv"):
+                # decode row s is slot s: its tail, then its own row
+                tails = t_pool[:B]
+                ext = jnp.concatenate([tails, u[:B, None]], 1)
+                conv_d = jax.vmap(ssm_conv, (0, None, None))(
+                    ext, L["conv_w"], L["conv_b"])[:, 0]
+                # the chunk: its slot's tail (zeros at a start), its rows
+                tail_c = jnp.where(starts, 0, t_pool[cslot])
+                ext_c = jnp.concatenate([tail_c, u[B:]])
+                conv_c = ssm_conv(ext_c, L["conv_w"], L["conv_b"])
+                # the last K - 1 rows before each sequence's next one
+                t_pool = t_pool.at[:B].set(
+                    jnp.where(live[:, None, None], ext[:, 1:], tails))
+                t_pool = jax.lax.dynamic_update_slice(
+                    t_pool, jax.lax.dynamic_slice(
+                        ext_c, (n_c, 0), (K - 1, ext_c.shape[1]))[None],
+                    (cslot, 0, 0))
+                u = jnp.concatenate([conv_d, conv_c])
+            with jax.named_scope("ssm_scan"):
+                x, dt, dA, bm, cm = ssm_operands(u, dt, L, cfg)
+                xf = x.astype(f32)
+                # the decode rows: one step each, the state in place
+                # (row s of the operands is slot s; row B the spare's)
+                rows = slice(0, B + 1)
+                heads = lambda m: jnp.repeat(          # noqa: E731
+                    m[rows], Hm // G, axis=1).swapaxes(1, 2)
+                y_d, z_pool = ssm_state_update(
+                    z_pool, slots, n_live,
+                    (xf[rows] * dt[rows, :, None]).swapaxes(1, 2),
+                    jnp.exp(dA[rows])[:, None, :], heads(bm), heads(cm))
+                y_d = jnp.where(live[:, None, None],
+                                y_d[:B].swapaxes(1, 2), 0)
+                # the chunk: a scan from its slot's state, where there
+                # is one; rows past its length are the identity
+                valid = (jnp.arange(C) < n_c)[:, None]
+                dt_c = jnp.where(valid, dt[B:], 0)
+
+                def scan():
+                    s0 = jax.lax.cond(
+                        starts, lambda: jnp.zeros(z_pool.shape[1:], f32),
+                        lambda: jax.lax.dynamic_index_in_dim(
+                            z_pool, cslot, 0, keepdims=False))
+                    return ssm_chunk_scan(
+                        xf[B:] * dt_c[..., None],
+                        jnp.where(valid, dA[B:], 0), bm[B:], cm[B:], s0,
+                        chunk=cfg.chunk_size)
+
+                y_c, s1 = jax.lax.cond(
+                    n_c > 0, scan,
+                    lambda: (jnp.zeros((C, Hm, P), f32),
+                             jnp.zeros(z_pool.shape[1:], f32)))
+                z_pool = ssm_state_put(
+                    z_pool, jnp.stack([cslot, (n_c > 0).astype(jnp.int32)]),
+                    s1)
+                y = jnp.concatenate([y_d, y_c]) \
+                    + L["D"].astype(f32)[None, :, None] * xf
+            with jax.named_scope("ssm_out"):
+                y = ssm_gated_norm(y.reshape(T, Hm * P), z, L["norm_g"], G,
+                                   eps).astype(dt_w)
+                return y @ L["w_out"], z_pool, t_pool
+
+        def step(w, tok, pools, positions, num_tokens, kv_lengths,
+                 tables, tok_page, tok_off):
+            del positions       # no rotary embedding, no position table
+            kv_lengths, tab = kv_lengths
+            with _scope("embed"):
+                x = w["embed"][tok][None]                # [1, T, hidden]
+            one = jnp.ones((T, D // 2), x.dtype)
+            with _scope("cache_write"):
+                runs = run_table(num_tokens, tok_page, tok_off)
+            kv_pools, ssm_pools = iter(pools["kv"]), iter(pools["ssm"])
+            sts = iter(moe_static)
+            new_kv, new_ssm, moe_stats = [], [], []
+            live = _owned_rows(T, seq_start, num_tokens)
+            for i, kind in enumerate(pattern):  # a letter: static
+                L = w["layers"][i]
+                with _scope("ffn_norm" if kind == "E" else "attn_norm"):
+                    a = fused_rms_norm(x, L["norm"], eps)
+                if kind == "E":
+                    x = x + _ffn_apply(L, a, next(sts), moe_stats, live)
+                elif kind == "M":
+                    y, z_pool, t_pool = ssm(L, a[0], *next(ssm_pools),
+                                            num_tokens, tab)
+                    new_ssm.append((z_pool, t_pool))
+                    x = x + y[None]
+                else:
+                    kp, vp = next(kv_pools)
+                    with _scope("qkv_proj"):
+                        q, k, v = a @ L["wq"], a @ L["wk"], a @ L["wv"]
+                    with _scope("cache_write"):
+                        q, kp, vp = fused_rope_append(
+                            q.reshape(T, Hq, D), k.reshape(T, KV, D),
+                            v.reshape(T, KV, D), one, jnp.zeros_like(one),
+                            kp, vp, runs)
+                    new_kv.append((kp, vp))
+                    with _scope("attention"):
+                        o = ragged_paged_attention(
+                            q, kp, vp, seq_start, num_tokens, kv_lengths,
+                            tables, scale=D ** -0.5, scope="attention")
+                    with _scope("attn_out"):
+                        x = x + o.reshape(1, T, Hq * D) @ L["wo"]
+            with _scope("head"):
+                x = fused_rms_norm(x, w["norm"], eps)
+                logits = _head_logits(
+                    w, _logit_rows(x, seq_start, num_tokens, 0))
+                tokens = _greedy(logits)
+            return (logits, {"kv": new_kv, "ssm": new_ssm}, tokens,
+                    _moe_step_counts(moe_stats))
 
         return step
 

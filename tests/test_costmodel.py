@@ -61,6 +61,8 @@ SHAPES = {
     "fused_ffn": dict(T=8, H=512, I=1792),
     "fused_qkv_rope_append": dict(T=8, H=512, Hq=32, KV=8, D=128,
                                   page_size=32),
+    "ssm_state_update": dict(live=3, P=16, N=16, H=8),
+    "ssm_state_put": dict(P=16, N=16, H=8),
 }
 
 
@@ -70,7 +72,8 @@ class TestRegistryCoverage:
         from paddle_tpu.ops import (fused, pallas_flash, pallas_flashmask,
                                     pallas_gmm, pallas_megadecode,
                                     pallas_megafront, pallas_mla,
-                                    pallas_paged, pallas_ragged, quant)
+                                    pallas_paged, pallas_ragged,
+                                    pallas_ssm, quant)
         from paddle_tpu.ops.oracles import oracles
         names = set(oracles())
         missing = names - set(cm.costs())

@@ -138,11 +138,28 @@ def test_scope_refuses_a_name_outside_the_vocabulary():
      ("ffn", "bwd")),
     ("jit(loss)/transpose(jvp(jvp()))/checkpoint/rematted_computation/"
      "attn_norm/mul", ("attn_norm", "remat")),
+    # a state-space mixer's parts and the latent projections (PR 43)
+    ("jit(step)/ssm_in_proj/dot_general", ("qkv_proj", "-")),
+    ("jit(step)/ssm_conv/mul", ("cache_write", "-")),
+    ("jit(step)/ssm_scan/pallas_call", ("attention", "-")),
+    ("jit(step)/ssm_out/dot_general", ("attn_out", "-")),
+    ("jit(step)/routed_ffn/latent_proj/dot_general", ("routed_ffn", "-")),
     ("jit(update)/jit(head)/mul", (None, "-")),     # function names
     ("jit(step)/jit(main)/add", (None, "-")),
 ])
 def test_a_path_gives_its_innermost_name_and_direction(path, want):
     assert at._path_scope(path) == want
+
+
+@pytest.mark.parametrize("path,want", [
+    ("jit(step)/ssm_scan/pallas_call", "ssm_scan"),
+    ("jit(step)/routed_ffn/latent_proj/dot_general", "latent_proj"),
+    ("jit(step)/attention/pallas_call", "attention"),
+    ("jit(step)/jit(main)/add", "")])
+def test_a_path_keeps_the_name_the_program_wrote(path, want):
+    """`OpScope.own`: an alias answers to the vocabulary AND stays
+    readable, so a reader can tell the mixer's scan from attention."""
+    assert at._path_own(path) == want
 
 
 def test_the_key_is_the_same_from_the_text_and_from_the_trace():
@@ -271,12 +288,23 @@ def _ouro():
     return m
 
 
+def _nemotron():
+    from paddle_tpu.models.nemotron_h import (NemotronHForCausalLM,
+                                              nemotron_h_tiny_config)
+    paddle.seed(0)
+    m = NemotronHForCausalLM(nemotron_h_tiny_config(
+        max_position_embeddings=64))
+    m.eval()
+    return m
+
+
 #: family -> names its unified step must show
 FAMILIES = {
     "llama": {"ffn"}, "moe": {"routed_ffn", "shared_expert"},
     "mla": {"routed_ffn"}, "gpt": {"ffn"},
     "laguna": {"routed_ffn", "shared_expert"}, "eva": {"ffn"},
     "looped": {"ffn", "loop_norm"},
+    "hybrid": {"routed_ffn", "shared_expert"},
 }
 EVERY_STEP = {"embed", "attn_norm", "qkv_proj", "cache_write", "attention",
               "attn_out", "ffn_norm", "head"}
@@ -285,7 +313,8 @@ EVERY_STEP = {"embed", "attn_norm", "qkv_proj", "cache_write", "attention",
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_every_op_of_a_serving_step_answers_to_a_name(family):
     m = _laguna() if family == "laguna" else _eva() if family == "eva" \
-        else _ouro() if family == "looped" else _tiny(family)
+        else _ouro() if family == "looped" \
+        else _nemotron() if family == "hybrid" else _tiny(family)
     kw = dict(max_slots=3, page_size=8, max_context=256, prefill_chunk=8,
               num_pages=64) if family == "eva" else \
         dict(max_slots=2, page_size=8, max_context=64, prefill_chunk=8)

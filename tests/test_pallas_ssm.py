@@ -1,0 +1,130 @@
+"""The state-space kernels (`paddle_tpu.ops.pallas_ssm`), interpreted,
+against the recurrence token by token: one step for the live slots in
+place (idle slots bit-unchanged), the chunk's scan at chunk lengths
+around the scan chunk (1, 127, 128, 129, 256 rows), `dt = 0` padding as
+the identity, and one slot put in place."""
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.ops.oracles import oracles, resolve_reference
+from paddle_tpu.ops.pallas_ssm import (ssm_chunk_scan, ssm_state_put,
+                                       ssm_state_update)
+from paddle_tpu.ops.references import \
+    ssm_recurrence_reference as ssm_recurrence
+
+H, P, G, N = 8, 16, 2, 16
+
+
+def _rows(rng, L):
+    """(xdt [L, H, P], dA [L, H] <= 0, B, C [L, G, N])."""
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.3), (L, H)))
+    A = -rng.uniform(1, 16, H)
+    return (jnp.asarray(rng.normal(0, 1, (L, H, P)) * dt[..., None],
+                        jnp.float32),
+            jnp.asarray(dt * A, jnp.float32),
+            jnp.asarray(rng.normal(0, 1, (L, G, N)), jnp.float32),
+            jnp.asarray(rng.normal(0, 1, (L, G, N)), jnp.float32))
+
+
+class TestChunkScan:
+    @pytest.mark.parametrize("L", [1, 127, 128, 129, 256])
+    def test_the_scan_is_the_recurrence(self, L):
+        rng = np.random.default_rng(L)
+        rows = _rows(rng, L)
+        s0 = jnp.asarray(rng.normal(0, 1, (P, N, H)), jnp.float32)
+        want_y, want_s = ssm_recurrence(*rows, s0)
+        got_y, got_s = ssm_chunk_scan(*rows, s0, chunk=128)
+        assert got_y.shape == (L, H, P) and got_s.shape == (P, N, H)
+        np.testing.assert_allclose(got_y, want_y, atol=2e-4, rtol=2e-4)
+        np.testing.assert_allclose(got_s, want_s, atol=2e-4, rtol=2e-4)
+
+    def test_rows_with_dt_zero_change_nothing(self):
+        """A chunk of 37 valid rows padded to 64: the padding's xdt and
+        dA are 0, the state is that of the 37 rows alone."""
+        rng = np.random.default_rng(5)
+        xdt, dA, bm, cm = _rows(rng, 64)
+        valid = (np.arange(64) < 37)
+        pad = (jnp.where(valid[:, None, None], xdt, 0),
+               jnp.where(valid[:, None], dA, 0), bm, cm)
+        s0 = jnp.asarray(rng.normal(0, 1, (P, N, H)), jnp.float32)
+        y, s = ssm_chunk_scan(*pad, s0, chunk=16)
+        want_y, want_s = ssm_recurrence(*(a[:37] for a in pad), s0)
+        np.testing.assert_allclose(s, want_s, atol=2e-4, rtol=2e-4)
+        np.testing.assert_allclose(y[:37], want_y, atol=2e-4, rtol=2e-4)
+
+    def test_two_chunks_carry_the_state(self):
+        rng = np.random.default_rng(6)
+        rows = _rows(rng, 48)
+        s0 = jnp.zeros((P, N, H), jnp.float32)
+        y1, s1 = ssm_chunk_scan(*(a[:20] for a in rows), s0, chunk=8)
+        y2, s2 = ssm_chunk_scan(*(a[20:] for a in rows), s1, chunk=8)
+        want_y, want_s = ssm_recurrence(*rows, s0)
+        np.testing.assert_allclose(jnp.concatenate([y1, y2]), want_y,
+                                   atol=2e-4, rtol=2e-4)
+        np.testing.assert_allclose(s2, want_s, atol=2e-4, rtol=2e-4)
+
+
+def _update_operands(rng, NS):
+    xdt, dA, bm, cm = _rows(rng, NS)
+    expand = lambda m: jnp.repeat(m, H // G, axis=1).swapaxes(1, 2)  # noqa
+    return (xdt.swapaxes(1, 2), jnp.exp(dA)[:, None, :], expand(bm),
+            expand(cm)), (xdt, dA, bm, cm)
+
+
+class TestStateUpdate:
+    @pytest.mark.parametrize("live", [[], [2], [0, 3, 1], [4, 0, 1, 2, 3]])
+    def test_one_step_for_the_live_slots_in_place(self, live):
+        """5 slots + the spare; P = 32 so that a slot is two grid steps
+        of 16."""
+        rng = np.random.default_rng(len(live))
+        NS, B = 6, 5
+        pool = jnp.asarray(rng.normal(0, 1, (NS, 32, N, H)), jnp.float32)
+        dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.3), (NS, H)))
+        xdt = jnp.asarray(rng.normal(0, 1, (NS, 32, H)), jnp.float32)
+        dec = jnp.asarray(np.exp(-dt * 3.0)[:, None, :], jnp.float32)
+        bh = jnp.asarray(rng.normal(0, 1, (NS, N, H)), jnp.float32)
+        ch = jnp.asarray(rng.normal(0, 1, (NS, N, H)), jnp.float32)
+        slots = np.full(B, NS - 1, np.int32)
+        slots[:len(live)] = live
+        args = (pool, jnp.asarray(slots),
+                jnp.asarray([len(live)], jnp.int32), xdt, dec, bh, ch)
+        entry = oracles()["ssm_state_update"]
+        want_y, want_pool = resolve_reference(entry)(*args)
+        got_y, got_pool = ssm_state_update(*args)
+        # idle slots (and the spare) come back bit for bit
+        idle = [s for s in range(NS) if s not in live]
+        np.testing.assert_array_equal(np.asarray(got_pool)[idle],
+                                      np.asarray(pool)[idle])
+        np.testing.assert_allclose(got_pool, want_pool, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(got_y)[live],
+                                   np.asarray(want_y)[live], atol=1e-4)
+
+    def test_a_step_is_the_recurrence(self):
+        rng = np.random.default_rng(9)
+        NS = 4
+        ops, (xdt, dA, bm, cm) = _update_operands(rng, NS)
+        pool = jnp.asarray(rng.normal(0, 1, (NS, P, N, H)), jnp.float32)
+        y, new = ssm_state_update(
+            pool, jnp.asarray([1, 2, 3], jnp.int32),
+            jnp.asarray([2], jnp.int32), *ops)
+        for s in (1, 2):
+            want_y, want_s = ssm_recurrence(
+                xdt[s:s + 1], dA[s:s + 1], bm[s:s + 1], cm[s:s + 1], pool[s])
+            np.testing.assert_allclose(new[s], want_s, atol=1e-5)
+            np.testing.assert_allclose(y[s].T, want_y[0], atol=1e-4)
+        np.testing.assert_array_equal(new[3], pool[3])
+        np.testing.assert_array_equal(new[0], pool[0])
+
+
+class TestStatePut:
+    @pytest.mark.parametrize("slot, go", [(0, 1), (2, 1), (1, 0)])
+    def test_one_slot_in_place(self, slot, go):
+        rng = np.random.default_rng(slot)
+        pool = jnp.asarray(rng.normal(0, 1, (3, 32, N, H)), jnp.float32)
+        state = jnp.asarray(rng.normal(0, 1, (32, N, H)), jnp.float32)
+        args = (pool, jnp.asarray([slot, go], jnp.int32), state)
+        want = resolve_reference(oracles()["ssm_state_put"])(*args)
+        np.testing.assert_array_equal(ssm_state_put(*args), want)
+        assert bool((want[slot] == state).all()) == bool(go)

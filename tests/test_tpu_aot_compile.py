@@ -47,7 +47,8 @@ def chip():
     from jax.experimental.compilation_cache import compilation_cache as cc
     from jax.sharding import SingleDeviceSharding
     from paddle_tpu.ops import (fused, pallas_flash, pallas_megadecode,
-                                pallas_megafront, pallas_ragged, quant)
+                                pallas_megafront, pallas_ragged, pallas_ssm,
+                                quant)
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
@@ -58,7 +59,7 @@ def chip():
     one = SingleDeviceSharding(topo.devices[0])
     mp = pytest.MonkeyPatch()
     for mod in (fused, pallas_flash, pallas_megadecode, pallas_megafront,
-                pallas_ragged, quant):
+                pallas_ragged, pallas_ssm, quant):
         mp.setattr(mod, "_interpret", lambda: False)
     # a described-device executable is written to the persistent cache
     # but cannot be read back without a chip: keep it off around these
@@ -361,6 +362,31 @@ def test_chunk_summary_kernels_compile_at_the_evabyte_cell_shapes(chip):
         _eva_layer_kernels, tok, tok, tok, trig, trig, pool, pool, vec, vec,
         seq, seq, seq, seq, chip.shape((s, nj), I32), row, row, slots,
         slots), chip.refusals.get(_eva_layer_kernels)
+
+
+def _ssm_layer_kernels(pool, tab, xdt, dec, bh, ch, xc, dac, bc, cc):
+    from paddle_tpu.ops.pallas_ssm import (ssm_chunk_scan, ssm_state_put,
+                                           ssm_state_update)
+    b = tab.shape[0] - 3
+    y, pool = ssm_state_update(pool, tab[:b], tab[b:b + 1], xdt, dec, bh, ch)
+    yc, s1 = ssm_chunk_scan(xc, dac, bc, cc, pool[tab[b + 1]], chunk=128)
+    return y, yc, ssm_state_put(pool, tab[b + 1:], s1)
+
+
+def test_state_space_kernels_compile_at_the_nemotron_cell_shapes(chip):
+    """`nemotron-3-super-serve-ep4-d11` as its cell runs it: a state pool
+    of 128 slots + the spare x [64, 128, 128] float32 (heads minor), the
+    decode rows' update in place, a 256-row chunk's scan in two scan
+    chunks of 128 (128 heads x 64 in 8 groups, state 128) and its
+    state's write in place."""
+    ns, p, n, h, g, c = 129, 64, 128, 128, 8, 256
+    assert chip.compiles(
+        _ssm_layer_kernels, chip.shape((ns, p, n, h), F32),
+        chip.shape((ns + 2,), I32), chip.shape((ns, p, h), F32),
+        chip.shape((ns, 1, h), F32), chip.shape((ns, n, h)),
+        chip.shape((ns, n, h)), chip.shape((c, h, p), F32),
+        chip.shape((c, h), F32), chip.shape((c, g, n)),
+        chip.shape((c, g, n))), chip.refusals.get(_ssm_layer_kernels)
 
 
 def _serve_norm_and_linears(x, nw, w8, s8, w4, s4):
