@@ -254,15 +254,16 @@ CANONICAL: Dict[str, Dict[str, Any]] = {
         any_inputs=(1, 2),
         token_tiled=False,
     ),
-    # one tile of TQ = 8 tokens (rows = TQ * rep) for a block of all 8
-    # KV heads (hb: `ragged_head_block` takes every head wherever the
-    # cell's VMEM allows, 16 at most); K/V stay in HBM behind the
-    # kernel's own page DMAs, as in paged v2; depth = the ring's slots
-    # (`_page_buffers`: 2 at serving page sizes, one block in flight
-    # beside the one computed)
+    # one cell of one tile (tb) of TQ = 8 tokens (rows = TQ * rep) for a
+    # block of all 8 KV heads (hb: `ragged_head_block` takes every head
+    # wherever the cell's VMEM allows, 16 at most; `ragged_tile_block`
+    # gives a cell several tiles only where it serves ONE head); K/V
+    # stay in HBM behind the kernel's own page DMAs, as in paged v2;
+    # depth = the ring's slots (`_page_buffers`: 2 at serving page
+    # sizes, one block in flight beside the one computed)
     "ragged_paged_attention": dict(
         kernel="ragged_paged_attention",
-        bindings=dict(KV=8, hb=8, n_tiles=1, rows=32, D=128, psz=32,
+        bindings=dict(KV=8, hb=8, tb=1, n_cells=1, rows=32, D=128, psz=32,
                       depth=2),
         in_widths=[2, 2, 2], out_widths=[2],
         cost_kwargs=dict(T=8, H=32, KV=8, D=128, S=8, pages_per_seq=8,
@@ -276,12 +277,18 @@ CANONICAL: Dict[str, Dict[str, Any]] = {
         # changes which pages a tile walks, not what it holds); the
         # looped decoder (Ouro: 16 KV heads of one query head, pages of
         # 64); chunk-summary attention (EvaByte: 32 KV heads of one
-        # query head, blocks of 16 — pooled rows ride in the same pages)
+        # query head, blocks of 16 — pooled rows ride in the same pages);
+        # latent attention (A.X-K1: 64 query heads over ONE row of 640
+        # columns, 8 tiles of 2 tokens a cell; priced at this site's
+        # specs, so with a [rows, D] output and a V ring its own launch,
+        # `_latent_call`, goes without)
         families={"llama": dict(KV=8, hb=8, rows=128, D=128, psz=256),
                   "laguna": dict(KV=8, hb=8, rows=144, D=128, psz=256),
                   "looped": dict(KV=16, hb=16, rows=128, D=128, psz=64),
                   "chunk_summary": dict(KV=32, hb=16, rows=128, D=128,
-                                        psz=256)},
+                                        psz=256),
+                  "latent": dict(KV=1, hb=1, tb=8, rows=128, D=640,
+                                 psz=256)},
     ),
     "mla_decode_attention": dict(
         kernel="mla_decode_attention",

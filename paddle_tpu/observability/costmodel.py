@@ -402,27 +402,42 @@ def _ragged_tile_tokens(T: int, rep: int, dtype_bytes: int) -> int:
     return min(tq, _ceil_div(T, unit) * unit)
 
 
+def _ragged_tile_block(KV: int, T: int, rep: int, tq: int) -> int:
+    """ops/pallas_ragged.ragged_tile_block where the cell's VMEM does
+    not bind (tests/test_costmodel.py holds the two equal at the serving
+    cells' shapes): a visit that serves ONE KV head serves the largest
+    power of two of query tiles up to 8 that the launch has; a block of
+    KV heads, one tile."""
+    tb, tiles = 1, _ceil_div(T, tq)
+    while KV == 1 and 2 * tb <= min(8, tiles):
+        tb *= 2
+    return tb
+
+
 @register_cost("ragged_paged_attention")
 def _c_ragged(*, T: int, H: int, KV: int, D: int, S: int,
               pages_per_seq: int, page_size: int,
               dtype_bytes: int = 2,
               window: Optional[int] = None) -> CostEstimate:
-    """Ragged mixed prefill+decode, grid (KV / hb, tiles of TQ tokens):
-    each cell reads the [TQ*rep, D] query tile of each of its hb KV
-    heads and writes their output tiles (the rows that pad T up to
-    whole tiles are not counted); the pools stay in HBM and a cell DMAs,
-    for every sequence with rows in its tile, the pages up to the tile's
-    causal limit, each page for its hb heads at once. The head block
-    (`ops/pallas_ragged.ragged_head_block`) changes how many DMAs bring
-    the bytes, not the bytes: nothing below depends on it. Stated for the
-    heaviest launch of these shapes: every table full and the T rows
-    spread evenly over the S sequences, so a sequence's pages cross once
-    for each tile its rows span (once for a decode batch, T == S). With
-    a sliding `window` a tile walks only the pages between the oldest
-    key its first row sees and its last row: at most those spanned by
-    `window - 1` old positions and the tile's own, on any page grid."""
+    """Ragged mixed prefill+decode, grid (KV / hb, cells of tb tiles of
+    TQ tokens): each cell reads the [tb*TQ*rep, D] query rows of each
+    of its hb KV heads and writes their output rows (the rows that pad
+    T up to whole cells are not counted); the pools stay in HBM and a
+    cell DMAs, for every sequence with rows in it, the pages up to the
+    cell's causal limit, each page for its hb heads and tb tiles at
+    once. The head block (`ops/pallas_ragged.ragged_head_block`) changes
+    how many DMAs bring the bytes, not the bytes; the tile block
+    (`ragged_tile_block`: several tiles a cell where there is ONE KV
+    head) changes the bytes: a sequence's pages cross once for each
+    CELL its rows span. Stated for the heaviest launch of these shapes:
+    every table full and the T rows spread evenly over the S sequences
+    (once for a decode batch, T == S). With a sliding `window` a cell
+    walks only the pages between the oldest key its first row sees and
+    its last row: at most those spanned by `window - 1` old positions
+    and the cell's own, on any page grid."""
     rep = H // KV
     tq = _ragged_tile_tokens(T, rep, dtype_bytes)
+    tq *= _ragged_tile_block(KV, T, rep, tq)
     per_seq = _ceil_div(T, S)
     spans = _ceil_div(per_seq, tq)
     pages, ctx = pages_per_seq, pages_per_seq * page_size

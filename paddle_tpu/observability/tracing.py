@@ -128,9 +128,12 @@ STEP_COUNTS_MOE: Tuple[str, ...] = (
     "moe_expert_rows_mean", "moe_experts_hit")
 #: ... and where the cache holds latent attention's rows: the prefill
 #: chunk's KV length after the step (0 without a chunk: what the chunk's
-#: query tiles each walk), and the bytes a token a layer as STORED (the
-#: row's lanes, padding included)
-STEP_COUNTS_LATENT: Tuple[str, ...] = ("chunk_kv_len", "latent_row_bytes")
+#: query tiles each walk), the bytes a token a layer as STORED (the
+#: row's lanes, padding included), and the (query tile, page) softmax
+#: updates the kernel computes in one layer: `pages_visited` counts a
+#: visit once for the block of tiles it serves, this every tile served
+STEP_COUNTS_LATENT: Tuple[str, ...] = ("chunk_kv_len", "latent_row_bytes",
+                                       "attn_tile_chains")
 #: ... and where every layer is chunk-summary (EVA) attention, whose
 #: cache is two lists of rows from one pool. Of the launch (the first
 #: four add up): the pooled and the exact rows its queries' sequences

@@ -25,31 +25,40 @@ pages included).
 
 Blocking: the flat buffer is cut into static TILES of TQ tokens
 (`ragged_tile_tokens`: TQ*rep query rows of one KV head, a multiple of
-the dtype's sublane packing; T is padded up to whole tiles). The work
-is a list of (tile, sequence) PAIRS — a sequence with rows in the tile
-— each with the number of pages the tile walks for it: the pages up to
-the causal limit of the sequence's LAST row inside the tile, never more
-than its live pages. `_tile_pages` computes that table for the kernel
-(in XLA, once a step: the per-layer calls are identical and merge) and
-for the engine's `pages_visited` counter (`ragged_pages_visited`), so
-the two cannot drift. Grid (KV / hb, tiles): a cell owns the
-[TQ*rep, D] query tiles of a BLOCK of hb KV heads (`ragged_head_block`,
-read from the shapes) and their output tiles; the K/V pools stay in HBM
-and the cell walks its pairs' pages, each page visit ONE K and ONE V DMA
-of the page as all hb heads hold it (a strided copy out of the
-[KV, pages, psz, D] pools) into a ring of `_page_buffers` slots. What a
-visit costs whatever the page holds — the work-list reads, the slot
-arithmetic, the read-ahead cursor, the DMA starts and waits, the mask —
-is paid once a visit; the hb heads' softmax updates are hb independent
-chains in the one loop body. The DMAs run ahead of the compute along
-the block's FLAT walk, across pair and tile boundaries (the read-ahead
-cursor is carried from cell to cell in SMEM). No grid step, DMA or
-branch exists for a dead (sequence, page) entry, and a page meets only
-the TQ*rep rows of a tile that holds rows of its sequence. Rows of
-OTHER sequences in that tile are masked (s = _MASKED -> p = 0, and m,
-l, acc untouched), so the per-row online-softmax state lets sequences
-share a tile. GQA-native, f32 scores / softmax state / accumulator,
-interpret mode off-TPU.
+the dtype's sublane packing) and a grid CELL owns a block of tb of them
+(`ragged_tile_block`, read from the shapes: one, but where a page visit
+serves ONE KV head — latent attention's 64 query heads over one row are
+tiles of 2 tokens, and 8 of them a cell; T is padded up to whole
+cells). The work is a list of (cell, sequence) PAIRS — a sequence with
+rows in the cell — each with the number of pages the cell walks for it:
+the pages up to the causal limit of the sequence's LAST row inside the
+cell, never more than its live pages. `_tile_pages` computes that table
+for the kernel (in XLA, once a step: the per-layer calls are identical
+and merge) and for the engine's `pages_visited` counter
+(`ragged_pages_visited`), so the two cannot drift. Grid (KV / hb,
+cells): a cell owns the tb [TQ*rep, D] query tiles of a BLOCK of hb KV
+heads (`ragged_head_block`, read from the shapes) and their output
+tiles; the K/V pools stay in HBM and the cell walks its pairs' pages,
+each page visit ONE K and ONE V DMA of the page as all hb heads hold it
+(a strided copy out of the [KV, pages, psz, D] pools) into a ring of
+`_page_buffers` slots. What a visit costs whatever the page holds — the
+work-list reads, the slot arithmetic, the read-ahead cursor, the DMA
+starts and waits — is paid once a visit; the hb heads' and tb tiles'
+softmax updates are hb x tb independent chains in the one loop body,
+each under its tile's mask. A visit serves only the tiles whose OWN walk
+(`_walk`, the work list's rule on the tile's tokens) holds the page: a
+decode row's pages meet the one tile it lives in, and where every tile
+of the cell is served — a chunk's cells but its first and last — the
+tiles' query rows go through the two matmuls together, the page being
+the MXU's stationary operand once for all of them. The DMAs run ahead of
+the compute along the block's FLAT walk, across pair and cell boundaries
+(the read-ahead cursor is carried from cell to cell in SMEM). No grid
+step, DMA or branch exists for a dead (sequence, page) entry, and a page
+meets only the TQ*rep rows of a tile that holds rows of its sequence.
+Rows of OTHER sequences in that tile are masked (s = _MASKED -> p = 0,
+and m, l, acc untouched), so the per-row online-softmax state lets
+sequences share a tile. GQA-native, f32 scores / softmax state /
+accumulator, interpret mode off-TPU.
 """
 
 from __future__ import annotations
@@ -69,7 +78,8 @@ from .pallas_paged import paged_kernel_eligible
 
 __all__ = ["ragged_paged_attention", "ragged_attention_reference",
            "ragged_kernel_eligible", "ragged_tile_tokens",
-           "ragged_head_block", "ragged_pages_visited"]
+           "ragged_head_block", "ragged_tile_block",
+           "ragged_pages_visited"]
 
 _NEG = -1e30
 _MASKED = -3e38
@@ -87,6 +97,9 @@ _VMEM_BUDGET = 10 * 1024 * 1024
 #: KV heads a page visit serves at most: PERF.md (PR 42) has the sweep
 #: on a v5e
 _HEAD_BLOCK_MAX = 16
+#: query tiles a page visit serves at most, where it serves one KV
+#: head: PERF.md (PR 44) has the sweep on a v5e
+_TILE_BLOCK_MAX = 8
 
 
 def _interpret() -> bool:
@@ -124,15 +137,20 @@ def _page_buffers(block_bytes: int) -> int:
     return 1 + min(7, max(1, _BYTES_IN_FLIGHT // (2 * block_bytes)))
 
 
-def _block_vmem(hb: int, rows: int, D: int, psz: int, itemsize: int) -> int:
-    """VMEM bytes of a grid cell that serves `hb` KV heads: the query
-    and output tiles (double-buffered by the pipeline), the f32
-    accumulator, m and l (a [rows, 1] column takes whole 128-lane
-    tiles), the K and the V ring."""
-    tile = hb * rows * D
-    state = tile * 4 + 2 * hb * rows * 128 * 4
+def _block_vmem(hb: int, rows: int, D: int, psz: int, itemsize: int,
+                tb: int = 1, v_dim: Optional[int] = None) -> int:
+    """VMEM bytes of a grid cell that serves `hb` KV heads and `tb`
+    query tiles: the query and output tiles (double-buffered by the
+    pipeline), the f32 accumulator, m and l (a [rows, 1] column takes
+    whole 128-lane tiles), the K and the V ring. Pages that hold K and
+    V in one row (`v_dim`) have ONE ring, and an output and an
+    accumulator of `v_dim` columns."""
+    q, o = hb * tb * rows * D, hb * tb * rows * (v_dim or D)
+    state = o * 4 + 2 * hb * tb * rows * 128 * 4
     block = hb * psz * D * itemsize
-    return 4 * tile * itemsize + state + 2 * _page_buffers(block) * block
+    rings = 1 if v_dim else 2
+    return (2 * (q + o) * itemsize + state
+            + rings * _page_buffers(block) * block)
 
 
 def ragged_head_block(KV: int, rows: int, D: int, psz: int, itemsize: int,
@@ -148,19 +166,37 @@ def ragged_head_block(KV: int, rows: int, D: int, psz: int, itemsize: int,
                           hb, rows, D, psz, itemsize) <= _VMEM_BUDGET])
 
 
-def _tile_pages(xp, seq_start, num_tokens, kv_lengths, *, tq, n_tiles,
-                page_size, pages_per_seq, window=None):
-    """[n_tiles, S] int32: the K/V pages tile t walks for sequence i —
-    pages 0 .. the causal limit of the sequence's last row in the tile;
-    0 where the sequence has no row there. `xp` is numpy (the counter)
-    or jax.numpy (the kernel's work list). With a `window` the walk
-    starts at the page of the oldest key the tile's FIRST row of the
-    sequence still sees (position - window + 1), and the result is the
-    pair (pages walked, first page)."""
-    lo = (xp.arange(n_tiles, dtype=xp.int32) * tq)[:, None]
-    first = xp.maximum(seq_start[None, :], lo)
-    last = xp.minimum((seq_start + num_tokens)[None, :], lo + tq) - 1
-    base = (kv_lengths - num_tokens - seq_start)[None, :]
+def ragged_tile_block(hb: int, tiles: int, rows: int, D: int, psz: int,
+                      itemsize: int, v_dim: Optional[int] = None) -> int:
+    """tb, the query tiles one page visit serves: 1 where the visit
+    already serves a block of KV heads (`hb` > 1); where it serves one
+    head (latent attention's one row for every query head), the largest
+    power of two, no more than _TILE_BLOCK_MAX, than the chains a visit
+    carries at most (_HEAD_BLOCK_MAX) and than the launch's `tiles`,
+    whose cell (`_block_vmem`) fits _VMEM_BUDGET."""
+    if hb > 1:
+        return 1
+    tb = 1
+    while 2 * tb <= min(_TILE_BLOCK_MAX, _HEAD_BLOCK_MAX, tiles) \
+            and _block_vmem(hb, rows, D, psz, itemsize, 2 * tb,
+                            v_dim) <= _VMEM_BUDGET:
+        tb *= 2
+    return tb
+
+
+def _walk(xp, lo, seq_start, num_tokens, kv_lengths, *, tq, page_size,
+          pages_per_seq, window=None, lift=lambda x: x):
+    """The K/V pages the `tq` flat rows from `lo` walk for a sequence —
+    pages 0 .. the causal limit of the sequence's last row among them;
+    0 where it has no row there. With a `window` the walk starts at the
+    page of the oldest key the FIRST such row still sees (position -
+    window + 1), and the result is the pair (pages walked, first page).
+    The ONE rule of the work list (`_tile_pages`: arrays, `lift` lays a
+    sequence's values against the tiles') and of the kernel's own test
+    of which of a cell's tiles a page meets (scalars)."""
+    first = xp.maximum(lift(seq_start), lo)
+    last = xp.minimum(lift(seq_start + num_tokens), lo + tq) - 1
+    base = lift(kv_lengths - num_tokens - seq_start)
     pages = xp.clip((base + last) // page_size + 1, 0, pages_per_seq)
     if window is None:
         return xp.where(last >= first, pages, 0).astype(xp.int32)
@@ -171,14 +207,27 @@ def _tile_pages(xp, seq_start, num_tokens, kv_lengths, *, tq, n_tiles,
             xp.where(live, start, 0).astype(xp.int32))
 
 
+def _tile_pages(xp, seq_start, num_tokens, kv_lengths, *, tq, n_tiles,
+                **walk):
+    """[n_tiles, S] int32: `_walk` of tile t (`tq` tokens: a grid cell's,
+    where it owns a block of tiles) for sequence i. `xp` is numpy (the
+    counter) or jax.numpy (the kernel's work list)."""
+    lo = (xp.arange(n_tiles, dtype=xp.int32) * tq)[:, None]
+    return _walk(xp, lo, seq_start, num_tokens, kv_lengths, tq=tq,
+                 lift=lambda x: x[None, :], **walk)
+
+
 def ragged_pages_visited(seq_start, num_tokens, kv_lengths, *, T: int,
                          rep: int, dtype, page_size: int,
                          pages_per_seq: int,
-                         window: Optional[int] = None) -> int:
+                         window: Optional[int] = None, tb: int = 1) -> int:
     """K/V page fetches PER KV HEAD that `ragged_paged_attention` makes
     for this launch (host-side numpy, the engine's `pages_visited`): the
-    sum over tiles of the pages each tile walks."""
-    tq = ragged_tile_tokens(T, rep, dtype)
+    sum over grid cells of `tb` tiles (`ragged_tile_block`) of the pages
+    each walks. At `tb` 1 whatever the launch's: the (tile, page) softmax
+    updates it computes, since a visit serves only the tiles whose own
+    walk holds the page."""
+    tq = tb * ragged_tile_tokens(T, rep, dtype)
     pages = _tile_pages(
         np, np.asarray(seq_start, np.int32),
         np.asarray(num_tokens, np.int32), np.asarray(kv_lengths, np.int32),
@@ -247,23 +296,36 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
                    q_ref, k_hbm, v_hbm, o_ref,
                    kbuf, vbuf, acc_ref, m_ref, l_ref, ahead_ref, sem,
                    *, page_size, rep, tq, total_pages, scale, window,
-                   summary=False):
+                   summary=False, tb=1):
     h = pl.program_id(0)
     t = pl.program_id(1)
     n_pairs = first_ref[pl.num_programs(1)]
     depth = kbuf.shape[0]
-    # the cell's block of KV heads: an axis of the ring and of the f32
-    # state, which the launch of one-row pages (one KV head) goes without
+    # the cell's block of KV heads: an axis of the ring and, with the
+    # cell's block of `tb` query tiles (`rows` query rows each, one
+    # after the other in the q and output blocks), of the f32 state:
+    # [hb x tb chains, rows, ..]. The launch of one-row pages (one KV
+    # head) goes without it where a cell is one tile
     hb = q_ref.shape[0]
-    blocked = acc_ref.ndim == 3
+    blocked = kbuf.ndim == 4
     heads = pl.ds(h * hb, hb) if blocked else h
+    rows = q_ref.shape[1] // tb
     # a windowed launch's pair table is twice as long: pair pi's first
     # page sits `pairs` entries after its sequence
     pairs = pseq_ref.shape[0] // 2
 
     def part(g):
-        """The index of head g's part of a ring slot or of the state."""
+        """The index of head g's part of a ring slot."""
         return (g, ...) if blocked else (...,)
+
+    def chain(g, b):
+        """The index of the f32 state of head g's tile b."""
+        return (g * tb + b, ...) if acc_ref.ndim == 3 else (...,)
+
+    def run(g, tiles):
+        """The index of a run of tiles in head g's q or output block."""
+        return g if len(tiles) == tb else \
+            (g, pl.ds(tiles[0] * rows, len(tiles) * rows))
 
     def page_dma(pi, j):
         # page j of pair pi, as the block's heads hold it, lands in the
@@ -297,7 +359,7 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
 
     # the walk's read-ahead cursor lives across the block's grid cells:
     # depth - 1 pages fly ahead of the one being computed, whichever
-    # pair or tile they belong to
+    # pair or cell they belong to
     @pl.when(t == 0)
     def _warmup():
         ahead = (jnp.int32(0), jnp.int32(0))
@@ -308,12 +370,17 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
     acc_ref[:] = jnp.zeros_like(acc_ref)
     m_ref[:] = jnp.full_like(m_ref, _NEG)
     l_ref[:] = jnp.zeros_like(l_ref)
-    q = [q_ref[g] for g in range(hb)]                    # [TQ*rep, D] each
-    rows = q_ref.shape[1]
-    # flat token of each query row ([TQ*rep, 1]: the rep query heads of
-    # one token are adjacent rows of the KV head's group)
-    tok = t * tq + jax.lax.broadcasted_iota(
-        jnp.int32, (rows, 1), 0) // rep
+    if tb == 1:
+        q = [q_ref[g] for g in range(hb)]                # [TQ*rep, D] each
+
+    def first_token(b):
+        """The flat token tile b of the cell starts at."""
+        return t * tq if tb == 1 else (t * tb + b) * tq
+
+    # flat token of each query row of tile b ([TQ*rep, 1]: the rep query
+    # heads of one token are adjacent rows of the KV head's group)
+    tok = [first_token(b) + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, 1), 0) // rep for b in range(tb)]
     in_page = jax.lax.broadcasted_iota(
         jnp.int32, (rows, page_size), 1)
 
@@ -322,8 +389,9 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
         first_row, nt = ss_ref[i], nt_ref[i]
         # local token t of this sequence attends positions <= limit;
         # the rows of other sequences attend nothing
-        limit = jnp.where((tok >= first_row) & (tok < first_row + nt),
-                          kvl_ref[i] - nt + (tok - first_row), -1)
+        limit = [jnp.where((tk >= first_row) & (tk < first_row + nt),
+                           kvl_ref[i] - nt + (tk - first_row), -1)
+                 for tk in tok]
         page0 = 0 if window is None else pseq_ref[pairs + pi]
         if summary:
             # the sequence's KV starts with `lo` pooled rows; the rest
@@ -331,10 +399,10 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
             lo = kvl_ref[kvl_ref.shape[0] // 2 + i]
             hi = (lo + page_size - 1) // page_size * page_size
 
-        def visible(j):
-            """The keys of page j each row sees: the same for every
-            head, so one mask a visit."""
-            rel = limit - (page0 + j) * page_size
+        def visible(j, b):
+            """The keys of page j each row of tile b sees: the same for
+            every head, so one mask a tile and visit."""
+            rel = limit[b] - (page0 + j) * page_size
             seen = in_page <= rel
             if window is not None:
                 # each row's own lower bound: keys older than its window
@@ -344,35 +412,96 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
                 seen &= (in_page < lo - at) | (in_page >= hi - at)
             return seen
 
+        def update(j, slot, tiles):
+            """Page j meets the run `tiles` of the cell's tiles: for each
+            head of the block ONE scores matmul and ONE values matmul
+            over the run's query rows (the page is the MXU's stationary
+            operand once for all of them), and between the two one
+            softmax update a tile under the tile's one mask — hb x
+            len(tiles) independent chains."""
+            seen = {}
+            for g in range(hb):
+                k = kbuf[(slot, *part(g))]               # [psz, D]
+                v = k[:, :acc_ref.shape[-1]] if vbuf is None \
+                    else vbuf[(slot, *part(g))]
+                s_run = jax.lax.dot_general(
+                    q[g] if tb == 1 else q_ref[run(g, tiles)],
+                    k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                chains, p_run = [], []
+                for n, b in enumerate(tiles):
+                    at = chain(g, b)
+                    s = s_run if len(tiles) == 1 \
+                        else s_run[n * rows:(n + 1) * rows]
+                    if b not in seen:   # after the first head's scores,
+                        seen[b] = visible(j, b)     # where one head a cell
+                    # _MASKED is so far below any m (>= _NEG) that exp
+                    # gives an exact 0: a row with nothing to attend here
+                    # keeps m, l, acc
+                    s = jnp.where(seen[b], s, _MASKED)
+                    m_prev = m_ref[at]
+                    m_new = jnp.maximum(m_prev,
+                                        jnp.max(s, -1, keepdims=True))
+                    p = jnp.exp(s - m_new)
+                    alpha = jnp.exp(m_prev - m_new)
+                    l_ref[at] = l_ref[at] * alpha \
+                        + jnp.sum(p, -1, keepdims=True)
+                    chains.append((at, alpha, m_new))
+                    p_run.append(p.astype(v.dtype))
+                for n, (at, alpha, m_new) in enumerate(chains):
+                    scaled = acc_ref[at] * alpha
+                    if n == 0:  # (behind the first rescale: the order of
+                        # the one-tile launch's text, which is pinned)
+                        pv = jax.lax.dot_general(
+                            p_run[0] if len(tiles) == 1
+                            else jnp.concatenate(p_run),
+                            v, (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+                    acc_ref[at] = scaled + (
+                        pv if len(tiles) == 1
+                        else pv[n * rows:(n + 1) * rows])
+                    m_ref[at] = m_new
+
+        if tb > 1:
+            # each tile's OWN walk for the sequence, by the work list's
+            # rule: a page meets the tiles whose walk holds it (a decode
+            # row's pages ONE tile, not the cell's tb), and where that
+            # is all of them they go through the matmuls as one run
+            walks = [_walk(jnp, first_token(b), first_row, nt, kvl_ref[i],
+                           tq=tq, page_size=page_size,
+                           pages_per_seq=tab_ref.shape[1], window=window)
+                     for b in range(tb)]
+            # [first page, end) of each tile, and of all of them
+            walks = [(0, n) for n in walks] if window is None else \
+                [(start, start + n) for n, start in walks]
+            every = [functools.reduce(f, pages) for f, pages in zip(
+                (jnp.maximum, jnp.minimum), zip(*walks))]
+
+        def meets(at, walk):
+            """Whether page `at` of the sequence lies in [first, end)."""
+            inside = at < walk[1]
+            return inside if window is None else inside & (at >= walk[0])
+
         def page(j, ahead):
             ahead = fetch_ahead(*ahead)
             slot, dmas = page_dma(pi, j)
             for dma in dmas:
                 dma.wait()
-            seen = None
-            for g in range(hb):
-                at = part(g)
-                k = kbuf[(slot, *at)]                    # [psz, D]
-                v = k[:, :acc_ref.shape[-1]] if vbuf is None \
-                    else vbuf[(slot, *at)]
-                s = jax.lax.dot_general(
-                    q[g], k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                if seen is None:    # after the first head's scores,
-                    seen = visible(j)   # where one head a cell builds it
-                # _MASKED is so far below any m (>= _NEG) that exp gives
-                # an exact 0: a row with nothing to attend here keeps m,
-                # l, acc
-                s = jnp.where(seen, s, _MASKED)
-                m_prev = m_ref[at]
-                m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-                p = jnp.exp(s - m_new)
-                alpha = jnp.exp(m_prev - m_new)
-                l_ref[at] = l_ref[at] * alpha + jnp.sum(p, -1, keepdims=True)
-                acc_ref[at] = acc_ref[at] * alpha + jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                m_ref[at] = m_new
+            if tb == 1:
+                update(j, slot, (0,))
+                return ahead
+            at = page0 + j
+            whole = meets(at, every)
+
+            pl.when(whole)(
+                functools.partial(update, j, slot, tuple(range(tb))))
+
+            @pl.when(jnp.logical_not(whole))
+            def _some_tiles():
+                for b in range(tb):
+                    pl.when(meets(at, walks[b]))(
+                        functools.partial(update, j, slot, (b,)))
+
             return ahead
 
         return jax.lax.fori_loop(
@@ -383,9 +512,11 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
     ahead_ref[0], ahead_ref[1] = ahead
     # rows of no sequence kept l == 0 and acc == 0: they emit zeros
     for g in range(hb):
-        l = l_ref[part(g)]
-        o_ref[g] = (acc_ref[part(g)] / jnp.where(l == 0.0, 1.0, l)).astype(
-            o_ref.dtype)
+        for b in range(tb):
+            l = l_ref[chain(g, b)]
+            o_ref[run(g, (b,))] = (
+                acc_ref[chain(g, b)] / jnp.where(l == 0.0, 1.0, l)).astype(
+                    o_ref.dtype)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
@@ -432,9 +563,9 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     takes the innermost name).
 
     VMEM (`_block_vmem`): for each of the cell's `ragged_head_block`
-    KV heads one [TQ*rep, D] query tile and output tile (double-buffered
-    by the pipeline), that much f32 state, and `_page_buffers` K and V
-    pages."""
+    KV heads `ragged_tile_block` [TQ*rep, D] query tiles and output
+    tiles (double-buffered by the pipeline), that much f32 state, and
+    `_page_buffers` K and V pages."""
     T, H, D = q.shape
     KV, total, psz, _ = k_pages.shape
     rep = H // KV
@@ -446,35 +577,39 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
         raise ValueError("give v_pages, or v_dim for rows that hold K "
                          "and V together; not both, not neither")
     latent = v_pages is None
-    if not _launch and not (latent and isinstance(q, jax.core.Tracer)):
-        # one trace and one lowering of the launch (the heads' chains
-        # are unrolled in it) for all the layers of a step that make it,
-        # and the trace the operands' memory-space pins below need. A
-        # traced launch of one-row pages stays in line: the program it
-        # was
+    if not _launch:
+        # one trace and one lowering of the launch (the heads' and the
+        # tiles' chains are unrolled in it) for all the layers of a step
+        # that make it, and the trace the operands' memory-space pins
+        # below need
         return _launch_jit(
             q, k_pages, v_pages, seq_start, num_tokens, kv_lengths,
             page_tables, scale=float(scale), window=window, v_dim=v_dim,
             summary_rows=summary_rows, scope=scope)
     tq = ragged_tile_tokens(T, rep, q.dtype)
-    n_tiles = -(-T // tq)
-    Tp, rows = n_tiles * tq, tq * rep
+    rows = tq * rep
     itemsize = k_pages.dtype.itemsize
     hb = ragged_head_block(KV, rows, D, psz, itemsize, latent=latent)
+    tb = ragged_tile_block(hb, -(-T // tq), rows, D, psz, itemsize, v_dim)
+    # a grid cell: `tb` tiles, `cell` tokens, `tb * rows` query rows a head
+    cell = tb * tq
+    n_cells = -(-T // cell)
+    Tp = n_cells * cell
     depth = _page_buffers(hb * psz * D * itemsize)
     ss = seq_start.astype(jnp.int32)
     nt = num_tokens.astype(jnp.int32)
     kvl = kv_lengths.astype(jnp.int32)
     tiling = {} if window is None else dict(window=window)
-    work = _work_list(ss, nt, kvl, tq=tq, n_tiles=n_tiles, page_size=psz,
+    work = _work_list(ss, nt, kvl, tq=cell, n_tiles=n_cells, page_size=psz,
                       pages_per_seq=nj, **tiling)
     # [T, H, D] -> [KV, Tp*rep, D]: a KV head's flat query group (rep
-    # rows per token, token-major), cut into tiles of TQ tokens
+    # rows per token, token-major), cut into cells of `tb` tiles of TQ
+    # tokens
     qg = (jnp.pad(q, ((0, Tp - T), (0, 0), (0, 0)))
           .reshape(Tp, KV, rep, D).transpose(1, 0, 2, 3)
           .reshape(KV, Tp * rep, D))
     static = dict(page_size=psz, rep=rep, tq=tq, total_pages=total,
-                  scale=float(scale), window=window)
+                  scale=float(scale), window=window, tb=tb)
     if summary_rows is not None:
         kvl = jnp.concatenate([kvl, summary_rows.astype(jnp.int32)])
         static["summary"] = True
@@ -482,23 +617,23 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     if latent:
         with jax.named_scope(scope) if scope else contextlib.nullcontext():
             out = _latent_call(qg, k_pages, tables, v_dim, rows, depth,
-                               n_tiles, interpret, **static)
+                               n_cells, interpret, **static)
         return _ungroup(out, T, H)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,      # row tables, page tables, work list
-        grid=(KV // hb, n_tiles),
+        grid=(KV // hb, n_cells),
         in_specs=[
-            pl.BlockSpec((hb, rows, D), _tile_map),
+            pl.BlockSpec((hb, tb * rows, D), _tile_map),
             pl.BlockSpec(memory_space=pltpu.HBM),    # the pools stay put
             pl.BlockSpec(memory_space=pltpu.HBM),
         ],
-        out_specs=pl.BlockSpec((hb, rows, D), _tile_map),
+        out_specs=pl.BlockSpec((hb, tb * rows, D), _tile_map),
         scratch_shapes=[pltpu.VMEM((depth, hb, psz, D), k_pages.dtype),
                         pltpu.VMEM((depth, hb, psz, D), v_pages.dtype),
-                        pltpu.VMEM((hb, rows, D), jnp.float32),
-                        pltpu.VMEM((hb, rows, 1), jnp.float32),
-                        pltpu.VMEM((hb, rows, 1), jnp.float32),
+                        pltpu.VMEM((hb * tb, rows, D), jnp.float32),
+                        pltpu.VMEM((hb * tb, rows, 1), jnp.float32),
+                        pltpu.VMEM((hb * tb, rows, 1), jnp.float32),
                         pltpu.SMEM((2,), jnp.int32),
                         pltpu.SemaphoreType.DMA((2, depth))],
     )
@@ -531,29 +666,30 @@ def _ungroup(out, T: int, H: int):
             .reshape(Tp, H, Dv)[:T])
 
 
-def _latent_call(qg, pages, tables, v_dim, rows, depth, n_tiles, interpret,
-                 **static):
+def _latent_call(qg, pages, tables, v_dim, rows, depth, n_cells,
+                 interpret, *, tb, **static):
     """`ragged_paged_attention`'s launch for pages that hold K and V in
     one row: the same grid, work list and tile maps at a head block of
     one, so without that axis; one pool operand, one ring of page
-    buffers, a [rows, v_dim] accumulator and output."""
+    buffers, a [tb * rows, v_dim] accumulator and output."""
     KV, flat, D = qg.shape
     psz = pages.shape[2]
+    chains = (tb,) if tb > 1 else ()    # the state's axis of tiles
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
-        grid=(KV, n_tiles),
-        in_specs=[pl.BlockSpec((1, rows, D), _tile_map),
+        grid=(KV, n_cells),
+        in_specs=[pl.BlockSpec((1, tb * rows, D), _tile_map),
                   pl.BlockSpec(memory_space=pltpu.HBM)],
-        out_specs=pl.BlockSpec((1, rows, v_dim), _tile_map),
+        out_specs=pl.BlockSpec((1, tb * rows, v_dim), _tile_map),
         scratch_shapes=[pltpu.VMEM((depth, psz, D), pages.dtype),
-                        pltpu.VMEM((rows, v_dim), jnp.float32),
-                        pltpu.VMEM((rows, 1), jnp.float32),
-                        pltpu.VMEM((rows, 1), jnp.float32),
+                        pltpu.VMEM((*chains, rows, v_dim), jnp.float32),
+                        pltpu.VMEM((*chains, rows, 1), jnp.float32),
+                        pltpu.VMEM((*chains, rows, 1), jnp.float32),
                         pltpu.SMEM((2,), jnp.int32),
                         pltpu.SemaphoreType.DMA((1, depth))],
     )
     return pl.pallas_call(
-        functools.partial(_latent_kernel, **static),
+        functools.partial(_latent_kernel, tb=tb, **static),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((KV, flat, v_dim), qg.dtype),
         compiler_params=pltpu.CompilerParams(

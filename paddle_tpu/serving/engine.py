@@ -85,7 +85,8 @@ from ..ops.pallas_ssm import (ssm_chunk_scan, ssm_state_put,
 from ..ops.pallas_ragged import (ragged_head_block,
                                  ragged_kernel_eligible,
                                  ragged_paged_attention,
-                                 ragged_pages_visited, ragged_tile_tokens)
+                                 ragged_pages_visited, ragged_tile_block,
+                                 ragged_tile_tokens)
 from .block_allocator import ChunkSummaryAllocator, PageBlockAllocator
 from .handoff import (HANDOFF_BYTES, HANDOFF_PAGES, HANDOFFS,
                       KVPageHandoff)
@@ -621,15 +622,21 @@ class ServingEngine:
         # costmodel budget
         self._kv_geom = (kv, d)
         self._kv_itemsize = int(jnp.dtype(dt).itemsize)
-        # the KV heads one page visit of the ragged kernel serves, for
-        # each query group size: the kernel's own choice at the unified
-        # step's row count (`attn_block_visits`)
+        # the KV heads and the query tiles one page visit of the ragged
+        # kernel serves, for each query group size: the kernel's own
+        # choice at the unified step's row count (`attn_block_visits`,
+        # `pages_visited`)
         T = self.max_slots * (1 + self.spec_k) + self.prefill_chunk
-        self._head_block = {
-            r: ragged_head_block(
-                kv, ragged_tile_tokens(T, r, dt) * r, d, self.page_size,
-                self._kv_itemsize, latent=self._family == "mla")
-            for reps in self._kind_rep.values() for r in reps}
+        latent = self._family == "mla"
+        self._head_block, self._tile_block = {}, {}
+        for r in {r for reps in self._kind_rep.values() for r in reps}:
+            tq = ragged_tile_tokens(T, r, dt)
+            self._head_block[r] = hb = ragged_head_block(
+                kv, tq * r, d, self.page_size, self._kv_itemsize,
+                latent=latent)
+            self._tile_block[r] = ragged_tile_block(
+                hb, -(-T // tq), tq * r, d, self.page_size,
+                self._kv_itemsize, cfg.kv_lora_rank if latent else None)
         # the unit of work of the rope + append kernel
         # (`ops.fused.append_run_table`): the rows of one cache tile
         self._append_tile = append_tile(dt, self.page_size)
@@ -1119,11 +1126,14 @@ class ServingEngine:
                 if self._ledger_tokens else 0.0),
         }
         if self.ragged:
-            # KV heads a page visit of the ragged kernel serves, by
-            # layer kind (the fewest over the kind's head counts)
+            # KV heads and query tiles a page visit of the ragged kernel
+            # serves, by layer kind (the fewest over the kind's head
+            # counts)
             for k, reps in self._kind_rep.items():
                 acct["attn_head_block" + (".window" if k else "")] = \
                     float(min(self._head_block[r] for r in reps))
+                acct["attn_tile_block" + (".window" if k else "")] = \
+                    float(min(self._tile_block[r] for r in reps))
         return acct
 
     def program_cache_sizes(self) -> Dict[str, int]:
@@ -1860,24 +1870,30 @@ class ServingEngine:
                 self._append_tile)
         # pages that hold this launch's tokens, against the K/V page
         # fetches the ragged kernel makes for each KV head (a sequence's
-        # pages once for every query tile that holds rows of it); a
-        # visit brings the page for a block of KV heads at once, so the
-        # visits it makes are the fetches of all heads over the block
+        # pages once for every grid cell — a block of query tiles —
+        # that holds rows of it); a visit brings the page for a block
+        # of KV heads at once, so the visits it makes are the fetches
+        # of all heads over the block
         counts["attn_block_visits"] = 0
 
-        def visited(kind, window=None):
-            # one layer of each head count of the kind, summed
+        def visited(kind, window=None, tiles=False):
+            # one layer of each head count of the kind, summed; with
+            # `tiles` the (tile, page) softmax updates a KV head: what
+            # the fetches would be at one tile a cell
             total = 0
             for r in self._kind_rep[kind]:
                 pages = ragged_pages_visited(
                     seq_start, num_tokens, kv_lengths, T=T, rep=r,
                     dtype=self._q_dtype, page_size=ps, pages_per_seq=nj,
-                    window=window)
+                    window=window, tb=1 if tiles else self._tile_block[r])
                 total += pages
-                counts["attn_block_visits"] += \
-                    pages * self._kv_geom[0] // self._head_block[r]
+                if not tiles:
+                    counts["attn_block_visits"] += \
+                        pages * self._kv_geom[0] // self._head_block[r]
             return total
 
+        if self._family == "mla":
+            counts["attn_tile_chains"] = visited(0, tiles=True)
         if self._family == "looped":
             n_layers = len(self._p["layers"])
             counts.update({
@@ -2714,7 +2730,8 @@ class ServingEngine:
                     # fetch serves both matmuls
                     o_lat = ragged_paged_attention(
                         q_cat, pool, None, seq_start, num_tokens,
-                        kv_lengths, tables, scale=scale, v_dim=r)
+                        kv_lengths, tables, scale=scale, v_dim=r,
+                        scope="mla_attention")
                 with jax.named_scope("mla_out"):
                     o = jnp.einsum("tnr,rnv->tnv", o_lat, w_v)
                     x = x + _mm_w(o.reshape(1, T, nh * dv), L, "wo")

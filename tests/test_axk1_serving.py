@@ -157,10 +157,42 @@ class TestEngineAgainstReference:
             assert r["pages_visited"] >= r["pages_live"] > 0
             # a latent cache is ONE KV head: a visit serves it alone
             assert r["attn_block_visits"] == r["pages_visited"]
-        assert eng.hbm_accounting()["attn_head_block"] == 1
+            # ... and, the launch being one tile here, one tile of it
+            assert r["attn_tile_chains"] == r["pages_visited"]
+        assert eng.hbm_accounting()["attn_head_block"] == 1 \
+            == eng.hbm_accounting()["attn_tile_block"]
         # a chunk's context grows by the chunk until the prompt ends
         ctx = [r["chunk_kv_len"] for r in recs if r["prefill_rows"]]
         assert ctx[:4] == [16, 32, 48, 61]
+
+    def test_a_launch_of_several_tiles_visits_a_page_for_a_block_of_them(
+            self, model, served):
+        """A 64-row chunk under 4 query heads is three tiles of 32
+        tokens: the launch takes them two a cell, makes the tokens the
+        one-tile launches made, fetches a chunk's pages once a CELL and
+        still computes a decode row's pages against ONE tile."""
+        from paddle_tpu.observability import tracing
+        prompts, reqs, _, _, _ = served
+        eng = ServingEngine(model, max_slots=3, page_size=8, max_context=128,
+                            prefill_chunk=64, num_pages=40,
+                            enable_prefix_cache=False)
+        assert eng.hbm_accounting()["attn_tile_block"] == 2
+        again = [eng.add_request(p, max_new_tokens=6) for p in prompts]
+        eng.run_to_completion()
+        assert [r.tokens for r in again] == [r.tokens for r in reqs]
+        recs = [r for r in tracing.recorder().steps()[-eng.steps:]
+                if r["prefill_rows"] or r["decode_rows"]]
+        for r in recs:
+            assert r["pages_live"] <= r["pages_visited"] \
+                == r["attn_block_visits"] <= r["attn_tile_chains"]
+            if not r["prefill_rows"]:   # decode rows: their pages, once
+                assert r["attn_tile_chains"] == r["pages_live"]
+        # the 61-token prompt's chunk (rows 3..63) has rows in tiles 0
+        # and 1, which are ONE cell: 4 + 8 pages by tile, its 8 by cell
+        first = recs[0]
+        assert (first["prefill_rows"], first["decode_rows"]) == (61, 0)
+        assert (first["attn_tile_chains"], first["pages_visited"],
+                first["pages_live"]) == (12, 8, 8)
 
     def test_the_benchmarks_rehearsal_table_builds_this_family(self):
         from benchmarks.lib.harness import as_run, load_json

@@ -167,8 +167,21 @@ class TestBlockSpecConsistency:
                    for t in (8, 9, 40, 288, 2048) for r in (1, 4, 8, 16)
                    for dt, w in (("float32", 4), ("bfloat16", 2),
                                  ("int8", 1)))
-        b = dict(KV=1, hb=1, n_tiles=-(-T // tq), rows=tq * rep, psz=psz,
-                 D=D)
+        # ... and the restated tile block equal to the kernel's wherever
+        # the cell's VMEM does not bind: the serving cells' launches
+        # (A.X-K1's 64 heads over one row of 640 columns; one KV head of
+        # 128 columns under 16, 4 and 1 query heads; a block of 8 heads)
+        for t, kv, r, d, v in ((288, 1, 64, 640, 512), (288, 1, 16, 128, None),
+                               (40, 1, 4, 128, None), (8, 1, 4, 128, None),
+                               (288, 1, 1, 128, None), (288, 8, 4, 128, None)):
+            tile = pr.ragged_tile_tokens(t, r, "bfloat16")
+            hb = pr.ragged_head_block(kv, tile * r, d, 256, 2,
+                                      latent=v is not None)
+            assert cm._ragged_tile_block(kv, t, r, tile) \
+                == pr.ragged_tile_block(hb, -(-t // tile), tile * r, d, 256,
+                                        2, v)
+        b = dict(KV=1, hb=1, tb=1, n_cells=-(-T // tq), rows=tq * rep,
+                 psz=psz, D=D)
         got = km.transfer_bytes(site, b, [BF16] * 3, [BF16])
         assert got is not None and got["in"][1:] == [None, None]
         # the head block moves whole tiles of the same rows: a launch's
@@ -260,9 +273,9 @@ class TestBlockSpecConsistency:
             v1, dict(B=2, KV=1, nj=8)) == [2, 1, 8]
         rag = _one(ss, "ragged_paged_attention")
         assert km.grid_values(
-            rag, dict(KV=1, hb=1, n_tiles=3)) == [1, 3]
+            rag, dict(KV=1, hb=1, n_cells=3)) == [1, 3]
         assert km.grid_values(
-            rag, dict(KV=16, hb=8, n_tiles=3)) == [2, 3]
+            rag, dict(KV=16, hb=8, n_cells=3)) == [2, 3]
         fwd = _one(ss, "_flash_fwd_impl")
         assert km.grid_values(
             fwd, dict(B=2, H=3, nq=2, nk=4)) == [2, 3, 2, 4]

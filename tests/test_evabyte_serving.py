@@ -444,16 +444,20 @@ class TestSummaryMask:
 #: PR 36 (rope + append by cache-tile runs) re-recorded the four that
 #: call `fused_rope_append`, PR 42 (a page visit of the ragged kernel
 #: serves a block of KV heads; one jitted launch a step's layers share)
-#: the same four; `mla` (`fused_append_rows`,
-#: ONE KV head: a block of one without the axis) is PR 35's parent's
-#: text still.
+#: the same four; `mla` (`fused_append_rows`, ONE KV head: a block of
+#: one without the axis) stayed PR 35's parent's text until PR 44 (a
+#: page visit serves a block of query TILES where it serves one KV head)
+#: sent its launch through the one jitted copy as well: unrolled over 8
+#: tiles, its body cost the chip's host 10 s of a warm first step to
+#: lower once a layer. The toy launch is one tile, so its kernel is the
+#: parent's; what changed is that the step calls it.
 LOWERED_AT_PARENT = {
     "llama": "986cc43c9b47e3f14971f0fe0c3f210dd2dc22265c81c4ed897c7ed7faa1"
              "ae7b",
     "moe": "c33d0d6ae5d0be6cf546453b86a3c7853e365ad818968696a84089b4d88919"
            "98",
-    "mla": "95716f117ba052bcd4cb7ab773eb7723b3cea776fb15ead50b69783698625c"
-           "6c",
+    "mla": "51054763d6e497f87030d9b8e6328767176dbc39266a1177379b0914c9560a"
+           "ef",
     "gpt": "f92873aa0b8da9418f9152b8cc776f57a3aae5975758b7c46a949665448a6c"
            "2d",
     "laguna": "69d5c90d27c571b0f35f8827269ad4e2f773120fd1780d26692f07d15ea"

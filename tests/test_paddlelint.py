@@ -1327,8 +1327,8 @@ class TestSeededKernelDefects:
     def test_pk104_catches_bf16_accumulator(self, tmp_path):
         fresh = self._seed(
             tmp_path, self.RAGGED,
-            old="pltpu.VMEM((hb, rows, D), jnp.float32),",
-            new="pltpu.VMEM((hb, rows, D), jnp.bfloat16),")
+            old="pltpu.VMEM((hb * tb, rows, D), jnp.float32),",
+            new="pltpu.VMEM((hb * tb, rows, D), jnp.bfloat16),")
         assert fresh and {f.rule for f in fresh} == {"PK104"}
         assert fresh[0].detail.startswith("acc:")
 
@@ -2166,8 +2166,8 @@ class TestSeededMemoryDefects:
         # per-core budget
         fresh = self._seed(
             tmp_path, self.RAGGED,
-            old="pltpu.VMEM((hb, rows, D), jnp.float32),",
-            new="pltpu.VMEM((hb, rows * 4096, D), jnp.float32),")
+            old="pltpu.VMEM((hb * tb, rows, D), jnp.float32),",
+            new="pltpu.VMEM((hb * tb, rows * 4096, D), jnp.float32),")
         assert fresh and {f.rule for f in fresh} == {"PF401"}
         assert fresh[0].detail == "vmem:ragged_paged_attention"
         assert "MiB" in fresh[0].message

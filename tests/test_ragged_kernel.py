@@ -19,6 +19,7 @@ from paddle_tpu.ops.pallas_ragged import (_work_list,
                                           ragged_kernel_eligible,
                                           ragged_paged_attention,
                                           ragged_pages_visited,
+                                          ragged_tile_block,
                                           ragged_tile_tokens)
 from paddle_tpu.ops.references import rope_append_reference
 
@@ -124,6 +125,12 @@ _LAYOUTS = {
     # a window wider than every context is full causal attention
     "window_wider_than_context": dict(kv_dec=[20, 27, 6], chunk=34,
                                       kv_chunk=50, window=100),
+    # ONE KV head under 16 query heads (tiles of 8 tokens, six of them):
+    # a page visit serves a block of 4 tiles (`ragged_tile_block`, read
+    # from the shapes); the chunk starts inside a cell
+    "one_kv_head_a_block_of_tiles": dict(
+        kv_dec=[17, 33, 0, 60, 1, 25], chunk=37, kv_chunk=20 + 37,
+        chunk_row=11, T=48, H=16, KV=1),
 }
 
 
@@ -249,15 +256,27 @@ class TestRaggedKernelParity:
         T, psz, pps = q.shape[0], kp.shape[2], tab.shape[1]
         rep = q.shape[1] // kp.shape[0]
         tq = ragged_tile_tokens(T, rep, q.dtype)
+        # the tiles a grid cell owns: one, but under ONE KV head
+        tb = ragged_tile_block(
+            ragged_head_block(kp.shape[0], tq * rep, q.shape[2], psz, 4),
+            -(-T // tq), tq * rep, q.shape[2], psz, 4)
+        assert tb == (4 if name == "one_kv_head_a_block_of_tiles" else 1)
         tiling = dict(page_size=psz, pages_per_seq=pps)
         visited = ragged_pages_visited(ss, nt, kvl, T=T, rep=rep,
-                                       dtype=q.dtype, window=window,
+                                       dtype=q.dtype, window=window, tb=tb,
                                        **tiling)
         if window is not None:
             tiling["window"] = window
         tile_first, _, pair_first = _work_list(
-            ss, nt, kvl, tq=tq, n_tiles=-(-T // tq), **tiling)
+            ss, nt, kvl, tq=tb * tq, n_tiles=-(-T // (tb * tq)), **tiling)
         assert int(pair_first[tile_first[-1]]) == visited
+        # the (tile, page) softmax updates: the visits at one tile a
+        # cell; a block of tiles fetches less and computes the same
+        chains = ragged_pages_visited(ss, nt, kvl, T=T, rep=rep,
+                                      dtype=q.dtype, window=window,
+                                      **{k: v for k, v in tiling.items()
+                                         if k != "window"})
+        assert (visited < chains) if tb > 1 else (visited == chains)
         if window is not None:
             # the walk is the pages that hold a key some row of the
             # (tile, sequence) pair sees, counted row by row
@@ -556,6 +575,181 @@ class TestFusedRopeAppend:
             ref[:, int(pg[t]), int(off[t])] = np.asarray(rows)[t]
         np.testing.assert_array_equal(np.asarray(out)[:, 1:],
                                       ref[:, 1:])
+
+
+#: launches of pages that hold K and V in ONE row (latent attention: 16
+#: query heads over the one row, so tiles of 8 tokens in float32 and
+#: six of them in the 48 flat rows), whose page visits serve a BLOCK of
+#: query tiles: the `_engine_layout` keys of each
+_TILE_BLOCKS = {
+    # 8 decode rows fill tile 0; the chunk owns tiles 1-5 whole
+    "chunk_on_cell_boundaries": dict(
+        kv_dec=[17, 33, 9, 60, 1, 25, 40, 8], chunk=40, kv_chunk=24 + 40),
+    # the chunk starts inside tile 1 and ends inside tile 4: its first
+    # and last cells serve some of their tiles only, at every block
+    "chunk_starts_and_ends_mid_cell": dict(
+        kv_dec=[5, 12, 30, 2, 44, 19], chunk=27, kv_chunk=64,
+        chunk_row=11, T=48),
+    # idle slots inside the cell the decode rows share with the chunk
+    "an_empty_slot_inside_a_cell": dict(
+        kv_dec=[7, 0, 19, 0, 0, 33, 0, 4, 0, 21], chunk=31, kv_chunk=50,
+        T=48),
+    "decode_rows_only": dict(
+        kv_dec=[7, 19, 0, 64, 33, 2, 50, 0, 11, 3], chunk=0, kv_chunk=0,
+        T=48),
+}
+
+
+def _latent_layout(name):
+    """q, the one pool, the row tables of a `_TILE_BLOCKS` launch."""
+    q, kp, _, *tables = _engine_layout(H=16, KV=1, D=128, **_TILE_BLOCKS[name])
+    return q, kp, tables
+
+
+def _forced_tile_block(monkeypatch, tb):
+    monkeypatch.setattr(pallas_ragged, "ragged_tile_block",
+                        lambda *a, **k: tb)
+    # (the launch is traced once for equal shapes: trace it again)
+    pallas_ragged._launch_jit.clear_cache()
+
+
+class TestTileBlock:
+    @pytest.mark.parametrize("name", list(_TILE_BLOCKS))
+    def test_a_tile_block_is_one_tile_a_visit_bit_for_bit(
+            self, name, monkeypatch):
+        q, kp, tables = _latent_layout(name)
+        ref = ragged_attention_reference(q, kp, None, *tables, v_dim=64)
+        outs = {}
+        for tb in (1, 2, 4, 8):
+            _forced_tile_block(monkeypatch, tb)
+            outs[tb] = np.asarray(ragged_paged_attention(
+                q, kp, None, *tables, v_dim=64))
+        pallas_ragged._launch_jit.clear_cache()
+        np.testing.assert_allclose(outs[1], np.asarray(ref), atol=2e-5,
+                                   rtol=2e-5)
+        for tb in (2, 4, 8):
+            np.testing.assert_array_equal(outs[tb], outs[1], str(tb))
+
+    @pytest.mark.parametrize("hb,window", [(1, None), (2, None), (2, 13)])
+    def test_a_tile_block_under_a_head_block(self, hb, window, monkeypatch):
+        """What no launch takes from its shapes today (a block of tiles
+        is for one KV head): the body serves hb x tb chains a visit,
+        with two pools and under a window too."""
+        q, kp, vp, *tables = _engine_layout(
+            kv_dec=[17, 0, 9, 30, 5], chunk=39, kv_chunk=16 + 39, T=48,
+            chunk_row=7, H=32, KV=2, D=32)
+        monkeypatch.setattr(pallas_ragged, "ragged_head_block",
+                            lambda *a, **k: hb)
+        outs = {}
+        for tb in (1, 4):
+            _forced_tile_block(monkeypatch, tb)
+            outs[tb] = np.asarray(ragged_paged_attention(
+                q, kp, vp, *tables, window=window))
+        pallas_ragged._launch_jit.clear_cache()
+        ref = ragged_attention_reference(q, kp, vp, *tables, window=window)
+        np.testing.assert_allclose(outs[1], np.asarray(ref), atol=2e-5,
+                                   rtol=2e-5)
+        np.testing.assert_array_equal(outs[4], outs[1])
+
+    @pytest.mark.parametrize("name,KV,tiles,rows,D,psz,v_dim,want", [
+        # the configurations' launches in bfloat16 (T = 288, Ouro 272,
+        # Nemotron 384 rows): the one that serves ONE head a visit takes
+        # a block of tiles, every block of heads exactly one tile
+        ("axk1_latent", 1, 144, 128, 640, 256, 512, 8),
+        ("mistral", 8, 9, 128, 128, 256, None, 1),
+        ("laguna_full_rep6", 8, 18, 96, 128, 256, None, 1),
+        ("laguna_window_rep9", 8, 18, 144, 128, 256, None, 1),
+        ("evabyte", 32, 3, 128, 128, 256, None, 1),
+        ("ouro", 16, 3, 128, 128, 64, None, 1),
+        ("nemotron_rep16", 2, 48, 128, 128, 256, None, 1),
+        # no more tiles than the launch has, in powers of two
+        ("a_launch_of_five_tiles", 1, 5, 128, 640, 256, 512, 4),
+        ("a_launch_of_one_tile", 1, 1, 128, 640, 256, 512, 1),
+        # one KV head without the latent row (multi-query attention)
+        ("one_kv_head_two_pools", 1, 36, 128, 128, 256, None, 8),
+        # a cell that does not fit at 8 tiles: 4 (1,024-column rows)
+        ("rows_too_wide_for_eight", 1, 144, 128, 1024, 64, 1024, 4),
+    ])
+    def test_tile_block_follows_the_shapes(self, name, KV, tiles, rows, D,
+                                           psz, v_dim, want):
+        hb = ragged_head_block(KV, rows, D, psz, 2, latent=v_dim is not None)
+        tb = ragged_tile_block(hb, tiles, rows, D, psz, 2, v_dim)
+        assert tb == want and (tb == 1 or hb == 1)
+        assert tb <= min(tiles, pallas_ragged._TILE_BLOCK_MAX)
+        assert hb * tb <= pallas_ragged._HEAD_BLOCK_MAX
+        vmem = pallas_ragged._block_vmem(hb, rows, D, psz, 2, tb, v_dim)
+        # the cell's VMEM by hand: q and out rows twice, f32 state (m
+        # and l a 128-lane column each), one ring of rows that hold K
+        # and V, else two
+        block = hb * psz * D * 2
+        out = v_dim or D
+        assert vmem == (
+            2 * hb * tb * rows * (D + out) * 2
+            + hb * tb * rows * (out + 256) * 4
+            + (1 if v_dim else 2) * pallas_ragged._page_buffers(block)
+            * block)
+        if tb > 1:
+            assert vmem <= pallas_ragged._VMEM_BUDGET
+        if hb == 1 and 2 * tb <= min(tiles, pallas_ragged._TILE_BLOCK_MAX):
+            assert pallas_ragged._block_vmem(
+                hb, rows, D, psz, 2, 2 * tb, v_dim) \
+                > pallas_ragged._VMEM_BUDGET
+
+    @pytest.mark.parametrize("tb", [1, 2, 4, 8])
+    @pytest.mark.parametrize("name", list(_TILE_BLOCKS))
+    def test_visit_count_follows_the_cells(self, name, tb):
+        # the exported count at a block of tb tiles is the kernel's own
+        # work list over cells of tb tiles; the (tile, page) updates it
+        # computes are the count at ONE tile a cell whatever tb, so a
+        # decode row's are its pages, not tb x them
+        q, kp, (ss, nt, kvl, tab) = _latent_layout(name)
+        T, psz, pps = q.shape[0], kp.shape[2], tab.shape[1]
+        tq = ragged_tile_tokens(T, 16, q.dtype)
+        assert tq == 8
+        counted = dict(T=T, rep=16, dtype=q.dtype, page_size=psz,
+                       pages_per_seq=pps)
+        visited = ragged_pages_visited(ss, nt, kvl, tb=tb, **counted)
+        cell_first, _, pair_first = _work_list(
+            ss, nt, kvl, tq=tb * tq, n_tiles=-(-T // (tb * tq)),
+            page_size=psz, pages_per_seq=pps)
+        assert int(pair_first[cell_first[-1]]) == visited
+        chains = ragged_pages_visited(ss, nt, kvl, **counted)
+        live = int(np.sum(-(-np.asarray(kvl)[np.asarray(nt) > 0] // psz)))
+        assert live <= visited <= chains
+        # the decode rows' part of both: their live pages, once each
+        dec = ragged_pages_visited(ss[:-1], nt[:-1], kvl[:-1], tb=tb,
+                                   **counted)
+        assert dec == live - -(-int(kvl[-1]) // psz) == ragged_pages_visited(
+            ss[:-1], nt[:-1], kvl[:-1], **counted)
+        if int(nt[-1]):
+            # the chunk's pages cross once a CELL it has rows in: by hand
+            first, last = int(ss[-1]), int(ss[-1]) + int(nt[-1]) - 1
+            base = int(kvl[-1]) - int(nt[-1]) - first
+            want = sum(
+                (base + min(last, c + tb * tq - 1)) // psz + 1
+                for c in range(0, T, tb * tq)
+                if c <= last and c + tb * tq > first)
+            assert visited - dec == want
+            assert (visited < chains) == (tb > 1)
+
+    def test_the_kernels_tile_test_is_the_work_lists_rule(self):
+        """`_walk` on scalars (the kernel's test of which tiles of a
+        cell a page meets) against `_tile_pages` on arrays (the count),
+        windowed too."""
+        _, _, (ss, nt, kvl, _) = _latent_layout(
+            "chunk_starts_and_ends_mid_cell")
+        for window in (None, 13):
+            tiling = dict(tq=8, page_size=8, pages_per_seq=8, window=window)
+            table = pallas_ragged._tile_pages(
+                np, *(np.asarray(x) for x in (ss, nt, kvl)), n_tiles=6,
+                **tiling)
+            for t in range(6):
+                for i in range(len(ss)):
+                    got = pallas_ragged._walk(
+                        jnp, jnp.int32(t * 8), ss[i], nt[i], kvl[i], **tiling)
+                    want = table[t, i] if window is None else \
+                        (table[0][t, i], table[1][t, i])
+                    assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
 class TestRaggedJit:

@@ -106,28 +106,40 @@ class TestFootprints:
         assert fp["unresolved"] == 0
 
     @pytest.mark.parametrize(
-        "family", ["llama", "laguna", "looped", "chunk_summary"])
+        "family", ["llama", "laguna", "looped", "chunk_summary", "latent"])
     def test_the_ragged_kernels_head_blocks_fit_vmem(self, sites, family):
         # the serving cells' cells of VMEM: what the model resolves (the
-        # q and out tiles of a block of heads, double-buffered; the f32
-        # accumulator, m and l, the read-ahead cursor), the two rings it
-        # counts unresolved beside the two pools in HBM (their dtype is
-        # the pools') added by hand, and the head block in the bindings
-        # is the one the kernel takes at those shapes
+        # q and out tiles of a block of heads and of tiles, double-
+        # buffered; the f32 accumulator, m and l, the read-ahead
+        # cursor), the two rings it counts unresolved beside the two
+        # pools in HBM (their dtype is the pools') added by hand, and
+        # the head block and the tile block in the bindings are the ones
+        # the kernel takes at those shapes (latent attention's value is
+        # its row's first 512 columns)
         from paddle_tpu.ops import pallas_ragged as pr
         entry = vm.CANONICAL["ragged_paged_attention"]
         b = vm.site_bindings(entry, family)
-        hb, rows, D, psz = b["hb"], b["rows"], b["D"], b["psz"]
+        hb, tb, rows, D, psz = b["hb"], b["tb"], b["rows"], b["D"], b["psz"]
+        latent = family == "latent"
         fp = vm.site_footprint(sites["ragged_paged_attention"], entry, b)
         assert fp["unresolved"] == 4
-        tile = hb * rows * D
-        assert fp["bytes"] == (4 * tile * 2 + tile * 4 + 2 * hb * rows * 4
-                               + 2 * 4)
-        assert pr.ragged_head_block(b["KV"], rows, D, psz, 2) == hb
+        tile = hb * tb * rows * D
+        assert fp["bytes"] == (4 * tile * 2 + tile * 4
+                               + 2 * hb * tb * rows * 4 + 2 * 4)
+        assert pr.ragged_head_block(b["KV"], rows, D, psz, 2,
+                                    latent=latent) == hb
+        tiles = 144 if latent else 3    # of 2 tokens; of 128 / 96 rows
+        assert pr.ragged_tile_block(hb, tiles, rows, D, psz, 2,
+                                    512 if latent else None) == tb
+        assert (tb > 1) == latent
         assert pr._page_buffers(hb * psz * D * 2) == b["depth"]
         rings = 2 * b["depth"] * hb * psz * D * 2
-        assert fp["bytes"] + rings <= pr._block_vmem(hb, rows, D, psz, 2) \
-            <= pr._VMEM_BUDGET < vm.VMEM_BYTES_PER_CORE
+        assert fp["bytes"] + rings <= pr._block_vmem(
+            hb, rows, D, psz, 2, tb) <= pr._VMEM_BUDGET \
+            < vm.VMEM_BYTES_PER_CORE
+        if latent:      # its own launch: a [rows, 512] output, ONE ring
+            assert pr._block_vmem(hb, rows, D, psz, 2, tb, 512) \
+                < pr._block_vmem(hb, rows, D, psz, 2, tb)
 
     def test_unresolved_blocks_are_counted_not_guessed(self, sites):
         # paged_decode_attention_v2 declares two data-dtype scratch

@@ -1,17 +1,22 @@
 """The ragged attention kernel ALONE on the local chip, at the serving
-cells' launches, over the head block `hb` (PERF.md section 6, PR 42):
+cells' launches, over the head block `hb` (PERF.md section 6, PR 42)
+and the tile block `tb` (PR 44):
 
     chiprun -- python tools/ragged_head_sweep.py [--hb 1 2 4 8 16]
+    chiprun -- python tools/ragged_head_sweep.py --hb 0 --tb 1 2 4 8 \
+        --launch axk1_6k axk1_24k axk1_decode
 
 One launch shape a cell (`LAUNCHES`), its row tables drawn from a seed;
-for every `hb` that divides the cell's KV heads, `--chain` launches in
-ONE jitted program (each launch's output is the next one's query, so
-nothing merges) timed on the host's clock around `block_until_ready`,
-the least of `--repeats`. A line a (launch, hb): ms a launch, us a
-(KV head, page) visit, and whether the output equals `hb` 1's bit for
-bit. `hb` is forced by replacing `pallas_ragged.ragged_head_block` for
-the sweep only; `0` leaves the kernel's own choice. `--depth` forces
-the ring's slots the same way. Appends its lines to
+for every `hb` that divides the cell's KV heads and every `tb`,
+`--chain` launches in ONE jitted program (each launch's output is the
+next one's query, so nothing merges) timed on the host's clock around
+`block_until_ready`, the least of `--repeats`. A line a (launch, hb,
+tb): ms a launch, us a (KV head, page) visit, us a (tile, page) softmax
+update, and whether the output equals the launch's first line's bit
+for bit. `hb` and `tb` are forced by replacing
+`pallas_ragged.ragged_head_block` / `ragged_tile_block` for the sweep
+only; `0` leaves the kernel's own choice. `--depth` forces the ring's
+slots the same way. Appends its lines to
 chiprun_out/ragged_head_sweep.jsonl.
 """
 
@@ -30,7 +35,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 #: name -> the launch: T rows, q / KV heads, page size, pool pages, page
 #: table width, decode contexts (lo, hi, how many of the slots are live),
 #: the chunk's (rows, context before it), window, summary (EvaByte's
-#: pooled rows: one a 16 tokens before the open 2,048-token window)
+#: pooled rows: one a 16 tokens before the open 2,048-token window), D
+#: the row's columns and v_dim (latent attention: K and V in ONE row)
 LAUNCHES = {
     "mistral_decode": dict(T=288, H=32, KV=8, psz=256, pages=187, nj=16,
                            slots=32, live=32, ctx=(300, 750), chunk=None),
@@ -48,6 +54,17 @@ LAUNCHES = {
     "laguna_window": dict(T=288, H=72, KV=8, psz=256, pages=160, nj=128,
                           slots=32, live=32, ctx=(256, 4096),
                           chunk=(256, 20000), window=512),
+    # A.X-K1's launch as `axk1-serve-longdoc-saturated` makes it: 64
+    # query heads over one row of 640 columns, ~6 live decode rows at
+    # 4-30 k beside a 256-row chunk at the cell's mean context and at
+    # its p95 gap's; and the decode rows alone, which a block of query
+    # tiles must not slow
+    **{"axk1_" + name: dict(T=288, H=64, KV=1, D=640, v_dim=512, psz=256,
+                            pages=2049, nj=128, slots=32, live=6,
+                            ctx=(4096, 30000), chunk=chunk)
+       for name, chunk in (("6k", (256, 6800 - 256)),
+                           ("24k", (256, 24000 - 256)),
+                           ("decode", None))},
 }
 
 
@@ -91,6 +108,7 @@ def _tables(spec, rng):
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--hb", type=int, nargs="+", default=[1, 2, 4, 8, 16, 0])
+    ap.add_argument("--tb", type=int, nargs="+", default=[0])
     ap.add_argument("--depth", type=int, default=0)
     ap.add_argument("--launch", nargs="+", default=list(LAUNCHES))
     ap.add_argument("--chain", type=int, default=48)
@@ -106,40 +124,50 @@ def main():
         print("WARNING: not on a TPU; the times mean nothing",
               file=sys.stderr)
     choose, buffers = pr.ragged_head_block, pr._page_buffers
+    choose_tb = pr.ragged_tile_block
     if args.depth:
         pr._page_buffers = lambda _bytes: args.depth
     out = []
     for name in args.launch:
         spec = LAUNCHES[name]
         rng = np.random.RandomState(args.seed)
-        T, H, KV, D, psz = spec["T"], spec["H"], spec["KV"], 128, spec["psz"]
+        T, H, KV, psz = spec["T"], spec["H"], spec["KV"], spec["psz"]
+        D, v_dim = spec.get("D", 128), spec.get("v_dim")
         ss, nt, kvl, tab, sr = _tables(spec, rng)
         window = spec.get("window")
-        visits = pr.ragged_pages_visited(
-            ss, nt, kvl, T=T, rep=H // KV, dtype=jnp.bfloat16,
-            page_size=psz, pages_per_seq=spec["nj"], window=window)
+        rep = H // KV
+        rows = pr.ragged_tile_tokens(T, rep, jnp.bfloat16) * rep
+        counted = dict(T=T, rep=rep, dtype=jnp.bfloat16, page_size=psz,
+                       pages_per_seq=spec["nj"], window=window)
+        chains = pr.ragged_pages_visited(ss, nt, kvl, **counted)
         key = jax.random.PRNGKey(args.seed)
         kq, kk, kv_ = jax.random.split(key, 3)
         q = jax.random.normal(kq, (T, H, D), jnp.bfloat16)
         pool = (KV, spec["pages"], psz, D)
         kp = jax.random.normal(kk, pool, jnp.bfloat16)
-        vp = jax.random.normal(kv_, pool, jnp.bfloat16)
+        # a latent launch's output is its query with v_dim columns: the
+        # chain pads it back to the row
+        vp = None if v_dim else jax.random.normal(kv_, pool, jnp.bfloat16)
         tables = [jnp.asarray(x) for x in (ss, nt, kvl, tab)]
         summary = jnp.asarray(sr) if spec.get("summary") else None
         first = None
-        for hb in args.hb:
+        for hb, tb in ((hb, tb) for hb in args.hb for tb in args.tb):
             if hb and KV % hb:
                 continue
             pr.ragged_head_block = choose if not hb else \
                 (lambda *a, _hb=hb, **k: _hb)
+            pr.ragged_tile_block = choose_tb if not tb else \
+                (lambda *a, _tb=tb, **k: _tb)
             pr._launch_jit.clear_cache()    # equal shapes: trace again
 
             # (the pools and tables are ARGUMENTS: closed over, they
             # would be constants of the program, 0.3-2.6 GB to compile)
             def launch(q, kp, vp, tables, summary):
-                return pr.ragged_paged_attention(
+                out = pr.ragged_paged_attention(
                     q, kp, vp, *tables, window=window,
-                    summary_rows=summary)
+                    summary_rows=summary, v_dim=v_dim)
+                return out if not v_dim else jnp.pad(
+                    out, ((0, 0), (0, 0), (0, D - v_dim)))
 
             def chain(q, *rest):
                 return jax.lax.fori_loop(
@@ -157,25 +185,31 @@ def main():
                     run(q, *rest).block_until_ready()
                     times.append(time.perf_counter() - t0)
             except Exception as e:  # noqa: BLE001 - the compiler's refusal
-                print(f"{name} hb {hb}: REFUSED {str(e)[:300]!r}", flush=True)
+                print(f"{name} hb {hb} tb {tb}: REFUSED {str(e)[:300]!r}",
+                      flush=True)
                 continue
             if first is None:
                 first = one
-            used = hb or choose(
-                KV, pr.ragged_tile_tokens(T, H // KV, jnp.bfloat16)
-                * (H // KV), D, psz, 2)
+            used = hb or choose(KV, rows, D, psz, 2, latent=bool(v_dim))
+            cell = tb or choose_tb(used, -(-T * rep // rows), rows, D, psz,
+                                   2, v_dim)
+            visits = pr.ragged_pages_visited(ss, nt, kvl, tb=cell, **counted)
             ms = min(times) / args.chain * 1e3
-            rec = dict(launch=name, hb=used, forced=bool(hb),
+            rec = dict(launch=name, hb=used, tb=cell,
+                       forced=bool(hb or tb),
                        depth=args.depth, ms_a_launch=ms,
                        visits_a_head=visits,
                        us_a_head_visit=ms * 1e3 / (visits * KV),
                        us_a_block_visit=ms * 1e3 / (visits * KV // used),
+                       tile_chains_a_head=chains,
+                       us_a_tile_update=ms * 1e3 / (chains * KV),
                        equal_to_first=bool(np.array_equal(one, first)),
                        finite=bool(np.isfinite(one).all()),
                        device=jax.devices()[0].device_kind)
             out.append(rec)
             print(json.dumps(rec), flush=True)
     pr.ragged_head_block, pr._page_buffers = choose, buffers
+    pr.ragged_tile_block = choose_tb
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/ragged_head_sweep.jsonl", "a") as f:
         f.writelines(json.dumps(rec) + "\n" for rec in out)
