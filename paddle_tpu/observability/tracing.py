@@ -112,7 +112,8 @@ STEP_COUNTS: Tuple[str, ...] = (
     "decode_rows", "prefill_rows", "live", "waiting", "admitted",
     "finished", "preempted", "cow_pages", "pools_in_place", "pages_live",
     "pages_visited", "pool_pages_used", "pool_pages_total",
-    "launch_ahead", "rows_dropped", "append_runs", "attn_block_visits")
+    "launch_ahead", "rows_dropped", "append_runs", "attn_block_visits",
+    "attn_narrow_updates")
 #: more counts where a model keeps two kinds of cache (full layers and
 #: sliding-window layers; the plain `pages_*` / `pool_pages_*` are then
 #: the sum of both kinds) ...

@@ -45,7 +45,8 @@ each page visit ONE K and ONE V DMA of the page as all hb heads hold it
 work-list reads, the slot arithmetic, the read-ahead cursor, the DMA
 starts and waits — is paid once a visit; the hb heads' and tb tiles'
 softmax updates are hb x tb independent chains in the one loop body,
-each under its tile's mask. A visit serves only the tiles whose OWN walk
+each under its tile's mask, taken in three passes over the heads (all
+scores, all softmaxes, all values). A visit serves only the tiles whose OWN walk
 (`_walk`, the work list's rule on the tile's tokens) holds the page: a
 decode row's pages meet the one tile it lives in, and where every tile
 of the cell is served — a chunk's cells but its first and last — the
@@ -57,7 +58,14 @@ step, DMA or branch exists for a dead (sequence, page) entry, and a page
 meets only the TQ*rep rows of a tile that holds rows of its sequence.
 Rows of OTHER sequences in that tile are masked (s = _MASKED -> p = 0,
 and m, l, acc untouched), so the per-row online-softmax state lets
-sequences share a tile. GQA-native, f32 scores / softmax state /
+sequences share a tile — and a sequence that owns only a FEW of the
+tile's rows (a decode row's rep query heads, a short speculative run, a
+chunk's last tokens) has its pages computed on the window of
+`ragged_narrow_rows` rows that holds them (16 rows of bfloat16 for a
+decode row, on a packed row of the tile), not on the tile's 128: the
+same update on a narrower extent of q, m, l and acc, chosen once a pair
+from the row tables (`_narrow_window`, the rule `ragged_narrow_updates`
+counts with). GQA-native, f32 scores / softmax state /
 accumulator, interpret mode off-TPU.
 """
 
@@ -79,7 +87,8 @@ from .pallas_paged import paged_kernel_eligible
 __all__ = ["ragged_paged_attention", "ragged_attention_reference",
            "ragged_kernel_eligible", "ragged_tile_tokens",
            "ragged_head_block", "ragged_tile_block",
-           "ragged_pages_visited"]
+           "ragged_narrow_rows", "ragged_visit_counts",
+           "ragged_pages_visited", "ragged_narrow_updates"]
 
 _NEG = -1e30
 _MASKED = -3e38
@@ -119,11 +128,17 @@ def ragged_kernel_eligible(H: int, KV: int, D: int,
     return paged_kernel_eligible(H, KV, D, page_size)
 
 
+def _sublane_pack(dtype) -> int:
+    """Rows of `dtype` one packed row of a vector register holds (8 rows
+    of 32 bits): what a slice of rows has to start on."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
 def ragged_tile_tokens(T: int, rep: int, dtype) -> int:
     """TQ, the tokens of one query tile: about _TILE_ROWS / rep, in
     units that keep TQ*rep a multiple of the dtype's sublane packing
     (8 rows of 32 bits), and no more than T rounded up to a unit."""
-    pack = 32 // jnp.dtype(dtype).itemsize
+    pack = _sublane_pack(dtype)
     unit = pack // math.gcd(rep, pack)
     tq = max(unit, _TILE_ROWS // rep // unit * unit)
     return min(tq, -(-T // unit) * unit)
@@ -184,6 +199,21 @@ def ragged_tile_block(hb: int, tiles: int, rows: int, D: int, psz: int,
     return tb
 
 
+def ragged_narrow_rows(rep: int, rows: int, dtype, tb: int = 1) -> int:
+    """W, the rows of a tile a page visit computes for a sequence that
+    owns only a few of them (a decode row's `rep`, a short speculative
+    run, a chunk's last tokens): the least multiple of the dtype's
+    sublane packing that holds a token's `rep` rows wherever in the tile
+    they start (past a packed row by a multiple of gcd(rep, packing)) —
+    16 rows of bfloat16 at rep 1, 4 and 16, 32 at rep 6 and 9. 0, no
+    narrow visit, where that is the whole tile of `rows` and where a
+    cell is a block of `tb` tiles (`ragged_tile_block`: its decode row
+    fills half a tile)."""
+    pack = _sublane_pack(dtype)
+    width = -(-(pack - math.gcd(rep, pack) + rep) // pack) * pack
+    return width if tb == 1 and width < rows else 0
+
+
 def _walk(xp, lo, seq_start, num_tokens, kv_lengths, *, tq, page_size,
           pages_per_seq, window=None, lift=lambda x: x):
     """The K/V pages the `tq` flat rows from `lo` walk for a sequence —
@@ -217,23 +247,68 @@ def _tile_pages(xp, seq_start, num_tokens, kv_lengths, *, tq, n_tiles,
                  lift=lambda x: x[None, :], **walk)
 
 
-def ragged_pages_visited(seq_start, num_tokens, kv_lengths, *, T: int,
-                         rep: int, dtype, page_size: int,
-                         pages_per_seq: int,
-                         window: Optional[int] = None, tb: int = 1) -> int:
+def _narrow_window(xp, lo, seq_start, num_tokens, *, tq, rep, pack, width,
+                   lift=lambda x: x):
+    """Whether the query rows a sequence owns among the `tq` tokens from
+    `lo` (a tile of `tq * rep` rows) lie inside ONE window of `width`
+    rows that starts on a packed row of the tile, and that window's
+    first row (the packed row at or before the sequence's first, no
+    further than `width` rows before the tile's end). The ONE rule of
+    the kernel's choice, once a pair, of the rows its page visits
+    compute (scalars) and of the `attn_narrow_updates` count (arrays,
+    `lift` as in `_walk`; only pairs that walk pages are counted)."""
+    first = xp.maximum(lift(seq_start), lo)
+    end = xp.minimum(lift(seq_start + num_tokens), lo + tq)
+    w0 = xp.minimum((first - lo) * rep // pack * pack, tq * rep - width)
+    return (end - lo) * rep <= w0 + width, w0
+
+
+def ragged_visit_counts(seq_start, num_tokens, kv_lengths, *, T: int,
+                        rep: int, dtype, page_size: int,
+                        pages_per_seq: int, window: Optional[int] = None,
+                        tb: int = 1):
+    """`ragged_pages_visited` and `ragged_narrow_updates` of one launch,
+    from ONE table of the pages each (cell, sequence) pair walks
+    (host-side numpy)."""
+    tile = ragged_tile_tokens(T, rep, dtype)
+    tq = tb * tile
+    ss, nt, kvl = (np.asarray(x, np.int32)
+                   for x in (seq_start, num_tokens, kv_lengths))
+    n_tiles = -(-T // tq)
+    pages = _tile_pages(np, ss, nt, kvl, tq=tq, n_tiles=n_tiles,
+                        page_size=page_size, pages_per_seq=pages_per_seq,
+                        window=window)
+    if window is not None:
+        pages = pages[0]
+    width = ragged_narrow_rows(rep, tile * rep, dtype, tb)
+    if not width:
+        return int(pages.sum()), 0
+    fits, _ = _narrow_window(
+        np, (np.arange(n_tiles, dtype=np.int32) * tq)[:, None], ss, nt,
+        tq=tq, rep=rep, pack=_sublane_pack(dtype), width=width,
+        lift=lambda x: x[None, :])
+    return int(pages.sum()), int(np.where(fits, pages, 0).sum())
+
+
+def ragged_pages_visited(seq_start, num_tokens, kv_lengths, **launch) -> int:
     """K/V page fetches PER KV HEAD that `ragged_paged_attention` makes
-    for this launch (host-side numpy, the engine's `pages_visited`): the
-    sum over grid cells of `tb` tiles (`ragged_tile_block`) of the pages
-    each walks. At `tb` 1 whatever the launch's: the (tile, page) softmax
-    updates it computes, since a visit serves only the tiles whose own
-    walk holds the page."""
-    tq = tb * ragged_tile_tokens(T, rep, dtype)
-    pages = _tile_pages(
-        np, np.asarray(seq_start, np.int32),
-        np.asarray(num_tokens, np.int32), np.asarray(kv_lengths, np.int32),
-        tq=tq, n_tiles=-(-T // tq), page_size=page_size,
-        pages_per_seq=pages_per_seq, window=window)
-    return int((pages if window is None else pages[0]).sum())
+    for this launch (`ragged_visit_counts`' keywords; the engine's
+    `pages_visited`): the sum over grid cells of `tb` tiles
+    (`ragged_tile_block`) of the pages each walks. At `tb` 1 whatever
+    the launch's: the (tile, page) softmax updates it computes, since a
+    visit serves only the tiles whose own walk holds the page."""
+    return ragged_visit_counts(seq_start, num_tokens, kv_lengths,
+                               **launch)[0]
+
+
+def ragged_narrow_updates(seq_start, num_tokens, kv_lengths, **launch) -> int:
+    """Of the (tile, page) softmax updates a KV head that this launch
+    computes, those that run on `ragged_narrow_rows` rows and not on the
+    tile's (the engine's `attn_narrow_updates`): the pages of every
+    (tile, sequence) pair whose rows fit the window, by the kernel's own
+    rule. 0 where the launch has no narrow visit."""
+    return ragged_visit_counts(seq_start, num_tokens, kv_lengths,
+                               **launch)[1]
 
 
 def _work_list(seq_start, num_tokens, kv_lengths, **tiling):
@@ -296,7 +371,7 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
                    q_ref, k_hbm, v_hbm, o_ref,
                    kbuf, vbuf, acc_ref, m_ref, l_ref, ahead_ref, sem,
                    *, page_size, rep, tq, total_pages, scale, window,
-                   summary=False, tb=1):
+                   summary=False, tb=1, narrow=0):
     h = pl.program_id(0)
     t = pl.program_id(1)
     n_pairs = first_ref[pl.num_programs(1)]
@@ -318,9 +393,10 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
         """The index of head g's part of a ring slot."""
         return (g, ...) if blocked else (...,)
 
-    def chain(g, b):
-        """The index of the f32 state of head g's tile b."""
-        return (g * tb + b, ...) if acc_ref.ndim == 3 else (...,)
+    def chain(g, b, at=...):
+        """The index of the f32 state of head g's tile b (of its rows
+        `at`)."""
+        return (g * tb + b, at) if acc_ref.ndim == 3 else (at,)
 
     def run(g, tiles):
         """The index of a run of tiles in head g's q or output block."""
@@ -387,11 +463,14 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
     def pair(pi, ahead):
         i = pseq_ref[pi]
         first_row, nt = ss_ref[i], nt_ref[i]
-        # local token t of this sequence attends positions <= limit;
-        # the rows of other sequences attend nothing
-        limit = [jnp.where((tk >= first_row) & (tk < first_row + nt),
-                           kvl_ref[i] - nt + (tk - first_row), -1)
-                 for tk in tok]
+
+        def limit_of(tk):
+            """Local token t of this sequence attends positions <= its
+            limit; the rows of other sequences attend nothing."""
+            return jnp.where((tk >= first_row) & (tk < first_row + nt),
+                             kvl_ref[i] - nt + (tk - first_row), -1)
+
+        limit = [limit_of(tk) for tk in tok]
         page0 = 0 if window is None else pseq_ref[pairs + pi]
         if summary:
             # the sequence's KV starts with `lo` pooled rows; the rest
@@ -399,10 +478,11 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
             lo = kvl_ref[kvl_ref.shape[0] // 2 + i]
             hi = (lo + page_size - 1) // page_size * page_size
 
-        def visible(j, b):
-            """The keys of page j each row of tile b sees: the same for
-            every head, so one mask a tile and visit."""
-            rel = limit[b] - (page0 + j) * page_size
+        def visible(j, limit, in_page):
+            """The keys of page j each of a tile's rows sees, by the
+            rows' limits: the same for every head, so one mask a tile
+            and visit."""
+            rel = limit - (page0 + j) * page_size
             seen = in_page <= rel
             if window is not None:
                 # each row's own lower bound: keys older than its window
@@ -412,29 +492,43 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
                 seen &= (in_page < lo - at) | (in_page >= hi - at)
             return seen
 
-        def update(j, slot, tiles):
+        def update(j, slot, tiles, own=None):
             """Page j meets the run `tiles` of the cell's tiles: for each
             head of the block ONE scores matmul and ONE values matmul
             over the run's query rows (the page is the MXU's stationary
             operand once for all of them), and between the two one
             softmax update a tile under the tile's one mask — hb x
-            len(tiles) independent chains."""
+            len(tiles) independent chains, in THREE PASSES over the
+            heads: every head's scores, every head's softmax, every
+            head's values. (One head after the other, each chain's
+            matmuls wait for its own softmax and the next head's for
+            them: on the chip a visit of 16 rows took 1.5 x longer.)
+            With `own` — the window of the one tile's rows that holds
+            the sequence's, those rows' limits, the key index over them
+            — the same on those rows alone: rows are independent in both
+            matmuls and in the state, and the others' m, l and acc are
+            what the whole tile's update leaves them."""
+            rows_at, limits, keys = own or (..., limit, in_page)
             seen = {}
+            v_run, s_run = [], []
             for g in range(hb):
                 k = kbuf[(slot, *part(g))]               # [psz, D]
-                v = k[:, :acc_ref.shape[-1]] if vbuf is None \
-                    else vbuf[(slot, *part(g))]
-                s_run = jax.lax.dot_general(
-                    q[g] if tb == 1 else q_ref[run(g, tiles)],
+                v_run.append(k[:, :acc_ref.shape[-1]] if vbuf is None
+                             else vbuf[(slot, *part(g))])
+                s_run.append(jax.lax.dot_general(
+                    q_ref[g, rows_at] if own
+                    else q[g] if tb == 1 else q_ref[run(g, tiles)],
                     k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                chains, p_run = [], []
+                    preferred_element_type=jnp.float32) * scale)
+            chains, p_run = [[] for _ in range(hb)], [[] for _ in range(hb)]
+            for g in range(hb):
                 for n, b in enumerate(tiles):
-                    at = chain(g, b)
-                    s = s_run if len(tiles) == 1 \
-                        else s_run[n * rows:(n + 1) * rows]
+                    at = chain(g, b, rows_at)
+                    s = s_run[g] if len(tiles) == 1 \
+                        else s_run[g][n * rows:(n + 1) * rows]
                     if b not in seen:   # after the first head's scores,
-                        seen[b] = visible(j, b)     # where one head a cell
+                        # where one head a cell
+                        seen[b] = visible(j, limits[b], keys)
                     # _MASKED is so far below any m (>= _NEG) that exp
                     # gives an exact 0: a row with nothing to attend here
                     # keeps m, l, acc
@@ -446,16 +540,17 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
                     alpha = jnp.exp(m_prev - m_new)
                     l_ref[at] = l_ref[at] * alpha \
                         + jnp.sum(p, -1, keepdims=True)
-                    chains.append((at, alpha, m_new))
-                    p_run.append(p.astype(v.dtype))
-                for n, (at, alpha, m_new) in enumerate(chains):
+                    chains[g].append((at, alpha, m_new))
+                    p_run[g].append(p.astype(v_run[g].dtype))
+            for g in range(hb):
+                for n, (at, alpha, m_new) in enumerate(chains[g]):
                     scaled = acc_ref[at] * alpha
                     if n == 0:  # (behind the first rescale: the order of
                         # the one-tile launch's text, which is pinned)
                         pv = jax.lax.dot_general(
-                            p_run[0] if len(tiles) == 1
-                            else jnp.concatenate(p_run),
-                            v, (((1,), (0,)), ((), ())),
+                            p_run[g][0] if len(tiles) == 1
+                            else jnp.concatenate(p_run[g]),
+                            v_run[g], (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
                     acc_ref[at] = scaled + (
                         pv if len(tiles) == 1
@@ -482,13 +577,13 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
             inside = at < walk[1]
             return inside if window is None else inside & (at >= walk[0])
 
-        def page(j, ahead):
+        def page(j, ahead, own=None):
             ahead = fetch_ahead(*ahead)
             slot, dmas = page_dma(pi, j)
             for dma in dmas:
                 dma.wait()
             if tb == 1:
-                update(j, slot, (0,))
+                update(j, slot, (0,), own)
                 return ahead
             at = page0 + j
             whole = meets(at, every)
@@ -504,8 +599,30 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
 
             return ahead
 
-        return jax.lax.fori_loop(
-            0, pfirst_ref[pi + 1] - pfirst_ref[pi], page, ahead)
+        n_pages = pfirst_ref[pi + 1] - pfirst_ref[pi]
+        if not narrow:
+            return jax.lax.fori_loop(0, n_pages, page, ahead)
+        # a sequence that owns a few rows of the tile (a decode row, a
+        # short speculative run, a chunk's last tokens): its pages meet
+        # the window of `narrow` rows that holds them, not the tile.
+        # Chosen once a pair, by the rule the host counts with
+        pack = _sublane_pack(q_ref.dtype)
+        fits, w0 = _narrow_window(jnp, t * tq, first_row, nt, tq=tq,
+                                  rep=rep, pack=pack, width=narrow)
+
+        def own_rows(ahead):
+            own_tok = t * tq + (w0 + jax.lax.broadcasted_iota(
+                jnp.int32, (narrow, 1), 0)) // rep
+            own = (pl.ds(pl.multiple_of(w0, pack), narrow),
+                   [limit_of(own_tok)],
+                   jax.lax.broadcasted_iota(
+                       jnp.int32, (narrow, page_size), 1))
+            return jax.lax.fori_loop(
+                0, n_pages, functools.partial(page, own=own), ahead)
+
+        return jax.lax.cond(
+            fits, own_rows,
+            lambda ahead: jax.lax.fori_loop(0, n_pages, page, ahead), ahead)
 
     ahead = jax.lax.fori_loop(first_ref[t], first_ref[t + 1], pair,
                               (ahead_ref[0], ahead_ref[1]))
@@ -609,7 +726,8 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
           .reshape(Tp, KV, rep, D).transpose(1, 0, 2, 3)
           .reshape(KV, Tp * rep, D))
     static = dict(page_size=psz, rep=rep, tq=tq, total_pages=total,
-                  scale=float(scale), window=window, tb=tb)
+                  scale=float(scale), window=window, tb=tb,
+                  narrow=ragged_narrow_rows(rep, rows, q.dtype, tb))
     if summary_rows is not None:
         kvl = jnp.concatenate([kvl, summary_rows.astype(jnp.int32)])
         static["summary"] = True

@@ -84,9 +84,9 @@ from ..ops.pallas_ssm import (ssm_chunk_scan, ssm_state_put,
                               ssm_state_update)
 from ..ops.pallas_ragged import (ragged_head_block,
                                  ragged_kernel_eligible,
-                                 ragged_paged_attention,
-                                 ragged_pages_visited, ragged_tile_block,
-                                 ragged_tile_tokens)
+                                 ragged_narrow_rows, ragged_paged_attention,
+                                 ragged_tile_block, ragged_tile_tokens,
+                                 ragged_visit_counts)
 from .block_allocator import ChunkSummaryAllocator, PageBlockAllocator
 from .handoff import (HANDOFF_BYTES, HANDOFF_PAGES, HANDOFFS,
                       KVPageHandoff)
@@ -294,7 +294,8 @@ class _Launch:
 #: of the others the record keeps the later launch's
 _ADDITIVE = frozenset(
     ("decode_rows", "prefill_rows", "append_runs", "rows_dropped",
-     "pages_live", "pages_visited", "attn_block_visits")
+     "pages_live", "pages_visited", "attn_block_visits",
+     "attn_narrow_updates")
     + _tracing.STEP_COUNTS_BY_KIND[:4]
     + _tracing.STEP_COUNTS_EVA[:4]
     + ("ssm_state_bytes_moved", "ssm_scan_rows", "ssm_state_resets"))
@@ -623,20 +624,22 @@ class ServingEngine:
         self._kv_geom = (kv, d)
         self._kv_itemsize = int(jnp.dtype(dt).itemsize)
         # the KV heads and the query tiles one page visit of the ragged
-        # kernel serves, for each query group size: the kernel's own
-        # choice at the unified step's row count (`attn_block_visits`,
-        # `pages_visited`)
+        # kernel serves, and the rows of a tile it computes for a
+        # sequence that owns a few, for each query group size: the
+        # kernel's own choice at the unified step's row count
+        # (`attn_block_visits`, `pages_visited`, `attn_narrow_updates`)
         T = self.max_slots * (1 + self.spec_k) + self.prefill_chunk
         latent = self._family == "mla"
-        self._head_block, self._tile_block = {}, {}
+        self._head_block, self._tile_block, self._narrow_rows = {}, {}, {}
         for r in {r for reps in self._kind_rep.values() for r in reps}:
             tq = ragged_tile_tokens(T, r, dt)
             self._head_block[r] = hb = ragged_head_block(
                 kv, tq * r, d, self.page_size, self._kv_itemsize,
                 latent=latent)
-            self._tile_block[r] = ragged_tile_block(
+            self._tile_block[r] = tb = ragged_tile_block(
                 hb, -(-T // tq), tq * r, d, self.page_size,
                 self._kv_itemsize, cfg.kv_lora_rank if latent else None)
+            self._narrow_rows[r] = ragged_narrow_rows(r, tq * r, dt, tb)
         # the unit of work of the rope + append kernel
         # (`ops.fused.append_run_table`): the rows of one cache tile
         self._append_tile = append_tile(dt, self.page_size)
@@ -1127,13 +1130,15 @@ class ServingEngine:
         }
         if self.ragged:
             # KV heads and query tiles a page visit of the ragged kernel
-            # serves, by layer kind (the fewest over the kind's head
-            # counts)
+            # serves, and the rows it computes for a sequence that owns
+            # a few of a tile's (0: always the tile's), by layer kind
+            # (the fewest over the kind's head counts)
             for k, reps in self._kind_rep.items():
-                acct["attn_head_block" + (".window" if k else "")] = \
-                    float(min(self._head_block[r] for r in reps))
-                acct["attn_tile_block" + (".window" if k else "")] = \
-                    float(min(self._tile_block[r] for r in reps))
+                for name, choice in (("attn_head_block", self._head_block),
+                                     ("attn_tile_block", self._tile_block),
+                                     ("attn_narrow_rows", self._narrow_rows)):
+                    acct[name + (".window" if k else "")] = \
+                        float(min(choice[r] for r in reps))
         return acct
 
     def program_cache_sizes(self) -> Dict[str, int]:
@@ -1874,7 +1879,7 @@ class ServingEngine:
         # that holds rows of it); a visit brings the page for a block
         # of KV heads at once, so the visits it makes are the fetches
         # of all heads over the block
-        counts["attn_block_visits"] = 0
+        counts["attn_block_visits"] = counts["attn_narrow_updates"] = 0
 
         def visited(kind, window=None, tiles=False):
             # one layer of each head count of the kind, summed; with
@@ -1882,7 +1887,7 @@ class ServingEngine:
             # the fetches would be at one tile a cell
             total = 0
             for r in self._kind_rep[kind]:
-                pages = ragged_pages_visited(
+                pages, narrow = ragged_visit_counts(
                     seq_start, num_tokens, kv_lengths, T=T, rep=r,
                     dtype=self._q_dtype, page_size=ps, pages_per_seq=nj,
                     window=window, tb=1 if tiles else self._tile_block[r])
@@ -1890,6 +1895,9 @@ class ServingEngine:
                 if not tiles:
                     counts["attn_block_visits"] += \
                         pages * self._kv_geom[0] // self._head_block[r]
+                    # of those updates, the ones on the few rows their
+                    # sequence owns (the kernel's rule, once a pair)
+                    counts["attn_narrow_updates"] += narrow
             return total
 
         if self._family == "mla":

@@ -159,8 +159,16 @@ class TestEngineAgainstReference:
             assert r["attn_block_visits"] == r["pages_visited"]
             # ... and, the launch being one tile here, one tile of it
             assert r["attn_tile_chains"] == r["pages_visited"]
+            # ... on the 8 rows of the 40 that hold a decode row's 4;
+            # the chunk's visits on the tile's
+            assert r["attn_narrow_updates"] <= r["pages_visited"]
+            if not r["prefill_rows"]:
+                assert r["attn_narrow_updates"] == r["pages_visited"]
+        assert any(0 < r["attn_narrow_updates"] < r["pages_visited"]
+                   for r in recs)
         assert eng.hbm_accounting()["attn_head_block"] == 1 \
             == eng.hbm_accounting()["attn_tile_block"]
+        assert eng.hbm_accounting()["attn_narrow_rows"] == 8
         # a chunk's context grows by the chunk until the prompt ends
         ctx = [r["chunk_kv_len"] for r in recs if r["prefill_rows"]]
         assert ctx[:4] == [16, 32, 48, 61]
@@ -177,6 +185,8 @@ class TestEngineAgainstReference:
                             prefill_chunk=64, num_pages=40,
                             enable_prefix_cache=False)
         assert eng.hbm_accounting()["attn_tile_block"] == 2
+        # a cell that is a block of tiles takes no narrow visit
+        assert eng.hbm_accounting()["attn_narrow_rows"] == 0
         again = [eng.add_request(p, max_new_tokens=6) for p in prompts]
         eng.run_to_completion()
         assert [r.tokens for r in again] == [r.tokens for r in reqs]
@@ -185,6 +195,7 @@ class TestEngineAgainstReference:
         for r in recs:
             assert r["pages_live"] <= r["pages_visited"] \
                 == r["attn_block_visits"] <= r["attn_tile_chains"]
+            assert r["attn_narrow_updates"] == 0
             if not r["prefill_rows"]:   # decode rows: their pages, once
                 assert r["attn_tile_chains"] == r["pages_live"]
         # the 61-token prompt's chunk (rows 3..63) has rows in tiles 0

@@ -450,18 +450,21 @@ class TestSummaryMask:
 #: sent its launch through the one jitted copy as well: unrolled over 8
 #: tiles, its body cost the chip's host 10 s of a warm first step to
 #: lower once a layer. The toy launch is one tile, so its kernel is the
-#: parent's; what changed is that the step calls it.
+#: parent's; what changed is that the step calls it. PR 45 (a page visit
+#: of a sequence that owns a few rows of its tile computes the window of
+#: rows that holds them; the heads' chains in three passes) re-recorded
+#: all five: every toy launch is ONE tile a cell, taller than the window.
 LOWERED_AT_PARENT = {
-    "llama": "986cc43c9b47e3f14971f0fe0c3f210dd2dc22265c81c4ed897c7ed7faa1"
-             "ae7b",
-    "moe": "c33d0d6ae5d0be6cf546453b86a3c7853e365ad818968696a84089b4d88919"
-           "98",
-    "mla": "51054763d6e497f87030d9b8e6328767176dbc39266a1177379b0914c9560a"
-           "ef",
-    "gpt": "f92873aa0b8da9418f9152b8cc776f57a3aae5975758b7c46a949665448a6c"
-           "2d",
-    "laguna": "69d5c90d27c571b0f35f8827269ad4e2f773120fd1780d26692f07d15ea"
-              "9264f",
+    "llama": "c4ebdf4852efdb2da55d0ebb719b313d2e92dc1635e255ee96ebf67445e0"
+             "078b",
+    "moe": "7f2b0001aa3cce9f7cf7ff3ecd8042ae1d3dd253a88066680133691480dd08"
+           "9d",
+    "mla": "d26a585a3d0e6625382cb85ca3be80c596f602a4374ba275434a3cf368e34d"
+           "ac",
+    "gpt": "663c3f3c4d2afd7ca79f46705c2730cf70402b7affd7238e977b6989d4da17"
+           "3b",
+    "laguna": "22aed154f936d23508b8b795885e385ca7dca6f917c042790442c102d0f"
+              "6e3af",
 }
 
 

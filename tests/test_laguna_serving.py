@@ -141,6 +141,12 @@ class TestEngineAgainstReference:
             assert acct["attn_head_block"] == kv \
                 == acct["attn_head_block.window"]
             assert r["attn_block_visits"] == r["pages_visited"]
+            # a decode row's visits, of either kind, run on a few rows
+            assert acct["attn_narrow_rows"] > 0 \
+                and acct["attn_narrow_rows.window"] > 0
+            assert r["attn_narrow_updates"] <= r["pages_visited"]
+            if not r["prefill_rows"]:
+                assert r["attn_narrow_updates"] == r["pages_visited"]
             assert r["pages_live.window"] <= r["pages_live.full"]
             assert r["pool_pages_total.full"] == eng.num_pages - 1
             assert r["pool_pages_total.window"] == eng.num_window_pages - 1

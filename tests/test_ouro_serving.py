@@ -133,14 +133,16 @@ def test_a_slot_is_reused_and_a_request_waits_on_pages(tiny):
 def test_the_text_holds_one_layer_in_one_loop(tiny):
     """The passes are a compiled loop around ONE jitted layer: 7 matmuls
     (and the interpreted kernel's 2 for each KV head of a page visit's
-    block: both heads here), the gate, the head — and staggered arrivals
-    still match."""
+    block, both heads here, on the tile's rows and again on the 8 a
+    decode row's visit computes), the gate, the head — and staggered
+    arrivals still match."""
     m, w, c = tiny
     prompts = _prompts(5, [13, 4])
     eng = _engine(m)
     assert eng.hbm_accounting()["attn_head_block"] == 2
+    assert eng.hbm_accounting()["attn_narrow_rows"] == 8
     assert _lower_unified(eng).as_text().count("stablehlo.dot_general") \
-        == 7 + 2 * 2 + 1 + 1
+        == 7 + 2 * 2 * 2 + 1 + 1
     for p, (tokens, got) in zip(prompts, _run(eng, prompts, [6, 9], 2)):
         np.testing.assert_allclose(got, _reference(w, c, p, tokens),
                                    atol=2e-4)
@@ -272,9 +274,11 @@ def test_the_split_programs_refuse_the_family(tiny, monkeypatch):
 #: chunk-summary attention, beside the five of `test_evabyte_serving`
 #: (unchanged there). The looped decoder came in beside these programs,
 #: not through them. PR 42 (a page visit of the ragged kernel serves a
-#: block of KV heads) re-recorded it with four of the five.
+#: block of KV heads) re-recorded it with four of the five, PR 45 (a
+#: decode row's page visit computes the few rows the row owns) with all
+#: five.
 EVA_LOWERED_AT_PARENT = \
-    "ad74a30170541461cbcf1366bcc60e5b69e0f18b221a2ec15216012a21436d2f"
+    "320faf7e6c6e1cb343d35a5b2b2f480cba7cc6b0f30148cca42297ee30f2d47b"
 
 
 def _lower_eva():

@@ -4,6 +4,8 @@
 ragged_attention_reference is the correctness oracle. Runs in Pallas
 interpret mode on CPU: same kernel logic as the TPU path."""
 
+import hashlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,6 +19,8 @@ from paddle_tpu.ops.pallas_ragged import (_work_list,
                                           ragged_attention_reference,
                                           ragged_head_block,
                                           ragged_kernel_eligible,
+                                          ragged_narrow_rows,
+                                          ragged_narrow_updates,
                                           ragged_paged_attention,
                                           ragged_pages_visited,
                                           ragged_tile_block,
@@ -59,13 +63,17 @@ def _check(q, kp, vp, ss, nt, kvl, tab, atol=2e-5, rtol=2e-5, window=None):
 
 
 def _engine_layout(kv_dec, chunk, kv_chunk, T=None, chunk_row=None,
-                   share=False, H=8, KV=2, D=64, psz=8, pps=8, seed=0):
+                   share=False, H=8, KV=2, D=64, psz=8, pps=8, seed=0,
+                   runs=None):
     """The engine's row tables in small: decode slot i owns row i (one
     token, or none where kv_dec[i] == 0), the prefill chunk owns `chunk`
     rows from `chunk_row` (default: right after the slots). rep 4 in
-    float32 gives tiles of 32 tokens, so T > 32 spans several."""
+    float32 gives tiles of 32 tokens, so T > 32 spans several. With
+    `runs` (speculative decoding) slot i owns runs[i] tokens from row
+    i * max(runs)."""
     B = len(kv_dec)
-    chunk_row = B if chunk_row is None else chunk_row
+    R = 1 if runs is None else max(runs)
+    chunk_row = B * R if chunk_row is None else chunk_row
     T = chunk_row + chunk if T is None else T
     S = B + 1
     rng = np.random.RandomState(seed)
@@ -78,8 +86,9 @@ def _engine_layout(kv_dec, chunk, kv_chunk, T=None, chunk_row=None,
         # a shared prefix: slot 1 and the chunk read slot 0's first pages
         tab[1, :2] = tab[0, :2]
         tab[S - 1, :1] = tab[0, :1]
-    ss = np.append(np.arange(B), chunk_row)
-    nt = np.append([int(k > 0) for k in kv_dec], chunk)
+    ss = np.append(np.arange(B) * R, chunk_row)
+    nt = np.append([int(k > 0) for k in kv_dec] if runs is None else runs,
+                   chunk)
     kvl = np.append(kv_dec, kv_chunk)
     return (q, kp, vp, jnp.asarray(ss, jnp.int32),
             jnp.asarray(nt, jnp.int32), jnp.asarray(kvl, jnp.int32),
@@ -131,6 +140,29 @@ _LAYOUTS = {
     "one_kv_head_a_block_of_tiles": dict(
         kv_dec=[17, 33, 0, 60, 1, 25], chunk=37, kv_chunk=20 + 37,
         chunk_row=11, T=48, H=16, KV=1),
+    # the chunk's last two tokens are alone in tile 1: 8 rows, the
+    # narrow window exactly (float32: 8 rows a packed row)
+    "chunk_tail_of_two_tokens": dict(
+        kv_dec=[17, 33, 9, 60, 1, 25, 40, 8], chunk=26, kv_chunk=20 + 26),
+    # one query head a KV head (rep 1, tiles of 128 tokens): a decode
+    # row is ONE row of its tile, the chunk's tail in tile 1 seven
+    "rep1_decode_rows_and_a_chunk": dict(
+        kv_dec=[17, 0, 9, 60, 1], chunk=130, kv_chunk=12 + 130, H=2,
+        pps=20),
+    # speculative runs of 1-3 tokens a slot (rep 4: 4-12 rows): two
+    # tokens fit the 8-row window where they start on a packed row
+    # (slots 0 and 2) and not where they start mid-way (slot 1: rows
+    # 12-19), three never
+    "speculative_runs_that_fit_and_not": dict(
+        kv_dec=[17, 33, 9, 60, 0, 25], runs=[2, 2, 1, 3, 0, 2], chunk=21,
+        kv_chunk=30 + 21),
+    # rep 6 without a window: a token's 6 rows straddle a packed row
+    # (the window is two of them, 16 rows); slots start 18 rows apart,
+    # so a run of two (12 rows) fits from row 0 (slot 0) and not from
+    # row 54 (slot 3: rows 54-65 against the window 48-63), three never
+    "rep6_runs_of_two_and_three": dict(
+        kv_dec=[17, 33, 9, 60, 5], runs=[2, 1, 3, 2, 1], chunk=9,
+        kv_chunk=9 + 14, H=12),
 }
 
 
@@ -141,6 +173,7 @@ def _layout(name):
     spec = dict(_LAYOUTS[name])
     window, dead = spec.pop("window", None), spec.pop("dead", False)
     q, kp, vp, ss, nt, kvl, tab = _engine_layout(**spec)
+    kvl = jnp.maximum(kvl, nt)      # a run's tokens are in its context
     if dead:
         psz = kp.shape[2]
         oldest = np.asarray(kvl) - np.asarray(nt) - window + 1
@@ -177,7 +210,7 @@ class TestRaggedKernelParity:
         kv_dec = [17, 0 if spec.pop("idle", False) else 9, 30]
         q, kp, vp, ss, nt, kvl, tab = _engine_layout(
             kv_dec=kv_dec, chunk=11, kv_chunk=16 + 11, H=KV * rep, KV=KV,
-            D=32, pps=4)
+            pps=4)
         if spec.pop("sentinel", False):
             live = -(-np.asarray(kvl) // kp.shape[2])
             tab = jnp.where(np.arange(tab.shape[1])[None] < live[:, None],
@@ -186,7 +219,7 @@ class TestRaggedKernelParity:
         if "summary" in spec:
             kw["summary_rows"] = jnp.asarray(spec.pop("summary"), jnp.int32)
         tq = ragged_tile_tokens(q.shape[0], rep, q.dtype)
-        assert ragged_head_block(KV, tq * rep, 32, kp.shape[2], 4) == hb
+        assert ragged_head_block(KV, tq * rep, 64, kp.shape[2], 4) == hb
         out = ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab, **kw)
         ref = ragged_attention_reference(q, kp, vp, ss, nt, kvl, tab, **kw)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -637,7 +670,7 @@ class TestTileBlock:
         with two pools and under a window too."""
         q, kp, vp, *tables = _engine_layout(
             kv_dec=[17, 0, 9, 30, 5], chunk=39, kv_chunk=16 + 39, T=48,
-            chunk_row=7, H=32, KV=2, D=32)
+            chunk_row=7, H=32, KV=2)
         monkeypatch.setattr(pallas_ragged, "ragged_head_block",
                             lambda *a, **k: hb)
         outs = {}
@@ -750,6 +783,203 @@ class TestTileBlock:
                     want = table[t, i] if window is None else \
                         (table[0][t, i], table[1][t, i])
                     assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def _forced_narrow_rows(monkeypatch, rows):
+    """Every page visit on `rows` rows of the tile (0: the tile's)."""
+    monkeypatch.setattr(pallas_ragged, "ragged_narrow_rows",
+                        lambda *a, **k: rows)
+    # (the launch is traced once for equal shapes: trace it again)
+    pallas_ragged._launch_jit.clear_cache()
+
+
+def _narrow_by_hand(ss, nt, kvl, *, T, rep, dtype, psz, window=None):
+    """An instrumented reference of the kernel's page walk: for every
+    (tile, sequence) pair the pages that hold a key some row of the pair
+    sees, counted row by row, and whether SOME window of
+    `ragged_narrow_rows` rows on a packed row of the tile holds the
+    pair's rows. Returns (updates in such pairs, all updates, pairs
+    that fit, pairs)."""
+    tq = ragged_tile_tokens(T, rep, dtype)
+    rows, pack = tq * rep, 32 // jnp.dtype(dtype).itemsize
+    W = ragged_narrow_rows(rep, rows, dtype)
+    narrow = total = fit = pairs = 0
+    for t0 in range(0, T, tq):
+        for i in range(len(ss)):
+            own = [r for r in range(t0, min(t0 + tq, T))
+                   if int(ss[i]) <= r < int(ss[i]) + int(nt[i])]
+            if not own:
+                continue
+            pos = [int(kvl[i]) - int(nt[i]) + r - int(ss[i]) for r in own]
+            lo = [0 if window is None else max(p_ - window + 1, 0)
+                  for p_ in pos]
+            pages = len({k // psz for a, p_ in zip(lo, pos)
+                         for k in range(a, p_ + 1)})
+            if window is None:      # a full walk starts at page 0
+                pages = max(pos) // psz + 1
+            r0, r1 = (own[0] - t0) * rep, (own[-1] + 1 - t0) * rep
+            fits = bool(W) and any(w <= r0 and r1 <= w + W
+                                   for w in range(0, rows - W + 1, pack))
+            pairs, fit = pairs + 1, fit + fits
+            total, narrow = total + pages, narrow + fits * pages
+    return narrow, total, fit, pairs
+
+
+#: the launches whose sequences own a few rows of a tile: a `_LAYOUTS`
+#: name -> (pairs whose rows fit the narrow window, pairs) by hand
+_NARROW = {
+    "engine": (8, 10),                      # 8 decode rows; the chunk x 2
+    "chunk_inside_a_tile": (4, 5),
+    "idle_between_live": (4, 6),            # idle slots are no pair
+    "no_chunk_decode_only": (4, 4),
+    "shared_physical_pages": (3, 5),
+    "chunk_tail_of_two_tokens": (9, 10),    # the tail's tile too
+    "rep1_decode_rows_and_a_chunk": (5, 6),
+    # slots 0 and 2; the chunk has rows in both tiles
+    "speculative_runs_that_fit_and_not": (2, 7),
+    "rep6_runs_of_two_and_three": (3, 7),   # slots 0, 1 and 4
+    "window_rep6_chunk_straddles": (6, 8),
+    "window_rep9_dead_pages": (4, 7),       # a token straddles 8 rows
+    "window_one_page": (4, 6),
+}
+
+
+class TestNarrowWindow:
+    """A page visit of a sequence that owns a few rows of its tile runs
+    on the window of `ragged_narrow_rows` rows that holds them: the same
+    numbers as on the tile's rows, bit for bit (at the widths the kernel
+    takes on a TPU: at D = 32 XLA's CPU code sums a page's keys in
+    another order for an 8-row operand of the values matmul than for a
+    64-row one)."""
+
+    @pytest.mark.parametrize("name", list(_NARROW))
+    def test_a_narrow_visit_is_the_tiles_bit_for_bit(self, name,
+                                                     monkeypatch):
+        arrays, window = _layout(name)
+        q, kp, vp, ss, nt, kvl, tab = arrays
+        out = np.asarray(ragged_paged_attention(*arrays, window=window))
+        ref = ragged_attention_reference(
+            q, jnp.nan_to_num(kp), jnp.nan_to_num(vp), ss, nt, kvl, tab,
+            window=window)
+        np.testing.assert_allclose(out, np.asarray(ref), atol=2e-5,
+                                   rtol=2e-5)
+        _forced_narrow_rows(monkeypatch, 0)
+        full = np.asarray(ragged_paged_attention(*arrays, window=window))
+        pallas_ragged._launch_jit.clear_cache()
+        np.testing.assert_array_equal(out, full)
+
+    @pytest.mark.parametrize("name", list(_NARROW))
+    def test_the_count_is_the_kernels_rule(self, name):
+        """`ragged_narrow_updates` (the engine's `attn_narrow_updates`)
+        against a reference that walks rows and pages one by one."""
+        (q, kp, _, ss, nt, kvl, tab), window = _layout(name)
+        rep = q.shape[1] // kp.shape[0]
+        launch = dict(T=q.shape[0], rep=rep, dtype=q.dtype)
+        narrow, total, fit, pairs = _narrow_by_hand(
+            ss, nt, kvl, psz=kp.shape[2], window=window, **launch)
+        assert (fit, pairs) == _NARROW[name]
+        tiling = dict(page_size=kp.shape[2], pages_per_seq=tab.shape[1],
+                      window=window, **launch)
+        assert ragged_pages_visited(ss, nt, kvl, **tiling) == total
+        assert ragged_narrow_updates(ss, nt, kvl, **tiling) == narrow
+        assert 0 < narrow < total or name == "no_chunk_decode_only"
+        # a cell that is a block of tiles has no narrow visit
+        assert ragged_narrow_updates(ss, nt, kvl, tb=2, **tiling) == 0
+
+    @pytest.mark.parametrize("name", list(_HEAD_BLOCKS))
+    def test_under_a_head_block(self, name, monkeypatch):
+        """The launches of `_HEAD_BLOCKS` (a window at rep 9, an idle
+        slot and sentinel tables, rep 6, chunk-summary rows at rep 1)
+        with every visit on the tile's rows: the same output."""
+        spec = dict(_HEAD_BLOCKS[name])
+        KV, rep = spec["KV"], spec["rep"]
+        q, kp, vp, ss, nt, kvl, tab = _engine_layout(
+            kv_dec=[17, 0 if spec.get("idle") else 9, 30], chunk=11,
+            kv_chunk=16 + 11, H=KV * rep, KV=KV, pps=4)
+        if spec.get("sentinel"):
+            live = -(-np.asarray(kvl) // kp.shape[2])
+            tab = jnp.where(np.arange(tab.shape[1])[None] < live[:, None],
+                            tab, -1)
+        kw = dict(window=spec.get("window"))
+        if "summary" in spec:
+            kw["summary_rows"] = jnp.asarray(spec["summary"], jnp.int32)
+        tq = ragged_tile_tokens(q.shape[0], rep, q.dtype)
+        assert 0 < ragged_narrow_rows(rep, tq * rep, q.dtype) < tq * rep
+        out = np.asarray(
+            ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab, **kw))
+        _forced_narrow_rows(monkeypatch, 0)
+        full = np.asarray(
+            ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab, **kw))
+        pallas_ragged._launch_jit.clear_cache()
+        np.testing.assert_array_equal(out, full)
+
+    def test_bfloat16_rows_pack_by_sixteen(self, monkeypatch):
+        """The dtype the cells run: a decode row's window is 16 rows."""
+        q, kp, vp, *tables = _engine_layout(
+            kv_dec=[17, 33, 0, 60, 1, 25], chunk=40, kv_chunk=24 + 40)
+        q, kp, vp = (x.astype(jnp.bfloat16) for x in (q, kp, vp))
+        assert ragged_narrow_rows(4, 128, q.dtype) == 16
+        out = ragged_paged_attention(q, kp, vp, *tables)
+        _forced_narrow_rows(monkeypatch, 0)
+        full = ragged_paged_attention(q, kp, vp, *tables)
+        pallas_ragged._launch_jit.clear_cache()
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.asarray(full, np.float32))
+
+    @pytest.mark.parametrize("name,rep,rows,dtype,tb,want", [
+        # the configurations' launches in bfloat16 (16 rows a packed
+        # row): a token of 1, 4 or 16 query heads never straddles one
+        ("mistral", 4, 128, jnp.bfloat16, 1, 16),
+        ("ouro", 1, 128, jnp.bfloat16, 1, 16),
+        ("evabyte", 1, 128, jnp.bfloat16, 1, 16),
+        ("nemotron_rep16", 16, 128, jnp.bfloat16, 1, 16),
+        # 6 and 9 do: two packed rows
+        ("laguna_full_rep6", 6, 96, jnp.bfloat16, 1, 32),
+        ("laguna_window_rep9", 9, 144, jnp.bfloat16, 1, 32),
+        # a block of tiles has no narrow visit; its one-tile launch has
+        ("axk1_latent", 64, 128, jnp.bfloat16, 8, 0),
+        ("a_latent_launch_of_one_tile", 64, 128, jnp.bfloat16, 1, 64),
+        # float32 packs 8 rows
+        ("float32_rep4", 4, 128, jnp.float32, 1, 8),
+        ("float32_rep9", 9, 72, jnp.float32, 1, 16),
+        # a tile no taller than the window is computed whole
+        ("a_tile_of_one_packed_row", 1, 16, jnp.bfloat16, 1, 0),
+        ("rep9_in_a_tile_of_two", 9, 32, jnp.bfloat16, 1, 0),
+    ])
+    def test_narrow_rows_follow_the_shapes(self, name, rep, rows, dtype, tb,
+                                           want):
+        W = ragged_narrow_rows(rep, rows, dtype, tb)
+        assert W == want
+        if not W:
+            return
+        pack = 32 // jnp.dtype(dtype).itemsize
+        assert W % pack == 0 and W < rows
+        # every token's rows lie in the window on the packed row at or
+        # before its first, and in no narrower one
+        starts = range(0, rows, rep)
+        assert all(r % pack + rep <= W for r in starts)
+        assert any(r % pack + rep > W - pack for r in starts)
+
+    @pytest.mark.parametrize("name,sha", [
+        ("latent_rows", "20fe146335804934f71a52b7fc1e15897fca3ce49d239aa84a"
+                        "79d058c6763903"),
+        ("two_pools_windowed", "ff5b68dc7c372173eac35c604025f025cb63b1ecb44"
+                               "e1c86ea92ff32d2745ecc"),
+    ])
+    def test_a_block_of_tiles_lowers_to_the_parents_text(self, name, sha):
+        """sha256 of the launch's lowered text at this PR's parent
+        (f44cae0), on the CPU under the suite's matmul precision: a cell
+        of `tb` > 1 tiles (4 here) takes no narrow visit, and its body's
+        two-branch page test is what PR 44 left."""
+        if name == "latent_rows":
+            q, kp, tables = _latent_layout("chunk_starts_and_ends_mid_cell")
+            lowered = jax.jit(lambda q, kp, *tabs: ragged_paged_attention(
+                q, kp, None, *tabs, v_dim=64)).lower(q, kp, *tables)
+        else:
+            arrays, _ = _layout("one_kv_head_a_block_of_tiles")
+            lowered = jax.jit(lambda *a: ragged_paged_attention(
+                *a, window=13)).lower(*arrays)
+        assert hashlib.sha256(lowered.as_text().encode()).hexdigest() == sha
 
 
 class TestRaggedJit:

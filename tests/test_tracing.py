@@ -451,9 +451,17 @@ class TestStepTimeline:
                 assert hb == kv > 1
                 assert s["attn_block_visits"] \
                     == s["pages_visited"] * kv // hb
+                # a decode row's visits run on the window of rows that
+                # holds its own; a chunk's on the tile's
+                assert eng.hbm_accounting()["attn_narrow_rows"] == 8
+                assert s["attn_narrow_updates"] <= s["pages_visited"]
+                if not out["prefill_tokens"]:
+                    assert s["attn_narrow_updates"] == s["pages_visited"]
             else:
                 assert s["attn_block_visits"] == 0
+                assert s["attn_narrow_updates"] == 0
                 assert "attn_head_block" not in eng.hbm_accounting()
+                assert "attn_narrow_rows" not in eng.hbm_accounting()
                 # two launches a step, each walks its own sequences
                 if not out["finished"]:
                     assert pages_used <= s["pages_live"] <= 2 * pages_used

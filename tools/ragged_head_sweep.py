@@ -1,10 +1,12 @@
 """The ragged attention kernel ALONE on the local chip, at the serving
-cells' launches, over the head block `hb` (PERF.md section 6, PR 42)
-and the tile block `tb` (PR 44):
+cells' launches, over the head block `hb` (PERF.md section 6, PR 42),
+the tile block `tb` (PR 44) and the narrow window of a few-row
+sequence's page visits (PR 45):
 
     chiprun -- python tools/ragged_head_sweep.py [--hb 1 2 4 8 16]
     chiprun -- python tools/ragged_head_sweep.py --hb 0 --tb 1 2 4 8 \
         --launch axk1_6k axk1_24k axk1_decode
+    chiprun -- python tools/ragged_head_sweep.py --hb 0 --narrow 0 1
 
 One launch shape a cell (`LAUNCHES`), its row tables drawn from a seed;
 for every `hb` that divides the cell's KV heads and every `tb`,
@@ -15,7 +17,11 @@ tb): ms a launch, us a (KV head, page) visit, us a (tile, page) softmax
 update, and whether the output equals the launch's first line's bit
 for bit. `hb` and `tb` are forced by replacing
 `pallas_ragged.ragged_head_block` / `ragged_tile_block` for the sweep
-only; `0` leaves the kernel's own choice. `--depth` forces the ring's
+only; `0` leaves the kernel's own choice. `--narrow 0` forces the
+window `ragged_narrow_rows` to 0 the same way (every visit computes the
+tile's rows), `1` leaves the kernel's own; a line says the window, the
+(tile, page) updates that ran on it, and with both given the tool fails
+unless their outputs are equal bit for bit. `--depth` forces the ring's
 slots the same way. Appends its lines to
 chiprun_out/ragged_head_sweep.jsonl.
 """
@@ -109,6 +115,8 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--hb", type=int, nargs="+", default=[1, 2, 4, 8, 16, 0])
     ap.add_argument("--tb", type=int, nargs="+", default=[0])
+    ap.add_argument("--narrow", type=int, nargs="+", default=[1],
+                    choices=[0, 1])
     ap.add_argument("--depth", type=int, default=0)
     ap.add_argument("--launch", nargs="+", default=list(LAUNCHES))
     ap.add_argument("--chain", type=int, default=48)
@@ -124,7 +132,7 @@ def main():
         print("WARNING: not on a TPU; the times mean nothing",
               file=sys.stderr)
     choose, buffers = pr.ragged_head_block, pr._page_buffers
-    choose_tb = pr.ragged_tile_block
+    choose_tb, choose_rows = pr.ragged_tile_block, pr.ragged_narrow_rows
     if args.depth:
         pr._page_buffers = lambda _bytes: args.depth
     out = []
@@ -151,13 +159,16 @@ def main():
         tables = [jnp.asarray(x) for x in (ss, nt, kvl, tab)]
         summary = jnp.asarray(sr) if spec.get("summary") else None
         first = None
-        for hb, tb in ((hb, tb) for hb in args.hb for tb in args.tb):
+        for hb, tb, narrow in ((hb, tb, n) for hb in args.hb
+                               for tb in args.tb for n in args.narrow):
             if hb and KV % hb:
                 continue
             pr.ragged_head_block = choose if not hb else \
                 (lambda *a, _hb=hb, **k: _hb)
             pr.ragged_tile_block = choose_tb if not tb else \
                 (lambda *a, _tb=tb, **k: _tb)
+            pr.ragged_narrow_rows = choose_rows if narrow else \
+                (lambda *a, **k: 0)
             pr._launch_jit.clear_cache()    # equal shapes: trace again
 
             # (the pools and tables are ARGUMENTS: closed over, they
@@ -195,21 +206,29 @@ def main():
                                    2, v_dim)
             visits = pr.ragged_pages_visited(ss, nt, kvl, tb=cell, **counted)
             ms = min(times) / args.chain * 1e3
+            equal = bool(np.array_equal(one, first))
+            if len(args.narrow) > 1 and not equal:
+                raise SystemExit(f"{name} hb {used} tb {cell}: the narrow "
+                                 "window's output is not the tile's")
             rec = dict(launch=name, hb=used, tb=cell,
-                       forced=bool(hb or tb),
+                       forced=bool(hb or tb or not narrow),
+                       narrow_rows=pr.ragged_narrow_rows(
+                           rep, rows, jnp.bfloat16, cell),
+                       narrow_updates_a_head=pr.ragged_narrow_updates(
+                           ss, nt, kvl, tb=cell, **counted),
                        depth=args.depth, ms_a_launch=ms,
                        visits_a_head=visits,
                        us_a_head_visit=ms * 1e3 / (visits * KV),
                        us_a_block_visit=ms * 1e3 / (visits * KV // used),
                        tile_chains_a_head=chains,
                        us_a_tile_update=ms * 1e3 / (chains * KV),
-                       equal_to_first=bool(np.array_equal(one, first)),
+                       equal_to_first=equal,
                        finite=bool(np.isfinite(one).all()),
                        device=jax.devices()[0].device_kind)
             out.append(rec)
             print(json.dumps(rec), flush=True)
     pr.ragged_head_block, pr._page_buffers = choose, buffers
-    pr.ragged_tile_block = choose_tb
+    pr.ragged_tile_block, pr.ragged_narrow_rows = choose_tb, choose_rows
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/ragged_head_sweep.jsonl", "a") as f:
         f.writelines(json.dumps(rec) + "\n" for rec in out)
