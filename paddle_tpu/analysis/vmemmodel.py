@@ -235,15 +235,6 @@ CANONICAL: Dict[str, Dict[str, Any]] = {
         token_tiled=False,
     ),
     # -- ops/pallas_paged.py / pallas_ragged.py / pallas_mla.py ------------
-    "paged_decode_attention": dict(
-        kernel="paged_decode_attention",
-        bindings=dict(B=8, KV=8, rep=4, D=128, nj=8, page_size=32),
-        in_widths=[2, 2, 2], out_widths=[2],
-        cost_kwargs=dict(B=8, H=32, KV=8, D=128, context=256,
-                         page_size=32, pages_per_seq=8),
-        token_tiled=False,
-        families={"llama": dict(KV=8, rep=4, D=128)},
-    ),
     "paged_decode_attention_v2": dict(
         kernel="paged_decode_attention_v2",
         bindings=dict(B=8, KV=8, rep=4, D=128, G=2, psz=32),

@@ -144,14 +144,6 @@ define_flag("FLAGS_flash_impl", "intree",
             "(jax.experimental.pallas.ops.tpu.flash_attention), or "
             "'composite' (never take a fused kernel)",
             validator=lambda v: v in ("intree", "bundled", "composite"))
-define_flag("FLAGS_paged_impl", "intree",
-            "paged-attention decode kernel: 'intree' (the grouped-DMA v2 "
-            "kernel, ops/pallas_paged.py), 'intree_v1' (the per-page "
-            "BlockSpec kernel, kept for comparison), 'bundled' "
-            "(jax.experimental paged_attention), or 'reference' (XLA "
-            "gather composite)",
-            validator=lambda v: v in ("intree", "intree_v1", "bundled",
-                                      "reference"))
 define_flag("FLAGS_mla_decode_impl", "auto",
             "MLA absorbed-latent decode attention: 'auto' (fused "
             "single-cache-read kernel ops/pallas_mla.py when the latent "

@@ -465,9 +465,7 @@ def _eva_decode_params(model):
     """EvaByteForCausalLM: a llama-layout weight tree whose norm gains
     are ``1 + g`` in float32 (``norm_add_unit_offset``, folded here once)
     and whose layers carry the chunk pooling's per-head ``phi`` and
-    ``mu`` [heads, head_dim]; ``head`` is the byte heads side by side.
-    ``attn_static`` marks the family as served by the unified ragged
-    step only."""
+    ``mu`` [heads, head_dim]; ``head`` is the byte heads side by side."""
     from .models.evabyte import rope_table
     inner, cfg = model.model, model.config
 
@@ -489,8 +487,6 @@ def _eva_decode_params(model):
         cfg=cfg, family="eva", embed=inner.embed_tokens.weight._data,
         layers=layers, norm=gain(inner.norm),
         head=model.lm_head.weight._data,
-        attn_static=(dict(heads=cfg.num_attention_heads, window=None,
-                          rope=""),) * len(layers),
         rope_fn=lambda n: dict(zip(("cos", "sin"), rope_table(
             cfg.rope_theta, cfg.head_dim, n))))
 
@@ -499,9 +495,7 @@ def _looped_decode_params(model):
     """OuroForCausalLM: a llama-layout weight tree run ``total_ut_steps``
     times a token. Each layer carries four gains (``ln1_out`` and
     ``ln2_out`` norm a sublayer's OUTPUT before the add); ``norm`` closes
-    every pass; ``gate_w`` [hidden] and ``gate_b`` [] are the exit gate.
-    ``attn_static`` marks the family as served by the unified ragged
-    step only."""
+    every pass; ``gate_w`` [hidden] and ``gate_b`` [] are the exit gate."""
     from .models.evabyte import rope_table
     inner, cfg = model.model, model.config
     layers = []
@@ -522,8 +516,6 @@ def _looped_decode_params(model):
         layers=layers, norm=inner.norm.weight._data,
         gate_w=gate.weight._data[:, 0], gate_b=gate.bias._data[0],
         head=model.lm_head.weight._data,
-        attn_static=(dict(heads=cfg.num_attention_heads, window=None,
-                          rope=""),) * len(layers),
         rope_fn=lambda n: dict(zip(("cos", "sin"), rope_table(
             cfg.rope_theta, cfg.head_dim, n))))
 
@@ -533,8 +525,7 @@ def _hybrid_decode_params(model):
     ``pattern`` names (static, outside the tree): ``M`` a Mamba-2
     state-space layer, ``*`` attention without rotary, ``E`` a latent
     routed FFN in `_ffn_apply`'s layout.  ``attn_static`` has one record
-    for each ATTENTION block (the only ones with pages) and marks the
-    family as served by the unified ragged step only; ``moe_static``
+    for each ATTENTION block (the only ones with pages); ``moe_static``
     one for each ``E`` block."""
     from .models.nemotron_h import arrays
     inner, cfg = model.model, model.config

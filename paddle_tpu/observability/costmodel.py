@@ -371,24 +371,14 @@ def _paged_cost(B: int, H: int, KV: int, D: int, context: int,
                         breakdown={"kv": kv, "activations": q + out})
 
 
-@register_cost("paged_decode_attention")
-def _c_paged_v1(*, B: int, H: int, KV: int, D: int, context: int,
-                page_size: int, pages_per_seq: Optional[int] = None,
-                dtype_bytes: int = 2) -> CostEstimate:
-    """Paged decode, grid (B, KV, pages): the K/V page blocks are
-    fetched once per (batch row, kv head, page) — the whole allocated
-    table unless pages_per_seq narrows it."""
-    return _paged_cost(B, H, KV, D, context, page_size, pages_per_seq,
-                       dtype_bytes)
-
-
 @register_cost("paged_decode_attention_v2")
-def _c_paged_v2(*, B: int, H: int, KV: int, D: int, context: int,
+def _c_paged(*, B: int, H: int, KV: int, D: int, context: int,
                 page_size: int, pages_per_seq: Optional[int] = None,
                 dtype_bytes: int = 2) -> CostEstimate:
-    """v2 keeps K/V in HBM and double-buffers page groups by manual DMA;
-    the per-launch HBM traffic model is the same as v1 (every live page
-    crosses once per (b, kv head))."""
+    """Paged decode: K/V stay in HBM and page groups are double-buffered
+    by manual DMA; every page of the table crosses once per (batch row,
+    kv head) — the whole allocated table unless pages_per_seq narrows
+    it."""
     return _paged_cost(B, H, KV, D, context, page_size, pages_per_seq,
                        dtype_bytes)
 

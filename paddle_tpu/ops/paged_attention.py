@@ -4,12 +4,14 @@ Reference capability (SURVEY §2.1 fused kernels): BlockMultiheadAttention /
 masked_multihead_attention (paged KV cache decoding kernels,
 paddle/phi/kernels/fusion/gpu/block_multi_head_attention*).
 
-TPU-native: routes to the in-tree AUTHORED Pallas decode kernel
-(ops/pallas_paged.py — scalar-prefetched page table, online softmax,
-GQA-native query groups) by default; FLAGS_paged_impl selects the
-bundled jax.experimental kernel (the Ragged-Paged-Attention lineage
-from PAPERS.md) or the gather-based XLA reference, which also remains
-the correctness oracle and the fallback for ineligible shapes.
+TPU-native: `paged_attention` is the in-tree AUTHORED Pallas decode
+kernel (ops/pallas_paged.py — scalar-prefetched page table, grouped
+double-buffered page DMAs, online softmax, GQA-native query groups)
+wherever the shapes tile (`paged_kernel_eligible`), and the gather-based
+XLA reference otherwise; the reference is also the correctness oracle.
+The serving engine does not come through here (its one step program
+calls `ops.pallas_ragged.ragged_paged_attention`); this is the
+`incubate.nn.functional.block_multihead_attention` surface.
 """
 
 from __future__ import annotations
@@ -22,7 +24,7 @@ import jax.numpy as jnp
 from .. import observability as _obs
 from .flash_attention import _count_kernel
 
-__all__ = ["paged_attention", "paged_attention_reference", "append_to_cache"]
+__all__ = ["paged_attention", "paged_attention_reference"]
 
 # serving KV-cache visibility: fraction of allocated page capacity that
 # holds live tokens, sampled at each EAGER paged-attention call (traced
@@ -85,73 +87,18 @@ def paged_attention_reference(q, k_pages, v_pages, lengths, page_indices,
 
 def paged_attention(q, k_pages, v_pages, lengths, page_indices,
                     scale: Optional[float] = None):
-    """Routing paged decode attention: the in-tree authored kernel
-    (ops/pallas_paged.py) by default, the bundled jax.experimental
-    kernel or the XLA gather composite via FLAGS_paged_impl; ineligible
-    shapes fall back to the composite."""
-    from ..flags import flag
-    impl = flag("FLAGS_paged_impl")
+    """Paged decode attention, routed from the shapes: the in-tree
+    grouped-DMA kernel (ops/pallas_paged.py) where they tile, the XLA
+    gather composite otherwise."""
+    from .pallas_paged import (paged_decode_attention_v2,
+                               paged_kernel_eligible)
     H, D = q.shape[1], q.shape[2]
     KV, page_size = k_pages.shape[0], k_pages.shape[2]
     _sample_kv_utilization(lengths, page_indices, page_size)
-    if impl == "intree":
-        from .pallas_paged import (paged_decode_attention_v2,
-                                   paged_kernel_eligible)
-        if paged_kernel_eligible(H, KV, D, page_size):
-            _count_kernel("paged_intree")
-            return paged_decode_attention_v2(q, k_pages, v_pages,
-                                             lengths, page_indices, scale)
-    elif impl == "intree_v1":
-        # the per-page BlockSpec kernel, kept for comparison benching
-        from .pallas_paged import (paged_decode_attention,
-                                   paged_kernel_eligible)
-        if paged_kernel_eligible(H, KV, D, page_size):
-            _count_kernel("paged_intree_v1")
-            return paged_decode_attention(q, k_pages, v_pages,
-                                          lengths, page_indices, scale)
-    elif impl == "bundled" and jax.default_backend() == "tpu":
-        try:
-            from jax.experimental.pallas.ops.tpu.paged_attention import (
-                paged_attention as _kernel)
-            from .pallas_paged import default_pages_per_group
-            # the bundled kernel applies NO internal scaling: pre-scale q
-            # (default 1/sqrt(D)); it also requires an explicit
-            # pages_per_compute_block or it raises and we'd silently fall
-            # back to the composite (round-4 fix: that fallback made
-            # "bundled" benchmarks measure the composite instead)
-            sq = q * (q.shape[-1] ** -0.5 if scale is None else scale)
-            nj = page_indices.shape[1]
-            ppcb = min(default_pages_per_group(nj, page_size), nj)
-            while nj % ppcb:
-                ppcb //= 2
-            out = _kernel(sq, k_pages, v_pages, lengths.astype(jnp.int32),
-                          page_indices.astype(jnp.int32),
-                          pages_per_compute_block=max(ppcb, 1))
-            _count_kernel("paged_bundled")
-            return out
-        except Exception:
-            pass
+    if paged_kernel_eligible(H, KV, D, page_size):
+        _count_kernel("paged_intree")
+        return paged_decode_attention_v2(q, k_pages, v_pages, lengths,
+                                         page_indices, scale)
     _count_kernel("paged_reference")
     return paged_attention_reference(q, k_pages, v_pages, lengths,
                                      page_indices, scale)
-
-
-def append_to_cache(k_pages, v_pages, k_new, v_new, lengths, page_indices):
-    """Write one decode step's K/V into the paged cache (functional update).
-
-    k_new/v_new: [B, KV, D]; returns updated (k_pages, v_pages, lengths).
-    """
-    page_size = k_pages.shape[2]
-    B = k_new.shape[0]
-    slot = lengths  # position to write
-    page_of = page_indices[jnp.arange(B), slot // page_size]
-    off = slot % page_size
-
-    def write(pages, new):
-        # pages [KV, P, S, D]; scatter one row per (b, kv head)
-        def body(pages, b):
-            return pages.at[:, page_of[b], off[b], :].set(new[b]), None
-        pages, _ = jax.lax.scan(body, pages, jnp.arange(B))
-        return pages
-
-    return (write(k_pages, k_new), write(v_pages, v_new), lengths + 1)

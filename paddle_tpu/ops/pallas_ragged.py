@@ -15,7 +15,7 @@ per-sequence row tables as scalar prefetch:
   - kv_lengths [S]: sequence i's KV length INCLUDING its new tokens
     (append-then-attend: the new K/V rows are already in the pages);
   - page_tables [S, pages_per_seq]: physical pages, sentinel entries
-    clamped like pallas_paged._page_map.
+    clamped into the pool.
 
 Causality is per sequence over its new tokens: local token t (0-based)
 attends KV positions 0 .. kv_lengths[i] - num_tokens[i] + t. A decode

@@ -1,7 +1,7 @@
 """Continuous-batching serving subsystem.
 
-Modules over the Pallas paged-decode kernel
-(`ops/pallas_paged.py` via `ops.paged_attention`):
+Modules over the Pallas ragged paged-attention kernel
+(`ops/pallas_ragged.py`):
 
   - `block_allocator`: fixed pool of page_size-token KV blocks with
     refcounts, per-sequence page tables, copy-on-write prefix sharing,
@@ -16,9 +16,9 @@ Modules over the Pallas paged-decode kernel
     deadlines (`set_deadline` → falsy TimeoutResult partials);
   - `spec_decode`: n-gram self-drafting speculative decoding, verified
     in the engine's single ragged launch per step;
-  - `engine`: `ServingEngine.add_request/step/collect`, a fixed-shape
-    jitted decode step (one compile per model/slot-count) plus chunked
-    prefill, for the llama/moe, gpt and mla families — each engine runs
+  - `engine`: `ServingEngine.add_request/step/collect`, ONE fixed-shape
+    jitted step (one compile per model/slot-count) that carries every
+    decode row and a chunk of prefill in one launch — each engine runs
     as a `prefill`, `decode`, or `colocated` (default) replica;
   - `handoff`: `KVPageHandoff`, the pin → export → import → unpin
     KV-page transfer between a prefill replica and a decode replica

@@ -8,37 +8,30 @@ leave the instant they hit EOS/max-tokens (their pages return to the
 pool immediately), and never retrace — one compile per (model-config,
 slot-count) pair, checked by the PT002-gated tests.
 
-The engine chooses its programs itself, once, at construction
-(`_ragged_step_eligible`; no constructor argument selects a path):
-
-- **unified**: ONE launch per step. Every decode slot's token and the
-  oldest prefill request's chunk ride a single flat token buffer
-  through ONE per-layer chain, the same at every width and on every
-  backend: norm -> q / k / v projections -> `fused_rope_append` (MLA:
-  `fused_append_rows`) -> `ragged_paged_attention` -> o-proj -> norm ->
-  `_ffn_apply`. A step that has both prefill and decode work issues
-  ONE device program instead of two (`serving.engine.launches` counts
-  the difference). Per-sequence row tables (seq_start / num_tokens /
-  kv_lengths / page table) make joins and leaves pure data changes.
-  Every family takes it on a TPU at published widths: llama / MoE /
-  Laguna / GPT heads of 64 or a multiple of 128, and latent attention
-  (MLA) whose latent rank is a multiple of 128 — its cache row
-  (latent | rope key) is stored padded to whole 128-lane registers
-  (`_latent_row_width`: 512 + 64 -> 640), K is the row, V its first
-  `kv_lora_rank` columns, one page fetch for both. Chunk-summary
-  (EVA) attention takes it too and nothing else: its sequence is one
-  page table of pooled rows followed by the current window's exact
-  rows (`ChunkSummaryAllocator`, `_eva_unified_body`).
-- **split**: the PR-5 alternating `_prefill_chunk` / `_decode`
-  dispatches over `paged_attention`/`append_to_cache`, built only
-  where the ragged kernel's tiling constraints do not hold on a TPU
-  (`ragged_kernel_eligible`): head sizes that are neither 64 nor a
-  multiple of 128, which today means toy presets on a chip
-  (`chip_smoke.py` runs none). No benchmark cell builds it.
+The engine has ONE step program, the unified ragged step: ONE launch
+per step. Every decode slot's token and the oldest prefill request's
+chunk ride a single flat token buffer through ONE per-layer chain, the
+same at every width and on every backend: norm -> q / k / v
+projections -> `fused_rope_append` (MLA: `fused_append_rows`) ->
+`ragged_paged_attention` -> o-proj -> norm -> `_ffn_apply`
+(`serving.engine.launches` counts the launches). Per-sequence row
+tables (seq_start / num_tokens / kv_lengths / page table) make joins
+and leaves pure data changes. Every family takes it on a TPU at
+published widths: llama / MoE / Laguna / GPT heads of 64 or a multiple
+of 128, and latent attention (MLA) whose latent rank is a multiple of
+128 — its cache row (latent | rope key) is stored padded to whole
+128-lane registers (`_latent_row_width`: 512 + 64 -> 640), K is the
+row, V its first `kv_lora_rank` columns, one page fetch for both.
+Chunk-summary (EVA) attention: its sequence is one page table of pooled
+rows followed by the current window's exact rows
+(`ChunkSummaryAllocator`, `_eva_unified_body`). A shape the ragged
+kernel cannot tile on a TPU (`_ragged_step_eligible`: a head width that
+is neither 64 nor a multiple of 128) is refused at construction with a
+`ValueError` that names it; no constructor argument selects a program.
 
 Inactive slots point their whole page table at the allocator's trash
-page 0 with length/num_tokens 0: both paths write their (garbage) K/V
-into the trash page and their logits are ignored on the host.
+page 0 with num_tokens 0: the step writes their (garbage) K/V into the
+trash page and their logits are ignored on the host.
 
 The unified step keeps ONE launch queued ahead (`_unified_step`):
 call k of `step()` builds and dispatches launch k BEFORE it reads the
@@ -58,8 +51,7 @@ greedy property; sampling strategies belong to the batch APIs.
 
 from __future__ import annotations
 
-import functools
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -79,7 +71,6 @@ from ..ops.fused import (append_run_count, append_run_table, append_tile,
 from ..models.nemotron_h import (ssm_conv, ssm_gated_norm, ssm_operands,
                                  ssm_split)
 from ..models.ouro import exit_distribution as _exit_distribution
-from ..ops.paged_attention import append_to_cache, paged_attention
 from ..ops.pallas_ssm import (ssm_chunk_scan, ssm_state_put,
                               ssm_state_update)
 from ..ops.pallas_ragged import (ragged_head_block,
@@ -162,11 +153,11 @@ def _lcp(a: np.ndarray, b: np.ndarray) -> int:
 
 
 def _ragged_step_eligible(heads, kv: int, d: int, page_size: int) -> bool:
-    """The ONE question `ServingEngine` asks, once, when it chooses its
-    programs: does the ragged kernel tile every layer's head count
-    here? On a TPU that is `ragged_kernel_eligible`; anywhere else the
-    kernel runs interpreted and has no tiling constraint. A test that
-    needs the other answer patches THIS function."""
+    """The ONE question `ServingEngine` asks, once, at construction:
+    does the ragged kernel tile every layer's head count here? On a TPU
+    that is `ragged_kernel_eligible`; anywhere else the kernel runs
+    interpreted and has no tiling constraint. A `False` is refused by
+    name; a test that needs that answer patches THIS function."""
     return (jax.default_backend() != "tpu"
             or all(ragged_kernel_eligible(h, kv, d, page_size)
                    for h in heads))
@@ -528,6 +519,13 @@ class ServingEngine:
         self._layer_kind = [int(st["window"] is not None)
                             for st in self._attn_static]
         heads = sorted({st["heads"] for st in self._attn_static})
+        if not _ragged_step_eligible(heads, kv, d, self.page_size):
+            raise ValueError(
+                f"family {self._family!r}: the ragged attention kernel "
+                f"does not tile query heads {heads} over {kv} KV heads of "
+                f"width {d} with pages of {self.page_size} on this "
+                f"backend, and the engine serves through the unified "
+                f"ragged step only")
         # what the ragged kernel's tiling follows (pages_visited): the
         # query rows a KV head of each layer kind's layers
         self._q_rep, self._q_dtype = heads[0] // kv, dt
@@ -561,16 +559,6 @@ class ServingEngine:
                            for sh in (wshape if k else shape
                                       for k in self._layer_kind)]
 
-        # the programs: the unified step wherever the ragged kernel
-        # tiles every layer's head count, else the split pair
-        self.ragged = _ragged_step_eligible(heads, kv, d, self.page_size)
-        if "attn_static" in p and not self.ragged:
-            raise ValueError(
-                f"family {self._family!r} (per-layer heads {heads}, window "
-                f"{self._window}) is served by the unified ragged step "
-                f"only (the split path has one page table and no window), "
-                f"and the ragged kernel is not eligible here: "
-                f"{kv} KV heads x {d}, page {self.page_size}")
         if spec_decode < 0:
             raise ValueError("spec_decode must be >= 0")
         if self._eva and jax.default_backend() == "tpu" and \
@@ -581,9 +569,8 @@ class ServingEngine:
                 f"as one block")
         # speculative decoding: each decode slot owns 1 + spec_k flat
         # rows of the unified step (n-gram drafts verified in the SAME
-        # ragged launch). The split path has no multi-row slots, so
-        # spec decoding rides the ragged path only.
-        self.spec_k = int(spec_decode) if self.ragged else 0
+        # ragged launch)
+        self.spec_k = int(spec_decode)
         self.launches = 0      # device program launches by THIS engine
         self.steps = 0         # step() calls: the step timeline's `seq`
         #: set to a callable (request, logits row [vocab]) to be handed
@@ -692,9 +679,12 @@ class ServingEngine:
             self.controller = None
 
     # Read by benchmarks/systems/*_serving.py (the `paths` line of every
-    # run) and by nothing else: two constants since the fused halves
-    # left the engine, and the one chain's launches a layer after and
-    # before attention. They go with the `benchmark` PR of ROADMAP R0 (c).
+    # run), benchmarks/tests/, benchmarks/tools/sweep_engine.py and
+    # chip_smoke.py, and by nothing in the package: three constants —
+    # the engine has one step program and no fused halves — and the one
+    # chain's launches a layer after and before attention. They go with
+    # the `benchmark` PR of ROADMAP R0 (c).
+    ragged = True
     megafront = megadecode = False
     back_half_launches = 6      # o-proj, add, norm; gate/up, act, down
 
@@ -724,24 +714,19 @@ class ServingEngine:
         instead of a copy of it. So the caller's pools are dead after
         the launch — `_launch` is the one caller."""
         self._programs = self._step_programs()
-        if self.ragged:
-            self._jit_unified = self._programs["unified"]
-            # the token feed: EVERY `tok` the unified step sees comes
-            # out of this one program (one type, one sharding, one
-            # committed-ness: `_jit_unified` keeps one cache entry) —
-            # row r takes the host's token where src[r] < 0, else row
-            # src[r] of the launch in flight's greedy tokens, which
-            # never leave the device. Compiled and run ONCE here, on
-            # the tokens of no launch
-            self._jit_feed = self._programs["feed"]
-            T = self.max_slots * (1 + self.spec_k) + self.prefill_chunk
-            self._no_tokens = jax.jit(lambda: jnp.zeros(
-                T if self.spec_k else self.max_slots + 1, jnp.int32))()
-            self._jit_feed(self._no_tokens, np.zeros(T, np.int32),
-                           np.full(T, -1, np.int32))
-        else:
-            self._jit_decode = self._programs["decode"]
-            self._jit_prefill = self._programs["prefill"]
+        self._jit_unified = self._programs["unified"]
+        # the token feed: EVERY `tok` the unified step sees comes out of
+        # this one program (one type, one sharding, one committed-ness:
+        # `_jit_unified` keeps one cache entry) — row r takes the host's
+        # token where src[r] < 0, else row src[r] of the launch in
+        # flight's greedy tokens, which never leave the device. Compiled
+        # and run ONCE here, on the tokens of no launch
+        self._jit_feed = self._programs["feed"]
+        T = self.max_slots * (1 + self.spec_k) + self.prefill_chunk
+        self._no_tokens = jax.jit(lambda: jnp.zeros(
+            T if self.spec_k else self.max_slots + 1, jnp.int32))()
+        self._jit_feed(self._no_tokens, np.zeros(T, np.int32),
+                       np.full(T, -1, np.int32))
         # the copy-on-write program (`_apply_copies`): as many pairs as
         # one sequence's new rows of one step can touch shared pages,
         # compiled and run ONCE here (trash page onto itself) so that
@@ -768,15 +753,10 @@ class ServingEngine:
     def _step_programs(self) -> Dict[str, object]:
         """{name: a FRESH `jax.jit`} of the step programs at the current
         max_slots/prefill_chunk/spec_k."""
-        if self.ragged:
-            return {"unified": jax.jit(self._make_unified_body(),
-                                       donate_argnums=2),
-                    "feed": jax.jit(lambda prev, tok, src: jnp.where(
-                        src < 0, tok, prev[jnp.maximum(src, 0)]))}
-        return {"decode": jax.jit(self._make_decode_body(),
-                                  donate_argnums=2),
-                "prefill": jax.jit(self._make_prefill_body(),
-                                   donate_argnums=2)}
+        return {"unified": jax.jit(self._make_unified_body(),
+                                   donate_argnums=2),
+                "feed": jax.jit(lambda prev, tok, src: jnp.where(
+                    src < 0, tok, prev[jnp.maximum(src, 0)]))}
 
     def _live_pools(self):
         """The page pools, for whoever reads or replaces them between
@@ -826,8 +806,6 @@ class ServingEngine:
         new_k = self.spec_k if spec_decode is None else int(spec_decode)
         if new_k < 0:
             raise ValueError("spec_decode must be >= 0")
-        if not self.ragged:
-            new_k = 0   # the split path has no multi-row slots
         if (new_chunk, new_k) == (self.prefill_chunk, self.spec_k):
             return False
         # the launch in flight was built by the programs that go
@@ -918,11 +896,10 @@ class ServingEngine:
         ones into free slots, then run the step's device work — ONE
         unified ragged launch carrying every decode slot's token plus
         one prefill chunk, dispatched before the previous launch is
-        read back (ragged path), or the legacy alternating
-        prefill-chunk / decode-step pair (split path). Returns counts
-        for observability/benching; `prefill_tokens`, `decoded` and
-        `finished` are those of work RETIRED in this call (results the
-        host holds), not of the launch it queued."""
+        read back. Returns counts for observability/benching;
+        `prefill_tokens`, `decoded` and `finished` are those of work
+        RETIRED in this call (results the host holds), not of the launch
+        it queued."""
         out = {"admitted": 0, "prefill_tokens": 0, "decoded": 0,
                "finished": 0}
         self.steps += 1
@@ -952,7 +929,7 @@ class ServingEngine:
     def _step_phases(self, out: Dict[str, int]) -> None:
         """The step timeline: disjoint child spans of
         ``serving.engine.step`` — admit, then build / launch / sync /
-        sample inside the step function(s), then account."""
+        sample inside `_unified_step`, then account."""
         with _obs.span("serving.engine.admit"):
             for req in self.scheduler.expire_waiting():
                 # a PREEMPTED request expiring in the queue still owns
@@ -978,14 +955,8 @@ class ServingEngine:
             out["admitted"] = self._admit()
             self._counts["live"] = self.scheduler.inflight
             self._counts["waiting"] = len(self.scheduler.waiting)
-        if self.ragged:
-            self._unified_step()
-            self._take_retired(out)
-        else:
-            out["prefill_tokens"], fin = self._prefill_chunk()
-            out["finished"] += fin
-            out["decoded"], fin = self._decode()
-            out["finished"] += fin
+        self._unified_step()
+        self._take_retired(out)
         with _obs.span("serving.engine.account"):
             # what the observability itself costs, measured by itself
             if _obs.enabled():
@@ -1128,25 +1099,23 @@ class ServingEngine:
                 self._ledger_model_bytes / self._ledger_tokens
                 if self._ledger_tokens else 0.0),
         }
-        if self.ragged:
-            # KV heads and query tiles a page visit of the ragged kernel
-            # serves, and the rows it computes for a sequence that owns
-            # a few of a tile's (0: always the tile's), by layer kind
-            # (the fewest over the kind's head counts)
-            for k, reps in self._kind_rep.items():
-                for name, choice in (("attn_head_block", self._head_block),
-                                     ("attn_tile_block", self._tile_block),
-                                     ("attn_narrow_rows", self._narrow_rows)):
-                    acct[name + (".window" if k else "")] = \
-                        float(min(choice[r] for r in reps))
+        # KV heads and query tiles a page visit of the ragged kernel
+        # serves, and the rows it computes for a sequence that owns a
+        # few of a tile's (0: always the tile's), by layer kind (the
+        # fewest over the kind's head counts)
+        for k, reps in self._kind_rep.items():
+            for name, choice in (("attn_head_block", self._head_block),
+                                 ("attn_tile_block", self._tile_block),
+                                 ("attn_narrow_rows", self._narrow_rows)):
+                acct[name + (".window" if k else "")] = \
+                    float(min(choice[r] for r in reps))
         return acct
 
     def program_cache_sizes(self) -> Dict[str, int]:
         """{program name: compiled-variant count} for this engine's
-        jitted programs — the PT002 no-retrace guard's hook. Ragged
-        engines expose {"unified": n}; split engines {"decode": n,
-        "prefill": n}. Every count must stay at 1 after any join/leave
-        pattern."""
+        jitted programs, {"unified": n, "feed": n} — the PT002
+        no-retrace guard's hook. Every count must stay at 1 after any
+        join/leave pattern."""
         return {name: fn._cache_size()
                 for name, fn in self._programs.items()}
 
@@ -1163,19 +1132,11 @@ class ServingEngine:
             return jax.ShapeDtypeStruct(np.shape(a), a.dtype)
 
         pools = self._live_pools()
-        if self.ragged:
-            host, src, *_ = self._build_unified(None, [], None)
-            rest = jax.tree_util.tree_map(shaped, host[1:])
-            args = {"unified": (self._w, shaped(host[0]), pools, *rest),
-                    "feed": (shaped(self._no_tokens), shaped(host[0]),
-                             shaped(src))}
-        else:
-            B, nj = self.max_slots, self.pages_per_seq
-            i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
-            args = {"decode": (self._w, i32((B,)), pools, i32((B,)),
-                               i32((B, nj))),
-                    "prefill": (self._w, i32((1, self.prefill_chunk)),
-                                pools, i32((1, nj)), i32(()), i32(()))}
+        host, src, *_ = self._build_unified(None, [], None)
+        rest = jax.tree_util.tree_map(shaped, host[1:])
+        args = {"unified": (self._w, shaped(host[0]), pools, *rest),
+                "feed": (shaped(self._no_tokens), shaped(host[0]),
+                         shaped(src))}
         return {name: compile_named(
                     fn, args[name], lambda n=name: self._step_programs()[n])
                 for name, fn in self._programs.items()}
@@ -1542,94 +1503,6 @@ class ServingEngine:
             _PREEMPTIONS.inc()
         return cand
 
-    # ------------------------------------------------------------ prefill
-    def _prefill_chunk(self) -> Tuple[int, int]:
-        """One chunk of prompt prefill for the OLDEST prefilling request
-        — bounded work between decode steps so long prompts never stall
-        the in-flight batch."""
-        while self._prefill_fifo and \
-                self._prefill_fifo[0].state != PREFILL:
-            self._prefill_fifo.pop(0)
-        if not self._prefill_fifo:
-            return 0, 0
-        req = self._prefill_fifo[0]
-        with _obs.span("serving.engine.build"):
-            n = min(self.prefill_chunk,
-                    int(req.prompt.size) - req.prefill_pos)
-            start = req.prefill_pos
-            self._apply_copies(self.allocator.extend(req.request_id, n),
-                               req)
-            ids = np.zeros((1, self.prefill_chunk), np.int32)
-            ids[0, :n] = req.prompt[start:start + n]
-            table = self.allocator.table(req.request_id)[None]
-            self._counts["prefill_rows"] += n
-            self._counts["pages_live"] += -(-(start + n) // self.page_size)
-            self._counts["pages_visited"] += self.pages_per_seq
-        with _obs.span("serving.engine.launch"):
-            logits, = self._launch(
-                self._jit_prefill, jnp.asarray(ids), jnp.asarray(table),
-                np.int32(start), np.int32(n))
-            _TRACE.stamp(req.request_id, "prefill_chunk", tokens=n,
-                         start=start)
-            req.prefill_pos += n
-            if _obs.enabled():
-                _LAUNCHES.labels(path="split").inc()
-                _STEPS.labels(phase="prefill").inc()
-                _TOKENS.labels(phase="prefill").inc(n)
-        if req.prefill_pos < int(req.prompt.size):
-            return n, 0     # mid-prompt: the logits are never read
-        with _obs.span("serving.engine.sync"):
-            row = np.asarray(logits[0])
-        with _obs.span("serving.engine.sample"):
-            self._prefill_fifo.pop(0)
-            req.state = DECODE
-            # cache the full prompt pages BEFORE _emit can finish the
-            # request and return its pages — trie pins keep them warm
-            if self.prefix_cache is not None and self.prefix_cache_admit:
-                self.prefix_cache.insert(
-                    req.prompt, self.allocator.seq_pages(req.request_id))
-            finished = self._emit(req, int(np.argmax(row)), row)
-            if not finished and self.role == "prefill":
-                self._stage_handoff(req)
-        return n, finished
-
-    # ------------------------------------------------------------- decode
-    def _decode(self) -> Tuple[int, int]:
-        active = self.scheduler.active(DECODE)
-        if not active:
-            return 0, 0
-        with _obs.span("serving.engine.build"):
-            B = self.max_slots
-            tok = np.zeros(B, np.int32)
-            lengths = np.zeros(B, np.int32)
-            tables = np.zeros((B, self.pages_per_seq), np.int32)
-            for slot, req in active:
-                tok[slot] = req.pending
-                lengths[slot] = self.allocator.seq_length(req.request_id)
-                self._apply_copies(
-                    self.allocator.extend(req.request_id, 1), req)
-                tables[slot] = self.allocator.table(req.request_id)
-            self._counts["decode_rows"] += len(active)
-            self._counts["pages_live"] += int(np.sum(
-                -(-(lengths[lengths > 0] + 1) // self.page_size)))
-            self._counts["pages_visited"] += B * self.pages_per_seq
-        with _obs.span("serving.engine.launch"):
-            logits, = self._launch(
-                self._jit_decode, jnp.asarray(tok),
-                jnp.asarray(lengths), jnp.asarray(tables))
-            if _obs.enabled():
-                _LAUNCHES.labels(path="split").inc()
-                _STEPS.labels(phase="decode").inc()
-                _TOKENS.labels(phase="decode").inc(len(active))
-        with _obs.span("serving.engine.sync"):
-            logits = np.asarray(logits)
-        with _obs.span("serving.engine.sample"):
-            finished = 0
-            for slot, req in active:
-                finished += self._emit(req, int(np.argmax(logits[slot])),
-                                       logits[slot])
-        return len(active), finished
-
     # ------------------------------------------------------------ unified
     def _unified_step(self) -> None:
         """ONE ragged launch for the whole step: decode slot `s` owns
@@ -1671,11 +1544,9 @@ class ServingEngine:
         KV length is shrunk past the first mismatch — engine output is
         bit-identical to plain decode, just fewer launches.
 
-        Vs the split path: a request that completes its prefill emits
-        its first token from its chunk's launch and takes its first
-        decode step in the NEXT one (the split path decodes it the same
-        engine step) — per-request token sequences are identical, the
-        step count shifts."""
+        A request that completes its prefill emits its first token from
+        its chunk's launch and takes its first decode step in the NEXT
+        one."""
         prev = self._inflight
         preq = self._next_chunk_request(prev)
         rows = self._decode_rows(prev)
@@ -2121,20 +1992,6 @@ class ServingEngine:
             self._copy_pages(*pairs)
 
     # ----------------------------------------------------- jitted bodies
-    def _make_decode_body(self):
-        if self._family == "gpt":
-            return self._gpt_decode_body()
-        if self._family == "mla":
-            return self._mla_decode_body()
-        return self._llama_decode_body()
-
-    def _make_prefill_body(self):
-        if self._family == "gpt":
-            return self._gpt_prefill_body()
-        if self._family == "mla":
-            return self._mla_prefill_body()
-        return self._llama_prefill_body()
-
     def _make_unified_body(self):
         if self._family == "eva":
             return self._eva_unified_body()
@@ -2757,405 +2614,3 @@ class ServingEngine:
             return logits, new_pools, tokens
 
         return step
-
-    # -- llama / moe ---------------------------------------------------
-    def _llama_decode_body(self):
-        cfg = self._p["cfg"]
-        Hh, KV, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                     cfg.head_dim)
-        eps = cfg.rms_norm_eps
-        moe_static = self._p.get("moe_static")
-        from ..flags import flag, flags_guard
-        # pinned at engine construction like the cached bodies' flash
-        # pin: the jit traces lazily and must compile the impl this
-        # engine was built under
-        paged_impl = flag("FLAGS_paged_impl")
-
-        def rms(h, wt):
-            # routed through the fused Pallas kernel — same op order as
-            # the inline form (ulp-level), one HBM round-trip
-            return fused_rms_norm(h, wt, eps)
-
-        def step(w, tok, pools, lengths, tables):
-            B = tok.shape[0]
-            x = w["embed"][tok][:, None]                 # [B, 1, H]
-            c = w["cos"][lengths]                        # [B, D/2]
-            s = w["sin"][lengths]
-
-            def rope(t):                                 # [B, 1, h, D]
-                d2 = t.shape[-1] // 2
-                t1, t2 = t[..., :d2], t[..., d2:]
-                cc = c[:, None, None, :].astype(t.dtype)
-                ss = s[:, None, None, :].astype(t.dtype)
-                return jnp.concatenate(
-                    [t1 * cc - t2 * ss, t2 * cc + t1 * ss], -1)
-
-            new_pools = []
-            sts = moe_static or (None,) * len(w["layers"])
-            with flags_guard(paged_impl=paged_impl):  # paddlelint: disable=PT005
-                for L, (kp, vp), st in zip(w["layers"], pools, sts):
-                    h = rms(x, L["ln1"])
-                    q, k, v = (_mm_w(h, L, "wq"), _mm_w(h, L, "wk"),
-                               _mm_w(h, L, "wv"))
-                    if "bq" in L:
-                        q, k, v = q + L["bq"], k + L["bk"], v + L["bv"]
-                    q = rope(q.reshape(B, 1, Hh, D))
-                    k = rope(k.reshape(B, 1, KV, D))
-                    v = v.reshape(B, 1, KV, D)
-                    kp, vp, _ = append_to_cache(kp, vp, k[:, 0], v[:, 0],
-                                                lengths, tables)
-                    new_pools.append((kp, vp))
-                    o = paged_attention(q[:, 0], kp, vp, lengths + 1,
-                                        tables, scale=D ** -0.5)
-                    x = x + _mm_w(o.reshape(B, 1, Hh * D), L, "wo")
-                    h2 = rms(x, L["ln2"])
-                    x = x + _ffn_apply(L, h2, st)
-            x = rms(x, w["norm"])
-            last = x[:, -1]
-            return _head_logits(w, last), new_pools
-
-        return step
-
-    def _llama_prefill_body(self):
-        cfg = self._p["cfg"]
-        Hh, KV, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                     cfg.head_dim)
-        eps = cfg.rms_norm_eps
-        rep = Hh // KV
-        moe_static = self._p.get("moe_static")
-        C = self.prefill_chunk
-        ps, nj = self.page_size, self.pages_per_seq
-        T = nj * ps
-
-        def rms(h, wt):
-            # routed through the fused Pallas kernel — same op order as
-            # the inline form (ulp-level), one HBM round-trip
-            return fused_rms_norm(h, wt, eps)
-
-        def prefill(w, ids, pools, table, start, n_valid):
-            x = w["embed"][ids]                          # [1, C, H]
-            pos = start + jnp.arange(C)
-            posc = jnp.clip(pos, 0, w["cos"].shape[0] - 1)
-            c, s = w["cos"][posc], w["sin"][posc]        # [C, D/2]
-
-            def rope(t):                                 # [1, C, h, D]
-                d2 = t.shape[-1] // 2
-                t1, t2 = t[..., :d2], t[..., d2:]
-                cc = c[None, :, None, :].astype(t.dtype)
-                ss = s[None, :, None, :].astype(t.dtype)
-                return jnp.concatenate(
-                    [t1 * cc - t2 * ss, t2 * cc + t1 * ss], -1)
-
-            valid = jnp.arange(C) < n_valid
-            # pad positions write to the trash page; real positions to
-            # this sequence's pages
-            pg = jnp.where(valid, table[0, jnp.clip(pos // ps, 0, nj - 1)],
-                           0)
-            off = jnp.where(valid, pos % ps, 0)
-            pos_t = jnp.arange(T)
-            vis = pos_t[None, :] <= pos[:, None]         # [C, T]
-
-            def write(pages, new):                       # new [C, kv, D]
-                def body(pages, i):
-                    return pages.at[:, pg[i], off[i], :].set(new[i]), None
-                pages, _ = jax.lax.scan(body, pages, jnp.arange(C))
-                return pages
-
-            new_pools = []
-            sts = moe_static or (None,) * len(w["layers"])
-            for L, (kp, vp), st in zip(w["layers"], pools, sts):
-                h = rms(x, L["ln1"])
-                q, k, v = (_mm_w(h, L, "wq"), _mm_w(h, L, "wk"),
-                           _mm_w(h, L, "wv"))
-                if "bq" in L:
-                    q, k, v = q + L["bq"], k + L["bk"], v + L["bv"]
-                q = rope(q.reshape(1, C, Hh, D))
-                k = rope(k.reshape(1, C, KV, D))
-                v = v.reshape(1, C, KV, D)
-                kp = write(kp, k[0])
-                vp = write(vp, v[0])
-                new_pools.append((kp, vp))
-                ks = kp[:, table[0]].reshape(KV, T, D)
-                vs = vp[:, table[0]].reshape(KV, T, D)
-                qg = q.reshape(1, C, KV, rep, D)
-                scores = jnp.einsum("bsgrd,gtd->bgrst", qg, ks) \
-                    * (D ** -0.5)
-                scores = jnp.where(vis[None, None, None],
-                                   scores.astype(jnp.float32), -1e30)
-                aw = jax.nn.softmax(scores, axis=-1).astype(vs.dtype)
-                o = jnp.einsum("bgrst,gtd->bsgrd", aw, vs).reshape(
-                    1, C, Hh * D)
-                x = x + _mm_w(o, L, "wo")
-                h2 = rms(x, L["ln2"])
-                x = x + _ffn_apply(L, h2, st)
-            x = rms(x, w["norm"])
-            last = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0,
-                                                keepdims=False)[None]
-            return _head_logits(w, last), new_pools
-
-        return prefill
-
-    # -- gpt -----------------------------------------------------------
-    def _gpt_decode_body(self):
-        cfg = self._p["cfg"]
-        nh, hd = cfg.num_attention_heads, cfg.head_dim
-        eps = cfg.layer_norm_eps
-        from ..flags import flag, flags_guard
-        paged_impl = flag("FLAGS_paged_impl")
-
-        def ln(h, wt, b):
-            # routed through the fused Pallas kernel — same op order as
-            # the inline form (ulp-level), one HBM round-trip
-            return fused_layer_norm(h, wt, b, eps)
-
-        def step(w, tok, pools, lengths, tables):
-            B = tok.shape[0]
-            x = w["embed"][tok][:, None] + w["pos"][lengths][:, None]
-            new_pools = []
-            with flags_guard(paged_impl=paged_impl):  # paddlelint: disable=PT005
-                for L, (kp, vp) in zip(w["layers"], pools):
-                    h = ln(x, L["ln1w"], L["ln1b"])
-                    qkv = h @ L["wqkv"] + L["bqkv"]
-                    q, k, v = jnp.split(qkv, 3, axis=-1)
-                    q = q.reshape(B, 1, nh, hd)
-                    k = k.reshape(B, 1, nh, hd)
-                    v = v.reshape(B, 1, nh, hd)
-                    kp, vp, _ = append_to_cache(kp, vp, k[:, 0], v[:, 0],
-                                                lengths, tables)
-                    new_pools.append((kp, vp))
-                    o = paged_attention(q[:, 0], kp, vp, lengths + 1,
-                                        tables, scale=hd ** -0.5)
-                    x = x + (o.reshape(B, 1, nh * hd) @ L["wo"] + L["bo"])
-                    h2 = ln(x, L["ln2w"], L["ln2b"])
-                    x = x + (jax.nn.gelu(h2 @ L["wi"] + L["bi"],
-                                         approximate=True) @ L["wf"]
-                             + L["bf"])
-            x = ln(x, w["normw"], w["normb"])
-            last = x[:, -1]
-            return _head_logits(w, last), new_pools
-
-        return step
-
-    def _gpt_prefill_body(self):
-        cfg = self._p["cfg"]
-        nh, hd = cfg.num_attention_heads, cfg.head_dim
-        eps = cfg.layer_norm_eps
-        C = self.prefill_chunk
-        ps, nj = self.page_size, self.pages_per_seq
-        T = nj * ps
-
-        def ln(h, wt, b):
-            # routed through the fused Pallas kernel — same op order as
-            # the inline form (ulp-level), one HBM round-trip
-            return fused_layer_norm(h, wt, b, eps)
-
-        def prefill(w, ids, pools, table, start, n_valid):
-            pos = start + jnp.arange(C)
-            posc = jnp.clip(pos, 0, w["pos"].shape[0] - 1)
-            x = w["embed"][ids] + w["pos"][posc][None]
-            valid = jnp.arange(C) < n_valid
-            pg = jnp.where(valid, table[0, jnp.clip(pos // ps, 0, nj - 1)],
-                           0)
-            off = jnp.where(valid, pos % ps, 0)
-            pos_t = jnp.arange(T)
-            vis = pos_t[None, :] <= pos[:, None]
-
-            def write(pages, new):
-                def body(pages, i):
-                    return pages.at[:, pg[i], off[i], :].set(new[i]), None
-                pages, _ = jax.lax.scan(body, pages, jnp.arange(C))
-                return pages
-
-            new_pools = []
-            for L, (kp, vp) in zip(w["layers"], pools):
-                h = ln(x, L["ln1w"], L["ln1b"])
-                qkv = h @ L["wqkv"] + L["bqkv"]
-                q, k, v = jnp.split(qkv, 3, axis=-1)
-                q = q.reshape(1, C, nh, hd)
-                k = k.reshape(1, C, nh, hd)
-                v = v.reshape(1, C, nh, hd)
-                kp = write(kp, k[0])
-                vp = write(vp, v[0])
-                new_pools.append((kp, vp))
-                ks = kp[:, table[0]].reshape(nh, T, hd)
-                vs = vp[:, table[0]].reshape(nh, T, hd)
-                scores = jnp.einsum("bshd,htd->bhst", q, ks) \
-                    * (hd ** -0.5)
-                scores = jnp.where(vis[None, None],
-                                   scores.astype(jnp.float32), -1e30)
-                aw = jax.nn.softmax(scores, axis=-1).astype(vs.dtype)
-                o = jnp.einsum("bhst,htd->bshd", aw, vs).reshape(
-                    1, C, nh * hd)
-                x = x + (o @ L["wo"] + L["bo"])
-                h2 = ln(x, L["ln2w"], L["ln2b"])
-                x = x + (jax.nn.gelu(h2 @ L["wi"] + L["bi"],
-                                     approximate=True) @ L["wf"]
-                         + L["bf"])
-            x = ln(x, w["normw"], w["normb"])
-            last = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0,
-                                                keepdims=False)[None]
-            return _head_logits(w, last), new_pools
-
-        return prefill
-
-    # -- mla -----------------------------------------------------------
-    def _mla_decode_body(self):
-        cfg = self._p["cfg"]
-        nh = cfg.num_attention_heads
-        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                      cfg.v_head_dim)
-        r = cfg.kv_lora_rank
-        eps = cfg.rms_norm_eps
-        width = self._kv_geom[1]
-        scale = cfg.softmax_scale
-        moe_static = self._p.get("moe_static")
-        from ..flags import flag, flags_guard
-        paged_impl = flag("FLAGS_paged_impl")
-
-        def rms(h, wt):
-            # routed through the fused Pallas kernel — same op order as
-            # the inline form (ulp-level), one HBM round-trip
-            return fused_rms_norm(h, wt, eps)
-
-        def step(w, tok, pools, lengths, tables):
-            B = tok.shape[0]
-            x = w["embed"][tok][:, None]
-            c = w["cos"][lengths]                        # [B, dr/2]
-            s = w["sin"][lengths]
-
-            def rope(t):                                 # [B, 1, h, dr]
-                d2 = t.shape[-1] // 2
-                t1, t2 = t[..., :d2], t[..., d2:]
-                cc = c[:, None, None, :].astype(t.dtype)
-                ss = s[:, None, None, :].astype(t.dtype)
-                return jnp.concatenate(
-                    [t1 * cc - t2 * ss, t2 * cc + t1 * ss], -1)
-
-            new_pools = []
-            sts = moe_static or (None,) * len(w["layers"])
-            with flags_guard(paged_impl=paged_impl):  # paddlelint: disable=PT005
-                for L, pool, st in zip(w["layers"], pools, sts):
-                    h = rms(x, L["ln1"])
-                    if "wqa" in L or "wqa_q" in L or "wqa_q4" in L:
-                        q = _mm_w(rms(_mm_w(h, L, "wqa"), L["gq"]),
-                                  L, "wqb")
-                    else:
-                        q = _mm_w(h, L, "wq")
-                    q = q.reshape(B, 1, nh, dn + dr)
-                    q_nope, q_pe = q[..., :dn], q[..., dn:]
-                    q_pe = rope(q_pe)
-                    kv_a = _mm_w(h, L, "wkva")           # [B, 1, r+dr]
-                    lat = rms(kv_a[..., :r], L["gkv"])
-                    k_pe = rope(kv_a[..., r:][:, :, None, :])[:, :, 0]
-                    row = _pad_lanes(
-                        jnp.concatenate([lat, k_pe], -1)[:, 0], width)
-                    pool = append_to_cache(pool, pool, row[:, None],
-                                           row[:, None], lengths,
-                                           tables)[0]
-                    new_pools.append(pool)
-                    wkb = _dq(L, "wkvb", x.dtype).reshape(r, nh, dn + dv)
-                    w_k, w_v = wkb[..., :dn], wkb[..., dn:]
-                    # absorbed concat-dot: softmax((q_eff|q_pe)·row) over
-                    # rows [lat|k_pe]; the weighted row sum sliced to the
-                    # latent part IS the latent attention output
-                    q_eff = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_k)
-                    q_cat = _pad_lanes(
-                        jnp.concatenate([q_eff, q_pe], -1)[:, 0], width)
-                    o_cat = paged_attention(q_cat, pool, pool,
-                                            lengths + 1, tables,
-                                            scale=scale)
-                    o = jnp.einsum("bnr,rnv->bnv", o_cat[..., :r], w_v)
-                    x = x + _mm_w(o.reshape(B, 1, nh * dv), L, "wo")
-                    h2 = rms(x, L["ln2"])
-                    x = x + _ffn_apply(L, h2, st)
-            x = rms(x, w["norm"])
-            last = x[:, -1]
-            return _head_logits(w, last), new_pools
-
-        return step
-
-    def _mla_prefill_body(self):
-        cfg = self._p["cfg"]
-        nh = cfg.num_attention_heads
-        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                      cfg.v_head_dim)
-        r = cfg.kv_lora_rank
-        eps = cfg.rms_norm_eps
-        width = self._kv_geom[1]
-        scale = cfg.softmax_scale
-        moe_static = self._p.get("moe_static")
-        C = self.prefill_chunk
-        ps, nj = self.page_size, self.pages_per_seq
-        T = nj * ps
-
-        def rms(h, wt):
-            # routed through the fused Pallas kernel — same op order as
-            # the inline form (ulp-level), one HBM round-trip
-            return fused_rms_norm(h, wt, eps)
-
-        def prefill(w, ids, pools, table, start, n_valid):
-            x = w["embed"][ids]
-            pos = start + jnp.arange(C)
-            posc = jnp.clip(pos, 0, w["cos"].shape[0] - 1)
-            c, s = w["cos"][posc], w["sin"][posc]
-
-            def rope(t):                                 # [1, C, h, dr]
-                d2 = t.shape[-1] // 2
-                t1, t2 = t[..., :d2], t[..., d2:]
-                cc = c[None, :, None, :].astype(t.dtype)
-                ss = s[None, :, None, :].astype(t.dtype)
-                return jnp.concatenate(
-                    [t1 * cc - t2 * ss, t2 * cc + t1 * ss], -1)
-
-            valid = jnp.arange(C) < n_valid
-            pg = jnp.where(valid, table[0, jnp.clip(pos // ps, 0, nj - 1)],
-                           0)
-            off = jnp.where(valid, pos % ps, 0)
-            pos_t = jnp.arange(T)
-            vis = pos_t[None, :] <= pos[:, None]
-
-            def write(pages, new):                       # new [C, 1, Dc]
-                def body(pages, i):
-                    return pages.at[:, pg[i], off[i], :].set(new[i]), None
-                pages, _ = jax.lax.scan(body, pages, jnp.arange(C))
-                return pages
-
-            new_pools = []
-            sts = moe_static or (None,) * len(w["layers"])
-            for L, pool, st in zip(w["layers"], pools, sts):
-                h = rms(x, L["ln1"])
-                if "wqa" in L or "wqa_q" in L or "wqa_q4" in L:
-                    q = _mm_w(rms(_mm_w(h, L, "wqa"), L["gq"]), L, "wqb")
-                else:
-                    q = _mm_w(h, L, "wq")
-                q = q.reshape(1, C, nh, dn + dr)
-                q_nope, q_pe = q[..., :dn], q[..., dn:]
-                q_pe = rope(q_pe)
-                kv_a = _mm_w(h, L, "wkva")               # [1, C, r+dr]
-                lat = rms(kv_a[..., :r], L["gkv"])
-                k_pe = rope(kv_a[..., r:][:, :, None, :])[:, :, 0]
-                rows_new = _pad_lanes(
-                    jnp.concatenate([lat, k_pe], -1), width)  # [1, C, Dc]
-                pool = write(pool, rows_new[0][:, None])
-                new_pools.append(pool)
-                wkb = _dq(L, "wkvb", x.dtype).reshape(r, nh, dn + dv)
-                w_k, w_v = wkb[..., :dn], wkb[..., dn:]
-                q_eff = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_k)
-                q_cat = _pad_lanes(
-                    jnp.concatenate([q_eff, q_pe], -1), width)
-                rows = pool[0, table[0]].reshape(T, width)
-                scores = jnp.einsum("bsnd,td->bnst", q_cat, rows) * scale
-                scores = jnp.where(vis[None, None],
-                                   scores.astype(jnp.float32), -1e30)
-                aw = jax.nn.softmax(scores, axis=-1).astype(rows.dtype)
-                o_cat = jnp.einsum("bnst,td->bsnd", aw, rows)
-                o = jnp.einsum("bsnr,rnv->bsnv", o_cat[..., :r], w_v)
-                x = x + _mm_w(o.reshape(1, C, nh * dv), L, "wo")
-                h2 = rms(x, L["ln2"])
-                x = x + _ffn_apply(L, h2, st)
-            x = rms(x, w["norm"])
-            last = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0,
-                                                keepdims=False)[None]
-            return _head_logits(w, last), new_pools
-
-        return prefill

@@ -47,8 +47,6 @@ SHAPES = {
                        block_q=128, block_k=128),
     "flashmask_sdpa": dict(B=2, H=3, Sq=256, Sk=512, D=64,
                            block_q=128, block_k=128),
-    "paged_decode_attention": dict(B=2, H=4, KV=1, D=128, context=128,
-                                   page_size=16),
     "paged_decode_attention_v2": dict(B=2, H=4, KV=1, D=128, context=128,
                                       page_size=16),
     "mla_decode_attention": dict(B=2, nh=16, r=512, dr=64, context=256),
@@ -127,23 +125,29 @@ def _one(sites, qualname):
 
 
 class TestBlockSpecConsistency:
-    def test_paged_v1_bytes_match_block_specs(self, sites):
+    def test_paged_bytes_match_block_specs(self, sites):
+        # q and the output ride BlockSpecs; K/V stay in HBM behind the
+        # kernel's page DMAs, so their bytes are the table's pages, each
+        # once a (sequence, KV head), K and V
         _, ss = sites
-        site = _one(ss, "paged_decode_attention")
-        b = dict(B=2, KV=1, rep=4, nj=8, page_size=16, D=128)
+        site = _one(ss, "paged_decode_attention_v2")
+        B, KV, D, psz, pages = 2, 1, 128, 16, 8
+        b = dict(B=B, KV=KV, rep=4, page_size=psz, D=D,
+                 pages_per_group=2, total_pages=8)
         got = km.transfer_bytes(site, b, [BF16] * 3, [BF16])
-        assert got is not None and None not in got["in"] + got["out"]
-        est = cm.cost("paged_decode_attention", B=2, H=4, KV=1, D=128,
-                      context=8 * 16, page_size=16, pages_per_seq=8)
-        q, k, v = got["in"]
-        assert q + k + v == est.bytes_read
+        assert got is not None
+        q = got["in"][0]
+        est = cm.cost("paged_decode_attention_v2", B=B, H=4, KV=KV, D=D,
+                      context=pages * psz, page_size=psz,
+                      pages_per_seq=pages)
+        assert q + got["out"][0] == est.breakdown["activations"]
         assert got["out"][0] == est.bytes_written
-        assert k + v == est.breakdown["kv"]
+        assert est.breakdown["kv"] == 2 * B * KV * pages * psz * D * BF16
+        assert est.bytes_read == q + est.breakdown["kv"]
 
     def test_paged_v2_any_specs_opt_out(self, sites):
-        # v2 keeps K/V in HBM behind manual DMA (memory_space=ANY): the
-        # evaluator must SKIP those specs, which is why the paged cost
-        # family is cross-checked against the v1 grid
+        # K/V stay in HBM behind manual DMA (memory_space=ANY): the
+        # evaluator must SKIP those specs
         _, ss = sites
         site = _one(ss, "paged_decode_attention_v2")
         b = dict(B=2, KV=1, rep=4, page_size=16, D=128,
@@ -268,9 +272,8 @@ class TestBlockSpecConsistency:
 
     def test_grids_evaluate_for_all_three_sites(self, sites):
         _, ss = sites
-        v1 = _one(ss, "paged_decode_attention")
-        assert km.grid_values(
-            v1, dict(B=2, KV=1, nj=8)) == [2, 1, 8]
+        paged = _one(ss, "paged_decode_attention_v2")
+        assert km.grid_values(paged, dict(B=2, KV=1)) == [2, 1]
         rag = _one(ss, "ragged_paged_attention")
         assert km.grid_values(
             rag, dict(KV=1, hb=1, n_cells=3)) == [1, 3]

@@ -53,9 +53,26 @@ class FailingDataset(SquareDataset):
 
 
 class WorkerInfoDataset(SquareDataset):
+    """Items report the worker that served them. The workers pull from
+    ONE task queue, so the first one up can serve every batch before a
+    slower one has started (spawn, which the loader takes once this
+    process has a jax backend, starts a worker in seconds): an item
+    therefore waits until every worker's `InitMarker` file is in
+    `directory`, with a limit sized for a loaded host. The worker that
+    waits holds one task, so each of the others takes one too."""
+
+    def __init__(self, n, directory):
+        super().__init__(n)
+        self.directory = str(directory)
+
     def __getitem__(self, i):
         info = get_worker_info()
         assert info is not None and info.num_workers == 2
+        limit = time.monotonic() + 120
+        while not all((pathlib.Path(self.directory) / f"init{w}").exists()
+                      for w in range(info.num_workers)):
+            assert time.monotonic() < limit, "a worker never started"
+            time.sleep(0.01)
         return np.asarray([i, info.id], np.int64)
 
 
@@ -112,8 +129,9 @@ class TestProcessWorkers:
             np.testing.assert_array_equal(a, b)
 
     def test_worker_info_and_init_fn(self, tmp_path):
-        out = _collect(DataLoader(WorkerInfoDataset(8), batch_size=2,
-                                  num_workers=2, worker_mode="process",
+        out = _collect(DataLoader(WorkerInfoDataset(8, tmp_path),
+                                  batch_size=2, num_workers=2,
+                                  worker_mode="process",
                                   worker_init_fn=InitMarker(tmp_path)))
         ids = np.concatenate([o[:, 1] for o in out])
         assert set(ids.tolist()) == {0, 1}

@@ -1,10 +1,9 @@
-"""`ServingEngine` chooses its programs itself and runs ONE layer chain
-(ISSUE 30): no constructor argument selects an implementation; the
-unified ragged step is built where `_ragged_step_eligible` says the
-kernel tiles and the split pair where it does not (a test answers for
-that one function); what is lowered calls neither fused half and reads
-the model's own projections; and the names `benchmarks/` reads of the
-engine are there."""
+"""`ServingEngine` has ONE step program and runs ONE layer chain: no
+constructor argument selects an implementation; a shape for which
+`_ragged_step_eligible` says the kernel does not tile is refused by name
+(a test answers for that one function); what is lowered calls neither
+fused half and reads the model's own projections; and the names
+`benchmarks/` reads of the engine are there."""
 
 import jax
 import jax.numpy as jnp
@@ -65,11 +64,11 @@ def _quantized_trace_exact(m, seed, quant, n=3):
 
 
 class TestRaggedPath:
-    """The engine chooses its programs itself (`_ragged_step_eligible`,
-    asked once at construction; no constructor argument): the unified
-    ragged step where the kernel tiles, the split pair where it does
-    not. Split-path parity, strictly fewer launches, and the quantized
-    families on the one chain."""
+    """The engine has one step program, the unified ragged step
+    (`_ragged_step_eligible` is asked once at construction; no
+    constructor argument): a shape the kernel does not tile is refused
+    by name, a draft length is never dropped, and the quantized
+    families run on the one chain."""
 
     ARGS = dict(max_slots=2, page_size=4, prefill_chunk=4)
 
@@ -77,48 +76,43 @@ class TestRaggedPath:
     def model(self):
         return _tiny("llama")
 
-    @pytest.fixture
-    def no_ragged_kernel(self, monkeypatch):
-        """What a chip answers for a head width that is not 64 or a
-        multiple of 128: the test steers the one function the engine
-        consults, not an option of the program."""
-        monkeypatch.setattr(engine_mod, "_ragged_step_eligible",
-                            lambda *a: False)
-
-    def test_split_path_still_exact(self, model, no_ragged_kernel):
-        # the alternating prefill/decode pair, where the kernel is out
-        V = model.config.vocab_size
-        results, ref, eng = _run_trace(model, V, 4, seed=6, **self.ARGS)
-        assert not eng.ragged
-        assert set(eng.program_cache_sizes()) == {"decode", "prefill"}
-        for rid in ref:
-            np.testing.assert_array_equal(results[rid], ref[rid])
-        assert all(v == 1 for v in eng.program_cache_sizes().values())
-
     @pytest.mark.parametrize("family", ["llama", "gpt", "mla", "moe"])
-    def test_ragged_matches_split(self, family, monkeypatch):
+    def test_an_untileable_shape_is_refused(self, family, monkeypatch):
+        # what a chip answers for a head width that is not 64 or a
+        # multiple of 128: the test steers the one function the engine
+        # consults, not an option of the program
         m = _tiny(family)
-        V = m.config.vocab_size
-        r1, _, e1 = _run_trace(m, V, 5, seed=7, **self.ARGS)
         monkeypatch.setattr(engine_mod, "_ragged_step_eligible",
                             lambda *a: False)
-        r2, _, e2 = _run_trace(m, V, 5, seed=7, spec_decode=2, **self.ARGS)
-        assert e1.ragged and not e2.ragged
-        assert set(e1.program_cache_sizes()) == {"unified", "feed"}
-        assert e2.spec_k == 0       # the split pair has no multi-row slots
-        assert set(r1) == set(r2)
-        for rid in r1:
-            np.testing.assert_array_equal(r1[rid], r2[rid])
+        cfg = m.config
+        if family == "gpt":
+            kv, d = cfg.num_attention_heads, cfg.head_dim
+        elif family == "mla":   # one latent row: toy ranks are not padded
+            kv, d = 1, cfg.kv_lora_rank + cfg.qk_rope_head_dim
+        else:
+            kv, d = cfg.num_key_value_heads, cfg.head_dim
+        with pytest.raises(ValueError) as e:
+            ServingEngine(m, **self.ARGS)
+        said = str(e.value)
+        assert "unified ragged step only" in said
+        for part in (f"heads [{cfg.num_attention_heads}]",
+                     f"{kv} KV heads", f"width {d}", "pages of 4"):
+            assert part in said, (part, said)
 
-    def test_unified_strictly_fewer_launches(self, model, monkeypatch):
-        # a trace with overlapping prefill+decode work: the split path
-        # pays two launches on every such step, the unified path one
-        V = model.config.vocab_size
-        _, _, e1 = _run_trace(model, V, 6, seed=8, **self.ARGS)
-        monkeypatch.setattr(engine_mod, "_ragged_step_eligible",
-                            lambda *a: False)
-        _, _, e2 = _run_trace(model, V, 6, seed=8, **self.ARGS)
-        assert e1.launches < e2.launches
+    def test_a_nonzero_spec_decode_is_kept_or_refused(self, model,
+                                                      monkeypatch):
+        # whatever the kernel answers and whatever the family: a
+        # constructor that returns has the draft length it was given
+        models = (model, _laguna())
+        for eligible in (True, False):
+            monkeypatch.setattr(engine_mod, "_ragged_step_eligible",
+                                lambda *a, _e=eligible: _e)
+            for m in models:
+                try:
+                    eng = ServingEngine(m, spec_decode=2, **self.ARGS)
+                except ValueError:
+                    continue
+                assert eng.spec_k == 2
 
     def test_launches_metric_series(self, model):
         from paddle_tpu import serving as srv
@@ -128,7 +122,7 @@ class TestRaggedPath:
         paths = {s["labels"]["path"]: s["value"]
                  for s in m["serving.engine.launches"]["series"]}
         assert paths.get("unified", 0) >= 1
-        assert set(paths) <= {"unified", "split"}
+        assert set(paths) == {"unified"}
 
     @pytest.mark.parametrize("switch", ["ragged", "megafront",
                                         "megadecode"])

@@ -10,8 +10,7 @@ from paddle_tpu.ops.fused import (fused_layer_norm, fused_rms_norm,
                                   fused_rope, swiglu)
 from paddle_tpu.ops.quant import (weight_only_linear, weight_quantize,
                                   weight_dequantize)
-from paddle_tpu.ops.paged_attention import (append_to_cache,
-                                            paged_attention,
+from paddle_tpu.ops.paged_attention import (paged_attention,
                                             paged_attention_reference)
 
 
@@ -220,19 +219,3 @@ class TestPagedAttention:
         out = paged_attention(q, kp, vp, lengths, pi)
         assert out.shape == q.shape
         assert np.isfinite(np.asarray(out)).all()
-
-    def test_append_to_cache(self):
-        q, kp, vp, lengths, pi = self._setup(seed=23)
-        B = q.shape[0]
-        KV, D = kp.shape[0], kp.shape[-1]
-        k_new = jnp.ones((B, KV, D), jnp.float32)
-        v_new = 2 * jnp.ones((B, KV, D), jnp.float32)
-        kp2, vp2, l2 = append_to_cache(kp, vp, k_new, v_new, lengths, pi)
-        assert list(np.asarray(l2)) == [8, 11]
-        # the written slot holds the new value
-        b = 0
-        slot = int(lengths[b])
-        page = int(pi[b, slot // kp.shape[2]])
-        off = slot % kp.shape[2]
-        np.testing.assert_allclose(np.asarray(kp2[:, page, off]), 1.0)
-        np.testing.assert_allclose(np.asarray(vp2[:, page, off]), 2.0)

@@ -29,9 +29,8 @@ EXPECTED = {
     "fused_rope", "fused_rope_append", "fused_append_rows", "swiglu",
     "mla_decode_attention", "gmm", "int4_dequantize",
     "weight_only_linear", "flash_sdpa", "flashmask_sdpa",
-    "paged_decode_attention", "paged_decode_attention_v2",
-    "ragged_paged_attention", "fused_oproj_norm", "fused_ffn",
-    "fused_qkv_rope_append",
+    "paged_decode_attention_v2", "ragged_paged_attention",
+    "fused_oproj_norm", "fused_ffn", "fused_qkv_rope_append",
 }
 
 

@@ -259,7 +259,7 @@ def test_the_cached_generate_path_refuses_the_family(tiny):
         _decode_params(m, weight_only_int8=True)
 
 
-def test_the_split_programs_refuse_the_family(tiny, monkeypatch):
+def test_an_untileable_shape_is_refused(tiny, monkeypatch):
     from paddle_tpu.serving import engine as eng_mod
     m, _, _ = tiny
     monkeypatch.setattr(eng_mod, "_ragged_step_eligible",

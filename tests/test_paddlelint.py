@@ -1278,7 +1278,6 @@ class TestSeededKernelDefects:
 
     RAGGED = "paddle_tpu/ops/pallas_ragged.py"
     FUSED = "paddle_tpu/ops/fused.py"
-    PAGED = "paddle_tpu/ops/pallas_paged.py"
 
     def _analyze(self, tmp_path, rel, tag, old="", new="", append=""):
         src = open(os.path.join(REPO, rel)).read()
@@ -1305,15 +1304,14 @@ class TestSeededKernelDefects:
                 == [], rel
 
     def test_pk101_catches_unclamped_page_table_read(self, tmp_path):
-        # the decode kernel's page map (the ragged kernel reads its
-        # table inside the kernel, for its own page DMAs)
+        # the row append's page map (the attention kernels read their
+        # tables inside the kernel, for their own page DMAs)
         fresh = self._seed(
-            tmp_path, self.PAGED,
-            old="phys = jnp.clip(tab[b, jnp.minimum(j, jmax)], 0, "
-                "total_pages - 1)",
-            new="phys = tab[b, jnp.minimum(j, jmax)]")
+            tmp_path, self.FUSED,
+            old="return (0, jnp.clip(pg[t], 0, total - 1), 0, 0)",
+            new="return (0, pg[t], 0, 0)")
         assert fresh and {f.rule for f in fresh} == {"PK101"}
-        assert all("tab" in f.detail for f in fresh)
+        assert all("pg" in f.detail for f in fresh)
 
     def test_pk103_catches_widened_alias_dtype(self, tmp_path):
         fresh = self._seed(

@@ -10,7 +10,8 @@ import pytest
 
 from paddle_tpu.ops.paged_attention import (paged_attention,
                                             paged_attention_reference)
-from paddle_tpu.ops.pallas_paged import (paged_decode_attention,
+from paddle_tpu.ops import paged_attention as routing
+from paddle_tpu.ops.pallas_paged import (paged_decode_attention_v2,
                                          paged_kernel_eligible)
 
 
@@ -35,7 +36,7 @@ class TestPagedKernelParity:
     ])
     def test_matches_reference(self, B, H, KV, D, psz, pps):
         q, kp, vp, lens, tab = _setup(B, H, KV, D, psz, pps)
-        out = paged_decode_attention(q, kp, vp, lens, tab)
+        out = paged_decode_attention_v2(q, kp, vp, lens, tab)
         ref = paged_attention_reference(q, kp, vp, lens, tab)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
@@ -44,7 +45,7 @@ class TestPagedKernelParity:
         # lens=1: only the first slot of the first page is visible
         q, kp, vp, _, tab = _setup(2, 4, 2, 128, 16, 4, seed=3)
         lens = jnp.asarray([1, 1], jnp.int32)
-        out = paged_decode_attention(q, kp, vp, lens, tab)
+        out = paged_decode_attention_v2(q, kp, vp, lens, tab)
         ref = paged_attention_reference(q, kp, vp, lens, tab)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
@@ -52,7 +53,7 @@ class TestPagedKernelParity:
     def test_bf16(self):
         q, kp, vp, lens, tab = _setup(2, 8, 2, 128, 16, 4, seed=5,
                                       dtype=jnp.bfloat16)
-        out = paged_decode_attention(q, kp, vp, lens, tab)
+        out = paged_decode_attention_v2(q, kp, vp, lens, tab)
         ref = paged_attention_reference(q, kp, vp, lens, tab)
         assert out.dtype == jnp.bfloat16
         np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -61,36 +62,36 @@ class TestPagedKernelParity:
 
     def test_custom_scale(self):
         q, kp, vp, lens, tab = _setup(2, 4, 2, 128, 16, 4, seed=7)
-        out = paged_decode_attention(q, kp, vp, lens, tab, scale=0.05)
+        out = paged_decode_attention_v2(q, kp, vp, lens, tab, scale=0.05)
         ref = paged_attention_reference(q, kp, vp, lens, tab, scale=0.05)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
 
 class TestRouting:
-    def test_default_routes_intree(self):
-        from paddle_tpu.flags import flag
-        assert flag("FLAGS_paged_impl") == "intree"
+    """`paged_attention` chooses from the shapes: the kernel where they
+    tile, the XLA composite where they do not."""
+
+    @pytest.fixture
+    def routed(self, monkeypatch):
+        names = []
+        monkeypatch.setattr(routing, "_count_kernel", names.append)
+        return names
+
+    def test_shapes_that_tile_route_to_the_kernel(self, routed):
         q, kp, vp, lens, tab = _setup(2, 4, 2, 128, 16, 4)
         out = paged_attention(q, kp, vp, lens, tab)
+        assert routed == ["paged_intree"]
         ref = paged_attention_reference(q, kp, vp, lens, tab)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
-    def test_ineligible_falls_back(self):
+    def test_ineligible_falls_back(self, routed):
         # D=96 is not MXU-eligible; the route must still be correct
         q, kp, vp, lens, tab = _setup(2, 4, 2, 96, 16, 4)
         assert not paged_kernel_eligible(4, 2, 96, 16)
         out = paged_attention(q, kp, vp, lens, tab)
-        ref = paged_attention_reference(q, kp, vp, lens, tab)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=2e-5)
-
-    def test_flag_reference_impl(self):
-        from paddle_tpu.flags import flags_guard
-        q, kp, vp, lens, tab = _setup(2, 4, 2, 128, 16, 4)
-        with flags_guard(paged_impl="reference"):
-            out = paged_attention(q, kp, vp, lens, tab)
+        assert routed == ["paged_reference"]
         ref = paged_attention_reference(q, kp, vp, lens, tab)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
@@ -103,7 +104,6 @@ class TestPagedV2GroupedDMA:
 
     @pytest.mark.parametrize("G", [1, 3, 4])
     def test_parity_group_sizes(self, G):
-        from paddle_tpu.ops.pallas_paged import paged_decode_attention_v2
         q, kp, vp, lens, tab = _setup(B=3, H=4, KV=2, D=128, psz=16,
                                       pages_per_seq=8, seed=3)
         out = paged_decode_attention_v2(q, kp, vp, lens, tab,
@@ -113,7 +113,6 @@ class TestPagedV2GroupedDMA:
                                    rtol=2e-5, atol=2e-5)
 
     def test_zero_length_and_full_length_rows(self):
-        from paddle_tpu.ops.pallas_paged import paged_decode_attention_v2
         q, kp, vp, _, tab = _setup(B=2, H=4, KV=1, D=128, psz=16,
                                    pages_per_seq=4, seed=5)
         lens = jnp.asarray([0, 64], jnp.int32)
@@ -126,7 +125,6 @@ class TestPagedV2GroupedDMA:
 
     def test_ragged_group_tail(self):
         # pages_per_seq not divisible by the group size
-        from paddle_tpu.ops.pallas_paged import paged_decode_attention_v2
         q, kp, vp, lens, tab = _setup(B=2, H=2, KV=2, D=128, psz=16,
                                       pages_per_seq=7, seed=7)
         out = paged_decode_attention_v2(q, kp, vp, lens, tab,
@@ -140,13 +138,3 @@ class TestPagedV2GroupedDMA:
         assert default_pages_per_group(256, 16) == 16    # 4k ctx
         assert default_pages_per_group(1024, 16) == 32   # 16k ctx
         assert default_pages_per_group(512, 32) == 32    # 16k ctx
-
-    def test_intree_routing_uses_v2(self):
-        from paddle_tpu.flags import flags_guard
-        q, kp, vp, lens, tab = _setup(B=2, H=4, KV=2, D=128, psz=16,
-                                      pages_per_seq=4, seed=9)
-        with flags_guard(paged_impl="intree"):
-            out = paged_attention(q, kp, vp, lens, tab)
-        ref = paged_attention_reference(q, kp, vp, lens, tab)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)

@@ -18,47 +18,66 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.observability import tracing
 from paddle_tpu.serving import ServingEngine
-from paddle_tpu.serving import engine as engine_mod
+from paddle_tpu.serving.block_allocator import ChunkSummaryAllocator
 from paddle_tpu.serving.scheduler import DECODE
+from test_engine_programs import _laguna, _tiny
 
 FAMILIES = ("llama", "gpt", "mla")
-PATHS = ("unified", "split")
-CASES = [(f, p) for f in FAMILIES for p in PATHS]
-IDS = [f"{f}-{p}" for f, p in CASES]
+ARGS = dict(max_slots=2, page_size=4, prefill_chunk=4)
 
 
-def _build(family):
+def _structure(name):
+    """(a toy model whose engine keeps pools of another structure than a
+    (K, V) pair a layer, the engine arguments it asks for, what says
+    that an engine holds that structure)."""
     paddle.seed(0)
-    if family == "gpt":
-        from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny_config
-        m = GPTForCausalLM(gpt_tiny_config(max_position_embeddings=64))
-    elif family == "mla":
-        from paddle_tpu.models.deepseek import (DeepSeekV2ForCausalLM,
-                                                deepseek_v2_tiny_config)
-        m = DeepSeekV2ForCausalLM(deepseek_v2_tiny_config(
-            moe_dropless=True, num_hidden_layers=2))
-    else:
-        from paddle_tpu.models.llama import (LlamaForCausalLM,
-                                             llama_tiny_config)
-        m = LlamaForCausalLM(llama_tiny_config(num_hidden_layers=2))
+    if name == "laguna":        # two kinds of pool: every page / a window's
+        return (_laguna(),
+                dict(max_slots=2, page_size=8, max_context=64,
+                     prefill_chunk=8),
+                lambda eng: eng.num_window_pages > 0 and len(
+                    {kp.shape[1] for kp, _ in eng._pools}) == 2)
+    if name == "eva":           # one pool, two page lists a sequence
+        from paddle_tpu.models.evabyte import (EvaByteForCausalLM,
+                                               evabyte_tiny_config)
+        m = EvaByteForCausalLM(evabyte_tiny_config())
+        args = dict(max_slots=2, page_size=8, max_context=256,
+                    prefill_chunk=8, num_pages=64)
+
+        def holds(eng):
+            return isinstance(eng.allocator, ChunkSummaryAllocator) \
+                and len(eng._pools) == len(eng._p["layers"])
+    elif name == "looped":      # a page id names a page of every pass
+        from paddle_tpu.models.ouro import OuroForCausalLM, ouro_tiny_config
+        m = OuroForCausalLM(ouro_tiny_config())
+        args = dict(max_slots=2, page_size=8, max_context=128,
+                    prefill_chunk=8, num_pages=24)
+
+        def holds(eng):
+            return eng._passes > 1 and all(
+                kp.shape[1] == eng._passes * eng.num_pages
+                for kp, _ in eng._pools)
+    else:                       # pages beside slot-indexed states
+        from paddle_tpu.models.nemotron_h import (NemotronHForCausalLM,
+                                                  nemotron_h_tiny_config)
+        m = NemotronHForCausalLM(nemotron_h_tiny_config())
+        args = dict(max_slots=2, page_size=8, max_context=128,
+                    prefill_chunk=16, num_pages=40)
+
+        def holds(eng):
+            return set(eng._pools) == {"kv", "ssm"} \
+                and len(eng._pools["ssm"]) > 0
     m.eval()
-    return m
+    return m, args, holds
 
 
 @pytest.fixture(scope="module")
 def models():
-    return functools.cache(_build)
+    return functools.cache(_tiny)
 
 
-def _engine(model, path, **kw):
-    args = dict(max_slots=2, page_size=4, prefill_chunk=4)
-    args.update(kw)
-    with pytest.MonkeyPatch.context() as mp:
-        # the engine asks once, at construction, whether the ragged
-        # kernel tiles here: the test answers for it
-        mp.setattr(engine_mod, "_ragged_step_eligible",
-                   lambda *a: path == "unified")
-        return ServingEngine(model, **args)
+def _engine(model, **kw):
+    return ServingEngine(model, **dict(ARGS, **kw))
 
 
 def _without_ownership(eng):
@@ -66,14 +85,8 @@ def _without_ownership(eng):
     programs `_build_programs` built before ISSUE 29 — now and after
     every `reconfigure()`."""
     def build():
-        if eng.ragged:
-            eng._jit_unified = jax.jit(eng._make_unified_body())
-            eng._programs = {"unified": eng._jit_unified}
-        else:
-            eng._jit_decode = jax.jit(eng._make_decode_body())
-            eng._jit_prefill = jax.jit(eng._make_prefill_body())
-            eng._programs = {"decode": eng._jit_decode,
-                             "prefill": eng._jit_prefill}
+        eng._jit_unified = jax.jit(eng._make_unified_body())
+        eng._programs = {"unified": eng._jit_unified}
     eng._build_programs = build
     build()
     return eng
@@ -83,10 +96,13 @@ def _records(eng):
     return tracing.recorder().steps()[-eng.steps:]
 
 
-@pytest.mark.parametrize("family,path", CASES, ids=IDS)
-def test_a_step_consumes_the_pools_it_was_handed(models, family, path):
-    m = models(family)
-    eng = _engine(m, path)
+@pytest.mark.parametrize(
+    "family", FAMILIES + ("laguna", "eva", "looped", "hybrid"))
+def test_a_step_consumes_the_pools_it_was_handed(models, family):
+    m, args, holds = (models(family), {}, None) if family in FAMILIES \
+        else _structure(family)
+    eng = _engine(m, **args)
+    assert holds is None or holds(eng)
     eng.add_request(np.arange(1, 10, dtype=np.int32), max_new_tokens=4)
     launched = []
     while eng.has_work():
@@ -141,7 +157,7 @@ def _scenario(eng, vocab):
     # between two steps "a" leaves through a handoff and comes back
     eng.import_request(eng.export_request(a))
     steps()
-    eng.reconfigure(prefill_chunk=8, spec_decode=1 if eng.ragged else 0)
+    eng.reconfigure(prefill_chunk=8, spec_decode=1)
     steps()
     # both slots are taken: the high-priority arrival preempts one
     eng.add_request(rng.randint(0, vocab, 5).astype(np.int32),
@@ -159,14 +175,14 @@ def _scenario(eng, vocab):
     return {k: np.asarray(v) for k, v in out.items()}, happened
 
 
-@pytest.mark.parametrize("family,path", CASES, ids=IDS)
+@pytest.mark.parametrize("family", FAMILIES)
 def test_tokens_are_those_of_programs_that_do_not_own_the_pools(
-        models, family, path):
+        models, family):
     m = models(family)
     V = m.config.vocab_size
-    kw = dict(spec_decode=2) if path == "unified" else {}
-    got, did = _scenario(_engine(m, path, **kw), V)
-    want, before = _scenario(_without_ownership(_engine(m, path, **kw)), V)
+    got, did = _scenario(_engine(m, spec_decode=2), V)
+    want, before = _scenario(
+        _without_ownership(_engine(m, spec_decode=2)), V)
     assert set(got) == {"a", "b", "c"} == set(want)
     for rid in want:
         np.testing.assert_array_equal(got[rid], want[rid])
@@ -174,57 +190,50 @@ def test_tokens_are_those_of_programs_that_do_not_own_the_pools(
     assert did["cow_pages"] >= 1 and did["preempted"] >= 1
     assert did["handoffs"] == {"export": 1, "import": 1}
     assert did["rebuilds"] == 1
-    if path == "unified":
-        assert did["drafts_rolled_back"] >= 1
+    assert did["drafts_rolled_back"] >= 1
     assert did["in_place"] == {1} and before["in_place"] == {0}
     did.pop("in_place"), before.pop("in_place")
     assert did == before
 
 
-@pytest.mark.parametrize("path", PATHS)
-def test_a_launch_that_took_the_pools_and_raised_ends_the_engine(
-        models, path):
+def test_a_launch_that_took_the_pools_and_raised_ends_the_engine(models):
     """Chosen behaviour (CHANGES.md, PR 29): the pools are not rebuilt —
     their contents, every live request's cache, are gone with them — so
     the engine says that it cannot run again, at the next step and at
     every other reader of the pools."""
-    eng = _engine(models("llama"), path)
+    eng = _engine(models("llama"))
     req = eng.add_request(np.arange(1, 8, dtype=np.int32), max_new_tokens=6)
     while req.state != DECODE:
         eng.step()
-    name = "_jit_unified" if eng.ragged else "_jit_decode"
-    program = getattr(eng, name)
+    program = eng._jit_unified
 
     def took_them_then_raised(w, tok, pools, *tables):
         for a in jax.tree.leaves(pools):
             a.delete()
         raise RuntimeError("device fault")
 
-    setattr(eng, name, took_them_then_raised)
+    eng._jit_unified = took_them_then_raised
     with pytest.raises(RuntimeError, match="device fault"):
         eng.step()
-    setattr(eng, name, program)
+    eng._jit_unified = program
     for use in (eng.step, lambda: eng.export_request(req)):
         with pytest.raises(RuntimeError, match="pools were lost"):
             use()
 
 
-@pytest.mark.parametrize("path", PATHS)
-def test_a_launch_that_raised_before_it_took_the_pools_keeps_them(
-        models, path):
-    eng = _engine(models("llama"), path)
+def test_a_launch_that_raised_before_it_took_the_pools_keeps_them(models):
+    eng = _engine(models("llama"))
     eng.add_request(np.arange(1, 8, dtype=np.int32), max_new_tokens=3)
-    name = "_jit_unified" if eng.ragged else "_jit_prefill"
-    program = getattr(eng, name)
+    program = eng._jit_unified
 
     def refused(*args):
         raise ValueError("refused before dispatch")
 
-    setattr(eng, name, refused)
+    eng._jit_unified = refused
     handed = jax.tree.leaves(eng._pools)
     with pytest.raises(ValueError, match="refused before dispatch"):
         eng.step()
-    setattr(eng, name, program)
+    eng._jit_unified = program
     kept = jax.tree.leaves(eng._live_pools())
     assert len(kept) == len(handed)
     assert all(a is b for a, b in zip(kept, handed))
@@ -238,7 +247,7 @@ def test_a_copy_on_write_compiles_nothing_when_it_comes(models):
     comes, mid-serving, nothing compiles (a compile inside a measured
     window makes a benchmark run incorrect: PERF.md, PR 33)."""
     m = models("llama")
-    eng = _engine(m, "unified")
+    eng = _engine(m)
     rng = np.random.RandomState(5)
     V = m.config.vocab_size
     first = rng.randint(0, V, 12).astype(np.int32)

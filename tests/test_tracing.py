@@ -399,14 +399,9 @@ class TestStepTimeline:
             outs.append((out, eng.allocator.stats()["pages_used"]))
         return outs
 
-    @pytest.mark.parametrize("ragged", [True, False])
-    def test_one_record_per_step_with_counts(self, ragged, monkeypatch):
-        # the engine asks once whether the ragged kernel tiles here
-        monkeypatch.setattr(srv.engine, "_ragged_step_eligible",
-                            lambda *a: ragged)
+    def test_one_record_per_step_with_counts(self):
         eng, cfg = _tiny_engine(prefix_sharing=False,
                                 enable_prefix_cache=False)
-        assert eng.ragged is ragged
         outs = self._script(eng, cfg)
         steps = tr.recorder().steps()
         assert [s["seq"] for s in steps] == list(range(1, len(outs) + 1))
@@ -419,55 +414,40 @@ class TestStepTimeline:
             _inside_and_disjoint((s["start_ns"], s["end_ns"]),
                                  [(a, b) for _, a, b in s["phases"]])
             names = [n for n, _, _ in s["phases"]]
-            assert names[0] == PHASES[0] and names[-1] == PHASES[-1]
-            assert set(names) <= set(PHASES)
-            if ragged:
-                assert names == PHASES
+            assert names == PHASES
             assert s["decode_rows"] == out["decoded"]
             assert s["prefill_rows"] == out["prefill_tokens"]
             assert s["admitted"] == out["admitted"]
             assert s["finished"] == out["finished"]
             assert s["pool_pages_used"] == pages_used
             assert s["pool_pages_total"] == eng.num_pages - 1
-            if ragged:
-                # the record describes the launch this call RETIRED,
-                # queued by the call before (ISSUE 34), like the counts
-                # `step()` returns. Nothing shared: its live pages are
-                # the allocator's own count when it was queued (a
-                # request that ended in that call had no row in it)
-                assert s["pages_live"] == queued_on
-                queued_on = pages_used
-                # the kernel fetches a sequence's pages once for each
-                # query tile that holds rows of it: decode slots sit in
-                # one tile each, the chunk may span several
-                assert s["pages_live"] <= s["pages_visited"] \
-                    <= tiles * s["pages_live"]
-                if not out["prefill_tokens"]:
-                    assert s["pages_visited"] == s["pages_live"]
-                # a visit brings the page for a block of KV heads (here
-                # all of them: `hbm_accounting` says the kernel's choice)
-                kv = eng._kv_geom[0]
-                hb = int(eng.hbm_accounting()["attn_head_block"])
-                assert hb == kv > 1
-                assert s["attn_block_visits"] \
-                    == s["pages_visited"] * kv // hb
-                # a decode row's visits run on the window of rows that
-                # holds its own; a chunk's on the tile's
-                assert eng.hbm_accounting()["attn_narrow_rows"] == 8
-                assert s["attn_narrow_updates"] <= s["pages_visited"]
-                if not out["prefill_tokens"]:
-                    assert s["attn_narrow_updates"] == s["pages_visited"]
-            else:
-                assert s["attn_block_visits"] == 0
-                assert s["attn_narrow_updates"] == 0
-                assert "attn_head_block" not in eng.hbm_accounting()
-                assert "attn_narrow_rows" not in eng.hbm_accounting()
-                # two launches a step, each walks its own sequences
-                if not out["finished"]:
-                    assert pages_used <= s["pages_live"] <= 2 * pages_used
-                assert s["pages_visited"] == eng.pages_per_seq * (
-                    bool(out["prefill_tokens"])
-                    + eng.max_slots * bool(out["decoded"]))
+            # the record describes the launch this call RETIRED,
+            # queued by the call before (ISSUE 34), like the counts
+            # `step()` returns. Nothing shared: its live pages are
+            # the allocator's own count when it was queued (a
+            # request that ended in that call had no row in it)
+            assert s["pages_live"] == queued_on
+            queued_on = pages_used
+            # the kernel fetches a sequence's pages once for each
+            # query tile that holds rows of it: decode slots sit in
+            # one tile each, the chunk may span several
+            assert s["pages_live"] <= s["pages_visited"] \
+                <= tiles * s["pages_live"]
+            if not out["prefill_tokens"]:
+                assert s["pages_visited"] == s["pages_live"]
+            # a visit brings the page for a block of KV heads (here
+            # all of them: `hbm_accounting` says the kernel's choice)
+            kv = eng._kv_geom[0]
+            hb = int(eng.hbm_accounting()["attn_head_block"])
+            assert hb == kv > 1
+            assert s["attn_block_visits"] \
+                == s["pages_visited"] * kv // hb
+            # a decode row's visits run on the window of rows that
+            # holds its own; a chunk's on the tile's
+            assert eng.hbm_accounting()["attn_narrow_rows"] == 8
+            assert s["attn_narrow_updates"] <= s["pages_visited"]
+            if not out["prefill_tokens"]:
+                assert s["attn_narrow_updates"] == s["pages_visited"]
             assert s["preempted"] == s["cow_pages"] == 0
         # two slots, three requests: one waited, then everyone left
         assert steps[0]["live"] == 2 and steps[0]["waiting"] == 1

@@ -82,7 +82,7 @@ MODULE_ROLES = {
                 "covers tracer-leak/retrace/host-sync classes JAX adds)",
     "serving": "continuous-batching engine: paged KV block allocator "
                "(refcount/COW prefix sharing), FCFS in-flight scheduler, "
-               "fixed-shape jitted decode over the paged kernel "
+               "one fixed-shape jitted step over the ragged paged kernel "
                "(docs/SERVING.md; upstream: FastDeploy/PaddleNLP "
                "PagedAttention serving)",
     "observability": "metrics registry + `observability.tracing` "

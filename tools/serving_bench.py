@@ -3,14 +3,11 @@
 perf product — paddle/fluid/inference/ + the masked/block decode attention
 kernel set, paddle/phi/kernels/fusion/gpu/block_multi_head_attention*).
 
-Measures, on the real device:
-  1. generate_compiled (one-XLA-program prefill + lax.scan decode loop)
-     on the per-chip shard of the mp=8 x pp=4 partitioned Llama-3-8B —
-     the same per-chip model the training bench measures, so the two
-     numbers compose the same way (multiply by chips, subtract the
-     collective terms accounted in docs/FLAGSHIP.md).
-  2. The paged-attention decode kernel vs the dense masked-cache
-     attention at serving shapes (microbench of the O(1)-per-step op).
+Measures, on the real device, generate_compiled (one-XLA-program
+prefill + lax.scan decode loop) on the per-chip shard of the mp=8 x pp=4
+partitioned Llama-3-8B — the same per-chip model the training bench
+measures, so the two numbers compose the same way (multiply by chips,
+subtract the collective terms accounted in docs/FLAGSHIP.md).
 
 Writes docs/SERVING_BENCH.json and prints a summary. Roofline note: at
 batch B with per-chip weight bytes W and per-sequence KV-cache bytes C(s),
@@ -461,112 +458,6 @@ def bench_mla_context_sweep(S0s=(512, 4096, 12288), B=8, new=128,
         rows=rows)
 
 
-def bench_paged_kernel(B=8, ctx=4096, page_size=16):
-    """Decode-attention op microbench: the grouped-DMA in-tree kernel (v2)
-    vs the per-page v1, the bundled kernel, and dense masked-cache
-    attention at serving shapes (per-chip shard heads)."""
-    import jax
-    import jax.numpy as jnp
-    from paddle_tpu.ops.paged_attention import paged_attention
-
-    H, KV, D = 4, 1, 128           # the mp=8 shard's head layout
-    layers = 8
-    rng = np.random.RandomState(0)
-    pages_per_seq = ctx // page_size
-    total_pages = B * pages_per_seq
-    q = jnp.asarray(rng.randn(B, H, D), jnp.bfloat16)
-    kp = jnp.asarray(rng.randn(KV, total_pages, page_size, D), jnp.bfloat16)
-    vp = jnp.asarray(rng.randn(KV, total_pages, page_size, D), jnp.bfloat16)
-    lengths = jnp.full((B,), ctx, jnp.int32)
-    page_idx = jnp.arange(total_pages, dtype=jnp.int32).reshape(
-        B, pages_per_seq)
-
-    CHAIN = 50
-
-    def chain(fn):
-        # run the op CHAIN times inside ONE program (output feeds the
-        # next query) so per-call dispatch doesn't dominate the time
-        def chained(q, *args):
-            def it(carry, _):
-                o = fn(carry, *args)
-                return o.astype(carry.dtype), ()
-            out, _ = jax.lax.scan(it, q, None, length=CHAIN)
-            return out
-        return jax.jit(chained)
-
-    from paddle_tpu.ops.pallas_paged import (paged_decode_attention,
-                                             paged_decode_attention_v2)
-    from paddle_tpu.flags import flags_guard
-    paged_v2 = chain(lambda q, kp, vp: paged_decode_attention_v2(
-        q, kp, vp, lengths, page_idx))
-    paged_v1 = chain(lambda q, kp, vp: paged_decode_attention(
-        q, kp, vp, lengths, page_idx))
-
-    def _bundled(q, kp, vp):
-        with flags_guard(paged_impl="bundled"):
-            return paged_attention(q, kp, vp, lengths, page_idx)
-    paged_bundled = chain(_bundled)
-
-    def dense_fn(q, k, v):
-        s = jnp.einsum("bhd,bthd->bht", q, k) * (D ** -0.5)
-        pos = jnp.arange(ctx)
-        s = jnp.where(pos[None, None, :] < lengths[:, None, None],
-                      s.astype(jnp.float32), -1e30)
-        w = jax.nn.softmax(s, -1).astype(v.dtype)
-        return jnp.einsum("bht,bthd->bhd", w, v)
-
-    k_dense = jnp.asarray(rng.randn(B, ctx, H, D), jnp.bfloat16)
-    v_dense = jnp.asarray(rng.randn(B, ctx, H, D), jnp.bfloat16)
-    dense = chain(dense_fn)
-
-    from bench_util import ab_rounds, band, ratio_band
-
-    # same-run interleaved A/B (VERDICT r4 item 3): every round times all
-    # four kernels back-to-back, ratios carry their per-round band
-    runs = ab_rounds({
-        "intree_v2": (paged_v2, (q, kp, vp)),
-        "intree_v1": (paged_v1, (q, kp, vp)),
-        "bundled": (paged_bundled, (q, kp, vp)),
-        "dense": (dense, (q, k_dense, v_dense)),
-    }, rounds=3, reps=4)
-    runs = {k: [t / CHAIN for t in v] for k, v in runs.items()}
-    # per-layer op; a full decode step runs `layers` of these
-    return dict(batch=B, context=ctx, page_size=page_size,
-                heads=f"{H}q/{KV}kv d{D}", layers_note=f"x{layers}/step",
-                rounds=3,
-                paged_intree=band(runs["intree_v2"]),
-                paged_intree_v1=band(runs["intree_v1"]),
-                paged_bundled=band(runs["bundled"]),
-                dense=band(runs["dense"]),
-                intree_vs_dense=ratio_band(runs["dense"],
-                                           runs["intree_v2"]),
-                intree_vs_bundled=ratio_band(runs["bundled"],
-                                             runs["intree_v2"]))
-
-
-def _sweep_note(sweep):
-    """Conclusion derived from THIS run's sweep (never a baked narrative
-    that can contradict the numbers beside it). Ratios are same-run
-    interleaved bands: a claim only counts where the whole band clears 1."""
-    vs_b_lo = min(r["intree_vs_bundled"]["min"] for r in sweep)
-    vs_b_hi = max(r["intree_vs_bundled"]["max"] for r in sweep)
-    dense_8k = [r["intree_vs_dense"] for r in sweep if r["context"] >= 8192]
-    beats_dense = all(v["min"] >= 1.0 for v in dense_8k)
-    verdict = ("beats (entire band >= 1)" if beats_dense
-               else "does NOT beat beyond noise")
-    # v1-vs-v2 from THIS run's rounds, like every other claim here
-    v1_ratios = [round(r["paged_intree_v1"]["mean_us"]
-                       / r["paged_intree"]["mean_us"], 1) for r in sweep]
-    return (f"this run, same-run interleaved x3: in-tree v2 vs bundled "
-            f"ratio bands span {vs_b_lo}-{vs_b_hi} across the sweep; v2 "
-            f"{verdict} dense at every >=8k shape "
-            f"(bands {[(v['min'], v['max']) for v in dense_8k]}). intree "
-            "stays the default while its band overlaps the bundled "
-            "kernel's (it is in-tree tunable); the v1 per-page kernel it "
-            f"replaced is {min(v1_ratios)}-{max(v1_ratios)}x slower in "
-            "the same rounds.")
-
-
 def bench_prefill_long(family="llama", S0=8192, B=4, dtype="bfloat16"):
     """Long-context PREFILL throughput — the r5 flash-prefill record.
     Before r5 every cached body materialized [*, S, max_len] f32 scores
@@ -680,8 +571,7 @@ def _serving_engine_row(model, cfg, reqs, max_slots, page_size, rounds):
         # per-round static_time/engine_time: >1 means in-flight wins
         inflight_vs_static=ratio_band(sta_ts, eng_ts),
         # {program_name: cache_size} — every value must stay 1 (the
-        # engine's PT002 contract); ragged engines expose "unified",
-        # split engines "decode"/"prefill"
+        # engine's PT002 contract): "unified" and "feed"
         programs_compiled=eng.program_cache_sizes(),
         note="same mixed-length trace both ways; tokens/s counts only "
              "the REQUESTED new tokens, so static batching pays for its "
@@ -1002,16 +892,6 @@ def bench_fleet_workloads(seed=0, dtype="bfloat16"):
              "exact greedy outputs through a mid-burst drain")
 
 
-def _paged_sweep_row():
-    # the old single-shot paged_attention_op row is gone: it duplicated
-    # sweep[0] and its pre-q-scaling-fix "bundled" number contradicted
-    # the sweep (VERDICT r4 weak #2) — the sweep with bands is the record
-    sweep = [bench_paged_kernel(ctx=c, page_size=p)
-             for c in (4096, 8192, 16384) for p in (16, 32)]
-    return dict(paged_attention_sweep=sweep,
-                paged_attention_sweep_note=_sweep_note(sweep))
-
-
 # One entry per artifact row. Latency point (B=1) and a fatter-batch
 # point: decode tok/s scales with B until the KV reads pass the weight
 # reads in the roofline denominator. int8/int4/bf16_ref use
@@ -1038,7 +918,6 @@ ROWS = {
     "spec_decode_b1": lambda: bench_spec_decode_b1(),
     "disaggregated": lambda: bench_disaggregated(),
     "fleet_workloads": lambda: bench_fleet_workloads(),
-    "_paged": _paged_sweep_row,
 }
 
 _ROW_MARK = "__ROW_JSON__"
@@ -1091,10 +970,7 @@ def main():
         if val is None:
             failed.append(name)
             continue
-        if name == "_paged":
-            report.update(val)
-        else:
-            report[name] = val
+        report[name] = val
     out = os.path.join(os.path.dirname(__file__), "..", "docs",
                        "SERVING_BENCH.json")
     if failed:
