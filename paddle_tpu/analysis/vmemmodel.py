@@ -414,6 +414,16 @@ CANONICAL: Dict[str, Dict[str, Any]] = {
         cost_kwargs=dict(P=64, N=128, H=128),
         token_tiled=False,
     ),
+    # -- ops/pallas_kda.py (the same pool, heads major: a [K, V] tile) -----
+    # 8 live slots of [H = 32, K, V] float32 in J = 4 blocks of HB heads:
+    # each slot's state once in and once out, its row's q, k, g, v, beta
+    "kda_state_update": dict(
+        kernel="kda_state_update",
+        bindings=dict(B=8, J=4, HB=8, K=128, V=128),
+        in_widths=[4, 4, 4, 4, 4, 4], out_widths=[4, 4],
+        cost_kwargs=dict(live=8, H=32, K=128, V=128),
+        token_tiled=False,
+    ),
 }
 
 #: The decode-layer kernel chain in launch order (PF404 walks adjacent

@@ -549,6 +549,55 @@ def _hybrid_decode_params(model):
                           rope=""),) * n_attn)
 
 
+def _bailing_decode_params(model):
+    """BailingHybridForCausalLM on the hybrid body: TWO blocks a layer,
+    of the kinds ``pattern`` spells (static, outside the tree): ``K`` a
+    KDA linear-attention mixer (its three convolutions' weights side by
+    side, as its tail pool holds their rows), ``L`` a gated
+    latent-attention mixer, ``D`` a dense SwiGLU FFN, ``E`` a routed FFN
+    in `_ffn_apply`'s layout.  ``attn_static`` has one record for each
+    ``L`` block (the only ones with pages); ``moe_static`` one for each
+    ``E`` block.
+
+    The published rope turns INTERLEAVED pairs; the engine's turns
+    halves.  The same column order is applied here to the rope columns
+    of ``wq`` (inside every head) and of ``wkva``, which leaves every
+    q.k unchanged (`_laguna_decode_params` does the like)."""
+    from .models.bailing_hybrid import arrays, interleaved_to_halves
+    inner, cfg = model.model, model.config
+    nh, dn, dr, r = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim, cfg.kv_lora_rank)
+    order = interleaved_to_halves(dr)
+    layers, moe_static = [], []
+    for blk in inner.layers:
+        mix = blk.mixer
+        d = dict(norm=blk.norm.weight._data)
+        if blk.kind == "E":
+            d["moe"] = arrays(mix.weights())
+            moe_static.append(mix.static())
+        else:
+            d.update(arrays(mix.weights()))
+        if blk.kind == "K":
+            d["conv_w"] = jnp.concatenate(
+                [d.pop(k) for k in ("q_conv", "k_conv", "v_conv")])
+        if blk.kind == "L":
+            wq = d["wq"].reshape(-1, nh, dn + dr)
+            d["wq"] = jnp.concatenate(
+                [wq[..., :dn], wq[..., dn:][..., order]],
+                -1).reshape(d["wq"].shape)
+            d["wkva"] = jnp.concatenate(
+                [d["wkva"][:, :r], d["wkva"][:, r:][:, order]], -1)
+        layers.append(d)
+    return dict(
+        cfg=cfg, family="hybrid", pattern=cfg.pattern,
+        embed=inner.embed_tokens.weight._data, layers=layers,
+        norm=inner.norm.weight._data, head=model.lm_head.weight._data,
+        moe_static=tuple(moe_static),
+        attn_static=(dict(heads=nh, window=None, rope=""),)
+        * cfg.pattern.count("L"),
+        rope_fn=lambda n: dict(zip(("cos", "sin"), cfg.rope_table(n))))
+
+
 def _mla_decode_params(model, weight_only_int8: bool = False,
                        algo: str = "weight_only_int8"):
     """DeepSeekV2ForCausalLM: multi-head latent attention with the
@@ -629,13 +678,16 @@ def _decode_params(model, weight_only_int8: bool = False,
         from .models.evabyte import EvaByteModel
         from .models.laguna import LagunaModel
         from .models.moe_llm import MoEModel
+        from .models.bailing_hybrid import BailingHybridModel
         from .models.nemotron_h import NemotronHModel
         from .models.ouro import OuroModel
-        if isinstance(inner, NemotronHModel):
+        if isinstance(inner, (NemotronHModel, BailingHybridModel)):
             if enabled:
                 raise NotImplementedError(
                     "weight-only quantisation is not wired for the "
-                    "Nemotron-H family")
+                    "Nemotron-H and Ling (bailing_hybrid) families")
+            if isinstance(inner, BailingHybridModel):
+                return _bailing_decode_params(model)
             return _hybrid_decode_params(model)
         if isinstance(inner, OuroModel):
             if enabled:

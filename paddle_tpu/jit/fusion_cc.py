@@ -46,10 +46,15 @@ def _build() -> Optional[str]:
     if os.path.exists(_SO) and \
             os.path.getmtime(_SO) >= os.path.getmtime(src):
         return _SO
+    # built under a name of this process's own and moved into place in one
+    # step: several test workers import this at once, and one that loads
+    # while another's g++ is still writing finds a file "too short"
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             ["g++", "-O2", "-std=c++17", "-fPIC", "-shared", src,
-             "-o", _SO], check=True, capture_output=True, timeout=180)
+             "-o", tmp], check=True, capture_output=True, timeout=180)
+        os.replace(tmp, _SO)
         return _SO
     except Exception:
         return None

@@ -191,12 +191,12 @@ def ssm_split(zxbcdt, c: NemotronHConfig):
 def ssm_conv(u_ext, w, b):
     """The depthwise causal convolution and its silu: ``u_ext`` [L + K
     - 1, W] is the K - 1 rows before the L rows, then the rows; ``w``
-    [W, K], ``b`` [W].  Float32 inside; returns [L, W] in ``u_ext``'s
-    type."""
+    [W, K], ``b`` [W] or None (no bias).  Float32 inside; returns [L, W]
+    in ``u_ext``'s type."""
     K = w.shape[-1]
     L = u_ext.shape[0] - (K - 1)
     f32 = jnp.float32
-    acc = b.astype(f32)[None]
+    acc = 0.0 if b is None else b.astype(f32)[None]
     for j in range(K):
         acc = acc + w[:, j].astype(f32)[None] * u_ext[j:j + L].astype(f32)
     return jax.nn.silu(acc).astype(u_ext.dtype)
@@ -288,6 +288,19 @@ def _apply_mixer(name, fn, a, w, c):
         return jax.vmap(lambda s: fn(s, L, c))(a)
 
     return apply(name, impl, [a] + [w[k] for k in names])
+
+
+def _apply_routed(name, a, weights, st):
+    """`generation._ffn_apply` over a routed layer's ``weights()`` tree
+    and its static knobs ``st``, as one dispatched op."""
+    from ..generation import _ffn_apply
+    flat, tree = jax.tree_util.tree_flatten(weights)
+
+    def impl(a, *vals):
+        mo = jax.tree_util.tree_unflatten(tree, vals)
+        return _ffn_apply(dict(moe=mo), a, st)
+
+    return apply(name, impl, [a] + flat)
 
 
 class _DtBias(I.Initializer):
@@ -396,15 +409,8 @@ class NemotronHMoE(nn.Layer):
                     scale=c.routed_scaling_factor, held=c.experts_held)
 
     def forward(self, a):
-        from ..generation import _ffn_apply
-        st = self.static()
-        flat, tree = jax.tree_util.tree_flatten(self.weights())
-
-        def impl(a, *vals):
-            mo = jax.tree_util.tree_unflatten(tree, vals)
-            return _ffn_apply(dict(moe=mo), a, st)
-
-        return apply("nemotron_h_moe", impl, [a] + flat)
+        return _apply_routed("nemotron_h_moe", a, self.weights(),
+                             self.static())
 
 
 MIXERS = {MAMBA: NemotronHMamba, ATTENTION: NemotronHAttention,

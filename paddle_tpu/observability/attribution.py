@@ -224,13 +224,20 @@ SCOPES = ("embed", "attn_norm", "qkv_proj", "cache_write", "attention",
 #: as `qkv_proj`, the convolution (which keeps the sequence's last
 #: rows) as `cache_write`, the state update and the chunk's scan as
 #: `attention`, the gated norm and out-projection as `attn_out`; the
-#: latent projections around the routed experts are `routed_ffn`.
+#: latent projections around the routed experts are `routed_ffn`.  A
+#: KDA (delta-rule) mixer's parts answer the same way: its projections,
+#: its convolutions with the heads' norm and gates, the decode rows'
+#: state update and the chunk's scan (a scope each: two kernels), the
+#: heads' norm, gate and out-projection.
 SCOPE_ALIASES = {"mla_q": "qkv_proj", "mla_kv": "qkv_proj",
                  "mla_attention": "attention", "mla_out": "attn_out",
                  "eva_attention": "attention", "eva_pool": "cache_write",
                  "ssm_in_proj": "qkv_proj", "ssm_conv": "cache_write",
                  "ssm_scan": "attention", "ssm_out": "attn_out",
-                 "latent_proj": "routed_ffn"}
+                 "latent_proj": "routed_ffn",
+                 "kda_in_proj": "qkv_proj", "kda_conv": "cache_write",
+                 "kda_state_update": "attention",
+                 "kda_chunk_scan": "attention", "kda_out": "attn_out"}
 
 
 def scope(name: str):

@@ -280,6 +280,49 @@ def _c_ssm_state_put(*, P: int, N: int, H: int) -> CostEstimate:
                         breakdown={"state": 3 * state})
 
 
+@register_cost("kda_state_update")
+def _c_kda_state_update(*, live: int, H: int, K: int, V: int) -> CostEstimate:
+    """One step of the gated delta rule (KDA) for `live` slots of a
+    slot-indexed state pool [slots, H, K, V] float32 (aliased in+out):
+    each live slot's state once in and once out, its row's q, k and log
+    decay [H, K], v [H, V] and beta [H] in float32; o [H, V] out. An idle
+    slot is neither read nor written. 7 FLOPs an element of the state:
+    the decay, S'^T k and S'^T q (a multiply-add each), the rank-one
+    write's multiply-add."""
+    state = live * H * K * V * 4
+    rows_in = live * H * (3 * K + V + 1) * 4
+    rows_out = live * H * V * 4
+    return CostEstimate(bytes_read=state + rows_in,
+                        bytes_written=state + rows_out,
+                        flops=7 * live * H * K * V,
+                        breakdown={"state": 2 * state,
+                                   "activations": rows_in + rows_out})
+
+
+def kda_chunk_scan_cost(*, rows: int, sub: int, H: int, K: int,
+                        V: int) -> CostEstimate:
+    """(Not in the kernel registry: the scan is plain XLA, no BlockSpec
+    to check it against.) `rows` rows (whole sub-chunks of `sub`) of ONE sequence through
+    the gated delta rule in the WY form, from and to a state [H, K, V]
+    float32: what the ALGORITHM needs. Bytes: the state once in and once
+    out, the rows' q, k, g [H, K], v [H, V], beta [H] in, o [H, V] out.
+    FLOPs a sub-chunk a head: the two decayed Gram matrices (3 x sub^2 x
+    K each: the decay's product, the multiply-add), the unit-lower
+    inverse (2 x sub^3 / 3), and the matmuls with the state and the
+    pseudo-values (q S, k S, T r, B u: 2 x sub x K x V twice, 2 x sub^2
+    x V twice; the state's update 2 x sub x K x V)."""
+    n = rows // sub
+    state = H * K * V * 4
+    rows_in = rows * H * (3 * K + V + 1) * 4
+    rows_out = rows * H * V * 4
+    flops = n * H * (6 * sub * sub * K + 2 * sub ** 3 // 3
+                     + 6 * sub * K * V + 4 * sub * sub * V)
+    return CostEstimate(bytes_read=state + rows_in,
+                        bytes_written=state + rows_out, flops=flops,
+                        breakdown={"state": 2 * state,
+                                   "activations": rows_in + rows_out})
+
+
 @register_cost("swiglu")
 def _c_swiglu(*, T: int, H: int, dtype_bytes: int = 2) -> CostEstimate:
     """gate/up [T, H] -> silu(gate) * up [T, H]."""
@@ -632,6 +675,18 @@ def ssm_state_bytes_per_seq_layer(*, heads: int, head_dim: int,
     rows of its input."""
     return (heads * head_dim * state_size * state_dtype_bytes
             + (conv_kernel - 1) * conv_dim * conv_dtype_bytes)
+
+
+def kda_state_bytes_per_seq_layer(*, heads: int, head_dim: int,
+                                  conv_kernel: int,
+                                  state_dtype_bytes: int = 4,
+                                  conv_dtype_bytes: int = 2) -> int:
+    """HBM bytes a SEQUENCE holds in one KDA (gated delta rule) layer,
+    whatever its length: the recurrent state heads x head_dim x head_dim
+    and the tails of the q, k and v convolutions, the last conv_kernel -
+    1 rows of each one's input."""
+    return (heads * head_dim * head_dim * state_dtype_bytes
+            + (conv_kernel - 1) * 3 * heads * head_dim * conv_dtype_bytes)
 
 
 def kv_bytes_per_token_layer(family: str, *, kv_heads: int = 0,

@@ -163,7 +163,8 @@ STEP_COUNTS_LOOP: Tuple[str, ...] = (
 #: moved over all such layers (each named slot's once in and once out;
 #: a slot that starts its sequence is not read), the chunk's rows, the
 #: slots the launch started from zero state, and the pool's slots that
-#: hold a request against all of them
+#: hold a request against all of them. A KDA (delta-rule) block's
+#: state lives in the same pool under the same counts
 STEP_COUNTS_SSM: Tuple[str, ...] = (
     "ssm_slots_live", "ssm_state_bytes", "ssm_state_bytes_moved",
     "ssm_scan_rows", "ssm_state_resets", "state_pool_slots_used",

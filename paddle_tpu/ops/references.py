@@ -21,7 +21,8 @@ __all__ = ["rms_norm_reference", "layer_norm_reference",
            "swiglu_reference", "mla_decode_reference", "gmm_reference",
            "oproj_norm_reference", "megadecode_ffn_reference",
            "qkv_rope_append_reference", "ssm_state_update_reference",
-           "ssm_state_put_reference", "ssm_recurrence_reference"]
+           "ssm_state_put_reference", "ssm_recurrence_reference",
+           "kda_state_update_reference", "kda_recurrence_reference"]
 
 
 def rms_norm_reference(x, weight, eps: float = 1e-6):
@@ -302,3 +303,42 @@ def ssm_recurrence_reference(xdt, dA, bm, cm, state):
         step, state.astype(f32),
         (xdt.astype(f32), dA.astype(f32), bm.astype(f32), cm.astype(f32)))
     return y, state
+
+
+def _kda_step(s, q, k, v, g, beta):
+    """One token of the gated delta rule on states s [..., K, V]: q, k,
+    g [..., K], v [..., V], beta [...] -> (o [..., V], the new state)."""
+    hi = jax.lax.Precision.HIGHEST
+    s = jnp.exp(g)[..., None] * s
+    w = beta[..., None] * (v - jnp.einsum("...kv,...k->...v", s, k,
+                                           precision=hi))
+    s = s + k[..., None] * w[..., None, :]
+    return jnp.einsum("...kv,...k->...v", s, q, precision=hi), s
+
+
+def kda_state_update_reference(pool, slots, n_live, q, k, v, g, beta):
+    """One step of the gated delta rule for the live slots (the first
+    ``n_live`` of ``slots``); every other slot of the pool unchanged."""
+    NS = pool.shape[0]
+    live = jnp.zeros(NS, bool).at[slots].max(
+        jnp.arange(slots.shape[0]) < n_live[0])
+    f32 = jnp.float32
+    o, new = _kda_step(pool, *(a[:NS].astype(f32) for a in (q, k, v, g)),
+                       beta[:NS, :, 0].astype(f32))
+    return (jnp.where(live[:, None, None], o, 0),
+            jnp.where(live[:, None, None, None], new, pool))
+
+
+def kda_recurrence_reference(q, k, v, g, beta, state):
+    """The gated delta rule token by token (what
+    `pallas_kda.kda_chunk_scan` and `kda_state_update` are tested
+    against): the operands of `kda_chunk_scan`."""
+    f32 = jnp.float32
+
+    def step(s, row):
+        o, s = _kda_step(s, *row)
+        return s, o
+
+    state, o = jax.lax.scan(step, state.astype(f32), tuple(
+        a.astype(f32) for a in (q, k, v, g, beta)))
+    return o, state

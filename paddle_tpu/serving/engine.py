@@ -68,9 +68,12 @@ from ..ops.fused import (append_run_count, append_run_table, append_tile,
                          fused_append_rows, fused_chunk_pool,
                          fused_layer_norm, fused_rms_norm,
                          fused_rope_append)
+from ..models.bailing_hybrid import kda_gated_norm, kda_operands
 from ..models.nemotron_h import (ssm_conv, ssm_gated_norm, ssm_operands,
                                  ssm_split)
 from ..models.ouro import exit_distribution as _exit_distribution
+from ..ops.pallas_kda import (kda_chunk_scan, kda_state_update,
+                              kda_tileable)
 from ..ops.pallas_ssm import (ssm_chunk_scan, ssm_state_put,
                               ssm_state_update)
 from ..ops.pallas_ragged import (ragged_head_block,
@@ -163,6 +166,14 @@ def _ragged_step_eligible(heads, kv: int, d: int, page_size: int) -> bool:
                    for h in heads))
 
 
+def _kda_step_eligible(heads: int, d: int, chunk: int, sub: int) -> bool:
+    """As `_ragged_step_eligible`, for a KDA block's two kernels: on a
+    TPU the state update tiles whole registers and the prefill chunk is
+    whole sub-chunks of the scan; interpreted, anything goes."""
+    return (jax.default_backend() != "tpu"
+            or (kda_tileable(heads, d, d) and chunk % sub == 0))
+
+
 def _refuse_shared_cache(why: str, enable_prefix_cache, spec_decode: int,
                          role: str) -> None:
     """What a cache whose pages are released mid-sequence cannot serve,
@@ -251,6 +262,75 @@ def _greedy(logits):
     device from the rows the host would take it from (the first index
     on ties, as `np.argmax`)."""
     return jnp.argmax(logits, -1).astype(jnp.int32)
+
+
+def _halves_rope(c, s):
+    """The rotary turn of t [1, T, h, dr] by the rows' angles c, s [T,
+    dr / 2], pairing (j, j + dr / 2): it runs on the split q_pe / k_pe
+    shapes (not D-halved cache rows), so the append is the row-scatter
+    kernel."""
+    def rope(t):
+        d2 = t.shape[-1] // 2
+        t1, t2 = t[..., :d2], t[..., d2:]
+        cc = c[None, :, None, :].astype(t.dtype)
+        ss = s[None, :, None, :].astype(t.dtype)
+        return jnp.concatenate(
+            [t1 * cc - t2 * ss, t2 * cc + t1 * ss], -1)
+    return rope
+
+
+def _latent_mixer(L, h, x, rope, pool, seq_start, num_tokens, kv_lengths,
+                  tables, tok_page, tok_off, *, nh: int, dn: int, dr: int,
+                  dv: int, r: int, width: int, eps: float, scale: float):
+    """Latent attention in the ABSORBED form, on the normed rows h [1,
+    T, H] of the residual x: the cache row is (RMSNorm(latent) |
+    RoPE(k_pe) | pad), the query of head a is (q_nope_a W_kvb^K_a |
+    RoPE(q_pe_a) | 0), the kernel's output the weighted sum of the
+    rows' latent columns, and W_kvb^V_a comes after; where the layer has
+    a head gate (``wgate``), each head's output times its sigmoid
+    before the out-projection. The prefill chunk rides the same form as
+    the decode rows. -> (x + the mixer's output, the pool). Shared by
+    `_mla_unified_body` and the hybrid body's ``L`` blocks."""
+    T = h.shape[1]
+    wkb = _dq(L, "wkvb", x.dtype).reshape(r, nh, dn + dv)
+    w_k, w_v = wkb[..., :dn], wkb[..., dn:]
+    with jax.named_scope("mla_q"):
+        if "wqa" in L or "wqa_q" in L or "wqa_q4" in L:
+            q = _mm_w(fused_rms_norm(_mm_w(h, L, "wqa"),
+                                     L["gq"], eps),
+                      L, "wqb")
+        else:
+            q = _mm_w(h, L, "wq")
+        q = q.reshape(1, T, nh, dn + dr)
+        q_nope, q_pe = q[..., :dn], q[..., dn:]
+        q_pe = rope(q_pe)
+        q_eff = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_k)
+        q_cat = _pad_lanes(
+            jnp.concatenate([q_eff, q_pe], -1)[0], width)
+    with jax.named_scope("mla_kv"):
+        kv_a = _mm_w(h, L, "wkva")           # [1, T, r+dr]
+        lat = fused_rms_norm(kv_a[..., :r], L["gkv"], eps)
+        k_pe = rope(kv_a[..., r:][:, :, None, :])[:, :, 0]
+        rows = _pad_lanes(
+            jnp.concatenate([lat, k_pe], -1)[0], width)
+        with _scope("cache_write"):
+            pool = fused_append_rows(pool, rows[:, None],
+                                     tok_page, tok_off)
+    with jax.named_scope("mla_attention"):
+        # K is the row, V its latent columns: one page
+        # fetch serves both matmuls
+        o_lat = ragged_paged_attention(
+            q_cat, pool, None, seq_start, num_tokens,
+            kv_lengths, tables, scale=scale, v_dim=r,
+            scope="mla_attention")
+    with jax.named_scope("mla_out"):
+        o = jnp.einsum("tnr,rnv->tnv", o_lat, w_v)
+        if "wgate" in L:
+            o = o * jax.nn.sigmoid(
+                (h[0] @ L["wgate"]).astype(jnp.float32))[
+                    ..., None].astype(o.dtype)
+        x = x + _mm_w(o.reshape(1, T, nh * dv), L, "wo")
+    return x, pool
 
 
 class _Launch:
@@ -447,11 +527,23 @@ class ServingEngine:
         # state cannot be cut at a token: nothing can adopt a prefix of
         # it, roll it back or move it, and a slot that is given away
         # takes the state with it
-        self._ssm_layers = p["pattern"].count("M") \
-            if self._family == "hybrid" else 0
+        pattern = p["pattern"] if self._family == "hybrid" else ""
+        self._ssm_layers = pattern.count("M") + pattern.count("K")
+        # ... of ONE kind a model: Mamba-2's (`M`) or the delta rule's
+        # (`K`, a KDA linear-attention block); and the attention blocks'
+        # pages hold GQA rows (`*`) or latent rows (`L`)
+        self._state_kind = "K" if "K" in pattern else "M"
+        self._latent = self._family == "mla" or "L" in pattern
         if self._ssm_layers:
+            if "K" in pattern and "M" in pattern or \
+                    "L" in pattern and "*" in pattern:
+                raise NotImplementedError(
+                    f"pattern {pattern!r}: one kind of state block and "
+                    f"one kind of attention block a model")
+            what = "state-space" if self._state_kind == "M" else \
+                "linear-attention (delta-rule)"
             _refuse_shared_cache(
-                f"this model has {self._ssm_layers} state-space blocks, "
+                f"this model has {self._ssm_layers} {what} blocks, "
                 f"whose memory of a sequence is one recurrent state in "
                 f"its slot, not rows that a snapshot could cut, so ",
                 enable_prefix_cache, spec_decode, role)
@@ -508,7 +600,7 @@ class ServingEngine:
         n_layers = len(p["layers"])
         if self._family == "gpt":
             kv, d = cfg.num_attention_heads, cfg.head_dim
-        elif self._family == "mla":
+        elif self._latent:
             kv, d = 1, _latent_row_width(cfg.kv_lora_rank,
                                          cfg.qk_rope_head_dim)
         else:
@@ -543,13 +635,30 @@ class ServingEngine:
             # `ops.pallas_ssm`) and the convolution's tail, one slot
             # more than the scheduler's: the spare takes what idle rows
             # and an absent chunk write
-            self._state_shape = (
-                self.max_slots + 1, cfg.mamba_head_dim, cfg.ssm_state_size,
-                cfg.mamba_num_heads)
+            # (a KDA block's state is heads-major, a [K, V] tile a head
+            # with V along the lanes: `ops.pallas_kda` says why; its
+            # tail holds the q, k and v convolutions' rows side by side)
+            if self._state_kind == "K":
+                nh, hd = cfg.num_attention_heads, cfg.head_dim
+                if not _kda_step_eligible(nh, hd, self.prefill_chunk,
+                                          cfg.kda_sub_chunk):
+                    raise ValueError(
+                        f"the KDA kernels do not tile {nh} heads of "
+                        f"{hd} x {hd} state on this backend, or a "
+                        f"prefill chunk of {self.prefill_chunk} rows is "
+                        f"not whole sub-chunks of {cfg.kda_sub_chunk}")
+                self._state_shape = (self.max_slots + 1, nh, hd, hd)
+            else:
+                self._state_shape = (
+                    self.max_slots + 1, cfg.mamba_head_dim,
+                    cfg.ssm_state_size, cfg.mamba_num_heads)
             self._tail_shape = (self.max_slots + 1, cfg.conv_kernel - 1,
                                 cfg.conv_dim)
             self._pools = {
-                "kv": [(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
+                # (latent rows: one plane, K whole and V in its first
+                # kv_lora_rank columns, as the mla family's)
+                "kv": [jnp.zeros(shape, dt) if self._latent
+                       else (jnp.zeros(shape, dt), jnp.zeros(shape, dt))
                        for _ in self._layer_kind],
                 "ssm": [(jnp.zeros(self._state_shape, jnp.float32),
                          jnp.zeros(self._tail_shape, dt))
@@ -584,7 +693,7 @@ class ServingEngine:
             self._count_names += _tracing.STEP_COUNTS_BY_KIND
         if any(st and "held" in st for st in p.get("moe_static") or ()):
             self._count_names += _tracing.STEP_COUNTS_MOE
-        if self._family == "mla":
+        if self._latent:
             self._count_names += _tracing.STEP_COUNTS_LATENT
         if self._eva:
             self._count_names += _tracing.STEP_COUNTS_EVA
@@ -616,7 +725,7 @@ class ServingEngine:
         # kernel's own choice at the unified step's row count
         # (`attn_block_visits`, `pages_visited`, `attn_narrow_updates`)
         T = self.max_slots * (1 + self.spec_k) + self.prefill_chunk
-        latent = self._family == "mla"
+        latent = self._latent
         self._head_block, self._tile_block, self._narrow_rows = {}, {}, {}
         for r in {r for reps in self._kind_rep.values() for r in reps}:
             tq = ragged_tile_tokens(T, r, dt)
@@ -630,7 +739,9 @@ class ServingEngine:
         # the unit of work of the rope + append kernel
         # (`ops.fused.append_run_table`): the rows of one cache tile
         self._append_tile = append_tile(dt, self.page_size)
-        planes = 1 if self._family == "mla" else 2
+        planes = 1 if self._latent else 2
+        # what the cost model's cache formulas call this engine's rows
+        self._cache_family = "mla" if self._latent else self._family
         self._hbm_weights_bytes = _costmodel.tree_bytes(self._w)
         self._hbm_pool_bytes = self._passes * sum(
             planes * kv * (self.num_window_pages if k else self.num_pages)
@@ -640,11 +751,20 @@ class ServingEngine:
         self._ssm_state_bytes = self._ssm_slot_bytes = 0
         if self._ssm_layers:
             self._ssm_state_bytes = 4 * int(np.prod(self._state_shape[1:]))
-            self._ssm_slot_bytes = _costmodel.ssm_state_bytes_per_seq_layer(
-                heads=cfg.mamba_num_heads, head_dim=cfg.mamba_head_dim,
-                state_size=cfg.ssm_state_size, conv_dim=cfg.conv_dim,
-                conv_kernel=cfg.conv_kernel,
-                conv_dtype_bytes=self._kv_itemsize)
+            if self._state_kind == "K":
+                self._ssm_slot_bytes = \
+                    _costmodel.kda_state_bytes_per_seq_layer(
+                        heads=cfg.num_attention_heads,
+                        head_dim=cfg.head_dim, conv_kernel=cfg.conv_kernel,
+                        conv_dtype_bytes=self._kv_itemsize)
+            else:
+                self._ssm_slot_bytes = \
+                    _costmodel.ssm_state_bytes_per_seq_layer(
+                        heads=cfg.mamba_num_heads,
+                        head_dim=cfg.mamba_head_dim,
+                        state_size=cfg.ssm_state_size,
+                        conv_dim=cfg.conv_dim, conv_kernel=cfg.conv_kernel,
+                        conv_dtype_bytes=self._kv_itemsize)
             self._hbm_pool_bytes += self._ssm_layers * (
                 self.max_slots + 1) * self._ssm_slot_bytes
         # what ONE launch reads of the weights: the layers once a pass
@@ -1030,8 +1150,8 @@ class ServingEngine:
         kv, d = self._kv_geom
         n_layers = len(self._layer_kind)    # the layers that keep pages
         per_tok = _costmodel.kv_bytes_per_token_layer(
-            self._family, kv_heads=kv, head_dim=d,
-            kv_latent_dim=(d if self._family == "mla" else 0),
+            self._cache_family, kv_heads=kv, head_dim=d,
+            kv_latent_dim=(d if self._latent else 0),
             kv_dtype_bytes=self._kv_itemsize, passes=self._passes)
         # (chunk-summary layers read a sequence's visible pooled rows and
         # its window's, not its length)
@@ -1063,11 +1183,11 @@ class ServingEngine:
                 # the budget's view of the SAME step: one weight pass +
                 # every live cache byte at the mean context
                 budget = _costmodel.decode_step_budget(
-                    self._family, batch=len(lens),
+                    self._cache_family, batch=len(lens),
                     context=sum(lens) / len(lens), layers=n_layers,
                     weight_bytes=self._hbm_weight_read_bytes,
                     kv_heads=kv, head_dim=d,
-                    kv_latent_dim=(d if self._family == "mla" else 0),
+                    kv_latent_dim=(d if self._latent else 0),
                     kv_dtype_bytes=self._kv_itemsize,
                     page_size=self.page_size, spec_rows=spec_rows,
                     passes=self._passes)
@@ -1734,7 +1854,7 @@ class ServingEngine:
         counts = {"decode_rows": int(num_tokens[:B].sum()),
                   "prefill_rows": n}
         seq_start = np.append(np.arange(B) * R, base)
-        if self._family == "mla":       # its rows go in one by one
+        if self._latent:                # its rows go in one by one
             counts["chunk_kv_len"] = int(kv_lengths[S - 1])
             counts["latent_row_bytes"] = \
                 self._kv_geom[1] * self._kv_itemsize
@@ -1771,7 +1891,7 @@ class ServingEngine:
                     counts["attn_narrow_updates"] += narrow
             return total
 
-        if self._family == "mla":
+        if self._latent:
             counts["attn_tile_chains"] = visited(0, tiles=True)
         if self._family == "looped":
             n_layers = len(self._p["layers"])
@@ -2301,13 +2421,16 @@ class ServingEngine:
         return step
 
     def _hybrid_unified_body(self):
-        """A hybrid (Nemotron-H) on the one launch: block l is `x +
-        mixer_l(RMSNorm(x))` with ONE mixer, of the kind the model's
-        pattern names.
+        """A hybrid (Nemotron-H, Ling 3.0) on the one launch: block l is
+        `x + mixer_l(RMSNorm(x))` with ONE mixer, of the kind the
+        model's pattern names (a Ling layer is two blocks).
 
         ``*``, attention without rotary: q / k / v -> `fused_rope_append`
         under an identity table (a plain append) -> `ragged_paged_attention`
         -> o-proj, over the pages of the attention blocks alone.
+
+        ``L``, gated latent attention: `_latent_mixer`, the mla
+        family's, over pages that hold latent rows.
 
         ``M``, a Mamba-2 state-space mixer, whose memory of a sequence is
         its slot of the block's state pool and of its convolution tail:
@@ -2323,17 +2446,23 @@ class ServingEngine:
         and the out-projection (`ssm_out`). A launch without a chunk skips
         the scan and moves no state for it.
 
-        ``E``, a latent routed FFN: `_ffn_apply` (`routed_ffn`,
-        `latent_proj`, `shared_expert`).
+        ``K``, a KDA linear-attention mixer: the same slot, tail and
+        table, another update rule — projections (`kda_in_proj`) -> the
+        q | k | v convolutions through `_conv_tails`, the heads' L2 norm
+        and the gates (`kda_conv`) -> `kda_state_update` in place
+        (`kda_state_update`) -> the chunk's `kda_chunk_scan` (rows past
+        its length carry g 0 and beta 0, the identity) and
+        `ssm_state_put` (`kda_chunk_scan`) -> each head's norm, its
+        gate and the out-projection (`kda_out`).
+
+        ``E``, a routed FFN (latent or not), and ``D``, a dense SwiGLU
+        FFN: `_ffn_apply` (`routed_ffn`, `latent_proj`,
+        `shared_expert`; `ffn`).
 
         ``kv_lengths`` is a pair: (the attention blocks' lengths, the
         state table [B + 3]: the live decode slots then the spare, their
         count, the chunk's slot, whether the launch starts it)."""
         cfg, pattern = self._p["cfg"], self._p["pattern"]
-        Hq, KV, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                     cfg.head_dim)
-        Hm, P, G, N = (cfg.mamba_num_heads, cfg.mamba_head_dim,
-                       cfg.n_groups, cfg.ssm_state_size)
         eps, K = cfg.layer_norm_epsilon, cfg.conv_kernel
         moe_static = self._p["moe_static"]
         B, C = self.max_slots, self.prefill_chunk
@@ -2341,6 +2470,59 @@ class ServingEngine:
         seq_start = _seq_starts(B, 1)
         run_table = self._run_table(seq_start)
         f32 = jnp.float32
+        if "*" in pattern:
+            Hq, KV, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                         cfg.head_dim)
+        if "M" in pattern:
+            Hm, P, G = cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups
+        if "K" in pattern:
+            Hk, Dk = cfg.num_attention_heads, cfg.head_dim
+        if "L" in pattern:
+            latent = dict(nh=cfg.num_attention_heads,
+                          dn=cfg.qk_nope_head_dim, dr=cfg.qk_rope_head_dim,
+                          dv=cfg.v_head_dim, r=cfg.kv_lora_rank,
+                          width=self._kv_geom[1], eps=eps,
+                          scale=cfg.softmax_scale)
+
+        def conv_tails(u, t_pool, conv_w, conv_b, live, n_c, cslot, starts):
+            """The causal convolution of the launch's rows u [T, W], a
+            decode row from its slot's tail, the chunk's rows from its
+            slot's tail (zeros at a start) and from each other; the
+            last K - 1 rows before each sequence's next one are written
+            back. -> (the convolved rows, the tail pool)."""
+            # decode row s is slot s: its tail, then its own row
+            tails = t_pool[:B]
+            ext = jnp.concatenate([tails, u[:B, None]], 1)
+            conv_d = jax.vmap(ssm_conv, (0, None, None))(
+                ext, conv_w, conv_b)[:, 0]
+            # the chunk: its slot's tail (zeros at a start), its rows
+            tail_c = jnp.where(starts, 0, t_pool[cslot])
+            ext_c = jnp.concatenate([tail_c, u[B:]])
+            conv_c = ssm_conv(ext_c, conv_w, conv_b)
+            t_pool = t_pool.at[:B].set(
+                jnp.where(live[:, None, None], ext[:, 1:], tails))
+            t_pool = jax.lax.dynamic_update_slice(
+                t_pool, jax.lax.dynamic_slice(
+                    ext_c, (n_c, 0), (K - 1, ext_c.shape[1]))[None],
+                (cslot, 0, 0))
+            return jnp.concatenate([conv_d, conv_c]), t_pool
+
+        def chunk_state(z_pool, n_c, cslot, starts, scan, y_shape):
+            """The chunk's rows through ``scan(state it starts from)``
+            -> (y, the state it leaves), where the launch has a chunk,
+            and that state put back in its slot in place."""
+            def run():
+                return scan(jax.lax.cond(
+                    starts, lambda: jnp.zeros(z_pool.shape[1:], f32),
+                    lambda: jax.lax.dynamic_index_in_dim(
+                        z_pool, cslot, 0, keepdims=False)))
+
+            y_c, s1 = jax.lax.cond(
+                n_c > 0, run,
+                lambda: (jnp.zeros(y_shape, f32),
+                         jnp.zeros(z_pool.shape[1:], f32)))
+            return y_c, ssm_state_put(
+                z_pool, jnp.stack([cslot, (n_c > 0).astype(jnp.int32)]), s1)
 
         def ssm(L, a, z_pool, t_pool, num_tokens, tab):
             """The state-space mixer of a [T, hidden] -> (its output [T,
@@ -2353,23 +2535,8 @@ class ServingEngine:
             with jax.named_scope("ssm_in_proj"):
                 z, u, dt = ssm_split(a @ L["w_in"], cfg)
             with jax.named_scope("ssm_conv"):
-                # decode row s is slot s: its tail, then its own row
-                tails = t_pool[:B]
-                ext = jnp.concatenate([tails, u[:B, None]], 1)
-                conv_d = jax.vmap(ssm_conv, (0, None, None))(
-                    ext, L["conv_w"], L["conv_b"])[:, 0]
-                # the chunk: its slot's tail (zeros at a start), its rows
-                tail_c = jnp.where(starts, 0, t_pool[cslot])
-                ext_c = jnp.concatenate([tail_c, u[B:]])
-                conv_c = ssm_conv(ext_c, L["conv_w"], L["conv_b"])
-                # the last K - 1 rows before each sequence's next one
-                t_pool = t_pool.at[:B].set(
-                    jnp.where(live[:, None, None], ext[:, 1:], tails))
-                t_pool = jax.lax.dynamic_update_slice(
-                    t_pool, jax.lax.dynamic_slice(
-                        ext_c, (n_c, 0), (K - 1, ext_c.shape[1]))[None],
-                    (cslot, 0, 0))
-                u = jnp.concatenate([conv_d, conv_c])
+                u, t_pool = conv_tails(u, t_pool, L["conv_w"], L["conv_b"],
+                                       live, n_c, cslot, starts)
             with jax.named_scope("ssm_scan"):
                 x, dt, dA, bm, cm = ssm_operands(u, dt, L, cfg)
                 xf = x.astype(f32)
@@ -2388,24 +2555,11 @@ class ServingEngine:
                 # is one; rows past its length are the identity
                 valid = (jnp.arange(C) < n_c)[:, None]
                 dt_c = jnp.where(valid, dt[B:], 0)
-
-                def scan():
-                    s0 = jax.lax.cond(
-                        starts, lambda: jnp.zeros(z_pool.shape[1:], f32),
-                        lambda: jax.lax.dynamic_index_in_dim(
-                            z_pool, cslot, 0, keepdims=False))
-                    return ssm_chunk_scan(
+                y_c, z_pool = chunk_state(
+                    z_pool, n_c, cslot, starts, lambda s0: ssm_chunk_scan(
                         xf[B:] * dt_c[..., None],
                         jnp.where(valid, dA[B:], 0), bm[B:], cm[B:], s0,
-                        chunk=cfg.chunk_size)
-
-                y_c, s1 = jax.lax.cond(
-                    n_c > 0, scan,
-                    lambda: (jnp.zeros((C, Hm, P), f32),
-                             jnp.zeros(z_pool.shape[1:], f32)))
-                z_pool = ssm_state_put(
-                    z_pool, jnp.stack([cslot, (n_c > 0).astype(jnp.int32)]),
-                    s1)
+                        chunk=cfg.chunk_size), (C, Hm, P))
                 y = jnp.concatenate([y_d, y_c]) \
                     + L["D"].astype(f32)[None, :, None] * xf
             with jax.named_scope("ssm_out"):
@@ -2413,30 +2567,80 @@ class ServingEngine:
                                    eps).astype(dt_w)
                 return y @ L["w_out"], z_pool, t_pool
 
+        def kda(L, a, z_pool, t_pool, num_tokens, tab):
+            """The KDA mixer of a [T, hidden] -> (its output [T, hidden],
+            the state pool, the tail pool)."""
+            slots, n_live = tab[:B], tab[B:B + 1]
+            cslot, starts = tab[B + 1], tab[B + 2] > 0
+            n_c = num_tokens[B]
+            live = num_tokens[:B] > 0
+            with jax.named_scope("kda_in_proj"):
+                u = jnp.concatenate(
+                    [a @ L["wq"], a @ L["wk"], a @ L["wv"]], -1)
+                f, b, gate = a @ L["wf"], a @ L["wb"], a @ L["wgate"]
+            with jax.named_scope("kda_conv"):
+                u, t_pool = conv_tails(u, t_pool, L["conv_w"], None, live,
+                                       n_c, cslot, starts)
+                q, k, v, g, beta = kda_operands(u, f, b, L, cfg)
+            with jax.named_scope("kda_state_update"):
+                # the decode rows: one step each, the state in place
+                # (row s of the operands is slot s; row B the spare's)
+                rows = slice(0, B + 1)
+                o_d, z_pool = kda_state_update(
+                    z_pool, slots, n_live, q[rows], k[rows], v[rows],
+                    g[rows], beta[rows, :, None])
+                o_d = jnp.where(live[:, None, None], o_d[:B], 0)
+            with jax.named_scope("kda_chunk_scan"):
+                # the chunk: a scan from its slot's state, where there
+                # is one; rows past its length are the identity
+                valid = (jnp.arange(C) < n_c)[:, None]
+                o_c, z_pool = chunk_state(
+                    z_pool, n_c, cslot, starts, lambda s0: kda_chunk_scan(
+                        q[B:], k[B:], v[B:],
+                        jnp.where(valid[..., None], g[B:], 0),
+                        jnp.where(valid, beta[B:], 0), s0,
+                        chunk=cfg.kda_sub_chunk), (C, Hk, Dk))
+            with jax.named_scope("kda_out"):
+                y = kda_gated_norm(jnp.concatenate([o_d, o_c]), gate,
+                                   L["norm_g"], eps).astype(a.dtype)
+                return y @ L["wo"], z_pool, t_pool
+
         def step(w, tok, pools, positions, num_tokens, kv_lengths,
                  tables, tok_page, tok_off):
-            del positions       # no rotary embedding, no position table
             kv_lengths, tab = kv_lengths
             with _scope("embed"):
                 x = w["embed"][tok][None]                # [1, T, hidden]
-            one = jnp.ones((T, D // 2), x.dtype)
-            with _scope("cache_write"):
-                runs = run_table(num_tokens, tok_page, tok_off)
+            if "L" in pattern:
+                rope = _halves_rope(w["cos"][positions],
+                                    w["sin"][positions])
+            else:
+                # no rotary embedding, no position table
+                one = jnp.ones((T, D // 2), x.dtype)
+                with _scope("cache_write"):
+                    runs = run_table(num_tokens, tok_page, tok_off)
             kv_pools, ssm_pools = iter(pools["kv"]), iter(pools["ssm"])
             sts = iter(moe_static)
             new_kv, new_ssm, moe_stats = [], [], []
             live = _owned_rows(T, seq_start, num_tokens)
             for i, kind in enumerate(pattern):  # a letter: static
                 L = w["layers"][i]
-                with _scope("ffn_norm" if kind == "E" else "attn_norm"):
+                with _scope("ffn_norm" if kind in "ED" else "attn_norm"):
                     a = fused_rms_norm(x, L["norm"], eps)
                 if kind == "E":
                     x = x + _ffn_apply(L, a, next(sts), moe_stats, live)
-                elif kind == "M":
-                    y, z_pool, t_pool = ssm(L, a[0], *next(ssm_pools),
-                                            num_tokens, tab)
+                elif kind == "D":
+                    x = x + _ffn_apply(L, a)
+                elif kind in "MK":
+                    y, z_pool, t_pool = (ssm if kind == "M" else kda)(
+                        L, a[0], *next(ssm_pools), num_tokens, tab)
                     new_ssm.append((z_pool, t_pool))
                     x = x + y[None]
+                elif kind == "L":
+                    x, pool = _latent_mixer(
+                        L, a, x, rope, next(kv_pools), seq_start,
+                        num_tokens, kv_lengths, tables, tok_page, tok_off,
+                        **latent)
+                    new_kv.append(pool)
                 else:
                     kp, vp = next(kv_pools)
                     with _scope("qkv_proj"):
@@ -2546,14 +2750,7 @@ class ServingEngine:
                 c = w["cos"][positions]                  # [T, dr/2]
                 s = w["sin"][positions]
 
-            def rope(t):                                 # [1, T, h, dr]
-                d2 = t.shape[-1] // 2
-                t1, t2 = t[..., :d2], t[..., d2:]
-                cc = c[None, :, None, :].astype(t.dtype)
-                ss = s[None, :, None, :].astype(t.dtype)
-                return jnp.concatenate(
-                    [t1 * cc - t2 * ss, t2 * cc + t1 * ss], -1)
-
+            rope = _halves_rope(c, s)
             new_pools = []
             moe_stats = [] if count_moe else None
             live = _owned_rows(T, seq_start, num_tokens) \
@@ -2562,44 +2759,11 @@ class ServingEngine:
             for L, pool, st in zip(w["layers"], pools, sts):
                 with _scope("attn_norm"):
                     h = fused_rms_norm(x, L["ln1"], eps)
-                wkb = _dq(L, "wkvb", x.dtype).reshape(r, nh, dn + dv)
-                w_k, w_v = wkb[..., :dn], wkb[..., dn:]
-                with jax.named_scope("mla_q"):
-                    if "wqa" in L or "wqa_q" in L or "wqa_q4" in L:
-                        q = _mm_w(fused_rms_norm(_mm_w(h, L, "wqa"),
-                                                 L["gq"], eps),
-                                  L, "wqb")
-                    else:
-                        q = _mm_w(h, L, "wq")
-                    q = q.reshape(1, T, nh, dn + dr)
-                    q_nope, q_pe = q[..., :dn], q[..., dn:]
-                    # rope runs on the split q_pe/k_pe shapes (not
-                    # D-halved cache rows), so the append is the
-                    # row-scatter kernel
-                    q_pe = rope(q_pe)
-                    q_eff = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_k)
-                    q_cat = _pad_lanes(
-                        jnp.concatenate([q_eff, q_pe], -1)[0], width)
-                with jax.named_scope("mla_kv"):
-                    kv_a = _mm_w(h, L, "wkva")           # [1, T, r+dr]
-                    lat = fused_rms_norm(kv_a[..., :r], L["gkv"], eps)
-                    k_pe = rope(kv_a[..., r:][:, :, None, :])[:, :, 0]
-                    rows = _pad_lanes(
-                        jnp.concatenate([lat, k_pe], -1)[0], width)
-                    with _scope("cache_write"):
-                        pool = fused_append_rows(pool, rows[:, None],
-                                                 tok_page, tok_off)
+                x, pool = _latent_mixer(
+                    L, h, x, rope, pool, seq_start, num_tokens, kv_lengths,
+                    tables, tok_page, tok_off, nh=nh, dn=dn, dr=dr, dv=dv,
+                    r=r, width=width, eps=eps, scale=scale)
                 new_pools.append(pool)
-                with jax.named_scope("mla_attention"):
-                    # K is the row, V its latent columns: one page
-                    # fetch serves both matmuls
-                    o_lat = ragged_paged_attention(
-                        q_cat, pool, None, seq_start, num_tokens,
-                        kv_lengths, tables, scale=scale, v_dim=r,
-                        scope="mla_attention")
-                with jax.named_scope("mla_out"):
-                    o = jnp.einsum("tnr,rnv->tnv", o_lat, w_v)
-                    x = x + _mm_w(o.reshape(1, T, nh * dv), L, "wo")
                 with _scope("ffn_norm"):
                     h2 = fused_rms_norm(x, L["ln2"], eps)
                 x = x + _ffn_apply(L, h2, st, moe_stats, live)

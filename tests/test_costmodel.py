@@ -61,6 +61,7 @@ SHAPES = {
                                   page_size=32),
     "ssm_state_update": dict(live=3, P=16, N=16, H=8),
     "ssm_state_put": dict(P=16, N=16, H=8),
+    "kda_state_update": dict(live=3, H=4, K=16, V=16),
 }
 
 
@@ -68,9 +69,9 @@ class TestRegistryCoverage:
     def test_all_oracle_kernels_have_costs(self):
         # registration side effects                          # noqa: F401
         from paddle_tpu.ops import (fused, pallas_flash, pallas_flashmask,
-                                    pallas_gmm, pallas_megadecode,
-                                    pallas_megafront, pallas_mla,
-                                    pallas_paged, pallas_ragged,
+                                    pallas_gmm, pallas_kda,
+                                    pallas_megadecode, pallas_megafront,
+                                    pallas_mla, pallas_paged, pallas_ragged,
                                     pallas_ssm, quant)
         from paddle_tpu.ops.oracles import oracles
         names = set(oracles())
@@ -88,6 +89,18 @@ class TestRegistryCoverage:
         assert est.arithmetic_intensity >= 0
         # bandwidth-bound time scales down with more bandwidth
         assert est.theoretical_us(819e9) >= est.theoretical_us(2765e9)
+
+    def test_the_kda_scan_and_state_costs(self):
+        """Plain functions beside the registry: the scan is XLA."""
+        est = cm.kda_chunk_scan_cost(rows=128, sub=64, H=4, K=16, V=16)
+        state = 4 * 16 * 16 * 4
+        assert est.breakdown["state"] == 2 * state
+        assert est.hbm_bytes == 2 * state + 128 * 4 * (3 * 16 + 16 + 1) * 4 \
+            + 128 * 4 * 16 * 4
+        assert est.flops == 2 * 4 * (6 * 64 * 64 * 16 + 2 * 64 ** 3 // 3
+                                     + 6 * 64 * 16 * 16 + 4 * 64 * 64 * 16)
+        assert cm.kda_state_bytes_per_seq_layer(
+            heads=32, head_dim=128, conv_kernel=4) == 2_097_152 + 73_728
 
     def test_unknown_kernel_raises_with_known_list(self):
         with pytest.raises(KeyError, match="known"):

@@ -144,6 +144,12 @@ def test_scope_refuses_a_name_outside_the_vocabulary():
     ("jit(step)/ssm_scan/pallas_call", ("attention", "-")),
     ("jit(step)/ssm_out/dot_general", ("attn_out", "-")),
     ("jit(step)/routed_ffn/latent_proj/dot_general", ("routed_ffn", "-")),
+    # a KDA (delta-rule) mixer's parts (PR 47): two kernels, two names
+    ("jit(step)/kda_in_proj/dot_general", ("qkv_proj", "-")),
+    ("jit(step)/kda_conv/mul", ("cache_write", "-")),
+    ("jit(step)/kda_state_update/pallas_call", ("attention", "-")),
+    ("jit(step)/kda_chunk_scan/cond/dot_general", ("attention", "-")),
+    ("jit(step)/kda_out/dot_general", ("attn_out", "-")),
     ("jit(update)/jit(head)/mul", (None, "-")),     # function names
     ("jit(step)/jit(main)/add", (None, "-")),
 ])
@@ -153,6 +159,8 @@ def test_a_path_gives_its_innermost_name_and_direction(path, want):
 
 @pytest.mark.parametrize("path,want", [
     ("jit(step)/ssm_scan/pallas_call", "ssm_scan"),
+    ("jit(step)/kda_state_update/pallas_call", "kda_state_update"),
+    ("jit(step)/kda_chunk_scan/cond/dot_general", "kda_chunk_scan"),
     ("jit(step)/routed_ffn/latent_proj/dot_general", "latent_proj"),
     ("jit(step)/attention/pallas_call", "attention"),
     ("jit(step)/jit(main)/add", "")])

@@ -46,9 +46,9 @@ def chip():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
     from jax.sharding import SingleDeviceSharding
-    from paddle_tpu.ops import (fused, pallas_flash, pallas_megadecode,
-                                pallas_megafront, pallas_ragged, pallas_ssm,
-                                quant)
+    from paddle_tpu.ops import (fused, pallas_flash, pallas_kda,
+                                pallas_megadecode, pallas_megafront,
+                                pallas_ragged, pallas_ssm, quant)
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
@@ -59,7 +59,7 @@ def chip():
     one = SingleDeviceSharding(topo.devices[0])
     mp = pytest.MonkeyPatch()
     for mod in (fused, pallas_flash, pallas_megadecode, pallas_megafront,
-                pallas_ragged, pallas_ssm, quant):
+                pallas_ragged, pallas_ssm, pallas_kda, quant):
         mp.setattr(mod, "_interpret", lambda: False)
     # a described-device executable is written to the persistent cache
     # but cannot be read back without a chip: keep it off around these
@@ -387,6 +387,31 @@ def test_state_space_kernels_compile_at_the_nemotron_cell_shapes(chip):
         chip.shape((ns, n, h)), chip.shape((c, h, p), F32),
         chip.shape((c, h), F32), chip.shape((c, g, n)),
         chip.shape((c, g, n))), chip.refusals.get(_ssm_layer_kernels)
+
+
+def _kda_layer_kernels(pool, tab, q, k, v, g, beta, qc, kc, vc, gc, bc):
+    from paddle_tpu.ops.pallas_kda import kda_chunk_scan, kda_state_update
+    from paddle_tpu.ops.pallas_ssm import ssm_state_put
+    b = tab.shape[0] - 3
+    o, pool = kda_state_update(pool, tab[:b], tab[b:b + 1], q, k, v, g, beta)
+    oc, s1 = kda_chunk_scan(qc, kc, vc, gc, bc, pool[tab[b + 1]], chunk=64)
+    return o, oc, ssm_state_put(pool, tab[b + 1:], s1)
+
+
+def test_delta_rule_kernels_compile_at_the_ling_cell_shapes(chip):
+    """`ling-3.0-flash-serve-ep8-d7` as its cell runs it: a state pool of
+    384 slots + the spare x [32, 128, 128] float32 (a head's [key, value]
+    tile, values along the lanes), the decode rows' update in place (the
+    rows' [8, 128] operands turned to columns in the kernel), a 256-row
+    chunk's scan in four sub-chunks of 64 and its state's write in place
+    by the state-space pool's own kernel."""
+    ns, h, d, c = 385, 32, 128, 256
+    row, crow = chip.shape((ns, h, d), F32), chip.shape((c, h, d), F32)
+    assert chip.compiles(
+        _kda_layer_kernels, chip.shape((ns, h, d, d), F32),
+        chip.shape((ns + 2,), I32), row, row, row, row,
+        chip.shape((ns, h, 1), F32), crow, crow, crow, crow,
+        chip.shape((c, h), F32)), chip.refusals.get(_kda_layer_kernels)
 
 
 def _serve_norm_and_linears(x, nw, w8, s8, w4, s4):
