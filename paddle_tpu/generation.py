@@ -202,7 +202,19 @@ def _llama_decode_params(model, weight_only_int8: bool = False,
     (ref: paddle/nn/quant weight-only deploy path).
     ``weight_only_quant``: 'int8' (same as the bool) or 'int4' (packed
     nibbles, quarter the weight reads; decode contracts even/odd rows so
-    the unpack fuses — see _int4_halves)."""
+    the unpack fuses — see _int4_halves).
+
+    Layout of the float leaves: ``wq`` / ``wk`` / ``wv`` are stored
+    [heads, head_dim, in] (`_heads_w`; read through `_mm_heads`),
+    because their output is split into heads for a kernel and the TPU
+    compiler then reads the weight in that form — from a stored
+    [in, out] it transposed all three once a layer of every step.
+    ``wo``, the FFN's matrices and the head produce 2-D outputs, are
+    read as stored and stay [in, out] (`_mm_w`). Every family's twin
+    below does the same for its head-split keys (the latent family:
+    ``wqb`` or its one-stage ``wq``, and ``wkvb``). The stored leaf is a
+    second buffer beside the module's parameter: 2 bytes x in x out a
+    key a layer. Quantized pairs keep [K, N]."""
     algo, enabled = _woq_algo(weight_only_int8, weight_only_quant)
     cfg = model.config
     inner = getattr(model, "llama", None)
@@ -233,6 +245,7 @@ def _llama_decode_params(model, weight_only_int8: bool = False,
             d["bv"] = a.v_proj.bias._data
         for k in ("wq", "wk", "wv", "wo", "wg", "wu", "wd"):
             _q8(d, k, enabled, algo)
+        _heads_w(d, cfg.head_dim, "wq", "wk", "wv")
         layers.append(d)
     head = model.lm_head.weight._data if model.lm_head is not None else None
     p = dict(cfg=cfg, family="llama",
@@ -303,6 +316,30 @@ def _q8(d, key, enabled: bool = True, algo: str = "weight_only_int8"):
         qw, sc = qfn(w)
     d[key + ("_q4" if algo == "weight_only_int4" else "_q")] = qw
     d[key + "_s"] = sc.astype(jnp.float32)
+
+
+def _heads_w(d, head_dim: int, *keys):
+    """Store the float leaves d[key] [in, heads * head_dim] as [heads,
+    head_dim, in], in place: the store side of `_mm_heads`, called once
+    at load by every `_*_decode_params` twin for the projections whose
+    output is split into heads for a kernel (``wq`` / ``wk`` / ``wv``;
+    the latent family's ``wqb`` or one-stage ``wq``, and ``wkvb``). For
+    such an output the TPU compiler reads the weight as [heads, D, in]
+    with ``in`` minor; a stored [in, heads * D] cannot be bitcast to
+    that, and a weight is an argument of the step, so the transposition
+    ran once a layer of every step. The 2-D [out, in] has the same
+    bytes and compiles copy-free too, but at Mistral's depth the
+    compiler then placed the step's activations elsewhere and the step
+    was 1.7 ms SLOWER on the chip; under the 3-D form the compiled step
+    is the parent's without the copies (PERF.md section 6, PR 48). The
+    stored leaf is a second buffer beside the module's parameter. A
+    quantized pair (``key_q`` / ``key_q4``, ``key_s``) has no float leaf
+    under ``key`` and keeps [K, N]: its kernel and its
+    per-output-channel scales are written for that."""
+    for key in keys:
+        if d.get(key) is not None:
+            w = d[key]
+            d[key] = w.T.reshape(-1, head_dim, w.shape[0])
 
 
 def _mlp_params(lyr, weight_only_int8: bool = False,
@@ -384,6 +421,7 @@ def _moe_decode_params(model, weight_only_int8: bool = False,
             ln2=lyr.post_attention_layernorm.weight._data)
         for k in ("wq", "wk", "wv", "wo"):
             _q8(d, k, weight_only_int8, algo)
+        _heads_w(d, cfg.head_dim, "wq", "wk", "wv")
         mlp_w, mlp_st = _mlp_params(lyr, weight_only_int8, algo)
         d.update(mlp_w)
         layers.append(d)
@@ -439,6 +477,7 @@ def _laguna_decode_params(model):
                  wv=a.v_proj.weight._data, wgate=a.g_proj.weight._data,
                  wo=a.o_proj.weight._data,
                  ln2=lyr.post_attention_layernorm.weight._data)
+        _heads_w(d, D, "wq", "wk", "wv")
         mlp_w, mlp_st = _mlp_params(lyr)
         d.update(mlp_w)
         layers.append(d)
@@ -483,6 +522,7 @@ def _eva_decode_params(model):
             ln2=gain(lyr.post_attention_layernorm),
             wg=m.gate_proj.weight._data, wu=m.up_proj.weight._data,
             wd=m.down_proj.weight._data))
+        _heads_w(layers[-1], cfg.head_dim, "wq", "wk", "wv")
     return dict(
         cfg=cfg, family="eva", embed=inner.embed_tokens.weight._data,
         layers=layers, norm=gain(inner.norm),
@@ -510,6 +550,7 @@ def _looped_decode_params(model):
             wg=m.gate_proj.weight._data, wu=m.up_proj.weight._data,
             wd=m.down_proj.weight._data,
             ln2_out=lyr.post_attention_layernorm_2.weight._data))
+        _heads_w(layers[-1], cfg.head_dim, "wq", "wk", "wv")
     gate = inner.early_exit_gate
     return dict(
         cfg=cfg, family="looped", embed=inner.embed_tokens.weight._data,
@@ -538,6 +579,8 @@ def _hybrid_decode_params(model):
             moe_static.append(mix.static())
         else:
             d.update(arrays(mix.weights()))
+        if blk.kind == "*":
+            _heads_w(d, cfg.head_dim, "wq", "wk", "wv")
         layers.append(d)
     n_attn = cfg.hybrid_override_pattern.count("*")
     return dict(
@@ -587,6 +630,8 @@ def _bailing_decode_params(model):
                 -1).reshape(d["wq"].shape)
             d["wkva"] = jnp.concatenate(
                 [d["wkva"][:, :r], d["wkva"][:, r:][:, order]], -1)
+            _heads_w(d, dn + dr, "wq")
+            _heads_w(d, dn + cfg.v_head_dim, "wkvb")
         layers.append(d)
     return dict(
         cfg=cfg, family="hybrid", pattern=cfg.pattern,
@@ -634,6 +679,9 @@ def _mla_decode_params(model, weight_only_int8: bool = False,
         for k in ("wkva", "wkvb", "wo", "wqa", "wqb", "wq"):
             if k in d:
                 _q8(d, k, weight_only_int8, algo)
+        _heads_w(d, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
+                 "wqb", "wq")
+        _heads_w(d, cfg.qk_nope_head_dim + cfg.v_head_dim, "wkvb")
         mlp_w, mlp_st = _mlp_params(lyr, weight_only_int8, algo)
         d.update(mlp_w)
         layers.append(d)
@@ -777,7 +825,10 @@ def _mm_w(h, L, key):
     bytes that bound decode); fp layouts hold the key directly. The ONE
     place both layouts' matmul goes through. Packed-int4 layouts
     (key_q4) contract even/odd input rows against the nibble planes so
-    the unpack fuses into the dot operand loads (_int4_halves)."""
+    the unpack fuses into the dot operand loads (_int4_halves).
+    Every weight here is [K, N] = [in, out]; a projection whose output
+    is split into heads goes through `_mm_heads`, whose float leaf is
+    stored [heads, head_dim, in]."""
     if key + "_q4" in L:
         # in-kernel unpack for ANY N: packed int4 is the only weight HBM
         # traffic (XLA cannot fuse the shift chain into the MXU feed, so
@@ -788,6 +839,30 @@ def _mm_w(h, L, key):
         return weight_only_linear(h, L[key + "_q4"], L[key + "_s"],
                                   algo="weight_only_int4")
     return h @ _dq(L, key, h.dtype)
+
+
+def _mm_heads(h, L, key):
+    """h @ W [..., heads * D] of a projection whose output is split into
+    heads: a float leaf is stored [heads, D, in] (`_heads_w`) and
+    contracted on its last axis — the same operands, contraction and
+    accumulation as `h @ W`, read the way the compiled dot reads them; a
+    quantized pair kept [K, N] and goes through `_mm_w`. The ONE reader
+    of those leaves."""
+    if key in L:
+        y = jax.lax.dot_general(h, L[key], (((h.ndim - 1,), (2,)), ((), ())))
+        return y.reshape(*h.shape[:-1], -1)
+    return _mm_w(h, L, key)
+
+
+def _kvb_heads(L, nh: int, dtype):
+    """The latent family's kv_b as [heads, dn + dv, r] — head a's W^K
+    rows then its W^V rows, ``r`` minor — for the absorbed form's two
+    einsums: the float leaf as stored (`_heads_w`), or a quantized pair
+    read whole ([r, out], `_dq`) and turned."""
+    if "wkvb" in L:
+        return L["wkvb"]
+    w = _dq(L, "wkvb", dtype)
+    return w.reshape(w.shape[0], nh, -1).transpose(1, 2, 0)
 
 
 def _ffn_apply(L, h2, st=None, stats=None, live=None):
@@ -889,8 +964,8 @@ def _llama_cached_step_body(cfg, max_len: int, moe_static=None):
         sts = moe_static or (None,) * len(w["layers"])
         for L, (ck, cv), st in zip(w["layers"], caches, sts):
             h = rms(x, L["ln1"])
-            q, k, v = (_mm_w(h, L, "wq"), _mm_w(h, L, "wk"),
-                       _mm_w(h, L, "wv"))
+            q, k, v = (_mm_heads(h, L, w)
+                       for w in ("wq", "wk", "wv"))
             if "bq" in L:                      # Qwen2 qkv biases
                 q, k, v = q + L["bq"], k + L["bk"], v + L["bv"]
             q = q.reshape(B, S, Hh, D)
@@ -1060,9 +1135,9 @@ def _mla_cached_step_body(cfg, max_len: int, moe_static=None):
         for L, (c_lat, c_pe), st in zip(w["layers"], caches, sts):
             h = rms(x, L["ln1"])
             if "wqa" in L or "wqa_q" in L or "wqa_q4" in L:
-                q = _mm_w(rms(_mm_w(h, L, "wqa"), L["gq"]), L, "wqb")
+                q = _mm_heads(rms(_mm_w(h, L, "wqa"), L["gq"]), L, "wqb")
             else:
-                q = _mm_w(h, L, "wq")
+                q = _mm_heads(h, L, "wq")
             q = q.reshape(B, S, nh, dn + dr)
             q_nope, q_pe = q[..., :dn], q[..., dn:]
             q_pe = apply_rope(q_pe, cos, sin)
@@ -1083,8 +1158,7 @@ def _mla_cached_step_body(cfg, max_len: int, moe_static=None):
                 # long-context prefill (matches models/deepseek.py
                 # forward, incl. the padded-head route for dv != dn+dr)
                 from .ops.flash_attention import sdpa_padded_heads
-                kv = (lat @ _dq(L, "wkvb", x.dtype)).reshape(
-                    B, S, nh, dn + dv)
+                kv = _mm_heads(lat, L, "wkvb").reshape(B, S, nh, dn + dv)
                 k_h = jnp.concatenate(
                     [kv[..., :dn],
                      jnp.broadcast_to(k_pe[:, :, None, :], (B, S, nh, dr))],
@@ -1098,10 +1172,10 @@ def _mla_cached_step_body(cfg, max_len: int, moe_static=None):
                 h2 = rms(x, L["ln2"])
                 x = x + _ffn_apply(L, h2, st)
                 continue
-            wkb = _dq(L, "wkvb", x.dtype).reshape(r, nh, dn + dv)
-            w_k, w_v = wkb[..., :dn], wkb[..., dn:]
+            wkb = _kvb_heads(L, nh, x.dtype)
+            w_k, w_v = wkb[:, :dn], wkb[:, dn:]
             # absorb W_k onto the query: score = q_eff . latent + q_pe . k_pe
-            q_eff = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_k)
+            q_eff = jnp.einsum("bsnd,ndr->bsnr", q_nope, w_k)
             if use_fused:
                 # single-read fused decode: each latent-cache byte feeds
                 # the score AND the output from one VMEM tile (the XLA
@@ -1118,7 +1192,7 @@ def _mla_cached_step_body(cfg, max_len: int, moe_static=None):
                                    scores.astype(jnp.float32), -1e30)
                 aw = jax.nn.softmax(scores, axis=-1).astype(c_lat.dtype)
                 o_lat = jnp.einsum("bnst,btr->bsnr", aw, c_lat)
-            o = jnp.einsum("bsnr,rnv->bsnv", o_lat, w_v)
+            o = jnp.einsum("bsnr,nvr->bsnv", o_lat, w_v)
             x = x + _mm_w(o.reshape(B, S, nh * dv), L, "wo")
             h2 = rms(x, L["ln2"])
             x = x + _ffn_apply(L, h2, st)

@@ -62,8 +62,8 @@ from .. import resilience as _res
 from ..observability import costmodel as _costmodel
 from ..observability.attribution import compile_named, scope as _scope
 from ..observability import tracing as _tracing
-from ..generation import (_decode_params, _dq, _ffn_apply, _llama_weights,
-                          _mm_w)
+from ..generation import (_decode_params, _ffn_apply, _kvb_heads,
+                          _llama_weights, _mm_heads, _mm_w)
 from ..ops.fused import (append_run_count, append_run_table, append_tile,
                          fused_append_rows, fused_chunk_pool,
                          fused_layer_norm, fused_rms_norm,
@@ -292,19 +292,19 @@ def _latent_mixer(L, h, x, rope, pool, seq_start, num_tokens, kv_lengths,
     the decode rows. -> (x + the mixer's output, the pool). Shared by
     `_mla_unified_body` and the hybrid body's ``L`` blocks."""
     T = h.shape[1]
-    wkb = _dq(L, "wkvb", x.dtype).reshape(r, nh, dn + dv)
-    w_k, w_v = wkb[..., :dn], wkb[..., dn:]
+    wkb = _kvb_heads(L, nh, x.dtype)
+    w_k, w_v = wkb[:, :dn], wkb[:, dn:]
     with jax.named_scope("mla_q"):
         if "wqa" in L or "wqa_q" in L or "wqa_q4" in L:
-            q = _mm_w(fused_rms_norm(_mm_w(h, L, "wqa"),
-                                     L["gq"], eps),
-                      L, "wqb")
+            q = _mm_heads(fused_rms_norm(_mm_w(h, L, "wqa"),
+                                         L["gq"], eps),
+                          L, "wqb")
         else:
-            q = _mm_w(h, L, "wq")
+            q = _mm_heads(h, L, "wq")
         q = q.reshape(1, T, nh, dn + dr)
         q_nope, q_pe = q[..., :dn], q[..., dn:]
         q_pe = rope(q_pe)
-        q_eff = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_k)
+        q_eff = jnp.einsum("bsnd,ndr->bsnr", q_nope, w_k)
         q_cat = _pad_lanes(
             jnp.concatenate([q_eff, q_pe], -1)[0], width)
     with jax.named_scope("mla_kv"):
@@ -324,7 +324,7 @@ def _latent_mixer(L, h, x, rope, pool, seq_start, num_tokens, kv_lengths,
             kv_lengths, tables, scale=scale, v_dim=r,
             scope="mla_attention")
     with jax.named_scope("mla_out"):
-        o = jnp.einsum("tnr,rnv->tnv", o_lat, w_v)
+        o = jnp.einsum("tnr,nvr->tnv", o_lat, w_v)
         if "wgate" in L:
             o = o * jax.nn.sigmoid(
                 (h[0] @ L["wgate"]).astype(jnp.float32))[
@@ -2203,8 +2203,8 @@ class ServingEngine:
                 with _scope("attn_norm"):
                     h = fused_rms_norm(x, L["ln1"], eps)
                 with _scope("qkv_proj"):
-                    q, k, v = (_mm_w(h, L, "wq"), _mm_w(h, L, "wk"),
-                               _mm_w(h, L, "wv"))
+                    q, k, v = (_mm_heads(h, L, w)
+                               for w in ("wq", "wk", "wv"))
                     if "bq" in L:
                         q, k, v = q + L["bq"], k + L["bk"], v + L["bv"]
                 with _scope("cache_write"):
@@ -2281,9 +2281,8 @@ class ServingEngine:
                 with _scope("attn_norm"):
                     h = norm(x, L["ln1"])
                 with _scope("qkv_proj"):
-                    q, k, v = ((h @ L["wq"]).reshape(T, H, D),
-                               (h @ L["wk"]).reshape(T, H, D),
-                               (h @ L["wv"]).reshape(T, H, D))
+                    q, k, v = (_mm_heads(h, L, w).reshape(T, H, D)
+                               for w in ("wq", "wk", "wv"))
                 # `eva_pool` / `eva_attention` stay the kernels' own
                 # (innermost) names: the trace's readers find them so
                 with _scope("cache_write"):
@@ -2366,7 +2365,8 @@ class ServingEngine:
                 with _scope("attn_norm"):
                     h = fused_rms_norm(x, L["ln1"], eps)
                 with _scope("qkv_proj"):
-                    q, k, v = h @ L["wq"], h @ L["wk"], h @ L["wv"]
+                    q, k, v = (_mm_heads(h, L, w)
+                               for w in ("wq", "wk", "wv"))
                 with _scope("cache_write"):
                     q, kp, vp = fused_rope_append(
                         q.reshape(T, H, D), k.reshape(T, KV, D),
@@ -2644,7 +2644,8 @@ class ServingEngine:
                 else:
                     kp, vp = next(kv_pools)
                     with _scope("qkv_proj"):
-                        q, k, v = a @ L["wq"], a @ L["wk"], a @ L["wv"]
+                        q, k, v = (_mm_heads(a, L, w)
+                                   for w in ("wq", "wk", "wv"))
                     with _scope("cache_write"):
                         q, kp, vp = fused_rope_append(
                             q.reshape(T, Hq, D), k.reshape(T, KV, D),

@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.generation import generate_cached
+from paddle_tpu.generation import (_decode_params, _kvb_heads, _mm_heads,
+                                   generate_cached)
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving import engine as engine_mod
 from test_serving_engine import _run_trace
@@ -219,6 +220,128 @@ class TestOneChain:
             assert here in text, here
         # launches before attention: GPT's one qkv dot, MLA's q-lora pair
         assert eng.front_half_launches == {"gpt": 3, "mla": 7}.get(family, 5)
+
+
+def _family_model(family):
+    """`_tiny` / `_laguna`, and a seeded toy of the four later families."""
+    if family == "laguna":
+        return _laguna()
+    if family not in ("eva", "looped", "hybrid", "bailing"):
+        return _tiny(family)
+    from paddle_tpu.models import bailing_hybrid, evabyte, nemotron_h, ouro
+    paddle.seed(0)
+    m = {"eva": lambda: evabyte.EvaByteForCausalLM(
+            evabyte.evabyte_tiny_config()),
+         "looped": lambda: ouro.OuroForCausalLM(ouro.ouro_tiny_config()),
+         "hybrid": lambda: nemotron_h.NemotronHForCausalLM(
+            nemotron_h.nemotron_h_tiny_config()),
+         "bailing": lambda: bailing_hybrid.BailingHybridForCausalLM(
+            bailing_hybrid.bailing_hybrid_tiny_config())}[family]()
+    m.eval()
+    return m
+
+
+#: the leaves whose output is split into heads for a kernel, and of
+#: those the ones `_decode_params` stores with their columns reordered
+#: (a partial or an interleaved rope): the rest are the module's own
+HEAD_SPLIT = ("wq", "wk", "wv", "wqb", "wkvb")
+REORDERED = {"laguna": ("wq", "wk"), "bailing": ("wq",)}
+
+
+class TestHeadSplitWeightsStoredAsRead:
+    """ISSUE 48: a float q / k / v (latent: q_b or the one-stage q, and
+    kv_b) leaf of every family's decode tree is stored [heads, head_dim,
+    in] — the module's parameter transposed, once, at load — and
+    `_mm_heads` contracts its last axis: `h @ W`. A quantized pair keeps
+    [K, N]. `generate` and `ServingEngine` build the tree through the
+    same `_decode_params` (the families' engine-against-`generate_cached`
+    and engine-against-reference tests hold them to the same logits)."""
+
+    @pytest.mark.parametrize("family", [
+        "llama", "moe", "mla", "mla_no_q_lora", "laguna", "eva", "looped",
+        "hybrid", "bailing"])
+    def test_stored_form_applied_equals_h_at_w(self, family):
+        m = _family_model(family)
+        p = _decode_params(m)
+        own = [np.asarray(prm._data) for _, prm in m.named_parameters()
+               if prm._data.ndim == 2]
+        rng = np.random.RandomState(0)
+        seen = set()
+        for i, L in enumerate(p["layers"]):
+            # a hybrid names its KDA mixer's in-projections `wq` too:
+            # their output feeds a convolution, not heads, and they stay
+            kda = "conv_w" in L and "wq" in L
+            for key in HEAD_SPLIT:
+                if key not in L:
+                    continue
+                stored = np.asarray(L[key])
+                if kda:
+                    assert any(stored.shape == w.shape
+                               and np.array_equal(stored, w) for w in own)
+                    continue
+                seen.add(key)
+                assert stored.ndim == 3
+                # [in, out], as the parent stored it
+                w = stored.reshape(-1, stored.shape[2]).T
+                if key not in REORDERED.get(family, ()):
+                    assert any(w.shape == o.shape and np.array_equal(w, o)
+                               for o in own), (i, key)
+                if key == "wkvb":
+                    # read for the absorbed form as [heads, dn + dv, r]:
+                    # the leaf itself, head a's columns of the parameter
+                    # as its rows
+                    nh = m.config.num_attention_heads
+                    np.testing.assert_array_equal(
+                        np.asarray(_kvb_heads(L, nh, L[key].dtype)),
+                        w.reshape(w.shape[0], nh, -1).transpose(1, 2, 0))
+                # the same contraction: equal bit for bit where the
+                # arithmetic is exact (whole numbers: no order of
+                # summation shows), and to float32's rounding on the
+                # parameter itself (the CPU sums a transposed operand
+                # in another order)
+                h = jnp.asarray(rng.randint(-4, 5, (1, 5, w.shape[0])),
+                                L[key].dtype)
+                whole = {key: jnp.round(L[key] * 64)}
+                np.testing.assert_array_equal(
+                    np.asarray(_mm_heads(h, whole, key)),
+                    np.asarray(h @ jnp.round(jnp.asarray(w) * 64)))
+                h = jnp.asarray(rng.randn(1, 5, w.shape[0]), L[key].dtype)
+                np.testing.assert_allclose(
+                    np.asarray(_mm_heads(h, L, key)),
+                    np.asarray(h @ jnp.asarray(w)), rtol=1e-5, atol=1e-6)
+        want = {"mla": {"wqb", "wkvb"}, "mla_no_q_lora": {"wq", "wkvb"},
+                "bailing": {"wq", "wkvb"}}.get(family, {"wq", "wk", "wv"})
+        assert seen == want
+        # the engine reads the same tree
+        eng = ServingEngine(m, max_slots=2, page_size=8, max_context=64,
+                            prefill_chunk=8)
+        for L, Le in zip(p["layers"], eng._w["layers"]):
+            for key in HEAD_SPLIT:
+                if key in L:
+                    np.testing.assert_array_equal(np.asarray(L[key]),
+                                                  np.asarray(Le[key]))
+
+    @pytest.mark.parametrize("family,quant", [
+        ("llama", "int8"), ("llama", "int4"), ("moe", "int8"),
+        ("mla", "int8"), ("mla", "int4")])
+    def test_quantized_leaves_keep_k_n(self, family, quant):
+        m = _family_model(family)
+        fp, q = _decode_params(m), _decode_params(m, weight_only_quant=quant)
+        sfx, rows = ("_q4", 2) if quant == "int4" else ("_q", 1)
+        n = 0
+        for Lf, Lq in zip(fp["layers"], q["layers"]):
+            for key in HEAD_SPLIT:
+                if key not in Lf:
+                    continue
+                heads, hd, k = Lf[key].shape    # the float leaf
+                out = heads * hd
+                assert key not in Lq
+                assert Lq[key + sfx].shape == (k // rows, out)
+                assert Lq[key + "_s"].shape == (out,)
+                h = jnp.ones((1, 3, k), jnp.float32)
+                assert _mm_heads(h, Lq, key).shape == (1, 3, out)
+                n += 1
+        assert n == {"mla": 2}.get(family, 3) * len(fp["layers"])
 
 
 class TestTheNamesTheBenchmarkReads:

@@ -454,17 +454,20 @@ class TestSummaryMask:
 #: of a sequence that owns a few rows of its tile computes the window of
 #: rows that holds them; the heads' chains in three passes) re-recorded
 #: all five: every toy launch is ONE tile a cell, taller than the window.
+#: PR 48 (the head-split projections' weights stored [heads, D, in] and
+#: contracted on their last axis, `generation._mm_heads`) re-recorded
+#: every family that has one: all but `gpt` (a fused `wqkv`, as it was).
 LOWERED_AT_PARENT = {
-    "llama": "c4ebdf4852efdb2da55d0ebb719b313d2e92dc1635e255ee96ebf67445e0"
-             "078b",
-    "moe": "7f2b0001aa3cce9f7cf7ff3ecd8042ae1d3dd253a88066680133691480dd08"
-           "9d",
-    "mla": "d26a585a3d0e6625382cb85ca3be80c596f602a4374ba275434a3cf368e34d"
-           "ac",
+    "llama": "4d83618490abd9d8f116cc016366e6172f711b6d4f6b0ce6ed29d45b7e3d"
+             "e951",
+    "moe": "0de185b03924605a0d214cf359c90904112bb12066a8eb1160461ff17cbae3"
+           "ef",
+    "mla": "539252a8649cd7d29838b137886cb8f145bcf82679d315f448c6a64ebd4891"
+           "17",
     "gpt": "663c3f3c4d2afd7ca79f46705c2730cf70402b7affd7238e977b6989d4da17"
            "3b",
-    "laguna": "22aed154f936d23508b8b795885e385ca7dca6f917c042790442c102d0f"
-              "6e3af",
+    "laguna": "0e64637de82469062d258f39ca057729aa8249d93545457be6ac89560ef"
+              "736f8",
 }
 
 

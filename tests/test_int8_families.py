@@ -190,7 +190,7 @@ class TestLlamaInt4:
         # not a property of this code path — a random-init tiny model
         # shows ~0.3 rel there, trained weights far less.
         from paddle_tpu.generation import (_llama_decode_params,
-                                           _cached_step_body,
+                                           _cached_step_body, _heads_w,
                                            _llama_weights, _init_caches)
         from paddle_tpu.ops.quant import weight_dequantize
         rng = np.random.RandomState(4)
@@ -214,6 +214,9 @@ class TestLlamaInt4:
                     continue
                 else:
                     out[k] = v
+            # a float q / k / v leaf is stored [heads, head_dim, in],
+            # as the decode-params builder leaves it
+            _heads_w(out, model.config.head_dim, "wq", "wk", "wv")
             return out
 
         pf = {k: (deq(v) if isinstance(v, dict)

@@ -253,9 +253,10 @@ def test_an_untileable_shape_is_refused(tiny, monkeypatch):
 #: `bias`, the experts' `relu2` and `_ffn_apply`'s latent projections
 #: add no operation where they are not asked for. PR 45 (a decode row's
 #: page visit of the ragged kernel computes the few rows the row owns)
-#: re-recorded it with the six others.
+#: re-recorded it with the six others, and PR 48 (q / k / v weights
+#: stored [heads, D, in]) with all of them but `gpt`.
 LOOPED_LOWERED_AT_PARENT = \
-    "527f6be01571fcad7787c4d3c63c6ab54acb6ea846cda0907d9481e850cff697"
+    "09dc064d067f5a3e7f31015364ec31bef43ede75b83d5deef0c11b4c0ad88e4c"
 
 
 def _lower_looped():

@@ -276,9 +276,9 @@ def test_an_untileable_shape_is_refused(tiny, monkeypatch):
 #: not through them. PR 42 (a page visit of the ragged kernel serves a
 #: block of KV heads) re-recorded it with four of the five, PR 45 (a
 #: decode row's page visit computes the few rows the row owns) with all
-#: five.
+#: five, PR 48 (q / k / v weights stored [heads, D, in]) with four of them.
 EVA_LOWERED_AT_PARENT = \
-    "320faf7e6c6e1cb343d35a5b2b2f480cba7cc6b0f30148cca42297ee30f2d47b"
+    "5ed8fa4892628ec664c39c2477f2474e02bc85bb8fdfe2f6beb3990a3d4ba697"
 
 
 def _lower_eva():

@@ -559,6 +559,130 @@ def test_unified_step_updates_its_pools_in_place(chip, family, n_shapes):
 
 
 # ---------------------------------------------------------------------------
+# the step reads its head-split projections as they are stored (ISSUE
+# 48): a q / k / v (latent: q_b / kv_b) output is split into heads for a
+# kernel, the compiler reads such a weight as [heads, D, in], and a
+# stored [in, heads * D] was transposed once a layer of every step.
+# Stored [heads, D, in] (`generation._heads_w`) and contracted on the
+# last axis (`_mm_heads`, `_kvb_heads`) no copy of a weight's shape is
+# left.
+# ---------------------------------------------------------------------------
+
+#: the leaves `_heads_w` stores [heads, D, in]
+HEAD_SPLIT = ("wq", "wk", "wv", "wqb", "wkvb")
+
+
+def _wide_engine(family):
+    """Two layers at a serving cell's ATTENTION widths (Mistral 32 / 8
+    heads x 128 over 4096, EvaByte 32 / 32, Ouro 16 / 16 over 2048,
+    A.X-K1's 64 latent heads over ranks 1536 / 512), its slots, chunk
+    and page; FFN, vocabulary (and the latent family's hidden width)
+    small. A toy weight is staged through fast memory whichever way it
+    is stored, and that hides the copy this test is about."""
+    import paddle_tpu as paddle
+    from paddle_tpu.serving import ServingEngine
+    paddle.seed(0)
+    small = dict(intermediate_size=512, vocab_size=512, num_hidden_layers=2,
+                 max_position_embeddings=1024)
+    eng = dict(max_slots=32, page_size=256, max_context=1024,
+               prefill_chunk=256)
+    if family == "eva":
+        from paddle_tpu.models.evabyte import (EvaByteForCausalLM,
+                                               evabyte_tiny_config)
+        model = EvaByteForCausalLM(evabyte_tiny_config(**dict(
+            small, hidden_size=4096, num_attention_heads=32,
+            num_key_value_heads=32, chunk_size=16, window_size=512,
+            num_pred_heads=2, vocab_size=320, rope_positions=1024)))
+    elif family == "looped":
+        from paddle_tpu.models.ouro import OuroForCausalLM, ouro_tiny_config
+        model = OuroForCausalLM(ouro_tiny_config(**dict(
+            small, hidden_size=2048, num_attention_heads=16,
+            num_key_value_heads=16, head_dim=128, total_ut_steps=4,
+            rope_positions=1024)))
+        eng.update(max_slots=16, page_size=64)
+    elif family == "latent":
+        from paddle_tpu.models.axk1 import (AXK1ForCausalLM,
+                                            axk1_tiny_config)
+        model = AXK1ForCausalLM(axk1_tiny_config(**dict(
+            small, hidden_size=1024, num_attention_heads=64,
+            num_key_value_heads=64, q_lora_rank=1536, kv_lora_rank=512,
+            qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+            intermediate_size=256, moe_intermediate_size=64,
+            experts_held=(4, 4), max_position_embeddings=4096,
+            rope_positions=1024)))
+    else:
+        from paddle_tpu.models.llama import (LlamaForCausalLM,
+                                             llama_tiny_config)
+        model = LlamaForCausalLM(llama_tiny_config(**dict(
+            small, hidden_size=4096, num_attention_heads=32,
+            num_key_value_heads=8, head_dim=128)))
+    model.eval()
+    for _, prm in model.named_parameters():
+        prm._data = prm._data.astype(jnp.bfloat16)
+    return ServingEngine(model, **eng)
+
+
+def _weight_copies(text, shapes):
+    """Lines of a compiled program that `copy` an array of a weight's
+    shape ``(heads, D, in)``: as stored, or 2-D in either orientation.
+    Not the `copy-done` of an asynchronous one: that is the prefetch of
+    a weight into fast memory as it is stored (ROADMAP S11 keeps it),
+    not a transposition."""
+    pat = re.compile("= (" + "|".join(
+        re.escape("bf16[" + ",".join(map(str, s)) + "]")
+        for h, d, k in shapes
+        for s in ((h, d, k), (h * d, k), (k, h * d))) + r")\S* copy\(")
+    return [ln.strip() for ln in text.splitlines() if pat.search(ln)]
+
+
+@pytest.mark.parametrize("family", ["llama", "eva", "looped", "latent"])
+def test_unified_step_reads_head_split_weights_as_stored(chip, family,
+                                                         monkeypatch):
+    from paddle_tpu.serving import engine as engine_mod
+    eng = _wide_engine(family)
+    assert eng.ragged
+    B, C = eng.max_slots, eng.prefill_chunk
+    rows, seqs = chip.shape((B + C,), I32), chip.shape((B + 1,), I32)
+    table = chip.shape((B + 1, eng.pages_per_seq), I32)
+    lens, page, off = seqs, rows, rows
+    if family == "eva":     # three operands are pairs (a pooling slot list)
+        slots = chip.shape((2, B + C // eng.allocator.chunk), I32)
+        lens, page, off = (seqs, seqs), (rows, slots), (rows, slots)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: chip.shape(a.shape, a.dtype), tree)
+
+    def args(w):
+        return (on_chip(w), rows, on_chip(eng._pools), rows, seqs, lens,
+                table, page, off)
+
+    held = [(k, L[k].shape) for L in eng._w["layers"] for k in HEAD_SPLIT
+            if k in L]
+    shapes = {s for _, s in held}
+    assert len(held) == {"latent": 2}.get(family, 3) * len(eng._w["layers"])
+    text = jax.jit(eng._make_unified_body()).lower(
+        *args(eng._w)).compile().as_text()
+    copies = _weight_copies(text, shapes)
+    assert not copies, copies[:3]
+    # the same body fed [in, out] weights through a plain `h @ w` (the
+    # kv_b through the read a quantized pair takes): one copy a weight,
+    # so the pattern still reads what the compiler prints
+    monkeypatch.setattr(engine_mod, "_mm_heads",
+                        lambda h, L, key: h @ L[key])
+    monkeypatch.setattr(
+        engine_mod, "_kvb_heads", lambda L, nh, dtype: L["wkvb"].reshape(
+            L["wkvb"].shape[0], nh, -1).transpose(1, 2, 0))
+    plain_w = dict(eng._w, layers=[
+        {k: jax.ShapeDtypeStruct((v.shape[2], v.shape[0] * v.shape[1]),
+                                 v.dtype)
+         if k in HEAD_SPLIT else v for k, v in L.items()}
+        for L in eng._w["layers"]])
+    plain = jax.jit(eng._make_unified_body()).lower(
+        *args(plain_w)).compile().as_text()
+    assert len(_weight_copies(plain, shapes)) >= len(held)
+
+
+# ---------------------------------------------------------------------------
 # train path (run_pretrain's llama3_8b_shard recipe: batch 3, seq 8192)
 # ---------------------------------------------------------------------------
 
