@@ -608,9 +608,39 @@ def spec_transfer_elems(spec: BlockSpecModel, grid: List[int],
         last = max(refs)
         if last >= len(grid):
             return None
-        for g in grid[: last + 1]:
+        for g in grid[:last]:
             runs *= g
+        runs *= _axis_fetch_runs(spec.index_map, last, grid_len, grid[last],
+                                 bindings)
     return runs * elems
+
+
+def _axis_fetch_runs(imap: IndexMapModel, axis: int, grid_len: int,
+                     extent: int, bindings: Dict[str, int]) -> int:
+    """Fetches along grid dim `axis`: its extent, unless the map reads the
+    dim ONLY as the index into scalar-prefetch tables (``tab[t]``: flash's
+    visit table) whose number of value changes along the axis is bound as
+    ``<tab>_runs`` — a block is re-copied when its index changes, and a
+    table of sorted runs changes less often than the axis ticks."""
+    param = imap.params[axis]
+    tables = set(imap.params[grid_len:])
+    nodes = [n for comps in imap.returns for c in comps for n in ast.walk(c)]
+    through: Set[str] = set()
+    indexing: Set[int] = set()      # the dim's uses as a table's index
+    for node in nodes:
+        if isinstance(node, ast.Subscript) \
+                and _subscript_root(node) in tables:
+            uses = {id(n) for n in ast.walk(node.slice)
+                    if isinstance(n, ast.Name) and n.id == param}
+            if uses:
+                through.add(_subscript_root(node))
+                indexing |= uses
+    bare = any(isinstance(n, ast.Name) and n.id == param
+               and id(n) not in indexing for n in nodes)
+    bound = [bindings.get(f"{t}_runs") for t in through]
+    if bare or not bound or None in bound:
+        return extent
+    return min(extent, max(bound))
 
 
 def transfer_bytes(site: KernelCallSite, bindings: Dict[str, int],

@@ -19,9 +19,11 @@ launches) and derives, purely from the committed AST,
     ROADMAP item 1 (mega-kernel decode).
 
 The flash/flashmask in_specs ride through the tuple-unpacked ``_specs``
-helpers, invisible to the flow-insensitive ``Env``; they are rebuilt by
-recording the ``order == 'qk'`` branch over the helper's scope (the same
-technique `tests/test_costmodel.py` committed for the flash pin).
+helpers, invisible to the flow-insensitive ``Env``; they are rebuilt
+over the helper's scope — flashmask's with its ``order == 'qk'`` branch
+recorded (the technique `tests/test_costmodel.py` committed for the
+flash pin); flash's maps read the launch's scalar-prefetched visit table
+(``qi[t]``), whose runs the canonical binding states (``qi_runs``).
 
 Pure stdlib (`ast` only): the cost registry is loaded from
 ``observability/costmodel.py`` BY FILE PATH, so nothing here ever
@@ -214,8 +216,12 @@ CANONICAL: Dict[str, Dict[str, Any]] = {
     # -- ops/pallas_flash.py / pallas_flashmask.py -------------------------
     "_flash_fwd_impl": dict(
         kernel="flash_sdpa",
+        # the grid's third axis walks the launch's visit table (every
+        # pair, without a causal mask): the query block changes once a
+        # run of nk pairs, the key block at every pair
         bindings=dict(B=1, H=8, Sq=1024, Sk=1024, D=128,
-                      bq=512, bk=512, nq=2, nk=2),
+                      bq=512, bk=512, nq=2, nk=2,
+                      n_pairs=4, qi_runs=2, kj_runs=4),
         in_widths=[4, 4, 2, 2, 2], out_widths=[2, 4],
         cost_kwargs=dict(B=1, H=8, Sq=1024, Sk=1024, D=128),
         rebuild=True,

@@ -359,8 +359,11 @@ def _c_flash_sdpa(*, B: int, H: int, Sq: int, Sk: int, D: int,
                   block_q: int = 512, block_k: int = 512,
                   causal: bool = False, dtype_bytes: int = 2,
                   seg_bytes: int = 4) -> CostEstimate:
-    """Tiled online-softmax attention, fwd grid (B, H, nq, nk): q read
-    once, K/V re-fetched per q-block (the flash HBM contract)."""
+    """Tiled online-softmax attention, fwd grid (B, H, visited pairs — a
+    query block's key blocks in a row): q read once, K/V re-fetched per
+    q-block (the flash HBM contract). `causal` halves the FLOPs; the
+    bytes stay the rectangle's (flashmask's sweep, which shares this
+    form, still walks it), an upper bound for flash's visit table."""
     bq, bk, nq, nk = _flash_blocks(Sq, Sk, block_q, block_k)
     q, kv, seg, out, lse = _flash_bytes(B, H, Sq, Sk, D, bq, bk, nq, nk,
                                         dtype_bytes, seg_bytes)

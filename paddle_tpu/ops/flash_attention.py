@@ -38,6 +38,22 @@ def _count_kernel(kernel: str) -> None:
         _KERNEL.labels(kernel=kernel).inc()
 
 
+# what the causal mask leaves of an in-tree flash launch's (query block,
+# key block) pairs, from the launch's own visit table (ops/pallas_flash.py)
+# as it is traced: `interior` pairs are grid steps with no mask arithmetic,
+# `masked` ones pay it, `skipped` ones are no grid step and no DMA.
+_BLOCK_PAIRS = _obs.registry().counter(
+    "pt_flash_block_pairs_total",
+    "in-tree flash (query block, key block) pairs by sweep and kind",
+    labels=("kernel", "kind"))
+
+
+def count_block_pairs(kernel: str, pairs: dict) -> None:
+    if _obs.enabled():
+        for kind, n in pairs.items():
+            _BLOCK_PAIRS.labels(kernel=kernel, kind=kind).inc(n)
+
+
 def sdpa_reference(q, k, v, mask=None, causal: bool = False,
                    dropout_p: float = 0.0, scale: Optional[float] = None):
     """[B,S,H,D] scaled-dot-product attention, bf16-safe (f32 softmax)."""

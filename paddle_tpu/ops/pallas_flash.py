@@ -15,12 +15,21 @@ kernel refuses):
     jnp.tril(..., k=Sk-Sq) convention, so the composite stays the oracle;
   - optional q/kv segment ids (varlen packing, key-padding routing) as
     an elementwise block-local mask;
-  - block-level skip for fully-above-diagonal blocks, computed from
-    program ids (static — no skip-map array needed);
-  - online-softmax forward emitting logsumexp; flash-style backward
-    (dq sweep over k blocks, dk/dv sweep over q blocks);
-  - caller-tunable block sizes (default 128x128), f32 accumulation,
-    interpret mode off-TPU so the CPU suite covers the kernel logic.
+  - each sweep walks only the (query block, key block) pairs the causal
+    mask leaves visible: a scalar-prefetched table, built at trace time
+    from the static geometry (lengths, blocks, offset), names every grid
+    step's pair, so a hidden pair is no grid step and no DMA, and says
+    whether the diagonal cuts it: an interior pair runs with no iota, no
+    compare and no `where` (with segment ids, which are data, every
+    visible pair stays masked);
+  - online-softmax forward emitting logsumexp; flash-style backward (dq
+    sweep over a query block's key blocks; dk/dv sweep over a key block's
+    query blocks on the TRANSPOSED scores); lse / di travel as dense
+    [B, H, 1, Sq] rows;
+  - caller-tunable block sizes (default 512x512, clamped to the sequence
+    lengths; `sdpa` picks the largest 128-multiple <= 512 that divides),
+    f32 accumulation, interpret mode off-TPU so the CPU suite covers the
+    kernel logic.
 
 Fully-hidden query rows (causal offset < 0 at the sequence head, or an
 unmatched segment) produce zero output and a +1e30 lse sentinel, so the
@@ -34,204 +43,265 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .flash_attention import count_block_pairs
 from .on_mesh import on_mesh
 
 __all__ = ["flash_sdpa", "flash_kernel_eligible"]
 
 _NEG = -1e30
 
-# B/H/outer-block grid dims are independent; only the innermost dim
-# carries the online-softmax / accumulator state. Marking them parallel
-# lets Mosaic split them across TensorCores (megacore parts)
+# the pair axis carries the online-softmax / accumulator state of a run
+# of pairs (one query block's, or in the dk / dv sweep one key block's)
+# and is sequential; batch and heads are independent, and Mosaic may
+# split them across TensorCores (megacore parts)
 _CPARAMS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+# what the mask leaves of a (query block, key block) pair: nothing (the
+# pair is no grid step), all of it (no mask arithmetic), or a part
+_HIDDEN, _INTERIOR, _MASKED = "skipped", "interior", "masked"
+# a visited pair's flags in the table: the first / last of its run (set
+# the accumulators up / write the run's block out) and whether it pays
+# the mask
+_FIRST, _LAST, _MASK = 1, 2, 4
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _mask_for_block(qi, kj, bq, bk, causal, off, use_seg, sq_ref, sk_ref):
-    """[bq, bk] bool mask of HIDDEN entries for this block."""
+def _pair_kind(qi, kj, bq, bk, off, causal, use_seg) -> str:
+    """Static geometry of pair (qi, kj): row r sees column c iff
+    c <= r + off. Segment ids are data, so with them no pair is interior."""
+    if causal and kj * bk > qi * bq + (bq - 1) + off:
+        return _HIDDEN              # above the diagonal of its LAST row
+    if use_seg or (causal and kj * bk + (bk - 1) > qi * bq + off):
+        return _MASKED              # the diagonal of its FIRST row cuts it
+    return _INTERIOR
+
+
+@functools.lru_cache(maxsize=None)
+def _visit_table(nq, nk, bq, bk, off, causal, use_seg, order):
+    """The pairs a sweep visits, in its order, as (qi, kj, flags) columns
+    and the sweep's {kind: pairs} counts. order 'qk': a run is a query
+    block's visible key blocks (forward, dq); 'kq': a key block's visible
+    query blocks (dk / dv). A run the mask leaves nothing of (the query
+    blocks above a causal diagonal with Sq > Sk) still visits one masked
+    pair, which writes its block: zeros and the lse sentinel."""
+    runs, inner = (nq, nk) if order == "qk" else (nk, nq)
+    rows, counts = [], {_INTERIOR: 0, _MASKED: 0}
+    for r in range(runs):
+        pairs = [(r, c) if order == "qk" else (c, r) for c in range(inner)]
+        run = [(qi, kj, _pair_kind(qi, kj, bq, bk, off, causal, use_seg))
+               for qi, kj in pairs]
+        run = [pair for pair in run if pair[2] != _HIDDEN] \
+            or [(*pairs[0], _MASKED)]
+        for n, (qi, kj, kind) in enumerate(run):
+            counts[kind] += 1
+            rows.append((qi, kj, _FIRST * (n == 0)
+                         + _LAST * (n == len(run) - 1)
+                         + _MASK * (kind == _MASKED)))
+    counts[_HIDDEN] = nq * nk - len(rows)
+    return np.asarray(rows, np.int32).T, counts
+
+
+def _mask_for_block(qi, kj, bq, bk, causal, off, use_seg, sq_ref, sk_ref,
+                    transposed=False):
+    """bool mask of HIDDEN entries for this block: [bq, bk], or [bk, bq]
+    for the dk / dv sweep's transposed scores."""
+    shape, qax = ((bk, bq), 1) if transposed else ((bq, bk), 0)
     masked = None
     if causal:
-        rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        cols = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, shape, qax)
+        cols = kj * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - qax)
         masked = cols > rows + off
     if use_seg:
-        seg = sq_ref[0, 0][:, None] != sk_ref[0, 0][None, :]
+        sq, sk = sq_ref[0, 0], sk_ref[0, 0]
+        seg = (sk[:, None] != sq[None, :] if transposed
+               else sq[:, None] != sk[None, :])
         masked = seg if masked is None else jnp.logical_or(masked, seg)
     return masked
 
 
-def _block_visible(qi, kj, bq, bk, off):
-    """Causal block skip: the block's lowest row sees its first column?"""
-    return kj * bk <= qi * bq + (bq - 1) + off
+def _sweep(tabs, init, pair, emit, *, bq, bk, causal, off, use_seg,
+           transposed=False):
+    """One grid step = one visited pair of the table: `init` on the first
+    of its run, `pair(masked)` with no mask at all on an interior pair and
+    with the block's mask on a masked one, `emit` on the run's last."""
+    qi_tab, kj_tab, fl_tab, sq_ref, sk_ref = tabs
+    t = pl.program_id(2)
+    qi, kj, fl = qi_tab[t], kj_tab[t], fl_tab[t]
+    pl.when(fl & _FIRST != 0)(init)
+    if causal or use_seg:
+        pl.when(fl & _MASK != 0)(lambda: pair(_mask_for_block(
+            qi, kj, bq, bk, causal, off, use_seg, sq_ref, sk_ref,
+            transposed)))
+    if not use_seg:
+        pl.when(fl & _MASK == 0)(lambda: pair(None))
+    pl.when(fl & _LAST != 0)(emit)
 
 
-def _fwd_kernel(sq_ref, sk_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, bq, bk, causal, off,
-                use_seg):
-    kj = pl.program_id(3)
-    nk = pl.num_programs(3)
-    qi = pl.program_id(2)
+def _lanes(x, n):
+    """The forward's running maximum and sum are LANE-REPLICATED
+    [rows, 128] (every lane of a row holds the row's value, as in the
+    bundled kernel), so they meet the [bq, bk] scores and the [bq, D]
+    accumulator lane for lane: as [bq, 1] columns a launch of the
+    training cell's shape took 8.3 ms where this form takes 5.0 (PERF.md,
+    PR 49). Here: such a statistic against [rows, n] data."""
+    if n % 128:
+        return x[:, :1]
+    return jnp.tile(x, (1, n // 128)) if n > 128 else x
 
-    @pl.when(kj == 0)
-    def _init():
+
+def _fwd_kernel(qi_tab, kj_tab, fl_tab, sq_ref, sk_ref, q_ref, k_ref, v_ref,
+                o_ref, lse_ref, acc_ref, m_ref, l_ref, *, scale, **geometry):
+    def init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    visible = _block_visible(qi, kj, bq, bk, off) if causal \
-        else (kj == kj)
-
-    @pl.when(visible)
-    def _compute():
+    def pair(masked):
         q = q_ref[0, 0]                                       # [bq, D]
         k = k_ref[0, 0]                                       # [bk, D]
         # inputs stay bf16 on the MXU (full throughput); accumulation is
         # f32 via preferred_element_type — same contract as the bundled
-        # kernel (casting inputs to f32 halves MXU throughput)
+        # kernel (casting inputs to f32 halves MXU throughput). The scores
+        # and their running maximum stay UNSCALED: the scale rides in the
+        # exponent's float32 constant, and rounds nothing
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # [bq, bk]
-        masked = _mask_for_block(qi, kj, bq, bk, causal, off, use_seg,
-                                 sq_ref, sk_ref)
+            preferred_element_type=jnp.float32)               # [bq, bk]
+        if scale < 0:       # the largest SCALED score is the maximum
+            s = -s
         if masked is not None:
             s = jnp.where(masked, _NEG, s)
         m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        p = jnp.exp((s - _lanes(m_new, s.shape[1])) * abs(scale))
         if masked is not None:
             p = jnp.where(masked, 0.0, p)
-        alpha = jnp.exp(m_prev - m_new)
+        alpha = jnp.exp((m_prev - m_new) * abs(scale))
         l_ref[:] = l_ref[:] * alpha + jnp.sum(p, -1, keepdims=True)
         v = v_ref[0, 0]
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[:] = acc_ref[:] * _lanes(alpha, v.shape[1]) \
+            + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
         m_ref[:] = m_new
 
-    @pl.when(kj == nk - 1)
-    def _emit():
+    def emit():
         l = l_ref[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.where(
-            l == 0.0, -_NEG, m_ref[:] + jnp.log(l_safe))
+        o_ref[0, 0] = (acc_ref[:] / _lanes(l_safe, acc_ref.shape[1])
+                       ).astype(o_ref.dtype)
+        # lse leaves as a dense [1, bq] row (a [.., bq, 1] array in HBM is
+        # 128 lanes of padding a number)
+        lse = jnp.where(l == 0.0, -_NEG,
+                        m_ref[:] * abs(scale) + jnp.log(l_safe))
+        lse_ref[0, 0] = lse.T[:1]
+
+    _sweep((qi_tab, kj_tab, fl_tab, sq_ref, sk_ref), init, pair, emit,
+           **geometry)
 
 
-def _bwd_dq_kernel(sq_ref, sk_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   di_ref, dq_ref, dq_acc, *, scale, bq, bk, causal, off,
-                   use_seg):
-    kj = pl.program_id(3)
-    nk = pl.num_programs(3)
-    qi = pl.program_id(2)
-
-    @pl.when(kj == 0)
-    def _init():
+def _bwd_dq_kernel(qi_tab, kj_tab, fl_tab, sq_ref, sk_ref, q_ref, k_ref,
+                   v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_acc, lse_col,
+                   di_col, *, scale, **geometry):
+    def init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
+        # the run's rows of lse / di, turned to columns once a query block
+        lse_col[:] = lse_ref[0, 0, 0][:, None]
+        di_col[:] = di_ref[0, 0, 0][:, None]
 
-    visible = _block_visible(qi, kj, bq, bk, off) if causal \
-        else (kj == kj)
-
-    @pl.when(visible)
-    def _compute():
+    def pair(masked):
         q = q_ref[0, 0]
         k = k_ref[0, 0]
         v = v_ref[0, 0]
         do = do_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        masked = _mask_for_block(qi, kj, bq, bk, causal, off, use_seg,
-                                 sq_ref, sk_ref)
-        p = jnp.exp(s - lse_ref[0, 0])
+            preferred_element_type=jnp.float32)
+        p = jnp.exp(s * scale - lse_col[:])
         if masked is not None:
             p = jnp.where(masked, 0.0, p)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = (p * (dp - di_ref[0, 0]) * scale).astype(k.dtype)
+        ds = (p * (dp - di_col[:])).astype(k.dtype)
         dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(kj == nk - 1)
-    def _emit():
-        dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
+    def emit():
+        # ds's scale, once a [bq, D] block and not once a score
+        dq_ref[0, 0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
+
+    _sweep((qi_tab, kj_tab, fl_tab, sq_ref, sk_ref), init, pair, emit,
+           **geometry)
 
 
-def _bwd_dkv_kernel(sq_ref, sk_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    di_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, bq,
-                    bk, causal, off, use_seg):
-    qi = pl.program_id(3)
-    nq = pl.num_programs(3)
-    kj = pl.program_id(2)
-
-    @pl.when(qi == 0)
-    def _init():
+def _bwd_dkv_kernel(qi_tab, kj_tab, fl_tab, sq_ref, sk_ref, q_ref, k_ref,
+                    v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref, dk_acc,
+                    dv_acc, *, scale, **geometry):
+    def init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    visible = _block_visible(qi, kj, bq, bk, off) if causal \
-        else (qi == qi)
-
-    @pl.when(visible)
-    def _compute():
+    def pair(masked):
         q = q_ref[0, 0]
         k = k_ref[0, 0]
         v = v_ref[0, 0]
         do = do_ref[0, 0]
+        # the TRANSPOSED scores k q^T: the queries' lse / di broadcast
+        # along sublanes as the dense rows they arrive as, and dv = p^T do,
+        # dk = ds^T q are plain matmuls
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # [bq, bk]
-        masked = _mask_for_block(qi, kj, bq, bk, causal, off, use_seg,
-                                 sq_ref, sk_ref)
-        p = jnp.exp(s - lse_ref[0, 0])
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)               # [bk, bq]
+        p = jnp.exp(s * scale - lse_ref[0, 0])
         if masked is not None:
             p = jnp.where(masked, 0.0, p)
         dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # [bk, D]
         dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bq, bk]
-        ds = (p * (dp - di_ref[0, 0]) * scale).astype(q.dtype)
+            v, do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)               # [bk, bq]
+        ds = (p * (dp - di_ref[0, 0])).astype(q.dtype)
         dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            ds, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # [bk, D]
 
-    @pl.when(qi == nq - 1)
-    def _emit():
-        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
+    def emit():
+        dk_ref[0, 0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
+    _sweep((qi_tab, kj_tab, fl_tab, sq_ref, sk_ref), init, pair, emit,
+           transposed=True, **geometry)
 
-def _specs(bq, bk, D, order: str):
-    """in_specs for (seg_q, seg_kv, q, k, v). order='qk': grid
-    (B, H, nq, nk) with q indexed by i; order='kq': grid (B, H, nk, nq)
-    with q indexed by j (the dkv sweep)."""
-    if order == "qk":
-        sqmap = lambda b, h, i, j: (b, 0, i)
-        skmap = lambda b, h, i, j: (b, 0, j)
-        qmap = lambda b, h, i, j: (b, h, i, 0)
-        kmap = lambda b, h, i, j: (b, h, j, 0)
-    else:
-        sqmap = lambda b, h, i, j: (b, 0, j)
-        skmap = lambda b, h, i, j: (b, 0, i)
-        qmap = lambda b, h, i, j: (b, h, j, 0)
-        kmap = lambda b, h, i, j: (b, h, i, 0)
-    # segment ids ride as [B, 1, S] so the (1, 1, blk) block satisfies the
-    # Mosaic trailing-dims rule (second-to-last block dim == full dim 1)
-    return ([pl.BlockSpec((1, 1, bq), sqmap),
-             pl.BlockSpec((1, 1, bk), skmap),
+
+def _specs(bq, bk, D):
+    """in_specs for (seg_q, seg_kv, q, k, v), the row spec of lse / di, and
+    the query- and key-block maps: the same for every sweep, since a grid
+    step's blocks are its pair's in the prefetched table."""
+    qmap = lambda b, h, t, qi, kj, fl: (b, h, qi[t], 0)
+    kmap = lambda b, h, t, qi, kj, fl: (b, h, kj[t], 0)
+    # segment ids ride as [B, 1, S] and lse / di as [B, H, 1, Sq], so the
+    # (.., 1, blk) block satisfies the Mosaic trailing-dims rule
+    # (second-to-last block dim == full dim 1) and a row is dense in HBM
+    row_spec = pl.BlockSpec((1, 1, 1, bq),
+                            lambda b, h, t, qi, kj, fl: (b, h, 0, qi[t]))
+    return ([pl.BlockSpec((1, 1, bq),
+                          lambda b, h, t, qi, kj, fl: (b, 0, qi[t])),
+             pl.BlockSpec((1, 1, bk),
+                          lambda b, h, t, qi, kj, fl: (b, 0, kj[t])),
              pl.BlockSpec((1, 1, bq, D), qmap),
              pl.BlockSpec((1, 1, bk, D), kmap),
-             pl.BlockSpec((1, 1, bk, D), kmap)], qmap, kmap)
+             pl.BlockSpec((1, 1, bk, D), kmap)], row_spec, qmap, kmap)
 
 
 def _out(shape, dtype, *like):
@@ -242,6 +312,17 @@ def _out(shape, dtype, *like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
+def _pairs(kernel, order, q, k, *, bq, bk, **geometry):
+    """The sweep's table as the three scalar-prefetch operands and its
+    length, counted into pt_flash_block_pairs_total as the launch is
+    traced."""
+    B, H, Sq, _ = q.shape
+    table, counts = _visit_table(Sq // bq, k.shape[2] // bk, bq, bk,
+                                 order=order, **geometry)
+    count_block_pairs(kernel, {kind: n * B * H for kind, n in counts.items()})
+    return tuple(jnp.asarray(col) for col in table), table.shape[1]
+
+
 # the kernel launches run per shard of the step's mesh, INSIDE the
 # custom_vjp rules (see ops/on_mesh.py)
 _QKV = ("bhsd", "bhsd", "bhsd", "b1s", "b1s")
@@ -250,7 +331,7 @@ _QKV = ("bhsd", "bhsd", "bhsd", "b1s", "b1s")
 def _fwd_on_mesh(q, k, v, seg_q, seg_kv, scale, causal, bq, bk, use_seg):
     fwd = functools.partial(_flash_fwd_impl, scale=scale, causal=causal,
                             bq=bq, bk=bk, use_seg=use_seg)
-    return on_mesh(fwd, (q, k, v, seg_q, seg_kv), _QKV, ("bhsd", "bhs1"))
+    return on_mesh(fwd, (q, k, v, seg_q, seg_kv), _QKV, ("bhsd", "bh1s"))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
@@ -264,25 +345,25 @@ def _flash_fwd_impl(q, k, v, seg_q, seg_kv, scale, causal, bq, bk,
                     use_seg):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    off = Sk - Sq
-    nq, nk = Sq // bq, Sk // bk
-    in_specs, qmap, _ = _specs(bq, bk, D, "qk")
+    geometry = dict(bq=bq, bk=bk, off=Sk - Sq, causal=causal,
+                    use_seg=use_seg)
+    table, n_pairs = _pairs("fwd", "qk", q, k, **geometry)
+    in_specs, row_spec, qmap, _ = _specs(bq, bk, D)
     o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, bq=bq, bk=bk,
-                          causal=causal, off=off, use_seg=use_seg),
-        grid=(B, H, nq, nk),
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, 1, bq, D), qmap),
-                   pl.BlockSpec((1, 1, bq, 1),
-                                lambda b, h, i, j: (b, h, i, 0))],
+        functools.partial(_fwd_kernel, scale=scale, **geometry),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,          # the table's qi, kj, flags
+            grid=(B, H, n_pairs),
+            in_specs=in_specs,
+            out_specs=[pl.BlockSpec((1, 1, bq, D), qmap), row_spec],
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
+                            pltpu.VMEM((bq, 128), jnp.float32),   # m
+                            pltpu.VMEM((bq, 128), jnp.float32)]),  # l
         out_shape=[_out((B, H, Sq, D), q.dtype, q, k, v),
-                   _out((B, H, Sq, 1), jnp.float32, q, k, v)],
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
-                        pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, 1), jnp.float32)],
+                   _out((B, H, 1, Sq), jnp.float32, q, k, v)],
         compiler_params=_CPARAMS,
         interpret=_interpret(),
-    )(seg_q, seg_kv, q, k, v)
+    )(*table, seg_q, seg_kv, q, k, v)
     return o, lse
 
 
@@ -297,7 +378,7 @@ def _flash_vjp_bwd(scale, causal, bq, bk, use_seg, res, do):
     bwd = functools.partial(_flash_bwd_impl, scale=scale, causal=causal,
                             bq=bq, bk=bk, use_seg=use_seg)
     dq, dk, dv = on_mesh(bwd, (*res, do),
-                         _QKV + ("bhsd", "bhs1", "bhsd"), ("bhsd",) * 3)
+                         _QKV + ("bhsd", "bh1s", "bhsd"), ("bhsd",) * 3)
     return dq, dk, dv, None, None
 
 
@@ -305,45 +386,46 @@ def _flash_bwd_impl(q, k, v, seg_q, seg_kv, o, lse, do, scale, causal,
                     bq, bk, use_seg):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    off = Sk - Sq
+    geometry = dict(bq=bq, bk=bk, off=Sk - Sq, causal=causal,
+                    use_seg=use_seg)
     di = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                 axis=-1, keepdims=True)                     # [B,H,Sq,1]
-    nq, nk = Sq // bq, Sk // bk
+                 axis=-1)[:, :, None]                        # [B,H,1,Sq]
+    in_specs, row_spec, qmap, kmap = _specs(bq, bk, D)
+    in_specs = in_specs + [pl.BlockSpec((1, 1, bq, D), qmap),
+                           row_spec, row_spec]
 
-    in_specs, qmap, kmap = _specs(bq, bk, D, "qk")
-    row_spec = pl.BlockSpec((1, 1, bq, 1),
-                            lambda b, h, i, j: (b, h, i, 0))
+    table, n_pairs = _pairs("dq", "qk", q, k, **geometry)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, bq=bq, bk=bk,
-                          causal=causal, off=off, use_seg=use_seg),
-        grid=(B, H, nq, nk),
-        in_specs=in_specs + [pl.BlockSpec((1, 1, bq, D), qmap),
-                             row_spec, row_spec],
-        out_specs=pl.BlockSpec((1, 1, bq, D), qmap),
+        functools.partial(_bwd_dq_kernel, scale=scale, **geometry),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, H, n_pairs),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, 1, bq, D), qmap),
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
+                            pltpu.VMEM((bq, 1), jnp.float32),
+                            pltpu.VMEM((bq, 1), jnp.float32)]),
         out_shape=_out((B, H, Sq, D), q.dtype, q, k, v, do),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=_CPARAMS,
         interpret=_interpret(),
-    )(seg_q, seg_kv, q, k, v, do, lse, di)
+    )(*table, seg_q, seg_kv, q, k, v, do, lse, di)
 
-    in_specs2, qmap2, kmap2 = _specs(bq, bk, D, "kq")
-    row_spec2 = pl.BlockSpec((1, 1, bq, 1),
-                             lambda b, h, i, j: (b, h, j, 0))
+    table, n_pairs = _pairs("dkv", "kq", q, k, **geometry)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, bq=bq, bk=bk,
-                          causal=causal, off=off, use_seg=use_seg),
-        grid=(B, H, nk, nq),
-        in_specs=in_specs2 + [pl.BlockSpec((1, 1, bq, D), qmap2),
-                              row_spec2, row_spec2],
-        out_specs=[pl.BlockSpec((1, 1, bk, D), kmap2),
-                   pl.BlockSpec((1, 1, bk, D), kmap2)],
+        functools.partial(_bwd_dkv_kernel, scale=scale, **geometry),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, H, n_pairs),
+            in_specs=in_specs,
+            out_specs=[pl.BlockSpec((1, 1, bk, D), kmap),
+                       pl.BlockSpec((1, 1, bk, D), kmap)],
+            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                            pltpu.VMEM((bk, D), jnp.float32)]),
         out_shape=[_out((B, H, Sk, D), k.dtype, q, k, v, do),
                    _out((B, H, Sk, D), v.dtype, q, k, v, do)],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
         compiler_params=_CPARAMS,
         interpret=_interpret(),
-    )(seg_q, seg_kv, q, k, v, do, lse, di)
+    )(*table, seg_q, seg_kv, q, k, v, do, lse, di)
     return dq, dk, dv
 
 

@@ -244,13 +244,8 @@ class TestBlockSpecConsistency:
         mi = idx.modules["paddle_tpu.ops.pallas_flash"]
         fi = mi.functions["_specs"]
         # the in_specs ride through the tuple-unpacked `_specs` helper;
-        # rebuild them with the order='qk' branch recorded over the env
-        # (Env is flow-insensitive, so the else-branch maps would win)
+        # rebuild them over its env
         env = km.Env(mi, fi)
-        branch = next(n for n in ast.walk(fi.node)
-                      if isinstance(n, ast.If))
-        for stmt in branch.body:
-            env._record(stmt)
         ret = next(n for n in ast.walk(fi.node)
                    if isinstance(n, ast.Return))
         spec_calls = ret.value.elts[0].elts
@@ -259,18 +254,23 @@ class TestBlockSpecConsistency:
 
         B, H, Sq, Sk, D, bq, bk = 2, 3, 256, 512, 64, 128, 128
         nq, nk = Sq // bq, Sk // bk
-        grid = [B, H, nq, nk]
-        binds = dict(bq=bq, bk=bk, D=D)
-        elems = [km.spec_transfer_elems(s, grid, 4, binds) for s in specs]
+        # the grid's third axis walks the visit table: without a causal
+        # mask every pair, a query block's nk pairs in a row — so the
+        # table's query block changes nq times a (batch, head) and its
+        # key block at every pair
+        grid = [B, H, nq * nk]
+        binds = dict(bq=bq, bk=bk, D=D, qi_runs=nq, kj_runs=nq * nk)
+        elems = [km.spec_transfer_elems(s, grid, 3, binds) for s in specs]
         assert None not in elems
         seg_q, seg_kv, q, k, v = elems
         read = (seg_q + seg_kv) * I32 + (q + k + v) * BF16
 
-        # out specs: o uses the same tuple-unpacked qmap (rebuild it
-        # under the qk env); the lse map is a literal lambda at the site
-        o_spec = km.build_block_spec(site.out_specs[0].node, mi, fi, env)
-        o = km.spec_transfer_elems(o_spec, grid, 4, binds)
-        lse = km.spec_transfer_elems(site.out_specs[1], grid, 4, binds)
+        # out specs: o uses the same tuple-unpacked qmap and lse the
+        # helper's row spec (rebuild both under its env)
+        o_spec, lse_spec = (km.build_block_spec(s.node, mi, fi, env)
+                            for s in site.out_specs)
+        o = km.spec_transfer_elems(o_spec, grid, 3, binds)
+        lse = km.spec_transfer_elems(lse_spec, grid, 3, binds)
         assert o is not None and lse is not None
         written = o * BF16 + lse * 4
 
@@ -282,6 +282,11 @@ class TestBlockSpecConsistency:
         assert q * BF16 == B * H * Sq * D * BF16
         assert k * BF16 == B * H * nq * Sk * D * BF16
         assert o * BF16 == B * H * Sq * D * BF16
+        # with the table's runs unbound the model falls back to one fetch
+        # a grid step: an upper bound, never a guess below
+        loose = km.spec_transfer_elems(specs[2], grid, 3,
+                                       dict(bq=bq, bk=bk, D=D))
+        assert loose == B * H * nq * nk * bq * D
 
     def test_grids_evaluate_for_all_three_sites(self, sites):
         _, ss = sites
@@ -294,7 +299,7 @@ class TestBlockSpecConsistency:
             rag, dict(KV=16, hb=8, n_cells=3)) == [2, 3]
         fwd = _one(ss, "_flash_fwd_impl")
         assert km.grid_values(
-            fwd, dict(B=2, H=3, nq=2, nk=4)) == [2, 3, 2, 4]
+            fwd, dict(B=2, H=3, n_pairs=8)) == [2, 3, 8]
 
 
 # ---------------------------------------------------------------------------

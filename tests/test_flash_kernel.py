@@ -150,3 +150,184 @@ class TestEligibilityAndRouting:
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(ref, np.float32),
             atol=3e-2, rtol=3e-2)
+
+
+# ---------------------------------------------------------------------------
+# the visit table (ISSUE 49): hidden pairs are no grid step, interior pairs
+# run unmasked, lse / di travel as dense rows
+# ---------------------------------------------------------------------------
+
+def _segments(S, cut):
+    return jnp.broadcast_to((jnp.arange(S) >= cut).astype(jnp.int32), (B, S))
+
+
+#: name -> (Sq, Sk, block_q, block_k, segment cut or None, dtype)
+GEOMETRIES = {
+    # 3 x 3 blocks: hidden, interior and masked pairs all occur
+    "causal_3x3": (192, 192, 64, 64, None, jnp.float32),
+    # off = +112 / -112: a multiple of neither block
+    "off_pos_unaligned": (128, 240, 64, 48, None, jnp.float32),
+    "off_neg_unaligned": (240, 128, 48, 64, None, jnp.float32),
+    "bq_lt_bk": (192, 192, 32, 64, None, jnp.float32),
+    "bq_gt_bk": (192, 192, 64, 32, None, jnp.float32),
+    # the boundary (32) falls inside key block 0, which query block 2
+    # sees whole by the causal geometry: the pair must still be masked
+    "segment_in_interior_pair": (192, 192, 64, 64, 32, jnp.float32),
+    "bf16": (192, 192, 64, 64, None, jnp.bfloat16),
+}
+
+
+def _case(name):
+    Sq, Sk, bq, bk, cut, dtype = GEOMETRIES[name]
+    q, k, v = _qkv(Sq, Sk, 64, seed=13, dtype=dtype)
+    kw = dict(causal=True, block_q=bq, block_k=bk)
+    mask = None
+    if cut is not None:
+        sq, sk = _segments(Sq, cut), _segments(Sk, cut)
+        kw.update(segment_ids_q=sq, segment_ids_kv=sk)
+        mask = (sq[:, :, None] == sk[:, None, :])[:, None]
+    # rows with no visible key are don't-care in the composite (a uniform
+    # average) and zero in the kernel: compare and differentiate the rest
+    valid = np.arange(Sq) + (Sk - Sq) >= 0
+    tol = 3e-2 if dtype == jnp.bfloat16 else 1e-4
+
+    def kernel(q, k, v):
+        return flash_sdpa(q, k, v, **kw)
+
+    def reference(q, k, v):
+        return sdpa_reference(q, k, v, mask=mask, causal=True)
+    return (q, k, v), kernel, reference, valid, tol
+
+
+class TestVisitTable:
+    @pytest.mark.parametrize("name", sorted(GEOMETRIES))
+    def test_forward_matches_reference(self, name):
+        qkv, kernel, reference, valid, tol = _case(name)
+        out = np.asarray(kernel(*qkv), np.float32)
+        ref = np.asarray(reference(*qkv), np.float32)
+        assert np.isfinite(out).all()
+        np.testing.assert_array_equal(out[:, ~valid], 0.0)
+        np.testing.assert_allclose(out[:, valid], ref[:, valid],
+                                   atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("name", sorted(GEOMETRIES))
+    def test_gradients_match_reference(self, name):
+        qkv, kernel, reference, valid, tol = _case(name)
+
+        def loss(f):
+            return lambda *a: jnp.sum(
+                f(*a).astype(jnp.float32)[:, valid] ** 2)
+        got = jax.grad(loss(kernel), (0, 1, 2))(*qkv)
+        want = jax.grad(loss(reference), (0, 1, 2))(*qkv)
+        for a, b in zip(got, want):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            assert np.isfinite(a).all()
+            np.testing.assert_allclose(a, b, atol=10 * tol, rtol=10 * tol)
+        # a row that sees nothing moves nothing
+        np.testing.assert_array_equal(
+            np.asarray(got[0], np.float32)[:, ~valid], 0.0)
+
+    def test_hidden_rows_emit_the_lse_sentinel_as_dense_rows(self):
+        from paddle_tpu.ops import pallas_flash
+        q, k, v = (jnp.swapaxes(x, 1, 2) for x in _qkv(240, 128, 64))
+        seg_q = jnp.zeros((B, 1, 240), jnp.int32)
+        seg_kv = jnp.zeros((B, 1, 128), jnp.int32)
+        o, lse = pallas_flash._flash_fwd_impl(
+            q, k, v, seg_q, seg_kv, 0.125, True, 48, 64, False)
+        assert lse.shape == (B, H, 1, 240)       # a row, not a column
+        lse = np.asarray(lse)[:, :, 0]
+        np.testing.assert_array_equal(lse[..., :112], np.float32(1e30))
+        assert (np.abs(lse[..., 112:]) < 1e3).all()
+        np.testing.assert_array_equal(np.asarray(o)[:, :, :112], 0.0)
+
+    def test_kinds_follow_the_dense_mask_and_counts_add_up(self):
+        from paddle_tpu.ops.pallas_flash import _pair_kind, _visit_table
+        checked = 0
+        for Sq, Sk, bq, bk in [(96, 96, 32, 32), (96, 96, 16, 48),
+                               (96, 96, 48, 16), (64, 160, 32, 32),
+                               (64, 176, 32, 16), (176, 64, 16, 32),
+                               (144, 48, 48, 24), (48, 48, 48, 48)]:
+            off, nq, nk = Sk - Sq, Sq // bq, Sk // bk
+            sees = np.tril(np.ones((Sq, Sk), bool), k=off)
+            kinds = {}
+            for qi in range(nq):
+                for kj in range(nk):
+                    block = sees[qi * bq:(qi + 1) * bq,
+                                 kj * bk:(kj + 1) * bk]
+                    want = ("interior" if block.all() else
+                            "masked" if block.any() else "skipped")
+                    kinds[qi, kj] = _pair_kind(qi, kj, bq, bk, off, True,
+                                               False)
+                    assert kinds[qi, kj] == want, (Sq, Sk, bq, bk, qi, kj)
+                    # segment ids are data: no visible pair is interior
+                    assert _pair_kind(qi, kj, bq, bk, off, True, True) == (
+                        "masked" if block.any() else "skipped")
+                    # and without the causal mask every pair is interior
+                    assert _pair_kind(qi, kj, bq, bk, off, False,
+                                      False) == "interior"
+                    checked += 1
+            for order in ("qk", "kq"):
+                (qi, kj, fl), counts = _visit_table(
+                    nq, nk, bq, bk, off, True, False, order)
+                assert sum(counts.values()) == nq * nk
+                assert counts["skipped"] == nq * nk - len(fl)
+                visible = {p for p, kind in kinds.items()
+                           if kind != "skipped"}
+                visited = set(zip(qi.tolist(), kj.tolist()))
+                assert len(visited) == len(fl) and visible <= visited
+                # a run = one output block: contiguous, opened by FIRST,
+                # closed by LAST, and every output block has one
+                run = qi if order == "qk" else kj
+                starts = np.flatnonzero(fl & 1)
+                ends = np.flatnonzero(fl & 2)
+                assert len(starts) == len(ends) == (
+                    nq if order == "qk" else nk)
+                for a, b in zip(starts, ends):
+                    assert a <= b and len(set(run[a:b + 1].tolist())) == 1
+                assert sorted(run[starts].tolist()) == list(
+                    range(len(starts)))
+                # a forced pair (a run the mask leaves nothing of) is masked
+                for n, pair in enumerate(zip(qi.tolist(), kj.tolist())):
+                    if pair not in visible:
+                        assert fl[n] == 1 | 2 | 4
+                    else:
+                        assert bool(fl[n] & 4) == (kinds[pair] == "masked")
+        assert checked == 94
+
+    def test_counter_counts_a_launch_by_kind(self):
+        from paddle_tpu.observability import registry, sample_values
+
+        def read():
+            flat = sample_values(registry())
+            return {(k, kind): flat.get(
+                'pt_flash_block_pairs_total{kernel="%s",kind="%s"}'
+                % (k, kind), 0)
+                for k in ("fwd", "dq", "dkv")
+                for kind in ("interior", "masked", "skipped")}
+        before = read()
+        q, k, v = _qkv(256, 256, 64, seed=17)
+        jax.grad(lambda q: flash_sdpa(q, k, v, causal=True, block_q=64,
+                                      block_k=64).sum())(q)
+        got = {key: val - before[key] for key, val in read().items()}
+        # 4 x 4 pairs a (batch, head): 6 interior, 4 on the diagonal, 6
+        # above it; the forward is traced twice (primal + the vjp's)
+        for kernel in ("dq", "dkv"):
+            assert got[kernel, "interior"] == 6 * B * H
+            assert got[kernel, "masked"] == 4 * B * H
+            assert got[kernel, "skipped"] == 6 * B * H
+        assert got["fwd", "masked"] * 6 == got["fwd", "interior"] * 4 > 0
+        assert got["fwd", "skipped"] == got["fwd", "interior"]
+
+    def test_negative_scale_keeps_the_scaled_maximum(self):
+        # the forward's running maximum is of the scores BEFORE the scale,
+        # which rides in the exponent: a negative scale flips them first
+        q, k, v = _qkv(128, 128, 64, seed=19)
+
+        def loss(f):
+            return lambda *a: jnp.sum(f(*a, causal=True, scale=-0.7) ** 2)
+        got = jax.grad(loss(lambda *a, **kw: flash_sdpa(
+            *a, block_q=32, block_k=32, **kw)), (0, 1, 2))(q, k, v)
+        want = jax.grad(loss(sdpa_reference), (0, 1, 2))(q, k, v)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-3, rtol=1e-3)

@@ -2543,6 +2543,21 @@ class TestSeededEffectsDefects:
         assert {f.detail for f in pe} == {"ww:o_ref:ax3",
                                           "ww:lse_ref:ax3"}
 
+    def test_pe501_flash_table_axis_is_a_revisited_axis(self, tmp_path):
+        # flash's grid walks a scalar-prefetched visit table: its output
+        # maps read the pair axis only as `qi[t]` / `kj[t]`, so the axis
+        # is table-driven, revisited, and has to be declared "arbitrary"
+        # (`_CPARAMS`); strip the forward's declaration and PE501 fires
+        fresh = self._seed(
+            tmp_path, "paddle_tpu/ops/pallas_flash.py",
+            old="        compiler_params=_CPARAMS,\n"
+                "        interpret=_interpret(),\n"
+                "    )(*table, seg_q, seg_kv, q, k, v)\n",
+            new="        interpret=_interpret(),\n"
+                "    )(*table, seg_q, seg_kv, q, k, v)\n")
+        pe = [f for f in fresh if f.rule == "PE501"]
+        assert {f.detail for f in pe} == {"ww:o_ref:ax2", "ww:lse_ref:ax2"}
+
 
 # --------------------------------- serving modules: no-clock regression
 

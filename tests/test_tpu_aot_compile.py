@@ -691,10 +691,43 @@ def _flash_loss(q, k, v):
     return flash_sdpa(q, k, v, causal=True).astype(F32).sum()
 
 
+def _padded_columns(text):
+    """(custom call, type) of every operand and result of the compiled
+    module's Mosaic calls whose MINOR dimension is 1 under an (8, 128)
+    tile: 128 lanes of HBM a number."""
+    import re
+    types = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\(.*?\)|\S+) ",
+                            text, re.M))
+    shape = re.compile(r"\w+\[([\d,]*)\]\{([\d,]*)(:[^}]*)?\}")
+    found = []
+    for name, typ, args in re.findall(
+            r"^\s*(?:ROOT )?(%[\w.\-]+) = (\(.*?\)|\S+) custom-call\((.*?)\),"
+            r" custom_call_target=\"tpu_custom_call\"", text, re.M):
+        operands = [types.get(o, "") for o in re.findall(r"%[\w.\-]+", args)]
+        for t in [typ, *operands]:
+            for dims, order, tiles in shape.findall(t):
+                dims = [int(d) for d in dims.split(",") if d]
+                if len(dims) > 1 and "T(8,128)" in tiles \
+                        and dims[int(order.split(",")[0])] == 1:
+                    found.append((name, t))
+    return found
+
+
 def test_flash_attention_fwd_and_grad_compile(chip):
     qkv = chip.shape((3, 8192, HQ, D))   # sdpa repeats kv heads first
     f = jax.grad(_flash_loss, argnums=(0, 1, 2))
     assert chip.compiles(f, qkv, qkv, qkv), chip.refusals.get(f)
+    # the four-chip training cell's one-chip shape, blocks of 512: the
+    # row statistics (lse, di) cross HBM as dense [B, H, 1, S] rows
+    cell = chip.shape((2, 8192, 16, D))
+    text = jax.jit(f).lower(cell, cell, cell).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert "f32[2,16,1,8192]" in text
+    assert _padded_columns(text) == []
+    assert _padded_columns(
+        "%a = f32[2,16,8192,1]{3,2,1,0:T(8,128)} parameter(0)\n"
+        "%c = bf16[2,16,8192,128]{3,2,1,0:T(8,128)(2,1)} custom-call(%a), "
+        'custom_call_target="tpu_custom_call"') != []
 
 
 def _train_elementwise_loss(x, nw, q, k, cos, sin, g, u):
