@@ -430,6 +430,29 @@ CANONICAL: Dict[str, Dict[str, Any]] = {
         cost_kwargs=dict(live=8, H=32, K=128, V=128),
         token_tiled=False,
     ),
+    # -- ops/pallas_mhc.py (a residual of n = 4 streams, [T, n C]) ---------
+    # T = 384 flat rows of [4 x 3584] bfloat16. `mhc_pre` holds R = 128
+    # rows a step: the block 3.5 MiB (7 MiB double-buffered), the turned
+    # weights [32, 14336] 0.9 MiB, the outputs [128, 3584] bf16 + [128,
+    # 128] f32 and the 64 KiB register square that is turned: 11.6 MiB of
+    # the 16 MiB; 256 rows would not fit, and fewer than 128 would turn a
+    # part-filled square. `mhc_post` holds R = 64 rows in AND out (1.75
+    # MiB each, double-buffered 7 MiB) beside y and the coefficients
+    "mhc_pre": dict(
+        kernel="mhc_pre",
+        bindings=dict(T=384, R=128, nC=14336, C=3584, rows=32, S=128,
+                      MHC_COEF_LANES=128),
+        in_widths=[2, 2, 4], out_widths=[2, 4],
+        cost_kwargs=dict(T=384, n=4, C=3584),
+        token_tiled=True,
+    ),
+    "mhc_post": dict(
+        kernel="mhc_post",
+        bindings=dict(T=384, R=64, nC=14336, C=3584, MHC_COEF_LANES=128),
+        in_widths=[2, 2, 4], out_widths=[2],
+        cost_kwargs=dict(T=384, n=4, C=3584),
+        token_tiled=True,
+    ),
 }
 
 #: The decode-layer kernel chain in launch order (PF404 walks adjacent

@@ -377,6 +377,8 @@ def _mlp_params(lyr, weight_only_int8: bool = False,
                   wup=m.w_up._data, wdn=m.w_down._data)
         for k in ("wge", "wup", "wdn"):
             _q8(mo, k, weight_only_int8, algo)
+        if m.e_score_correction_bias is not None:
+            mo["bias"] = m.e_score_correction_bias._data
         if m.shared_up is not None:
             sh = dict(sg=m.shared_gate.weight._data,
                       su=m.shared_up.weight._data,
@@ -659,6 +661,11 @@ def _mla_decode_params(model, weight_only_int8: bool = False,
     stored stack is quarter-width)."""
     inner = model.model
     cfg = model.config
+    if cfg.hc_mult > 1 and weight_only_int8:
+        raise NotImplementedError(
+            "weight-only quantisation is not wired for a hyper-connected "
+            "residual (hc_mult > 1: the Xing family)")
+    from .ops.references import mhc_pack
     layers = []
     moe_static = []
     for lyr in inner.layers:
@@ -684,6 +691,13 @@ def _mla_decode_params(model, weight_only_int8: bool = False,
         _heads_w(d, cfg.qk_nope_head_dim + cfg.v_head_dim, "wkvb")
         mlp_w, mlp_st = _mlp_params(lyr, weight_only_int8, algo)
         d.update(mlp_w)
+        if cfg.hc_mult > 1:
+            # a residual of hc_mult streams: each sublayer's mixing
+            # weights as the step's kernels read them (`phi` turned, a
+            # group of coefficients a sublane tile: `ops.pallas_mhc`)
+            for key, hc in (("hc1", lyr.hc_attn), ("hc2", lyr.hc_ffn)):
+                d[key] = dict(zip(("phi_t", "ab"), mhc_pack(
+                    hc.phi._data, hc.b._data, hc.a._data, cfg.hc_mult)))
         layers.append(d)
         moe_static.append(mlp_st)
     head = model.lm_head.weight._data if model.lm_head is not None else None
@@ -1235,6 +1249,11 @@ def _cached_step_body(p, max_len: int):
             "contiguous-cache bodies keep rows only")
     if p["family"] == "gpt":
         return _gpt_cached_step_body(p["cfg"], max_len)
+    if p["family"] == "mla" and p["cfg"].hc_mult > 1:
+        raise NotImplementedError(
+            "the Xing family (a residual of hc_mult streams mixed by "
+            "hyper-connections) runs through ServingEngine's unified step "
+            "only; generate_cached / generate_compiled have no such body")
     if p["family"] == "mla":
         return _mla_cached_step_body(p["cfg"], max_len,
                                      p.get("moe_static"))

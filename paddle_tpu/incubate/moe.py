@@ -320,7 +320,7 @@ class MoELayer(nn.Layer):
                  shared_expert_hidden: int = 0, z_loss_weight: float = 0.0,
                  name=None, experts_held=None, routed_scale: float = 1.0,
                  score: str = "softmax", n_group: int = 1,
-                 topk_group: int = 1):
+                 topk_group: int = 1, correction_bias: bool = False):
         super().__init__()
         if activation not in ("swiglu", "gelu"):
             raise ValueError(f"unsupported activation: {activation}")
@@ -328,10 +328,11 @@ class MoELayer(nn.Layer):
         # sigmoid an expert) and the group limit of its top-k (`_route`)
         if score not in ("softmax", "sigmoid"):
             raise ValueError(f"unsupported router score: {score}")
-        if (score != "softmax" or n_group > 1) and not dropless:
+        if (score != "softmax" or n_group > 1 or correction_bias) \
+                and not dropless:
             raise NotImplementedError(
-                "sigmoid scores and group-limited routing need "
-                "dropless=True")
+                "sigmoid scores, group-limited routing and a correction "
+                "bias need dropless=True")
         self.score = score
         self.route_group = (int(n_group), int(topk_group)) \
             if n_group > 1 else None
@@ -371,6 +372,11 @@ class MoELayer(nn.Layer):
                                 *rest)  # noqa: E731
         self.gate_weight = self.create_parameter(
             [H, Eg], default_initializer=I.Normal(0.0, 0.02))
+        # a per-expert correction of the CHOICE (`_route`: it picks, it
+        # does not weigh), over all the router's outputs
+        self.e_score_correction_bias = self.create_parameter(
+            [Eg], default_initializer=I.Constant(0.0)) \
+            if correction_bias else None
         self.w_up = self.create_parameter([E, H, Iw], default_initializer=init)
         self.w_up._sharding_spec = espec(None, None)
         if activation == "swiglu":
@@ -418,8 +424,13 @@ class MoELayer(nn.Layer):
         inputs = [x, self.gate_weight, self.w_up, self.w_down]
         if self.w_gate is not None:
             inputs.append(self.w_gate)
+        if self.e_score_correction_bias is not None:
+            inputs.append(self.e_score_correction_bias)
 
         def impl(xa, gw, wu, wd, *rest):
+            rest = list(rest)
+            bias = rest.pop().astype(jnp.float32) \
+                if self.e_score_correction_bias is not None else None
             wg = rest[0] if rest else None
             xt = xa.reshape(T, shape[-1])
             logits = (xt.astype(jnp.float32)
@@ -427,7 +438,7 @@ class MoELayer(nn.Layer):
             gates = jax.nn.sigmoid(logits) if self.score == "sigmoid" \
                 else jax.nn.softmax(logits, axis=-1)
             if self.dropless:
-                y, aux = self._dropless(xt, logits, gates, wg, wu, wd)
+                y, aux = self._dropless(xt, logits, gates, wg, wu, wd, bias)
             else:
                 dispatch, combine, aux = top_k_gating(
                     gates, k, cap, renormalize=self.renormalize)
@@ -450,7 +461,7 @@ class MoELayer(nn.Layer):
             out = out + self.shared_down(s)
         return out
 
-    def _dropless(self, xt, logits, gates, wg, wu, wd):
+    def _dropless(self, xt, logits, gates, wg, wu, wd, bias=None):
         """Megablocks pattern: flatten (token, choice) rows, sort by expert,
         one ragged grouped GEMM, unsort, weighted-combine."""
         k, E = self.top_k, self.num_experts
@@ -459,7 +470,7 @@ class MoELayer(nn.Layer):
                                       activation=self.activation,
                                       held=self.experts_held,
                                       scale=self.routed_scale,
-                                      group=self.route_group)
+                                      group=self.route_group, bias=bias)
         mask1 = jax.nn.one_hot(topi[:, 0], E, dtype=gates.dtype)
         return y, load_balance_loss(gates, mask1)
 

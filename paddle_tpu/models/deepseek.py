@@ -47,15 +47,26 @@ class DeepSeekV2Config(MoEConfig):
     ``norm_topk_prob``, ``routed_scaling_factor``), one chip's share of
     an expert-parallel layer (``experts_held = (first, count)``) and
     ``rope_positions`` (rows of the rotary table the model builds; the
-    published maximum if None)."""
+    published maximum if None), a per-expert correction bias of the
+    router's choice (``correction_bias``: ``topk_method`` ``noaux_tc``)
+    and a residual of ``hc_mult`` streams mixed by hyper-connections
+    (`models/xing.py`: ``hc_sinkhorn_iters``, ``hc_eps``,
+    ``mhc_h_res_clamp`` = (min, max))."""
 
     def __init__(self, q_lora_rank=None, kv_lora_rank=512,
                  qk_nope_head_dim=128, qk_rope_head_dim=64,
                  v_head_dim=128, rope_scaling=None,
                  scoring_func="softmax", n_group=1, topk_group=1,
                  norm_topk_prob=True, routed_scaling_factor=1.0,
-                 experts_held=None, rope_positions=None, **kw):
+                 experts_held=None, rope_positions=None,
+                 correction_bias=False, hc_mult=1, hc_sinkhorn_iters=20,
+                 hc_eps=1e-6, mhc_h_res_clamp=(-30.0, 30.0), **kw):
         super().__init__(**kw)
+        self.correction_bias = bool(correction_bias)
+        self.hc_mult = int(hc_mult)
+        self.hc_sinkhorn_iters = int(hc_sinkhorn_iters)
+        self.hc_eps = float(hc_eps)
+        self.mhc_h_res_clamp = tuple(float(v) for v in mhc_h_res_clamp)
         self.q_lora_rank = q_lora_rank
         self.kv_lora_rank = kv_lora_rank
         self.qk_nope_head_dim = qk_nope_head_dim
@@ -266,7 +277,8 @@ class DeepSeekV2DecoderLayer(nn.Layer):
                 experts_held=c.experts_held,
                 routed_scale=c.routed_scaling_factor,
                 score=c.scoring_func, n_group=c.n_group,
-                topk_group=c.topk_group)
+                topk_group=c.topk_group,
+                correction_bias=c.correction_bias)
 
     def forward(self, x, cos, sin, attn_mask=None):
         h = x + self.self_attn(self.input_layernorm(x), cos, sin, attn_mask)
@@ -274,6 +286,9 @@ class DeepSeekV2DecoderLayer(nn.Layer):
 
 
 class DeepSeekV2Model(nn.Layer):
+    #: the layer a member of the family builds (`models/xing.py`)
+    layer_cls = DeepSeekV2DecoderLayer
+
     def __init__(self, config: DeepSeekV2Config):
         super().__init__()
         self.config = config
@@ -286,7 +301,7 @@ class DeepSeekV2Model(nn.Layer):
                 [config.vocab_size, config.hidden_size], "float32")
         self.embed_tokens.weight._sharding_spec = P(MP_AXIS, None)
         self.layers = nn.LayerList(
-            [DeepSeekV2DecoderLayer(config, i)
+            [self.layer_cls(config, i)
              for i in range(config.num_hidden_layers)])
         self.norm = nn.RMSNorm(config.hidden_size, config.rms_norm_eps)
         cos, sin = config.rope_table(config.rope_positions)
@@ -301,8 +316,16 @@ class DeepSeekV2Model(nn.Layer):
                 total = la if total is None else total + la
         return total
 
+    def enter(self, x):
+        """The residual the layers carry, from the embedding's rows."""
+        return x
+
+    def exit(self, x):
+        """... and back to [.., hidden] before the last norm."""
+        return x
+
     def forward(self, input_ids, attn_mask=None):
-        x = self.embed_tokens(input_ids)
+        x = self.enter(self.embed_tokens(input_ids))
         cos, sin = self.rope_cos._data, self.rope_sin._data
         for layer in self.layers:
             if self.config.recompute and self.training:
@@ -310,14 +333,17 @@ class DeepSeekV2Model(nn.Layer):
                 x = recompute(layer, x, cos, sin, attn_mask)
             else:
                 x = layer(x, cos, sin, attn_mask)
-        return self.norm(x)
+        return self.norm(self.exit(x))
 
 
 class DeepSeekV2ForCausalLM(nn.Layer):
+    #: the decoder a member of the family builds (`models/xing.py`)
+    model_cls = DeepSeekV2Model
+
     def __init__(self, config: DeepSeekV2Config):
         super().__init__()
         self.config = config
-        self.model = DeepSeekV2Model(config)
+        self.model = self.model_cls(config)
         self.lm_head = nn.Linear(config.hidden_size, config.vocab_size,
                                  bias_attr=False)
         self.lm_head.weight._sharding_spec = P(None, MP_AXIS)

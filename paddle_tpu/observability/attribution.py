@@ -228,7 +228,13 @@ SCOPES = ("embed", "attn_norm", "qkv_proj", "cache_write", "attention",
 #: KDA (delta-rule) mixer's parts answer the same way: its projections,
 #: its convolutions with the heads' norm and gates, the decode rows'
 #: state update and the chunk's scan (a scope each: two kernels), the
-#: heads' norm, gate and out-projection.
+#: heads' norm, gate and out-projection.  A residual of several streams
+#: (hyper-connections, `serving.engine._HyperResidual`) is mixed under
+#: three names: `mhc_pre` (a sublayer's input from the stream, before the
+#: sublayer's own norm), `mhc_post` (the stream's update), `mhc_merge`
+#: (entry and exit).  They answer as the norms do — the parts of a
+#: sublayer that are neither a projection nor an FFN — so that the sums
+#: readers take over `qkv_proj` / `attn_out` / `ffn` stay what they were.
 SCOPE_ALIASES = {"mla_q": "qkv_proj", "mla_kv": "qkv_proj",
                  "mla_attention": "attention", "mla_out": "attn_out",
                  "eva_attention": "attention", "eva_pool": "cache_write",
@@ -237,7 +243,9 @@ SCOPE_ALIASES = {"mla_q": "qkv_proj", "mla_kv": "qkv_proj",
                  "latent_proj": "routed_ffn",
                  "kda_in_proj": "qkv_proj", "kda_conv": "cache_write",
                  "kda_state_update": "attention",
-                 "kda_chunk_scan": "attention", "kda_out": "attn_out"}
+                 "kda_chunk_scan": "attention", "kda_out": "attn_out",
+                 "mhc_pre": "attn_norm", "mhc_post": "ffn_norm",
+                 "mhc_merge": "ffn_norm"}
 
 
 def scope(name: str):

@@ -299,6 +299,48 @@ def _c_kda_state_update(*, live: int, H: int, K: int, V: int) -> CostEstimate:
                                    "activations": rows_in + rows_out})
 
 
+@register_cost("mhc_pre")
+def _c_mhc_pre(*, T: int, n: int, C: int, dtype_bytes: int = 2
+               ) -> CostEstimate:
+    """What a sublayer reads of a residual of `n` streams (hyper-
+    connections, `ops.pallas_mhc.mhc_pre`): the stream [T, n C] ONCE in,
+    the turned mixing weights [32, n C] and their [32, 128] float32
+    scale / bias register once a launch; the sublayer's input [T, C] and the
+    coefficients [T, 128] float32 out. FLOPs a row: the product with
+    the weights (2 x n C x (n^2 + 2 n)), the sum of squares and the
+    weighted sum of the streams (2 x n C each). These are the bytes the
+    BlockSpecs move, as every entry's; ``breakdown["stream"]`` is kept
+    apart because WHERE the stream lies is the compiler's choice: a
+    launch whose stream fits on-chip memory (384 rows x 28,672 B on a
+    v5e: `S(1)` in the compiled step) does not cross HBM with it, and
+    `benchmarks/lib/costs_xing.py` counts the same bytes and leaves
+    exactly these out of the step's HBM roofline."""
+    stream = T * n * C * dtype_bytes
+    weights = 32 * n * C * dtype_bytes + 32 * 128 * 4
+    out = T * C * dtype_bytes + T * 128 * 4
+    return CostEstimate(bytes_read=stream + weights, bytes_written=out,
+                        flops=T * (2 * n * C * (n * n + 2 * n) + 4 * n * C),
+                        breakdown={"stream": stream, "weights": weights,
+                                   "activations": out})
+
+
+@register_cost("mhc_post")
+def _c_mhc_post(*, T: int, n: int, C: int, dtype_bytes: int = 2
+                ) -> CostEstimate:
+    """The stream after a sublayer (`ops.pallas_mhc.mhc_post`): the
+    stream [T, n C] once in and once out (aliased), the sublayer's
+    output [T, C] and the coefficients [T, 128] float32 in. FLOPs a
+    row: n^2 + n multiply-adds a column. ``breakdown["stream"]`` as
+    `mhc_pre`'s: not HBM bytes where the launch's stream stays on the
+    chip."""
+    stream = T * n * C * dtype_bytes
+    rows_in = T * C * dtype_bytes + T * 128 * 4
+    return CostEstimate(bytes_read=stream + rows_in, bytes_written=stream,
+                        flops=2 * T * (n * n + n) * C,
+                        breakdown={"stream": 2 * stream,
+                                   "activations": rows_in})
+
+
 def kda_chunk_scan_cost(*, rows: int, sub: int, H: int, K: int,
                         V: int) -> CostEstimate:
     """(Not in the kernel registry: the scan is plain XLA, no BlockSpec

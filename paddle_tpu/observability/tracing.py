@@ -62,7 +62,8 @@ __all__ = ["TraceEvent", "RequestTrace", "TraceRecorder", "recorder",
            "enabled", "set_enabled", "percentile", "percentiles",
            "slo_summary", "SLO_METRICS", "STEP_COUNTS", "STEPS_PER_SLOT",
            "STEP_COUNTS_BY_KIND", "STEP_COUNTS_MOE", "STEP_COUNTS_LATENT",
-           "STEP_COUNTS_EVA", "STEP_COUNTS_LOOP", "STEP_COUNTS_SSM"]
+           "STEP_COUNTS_EVA", "STEP_COUNTS_LOOP", "STEP_COUNTS_SSM",
+           "STEP_COUNTS_MHC"]
 
 _FLAG = _flags._registry["FLAGS_request_tracing"]
 
@@ -169,6 +170,18 @@ STEP_COUNTS_SSM: Tuple[str, ...] = (
     "ssm_slots_live", "ssm_state_bytes", "ssm_state_bytes_moved",
     "ssm_scan_rows", "ssm_state_resets", "state_pool_slots_used",
     "state_pool_slots_total")
+#: ... and where the residual is wider than one stream a token
+#: (hyper-connections: `serving.engine._HyperResidual`). Of the launch
+#: the record retires: the rows mixed (the flat buffer's, idle rows
+#: too; it adds up over a record's launches; the rows a sequence owned
+#: are `decode_rows` + `prefill_rows`), the sublayers a row is mixed
+#: around (two a layer); and — taken on the device, returned with the
+#: step's logits beside the routed layers' counts — the largest
+#: |column sum - 1| of any residual matrix of an owned row: whether the
+#: Sinkhorn iterations converged on this traffic. (The bytes of a row
+#: of the stream are a constant of the engine: `hbm_accounting()`.)
+STEP_COUNTS_MHC: Tuple[str, ...] = (
+    "mhc_rows", "mhc_sublayers", "mhc_colsum_err_max")
 #: step records kept for each slot of the request ring. A request lives
 #: through tens to hundreds of steps, and whoever reads a whole measured
 #: window from the records (`benchmarks/lib/program_spans.py`: 50-56 s

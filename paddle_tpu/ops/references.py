@@ -22,7 +22,9 @@ __all__ = ["rms_norm_reference", "layer_norm_reference",
            "oproj_norm_reference", "megadecode_ffn_reference",
            "qkv_rope_append_reference", "ssm_state_update_reference",
            "ssm_state_put_reference", "ssm_recurrence_reference",
-           "kda_state_update_reference", "kda_recurrence_reference"]
+           "kda_state_update_reference", "kda_recurrence_reference",
+           "mhc_layout", "mhc_pack", "sinkhorn_reference",
+           "mhc_pre_reference", "mhc_post_reference", "MHC_COEF_LANES"]
 
 
 def rms_norm_reference(x, weight, eps: float = 1e-6):
@@ -342,3 +344,91 @@ def kda_recurrence_reference(q, k, v, g, beta, state):
     state, o = jax.lax.scan(step, state.astype(f32), tuple(
         a.astype(f32) for a in (q, k, v, g, beta)))
     return o, state
+
+
+# -- manifold-constrained hyper-connections (`ops.pallas_mhc`) ---------
+#: lanes of the coefficient rows `mhc_pre` hands to `mhc_post`
+MHC_COEF_LANES = 128
+
+
+def mhc_layout(n: int):
+    """Rows of the packed mixing weights ``phi_t`` / ``ab`` for ``n``
+    streams: (first row of the post group, first row of the residual
+    group, rows in all). The pre group starts at row 0; each group
+    starts at a multiple of 8, a whole sublane tile."""
+    post0 = -(-n // 8) * 8
+    res0 = 2 * post0
+    return post0, res0, -(-(res0 + n * n) // 8) * 8
+
+
+def mhc_pack(phi, b, a, n: int, dtype=None):
+    """The published parameters of one sublayer's mixing — ``phi`` [n C,
+    n^2 + 2 n] (columns: pre, post, residual row-major), ``b`` [n^2 +
+    2 n], ``a`` [3] (a_pre, a_post, a_res) — as the kernels read them:
+    ``phi_t`` [rows, n C] (``phi`` turned, a group a sublane tile,
+    zeros between) in ``dtype`` and ``ab`` [rows, 128] float32 (lane 0
+    the group's ``a``, lane 1 ``b``, the rest of the register zeros)."""
+    post0, res0, rows = mhc_layout(n)
+    at = {0: (0, n), post0: (n, 2 * n), res0: (2 * n, 2 * n + n * n)}
+    phi_t = jnp.zeros((rows, phi.shape[0]), dtype or phi.dtype)
+    ab = jnp.zeros((rows, MHC_COEF_LANES), jnp.float32)
+    for g, (row, (c0, c1)) in enumerate(at.items()):
+        phi_t = phi_t.at[row:row + c1 - c0].set(
+            phi[:, c0:c1].T.astype(phi_t.dtype))
+        ab = ab.at[row:row + c1 - c0, 0].set(a[g].astype(jnp.float32))
+        ab = ab.at[row:row + c1 - c0, 1].set(b[c0:c1].astype(jnp.float32))
+    return phi_t, ab
+
+
+def sinkhorn_reference(z, iters: int, hc_eps: float):
+    """exp(z) [..., n, n] projected towards the doubly stochastic
+    matrices: ``iters`` times, columns normalised, then rows."""
+    m = jnp.exp(z)
+    for _ in range(iters):
+        m = m / (jnp.sum(m, -2, keepdims=True) + hc_eps)
+        m = m / (jnp.sum(m, -1, keepdims=True) + hc_eps)
+    return m
+
+
+def mhc_pre_reference(x, phi_t, ab, *, n: int, eps: float = 1e-6,
+                      hc_eps: float = 1e-6, iters: int = 20,
+                      clamp=(-30.0, 30.0)):
+    """What a sublayer reads of the stream x [T, n C] (stream j in
+    columns [j C, (j + 1) C)): ONE scalar norm a row over all n C
+    columns, ``u = r (x phi)``, the three groups of coefficients, the
+    Sinkhorn projection. -> (x_in [T, C] = sum_j Hpre_j x_j in x's
+    type, coef [T, 128] float32: lanes [0, n) Hpost, [n, n + n^2) Hres
+    row-major, then n lanes of Hpre)."""
+    f32 = jnp.float32
+    T = x.shape[0]
+    C = x.shape[1] // n
+    post0, res0, _ = mhc_layout(n)
+    xf = x.astype(f32)
+    r = jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    u = r * jnp.dot(xf, phi_t.astype(f32).T,
+                    precision=jax.lax.Precision.HIGHEST)
+    z = ab[:, 0] * u + ab[:, 1]
+    hpre = jax.nn.sigmoid(z[:, :n])
+    hpost = 2.0 * jax.nn.sigmoid(z[:, post0:post0 + n])
+    hres = sinkhorn_reference(
+        jnp.clip(z[:, res0:res0 + n * n], *clamp).reshape(T, n, n),
+        iters, hc_eps)
+    x_in = jnp.einsum("tj,tjc->tc", hpre, xf.reshape(T, n, C))
+    coef = jnp.zeros((T, MHC_COEF_LANES), f32)
+    coef = coef.at[:, :n].set(hpost)
+    coef = coef.at[:, n:n + n * n].set(hres.reshape(T, n * n))
+    coef = coef.at[:, n + n * n:2 * n + n * n].set(hpre)
+    return x_in.astype(x.dtype), coef
+
+
+def mhc_post_reference(x, y, coef, *, n: int):
+    """The stream after a sublayer: x'_i = sum_j Hres[i, j] x_j +
+    Hpost[i] y, accumulated in float32, in x's type."""
+    f32 = jnp.float32
+    T = x.shape[0]
+    C = x.shape[1] // n
+    hpost = coef[:, :n]
+    hres = coef[:, n:n + n * n].reshape(T, n, n)
+    out = jnp.einsum("tij,tjc->tic", hres, x.astype(f32).reshape(T, n, C)) \
+        + hpost[:, :, None] * y.astype(f32)[:, None, :]
+    return out.reshape(T, n * C).astype(x.dtype)

@@ -74,6 +74,8 @@ from ..models.nemotron_h import (ssm_conv, ssm_gated_norm, ssm_operands,
 from ..models.ouro import exit_distribution as _exit_distribution
 from ..ops.pallas_kda import (kda_chunk_scan, kda_state_update,
                               kda_tileable)
+from ..ops.pallas_mhc import (mhc_enter, mhc_exit, mhc_post, mhc_pre,
+                              mhc_tileable)
 from ..ops.pallas_ssm import (ssm_chunk_scan, ssm_state_put,
                               ssm_state_update)
 from ..ops.pallas_ragged import (ragged_head_block,
@@ -172,6 +174,14 @@ def _kda_step_eligible(heads: int, d: int, chunk: int, sub: int) -> bool:
     whole sub-chunks of the scan; interpreted, anything goes."""
     return (jax.default_backend() != "tpu"
             or (kda_tileable(heads, d, d) and chunk % sub == 0))
+
+
+def _mhc_step_eligible(rows: int, n: int, C: int) -> bool:
+    """As `_ragged_step_eligible`, for the two hyper-connection kernels:
+    on a TPU the launch's flat rows are whole row blocks and a stream is
+    whole registers (`mhc_tileable`); interpreted, anything goes. The
+    kernels have no other path: the plain forms are the oracle."""
+    return jax.default_backend() != "tpu" or mhc_tileable(rows, n, C)
 
 
 def _refuse_shared_cache(why: str, enable_prefix_cache, spec_decode: int,
@@ -279,20 +289,21 @@ def _halves_rope(c, s):
     return rope
 
 
-def _latent_mixer(L, h, x, rope, pool, seq_start, num_tokens, kv_lengths,
+def _latent_mixer(L, h, rope, pool, seq_start, num_tokens, kv_lengths,
                   tables, tok_page, tok_off, *, nh: int, dn: int, dr: int,
                   dv: int, r: int, width: int, eps: float, scale: float):
     """Latent attention in the ABSORBED form, on the normed rows h [1,
-    T, H] of the residual x: the cache row is (RMSNorm(latent) |
+    T, H] of a sublayer's input: the cache row is (RMSNorm(latent) |
     RoPE(k_pe) | pad), the query of head a is (q_nope_a W_kvb^K_a |
     RoPE(q_pe_a) | 0), the kernel's output the weighted sum of the
     rows' latent columns, and W_kvb^V_a comes after; where the layer has
     a head gate (``wgate``), each head's output times its sigmoid
     before the out-projection. The prefill chunk rides the same form as
-    the decode rows. -> (x + the mixer's output, the pool). Shared by
+    the decode rows. -> (the mixer's output y [1, T, H], the pool): the
+    caller's residual takes y (`_Residual.leave`). Shared by
     `_mla_unified_body` and the hybrid body's ``L`` blocks."""
     T = h.shape[1]
-    wkb = _kvb_heads(L, nh, x.dtype)
+    wkb = _kvb_heads(L, nh, h.dtype)
     w_k, w_v = wkb[:, :dn], wkb[:, dn:]
     with jax.named_scope("mla_q"):
         if "wqa" in L or "wqa_q" in L or "wqa_q4" in L:
@@ -329,8 +340,84 @@ def _latent_mixer(L, h, x, rope, pool, seq_start, num_tokens, kv_lengths,
             o = o * jax.nn.sigmoid(
                 (h[0] @ L["wgate"]).astype(jnp.float32))[
                     ..., None].astype(o.dtype)
-        x = x + _mm_w(o.reshape(1, T, nh * dv), L, "wo")
-    return x, pool
+        y = _mm_w(o.reshape(1, T, nh * dv), L, "wo")
+    return y, pool
+
+
+# -- the residual: how a step body enters, feeds and leaves it ---------
+class _Residual:
+    """The ONE place a step body's residual is written. `enter`: the
+    embedding's rows [1, T, C] -> what the layers carry; `feed`: that ->
+    (a sublayer's input [1, T, C], before the sublayer's own norm, and
+    what `leave` needs of this reading); `leave`: the residual, the
+    sublayer's output y [1, T, C] -> the residual after it; `exit`: ->
+    [1, T, C] for the last norm. This class is `x = x + f(norm(x))` and
+    adds no operation: a body written on it lowers to the text it had
+    with the adds written out."""
+
+    def enter(self, x):
+        return x
+
+    def feed(self, x, hc=None):
+        return x, None
+
+    def leave(self, x, y, keep=None):
+        return x + y
+
+    def exit(self, x):
+        return x
+
+
+class _HyperResidual(_Residual):
+    """A residual of ``hc_mult`` streams a token, [T, n C] in the
+    weights' type, mixed around EVERY sublayer by manifold-constrained
+    hyper-connections (`ops.pallas_mhc`: `feed` is ONE pass over the
+    stream — the norm, the coefficients, their Sinkhorn projection, the
+    sublayer's input — `leave` one more, in place). Entry copies the
+    embedding to every stream, exit sums them. Built inside the traced
+    step: ``live`` [T] marks the rows a sequence owns, over which the
+    largest |column sum - 1| of any residual matrix is kept
+    (`mhc_colsum_err_max`: whether the iterations converged)."""
+
+    #: the device counts it hands back beside the logits
+    counts = ("mhc_colsum_err_max",)
+
+    def __init__(self, cfg, live):
+        self.n = n = cfg.hc_mult
+        self.knobs = dict(n=n, eps=cfg.rms_norm_eps, hc_eps=cfg.hc_eps,
+                          iters=cfg.hc_sinkhorn_iters,
+                          clamp=cfg.mhc_h_res_clamp)
+        self.live = live
+        self.err = 0.0          # [T, n]: the largest so far, a column
+
+    def enter(self, x):
+        with jax.named_scope("mhc_merge"):
+            return mhc_enter(x[0], self.n)
+
+    def feed(self, x, hc=None):
+        n = self.n
+        with jax.named_scope("mhc_pre"):
+            a, coef = mhc_pre(x, hc["phi_t"], hc["ab"], **self.knobs)
+            # column j's sum: lanes n + i n + j over the rows i, slices
+            # added elementwise (ONE reduction, at the step's end)
+            cols = sum(coef[:, n + i * n:n + (i + 1) * n]
+                       for i in range(n))
+            self.err = jnp.maximum(self.err, jnp.abs(cols - 1.0))
+        return a[None], coef
+
+    def leave(self, x, y, keep=None):
+        with jax.named_scope("mhc_post"):
+            return mhc_post(x, y[0], keep, n=self.n)
+
+    def exit(self, x):
+        with jax.named_scope("mhc_merge"):
+            return mhc_exit(x, self.n)[None]
+
+    def device_counts(self):
+        return jnp.max(jnp.where(self.live[:, None], self.err, 0.0))[None]
+
+
+_PLAIN = _Residual()
 
 
 class _Launch:
@@ -369,7 +456,8 @@ _ADDITIVE = frozenset(
      "attn_narrow_updates")
     + _tracing.STEP_COUNTS_BY_KIND[:4]
     + _tracing.STEP_COUNTS_EVA[:4]
-    + ("ssm_state_bytes_moved", "ssm_scan_rows", "ssm_state_resets"))
+    + ("ssm_state_bytes_moved", "ssm_scan_rows", "ssm_state_resets")
+    + _tracing.STEP_COUNTS_MHC[:1])
 
 
 class ServingEngine:
@@ -573,6 +661,25 @@ class ServingEngine:
                 window=self._window,
                 window_pages=self.num_window_pages or None,
                 window_span=self.prefill_chunk)
+        # a residual of several streams a token (hyper-connections,
+        # `_HyperResidual`): the configuration's, never a switch
+        self._hc = cfg.hc_mult if self._family == "mla" else 1
+        if self._hc > 1 and spec_decode:
+            raise ValueError(
+                f"this model's residual is {self._hc} streams mixed by "
+                f"hyper-connections and its drafter would be its "
+                f"prediction block, which is not loaded; n-gram drafts "
+                f"through the wide stream are not measured (ROADMAP R9); "
+                f"spec_decode must be 0")
+        rows = self.max_slots + self.prefill_chunk
+        if self._hc > 1 and not _mhc_step_eligible(
+                rows, self._hc, cfg.hidden_size):
+            raise ValueError(
+                f"the hyper-connection kernels do not tile a launch of "
+                f"{rows} rows (max_slots + prefill_chunk) of {self._hc} "
+                f"streams of width {cfg.hidden_size} on this backend: "
+                f"the rows must be whole blocks of 128 and a stream "
+                f"whole 128-lane registers")
         self.prefix_sharing = bool(prefix_sharing)
         admission = getattr(config, "_admission", None)
         self._default_deadline_s = getattr(config, "_deadline_s", None)
@@ -701,6 +808,14 @@ class ServingEngine:
             self._count_names += _tracing.STEP_COUNTS_LOOP
         if self._ssm_layers:
             self._count_names += _tracing.STEP_COUNTS_SSM
+        if self._hc > 1:
+            self._count_names += _tracing.STEP_COUNTS_MHC
+        #: the counts a launch takes on the device and returns beside
+        #: its logits, in the order of the one array they come in
+        self._device_count_names = (
+            _tracing.STEP_COUNTS_MOE
+            if _tracing.STEP_COUNTS_MOE[0] in self._count_names else ()) \
+            + (_HyperResidual.counts if self._hc > 1 else ())
         self._counts = dict.fromkeys(self._count_names, 0)
         # the pool handles this step's launches were handed (dead
         # arrays, no buffers): `pools_in_place` asks them at account
@@ -767,6 +882,9 @@ class ServingEngine:
                         conv_dtype_bytes=self._kv_itemsize)
             self._hbm_pool_bytes += self._ssm_layers * (
                 self.max_slots + 1) * self._ssm_slot_bytes
+        # a wide residual: the bytes of one row of the stream as stored
+        self._stream_row_bytes = (self._hc * cfg.hidden_size
+                                  * self._kv_itemsize) if self._hc > 1 else 0
         # what ONE launch reads of the weights: the layers once a pass
         self._hbm_weight_read_bytes = self._hbm_weights_bytes + (
             self._passes - 1) * _costmodel.tree_bytes(self._w["layers"])
@@ -1210,6 +1328,11 @@ class ServingEngine:
             "state_pool_bytes": float(self._ssm_layers * (
                 self.max_slots + 1) * self._ssm_slot_bytes),
             "draft_bytes": float(_G_HBM_DRAFT.value),
+            # a residual of several streams a token: what the step's
+            # flat buffer holds of it between two sublayers
+            "residual_stream_bytes": float(
+                (self.max_slots * (1 + self.spec_k) + self.prefill_chunk)
+                * self._stream_row_bytes),
             "ledger_bytes": float(self._ledger_bytes),
             "ledger_tokens": int(self._ledger_tokens),
             "bytes_per_token_measured": (
@@ -1893,6 +2016,10 @@ class ServingEngine:
 
         if self._latent:
             counts["attn_tile_chains"] = visited(0, tiles=True)
+        if self._hc > 1:
+            # every row of the flat buffer is mixed, owned or not
+            counts.update({"mhc_rows": T,
+                           "mhc_sublayers": 2 * len(self._p["layers"])})
         if self._family == "looped":
             n_layers = len(self._p["layers"])
             counts.update({
@@ -1975,8 +2102,9 @@ class ServingEngine:
             fl.counts["ut_exit_mass"] = tuple(
                 float(v) for v in np.asarray(fl.moe))
         elif fl.moe is not None:
-            # the routed layers' counts came back with the tokens
-            fl.counts.update(zip(_tracing.STEP_COUNTS_MOE,
+            # the routed layers' counts (and a wide residual's) came
+            # back with the tokens
+            fl.counts.update(zip(self._device_count_names,
                                  (float(v) for v in np.asarray(fl.moe))))
         return tokens, logits
 
@@ -2636,11 +2764,12 @@ class ServingEngine:
                     new_ssm.append((z_pool, t_pool))
                     x = x + y[None]
                 elif kind == "L":
-                    x, pool = _latent_mixer(
-                        L, a, x, rope, next(kv_pools), seq_start,
+                    y, pool = _latent_mixer(
+                        L, a, rope, next(kv_pools), seq_start,
                         num_tokens, kv_lengths, tables, tok_page, tok_off,
                         **latent)
                     new_kv.append(pool)
+                    x = x + y
                 else:
                     kp, vp = next(kv_pools)
                     with _scope("qkv_proj"):
@@ -2728,8 +2857,11 @@ class ServingEngine:
         head a is (q_nope_a W_kvb^K_a | RoPE(q_pe_a) | 0), the kernel's
         output the weighted sum of the rows' latent columns, and
         W_kvb^V_a comes after. The prefill chunk rides the same form as
-        the decode rows."""
+        the decode rows. The residual is the configuration's: the plain
+        add, or — ``hc_mult`` > 1 — a stream of that many rows a token
+        mixed around every sublayer (`_HyperResidual`)."""
         cfg = self._p["cfg"]
+        hyper = cfg.hc_mult > 1
         nh = cfg.num_attention_heads
         dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
@@ -2755,27 +2887,38 @@ class ServingEngine:
             new_pools = []
             moe_stats = [] if count_moe else None
             live = _owned_rows(T, seq_start, num_tokens) \
-                if count_moe else None
+                if count_moe or hyper else None
+            res = _HyperResidual(cfg, live) if hyper else _PLAIN
+            x = res.enter(x)
             sts = moe_static or (None,) * len(w["layers"])
             for L, pool, st in zip(w["layers"], pools, sts):
+                a, keep = res.feed(x, L.get("hc1"))
                 with _scope("attn_norm"):
-                    h = fused_rms_norm(x, L["ln1"], eps)
-                x, pool = _latent_mixer(
-                    L, h, x, rope, pool, seq_start, num_tokens, kv_lengths,
+                    h = fused_rms_norm(a, L["ln1"], eps)
+                y, pool = _latent_mixer(
+                    L, h, rope, pool, seq_start, num_tokens, kv_lengths,
                     tables, tok_page, tok_off, nh=nh, dn=dn, dr=dr, dv=dv,
                     r=r, width=width, eps=eps, scale=scale)
+                x = res.leave(x, y, keep)
                 new_pools.append(pool)
+                a, keep = res.feed(x, L.get("hc2"))
                 with _scope("ffn_norm"):
-                    h2 = fused_rms_norm(x, L["ln2"], eps)
-                x = x + _ffn_apply(L, h2, st, moe_stats, live)
+                    h2 = fused_rms_norm(a, L["ln2"], eps)
+                x = res.leave(x, _ffn_apply(L, h2, st, moe_stats, live),
+                              keep)
+            x = res.exit(x)
             with _scope("head"):
                 x = fused_rms_norm(x, w["norm"], eps)
                 logits = _head_logits(
                     w, _logit_rows(x, seq_start, num_tokens, K))
                 tokens = _greedy(logits)
-                if moe_stats:
+                counts = [_moe_step_counts(moe_stats)] if moe_stats else []
+                if hyper:
+                    counts.append(res.device_counts())
+                if counts:
                     return (logits, new_pools, tokens,
-                            _moe_step_counts(moe_stats))
+                            counts[0] if len(counts) == 1
+                            else jnp.concatenate(counts))
             return logits, new_pools, tokens
 
         return step

@@ -405,3 +405,17 @@ def test_the_nemotron_step_lowers_to_the_parents_text():
     # the seven pins are where they were: five, the chunk-summary
     # family's and the looped decoder's
     assert len(LOWERED_AT_PARENT) == 5 and LOOPED_LOWERED_AT_PARENT
+
+
+#: ... and of THIS family's step at PR 50's parent (f1e4629), which had
+#: no pin: the ninth. PR 50 made `_latent_mixer` return the mixer's
+#: output for the caller's residual to take — the ``L`` blocks add it,
+#: as they did.
+LING_LOWERED_AT_PARENT = \
+    "e6f143cc0175056d69704bcae655aea15d2254671daed7d5bf7e17019ac6da88"
+
+
+def test_the_ling_step_lowers_to_the_parents_text(tiny):
+    text = _lower(_engine(tiny[0])).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == LING_LOWERED_AT_PARENT

@@ -62,6 +62,8 @@ SHAPES = {
     "ssm_state_update": dict(live=3, P=16, N=16, H=8),
     "ssm_state_put": dict(P=16, N=16, H=8),
     "kda_state_update": dict(live=3, H=4, K=16, V=16),
+    "mhc_pre": dict(T=16, n=4, C=64),
+    "mhc_post": dict(T=16, n=4, C=64),
 }
 
 
@@ -71,8 +73,8 @@ class TestRegistryCoverage:
         from paddle_tpu.ops import (fused, pallas_flash, pallas_flashmask,
                                     pallas_gmm, pallas_kda,
                                     pallas_megadecode, pallas_megafront,
-                                    pallas_mla, pallas_paged, pallas_ragged,
-                                    pallas_ssm, quant)
+                                    pallas_mhc, pallas_mla, pallas_paged,
+                                    pallas_ragged, pallas_ssm, quant)
         from paddle_tpu.ops.oracles import oracles
         names = set(oracles())
         missing = names - set(cm.costs())

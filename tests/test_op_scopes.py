@@ -150,6 +150,11 @@ def test_scope_refuses_a_name_outside_the_vocabulary():
     ("jit(step)/kda_state_update/pallas_call", ("attention", "-")),
     ("jit(step)/kda_chunk_scan/cond/dot_general", ("attention", "-")),
     ("jit(step)/kda_out/dot_general", ("attn_out", "-")),
+    # a residual of several streams (PR 50): the mixing answers as the
+    # norms do, under three names of its own
+    ("jit(step)/mhc_pre/pallas_call", ("attn_norm", "-")),
+    ("jit(step)/mhc_post/pallas_call", ("ffn_norm", "-")),
+    ("jit(step)/mhc_merge/reduce_sum", ("ffn_norm", "-")),
     ("jit(update)/jit(head)/mul", (None, "-")),     # function names
     ("jit(step)/jit(main)/add", (None, "-")),
 ])
@@ -160,6 +165,9 @@ def test_a_path_gives_its_innermost_name_and_direction(path, want):
 @pytest.mark.parametrize("path,want", [
     ("jit(step)/ssm_scan/pallas_call", "ssm_scan"),
     ("jit(step)/kda_state_update/pallas_call", "kda_state_update"),
+    ("jit(step)/mhc_pre/pallas_call", "mhc_pre"),
+    ("jit(step)/mhc_post/pallas_call", "mhc_post"),
+    ("jit(step)/mhc_merge/reduce_sum", "mhc_merge"),
     ("jit(step)/kda_chunk_scan/cond/dot_general", "kda_chunk_scan"),
     ("jit(step)/routed_ffn/latent_proj/dot_general", "latent_proj"),
     ("jit(step)/attention/pallas_call", "attention"),

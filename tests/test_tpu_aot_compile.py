@@ -48,7 +48,7 @@ def chip():
     from jax.sharding import SingleDeviceSharding
     from paddle_tpu.ops import (fused, pallas_flash, pallas_kda,
                                 pallas_megadecode, pallas_megafront,
-                                pallas_ragged, pallas_ssm, quant)
+                                pallas_mhc, pallas_ragged, pallas_ssm, quant)
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
@@ -59,7 +59,7 @@ def chip():
     one = SingleDeviceSharding(topo.devices[0])
     mp = pytest.MonkeyPatch()
     for mod in (fused, pallas_flash, pallas_megadecode, pallas_megafront,
-                pallas_ragged, pallas_ssm, pallas_kda, quant):
+                pallas_ragged, pallas_ssm, pallas_kda, pallas_mhc, quant):
         mp.setattr(mod, "_interpret", lambda: False)
     # a described-device executable is written to the persistent cache
     # but cannot be read back without a chip: keep it off around these
@@ -412,6 +412,26 @@ def test_delta_rule_kernels_compile_at_the_ling_cell_shapes(chip):
         chip.shape((ns + 2,), I32), row, row, row, row,
         chip.shape((ns, h, 1), F32), crow, crow, crow, crow,
         chip.shape((c, h), F32)), chip.refusals.get(_kda_layer_kernels)
+
+
+def _mhc_sublayer_kernels(x, phi_t, ab, y):
+    from paddle_tpu.ops.pallas_mhc import mhc_post, mhc_pre
+    x_in, coef = mhc_pre(x, phi_t, ab, n=4)
+    return mhc_post(x, y + x_in, coef, n=4)
+
+
+def test_hyper_connection_kernels_compile_at_the_xing_cell_shapes(chip):
+    """`xing4.0-29b-a4b-serve-ep4-d20` as its cell runs it: 384 flat
+    rows (128 slots + a 256-row chunk) of a four-stream residual, [384,
+    14336] bfloat16; `mhc_pre` holds 128 rows a step (the product with
+    the turned `phi_t` [32, 14336], the sum of squares, the Sinkhorn
+    iterations on [1, 128] vectors, two turns of a 128 x 128 register
+    square), `mhc_post` 64 rows in and out in place."""
+    t, n, c = 384, 4, 3584
+    assert chip.compiles(
+        _mhc_sublayer_kernels, chip.shape((t, n * c)),
+        chip.shape((32, n * c)), chip.shape((32, 128), F32),
+        chip.shape((t, c))), chip.refusals.get(_mhc_sublayer_kernels)
 
 
 def _serve_norm_and_linears(x, nw, w8, s8, w4, s4):
