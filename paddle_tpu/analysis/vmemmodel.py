@@ -179,7 +179,7 @@ CANONICAL: Dict[str, Dict[str, Any]] = {
     # `_rope_forward`'s, above; the cost with rope=False leaves it out):
     # G = 8 runs of one row each over the resident [T, KV, D] roped-K
     # and V rows, one (KV, 1, tile, D) block a plane a run
-    "fused_rope_append": dict(
+    "_append_kv_runs": dict(
         kernel="fused_rope_append",
         bindings=dict(T=8, KV=8, D=128, tile=16, G=8),
         in_widths=[2, 2, 2, 2], out_widths=[2, 2],
@@ -189,13 +189,15 @@ CANONICAL: Dict[str, Dict[str, Any]] = {
         families={"llama": dict(KV=8, D=128),
                   "gpt": dict(KV=32, D=128)},
     ),
+    # the same work list over ONE pool: the latent row's one head, whose
+    # resident rows ride as float32, one (1, 1, tile, D) block a run
     "fused_append_rows": dict(
         kernel="fused_append_rows",
-        bindings=dict(T=8, KV=8, D=128, psz=32),
-        in_widths=[2, 2], out_widths=[2],
-        cost_kwargs=dict(T=8, KV=8, D=128, page_size=32),
-        token_tiled=True,
-        families={"mla": dict(KV=1, D=576)},
+        bindings=dict(T=8, KV=1, D=640, tile=16, G=8),
+        in_widths=[4, 2], out_widths=[2],
+        cost_kwargs=dict(T=8, KV=1, D=640, page_size=32, runs=8, tile=16),
+        token_tiled=False,
+        families={"mla": dict(KV=1, D=640)},
     ),
     # EvaByte's pooling: 6 closed chunks of 16 rows, 32 KV heads
     "fused_chunk_pool": dict(
@@ -484,7 +486,7 @@ DECODE_CHAIN: List[str] = [
 
 _CHAIN_SITE: Dict[str, str] = {
     "fused_rms_norm": "_rms_forward",
-    "fused_rope_append": "fused_rope_append",
+    "fused_rope_append": "_append_kv_runs",
     "fused_qkv_rope_append": "_qkv_rope_append_fwd",
     "ragged_paged_attention": "ragged_paged_attention",
     "fused_oproj_norm": "_oproj_norm_forward",

@@ -192,6 +192,16 @@ def _c_fused_rope(*, B: int, S: int, H: int, D: int, Hk: int = 0,
                         breakdown={"activations": 2 * act + trig})
 
 
+def _append_tile(tile: Optional[int], page_size: int,
+                 dtype_bytes: int) -> int:
+    """`ops.fused.append_tile`: a sublane tile of the dtype, or the
+    whole page where a page is not whole tiles."""
+    if tile is not None:
+        return tile
+    rows = 32 // dtype_bytes
+    return rows if page_size % rows == 0 else page_size
+
+
 @register_cost("fused_rope_append")
 def _c_fused_rope_append(*, T: int, Hq: int, KV: int, D: int,
                          page_size: int, dtype_bytes: int = 2,
@@ -206,9 +216,7 @@ def _c_fused_rope_append(*, T: int, Hq: int, KV: int, D: int,
     `runs` to T: a decode row is a run of its own, a prefill chunk makes
     one for each `tile` rows. `rope=False` is the append launch alone
     (the site `analysis/vmemmodel.py` checks this entry against)."""
-    if tile is None:
-        tile = 32 // dtype_bytes
-        tile = tile if page_size % tile == 0 else page_size
+    tile = _append_tile(tile, page_size, dtype_bytes)
     runs = T if runs is None else runs
     rows = 2 * T * KV * D * dtype_bytes                # roped K, V
     tiles = 2 * runs * KV * tile * D * dtype_bytes     # k_pages + v_pages
@@ -229,14 +237,22 @@ def _c_fused_rope_append(*, T: int, Hq: int, KV: int, D: int,
 
 @register_cost("fused_append_rows")
 def _c_fused_append_rows(*, T: int, KV: int, D: int, page_size: int,
-                         dtype_bytes: int = 2) -> CostEstimate:
-    """Scatter T rows [KV, D] into paged cache: each token
-    read-modify-writes one (KV, page_size, D) block (aliased in+out)."""
-    pages = T * KV * page_size * D * dtype_bytes
-    return CostEstimate(bytes_read=(T * KV * D * dtype_bytes) + pages,
-                        bytes_written=pages, flops=0,
-                        breakdown={"kv": 2 * pages,
-                                   "activations": T * KV * D * dtype_bytes})
+                         dtype_bytes: int = 2,
+                         runs: Optional[int] = None,
+                         tile: Optional[int] = None) -> CostEstimate:
+    """T rows [KV, D] into ONE paged pool by RUNS, grid (runs,): the
+    launch's rows resident once, and ONE (KV, tile, D) block read and
+    written a run (aliased in+out); `tile` and `runs` default as
+    `fused_rope_append`'s do. The rows ride as float32 where KV is not
+    whole sublane tiles of the pool's type (the latent row's one head).
+    A K / V pair is `fused_rope_append` with ``rope=False``."""
+    tile = _append_tile(tile, page_size, dtype_bytes)
+    runs = T if runs is None else runs
+    rows = T * KV * D * (4 if KV % (32 // dtype_bytes) else dtype_bytes)
+    tiles = runs * KV * tile * D * dtype_bytes
+    return CostEstimate(bytes_read=rows + tiles, bytes_written=tiles,
+                        flops=0, breakdown={"kv": 2 * tiles,
+                                            "activations": rows})
 
 
 @register_cost("fused_chunk_pool")

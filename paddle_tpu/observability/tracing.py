@@ -138,16 +138,19 @@ STEP_COUNTS_LATENT: Tuple[str, ...] = ("chunk_kv_len", "latent_row_bytes",
                                        "attn_tile_chains")
 #: ... and where every layer is chunk-summary (EVA) attention, whose
 #: cache is two lists of rows from one pool. Of the launch (the first
-#: four add up): the pooled and the exact rows its queries' sequences
-#: read in ONE layer, the pooled rows it wrote, the windows its new
-#: tokens closed; the bytes of one row of either list in one layer.
-#: Of the call: the pages its closes returned, and the pool's pages by
-#: list (the total is the one pool's, the same under both names)
+#: four and the last add up): the pooled and the exact rows its
+#: queries' sequences read in ONE layer, the pooled rows it wrote, the
+#: windows its new tokens closed; the bytes of one row of either list
+#: in one layer. Of the call: the pages its closes returned, and the
+#: pool's pages by list (the total is the one pool's, the same under
+#: both names). Of the launch again: the cache-tile runs its pooled
+#: rows' append makes in one layer (`ops.fused.append_slot_run_table`)
 STEP_COUNTS_EVA: Tuple[str, ...] = (
     "summary_rows_live", "window_rows_live", "summaries_written",
     "windows_closed", "cache_row_bytes", "window_pages_freed",
     "pool_pages_used.summary", "pool_pages_used.exact",
-    "pool_pages_total.summary", "pool_pages_total.exact")
+    "pool_pages_total.summary", "pool_pages_total.exact",
+    "pool_append_runs")
 #: ... and where the layer list runs several times a token (a looped
 #: decoder): the passes of a launch, the layer applications they make,
 #: the bytes ALL of a token's cache rows take (passes x layers x K + V),

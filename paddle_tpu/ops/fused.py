@@ -16,6 +16,7 @@ Kernel design notes (pallas_guide.md):
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Optional
 
@@ -28,7 +29,8 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["fused_rms_norm", "fused_rope", "swiglu", "fused_layer_norm",
            "fused_bias_residual_layer_norm", "fused_moe_dispatch_combine",
            "fused_rope_append", "fused_append_rows", "fused_chunk_pool",
-           "append_tile", "append_run_table", "append_run_count"]
+           "append_tile", "append_run_table", "append_slot_run_table",
+           "append_run_count"]
 
 
 def _interpret() -> bool:
@@ -401,26 +403,43 @@ def append_run_count(live, first, page_idx, page_off, tile: int) -> int:
 
 def append_run_table(seq_start, num_tokens, page_idx, page_off, *,
                      tile: int, max_runs: int):
-    """The work list of `fused_rope_append`, on the device: [5 * G]
-    int32 (G = max_runs), five columns of G laid end to end — a run's
-    first row of the flat buffer, its rows, its page, the tile of that
-    page, the first row's offset inside the tile. A RUN is the
-    consecutive live rows that land in one tile of one cache page
-    (`_run_starts`); idle rows make none. Runs past the last live one
-    have no rows and name the last live run's tile (the trash page's
-    first when nothing is live), so the kernel's block does not move.
-    `max_runs` bounds the runs the row tables can make (the engine:
-    every decode row its own, a chunk of C rows ceil(C / tile) + 1);
-    compare-and-sum over [G, T], no scatter."""
-    G = max_runs
+    """The work list of `fused_rope_append` and `fused_append_rows`, on
+    the device: [5 * G] int32 (G = max_runs), five columns of G laid
+    end to end — a run's first row of the flat buffer, its rows, its
+    page, the tile of that page, the first row's offset inside the
+    tile. A RUN is the consecutive live rows that land in one tile of
+    one cache page (`_run_starts`); idle rows make none. Runs past the
+    last live one have no rows and name the last live run's tile (the
+    trash page's first when nothing is live), so the kernel's block
+    does not move. `max_runs` bounds the runs the row tables can make
+    (the engine: every decode row its own, a chunk of C rows
+    ceil(C / tile) + 1); compare-and-sum over [G, T], no scatter."""
     T = page_idx.shape[0]
     i32 = jnp.int32
     page_idx, page_off = page_idx.astype(i32), page_off.astype(i32)
     row = jnp.arange(T, dtype=i32)[:, None]
     owned = (row >= seq_start) & (row < seq_start + num_tokens)
     live = owned.any(-1)
-    start = _run_starts(jnp, live, (owned & (row == seq_start)).any(-1),
-                        page_idx, page_off, tile)
+    return _run_table(live, (owned & (row == seq_start)).any(-1),
+                      page_idx, page_off, tile, max_runs)
+
+
+def append_slot_run_table(page_idx, page_off, *, tile: int, max_runs: int):
+    """`append_run_table` of rows that belong to no sequence's span of
+    the flat buffer (the chunk-summary engine's pooling slots): a row
+    is live where its page is not the trash page 0, and a run opens
+    wherever the (page, tile) moves — no two sequences write one
+    page."""
+    i32 = jnp.int32
+    page_idx, page_off = page_idx.astype(i32), page_off.astype(i32)
+    return _run_table(page_idx > 0, False, page_idx, page_off, tile,
+                      max_runs)
+
+
+def _run_table(live, first, page_idx, page_off, tile: int, G: int):
+    T = page_idx.shape[0]
+    i32 = jnp.int32
+    start = _run_starts(jnp, live, first, page_idx, page_off, tile)
     run_of = jnp.cumsum(start.astype(i32)) - 1          # [T]
     g = jnp.arange(G, dtype=i32)[:, None]
     mine = run_of[None] == g                            # [G, T]
@@ -497,12 +516,20 @@ def fused_rope_append(q, k, v, cos, sin, k_pages, v_pages, runs):
     (a sequence's rows are consecutive positions, and no two sequences
     write one page). Identity rope (cos=1, sin=0) turns this into a
     pure append for the GPT family."""
+    q = _rope_forward(q[None], cos, sin)[0]
+    k = _rope_forward(k[None], cos, sin)[0]
+    kp, vp = _append_kv_runs(k, v, k_pages, v_pages, runs)
+    return q, kp, vp
+
+
+def _append_kv_runs(k, v, k_pages, v_pages, runs):
+    """The K / V rows [T, KV, D] of a launch into their pools by the
+    run table: `fused_rope_append`'s append, and `fused_append_rows`'s
+    of a pair."""
     T, KV, D = k.shape
     total, psz = k_pages.shape[1], k_pages.shape[2]
     tile = append_tile(k_pages.dtype, psz)
     G = runs.shape[0] // 5
-    q = _rope_forward(q[None], cos, sin)[0]
-    k = _rope_forward(k[None], cos, sin)[0]
 
     def row_map(g, runs):
         return (0, 0, 0)
@@ -521,7 +548,7 @@ def fused_rope_append(q, k, v, cos, sin, k_pages, v_pages, runs):
         in_specs=[row_spec, row_spec, tile_spec, tile_spec],
         out_specs=[tile_spec, tile_spec],
     )
-    kp, vp = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_append_runs_kernel, G=G),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
@@ -532,56 +559,96 @@ def fused_rope_append(q, k, v, cos, sin, k_pages, v_pages, runs):
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(runs.astype(jnp.int32), k, v, k_pages, v_pages)
-    return q, kp, vp
 
 
-def _append_rows_kernel(pg_ref, off_ref, r_ref, pin_ref, po_ref):
-    t = pl.program_id(0)
-    prev = pg_ref[jnp.maximum(t - 1, 0)]
+def _append_row_runs_kernel(runs_ref, r_ref, pin_ref, po_ref, *,
+                            G: int, KV: int):
+    # `_append_runs_kernel` for ONE pool, its rows laid [T * KV, D]
+    g = pl.program_id(0)
+    n = runs_ref[G + g]
 
-    @pl.when((t == 0) | (pg_ref[t] != prev))
-    def _seed():
+    @pl.when((n > 0) | (g == 0))
+    def _run():
         po_ref[:] = pin_ref[:]
+        first = runs_ref[g]
+        base = runs_ref[4 * G + g]
 
-    r = r_ref[0]
-    for h in range(r.shape[0]):
-        po_ref[h, 0] = _put_row(po_ref[h, 0], off_ref[t], r[h:h + 1])
+        def put(j, carry):
+            at = pl.multiple_of((first + j) * KV, KV)
+            r = r_ref[pl.ds(at, KV), :]                # [KV, D]
+            for h in range(KV):
+                po_ref[h, 0] = _put_row(po_ref[h, 0], base + j, r[h:h + 1])
+            return carry
+
+        jax.lax.fori_loop(0, n, put, 0)
 
 
-def fused_append_rows(pages, rows, page_idx, page_off):
-    """Scatter per-token cache rows [T, KV, D] into paged pools
-    [KV, total_pages, page_size, D] at (page_idx[t], page_off[t]) in one
-    pallas_call — the MLA engine's latent-row append (its rope runs on
-    split q_pe/k_pe shapes before the rows are concatenated) and the
-    chunk-summary engine's pooled rows. grid (T,), one row and one whole
-    page block a step. Contract: tokens that share a page are ADJACENT
-    in t (the engine's prefill chunk); non-adjacent revisits only happen
-    on the trash page (inactive slots), whose content is garbage by
-    design."""
-    T, KV, D = rows.shape
-    total, psz = pages.shape[1], pages.shape[2]
+def fused_append_rows(pages, rows, runs, *, scope: Optional[str] = None,
+                      _launch: bool = False):
+    """Cache rows [T, KV, D] into a paged pool [KV, total_pages,
+    page_size, D] by RUNS (`append_run_table` / `append_slot_run_table`
+    over the rows' tables, with `append_tile` of the pool): grid (G,),
+    each step holding one (KV, 1, tile, D) block — the latent engines'
+    row append (their rope runs on split q_pe / k_pe shapes before the
+    rows are concatenated). `pages` and `rows` may be PAIRS, the K and
+    V pools and their rows (the chunk-summary engine's pooled rows):
+    one walk of the table fills both, through `fused_rope_append`'s own
+    append, and a pair comes back.
 
-    def page_map(t, pg, off):
-        return (0, jnp.clip(pg[t], 0, total - 1), 0, 0)
+    The pools are donated as `fused_rope_append`'s are, and its
+    contract holds: a tile no run names — the trash page's too — comes
+    back bit for bit, and no two runs name one tile. `scope` (static)
+    names the launch in the compiled program as
+    `ragged_paged_attention`'s does: the launch is traced and lowered
+    ONCE for equal shapes inside a jitted copy of this function, which
+    a step's layers then share, and the instruction takes the innermost
+    name."""
+    if not _launch:
+        return _append_rows_jit(pages, rows, runs, scope=scope)
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        if isinstance(pages, (tuple, list)):
+            return _append_kv_runs(*rows, *pages, runs)
+        T, KV, D = rows.shape
+        total, psz = pages.shape[1], pages.shape[2]
+        tile = append_tile(pages.dtype, psz)
+        G = runs.shape[0] // 5
+        # the launch's rows stay resident as [T * KV, D] (a [T, 1, D]
+        # block would pad every row to a whole sublane tile): a run
+        # reads a row's KV sublanes at a dynamic offset, which Mosaic
+        # takes for a packed type only on whole tiles — fewer heads
+        # than that (the latent row's one) ride as float32, which
+        # `_put_row` makes of them anyway
+        if KV % (32 // rows.dtype.itemsize):
+            rows = rows.astype(jnp.float32)
+        rows = rows.reshape(T * KV, D)
 
-    page_spec = pl.BlockSpec((KV, 1, psz, D), page_map)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(T,),
-        in_specs=[pl.BlockSpec((1, KV, D), lambda t, pg, off: (t, 0, 0)),
-                  page_spec],
-        out_specs=page_spec,
-    )
-    return pl.pallas_call(
-        _append_rows_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(pages.shape, pages.dtype),
-        input_output_aliases={3: 0},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
-    )(page_idx.astype(jnp.int32), page_off.astype(jnp.int32),
-      rows, pages)
+        def tile_map(g, runs):
+            return (0, jnp.clip(runs[2 * G + g], 0, total - 1),
+                    jnp.clip(runs[3 * G + g], 0, psz // tile - 1), 0)
+
+        tile_spec = pl.BlockSpec((KV, 1, tile, D), tile_map)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                 # the run table
+            grid=(G,),
+            in_specs=[pl.BlockSpec((T * KV, D), lambda g, runs: (0, 0)),
+                      tile_spec],
+            out_specs=tile_spec,
+        )
+        return pl.pallas_call(
+            functools.partial(_append_row_runs_kernel, G=G, KV=KV),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(pages.shape, pages.dtype),
+            # flat-input indices INCLUDE the scalar-prefetch operand
+            input_output_aliases={2: 0},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=_interpret(),
+        )(runs.astype(jnp.int32), rows, pages)
+
+
+@functools.partial(jax.jit, static_argnames=("scope",))
+def _append_rows_jit(pages, rows, runs, *, scope):
+    return fused_append_rows(pages, rows, runs, scope=scope, _launch=True)
 
 
 # ---------------------------------------------------------------------------

@@ -397,9 +397,9 @@ def fused_qkv_rope_append(h, w, scale, bias, cos, sin, k_pages, v_pages,
     nope_dim+rope_dim] with its rope tail rotated, pool)`` — the
     absorbed kv_b einsums stay outside.
 
-    Same adjacency contract as fused_append_rows: tokens sharing a page
-    are adjacent in t; callers must use the RETURNED pools, never
-    re-read the donated arguments."""
+    Adjacency contract: tokens sharing a page are adjacent in t;
+    callers must use the RETURNED pools, never re-read the donated
+    arguments."""
     T, H = h.shape
     if lora_rank:
         if v_pages is not None:

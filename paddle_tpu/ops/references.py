@@ -97,9 +97,27 @@ def rope_append_reference(q, k, v, cos, sin, k_pages, v_pages,
     return qr, kp, vp
 
 
-def append_rows_reference(pages, rows, page_idx, page_off):
-    return pages.at[:, page_idx, page_off, :].set(
-        rows.astype(pages.dtype).swapaxes(0, 1))
+def append_rows_reference(pages, rows, runs):
+    """Row t of rows [T, KV, D] at the page and offset the run that
+    holds it says ([5 * G] of `ops.fused.append_run_table`: first row,
+    rows, page, tile of the page, offset in the tile); a row no run
+    holds writes nothing. A tuple of pools takes a tuple of rows."""
+    from .fused import append_tile
+    if isinstance(pages, (tuple, list)):
+        return tuple(append_rows_reference(p, r, runs)
+                     for p, r in zip(pages, rows))
+    first, n, page, part, base = runs.reshape(5, -1)
+    tile = append_tile(pages.dtype, pages.shape[2])
+    t = jnp.arange(rows.shape[0])[:, None]
+    mine = (t >= first) & (t < first + n)                # [T, G]
+
+    def of_run(x):
+        return jnp.sum(jnp.where(mine, x, 0), -1)
+
+    page_idx = jnp.where(mine.any(-1), of_run(page), pages.shape[1])
+    return pages.at[:, page_idx, of_run(part * tile + base + t - first),
+                    :].set(rows.astype(pages.dtype).swapaxes(0, 1),
+                           mode="drop")
 
 
 def chunk_pool_reference(k_pages, v_pages, phi, mu, page_idx, chunk_idx, *,

@@ -64,7 +64,8 @@ from ..observability.attribution import compile_named, scope as _scope
 from ..observability import tracing as _tracing
 from ..generation import (_decode_params, _ffn_apply, _kvb_heads,
                           _llama_weights, _mm_heads, _mm_w)
-from ..ops.fused import (append_run_count, append_run_table, append_tile,
+from ..ops.fused import (append_run_count, append_run_table,
+                         append_slot_run_table, append_tile,
                          fused_append_rows, fused_chunk_pool,
                          fused_layer_norm, fused_rms_norm,
                          fused_rope_append)
@@ -290,8 +291,8 @@ def _halves_rope(c, s):
 
 
 def _latent_mixer(L, h, rope, pool, seq_start, num_tokens, kv_lengths,
-                  tables, tok_page, tok_off, *, nh: int, dn: int, dr: int,
-                  dv: int, r: int, width: int, eps: float, scale: float):
+                  tables, runs, *, nh: int, dn: int, dr: int, dv: int,
+                  r: int, width: int, eps: float, scale: float):
     """Latent attention in the ABSORBED form, on the normed rows h [1,
     T, H] of a sublayer's input: the cache row is (RMSNorm(latent) |
     RoPE(k_pe) | pad), the query of head a is (q_nope_a W_kvb^K_a |
@@ -299,8 +300,9 @@ def _latent_mixer(L, h, rope, pool, seq_start, num_tokens, kv_lengths,
     rows' latent columns, and W_kvb^V_a comes after; where the layer has
     a head gate (``wgate``), each head's output times its sigmoid
     before the out-projection. The prefill chunk rides the same form as
-    the decode rows. -> (the mixer's output y [1, T, H], the pool): the
-    caller's residual takes y (`_Residual.leave`). Shared by
+    the decode rows; `runs` is the step's one append work list
+    (`ServingEngine._run_table`). -> (the mixer's output y [1, T, H],
+    the pool): the caller's residual takes y (`_Residual.leave`). Shared by
     `_mla_unified_body` and the hybrid body's ``L`` blocks."""
     T = h.shape[1]
     wkb = _kvb_heads(L, nh, h.dtype)
@@ -325,8 +327,8 @@ def _latent_mixer(L, h, rope, pool, seq_start, num_tokens, kv_lengths,
         rows = _pad_lanes(
             jnp.concatenate([lat, k_pe], -1)[0], width)
         with _scope("cache_write"):
-            pool = fused_append_rows(pool, rows[:, None],
-                                     tok_page, tok_off)
+            pool = fused_append_rows(pool, rows[:, None], runs,
+                                     scope="cache_write")
     with jax.named_scope("mla_attention"):
         # K is the row, V its latent columns: one page
         # fetch serves both matmuls
@@ -455,7 +457,7 @@ _ADDITIVE = frozenset(
      "pages_live", "pages_visited", "attn_block_visits",
      "attn_narrow_updates")
     + _tracing.STEP_COUNTS_BY_KIND[:4]
-    + _tracing.STEP_COUNTS_EVA[:4]
+    + _tracing.STEP_COUNTS_EVA[:4] + _tracing.STEP_COUNTS_EVA[-1:]
     + ("ssm_state_bytes_moved", "ssm_scan_rows", "ssm_state_resets")
     + _tracing.STEP_COUNTS_MHC[:1])
 
@@ -1977,16 +1979,14 @@ class ServingEngine:
         counts = {"decode_rows": int(num_tokens[:B].sum()),
                   "prefill_rows": n}
         seq_start = np.append(np.arange(B) * R, base)
-        if self._latent:                # its rows go in one by one
+        if self._latent:
             counts["chunk_kv_len"] = int(kv_lengths[S - 1])
             counts["latent_row_bytes"] = \
                 self._kv_geom[1] * self._kv_itemsize
-        else:
-            # the runs the launch's rope + append makes of these rows,
-            # one layer of each kind (the kinds' pages turn together)
-            counts["append_runs"] = (1 + windowed) * append_run_count(
-                row_live, row_first, tok_page, tok_off,
-                self._append_tile)
+        # the runs the launch's append makes of these rows, one layer of
+        # each kind (the kinds' pages turn together)
+        counts["append_runs"] = (1 + windowed) * append_run_count(
+            row_live, row_first, tok_page, tok_off, self._append_tile)
         # pages that hold this launch's tokens, against the K/V page
         # fetches the ragged kernel makes for each KV head (a sequence's
         # pages once for every grid cell — a block of query tiles —
@@ -2062,6 +2062,9 @@ class ServingEngine:
                 "window_rows_live": int(
                     (kv_lengths - -(-summary_rows // ps) * ps)[seen].sum()),
                 "summaries_written": int((pool_page[1] > 0).sum()),
+                "pool_append_runs": append_run_count(
+                    pool_page[1] > 0, False, pool_page[1], pool_off[1],
+                    self._append_tile),
                 "windows_closed": int(
                     (ends % self.allocator.span == 0).sum()),
                 "cache_row_bytes": 2 * self._kv_geom[0] * self._kv_geom[1]
@@ -2287,6 +2290,18 @@ class ServingEngine:
 
         return run_table
 
+    def _slot_run_table(self, pool_page, pool_off):
+        """The chunk-summary step's second, smaller work list: of the
+        pooled rows of its pooling slots (a slot whose summary page is
+        not the trash page closed a chunk). At most a decode slot's row
+        a run, and one for each tile the chunk's C / chunk consecutive
+        rows of a summary page touch."""
+        tile = self._append_tile
+        chunks = self.prefill_chunk // self.allocator.chunk
+        return append_slot_run_table(
+            pool_page, pool_off, tile=tile,
+            max_runs=self.max_slots + -(-chunks // tile) + 1)
+
     def _llama_unified_body(self):
         cfg = self._p["cfg"]
         KV, D = cfg.num_key_value_heads, cfg.head_dim
@@ -2404,6 +2419,8 @@ class ServingEngine:
                 c, s = w["cos"][positions], w["sin"][positions]
             with _scope("cache_write"):
                 runs = run_table(num_tokens, tok_page, tok_off)
+                pool_runs = self._slot_run_table(pool_page[1],
+                                                 pool_off[1])
             new_pools = []
             for L, (kp, vp) in zip(w["layers"], pools):
                 with _scope("attn_norm"):
@@ -2420,10 +2437,8 @@ class ServingEngine:
                         kt, vt = fused_chunk_pool(
                             kp, vp, L["phi"], L["mu"], pool_page[0],
                             pool_off[0], chunk=ck, scale=scale)
-                        kp = fused_append_rows(kp, kt, pool_page[1],
-                                               pool_off[1])
-                        vp = fused_append_rows(vp, vt, pool_page[1],
-                                               pool_off[1])
+                        kp, vp = fused_append_rows(
+                            (kp, vp), (kt, vt), pool_runs, scope="eva_pool")
                 new_pools.append((kp, vp))
                 with _scope("attention"), jax.named_scope("eva_attention"):
                     o = ragged_paged_attention(
@@ -2744,8 +2759,8 @@ class ServingEngine:
             else:
                 # no rotary embedding, no position table
                 one = jnp.ones((T, D // 2), x.dtype)
-                with _scope("cache_write"):
-                    runs = run_table(num_tokens, tok_page, tok_off)
+            with _scope("cache_write"):
+                runs = run_table(num_tokens, tok_page, tok_off)
             kv_pools, ssm_pools = iter(pools["kv"]), iter(pools["ssm"])
             sts = iter(moe_static)
             new_kv, new_ssm, moe_stats = [], [], []
@@ -2766,8 +2781,7 @@ class ServingEngine:
                 elif kind == "L":
                     y, pool = _latent_mixer(
                         L, a, rope, next(kv_pools), seq_start,
-                        num_tokens, kv_lengths, tables, tok_page, tok_off,
-                        **latent)
+                        num_tokens, kv_lengths, tables, runs, **latent)
                     new_kv.append(pool)
                     x = x + y
                 else:
@@ -2875,6 +2889,7 @@ class ServingEngine:
         R = 1 + K
         T = B * R + C
         seq_start = _seq_starts(B, R)
+        run_table = self._run_table(seq_start)
 
         def step(w, tok, pools, positions, num_tokens, kv_lengths,
                  tables, tok_page, tok_off):
@@ -2882,6 +2897,8 @@ class ServingEngine:
                 x = w["embed"][tok][None]                # [1, T, H]
                 c = w["cos"][positions]                  # [T, dr/2]
                 s = w["sin"][positions]
+            with _scope("cache_write"):
+                runs = run_table(num_tokens, tok_page, tok_off)
 
             rope = _halves_rope(c, s)
             new_pools = []
@@ -2897,8 +2914,8 @@ class ServingEngine:
                     h = fused_rms_norm(a, L["ln1"], eps)
                 y, pool = _latent_mixer(
                     L, h, rope, pool, seq_start, num_tokens, kv_lengths,
-                    tables, tok_page, tok_off, nh=nh, dn=dn, dr=dr, dv=dv,
-                    r=r, width=width, eps=eps, scale=scale)
+                    tables, runs, nh=nh, dn=dn, dr=dr, dv=dv, r=r,
+                    width=width, eps=eps, scale=scale)
                 x = res.leave(x, y, keep)
                 new_pools.append(pool)
                 a, keep = res.feed(x, L.get("hc2"))

@@ -23,6 +23,7 @@ from paddle_tpu.models.axk1 import AXK1ForCausalLM, axk1_tiny_config
 from paddle_tpu.ops.pallas_ragged import (ragged_attention_reference,
                                           ragged_paged_attention)
 from paddle_tpu.serving import ServingEngine
+from test_engine_programs import _spy_append_runs
 
 #: Engine logits against the float32 reference's, both in float32 at the
 #: highest matmul precision: what is left is the order of float32 sums
@@ -91,6 +92,7 @@ def served(model):
     eng.on_logits = lambda req, row: rows.setdefault(
         req.request_id, []).append(row.copy())
     reqs = [eng.add_request(p, max_new_tokens=6) for p in prompts]
+    eng.append_launches = _spy_append_runs(eng)
     eng.run_to_completion()
     return prompts, reqs, rows, eng, \
         list(tracing.recorder().steps()[-eng.steps:])
@@ -172,6 +174,23 @@ class TestEngineAgainstReference:
         # a chunk's context grows by the chunk until the prompt ends
         ctx = [r["chunk_kv_len"] for r in recs if r["prefill_rows"]]
         assert ctx[:4] == [16, 32, 48, 61]
+
+    def test_append_runs_is_the_devices_table(self, served):
+        """The latent engine's step record carries `append_runs`: every
+        launch's count is the live runs of the table the jitted step
+        makes of the same row tables (`fused_append_rows` walks it)."""
+        _, _, _, eng, records = served
+        assert len(eng.append_launches) > 10
+        for counts, on_device, _ in eng.append_launches:
+            assert counts["append_runs"] == on_device
+            rows = counts["decode_rows"] + counts["prefill_rows"]
+            # pages of 8 under float32: a tile is a page, a chunk of 16
+            # touches two or three
+            assert on_device <= rows <= on_device * 8
+        assert any(c["prefill_rows"] == 16 and n in (2, 3)
+                   for c, n, _ in eng.append_launches)
+        assert sum(r["append_runs"] for r in records) \
+            == sum(n for _, n, _ in eng.append_launches)
 
     def test_a_launch_of_several_tiles_visits_a_page_for_a_block_of_them(
             self, model, served):

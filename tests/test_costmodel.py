@@ -40,7 +40,7 @@ SHAPES = {
     "fused_moe_dispatch_combine": dict(T=8, K=2, E=4, C=16),
     "fused_rope": dict(B=2, S=16, H=4, D=64, Hk=1),
     "fused_rope_append": dict(T=8, Hq=4, KV=1, D=64, page_size=16),
-    "fused_append_rows": dict(T=8, KV=1, D=64, page_size=16),
+    "fused_append_rows": dict(T=8, KV=1, D=64, page_size=16, runs=3),
     "fused_chunk_pool": dict(P=6, KV=2, D=64, chunk=16),
     "swiglu": dict(T=8, H=256),
     "flash_sdpa": dict(B=2, H=3, Sq=256, Sk=512, D=64,

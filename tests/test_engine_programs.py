@@ -5,6 +5,8 @@ constructor argument selects an implementation; a shape for which
 fused half and reads the model's own projections; and the names
 `benchmarks/` reads of the engine are there."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -182,6 +184,46 @@ def _lower_unified(eng):
         table, page, i32(B + C))
 
 
+@functools.lru_cache(maxsize=None)
+def _lowered_toy(family):
+    """(the model, its engine at the pins' sizes, its unified step
+    lowered from shapes), made once a process for the tests that only
+    READ them: nothing steps this engine."""
+    m = _laguna() if family == "laguna" else _tiny(family)
+    eng = ServingEngine(m, max_slots=2, page_size=8, max_context=64,
+                        prefill_chunk=8)
+    return m, eng, _lower_unified(eng)
+
+
+def _spy_append_runs(eng):
+    """Wrap `eng._build_unified`: beside the counts every launch's step
+    record takes on the host, the live runs of the work lists the jitted
+    step makes of the same row tables on the device -> the list, one
+    (counts, the rows' live runs, the pooling slots' or None) a launch."""
+    from paddle_tpu.serving.engine import _seq_starts
+    seen, build = [], eng._build_unified
+    run_table = jax.jit(eng._run_table(
+        _seq_starts(eng.max_slots, 1 + eng.spec_k)))
+    slot_table = jax.jit(eng._slot_run_table)
+
+    def live_runs(table):
+        return int((np.asarray(table).reshape(5, -1)[1] > 0).sum())
+
+    def spy(*a):
+        out = build(*a)
+        _, _, num_tokens, _, _, tok_page, tok_off = out[0]
+        pooled = None
+        if eng._eva:
+            (tok_page, pool_page), (tok_off, pool_off) = tok_page, tok_off
+            pooled = live_runs(slot_table(pool_page[1], pool_off[1]))
+        seen.append((out[-1], live_runs(run_table(
+            num_tokens, tok_page, tok_off)), pooled))
+        return out
+
+    eng._build_unified = spy
+    return seen
+
+
 #: family -> (the model's own projections of layer 0, the append its
 #: front half ends in)
 CHAIN = {
@@ -201,9 +243,7 @@ class TestOneChain:
 
     @pytest.mark.parametrize("family", sorted(CHAIN))
     def test_lowered_step_calls_no_fused_half(self, family):
-        m = _laguna() if family == "laguna" else _tiny(family)
-        eng = ServingEngine(m, max_slots=2, page_size=8, max_context=64,
-                            prefill_chunk=8)
+        _, eng, lowered = _lowered_toy(family)
         assert eng.ragged
         own, append = CHAIN[family]
         keys = set(eng._w["layers"][0])
@@ -212,7 +252,7 @@ class TestOneChain:
         assert ("wqkv" in keys) == (family == "gpt")
         assert "wqkva" not in keys
         # locations name the python functions the step was traced through
-        text = _lower_unified(eng).as_text(debug_info=True)
+        text = lowered.as_text(debug_info=True)
         for gone in ("fused_qkv_rope_append", "fused_oproj_norm",
                      "fused_ffn", "pallas_megafront", "pallas_megadecode"):
             assert gone not in text, gone
@@ -352,9 +392,7 @@ class TestTheNamesTheBenchmarkReads:
 
     @pytest.mark.parametrize("family", ["llama", "laguna"])
     def test_the_names_the_benchmark_reads(self, family):
-        m = _laguna() if family == "laguna" else _tiny(family)
-        eng = ServingEngine(m, max_slots=2, page_size=8, max_context=64,
-                            prefill_chunk=8)
+        m, eng, lowered = _lowered_toy(family)
         assert eng.ragged is True
         assert eng.megafront is False and eng.megadecode is False
         assert (eng.front_half_launches, eng.back_half_launches) == (5, 6)
@@ -362,6 +400,6 @@ class TestTheNamesTheBenchmarkReads:
         assert bool(eng.num_window_pages) == (family == "laguna")
         assert len(eng._attn_static) == len(eng._p["layers"]) \
             == len(eng._w["layers"]) == len(eng._pools)
-        logits, pools, *_ = _lower_unified(eng).out_info
+        logits, pools, *_ = lowered.out_info
         assert logits.shape == (eng.max_slots + 1, m.config.vocab_size)
         assert jax.tree.structure(pools) == jax.tree.structure(eng._pools)

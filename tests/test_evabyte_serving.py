@@ -24,13 +24,14 @@ from benchmarks.systems.evabyte_serving import model_layers
 from paddle_tpu import resilience
 from paddle_tpu.models.evabyte import (EvaByteForCausalLM,
                                        evabyte_tiny_config)
-from paddle_tpu.ops.fused import fused_append_rows, fused_chunk_pool
+from paddle_tpu.ops.fused import (append_slot_run_table, fused_append_rows,
+                                  fused_chunk_pool)
 from paddle_tpu.ops.pallas_ragged import (ragged_attention_reference,
                                           ragged_paged_attention)
 from paddle_tpu.ops.references import chunk_pool_reference
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.block_allocator import ChunkSummaryAllocator
-from test_engine_programs import _laguna, _lower_unified, _tiny
+from test_engine_programs import _lowered_toy, _spy_append_runs
 
 
 # --------------------------------------------------------------- model
@@ -149,11 +150,22 @@ class TestEngineAgainstReference:
         eng = _engine(m)
         eng.add_request(np.arange(40, dtype=np.int32) % 64,
                         max_new_tokens=10)
+        launches = _spy_append_runs(eng)
         while eng.has_work():
             eng.step()
         recs = tracing.recorder().steps()[-eng.steps:]
         for k in tracing.STEP_COUNTS_EVA:
             assert all(k in r for r in recs), k
+        # both work lists of the step's appends, counted on the host by
+        # the rule the device makes them by: the rows' and — a chunk of
+        # 8 closes two pooling chunks of 4, neighbours in a summary page
+        # (one run), a decode row one at most — the pooled rows'
+        for counts, on_device, pooled in launches:
+            assert counts["append_runs"] == on_device
+            assert counts["pool_append_runs"] == pooled \
+                <= counts["summaries_written"] <= 2 * pooled
+        assert sum(r["pool_append_runs"] for r in recs) \
+            == sum(p for _, _, p in launches) == 5 + 2
         # 49 rows cached (the tenth token is never fed): 12 whole chunks
         # pooled, closes at 16, 32 and 48
         assert sum(r["summaries_written"] for r in recs) == 12
@@ -362,9 +374,19 @@ class TestChunkPool:
         kt, _ = fused_chunk_pool(kp, vp, phi, phi, jnp.asarray([2]),
                                  jnp.asarray([1]), chunk=c, scale=1.0)
         np.testing.assert_allclose(kt[0], kp[:, 2, 4:8].mean(1), atol=1e-6)
-        out = fused_append_rows(kp, kt, jnp.asarray([4]), jnp.asarray([5]))
+        # a pooling slot whose summary page is the trash page is idle
+        runs = append_slot_run_table(jnp.asarray([0, 4]), jnp.asarray([0, 5]),
+                                     tile=8, max_runs=2)
+        rows = jnp.concatenate([jnp.full_like(kt, 7.0), kt])
+        out, same = fused_append_rows((kp, vp), (rows, vp[:, 0, :2].swapaxes(
+            0, 1)), runs)
         np.testing.assert_allclose(out[:, 4, 5], kt[0], atol=0)
-        np.testing.assert_allclose(out[:, 4, :5], kp[:, 4, :5], atol=0)
+        keep = np.ones(kp.shape[1:3], bool)
+        keep[4, 5] = False
+        np.testing.assert_array_equal(np.asarray(out)[:, keep],
+                                      np.asarray(kp)[:, keep])
+        np.testing.assert_array_equal(np.asarray(same)[:, keep],
+                                      np.asarray(vp)[:, keep])
 
     def test_a_page_is_whole_chunks(self):
         z = jnp.zeros((1, 2, 8, 32))
@@ -457,13 +479,16 @@ class TestSummaryMask:
 #: PR 48 (the head-split projections' weights stored [heads, D, in] and
 #: contracted on their last axis, `generation._mm_heads`) re-recorded
 #: every family that has one: all but `gpt` (a fused `wqkv`, as it was).
+#: PR 51 (`fused_append_rows` by cache-tile runs: the row-a-grid-step
+#: kernel left the tree) re-recorded `mla`, the one that calls it; the
+#: four that call `fused_rope_append` did not move.
 LOWERED_AT_PARENT = {
     "llama": "4d83618490abd9d8f116cc016366e6172f711b6d4f6b0ce6ed29d45b7e3d"
              "e951",
     "moe": "0de185b03924605a0d214cf359c90904112bb12066a8eb1160461ff17cbae3"
            "ef",
-    "mla": "539252a8649cd7d29838b137886cb8f145bcf82679d315f448c6a64ebd4891"
-           "17",
+    "mla": "8d1333e7645fd18b6c8f60a28fb286db0fa31b1ae9737b105449c7318885c0"
+           "41",
     "gpt": "663c3f3c4d2afd7ca79f46705c2730cf70402b7affd7238e977b6989d4da17"
            "3b",
     "laguna": "0e64637de82469062d258f39ca057729aa8249d93545457be6ac89560ef"
@@ -473,10 +498,7 @@ LOWERED_AT_PARENT = {
 
 @pytest.mark.parametrize("family", sorted(LOWERED_AT_PARENT))
 def test_the_five_families_lower_to_the_parents_text(family):
-    m = _laguna() if family == "laguna" else _tiny(family)
-    eng = ServingEngine(m, max_slots=2, page_size=8, max_context=64,
-                        prefill_chunk=8)
-    text = _lower_unified(eng).as_text()
+    text = _lowered_toy(family)[2].as_text()
     assert hashlib.sha256(text.encode()).hexdigest() \
         == LOWERED_AT_PARENT[family]
 

@@ -11,8 +11,10 @@ CPU). paddlelint rule PK105 enforces the same contract statically.
 import os
 import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 # registration side effects                                  # noqa: F401
 from paddle_tpu.ops import (fused, pallas_flash, pallas_flashmask,
@@ -87,13 +89,25 @@ class TestOracleParity:
         gv = jnp.asarray(rng.random((T, K)), jnp.float32)
         self._check("fused_moe_dispatch_combine", keep, oh, gv)
 
-    def test_append_rows(self):
+    @pytest.mark.parametrize("pair", [False, True], ids=["one", "pair"])
+    def test_append_rows(self, pair):
+        from paddle_tpu.ops.fused import append_run_table, append_tile
         rng = np.random.default_rng(2)
-        KV, total, psz, D, T = 2, 4, 4, 128, 4
-        pages = jnp.asarray(rng.standard_normal((KV, total, psz, D)),
-                            jnp.float32)
-        rows = jnp.asarray(rng.standard_normal((T, KV, D)), jnp.float32)
-        # engine contract: tokens sharing a page are adjacent in t
-        page_idx = jnp.asarray([1, 1, 2, 2], jnp.int32)
-        page_off = jnp.asarray([0, 1, 0, 1], jnp.int32)
-        self._check("fused_append_rows", pages, rows, page_idx, page_off)
+        KV, total, psz, D, T = 2, 4, 16, 128, 12
+        pages, rows = (tuple(
+            jnp.asarray(rng.standard_normal(shape), jnp.float32)
+            for _ in range(2)) for shape in ((KV, total, psz, D), (T, KV, D)))
+        # a decode row, an idle one, a chunk across a tile and a page
+        page_idx = jnp.asarray([1, 0] + [2] * 6 + [3] * 2 + [0] * 2,
+                               jnp.int32)
+        page_off = jnp.asarray([5, 0] + list(range(10, 16)) + [0, 1, 0, 0],
+                               jnp.int32)
+        runs = jax.jit(append_run_table, static_argnames=(
+            "tile", "max_runs"))(
+            jnp.asarray([0, 1, 2]), jnp.asarray([1, 0, 8]), page_idx,
+            page_off, tile=append_tile(jnp.float32, psz), max_runs=5)
+        if pair:
+            self._check("fused_append_rows", pages, rows, runs, atol=0)
+        else:
+            self._check("fused_append_rows", pages[0], rows[0], runs,
+                        atol=0)

@@ -277,8 +277,10 @@ def test_an_untileable_shape_is_refused(tiny, monkeypatch):
 #: block of KV heads) re-recorded it with four of the five, PR 45 (a
 #: decode row's page visit computes the few rows the row owns) with all
 #: five, PR 48 (q / k / v weights stored [heads, D, in]) with four of them.
+#: PR 51 (the pooled K and V rows appended by cache-tile runs in ONE call,
+#: their run table made in the step) re-recorded it with `mla` and Ling's.
 EVA_LOWERED_AT_PARENT = \
-    "5ed8fa4892628ec664c39c2477f2474e02bc85bb8fdfe2f6beb3990a3d4ba697"
+    "c69e6c31e99b7b8abdbd947eae23ca40c1f926fea843d3959a3d424752aa4354"
 
 
 def _lower_eva():

@@ -1308,10 +1308,12 @@ class TestSeededKernelDefects:
         # tables inside the kernel, for their own page DMAs)
         fresh = self._seed(
             tmp_path, self.FUSED,
-            old="return (0, jnp.clip(pg[t], 0, total - 1), 0, 0)",
-            new="return (0, pg[t], 0, 0)")
+            old="            return (0, jnp.clip(runs[2 * G + g], 0, "
+                "total - 1),",
+            new="            return (0, runs[2 * G + g],")
         assert fresh and {f.rule for f in fresh} == {"PK101"}
-        assert all("pg" in f.detail for f in fresh)
+        assert all("runs" in f.detail for f in fresh)
+        assert {f.qualname for f in fresh} == {"fused_append_rows"}
 
     def test_pk103_catches_widened_alias_dtype(self, tmp_path):
         fresh = self._seed(
@@ -2175,8 +2177,9 @@ class TestSeededMemoryDefects:
         # it after the launch observes the in-place overwrite
         fresh = self._seed(
             tmp_path, self.FUSED,
-            old="      rows, pages)",
-            new="      rows, pages)\n    _ = pages.mean()")
+            old="        )(runs.astype(jnp.int32), rows, pages)",
+            new="        )(runs.astype(jnp.int32), rows, pages)\n"
+                "        _ = pages.mean()")
         assert fresh and {f.rule for f in fresh} == {"PF402"}
         assert fresh[0].detail == "alias:pages->out0"
         assert fresh[0].qualname == "fused_append_rows"
@@ -2413,7 +2416,7 @@ class TestSeededEffectsDefects:
         assert fresh and "PE502" in {f.rule for f in fresh}
         pe = next(f for f in fresh if f.rule == "PE502")
         assert pe.detail == "radw:vin_ref->kp_ref"
-        assert pe.qualname == "fused_rope_append"
+        assert pe.qualname == "_append_kv_runs"
 
     def test_pe503_catches_dropped_accumulator_guard(self, tmp_path):
         # delete the seed of the online-softmax state at the top of a

@@ -12,7 +12,8 @@ import numpy as np
 import pytest
 
 from paddle_tpu.ops.fused import (append_run_count, append_run_table,
-                                  append_tile, fused_append_rows,
+                                  append_slot_run_table, append_tile,
+                                  fused_append_rows,
                                   fused_rope_append)
 from paddle_tpu.ops import pallas_ragged
 from paddle_tpu.ops.pallas_ragged import (_work_list,
@@ -25,7 +26,18 @@ from paddle_tpu.ops.pallas_ragged import (_work_list,
                                           ragged_pages_visited,
                                           ragged_tile_block,
                                           ragged_tile_tokens)
-from paddle_tpu.ops.references import rope_append_reference
+from paddle_tpu.ops.references import (append_rows_reference,
+                                       rope_append_reference)
+
+# the run tables as the engine's step makes them, and the attention
+# oracle: inside jitted programs (op by op, every primitive of every
+# case's shapes compiles: 1.7 s an oracle call against 0.4)
+append_run_table = jax.jit(append_run_table,
+                           static_argnames=("tile", "max_runs"))
+append_slot_run_table = jax.jit(append_slot_run_table,
+                                static_argnames=("tile", "max_runs"))
+ragged_attention_reference = jax.jit(
+    ragged_attention_reference, static_argnames=("scale", "window", "v_dim"))
 
 
 def _setup(T, S, H, KV, D, psz, pps, seed=0, dtype=jnp.float32):
@@ -594,20 +606,91 @@ class TestFusedRopeAppend:
         assert not table[1, n:].any()
         assert (table[2:4, n:] == table[2:4, n - 1:n]).all()
 
-    def test_append_rows(self):
-        # the MLA latent-row scatter (KV=1 single pool)
-        T, D, psz, total = 5, 24, 4, 6
+    #: row tables of ONE geometry (3 slots of 2 rows, a chunk of 24,
+    #: pages of 32: the cases of a dtype and pool count share a compile):
+    #: name -> (dec, chunk) of `_row_tables`, or the (page, offset) of
+    #: 30 pooling slots (`append_slot_run_table`: page 0 is idle)
+    ROWS = {
+        "chunk_across_tiles": ({0: (3, 1)}, (5, 24)),
+        "chunk_across_a_page": ({}, (20, 24)),
+        # slot 0's rows 15, 16 cross a tile; slot 1 idle; slot 2 one of 2
+        "idle_rows_between_live": ({0: (15, 2), 2: (30, 1)}, (0, 7)),
+        "nothing_live": ({}, None),
+        # two decode slots' pooled rows, then a chunk's six neighbours
+        # of a summary page from row 13 on (they cross a tile)
+        "pooling_slots": (
+            [3, 0, 0, 7, 0] + [5] * 6 + [0] * 19,
+            [31, 0, 0, 16, 0] + list(range(13, 19)) + [0] * 19),
+        "pooling_slots_idle": ([0] * 30, [0] * 30),
+    }
+
+    @pytest.mark.parametrize("name,dtype,pools,KV", [
+        ("chunk_across_tiles", jnp.float32, 1, 1),
+        ("chunk_across_tiles", jnp.bfloat16, 2, 2),
+        ("chunk_across_a_page", jnp.bfloat16, 1, 1),
+        ("chunk_across_a_page", jnp.float32, 2, 2),
+        ("idle_rows_between_live", jnp.bfloat16, 1, 1),
+        ("idle_rows_between_live", jnp.bfloat16, 2, 2),
+        ("nothing_live", jnp.bfloat16, 1, 1),
+        ("nothing_live", jnp.float32, 2, 2),
+        ("pooling_slots", jnp.bfloat16, 2, 2),
+        ("pooling_slots", jnp.float32, 1, 1),
+        # 16 heads: whole sublane tiles of bfloat16, rows not widened
+        ("pooling_slots", jnp.bfloat16, 1, 16),
+        ("pooling_slots_idle", jnp.bfloat16, 2, 2),
+    ], ids=lambda v: getattr(v, "__name__", str(v)))
+    def test_append_rows(self, name, dtype, pools, KV):
+        """`fused_append_rows` by the run table — ONE pool (the latent
+        row) or a K / V pair (the pooled rows) — against the rows put by
+        hand and `append_rows_reference`, bit for bit on EVERY tile of
+        every pool; the device table's live runs are the host's count
+        (the step records' `append_runs` / `pool_append_runs`)."""
+        B, R, C, psz, D, total = 3, 2, 24, 32, 32, 17
+        tile = append_tile(dtype, psz)
+        G = B * R + -(-C // tile) + 1
+        if name.startswith("pooling_slots"):
+            page, off = (np.asarray(x, np.int32) for x in self.ROWS[name])
+            live, first = page > 0, False
+            runs = append_slot_run_table(
+                jnp.asarray(page), jnp.asarray(off), tile=tile, max_runs=G)
+        else:
+            seq_start, num_tokens, page, off, _ = _row_tables(
+                B, R, C, psz, *self.ROWS[name])
+            live, first = (np.zeros(len(page), bool) for _ in range(2))
+            for s0, k in zip(seq_start, num_tokens):
+                live[s0:s0 + k], first[s0] = True, k > 0
+            runs = append_run_table(
+                jnp.asarray(seq_start), jnp.asarray(num_tokens),
+                jnp.asarray(page), jnp.asarray(off), tile=tile, max_runs=G)
         rng = np.random.RandomState(2)
-        rows = jnp.asarray(rng.randn(T, 1, D), jnp.float32)
-        pool = jnp.asarray(rng.randn(1, total, psz, D), jnp.float32)
-        pg = jnp.asarray([2, 2, 2, 4, 5], jnp.int32)
-        off = jnp.asarray([1, 2, 3, 0, 3], jnp.int32)
-        out = fused_append_rows(pool, rows, pg, off)
-        ref = np.array(pool)
-        for t in range(T):
-            ref[:, int(pg[t]), int(off[t])] = np.asarray(rows)[t]
-        np.testing.assert_array_equal(np.asarray(out)[:, 1:],
-                                      ref[:, 1:])
+        pages = tuple(jnp.asarray(rng.randn(KV, total, psz, D), dtype)
+                      for _ in range(pools))
+        rows = tuple(jnp.asarray(rng.randn(len(page), KV, D), dtype)
+                     for _ in range(pools))
+        # by hand: the live rows where the row tables say, nothing else
+        want = [np.array(p.astype(jnp.float32)) for p in pages]
+        for w, r in zip(want, rows):
+            for t in np.flatnonzero(live):
+                w[:, page[t], off[t]] = np.asarray(r.astype(jnp.float32))[t]
+        args = (pages, rows, runs) if pools > 1 else \
+            (pages[0], rows[0], runs)
+        ref, got = append_rows_reference(*args), fused_append_rows(*args)
+        assert isinstance(got, tuple) == (pools > 1)
+        if pools == 1:
+            ref, got = (ref,), (got,)
+        for g, r, w, p in zip(got, ref, want, pages):
+            assert g.dtype == p.dtype and g.shape == p.shape
+            for x in (g, r):
+                np.testing.assert_array_equal(
+                    np.asarray(x.astype(jnp.float32)), w)
+        n_runs = int((np.asarray(runs)[G:2 * G] > 0).sum())
+        assert n_runs == append_run_count(live, first, page, off, tile)
+        assert n_runs <= G and (n_runs > 0) == bool(live.any())
+        if name == "pooling_slots":
+            # two decode slots' rows, and the tiles rows 13..18 touch
+            assert n_runs == 2 + (18 // tile - 13 // tile + 1)
+        if name == "idle_rows_between_live":
+            assert not live[1:3].all() and live[0] and live[4]
 
 
 #: launches of pages that hold K and V in ONE row (latent attention: 16

@@ -171,13 +171,9 @@ def _rope_append(q, k, v, c, s, kp, vp, ss, nt, pg, off):
     """The step's two pieces: the run table from the row tables, once,
     and the kernel that works by it (32 slots + a 256-row chunk where T
     is 288, the smoke's 8 + 32 else)."""
-    from paddle_tpu.ops.fused import (append_run_table, append_tile,
-                                      fused_rope_append)
-    tile = append_tile(kp.dtype, kp.shape[2])
-    slots = ss.shape[0] - 1
-    bound = slots + -(-(q.shape[0] - slots) // tile) + 1
-    runs = append_run_table(ss, nt, pg, off, tile=tile, max_runs=bound)
-    return fused_rope_append(q, k, v, c, s, kp, vp, runs)
+    from paddle_tpu.ops.fused import fused_rope_append
+    return fused_rope_append(q, k, v, c, s, kp, vp,
+                             _run_table(ss, nt, pg, off, kp))
 
 
 def _rope_append_args(chip, t, hq, kv, s, n_pages, psz):
@@ -209,17 +205,33 @@ def test_fused_rope_append_compiles(chip, t, hq, kv, s, n_pages, psz):
         chip.refusals.get(_rope_append)
 
 
-def _append_rows(pages, rows, pg, off):
+def _run_table(ss, nt, pg, off, pages):
+    """The step's one work list of its appends, as the engine bounds
+    it: a decode row a run, the chunk's one for each tile it touches."""
+    from paddle_tpu.ops.fused import append_run_table, append_tile
+    tile = append_tile(pages.dtype, pages.shape[2])
+    slots = ss.shape[0] - 1
+    bound = slots + -(-(pg.shape[0] - slots) // tile) + 1
+    return append_run_table(ss, nt, pg, off, tile=tile, max_runs=bound)
+
+
+def _append_rows(pages, rows, ss, nt, pg, off):
     from paddle_tpu.ops.fused import fused_append_rows
-    return fused_append_rows(pages, rows, pg, off)
+    return fused_append_rows(pages, rows, _run_table(ss, nt, pg, off, pages),
+                             scope="cache_write")
 
 
-def test_fused_append_rows_compiles(chip):
-    """The MLA split front's latent-row append ([latent 512 | rope 64]
-    rows — same page-row write as the llama kernels)."""
-    tok = chip.shape((T,), I32)
-    assert chip.compiles(_append_rows, chip.shape((1, NP, PSZ, 576)),
-                         chip.shape((T, 1, 576)), tok, tok), \
+@pytest.mark.parametrize("t,s,n_pages,psz,width", [
+    (T, S, NP, PSZ, 576), (384, 129, 641, 256, 640),
+    (640, 385, 3073, 256, 640)], ids=["smoke", "xing", "ling"])
+def test_fused_append_rows_compiles(chip, t, s, n_pages, psz, width):
+    """The latent-row append by cache-tile runs ([latent 512 | rope 64]
+    rows, ONE (1, 1, 16, width) block a grid step, the launch's rows
+    resident as float32 [T, width]) at the smoke's widths and at the
+    Xing and Ling cells': T 384 / G 145 and T 640 / G 401."""
+    tok, seq = chip.shape((t,), I32), chip.shape((s,), I32)
+    assert chip.compiles(_append_rows, chip.shape((1, n_pages, psz, width)),
+                         chip.shape((t, 1, width)), seq, seq, tok, tok), \
         chip.refusals.get(_append_rows)
 
 
@@ -298,7 +310,7 @@ def test_ragged_paged_attention_compiles_at_the_laguna_cell_shapes(
 def _ragged_latent(q, pool, rows, ss, nt, kvl, tab, pg, off):
     from paddle_tpu.ops.fused import fused_append_rows
     from paddle_tpu.ops.pallas_ragged import ragged_paged_attention
-    pool = fused_append_rows(pool, rows, pg, off)
+    pool = fused_append_rows(pool, rows, _run_table(ss, nt, pg, off, pool))
     return ragged_paged_attention(q, pool, None, ss, nt, kvl, tab,
                                   v_dim=512), pool
 
@@ -329,15 +341,21 @@ def _eva_layer_kernels(q, k, v, c, s, kp, vp, phi, mu, ss, nt, kvl, sr, tab,
                        pg, off, pool_pg, pool_off):
     """One EvaByte layer's kernels in the engine's order: rope + append
     into the window's pages, the pooling of the chunks that closed, the
-    two appends of their pooled rows, ONE softmax over pooled and exact
-    rows."""
-    from paddle_tpu.ops.fused import fused_append_rows, fused_chunk_pool
+    ONE append of their pooled K and V rows (48 slots: 32 decode rows'
+    and the 16 a chunk can close, 34 runs at most), ONE softmax over
+    pooled and exact rows."""
+    from paddle_tpu.ops.fused import (append_slot_run_table, append_tile,
+                                      fused_append_rows, fused_chunk_pool)
     from paddle_tpu.ops.pallas_ragged import ragged_paged_attention
     q, kp, vp = _rope_append(q, k, v, c, s, kp, vp, ss, nt, pg, off)
     kt, vt = fused_chunk_pool(kp, vp, phi, mu, pool_pg[0], pool_off[0],
                               chunk=16, scale=D ** -0.5)
-    kp = fused_append_rows(kp, kt, pool_pg[1], pool_off[1])
-    vp = fused_append_rows(vp, vt, pool_pg[1], pool_off[1])
+    tile = append_tile(kp.dtype, kp.shape[2])
+    slots = ss.shape[0] - 1
+    runs = append_slot_run_table(
+        pool_pg[1], pool_off[1], tile=tile,
+        max_runs=slots + -(-(pool_pg.shape[1] - slots) // tile) + 1)
+    kp, vp = fused_append_rows((kp, vp), (kt, vt), runs, scope="eva_pool")
     return ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab,
                                   summary_rows=sr), kp, vp
 
