@@ -330,25 +330,29 @@ _handle_lock = threading.Lock()
 
 
 def handle_timeout(rec: FlightRecord) -> None:
-    """Declare `rec` timed out: mark + cancel it, compute the cross-rank
-    desync report when a store is attached, dump the ring next to the
-    worker log, and count the event. Idempotent per record (the monitor
-    and a cooperative wait site may race to report the same hang)."""
+    """Declare `rec` timed out: mark it, compute the cross-rank desync
+    report when a store is attached, dump the ring next to the worker
+    log, count the event, and only then cancel the record. Idempotent per
+    record (the monitor and a cooperative wait site may race to report
+    the same hang): the loser waits on the lock until the winner's dump
+    is written, and a wait site that polls `rec.cancelled` without the
+    lock never sees it set while `dump_path` / `lagging_rank` are still
+    to come."""
     with _handle_lock:
         if rec.cancelled:
             return
-        rec.cancelled = True
         rec.status = "timeout"
-    _M_TIMEOUTS.labels(collective=rec.op).inc()
-    desync = None
-    with contextlib.suppress(Exception):
-        publish_progress()          # let peers see where we stopped
-        desync = desync_report()
-    if desync is not None:
-        rec.lagging_rank = desync.get("lagging_rank")
-    with contextlib.suppress(Exception):
-        rec.dump_path = recorder().dump_to(
-            timed_out_seq=rec.seq, desync=desync)
+        _M_TIMEOUTS.labels(collective=rec.op).inc()
+        desync = None
+        with contextlib.suppress(Exception):
+            publish_progress()          # let peers see where we stopped
+            desync = desync_report()
+        if desync is not None:
+            rec.lagging_rank = desync.get("lagging_rank")
+        with contextlib.suppress(Exception):
+            rec.dump_path = recorder().dump_to(
+                timed_out_seq=rec.seq, desync=desync)
+        rec.cancelled = True
 
 
 def timeout_error(rec: Optional[FlightRecord], op: str,

@@ -6,7 +6,10 @@ xla_force_host_platform_device_count so sharding/collective tests execute a
 real 8-way SPMD program without hardware. Must run before jax import.
 """
 
+import faulthandler
 import os
+import signal
+import sys
 
 # tests force the CPU: the 8-device simulated mesh only exists on the cpu
 # platform (the chip is exercised by chip_smoke.py, not by this suite)
@@ -29,6 +32,8 @@ import jax  # noqa: E402
 # (bf16 MXU passes) is exercised explicitly by the kernel/perf tests instead
 jax.config.update("jax_default_matmul_precision", "highest")
 
+import pytest  # noqa: E402
+
 # persistent compilation cache: the suite is compile-bound; cached XLA
 # executables cut full-suite time several-fold on reruns
 from paddle_tpu._bootstrap import configure_compile_cache  # noqa: E402
@@ -36,13 +41,62 @@ from paddle_tpu._bootstrap import configure_compile_cache  # noqa: E402
 configure_compile_cache()
 
 
+def pytest_configure(config):
+    # pytest-xdist 3.8.0 under `--dist loadfile` hands the files out by
+    # NUMBER OF TESTS, descending, so the one-case files (the quality gates,
+    # minutes each) start last and the run ends on one worker with five
+    # idle. Off, files go out in collection order. The scheduler reads the
+    # option in the controller (scheduler/loadscope.py:374); set here and
+    # not as `addopts = --no-loadscope-reorder`, which a run with
+    # `-p no:xdist` rejects as an unknown argument.
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
+def pytest_collection_modifyitems(items):
+    # the quality gates are trainings, minutes each under the eager tape
+    # (test_quality_gate_ocr.py: ~630 s beside five busy workers): started
+    # in their alphabetical place, two thirds in, they end the run alone
+    items.sort(key=lambda item: "test_quality_gate_" not in item.nodeid)
+
+
+# ---------------------------------------------------------------------------
+# every case has a limit of its own: a case that hangs (a child that never
+# answers, a rendezvous nobody joins) fails with its stack, and does not
+# cost the whole run its clock. ONE limit: twice the longest case of the
+# driver's command under six workers, fixtures' setup included, and not over
+# a third of the suite's clock, which is what binds (the rec gate with its
+# net's training reads 246-313 s, PR 52). A hook around the whole protocol and
+# not a fixture: a function-scoped fixture is set up after the module-scoped
+# ones, whose setup is where the long cases spend their time.
+# ---------------------------------------------------------------------------
+CASE_LIMIT_S = 480
+
+
+def _case_timed_out(signum, frame):
+    pytest.fail(f"case exceeded CASE_LIMIT_S = {CASE_LIMIT_S} s "
+                f"(every thread's stack: captured stderr)")
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_protocol(item):
+    old = signal.signal(signal.SIGALRM, _case_timed_out)
+    signal.setitimer(signal.ITIMER_REAL, CASE_LIMIT_S)
+    # a second earlier, and from a thread of its own: it writes even when
+    # the main thread is stuck in a call that lets no handler run
+    faulthandler.dump_traceback_later(CASE_LIMIT_S - 1, file=sys.__stderr__)
+    try:
+        return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, old)
+
+
 # ---------------------------------------------------------------------------
 # global-state hygiene: tests that fleet.init() a hybrid mesh must not leak
 # it into later tests (the ambient mesh changes eager-collective routing)
 # ---------------------------------------------------------------------------
-import pytest  # noqa: E402
-
-
 @pytest.fixture(autouse=True)
 def _restore_global_mesh():
     from paddle_tpu.distributed.mesh import get_mesh, set_mesh

@@ -34,7 +34,7 @@ class TestLauncherDrivenTuning:
                 "log_interval": 10,
                 "hbm_budget_bytes": 12 * 1024 * 1024}
         best, history = tuner.tune_launched(
-            base, workdir=str(tmp_path), steps=4, timeout=420,
+            base, workdir=str(tmp_path), steps=4, timeout=300,
             env={"JAX_PLATFORMS": "cpu",
                  "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
                  "PYTHONPATH": REPO + os.pathsep

@@ -83,7 +83,7 @@ class TestMultiHostPsum:
                 ASSETS, "multihost_psum_worker.py"),
                 str(tmp_path), out_dir)
             for r in range(2)]
-        outs, logs = _wait_and_assert_ok(procs, tmp_path, timeout=420)
+        outs, logs = _wait_and_assert_ok(procs, tmp_path, timeout=300)
         for r in range(2):
             f = os.path.join(out_dir, f"ok.{r}")
             assert os.path.exists(f), (outs, logs)
@@ -116,7 +116,7 @@ class TestMultiHostTrain:
                          str(tmp_path), out_dir,
                          extra_env={"MH_TRAIN_CFG": cfg_name})
             for r in range(2)]
-        outs, logs = _wait_and_assert_ok(procs, tmp_path, timeout=420)
+        outs, logs = _wait_and_assert_ok(procs, tmp_path, timeout=300)
         for r in range(2):
             f = os.path.join(out_dir, f"losses.{r}.json")
             assert os.path.exists(f), (outs, logs)
@@ -157,7 +157,7 @@ class TestMultiHostRunPretrain:
         r = subprocess.run(
             [sys.executable, "-m", "paddle_tpu.trainer.run_pretrain",
              "--config", ref_cfg_path],
-            env=env, cwd=REPO, capture_output=True, text=True, timeout=420)
+            env=env, cwd=REPO, capture_output=True, text=True, timeout=300)
         assert r.returncode == 0, (r.stdout, r.stderr)
         ref = [json.loads(x)["loss"] for x in open(
             os.path.join(ref_cfg["output_dir"], "losses.jsonl"))]
@@ -181,7 +181,7 @@ class TestMultiHostRunPretrain:
                              str(tmp_path), out_dir,
                              extra_env={"MH_CFG": stage_path})
                 for rk in range(2)]
-            outs, logs = _wait_and_assert_ok(procs, tmp_path, timeout=420)
+            outs, logs = _wait_and_assert_ok(procs, tmp_path, timeout=300)
         assert any("resumed from ckpt_step3" in lg for lg in logs), logs
         got = {}
         for x in open(os.path.join(mh_cfg["output_dir"], "losses.jsonl")):

@@ -6,14 +6,20 @@ need corpora this environment cannot download, so these gates train the
 SAME model/loss/optimizer stacks on bundled synthetic data with fixed
 seeds and assert accuracy thresholds — a regression tripwire for the
 end-to-end training paths, not a replica of the published numbers
-(documented in BASELINE.md rows 4-5).  One gate a file (four, since
-ISSUE 30: under `--dist loadfile` a file is one worker's unit of work,
-and the four gates together were the run's longest, 599 s).
+(documented in BASELINE.md rows 4-5).
+
+The PTQ gate (from test_quantization_depth.py, which trained the same
+model on the same data in another process) reads a copy of the one
+fine-tuned model: one training, two gates (ISSUE 52).
 """
 
+import copy
+
 import numpy as np
+import pytest
 
 import paddle_tpu as paddle
+from paddle_tpu.quantization import PTQ, HistObserver, QuantConfig
 
 
 def _sentiment_corpus(n, seed, seq=16):
@@ -37,34 +43,60 @@ def _sentiment_corpus(n, seed, seq=16):
     return X, y
 
 
+@pytest.fixture(scope="module")
+def finetuned():
+    """The SST-2 fine-tune path (model + CE loss + AdamW + scheduler), in
+    eval mode, and the dev set."""
+    from paddle_tpu.models.bert import (BertForSequenceClassification,
+                                        bert_tiny_config)
+    paddle.seed(0)
+    cfg = bert_tiny_config(vocab_size=64, hidden_size=64,
+                           num_hidden_layers=2, num_attention_heads=4,
+                           intermediate_size=128,
+                           max_position_embeddings=32, num_labels=2)
+    model = BertForSequenceClassification(cfg)
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                 parameters=list(model.parameters()))
+    Xtr, ytr = _sentiment_corpus(512, 0)
+    B = 32
+    for epoch in range(10):
+        perm = np.random.RandomState(epoch).permutation(len(Xtr))
+        for i in range(0, len(Xtr), B):
+            idx = perm[i:i + B]
+            loss, _ = model(paddle.to_tensor(Xtr[idx]),
+                            labels=paddle.to_tensor(ytr[idx]))
+            loss.backward()
+            opt.step()
+            opt.clear_grad()
+    model.eval()
+    return (model,) + _sentiment_corpus(128, 1)
+
+
+def _accuracy(model, X, y):
+    return (np.asarray(model(paddle.to_tensor(X)).numpy()).argmax(-1)
+            == y).mean()
+
+
 class TestClassificationGate:
-    def test_bert_style_finetune_accuracy(self):
-        """The SST-2 fine-tune path (model + CE loss + AdamW + scheduler)
-        must reach >= 90% on the separable synthetic dev set."""
-        from paddle_tpu.models.bert import (BertForSequenceClassification,
-                                            bert_tiny_config)
-        paddle.seed(0)
-        cfg = bert_tiny_config(vocab_size=64, hidden_size=64,
-                               num_hidden_layers=2, num_attention_heads=4,
-                               intermediate_size=128,
-                               max_position_embeddings=32, num_labels=2)
-        model = BertForSequenceClassification(cfg)
-        opt = paddle.optimizer.AdamW(learning_rate=1e-3,
-                                     parameters=list(model.parameters()))
-        Xtr, ytr = _sentiment_corpus(512, 0)
-        Xdev, ydev = _sentiment_corpus(128, 1)
-        B = 32
-        for epoch in range(10):
-            perm = np.random.RandomState(epoch).permutation(len(Xtr))
-            for i in range(0, len(Xtr), B):
-                idx = perm[i:i + B]
-                loss, _ = model(paddle.to_tensor(Xtr[idx]),
-                                labels=paddle.to_tensor(ytr[idx]))
-                loss.backward()
-                opt.step()
-                opt.clear_grad()
-        model.eval()
-        logits = model(paddle.to_tensor(Xdev))
-        pred = np.asarray(logits.numpy()).argmax(-1)
-        acc = (pred == ydev).mean()
+    def test_bert_style_finetune_accuracy(self, finetuned):
+        """The fine-tuned model must reach >= 92% on the separable
+        synthetic dev set."""
+        acc = _accuracy(*finetuned)
         assert acc >= 0.92, f"classification gate: dev acc {acc:.3f}"
+
+
+class TestPTQAccuracyGate:
+    def test_bert_gate_survives_ptq_int8(self, finetuned):
+        """PTQ weight-only-int8 must not break the classification gate:
+        quantized accuracy within 2 points of the fp32 model's."""
+        model, Xdev, ydev = finetuned
+        fp_acc = _accuracy(model, Xdev, ydev)
+        model = copy.deepcopy(model)
+        ptq = PTQ(QuantConfig(activation=HistObserver))
+        ptq.quantize(model)
+        model(paddle.to_tensor(Xdev[:64]))       # calibration pass
+        ptq.convert(model)
+        q_acc = _accuracy(model, Xdev, ydev)
+        assert len(ptq.observers) > 0
+        assert q_acc >= fp_acc - 0.02, (q_acc, fp_acc)
+        assert q_acc >= 0.90, q_acc

@@ -67,7 +67,7 @@ class TestRunPretrainCLI:
         # uninterrupted reference
         cfg_ref, ref_cfg = _cfg(tmp_path, "ref")
         r = subprocess.run(_cmd(cfg_ref), env=env, cwd=REPO,
-                           capture_output=True, text=True, timeout=420)
+                           capture_output=True, text=True, timeout=300)
         assert r.returncode == 0, (r.stdout, r.stderr)
         ref = _losses(ref_cfg["output_dir"])
         assert len(ref) == 10 and all(v == v for v in ref.values())
@@ -103,7 +103,7 @@ class TestRunPretrainCLI:
 
         # resume with the SAME command: must continue from the checkpoint
         r2 = subprocess.run(_cmd(cfg_k), env=env, cwd=REPO,
-                            capture_output=True, text=True, timeout=420)
+                            capture_output=True, text=True, timeout=300)
         assert r2.returncode == 0, (r2.stdout, r2.stderr)
         assert "resumed from ckpt_step" in r2.stdout, r2.stdout
         got = _losses(k_cfg["output_dir"])

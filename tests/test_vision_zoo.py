@@ -1,6 +1,6 @@
 """Vision model zoo, transforms and datasets (SURVEY §2.2 vision).  The
-PP-OCR det/rec tests are in test_vision_ocr.py: a file is one worker's
-unit of work under `--dist loadfile`."""
+PP-OCR det/rec tests are in test_quality_gate_ocr.py, at the gates' shapes:
+a file is one worker's unit of work under `--dist loadfile`."""
 
 import numpy as np
 import jax.numpy as jnp

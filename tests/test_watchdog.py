@@ -128,6 +128,31 @@ def test_hang_detected_within_timeout(tmp_path, monkeypatch):
     assert d["records"][0]["status"] == "timeout"
 
 
+def test_timeout_error_names_a_dump_held_back(tmp_path, monkeypatch):
+    """`cancelled` is published after the dump: a wait site that polls it
+    while another thread (the monitor) is still writing the dump raises
+    an error that names the dump, not one built before it existed."""
+    monkeypatch.setenv("PADDLE_LOG_DIR", str(tmp_path))
+    wd.set_recording(True)
+    rec = wd.start_record("all_reduce")
+    real = wd.recorder().dump_to
+
+    def held_back(*a, **k):
+        time.sleep(0.2)
+        return real(*a, **k)
+    monkeypatch.setattr(wd.recorder(), "dump_to", held_back)
+    t = threading.Thread(target=wd.handle_timeout, args=(rec,))
+    t.start()
+    try:
+        with pytest.raises(wd.CollectiveTimeout) as ei:
+            wd.simulate_hang("all_reduce", 5.0)
+    finally:
+        t.join()
+        wd.end_record(rec, "timeout")
+    assert ei.value.dump_path == str(tmp_path / "flightdump.0.json")
+    assert os.path.exists(ei.value.dump_path)
+
+
 def test_unguarded_hang_is_bounded_by_ms():
     # watchdog off: the injected hang still returns after ms, not forever
     res.set_fault_spec("seed=1;collective_hang@collective=all_reduce:ms=50")
