@@ -108,13 +108,17 @@ _H_E2E = registry().histogram(
 
 
 #: the counts of one step record, taken by the engine where the work
-#: happens (docs/OBSERVABILITY.md says what each one is)
+#: happens (docs/OBSERVABILITY.md says what each one is). The last,
+#: `rows_computed`: the flat rows the retired launch computed, beside
+#: the rows it owned (`decode_rows` + `prefill_rows`) — the step's full
+#: row count where it carried a prompt's chunk, the decode rows' alone
+#: where no prompt was being dispatched
 STEP_COUNTS: Tuple[str, ...] = (
     "decode_rows", "prefill_rows", "live", "waiting", "admitted",
     "finished", "preempted", "cow_pages", "pools_in_place", "pages_live",
     "pages_visited", "pool_pages_used", "pool_pages_total",
     "launch_ahead", "rows_dropped", "append_runs", "attn_block_visits",
-    "attn_narrow_updates")
+    "attn_narrow_updates", "rows_computed")
 #: more counts where a model keeps two kinds of cache (full layers and
 #: sliding-window layers; the plain `pages_*` / `pool_pages_*` are then
 #: the sum of both kinds) ...

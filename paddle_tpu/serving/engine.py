@@ -8,7 +8,7 @@ leave the instant they hit EOS/max-tokens (their pages return to the
 pool immediately), and never retrace — one compile per (model-config,
 slot-count) pair, checked by the PT002-gated tests.
 
-The engine has ONE step program, the unified ragged step: ONE launch
+The engine has ONE step body, the unified ragged step: ONE launch
 per step. Every decode slot's token and the oldest prefill request's
 chunk ride a single flat token buffer through ONE per-layer chain, the
 same at every width and on every backend: norm -> q / k / v
@@ -29,6 +29,15 @@ kernel cannot tile on a TPU (`_ragged_step_eligible`: a head width that
 is neither 64 nor a multiple of 128) is refused at construction with a
 `ValueError` that names it; no constructor argument selects a program.
 
+A launch computes the rows it carries. The body is compiled at two row
+counts: `max_slots x (1 + spec_k) + prefill_chunk` flat rows, and the
+same tables' prefix of `max_slots x (1 + spec_k)` rows with no chunk
+part (`_step_programs`). `_unified_step` launches the second whenever no
+prompt is being dispatched in the launch it builds — which the host
+knows a launch ahead, like every other table — so a decode-only launch
+pays for no idle chunk row: at a few hundred rows the layers' matmuls
+are bound by the rows computed, not by the weights' bytes.
+
 Inactive slots point their whole page table at the allocator's trash
 page 0 with num_tokens 0: the step writes their (garbage) K/V into the
 trash page and their logits are ignored on the host.
@@ -39,7 +48,7 @@ result of launch k-1, so the device finds its next program queued the
 moment the last one ends and the host's admit / build / launch /
 sample / account run beside a device step instead of between two. The
 greedy token of every logits row is taken on the device and a decode
-row of launch k is fed from there (`_jit_feed`); the host reads launch
+row of launch k is fed from there (the `feed` programs); the host reads launch
 k-1's tokens after it has queued launch k. What `step()` returns and
 what a `Request` shows (`tokens`, `prefill_pos`) is always RETIRED
 work: results the host holds.
@@ -51,6 +60,7 @@ greedy property; sampling strategies belong to the batch APIs.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional
 
 import jax
@@ -223,6 +233,31 @@ def _pad_lanes(x, width: int):
     return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
 
 
+@functools.lru_cache(maxsize=None)
+def _kernel_jit(fn, scope: str, static):
+    def run(*args):
+        with jax.named_scope(scope):
+            return fn(*args, **dict(static))
+    return jax.jit(run)
+
+
+def _once(fn, scope: str, *args, **static):
+    """``fn(*args, **static)`` under the name `scope`, inside a jitted
+    copy of ``fn`` that every layer of a step — and every step program
+    of the process — shares: a kernel is traced and lowered ONCE for
+    equal shapes, not once a layer, and that is most of what a step
+    program's first launch costs when the compile cache answers
+    (`ragged_paged_attention` and `fused_append_rows` do the same
+    themselves). XLA inlines the call: the compiled step is the one the
+    plain call gives (`tests/test_tpu_aot_compile.py` compiles both).
+    The name is opened here, around the call, and again inside the
+    shared copy (static, innermost): the instructions answer to `scope`
+    whichever layer's call was lowered first. `static` are ``fn``'s
+    keyword arguments that are not arrays."""
+    with jax.named_scope(scope):
+        return _kernel_jit(fn, scope, tuple(sorted(static.items())))(*args)
+
+
 # -- the step's entry and exit, shared by the jitted bodies ------------
 def _seq_starts(B: int, R: int):
     """[B + 1] baked row starts of the unified step: decode slot s owns
@@ -309,8 +344,8 @@ def _latent_mixer(L, h, rope, pool, seq_start, num_tokens, kv_lengths,
     w_k, w_v = wkb[:, :dn], wkb[:, dn:]
     with jax.named_scope("mla_q"):
         if "wqa" in L or "wqa_q" in L or "wqa_q4" in L:
-            q = _mm_heads(fused_rms_norm(_mm_w(h, L, "wqa"),
-                                         L["gq"], eps),
+            q = _mm_heads(_once(fused_rms_norm, "mla_q",
+                                _mm_w(h, L, "wqa"), L["gq"], eps=eps),
                           L, "wqb")
         else:
             q = _mm_heads(h, L, "wq")
@@ -322,7 +357,8 @@ def _latent_mixer(L, h, rope, pool, seq_start, num_tokens, kv_lengths,
             jnp.concatenate([q_eff, q_pe], -1)[0], width)
     with jax.named_scope("mla_kv"):
         kv_a = _mm_w(h, L, "wkva")           # [1, T, r+dr]
-        lat = fused_rms_norm(kv_a[..., :r], L["gkv"], eps)
+        lat = _once(fused_rms_norm, "mla_kv", kv_a[..., :r], L["gkv"],
+                    eps=eps)
         k_pe = rope(kv_a[..., r:][:, :, None, :])[:, :, 0]
         rows = _pad_lanes(
             jnp.concatenate([lat, k_pe], -1)[0], width)
@@ -399,7 +435,8 @@ class _HyperResidual(_Residual):
     def feed(self, x, hc=None):
         n = self.n
         with jax.named_scope("mhc_pre"):
-            a, coef = mhc_pre(x, hc["phi_t"], hc["ab"], **self.knobs)
+            a, coef = _once(mhc_pre, "mhc_pre", x, hc["phi_t"], hc["ab"],
+                            **self.knobs)
             # column j's sum: lanes n + i n + j over the rows i, slices
             # added elementwise (ONE reduction, at the step's end)
             cols = sum(coef[:, n + i * n:n + (i + 1) * n]
@@ -408,8 +445,7 @@ class _HyperResidual(_Residual):
         return a[None], coef
 
     def leave(self, x, y, keep=None):
-        with jax.named_scope("mhc_post"):
-            return mhc_post(x, y[0], keep, n=self.n)
+        return _once(mhc_post, "mhc_post", x, y[0], keep, n=self.n)
 
     def exit(self, x):
         with jax.named_scope("mhc_merge"):
@@ -453,7 +489,8 @@ class _Launch:
 #: launches (a retire forced between two steps, then the step's own);
 #: of the others the record keeps the later launch's
 _ADDITIVE = frozenset(
-    ("decode_rows", "prefill_rows", "append_runs", "rows_dropped",
+    ("decode_rows", "prefill_rows", "rows_computed", "append_runs",
+     "rows_dropped",
      "pages_live", "pages_visited", "attn_block_visits",
      "attn_narrow_updates")
     + _tracing.STEP_COUNTS_BY_KIND[:4]
@@ -673,15 +710,16 @@ class ServingEngine:
                 f"prediction block, which is not loaded; n-gram drafts "
                 f"through the wide stream are not measured (ROADMAP R9); "
                 f"spec_decode must be 0")
-        rows = self.max_slots + self.prefill_chunk
-        if self._hc > 1 and not _mhc_step_eligible(
-                rows, self._hc, cfg.hidden_size):
-            raise ValueError(
-                f"the hyper-connection kernels do not tile a launch of "
-                f"{rows} rows (max_slots + prefill_chunk) of {self._hc} "
-                f"streams of width {cfg.hidden_size} on this backend: "
-                f"the rows must be whole blocks of 128 and a stream "
-                f"whole 128-lane registers")
+        for rows in (self.max_slots + self.prefill_chunk,
+                     self.max_slots) if self._hc > 1 else ():
+            if not _mhc_step_eligible(rows, self._hc, cfg.hidden_size):
+                raise ValueError(
+                    f"the hyper-connection kernels do not tile a launch of "
+                    f"{rows} rows (max_slots + prefill_chunk with a chunk, "
+                    f"max_slots without) of {self._hc} "
+                    f"streams of width {cfg.hidden_size} on this backend: "
+                    f"the rows must be whole blocks of 128 and a stream "
+                    f"whole 128-lane registers")
         self.prefix_sharing = bool(prefix_sharing)
         admission = getattr(config, "_admission", None)
         self._default_deadline_s = getattr(config, "_deadline_s", None)
@@ -790,6 +828,9 @@ class ServingEngine:
         # ragged launch)
         self.spec_k = int(spec_decode)
         self.launches = 0      # device program launches by THIS engine
+        # the flat rows those launches computed, and of them the rows a
+        # sequence owned (`scrape`; the step record's `rows_computed`)
+        self.rows_computed = self.rows_owned = 0
         self.steps = 0         # step() calls: the step timeline's `seq`
         #: set to a callable (request, logits row [vocab]) to be handed
         #: the host copy of the row each emitted token was sampled from
@@ -836,23 +877,7 @@ class ServingEngine:
         # costmodel budget
         self._kv_geom = (kv, d)
         self._kv_itemsize = int(jnp.dtype(dt).itemsize)
-        # the KV heads and the query tiles one page visit of the ragged
-        # kernel serves, and the rows of a tile it computes for a
-        # sequence that owns a few, for each query group size: the
-        # kernel's own choice at the unified step's row count
-        # (`attn_block_visits`, `pages_visited`, `attn_narrow_updates`)
-        T = self.max_slots * (1 + self.spec_k) + self.prefill_chunk
-        latent = self._latent
-        self._head_block, self._tile_block, self._narrow_rows = {}, {}, {}
-        for r in {r for reps in self._kind_rep.values() for r in reps}:
-            tq = ragged_tile_tokens(T, r, dt)
-            self._head_block[r] = hb = ragged_head_block(
-                kv, tq * r, d, self.page_size, self._kv_itemsize,
-                latent=latent)
-            self._tile_block[r] = tb = ragged_tile_block(
-                hb, -(-T // tq), tq * r, d, self.page_size,
-                self._kv_itemsize, cfg.kv_lora_rank if latent else None)
-            self._narrow_rows[r] = ragged_narrow_rows(r, tq * r, dt, tb)
+        self._tiling: Dict[int, dict] = {}      # `_attn_tiling`, by rows
         # the unit of work of the rope + append kernel
         # (`ops.fused.append_run_table`): the rows of one cache tile
         self._append_tile = append_tile(dt, self.page_size)
@@ -940,11 +965,53 @@ class ServingEngine:
             return 7 if qlora else 5
         return 5                # norm + q/k/v dots + rope-append
 
+    def _chunk_parts(self) -> Dict[str, int]:
+        """{suffix of the program names: the chunk part's length} the
+        step body is compiled at: the prefill chunk's rows behind the
+        decode rows (`unified`, `feed`), and none (`unified_nochunk`,
+        `feed_nochunk`)."""
+        return {"": self.prefill_chunk, "_nochunk": 0}
+
+    def _launch_rows(self, chunk: int) -> int:
+        """Flat rows of a launch whose chunk part is `chunk` rows."""
+        return self.max_slots * (1 + self.spec_k) + chunk
+
+    def _attn_tiling(self, T: int) -> Dict[str, Dict[int, int]]:
+        """The KV heads and the query tiles one page visit of the ragged
+        kernel serves, and the rows of a tile it computes for a sequence
+        that owns a few, for each query group size: the kernel's own
+        choice at a launch of `T` flat rows (`attn_block_visits`,
+        `pages_visited`, `attn_narrow_updates`), asked once a row
+        count."""
+        if T in self._tiling:
+            return self._tiling[T]
+        kv, d = self._kv_geom
+        dt, latent = self._q_dtype, self._latent
+        out = {"head_block": {}, "tile_block": {}, "narrow_rows": {}}
+        for r in {r for reps in self._kind_rep.values() for r in reps}:
+            tq = ragged_tile_tokens(T, r, dt)
+            out["head_block"][r] = hb = ragged_head_block(
+                kv, tq * r, d, self.page_size, self._kv_itemsize,
+                latent=latent)
+            out["tile_block"][r] = tb = ragged_tile_block(
+                hb, -(-T // tq), tq * r, d, self.page_size,
+                self._kv_itemsize,
+                self._p["cfg"].kv_lora_rank if latent else None)
+            out["narrow_rows"][r] = ragged_narrow_rows(r, tq * r, dt, tb)
+        self._tiling[T] = out
+        return out
+
     def _build_programs(self) -> None:
         """(Re)build the fixed-shape jitted programs for the CURRENT
         max_slots/prefill_chunk/spec_k. Called once from __init__ and
         again from `reconfigure()` — fresh `jax.jit` objects each time,
         so `program_cache_sizes()` stays at 1 per program (PT002).
+
+        The step body is built at each length of its chunk part
+        (`_chunk_parts`): `unified` carries the prefill chunk's rows
+        behind the decode rows, `unified_nochunk` is the same body over
+        the decode rows alone, for a launch that dispatches no prompt.
+        Each has the token feed of its own row count beside it.
 
         Every step program takes the page pools as argument 2, returns
         the pools that replace them, and OWNS the ones it is handed
@@ -954,31 +1021,43 @@ class ServingEngine:
         instead of a copy of it. So the caller's pools are dead after
         the launch — `_launch` is the one caller."""
         self._programs = self._step_programs()
-        self._jit_unified = self._programs["unified"]
-        # the token feed: EVERY `tok` the unified step sees comes out of
-        # this one program (one type, one sharding, one committed-ness:
-        # `_jit_unified` keeps one cache entry) — row r takes the host's
-        # token where src[r] < 0, else row src[r] of the launch in
-        # flight's greedy tokens, which never leave the device. Compiled
-        # and run ONCE here, on the tokens of no launch
-        self._jit_feed = self._programs["feed"]
-        T = self.max_slots * (1 + self.spec_k) + self.prefill_chunk
+        # the token feed: EVERY `tok` a step program sees comes out of
+        # the feed of its row count (one type, one sharding, one
+        # committed-ness: the step keeps one cache entry) — row r takes
+        # the host's token where src[r] < 0, else row src[r] of the
+        # launch in flight's greedy tokens, which never leave the
+        # device. Compiled and run ONCE here, on the tokens of no launch
         self._no_tokens = jax.jit(lambda: jnp.zeros(
-            T if self.spec_k else self.max_slots + 1, jnp.int32))()
-        self._jit_feed(self._no_tokens, np.zeros(T, np.int32),
-                       np.full(T, -1, np.int32))
+            self._launch_rows(self.prefill_chunk) if self.spec_k
+            else self.max_slots + 1, jnp.int32))()
+        for sfx, chunk in self._chunk_parts().items():
+            T = self._launch_rows(chunk)
+            self._programs["feed" + sfx](
+                self._no_tokens, np.zeros(T, np.int32),
+                np.full(T, -1, np.int32))
         # the copy-on-write program (`_apply_copies`): as many pairs as
         # one sequence's new rows of one step can touch shared pages,
         # compiled and run ONCE here (trash page onto itself) so that
-        # no copy ever compiles inside a serving loop
+        # no copy ever compiles inside a serving loop — where a page can
+        # come to be shared at all (a live donor's fork, a cached
+        # prefix's pin): a cache that refuses both (a window kind, two
+        # page lists, passes, a state) never copies, and does not pay
+        # for the run
         rows = max(self.prefill_chunk, 1 + self.spec_k)
         self._copy_slots = -(-rows // self.page_size) + 1
         self._jit_copy = jax.jit(
             lambda pools, src, dst: jax.tree_util.tree_map(
                 lambda p: p.at[:, dst].set(p[:, src]), pools),
             donate_argnums=0)
-        idle = np.zeros(self._copy_slots, np.int32)
-        self._copy_pages(idle, idle)
+        if self.prefix_sharing or self.prefix_cache is not None:
+            self._copy_pages(*np.zeros((2, self._copy_slots), np.int32))
+
+    @property
+    def _jit_unified(self):
+        """The step program at the full row count, for whoever lowers
+        it (`chip_smoke.py`, `benchmarks/tests`); the engine launches
+        from `_programs`."""
+        return self._programs["unified"]
 
     def _copy_pages(self, src, dst) -> None:
         """Pages `src` copied onto pages `dst` in every page pool (a
@@ -992,11 +1071,20 @@ class ServingEngine:
 
     def _step_programs(self) -> Dict[str, object]:
         """{name: a FRESH `jax.jit`} of the step programs at the current
-        max_slots/prefill_chunk/spec_k."""
-        return {"unified": jax.jit(self._make_unified_body(),
-                                   donate_argnums=2),
-                "feed": jax.jit(lambda prev, tok, src: jnp.where(
-                    src < 0, tok, prev[jnp.maximum(src, 0)]))}
+        max_slots/prefill_chunk/spec_k: the one body and its token feed
+        at each length of the chunk part."""
+        parts = self._chunk_parts()
+
+        def step(sfx):
+            return jax.jit(self._make_unified_body(parts[sfx]),
+                           donate_argnums=2)
+
+        def feed():
+            return jax.jit(lambda prev, tok, src: jnp.where(
+                src < 0, tok, prev[jnp.maximum(src, 0)]))
+
+        return {"unified": step(""), "feed": feed(),
+                "unified_nochunk": step("_nochunk"), "feed_nochunk": feed()}
 
     def _live_pools(self):
         """The page pools, for whoever reads or replaces them between
@@ -1333,7 +1421,7 @@ class ServingEngine:
             # a residual of several streams a token: what the step's
             # flat buffer holds of it between two sublayers
             "residual_stream_bytes": float(
-                (self.max_slots * (1 + self.spec_k) + self.prefill_chunk)
+                self._launch_rows(self.prefill_chunk)
                 * self._stream_row_bytes),
             "ledger_bytes": float(self._ledger_bytes),
             "ledger_tokens": int(self._ledger_tokens),
@@ -1347,20 +1435,23 @@ class ServingEngine:
         # KV heads and query tiles a page visit of the ragged kernel
         # serves, and the rows it computes for a sequence that owns a
         # few of a tile's (0: always the tile's), by layer kind (the
-        # fewest over the kind's head counts)
+        # fewest over the kind's head counts), at the row count of a
+        # launch with a chunk
         for k, reps in self._kind_rep.items():
-            for name, choice in (("attn_head_block", self._head_block),
-                                 ("attn_tile_block", self._tile_block),
-                                 ("attn_narrow_rows", self._narrow_rows)):
-                acct[name + (".window" if k else "")] = \
+            for name, choice in self._attn_tiling(
+                    self._launch_rows(self.prefill_chunk)).items():
+                acct["attn_" + name + (".window" if k else "")] = \
                     float(min(choice[r] for r in reps))
         return acct
 
     def program_cache_sizes(self) -> Dict[str, int]:
         """{program name: compiled-variant count} for this engine's
-        jitted programs, {"unified": n, "feed": n} — the PT002
-        no-retrace guard's hook. Every count must stay at 1 after any
-        join/leave pattern."""
+        jitted programs, {"unified": n, "feed": n, "unified_nochunk":
+        n, "feed_nochunk": n} — the PT002 no-retrace guard's hook. Every
+        count must stay at 1 after any join/leave pattern (a step
+        program counts 0 until its first launch: `unified_nochunk` on an
+        engine that has not decoded yet, or never does —
+        ``role="prefill"``)."""
         return {name: fn._cache_size()
                 for name, fn in self._programs.items()}
 
@@ -1368,23 +1459,32 @@ class ServingEngine:
         """{program name: its `jax.stages.Compiled`} of this engine's
         step programs, for whoever reads a trace of them afterwards
         (`observability.attribution.op_scopes`). Each is lowered again
-        at the shapes its launches have — an idle launch's row tables,
-        the weights and pools as they stand — and compiled, which the
+        at the shapes its launches have — an idle launch's row tables
+        at the program's own row count, the weights and pools as they
+        stand — and compiled, which the
         compile cache answers where it answered the step; nothing is
         launched and no pool is taken. Called by no part of the engine:
         a run that does not ask pays nothing."""
-        def shaped(a):
-            return jax.ShapeDtypeStruct(np.shape(a), a.dtype)
-
-        pools = self._live_pools()
-        host, src, *_ = self._build_unified(None, [], None)
-        rest = jax.tree_util.tree_map(shaped, host[1:])
-        args = {"unified": (self._w, shaped(host[0]), pools, *rest),
-                "feed": (shaped(self._no_tokens), shaped(host[0]),
-                         shaped(src))}
+        args = {}
+        for sfx, chunk in self._chunk_parts().items():
+            args["unified" + sfx], args["feed" + sfx] = \
+                self._program_shapes(chunk)
         return {name: compile_named(
                     fn, args[name], lambda n=name: self._step_programs()[n])
                 for name, fn in self._programs.items()}
+
+    def _program_shapes(self, chunk: int):
+        """(the step program's arguments, its token feed's) at a chunk
+        part of `chunk` rows, in shapes: an idle launch's row tables,
+        the pools as they stand; the weights themselves."""
+        def shaped(a):
+            return jax.ShapeDtypeStruct(np.shape(a), a.dtype)
+
+        host, src, *_ = self._build_unified(None, [], None, chunk)
+        tok, *rest = jax.tree_util.tree_map(shaped, host)
+        pools = jax.tree_util.tree_map(shaped, self._live_pools())
+        return ((self._w, tok, pools, *rest),
+                (shaped(self._no_tokens), tok, shaped(src)))
 
     def collect(self) -> Dict[object, object]:
         """Results of every request finished since the last collect():
@@ -1427,6 +1527,14 @@ class ServingEngine:
                           self.prefix_cache.pages)
         reg.counter("serving.replica.launches",
                     "device program launches").inc(self.launches)
+        reg.counter("serving.replica.rows_computed",
+                    "flat rows the step launches computed: a launch with "
+                    "a prompt's chunk max_slots x (1 + spec_k) + "
+                    "prefill_chunk, one without max_slots x (1 + spec_k)"
+                    ).inc(self.rows_computed)
+        reg.counter("serving.replica.rows_owned",
+                    "of the rows computed, those a sequence owned (decode "
+                    "and draft rows, prompt rows)").inc(self.rows_owned)
         hc = reg.counter("serving.replica.handoffs",
                          "KV-page handoffs by direction",
                          labels=("direction",))
@@ -1759,6 +1867,15 @@ class ServingEngine:
         tell the ragged kernel who owns which rows; idle rows write to
         the trash page and emit garbage logits the host never reads.
 
+        A launch computes the rows it carries: when no prompt is being
+        dispatched (`_next_chunk_request` gives None for THIS launch)
+        the flat buffer ends at row max_slots*R and the launch runs the
+        body compiled at that row count (`unified_nochunk`, with its
+        feed), the prefix of the tables a launch with a chunk takes.
+        The sequence tables keep their one shape, the chunk's sequence
+        empty, so what a launch returns and what retires it do not
+        know which of the two ran (`rows_computed` says).
+
         A launch queue of depth one: this call builds and DISPATCHES
         its launch first and only then reads back the launch the call
         before dispatched (`_await_launch` in the sync phase,
@@ -1768,7 +1885,7 @@ class ServingEngine:
         dispatch: a decode row's position is the sequence's length (the
         allocator is extended at dispatch), its input token is row
         `src` of the launch in flight's greedy tokens and is fed on the
-        device (`_jit_feed`), a request whose token in flight is its
+        device (the `feed` program), a request whose token in flight is its
         `max_new_tokens`-th gets no row, and a prompt advances by
         `_sent_pos`. What cannot be known a launch ahead is seen a
         launch late: an EOS finish leaves one row in the next launch,
@@ -1798,17 +1915,20 @@ class ServingEngine:
         if prev is None and preq is None and not rows:
             return
         new, work = None, preq is not None or bool(rows)
+        # the launch's programs: those of the rows it carries
+        sfx = "" if preq is not None else "_nochunk"
         with _obs.span("serving.engine.build"):
             if work:
                 host, src, drafts, n, start, counts = \
-                    self._build_unified(preq, rows, prev)
+                    self._build_unified(preq, rows, prev,
+                                        self._chunk_parts()[sfx])
         with _obs.span("serving.engine.launch"):
             if work:
-                tok = self._jit_feed(
+                tok = self._programs["feed" + sfx](
                     self._no_tokens if prev is None else prev.tokens,
                     host[0], src)
                 logits, tokens, *moe = self._launch(
-                    self._jit_unified, tok,
+                    self._programs["unified" + sfx], tok,
                     *(jax.tree_util.tree_map(jnp.asarray, t)
                       for t in host[1:]))
                 row_of = {id(req): slot for slot, req, _ in rows}
@@ -1822,6 +1942,8 @@ class ServingEngine:
                     [(slot, req) for slot, req, _ in rows], drafts,
                     row_of, counts)
                 self._counts["launch_ahead"] = int(prev is not None)
+                self.rows_computed += counts["rows_computed"]
+                self.rows_owned += counts["decode_rows"] + n
                 if _obs.enabled():
                     _LAUNCHES.labels(path="unified").inc()
                     _STEPS.labels(phase="unified").inc()
@@ -1869,9 +1991,13 @@ class ServingEngine:
         return rows
 
     def _build_unified(self, preq: Optional[Request], rows,
-                       fl: Optional[_Launch]):
+                       fl: Optional[_Launch], chunk: int):
         """The host half of the unified launch: extend every sequence
-        (applying copy-on-write copies) and fill the row tables.
+        (applying copy-on-write copies) and fill the row tables, T =
+        max_slots x (1 + spec_k) + `chunk` flat rows long: `chunk` is
+        `prefill_chunk` where the launch carries `preq`'s rows and 0
+        where it carries none (`_unified_step`; `compiled_programs`
+        asks for an idle launch's tables at either length).
         Returns ((tok, positions, num_tokens, kv_lengths, tables,
         tok_page, tok_off), src, drafts by slot, prefill rows, their
         start, the launch's counts for the step record that retires
@@ -1885,10 +2011,11 @@ class ServingEngine:
         visible), (`tok_page`, [2, P] pages: where each closing chunk's
         tokens lie and where its pooled row goes), (`tok_off`, [2, P]:
         the chunk within that page, the row within that one)."""
-        B, C, K = self.max_slots, self.prefill_chunk, self.spec_k
+        B, C, K = self.max_slots, chunk, self.spec_k
         R = 1 + K
         base = B * R
         T, S = base + C, B + 1
+        tiling = self._attn_tiling(T)   # the kernel's, at THIS row count
         ps, nj = self.page_size, self.pages_per_seq
         tok = np.zeros(T, np.int32)
         src = np.full(T, -1, np.int32)
@@ -1977,7 +2104,7 @@ class ServingEngine:
             tok[base:base + n] = preq.prompt[start:start + n]
             place(preq.request_id, S - 1, base, start + np.arange(n), B)
         counts = {"decode_rows": int(num_tokens[:B].sum()),
-                  "prefill_rows": n}
+                  "prefill_rows": n, "rows_computed": T}
         seq_start = np.append(np.arange(B) * R, base)
         if self._latent:
             counts["chunk_kv_len"] = int(kv_lengths[S - 1])
@@ -2004,11 +2131,12 @@ class ServingEngine:
                 pages, narrow = ragged_visit_counts(
                     seq_start, num_tokens, kv_lengths, T=T, rep=r,
                     dtype=self._q_dtype, page_size=ps, pages_per_seq=nj,
-                    window=window, tb=1 if tiles else self._tile_block[r])
+                    window=window,
+                    tb=1 if tiles else tiling["tile_block"][r])
                 total += pages
                 if not tiles:
                     counts["attn_block_visits"] += \
-                        pages * self._kv_geom[0] // self._head_block[r]
+                        pages * self._kv_geom[0] // tiling["head_block"][r]
                     # of those updates, the ones on the few rows their
                     # sequence owns (the kernel's rule, once a pair)
                     counts["attn_narrow_updates"] += narrow
@@ -2223,7 +2351,8 @@ class ServingEngine:
     def _apply_copies(self, copies, req: Optional[Request] = None) -> None:
         """Apply the allocator's copy-on-write page copies to the device
         pools before the write that triggered them: the ONE fixed-shape
-        program `_build_programs` built and ran once (`_jit_copy`), so
+        program `_build_programs` built and ran once (`_jit_copy`: where
+        this engine's pages can be shared at all), so
         a copy compiles nothing when it comes — a shared first token of
         two random prompts is enough to bring one — `_copy_slots`
         (source, destination) pairs a call, padded with the trash page
@@ -2243,24 +2372,29 @@ class ServingEngine:
             self._copy_pages(*pairs)
 
     # ----------------------------------------------------- jitted bodies
-    def _make_unified_body(self):
+    def _make_unified_body(self, chunk: int):
+        """The family's step body with a chunk part of `chunk` rows
+        behind the decode rows (`_chunk_parts`)."""
         if self._family == "eva":
-            return self._eva_unified_body()
+            return self._eva_unified_body(chunk)
         if self._family == "hybrid":
-            return self._hybrid_unified_body()
+            return self._hybrid_unified_body(chunk)
         if self._family == "looped":
-            return self._looped_unified_body()
+            return self._looped_unified_body(chunk)
         if self._family == "gpt":
-            return self._gpt_unified_body()
+            return self._gpt_unified_body(chunk)
         if self._family == "mla":
-            return self._mla_unified_body()
-        return self._llama_unified_body()
+            return self._mla_unified_body(chunk)
+        return self._llama_unified_body(chunk)
 
     # -- unified ragged step -------------------------------------------
-    # One fused launch per engine step: T = max_slots + prefill_chunk
-    # flat token rows, S = max_slots + 1 sequences with BAKED seq_start
-    # [0..B-1, B] (decode slot i owns row i; the prefill chunk owns rows
-    # B..B+n-1). The ragged kernel cuts those rows into tiles of TQ
+    # One fused launch per engine step: T = max_slots x (1 + spec_k) + C
+    # flat token rows — C the chunk part's length, `prefill_chunk` or 0,
+    # the builders' one argument — and S = max_slots + 1 sequences with
+    # BAKED seq_start [0..B-1, B] (decode slot i owns row i; the prefill
+    # chunk owns rows B..B+n-1; with C = 0 the last sequence is empty
+    # and starts where the buffer ends). The ragged kernel cuts those
+    # rows into tiles of TQ
     # tokens and walks, for each tile, only the live pages of the
     # sequences with rows in it (decode slots share a tile; the chunk
     # spans several and refetches its pages once a tile); its work list
@@ -2278,15 +2412,16 @@ class ServingEngine:
         """(num_tokens, tok_page, tok_off) -> the work list of the
         step's `fused_rope_append` calls, made on the device from the
         row tables the step already takes. Its length is the most runs
-        a launch can make: every decode row its own, the chunk's one
-        for each tile it touches."""
+        a launch of the tables' row count can make: every decode row
+        its own, the chunk's one for each tile it touches."""
         tile = self._append_tile
-        bound = (self.max_slots * (1 + self.spec_k)
-                 + -(-self.prefill_chunk // tile) + 1)
+        base = self.max_slots * (1 + self.spec_k)
 
         def run_table(num_tokens, tok_page, tok_off):
-            return append_run_table(seq_start, num_tokens, tok_page,
-                                    tok_off, tile=tile, max_runs=bound)
+            chunk = tok_page.shape[0] - base
+            return append_run_table(
+                seq_start, num_tokens, tok_page, tok_off, tile=tile,
+                max_runs=base + -(-chunk // tile) + 1)
 
         return run_table
 
@@ -2297,19 +2432,19 @@ class ServingEngine:
         a run, and one for each tile the chunk's C / chunk consecutive
         rows of a summary page touch."""
         tile = self._append_tile
-        chunks = self.prefill_chunk // self.allocator.chunk
+        chunks = pool_page.shape[0] - self.max_slots
         return append_slot_run_table(
             pool_page, pool_off, tile=tile,
             max_runs=self.max_slots + -(-chunks // tile) + 1)
 
-    def _llama_unified_body(self):
+    def _llama_unified_body(self, C: int):
         cfg = self._p["cfg"]
         KV, D = cfg.num_key_value_heads, cfg.head_dim
         eps = cfg.rms_norm_eps
         moe_static = self._p.get("moe_static")
         attn_static = self._attn_static
         count_moe = _tracing.STEP_COUNTS_MOE[0] in self._count_names
-        B, C, K = self.max_slots, self.prefill_chunk, self.spec_k
+        B, K = self.max_slots, self.spec_k
         R = 1 + K
         T = B * R + C
         seq_start = _seq_starts(B, R)
@@ -2343,15 +2478,15 @@ class ServingEngine:
                 # physical pages its new rows land in
                 kind = int(window is not None)
                 table = tables[kind]
-                with _scope("attn_norm"):
-                    h = fused_rms_norm(x, L["ln1"], eps)
+                h = _once(fused_rms_norm, "attn_norm", x, L["ln1"], eps=eps)
                 with _scope("qkv_proj"):
                     q, k, v = (_mm_heads(h, L, w)
                                for w in ("wq", "wk", "wv"))
                     if "bq" in L:
                         q, k, v = q + L["bq"], k + L["bk"], v + L["bv"]
                 with _scope("cache_write"):
-                    q, kp, vp = fused_rope_append(
+                    q, kp, vp = _once(
+                        fused_rope_append, "cache_write",
                         q.reshape(T, Hh, D), k.reshape(T, KV, D),
                         v.reshape(T, KV, D), c, s, kp, vp, runs[kind])
                 new_pools.append((kp, vp))
@@ -2367,11 +2502,10 @@ class ServingEngine:
                         g = jax.nn.sigmoid(_mm_w(h, L, "wgate"))[0]
                         o = o * g[..., None].astype(o.dtype)
                     x = x + _mm_w(o.reshape(1, T, Hh * D), L, "wo")
-                with _scope("ffn_norm"):
-                    h2 = fused_rms_norm(x, L["ln2"], eps)
+                h2 = _once(fused_rms_norm, "ffn_norm", x, L["ln2"], eps=eps)
                 x = x + _ffn_apply(L, h2, st, moe_stats, live)
             with _scope("head"):
-                x = fused_rms_norm(x, w["norm"], eps)
+                x = _once(fused_rms_norm, "head", x, w["norm"], eps=eps)
                 logits = _head_logits(
                     w, _logit_rows(x, seq_start, num_tokens, K))
                 tokens = _greedy(logits)
@@ -2382,7 +2516,7 @@ class ServingEngine:
 
         return step
 
-    def _eva_unified_body(self):
+    def _eva_unified_body(self, C: int):
         """Chunk-summary (EVA) attention on the one chain, a float32
         residual stream. Per layer: norm (gain 1 + g) -> q / k / v ->
         `fused_rope_append` into the window's pages -> `eva_pool`: every
@@ -2397,7 +2531,7 @@ class ServingEngine:
         H, D, V = cfg.num_attention_heads, cfg.head_dim, cfg.vocab_size
         eps, ck = cfg.rms_norm_eps, cfg.chunk_size
         scale = D ** -0.5
-        B, C = self.max_slots, self.prefill_chunk
+        B = self.max_slots
         T = B + C
         seq_start = _seq_starts(B, 1)
         run_table = self._run_table(seq_start)
@@ -2431,12 +2565,13 @@ class ServingEngine:
                 # `eva_pool` / `eva_attention` stay the kernels' own
                 # (innermost) names: the trace's readers find them so
                 with _scope("cache_write"):
-                    q, kp, vp = fused_rope_append(q, k, v, c, s, kp, vp,
-                                                  runs)
+                    q, kp, vp = _once(fused_rope_append, "cache_write",
+                                      q, k, v, c, s, kp, vp, runs)
                     with jax.named_scope("eva_pool"):
-                        kt, vt = fused_chunk_pool(
-                            kp, vp, L["phi"], L["mu"], pool_page[0],
-                            pool_off[0], chunk=ck, scale=scale)
+                        kt, vt = _once(
+                            fused_chunk_pool, "eva_pool", kp, vp, L["phi"],
+                            L["mu"], pool_page[0], pool_off[0], chunk=ck,
+                            scale=scale)
                         kp, vp = fused_append_rows(
                             (kp, vp), (kt, vt), pool_runs, scope="eva_pool")
                 new_pools.append((kp, vp))
@@ -2464,7 +2599,7 @@ class ServingEngine:
 
         return step
 
-    def _looped_unified_body(self):
+    def _looped_unified_body(self, C: int):
         """A looped decoder on the one chain: the layer list runs
         `total_ut_steps` times inside the launch over weights held once.
         Pass u of layer l keeps its own cache rows: page p of the row
@@ -2483,7 +2618,7 @@ class ServingEngine:
         H, KV, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
                     cfg.head_dim)
         eps, U, N = cfg.rms_norm_eps, self._passes, self.num_pages
-        B, C = self.max_slots, self.prefill_chunk
+        B = self.max_slots
         T = B + C
         seq_start = _seq_starts(B, 1)
         run_table = self._run_table(seq_start)
@@ -2563,7 +2698,7 @@ class ServingEngine:
 
         return step
 
-    def _hybrid_unified_body(self):
+    def _hybrid_unified_body(self, C: int):
         """A hybrid (Nemotron-H, Ling 3.0) on the one launch: block l is
         `x + mixer_l(RMSNorm(x))` with ONE mixer, of the kind the
         model's pattern names (a Ling layer is two blocks).
@@ -2608,7 +2743,7 @@ class ServingEngine:
         cfg, pattern = self._p["cfg"], self._p["pattern"]
         eps, K = cfg.layer_norm_epsilon, cfg.conv_kernel
         moe_static = self._p["moe_static"]
-        B, C = self.max_slots, self.prefill_chunk
+        B = self.max_slots
         T = B + C
         seq_start = _seq_starts(B, 1)
         run_table = self._run_table(seq_start)
@@ -2627,6 +2762,15 @@ class ServingEngine:
                           width=self._kv_geom[1], eps=eps,
                           scale=cfg.softmax_scale)
 
+        def with_spare(m):
+            """A launch's operand m [T, ...] with a row B, the spare
+            slot's: the chunk's first row, which no live slot reads,
+            or — no chunk part — a row of zeros behind the decode
+            rows."""
+            if C:
+                return m
+            return jnp.pad(m, ((0, 1),) + ((0, 0),) * (m.ndim - 1))
+
         def conv_tails(u, t_pool, conv_w, conv_b, live, n_c, cslot, starts):
             """The causal convolution of the launch's rows u [T, W], a
             decode row from its slot's tail, the chunk's rows from its
@@ -2638,6 +2782,9 @@ class ServingEngine:
             ext = jnp.concatenate([tails, u[:B, None]], 1)
             conv_d = jax.vmap(ssm_conv, (0, None, None))(
                 ext, conv_w, conv_b)[:, 0]
+            if not C:       # no chunk part: the decode rows' tails alone
+                return conv_d, t_pool.at[:B].set(
+                    jnp.where(live[:, None, None], ext[:, 1:], tails))
             # the chunk: its slot's tail (zeros at a start), its rows
             tail_c = jnp.where(starts, 0, t_pool[cslot])
             ext_c = jnp.concatenate([tail_c, u[B:]])
@@ -2650,10 +2797,11 @@ class ServingEngine:
                 (cslot, 0, 0))
             return jnp.concatenate([conv_d, conv_c]), t_pool
 
-        def chunk_state(z_pool, n_c, cslot, starts, scan, y_shape):
+        def chunk_state(z_pool, n_c, cslot, starts, scan, y_shape, scope):
             """The chunk's rows through ``scan(state it starts from)``
             -> (y, the state it leaves), where the launch has a chunk,
-            and that state put back in its slot in place."""
+            and that state put back in its slot in place (under the
+            caller's name `scope`)."""
             def run():
                 return scan(jax.lax.cond(
                     starts, lambda: jnp.zeros(z_pool.shape[1:], f32),
@@ -2664,8 +2812,9 @@ class ServingEngine:
                 n_c > 0, run,
                 lambda: (jnp.zeros(y_shape, f32),
                          jnp.zeros(z_pool.shape[1:], f32)))
-            return y_c, ssm_state_put(
-                z_pool, jnp.stack([cslot, (n_c > 0).astype(jnp.int32)]), s1)
+            return y_c, _once(
+                ssm_state_put, scope, z_pool,
+                jnp.stack([cslot, (n_c > 0).astype(jnp.int32)]), s1)
 
         def ssm(L, a, z_pool, t_pool, num_tokens, tab):
             """The state-space mixer of a [T, hidden] -> (its output [T,
@@ -2686,25 +2835,29 @@ class ServingEngine:
                 # the decode rows: one step each, the state in place
                 # (row s of the operands is slot s; row B the spare's)
                 rows = slice(0, B + 1)
+                xs, dts, dAs = with_spare(xf), with_spare(dt), with_spare(dA)
                 heads = lambda m: jnp.repeat(          # noqa: E731
-                    m[rows], Hm // G, axis=1).swapaxes(1, 2)
-                y_d, z_pool = ssm_state_update(
-                    z_pool, slots, n_live,
-                    (xf[rows] * dt[rows, :, None]).swapaxes(1, 2),
-                    jnp.exp(dA[rows])[:, None, :], heads(bm), heads(cm))
-                y_d = jnp.where(live[:, None, None],
-                                y_d[:B].swapaxes(1, 2), 0)
-                # the chunk: a scan from its slot's state, where there
-                # is one; rows past its length are the identity
-                valid = (jnp.arange(C) < n_c)[:, None]
-                dt_c = jnp.where(valid, dt[B:], 0)
-                y_c, z_pool = chunk_state(
-                    z_pool, n_c, cslot, starts, lambda s0: ssm_chunk_scan(
-                        xf[B:] * dt_c[..., None],
-                        jnp.where(valid, dA[B:], 0), bm[B:], cm[B:], s0,
-                        chunk=cfg.chunk_size), (C, Hm, P))
-                y = jnp.concatenate([y_d, y_c]) \
-                    + L["D"].astype(f32)[None, :, None] * xf
+                    with_spare(m)[rows], Hm // G, axis=1).swapaxes(1, 2)
+                y_d, z_pool = _once(
+                    ssm_state_update, "ssm_scan", z_pool, slots, n_live,
+                    (xs[rows] * dts[rows, :, None]).swapaxes(1, 2),
+                    jnp.exp(dAs[rows])[:, None, :], heads(bm), heads(cm))
+                y = jnp.where(live[:, None, None],
+                              y_d[:B].swapaxes(1, 2), 0)
+                if C:
+                    # the chunk: a scan from its slot's state, where
+                    # there is one; rows past its length are the identity
+                    valid = (jnp.arange(C) < n_c)[:, None]
+                    dt_c = jnp.where(valid, dt[B:], 0)
+                    y_c, z_pool = chunk_state(
+                        z_pool, n_c, cslot, starts,
+                        lambda s0: _once(
+                            ssm_chunk_scan, "ssm_scan",
+                            xf[B:] * dt_c[..., None],
+                            jnp.where(valid, dA[B:], 0), bm[B:], cm[B:], s0,
+                            chunk=cfg.chunk_size), (C, Hm, P), "ssm_scan")
+                    y = jnp.concatenate([y, y_c])
+                y = y + L["D"].astype(f32)[None, :, None] * xf
             with jax.named_scope("ssm_out"):
                 y = ssm_gated_norm(y.reshape(T, Hm * P), z, L["norm_g"], G,
                                    eps).astype(dt_w)
@@ -2729,23 +2882,31 @@ class ServingEngine:
                 # the decode rows: one step each, the state in place
                 # (row s of the operands is slot s; row B the spare's)
                 rows = slice(0, B + 1)
-                o_d, z_pool = kda_state_update(
-                    z_pool, slots, n_live, q[rows], k[rows], v[rows],
-                    g[rows], beta[rows, :, None])
-                o_d = jnp.where(live[:, None, None], o_d[:B], 0)
-            with jax.named_scope("kda_chunk_scan"):
-                # the chunk: a scan from its slot's state, where there
-                # is one; rows past its length are the identity
-                valid = (jnp.arange(C) < n_c)[:, None]
-                o_c, z_pool = chunk_state(
-                    z_pool, n_c, cslot, starts, lambda s0: kda_chunk_scan(
-                        q[B:], k[B:], v[B:],
-                        jnp.where(valid[..., None], g[B:], 0),
-                        jnp.where(valid, beta[B:], 0), s0,
-                        chunk=cfg.kda_sub_chunk), (C, Hk, Dk))
+                qs, ks, vs, gs, bs = (with_spare(m)
+                                      for m in (q, k, v, g, beta))
+                o, z_pool = _once(
+                    kda_state_update, "kda_state_update", z_pool, slots,
+                    n_live, qs[rows], ks[rows], vs[rows], gs[rows],
+                    bs[rows, :, None])
+                o = jnp.where(live[:, None, None], o[:B], 0)
+            if C:
+                with jax.named_scope("kda_chunk_scan"):
+                    # the chunk: a scan from its slot's state, where
+                    # there is one; rows past its length are the identity
+                    valid = (jnp.arange(C) < n_c)[:, None]
+                    o_c, z_pool = chunk_state(
+                        z_pool, n_c, cslot, starts,
+                        lambda s0: _once(
+                            kda_chunk_scan, "kda_chunk_scan",
+                            q[B:], k[B:], v[B:],
+                            jnp.where(valid[..., None], g[B:], 0),
+                            jnp.where(valid, beta[B:], 0), s0,
+                            chunk=cfg.kda_sub_chunk), (C, Hk, Dk),
+                        "kda_chunk_scan")
+                    o = jnp.concatenate([o, o_c])
             with jax.named_scope("kda_out"):
-                y = kda_gated_norm(jnp.concatenate([o_d, o_c]), gate,
-                                   L["norm_g"], eps).astype(a.dtype)
+                y = kda_gated_norm(o, gate, L["norm_g"],
+                                   eps).astype(a.dtype)
                 return y @ L["wo"], z_pool, t_pool
 
         def step(w, tok, pools, positions, num_tokens, kv_lengths,
@@ -2767,8 +2928,9 @@ class ServingEngine:
             live = _owned_rows(T, seq_start, num_tokens)
             for i, kind in enumerate(pattern):  # a letter: static
                 L = w["layers"][i]
-                with _scope("ffn_norm" if kind in "ED" else "attn_norm"):
-                    a = fused_rms_norm(x, L["norm"], eps)
+                a = _once(fused_rms_norm,
+                          "ffn_norm" if kind in "ED" else "attn_norm",
+                          x, L["norm"], eps=eps)
                 if kind == "E":
                     x = x + _ffn_apply(L, a, next(sts), moe_stats, live)
                 elif kind == "D":
@@ -2790,7 +2952,8 @@ class ServingEngine:
                         q, k, v = (_mm_heads(a, L, w)
                                    for w in ("wq", "wk", "wv"))
                     with _scope("cache_write"):
-                        q, kp, vp = fused_rope_append(
+                        q, kp, vp = _once(
+                            fused_rope_append, "cache_write",
                             q.reshape(T, Hq, D), k.reshape(T, KV, D),
                             v.reshape(T, KV, D), one, jnp.zeros_like(one),
                             kp, vp, runs)
@@ -2811,11 +2974,11 @@ class ServingEngine:
 
         return step
 
-    def _gpt_unified_body(self):
+    def _gpt_unified_body(self, C: int):
         cfg = self._p["cfg"]
         nh, hd = cfg.num_attention_heads, cfg.head_dim
         eps = cfg.layer_norm_eps
-        B, C, K = self.max_slots, self.prefill_chunk, self.spec_k
+        B, K = self.max_slots, self.spec_k
         R = 1 + K
         T = B * R + C
         seq_start = _seq_starts(B, R)
@@ -2833,13 +2996,14 @@ class ServingEngine:
                 runs = run_table(num_tokens, tok_page, tok_off)
             new_pools = []
             for L, (kp, vp) in zip(w["layers"], pools):
-                with _scope("attn_norm"):
-                    h = fused_layer_norm(x, L["ln1w"], L["ln1b"], eps)
+                h = _once(fused_layer_norm, "attn_norm", x, L["ln1w"],
+                          L["ln1b"], eps=eps)
                 with _scope("qkv_proj"):
                     qkv = h @ L["wqkv"] + L["bqkv"]
                     q, k, v = jnp.split(qkv, 3, axis=-1)
                 with _scope("cache_write"):
-                    q, kp, vp = fused_rope_append(
+                    q, kp, vp = _once(
+                        fused_rope_append, "cache_write",
                         q.reshape(T, nh, hd), k.reshape(T, nh, hd),
                         v.reshape(T, nh, hd), c, s, kp, vp, runs)
                 new_pools.append((kp, vp))
@@ -2850,8 +3014,8 @@ class ServingEngine:
                                                scope="attention")
                 with _scope("attn_out"):
                     x = x + (o.reshape(1, T, nh * hd) @ L["wo"] + L["bo"])
-                with _scope("ffn_norm"):
-                    h2 = fused_layer_norm(x, L["ln2w"], L["ln2b"], eps)
+                h2 = _once(fused_layer_norm, "ffn_norm", x, L["ln2w"],
+                           L["ln2b"], eps=eps)
                 with _scope("ffn"):
                     x = x + (jax.nn.gelu(h2 @ L["wi"] + L["bi"],
                                          approximate=True) @ L["wf"]
@@ -2865,7 +3029,7 @@ class ServingEngine:
 
         return step
 
-    def _mla_unified_body(self):
+    def _mla_unified_body(self, C: int):
         """Latent attention in the ABSORBED form on the one chain: the
         cache row is (RMSNorm(latent) | RoPE(k_pe) | pad), the query of
         head a is (q_nope_a W_kvb^K_a | RoPE(q_pe_a) | 0), the kernel's
@@ -2885,7 +3049,7 @@ class ServingEngine:
         scale = cfg.softmax_scale       # yarn's mscale^2 included
         moe_static = self._p.get("moe_static")
         count_moe = _tracing.STEP_COUNTS_MOE[0] in self._count_names
-        B, C, K = self.max_slots, self.prefill_chunk, self.spec_k
+        B, K = self.max_slots, self.spec_k
         R = 1 + K
         T = B * R + C
         seq_start = _seq_starts(B, R)
@@ -2910,8 +3074,7 @@ class ServingEngine:
             sts = moe_static or (None,) * len(w["layers"])
             for L, pool, st in zip(w["layers"], pools, sts):
                 a, keep = res.feed(x, L.get("hc1"))
-                with _scope("attn_norm"):
-                    h = fused_rms_norm(a, L["ln1"], eps)
+                h = _once(fused_rms_norm, "attn_norm", a, L["ln1"], eps=eps)
                 y, pool = _latent_mixer(
                     L, h, rope, pool, seq_start, num_tokens, kv_lengths,
                     tables, runs, nh=nh, dn=dn, dr=dr, dv=dv, r=r,
@@ -2919,8 +3082,7 @@ class ServingEngine:
                 x = res.leave(x, y, keep)
                 new_pools.append(pool)
                 a, keep = res.feed(x, L.get("hc2"))
-                with _scope("ffn_norm"):
-                    h2 = fused_rms_norm(a, L["ln2"], eps)
+                h2 = _once(fused_rms_norm, "ffn_norm", a, L["ln2"], eps=eps)
                 x = res.leave(x, _ffn_apply(L, h2, st, moe_stats, live),
                               keep)
             x = res.exit(x)

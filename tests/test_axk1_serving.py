@@ -105,7 +105,8 @@ class TestEngineAgainstReference:
         assert eng.ragged and eng._family == "mla"
         # the row is stored padded to whole 128-lane registers
         assert eng._pools[0].shape == (1, 40, 8, 256)
-        assert eng.program_cache_sizes() == {"unified": 1, "feed": 1}
+        assert eng.program_cache_sizes() == {
+            "unified": 1, "feed": 1, "unified_nochunk": 1, "feed_nochunk": 1}
         for r, p in zip(reqs, prompts):
             got = np.stack(rows[r.request_id])
             want = _reference_rows(model, p, np.asarray(r.tokens))
@@ -214,9 +215,13 @@ class TestEngineAgainstReference:
         for r in recs:
             assert r["pages_live"] <= r["pages_visited"] \
                 == r["attn_block_visits"] <= r["attn_tile_chains"]
-            assert r["attn_narrow_updates"] == 0
-            if not r["prefill_rows"]:   # decode rows: their pages, once
-                assert r["attn_tile_chains"] == r["pages_live"]
+            if r["prefill_rows"]:
+                assert r["attn_narrow_updates"] == 0
+            else:
+                # decode rows: their pages, once — and, the launch being
+                # those rows alone, ONE tile, on the narrow window again
+                assert r["attn_tile_chains"] == r["pages_live"] \
+                    == r["attn_narrow_updates"]
         # the 61-token prompt's chunk (rows 3..63) has rows in tiles 0
         # and 1, which are ONE cell: 4 + 8 pages by tile, its 8 by cell
         first = recs[0]

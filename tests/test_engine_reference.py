@@ -69,7 +69,8 @@ def _serve(m, prompts, max_new, stagger=0, **engine):
             reqs.append(eng.add_request(waiting.pop(0),
                                         max_new_tokens=max_new))
         eng.step()
-    assert eng.program_cache_sizes() == {"unified": 1, "feed": 1}
+    assert eng.program_cache_sizes() == {
+        "unified": 1, "feed": 1, "unified_nochunk": 1, "feed_nochunk": 1}
     assert eng.allocator.stats()["pages_used"] == 0
     return [(np.asarray(r.tokens), np.stack(rows[r.request_id]))
             for r in reqs], eng

@@ -92,7 +92,8 @@ class TestEngineAgainstReference:
             self, model, served):
         prompts, reqs, rows, eng, _ = served
         assert eng.ragged and eng._window == 24
-        assert eng.program_cache_sizes() == {"unified": 1, "feed": 1}
+        assert eng.program_cache_sizes() == {
+            "unified": 1, "feed": 1, "unified_nochunk": 1, "feed_nochunk": 1}
         for r, p in zip(reqs, prompts):
             got = np.stack(rows[r.request_id])
             want = _reference_rows(model, p, np.asarray(r.tokens))

@@ -284,7 +284,8 @@ def test_nothing_compiles_after_the_warm_up(models, family):
                     max_new_tokens=2)
     eng.run_to_completion()
     sizes = eng.program_cache_sizes()
-    assert sizes == {"unified": 1, "feed": 1}
+    assert sizes == {
+        "unified": 1, "feed": 1, "unified_nochunk": 1, "feed_nochunk": 1}
     first = rng.integers(1, V, 2 * C + 3, dtype=np.int32)
     eng.add_request(first, max_new_tokens=6)
     seq0 = eng.steps

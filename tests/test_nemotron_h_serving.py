@@ -97,7 +97,8 @@ def test_engine_logits_match_the_reference(tiny, case):
         assert got.shape == want.shape == (len(tokens), 96)
         np.testing.assert_allclose(got, want, atol=3e-4)
         np.testing.assert_array_equal(tokens, want.argmax(-1))
-    assert eng.program_cache_sizes() == {"unified": 1, "feed": 1}
+    assert eng.program_cache_sizes() == {
+        "unified": 1, "feed": 1, "unified_nochunk": 1, "feed_nochunk": 1}
     assert eng.launches == eng.steps - 1    # ONE launch a step, one ahead
 
 

@@ -343,16 +343,28 @@ def test_every_op_of_a_serving_step_answers_to_a_name(family):
     # nothing was launched, no pool taken, no jit retraced
     assert eng.launches == launches and eng._pools is pools
     assert not any(p.is_deleted() for p in jax.tree.leaves(pools))
-    assert set(programs) == {"unified", "feed"}
+    assert set(programs) == {"unified", "feed", "unified_nochunk",
+                             "feed_nochunk"}
     assert all(n == 1 for n in eng.program_cache_sizes().values())
+    # the step at each of its row counts, lowered at its own shapes
+    for name, rows in (
+            ("unified", kw["max_slots"] + kw["prefill_chunk"]),
+            ("unified_nochunk", kw["max_slots"])):
+        (_, tok, *_), _ = programs[name].args_info
+        assert tok.shape == (rows,), (name, tok)
+        step = list(at.op_scopes({name: programs[name]}).values())
+        names = {r.scope for r in step}
+        assert names - {None} <= set(at.SCOPES)
+        assert EVERY_STEP | FAMILIES[family] <= names, (name, sorted(
+            EVERY_STEP | FAMILIES[family] - names))
+        scoped = sum(r.scope is not None for r in step)
+        assert scoped >= 0.9 * len(step), (name, scoped, len(step), [
+            r for r in step if r.scope is None][:10])
+    # ... and read together, as a trace's reader does: a key the two
+    # answer differently keeps neither scope, and few do
     table = at.op_scopes(programs)
-    step = [r for r in table.values() if r.program == "unified"]
-    names = {r.scope for r in step}
-    assert names - {None} <= set(at.SCOPES)
-    assert EVERY_STEP | FAMILIES[family] <= names, sorted(
-        EVERY_STEP | FAMILIES[family] - names)
-    scoped = sum(r.scope is not None for r in step)
-    assert scoped >= 0.9 * len(step), (scoped, len(step), [
+    scoped = sum(r.scope is not None for r in table.values())
+    assert scoped >= 0.9 * len(table), (scoped, len(table), [
         k for k, r in table.items() if r.scope is None][:10])
     # the engine still serves
     eng.add_request(np.arange(4, dtype=np.int32), max_new_tokens=2)

@@ -98,7 +98,8 @@ def test_engine_logits_match_the_reference(tiny, case):
         assert got.shape == want.shape == (len(tokens), 96)
         np.testing.assert_allclose(got, want, atol=2e-4)
         np.testing.assert_array_equal(tokens, want.argmax(-1))
-    assert eng.program_cache_sizes() == {"unified": 1, "feed": 1}
+    assert eng.program_cache_sizes() == {
+        "unified": 1, "feed": 1, "unified_nochunk": 1, "feed_nochunk": 1}
     assert eng.launches == eng.steps - 1    # ONE launch a step, one ahead
 
 
@@ -279,8 +280,10 @@ def test_an_untileable_shape_is_refused(tiny, monkeypatch):
 #: five, PR 48 (q / k / v weights stored [heads, D, in]) with four of them.
 #: PR 51 (the pooled K and V rows appended by cache-tile runs in ONE call,
 #: their run table made in the step) re-recorded it with `mla` and Ling's.
+#: PR 53 (the per-layer kernels through one jitted copy a step's layers
+#: share, `engine._once`) re-recorded it with the five, Nemotron's and Ling's.
 EVA_LOWERED_AT_PARENT = \
-    "c69e6c31e99b7b8abdbd947eae23ca40c1f926fea843d3959a3d424752aa4354"
+    "075055efd9e29b2e4cada9c125b8a300c68d6164bef7179823e8f3ccfa9c5214"
 
 
 def _lower_eva():

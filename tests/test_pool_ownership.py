@@ -84,9 +84,13 @@ def _without_ownership(eng):
     """The same engine with programs that do not own their pools — the
     programs `_build_programs` built before ISSUE 29 — now and after
     every `reconfigure()`."""
+    rebuild = eng._build_programs
+
     def build():
-        eng._jit_unified = jax.jit(eng._make_unified_body())
-        eng._programs = {"unified": eng._jit_unified}
+        rebuild()
+        eng._programs.update(
+            ("unified" + sfx, jax.jit(eng._make_unified_body(chunk)))
+            for sfx, chunk in eng._chunk_parts().items())
     eng._build_programs = build
     build()
     return eng
@@ -205,17 +209,17 @@ def test_a_launch_that_took_the_pools_and_raised_ends_the_engine(models):
     req = eng.add_request(np.arange(1, 8, dtype=np.int32), max_new_tokens=6)
     while req.state != DECODE:
         eng.step()
-    program = eng._jit_unified
+    program = eng._programs["unified_nochunk"]  # a launch of decode rows
 
     def took_them_then_raised(w, tok, pools, *tables):
         for a in jax.tree.leaves(pools):
             a.delete()
         raise RuntimeError("device fault")
 
-    eng._jit_unified = took_them_then_raised
+    eng._programs["unified_nochunk"] = took_them_then_raised
     with pytest.raises(RuntimeError, match="device fault"):
         eng.step()
-    eng._jit_unified = program
+    eng._programs["unified_nochunk"] = program
     for use in (eng.step, lambda: eng.export_request(req)):
         with pytest.raises(RuntimeError, match="pools were lost"):
             use()
@@ -224,16 +228,16 @@ def test_a_launch_that_took_the_pools_and_raised_ends_the_engine(models):
 def test_a_launch_that_raised_before_it_took_the_pools_keeps_them(models):
     eng = _engine(models("llama"))
     eng.add_request(np.arange(1, 8, dtype=np.int32), max_new_tokens=3)
-    program = eng._jit_unified
+    program = eng._programs["unified"]
 
     def refused(*args):
         raise ValueError("refused before dispatch")
 
-    eng._jit_unified = refused
+    eng._programs["unified"] = refused
     handed = jax.tree.leaves(eng._pools)
     with pytest.raises(ValueError, match="refused before dispatch"):
         eng.step()
-    eng._jit_unified = program
+    eng._programs["unified"] = program
     kept = jax.tree.leaves(eng._live_pools())
     assert len(kept) == len(handed)
     assert all(a is b for a, b in zip(kept, handed))
@@ -252,8 +256,9 @@ def test_a_copy_on_write_compiles_nothing_when_it_comes(models):
     V = m.config.vocab_size
     first = rng.randint(0, V, 12).astype(np.int32)
     eng.add_request(first, max_new_tokens=8, request_id="donor")
-    for _ in range(3):              # the unified step is compiled here
+    for _ in range(5):      # the step is compiled here, at both row counts
         eng.step()
+    assert all(n == 1 for n in eng.program_cache_sizes().values())
     compiles = []
     jax.monitoring.register_event_duration_secs_listener(
         lambda name, *_a, **_k: compiles.append(name)

@@ -532,9 +532,13 @@ class TestDisaggregated:
             np.testing.assert_array_equal(results[rid], ref[rid])
         # every request crossed the prefill→decode boundary exactly once
         assert router.handoff_count == len(ref)
-        for eng in engines.values():
-            assert all(v == 1
-                       for v in eng.program_cache_sizes().values())
+        # a prefill replica's every launch carries a chunk and a decode
+        # replica's none: each ran ONE of the step's two row counts
+        for name, eng in engines.items():
+            role, sizes = self.ROLES[name], eng.program_cache_sizes()
+            assert sizes.pop("unified" if role == "decode"
+                             else "unified_nochunk") == 0, (role, sizes)
+            assert all(v == 1 for v in sizes.values()), (role, sizes)
 
     def test_llama_disaggregated_exact(self):
         from paddle_tpu.models.llama import (LlamaForCausalLM,

@@ -545,14 +545,22 @@ def _pool_copies(text, shapes):
     return [ln.strip() for ln in text.splitlines() if pat.search(ln)]
 
 
+@pytest.mark.parametrize("chunk_part", ["chunk", "nochunk"])
 @pytest.mark.parametrize("family,n_shapes", [("llama", 1), ("window", 2),
                                              ("latent", 1)],
                          ids=["llama_engines_choice", "window_two_pools",
                               "latent_one_pool_a_layer"])
-def test_unified_step_updates_its_pools_in_place(chip, family, n_shapes):
+def test_unified_step_updates_its_pools_in_place(chip, family, n_shapes,
+                                                 chunk_part):
+    """... at both of the step's row counts (ISSUE 53): with the prefill
+    chunk's rows behind the decode rows, and the decode rows alone."""
     eng = _small_engine(family)
     assert eng.ragged
-    B, C = eng.max_slots, eng.prefill_chunk
+    B = eng.max_slots
+    sfx = "" if chunk_part == "chunk" else "_nochunk"
+    C = eng._chunk_parts()[sfx]
+    unified, jit_feed = (eng._programs[name + sfx]
+                         for name in ("unified", "feed"))
     rows, seqs = chip.shape((B + C,), I32), chip.shape((B + 1,), I32)
     table = chip.shape((B + 1, eng.pages_per_seq), I32)
     if family == "window":      # a table and a page column a layer kind
@@ -575,7 +583,7 @@ def test_unified_step_updates_its_pools_in_place(chip, family, n_shapes):
             rows, pools, rows, seqs, seqs, table, page, rows)
     shapes = {p.shape for p in jax.tree.leaves(pools)}
     assert len(shapes) == n_shapes
-    compiled = eng._jit_unified.lower(*args).compile()
+    compiled = unified.lower(*args).compile()
     assert compiled.memory_analysis().alias_size_in_bytes == sum(
         2 * math.prod(p.shape) for p in jax.tree.leaves(pools))
     copies = _pool_copies(compiled.as_text(), shapes)
@@ -586,12 +594,15 @@ def test_unified_step_updates_its_pools_in_place(chip, family, n_shapes):
     # step takes
     logits, _, tokens, *_ = compiled.out_info
     assert (tokens.shape, tokens.dtype) == (logits.shape[:1], I32)
-    feed = eng._jit_feed.lower(chip.shape(tokens.shape, I32), rows,
-                               rows).compile()
+    feed = jit_feed.lower(chip.shape(tokens.shape, I32), rows,
+                          rows).compile()
     assert (feed.out_info.shape, feed.out_info.dtype) == (rows.shape, I32)
+    if not C:
+        return
     # the same body without ownership: the copies this test looks for
     # are there, so the pattern still reads what the compiler prints
-    plain = jax.jit(eng._make_unified_body()).lower(*args).compile()
+    # (asked once, at the full row count)
+    plain = jax.jit(eng._make_unified_body(C)).lower(*args).compile()
     assert plain.memory_analysis().alias_size_in_bytes == 0
     assert len(_pool_copies(plain.as_text(), shapes)) >= len(eng._pools)
 
@@ -673,13 +684,18 @@ def _weight_copies(text, shapes):
     return [ln.strip() for ln in text.splitlines() if pat.search(ln)]
 
 
+@pytest.mark.parametrize("chunk_part", ["chunk", "nochunk"])
 @pytest.mark.parametrize("family", ["llama", "eva", "looped", "latent"])
 def test_unified_step_reads_head_split_weights_as_stored(chip, family,
+                                                         chunk_part,
                                                          monkeypatch):
+    """... at both of the step's row counts (ISSUE 53): 288 / 272 flat
+    rows with the chunk's behind the decode rows, 32 / 16 without."""
     from paddle_tpu.serving import engine as engine_mod
     eng = _wide_engine(family)
     assert eng.ragged
-    B, C = eng.max_slots, eng.prefill_chunk
+    B = eng.max_slots
+    C = eng.prefill_chunk if chunk_part == "chunk" else 0
     rows, seqs = chip.shape((B + C,), I32), chip.shape((B + 1,), I32)
     table = chip.shape((B + 1, eng.pages_per_seq), I32)
     lens, page, off = seqs, rows, rows
@@ -698,13 +714,16 @@ def test_unified_step_reads_head_split_weights_as_stored(chip, family,
             if k in L]
     shapes = {s for _, s in held}
     assert len(held) == {"latent": 2}.get(family, 3) * len(eng._w["layers"])
-    text = jax.jit(eng._make_unified_body()).lower(
+    text = jax.jit(eng._make_unified_body(C)).lower(
         *args(eng._w)).compile().as_text()
     copies = _weight_copies(text, shapes)
     assert not copies, copies[:3]
+    if not C:
+        return
     # the same body fed [in, out] weights through a plain `h @ w` (the
     # kv_b through the read a quantized pair takes): one copy a weight,
-    # so the pattern still reads what the compiler prints
+    # so the pattern still reads what the compiler prints (asked once,
+    # at the full row count)
     monkeypatch.setattr(engine_mod, "_mm_heads",
                         lambda h, L, key: h @ L[key])
     monkeypatch.setattr(
@@ -715,9 +734,172 @@ def test_unified_step_reads_head_split_weights_as_stored(chip, family,
                                  v.dtype)
          if k in HEAD_SPLIT else v for k, v in L.items()}
         for L in eng._w["layers"]])
-    plain = jax.jit(eng._make_unified_body()).lower(
+    plain = jax.jit(eng._make_unified_body(C)).lower(
         *args(plain_w)).compile().as_text()
     assert len(_weight_copies(plain, shapes)) >= len(held)
+
+
+# ---------------------------------------------------------------------------
+# the per-layer kernels go through ONE jitted copy a step's layers share
+# (`engine._once`, ISSUE 53: a program's first launch traces and lowers
+# a kernel once, not once a layer).  The step then CALLS what it held
+# inline, so its lowered text is not the plain calls'; the compiler
+# inlines the calls, and this is where that is checked: the compiled
+# step is the plain calls' step, instruction for instruction, in every
+# family that takes the shared copies and at both row counts.
+# ---------------------------------------------------------------------------
+
+def _kernel_family_engine(family):
+    """An engine of each family whose body calls `_once`, at widths the
+    chip's tiling accepts (beside `_small_engine`'s three): GPT, EvaByte,
+    a Nemotron stage (state-space, routed, attention), three Ling layers
+    (KDA dense, KDA routed, latent routed), Xing's four streams."""
+    import paddle_tpu as paddle
+    from paddle_tpu.serving import ServingEngine
+    if family in ("llama", "window", "latent"):
+        return _small_engine(family)
+    paddle.seed(0)
+    eng = dict(max_slots=8, page_size=16, max_context=256,
+               prefill_chunk=128, num_pages=65)
+    if family == "gpt":
+        from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny_config
+        model = GPTForCausalLM(gpt_tiny_config(
+            hidden_size=256, num_attention_heads=2,
+            max_position_embeddings=256))
+    elif family == "eva":
+        from paddle_tpu.models.evabyte import (EvaByteForCausalLM,
+                                               evabyte_tiny_config)
+        model = EvaByteForCausalLM(evabyte_tiny_config(
+            intermediate_size=512, vocab_size=320, num_hidden_layers=2,
+            max_position_embeddings=1024, hidden_size=512,
+            num_attention_heads=4, num_key_value_heads=4, chunk_size=16,
+            window_size=512, num_pred_heads=2, rope_positions=1024))
+        eng = dict(max_slots=32, page_size=256, max_context=1024,
+                   prefill_chunk=256)
+    elif family == "ssm":
+        from paddle_tpu.models.nemotron_h import (NemotronHForCausalLM,
+                                                  nemotron_h_tiny_config)
+        model = NemotronHForCausalLM(nemotron_h_tiny_config(
+            hidden_size=256, hybrid_override_pattern="ME*E",
+            num_attention_heads=2, num_key_value_heads=1, head_dim=128,
+            mamba_num_heads=8, mamba_head_dim=64, n_groups=2,
+            ssm_state_size=128, chunk_size=128, moe_latent_size=128,
+            moe_intermediate_size=128,
+            moe_shared_expert_intermediate_size=128))
+    elif family == "kda":
+        from paddle_tpu.models.bailing_hybrid import (
+            BailingHybridForCausalLM, bailing_hybrid_tiny_config)
+        model = BailingHybridForCausalLM(bailing_hybrid_tiny_config(
+            hidden_size=256, intermediate_size=256, layers_held=(0, 4, 5),
+            num_attention_heads=2, num_key_value_heads=2, head_dim=128,
+            kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+            v_head_dim=128, kda_sub_chunk=64, moe_intermediate_size=128,
+            moe_shared_expert_intermediate_size=128))
+    else:
+        assert family == "mhc"
+        from paddle_tpu.models.xing import XingForCausalLM, xing_tiny_config
+        model = XingForCausalLM(xing_tiny_config(
+            hidden_size=128, kv_lora_rank=512, qk_rope_head_dim=64,
+            qk_nope_head_dim=128, v_head_dim=128))
+        eng.update(max_slots=128, num_pages=1100)
+    model.eval()
+    for _, prm in model.named_parameters():
+        prm._data = prm._data.astype(jnp.bfloat16)
+    return ServingEngine(model, **eng)
+
+
+_HLO_DEF = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = (.*)$")
+_HLO_REF = re.compile(r"%[\w.\-]+")
+
+
+def _what_it_computes(text):
+    """A compiled module as the multiset of its ENTRY instructions, each
+    named by what it computes: its line with every instruction's and
+    called computation's own name replaced by the digest of what that
+    name stands for. Left out, because they say where an instruction
+    came from and not what it does: metadata and frontend attributes, a
+    Mosaic kernel's serialized body (its bytecode carries source
+    locations; the kernels are the same Python either way), the number
+    of a parameter and the order of a fusion's operands (XLA numbers a
+    fused computation's parameters as it meets them)."""
+    import collections
+    import hashlib
+    text = re.sub(r",? ?(metadata|frontend_attributes)="
+                  r"\{(?:[^{}]|\{[^}]*\})*\}", "", text)
+    text = re.sub(r'"body":"[^"]*"', '"body":""', text)
+    comps, entry, name = {}, None, None
+    for ln in text.splitlines():
+        head = re.match(r"^(ENTRY )?(%[\w.\-]+) .*\{$", ln)
+        if head and " = " not in ln:
+            name = head.group(2)
+            comps[name] = []
+            entry = name if head.group(1) else entry
+        elif ln.startswith("}"):
+            name = None
+        elif name and _HLO_DEF.match(ln):
+            comps[name].append(_HLO_DEF.match(ln).groups())
+    digests = {}
+
+    def sha(s):
+        return hashlib.sha1(s.encode()).hexdigest()[:16]
+
+    def computation(c):
+        if c not in digests:
+            digests[c] = sha("\n".join(sorted(instructions(c).values())))
+        return digests[c]
+
+    def instructions(c):
+        seen = {}
+
+        def named(m):
+            ref = m.group(0)
+            return seen.get(ref) or (
+                "@" + computation(ref) if ref in comps else ref)
+
+        for lhs, rhs in comps[c]:
+            rhs = re.sub(r"/\*index=\d+\*/", "", _HLO_REF.sub(named, rhs))
+            rhs = re.sub(r"parameter\(\d+\)", "parameter()", rhs)
+            rhs = re.sub(
+                r" fusion\(([^)]*)\)", lambda m: " fusion(%s)" % ",".join(
+                    sorted(m.group(1).split(", "))), rhs)
+            seen[lhs] = "#" + sha(rhs)
+        return seen
+
+    return collections.Counter(instructions(entry).values())
+
+
+@pytest.mark.parametrize("family", ["llama", "window", "latent", "gpt",
+                                    "eva", "ssm", "kda", "mhc"])
+def test_shared_kernel_copies_compile_to_the_plain_calls_step(
+        chip, family, monkeypatch):
+    from paddle_tpu.serving import engine as engine_mod
+    eng = _kernel_family_engine(family)
+    assert eng.ragged
+
+    def plain(fn, scope, *args, **static):
+        with jax.named_scope(scope):
+            return fn(*args, **static)
+
+    def compiled(chunk):
+        args, _ = eng._program_shapes(chunk)
+        args = jax.tree.map(lambda a: chip.shape(a.shape, a.dtype), args)
+        return jax.jit(eng._make_unified_body(chunk),
+                       donate_argnums=2).lower(*args).compile()
+
+    for chunk in eng._chunk_parts().values():
+        shared = compiled(chunk)
+        text = shared.as_text()
+        with monkeypatch.context() as mp:
+            mp.setattr(engine_mod, "_once", plain)
+            inline = compiled(chunk)
+        assert text.count("tpu_custom_call") > len(eng._w["layers"])
+        assert _what_it_computes(text) == \
+            _what_it_computes(inline.as_text())
+        assert sum(_what_it_computes(text).values()) > 100
+        for stat in ("alias_size_in_bytes", "temp_size_in_bytes",
+                     "argument_size_in_bytes", "output_size_in_bytes"):
+            assert getattr(shared.memory_analysis(), stat) == \
+                getattr(inline.memory_analysis(), stat), stat
 
 
 # ---------------------------------------------------------------------------

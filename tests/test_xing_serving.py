@@ -73,7 +73,8 @@ def test_prefill_in_chunks_then_paged_decode_matches_in_logits(tiny, served):
         got = np.stack(rows[r.request_id])
         np.testing.assert_allclose(got, want, atol=ATOL, rtol=0)
         np.testing.assert_array_equal(toks, want.argmax(-1))
-    assert eng.program_cache_sizes() == {"unified": 1, "feed": 1}
+    assert eng.program_cache_sizes() == {
+        "unified": 1, "feed": 1, "unified_nochunk": 1, "feed_nochunk": 1}
     assert eng.launches == eng.steps - 1    # ONE launch a step, one ahead
     assert eng.allocator.stats()["pages_used"] == 0
 
@@ -85,9 +86,13 @@ def test_the_step_record_counts_the_mixing(served):
     assert all(k in recs[-1] for k in tracing.STEP_COUNTS_MOE)
     assert all(k in recs[-1] for k in tracing.STEP_COUNTS_LATENT)
     for r in recs:
-        # every row of the flat buffer is mixed, around two sublayers a
-        # layer; a row of the stream is 4 x 64 float32 here
-        assert r["mhc_rows"] % (2 + CHUNK) == 0 and r["mhc_sublayers"] == 4
+        # every row of the launch's flat buffer is mixed (the two
+        # slots' rows, and a chunk's behind them where it carries one),
+        # around two sublayers a layer; a row of the stream is 4 x 64
+        # float32 here
+        assert r["mhc_rows"] == r["rows_computed"] \
+            == 2 + CHUNK * bool(r["prefill_rows"])
+        assert r["mhc_sublayers"] == 4
         # taken on the device: rows sum to 1, columns as far as twenty
         # iterations bring them
         assert 0 < r["mhc_colsum_err_max"] < 0.2
