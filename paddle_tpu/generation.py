@@ -594,6 +594,38 @@ def _hybrid_decode_params(model):
                           rope=""),) * n_attn)
 
 
+def _falcon_h1_decode_params(model):
+    """FalconH1ForCausalLM on the hybrid body: TWO blocks a layer, the
+    first with TWO mixers on its one norm — ``[M*]``: a Mamba-2
+    state-space mixer and a rotary GQA mixer, both fed the block's
+    normed input, their outputs summed into the residual — then ``D``, a
+    dense SwiGLU FFN.  ``attn_static`` has one record for each layer
+    (every layer has pages AND a state slot).  ``mults`` holds the
+    fourteen muP multipliers (`models.falcon_h1.MULTIPLIERS`), static
+    scalars the body applies where the model's equations put them; no
+    stored weight is scaled."""
+    from .models.falcon_h1 import arrays
+    inner, cfg = model.model, model.config
+    layers = []
+    for lyr in inner.layers:
+        d = dict(norm=lyr.input_layernorm.weight._data)
+        d.update(arrays(lyr.mamba.weights()))
+        d.update(arrays(lyr.self_attn.weights()))
+        _heads_w(d, cfg.head_dim, "wq", "wk", "wv")
+        layers.append(d)
+        layers.append(dict(norm=lyr.pre_ff_layernorm.weight._data,
+                           **arrays(lyr.feed_forward.weights())))
+    return dict(
+        cfg=cfg, family="hybrid", pattern=cfg.pattern,
+        embed=inner.embed_tokens.weight._data, layers=layers,
+        norm=inner.final_layernorm.weight._data,
+        head=model.lm_head.weight._data, moe_static=(),
+        mults=dict(cfg.multipliers),
+        attn_static=(dict(heads=cfg.num_attention_heads, window=None,
+                          rope=""),) * cfg.num_hidden_layers,
+        rope_fn=lambda n: dict(zip(("cos", "sin"), cfg.rope_table(n))))
+
+
 def _bailing_decode_params(model):
     """BailingHybridForCausalLM on the hybrid body: TWO blocks a layer,
     of the kinds ``pattern`` spells (static, outside the tree): ``K`` a
@@ -741,15 +773,20 @@ def _decode_params(model, weight_only_int8: bool = False,
         from .models.laguna import LagunaModel
         from .models.moe_llm import MoEModel
         from .models.bailing_hybrid import BailingHybridModel
+        from .models.falcon_h1 import FalconH1Model
         from .models.nemotron_h import NemotronHModel
         from .models.ouro import OuroModel
-        if isinstance(inner, (NemotronHModel, BailingHybridModel)):
+        if isinstance(inner, (NemotronHModel, BailingHybridModel,
+                              FalconH1Model)):
             if enabled:
                 raise NotImplementedError(
                     "weight-only quantisation is not wired for the "
-                    "Nemotron-H and Ling (bailing_hybrid) families")
+                    "Nemotron-H, Ling (bailing_hybrid) and Falcon-H1 "
+                    "families")
             if isinstance(inner, BailingHybridModel):
                 return _bailing_decode_params(model)
+            if isinstance(inner, FalconH1Model):
+                return _falcon_h1_decode_params(model)
             return _hybrid_decode_params(model)
         if isinstance(inner, OuroModel):
             if enabled:
@@ -785,7 +822,7 @@ def _llama_weights(p):
     time."""
     return {k: v for k, v in p.items()
             if k not in ("cfg", "family", "moe_static", "attn_static",
-                         "rope_fn", "pattern")}
+                         "rope_fn", "pattern", "mults")}
 
 
 def _dq(d, key, dtype):
@@ -887,13 +924,19 @@ def _ffn_apply(L, h2, st=None, stats=None, live=None):
     knobs (top_k, renorm; held, scale where the layer is one chip's
     share of an expert-parallel one; score, group where the router is
     not a softmax top-k; act "relu2" where the experts are two matrices)
-    from _mlp_params. ``L["moe"]`` may hold a correction ``bias`` [E]
+    from _mlp_params; for a DENSE layer it may hold the two multipliers
+    ``mlp_gate`` (on the gate's pre-activation) and ``mlp_down`` (on the
+    output). ``L["moe"]`` may hold a correction ``bias`` [E]
     of the choice and the latent projections ``lat_dn`` / ``lat_up``
     around the experts (Nemotron-H). A routed layer
     appends its `moe.routing_stats` to the list ``stats``, counted over
     the rows that ``live`` [B * S] marks (all, if None)."""
     if "moe" not in L:
         with jax.named_scope("ffn"):
+            if st is not None:  # a dense FFN's multipliers (Falcon-H1)
+                return _mm_w(
+                    jax.nn.silu(_mm_w(h2, L, "wg") * st["mlp_gate"])
+                    * _mm_w(h2, L, "wu"), L, "wd") * st["mlp_down"]
             return _mm_w(jax.nn.silu(_mm_w(h2, L, "wg"))
                          * _mm_w(h2, L, "wu"), L, "wd")
     mo = L["moe"]
@@ -1243,10 +1286,11 @@ def _cached_step_body(p, max_len: int):
             "one row a layer")
     if p["family"] == "hybrid":
         raise NotImplementedError(
-            "the Nemotron-H family (state-space mixers whose memory of a "
-            "sequence is a slot of recurrent state, beside the attention "
-            "blocks' pages) decodes through serving.ServingEngine; the "
-            "contiguous-cache bodies keep rows only")
+            "a hybrid family (Nemotron-H, Ling, Falcon-H1: mixers whose "
+            "memory of a sequence is a slot of recurrent state, beside the "
+            "attention mixers' pages) decodes through "
+            "serving.ServingEngine; the contiguous-cache bodies keep rows "
+            "only")
     if p["family"] == "gpt":
         return _gpt_cached_step_body(p["cfg"], max_len)
     if p["family"] == "mla" and p["cfg"].hc_mult > 1:

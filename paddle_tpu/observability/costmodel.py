@@ -269,15 +269,21 @@ def _c_fused_chunk_pool(*, P: int, KV: int, D: int, chunk: int,
 
 @register_cost("ssm_state_update")
 def _c_ssm_state_update(*, live: int, P: int, N: int, H: int,
-                        dtype_bytes: int = 2) -> CostEstimate:
+                        dtype_bytes: int = 2, layout: str = "heads_minor",
+                        G: int = 1) -> CostEstimate:
     """One step of the Mamba-2 recurrence for `live` slots of a
-    slot-indexed state pool [slots, P, N, H] float32 (aliased in+out):
-    each live slot's state once in and once out, its row's dt x [P, H]
-    and decay [H] in float32, B and C [N, H]; y [P, H] out. An idle
-    slot is neither read nor written. 5 FLOPs an element of the state
-    (decay, the outer product's multiply-add, the read-out's)."""
+    slot-indexed float32 state pool (aliased in+out), [slots, P, N, H]
+    heads-minor or [slots, H, P, N] state-minor (`ops.pallas_ssm`): each
+    live slot's state once in and once out, its row's dt x [P, H] and
+    decay [H] in float32, B and C — expanded to heads, [N, H] in the
+    serving type, heads-minor; a group's rows [G, N] float32,
+    state-minor; y [P, H] out. An idle slot is neither read nor written.
+    5 FLOPs an element of the state (decay, the outer product's
+    multiply-add, the read-out's)."""
     state = live * P * N * H * 4
-    rows_in = live * ((P * H + H) * 4 + 2 * N * H * dtype_bytes)
+    bc = 2 * G * N * 4 if layout == "state_minor" \
+        else 2 * N * H * dtype_bytes
+    rows_in = live * ((P * H + H) * 4 + bc)
     rows_out = live * P * H * 4
     return CostEstimate(bytes_read=state + rows_in,
                         bytes_written=state + rows_out,
@@ -288,7 +294,8 @@ def _c_ssm_state_update(*, live: int, P: int, N: int, H: int,
 
 @register_cost("ssm_state_put")
 def _c_ssm_state_put(*, P: int, N: int, H: int) -> CostEstimate:
-    """One slot [P, N, H] float32 of the state pool replaced in place:
+    """One slot [P, N, H] (or state-minor [H, P, N]: the same bytes)
+    float32 of the state pool replaced in place:
     the new state read, the slot written (its old content rides in with
     the aliased block and is dropped)."""
     state = P * N * H * 4
@@ -736,6 +743,21 @@ def ssm_state_bytes_per_seq_layer(*, heads: int, head_dim: int,
     rows of its input."""
     return (heads * head_dim * state_size * state_dtype_bytes
             + (conv_kernel - 1) * conv_dim * conv_dtype_bytes)
+
+
+def ssm_state_stored_bytes(*, heads: int, head_dim: int, state_size: int,
+                           layout: str = "heads_minor",
+                           dtype_bytes: int = 4, lanes: int = 128) -> int:
+    """HBM bytes ONE (slot, layer)'s recurrent state takes on a TPU as
+    the pool STORES it: the pool's minor dimension — the heads
+    (heads-minor [P, N, H]) or the state's columns (state-minor [H, P,
+    N]) — lies along the lanes and is stored in whole rows of `lanes`.
+    32 heads x 128 over a state of 256: 16,777,216 heads-minor (32 of
+    128 lanes used), 4,194,304 state-minor; 128 heads x 64 over 128:
+    4,194,304 either way. `ops.pallas_ssm.state_layout` picks by this."""
+    minor, rest = (state_size, heads * head_dim) \
+        if layout == "state_minor" else (heads, head_dim * state_size)
+    return -(-minor // lanes) * lanes * rest * dtype_bytes
 
 
 def kda_state_bytes_per_seq_layer(*, heads: int, head_dim: int,

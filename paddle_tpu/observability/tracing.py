@@ -172,7 +172,11 @@ STEP_COUNTS_LOOP: Tuple[str, ...] = (
 #: a slot that starts its sequence is not read), the chunk's rows, the
 #: slots the launch started from zero state, and the pool's slots that
 #: hold a request against all of them. A KDA (delta-rule) block's
-#: state lives in the same pool under the same counts
+#: state lives in the same pool under the same counts, and so does the
+#: state of a block whose one norm feeds a state-space AND an attention
+#: mixer (Falcon-H1: "such layers" are then ALL layers, each with pages
+#: too); `ssm_state_bytes` is the slot as the pool's layout stores it
+#: (`ops.pallas_ssm.state_layout`)
 STEP_COUNTS_SSM: Tuple[str, ...] = (
     "ssm_slots_live", "ssm_state_bytes", "ssm_state_bytes_moved",
     "ssm_scan_rows", "ssm_state_resets", "state_pool_slots_used",

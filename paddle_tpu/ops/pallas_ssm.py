@@ -6,12 +6,28 @@ For head ``h`` (group ``g = h // (H / G)``), state ``S_h`` [P, N]::
     S_t = exp(dt_t[h] A[h]) S_{t-1} + dt_t[h] x_t[h] (outer) B_t[g]
     y_t[h] = S_t C_t[g]                     (the caller adds D[h] x_t[h])
 
-The pool is stored HEADS-MINOR, ``[slots, P, N, H]`` float32 (P the
-head width, N the state size, H the heads): for a fixed (p, n) the H
-heads lie along the 128 lanes, so a head's scalars (its decay, its
-``dt``) are lane vectors, a group's ``B`` / ``C`` rows expanded to heads
-are [N, H] tiles, and the update is plain elementwise work on whole
-registers — no transpose, no broadcast across lanes.
+(P the head width, N the state size, H the heads.)  The pool is float32
+in ONE of two layouts, which `state_layout(H, N)` picks by what fills
+the 128 lanes of the MINOR dimension — a minor dimension is stored in
+whole 128-lane rows, so one of 32 is stored fourfold:
+
+- HEADS-MINOR ``[slots, P, N, H]`` where H is whole registers
+  (Nemotron-3-Super: 128 heads of 64 over a state of 128): for a fixed
+  (p, n) the H heads lie along the lanes, so a head's scalars (its
+  decay, its ``dt``) are lane vectors, a group's ``B`` / ``C`` rows
+  expanded to heads are [N, H] tiles, and the update is plain
+  elementwise work on whole registers — no transpose, no broadcast
+  across lanes.
+- STATE-MINOR ``[slots, H, P, N]`` where H is NOT whole registers and N
+  is (Falcon-H1-34B: 32 heads of 128 over a state of 256, two registers
+  wide; heads-minor would store 128 lanes for its 32 heads, 16 MiB a
+  (slot, layer) for 4 MiB of state): a head's state is a [P, N] tile
+  with the state's columns along the lanes, a group's ``B`` / ``C`` row
+  is a lane vector shared by the group's heads (NOT expanded), and a
+  head's ``dt x`` is a column [P, 1] that the kernel spreads along the
+  lanes, its decay one value spread over the tile.
+
+Shapes that fill neither (toy widths) stay heads-minor.
 
 - `ssm_state_update`: ONE step of the recurrence for the decode rows.
   Each LIVE slot's state is read once and written once, in place
@@ -23,7 +39,10 @@ registers — no transpose, no broadcast across lanes.
   batched matmuls over (group, head). Rows whose ``dt`` is 0 are the
   identity, so a chunk is padded by zeroing ``dt``.
 - `ssm_state_put`: writes one slot of the pool in place (the chunk's
-  new state).
+  new state); blocks of the pool's second dimension, either layout.
+
+``layout="state_minor"`` selects the second form of the first two; the
+default is the first, and its operands are what they were.
 """
 
 from __future__ import annotations
@@ -35,24 +54,57 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["ssm_state_update", "ssm_chunk_scan", "ssm_state_put"]
+__all__ = ["ssm_state_update", "ssm_chunk_scan", "ssm_state_put",
+           "state_layout", "state_pool_shape", "HEADS_MINOR", "STATE_MINOR"]
 
-#: rows of P a grid step of the update holds: a block of
-#: [PB, N, H] float32 (1 MiB at N = H = 128)
+HEADS_MINOR, STATE_MINOR = "heads_minor", "state_minor"
+_LANES = 128
+
+#: rows of the pool's second dimension a grid step holds, at most, and
+#: the bytes of that block, at most (1 MiB: [16, 128, 128] heads-minor
+#: at N = H = 128, [8, 128, 256] state-minor at P = 128, N = 256)
 _PB = 16
+_BLOCK_BYTES = 1 << 20
+
+
+def state_layout(H: int, N: int) -> str:
+    """The pool's layout for H heads over a state of N (module
+    docstring): state-minor where N is whole 128-lane registers and H
+    is not, else heads-minor."""
+    return STATE_MINOR if N % _LANES == 0 and H % _LANES else HEADS_MINOR
+
+
+def state_pool_shape(slots: int, H: int, P: int, N: int,
+                     layout: str = HEADS_MINOR):
+    """The pool's shape for `slots` slots in `layout`."""
+    return (slots, H, P, N) if layout == STATE_MINOR else (slots, P, N, H)
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _p_block(P: int) -> int:
-    return _PB if P % _PB == 0 else P
+def _p_block(P: int, row_bytes: int = 0) -> int:
+    """Rows of a pool's second dimension (of `row_bytes` each) a grid
+    step holds: _PB, halved while the block is over _BLOCK_BYTES; all
+    of them where that does not divide P."""
+    pb = _PB
+    while pb > 1 and pb * row_bytes > _BLOCK_BYTES:
+        pb //= 2
+    return pb if P % pb == 0 else P
 
 
 # ---------------------------------------------------------------------------
 # one step of the recurrence, the decode rows
 # ---------------------------------------------------------------------------
+
+def _slot_at(NS: int, i, slots, n):
+    """(the slot of grid step i, whether the step is idle): past the
+    live ones, the last live slot again, whose last block the index
+    maps then name — it stays resident and is not touched."""
+    last = jnp.maximum(n[0] - 1, 0)
+    return jnp.clip(slots[jnp.minimum(i, last)], 0, NS - 1), i >= n[0]
+
 
 def _update_kernel(slots_ref, n_ref,                    # scalar prefetch
                    xdt_ref, dec_ref, b_ref, c_ref, sin_ref,
@@ -76,9 +128,13 @@ def _update_kernel(slots_ref, n_ref,                    # scalar prefetch
             y_ref[0, p:p + 1, :] = jnp.sum(new * cm, axis=0, keepdims=True)
 
 
-def ssm_state_update(pool, slots, n_live, xdt, dec, bh, ch):
+def ssm_state_update(pool, slots, n_live, xdt, dec, bh, ch, *,
+                     layout: str = HEADS_MINOR):
     """One step of the recurrence for the launch's decode rows, the
     pool updated in place.
+
+    ``layout="state_minor"``: `_update_state_minor` (pool [NS, H, P, N],
+    ``bh`` / ``ch`` a group's rows [R, G, N], not expanded).  Else:
 
     pool [NS, P, N, H] float32; ``slots`` [B] int32: the live slots
     FIRST (any order), then padding that names the spare slot NS - 1;
@@ -92,16 +148,14 @@ def ssm_state_update(pool, slots, n_live, xdt, dec, bh, ch):
     step holds [PB, N, H] of one slot's state; the steps past the live
     slots repeat the last live block, which stays resident and is not
     touched, so an idle slot's state is neither read nor written."""
+    if layout == STATE_MINOR:
+        return _update_state_minor(pool, slots, n_live, xdt, dec, bh, ch)
     NS, P, N, H = pool.shape
     B = slots.shape[0]
     PB = _p_block(P)
     J = P // PB
 
-    def at(i, slots, n):
-        # (slot, block of P) of step i: past the live ones, the last
-        # live slot's last block again
-        last = jnp.maximum(n[0] - 1, 0)
-        return jnp.clip(slots[jnp.minimum(i, last)], 0, NS - 1), i >= n[0]
+    at = functools.partial(_slot_at, NS)
 
     def state_map(i, j, slots, n):
         s, idle = at(i, slots, n)
@@ -139,6 +193,91 @@ def ssm_state_update(pool, slots, n_live, xdt, dec, bh, ch):
     return y, new_pool
 
 
+def _update_sm_kernel(slots_ref, n_ref,                 # scalar prefetch
+                      xdt_ref, dec_ref, b_ref, c_ref, sin_ref,
+                      y_ref, sout_ref, *, HB: int):
+    i, j = pl.program_id(0), pl.program_id(1)
+    n = n_ref[0]
+    seed = (n == 0) & (i == 0) & (j == 0)   # as `_update_kernel`'s
+
+    @pl.when((i < n) | seed)
+    def _step():
+        H = xdt_ref.shape[-1]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, H), 1)
+        xdt = xdt_ref[0]                                # [P, H]
+        dec = jnp.where(seed, 1.0, dec_ref[0])          # [1, H]
+        bm = jnp.where(seed, 0.0, b_ref[0])             # [1, N]
+        cm = c_ref[0]                                   # [1, N]
+
+        @pl.when(j == 0)
+        def _clear():
+            y_ref[0] = jnp.zeros_like(y_ref[0])
+
+        y = y_ref[0]
+        for hb in range(HB):
+            # head h's column of dt x and its decay, spread along the
+            # lanes: a masked lane sum, no lane -> sublane move
+            mine = lane == j * HB + hb
+            col = jnp.sum(jnp.where(mine, xdt, 0.0), 1, keepdims=True)
+            d = jnp.sum(jnp.where(mine, dec, 0.0), 1, keepdims=True)
+            new = d * sin_ref[0, hb] + col * bm         # [P, N]
+            sout_ref[0, hb] = new
+            y = jnp.where(mine, jnp.sum(new * cm, 1, keepdims=True), y)
+        y_ref[0] = y
+
+
+def _update_state_minor(pool, slots, n_live, xdt, dec, bm, cm):
+    """`ssm_state_update` over the state-minor pool [NS, H, P, N]:
+    ``xdt`` [R, P, H] and ``dec`` [R, 1, H] as there, ``bm`` / ``cm``
+    [R, G, N] a group's rows.  Returns (y [NS, P, H], the pool).  Grid
+    (B, H / HB): a step holds HB heads of ONE group of one slot, [HB, P,
+    N]; a slot's y block stays resident over its head blocks."""
+    NS, H, P, N = pool.shape
+    B = slots.shape[0]
+    R, G = bm.shape[0], bm.shape[1]
+    K = H // G
+    HB = _p_block(K, P * N * 4)
+    J = H // HB
+    f32 = jnp.float32
+
+    at = functools.partial(_slot_at, NS)
+
+    def state_map(i, j, slots, n):
+        s, idle = at(i, slots, n)
+        return (s, jnp.where(idle, J - 1, j), 0, 0)
+
+    def whole_map(i, j, slots, n):
+        return (at(i, slots, n)[0], 0, 0)
+
+    def group_map(i, j, slots, n):
+        s, idle = at(i, slots, n)
+        return (s * G + jnp.where(idle, J - 1, j) * HB // K, 0, 0)
+
+    state_spec = pl.BlockSpec((1, HB, P, N), state_map)
+    row_spec = pl.BlockSpec((1, P, H), whole_map)
+    group_spec = pl.BlockSpec((1, 1, N), group_map)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, J),
+        in_specs=[row_spec, pl.BlockSpec((1, 1, H), whole_map),
+                  group_spec, group_spec, state_spec],
+        out_specs=[row_spec, state_spec],
+    )
+    y, new_pool = pl.pallas_call(
+        functools.partial(_update_sm_kernel, HB=HB),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((NS, P, H), f32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=_interpret(),
+    )(slots.astype(jnp.int32), n_live.astype(jnp.int32), xdt, dec,
+      bm.astype(f32).reshape(R * G, 1, N),
+      cm.astype(f32).reshape(R * G, 1, N), pool)
+    return y, new_pool
+
+
 # ---------------------------------------------------------------------------
 # one slot written in place
 # ---------------------------------------------------------------------------
@@ -155,11 +294,12 @@ def _put_kernel(slot_ref, new_ref, pin_ref, po_ref):
 
 def ssm_state_put(pool, slot, state):
     """``pool`` [NS, P, N, H] with slot ``slot[0]`` replaced by ``state``
-    [P, N, H], in place: the other slots are not touched.  ``slot`` is
-    [2] int32: (the slot, whether to put at all) — with 0 there the
-    pool comes back as it was."""
+    [P, N, H], in place: the other slots are not touched (a state-minor
+    pool [NS, H, P, N] and its [H, P, N] alike: blocks of the second
+    dimension).  ``slot`` is [2] int32: (the slot, whether to put at
+    all) — with 0 there the pool comes back as it was."""
     NS, P, N, H = pool.shape
-    PB = _p_block(P)
+    PB = _p_block(P, N * H * pool.dtype.itemsize)
     J = P // PB
 
     def block(j, s):
@@ -187,12 +327,14 @@ def ssm_state_put(pool, slot, state):
 # a run of rows of one sequence, in scan chunks
 # ---------------------------------------------------------------------------
 
-def _scan_chunk(state, rows, *, G: int):
-    """One scan chunk: (state [P, N, H], (xdt [L, H, P], dA [L, H], B,
-    C [L, G, N])) -> (new state, y [L, H, P]); float32."""
+def _scan_chunk(state, rows, *, G: int, layout: str = HEADS_MINOR):
+    """One scan chunk: (state [P, N, H] — [H, P, N] state-minor —, (xdt
+    [L, H, P], dA [L, H], B, C [L, G, N])) -> (new state, y [L, H, P]);
+    float32."""
     xdt, dA, bm, cm = rows
     L, H, P = xdt.shape
     N, K = bm.shape[-1], H // G
+    sm = layout == STATE_MINOR
     hi = jax.lax.Precision.HIGHEST
     cs = jnp.cumsum(dA, 0)                              # [L, H], <= 0
     # inside the chunk: y_t += sum_{r<=t} exp(cs_t - cs_r) (C_t.B_r) xdt_r
@@ -204,22 +346,26 @@ def _scan_chunk(state, rows, *, G: int):
     x5 = xdt.reshape(L, G, K, P)
     y = jnp.einsum("trgk,rgkp->tgkp", m, x5)
     # from the state the chunk starts with
-    s5 = state.reshape(P, N, G, K)
+    s5 = state.reshape((G, K, P, N) if sm else (P, N, G, K))
     y = y + jnp.exp(cs).reshape(L, G, K, 1) * jnp.einsum(
-        "tgn,pngk->tgkp", cm, s5, precision=hi)
+        "tgn,gkpn->tgkp" if sm else "tgn,pngk->tgkp", cm, s5, precision=hi)
     # the state the chunk leaves
     w = jnp.exp(cs[-1][None] - cs).reshape(L, G, K, 1)
-    new = jnp.exp(cs[-1]).reshape(1, 1, G, K) * s5 + jnp.einsum(
-        "rgkp,rgn->pngk", x5 * w, bm, precision=hi)
-    return new.reshape(P, N, H), y.reshape(L, H, P)
+    last = jnp.exp(cs[-1]).reshape((G, K, 1, 1) if sm else (1, 1, G, K))
+    new = last * s5 + jnp.einsum(
+        "rgkp,rgn->gkpn" if sm else "rgkp,rgn->pngk", x5 * w, bm,
+        precision=hi)
+    return new.reshape(state.shape), y.reshape(L, H, P)
 
 
-def ssm_chunk_scan(xdt, dA, bm, cm, state, *, chunk: int = 128):
+def ssm_chunk_scan(xdt, dA, bm, cm, state, *, chunk: int = 128,
+                   layout: str = HEADS_MINOR):
     """A run of L rows of ONE sequence from ``state``, in scan chunks of
     ``chunk`` rows (L is padded up with identity rows).
 
     xdt [L, H, P] (dt x), dA [L, H] (dt A, <= 0), bm / cm [L, G, N],
-    state [P, N, H]; float32 inside. A row with ``dt`` 0 changes
+    state [P, N, H] ([H, P, N] under ``layout="state_minor"``); float32
+    inside. A row with ``dt`` 0 changes
     nothing (its xdt and dA are 0) and its own y is discarded by the
     caller. Returns (y [L, H, P] float32 = S_t C_t, the state after the
     last row)."""
@@ -230,8 +376,9 @@ def ssm_chunk_scan(xdt, dA, bm, cm, state, *, chunk: int = 128):
     rows = tuple(jnp.pad(a.astype(f32), ((0, pad),) + ((0, 0),) * (a.ndim - 1))
                  .reshape((-1, chunk) + a.shape[1:])
                  for a in (xdt, dA, bm, cm))
-    state, y = jax.lax.scan(functools.partial(_scan_chunk, G=G),
-                            state.astype(f32), rows)
+    state, y = jax.lax.scan(
+        functools.partial(_scan_chunk, G=G, layout=layout),
+        state.astype(f32), rows)
     return y.reshape(-1, H, P)[:L], state
 
 

@@ -284,13 +284,24 @@ def gmm_reference(lhs, rhs, group_sizes, block_m: int = 128,
     return out.astype(lhs.dtype)
 
 
-def ssm_state_update_reference(pool, slots, n_live, xdt, dec, bh, ch):
+def ssm_state_update_reference(pool, slots, n_live, xdt, dec, bh, ch, *,
+                               layout: str = "heads_minor"):
     """One step of the Mamba-2 recurrence for the live slots (the first
-    ``n_live`` of ``slots``); every other slot of the pool unchanged."""
+    ``n_live`` of ``slots``); every other slot of the pool unchanged.
+    ``layout="state_minor"``: pool [NS, H, P, N], ``bh`` / ``ch`` a
+    group's rows [R, G, N] (`pallas_ssm`)."""
     NS = pool.shape[0]
     f32 = jnp.float32
     live = jnp.zeros(NS, bool).at[slots].max(
         jnp.arange(slots.shape[0]) < n_live[0])
+    if layout == "state_minor":
+        K = pool.shape[1] // bh.shape[1]
+        bh, ch = (jnp.repeat(m[:NS].astype(f32), K, 1) for m in (bh, ch))
+        new = (dec[:NS, 0][:, :, None, None] * pool
+               + xdt[:NS].swapaxes(1, 2)[..., None] * bh[:, :, None])
+        y = jnp.sum(new * ch[:, :, None], -1).swapaxes(1, 2)
+        return (jnp.where(live[:, None, None], y, 0),
+                jnp.where(live[:, None, None, None], new, pool))
     new = (dec[:NS, 0][:, None, None, :] * pool
            + xdt[:NS, :, None, :] * bh[:NS].astype(f32)[:, None])
     y = jnp.sum(new * ch[:NS].astype(f32)[:, None], axis=2)

@@ -61,7 +61,7 @@ greedy property; sampling strategies belong to the batch APIs.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -87,8 +87,9 @@ from ..ops.pallas_kda import (kda_chunk_scan, kda_state_update,
                               kda_tileable)
 from ..ops.pallas_mhc import (mhc_enter, mhc_exit, mhc_post, mhc_pre,
                               mhc_tileable)
-from ..ops.pallas_ssm import (ssm_chunk_scan, ssm_state_put,
-                              ssm_state_update)
+from ..ops.pallas_ssm import (HEADS_MINOR, ssm_chunk_scan, ssm_state_put,
+                              ssm_state_update, state_layout,
+                              state_pool_shape)
 from ..ops.pallas_ragged import (ragged_head_block,
                                  ragged_kernel_eligible,
                                  ragged_narrow_rows, ragged_paged_attention,
@@ -382,6 +383,74 @@ def _latent_mixer(L, h, rope, pool, seq_start, num_tokens, kv_lengths,
     return y, pool
 
 
+def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
+               runs, *, heads: int, kv: int, d: int, mults=None):
+    """Grouped-query attention on the normed rows h [1, T, H] of a
+    block's input, over the pages `pools` = (K, V): q / k / v ->
+    `fused_rope_append` (rotary on all `d` dims, half-split pairs, by the
+    rows' angles `rope` = (cos, sin) [T, d / 2]; with ``None``, or
+    `_no_turn`'s pair, the model has NO rotary embedding and the kernel
+    appends under the identity turn) -> `ragged_paged_attention` ->
+    o-proj. `mults` (Falcon-H1):
+    the input's, the key's and the output's static multipliers. -> (the
+    mixer's output y [1, T, H], the pools). The hybrid body's ``*``
+    mixer, alone in its block (Nemotron-H) or beside a state-space mixer
+    on the same norm (Falcon-H1)."""
+    T = h.shape[1]
+    kp, vp = pools
+    cos, sin = rope or _no_turn(T, d, h.dtype)
+    with _scope("qkv_proj"):
+        if mults:
+            h = h * mults["attention_in"]
+        q, k, v = (_mm_heads(h, L, w) for w in ("wq", "wk", "wv"))
+        if mults:
+            k = k * mults["key"]
+    with _scope("cache_write"):
+        q, kp, vp = _once(
+            fused_rope_append, "cache_write", q.reshape(T, heads, d),
+            k.reshape(T, kv, d), v.reshape(T, kv, d), cos,
+            jnp.zeros_like(cos) if sin is None else sin, kp, vp, runs)
+    with _scope("attention"):
+        o = ragged_paged_attention(
+            q, kp, vp, seq_start, num_tokens, kv_lengths, tables,
+            scale=d ** -0.5, scope="attention")
+    with _scope("attn_out"):
+        y = o.reshape(1, T, heads * d) @ L["wo"]
+        if mults:
+            y = y * mults["attention_out"]
+    return y, (kp, vp)
+
+
+def _no_turn(T: int, d: int, dtype):
+    """`_gqa_mixer`'s `rope` for T rows of a model WITHOUT a rotary
+    embedding: cos 1 (and sin None, zeros made at the call)."""
+    return jnp.ones((T, d // 2), dtype), None
+
+
+def _pattern_blocks(pattern: str) -> Tuple[str, ...]:
+    """A hybrid's pattern as its blocks. A letter is a block of ONE
+    sublayer on its own norm — a mixer (``M`` ``K`` ``*`` ``L``) or an
+    FFN (``E`` ``D``); ``[..]`` is a block whose one norm feeds SEVERAL
+    mixers, their outputs summed into the residual (``[M*]D``: a
+    Falcon-H1 layer)."""
+    blocks, i = [], 0
+    while i < len(pattern):
+        if pattern[i] == "[":
+            j = pattern.index("]", i)
+            blocks.append(pattern[i + 1:j])
+        else:
+            j = i
+            blocks.append(pattern[i])
+        i = j + 1
+    for b in blocks:
+        if not b or set(b) - set("MK*LED") or \
+                len(b) > 1 and set(b) - set("MK*L"):
+            raise ValueError(
+                f"pattern {pattern!r}: a block is one letter of MK*LED, or "
+                f"several mixers (MK*L) in brackets")
+    return tuple(blocks)
+
+
 # -- the residual: how a step body enters, feeds and leaves it ---------
 class _Residual:
     """The ONE place a step body's residual is written. `enter`: the
@@ -648,18 +717,22 @@ class ServingEngine:
                 enable_prefix_cache, spec_decode, role)
             # a forked page's copy-on-write would copy pass 0 only
             enable_prefix_cache = prefix_sharing = False
-        # a hybrid's state-space blocks keep a FIXED-SIZE state a
+        # a hybrid's state-space mixers keep a FIXED-SIZE state a
         # sequence, in the slot the scheduler gave it, beside the pages
-        # of its attention blocks: two kinds of cache, one engine. A
-        # state cannot be cut at a token: nothing can adopt a prefix of
-        # it, roll it back or move it, and a slot that is given away
-        # takes the state with it
+        # of its attention mixers — in other blocks (Nemotron-H, Ling)
+        # or in the SAME block, on the same norm (Falcon-H1, where every
+        # layer holds a slot and pages): two kinds of cache, one
+        # engine. A state cannot be cut at a token: nothing can adopt a
+        # prefix of it, roll it back or move it, and a slot that is
+        # given away takes the state with it
         pattern = p["pattern"] if self._family == "hybrid" else ""
+        self._blocks = _pattern_blocks(pattern)
         self._ssm_layers = pattern.count("M") + pattern.count("K")
         # ... of ONE kind a model: Mamba-2's (`M`) or the delta rule's
         # (`K`, a KDA linear-attention block); and the attention blocks'
         # pages hold GQA rows (`*`) or latent rows (`L`)
         self._state_kind = "K" if "K" in pattern else "M"
+        self._state_layout = HEADS_MINOR
         self._latent = self._family == "mla" or "L" in pattern
         if self._ssm_layers:
             if "K" in pattern and "M" in pattern or \
@@ -777,11 +850,13 @@ class ServingEngine:
             # K whole and V in its first kv_lora_rank columns
             self._pools = [jnp.zeros(shape, dt) for _ in range(n_layers)]
         elif self._ssm_layers:
-            # the attention blocks' pages, and for each state-space
-            # block the slot-indexed state pool (heads-minor float32,
-            # `ops.pallas_ssm`) and the convolution's tail, one slot
-            # more than the scheduler's: the spare takes what idle rows
-            # and an absent chunk write
+            # the attention mixers' pages, and for each state-space
+            # mixer the slot-indexed state pool (float32, heads-minor
+            # or — fewer heads than lanes over a state of whole
+            # registers — state-minor: `ops.pallas_ssm.state_layout`)
+            # and the convolution's tail, one slot more than the
+            # scheduler's: the spare takes what idle rows and an absent
+            # chunk write
             # (a KDA block's state is heads-major, a [K, V] tile a head
             # with V along the lanes: `ops.pallas_kda` says why; its
             # tail holds the q, k and v convolutions' rows side by side)
@@ -796,9 +871,12 @@ class ServingEngine:
                         f"not whole sub-chunks of {cfg.kda_sub_chunk}")
                 self._state_shape = (self.max_slots + 1, nh, hd, hd)
             else:
-                self._state_shape = (
-                    self.max_slots + 1, cfg.mamba_head_dim,
-                    cfg.ssm_state_size, cfg.mamba_num_heads)
+                self._state_layout = state_layout(cfg.mamba_num_heads,
+                                                  cfg.ssm_state_size)
+                self._state_shape = state_pool_shape(
+                    self.max_slots + 1, cfg.mamba_num_heads,
+                    cfg.mamba_head_dim, cfg.ssm_state_size,
+                    self._state_layout)
             self._tail_shape = (self.max_slots + 1, cfg.conv_kernel - 1,
                                 cfg.conv_dim)
             self._pools = {
@@ -2699,13 +2777,17 @@ class ServingEngine:
         return step
 
     def _hybrid_unified_body(self, C: int):
-        """A hybrid (Nemotron-H, Ling 3.0) on the one launch: block l is
-        `x + mixer_l(RMSNorm(x))` with ONE mixer, of the kind the
-        model's pattern names (a Ling layer is two blocks).
+        """A hybrid (Nemotron-H, Ling 3.0, Falcon-H1) on the one launch:
+        block l is `x + sum of mixers_l(RMSNorm(x))`, its ONE norm
+        feeding the mixers the model's pattern names for it
+        (`_pattern_blocks`: one a block for Nemotron-H and Ling, whose
+        layer is two blocks; a state-space AND an attention mixer side by
+        side in Falcon-H1's ``[M*]``, whose layer is that block and a
+        ``D`` block), or `x + ffn_l(RMSNorm(x))`.
 
-        ``*``, attention without rotary: q / k / v -> `fused_rope_append`
-        under an identity table (a plain append) -> `ragged_paged_attention`
-        -> o-proj, over the pages of the attention blocks alone.
+        ``*``, grouped-query attention: `_gqa_mixer`, with the model's
+        rope table or — Nemotron-H has no rotary embedding — none, over
+        the pages of the attention mixers alone.
 
         ``L``, gated latent attention: `_latent_mixer`, the mla
         family's, over pages that hold latent rows.
@@ -2737,20 +2819,29 @@ class ServingEngine:
         FFN: `_ffn_apply` (`routed_ffn`, `latent_proj`,
         `shared_expert`; `ffn`).
 
+        A model's static multipliers (``mults``, Falcon-H1's fourteen)
+        are applied where its equations put them, inside the scope of
+        the operation they scale; without them no operation is added.
+
         ``kv_lengths`` is a pair: (the attention blocks' lengths, the
         state table [B + 3]: the live decode slots then the spare, their
         count, the chunk's slot, whether the launch starts it)."""
         cfg, pattern = self._p["cfg"], self._p["pattern"]
+        blocks, mu = self._blocks, self._p.get("mults")
         eps, K = cfg.layer_norm_epsilon, cfg.conv_kernel
         moe_static = self._p["moe_static"]
+        layout = self._state_layout
         B = self.max_slots
         T = B + C
         seq_start = _seq_starts(B, 1)
         run_table = self._run_table(seq_start)
         f32 = jnp.float32
         if "*" in pattern:
-            Hq, KV, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                         cfg.head_dim)
+            gqa = dict(heads=cfg.num_attention_heads,
+                       kv=cfg.num_key_value_heads, d=cfg.head_dim, mults=mu)
+        if mu:
+            from ..models.falcon_h1 import mup_vector
+            ffn_mults = {k: mu[k] for k in ("mlp_gate", "mlp_down")}
         if "M" in pattern:
             Hm, P, G = cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups
         if "K" in pattern:
@@ -2825,7 +2916,12 @@ class ServingEngine:
             n_c = num_tokens[B]
             live = num_tokens[:B] > 0
             with jax.named_scope("ssm_in_proj"):
-                z, u, dt = ssm_split(a @ L["w_in"], cfg)
+                if mu:
+                    zxbcdt = ((a * mu["ssm_in"]) @ L["w_in"]) \
+                        * mup_vector(cfg, dt_w)
+                else:
+                    zxbcdt = a @ L["w_in"]
+                z, u, dt = ssm_split(zxbcdt, cfg)
             with jax.named_scope("ssm_conv"):
                 u, t_pool = conv_tails(u, t_pool, L["conv_w"], L["conv_b"],
                                        live, n_c, cslot, starts)
@@ -2836,12 +2932,18 @@ class ServingEngine:
                 # (row s of the operands is slot s; row B the spare's)
                 rows = slice(0, B + 1)
                 xs, dts, dAs = with_spare(xf), with_spare(dt), with_spare(dA)
-                heads = lambda m: jnp.repeat(          # noqa: E731
-                    with_spare(m)[rows], Hm // G, axis=1).swapaxes(1, 2)
+                if layout == HEADS_MINOR:
+                    # a group's B / C rows expanded to its heads, [N, H]
+                    heads = lambda m: jnp.repeat(          # noqa: E731
+                        with_spare(m)[rows], Hm // G, axis=1).swapaxes(1, 2)
+                else:
+                    # state-minor: a group's rows as they are, [G, N]
+                    heads = lambda m: with_spare(m)[rows]  # noqa: E731
                 y_d, z_pool = _once(
                     ssm_state_update, "ssm_scan", z_pool, slots, n_live,
                     (xs[rows] * dts[rows, :, None]).swapaxes(1, 2),
-                    jnp.exp(dAs[rows])[:, None, :], heads(bm), heads(cm))
+                    jnp.exp(dAs[rows])[:, None, :], heads(bm), heads(cm),
+                    layout=layout)
                 y = jnp.where(live[:, None, None],
                               y_d[:B].swapaxes(1, 2), 0)
                 if C:
@@ -2855,13 +2957,17 @@ class ServingEngine:
                             ssm_chunk_scan, "ssm_scan",
                             xf[B:] * dt_c[..., None],
                             jnp.where(valid, dA[B:], 0), bm[B:], cm[B:], s0,
-                            chunk=cfg.chunk_size), (C, Hm, P), "ssm_scan")
+                            chunk=cfg.chunk_size, layout=layout), (C, Hm, P),
+                        "ssm_scan")
                     y = jnp.concatenate([y, y_c])
                 y = y + L["D"].astype(f32)[None, :, None] * xf
             with jax.named_scope("ssm_out"):
                 y = ssm_gated_norm(y.reshape(T, Hm * P), z, L["norm_g"], G,
                                    eps).astype(dt_w)
-                return y @ L["w_out"], z_pool, t_pool
+                y = y @ L["w_out"]
+                if mu:
+                    y = y * mu["ssm_out"]
+                return y, z_pool, t_pool
 
         def kda(L, a, z_pool, t_pool, num_tokens, tab):
             """The KDA mixer of a [T, hidden] -> (its output [T, hidden],
@@ -2914,63 +3020,67 @@ class ServingEngine:
             kv_lengths, tab = kv_lengths
             with _scope("embed"):
                 x = w["embed"][tok][None]                # [1, T, hidden]
+                if mu:
+                    x = x * mu["embedding"]
+            # the rows' angles, where the model has a position table
+            # (none: no rotary embedding)
+            rope = None
             if "L" in pattern:
                 rope = _halves_rope(w["cos"][positions],
                                     w["sin"][positions])
-            else:
-                # no rotary embedding, no position table
-                one = jnp.ones((T, D // 2), x.dtype)
+            elif "cos" in w:
+                rope = w["cos"][positions], w["sin"][positions]
+            elif "*" in pattern:
+                # (made once a step, here, as the parent's text has it)
+                rope = _no_turn(T, gqa["d"], x.dtype)
             with _scope("cache_write"):
                 runs = run_table(num_tokens, tok_page, tok_off)
             kv_pools, ssm_pools = iter(pools["kv"]), iter(pools["ssm"])
             sts = iter(moe_static)
             new_kv, new_ssm, moe_stats = [], [], []
             live = _owned_rows(T, seq_start, num_tokens)
-            for i, kind in enumerate(pattern):  # a letter: static
+            for i, block in enumerate(blocks):  # its letters: static
                 L = w["layers"][i]
                 a = _once(fused_rms_norm,
-                          "ffn_norm" if kind in "ED" else "attn_norm",
+                          "ffn_norm" if block in "ED" else "attn_norm",
                           x, L["norm"], eps=eps)
-                if kind == "E":
+                if block == "E":
                     x = x + _ffn_apply(L, a, next(sts), moe_stats, live)
-                elif kind == "D":
-                    x = x + _ffn_apply(L, a)
-                elif kind in "MK":
-                    y, z_pool, t_pool = (ssm if kind == "M" else kda)(
-                        L, a[0], *next(ssm_pools), num_tokens, tab)
-                    new_ssm.append((z_pool, t_pool))
-                    x = x + y[None]
-                elif kind == "L":
-                    y, pool = _latent_mixer(
-                        L, a, rope, next(kv_pools), seq_start,
-                        num_tokens, kv_lengths, tables, runs, **latent)
-                    new_kv.append(pool)
+                    continue
+                if block == "D":
+                    x = x + (_ffn_apply(L, a, ffn_mults) if mu
+                             else _ffn_apply(L, a))
+                    continue
+                # the block's mixers, each on the one normed input; the
+                # residual takes their sum
+                for kind in block:
+                    if kind in "MK":
+                        y, z_pool, t_pool = (ssm if kind == "M" else kda)(
+                            L, a[0], *next(ssm_pools), num_tokens, tab)
+                        new_ssm.append((z_pool, t_pool))
+                        y = y[None]
+                    elif kind == "L":
+                        y, pool = _latent_mixer(
+                            L, a, rope, next(kv_pools), seq_start,
+                            num_tokens, kv_lengths, tables, runs, **latent)
+                        new_kv.append(pool)
+                    else:
+                        y, pool = _gqa_mixer(
+                            L, a, rope, next(kv_pools), seq_start,
+                            num_tokens, kv_lengths, tables, runs, **gqa)
+                        new_kv.append(pool)
                     x = x + y
-                else:
-                    kp, vp = next(kv_pools)
-                    with _scope("qkv_proj"):
-                        q, k, v = (_mm_heads(a, L, w)
-                                   for w in ("wq", "wk", "wv"))
-                    with _scope("cache_write"):
-                        q, kp, vp = _once(
-                            fused_rope_append, "cache_write",
-                            q.reshape(T, Hq, D), k.reshape(T, KV, D),
-                            v.reshape(T, KV, D), one, jnp.zeros_like(one),
-                            kp, vp, runs)
-                    new_kv.append((kp, vp))
-                    with _scope("attention"):
-                        o = ragged_paged_attention(
-                            q, kp, vp, seq_start, num_tokens, kv_lengths,
-                            tables, scale=D ** -0.5, scope="attention")
-                    with _scope("attn_out"):
-                        x = x + o.reshape(1, T, Hq * D) @ L["wo"]
             with _scope("head"):
                 x = fused_rms_norm(x, w["norm"], eps)
                 logits = _head_logits(
                     w, _logit_rows(x, seq_start, num_tokens, 0))
+                if mu:
+                    logits = logits * mu["lm_head"]
                 tokens = _greedy(logits)
-            return (logits, {"kv": new_kv, "ssm": new_ssm}, tokens,
-                    _moe_step_counts(moe_stats))
+            out = logits, {"kv": new_kv, "ssm": new_ssm}, tokens
+            # (a model without routed FFNs has no such counts)
+            return out + (_moe_step_counts(moe_stats),) if moe_stats \
+                else out
 
         return step
 

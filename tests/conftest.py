@@ -94,6 +94,34 @@ def pytest_runtest_protocol(item):
 
 
 # ---------------------------------------------------------------------------
+# jax keeps every jitted function's fast-path entries of a PROCESS in two
+# shared LRU lists of 8,192 (`jax._src.pjit._cpp_pjit_cache_*`). A worker
+# that has run a few hundred cases — the eager OCR trainings alone dispatch
+# thousands of op signatures — fills them, and a function jitted after that
+# keeps NO entry: `f._cache_size()` reads 0 after a call, and the cases that
+# count a program's compiled variants (`program_cache_sizes()`,
+# `test_ragged_kernel.py::TestRaggedJit`) fail on whichever worker got
+# there first (PR 54: five `TestDisaggregated` cases in one run, a
+# `TestRaggedJit` case in the next). Before a FILE's first case, lists more
+# than half full are emptied: the entries only, not the traced or compiled
+# programs behind them, so the next call of a live function re-enters at
+# the cost of a dictionary lookup.
+# ---------------------------------------------------------------------------
+@pytest.fixture(autouse=True, scope="module")
+def _room_in_the_jit_fast_path_lists():
+    try:
+        from jax._src import pjit as _pjit
+        lists = (_pjit._cpp_pjit_cache_fun_only,
+                 _pjit._cpp_pjit_cache_explicit_attributes)
+        if any(2 * c.size() > c.capacity() for c in lists):
+            for c in lists:
+                c.clear_all()
+    except (ImportError, AttributeError):   # another jax: its own lists
+        pass
+    yield
+
+
+# ---------------------------------------------------------------------------
 # global-state hygiene: tests that fleet.init() a hybrid mesh must not leak
 # it into later tests (the ambient mesh changes eager-collective routing)
 # ---------------------------------------------------------------------------

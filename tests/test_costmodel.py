@@ -104,6 +104,35 @@ class TestRegistryCoverage:
         assert cm.kda_state_bytes_per_seq_layer(
             heads=32, head_dim=128, conv_kernel=4) == 2_097_152 + 73_728
 
+    @pytest.mark.parametrize("heads, head_dim, state, layout, stored", [
+        (128, 64, 128, "heads_minor", 4_194_304),       # Nemotron-3-Super
+        (128, 64, 128, "state_minor", 4_194_304),
+        (32, 128, 256, "heads_minor", 16_777_216),      # Falcon-H1-34B: x 4
+        (32, 128, 256, "state_minor", 4_194_304),
+        (8, 8, 16, "heads_minor", 8 * 16 * 128 * 4)])
+    def test_the_state_as_a_layout_stores_it(self, heads, head_dim, state,
+                                             layout, stored):
+        """A pool's minor dimension is stored in whole 128-lane rows."""
+        assert cm.ssm_state_stored_bytes(
+            heads=heads, head_dim=head_dim, state_size=state,
+            layout=layout) == stored
+        assert cm.ssm_state_bytes_per_seq_layer(
+            heads=32, head_dim=128, state_size=256, conv_dim=5120,
+            conv_kernel=4) == 4_194_304 + 30_720
+
+    def test_the_state_update_costs_by_layout(self):
+        """Falcon-H1-34B's update of 58 live slots: the state once in and
+        once out either way; a group's B / C rows [2, 256] float32
+        state-minor against [256, 32] in the serving type expanded."""
+        shape = dict(live=58, P=128, N=256, H=32)
+        hm = cm.cost("ssm_state_update", **shape)
+        sm = cm.cost("ssm_state_update", layout="state_minor", G=2, **shape)
+        assert hm.breakdown["state"] == sm.breakdown["state"] \
+            == 2 * 58 * 4_194_304
+        assert hm.flops == sm.flops == 5 * 58 * 128 * 256 * 32
+        assert hm.hbm_bytes - sm.hbm_bytes == 58 * (
+            2 * 256 * 32 * 2 - 2 * 2 * 256 * 4)
+
     def test_unknown_kernel_raises_with_known_list(self):
         with pytest.raises(KeyError, match="known"):
             cm.cost("no_such_kernel", T=1)

@@ -30,6 +30,7 @@ from paddle_tpu.observability import tracing
 from paddle_tpu.serving import ServingEngine
 from test_bailing_hybrid_serving import seeded as ling_seeded
 from test_engine_programs import _laguna, _tiny
+from test_falcon_h1 import seeded as falcon_seeded
 from test_nemotron_h import seeded as nemotron_seeded
 from test_ouro import seeded as ouro_seeded
 
@@ -61,6 +62,7 @@ CASES = {
         num_pages=24)),
     "hybrid_ssm": (lambda: nemotron_seeded()[0], _HYBRID),
     "hybrid_kda": (lambda: ling_seeded()[0], _HYBRID),
+    "hybrid_two_mixers": (lambda: falcon_seeded()[0], _HYBRID),
     "gpt": (lambda: _tiny("gpt"), _DENSE),
     "mla": (lambda: _tiny("mla"), _DENSE),
     "llama_spec": (lambda: _tiny("llama"), dict(_DENSE, spec_decode=2)),

@@ -314,6 +314,11 @@ def _nemotron():
     return m
 
 
+def _falcon():
+    from test_falcon_h1 import seeded
+    return seeded(max_position_embeddings=64)[0]
+
+
 #: family -> names its unified step must show
 FAMILIES = {
     "llama": {"ffn"}, "moe": {"routed_ffn", "shared_expert"},
@@ -321,6 +326,9 @@ FAMILIES = {
     "laguna": {"routed_ffn", "shared_expert"}, "eva": {"ffn"},
     "looped": {"ffn", "loop_norm"},
     "hybrid": {"routed_ffn", "shared_expert"},
+    # both mixers of a block on one norm, a dense FFN: every name of a
+    # layer (the state-space parts answer through SCOPE_ALIASES)
+    "hybrid_two_mixers": {"ffn"},
 }
 EVERY_STEP = {"embed", "attn_norm", "qkv_proj", "cache_write", "attention",
               "attn_out", "ffn_norm", "head"}
@@ -330,7 +338,8 @@ EVERY_STEP = {"embed", "attn_norm", "qkv_proj", "cache_write", "attention",
 def test_every_op_of_a_serving_step_answers_to_a_name(family):
     m = _laguna() if family == "laguna" else _eva() if family == "eva" \
         else _ouro() if family == "looped" \
-        else _nemotron() if family == "hybrid" else _tiny(family)
+        else _nemotron() if family == "hybrid" \
+        else _falcon() if family == "hybrid_two_mixers" else _tiny(family)
     kw = dict(max_slots=3, page_size=8, max_context=256, prefill_chunk=8,
               num_pages=64) if family == "eva" else \
         dict(max_slots=2, page_size=8, max_context=64, prefill_chunk=8)
@@ -360,6 +369,17 @@ def test_every_op_of_a_serving_step_answers_to_a_name(family):
         scoped = sum(r.scope is not None for r in step)
         assert scoped >= 0.9 * len(step), (name, scoped, len(step), [
             r for r in step if r.scope is None][:10])
+        if family == "hybrid_two_mixers":
+            # the mixers' own names, and no arithmetic outside a name:
+            # each multiplier is applied inside the scope of the
+            # operation it scales
+            assert {"ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_out"} \
+                <= {r.own for r in step}
+            # (what has none: the zero state and zero y of a launch
+            # whose chunk is absent, the hybrid body's `chunk_state`)
+            fills = {"f32[4,8,128]", "f32[8,4,8]"}
+            assert not [r for r in step if r.scope is None
+                        and r.kind == "compute" and r.shape not in fills]
     # ... and read together, as a trace's reader does: a key the two
     # answer differently keeps neither scope, and few do
     table = at.op_scopes(programs)

@@ -2,15 +2,19 @@
 against the recurrence token by token: one step for the live slots in
 place (idle slots bit-unchanged), the chunk's scan at chunk lengths
 around the scan chunk (1, 127, 128, 129, 256 rows), `dt = 0` padding as
-the identity, and one slot put in place."""
+the identity, and one slot put in place; and the same over the
+state-minor pool [slots, H, P, N] that a model of fewer than 128 heads
+over a state of whole registers takes (`TestStateMinor`)."""
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from paddle_tpu.ops.oracles import oracles, resolve_reference
-from paddle_tpu.ops.pallas_ssm import (ssm_chunk_scan, ssm_state_put,
-                                       ssm_state_update)
+from paddle_tpu.ops.pallas_ssm import (HEADS_MINOR, STATE_MINOR,
+                                       ssm_chunk_scan, ssm_state_put,
+                                       ssm_state_update, state_layout,
+                                       state_pool_shape)
 from paddle_tpu.ops.references import \
     ssm_recurrence_reference as ssm_recurrence
 
@@ -128,3 +132,101 @@ class TestStatePut:
         want = resolve_reference(oracles()["ssm_state_put"])(*args)
         np.testing.assert_array_equal(ssm_state_put(*args), want)
         assert bool((want[slot] == state).all()) == bool(go)
+
+
+# ------------------------------------------- the state-minor layout
+#: fewer heads than lanes over a state of whole registers: 4 heads of 8
+#: in 2 groups over a state of 128 (Falcon-H1-34B's 32 x 128 over 256)
+SH, SP, SG, SN = 4, 8, 2, 128
+
+
+def _sm_rows(rng, L):
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.3), (L, SH)))
+    A = -rng.uniform(1, 16, SH)
+    return (jnp.asarray(rng.normal(0, 1, (L, SH, SP)) * dt[..., None],
+                        jnp.float32),
+            jnp.asarray(dt * A, jnp.float32),
+            jnp.asarray(rng.normal(0, 1, (L, SG, SN)), jnp.float32),
+            jnp.asarray(rng.normal(0, 1, (L, SG, SN)), jnp.float32))
+
+
+def _turned(s):
+    """[H, P, N] -> the heads-minor [P, N, H] the recurrence is in."""
+    return jnp.transpose(s, (1, 2, 0))
+
+
+class TestStateMinor:
+    @pytest.mark.parametrize("H, N, want", [
+        (128, 128, HEADS_MINOR), (32, 256, STATE_MINOR),
+        (8, 16, HEADS_MINOR), (4, 128, STATE_MINOR),
+        (256, 128, HEADS_MINOR), (32, 64, HEADS_MINOR)])
+    def test_the_lanes_pick_the_layout(self, H, N, want):
+        assert state_layout(H, N) == want
+        shape = state_pool_shape(3, H, 64, N, want)
+        assert shape == ((3, H, 64, N) if want == STATE_MINOR
+                         else (3, 64, N, H))
+
+    @pytest.mark.parametrize("live", [[], [2], [0, 3, 1], [4, 0, 1, 2, 3]])
+    def test_one_step_for_the_live_slots_in_place(self, live):
+        """5 slots + the spare: an idle slot comes back bit for bit."""
+        rng = np.random.default_rng(len(live) + 20)
+        NS, B = 6, 5
+        pool = jnp.asarray(rng.normal(0, 1, (NS, SH, SP, SN)), jnp.float32)
+        xdt, dA, bm, cm = _sm_rows(rng, NS)
+        slots = np.full(B, NS - 1, np.int32)
+        slots[:len(live)] = live
+        args = (pool, jnp.asarray(slots),
+                jnp.asarray([len(live)], jnp.int32), xdt.swapaxes(1, 2),
+                jnp.exp(dA)[:, None, :], bm, cm)
+        want_y, want_pool = resolve_reference(
+            oracles()["ssm_state_update"])(*args, layout=STATE_MINOR)
+        got_y, got_pool = ssm_state_update(*args, layout=STATE_MINOR)
+        idle = [s for s in range(NS) if s not in live]
+        np.testing.assert_array_equal(np.asarray(got_pool)[idle],
+                                      np.asarray(pool)[idle])
+        np.testing.assert_allclose(got_pool, want_pool, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(got_y)[live],
+                                   np.asarray(want_y)[live], atol=1e-4)
+        # ... and a live slot's step is the recurrence's
+        for s in live[:2]:
+            y1, s1 = ssm_recurrence(xdt[s:s + 1], dA[s:s + 1], bm[s:s + 1],
+                                    cm[s:s + 1], _turned(pool[s]))
+            np.testing.assert_allclose(_turned(got_pool[s]), s1, atol=1e-5)
+            np.testing.assert_allclose(got_y[s].T, y1[0], atol=1e-4)
+
+    @pytest.mark.parametrize("L", [1, 15, 16, 17, 40])
+    def test_the_scan_hands_its_state_to_the_update(self, L):
+        """A chunk's scan from a slot's state, its last state put back,
+        then one decode step of that slot: the recurrence over L + 1
+        rows."""
+        rng = np.random.default_rng(L)
+        rows = _sm_rows(rng, L + 1)
+        pool = jnp.asarray(rng.normal(0, 1, (3, SH, SP, SN)), jnp.float32)
+        want_y, want_s = ssm_recurrence(*rows, _turned(pool[1]))
+        y, s1 = ssm_chunk_scan(*(a[:L] for a in rows), pool[1], chunk=16,
+                               layout=STATE_MINOR)
+        assert s1.shape == (SH, SP, SN)
+        put = ssm_state_put(pool, jnp.asarray([1, 1], jnp.int32), s1)
+        np.testing.assert_array_equal(put[0], pool[0])
+        np.testing.assert_array_equal(put[2], pool[2])
+        xdt, dA, bm, cm = (jnp.broadcast_to(a[L:], (3,) + a.shape[1:])
+                           for a in rows)
+        y2, new = ssm_state_update(
+            put, jnp.asarray([1, 2], jnp.int32), jnp.asarray([1], jnp.int32),
+            xdt.swapaxes(1, 2), jnp.exp(dA)[:, None, :], bm, cm,
+            layout=STATE_MINOR)
+        np.testing.assert_allclose(y, want_y[:L], atol=2e-4, rtol=2e-4)
+        np.testing.assert_allclose(y2[1].T, want_y[L], atol=2e-4, rtol=2e-4)
+        np.testing.assert_allclose(_turned(new[1]), want_s, atol=2e-4,
+                                   rtol=2e-4)
+        np.testing.assert_array_equal(new[0], pool[0])
+        np.testing.assert_array_equal(new[2], pool[2])
+
+    @pytest.mark.parametrize("slot, go", [(0, 1), (2, 1), (1, 0)])
+    def test_one_slot_put_in_place(self, slot, go):
+        rng = np.random.default_rng(slot + 30)
+        pool = jnp.asarray(rng.normal(0, 1, (3, 32, SP, SN)), jnp.float32)
+        state = jnp.asarray(rng.normal(0, 1, (32, SP, SN)), jnp.float32)
+        args = (pool, jnp.asarray([slot, go], jnp.int32), state)
+        want = resolve_reference(oracles()["ssm_state_put"])(*args)
+        np.testing.assert_array_equal(ssm_state_put(*args), want)

@@ -407,6 +407,36 @@ def test_state_space_kernels_compile_at_the_nemotron_cell_shapes(chip):
         chip.shape((c, g, n))), chip.refusals.get(_ssm_layer_kernels)
 
 
+def _ssm_state_minor_kernels(pool, tab, xdt, dec, bm, cm, xc, dac, bc, cc):
+    from paddle_tpu.ops.pallas_ssm import (STATE_MINOR, ssm_chunk_scan,
+                                           ssm_state_put, ssm_state_update)
+    b = tab.shape[0] - 3
+    y, pool = ssm_state_update(pool, tab[:b], tab[b:b + 1], xdt, dec, bm, cm,
+                               layout=STATE_MINOR)
+    yc, s1 = ssm_chunk_scan(xc, dac, bc, cc, pool[tab[b + 1]], chunk=128,
+                            layout=STATE_MINOR)
+    return y, yc, ssm_state_put(pool, tab[b + 1:], s1)
+
+
+def test_state_space_kernels_compile_at_the_falcon_cell_shapes(chip):
+    """`falcon-h1-34b-serve-pp8-d9` as its cell runs it: a state pool of
+    64 slots + the spare x [32, 128, 256] float32 (STATE minor: 32 heads
+    would fill a quarter of the lanes), the decode rows' update in place
+    with a group's B / C rows not expanded, a 256-row chunk's scan in two
+    scan chunks of 128 (32 heads x 128 in 2 groups, state 256) and its
+    state's write in place in blocks of 8 heads."""
+    from paddle_tpu.ops.pallas_ssm import STATE_MINOR, state_layout
+    ns, p, n, h, g, c = 65, 128, 256, 32, 2, 256
+    assert state_layout(h, n) == STATE_MINOR
+    assert chip.compiles(
+        _ssm_state_minor_kernels, chip.shape((ns, h, p, n), F32),
+        chip.shape((ns + 2,), I32), chip.shape((ns, p, h), F32),
+        chip.shape((ns, 1, h), F32), chip.shape((ns, g, n)),
+        chip.shape((ns, g, n)), chip.shape((c, h, p), F32),
+        chip.shape((c, h), F32), chip.shape((c, g, n)),
+        chip.shape((c, g, n))), chip.refusals.get(_ssm_state_minor_kernels)
+
+
 def _kda_layer_kernels(pool, tab, q, k, v, g, beta, qc, kc, vc, gc, bc):
     from paddle_tpu.ops.pallas_kda import kda_chunk_scan, kda_state_update
     from paddle_tpu.ops.pallas_ssm import ssm_state_put
