@@ -48,6 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import count_block_pairs
+from .lane_stat import lanes as _lanes
 from .on_mesh import on_mesh
 
 __all__ = ["flash_sdpa", "flash_kernel_eligible"]
@@ -143,18 +144,6 @@ def _sweep(tabs, init, pair, emit, *, bq, bk, causal, off, use_seg,
     if not use_seg:
         pl.when(fl & _MASK == 0)(lambda: pair(None))
     pl.when(fl & _LAST != 0)(emit)
-
-
-def _lanes(x, n):
-    """The forward's running maximum and sum are LANE-REPLICATED
-    [rows, 128] (every lane of a row holds the row's value, as in the
-    bundled kernel), so they meet the [bq, bk] scores and the [bq, D]
-    accumulator lane for lane: as [bq, 1] columns a launch of the
-    training cell's shape took 8.3 ms where this form takes 5.0 (PERF.md,
-    PR 49). Here: such a statistic against [rows, n] data."""
-    if n % 128:
-        return x[:, :1]
-    return jnp.tile(x, (1, n // 128)) if n > 128 else x
 
 
 def _fwd_kernel(qi_tab, kj_tab, fl_tab, sq_ref, sk_ref, q_ref, k_ref, v_ref,

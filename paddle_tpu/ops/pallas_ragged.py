@@ -67,6 +67,16 @@ same update on a narrower extent of q, m, l and acc, chosen once a pair
 from the row tables (`_narrow_window`, the rule `ragged_narrow_updates`
 counts with). GQA-native, f32 scores / softmax state /
 accumulator, interpret mode off-TPU.
+
+The softmax state m and l (and a visit's m_new and alpha) is held
+LANE-REPLICATED: [rows, 128] with a row's value in every lane, meeting
+the [rows, page] scores and the [rows, D] accumulator lane for lane
+(`lane_stat.lanes`: tiled along a multiple of 128 columns, a lane
+slice under them). As [rows, 1] columns — one live lane a register,
+spread along the lanes again for every chain of every visit — a (tile,
+page) update of the latent launch took 0.52-0.56 us where this form
+takes 0.42-0.47, and the output is that form's bit for bit (PERF.md
+section 6, PR 55).
 """
 
 from __future__ import annotations
@@ -82,6 +92,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .lane_stat import lanes as _lanes
 from .pallas_paged import paged_kernel_eligible
 
 __all__ = ["ragged_paged_attention", "ragged_attention_reference",
@@ -156,8 +167,9 @@ def _block_vmem(hb: int, rows: int, D: int, psz: int, itemsize: int,
                 tb: int = 1, v_dim: Optional[int] = None) -> int:
     """VMEM bytes of a grid cell that serves `hb` KV heads and `tb`
     query tiles: the query and output tiles (double-buffered by the
-    pipeline), the f32 accumulator, m and l (a [rows, 1] column takes
-    whole 128-lane tiles), the K and the V ring. Pages that hold K and
+    pipeline), the f32 accumulator, m and l (lane-replicated [rows,
+    128]: what a [rows, 1] column took too, in whole 128-lane tiles;
+    PERF.md section 6, PR 55), the K and the V ring. Pages that hold K and
     V in one row (`v_dim`) have ONE ring, and an output and an
     accumulator of `v_dim` columns."""
     q, o = hb * tb * rows * D, hb * tb * rows * (v_dim or D)
@@ -533,10 +545,12 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
                     # gives an exact 0: a row with nothing to attend here
                     # keeps m, l, acc
                     s = jnp.where(seen[b], s, _MASKED)
+                    # m, l, alpha: [rows, 128], a row's value in every
+                    # lane (`_lanes`)
                     m_prev = m_ref[at]
                     m_new = jnp.maximum(m_prev,
                                         jnp.max(s, -1, keepdims=True))
-                    p = jnp.exp(s - m_new)
+                    p = jnp.exp(s - _lanes(m_new, s.shape[1]))
                     alpha = jnp.exp(m_prev - m_new)
                     l_ref[at] = l_ref[at] * alpha \
                         + jnp.sum(p, -1, keepdims=True)
@@ -544,7 +558,7 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
                     p_run[g].append(p.astype(v_run[g].dtype))
             for g in range(hb):
                 for n, (at, alpha, m_new) in enumerate(chains[g]):
-                    scaled = acc_ref[at] * alpha
+                    scaled = acc_ref[at] * _lanes(alpha, acc_ref.shape[-1])
                     if n == 0:  # (behind the first rescale: the order of
                         # the one-tile launch's text, which is pinned)
                         pv = jax.lax.dot_general(
@@ -631,8 +645,8 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
     for g in range(hb):
         for b in range(tb):
             l = l_ref[chain(g, b)]
-            o_ref[run(g, (b,))] = (
-                acc_ref[chain(g, b)] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref[run(g, (b,))] = (acc_ref[chain(g, b)] / _lanes(
+                jnp.where(l == 0.0, 1.0, l), acc_ref.shape[-1])).astype(
                     o_ref.dtype)
 
 
@@ -750,8 +764,8 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
         scratch_shapes=[pltpu.VMEM((depth, hb, psz, D), k_pages.dtype),
                         pltpu.VMEM((depth, hb, psz, D), v_pages.dtype),
                         pltpu.VMEM((hb * tb, rows, D), jnp.float32),
-                        pltpu.VMEM((hb * tb, rows, 1), jnp.float32),
-                        pltpu.VMEM((hb * tb, rows, 1), jnp.float32),
+                        pltpu.VMEM((hb * tb, rows, 128), jnp.float32),
+                        pltpu.VMEM((hb * tb, rows, 128), jnp.float32),
                         pltpu.SMEM((2,), jnp.int32),
                         pltpu.SemaphoreType.DMA((2, depth))],
     )
@@ -801,8 +815,8 @@ def _latent_call(qg, pages, tables, v_dim, rows, depth, n_cells,
         out_specs=pl.BlockSpec((1, tb * rows, v_dim), _tile_map),
         scratch_shapes=[pltpu.VMEM((depth, psz, D), pages.dtype),
                         pltpu.VMEM((*chains, rows, v_dim), jnp.float32),
-                        pltpu.VMEM((*chains, rows, 1), jnp.float32),
-                        pltpu.VMEM((*chains, rows, 1), jnp.float32),
+                        pltpu.VMEM((*chains, rows, 128), jnp.float32),
+                        pltpu.VMEM((*chains, rows, 128), jnp.float32),
                         pltpu.SMEM((2,), jnp.int32),
                         pltpu.SemaphoreType.DMA((1, depth))],
     )

@@ -396,9 +396,10 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
 #: [heads, D, in]) re-recorded it: its one attention block reads them so.
 #: PR 53 (the per-layer kernels through one jitted copy a step's layers
 #: share, `engine._once`: the norms, the state updates, the chunk's scan
-#: and put) re-recorded it and Ling's.
+#: and put) re-recorded it and Ling's, PR 55 (the ragged kernel's softmax
+#: state lane-replicated: `test_evabyte_serving`) both with the seven.
 HYBRID_LOWERED_AT_PARENT = \
-    "2b1de72304acc6d040d8e67da616ddae6510eebebf6143bed2600ec37d8a80a6"
+    "0a3d02ddbd654578ff999818b1ee799ddf49fb8135d5e873f2f64e99e1f318c9"
 
 
 def test_the_nemotron_step_lowers_to_the_parents_text():
@@ -418,7 +419,7 @@ def test_the_nemotron_step_lowers_to_the_parents_text():
 #: run table, which a pattern with ``L`` now makes too) re-recorded it;
 #: Nemotron's, whose pattern made that table already, did not move.
 LING_LOWERED_AT_PARENT = \
-    "65d1f21716b66901eeb9b253aeda4d815e533cc82e4c8264a2964735519b368a"
+    "414cd126ad8267e4138a365f59bbc3bf74b0f0e6042597e91e364c86adfb9030"
 
 
 def test_the_ling_step_lowers_to_the_parents_text(tiny):

@@ -255,9 +255,10 @@ def test_an_untileable_shape_is_refused(tiny, monkeypatch):
 #: add no operation where they are not asked for. PR 45 (a decode row's
 #: page visit of the ragged kernel computes the few rows the row owns)
 #: re-recorded it with the six others, and PR 48 (q / k / v weights
-#: stored [heads, D, in]) with all of them but `gpt`.
+#: stored [heads, D, in]) with all of them but `gpt`, PR 55 (the ragged
+#: kernel's softmax state lane-replicated) with all nine.
 LOOPED_LOWERED_AT_PARENT = \
-    "09dc064d067f5a3e7f31015364ec31bef43ede75b83d5deef0c11b4c0ad88e4c"
+    "c78ea15ea6da800562dbe0f38d5247585e8bc0d212966c83174475fcc8ef8f42"
 
 
 def _lower_looped():

@@ -281,9 +281,10 @@ def test_an_untileable_shape_is_refused(tiny, monkeypatch):
 #: PR 51 (the pooled K and V rows appended by cache-tile runs in ONE call,
 #: their run table made in the step) re-recorded it with `mla` and Ling's.
 #: PR 53 (the per-layer kernels through one jitted copy a step's layers
-#: share, `engine._once`) re-recorded it with the five, Nemotron's and Ling's.
+#: share, `engine._once`) re-recorded it with the five, Nemotron's and Ling's,
+#: PR 55 (the ragged kernel's softmax state lane-replicated) with all nine.
 EVA_LOWERED_AT_PARENT = \
-    "075055efd9e29b2e4cada9c125b8a300c68d6164bef7179823e8f3ccfa9c5214"
+    "375c700ab6799f1dda5c6653d05e9cb38a6547370ccfa713b8144e1e41899947"
 
 
 def _lower_eva():

@@ -21,8 +21,8 @@ from paddle_tpu.ops.pallas_ragged import (_work_list, ragged_head_block,
                                           ragged_pages_visited,
                                           ragged_tile_block,
                                           ragged_tile_tokens)
-from test_ragged_kernel import (_HEAD_BLOCKS, _engine_layout, _layout,
-                                ragged_attention_reference)
+from test_ragged_kernel import (_HEAD_BLOCKS, _LAYOUTS, _engine_layout,
+                                _layout, ragged_attention_reference)
 
 
 #: launches of pages that hold K and V in ONE row (latent attention: 16
@@ -259,6 +259,83 @@ _NARROW = {
 }
 
 
+def _pinned_launch(name):
+    """The output of a `_PARENTS_OUTPUT` launch."""
+    if name == "latent_rows":           # a block of 4 tiles, one pool
+        q, kp, tables = _latent_layout("chunk_starts_and_ends_mid_cell")
+        return ragged_paged_attention(q, kp, None, *tables, v_dim=64)
+    if name == "two_pools_windowed":    # the same, two pools
+        arrays, _ = _layout("one_kv_head_a_block_of_tiles")
+        return ragged_paged_attention(*arrays, window=13)
+    if name in _LAYOUTS:
+        arrays, window = _layout(name)
+        return ragged_paged_attention(*arrays, window=window)
+    if name in _HEAD_BLOCKS:            # `test_under_a_head_block`'s
+        spec = _HEAD_BLOCKS[name]
+        *arrays, kvl, tab = _engine_layout(
+            kv_dec=[17, 0 if spec.get("idle") else 9, 30], chunk=11,
+            kv_chunk=16 + 11, H=spec["KV"] * spec["rep"], KV=spec["KV"],
+            pps=4)
+        if spec.get("sentinel"):
+            live = -(-np.asarray(kvl) // arrays[1].shape[2])
+            tab = jnp.where(np.arange(tab.shape[1])[None] < live[:, None],
+                            tab, -1)
+        summary = None if "summary" not in spec else \
+            jnp.asarray(spec["summary"], jnp.int32)
+        return ragged_paged_attention(*arrays, kvl, tab,
+                                      window=spec.get("window"),
+                                      summary_rows=summary)
+    # the widths a TPU launch has, in bfloat16
+    spec = dict(_LANE_WIDTHS[name])
+    v_dim = spec.pop("v_dim", None)
+    q, kp, vp, *tables = _engine_layout(**spec)
+    q, kp, vp = (x.astype(jnp.bfloat16) for x in (q, kp, vp))
+    return ragged_paged_attention(q, kp, None if v_dim else vp, *tables,
+                                  v_dim=v_dim)
+
+
+#: launches at lane widths: `_engine_layout` keys (and `v_dim`)
+_LANE_WIDTHS = {
+    # Ouro's: one query head a KV head over pages of 64, a block of 4
+    # heads, decode rows on the narrow window: the statistic meets the
+    # scores through a lane slice and the accumulator as it is
+    "pages_of_64_rep1": dict(kv_dec=[70, 0, 150, 33], chunk=140,
+                             kv_chunk=30 + 140, H=4, KV=4, D=128, psz=64,
+                             pps=3),
+    # latent attention's: 16 query heads over one row of 384 columns
+    # whose first 256 are the value, pages of 256, a block of tiles: the
+    # statistic is tiled twice along the scores and the accumulator
+    "latent_row_of_384": dict(kv_dec=[300, 17, 0, 511], chunk=30,
+                              kv_chunk=250 + 30, H=16, KV=1, D=384,
+                              v_dim=256, psz=256, pps=2),
+}
+
+#: launch -> sha256 of its output's bytes at the parent commit, for every
+#: form the softmax's statistic takes: a block of tiles with one pool and
+#: with two under a window, a block of KV heads (GQA, a window at rep 9,
+#: chunk-summary rows), the narrow window, pages of 64, lane multiples
+_PARENTS_OUTPUT = {
+    "latent_rows":
+        "56b2dc733fbb06a34c204186245ef5a3130d9541c6a6a970d82fa1d6a36fcdff",
+    "two_pools_windowed":
+        "ac16643d25cff9981aa756dc7e4ac57accf1ea59434819ae9f42125d75fdc98b",
+    "kv8_rep4_idle_slot_sentinel":
+        "678b5184188c9e13ff20917e8feaae2f16f6bb5e022d706509942910ca5d3aa5",
+    "kv2_rep9_window":
+        "5efbb6d331068b7060f1513f87e5def8f2d7c8b41f8bdaf5248fe8094e949563",
+    "kv32_rep1_summary":
+        "e6099abe7bf9b68597357ca739316cc0daa8c849cf933fece4e82d5ffa5d0467",
+    "engine":
+        "50f522c092bd75a205ea12d8ec06472270ef373435f512d159893a64509b4067",
+    "speculative_runs_that_fit_and_not":
+        "01502411ee84e7dafb55831fc714c3faceb588597e5c5f0efc4da38b7deb651c",
+    "pages_of_64_rep1":
+        "60052d1602a34f7f58bc4aace85f570e74d7783846a55a4c622b3400efccf7c9",
+    "latent_row_of_384":
+        "c7eff5daa3656be30ed38062a5c9441ebd89dfbbd307ee685a535263e3efefb8",
+}
+
+
 class TestNarrowWindow:
     """A page visit of a sequence that owns a few rows of its tile runs
     on the window of `ragged_narrow_rows` rows that holds them: the same
@@ -375,23 +452,23 @@ class TestNarrowWindow:
         assert all(r % pack + rep <= W for r in starts)
         assert any(r % pack + rep > W - pack for r in starts)
 
-    @pytest.mark.parametrize("name,sha", [
-        ("latent_rows", "20fe146335804934f71a52b7fc1e15897fca3ce49d239aa84a"
-                        "79d058c6763903"),
-        ("two_pools_windowed", "ff5b68dc7c372173eac35c604025f025cb63b1ecb44"
-                               "e1c86ea92ff32d2745ecc"),
-    ])
-    def test_a_block_of_tiles_lowers_to_the_parents_text(self, name, sha):
-        """sha256 of the launch's lowered text at this PR's parent
-        (f44cae0), on the CPU under the suite's matmul precision: a cell
-        of `tb` > 1 tiles (4 here) takes no narrow visit, and its body's
-        two-branch page test is what PR 44 left."""
-        if name == "latent_rows":
-            q, kp, tables = _latent_layout("chunk_starts_and_ends_mid_cell")
-            lowered = jax.jit(lambda q, kp, *tabs: ragged_paged_attention(
-                q, kp, None, *tabs, v_dim=64)).lower(q, kp, *tables)
-        else:
-            arrays, _ = _layout("one_kv_head_a_block_of_tiles")
-            lowered = jax.jit(lambda *a: ragged_paged_attention(
-                *a, window=13)).lower(*arrays)
-        assert hashlib.sha256(lowered.as_text().encode()).hexdigest() == sha
+    @pytest.mark.parametrize("name", list(_PARENTS_OUTPUT))
+    def test_the_output_is_the_parents_bit_for_bit(self, name):
+        """sha256 of the launch's output bytes at this PR's parent
+        (5980fd3: m, l and alpha as [rows, 1] columns), on the CPU under
+        the suite's matmul precision: held lane-replicated the statistic
+        is the same float32 operations on the same values, in every form
+        it meets the scores and the accumulator (a lane slice, itself,
+        tiled). The bytes are the CPU backend's (its matmul and `exp`):
+        if a jax / XLA or host change fails all nine at once, tell it
+        from a kernel regression by re-recording at the parent's kernel
+        — `git archive 5980fd3 paddle_tpu/ops/pallas_ragged.py | tar -x
+        -C <dir>`, load that file as `paddle_tpu.ops._pallas_ragged_text`
+        (`tools/bench_util.load_text`), point this module's
+        `ragged_paged_attention` at its function and print
+        `_pinned_launch(name)`'s sha256: hashes that move with the
+        parent's kernel too are the backend's, not this kernel's."""
+        out = np.asarray(_pinned_launch(name), np.float32)
+        assert np.isfinite(out).all()
+        assert hashlib.sha256(out.tobytes()).hexdigest() \
+            == _PARENTS_OUTPUT[name]

@@ -249,6 +249,50 @@ def test_ragged_paged_attention_compiles_where_gate_says(chip):
         chip.refusals.get(_ragged)
 
 
+def _ragged_one_row(q, pool, ss, nt, kvl, tab):
+    from paddle_tpu.ops.pallas_ragged import ragged_paged_attention
+    return ragged_paged_attention(q, pool, None, ss, nt, kvl, tab, v_dim=512)
+
+
+@pytest.mark.parametrize("name,t,hq,kv,width,psz,n_pages,s,nj,hb,tb", [
+    # A.X-K1's and Xing's latent launches: 64 / 32 query heads over ONE
+    # row of 640 columns, the value its first 512, a block of 8 tiles:
+    # the statistic tiled twice along the scores, four times along the
+    # accumulator (the cells' rows, tables and blocks; the pool, which
+    # stays in HBM and shapes nothing of the kernel, cut to 65 pages)
+    ("axk1_latent", 288, 64, 1, 640, 256, 65, 33, 128, 1, 8),
+    ("xing_latent", 384, 32, 1, 640, 256, 65, 129, 18, 1, 8),
+    # Ouro's: a block of 16 heads over pages of 64, where the statistic
+    # meets the scores through a lane slice; decode rows on 16 rows
+    ("ouro_pages_of_64", 272, 16, 16, 128, 64, 65, 17, 64, 16, 1),
+])
+def test_the_lane_replicated_softmax_state_compiles(chip, name, t, hq, kv,
+                                                     width, psz, n_pages, s,
+                                                     nj, hb, tb):
+    """The kernel's running maximum and sum as [rows, 128] scratch (PR
+    55), in each form they meet the data, at the blocks the cells' shapes
+    give: what Mosaic refuses of it is caught here, off the chip."""
+    from paddle_tpu.ops.pallas_ragged import (ragged_head_block,
+                                              ragged_narrow_rows,
+                                              ragged_tile_block,
+                                              ragged_tile_tokens)
+    rep, latent = hq // kv, kv == 1
+    rows = ragged_tile_tokens(t, rep, jnp.bfloat16) * rep
+    assert rows == 128
+    assert ragged_head_block(kv, rows, width, psz, 2, latent=latent) == hb
+    assert ragged_tile_block(hb, -(-t * rep // rows), rows, width, psz, 2,
+                             512 if latent else None) == tb
+    assert ragged_narrow_rows(rep, rows, jnp.bfloat16, tb) \
+        == (0 if latent else 16)
+    seq = chip.shape((s,), I32)
+    pool = chip.shape((kv, n_pages, psz, width))
+    pools, fn = ((pool,), _ragged_one_row) if latent else \
+        ((pool, pool), _ragged)
+    assert chip.compiles(fn, chip.shape((t, hq, width)), *pools,
+                         seq, seq, seq, chip.shape((s, nj), I32)), \
+        chip.refusals.get(fn)
+
+
 def test_ragged_paged_attention_compiles_at_the_serving_cells_shapes(chip):
     """`mistral-7b-v0.3-serve-d16` as BENCHMARK.json's serving cells run
     it: T = 32 slots + a 256-row chunk, 32 q / 8 kv heads x 128, page

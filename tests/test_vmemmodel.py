@@ -124,8 +124,10 @@ class TestFootprints:
         fp = vm.site_footprint(sites["ragged_paged_attention"], entry, b)
         assert fp["unresolved"] == 4
         tile = hb * tb * rows * D
+        # (m and l lane-replicated, [rows, 128] each, since PR 55: what
+        # `_block_vmem` always charged the [rows, 1] columns as)
         assert fp["bytes"] == (4 * tile * 2 + tile * 4
-                               + 2 * hb * tb * rows * 4 + 2 * 4)
+                               + 2 * hb * tb * rows * 128 * 4 + 2 * 4)
         assert pr.ragged_head_block(b["KV"], rows, D, psz, 2,
                                     latent=latent) == hb
         tiles = 144 if latent else 3    # of 2 tokens; of 128 / 96 rows
@@ -134,7 +136,9 @@ class TestFootprints:
         assert (tb > 1) == latent
         assert pr._page_buffers(hb * psz * D * 2) == b["depth"]
         rings = 2 * b["depth"] * hb * psz * D * 2
-        assert fp["bytes"] + rings <= pr._block_vmem(
+        # with the rings, the kernel's own sum to the byte (which leaves
+        # out the cursor's two words of SMEM)
+        assert fp["bytes"] - 2 * 4 + rings == pr._block_vmem(
             hb, rows, D, psz, 2, tb) <= pr._VMEM_BUDGET \
             < vm.VMEM_BYTES_PER_CORE
         if latent:      # its own launch: a [rows, 512] output, ONE ring

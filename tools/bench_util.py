@@ -6,7 +6,21 @@ jax returns before the device finishes; every timing here ends in
 
 from __future__ import annotations
 
+import importlib.util
+import sys
 import time
+
+
+def load_text(module: str, name: str, path: str):
+    """Another checkout's copy of `paddle_tpu.ops.<module>` (a file at
+    `path`) as a module of THIS package, so that its relative imports
+    resolve here: a kernel text to time beside this tree's."""
+    spec = importlib.util.spec_from_file_location(
+        f"paddle_tpu.ops._{module}_text_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def ready(out):

@@ -25,7 +25,6 @@ writes no file.
 from __future__ import annotations
 
 import argparse
-import importlib.util
 import json
 import os
 import sys
@@ -34,20 +33,9 @@ import numpy as np
 
 _TOOLS = os.path.dirname(os.path.abspath(__file__))
 sys.path[:0] = [_TOOLS, os.path.dirname(_TOOLS)]
-from bench_util import ab_rounds, band, ratio_band  # noqa: E402
+from bench_util import ab_rounds, band, load_text, ratio_band  # noqa: E402
 
 PEAK_FLOPS = 197e12      # bf16, one v5e chip (Google Cloud, "TPU v5e")
-
-
-def _load_text(name: str, path: str):
-    """Another checkout's ops/pallas_flash.py as a module of THIS
-    package (its relative imports resolve here)."""
-    spec = importlib.util.spec_from_file_location(
-        f"paddle_tpu.ops._flash_text_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules[spec.name] = mod
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def _launches(mod, B, H, S, D, block):
@@ -210,7 +198,7 @@ def main():
     texts = {"change": pallas_flash}
     for item in args.text:
         name, path = item.split("=", 1)
-        texts[name] = _load_text(name, path)
+        texts[name] = load_text("pallas_flash", name, path)
     cell(texts, *args.shape, rounds=args.rounds,
          launches=tuple(args.launches))
 

@@ -7,6 +7,8 @@ sequence's page visits (PR 45):
     chiprun -- python tools/ragged_head_sweep.py --hb 0 --tb 1 2 4 8 \
         --launch axk1_6k axk1_24k axk1_decode
     chiprun -- python tools/ragged_head_sweep.py --hb 0 --narrow 0 1
+    chiprun -- python tools/ragged_head_sweep.py --hb 0 \
+        --text parent=<tree>/paddle_tpu/ops/pallas_ragged.py
 
 One launch shape a cell (`LAUNCHES`), its row tables drawn from a seed;
 for every `hb` that divides the cell's KV heads and every `tb`,
@@ -22,8 +24,12 @@ window `ragged_narrow_rows` to 0 the same way (every visit computes the
 tile's rows), `1` leaves the kernel's own; a line says the window, the
 (tile, page) updates that ran on it, and with both given the tool fails
 unless their outputs are equal bit for bit. `--depth` forces the ring's
-slots the same way. Appends its lines to
-chiprun_out/ragged_head_sweep.jsonl.
+slots the same way. `--text NAME=PATH` loads another checkout's
+`pallas_ragged.py` beside this one's (PR 55: the parent's, unpacked
+under .archive_check/): every line is then made once a text, this
+tree's (`change`) first, the texts' repeats taking turns, and the tool
+fails unless a text's output equals this tree's bit for bit. Appends
+its lines to chiprun_out/ragged_head_sweep.jsonl.
 """
 
 from __future__ import annotations
@@ -36,7 +42,9 @@ import time
 
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+_TOOLS = os.path.dirname(os.path.abspath(__file__))
+sys.path[:0] = [_TOOLS, os.path.dirname(_TOOLS)]
+from bench_util import load_text  # noqa: E402
 
 #: name -> the launch: T rows, q / KV heads, page size, pool pages, page
 #: table width, decode contexts (lo, hi, how many of the slots are live),
@@ -71,6 +79,13 @@ LAUNCHES = {
        for name, chunk in (("6k", (256, 6800 - 256)),
                            ("24k", (256, 24000 - 256)),
                            ("decode", None))},
+    # Xing's launch as `xing4-serve-assistant-steady` makes it (ROADMAP
+    # S13 c's regime): 384 rows, 32 query heads over the one latent row,
+    # ~105 live decode rows at 64-3,072 beside a 256-row chunk at ~380
+    # tokens of context; 128 slots of 18 pages over a pool of 641
+    "xing_assist": dict(T=384, H=32, KV=1, D=640, v_dim=512, psz=256,
+                        pages=641, nj=18, slots=128, live=105,
+                        ctx=(64, 3072), chunk=(256, 380 - 256)),
 }
 
 
@@ -122,6 +137,8 @@ def main():
     ap.add_argument("--chain", type=int, default=48)
     ap.add_argument("--repeats", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--text", action="append", default=[],
+                    metavar="NAME=PATH")
     args = ap.parse_args()
 
     import jax
@@ -131,10 +148,13 @@ def main():
     if jax.default_backend() != "tpu":
         print("WARNING: not on a TPU; the times mean nothing",
               file=sys.stderr)
-    choose, buffers = pr.ragged_head_block, pr._page_buffers
-    choose_tb, choose_rows = pr.ragged_tile_block, pr.ragged_narrow_rows
-    if args.depth:
-        pr._page_buffers = lambda _bytes: args.depth
+    texts = {"change": pr}
+    for item in args.text:
+        name, path = item.split("=", 1)
+        texts[name] = load_text("pallas_ragged", name, path)
+    # the choices are this tree's, forced on every text alike
+    choose, choose_tb = pr.ragged_head_block, pr.ragged_tile_block
+    choose_rows = pr.ragged_narrow_rows
     out = []
     for name in args.launch:
         spec = LAUNCHES[name]
@@ -158,77 +178,85 @@ def main():
         vp = None if v_dim else jax.random.normal(kv_, pool, jnp.bfloat16)
         tables = [jnp.asarray(x) for x in (ss, nt, kvl, tab)]
         summary = jnp.asarray(sr) if spec.get("summary") else None
+        rest = (kp, vp, tables, summary)
         first = None
         for hb, tb, narrow in ((hb, tb, n) for hb in args.hb
                                for tb in args.tb for n in args.narrow):
             if hb and KV % hb:
                 continue
-            pr.ragged_head_block = choose if not hb else \
-                (lambda *a, _hb=hb, **k: _hb)
-            pr.ragged_tile_block = choose_tb if not tb else \
-                (lambda *a, _tb=tb, **k: _tb)
-            pr.ragged_narrow_rows = choose_rows if narrow else \
-                (lambda *a, **k: 0)
-            pr._launch_jit.clear_cache()    # equal shapes: trace again
+            ones, runs = {}, {}
+            for text, mod in texts.items():
+                mod.ragged_head_block = choose if not hb else \
+                    (lambda *a, _hb=hb, **k: _hb)
+                mod.ragged_tile_block = choose_tb if not tb else \
+                    (lambda *a, _tb=tb, **k: _tb)
+                mod.ragged_narrow_rows = choose_rows if narrow else \
+                    (lambda *a, **k: 0)
+                if args.depth:
+                    mod._page_buffers = lambda _bytes: args.depth
+                mod._launch_jit.clear_cache()   # equal shapes: trace again
 
-            # (the pools and tables are ARGUMENTS: closed over, they
-            # would be constants of the program, 0.3-2.6 GB to compile)
-            def launch(q, kp, vp, tables, summary):
-                out = pr.ragged_paged_attention(
-                    q, kp, vp, *tables, window=window,
-                    summary_rows=summary, v_dim=v_dim)
-                return out if not v_dim else jnp.pad(
-                    out, ((0, 0), (0, 0), (0, D - v_dim)))
+                # (the pools and tables are ARGUMENTS: closed over, they
+                # would be constants of the program, 0.3-2.6 GB to compile)
+                def launch(q, kp, vp, tables, summary, _mod=mod):
+                    out = _mod.ragged_paged_attention(
+                        q, kp, vp, *tables, window=window,
+                        summary_rows=summary, v_dim=v_dim)
+                    return out if not v_dim else jnp.pad(
+                        out, ((0, 0), (0, 0), (0, D - v_dim)))
 
-            def chain(q, *rest):
-                return jax.lax.fori_loop(
-                    0, args.chain, lambda _, x: launch(x, *rest), q)
+                def chain(q, *rest, _launch=launch):
+                    return jax.lax.fori_loop(
+                        0, args.chain, lambda _, x: _launch(x, *rest), q)
 
-            rest = (kp, vp, tables, summary)
-            try:
-                one = np.asarray(
-                    jax.jit(launch)(q, *rest).astype(jnp.float32))
-                run = jax.jit(chain)
-                run(q, *rest).block_until_ready()
-                times = []
-                for _ in range(args.repeats):
+                try:
+                    ones[text] = np.asarray(
+                        jax.jit(launch)(q, *rest).astype(jnp.float32))
+                    runs[text] = jax.jit(chain)
+                    runs[text](q, *rest).block_until_ready()
+                except Exception as e:  # noqa: BLE001 - the compiler's refusal
+                    print(f"{name} {text} hb {hb} tb {tb}: REFUSED "
+                          f"{str(e)[:300]!r}", flush=True)
+            times = {text: [] for text in runs}
+            for _ in range(args.repeats):       # the texts take turns
+                for text, run in runs.items():
                     t0 = time.perf_counter()
                     run(q, *rest).block_until_ready()
-                    times.append(time.perf_counter() - t0)
-            except Exception as e:  # noqa: BLE001 - the compiler's refusal
-                print(f"{name} hb {hb} tb {tb}: REFUSED {str(e)[:300]!r}",
-                      flush=True)
-                continue
-            if first is None:
-                first = one
+                    times[text].append(time.perf_counter() - t0)
             used = hb or choose(KV, rows, D, psz, 2, latent=bool(v_dim))
             cell = tb or choose_tb(used, -(-T * rep // rows), rows, D, psz,
                                    2, v_dim)
             visits = pr.ragged_pages_visited(ss, nt, kvl, tb=cell, **counted)
-            ms = min(times) / args.chain * 1e3
-            equal = bool(np.array_equal(one, first))
-            if len(args.narrow) > 1 and not equal:
-                raise SystemExit(f"{name} hb {used} tb {cell}: the narrow "
-                                 "window's output is not the tile's")
-            rec = dict(launch=name, hb=used, tb=cell,
-                       forced=bool(hb or tb or not narrow),
-                       narrow_rows=pr.ragged_narrow_rows(
-                           rep, rows, jnp.bfloat16, cell),
-                       narrow_updates_a_head=pr.ragged_narrow_updates(
-                           ss, nt, kvl, tb=cell, **counted),
-                       depth=args.depth, ms_a_launch=ms,
-                       visits_a_head=visits,
-                       us_a_head_visit=ms * 1e3 / (visits * KV),
-                       us_a_block_visit=ms * 1e3 / (visits * KV // used),
-                       tile_chains_a_head=chains,
-                       us_a_tile_update=ms * 1e3 / (chains * KV),
-                       equal_to_first=equal,
-                       finite=bool(np.isfinite(one).all()),
-                       device=jax.devices()[0].device_kind)
-            out.append(rec)
-            print(json.dumps(rec), flush=True)
-    pr.ragged_head_block, pr._page_buffers = choose, buffers
-    pr.ragged_tile_block, pr.ragged_narrow_rows = choose_tb, choose_rows
+            for text in runs:
+                one = ones[text]
+                if first is None:
+                    first = one
+                ms = min(times[text]) / args.chain * 1e3
+                equal = bool(np.array_equal(one, first))
+                if len(args.narrow) > 1 and not equal:
+                    raise SystemExit(
+                        f"{name} hb {used} tb {cell}: the narrow window's "
+                        "output is not the tile's")
+                if not np.array_equal(one, ones.get("change", one)):
+                    raise SystemExit(f"{name} hb {used} tb {cell}: the "
+                                     f"output of {text} is not this tree's")
+                rec = dict(launch=name, text=text, hb=used, tb=cell,
+                           forced=bool(hb or tb or not narrow),
+                           narrow_rows=pr.ragged_narrow_rows(
+                               rep, rows, jnp.bfloat16, cell),
+                           narrow_updates_a_head=pr.ragged_narrow_updates(
+                               ss, nt, kvl, tb=cell, **counted),
+                           depth=args.depth, ms_a_launch=ms,
+                           visits_a_head=visits,
+                           us_a_head_visit=ms * 1e3 / (visits * KV),
+                           us_a_block_visit=ms * 1e3 / (visits * KV // used),
+                           tile_chains_a_head=chains,
+                           us_a_tile_update=ms * 1e3 / (chains * KV),
+                           equal_to_first=equal,
+                           finite=bool(np.isfinite(one).all()),
+                           device=jax.devices()[0].device_kind)
+                out.append(rec)
+                print(json.dumps(rec), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/ragged_head_sweep.jsonl", "a") as f:
         f.writelines(json.dumps(rec) + "\n" for rec in out)
