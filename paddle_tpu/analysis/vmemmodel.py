@@ -422,6 +422,23 @@ CANONICAL: Dict[str, Dict[str, Any]] = {
         cost_kwargs=dict(P=64, N=128, H=128),
         token_tiled=False,
     ),
+    # Mamba-1 (a decay a (channel, column)): 8 live slots of [1, N = 16, C]
+    # float32, one slot's whole state a grid step; and a chunk's 256 rows
+    # in row blocks of RB over channel blocks of CB lanes
+    "ssm1_state_update": dict(
+        kernel="ssm1_state_update",
+        bindings=dict(B=8, N=16, C=5120),
+        in_widths=[4, 4, 4, 4, 4, 4], out_widths=[4, 4],
+        cost_kwargs=dict(live=8, C=5120, N=16),
+        token_tiled=False,
+    ),
+    "ssm1_chunk_scan": dict(
+        kernel="ssm1_chunk_scan",
+        bindings=dict(Lp=256, RB=64, CB=512, N=16, C=5120),
+        in_widths=[4, 4, 4, 4, 4, 4], out_widths=[4, 4],
+        cost_kwargs=dict(rows=256, C=5120, N=16),
+        token_tiled=False,
+    ),
     # -- ops/pallas_kda.py (the same pool, heads major: a [K, V] tile) -----
     # 8 live slots of [H = 32, K, V] float32 in J = 4 blocks of HB heads:
     # each slot's state once in and once out, its row's q, k, g, v, beta

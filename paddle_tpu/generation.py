@@ -626,6 +626,46 @@ def _falcon_h1_decode_params(model):
         rope_fn=lambda n: dict(zip(("cos", "sin"), cfg.rope_table(n))))
 
 
+def _phi4flash_decode_params(model):
+    """Phi4FlashForCausalLM on the hybrid body: TWO blocks a layer, a
+    mixer then a dense SwiGLU FFN ``D``, each on its own LayerNorm WITH
+    bias (``norm`` / ``norm_b``).  The mixers (`models.phi4flash.
+    Phi4FlashConfig.pattern`): ``S`` Mamba-1, ``A_log`` stored turned,
+    [N, C], as its pool has the channels along the lanes; ``*``
+    differential attention with pages of its own — ``attn_static`` has
+    one record for each, the first-half layers' with the window; ``G<j>``
+    a gated unit and ``X<j>`` a cross-attention mixer, which own NO
+    memory and read block j's scan output / pages.  ``diff`` holds each
+    attention mixer's layer index (its ``lambda_init``), by block.  The
+    head is the embedding (``head`` None)."""
+    from .models.phi4flash import arrays
+    inner, cfg = model.model, model.config
+    layers, diff, attn_static = [], {}, []
+    for l, lyr in enumerate(inner.layers):
+        d = dict(norm=lyr.input_layernorm.weight._data,
+                 norm_b=lyr.input_layernorm.bias._data,
+                 **arrays(lyr.mixer.weights()))
+        if lyr.kind == "S":
+            d["a_log_t"] = d.pop("A_log").T
+        if lyr.kind in "WFX":
+            _heads_w(d, cfg.head_dim, "wq", "wk", "wv")
+            diff[len(layers)] = l
+        if lyr.kind in "WF":
+            attn_static.append(dict(
+                heads=cfg.num_attention_heads, rope="",
+                window=cfg.sliding_window if lyr.kind == "W" else None))
+        layers.append(d)
+        layers.append(dict(norm=lyr.post_attention_layernorm.weight._data,
+                           norm_b=lyr.post_attention_layernorm.bias._data,
+                           **arrays(lyr.mlp.weights())))
+    return dict(
+        cfg=cfg, family="hybrid", pattern=cfg.pattern,
+        embed=inner.embed_tokens.weight._data, layers=layers,
+        norm=inner.final_layernorm.weight._data,
+        norm_b=inner.final_layernorm.bias._data, head=None, moe_static=(),
+        diff=diff, attn_static=tuple(attn_static))
+
+
 def _bailing_decode_params(model):
     """BailingHybridForCausalLM on the hybrid body: TWO blocks a layer,
     of the kinds ``pattern`` spells (static, outside the tree): ``K`` a
@@ -776,13 +816,16 @@ def _decode_params(model, weight_only_int8: bool = False,
         from .models.falcon_h1 import FalconH1Model
         from .models.nemotron_h import NemotronHModel
         from .models.ouro import OuroModel
+        from .models.phi4flash import Phi4FlashModel
         if isinstance(inner, (NemotronHModel, BailingHybridModel,
-                              FalconH1Model)):
+                              FalconH1Model, Phi4FlashModel)):
             if enabled:
                 raise NotImplementedError(
                     "weight-only quantisation is not wired for the "
-                    "Nemotron-H, Ling (bailing_hybrid) and Falcon-H1 "
-                    "families")
+                    "Nemotron-H, Ling (bailing_hybrid), Falcon-H1 and "
+                    "Phi-4-flash families")
+            if isinstance(inner, Phi4FlashModel):
+                return _phi4flash_decode_params(model)
             if isinstance(inner, BailingHybridModel):
                 return _bailing_decode_params(model)
             if isinstance(inner, FalconH1Model):
@@ -822,7 +865,7 @@ def _llama_weights(p):
     time."""
     return {k: v for k, v in p.items()
             if k not in ("cfg", "family", "moe_static", "attn_static",
-                         "rope_fn", "pattern", "mults")}
+                         "rope_fn", "pattern", "mults", "diff")}
 
 
 def _dq(d, key, dtype):

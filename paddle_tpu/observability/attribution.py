@@ -235,6 +235,11 @@ SCOPES = ("embed", "attn_norm", "qkv_proj", "cache_write", "attention",
 #: (entry and exit).  They answer as the norms do — the parts of a
 #: sublayer that are neither a projection nor an FFN — so that the sums
 #: readers take over `qkv_proj` / `attn_out` / `ffn` stay what they were.
+#: A Mamba-1 mixer's parts (`ssm1_*`) answer as Mamba-2's; a gated
+#: memory unit (`gmu`: two projections around another block's scan
+#: output) and the differential heads' combine after the launch
+#: (`diff_combine`) as `attn_out`; a launch over ANOTHER block's pages
+#: is `shared_attention`, told from `attention` over a block's own.
 SCOPE_ALIASES = {"mla_q": "qkv_proj", "mla_kv": "qkv_proj",
                  "mla_attention": "attention", "mla_out": "attn_out",
                  "eva_attention": "attention", "eva_pool": "cache_write",
@@ -245,7 +250,11 @@ SCOPE_ALIASES = {"mla_q": "qkv_proj", "mla_kv": "qkv_proj",
                  "kda_state_update": "attention",
                  "kda_chunk_scan": "attention", "kda_out": "attn_out",
                  "mhc_pre": "attn_norm", "mhc_post": "ffn_norm",
-                 "mhc_merge": "ffn_norm"}
+                 "mhc_merge": "ffn_norm",
+                 "ssm1_in_proj": "qkv_proj", "ssm1_conv": "cache_write",
+                 "ssm1_scan": "attention", "ssm1_out": "attn_out",
+                 "gmu": "attn_out", "shared_attention": "attention",
+                 "diff_combine": "attn_out"}
 
 
 def scope(name: str):

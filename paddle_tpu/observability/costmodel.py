@@ -292,6 +292,54 @@ def _c_ssm_state_update(*, live: int, P: int, N: int, H: int,
                                    "activations": rows_in + rows_out})
 
 
+@register_cost("ssm1_state_update")
+def _c_ssm1_state_update(*, live: int, C: int, N: int = 16) -> CostEstimate:
+    """One step of the Mamba-1 recurrence (a decay a (channel, state
+    column)) for `live` slots of the float32 pool [slots, 1, N, C]
+    (aliased in+out; `ops.pallas_ssm`): each live slot's state once in
+    and once out, its row's dt and x [C] and its B and C rows [N] in
+    float32, y [C] out; A [N, C] once a launch. 7 FLOPs an element of the
+    state (dt A, the decay's multiply, dt x B, the add, the read-out's
+    multiply-add) beside its exponential."""
+    state = live * N * C * 4
+    rows_in = live * (2 * C + 2 * N) * 4 + (N * C * 4 if live else 0)
+    rows_out = live * C * 4
+    return CostEstimate(bytes_read=state + rows_in,
+                        bytes_written=state + rows_out,
+                        flops=7 * live * N * C,
+                        breakdown={"state": 2 * state,
+                                   "activations": rows_in + rows_out})
+
+
+@register_cost("ssm1_chunk_scan")
+def _c_ssm1_chunk_scan(*, rows: int, C: int, N: int = 16,
+                       CB: int = 512) -> CostEstimate:
+    """The Mamba-1 selective scan over a chunk of `rows` rows of ONE
+    sequence, in channel blocks of `CB` lanes: the state [N, C] once in
+    and once out, a row's dt and x in and y out [C] in float32, its B
+    and C rows [N] once A CHANNEL BLOCK (every block walks all the
+    rows), A once. 7 FLOPs an element of the state a row, elementwise
+    and sequential in the rows: vector work, which no matrix peak
+    bounds."""
+    state = N * C * 4
+    rows_in = rows * (2 * C + 2 * N * max(C // CB, 1)) * 4 + state
+    return CostEstimate(bytes_read=state + rows_in,
+                        bytes_written=state + rows * C * 4,
+                        flops=7 * rows * N * C,
+                        breakdown={"state": 2 * state,
+                                   "activations": rows_in + rows * C * 4})
+
+
+def shared_pool_read_bytes(*, pages: int, page_bytes: int,
+                           readers: int) -> int:
+    """HBM bytes the launches of ONE step read of a page pool that
+    `readers` blocks attend over (the block that owns it and the blocks
+    that borrow it: a cross-decoder's layers over one layer's keys and
+    values): every reader fetches every visited page — one pool stored,
+    `readers` times read."""
+    return pages * page_bytes * readers
+
+
 @register_cost("ssm_state_put")
 def _c_ssm_state_put(*, P: int, N: int, H: int) -> CostEstimate:
     """One slot [P, N, H] (or state-minor [H, P, N]: the same bytes)

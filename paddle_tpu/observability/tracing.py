@@ -63,7 +63,7 @@ __all__ = ["TraceEvent", "RequestTrace", "TraceRecorder", "recorder",
            "slo_summary", "SLO_METRICS", "STEP_COUNTS", "STEPS_PER_SLOT",
            "STEP_COUNTS_BY_KIND", "STEP_COUNTS_MOE", "STEP_COUNTS_LATENT",
            "STEP_COUNTS_EVA", "STEP_COUNTS_LOOP", "STEP_COUNTS_SSM",
-           "STEP_COUNTS_MHC"]
+           "STEP_COUNTS_MHC", "STEP_COUNTS_SHARED"]
 
 _FLAG = _flags._registry["FLAGS_request_tracing"]
 
@@ -193,6 +193,11 @@ STEP_COUNTS_SSM: Tuple[str, ...] = (
 #: of the stream are a constant of the engine: `hbm_accounting()`.)
 STEP_COUNTS_MHC: Tuple[str, ...] = (
     "mhc_rows", "mhc_sublayers", "mhc_colsum_err_max")
+#: ... and where blocks that own no pages read ANOTHER block's pool
+#: inside the launch (a cross-decoder over one layer's keys and values):
+#: the launches of a step that fetch the full kind's pages, the owner's
+#: and every borrower's; `pages_visited.full` is what ONE of them visits
+STEP_COUNTS_SHARED: Tuple[str, ...] = ("shared_pool_readers",)
 #: step records kept for each slot of the request ring. A request lives
 #: through tens to hundreds of steps, and whoever reads a whole measured
 #: window from the records (`benchmarks/lib/program_spans.py`: 50-56 s

@@ -1,12 +1,34 @@
-"""Mamba-2 (SSD, arXiv:2405.21060) state-space mixing for serving: a
-FIXED-SIZE recurrent state a (sequence, layer) in a slot-indexed pool.
+"""State-space mixing for serving: a FIXED-SIZE recurrent state a
+(sequence, layer) in a slot-indexed float32 pool. TWO recurrences live
+here, each with its own kernels:
 
-For head ``h`` (group ``g = h // (H / G)``), state ``S_h`` [P, N]::
+**Mamba-2** (SSD, arXiv:2405.21060; Nemotron-H, Falcon-H1): ONE decay a
+head. For head ``h`` (group ``g = h // (H / G)``), state ``S_h`` [P, N]::
 
     S_t = exp(dt_t[h] A[h]) S_{t-1} + dt_t[h] x_t[h] (outer) B_t[g]
     y_t[h] = S_t C_t[g]                     (the caller adds D[h] x_t[h])
 
-(P the head width, N the state size, H the heads.)  The pool is float32
+(P the head width, N the state size, H the heads.)  Served by
+`ssm_state_update` (the decode rows), `ssm_chunk_scan` (a chunk's rows:
+SSD's quadratic form, which needs the one decay a head) and
+`ssm_state_put`.
+
+**Mamba-1** (the selective scan, arXiv:2312.00752; Phi-4-mini-flash): a
+decay for every (channel, state column). For channel ``c`` of C, state
+``h`` [N, C] (N = 16 columns, ``B`` / ``C`` rows of N shared by all
+channels)::
+
+    h_t[n, c] = exp(dt_t[c] A[n, c]) h_{t-1}[n, c] + dt_t[c] x_t[c] B_t[n]
+    y_t[c] = sum_n h_t[n, c] C_t[n]         (the caller adds D[c] x_t[c])
+
+Served by `ssm1_state_update` (the decode rows) and `ssm1_chunk_scan` (a
+chunk's rows: no quadratic form exists without the one decay, so a
+channel block's state stays in VMEM and the kernel walks the rows), and
+by the same `ssm_state_put`. Its pool is ``[slots, 1, N, C]``: the C
+channels along the lanes, the N columns on the sublanes, nothing padded
+(state-minor would store 16 columns in 128 lanes, eightfold).
+
+Mamba-2's pool is float32
 in ONE of two layouts, which `state_layout(H, N)` picks by what fills
 the 128 lanes of the MINOR dimension — a minor dimension is stored in
 whole 128-lane rows, so one of 32 is stored fourfold:
@@ -55,7 +77,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["ssm_state_update", "ssm_chunk_scan", "ssm_state_put",
-           "state_layout", "state_pool_shape", "HEADS_MINOR", "STATE_MINOR"]
+           "ssm1_state_update", "ssm1_chunk_scan", "state_layout",
+           "state_pool_shape", "HEADS_MINOR", "STATE_MINOR"]
 
 HEADS_MINOR, STATE_MINOR = "heads_minor", "state_minor"
 _LANES = 128
@@ -383,6 +406,143 @@ def ssm_chunk_scan(xdt, dA, bm, cm, state, *, chunk: int = 128,
 
 
 # ---------------------------------------------------------------------------
+# Mamba-1: a decay for every (channel, state column)
+# ---------------------------------------------------------------------------
+
+#: rows of a chunk a grid step of `ssm1_chunk_scan` walks, at most
+_RB = 64
+#: channels (lanes) of a grid step of `ssm1_chunk_scan`, at most
+_CB = 512
+
+
+def _update1_kernel(slots_ref, n_ref,                   # scalar prefetch
+                    dt_ref, x_ref, a_ref, b_ref, c_ref, sin_ref,
+                    y_ref, sout_ref):
+    i = pl.program_id(0)
+    n = n_ref[0]
+    seed = (n == 0) & (i == 0)      # as `_update_kernel`'s: dt 0 once
+
+    @pl.when((i < n) | seed)
+    def _step():
+        dt = jnp.where(seed, 0.0, dt_ref[0])            # [1, C]
+        new = jnp.exp(dt * a_ref[...]) * sin_ref[0, 0] \
+            + (dt * x_ref[0]) * b_ref[0]                # [N, C]
+        sout_ref[0, 0] = new
+        y_ref[0] = jnp.sum(new * c_ref[0], axis=0, keepdims=True)
+
+
+def ssm1_state_update(pool, slots, n_live, dt, x, a, bm, cm):
+    """One step of the Mamba-1 recurrence for the launch's decode rows,
+    the pool updated in place.
+
+    pool [NS, 1, N, C] float32; ``slots`` [B] int32 and ``n_live`` [1] as
+    `ssm_state_update`'s (the live slots first, then the spare NS - 1).
+    Row ``s`` of the operands belongs to slot ``s``: ``dt`` [R, C]
+    float32 (after the softplus; 0 is the identity), ``x`` [R, C]
+    float32, ``bm`` / ``cm`` [R, N]; ``a`` [N, C] float32 (-exp(A_log),
+    turned), R >= NS.
+
+    Returns (y [NS, C] float32 — sum_n h_t C_t; rows of slots that are
+    not live hold nothing meaningful — and the pool). Grid (B,): a step
+    holds ONE slot's whole state [N, C] (327,680 B at 16 x 5120); the
+    steps past the live slots repeat the last live block, which stays
+    resident and is not touched, so an idle slot's state is neither read
+    nor written."""
+    NS, _, N, C = pool.shape
+    B, R = slots.shape[0], dt.shape[0]
+    f32 = jnp.float32
+
+    def at(i, slots, n):
+        return _slot_at(NS, i, slots, n)[0]
+
+    row_spec = pl.BlockSpec((1, 1, C), lambda i, s, n: (at(i, s, n), 0, 0))
+    col_spec = pl.BlockSpec((1, N, 1), lambda i, s, n: (at(i, s, n), 0, 0))
+    state_spec = pl.BlockSpec((1, 1, N, C),
+                              lambda i, s, n: (at(i, s, n), 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(B,),
+        in_specs=[row_spec, row_spec,
+                  pl.BlockSpec((N, C), lambda i, s, n: (0, 0)),
+                  col_spec, col_spec, state_spec],
+        out_specs=[row_spec, state_spec])
+    y, new_pool = pl.pallas_call(
+        _update1_kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((NS, 1, C), f32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        # flat-input indices INCLUDE the scalar-prefetch operands
+        input_output_aliases={7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+    )(slots.astype(jnp.int32), n_live.astype(jnp.int32),
+      dt.astype(f32).reshape(R, 1, C), x.astype(f32).reshape(R, 1, C),
+      a.astype(f32), bm.astype(f32).reshape(R, N, 1),
+      cm.astype(f32).reshape(R, N, 1), pool)
+    return y[:, 0], new_pool
+
+
+def _scan1_kernel(dt_ref, x_ref, a_ref, b_ref, c_ref, s0_ref,
+                  y_ref, s1_ref):
+    @pl.when(pl.program_id(1) == 0)
+    def _start():
+        s1_ref[...] = s0_ref[...]
+
+    a = a_ref[...]                                      # [N, CB]
+    h = s1_ref[...]
+    for r in range(dt_ref.shape[0]):    # static: no dynamic row index
+        dt = dt_ref[r:r + 1, :]                         # [1, CB]
+        h = jnp.exp(dt * a) * h + (dt * x_ref[r:r + 1, :]) * b_ref[r]
+        y_ref[r:r + 1, :] = jnp.sum(h * c_ref[r], axis=0, keepdims=True)
+    s1_ref[...] = h
+
+
+def ssm1_chunk_scan(dt, x, a, bm, cm, state):
+    """A run of L rows of ONE sequence from ``state`` through the
+    Mamba-1 recurrence (L is padded up to whole row blocks with identity
+    rows).
+
+    dt [L, C] float32 (after the softplus), x [L, C], a [N, C]
+    (-exp(A_log), turned), bm / cm [L, N], state [..., N, C] (a slot of
+    the pool, [1, N, C]); float32 inside. A row with ``dt`` 0 changes
+    nothing and its own y is discarded by the caller. Returns (y [L, C]
+    float32 = sum_n h_t C_t, the state after the last row, in
+    ``state``'s shape).
+
+    Grid (C / CB, L / RB): independent channel blocks, and for each the
+    row blocks in order; a channel block's state [N, CB] is the resident
+    output block (registers inside a step, at 16 x 512) while the step
+    walks its RB rows — elementwise and sequential by nature: per row an
+    exp, four multiplies and an add over [N, CB], and a sum over the N
+    sublanes. ``B`` / ``C`` ride as [L, N, 1] columns, spread along the
+    lanes in the kernel."""
+    L, C = dt.shape
+    N = a.shape[0]
+    f32 = jnp.float32
+    RB = min(_RB, -(-L // 8) * 8)
+    pad = -L % RB
+    dt, x, bm, cm = (jnp.pad(m.astype(f32), ((0, pad), (0, 0)))
+                     for m in (dt, x, bm, cm))
+    Lp = L + pad
+    CB = next((cb for cb in (_CB, 256, 128) if C % cb == 0), C)
+    rows_spec = pl.BlockSpec((RB, CB), lambda j, i: (i, j))
+    col_spec = pl.BlockSpec((RB, N, 1), lambda j, i: (i, 0, 0))
+    state_spec = pl.BlockSpec((N, CB), lambda j, i: (0, j))
+    y, s1 = pl.pallas_call(
+        _scan1_kernel, grid=(C // CB, Lp // RB),
+        in_specs=[rows_spec, rows_spec, state_spec, col_spec, col_spec,
+                  state_spec],
+        out_specs=[rows_spec, state_spec],
+        out_shape=[jax.ShapeDtypeStruct((Lp, C), f32),
+                   jax.ShapeDtypeStruct((N, C), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=_interpret(),
+    )(dt, x, a.astype(f32), bm.reshape(Lp, N, 1), cm.reshape(Lp, N, 1),
+      state.astype(f32).reshape(N, C))
+    return y[:L], s1.reshape(state.shape)
+
+
+# ---------------------------------------------------------------------------
 # certification (paddlelint PK105)
 # ---------------------------------------------------------------------------
 
@@ -392,6 +552,14 @@ register_oracle(
     "ssm_state_update", kernel=ssm_state_update,
     reference="paddle_tpu.ops.references:ssm_state_update_reference",
     parity_test="tests/test_pallas_ssm.py::TestStateUpdate")
+register_oracle(
+    "ssm1_state_update", kernel=ssm1_state_update,
+    reference="paddle_tpu.ops.references:ssm1_state_update_reference",
+    parity_test="tests/test_pallas_ssm.py::TestMamba1")
+register_oracle(
+    "ssm1_chunk_scan", kernel=ssm1_chunk_scan,
+    reference="paddle_tpu.ops.references:ssm1_recurrence_reference",
+    parity_test="tests/test_pallas_ssm.py::TestMamba1")
 register_oracle(
     "ssm_state_put", kernel=ssm_state_put,
     reference="paddle_tpu.ops.references:ssm_state_put_reference",

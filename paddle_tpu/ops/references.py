@@ -22,6 +22,7 @@ __all__ = ["rms_norm_reference", "layer_norm_reference",
            "oproj_norm_reference", "megadecode_ffn_reference",
            "qkv_rope_append_reference", "ssm_state_update_reference",
            "ssm_state_put_reference", "ssm_recurrence_reference",
+           "ssm1_state_update_reference", "ssm1_recurrence_reference",
            "kda_state_update_reference", "kda_recurrence_reference",
            "mhc_layout", "mhc_pack", "sinkhorn_reference",
            "mhc_pre_reference", "mhc_post_reference", "MHC_COEF_LANES"]
@@ -334,6 +335,40 @@ def ssm_recurrence_reference(xdt, dA, bm, cm, state):
         step, state.astype(f32),
         (xdt.astype(f32), dA.astype(f32), bm.astype(f32), cm.astype(f32)))
     return y, state
+
+
+def ssm1_state_update_reference(pool, slots, n_live, dt, x, a, bm, cm):
+    """One step of the Mamba-1 recurrence for the live slots (the first
+    ``n_live`` of ``slots``) of the pool [NS, 1, N, C]; every other slot
+    unchanged (`pallas_ssm.ssm1_state_update`'s operands)."""
+    NS = pool.shape[0]
+    f32 = jnp.float32
+    live = jnp.zeros(NS, bool).at[slots].max(
+        jnp.arange(slots.shape[0]) < n_live[0])
+    dt, x = dt[:NS].astype(f32), x[:NS].astype(f32)
+    new = (jnp.exp(dt[:, None, :] * a.astype(f32)[None]) * pool[:, 0]
+           + (dt * x)[:, None, :] * bm[:NS].astype(f32)[:, :, None])
+    y = jnp.sum(new * cm[:NS].astype(f32)[:, :, None], 1)
+    return (jnp.where(live[:, None], y, 0),
+            jnp.where(live[:, None, None, None], new[:, None], pool))
+
+
+def ssm1_recurrence_reference(dt, x, a, bm, cm, state):
+    """The Mamba-1 recurrence token by token (what
+    `pallas_ssm.ssm1_chunk_scan` and `ssm1_state_update` are tested
+    against): the operands of `ssm1_chunk_scan`."""
+    f32 = jnp.float32
+    a = a.astype(f32)
+
+    def step(h, row):
+        d, u, b, c = row                    # [C], [C], [N], [N]
+        h = jnp.exp(d[None] * a) * h + (d * u)[None] * b[:, None]
+        return h, jnp.sum(h * c[:, None], 0)
+
+    h, y = jax.lax.scan(
+        step, state.astype(f32).reshape(a.shape),
+        (dt.astype(f32), x.astype(f32), bm.astype(f32), cm.astype(f32)))
+    return y, h.reshape(state.shape)
 
 
 def _kda_step(s, q, k, v, g, beta):

@@ -80,6 +80,7 @@ from ..ops.fused import (append_run_count, append_run_table,
                          fused_layer_norm, fused_rms_norm,
                          fused_rope_append)
 from ..models.bailing_hybrid import kda_gated_norm, kda_operands
+from ..models.phi4flash import diff_combine, pair_queries, ssm1_operands
 from ..models.nemotron_h import (ssm_conv, ssm_gated_norm, ssm_operands,
                                  ssm_split)
 from ..models.ouro import exit_distribution as _exit_distribution
@@ -87,8 +88,9 @@ from ..ops.pallas_kda import (kda_chunk_scan, kda_state_update,
                               kda_tileable)
 from ..ops.pallas_mhc import (mhc_enter, mhc_exit, mhc_post, mhc_pre,
                               mhc_tileable)
-from ..ops.pallas_ssm import (HEADS_MINOR, ssm_chunk_scan, ssm_state_put,
-                              ssm_state_update, state_layout,
+from ..ops.pallas_ssm import (HEADS_MINOR, ssm1_chunk_scan,
+                              ssm1_state_update, ssm_chunk_scan,
+                              ssm_state_put, ssm_state_update, state_layout,
                               state_pool_shape)
 from ..ops.pallas_ragged import (ragged_head_block,
                                  ragged_kernel_eligible,
@@ -384,38 +386,75 @@ def _latent_mixer(L, h, rope, pool, seq_start, num_tokens, kv_lengths,
 
 
 def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
-               runs, *, heads: int, kv: int, d: int, mults=None):
+               runs, *, heads: int, kv: int, d: int, mults=None,
+               window=None, diff=None, borrowed: bool = False,
+               shared: bool = False, eps: float = 1e-5):
     """Grouped-query attention on the normed rows h [1, T, H] of a
-    block's input, over the pages `pools` = (K, V): q / k / v ->
-    `fused_rope_append` (rotary on all `d` dims, half-split pairs, by the
-    rows' angles `rope` = (cos, sin) [T, d / 2]; with ``None``, or
-    `_no_turn`'s pair, the model has NO rotary embedding and the kernel
-    appends under the identity turn) -> `ragged_paged_attention` ->
-    o-proj. `mults` (Falcon-H1):
-    the input's, the key's and the output's static multipliers. -> (the
-    mixer's output y [1, T, H], the pools). The hybrid body's ``*``
-    mixer, alone in its block (Nemotron-H) or beside a state-space mixer
-    on the same norm (Falcon-H1)."""
+    block's input, over the pages `pools` = (K, V): q / k / v (+ their
+    biases, where the layer has them) -> `fused_rope_append` (rotary on
+    all `d` dims, half-split pairs, by the rows' angles `rope` = (cos,
+    sin) [T, d / 2]; with ``None``, or `_no_turn`'s pair, the model has
+    NO rotary embedding and the kernel appends under the identity turn)
+    -> `ragged_paged_attention` -> o-proj. `mults` (Falcon-H1): the
+    input's, the key's and the output's static multipliers. `window`:
+    the layer's sliding window (its `tables` and `runs` are then the
+    window kind's). -> (the mixer's output y [1, T, H], the pools).
+
+    `diff` (a layer index; Phi-4-flash) is DIFFERENTIAL attention in the
+    PAIR layout: a KV head of the pool is two published heads side by
+    side (``kv``, ``d`` are the pool's: half the heads, twice the
+    width), each query head is projected d / 2 wide and padded with
+    zeros on the side of the K head it does not use
+    (`models.phi4flash.pair_queries`), the scale is the published
+    head's, and the kernel's head 2i / 2i + 1 are the two softmaxes of
+    differential head i over the pair's two V heads; `diff_combine`
+    (``a1 - lambda a2``, RMSNorm, ``1 - lambda_init``) follows the
+    launch. `borrowed`: the mixer owns NO memory — no K / V projection,
+    no append; `pools` are another block's, after that block's append of
+    this launch's rows. `shared`: the pool is read by SEVERAL blocks of
+    the launch (its owner and its borrowers); such a launch is named
+    ``shared_attention``, one over a pool only its block reads
+    ``attention``.
+
+    The hybrid body's ``*`` mixer, alone in its block (Nemotron-H,
+    Phi-4-flash) or beside a state-space mixer on the same norm
+    (Falcon-H1), and its ``X`` mixer."""
     T = h.shape[1]
     kp, vp = pools
     cos, sin = rope or _no_turn(T, d, h.dtype)
+    dq = d // 2 if diff is not None else d
     with _scope("qkv_proj"):
         if mults:
             h = h * mults["attention_in"]
-        q, k, v = (_mm_heads(h, L, w) for w in ("wq", "wk", "wv"))
+        q, k, v = (_mm_heads(h, L, w) if w in L else None
+                   for w in ("wq", "wk", "wv"))
         if mults:
             k = k * mults["key"]
-    with _scope("cache_write"):
-        q, kp, vp = _once(
-            fused_rope_append, "cache_write", q.reshape(T, heads, d),
-            k.reshape(T, kv, d), v.reshape(T, kv, d), cos,
-            jnp.zeros_like(cos) if sin is None else sin, kp, vp, runs)
-    with _scope("attention"):
+        if "bq" in L:
+            q = q + L["bq"]
+            if not borrowed:
+                k, v = k + L["bk"], v + L["bv"]
+        q = q.reshape(T, heads, dq)
+        if diff is not None:
+            q = pair_queries(q)
+    if not borrowed:
+        with _scope("cache_write"):
+            q, kp, vp = _once(
+                fused_rope_append, "cache_write", q,
+                k.reshape(T, kv, d), v.reshape(T, kv, d), cos,
+                jnp.zeros_like(cos) if sin is None else sin, kp, vp, runs)
+    name = "shared_attention" if borrowed or shared else "attention"
+    with jax.named_scope(name):
         o = ragged_paged_attention(
             q, kp, vp, seq_start, num_tokens, kv_lengths, tables,
-            scale=d ** -0.5, scope="attention")
+            scale=dq ** -0.5, window=window, scope=name)
+    if diff is not None:
+        with jax.named_scope("diff_combine"):
+            o = diff_combine(o, L, diff, eps).astype(h.dtype)
     with _scope("attn_out"):
-        y = o.reshape(1, T, heads * d) @ L["wo"]
+        y = o.reshape(1, T, heads * dq) @ L["wo"]
+        if "bo" in L:
+            y = y + L["bo"]
         if mults:
             y = y * mults["attention_out"]
     return y, (kp, vp)
@@ -429,10 +468,13 @@ def _no_turn(T: int, d: int, dtype):
 
 def _pattern_blocks(pattern: str) -> Tuple[str, ...]:
     """A hybrid's pattern as its blocks. A letter is a block of ONE
-    sublayer on its own norm — a mixer (``M`` ``K`` ``*`` ``L``) or an
-    FFN (``E`` ``D``); ``[..]`` is a block whose one norm feeds SEVERAL
-    mixers, their outputs summed into the residual (``[M*]D``: a
-    Falcon-H1 layer)."""
+    sublayer on its own norm — a mixer (``M`` ``K`` ``S`` ``*`` ``L``)
+    or an FFN (``E`` ``D``); ``[..]`` is a block whose one norm feeds
+    SEVERAL mixers, their outputs summed into the residual (``[M*]D``: a
+    Falcon-H1 layer). ``G<j>`` and ``X<j>`` are mixers that own NO
+    memory and read block j's: a gated unit over the scan output of the
+    ``S`` block j, an attention mixer over the pages of the ``*`` block
+    j (``SD*DG0DX2D``: blocks 4 and 6 read blocks 0 and 2)."""
     blocks, i = [], 0
     while i < len(pattern):
         if pattern[i] == "[":
@@ -440,14 +482,20 @@ def _pattern_blocks(pattern: str) -> Tuple[str, ...]:
             blocks.append(pattern[i + 1:j])
         else:
             j = i
-            blocks.append(pattern[i])
+            while pattern[i] in "GX" and pattern[j + 1:j + 2].isdigit():
+                j += 1
+            blocks.append(pattern[i:j + 1])
         i = j + 1
-    for b in blocks:
-        if not b or set(b) - set("MK*LED") or \
+    for n, b in enumerate(blocks):
+        if b[:1] in ("G", "X") and b[1:].isdigit() and int(b[1:]) < n and \
+                blocks[int(b[1:])] == "S*"[b[0] == "X"]:
+            continue
+        if not b or set(b) - set("MKS*LED") or \
                 len(b) > 1 and set(b) - set("MK*L"):
             raise ValueError(
-                f"pattern {pattern!r}: a block is one letter of MK*LED, or "
-                f"several mixers (MK*L) in brackets")
+                f"pattern {pattern!r}: a block is one letter of MKS*LED, "
+                f"several mixers (MK*L) in brackets, or G<j> / X<j> with j "
+                f"an earlier S / * block")
     return tuple(blocks)
 
 
@@ -727,21 +775,30 @@ class ServingEngine:
         # given away takes the state with it
         pattern = p["pattern"] if self._family == "hybrid" else ""
         self._blocks = _pattern_blocks(pattern)
-        self._ssm_layers = pattern.count("M") + pattern.count("K")
-        # ... of ONE kind a model: Mamba-2's (`M`) or the delta rule's
-        # (`K`, a KDA linear-attention block); and the attention blocks'
-        # pages hold GQA rows (`*`) or latent rows (`L`)
-        self._state_kind = "K" if "K" in pattern else "M"
+        self._ssm_layers = sum(pattern.count(k) for k in "MKS")
+        # ... of ONE kind a model: Mamba-2's (`M`), the delta rule's
+        # (`K`, a KDA linear-attention block) or Mamba-1's (`S`); and
+        # the attention blocks' pages hold GQA rows (`*`) or latent rows
+        # (`L`). A block that BORROWS (`G<j>`, `X<j>`) holds neither: it
+        # reads block j's scan output / pages inside the launch
+        self._state_kind = next((k for k in "KS" if k in pattern), "M")
         self._state_layout = HEADS_MINOR
         self._latent = self._family == "mla" or "L" in pattern
+        # the blocks that read each page-holding block's pool in a
+        # launch: itself and the `X` blocks that name it
+        owners = [i for i, b in enumerate(self._blocks)
+                  if "*" in b or "L" in b]
+        self._pool_readers = [
+            1 + sum(b == f"X{i}" for b in self._blocks) for i in owners] \
+            if owners else [1] * len(self._attn_static)
         if self._ssm_layers:
-            if "K" in pattern and "M" in pattern or \
+            if sum(k in pattern for k in "MKS") > 1 or \
                     "L" in pattern and "*" in pattern:
                 raise NotImplementedError(
                     f"pattern {pattern!r}: one kind of state block and "
                     f"one kind of attention block a model")
-            what = "state-space" if self._state_kind == "M" else \
-                "linear-attention (delta-rule)"
+            what = "linear-attention (delta-rule)" \
+                if self._state_kind == "K" else "state-space"
             _refuse_shared_cache(
                 f"this model has {self._ssm_layers} {what} blocks, "
                 f"whose memory of a sequence is one recurrent state in "
@@ -825,11 +882,19 @@ class ServingEngine:
                                          cfg.qk_rope_head_dim)
         else:
             kv, d = cfg.num_key_value_heads, cfg.head_dim
+            if p.get("diff"):
+                # differential heads: the pool's KV head is a PAIR of
+                # published heads side by side (`_gqa_mixer`)
+                kv, d = kv // 2, 2 * d
         shape = (kv, self._passes * self.num_pages, self.page_size, d)
         wshape = (kv, self.num_window_pages, self.page_size, d)
         # each layer's kind: 0 keeps every page, 1 is the window kind
         self._layer_kind = [int(st["window"] is not None)
                             for st in self._attn_static]
+        # the blocks that fetch the pages of each kind in a launch
+        self._kind_readers = [
+            sum(r for r, k in zip(self._pool_readers, self._layer_kind)
+                if k == kind) for kind in (0, 1)]
         heads = sorted({st["heads"] for st in self._attn_static})
         if not _ragged_step_eligible(heads, kv, d, self.page_size):
             raise ValueError(
@@ -870,6 +935,12 @@ class ServingEngine:
                         f"prefill chunk of {self.prefill_chunk} rows is "
                         f"not whole sub-chunks of {cfg.kda_sub_chunk}")
                 self._state_shape = (self.max_slots + 1, nh, hd, hd)
+            elif self._state_kind == "S":
+                # Mamba-1: a decay a (channel, column); the channels
+                # along the lanes, the 16 columns on the sublanes,
+                # nothing padded (`ops.pallas_ssm`)
+                self._state_shape = (self.max_slots + 1, 1,
+                                     cfg.ssm_state_size, cfg.d_inner)
             else:
                 self._state_layout = state_layout(cfg.mamba_num_heads,
                                                   cfg.ssm_state_size)
@@ -883,8 +954,9 @@ class ServingEngine:
                 # (latent rows: one plane, K whole and V in its first
                 # kv_lora_rank columns, as the mla family's)
                 "kv": [jnp.zeros(shape, dt) if self._latent
-                       else (jnp.zeros(shape, dt), jnp.zeros(shape, dt))
-                       for _ in self._layer_kind],
+                       else (jnp.zeros(sh, dt), jnp.zeros(sh, dt))
+                       for sh in (wshape if k else shape
+                                  for k in self._layer_kind)],
                 "ssm": [(jnp.zeros(self._state_shape, jnp.float32),
                          jnp.zeros(self._tail_shape, dt))
                         for _ in range(self._ssm_layers)]}
@@ -929,6 +1001,8 @@ class ServingEngine:
             self._count_names += _tracing.STEP_COUNTS_LOOP
         if self._ssm_layers:
             self._count_names += _tracing.STEP_COUNTS_SSM
+        if max(self._pool_readers) > 1:
+            self._count_names += _tracing.STEP_COUNTS_SHARED
         if self._hc > 1:
             self._count_names += _tracing.STEP_COUNTS_MHC
         #: the counts a launch takes on the device and returns beside
@@ -976,6 +1050,13 @@ class ServingEngine:
                     _costmodel.kda_state_bytes_per_seq_layer(
                         heads=cfg.num_attention_heads,
                         head_dim=cfg.head_dim, conv_kernel=cfg.conv_kernel,
+                        conv_dtype_bytes=self._kv_itemsize)
+            elif self._state_kind == "S":
+                self._ssm_slot_bytes = \
+                    _costmodel.ssm_state_bytes_per_seq_layer(
+                        heads=1, head_dim=cfg.d_inner,
+                        state_size=cfg.ssm_state_size,
+                        conv_dim=cfg.conv_dim, conv_kernel=cfg.conv_kernel,
                         conv_dtype_bytes=self._kv_itemsize)
             else:
                 self._ssm_slot_bytes = \
@@ -1453,14 +1534,14 @@ class ServingEngine:
                                 + int(out["prefill_tokens"]))
         if dl:
             pages = sum(-(-ln // self.page_size) for ln in lens)
-            layer_pages = pages * n_layers
+            # (a pool is fetched once by every block that reads it)
+            readers = self._kind_readers
+            layer_pages = pages * readers[0]
             if self._window is not None:
                 # a window layer reads the pages its window spans
                 wcap = -(-self._window // self.page_size) + 1
-                layer_pages = (
-                    pages * self._layer_kind.count(0)
-                    + sum(min(-(-ln // self.page_size), wcap)
-                          for ln in lens) * self._layer_kind.count(1))
+                layer_pages += sum(min(-(-ln // self.page_size), wcap)
+                                   for ln in lens) * readers[1]
             self._ledger_bytes += (
                 dl * self._hbm_weight_read_bytes
                 + dl * layer_pages * self.page_size * per_tok
@@ -2258,8 +2339,8 @@ class ServingEngine:
                 "ssm_state_bytes_moved": (2 * slots - starts)
                 * self._ssm_layers * self._ssm_state_bytes,
                 "ssm_scan_rows": n, "ssm_state_resets": starts})
-            return ((tok, positions, num_tokens, (kv_lengths, tab), tables,
-                     tok_page, tok_off), src, drafts, n, start, counts)
+        # what the step takes as `kv_lengths`: with a state table beside
+        kvl = (kv_lengths, tab) if self._ssm_layers else kv_lengths
         if eva:
             seen = num_tokens > 0
             ends = positions[(seq_start + num_tokens - 1)[seen]] + 1
@@ -2279,7 +2360,7 @@ class ServingEngine:
                      tables, (tok_page, pool_page), (tok_off, pool_off)),
                     src, drafts, n, start, counts)
         if not windowed:
-            return ((tok, positions, num_tokens, kv_lengths, tables,
+            return ((tok, positions, num_tokens, kvl, tables,
                      tok_page, tok_off), src, drafts, n, start, counts)
         # the window kind: the pages between each sequence's oldest
         # visible key and its newest
@@ -2292,9 +2373,12 @@ class ServingEngine:
             "pages_live.full": live, "pages_live.window": wlive,
             "pages_visited.full": counts["pages_visited"],
             "pages_visited.window": wvisited})
+        if max(self._pool_readers) > 1:
+            # the launches of this step that fetch the full kind's pages
+            counts["shared_pool_readers"] = self._kind_readers[0]
         counts["pages_live"] += wlive
         counts["pages_visited"] += wvisited
-        return ((tok, positions, num_tokens, kv_lengths,
+        return ((tok, positions, num_tokens, kvl,
                  (tables, wtables), (tok_page, wtok_page), tok_off),
                 src, drafts, n, start, counts)
 
@@ -2777,17 +2861,45 @@ class ServingEngine:
         return step
 
     def _hybrid_unified_body(self, C: int):
-        """A hybrid (Nemotron-H, Ling 3.0, Falcon-H1) on the one launch:
-        block l is `x + sum of mixers_l(RMSNorm(x))`, its ONE norm
-        feeding the mixers the model's pattern names for it
-        (`_pattern_blocks`: one a block for Nemotron-H and Ling, whose
-        layer is two blocks; a state-space AND an attention mixer side by
-        side in Falcon-H1's ``[M*]``, whose layer is that block and a
-        ``D`` block), or `x + ffn_l(RMSNorm(x))`.
+        """A hybrid (Nemotron-H, Ling 3.0, Falcon-H1, Phi-4-flash) on the
+        one launch: block l is `x + sum of mixers_l(norm(x))`, its ONE
+        norm feeding the mixers the model's pattern names for it
+        (`_pattern_blocks`: one a block for Nemotron-H, Ling and
+        Phi-4-flash, whose layer is two blocks; a state-space AND an
+        attention mixer side by side in Falcon-H1's ``[M*]``, whose layer
+        is that block and a ``D`` block), or `x + ffn_l(norm(x))`. The
+        norm is RMSNorm, or — where the block has a ``norm_b`` —
+        LayerNorm with weight and bias (Phi-4-flash).
+
+        A mixer OWNS its memory — its entry of ``pools["kv"]`` or
+        ``pools["ssm"]``, in the order of the pattern — or owns NONE
+        (``G<j>``, ``X<j>``): it reads what block j made for the SAME
+        rows earlier in this launch, handed down the body as a value —
+        block j's pages after its append, block j's scan output — and
+        takes no pool entry.
 
         ``*``, grouped-query attention: `_gqa_mixer`, with the model's
-        rope table or — Nemotron-H has no rotary embedding — none, over
-        the pages of the attention mixers alone.
+        rope table or — Nemotron-H and Phi-4-flash have no rotary
+        embedding — none, over the pages of the attention mixers alone;
+        where the model has sliding-window layers (`attn_static`), over
+        the WINDOW kind of pages, table and append runs (`tables` and
+        `tok_page` are then pairs, as the llama body's), whose pages the
+        allocator releases as the window passes; with differential
+        heads in the pair layout.
+
+        ``X<j>``: the same mixer with a query and an output projection
+        only, over block j's pages (`shared_attention`).
+
+        ``S``, a Mamba-1 state-space mixer (a decay a (channel, state
+        column); the slot's state [1, N, C], channels along the lanes):
+        in-projection (`ssm1_in_proj`) -> `_conv_tails` (`ssm1_conv`)
+        -> dt / B / C projections, every live decode slot ONE step in
+        place (`ssm1_state_update`), the chunk's rows the selective
+        scan from its slot's state (`ssm1_chunk_scan`; rows past the
+        chunk's length carry dt 0, the identity), `ssm_state_put`
+        (`ssm1_scan`) -> the gate and the out-projection (`ssm1_out`).
+        Its scan output y (with the D term, before the gate) is what a
+        ``G<j>`` block reads: `silu(a W_a) * y`, then W_b (`gmu`).
 
         ``L``, gated latent attention: `_latent_mixer`, the mla
         family's, over pages that hold latent rows.
@@ -2829,6 +2941,8 @@ class ServingEngine:
         cfg, pattern = self._p["cfg"], self._p["pattern"]
         blocks, mu = self._blocks, self._p.get("mults")
         eps, K = cfg.layer_norm_epsilon, cfg.conv_kernel
+        diff, layer_kind = self._p.get("diff", {}), self._layer_kind
+        attn_static = self._attn_static
         moe_static = self._p["moe_static"]
         layout = self._state_layout
         B = self.max_slots
@@ -2837,8 +2951,10 @@ class ServingEngine:
         run_table = self._run_table(seq_start)
         f32 = jnp.float32
         if "*" in pattern:
-            gqa = dict(heads=cfg.num_attention_heads,
-                       kv=cfg.num_key_value_heads, d=cfg.head_dim, mults=mu)
+            # (the pool's geometry: a pair of heads where they are
+            # differential, else the config's)
+            gqa = dict(heads=cfg.num_attention_heads, kv=self._kv_geom[0],
+                       d=self._kv_geom[1], mults=mu, eps=eps)
         if mu:
             from ..models.falcon_h1 import mup_vector
             ffn_mults = {k: mu[k] for k in ("mlp_gate", "mlp_down")}
@@ -2969,6 +3085,50 @@ class ServingEngine:
                     y = y * mu["ssm_out"]
                 return y, z_pool, t_pool
 
+        def ssm1(L, a, z_pool, t_pool, num_tokens, tab):
+            """The Mamba-1 mixer of a [T, hidden] -> (its output [T,
+            hidden], the state pool, the tail pool, its scan output y
+            [T, d_inner] float32: with the D term, before the gate)."""
+            slots, n_live = tab[:B], tab[B:B + 1]
+            cslot, starts = tab[B + 1], tab[B + 2] > 0
+            n_c = num_tokens[B]
+            live = num_tokens[:B] > 0
+            Ci = cfg.d_inner
+            with jax.named_scope("ssm1_in_proj"):
+                xz = a @ L["w_in"]
+                u, z = xz[:, :Ci], xz[:, Ci:]
+            with jax.named_scope("ssm1_conv"):
+                u, t_pool = conv_tails(u, t_pool, L["conv_w"], L["conv_b"],
+                                       live, n_c, cslot, starts)
+            with jax.named_scope("ssm1_scan"):
+                dt, bm, cm = ssm1_operands(u, L, cfg)
+                uf = u.astype(f32)
+                neg_a = -jnp.exp(L["a_log_t"].astype(f32))      # [N, C]
+                # the decode rows: one step each, the state in place
+                # (row s of the operands is slot s; row B the spare's)
+                rows = slice(0, B + 1)
+                y_d, z_pool = _once(
+                    ssm1_state_update, "ssm1_scan", z_pool, slots, n_live,
+                    with_spare(dt)[rows], with_spare(uf)[rows], neg_a,
+                    with_spare(bm)[rows], with_spare(cm)[rows])
+                y = jnp.where(live[:, None], y_d[:B], 0)
+                if C:
+                    # the chunk: the selective scan from its slot's
+                    # state; rows past its length are the identity
+                    valid = (jnp.arange(C) < n_c)[:, None]
+                    y_c, z_pool = chunk_state(
+                        z_pool, n_c, cslot, starts,
+                        lambda s0: _once(
+                            ssm1_chunk_scan, "ssm1_scan",
+                            jnp.where(valid, dt[B:], 0), uf[B:], neg_a,
+                            bm[B:], cm[B:], s0), (C, Ci), "ssm1_scan")
+                    y = jnp.concatenate([y, y_c])
+                y = y + L["D"].astype(f32)[None] * uf
+            with jax.named_scope("ssm1_out"):
+                out = (y * jax.nn.silu(z.astype(f32))).astype(a.dtype) \
+                    @ L["w_out"]
+                return out, z_pool, t_pool, y
+
         def kda(L, a, z_pool, t_pool, num_tokens, tab):
             """The KDA mixer of a [T, hidden] -> (its output [T, hidden],
             the state pool, the tail pool)."""
@@ -3033,17 +3193,44 @@ class ServingEngine:
             elif "*" in pattern:
                 # (made once a step, here, as the parent's text has it)
                 rope = _no_turn(T, gqa["d"], x.dtype)
+            # the append's runs: once, or once a kind of cache (a model
+            # with window layers: the full kind's, then the window's)
             with _scope("cache_write"):
-                runs = run_table(num_tokens, tok_page, tok_off)
+                if isinstance(tables, tuple):
+                    runs = [run_table(num_tokens, page, tok_off)
+                            for page in tok_page]
+                else:
+                    runs, tables = (run_table(num_tokens, tok_page,
+                                              tok_off),) * 2, (tables,) * 2
             kv_pools, ssm_pools = iter(pools["kv"]), iter(pools["ssm"])
-            sts = iter(moe_static)
+            sts, owned = iter(moe_static), iter(zip(
+                layer_kind, attn_static, self._pool_readers))
             new_kv, new_ssm, moe_stats = [], [], []
+            # what a block that owns no memory reads: by the block that
+            # made it, this launch
+            pages_of, scan_of = {}, {}
             live = _owned_rows(T, seq_start, num_tokens)
             for i, block in enumerate(blocks):  # its letters: static
                 L = w["layers"][i]
-                a = _once(fused_rms_norm,
-                          "ffn_norm" if block in "ED" else "attn_norm",
-                          x, L["norm"], eps=eps)
+                scope = "ffn_norm" if block in "ED" else "attn_norm"
+                if "norm_b" in L:
+                    a = _once(fused_layer_norm, scope, x, L["norm"],
+                              L["norm_b"], eps=eps)
+                else:
+                    a = _once(fused_rms_norm, scope, x, L["norm"], eps=eps)
+                if block[0] == "G":     # a gated unit over a scan output
+                    with jax.named_scope("gmu"):
+                        g = jax.nn.silu((a[0] @ L["w_a"]).astype(f32))
+                        x = x + ((g * scan_of[int(block[1:])][0])
+                                 .astype(a.dtype) @ L["w_b"])[None]
+                    continue
+                if block[0] == "X":     # attention over another's pages
+                    y, _ = _gqa_mixer(
+                        L, a, rope, pages_of[int(block[1:])], seq_start,
+                        num_tokens, kv_lengths, tables[0], None,
+                        diff=diff.get(i), borrowed=True, **gqa)
+                    x = x + y
+                    continue
                 if block == "E":
                     x = x + _ffn_apply(L, a, next(sts), moe_stats, live)
                     continue
@@ -3054,24 +3241,32 @@ class ServingEngine:
                 # the block's mixers, each on the one normed input; the
                 # residual takes their sum
                 for kind in block:
-                    if kind in "MK":
-                        y, z_pool, t_pool = (ssm if kind == "M" else kda)(
+                    if kind in "MKS":
+                        y, z_pool, t_pool, *scan = {
+                            "M": ssm, "K": kda, "S": ssm1}[kind](
                             L, a[0], *next(ssm_pools), num_tokens, tab)
                         new_ssm.append((z_pool, t_pool))
+                        scan_of[i] = scan   # ([y] of an `S` mixer)
                         y = y[None]
                     elif kind == "L":
                         y, pool = _latent_mixer(
                             L, a, rope, next(kv_pools), seq_start,
-                            num_tokens, kv_lengths, tables, runs, **latent)
+                            num_tokens, kv_lengths, tables[0], runs[0],
+                            **latent)
                         new_kv.append(pool)
                     else:
+                        k, st, readers = next(owned)
                         y, pool = _gqa_mixer(
                             L, a, rope, next(kv_pools), seq_start,
-                            num_tokens, kv_lengths, tables, runs, **gqa)
+                            num_tokens, kv_lengths, tables[k], runs[k],
+                            window=st["window"], diff=diff.get(i),
+                            shared=readers > 1, **gqa)
                         new_kv.append(pool)
+                        pages_of[i] = pool
                     x = x + y
             with _scope("head"):
-                x = fused_rms_norm(x, w["norm"], eps)
+                x = fused_layer_norm(x, w["norm"], w["norm_b"], eps) \
+                    if "norm_b" in w else fused_rms_norm(x, w["norm"], eps)
                 logits = _head_logits(
                     w, _logit_rows(x, seq_start, num_tokens, 0))
                 if mu:

@@ -61,6 +61,8 @@ SHAPES = {
                                   page_size=32),
     "ssm_state_update": dict(live=3, P=16, N=16, H=8),
     "ssm_state_put": dict(P=16, N=16, H=8),
+    "ssm1_state_update": dict(live=3, C=256),
+    "ssm1_chunk_scan": dict(rows=64, C=256),
     "kda_state_update": dict(live=3, H=4, K=16, V=16),
     "mhc_pre": dict(T=16, n=4, C=64),
     "mhc_post": dict(T=16, n=4, C=64),
@@ -132,6 +134,23 @@ class TestRegistryCoverage:
         assert hm.flops == sm.flops == 5 * 58 * 128 * 256 * 32
         assert hm.hbm_bytes - sm.hbm_bytes == 58 * (
             2 * 256 * 32 * 2 - 2 * 2 * 256 * 4)
+
+    def test_mamba1_at_the_published_shapes(self):
+        """Phi-4-mini-flash: 5,120 channels x 16 columns, 327,680 B a
+        (slot, layer) as stored — the channels along the lanes, nothing
+        padded — and ONE full pool read by eight blocks."""
+        assert cm.ssm_state_bytes_per_seq_layer(
+            heads=1, head_dim=5120, state_size=16, conv_dim=5120,
+            conv_kernel=4) == 327_680 + 30_720
+        up = cm.cost("ssm1_state_update", live=32, C=5120)
+        assert up.breakdown["state"] == 2 * 32 * 327_680
+        assert up.flops == 7 * 32 * 81_920
+        scan = cm.cost("ssm1_chunk_scan", rows=256, C=5120)
+        assert scan.breakdown["state"] == 2 * 327_680
+        assert scan.flops == 7 * 256 * 81_920
+        # 32 sequences at ~6.4 k tokens: 25 pages each of 1,310,720 B
+        assert cm.shared_pool_read_bytes(
+            pages=800, page_bytes=1_310_720, readers=8) == 8_388_608_000
 
     def test_unknown_kernel_raises_with_known_list(self):
         with pytest.raises(KeyError, match="known"):

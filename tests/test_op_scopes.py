@@ -319,6 +319,11 @@ def _falcon():
     return seeded(max_position_embeddings=64)[0]
 
 
+def _phi4flash():
+    from test_phi4flash import seeded
+    return seeded(max_position_embeddings=64)[0]
+
+
 #: family -> names its unified step must show
 FAMILIES = {
     "llama": {"ffn"}, "moe": {"routed_ffn", "shared_expert"},
@@ -329,6 +334,9 @@ FAMILIES = {
     # both mixers of a block on one norm, a dense FFN: every name of a
     # layer (the state-space parts answer through SCOPE_ALIASES)
     "hybrid_two_mixers": {"ffn"},
+    # blocks that own no memory beside blocks that do, LayerNorm, window
+    # pages: every name of a layer (through SCOPE_ALIASES)
+    "hybrid_borrowed": {"ffn"},
 }
 EVERY_STEP = {"embed", "attn_norm", "qkv_proj", "cache_write", "attention",
               "attn_out", "ffn_norm", "head"}
@@ -339,7 +347,8 @@ def test_every_op_of_a_serving_step_answers_to_a_name(family):
     m = _laguna() if family == "laguna" else _eva() if family == "eva" \
         else _ouro() if family == "looped" \
         else _nemotron() if family == "hybrid" \
-        else _falcon() if family == "hybrid_two_mixers" else _tiny(family)
+        else _falcon() if family == "hybrid_two_mixers" \
+        else _phi4flash() if family == "hybrid_borrowed" else _tiny(family)
     kw = dict(max_slots=3, page_size=8, max_context=256, prefill_chunk=8,
               num_pages=64) if family == "eva" else \
         dict(max_slots=2, page_size=8, max_context=64, prefill_chunk=8)
@@ -378,6 +387,16 @@ def test_every_op_of_a_serving_step_answers_to_a_name(family):
             # (what has none: the zero state and zero y of a launch
             # whose chunk is absent, the hybrid body's `chunk_state`)
             fills = {"f32[4,8,128]", "f32[8,4,8]"}
+            assert not [r for r in step if r.scope is None
+                        and r.kind == "compute" and r.shape not in fills]
+        if family == "hybrid_borrowed":
+            # every mixer's own names — a launch over borrowed pages
+            # told from one over a block's own — and no arithmetic
+            # outside a name (what has none: `chunk_state`'s fills)
+            assert {"ssm1_in_proj", "ssm1_conv", "ssm1_scan", "ssm1_out",
+                    "gmu", "attention", "shared_attention",
+                    "diff_combine"} <= {r.own for r in step}
+            fills = {"f32[1,16,128]", "f32[8,128]"}
             assert not [r for r in step if r.scope is None
                         and r.kind == "compute" and r.shape not in fills]
     # ... and read together, as a trace's reader does: a key the two

@@ -2,9 +2,11 @@
 against the recurrence token by token: one step for the live slots in
 place (idle slots bit-unchanged), the chunk's scan at chunk lengths
 around the scan chunk (1, 127, 128, 129, 256 rows), `dt = 0` padding as
-the identity, and one slot put in place; and the same over the
+the identity, and one slot put in place; the same over the
 state-minor pool [slots, H, P, N] that a model of fewer than 128 heads
-over a state of whole registers takes (`TestStateMinor`)."""
+over a state of whole registers takes (`TestStateMinor`); and Mamba-1's
+two kernels — a decay a (channel, column), the pool [slots, 1, N, C] —
+against ITS recurrence token by token (`TestMamba1`)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -12,9 +14,12 @@ import pytest
 
 from paddle_tpu.ops.oracles import oracles, resolve_reference
 from paddle_tpu.ops.pallas_ssm import (HEADS_MINOR, STATE_MINOR,
+                                       ssm1_chunk_scan, ssm1_state_update,
                                        ssm_chunk_scan, ssm_state_put,
                                        ssm_state_update, state_layout,
                                        state_pool_shape)
+from paddle_tpu.ops.references import (ssm1_recurrence_reference,
+                                       ssm1_state_update_reference)
 from paddle_tpu.ops.references import \
     ssm_recurrence_reference as ssm_recurrence
 
@@ -230,3 +235,94 @@ class TestStateMinor:
         args = (pool, jnp.asarray([slot, go], jnp.int32), state)
         want = resolve_reference(oracles()["ssm_state_put"])(*args)
         np.testing.assert_array_equal(ssm_state_put(*args), want)
+
+
+# ---------------------------------------------------------------------------
+# Mamba-1: a decay for every (channel, state column)
+# ---------------------------------------------------------------------------
+
+C1, N1 = 256, 16
+
+
+def _rows1(rng, L):
+    """(dt [L, C] > 0, x [L, C], A [N, C] < 0, B, C [L, N])."""
+    f32 = jnp.float32
+    return (jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(0.3),
+                                           (L, C1))), f32),
+            jnp.asarray(rng.normal(0, 1, (L, C1)), f32),
+            -jnp.asarray(rng.uniform(1, 16, (N1, C1)), f32),
+            jnp.asarray(rng.normal(0, 1, (L, N1)), f32),
+            jnp.asarray(rng.normal(0, 1, (L, N1)), f32))
+
+
+class TestMamba1:
+    @pytest.mark.parametrize("L", [1, 7, 8, 21, 64])
+    def test_the_scan_is_the_recurrence(self, L):
+        rng = np.random.default_rng(L)
+        dt, x, a, bm, cm = _rows1(rng, L)
+        s0 = jnp.asarray(rng.normal(0, 1, (1, N1, C1)), jnp.float32)
+        y, s1 = ssm1_chunk_scan(dt, x, a, bm, cm, s0)
+        yr, sr = ssm1_recurrence_reference(dt, x, a, bm, cm, s0)
+        assert y.shape == (L, C1) and s1.shape == s0.shape
+        np.testing.assert_allclose(y, yr, atol=2e-5, rtol=1e-5)
+        np.testing.assert_allclose(s1, sr, atol=2e-5, rtol=1e-5)
+
+    def test_rows_with_dt_zero_change_nothing(self):
+        """Padding a chunk: rows past its length carry dt 0."""
+        rng = np.random.default_rng(0)
+        dt, x, a, bm, cm = _rows1(rng, 24)
+        s0 = jnp.asarray(rng.normal(0, 1, (1, N1, C1)), jnp.float32)
+        valid = (jnp.arange(24) < 13)[:, None]
+        y, s1 = ssm1_chunk_scan(jnp.where(valid, dt, 0), x, a, bm, cm, s0)
+        yr, sr = ssm1_recurrence_reference(dt[:13], x[:13], a, bm[:13],
+                                           cm[:13], s0)
+        np.testing.assert_allclose(y[:13], yr, atol=2e-5, rtol=1e-5)
+        np.testing.assert_allclose(s1, sr, atol=2e-5, rtol=1e-5)
+
+    @pytest.mark.parametrize("live", [(), (2,), (3, 0, 1)])
+    def test_one_step_for_the_live_slots_in_place(self, live):
+        """An idle slot is neither read nor written: bit for bit."""
+        rng = np.random.default_rng(len(live))
+        NS, B = 5, 4
+        dt, x, a, bm, cm = _rows1(rng, NS)
+        pool = jnp.asarray(rng.normal(0, 1, (NS, 1, N1, C1)), jnp.float32)
+        slots = jnp.asarray(list(live) + [NS - 1] * (B - len(live)),
+                            jnp.int32)
+        n = jnp.asarray([len(live)], jnp.int32)
+        before = np.asarray(pool)
+        y, new = ssm1_state_update(pool, slots, n, dt, x, a, bm, cm)
+        yr, want = ssm1_state_update_reference(pool, slots, n, dt, x, a, bm,
+                                               cm)
+        rows = list(live)
+        np.testing.assert_allclose(np.asarray(y)[rows], np.asarray(yr)[rows],
+                                   atol=2e-5, rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(new)[rows],
+                                   np.asarray(want)[rows], atol=2e-5,
+                                   rtol=1e-5)
+        idle = [s for s in range(NS) if s not in live]
+        np.testing.assert_array_equal(np.asarray(new)[idle], before[idle])
+
+    def test_the_scan_hands_its_state_to_the_update(self):
+        """A chunk's scan, its state put in a slot, then one decode step
+        from it: the recurrence over all the rows."""
+        rng = np.random.default_rng(5)
+        L, NS = 19, 3
+        dt, x, a, bm, cm = _rows1(rng, L + 1)
+        zero = jnp.zeros((1, N1, C1), jnp.float32)
+        _, s1 = ssm1_chunk_scan(dt[:L], x[:L], a, bm[:L], cm[:L], zero)
+        pool = ssm_state_put(jnp.ones((NS, 1, N1, C1), jnp.float32),
+                             jnp.asarray([1, 1], jnp.int32), s1)
+        row = lambda m: jnp.zeros((NS,) + m.shape[1:]).at[1].set(m[L])  # noqa
+        y, pool = ssm1_state_update(
+            pool, jnp.asarray([1, NS - 1], jnp.int32),
+            jnp.asarray([1], jnp.int32), row(dt), row(x), a, row(bm),
+            row(cm))
+        yr, sr = ssm1_recurrence_reference(dt, x, a, bm, cm, zero)
+        np.testing.assert_allclose(y[1], yr[L], atol=2e-5, rtol=1e-5)
+        np.testing.assert_allclose(pool[1], sr, atol=2e-5, rtol=1e-5)
+        np.testing.assert_array_equal(pool[0], 1.0)
+
+    def test_the_oracles_are_registered(self):
+        for name in ("ssm1_state_update", "ssm1_chunk_scan"):
+            assert name in oracles()
+            assert callable(resolve_reference(oracles()[name]))

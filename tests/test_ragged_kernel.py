@@ -354,6 +354,38 @@ class TestRaggedKernelParity:
     def test_matches_reference(self, T, S, H, KV, D, psz, pps):
         _check(*_setup(T, S, H, KV, D, psz, pps))
 
+    @pytest.mark.parametrize("window", [None, 512, 11])
+    def test_a_padded_pair_is_two_unpadded_softmaxes(self, window):
+        """Differential heads in the pair layout (Phi-4-flash: KV 10, D
+        128, 4 query heads a KV head, no rotary; here the same group at
+        D 32 over 2 pairs): a KV head of the pool is two published heads
+        side by side, a query head is padded with zeros on the side of
+        the K head it does not use, the scale is the unpadded head's.
+        Head h of the output is then softmax(q_h K_{2 (h // 4) + h % 2}^T)
+        [V_2j | V_2j+1] — computed here UNPADDED, a 16-wide softmax a
+        query head against its own K head, once for each V head of the
+        pair."""
+        from paddle_tpu.models.phi4flash import pair_queries
+        T, S, H, KV, D, psz, pps = 40, 3, 8, 2, 32, 8, 6
+        q, kp, vp, ss, nt, kvl, tab = _setup(T, S, H, KV, D, psz, pps,
+                                             seed=4)
+        q16 = q[..., :D // 2]
+        scale = (D // 2) ** -0.5
+        out = ragged_paged_attention(pair_queries(q16), kp, vp, ss, nt, kvl,
+                                     tab, scale=scale, window=window)
+        h = np.arange(H)
+        half = (h % 2)[:, None] * (D // 2) + np.arange(D // 2)[None]
+        # query head h's own K head, 16 wide: a pool of H heads, rep 1
+        k_own = jnp.take_along_axis(
+            kp[h // 4], jnp.asarray(half)[:, None, None, :], -1)
+        want = jnp.concatenate([
+            ragged_attention_reference(
+                q16, k_own, vp[h // 4][..., side], ss, nt, kvl, tab,
+                scale=scale, window=window)
+            for side in (slice(0, D // 2), slice(D // 2, D))], -1)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+
     def test_mixed_prefill_decode_batch(self):
         # the engine's exact shape: decode rows 0..B-1 (1 token each),
         # a prefill chunk on rows B.., kv_lengths include the new rows

@@ -451,6 +451,35 @@ def test_state_space_kernels_compile_at_the_nemotron_cell_shapes(chip):
         chip.shape((c, g, n))), chip.refusals.get(_ssm_layer_kernels)
 
 
+def _ssm1_layer_kernels(pool, tab, dt, x, a, bm, cm, dtc, xc, bc, cc):
+    from paddle_tpu.ops.pallas_ssm import (ssm1_chunk_scan,
+                                           ssm1_state_update, ssm_state_put)
+    b = tab.shape[0] - 3
+    y, pool = ssm1_state_update(pool, tab[:b], tab[b:b + 1], dt, x, a, bm, cm)
+    yc, s1 = ssm1_chunk_scan(dtc, xc, a, bc, cc, pool[tab[b + 1]])
+    return y, yc, ssm_state_put(pool, tab[b + 1:], s1)
+
+
+def test_mamba1_kernels_compile_at_the_phi4flash_cell_shapes(chip):
+    """`phi-4-mini-flash-serve-whole` as its cell runs it: a state pool of
+    32 slots + the spare x [1, 16, 5120] float32 (channels along the
+    lanes), the decode rows' update in place, a 256-row chunk's
+    selective scan in four row blocks a channel block and its state's
+    write in place; and the ragged kernel tiles the pair layout (40
+    query heads over 10 KV pairs of 128, pages of 256)."""
+    from paddle_tpu.ops.pallas_ragged import ragged_kernel_eligible
+    ns, n, c, rows = 33, 16, 5120, 256
+    assert chip.compiles(
+        _ssm1_layer_kernels, chip.shape((ns, 1, n, c), F32),
+        chip.shape((ns + 2,), I32), chip.shape((ns, c), F32),
+        chip.shape((ns, c), F32), chip.shape((n, c), F32),
+        chip.shape((ns, n), F32), chip.shape((ns, n), F32),
+        chip.shape((rows, c), F32), chip.shape((rows, c), F32),
+        chip.shape((rows, n), F32), chip.shape((rows, n), F32)), \
+        chip.refusals.get(_ssm1_layer_kernels)
+    assert ragged_kernel_eligible(40, 10, 128, 256)
+
+
 def _ssm_state_minor_kernels(pool, tab, xdt, dec, bm, cm, xc, dac, bc, cc):
     from paddle_tpu.ops.pallas_ssm import (STATE_MINOR, ssm_chunk_scan,
                                            ssm_state_put, ssm_state_update)

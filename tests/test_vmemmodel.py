@@ -40,7 +40,7 @@ class TestCanonicalCoverage:
         registered = set(cm.costs())
         modeled = {e["kernel"] for e in vm.CANONICAL.values()}
         assert modeled == registered
-        assert len(registered) == 25
+        assert len(registered) == 27
 
     def test_every_entry_resolves_to_one_repo_site(self, sites):
         missing = sorted(set(vm.CANONICAL) - set(sites))
@@ -53,7 +53,7 @@ class TestCostAgreement:
 
     def test_all_canonical_sites_within_tolerance(self, index):
         recs = vm.derive_cost_bytes(index)
-        assert len(recs) == 29
+        assert len(recs) == 31
         bad = [(r["kernel"], r["status"], r.get("rel_err"))
                for r in recs if r["status"] != "ok"]
         assert bad == []
