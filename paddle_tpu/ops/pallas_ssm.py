@@ -57,14 +57,29 @@ Shapes that fill neither (toy widths) stay heads-minor.
   written. Memory-bound by construction: 2 x P x N x H x 4 bytes a slot.
 - `ssm_chunk_scan`: a run of rows of ONE sequence from its state, in
   scan chunks (the config's ``chunk_size``, 128): inside a chunk the
-  quadratic (attention-like) form, between chunks the state. Plain XLA:
-  batched matmuls over (group, head). Rows whose ``dt`` is 0 are the
-  identity, so a chunk is padded by zeroing ``dt``.
+  quadratic (attention-like) form, between chunks the state. Plain XLA,
+  the heads batch-MAJOR: for head ``h`` of group ``g``, with ``cs`` the
+  cumulative sum of ``dt A`` down a scan chunk of L rows (<= 0, so
+  every exponential's argument is)::
+
+      CB   = C_g B_g^T                                 [L, L]  once a group
+      M    = tril(exp(cs_t - cs_r)) * CB               [L, L]
+      Y_h  = M X'_h + exp(cs)[:, None] * (C_g S_h^T)   [L, P]
+      S_h' = exp(cs_L) S_h + (X'_h * exp(cs_L - cs))^T B_g     [P, N]
+
+  matmuls batched over (group, head), the two with the state float32
+  `HIGHEST`; the scan chunks are a Python loop (two in a serving step),
+  NOT a `lax.scan`: inside the step program the loop's stacked y was
+  laid out in tiles of two rows and ONE dynamic-update-slice of it took
+  half the scan's time (PERF.md section 6, PR 57). Rows whose ``dt`` is
+  0 are the identity, so a chunk is padded by zeroing ``dt``.
 - `ssm_state_put`: writes one slot of the pool in place (the chunk's
   new state); blocks of the pool's second dimension, either layout.
 
 ``layout="state_minor"`` selects the second form of the first two; the
-default is the first, and its operands are what they were.
+default is the first, and its operands are what they were. The chunk's
+scan is written over the state-minor slot [H, P, N]; a heads-minor
+caller's ONE slot is turned around it.
 """
 
 from __future__ import annotations
@@ -350,37 +365,6 @@ def ssm_state_put(pool, slot, state):
 # a run of rows of one sequence, in scan chunks
 # ---------------------------------------------------------------------------
 
-def _scan_chunk(state, rows, *, G: int, layout: str = HEADS_MINOR):
-    """One scan chunk: (state [P, N, H] — [H, P, N] state-minor —, (xdt
-    [L, H, P], dA [L, H], B, C [L, G, N])) -> (new state, y [L, H, P]);
-    float32."""
-    xdt, dA, bm, cm = rows
-    L, H, P = xdt.shape
-    N, K = bm.shape[-1], H // G
-    sm = layout == STATE_MINOR
-    hi = jax.lax.Precision.HIGHEST
-    cs = jnp.cumsum(dA, 0)                              # [L, H], <= 0
-    # inside the chunk: y_t += sum_{r<=t} exp(cs_t - cs_r) (C_t.B_r) xdt_r
-    t = jnp.arange(L)
-    seg = jnp.where((t[:, None] >= t[None, :])[..., None],
-                    cs[:, None, :] - cs[None, :, :], -jnp.inf)
-    cb = jnp.einsum("tgn,rgn->trg", cm, bm)             # [L, L, G]
-    m = jnp.exp(seg).reshape(L, L, G, K) * cb[..., None]
-    x5 = xdt.reshape(L, G, K, P)
-    y = jnp.einsum("trgk,rgkp->tgkp", m, x5)
-    # from the state the chunk starts with
-    s5 = state.reshape((G, K, P, N) if sm else (P, N, G, K))
-    y = y + jnp.exp(cs).reshape(L, G, K, 1) * jnp.einsum(
-        "tgn,gkpn->tgkp" if sm else "tgn,pngk->tgkp", cm, s5, precision=hi)
-    # the state the chunk leaves
-    w = jnp.exp(cs[-1][None] - cs).reshape(L, G, K, 1)
-    last = jnp.exp(cs[-1]).reshape((G, K, 1, 1) if sm else (1, 1, G, K))
-    new = last * s5 + jnp.einsum(
-        "rgkp,rgn->gkpn" if sm else "rgkp,rgn->pngk", x5 * w, bm,
-        precision=hi)
-    return new.reshape(state.shape), y.reshape(L, H, P)
-
-
 def ssm_chunk_scan(xdt, dA, bm, cm, state, *, chunk: int = 128,
                    layout: str = HEADS_MINOR):
     """A run of L rows of ONE sequence from ``state``, in scan chunks of
@@ -391,18 +375,47 @@ def ssm_chunk_scan(xdt, dA, bm, cm, state, *, chunk: int = 128,
     inside. A row with ``dt`` 0 changes
     nothing (its xdt and dA are 0) and its own y is discarded by the
     caller. Returns (y [L, H, P] float32 = S_t C_t, the state after the
-    last row)."""
+    last row).
+
+    Plain XLA, the heads batch-MAJOR (module docstring's equations):
+    every product is a matmul batched over (group, head) with the batch
+    dimensions leading, and the scan chunks are walked by a Python loop
+    — L / chunk is static, two in a serving step — so the program holds
+    no loop and no stacked output for the compiler to lay out."""
     L, H, P = xdt.shape
-    G = bm.shape[1]
+    G, N = bm.shape[1:]
+    K = H // G
     f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
     pad = -L % chunk
-    rows = tuple(jnp.pad(a.astype(f32), ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-                 .reshape((-1, chunk) + a.shape[1:])
-                 for a in (xdt, dA, bm, cm))
-    state, y = jax.lax.scan(
-        functools.partial(_scan_chunk, G=G, layout=layout),
-        state.astype(f32), rows)
-    return y.reshape(-1, H, P)[:L], state
+    xdt, dA, bm, cm = (
+        jnp.pad(a.astype(f32), ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+        .reshape((-1, chunk) + a.shape[1:]) for a in (xdt, dA, bm, cm))
+    x = xdt.reshape(-1, chunk, G, K, P).transpose(0, 2, 3, 1, 4)
+    b, c = bm.transpose(0, 2, 1, 3), cm.transpose(0, 2, 1, 3)
+    cs = jnp.cumsum(dA.reshape(-1, chunk, G, K), 1).transpose(0, 2, 3, 1)
+    sm = layout == STATE_MINOR
+    s = state.astype(f32)
+    s = (s if sm else s.transpose(2, 0, 1)).reshape(G, K, P, N)
+    t = jnp.arange(chunk)
+    below = t[:, None] >= t[None, :]
+    ys = []
+    for x_i, b_i, c_i, cs_i in zip(x, b, c, cs):
+        # x' [G, K, Lc, P], B and C [G, Lc, N], cs [G, K, Lc]; t >= r
+        # keeps every exponential's argument <= 0
+        m = jnp.exp(jnp.where(below, cs_i[..., :, None] - cs_i[..., None, :],
+                              -jnp.inf)) \
+            * jnp.einsum("gtn,grn->gtr", c_i, b_i)[:, None]
+        ys.append(jnp.einsum("gktr,gkrp->gktp", m, x_i)
+                  + jnp.exp(cs_i)[..., None] * jnp.einsum(
+                      "gtn,gkpn->gktp", c_i, s, precision=hi))
+        last = cs_i[..., -1:]
+        s = jnp.exp(last)[..., None] * s + jnp.einsum(
+            "gkrp,grn->gkpn", x_i * jnp.exp(last - cs_i)[..., None], b_i,
+            precision=hi)
+    y = jnp.stack(ys).transpose(0, 3, 1, 2, 4).reshape(-1, H, P)[:L]
+    s = s.reshape(H, P, N)
+    return y, (s if sm else s.transpose(1, 2, 0))
 
 
 # ---------------------------------------------------------------------------

@@ -398,8 +398,11 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
 #: share, `engine._once`: the norms, the state updates, the chunk's scan
 #: and put) re-recorded it and Ling's, PR 55 (the ragged kernel's softmax
 #: state lane-replicated: `test_evabyte_serving`) both with the seven.
+#: PR 57 (`ssm_chunk_scan` the heads batch-major, its scan chunks a Python
+#: loop and no `lax.scan`) re-recorded THIS one alone: Ling's chunk scan
+#: is `kda_chunk_scan`, the other eight programs hold no Mamba-2 block.
 HYBRID_LOWERED_AT_PARENT = \
-    "0a3d02ddbd654578ff999818b1ee799ddf49fb8135d5e873f2f64e99e1f318c9"
+    "8189d8f0d7798726eac8d18b718860c3302d51c03f858aa993d1c236f4b12384"
 
 
 def test_the_nemotron_step_lowers_to_the_parents_text():

@@ -25,9 +25,21 @@ from paddle_tpu.ops.references import \
 
 H, P, G, N = 8, 16, 2, 16
 
+#: (heads, head width, groups, state, layout) the chunk's scan is held
+#: to: the two layouts, one head a group and sixteen, and a head width
+#: under the 128 lanes (Nemotron's 64)
+GEOMETRIES = {
+    "heads_minor": (H, P, G, N, HEADS_MINOR),
+    "state_minor": (4, 8, 2, 128, STATE_MINOR),
+    "one_head_a_group": (8, 16, 8, 16, HEADS_MINOR),
+    "sixteen_heads_a_group": (32, 8, 2, 16, HEADS_MINOR),
+    "heads_of_64": (4, 64, 2, 128, STATE_MINOR),
+}
 
-def _rows(rng, L):
+
+def _rows(rng, L, geometry="heads_minor"):
     """(xdt [L, H, P], dA [L, H] <= 0, B, C [L, G, N])."""
+    H, P, G, N, _ = GEOMETRIES[geometry]
     dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.3), (L, H)))
     A = -rng.uniform(1, 16, H)
     return (jnp.asarray(rng.normal(0, 1, (L, H, P)) * dt[..., None],
@@ -35,6 +47,25 @@ def _rows(rng, L):
             jnp.asarray(dt * A, jnp.float32),
             jnp.asarray(rng.normal(0, 1, (L, G, N)), jnp.float32),
             jnp.asarray(rng.normal(0, 1, (L, G, N)), jnp.float32))
+
+
+def _state(rng, geometry, zeros=False):
+    """A state in the recurrence's [P, N, H] and as the geometry's
+    layout stores it."""
+    H, P, _, N, layout = GEOMETRIES[geometry]
+    s = jnp.asarray(np.zeros((P, N, H)) if zeros
+                    else rng.normal(0, 1, (P, N, H)), jnp.float32)
+    return s, (jnp.transpose(s, (2, 0, 1)) if layout == STATE_MINOR else s)
+
+
+def _scan(rows, stored, geometry, chunk):
+    """`ssm_chunk_scan` in the geometry's layout -> (y, the state in the
+    recurrence's [P, N, H], the state as stored)."""
+    layout = GEOMETRIES[geometry][-1]
+    y, s = ssm_chunk_scan(*rows, stored, chunk=chunk, layout=layout)
+    assert s.shape == stored.shape
+    return y, (jnp.transpose(s, (1, 2, 0)) if layout == STATE_MINOR
+               else s), s
 
 
 class TestChunkScan:
@@ -49,30 +80,73 @@ class TestChunkScan:
         np.testing.assert_allclose(got_y, want_y, atol=2e-4, rtol=2e-4)
         np.testing.assert_allclose(got_s, want_s, atol=2e-4, rtol=2e-4)
 
-    def test_rows_with_dt_zero_change_nothing(self):
-        """A chunk of 37 valid rows padded to 64: the padding's xdt and
-        dA are 0, the state is that of the 37 rows alone."""
+    @pytest.mark.parametrize("start", ["zeros", "a_state"])
+    @pytest.mark.parametrize("geometry", list(GEOMETRIES))
+    def test_every_geometry_is_the_recurrence(self, geometry, start):
+        """40 rows in scan chunks of 16 (not a whole number of them),
+        from zeros and from a state."""
+        rng = np.random.default_rng(len(geometry))
+        rows = _rows(rng, 40, geometry)
+        s0, stored = _state(rng, geometry, zeros=start == "zeros")
+        want_y, want_s = ssm_recurrence(*rows, s0)
+        got_y, got_s, _ = _scan(rows, stored, geometry, 16)
+        assert got_y.shape == want_y.shape
+        np.testing.assert_allclose(got_y, want_y, atol=2e-4, rtol=2e-4)
+        np.testing.assert_allclose(got_s, want_s, atol=2e-4, rtol=2e-4)
+
+    @pytest.mark.parametrize("geometry", ["heads_minor", "state_minor"])
+    @pytest.mark.parametrize("where", ["at_the_end", "in_the_middle"])
+    def test_rows_with_dt_zero_change_nothing(self, where, geometry):
+        """A chunk of 64 rows of which 37 are valid — the first 37, or
+        with rows 20 to 46 idle in the middle: the idle rows' xdt and dA
+        are 0, the state and the valid rows' y are those of the 37 rows
+        alone."""
         rng = np.random.default_rng(5)
-        xdt, dA, bm, cm = _rows(rng, 64)
-        valid = (np.arange(64) < 37)
+        xdt, dA, bm, cm = _rows(rng, 64, geometry)
+        valid = np.arange(64) < 37 if where == "at_the_end" \
+            else (np.arange(64) < 20) | (np.arange(64) >= 47)
         pad = (jnp.where(valid[:, None, None], xdt, 0),
                jnp.where(valid[:, None], dA, 0), bm, cm)
-        s0 = jnp.asarray(rng.normal(0, 1, (P, N, H)), jnp.float32)
-        y, s = ssm_chunk_scan(*pad, s0, chunk=16)
-        want_y, want_s = ssm_recurrence(*(a[:37] for a in pad), s0)
+        s0, stored = _state(rng, geometry)
+        y, s, _ = _scan(pad, stored, geometry, 16)
+        want_y, want_s = ssm_recurrence(*(a[valid] for a in pad), s0)
         np.testing.assert_allclose(s, want_s, atol=2e-4, rtol=2e-4)
-        np.testing.assert_allclose(y[:37], want_y, atol=2e-4, rtol=2e-4)
+        np.testing.assert_allclose(y[valid], want_y, atol=2e-4, rtol=2e-4)
 
-    def test_two_chunks_carry_the_state(self):
+    @pytest.mark.parametrize("geometry", ["heads_minor", "state_minor"])
+    def test_two_chunks_carry_the_state(self, geometry):
+        """Two calls, the second from the first one's state, are ONE
+        call over all the rows, and the recurrence."""
         rng = np.random.default_rng(6)
-        rows = _rows(rng, 48)
-        s0 = jnp.zeros((P, N, H), jnp.float32)
-        y1, s1 = ssm_chunk_scan(*(a[:20] for a in rows), s0, chunk=8)
-        y2, s2 = ssm_chunk_scan(*(a[20:] for a in rows), s1, chunk=8)
+        rows = _rows(rng, 48, geometry)
+        s0, stored = _state(rng, geometry, zeros=True)
+        y1, _, kept = _scan([a[:24] for a in rows], stored, geometry, 8)
+        y2, s2, _ = _scan([a[24:] for a in rows], kept, geometry, 8)
+        one_y, one_s, _ = _scan(rows, stored, geometry, 8)
         want_y, want_s = ssm_recurrence(*rows, s0)
-        np.testing.assert_allclose(jnp.concatenate([y1, y2]), want_y,
-                                   atol=2e-4, rtol=2e-4)
-        np.testing.assert_allclose(s2, want_s, atol=2e-4, rtol=2e-4)
+        for got_y, got_s in ((jnp.concatenate([y1, y2]), s2),
+                             (one_y, one_s)):
+            np.testing.assert_allclose(got_y, want_y, atol=2e-4, rtol=2e-4)
+            np.testing.assert_allclose(got_s, want_s, atol=2e-4, rtol=2e-4)
+
+    def test_the_scan_has_the_recurrences_gradient(self):
+        """The models' eager forwards run under `jax.vjp`: the scan is
+        plain XLA and differentiable, and the gradient of its outputs in
+        x' is the token-by-token recurrence's."""
+        import jax
+        rng = np.random.default_rng(7)
+        rows = _rows(rng, 24)
+        s0 = jnp.asarray(rng.normal(0, 1, (P, N, H)), jnp.float32)
+        weight = jnp.asarray(rng.normal(0, 1, (24, H, P)), jnp.float32)
+
+        def loss(scan, x):
+            y, s = scan(x, *rows[1:], s0)
+            return jnp.sum(y * weight) + jnp.sum(s)
+
+        got = jax.grad(lambda x: loss(
+            lambda *a: ssm_chunk_scan(*a, chunk=8), x))(rows[0])
+        want = jax.grad(lambda x: loss(ssm_recurrence, x))(rows[0])
+        np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
 
 
 def _update_operands(rng, NS):

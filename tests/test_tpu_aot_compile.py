@@ -42,7 +42,8 @@ ALGOS = {"bf16": None, "int8": "weight_only_int8",
 def chip():
     """One described v5e chip: ``shape(dims, dtype)`` builds an argument
     placed on it, ``compiles(fn, *shapes)`` says whether the TPU compiler
-    accepts ``jit(fn)`` (and keeps the reason when it does not)."""
+    accepts ``jit(fn)`` (and keeps the reason when it does not, the
+    compiled text under ``texts[fn]`` when it does)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
     from jax.sharding import SingleDeviceSharding
@@ -71,7 +72,7 @@ def chip():
     precision_was = jax.config.jax_default_matmul_precision
     jax.config.update("jax_default_matmul_precision", None)
 
-    verdicts, refusals = {}, {}
+    verdicts, refusals, texts = {}, {}, {}
 
     def shape(dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
@@ -80,7 +81,7 @@ def chip():
         key = (fn, shapes)      # each kernel variant compiles once
         if key not in verdicts:
             try:
-                jax.jit(fn).lower(*shapes).compile()
+                texts[fn] = jax.jit(fn).lower(*shapes).compile().as_text()
                 verdicts[key] = True
             except Exception as e:  # noqa: BLE001 - the compiler's refusal
                 refusals[fn] = str(e)[:400]
@@ -88,7 +89,7 @@ def chip():
         return verdicts[key]
 
     yield types.SimpleNamespace(shape=shape, compiles=compiles,
-                                refusals=refusals)
+                                refusals=refusals, texts=texts)
     mp.undo()
     jax.config.update("jax_default_matmul_precision", precision_was)
     jax.config.update("jax_enable_compilation_cache", cache_was)
@@ -435,12 +436,24 @@ def _ssm_layer_kernels(pool, tab, xdt, dec, bh, ch, xc, dac, bc, cc):
     return y, yc, ssm_state_put(pool, tab[b + 1:], s1)
 
 
+def _chunk_scan_holds_no_loop(text, heads, groups):
+    """The chunk's scan in a compiled text is not the `lax.scan` of
+    einsums it was (PR 57: inside the step program that loop's stacked
+    output was half its time): no `while`, and none of that form's
+    float32 [128, 128, heads] / [128, 128, groups] intermediates, whose
+    minor dimension of 2 to 32 took 128 lanes."""
+    assert " while(" not in text
+    assert not re.search(r"f32\[128,128,(%d|%d)\]" % (heads, groups), text)
+    return True
+
+
 def test_state_space_kernels_compile_at_the_nemotron_cell_shapes(chip):
     """`nemotron-3-super-serve-ep4-d11` as its cell runs it: a state pool
     of 128 slots + the spare x [64, 128, 128] float32 (heads minor), the
     decode rows' update in place, a 256-row chunk's scan in two scan
-    chunks of 128 (128 heads x 64 in 8 groups, state 128) and its
-    state's write in place."""
+    chunks of 128 (128 heads x 64 in 8 groups, state 128: the heads
+    batch-major, the slot turned around it, no loop) and its state's
+    write in place."""
     ns, p, n, h, g, c = 129, 64, 128, 128, 8, 256
     assert chip.compiles(
         _ssm_layer_kernels, chip.shape((ns, p, n, h), F32),
@@ -449,6 +462,7 @@ def test_state_space_kernels_compile_at_the_nemotron_cell_shapes(chip):
         chip.shape((ns, n, h)), chip.shape((c, h, p), F32),
         chip.shape((c, h), F32), chip.shape((c, g, n)),
         chip.shape((c, g, n))), chip.refusals.get(_ssm_layer_kernels)
+    assert _chunk_scan_holds_no_loop(chip.texts[_ssm_layer_kernels], h, g)
 
 
 def _ssm1_layer_kernels(pool, tab, dt, x, a, bm, cm, dtc, xc, bc, cc):
@@ -496,8 +510,9 @@ def test_state_space_kernels_compile_at_the_falcon_cell_shapes(chip):
     64 slots + the spare x [32, 128, 256] float32 (STATE minor: 32 heads
     would fill a quarter of the lanes), the decode rows' update in place
     with a group's B / C rows not expanded, a 256-row chunk's scan in two
-    scan chunks of 128 (32 heads x 128 in 2 groups, state 256) and its
-    state's write in place in blocks of 8 heads."""
+    scan chunks of 128 (32 heads x 128 in 2 groups, state 256: the heads
+    batch-major over the slot as stored, no loop) and its state's write
+    in place in blocks of 8 heads."""
     from paddle_tpu.ops.pallas_ssm import STATE_MINOR, state_layout
     ns, p, n, h, g, c = 65, 128, 256, 32, 2, 256
     assert state_layout(h, n) == STATE_MINOR
@@ -508,6 +523,8 @@ def test_state_space_kernels_compile_at_the_falcon_cell_shapes(chip):
         chip.shape((ns, g, n)), chip.shape((c, h, p), F32),
         chip.shape((c, h), F32), chip.shape((c, g, n)),
         chip.shape((c, g, n))), chip.refusals.get(_ssm_state_minor_kernels)
+    assert _chunk_scan_holds_no_loop(
+        chip.texts[_ssm_state_minor_kernels], h, g)
 
 
 def _kda_layer_kernels(pool, tab, q, k, v, g, beta, qc, kc, vc, gc, bc):
