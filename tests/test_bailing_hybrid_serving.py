@@ -11,12 +11,9 @@ tables) — against the plain float32 reference's full forward
 planted faults, which have to show; idle slots' state bit-unchanged; the
 bytes the engine says it holds; what it refuses, by name; the step
 record's counts; the share test — eight chips' routed addends plus the
-shared expert ONCE add up to the uncut layer; and the unified step of
-the eight families that were there before, pinned to the text it
-lowered to at this PR's parent.
+shared expert ONCE add up to the uncut layer. (The step programs'
+pinned texts: `test_step_program_pins.py`.)
 """
-
-import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -33,10 +30,7 @@ from paddle_tpu.models.bailing_hybrid import (BailingHybridForCausalLM,
                                               bailing_hybrid_config,
                                               bailing_hybrid_tiny_config)
 from paddle_tpu.observability import tracing
-from test_evabyte_serving import LOWERED_AT_PARENT
-from test_nemotron_h import seeded as nemotron_seeded
-from test_nemotron_h_serving import (LOOPED_LOWERED_AT_PARENT, PAGE,
-                                     _engine, _prompts, _run)
+from test_nemotron_h_serving import PAGE, _engine, _prompts, _run
 
 # (PAGE 8, CHUNK 16: a prefill chunk is two sub-chunks of 8)
 CFG_KEYS = ("layers_held", "layer_group_size", "first_k_dense_replace",
@@ -383,49 +377,3 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     part = dict(tree, **{k: tree[k][4:8] for k in ("wge", "wup", "wdn")})
     got4 = _ffn_apply(dict(moe=part), a, dict(mix.static(), held=(4, 4)))
     np.testing.assert_allclose(got4[0], want4, atol=1e-5)
-
-
-# ------------------------------------------- the families before this
-#: sha256 of the Nemotron-H hybrid's `_jit_unified.lower(...).as_text()`
-#: at this PR's parent (a05aa9d), toy widths, on the CPU under the
-#: suite's matmul precision, beside the seven pins of the earlier test
-#: files (which still run). Ling came in THROUGH the hybrid body — the
-#: convolution's tails and the chunk's state became functions two update
-#: rules call — and through `_latent_mixer`, lifted out of the mla body:
-#: neither program's text moved. PR 48 (q / k / v weights stored
-#: [heads, D, in]) re-recorded it: its one attention block reads them so.
-#: PR 53 (the per-layer kernels through one jitted copy a step's layers
-#: share, `engine._once`: the norms, the state updates, the chunk's scan
-#: and put) re-recorded it and Ling's, PR 55 (the ragged kernel's softmax
-#: state lane-replicated: `test_evabyte_serving`) both with the seven.
-#: PR 57 (`ssm_chunk_scan` the heads batch-major, its scan chunks a Python
-#: loop and no `lax.scan`) re-recorded THIS one alone: Ling's chunk scan
-#: is `kda_chunk_scan`, the other eight programs hold no Mamba-2 block.
-HYBRID_LOWERED_AT_PARENT = \
-    "8189d8f0d7798726eac8d18b718860c3302d51c03f858aa993d1c236f4b12384"
-
-
-def test_the_nemotron_step_lowers_to_the_parents_text():
-    m, _, _ = nemotron_seeded()
-    text = _lower(_engine(m)).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() \
-        == HYBRID_LOWERED_AT_PARENT
-    # the seven pins are where they were: five, the chunk-summary
-    # family's and the looped decoder's
-    assert len(LOWERED_AT_PARENT) == 5 and LOOPED_LOWERED_AT_PARENT
-
-
-#: ... and of THIS family's step at PR 50's parent (f1e4629), which had
-#: no pin: the ninth. PR 50 made `_latent_mixer` return the mixer's
-#: output for the caller's residual to take — the ``L`` blocks add it,
-#: as they did. PR 51 (`_latent_mixer` appends its rows by the step's
-#: run table, which a pattern with ``L`` now makes too) re-recorded it;
-#: Nemotron's, whose pattern made that table already, did not move.
-LING_LOWERED_AT_PARENT = \
-    "414cd126ad8267e4138a365f59bbc3bf74b0f0e6042597e91e364c86adfb9030"
-
-
-def test_the_ling_step_lowers_to_the_parents_text(tiny):
-    text = _lower(_engine(tiny[0])).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() \
-        == LING_LOWERED_AT_PARENT

@@ -6,12 +6,9 @@ slot reused after a finish — against the plain float32 reference's full
 forward (`benchmarks/lib/reference_evabyte.py`) on seeded weights; the
 model's own forward (all byte heads) against the same reference; the
 allocator's two page lists; the pooling kernel against `jax.numpy`; the
-ragged kernel's summary mask against its oracle; and the unified step of
-the five families that were there before, pinned to the text it lowered
-to at this PR's parent.
+ragged kernel's summary mask against its oracle. (The step programs'
+pinned texts: `test_step_program_pins.py`, which builds its toy here.)
 """
-
-import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +28,7 @@ from paddle_tpu.ops.pallas_ragged import (ragged_attention_reference,
 from paddle_tpu.ops.references import chunk_pool_reference
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.block_allocator import ChunkSummaryAllocator
-from test_engine_programs import _lowered_toy, _spy_append_runs
+from test_engine_programs import _spy_append_runs
 
 
 # --------------------------------------------------------------- model
@@ -459,59 +456,14 @@ class TestSummaryMask:
                     atol=2e-5)
 
 
-# ------------------------------------------- the families before this
-#: sha256 of `_jit_unified.lower(...).as_text()` at this PR's parent
-#: (1d73225), toy widths, on the CPU under the suite's matmul precision:
-#: chunk-summary attention came in beside these programs, not through
-#: them. A PR that means to change one records the new text here.
-#: PR 36 (rope + append by cache-tile runs) re-recorded the four that
-#: call `fused_rope_append`, PR 42 (a page visit of the ragged kernel
-#: serves a block of KV heads; one jitted launch a step's layers share)
-#: the same four; `mla` (`fused_append_rows`, ONE KV head: a block of
-#: one without the axis) stayed PR 35's parent's text until PR 44 (a
-#: page visit serves a block of query TILES where it serves one KV head)
-#: sent its launch through the one jitted copy as well: unrolled over 8
-#: tiles, its body cost the chip's host 10 s of a warm first step to
-#: lower once a layer. The toy launch is one tile, so its kernel is the
-#: parent's; what changed is that the step calls it. PR 45 (a page visit
-#: of a sequence that owns a few rows of its tile computes the window of
-#: rows that holds them; the heads' chains in three passes) re-recorded
-#: all five: every toy launch is ONE tile a cell, taller than the window.
-#: PR 48 (the head-split projections' weights stored [heads, D, in] and
-#: contracted on their last axis, `generation._mm_heads`) re-recorded
-#: every family that has one: all but `gpt` (a fused `wqkv`, as it was).
-#: PR 51 (`fused_append_rows` by cache-tile runs: the row-a-grid-step
-#: kernel left the tree) re-recorded `mla`, the one that calls it; the
-#: four that call `fused_rope_append` did not move. PR 53 (the step is
-#: compiled at two row counts, so a program's first launch had to get
-#: cheaper: the per-layer kernels — norms, rope + append, the
-#: hyper-connections, the state updates — go through ONE jitted copy a
-#: step's layers share, `engine._once`, as the attention launch has
-#: since PR 42) re-recorded all five: the step CALLS the kernels it
-#: held inline; the looped decoder's, one jitted layer already, stayed.
-#: PR 55 (the ragged kernel's running maximum and sum held lane-replicated,
-#: [rows, 128] scratch for [rows, 1]: the kernel's text moved, its output
-#: did not, `test_the_output_is_the_parents_bit_for_bit`) re-recorded all
-#: nine pins: every family's step launches the kernel.
-LOWERED_AT_PARENT = {
-    "llama":
-        "d04b742de247dc594c6e05a8b0b29146a4a87aee2367c7ae2ea1be6387b4b117",
-    "moe":
-        "d7641834ce331be935b3e234fdded13754d4371a03efc7bb846282fb9eb40676",
-    "mla":
-        "4789c63ca885428be5bb8927624224428bc6112b8a2aa92597e926d64c82fe9b",
-    "gpt":
-        "cdbd25f7193cf1f43bb83d09919ba8b6d6a441d36229e25f1530f9f251dd2227",
-    "laguna":
-        "7c93ed69d80782546a97a396a03901c63abcbab0d3ccafcaef38b76340424431",
-}
-
-
-@pytest.mark.parametrize("family", sorted(LOWERED_AT_PARENT))
-def test_the_five_families_lower_to_the_parents_text(family):
-    text = _lowered_toy(family)[2].as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() \
-        == LOWERED_AT_PARENT[family]
+def _pin_engine():
+    """The toy engine whose step programs `test_step_program_pins` pins
+    (an unseeded toy: a program's text reads shapes, not values)."""
+    paddle.seed(0)
+    m = EvaByteForCausalLM(evabyte_tiny_config())
+    m.eval()
+    return ServingEngine(m, max_slots=2, page_size=8, max_context=64,
+                         prefill_chunk=8)
 
 
 def test_the_eva_step_takes_the_nine_inputs(tiny):

@@ -4,8 +4,8 @@ caches of every layer — pages for the rotary GQA mixer, a slot of the
 state-minor pool for the Mamba-2 mixer, both fed by the block's one norm
 — then decode steps through both, on the hybrid body's one step program
 at both row counts; a slot handed on starts from zero state and fresh
-pages; what cannot be served is refused; the ten earlier programs'
-pins hold.  Toy sizes as `test_falcon_h1.py`'s."""
+pages; what cannot be served is refused.  (The step programs' pinned
+texts: `test_step_program_pins.py`.)  Toy sizes as `test_falcon_h1.py`'s."""
 
 import jax
 import jax.numpy as jnp
@@ -18,12 +18,7 @@ from paddle_tpu.observability import tracing
 from paddle_tpu.ops.pallas_ssm import STATE_MINOR
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.engine import _gqa_mixer, _pattern_blocks
-from test_bailing_hybrid_serving import (HYBRID_LOWERED_AT_PARENT,
-                                         LING_LOWERED_AT_PARENT,
-                                         LOOPED_LOWERED_AT_PARENT,
-                                         LOWERED_AT_PARENT)
 from test_falcon_h1 import seeded
-from test_ouro_serving import EVA_LOWERED_AT_PARENT
 
 PAGE, CHUNK = 8, 16
 #: the engine's float32 logits against the reference's: the order of
@@ -258,20 +253,6 @@ def test_the_gqa_mixer_without_a_table_is_the_identity_turn(tiny):
                        runs, **geom)
     np.testing.assert_array_equal(y0, y1)
     assert float(jnp.abs(y0[0, 3:12]).max()) > 0
-
-
-# ------------------------------------------- the families before this
-def test_ten_programs_are_pinned_at_the_parent():
-    """The pins of the earlier test files are where they were — five,
-    the chunk-summary family's, the looped decoder's, the Nemotron
-    hybrid's, Ling's (all lowered again by their own files, which still
-    run) — and Xing's ride the five's mla text. This PR wrote the hybrid
-    body over BLOCKS (`_pattern_blocks`) and took the GQA mixer out of
-    it (`_gqa_mixer`): Nemotron's and Ling's text did not move."""
-    pins = list(LOWERED_AT_PARENT.values()) + [
-        EVA_LOWERED_AT_PARENT, LOOPED_LOWERED_AT_PARENT,
-        HYBRID_LOWERED_AT_PARENT, LING_LOWERED_AT_PARENT]
-    assert len(set(pins)) == 9 and all(len(p) == 64 for p in pins)
 
 
 def test_the_step_lowers_with_every_scope(tiny):

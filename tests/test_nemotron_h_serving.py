@@ -7,27 +7,20 @@ finished request to a new one (which starts from ZERO state by a flag in
 the row tables) — against the plain float32 reference's full forward
 (`benchmarks/lib/reference_nemotron.py`) on seeded weights, in logits;
 idle slots' state bit-unchanged; the bytes the engine says it holds; what
-it refuses; the step record's counts; and the unified step of the seven
-families that were there before, pinned to the text it lowered to at this
-PR's parent.
+it refuses; the step record's counts. (The step programs' pinned
+texts: `test_step_program_pins.py`.)
 """
-
-import hashlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from benchmarks.lib import reference_nemotron as ref
 from paddle_tpu.generation import _cached_step_body, _decode_params
 from paddle_tpu.observability import tracing
 from paddle_tpu.serving import ServingEngine
-from test_engine_programs import _lower_unified
-from test_evabyte_serving import LOWERED_AT_PARENT
 from test_nemotron_h import seeded
-from test_ouro_serving import EVA_LOWERED_AT_PARENT, _lower_eva
 
 PAGE, CHUNK = 8, 16         # a prefill chunk is two scan chunks of 8
 
@@ -243,42 +236,6 @@ def test_an_untileable_shape_is_refused(tiny, monkeypatch):
                         lambda *a: False)
     with pytest.raises(ValueError, match="unified ragged step only"):
         _engine(m)
-
-
-# ------------------------------------------- the families before this
-#: sha256 of `_jit_unified.lower(...).as_text()` at this PR's parent
-#: (7864f15), toy widths, on the CPU under the suite's matmul precision:
-#: the looped decoder, beside the five of `test_evabyte_serving` and the
-#: chunk-summary family of `test_ouro_serving` (unchanged there). The
-#: hybrid came in beside these programs, not through them: `_route`'s
-#: `bias`, the experts' `relu2` and `_ffn_apply`'s latent projections
-#: add no operation where they are not asked for. PR 45 (a decode row's
-#: page visit of the ragged kernel computes the few rows the row owns)
-#: re-recorded it with the six others, and PR 48 (q / k / v weights
-#: stored [heads, D, in]) with all of them but `gpt`, PR 55 (the ragged
-#: kernel's softmax state lane-replicated) with all nine.
-LOOPED_LOWERED_AT_PARENT = \
-    "c78ea15ea6da800562dbe0f38d5247585e8bc0d212966c83174475fcc8ef8f42"
-
-
-def _lower_looped():
-    from paddle_tpu.models.ouro import OuroForCausalLM, ouro_tiny_config
-    paddle.seed(0)
-    m = OuroForCausalLM(ouro_tiny_config(max_position_embeddings=64,
-                                         rope_positions=64))
-    m.eval()
-    return _lower_unified(ServingEngine(
-        m, max_slots=2, page_size=8, max_context=64, prefill_chunk=8))
-
-
-def test_the_seven_families_lower_to_the_parents_text():
-    """Five: `test_evabyte_serving`'s pins, which still run; the sixth
-    and the seventh here."""
-    assert len(LOWERED_AT_PARENT) == 5
-    assert hashlib.sha256(_lower_eva().as_text().encode()).hexdigest() \
-        == EVA_LOWERED_AT_PARENT
-    assert hashlib.sha256(_lower_looped().as_text().encode()).hexdigest() \
-        == LOOPED_LOWERED_AT_PARENT
 
 
 def test_the_hybrid_step_takes_the_nine_inputs(tiny):

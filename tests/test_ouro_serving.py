@@ -7,14 +7,10 @@ plain float32 reference's full forward
 (`benchmarks/lib/reference_ouro.py`) on seeded weights, in logits; the
 planted fault of passes that share one cache slot, which has to fail
 that comparison; the bytes the engine says it holds; what it refuses;
-the step record's counts; and the unified step of the six families that
-were there before, pinned to the text it lowered to at this PR's
-parent.
+the step record's counts. (The step programs' pinned texts:
+`test_step_program_pins.py`, which builds its looped toy here.)
 """
 
-import hashlib
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -26,7 +22,6 @@ from paddle_tpu.generation import _cached_step_body, _decode_params
 from paddle_tpu.observability import tracing
 from paddle_tpu.serving import ServingEngine
 from test_engine_programs import _lower_unified
-from test_evabyte_serving import LOWERED_AT_PARENT
 from test_ouro import seeded
 
 PAGE, CHUNK = 8, 8
@@ -269,46 +264,12 @@ def test_an_untileable_shape_is_refused(tiny, monkeypatch):
         _engine(m)
 
 
-# ------------------------------------------- the families before this
-#: sha256 of `_jit_unified.lower(...).as_text()` at this PR's parent
-#: (4851f97), toy widths, on the CPU under the suite's matmul precision:
-#: chunk-summary attention, beside the five of `test_evabyte_serving`
-#: (unchanged there). The looped decoder came in beside these programs,
-#: not through them. PR 42 (a page visit of the ragged kernel serves a
-#: block of KV heads) re-recorded it with four of the five, PR 45 (a
-#: decode row's page visit computes the few rows the row owns) with all
-#: five, PR 48 (q / k / v weights stored [heads, D, in]) with four of them.
-#: PR 51 (the pooled K and V rows appended by cache-tile runs in ONE call,
-#: their run table made in the step) re-recorded it with `mla` and Ling's.
-#: PR 53 (the per-layer kernels through one jitted copy a step's layers
-#: share, `engine._once`) re-recorded it with the five, Nemotron's and Ling's,
-#: PR 55 (the ragged kernel's softmax state lane-replicated) with all nine.
-EVA_LOWERED_AT_PARENT = \
-    "375c700ab6799f1dda5c6653d05e9cb38a6547370ccfa713b8144e1e41899947"
-
-
-def _lower_eva():
-    from paddle_tpu.models.evabyte import (EvaByteForCausalLM,
-                                           evabyte_tiny_config)
+def _pin_engine():
+    """The toy engine whose step programs `test_step_program_pins` pins."""
+    from paddle_tpu.models.ouro import OuroForCausalLM, ouro_tiny_config
     paddle.seed(0)
-    m = EvaByteForCausalLM(evabyte_tiny_config())
+    m = OuroForCausalLM(ouro_tiny_config(max_position_embeddings=64,
+                                         rope_positions=64))
     m.eval()
-    eng = ServingEngine(m, max_slots=2, page_size=8, max_context=64,
-                        prefill_chunk=8)
-    B, C = eng.max_slots, eng.prefill_chunk
-    P = B + C // 4
-
-    def i32(*d):
-        return jax.ShapeDtypeStruct(d, jnp.int32)
-
-    return eng._jit_unified.lower(
-        eng._w, i32(B + C), eng._pools, i32(B + C), i32(B + 1),
-        (i32(B + 1), i32(B + 1)), i32(B + 1, eng.pages_per_seq),
-        (i32(B + C), i32(2, P)), (i32(B + C), i32(2, P)))
-
-
-def test_the_sixth_family_lowers_to_the_parents_text():
-    """The five others: `test_evabyte_serving`'s pins, which still run."""
-    assert len(LOWERED_AT_PARENT) == 5
-    assert hashlib.sha256(_lower_eva().as_text().encode()).hexdigest() \
-        == EVA_LOWERED_AT_PARENT
+    return ServingEngine(m, max_slots=2, page_size=8, max_context=64,
+                         prefill_chunk=8)

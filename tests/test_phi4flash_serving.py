@@ -5,8 +5,8 @@ steps through all three, on the hybrid body's one step program at both
 row counts; fourteen — here four — of its blocks own NO memory and read
 another block's pages or scan output inside the launch; a context past
 the window with released pages; a slot handed on starts from zero state
-and fresh pages of both kinds; what cannot be served is refused; the
-earlier programs' pins hold.  Toy sizes as `test_phi4flash.py`'s; ONE
+and fresh pages of both kinds; what cannot be served is refused.  (The
+step programs' pinned texts: `test_step_program_pins.py`.)  Toy sizes as `test_phi4flash.py`'s; ONE
 engine a module (two slots), compiled once."""
 
 import jax
@@ -19,11 +19,6 @@ from paddle_tpu.generation import _cached_step_body, _decode_params
 from paddle_tpu.observability import tracing
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.engine import _pattern_blocks
-from test_bailing_hybrid_serving import (HYBRID_LOWERED_AT_PARENT,
-                                         LING_LOWERED_AT_PARENT,
-                                         LOOPED_LOWERED_AT_PARENT,
-                                         LOWERED_AT_PARENT)
-from test_ouro_serving import EVA_LOWERED_AT_PARENT
 from test_phi4flash import seeded
 
 PAGE, CHUNK, WINDOW = 8, 16, 12
@@ -209,18 +204,6 @@ def test_a_pattern_names_blocks_that_borrow(pattern, want):
 def test_a_borrower_of_nothing_is_refused(pattern):
     with pytest.raises(ValueError):
         _pattern_blocks(pattern)
-
-
-def test_the_earlier_programs_are_pinned_at_the_parent():
-    """The pins of the earlier test files are where they were (their own
-    files lower them again, and still run). This PR gave the hybrid body
-    blocks that own no memory, the window kind and LayerNorm, and
-    `_gqa_mixer` a window, a pair layout and biases: no earlier text
-    moved."""
-    pins = list(LOWERED_AT_PARENT.values()) + [
-        EVA_LOWERED_AT_PARENT, LOOPED_LOWERED_AT_PARENT,
-        HYBRID_LOWERED_AT_PARENT, LING_LOWERED_AT_PARENT]
-    assert len(set(pins)) == 9 and all(len(p) == 64 for p in pins)
 
 
 def test_the_step_lowers_with_every_scope(tiny, eng):

@@ -1,0 +1,144 @@
+"""Every family's step program, pinned to the text it lowers to.
+
+ONE place for what guards "another family's program did not move": the
+sha256 of `eng._programs[name].lower(...).as_text()` (no debug info) for
+twelve toy families times the two row counts the step body is compiled
+at (`unified`: the decode rows and a prefill chunk; `unified_nochunk`:
+the decode rows alone), at toy widths, on the CPU under the suite's
+matmul precision. The toy models and engines are the family files' own
+and are imported from there; nothing here steps an engine.
+
+A pin moves when the TEXT moves, which is more often than the program:
+a PR that changes a kernel every step launches re-records every pin
+(PR 42, 45, 55: the ragged kernel; PR 48: the stored weight layout;
+PR 53: the per-layer kernels through `engine._once`; PR 57: Nemotron's
+alone, the Mamba-2 chunk scan). A PR whose claim is that the programs
+did NOT change (PR 58: six step bodies became three) re-records none,
+or shows on the chip why a text moved and that the compiled program did
+not (ISSUE 58 says how). The nine `unified` hashes of llama, moe, mla,
+gpt, laguna, eva, looped, nemotron and ling are the ones PR 55 / PR 57
+recorded in the family files; the other fifteen were recorded at PR 58's
+parent (404ac2e), before `engine.py` was touched.
+"""
+
+import functools
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import test_bailing_hybrid_serving as ling
+import test_evabyte_serving as eva
+import test_falcon_h1_serving as falcon_h1
+import test_nemotron_h_serving as nemotron
+import test_ouro_serving as looped
+import test_phi4flash_serving as phi4flash
+import test_xing_serving as xing
+from test_engine_programs import _lowered_toy
+
+#: family -> its toy engine at the pin's sizes
+ENGINES = {
+    **{f: functools.partial(lambda f: _lowered_toy(f)[1], f)
+       for f in ("llama", "moe", "mla", "gpt", "laguna")},
+    "eva": eva._pin_engine,
+    "looped": looped._pin_engine,
+    "nemotron": lambda: nemotron._engine(nemotron.seeded()[0]),
+    "ling": lambda: ling._engine(ling.seeded()[0]),
+    "xing": lambda: xing._engine(xing.seeded(experts_held=(4, 4))[0]),
+    "falcon_h1": lambda: falcon_h1._engine(falcon_h1.seeded()[0]),
+    "phi4flash": lambda: phi4flash._engine(phi4flash.seeded()[0]),
+}
+
+PINS = {
+    ("llama", "unified"):
+        "d04b742de247dc594c6e05a8b0b29146a4a87aee2367c7ae2ea1be6387b4b117",
+    ("moe", "unified"):
+        "d7641834ce331be935b3e234fdded13754d4371a03efc7bb846282fb9eb40676",
+    ("mla", "unified"):
+        "4789c63ca885428be5bb8927624224428bc6112b8a2aa92597e926d64c82fe9b",
+    ("gpt", "unified"):
+        "cdbd25f7193cf1f43bb83d09919ba8b6d6a441d36229e25f1530f9f251dd2227",
+    ("laguna", "unified"):
+        "7c93ed69d80782546a97a396a03901c63abcbab0d3ccafcaef38b76340424431",
+    ("eva", "unified"):
+        "375c700ab6799f1dda5c6653d05e9cb38a6547370ccfa713b8144e1e41899947",
+    ("looped", "unified"):
+        "c78ea15ea6da800562dbe0f38d5247585e8bc0d212966c83174475fcc8ef8f42",
+    ("nemotron", "unified"):
+        "8189d8f0d7798726eac8d18b718860c3302d51c03f858aa993d1c236f4b12384",
+    ("ling", "unified"):
+        "414cd126ad8267e4138a365f59bbc3bf74b0f0e6042597e91e364c86adfb9030",
+    # recorded at PR 58's parent (404ac2e), the engine untouched
+    ("xing", "unified"):
+        "65e1b60f81b73612ceceebcd7e535dd5c13df24a07917040643f90456cf252b8",
+    ("falcon_h1", "unified"):
+        "4fb593c95e43281d056eebc23300a9a522b7c632dc73ebea3351cb39c9784e0f",
+    ("phi4flash", "unified"):
+        "3ae4fc7188e622265e5273964f94a67a37050d22ac3068600f5da493d3310e48",
+    ("llama", "unified_nochunk"):
+        "d2f6454edab36b3b27766ef733c2fc04ecc7515c852225b39bc6544d1f8a69ff",
+    ("moe", "unified_nochunk"):
+        "ea2b03020a75e96ece680d296351e32480e533da85757c93d761cd21e3ceeaab",
+    ("mla", "unified_nochunk"):
+        "c6ab7866cf55963610d82449bcc7786b14121e278b094dc11574172992501e5b",
+    ("gpt", "unified_nochunk"):
+        "f9f2a44b0d33939bfa93c2e130a1c44dd513d8b6b6c3a1fd185f041e0fdd18f2",
+    ("laguna", "unified_nochunk"):
+        "21e1a3977f190282d5fa90e5cbaeb2041d36d1208968f305e9d9c0fbe69ed2df",
+    ("eva", "unified_nochunk"):
+        "f82521a1f0cb9211be1857d08c3a93467809878966f0b748fc0557337311c680",
+    ("looped", "unified_nochunk"):
+        "e91d8482b746b2bb229f55f2b1c3168dbb48c219c13bdc559f5fed5edbe9396a",
+    ("nemotron", "unified_nochunk"):
+        "d8d0cdd1eec522a09e2e2bed46fad0da443adcea2b86fe9d3f898e96a9cd3313",
+    ("ling", "unified_nochunk"):
+        "b9a588c6a3826e1e1994dd1e06a968f7e9e4432db9c1454b43b4f06b17d9e0fe",
+    ("xing", "unified_nochunk"):
+        "551f3ee4a73a5a6bf0f3ffe1fd0cf6d21b401adcfa694b726aa4e6fcdff187d7",
+    ("falcon_h1", "unified_nochunk"):
+        "6f13b59af144032a3a3add14309070c849b32d2ecf3085de29dd28147073a78a",
+    ("phi4flash", "unified_nochunk"):
+        "88832a20c2a221c8e5021f778738a2abdf1d0bed621969964f4010838742bdbe",
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _engine(family):
+    return ENGINES[family]()
+
+
+def lower_step(eng, program):
+    """The step program `program` of `eng` lowered from shapes, with the
+    nine positional inputs `benchmarks/` passes it: (w, tok, pools,
+    positions, num_tokens, kv_lengths, tables, tok_page, tok_off). What
+    is a pair for whom: a table and a page column a layer KIND where the
+    model has window layers; the summary rows, pooling pages and offsets
+    of chunk-summary attention; the state table of a hybrid."""
+    B = eng.max_slots
+    C = eng._chunk_parts()[program[len("unified"):]]
+    T = eng._launch_rows(C)
+
+    def i32(*d):
+        return jax.ShapeDtypeStruct(d, jnp.int32)
+
+    lens, table, page, off = i32(B + 1), i32(B + 1, eng.pages_per_seq), \
+        i32(T), i32(T)
+    if eng.num_window_pages:
+        table, page = (table, table), (page, page)
+    if eng._eva:
+        pooled = i32(2, B + C // eng._p["cfg"].chunk_size)
+        lens, page, off = (lens, lens), (page, pooled), (off, pooled)
+    if eng._ssm_layers:
+        lens = (lens, i32(B + 3))
+    return eng._programs[program].lower(
+        eng._w, i32(T), eng._pools, i32(T), i32(B + 1), lens, table, page,
+        off)
+
+
+@pytest.mark.parametrize("program", ["unified", "unified_nochunk"])
+@pytest.mark.parametrize("family", sorted(ENGINES))
+def test_the_step_lowers_to_the_pinned_text(family, program):
+    text = lower_step(_engine(family), program).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == PINS[family, program]

@@ -3,9 +3,8 @@
 through the pages, the four-stream residual mixed by the two
 `ops.pallas_mhc` kernels (interpreted here) around every sublayer of the
 ONE mla step body — whose residual is a seam (`engine._Residual`), the
-plain add its default.  The eight pinned programs of the earlier
-families lower to the parent's text, and Ling's step, which had no pin,
-is pinned here as the ninth.  Toy sizes as `test_xing.py`'s: the tier-1
+plain add its default.  (The step programs' pinned texts:
+`test_step_program_pins.py`.)  Toy sizes as `test_xing.py`'s: the tier-1
 run has seconds to spare."""
 
 import jax
@@ -18,11 +17,6 @@ from paddle_tpu.generation import _cached_step_body, _decode_params
 from paddle_tpu.observability import tracing
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving import engine as eng_mod
-from test_bailing_hybrid_serving import (HYBRID_LOWERED_AT_PARENT,
-                                         LING_LOWERED_AT_PARENT,
-                                         LOOPED_LOWERED_AT_PARENT,
-                                         LOWERED_AT_PARENT)
-from test_ouro_serving import EVA_LOWERED_AT_PARENT
 from test_xing import seeded
 
 PAGE, CHUNK = 8, 16
@@ -159,19 +153,3 @@ def test_the_plain_residual_adds_nothing():
     assert res.enter(x) is x and res.exit(x) is x
     assert res.feed(x) == (x, None)
     assert bool((res.leave(x, y) == x + y).all())
-
-
-# ------------------------------------------- the families before this
-def test_nine_programs_are_pinned_at_the_parent():
-    """The eight pins of the earlier test files are where they were —
-    five, the chunk-summary family's, the looped decoder's, the Nemotron
-    hybrid's — and Ling's step, which had none, is the ninth
-    (`test_bailing_hybrid_serving.py`, beside its own toy engine: the
-    tier-1 run has seconds to spare). This PR made `_latent_mixer`
-    return the mixer's output for the caller's residual to take (the
-    hybrid body's ``L`` blocks add it, as they did) and wrote the mla
-    body on `_Residual`: no program's text moved."""
-    pins = list(LOWERED_AT_PARENT.values()) + [
-        EVA_LOWERED_AT_PARENT, LOOPED_LOWERED_AT_PARENT,
-        HYBRID_LOWERED_AT_PARENT, LING_LOWERED_AT_PARENT]
-    assert len(set(pins)) == 9 and all(len(p) == 64 for p in pins)
