@@ -49,7 +49,8 @@ layer that is built is refused: the clamp's form is not in the config.
 The prediction block (``num_nextn_predict_layers``) feeds no logit of
 the main pass and is not built.  This is the whole-sequence forward
 from zero state; the serving engine keeps a slot of state a sequence
-(`serving.engine.ServingEngine._hybrid_unified_body`).
+(`serving.engine.ServingEngine._chain_unified_body`, whose blocks
+`serving.engine._chain_of` reads off the pattern).
 """
 
 from __future__ import annotations

@@ -37,7 +37,8 @@ weight.  What the config leaves open is listed as ``assumed`` in
 ``benchmarks/configs/falcon-h1-34b-serve-pp8-d9.json``.  This is the
 whole-sequence forward from zero state; the serving engine keeps a slot
 of state and pages a sequence (`serving.engine.ServingEngine.
-_hybrid_unified_body`, pattern ``[M*]D`` a layer).
+_chain_unified_body`, whose blocks `serving.engine._chain_of` reads off
+the pattern, ``[M*]D`` a layer).
 """
 
 from __future__ import annotations

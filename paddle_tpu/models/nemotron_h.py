@@ -39,7 +39,8 @@ published width), ``vocab_size`` is the rows of the vocabulary held
 here.  The prediction block (``num_nextn_predict_layers``) feeds no
 logit of the main pass and is not built.  This is the whole-sequence
 forward from zero state; the serving engine keeps a slot of state a
-sequence (`serving.engine.ServingEngine._hybrid_unified_body`).
+sequence (`serving.engine.ServingEngine._chain_unified_body`, whose
+blocks `serving.engine._chain_of` reads off the pattern).
 """
 
 from __future__ import annotations

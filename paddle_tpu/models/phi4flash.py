@@ -46,8 +46,8 @@ n / 2``:
 This is the whole-sequence forward from zero state, one dispatched op,
 for inference (the scan kernel has no gradient).  The serving engine
 keeps a state slot, window pages and ONE full pool a sequence
-(`serving.engine.ServingEngine._hybrid_unified_body`;
-`Phi4FlashConfig.pattern`).
+(`serving.engine.ServingEngine._chain_unified_body`, whose blocks
+`serving.engine._chain_of` reads off `Phi4FlashConfig.pattern`).
 """
 
 from __future__ import annotations

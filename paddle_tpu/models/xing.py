@@ -41,7 +41,8 @@ share of an expert-parallel deployment is an argument, as in
 rows held.  The prediction block (``num_nextn_predict_layers``) feeds
 no logit of the main pass and is not built.  The serving engine runs
 the same mixing through `ops.pallas_mhc`
-(`serving.engine.ServingEngine._mla_unified_body`).
+(`serving.engine.ServingEngine._chain_unified_body`: the latent
+family's blocks under `_HyperResidual`).
 """
 
 from __future__ import annotations

@@ -8,7 +8,7 @@ leave the instant they hit EOS/max-tokens (their pages return to the
 pool immediately), and never retrace — one compile per (model-config,
 slot-count) pair, checked by the PT002-gated tests.
 
-The engine has ONE step body, the unified ragged step: ONE launch
+The engine has ONE step program, the unified ragged step: ONE launch
 per step. Every decode slot's token and the oldest prefill request's
 chunk ride a single flat token buffer through ONE per-layer chain, the
 same at every width and on every backend: norm -> q / k / v
@@ -16,7 +16,12 @@ projections -> `fused_rope_append` (MLA: `fused_append_rows`) ->
 `ragged_paged_attention` -> o-proj -> norm -> `_ffn_apply`
 (`serving.engine.launches` counts the launches). Per-sequence row
 tables (seq_start / num_tokens / kv_lengths / page table) make joins
-and leaves pure data changes. Every family takes it on a TPU at
+and leaves pure data changes. The step is built from ONE description a
+family (`_chain_of` -> `_Chain`: the blocks in order — a norm feeding
+its mixers, or an FFN — the residual, the rotary rows, the family's pool
+pytree) by ONE builder, `ServingEngine._chain_unified_body`; chunk-summary
+attention and the looped decoder have builders of their own beside it
+(`_make_unified_body`). Every family takes it on a TPU at
 published widths: llama / MoE / Laguna / GPT heads of 64 or a multiple
 of 128, and latent attention (MLA) whose latent rank is a multiple of
 128 — its cache row (latent | rope key) is stored padded to whole
@@ -61,7 +66,9 @@ greedy property; sampling strategies belong to the batch APIs.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Optional, Tuple
+from contextlib import nullcontext
+from typing import (Callable, Dict, List, NamedTuple, Optional,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -340,8 +347,8 @@ def _latent_mixer(L, h, rope, pool, seq_start, num_tokens, kv_lengths,
     before the out-projection. The prefill chunk rides the same form as
     the decode rows; `runs` is the step's one append work list
     (`ServingEngine._run_table`). -> (the mixer's output y [1, T, H],
-    the pool): the caller's residual takes y (`_Residual.leave`). Shared by
-    `_mla_unified_body` and the hybrid body's ``L`` blocks."""
+    the pool): the caller's residual takes y (`_Residual.leave`). The chain's
+    ``L`` mixer: the mla family's layers and a hybrid's ``L`` blocks."""
     T = h.shape[1]
     wkb = _kvb_heads(L, nh, h.dtype)
     w_k, w_v = wkb[:, :dn], wkb[:, dn:]
@@ -391,11 +398,16 @@ def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
                shared: bool = False, eps: float = 1e-5):
     """Grouped-query attention on the normed rows h [1, T, H] of a
     block's input, over the pages `pools` = (K, V): q / k / v (+ their
-    biases, where the layer has them) -> `fused_rope_append` (rotary on
+    biases, where the layer has them; ONE fused ``wqkv`` + ``bqkv``
+    split in three where the checkpoint ships that: gpt) ->
+    `fused_rope_append` (rotary on
     all `d` dims, half-split pairs, by the rows' angles `rope` = (cos,
     sin) [T, d / 2]; with ``None``, or `_no_turn`'s pair, the model has
     NO rotary embedding and the kernel appends under the identity turn)
-    -> `ragged_paged_attention` -> o-proj. `mults` (Falcon-H1): the
+    -> `ragged_paged_attention` -> where the layer has a head gate
+    (``wgate``, Laguna) each head's output times one sigmoid scalar of
+    the normed input -> o-proj through `_mm_w` (the weight-only int8 /
+    int4 layouts) + its bias. `mults` (Falcon-H1): the
     input's, the key's and the output's static multipliers. `window`:
     the layer's sliding window (its `tables` and `runs` are then the
     window kind's). -> (the mixer's output y [1, T, H], the pools).
@@ -416,9 +428,9 @@ def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
     ``shared_attention``, one over a pool only its block reads
     ``attention``.
 
-    The hybrid body's ``*`` mixer, alone in its block (Nemotron-H,
-    Phi-4-flash) or beside a state-space mixer on the same norm
-    (Falcon-H1), and its ``X`` mixer."""
+    The chain's ``*`` mixer — a llama / MoE / Laguna / gpt layer's,
+    alone in its block (Nemotron-H, Phi-4-flash) or beside a state-space
+    mixer on the same norm (Falcon-H1) — and its ``X`` mixer."""
     T = h.shape[1]
     kp, vp = pools
     cos, sin = rope or _no_turn(T, d, h.dtype)
@@ -426,8 +438,11 @@ def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
     with _scope("qkv_proj"):
         if mults:
             h = h * mults["attention_in"]
-        q, k, v = (_mm_heads(h, L, w) if w in L else None
-                   for w in ("wq", "wk", "wv"))
+        if "wqkv" in L:
+            q, k, v = jnp.split(h @ L["wqkv"] + L["bqkv"], 3, axis=-1)
+        else:
+            q, k, v = (None if borrowed and w != "wq"
+                       else _mm_heads(h, L, w) for w in ("wq", "wk", "wv"))
         if mults:
             k = k * mults["key"]
         if "bq" in L:
@@ -452,7 +467,10 @@ def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
         with jax.named_scope("diff_combine"):
             o = diff_combine(o, L, diff, eps).astype(h.dtype)
     with _scope("attn_out"):
-        y = o.reshape(1, T, heads * dq) @ L["wo"]
+        if "wgate" in L:
+            g = jax.nn.sigmoid(_mm_w(h, L, "wgate"))[0]
+            o = o * g[..., None].astype(o.dtype)
+        y = _mm_w(o.reshape(1, T, heads * dq), L, "wo")
         if "bo" in L:
             y = y + L["bo"]
         if mults:
@@ -460,10 +478,12 @@ def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
     return y, (kp, vp)
 
 
-def _no_turn(T: int, d: int, dtype):
+def _no_turn(T: int, d: int, dtype, zeros: bool = False):
     """`_gqa_mixer`'s `rope` for T rows of a model WITHOUT a rotary
-    embedding: cos 1 (and sin None, zeros made at the call)."""
-    return jnp.ones((T, d // 2), dtype), None
+    embedding: cos 1, and sin None (zeros made at the call: Nemotron-H's
+    and Phi-4-flash's text) or, with `zeros`, made here (gpt's)."""
+    return (jnp.ones((T, d // 2), dtype),
+            jnp.zeros((T, d // 2), dtype) if zeros else None)
 
 
 def _pattern_blocks(pattern: str) -> Tuple[str, ...]:
@@ -497,6 +517,129 @@ def _pattern_blocks(pattern: str) -> Tuple[str, ...]:
                 f"several mixers (MK*L) in brackets, or G<j> / X<j> with j "
                 f"an earlier S / * block")
     return tuple(blocks)
+
+
+class _Block(NamedTuple):
+    """One block of the chain: ONE norm feeding its mixers, whose outputs
+    the residual takes one after the other, or ONE FFN."""
+
+    kind: str                       # its letters (`_pattern_blocks`);
+    #                                 ``B``: gpt's biased GELU FFN
+    layer: int                      # it reads w["layers"][layer] ...
+    norm: Tuple[str, ...]           # ... its norm under these keys: a gain
+    #                                 (RMSNorm), or a weight and a bias
+    #                                 (LayerNorm) ...
+    hc: Optional[str] = None        # ... and its hyper-connection's weights
+    #                                 under this one (`_HyperResidual`)
+    attn: Optional[dict] = None     # its attention mixer's static keywords
+    #                                 (`_gqa_mixer`, `_latent_mixer`)
+    pages: int = 0                  # the kind of cache that mixer reads:
+    #                                 0 keeps every page, 1 is the window's
+    rope: str = ""                  # ... and its rope table's key suffix
+    st: Optional[dict] = None       # its FFN's static (`_ffn_apply`)
+
+
+class _Chain(NamedTuple):
+    """What the ONE step builder (`ServingEngine._chain_unified_body`)
+    reads of a family: made once, by `_chain_of`, from the decode
+    parameters. The last four fields hold where the families' program
+    TEXTS differed when their bodies were four (PR 58) and add nothing
+    else."""
+
+    blocks: Tuple[_Block, ...]
+    eps: float
+    head_norm: Tuple[str, ...]      # the last norm's keys in w, as a block's
+    hyper: bool                     # the residual: `_HyperResidual` or plain
+    mults: Optional[dict]           # static multipliers (Falcon-H1)
+    #: the rotary rows the mixers get: one (cos, sin) a key suffix of
+    #: the model's tables, or — a model without a rotary embedding —
+    #: `_no_turn`'s (d, zeros) for the identity turn
+    rope_tables: Tuple[str, ...]
+    no_turn: Optional[Tuple[int, bool]]
+    #: (pools, kv_lengths) -> (page pools, state pools, kv_lengths, the
+    #: state table), and (page pools, state pools) -> the family's pytree
+    split: Callable
+    join: Callable
+    moe_counts: bool                # the routed layers' counts are taken
+    ends_scoped: bool               # the rotary rows are made inside
+    #                                 `embed`, the counts inside `head`
+    head_once: bool                 # the last norm goes through `_once`
+
+
+def _chain_of(p, attn_static, layer_kind, pool_readers, kv_geom,
+              moe_counts: bool) -> _Chain:
+    """The chain of the decode parameters `p` (family llama / moe /
+    laguna / gpt / mla / hybrid). A llama, gpt or mla LAYER is two
+    blocks over one dict of weights, under that dict's own key names; a
+    hybrid's pattern names its blocks, a dict each. `attn_static`,
+    `layer_kind`, `pool_readers` have an entry a page-holding block, in
+    order; `kv_geom` is the pool's (KV heads, width: a pair of heads
+    where they are differential, the padded latent row)."""
+    cfg, family, mu = p["cfg"], p["family"], p.get("mults")
+    hybrid, gpt = family == "hybrid", family == "gpt"
+    eps = cfg.layer_norm_epsilon if hybrid else cfg.layer_norm_eps \
+        if gpt else cfg.rms_norm_eps
+    if hybrid:
+        sts = iter(p["moe_static"])
+        ffn = {k: mu[k] for k in ("mlp_gate", "mlp_down")} if mu else None
+        blocks = [_Block(k, i, ("norm", "norm_b") if "norm_b" in L
+                         else ("norm",), st=next(sts) if k == "E" else ffn)
+                  for i, (k, L) in enumerate(zip(
+                      _pattern_blocks(p["pattern"]), p["layers"]))]
+    else:
+        ln1, ln2 = (("ln1w", "ln1b"), ("ln2w", "ln2b")) if gpt \
+            else (("ln1",), ("ln2",))
+        sts = p.get("moe_static") or (None,) * len(p["layers"])
+        blocks = [b for i, st in enumerate(sts) for b in (
+            _Block("L" if family == "mla" else "*", i, ln1, "hc1"),
+            _Block("B" if gpt else "E" if st else "D", i, ln2, "hc2",
+                   st=st))]
+    # each attention mixer's static keywords
+    gqa = dict(kv=kv_geom[0], d=kv_geom[1], mults=mu, eps=eps)
+    diff = p.get("diff", {})
+    owned = iter(zip(layer_kind, attn_static, pool_readers))
+    for i, b in enumerate(blocks):
+        if b.kind[0] == "X":    # attention over another block's pages
+            blocks[i] = b._replace(attn=dict(
+                gqa, heads=cfg.num_attention_heads, diff=diff.get(i),
+                borrowed=True))
+        elif "L" in b.kind:
+            next(owned)
+            blocks[i] = b._replace(attn=dict(
+                nh=cfg.num_attention_heads, dn=cfg.qk_nope_head_dim,
+                dr=cfg.qk_rope_head_dim, dv=cfg.v_head_dim,
+                r=cfg.kv_lora_rank, width=kv_geom[1], eps=eps,
+                scale=cfg.softmax_scale))   # yarn's mscale^2 included
+        elif "*" in b.kind:
+            k, st, readers = next(owned)
+            blocks[i] = b._replace(
+                pages=k, rope=st["rope"], attn=dict(
+                    gqa, heads=st["heads"], window=st["window"],
+                    diff=diff.get(i), shared=readers > 1))
+    tables = tuple(sfx for sfx in sorted({st["rope"] for st in attn_static})
+                   if "cos" + sfx in p)
+    gqa_blocks = any("*" in b.kind for b in blocks)
+    return _Chain(
+        tuple(blocks), eps,
+        head_norm=("normw", "normb") if gpt else ("norm", "norm_b")
+        if "norm_b" in p else ("norm",),
+        hyper=family == "mla" and cfg.hc_mult > 1, mults=mu,
+        rope_tables=tables,
+        no_turn=(kv_geom[1], gpt) if gqa_blocks and not tables else None,
+        split=(lambda pools, kvl: (pools["kv"], pools["ssm"], *kvl))
+        if hybrid else (lambda pools, kvl: (pools, (), kvl, None)),
+        join=(lambda kv, ssm: {"kv": kv, "ssm": ssm}) if hybrid
+        else (lambda kv, ssm: kv),
+        moe_counts=hybrid or moe_counts,
+        ends_scoped=not (hybrid or gpt),
+        head_once=family not in ("hybrid", "gpt", "mla"))
+
+
+def _gelu_ffn(L, h2):
+    """gpt's FFN: two biased matrices around a tanh GELU."""
+    with _scope("ffn"):
+        return jax.nn.gelu(h2 @ L["wi"] + L["bi"],
+                           approximate=True) @ L["wf"] + L["bf"]
 
 
 # -- the residual: how a step body enters, feeds and leaves it ---------
@@ -617,7 +760,8 @@ _ADDITIVE = frozenset(
 
 
 class ServingEngine:
-    """Continuous-batching engine for llama/moe, gpt and mla families.
+    """Continuous-batching engine for the llama / MoE / Laguna, gpt, mla,
+    hybrid, chunk-summary and looped families (`generation._decode_params`).
 
     Typical loop::
 
@@ -1083,6 +1227,12 @@ class ServingEngine:
             _G_HBM_POOL.set(self._hbm_pool_bytes)
             _G_HBM_DRAFT.set(0)
 
+        # what the one step builder reads of this family (eva and looped
+        # have bodies of their own)
+        self._chain = None if self._family in ("eva", "looped") \
+            else _chain_of(p, self._attn_static, self._layer_kind,
+                           self._pool_readers, self._kv_geom,
+                           _tracing.STEP_COUNTS_MOE[0] in self._count_names)
         # the fixed-shape programs: built ONCE here, never in the step
         # loop (paddlelint PT002)
         self._build_programs()
@@ -2539,15 +2689,9 @@ class ServingEngine:
         behind the decode rows (`_chunk_parts`)."""
         if self._family == "eva":
             return self._eva_unified_body(chunk)
-        if self._family == "hybrid":
-            return self._hybrid_unified_body(chunk)
         if self._family == "looped":
             return self._looped_unified_body(chunk)
-        if self._family == "gpt":
-            return self._gpt_unified_body(chunk)
-        if self._family == "mla":
-            return self._mla_unified_body(chunk)
-        return self._llama_unified_body(chunk)
+        return self._chain_unified_body(chunk)
 
     # -- unified ragged step -------------------------------------------
     # One fused launch per engine step: T = max_slots x (1 + spec_k) + C
@@ -2564,10 +2708,16 @@ class ServingEngine:
     # identical copies merge). The per-layer body is ONE chain: norm ->
     # q / k / v projections -> fused_rope_append (MLA:
     # fused_append_rows) -> ragged_paged_attention -> o-proj -> norm ->
-    # _ffn_apply. Entry (_seq_starts) and exit (_logit_rows,
-    # _head_logits, _greedy) are shared by the three families: a step
-    # returns (logits rows, the pools, their greedy tokens[, the routed
-    # layers' counts]).
+    # _ffn_apply. THREE builders make a step: `_chain_unified_body`
+    # walks a family's list of blocks (`_chain_of`, made once at
+    # construction: llama / MoE / Laguna, gpt, mla under either
+    # residual, the four hybrids); `_eva_unified_body` (a float32
+    # stream, a pooling kernel between append and attention) and
+    # `_looped_unified_body` (a compiled loop of passes over the layer
+    # list) stand beside it. Entry (_seq_starts) and exit (_logit_rows,
+    # _head_logits, _greedy) are shared by all three: a step returns
+    # (logits rows, the pools, their greedy tokens[, the counts taken on
+    # the device]).
     # No flags_guard: nothing in the chain is flag-routed.
 
     def _run_table(self, seq_start):
@@ -2598,85 +2748,6 @@ class ServingEngine:
         return append_slot_run_table(
             pool_page, pool_off, tile=tile,
             max_runs=self.max_slots + -(-chunks // tile) + 1)
-
-    def _llama_unified_body(self, C: int):
-        cfg = self._p["cfg"]
-        KV, D = cfg.num_key_value_heads, cfg.head_dim
-        eps = cfg.rms_norm_eps
-        moe_static = self._p.get("moe_static")
-        attn_static = self._attn_static
-        count_moe = _tracing.STEP_COUNTS_MOE[0] in self._count_names
-        B, K = self.max_slots, self.spec_k
-        R = 1 + K
-        T = B * R + C
-        seq_start = _seq_starts(B, R)
-        run_table = self._run_table(seq_start)
-
-        def step(w, tok, pools, positions, num_tokens, kv_lengths,
-                 tables, tok_page, tok_off):
-            with _scope("embed"):
-                x = w["embed"][tok][None]                # [1, T, H]
-                # [T, D/2] trig rows of each rope table the layers name
-                trig = {sfx: (w["cos" + sfx][positions],
-                              w["sin" + sfx][positions])
-                        for sfx in sorted({a["rope"]
-                                           for a in attn_static})}
-            if not isinstance(tables, tuple):
-                tables, tok_page = (tables,), (tok_page,)
-            # the append's runs, once a kind of cache
-            with _scope("cache_write"):
-                runs = [run_table(num_tokens, page, tok_off)
-                        for page in tok_page]
-            new_pools = []
-            moe_stats = [] if count_moe else None
-            live = _owned_rows(T, seq_start, num_tokens) \
-                if count_moe else None
-            sts = moe_static or (None,) * len(w["layers"])
-            for L, (kp, vp), st, ast in zip(w["layers"], pools, sts,
-                                            attn_static):
-                Hh, window = ast["heads"], ast["window"]
-                c, s = trig[ast["rope"]]
-                # the layer's kind of cache: its page table and the
-                # physical pages its new rows land in
-                kind = int(window is not None)
-                table = tables[kind]
-                h = _once(fused_rms_norm, "attn_norm", x, L["ln1"], eps=eps)
-                with _scope("qkv_proj"):
-                    q, k, v = (_mm_heads(h, L, w)
-                               for w in ("wq", "wk", "wv"))
-                    if "bq" in L:
-                        q, k, v = q + L["bq"], k + L["bk"], v + L["bv"]
-                with _scope("cache_write"):
-                    q, kp, vp = _once(
-                        fused_rope_append, "cache_write",
-                        q.reshape(T, Hh, D), k.reshape(T, KV, D),
-                        v.reshape(T, KV, D), c, s, kp, vp, runs[kind])
-                new_pools.append((kp, vp))
-                with _scope("attention"):
-                    o = ragged_paged_attention(q, kp, vp, seq_start,
-                                               num_tokens, kv_lengths,
-                                               table, scale=D ** -0.5,
-                                               window=window,
-                                               scope="attention")
-                with _scope("attn_out"):
-                    if "wgate" in L:
-                        # one sigmoid scalar a head, from the normed input
-                        g = jax.nn.sigmoid(_mm_w(h, L, "wgate"))[0]
-                        o = o * g[..., None].astype(o.dtype)
-                    x = x + _mm_w(o.reshape(1, T, Hh * D), L, "wo")
-                h2 = _once(fused_rms_norm, "ffn_norm", x, L["ln2"], eps=eps)
-                x = x + _ffn_apply(L, h2, st, moe_stats, live)
-            with _scope("head"):
-                x = _once(fused_rms_norm, "head", x, w["norm"], eps=eps)
-                logits = _head_logits(
-                    w, _logit_rows(x, seq_start, num_tokens, K))
-                tokens = _greedy(logits)
-                if moe_stats:
-                    return (logits, new_pools, tokens,
-                            _moe_step_counts(moe_stats))
-            return logits, new_pools, tokens
-
-        return step
 
     def _eva_unified_body(self, C: int):
         """Chunk-summary (EVA) attention on the one chain, a float32
@@ -2860,35 +2931,15 @@ class ServingEngine:
 
         return step
 
-    def _hybrid_unified_body(self, C: int):
-        """A hybrid (Nemotron-H, Ling 3.0, Falcon-H1, Phi-4-flash) on the
-        one launch: block l is `x + sum of mixers_l(norm(x))`, its ONE
-        norm feeding the mixers the model's pattern names for it
-        (`_pattern_blocks`: one a block for Nemotron-H, Ling and
-        Phi-4-flash, whose layer is two blocks; a state-space AND an
-        attention mixer side by side in Falcon-H1's ``[M*]``, whose layer
-        is that block and a ``D`` block), or `x + ffn_l(norm(x))`. The
-        norm is RMSNorm, or — where the block has a ``norm_b`` —
-        LayerNorm with weight and bias (Phi-4-flash).
-
-        A mixer OWNS its memory — its entry of ``pools["kv"]`` or
-        ``pools["ssm"]``, in the order of the pattern — or owns NONE
-        (``G<j>``, ``X<j>``): it reads what block j made for the SAME
-        rows earlier in this launch, handed down the body as a value —
-        block j's pages after its append, block j's scan output — and
-        takes no pool entry.
-
-        ``*``, grouped-query attention: `_gqa_mixer`, with the model's
-        rope table or — Nemotron-H and Phi-4-flash have no rotary
-        embedding — none, over the pages of the attention mixers alone;
-        where the model has sliding-window layers (`attn_static`), over
-        the WINDOW kind of pages, table and append runs (`tables` and
-        `tok_page` are then pairs, as the llama body's), whose pages the
-        allocator releases as the window passes; with differential
-        heads in the pair layout.
-
-        ``X<j>``: the same mixer with a query and an output projection
-        only, over block j's pages (`shared_attention`).
+    def _state_mixers(self, C: int):
+        """{letter: mixer} of a hybrid's state blocks at a launch whose
+        chunk part is `C` rows. A mixer's memory of a sequence is its slot
+        of the block's state pool and of its convolution tail: ``(L, a [T,
+        hidden], the state pool, the tail pool, num_tokens, the state
+        table) -> (its output [T, hidden], the state pool, the tail
+        pool[, its scan output])``. The state table [B + 3]: the live
+        decode slots then the spare, their count, the chunk's slot,
+        whether the launch starts it.
 
         ``S``, a Mamba-1 state-space mixer (a decay a (channel, state
         column); the slot's state [1, N, C], channels along the lanes):
@@ -2899,13 +2950,9 @@ class ServingEngine:
         chunk's length carry dt 0, the identity), `ssm_state_put`
         (`ssm1_scan`) -> the gate and the out-projection (`ssm1_out`).
         Its scan output y (with the D term, before the gate) is what a
-        ``G<j>`` block reads: `silu(a W_a) * y`, then W_b (`gmu`).
+        ``G<j>`` block reads.
 
-        ``L``, gated latent attention: `_latent_mixer`, the mla
-        family's, over pages that hold latent rows.
-
-        ``M``, a Mamba-2 state-space mixer, whose memory of a sequence is
-        its slot of the block's state pool and of its convolution tail:
+        ``M``, a Mamba-2 state-space mixer:
         in-projection (`ssm_in_proj`) -> the causal convolution, a decode
         row from its slot's tail, the chunk's rows from its slot's tail
         (zeros where the launch starts the sequence) and from each other,
@@ -2925,49 +2972,20 @@ class ServingEngine:
         (`kda_state_update`) -> the chunk's `kda_chunk_scan` (rows past
         its length carry g 0 and beta 0, the identity) and
         `ssm_state_put` (`kda_chunk_scan`) -> each head's norm, its
-        gate and the out-projection (`kda_out`).
-
-        ``E``, a routed FFN (latent or not), and ``D``, a dense SwiGLU
-        FFN: `_ffn_apply` (`routed_ffn`, `latent_proj`,
-        `shared_expert`; `ffn`).
-
-        A model's static multipliers (``mults``, Falcon-H1's fourteen)
-        are applied where its equations put them, inside the scope of
-        the operation they scale; without them no operation is added.
-
-        ``kv_lengths`` is a pair: (the attention blocks' lengths, the
-        state table [B + 3]: the live decode slots then the spare, their
-        count, the chunk's slot, whether the launch starts it)."""
+        gate and the out-projection (`kda_out`)."""
         cfg, pattern = self._p["cfg"], self._p["pattern"]
-        blocks, mu = self._blocks, self._p.get("mults")
+        mu = self._p.get("mults")
         eps, K = cfg.layer_norm_epsilon, cfg.conv_kernel
-        diff, layer_kind = self._p.get("diff", {}), self._layer_kind
-        attn_static = self._attn_static
-        moe_static = self._p["moe_static"]
         layout = self._state_layout
         B = self.max_slots
         T = B + C
-        seq_start = _seq_starts(B, 1)
-        run_table = self._run_table(seq_start)
         f32 = jnp.float32
-        if "*" in pattern:
-            # (the pool's geometry: a pair of heads where they are
-            # differential, else the config's)
-            gqa = dict(heads=cfg.num_attention_heads, kv=self._kv_geom[0],
-                       d=self._kv_geom[1], mults=mu, eps=eps)
         if mu:
             from ..models.falcon_h1 import mup_vector
-            ffn_mults = {k: mu[k] for k in ("mlp_gate", "mlp_down")}
         if "M" in pattern:
             Hm, P, G = cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups
         if "K" in pattern:
             Hk, Dk = cfg.num_attention_heads, cfg.head_dim
-        if "L" in pattern:
-            latent = dict(nh=cfg.num_attention_heads,
-                          dn=cfg.qk_nope_head_dim, dr=cfg.qk_rope_head_dim,
-                          dv=cfg.v_head_dim, r=cfg.kv_lora_rank,
-                          width=self._kv_geom[1], eps=eps,
-                          scale=cfg.softmax_scale)
 
         def with_spare(m):
             """A launch's operand m [T, ...] with a row B, the spare
@@ -3175,234 +3193,167 @@ class ServingEngine:
                                    eps).astype(a.dtype)
                 return y @ L["wo"], z_pool, t_pool
 
+        return {"M": ssm, "K": kda, "S": ssm1}
+
+    def _chain_unified_body(self, C: int):
+        """The step of every family whose layers are a list of BLOCKS
+        (`_chain_of`: llama / MoE / Laguna, gpt, latent attention under
+        either residual, the four hybrids): block l is `x + sum of
+        mixers_l(norm(x))`, its ONE norm feeding the mixers its letters
+        name (one a block for every family but Falcon-H1, whose ``[M*]``
+        holds a state-space AND an attention mixer side by side), or `x
+        + ffn_l(norm(x))`. The norm is RMSNorm or — two keys — LayerNorm
+        with weight and bias (gpt, Phi-4-flash). The residual is a seam:
+        the plain add, or `_HyperResidual`'s streams mixed around every
+        block (``feed`` / ``leave``).
+
+        A mixer OWNS its memory — the next entry of the family's page
+        pools or state pools, in the order of the blocks — or owns NONE
+        (``G<j>``, ``X<j>``): it reads what block j made for the SAME
+        rows earlier in this launch, handed down the body as a value —
+        block j's pages after its append, block j's scan output — and
+        takes no pool entry.
+
+        ``*``, grouped-query attention: `_gqa_mixer`, turned by the rows
+        of the rope table the block names or — gpt, Nemotron-H and
+        Phi-4-flash have no rotary embedding — by none, over the pages
+        of the block's KIND: where the model has sliding-window layers
+        (`attn_static`) `tables` and `tok_page` are pairs, and a window
+        block reads the window kind's pages, table and append runs,
+        whose pages the allocator releases as the window passes.
+        ``X<j>``: the same mixer with a query and an output projection
+        only, over block j's pages (`shared_attention`). ``L``, latent
+        attention: `_latent_mixer`, over pages that hold latent rows.
+        ``M`` ``K`` ``S``: `_state_mixers`. ``G<j>``: `silu(a W_a) * y`
+        of block j's scan output y, then W_b (`gmu`).
+
+        ``E``, a routed FFN (latent or not), and ``D``, a dense SwiGLU
+        FFN: `_ffn_apply` (`routed_ffn`, `latent_proj`,
+        `shared_expert`; `ffn`); ``B``: `_gelu_ffn`.
+
+        A model's static multipliers (``mults``, Falcon-H1's fourteen)
+        are applied where its equations put them, inside the scope of
+        the operation they scale; without them no operation is added.
+
+        Where a family has state pools `kv_lengths` is a pair (the
+        attention blocks' lengths, the state table) and `pools` a dict
+        (`_Chain.split` / `join`)."""
+        d, cfg = self._chain, self._p["cfg"]
+        eps, mu = d.eps, d.mults
+        B, K = self.max_slots, self.spec_k
+        R = 1 + K
+        T = B * R + C
+        seq_start = _seq_starts(B, R)
+        run_table = self._run_table(seq_start)
+        state = self._state_mixers(C) if self._ssm_layers else {}
+        f32 = jnp.float32
+
+        def norm(x, scope, w, keys, once=True):
+            fn = fused_layer_norm if len(keys) > 1 else fused_rms_norm
+            gains = [w[k] for k in keys]
+            return _once(fn, scope, x, *gains, eps=eps) if once \
+                else fn(x, *gains, eps)
+
         def step(w, tok, pools, positions, num_tokens, kv_lengths,
                  tables, tok_page, tok_off):
-            kv_lengths, tab = kv_lengths
+            kv_pools, ssm_pools, kv_lengths, tab = d.split(pools, kv_lengths)
+            kv_pools, ssm_pools = iter(kv_pools), iter(ssm_pools)
             with _scope("embed"):
-                x = w["embed"][tok][None]                # [1, T, hidden]
+                x = w["embed"][tok]
+                if "pos" in w:      # learned positions (gpt)
+                    x = x + w["pos"][positions]
+                x = x[None]                              # [1, T, hidden]
                 if mu:
                     x = x * mu["embedding"]
-            # the rows' angles, where the model has a position table
-            # (none: no rotary embedding)
-            rope = None
-            if "L" in pattern:
-                rope = _halves_rope(w["cos"][positions],
-                                    w["sin"][positions])
-            elif "cos" in w:
-                rope = w["cos"][positions], w["sin"][positions]
-            elif "*" in pattern:
-                # (made once a step, here, as the parent's text has it)
-                rope = _no_turn(T, gqa["d"], x.dtype)
-            # the append's runs: once, or once a kind of cache (a model
-            # with window layers: the full kind's, then the window's)
+            # the rows' angles [T, D / 2], one (cos, sin) a rope table
+            # the blocks name; without a table, the identity turn
+            with _scope("embed") if d.ends_scoped else nullcontext():
+                trig = {sfx: (w["cos" + sfx][positions],
+                              w["sin" + sfx][positions])
+                        for sfx in d.rope_tables}
+                if d.no_turn:
+                    trig[""] = _no_turn(T, d.no_turn[0], x.dtype,
+                                        d.no_turn[1])
+            # the append's runs, once a kind of cache (a model with
+            # window layers: the full kind's, then the window's)
+            if not isinstance(tables, tuple):
+                tables, tok_page = (tables,), (tok_page,)
             with _scope("cache_write"):
-                if isinstance(tables, tuple):
-                    runs = [run_table(num_tokens, page, tok_off)
-                            for page in tok_page]
-                else:
-                    runs, tables = (run_table(num_tokens, tok_page,
-                                              tok_off),) * 2, (tables,) * 2
-            kv_pools, ssm_pools = iter(pools["kv"]), iter(pools["ssm"])
-            sts, owned = iter(moe_static), iter(zip(
-                layer_kind, attn_static, self._pool_readers))
-            new_kv, new_ssm, moe_stats = [], [], []
+                runs = [run_table(num_tokens, page, tok_off)
+                        for page in tok_page]
+            new_kv, new_ssm = [], []
+            moe_stats = [] if d.moe_counts else None
+            live = _owned_rows(T, seq_start, num_tokens) \
+                if d.moe_counts or d.hyper else None
             # what a block that owns no memory reads: by the block that
             # made it, this launch
             pages_of, scan_of = {}, {}
-            live = _owned_rows(T, seq_start, num_tokens)
-            for i, block in enumerate(blocks):  # its letters: static
-                L = w["layers"][i]
-                scope = "ffn_norm" if block in "ED" else "attn_norm"
-                if "norm_b" in L:
-                    a = _once(fused_layer_norm, scope, x, L["norm"],
-                              L["norm_b"], eps=eps)
-                else:
-                    a = _once(fused_rms_norm, scope, x, L["norm"], eps=eps)
-                if block[0] == "G":     # a gated unit over a scan output
+            res = _HyperResidual(cfg, live) if d.hyper else _PLAIN
+            x = res.enter(x)
+            for i, blk in enumerate(d.blocks):  # all of `blk`: static
+                L = w["layers"][blk.layer]
+                a, keep = res.feed(x, L.get(blk.hc))
+                a = norm(a, "ffn_norm" if blk.kind in "EDB"
+                         else "attn_norm", L, blk.norm)
+                if blk.kind in "EDB":
+                    x = res.leave(x, _gelu_ffn(L, a) if blk.kind == "B"
+                                  else _ffn_apply(L, a, blk.st, moe_stats,
+                                                  live), keep)
+                    continue
+                if blk.kind[0] == "G":  # a gated unit over a scan output
                     with jax.named_scope("gmu"):
                         g = jax.nn.silu((a[0] @ L["w_a"]).astype(f32))
-                        x = x + ((g * scan_of[int(block[1:])][0])
-                                 .astype(a.dtype) @ L["w_b"])[None]
+                        x = res.leave(
+                            x, ((g * scan_of[int(blk.kind[1:])][0])
+                                .astype(a.dtype) @ L["w_b"])[None], keep)
                     continue
-                if block[0] == "X":     # attention over another's pages
+                if blk.kind[0] == "X":  # attention over another's pages
                     y, _ = _gqa_mixer(
-                        L, a, rope, pages_of[int(block[1:])], seq_start,
-                        num_tokens, kv_lengths, tables[0], None,
-                        diff=diff.get(i), borrowed=True, **gqa)
-                    x = x + y
-                    continue
-                if block == "E":
-                    x = x + _ffn_apply(L, a, next(sts), moe_stats, live)
-                    continue
-                if block == "D":
-                    x = x + (_ffn_apply(L, a, ffn_mults) if mu
-                             else _ffn_apply(L, a))
+                        L, a, trig[blk.rope], pages_of[int(blk.kind[1:])],
+                        seq_start, num_tokens, kv_lengths, tables[0], None,
+                        **blk.attn)
+                    x = res.leave(x, y, keep)
                     continue
                 # the block's mixers, each on the one normed input; the
-                # residual takes their sum
-                for kind in block:
+                # residual takes one after the other
+                for kind in blk.kind:
                     if kind in "MKS":
-                        y, z_pool, t_pool, *scan = {
-                            "M": ssm, "K": kda, "S": ssm1}[kind](
+                        y, z_pool, t_pool, *scan = state[kind](
                             L, a[0], *next(ssm_pools), num_tokens, tab)
                         new_ssm.append((z_pool, t_pool))
                         scan_of[i] = scan   # ([y] of an `S` mixer)
                         y = y[None]
                     elif kind == "L":
                         y, pool = _latent_mixer(
-                            L, a, rope, next(kv_pools), seq_start,
-                            num_tokens, kv_lengths, tables[0], runs[0],
-                            **latent)
+                            L, a, _halves_rope(*trig[blk.rope]),
+                            next(kv_pools), seq_start, num_tokens,
+                            kv_lengths, tables[0], runs[0], **blk.attn)
                         new_kv.append(pool)
                     else:
-                        k, st, readers = next(owned)
                         y, pool = _gqa_mixer(
-                            L, a, rope, next(kv_pools), seq_start,
-                            num_tokens, kv_lengths, tables[k], runs[k],
-                            window=st["window"], diff=diff.get(i),
-                            shared=readers > 1, **gqa)
+                            L, a, trig[blk.rope], next(kv_pools), seq_start,
+                            num_tokens, kv_lengths, tables[blk.pages],
+                            runs[blk.pages], **blk.attn)
                         new_kv.append(pool)
                         pages_of[i] = pool
-                    x = x + y
+                    x = res.leave(x, y, keep)
+            x = res.exit(x)
             with _scope("head"):
-                x = fused_layer_norm(x, w["norm"], w["norm_b"], eps) \
-                    if "norm_b" in w else fused_rms_norm(x, w["norm"], eps)
+                x = norm(x, "head", w, d.head_norm, d.head_once)
                 logits = _head_logits(
-                    w, _logit_rows(x, seq_start, num_tokens, 0))
+                    w, _logit_rows(x, seq_start, num_tokens, K))
                 if mu:
                     logits = logits * mu["lm_head"]
                 tokens = _greedy(logits)
-            out = logits, {"kv": new_kv, "ssm": new_ssm}, tokens
-            # (a model without routed FFNs has no such counts)
-            return out + (_moe_step_counts(moe_stats),) if moe_stats \
-                else out
-
-        return step
-
-    def _gpt_unified_body(self, C: int):
-        cfg = self._p["cfg"]
-        nh, hd = cfg.num_attention_heads, cfg.head_dim
-        eps = cfg.layer_norm_eps
-        B, K = self.max_slots, self.spec_k
-        R = 1 + K
-        T = B * R + C
-        seq_start = _seq_starts(B, R)
-        run_table = self._run_table(seq_start)
-
-        def step(w, tok, pools, positions, num_tokens, kv_lengths,
-                 tables, tok_page, tok_off):
-            with _scope("embed"):
-                x = (w["embed"][tok] + w["pos"][positions])[None]
-            # identity rope (cos=1, sin=0): fused_rope_append becomes a
-            # pure fused K/V append, bitwise-exact on q/k
-            c = jnp.ones((T, hd // 2), x.dtype)
-            s = jnp.zeros((T, hd // 2), x.dtype)
-            with _scope("cache_write"):
-                runs = run_table(num_tokens, tok_page, tok_off)
-            new_pools = []
-            for L, (kp, vp) in zip(w["layers"], pools):
-                h = _once(fused_layer_norm, "attn_norm", x, L["ln1w"],
-                          L["ln1b"], eps=eps)
-                with _scope("qkv_proj"):
-                    qkv = h @ L["wqkv"] + L["bqkv"]
-                    q, k, v = jnp.split(qkv, 3, axis=-1)
-                with _scope("cache_write"):
-                    q, kp, vp = _once(
-                        fused_rope_append, "cache_write",
-                        q.reshape(T, nh, hd), k.reshape(T, nh, hd),
-                        v.reshape(T, nh, hd), c, s, kp, vp, runs)
-                new_pools.append((kp, vp))
-                with _scope("attention"):
-                    o = ragged_paged_attention(q, kp, vp, seq_start,
-                                               num_tokens, kv_lengths,
-                                               tables, scale=hd ** -0.5,
-                                               scope="attention")
-                with _scope("attn_out"):
-                    x = x + (o.reshape(1, T, nh * hd) @ L["wo"] + L["bo"])
-                h2 = _once(fused_layer_norm, "ffn_norm", x, L["ln2w"],
-                           L["ln2b"], eps=eps)
-                with _scope("ffn"):
-                    x = x + (jax.nn.gelu(h2 @ L["wi"] + L["bi"],
-                                         approximate=True) @ L["wf"]
-                             + L["bf"])
-            with _scope("head"):
-                x = fused_layer_norm(x, w["normw"], w["normb"], eps)
-                logits = _head_logits(
-                    w, _logit_rows(x, seq_start, num_tokens, K))
-                tokens = _greedy(logits)
-            return logits, new_pools, tokens
-
-        return step
-
-    def _mla_unified_body(self, C: int):
-        """Latent attention in the ABSORBED form on the one chain: the
-        cache row is (RMSNorm(latent) | RoPE(k_pe) | pad), the query of
-        head a is (q_nope_a W_kvb^K_a | RoPE(q_pe_a) | 0), the kernel's
-        output the weighted sum of the rows' latent columns, and
-        W_kvb^V_a comes after. The prefill chunk rides the same form as
-        the decode rows. The residual is the configuration's: the plain
-        add, or — ``hc_mult`` > 1 — a stream of that many rows a token
-        mixed around every sublayer (`_HyperResidual`)."""
-        cfg = self._p["cfg"]
-        hyper = cfg.hc_mult > 1
-        nh = cfg.num_attention_heads
-        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                      cfg.v_head_dim)
-        r = cfg.kv_lora_rank
-        width = self._kv_geom[1]
-        eps = cfg.rms_norm_eps
-        scale = cfg.softmax_scale       # yarn's mscale^2 included
-        moe_static = self._p.get("moe_static")
-        count_moe = _tracing.STEP_COUNTS_MOE[0] in self._count_names
-        B, K = self.max_slots, self.spec_k
-        R = 1 + K
-        T = B * R + C
-        seq_start = _seq_starts(B, R)
-        run_table = self._run_table(seq_start)
-
-        def step(w, tok, pools, positions, num_tokens, kv_lengths,
-                 tables, tok_page, tok_off):
-            with _scope("embed"):
-                x = w["embed"][tok][None]                # [1, T, H]
-                c = w["cos"][positions]                  # [T, dr/2]
-                s = w["sin"][positions]
-            with _scope("cache_write"):
-                runs = run_table(num_tokens, tok_page, tok_off)
-
-            rope = _halves_rope(c, s)
-            new_pools = []
-            moe_stats = [] if count_moe else None
-            live = _owned_rows(T, seq_start, num_tokens) \
-                if count_moe or hyper else None
-            res = _HyperResidual(cfg, live) if hyper else _PLAIN
-            x = res.enter(x)
-            sts = moe_static or (None,) * len(w["layers"])
-            for L, pool, st in zip(w["layers"], pools, sts):
-                a, keep = res.feed(x, L.get("hc1"))
-                h = _once(fused_rms_norm, "attn_norm", a, L["ln1"], eps=eps)
-                y, pool = _latent_mixer(
-                    L, h, rope, pool, seq_start, num_tokens, kv_lengths,
-                    tables, runs, nh=nh, dn=dn, dr=dr, dv=dv, r=r,
-                    width=width, eps=eps, scale=scale)
-                x = res.leave(x, y, keep)
-                new_pools.append(pool)
-                a, keep = res.feed(x, L.get("hc2"))
-                h2 = _once(fused_rms_norm, "ffn_norm", a, L["ln2"], eps=eps)
-                x = res.leave(x, _ffn_apply(L, h2, st, moe_stats, live),
-                              keep)
-            x = res.exit(x)
-            with _scope("head"):
-                x = fused_rms_norm(x, w["norm"], eps)
-                logits = _head_logits(
-                    w, _logit_rows(x, seq_start, num_tokens, K))
-                tokens = _greedy(logits)
+            out = logits, d.join(new_kv, new_ssm), tokens
+            # the counts taken on the device ride beside the logits as
+            # ONE array, in the order of `_device_count_names`
+            with _scope("head") if d.ends_scoped else nullcontext():
                 counts = [_moe_step_counts(moe_stats)] if moe_stats else []
-                if hyper:
+                if d.hyper:
                     counts.append(res.device_counts())
-                if counts:
-                    return (logits, new_pools, tokens,
-                            counts[0] if len(counts) == 1
-                            else jnp.concatenate(counts))
-            return logits, new_pools, tokens
+                if len(counts) > 1:
+                    counts = [jnp.concatenate(counts)]
+            return out + tuple(counts)
 
         return step

@@ -77,12 +77,28 @@ class TestEngineSpecDecode:
         return m
 
     def _repetitive_prompt(self, model):
-        """A prompt whose greedy continuation is repetitive: extend a
-        seed prompt with its own greedy output up into the cyclic tail
-        tiny greedy models converge to."""
-        base = np.asarray([251, 195, 359, 9, 211], np.int32)
-        cont = _solo(model, base, 16)
-        return np.concatenate([base, np.asarray(cont[:10], np.int32)])
+        """A prompt whose greedy continuation is repetitive: the toy fed
+        a PERIODIC prompt falls into a short cycle (fed a random one its
+        greedy output repeats no n-gram in a hundred tokens: the premise
+        this helper was written on at PR 10 stopped holding, and the
+        drafter was offered one verify step in twelve). The first of a
+        few periods whose continuation the n-gram drafter predicts at
+        more than 1.5 tokens a verify step, then its own first tokens:
+        chosen by looking at the toy, so a change of its numerics moves
+        the choice and not the test."""
+        for period in ([100, 200, 300, 400], [7, 300, 41], [5, 9],
+                       [251, 195, 359, 9, 211]):
+            base = np.asarray(period * 6, np.int32)
+            cont = _solo(model, base, 20)
+            ctx, tail = list(base) + cont[:8], cont[8:]
+            drafted = steps = pos = 0
+            while pos < len(tail):
+                d = ngram_draft(ctx + tail[:pos], 4)
+                m = accept_length(d, tail[pos:])
+                drafted, steps, pos = drafted + m, steps + bool(d), pos + m + 1
+            if steps and drafted / steps > 1.5:
+                return np.asarray(ctx, np.int32)
+        pytest.fail("no periodic prompt gives the toy a repetitive trace")
 
     def test_spec_decode_exact_and_accepts_over_one(self, model):
         # acceptance: > 1 mean accepted tokens per verify step on a

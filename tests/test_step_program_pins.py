@@ -39,7 +39,7 @@ from test_engine_programs import _lowered_toy
 
 #: family -> its toy engine at the pin's sizes
 ENGINES = {
-    **{f: functools.partial(lambda f: _lowered_toy(f)[1], f)
+    **{f: (lambda f=f: _lowered_toy(f)[1])
        for f in ("llama", "moe", "mla", "gpt", "laguna")},
     "eva": eva._pin_engine,
     "looped": looped._pin_engine,
