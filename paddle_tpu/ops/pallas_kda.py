@@ -32,11 +32,18 @@ lanes.)
 - `kda_chunk_scan`: a run of rows of ONE sequence from its state, in
   sub-chunks (64): inside a sub-chunk the WY / UT-transform form — a
   unit-lower-triangular solve a head — between sub-chunks the state.
-  Plain XLA. The decays between two rows of a sub-chunk are taken as
-  exp of the DIFFERENCE of their cumulative log gates (<= 0), never as
-  a quotient of two exponentials: at the gate's lower bound (-5 a
-  token) a sub-chunk spans e^-320. Rows whose ``g`` and ``beta`` are 0
-  are the identity, so a chunk is padded by zeroing both.
+  Plain XLA (differentiable: the eager model runs it under `jax.vjp`),
+  every product a batch of float32 `HIGHEST` matmuls over the heads,
+  which are turned batch-major once at the entry; the sub-chunks of a
+  chunk, known at trace time, are a Python loop, not a `lax.scan`. The
+  decay between two rows of a sub-chunk is exp of the DIFFERENCE of
+  their cumulative log gates (<= 0), never a quotient of two
+  exponentials: at the gate's lower bound (-5 a token) a sub-chunk
+  spans e^-320. Pair by pair only inside diagonal blocks of 16 rows;
+  between blocks the difference is split at a row between the two, two
+  exponents <= 0 on the two operands of a matmul over K
+  (`_decayed_grams`). Rows whose ``g`` and ``beta`` are 0 are the
+  identity, so a chunk is padded by zeroing both.
 - a slot is written in place by `ops.pallas_ssm.ssm_state_put`: the
   pool is four-dimensional like Mamba-2's.
 """
@@ -189,55 +196,110 @@ def _unit_lower_inverse(a):
     """``(I + a)^-1`` for strictly lower-triangular ``a`` [..., C, C], C
     a power of two, by doubling: the inverse of the diagonal blocks of
     size 2b from those of size b, ``[[X, 0], [-Y A21 X, Y]]`` — block
-    forward substitution, six levels at C = 64."""
+    forward substitution, six levels at C = 64, the first of which (X
+    = Y = 1) is a subtraction."""
     C = a.shape[-1]
     r = jnp.arange(C)
-    d = jnp.broadcast_to(jnp.eye(C, dtype=a.dtype), a.shape)
-    b = 1
+
+    def below(b):       # A21 of every diagonal block of size 2b
+        return jnp.where(
+            (r[:, None] // b == r[None, :] // b + 1)
+            & (r[:, None] // (2 * b) == r[None, :] // (2 * b)), a, 0.0)
+
+    d = jnp.eye(C, dtype=a.dtype) - below(1)
+    b = 2
     while b < C:
-        below = (r[:, None] // b == r[None, :] // b + 1) \
-            & (r[:, None] // (2 * b) == r[None, :] // (2 * b))
-        d = d - jnp.matmul(jnp.matmul(d, jnp.where(below, a, 0.0),
-                                      precision=_HI), d, precision=_HI)
+        d = d - jnp.matmul(jnp.matmul(d, below(b), precision=_HI), d,
+                           precision=_HI)
         b *= 2
     return d
 
 
-def _sub_chunk_forms(q, k, g, beta):
-    """What a sub-chunk's rows give WITHOUT its state: q, k, g [C, H, K],
-    beta [C, H] -> (T [H, C, C] = (I + A)^-1 Diag(beta), B [H, C, C],
-    cs [C, H, K] the cumulative log gates)."""
-    C = q.shape[0]
-    cs = jnp.cumsum(g, 0)                               # <= 0
+#: rows of a DIAGONAL block of a sub-chunk's decayed Gram matrices: inside
+#: one the decays are taken pair by pair (elementwise), between blocks
+#: they factor into two matmul operands. A constant of the arithmetic,
+#: not a knob: a sub-chunk of fewer rows is one diagonal block.
+_GRAM_BLOCK = 16
+
+
+def _decayed_grams(q, k, cs):
+    """The two decayed Gram matrices of sub-chunks, heads batch-major: q,
+    k and cs (the cumulative log gates, decreasing down the rows) [...,
+    C, K], C a power of two -> (A, B) [..., C, C], ``A[t, i] = sum_c
+    k_t[c] k_i[c] exp(cs_t[c] - cs_i[c])`` and B with ``q_t`` for i <=
+    t, 0 above the diagonal.
+
+    Inside the diagonal blocks of `_GRAM_BLOCK` rows the decays are
+    taken pair by pair. Between them by halves: at half-size h (the
+    block, then doubling to C / 2) a row t of the LATER half of its
+    span of 2h rows sees a row i of the EARLIER half through the log
+    gate ``rho`` at the earlier half's end, ``(x_t exp(cs_t - rho)) .
+    (k_i exp(rho - cs_i))`` — one matmul over K a level for every span
+    at once, rows of the other half zeroed and pairs of different spans
+    masked off. Both exponents are <= 0 and each factor bounds the
+    decay it is a part of, so a factor that underflows stands for a
+    decay that underflows too: no quotient of two exponentials, no
+    positive exponent."""
+    C, K = k.shape[-2:]
+    lead = k.shape[:-2]
+    b = min(C, _GRAM_BLOCK)
+    qb, kb, cb = (a.reshape(lead + (C // b, b, K)) for a in (q, k, cs))
+    t = jnp.arange(b)
+    seg = cb[..., :, None, :] - cb[..., None, :, :]     # [.., b, b, K]
+    kd = kb[..., None, :, :] * jnp.where(               # k_i as row t sees it
+        (t[:, None] >= t[None, :])[..., None],
+        jnp.exp(jnp.minimum(seg, 0.0)), 0.0)
+    # (elementwise into both reductions: no [.., b, b, K] array is kept)
+    eye = jnp.eye(C // b, dtype=k.dtype)[:, None, :, None]
+    grams = [(jnp.sum(x[..., :, None, :] * kd, -1)[..., :, :, None, :]
+              * eye).reshape(lead + (C, C)) for x in (kb, qb)]
     t = jnp.arange(C)
-    # decay from after row i to row t, i <= t: a difference, <= 0
-    seg = cs[:, None] - cs[None, :]                     # [C, C, H, K]
-    dec = jnp.where((t[:, None] >= t[None, :])[..., None, None],
-                    jnp.exp(jnp.minimum(seg, 0.0)), 0.0)
-    kd = k[None, :] * dec                               # k_i as row t sees it
-    # (elementwise into the reduction: no [C, C, H, K] array is kept)
-    a = jnp.sum(k[:, None] * kd, -1).transpose(2, 0, 1)     # [H, C, C]
-    b = jnp.sum(q[:, None] * kd, -1).transpose(2, 0, 1)
-    bt = beta.T[:, :, None]                             # [H, C, 1]
-    a = jnp.where(t[:, None] > t[None, :], a, 0.0) * bt
-    return _unit_lower_inverse(a) * bt.swapaxes(1, 2), b, cs
+    kq = jnp.stack([k, q], -3)                          # [.., 2, C, K]
+    h = b
+    while h < C:
+        spans = lead + (C // (2 * h), 2 * h, K)
+        rho = jnp.broadcast_to(
+            cs.reshape(spans)[..., h - 1:h, :], spans).reshape(cs.shape)
+        later = ((t // h) % 2 == 1)[:, None]
+        low = jnp.where(later, jnp.exp(jnp.minimum(cs - rho, 0.0)), 0.0)
+        high = jnp.where(later, 0.0, jnp.exp(jnp.minimum(rho - cs, 0.0)))
+        g = jnp.einsum("...rtc,...ic->...rti", kq * low[..., None, :, :],
+                       k * high, precision=_HI)
+        same = t[:, None] // (2 * h) == t[None, :] // (2 * h)
+        grams = [m + jnp.where(same, g[..., r, :, :], 0.0)
+                 for r, m in enumerate(grams)]
+        h *= 2
+    return grams
 
 
-def _scan_sub_chunk(state, rows):
-    """One sub-chunk: (state [H, K, V], (q, k [C, H, K], v [C, H, V], T,
-    B [H, C, C], cs [C, H, K])) -> (new state, o [C, H, V])."""
-    q, k, v, tinv, b, cs = rows
-    gam = jnp.exp(cs)                                   # from the start
-    # what row i's write sees of the state it starts from, then the
-    # pseudo-values U = (I + A)^-1 Diag(beta) (V - (Gamma k) S_0)
-    seen = jnp.einsum("ihc,hcv->hiv", k * gam, state, precision=_HI)
-    u = jnp.matmul(tinv, v.swapaxes(0, 1) - seen, precision=_HI)
-    o = jnp.einsum("thc,hcv->htv", q * gam, state, precision=_HI) \
-        + jnp.matmul(b, u, precision=_HI)
-    left = jnp.exp(cs[-1][None] - cs)                   # to the end, <= 1
-    new = gam[-1][..., None] * state + jnp.einsum(
-        "ihc,hiv->hcv", k * left, u, precision=_HI)
-    return new, o.swapaxes(0, 1)
+def _cumsum_rows(g, reverse: bool = False):
+    """The inclusive sum down the rows of g [..., C, K] (up them, if
+    `reverse`): by doubling (shifted adds) inside the blocks of
+    `_GRAM_BLOCK` rows, the blocks' totals summed in order.
+    (`jnp.cumsum` reaches the chip as a matmul with a triangle of ones,
+    three times this.)"""
+    C, K = g.shape[-2:]
+    b = min(C, _GRAM_BLOCK)
+    x = g.reshape(g.shape[:-2] + (C // b, b, K))
+    lead = [(0, 0)] * (x.ndim - 2)
+    d = 1
+    while d < b:
+        x = x + (jnp.pad(x[..., d:, :], lead + [(0, d), (0, 0)]) if reverse
+                 else jnp.pad(x[..., :b - d, :], lead + [(d, 0), (0, 0)]))
+        d *= 2
+    # what the blocks before (after) a block add up to
+    if reverse:
+        rest = jnp.pad(jnp.cumsum(x[..., ::-1, 0, :], -2)[..., -2::-1, :],
+                       lead[:-1] + [(0, 1), (0, 0)])
+    else:
+        rest = jnp.pad(jnp.cumsum(x[..., -1, :], -2)[..., :-1, :],
+                       lead[:-1] + [(1, 0), (0, 0)])
+    return (x + rest[..., None, :]).reshape(g.shape)
+
+
+def _heads_major(a, chunk: int):
+    """[L, H, ...] -> [L / chunk, H, chunk, ...]."""
+    return jnp.moveaxis(a.reshape((-1, chunk) + a.shape[1:]), 2, 1)
 
 
 def kda_chunk_scan(q, k, v, g, beta, state, *, chunk: int = 64):
@@ -248,19 +310,41 @@ def kda_chunk_scan(q, k, v, g, beta, state, *, chunk: int = 64):
     (log decay, <= 0), beta [L, H], state [H, K, V]; float32 inside. A
     row with ``g`` 0 and ``beta`` 0 changes nothing and its own output
     is discarded by the caller. Returns (o [L, H, V] float32 = S_t^T
-    q_t, the state after the last row)."""
+    q_t, the state after the last row).
+
+    The operands are turned heads batch-MAJOR once ([sub-chunks, H,
+    chunk, .]) and ``o`` once at the exit; every product is a batch of
+    matmuls over the heads. What does not read the state — the Gram
+    matrices, the solve, every decay — is taken for all sub-chunks at
+    once; the sub-chunks, known at trace time, are then a Python loop
+    of four products each."""
     if chunk & (chunk - 1):
         raise ValueError(f"sub-chunk {chunk} must be a power of two")
     L = q.shape[0]
     f32 = jnp.float32
     pad = -L % chunk
-    q, k, v, g, beta = (
-        jnp.pad(a.astype(f32), ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-        .reshape((-1, chunk) + a.shape[1:]) for a in (q, k, v, g, beta))
-    # the solves do not read the state: all sub-chunks' at once
-    tinv, b, cs = jax.vmap(_sub_chunk_forms)(q, k, g, beta)
-    state, o = jax.lax.scan(_scan_sub_chunk, state.astype(f32),
-                            (q, k, v, tinv, b, cs))
+    q, k, v, g, beta = (_heads_major(jnp.pad(
+        a.astype(f32), ((0, pad),) + ((0, 0),) * (a.ndim - 1)), chunk)
+        for a in (q, k, v, g, beta))
+    cs = _cumsum_rows(g)                                # <= 0
+    a, b = _decayed_grams(q, k, cs)                     # [n, H, C, C]
+    t = jnp.arange(chunk)
+    a = jnp.where(t[:, None] > t[None, :], a, 0.0) * beta[..., :, None]
+    tinv = _unit_lower_inverse(a) * beta[..., None, :]  # (I + A)^-1 Diag(b)
+    gam = jnp.exp(cs)                                   # from the start
+    kq = jnp.concatenate([k * gam, q * gam], -2)        # [n, H, 2 C, K]
+    kl = k * jnp.exp(_cumsum_rows(g, reverse=True) - g)  # to the end, <= 1
+    state, o = state.astype(f32), []
+    for j in range(q.shape[0]):
+        # what row i's write sees of the state it starts from (and q's
+        # rows read of it), then the pseudo-values
+        # U = (I + A)^-1 Diag(beta) (V - (Gamma k) S_0)
+        from_state = jnp.matmul(kq[j], state, precision=_HI)
+        u = jnp.matmul(tinv[j], v[j] - from_state[:, :chunk], precision=_HI)
+        o.append(from_state[:, chunk:] + jnp.matmul(b[j], u, precision=_HI))
+        state = gam[j, :, -1, :, None] * state + jnp.einsum(
+            "hic,hiv->hcv", kl[j], u, precision=_HI)
+    o = jnp.moveaxis(jnp.stack(o), 1, 2)                # [n, C, H, V]
     return o.reshape((-1,) + o.shape[2:])[:L], state
 
 

@@ -7,6 +7,7 @@ zero state, with padding rows as the identity, and with the log gates at
 both ends of (-5, 0), where a sub-chunk spans e^-320 and nothing may
 come out inf or nan."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -124,6 +125,82 @@ class TestChunkScan:
         o_ref, s_ref = kda_recurrence_reference(*rows, s0)
         np.testing.assert_allclose(o, o_ref, atol=5e-6)
         np.testing.assert_allclose(s, s_ref, atol=5e-6)
+
+    @pytest.mark.parametrize("zero", [False, True],
+                             ids=["from_a_state", "from_zero"])
+    @pytest.mark.parametrize("g_lo, g_hi", [(-5.0, -4.999), (-1e-6, 0.0),
+                                            (-5.0, 0.0)])
+    @pytest.mark.parametrize("L", [15, 16, 17, 33, 48, 100, 256])
+    def test_rows_cross_the_blocks_of_a_sub_chunk_of_64(self, L, g_lo, g_hi,
+                                                        zero):
+        """Inside a sub-chunk of 64 the decays between two blocks of 16
+        rows are two matmul operands, each exp of a number <= 0: at -5
+        a token the factor over 48 earlier rows underflows to 0, as the
+        decay it stands for does, and the result is the recurrence's
+        whether a run ends before, on or after a block's border."""
+        rng = np.random.default_rng(L)
+        rows, s0 = _rows(rng, L, g_lo, g_hi), _state(rng)
+        if zero:
+            s0 = jnp.zeros_like(s0)
+        o, s = kda_chunk_scan(*rows, s0, chunk=64)
+        assert bool(jnp.isfinite(o).all() & jnp.isfinite(s).all())
+        o_ref, s_ref = kda_recurrence_reference(*rows, s0)
+        np.testing.assert_allclose(o, o_ref, atol=5e-6)
+        np.testing.assert_allclose(s, s_ref, atol=5e-6)
+
+    @pytest.mark.parametrize("n", [0, 1, 15, 16, 17, 40, 63, 64, 65, 100])
+    def test_padding_rows_end_inside_a_block(self, n):
+        """A chunk of 128 rows in sub-chunks of 64 of which n are live:
+        the identity rows (g 0, beta 0) start inside a block of 16, on
+        its border, or fill whole sub-chunks."""
+        rng = np.random.default_rng(100 + n)
+        q, k, v, g, beta = _rows(rng, 128)
+        s0 = _state(rng)
+        valid = jnp.arange(128) < n
+        o, s = kda_chunk_scan(q, k, v, jnp.where(valid[:, None, None], g, 0),
+                              jnp.where(valid[:, None], beta, 0), s0,
+                              chunk=64)
+        o_ref, s_ref = kda_recurrence_reference(
+            q[:n], k[:n], v[:n], g[:n], beta[:n], s0)
+        np.testing.assert_allclose(s, s_ref, atol=5e-6)
+        if n:
+            np.testing.assert_allclose(o[:n], o_ref, atol=5e-6)
+
+    def test_the_gradient_is_the_recurrences(self):
+        """The eager model runs the scan under `jax.vjp`: the cotangents
+        of k and g (and of the state it starts from) are those of the
+        token-by-token recurrence."""
+        rng = np.random.default_rng(11)
+        (q, k, v, g, beta), s0 = _rows(rng, 40, -2.0, 0.0), _state(rng)
+        w_o = jnp.asarray(rng.normal(size=(40, H, V)), jnp.float32)
+        w_s = jnp.asarray(rng.normal(size=(H, K, V)), jnp.float32)
+
+        def through(scan):
+            def f(k, g, s0):
+                return scan(q, k, v, g, beta, s0)
+            out, pull = jax.vjp(f, k, g, s0)
+            return out, pull((w_o, w_s))
+
+        (o, s), got = through(
+            lambda *a: kda_chunk_scan(*a, chunk=16))
+        (o_ref, s_ref), want = through(kda_recurrence_reference)
+        np.testing.assert_allclose(o, o_ref, atol=5e-6)
+        for name, a, b in zip(("k", "g", "state"), got, want):
+            assert bool(jnp.isfinite(a).all()), name
+            np.testing.assert_allclose(a, b, atol=2e-5, err_msg=name)
+
+    def test_the_cell_s_chunk_lowers_to_no_loop(self):
+        """The sub-chunks of a chunk are known at trace time: at the
+        Ling cell's shapes (256 rows, 32 heads x 128 x 128, sub-chunks
+        of 64) the scan's lowered text holds no `while`."""
+        f32 = jnp.float32
+        row = jax.ShapeDtypeStruct((256, 32, 128), f32)
+        text = jax.jit(
+            lambda *a: kda_chunk_scan(*a, chunk=64)).lower(
+            row, row, row, row, jax.ShapeDtypeStruct((256, 32), f32),
+            jax.ShapeDtypeStruct((32, 128, 128), f32)).as_text()
+        assert "dot_general" in text
+        assert "while" not in text
 
     def test_chunk_then_update_is_one_sequence(self):
         """A chunk's last state put in a slot, then one decode step of

@@ -12,12 +12,13 @@ A pin moves when the TEXT moves, which is more often than the program:
 a PR that changes a kernel every step launches re-records every pin
 (PR 42, 45, 55: the ragged kernel; PR 48: the stored weight layout;
 PR 53: the per-layer kernels through `engine._once`; PR 57: Nemotron's
-alone, the Mamba-2 chunk scan). A PR whose claim is that the programs
+alone, the Mamba-2 chunk scan; PR 59: Ling's `unified` alone, the KDA
+chunk scan — its `unified_nochunk` holds no scan and held). A PR whose claim is that the programs
 did NOT change (PR 58: six step bodies became three) re-records none,
 or shows on the chip why a text moved and that the compiled program did
 not (ISSUE 58 says how). The nine `unified` hashes of llama, moe, mla,
 gpt, laguna, eva, looped, nemotron and ling are the ones PR 55 / PR 57
-recorded in the family files; the other fifteen were recorded at PR 58's
+/ PR 59 recorded; the other fifteen were recorded at PR 58's
 parent (404ac2e), before `engine.py` was touched.
 """
 
@@ -67,8 +68,9 @@ PINS = {
         "c78ea15ea6da800562dbe0f38d5247585e8bc0d212966c83174475fcc8ef8f42",
     ("nemotron", "unified"):
         "8189d8f0d7798726eac8d18b718860c3302d51c03f858aa993d1c236f4b12384",
+    # re-recorded by PR 59 (the KDA chunk scan's form)
     ("ling", "unified"):
-        "414cd126ad8267e4138a365f59bbc3bf74b0f0e6042597e91e364c86adfb9030",
+        "8face0118886ee17356ab663cecb146319e007bce153f63983f17f1e3bf2decb",
     # recorded at PR 58's parent (404ac2e), the engine untouched
     ("xing", "unified"):
         "65e1b60f81b73612ceceebcd7e535dd5c13df24a07917040643f90456cf252b8",

@@ -541,8 +541,11 @@ def test_delta_rule_kernels_compile_at_the_ling_cell_shapes(chip):
     384 slots + the spare x [32, 128, 128] float32 (a head's [key, value]
     tile, values along the lanes), the decode rows' update in place (the
     rows' [8, 128] operands turned to columns in the kernel), a 256-row
-    chunk's scan in four sub-chunks of 64 and its state's write in place
-    by the state-space pool's own kernel."""
+    chunk's scan in four sub-chunks of 64 (PR 59: the heads batch-major,
+    the sub-chunks a Python loop — no `while` — and none of the
+    elementwise Gram form's [64, 64, heads] float32 intermediates, whose
+    32 heads took 128 lanes) and its state's write in place by the
+    state-space pool's own kernel."""
     ns, h, d, c = 385, 32, 128, 256
     row, crow = chip.shape((ns, h, d), F32), chip.shape((c, h, d), F32)
     assert chip.compiles(
@@ -550,6 +553,9 @@ def test_delta_rule_kernels_compile_at_the_ling_cell_shapes(chip):
         chip.shape((ns + 2,), I32), row, row, row, row,
         chip.shape((ns, h, 1), F32), crow, crow, crow, crow,
         chip.shape((c, h), F32)), chip.refusals.get(_kda_layer_kernels)
+    text = chip.texts[_kda_layer_kernels]
+    assert " while(" not in text
+    assert not re.search(r"f32\[4,64,64,%d\]" % h, text)
 
 
 def _mhc_sublayer_kernels(x, phi_t, ab, y):

@@ -23,6 +23,36 @@ def load_text(module: str, name: str, path: str):
     return mod
 
 
+def device_events(run, args, calls: int = 3) -> dict:
+    """Device seconds a call of ``run(*args)`` by event kind (``stem
+    opcode shape``), from a `jax.profiler` trace of ``calls`` calls:
+    every device event but loops, conditionals and calls, which span
+    their bodies' events. Empty where the backend traces no device."""
+    import shutil
+    import tempfile
+    import jax
+    from benchmarks.lib.trace import base_name, find_xplane, load_xplane
+    where = tempfile.mkdtemp(prefix="device_events_")
+    try:
+        jax.profiler.start_trace(where)
+        for _ in range(calls):
+            jax.block_until_ready(run(*args))
+        jax.profiler.stop_trace()
+        path = find_xplane(where)
+        ops = load_xplane(path).device_ops if path else {}
+    finally:
+        shutil.rmtree(where, ignore_errors=True)
+    by_kind: dict = {}
+    for events in ops.values():
+        for e in events:
+            kind = base_name(e.name)        # "stem opcode shape"
+            opcode = (kind.split(" ") + ["?"])[1]
+            if opcode not in ("while", "conditional", "call"):
+                by_kind[kind] = by_kind.get(kind, 0.0) + e.end - e.start
+    n = max(len(ops), 1) * calls
+    return {kind: s / n for kind, s in by_kind.items()}
+
+
 def ready(out):
     """Wait until every array in ``out`` has been computed."""
     import jax

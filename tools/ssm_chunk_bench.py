@@ -41,22 +41,24 @@ from the token-by-token reference
 (`ops.references.ssm_recurrence_reference`).  It prints; it writes no
 file.  ``--rows 40 --chain 2 --repeats 1`` is the rehearsal here on the
 CPU (no device events there: the device's time says "not measured").
+CAVEAT (PR 59): every launch of the chain reads the SAME rows, so XLA
+computes what does not read the state (``C B^T``, the decays) ONCE a
+program and a launch reads too fast by that share;
+`tools/kda_chunk_bench.py` gives each launch its own rows.
 """
 
 from __future__ import annotations
 
 import argparse
 import os
-import shutil
 import sys
-import tempfile
 import time
 
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from bench_util import load_text  # noqa: E402
+from bench_util import device_events, load_text  # noqa: E402
 
 BF16_FLOPS, HBM_BYTES_PER_S = 197e12, 819e9   # one v5e chip
 #: heads, head width, groups, state, scan chunk
@@ -185,31 +187,10 @@ def kernel_scan(xdt, dA, bm, cm, state, *, chunk: int = 128,
 
 def device_seconds(run, args, calls: int = 3):
     """(device seconds a call, the largest event's stem and its seconds a
-    call) of ``run(*args)``, from a trace of ``calls`` calls: every
-    device event but loops, conditionals and calls, which span their
-    bodies' events."""
-    import jax
-    from benchmarks.lib.trace import base_name, find_xplane, load_xplane
-    where = tempfile.mkdtemp(prefix="ssm_chunk_bench_")
-    try:
-        jax.profiler.start_trace(where)
-        for _ in range(calls):
-            jax.block_until_ready(run(*args))
-        jax.profiler.stop_trace()
-        path = find_xplane(where)
-        ops = load_xplane(path).device_ops if path else {}
-    finally:
-        shutil.rmtree(where, ignore_errors=True)
-    by_kind = {}
-    for events in ops.values():
-        for e in events:
-            kind = base_name(e.name)        # "stem opcode shape"
-            opcode = (kind.split(" ") + ["?"])[1]
-            if opcode not in ("while", "conditional", "call"):
-                by_kind[kind] = by_kind.get(kind, 0.0) + e.end - e.start
-    n = max(len(ops), 1) * calls
+    call) of ``run(*args)``: `bench_util.device_events` added up."""
+    by_kind = device_events(run, args, calls)
     top = max(by_kind.items(), key=lambda kv: kv[1], default=("-", 0.0))
-    return sum(by_kind.values()) / n, top[0], top[1] / n
+    return sum(by_kind.values()), top[0], top[1]
 
 
 def main(argv=None) -> int:
