@@ -122,6 +122,18 @@ def _filter_logits(logits, top_k, top_p, temperature):
     return logits
 
 
+def _refuse_block_diffusion(model, what: str) -> None:
+    """A model that generates by diffusion over blocks (`models.sdar`:
+    its config has a ``block_length``) is never decoded one causal
+    token at a time: `serving.ServingEngine` runs its rule."""
+    block = getattr(getattr(model, "config", None), "block_length", None)
+    if block is not None:
+        raise NotImplementedError(
+            f"{what}: this model generates by diffusion over blocks of "
+            f"{block} under a block-causal mask; one causal token a step "
+            f"is another model. serving.ServingEngine runs its rule")
+
+
 def generate(model, input_ids, max_new_tokens: int = 20,
              decode_strategy: str = "sampling", top_k: Optional[int] = None,
              top_p: Optional[float] = None, temperature: float = 1.0,
@@ -137,6 +149,7 @@ def generate(model, input_ids, max_new_tokens: int = 20,
     resilience.TimeoutResult whose .partial carries the (padded) tokens
     produced in time — a typed outcome, never an unbounded hang.
     """
+    _refuse_block_diffusion(model, "generate")
     if decode_strategy not in ("greedy_search", "sampling"):
         raise ValueError(f"decode_strategy {decode_strategy!r}: expected "
                          "'greedy_search' or 'sampling'")
@@ -409,9 +422,19 @@ def _moe_decode_params(model, weight_only_int8: bool = False,
     backbone, per-layer dense-or-routed FFN. ``weight_only_int8`` cuts
     the HBM weight reads (the expert stacks are the bulk of them) with
     ``algo`` — 'weight_only_int4' packs the 3-D expert stacks two
-    nibbles per byte for quarter-width reads — see _llama_decode_params."""
+    nibbles per byte for quarter-width reads — see _llama_decode_params.
+
+    SDARMoeForCausalLM (`models.sdar`) is this family with three
+    additions: each layer's ``q_norm`` / ``k_norm`` leaves (a per-head
+    RMSNorm of q and k before the rotary turn), routed layers that HOLD
+    every expert and say so (``held`` None, ``scale`` 1: the uncut
+    layer's numerics, and the routed layers' counts are taken), and rope
+    tables made by ``p["rope_fn"](positions)`` once the caller knows how
+    many it serves. That it generates by diffusion over blocks is read
+    from its ``cfg`` (``block_length``) by whoever generates."""
     inner = model.model
     cfg = model.config
+    sdar = hasattr(cfg, "block_length")
     layers = []
     moe_static = []
     for lyr in inner.layers:
@@ -421,10 +444,15 @@ def _moe_decode_params(model, weight_only_int8: bool = False,
             wq=a.q_proj.weight._data, wk=a.k_proj.weight._data,
             wv=a.v_proj.weight._data, wo=a.o_proj.weight._data,
             ln2=lyr.post_attention_layernorm.weight._data)
+        if sdar:
+            d.update(q_norm=a.q_norm.weight._data,
+                     k_norm=a.k_norm.weight._data)
         for k in ("wq", "wk", "wv", "wo"):
             _q8(d, k, weight_only_int8, algo)
         _heads_w(d, cfg.head_dim, "wq", "wk", "wv")
         mlp_w, mlp_st = _mlp_params(lyr, weight_only_int8, algo)
+        if sdar:
+            mlp_st.update(held=None, scale=1.0)
         d.update(mlp_w)
         layers.append(d)
         moe_static.append(mlp_st)
@@ -432,8 +460,12 @@ def _moe_decode_params(model, weight_only_int8: bool = False,
     p = dict(cfg=cfg, family="moe",
              embed=inner.embed_tokens.weight._data,
              layers=layers, norm=inner.norm.weight._data, head=head,
-             cos=inner.rope_cos._data, sin=inner.rope_sin._data,
              moe_static=tuple(moe_static))
+    if sdar:
+        p["rope_fn"] = lambda n: dict(zip(("cos", "sin"),
+                                          inner.rope_tables(n)))
+    else:
+        p.update(cos=inner.rope_cos._data, sin=inner.rope_sin._data)
     if weight_only_int8 and head is not None:
         _q8(p, "head", True, algo)
         p["head"] = None
@@ -817,6 +849,7 @@ def _decode_params(model, weight_only_int8: bool = False,
         from .models.nemotron_h import NemotronHModel
         from .models.ouro import OuroModel
         from .models.phi4flash import Phi4FlashModel
+        from .models.sdar import SDARMoeModel
         if isinstance(inner, (NemotronHModel, BailingHybridModel,
                               FalconH1Model, Phi4FlashModel)):
             if enabled:
@@ -851,7 +884,7 @@ def _decode_params(model, weight_only_int8: bool = False,
             return _laguna_decode_params(model)
         if isinstance(inner, DeepSeekV2Model):
             return _mla_decode_params(model, enabled, algo)
-        if isinstance(inner, MoEModel):
+        if isinstance(inner, (MoEModel, SDARMoeModel)):
             return _moe_decode_params(model, enabled, algo)
     return _llama_decode_params(model, weight_only_int8,
                                 weight_only_quant)
@@ -1407,6 +1440,7 @@ def generate_cached(model, input_ids, max_new_tokens: int = 20,
     holds for moe_dropless=True models; capacity-mode models get a
     warning (drops are a training-time regularizer).
     """
+    _refuse_block_diffusion(model, "generate_cached")
     if decode_strategy not in ("greedy_search", "sampling"):
         raise ValueError(f"decode_strategy {decode_strategy!r}: expected "
                          "'greedy_search' or 'sampling'")
@@ -1584,6 +1618,7 @@ def generate_compiled(model, input_ids, max_new_tokens: int = 20,
     budget before launch short-circuits to a TimeoutResult (partial
     None), and a launch that finishes past the budget returns a
     TimeoutResult whose .partial holds the full output."""
+    _refuse_block_diffusion(model, "generate_compiled")
     if decode_strategy not in ("greedy_search", "sampling"):
         raise ValueError(f"decode_strategy {decode_strategy!r}: expected "
                          "'greedy_search' or 'sampling'")

@@ -212,7 +212,7 @@ def render_flagship_table(d: Mapping[str, Any]) -> str:
 #: the ONE vocabulary, the same in every model family and in the trainer
 SCOPES = ("embed", "attn_norm", "qkv_proj", "cache_write", "attention",
           "attn_out", "ffn_norm", "ffn", "routed_ffn", "shared_expert",
-          "loop_norm", "head", "head_loss", "update")
+          "loop_norm", "head", "head_loss", "update", "qk_norm", "unmask")
 
 #: scopes that name a kernel in the device trace (a Pallas call's
 #: instruction takes its INNERMOST scope's name, and trace readers find

@@ -63,7 +63,8 @@ __all__ = ["TraceEvent", "RequestTrace", "TraceRecorder", "recorder",
            "slo_summary", "SLO_METRICS", "STEP_COUNTS", "STEPS_PER_SLOT",
            "STEP_COUNTS_BY_KIND", "STEP_COUNTS_MOE", "STEP_COUNTS_LATENT",
            "STEP_COUNTS_EVA", "STEP_COUNTS_LOOP", "STEP_COUNTS_SSM",
-           "STEP_COUNTS_MHC", "STEP_COUNTS_SHARED"]
+           "STEP_COUNTS_MHC", "STEP_COUNTS_SHARED",
+           "STEP_COUNTS_DIFFUSION"]
 
 _FLAG = _flags._registry["FLAGS_request_tracing"]
 
@@ -198,6 +199,19 @@ STEP_COUNTS_MHC: Tuple[str, ...] = (
 #: the launches of a step that fetch the full kind's pages, the owner's
 #: and every borrower's; `pages_visited.full` is what ONE of them visits
 STEP_COUNTS_SHARED: Tuple[str, ...] = ("shared_pool_readers",)
+#: ... and where the model generates by diffusion over blocks: of the
+#: launch the record retires, the slots whose rows were a DENOISE pass
+#: of their block (the transfer rule reads their logits) and a COMMIT
+#: pass (it writes the block's final K/V), the decode rows that were
+#: still masked going in (the rows whose logits the rule reads), the
+#: tokens its commits emitted, the cache tokens its sequences held, open
+#: blocks and the chunk included — what ONE layer's attention has to
+#: read (the first five add up over a record's launches), and the slots
+#: that held an open block
+STEP_COUNTS_DIFFUSION: Tuple[str, ...] = (
+    "diffusion_passes_denoise", "diffusion_passes_commit",
+    "diffusion_rows_masked", "diffusion_tokens_committed",
+    "diffusion_kv_tokens", "diffusion_blocks_open")
 #: step records kept for each slot of the request ring. A request lives
 #: through tens to hundreds of steps, and whoever reads a whole measured
 #: window from the records (`benchmarks/lib/program_spans.py`: 50-56 s

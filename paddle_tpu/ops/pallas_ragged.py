@@ -21,7 +21,14 @@ Causality is per sequence over its new tokens: local token t (0-based)
 attends KV positions 0 .. kv_lengths[i] - num_tokens[i] + t. A decode
 row (num_tokens=1) therefore sees its whole context; a prefill chunk is
 causal within the chunk and sees everything before it (shared-prefix
-pages included).
+pages included). With a `block` (static; generation by diffusion over
+blocks) the rule is BLOCK-causal: the token at position p attends
+positions 0 .. the END of p's block of `block` positions,
+``min(kv - 1, (p // block + 1) * block - 1)`` — the rows of a block see
+each other and everything before them; ``block`` 1 is the causal rule.
+The walk of the pages does not move: a block never crosses a page
+(``page_size % block == 0``, which the caller holds), so the page of a
+tile's last row is still the last page any of its rows sees.
 
 Blocking: the flat buffer is cut into static TILES of TQ tokens
 (`ragged_tile_tokens`: TQ*rep query rows of one KV head, a multiple of
@@ -383,7 +390,7 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
                    q_ref, k_hbm, v_hbm, o_ref,
                    kbuf, vbuf, acc_ref, m_ref, l_ref, ahead_ref, sem,
                    *, page_size, rep, tq, total_pages, scale, window,
-                   summary=False, tb=1, narrow=0):
+                   summary=False, tb=1, narrow=0, block=None):
     h = pl.program_id(0)
     t = pl.program_id(1)
     n_pairs = first_ref[pl.num_programs(1)]
@@ -478,9 +485,14 @@ def _ragged_kernel(ss_ref, nt_ref, kvl_ref, tab_ref,    # scalar prefetch
 
         def limit_of(tk):
             """Local token t of this sequence attends positions <= its
-            limit; the rows of other sequences attend nothing."""
-            return jnp.where((tk >= first_row) & (tk < first_row + nt),
-                             kvl_ref[i] - nt + (tk - first_row), -1)
+            limit (with a `block`: up to the end of its block); the rows
+            of other sequences attend nothing."""
+            mine = (tk >= first_row) & (tk < first_row + nt)
+            pos = kvl_ref[i] - nt + (tk - first_row)
+            if block is not None:
+                pos = jnp.minimum(kvl_ref[i] - 1,
+                                  (pos // block + 1) * block - 1)
+            return jnp.where(mine, pos, -1)
 
         limit = [limit_of(tk) for tk in tok]
         page0 = 0 if window is None else pseq_ref[pairs + pi]
@@ -656,6 +668,7 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
                            window: Optional[int] = None,
                            v_dim: Optional[int] = None,
                            summary_rows=None, scope: Optional[str] = None,
+                           block: Optional[int] = None,
                            _launch: bool = False):
     """q [T, H, D] flat new-token buffer; k/v_pages [KV, total_pages,
     page_size, D]; seq_start/num_tokens/kv_lengths [S] int32;
@@ -687,6 +700,13 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     has the operands it had; `None` compiles the program it did before
     the argument existed.
 
+    `block` (static) makes the rule block-causal: the query at position
+    i sees keys j <= the end of i's block of `block` positions (and <
+    its sequence's KV length). The caller holds ``page_size % block ==
+    0`` and gives every sequence whole blocks, so the work list is the
+    causal one. Not with a `window`. `block=None` compiles the program
+    it did before the argument existed.
+
     `scope` (static) names the launch in the compiled program (the
     caller's own `jax.named_scope`, said again: the launch is traced
     and lowered ONCE for equal shapes inside a jitted copy of this
@@ -708,6 +728,10 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
         raise ValueError("give v_pages, or v_dim for rows that hold K "
                          "and V together; not both, not neither")
     latent = v_pages is None
+    if block is not None and (window is not None or psz % block):
+        raise ValueError(
+            f"block {block}: a block-causal launch takes no window and "
+            f"pages of whole blocks (page_size {psz})")
     if not _launch:
         # one trace and one lowering of the launch (the heads' and the
         # tiles' chains are unrolled in it) for all the layers of a step
@@ -716,7 +740,8 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
         return _launch_jit(
             q, k_pages, v_pages, seq_start, num_tokens, kv_lengths,
             page_tables, scale=float(scale), window=window, v_dim=v_dim,
-            summary_rows=summary_rows, scope=scope)
+            summary_rows=summary_rows, scope=scope,
+            **({} if block is None else {"block": block}))
     tq = ragged_tile_tokens(T, rep, q.dtype)
     rows = tq * rep
     itemsize = k_pages.dtype.itemsize
@@ -742,6 +767,8 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
     static = dict(page_size=psz, rep=rep, tq=tq, total_pages=total,
                   scale=float(scale), window=window, tb=tb,
                   narrow=ragged_narrow_rows(rep, rows, q.dtype, tb))
+    if block is not None:
+        static["block"] = block
     if summary_rows is not None:
         kvl = jnp.concatenate([kvl, summary_rows.astype(jnp.int32)])
         static["summary"] = True
@@ -785,7 +812,8 @@ def ragged_paged_attention(q, k_pages, v_pages, seq_start, num_tokens,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("scale", "window", "v_dim", "scope"))
+                   static_argnames=("scale", "window", "v_dim", "scope",
+                                    "block"))
 def _launch_jit(*operands, **options):
     return ragged_paged_attention(*operands, _launch=True, **options)
 
@@ -835,7 +863,8 @@ def ragged_attention_reference(q, k_pages, v_pages, seq_start,
                                scale: Optional[float] = None,
                                window: Optional[int] = None,
                                v_dim: Optional[int] = None,
-                               summary_rows=None):
+                               summary_rows=None,
+                               block: Optional[int] = None):
     """Plain-XLA oracle with the same ragged semantics (full-softmax,
     gathered pages, jnp.repeat GQA — everything the kernel avoids)."""
     if v_pages is None:
@@ -861,6 +890,9 @@ def ragged_attention_reference(q, k_pages, v_pages, seq_start,
     rv = (t_idx[None, :] >= ss[:, None]) & \
         (t_idx[None, :] < (ss + nt)[:, None])             # [S, T]
     limit = (kvl - nt)[:, None] + (t_idx[None, :] - ss[:, None])
+    if block is not None:
+        limit = jnp.minimum(kvl[:, None] - 1,
+                            (limit // block + 1) * block - 1)
     pos = jnp.arange(Tk)
     mask = rv[:, None, :, None] & \
         (pos[None, None, None, :] <= limit[:, None, :, None])

@@ -9,7 +9,9 @@ pool immediately), and never retrace — one compile per (model-config,
 slot-count) pair, checked by the PT002-gated tests.
 
 The engine has ONE step program, the unified ragged step: ONE launch
-per step. Every decode slot's token and the oldest prefill request's
+per step. Every decode slot's rows — one token a decode row; 1 + k with
+speculative drafts; a BLOCK of `block_length` rows where the model
+generates by diffusion over blocks — and the oldest prefill request's
 chunk ride a single flat token buffer through ONE per-layer chain, the
 same at every width and on every backend: norm -> q / k / v
 projections -> `fused_rope_append` (MLA: `fused_append_rows`) ->
@@ -35,9 +37,9 @@ is neither 64 nor a multiple of 128) is refused at construction with a
 `ValueError` that names it; no constructor argument selects a program.
 
 A launch computes the rows it carries. The body is compiled at two row
-counts: `max_slots x (1 + spec_k) + prefill_chunk` flat rows, and the
-same tables' prefix of `max_slots x (1 + spec_k)` rows with no chunk
-part (`_step_programs`). `_unified_step` launches the second whenever no
+counts: `max_slots x R + prefill_chunk` flat rows (R = `_slot_rows`: 1 +
+spec_k, or the block length), and the same tables' prefix of `max_slots
+x R` rows with no chunk part (`_step_programs`). `_unified_step` launches the second whenever no
 prompt is being dispatched in the launch it builds — which the host
 knows a launch ahead, like every other table — so a decode-only launch
 pays for no idle chunk row: at a few hundred rows the layers' matmuls
@@ -61,6 +63,26 @@ work: results the host holds.
 Greedy decoding only: the exactness contract (engine tokens ==
 solo `generate_cached` tokens per request, the acceptance test) is a
 greedy property; sampling strategies belong to the batch APIs.
+
+Generation by diffusion over blocks (a model whose config has a
+`block_length`: `models.sdar`; no constructor argument selects it). A
+decode slot owns a block of B flat rows that see each other and
+everything before them (the ragged kernel's `block` rule); the prompt's
+whole blocks are prefilled under the same rule and its remainder opens
+the first block as GIVEN tokens. A block is fed for `denoising_steps`
+denoise passes — every pass writes the block's K/V again; on the device
+(`_unmask`, scope `unmask`) the B / S still-masked rows of the largest
+confidence take their argmax, and the block after the pass is the next
+launch's input through the same `feed` programs, so the one launch
+queued ahead survives — and ONE commit pass with the final tokens, after
+which its K/V is final and its tokens are emitted: `Request.tokens`
+grows by up to B at a commit, and only then. The schedule is static
+(`models.sdar.block_passes`), so the host knows a launch ahead which
+pass each slot is in; an EOS is seen a launch late, like any other. Its
+exactness contract is the reference rule's (`benchmarks/lib/
+reference_sdar.py`): every pass's logits, the rule on them, the tokens
+committed. Drafting, the prefix cache, live-donor sharing, hand-off and
+preemption are refused at construction by name.
 """
 
 from __future__ import annotations
@@ -88,6 +110,7 @@ from ..ops.fused import (append_run_count, append_run_table,
                          fused_rope_append)
 from ..models.bailing_hybrid import kda_gated_norm, kda_operands
 from ..models.phi4flash import diff_combine, pair_queries, ssm1_operands
+from ..models.sdar import block_passes
 from ..models.nemotron_h import (ssm_conv, ssm_gated_norm, ssm_operands,
                                  ssm_split)
 from ..models.ouro import exit_distribution as _exit_distribution
@@ -131,6 +154,11 @@ _REBUILDS = _obs.registry().counter(
     "serving.controller.rebuilds",
     "jit program rebuilds triggered by chunk/spec-k actuation "
     "(ServingEngine.reconfigure)", labels=("replica",))
+_DIFF_PASSES = _obs.registry().counter(
+    "serving.engine.diffusion_passes",
+    "passes of a block of a model that generates by diffusion over "
+    "blocks, a slot a launch: denoise (the rule reads its logits) or "
+    "commit (it writes the block's final K/V)", labels=("kind",))
 _PREEMPTIONS = _obs.registry().counter(
     "serving.engine.preemptions",
     "low-priority decodes re-queued (pages intact) for a higher-"
@@ -320,6 +348,31 @@ def _greedy(logits):
     return jnp.argmax(logits, -1).astype(jnp.int32)
 
 
+def _unmask(block, logits, take, mask_id: int):
+    """The transfer rule of generation by diffusion over blocks
+    (``low_confidence_static``), on the device: `block` [M, B] the
+    slots' blocks as this launch was fed them (`mask_id` where a row is
+    still masked), `logits` [M * B, V] float32 of their rows, `take` [M]
+    how many rows a slot's pass unmasks (0: a commit pass, or an idle
+    slot). ``x0 = argmax(logits)``, ``c = max softmax(logits)``; among
+    a slot's masked rows the `take` with the largest c — all that are
+    left, if fewer; ties to the lower position — take their x0; no other
+    row moves. -> the blocks after the pass [M, B]: the next pass's
+    input, which never leaves the device (the `feed` programs)."""
+    M, B = block.shape
+    x0 = _greedy(logits).reshape(M, B)
+    top = jnp.max(logits, -1, keepdims=True)
+    conf = (1.0 / jnp.sum(jnp.exp(logits - top), -1)).reshape(M, B)
+    masked = block == mask_id
+    score = jnp.where(masked, conf, -1.0)       # c > 0 where masked
+    at = jnp.arange(B)
+    # rows that go before row i: a larger c, or the same at a lower place
+    before = (score[:, None, :] > score[:, :, None]) | (
+        (score[:, None, :] == score[:, :, None]) & (at[None, :] < at[:, None]))
+    chosen = masked & (before.sum(-1) < take[:, None])
+    return jnp.where(chosen, x0, block)
+
+
 def _halves_rope(c, s):
     """The rotary turn of t [1, T, h, dr] by the rows' angles c, s [T,
     dr / 2], pairing (j, j + dr / 2): it runs on the split q_pe / k_pe
@@ -395,7 +448,7 @@ def _latent_mixer(L, h, rope, pool, seq_start, num_tokens, kv_lengths,
 def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
                runs, *, heads: int, kv: int, d: int, mults=None,
                window=None, diff=None, borrowed: bool = False,
-               shared: bool = False, eps: float = 1e-5):
+               shared: bool = False, eps: float = 1e-5, block=None):
     """Grouped-query attention on the normed rows h [1, T, H] of a
     block's input, over the pages `pools` = (K, V): q / k / v (+ their
     biases, where the layer has them; ONE fused ``wqkv`` + ``bqkv``
@@ -407,7 +460,12 @@ def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
     -> `ragged_paged_attention` -> where the layer has a head gate
     (``wgate``, Laguna) each head's output times one sigmoid scalar of
     the normed input -> o-proj through `_mm_w` (the weight-only int8 /
-    int4 layouts) + its bias. `mults` (Falcon-H1): the
+    int4 layouts) + its bias. Where the layer has ``q_norm`` / ``k_norm``
+    leaves (SDAR) each head's q and k are RMS-normalised over the head's
+    `d` dims, one gain vector for all heads, BEFORE the rotary turn
+    (`qk_norm`). `block`: the launch is block-causal — a row sees the
+    keys up to the end of its block of `block` positions (generation by
+    diffusion over blocks). `mults` (Falcon-H1): the
     input's, the key's and the output's static multipliers. `window`:
     the layer's sliding window (its `tables` and `runs` are then the
     window kind's). -> (the mixer's output y [1, T, H], the pools).
@@ -452,6 +510,11 @@ def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
         q = q.reshape(T, heads, dq)
         if diff is not None:
             q = pair_queries(q)
+    if "q_norm" in L:
+        with _scope("qk_norm"):
+            q = _once(fused_rms_norm, "qk_norm", q, L["q_norm"], eps=eps)
+            k = _once(fused_rms_norm, "qk_norm", k.reshape(T, kv, d),
+                      L["k_norm"], eps=eps)
     if not borrowed:
         with _scope("cache_write"):
             q, kp, vp = _once(
@@ -462,7 +525,8 @@ def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
     with jax.named_scope(name):
         o = ragged_paged_attention(
             q, kp, vp, seq_start, num_tokens, kv_lengths, tables,
-            scale=dq ** -0.5, window=window, scope=name)
+            scale=dq ** -0.5, window=window, scope=name,
+            **({"block": block} if block else {}))
     if diff is not None:
         with jax.named_scope("diff_combine"):
             o = diff_combine(o, L, diff, eps).astype(h.dtype)
@@ -561,6 +625,11 @@ class _Chain(NamedTuple):
     split: Callable
     join: Callable
     moe_counts: bool                # the routed layers' counts are taken
+    #: generation by diffusion over blocks: (block length, the mask
+    #: token's id) — a decode slot's rows are a block that sees itself,
+    #: and `kv_lengths` is a pair, like a hybrid's: the lengths and, a
+    #: slot, the rows the launch's pass unmasks
+    block: Optional[Tuple[int, int]]
     ends_scoped: bool               # the rotary rows are made inside
     #                                 `embed`, the counts inside `head`
     head_once: bool                 # the last norm goes through `_once`
@@ -577,6 +646,7 @@ def _chain_of(p, attn_static, layer_kind, pool_readers, kv_geom,
     where they are differential, the padded latent row)."""
     cfg, family, mu = p["cfg"], p["family"], p.get("mults")
     hybrid, gpt = family == "hybrid", family == "gpt"
+    block = getattr(cfg, "block_length", None)
     eps = cfg.layer_norm_epsilon if hybrid else cfg.layer_norm_eps \
         if gpt else cfg.rms_norm_eps
     if hybrid:
@@ -596,6 +666,8 @@ def _chain_of(p, attn_static, layer_kind, pool_readers, kv_geom,
                    st=st))]
     # each attention mixer's static keywords
     gqa = dict(kv=kv_geom[0], d=kv_geom[1], mults=mu, eps=eps)
+    if block:
+        gqa["block"] = block
     diff = p.get("diff", {})
     owned = iter(zip(layer_kind, attn_static, pool_readers))
     for i, b in enumerate(blocks):
@@ -627,10 +699,12 @@ def _chain_of(p, attn_static, layer_kind, pool_readers, kv_geom,
         rope_tables=tables,
         no_turn=(kv_geom[1], gpt) if gqa_blocks and not tables else None,
         split=(lambda pools, kvl: (pools["kv"], pools["ssm"], *kvl))
-        if hybrid else (lambda pools, kvl: (pools, (), kvl, None)),
+        if hybrid else (lambda pools, kvl: (pools, (), *kvl)) if block
+        else (lambda pools, kvl: (pools, (), kvl, None)),
         join=(lambda kv, ssm: {"kv": kv, "ssm": ssm}) if hybrid
         else (lambda kv, ssm: kv),
         moe_counts=hybrid or moe_counts,
+        block=(block, cfg.mask_token_id) if block else None,
         ends_scoped=not (hybrid or gpt),
         head_once=family not in ("hybrid", "gpt", "mla"))
 
@@ -737,7 +811,10 @@ class _Launch:
         self.preq = preq        # the request whose chunk rides it, or None
         self.n = n              # that chunk's rows
         self.rows = rows        # [(slot, request)] of its decode rows
-        self.drafts = drafts    # {slot: drafted tokens} (spec decoding)
+        self.drafts = drafts    # {slot: drafted tokens} (spec decoding);
+        #                         a block family: {slot: (the pass of its
+        #                         block these rows are, the block's passes,
+        #                         its given tokens)}
         #: {id(request): row of `tokens`} of every request whose NEXT
         #: input token this launch produces: its decode rows, and the
         #: chunk's row when the chunk ends its prompt
@@ -756,7 +833,7 @@ _ADDITIVE = frozenset(
     + _tracing.STEP_COUNTS_BY_KIND[:4]
     + _tracing.STEP_COUNTS_EVA[:4] + _tracing.STEP_COUNTS_EVA[-1:]
     + ("ssm_state_bytes_moved", "ssm_scan_rows", "ssm_state_resets")
-    + _tracing.STEP_COUNTS_MHC[:1])
+    + _tracing.STEP_COUNTS_MHC[:1] + _tracing.STEP_COUNTS_DIFFUSION[:5])
 
 
 class ServingEngine:
@@ -951,6 +1028,33 @@ class ServingEngine:
             # neither is a preempted sequence's state kept once its slot
             # is handed on (ROADMAP R4 b): the engine never preempts
             enable_prefix_cache = prefix_sharing = preemption = False
+        # generation by diffusion over blocks: a decode slot owns a
+        # BLOCK of `block_length` flat rows that see each other, fed for
+        # `denoising_steps` denoise passes and one commit pass; the
+        # model's config says so, never a switch. The block's K/V is
+        # written every pass and final only after the commit pass
+        self._block = int(getattr(cfg, "block_length", 0) or 0)
+        if self._block:
+            self._diff_steps = int(cfg.denoising_steps)
+            self._mask_id = int(cfg.mask_token_id)
+            _refuse_shared_cache(
+                f"this model generates by diffusion over blocks of "
+                f"{self._block} rows whose K/V is rewritten every pass "
+                f"and final only after a commit pass: a page whose last "
+                f"block is being rewritten cannot be shared or handed on, "
+                f"and a block is not a draft, so ", enable_prefix_cache,
+                spec_decode, role)
+            for what, v in (("page_size", self.page_size),
+                            ("prefill_chunk", self.prefill_chunk)):
+                if v % self._block:
+                    raise ValueError(
+                        f"{what} {v} must be whole blocks of "
+                        f"{self._block} (block_length): a block never "
+                        f"crosses a page, and a prompt is prefilled in "
+                        f"whole blocks")
+            # committed pages could be shared, a request handed on or
+            # preempted at a block border: ROADMAP
+            enable_prefix_cache = prefix_sharing = preemption = False
         if self._eva:
             _refuse_shared_cache(
                 "this model's layers are chunk-summary attention (pooled "
@@ -1130,6 +1234,12 @@ class ServingEngine:
         #: the host copy of the row each emitted token was sampled from
         #: (a check against a reference in logits); None costs nothing
         self.on_logits = None
+        #: a block family's twin: a callable (request, pass index, the
+        #: block's passes, the block's tokens going in [B], its logits
+        #: rows [B, vocab] float32, the block after the pass [B]) called
+        #: as each pass of a block RETIRES; `on_logits` is not called for
+        #: such a family (a token is not sampled from one row)
+        self.on_block = None
         # the open step's counts, taken where the work happens and
         # closed into the recorder's step record (tracing.STEP_COUNTS)
         self._count_names = _tracing.STEP_COUNTS
@@ -1149,6 +1259,8 @@ class ServingEngine:
             self._count_names += _tracing.STEP_COUNTS_SHARED
         if self._hc > 1:
             self._count_names += _tracing.STEP_COUNTS_MHC
+        if self._block:
+            self._count_names += _tracing.STEP_COUNTS_DIFFUSION
         #: the counts a launch takes on the device and returns beside
         #: its logits, in the order of the one array they come in
         self._device_count_names = (
@@ -1281,9 +1393,22 @@ class ServingEngine:
         `feed_nochunk`)."""
         return {"": self.prefill_chunk, "_nochunk": 0}
 
+    @property
+    def _slot_rows(self) -> int:
+        """Flat rows a decode slot owns in a launch: its token and its
+        drafts, or — generation by diffusion over blocks — its block."""
+        return self._block or 1 + self.spec_k
+
     def _launch_rows(self, chunk: int) -> int:
         """Flat rows of a launch whose chunk part is `chunk` rows."""
-        return self.max_slots * (1 + self.spec_k) + chunk
+        return self.max_slots * self._slot_rows + chunk
+
+    def _prompt_rows(self, req: Request) -> int:
+        """Prompt tokens of `req` that are PREFILLED: all of them, or —
+        a block family — its whole blocks (the remainder opens the
+        first block as given tokens)."""
+        n = int(req.prompt.size)
+        return n - n % self._block if self._block else n
 
     def _attn_tiling(self, T: int) -> Dict[str, Dict[int, int]]:
         """The KV heads and the query tiles one page visit of the ragged
@@ -1338,6 +1463,7 @@ class ServingEngine:
         # device. Compiled and run ONCE here, on the tokens of no launch
         self._no_tokens = jax.jit(lambda: jnp.zeros(
             self._launch_rows(self.prefill_chunk) if self.spec_k
+            else self._launch_rows(0) if self._block
             else self.max_slots + 1, jnp.int32))()
         for sfx, chunk in self._chunk_parts().items():
             T = self._launch_rows(chunk)
@@ -1360,6 +1486,11 @@ class ServingEngine:
             donate_argnums=0)
         if self.prefix_sharing or self.prefix_cache is not None:
             self._copy_pages(*np.zeros((2, self._copy_slots), np.int32))
+        # a block's logits rows for `on_block`: [B, vocab] of [slots x B,
+        # vocab], the slot a traced argument (one compile, at first use)
+        self._jit_block_rows = jax.jit(
+            lambda logits, r0: jax.lax.dynamic_slice_in_dim(
+                logits, r0, self._block, 0)) if self._block else None
 
     @property
     def _jit_unified(self):
@@ -1457,6 +1588,11 @@ class ServingEngine:
             raise ValueError(
                 "a model with state-space blocks cannot roll a rejected "
                 "draft's state back: spec_decode stays 0")
+        if self._block and (new_k or new_chunk % self._block):
+            raise ValueError(
+                f"a model that generates by diffusion over blocks takes "
+                f"prefill chunks of whole blocks of {self._block}, and "
+                f"spec_decode stays 0")
         if self._eva and (new_k or new_chunk
                           % self._p["cfg"].chunk_size):
             raise ValueError(
@@ -1492,11 +1628,14 @@ class ServingEngine:
                       deadline_s=(deadline_s if deadline_s is not None
                                   else self._default_deadline_s),
                       request_id=request_id,
-                      priority=priority, tenant=tenant)
+                      priority=priority, tenant=tenant,
+                      block=self._block or 1)
         if req.total_tokens > self.max_context:
             raise ValueError(
                 f"prompt+max_new_tokens = {req.total_tokens} exceeds "
-                f"max_context {self.max_context}")
+                f"max_context {self.max_context}"
+                + (f" (whole blocks of {self._block})" if self._block
+                   else ""))
         try:
             self.scheduler.submit(req)
         except _res.Shed:
@@ -1943,6 +2082,10 @@ class ServingEngine:
         return handoff
 
     def _no_handoff(self, what: str) -> None:
+        if self._block:
+            raise NotImplementedError(
+                f"{what}: this model generates by diffusion over blocks; "
+                f"a handoff at a block border is not implemented")
         if self._ssm_layers:
             raise NotImplementedError(
                 f"{what}: this model has state-space blocks; a handoff of "
@@ -2057,7 +2200,10 @@ class ServingEngine:
                              else "prefix_share",
                              tokens=req.shared_tokens,
                              **req._share_meta)
-            self._prefill_fifo.append(req)
+            if self._prompt_rows(req):
+                self._prefill_fifo.append(req)
+            else:       # a prompt shorter than a block: all given tokens
+                req.state = DECODE
             admitted += 1
         return admitted
 
@@ -2241,9 +2387,17 @@ class ServingEngine:
                     *(jax.tree_util.tree_map(jnp.asarray, t)
                       for t in host[1:]))
                 row_of = {id(req): slot for slot, req, _ in rows}
+                if self._block:
+                    # a slot's NEXT input is its block after this pass,
+                    # rows [slot x B, ..) of the launch's tokens — or the
+                    # host's own (-1): a new block, all given and masks
+                    row_of = {id(req): slot * self._block
+                              if req.block_pass else -1
+                              for slot, req, _ in rows}
                 if preq is not None:
-                    if start + n == int(preq.prompt.size):
-                        row_of[id(preq)] = self.max_slots
+                    if start + n == self._prompt_rows(preq):
+                        row_of[id(preq)] = -1 if self._block \
+                            else self.max_slots
                     _TRACE.stamp(preq.request_id, "prefill_chunk",
                                  tokens=n, start=start)
                 new = self._inflight = _Launch(
@@ -2279,7 +2433,7 @@ class ServingEngine:
         fifo = self._prefill_fifo
         while fifo and (fifo[0].state != PREFILL
                         or self._sent_pos(fifo[0], fl)
-                        >= int(fifo[0].prompt.size)):
+                        >= self._prompt_rows(fifo[0])):
             fifo.pop(0)
         return fifo[0] if fifo else None
 
@@ -2288,16 +2442,30 @@ class ServingEngine:
         every request in a slot whose prompt is dispatched in full and
         that has a token left to make. `src` is the row of the launch
         in flight `fl` that produces the request's input token, None
-        when the host holds it (`Request.pending`)."""
+        when the host holds it (`Request.pending`). A block family: every
+        request with a block left to dispatch — the host knows how many
+        its prompt and budget make (`_blocks_of`) — and `src` the first
+        row of its block after the pass in flight, None where the host
+        holds the block (`Request.block_tokens`) or opens a new one."""
         rows = []
         for slot, req in self.scheduler.active():
             src = fl.row_of.get(id(req)) if fl is not None else None
             if req.state != DECODE and (src is None
                                         or self.role == "prefill"):
                 continue    # mid-prompt, or staged for export next
-            if len(req.tokens) + (src is not None) < req.max_new_tokens:
+            if self._block:
+                if req.blocks_sent < self._blocks_of(req):
+                    rows.append((slot, req, None if src is None or src < 0
+                                 else src))
+            elif len(req.tokens) + (src is not None) < req.max_new_tokens:
                 rows.append((slot, req, src))
         return rows
+
+    def _blocks_of(self, req: Request) -> int:
+        """Blocks a request generates: its given tokens (the prompt's
+        remainder) and its budget in whole blocks; the last one is cut."""
+        B = self._block
+        return -(-(int(req.prompt.size) % B + req.max_new_tokens) // B)
 
     def _build_unified(self, preq: Optional[Request], rows,
                        fl: Optional[_Launch], chunk: int):
@@ -2321,7 +2489,7 @@ class ServingEngine:
         tokens lie and where its pooled row goes), (`tok_off`, [2, P]:
         the chunk within that page, the row within that one)."""
         B, C, K = self.max_slots, chunk, self.spec_k
-        R = 1 + K
+        R, blk = self._slot_rows, self._block
         base = B * R
         T, S = base + C, B + 1
         tiling = self._attn_tiling(T)   # the kernel's, at THIS row count
@@ -2378,7 +2546,39 @@ class ServingEngine:
                 wtok_page[rows] = wt[pos // ps]
 
         drafts: Dict[int, List[int]] = {}
+        if blk:
+            # rows a slot's pass unmasks (0: a commit pass, an idle slot)
+            take = np.zeros(B, np.int32)
+            diff = dict.fromkeys(_tracing.STEP_COUNTS_DIFFUSION[:3], 0)
         for slot, req, feed in rows:
+            if blk:
+                # pass `p` of the request's open block, of `total`: the
+                # schedule is static, so the host knows it a launch ahead
+                g = int(req.prompt.size) % blk if not req.blocks_sent else 0
+                p, total = req.block_pass, block_passes(
+                    blk, self._diff_steps, g)
+                r0, commit = slot * R, p == total - 1
+                if p == 0:
+                    # the block's rows join the sequence, and stay
+                    self.allocator.extend(req.request_id, blk)
+                    tok[r0:r0 + blk] = self._fresh_block(req, g)
+                elif feed is None:
+                    tok[r0:r0 + blk] = req.block_tokens
+                else:       # the block is still on the device
+                    src[r0:r0 + blk] = feed + np.arange(blk)
+                per = blk // self._diff_steps
+                take[slot] = 0 if commit else per
+                ln = self.allocator.seq_length(req.request_id)
+                place(req.request_id, slot, r0, ln - blk + np.arange(blk),
+                      slot)
+                drafts[slot] = (p, total, g)
+                req.block_pass = 0 if commit else p + 1
+                req.blocks_sent += commit
+                diff["diffusion_passes_commit" if commit
+                     else "diffusion_passes_denoise"] += 1
+                diff["diffusion_rows_masked"] += \
+                    0 if commit else blk - g - p * per
+                continue
             ln = self.allocator.seq_length(req.request_id)
             d: List[int] = []
             if K:
@@ -2404,7 +2604,7 @@ class ServingEngine:
         n, start = 0, 0
         if preq is not None:
             start = self._sent_pos(preq, fl)
-            n = min(C, int(preq.prompt.size) - start)
+            n = min(C, self._prompt_rows(preq) - start)
             if eva:     # a chunk may not straddle a window
                 n = min(n, self.allocator.span
                         - start % self.allocator.span)
@@ -2414,6 +2614,9 @@ class ServingEngine:
             place(preq.request_id, S - 1, base, start + np.arange(n), B)
         counts = {"decode_rows": int(num_tokens[:B].sum()),
                   "prefill_rows": n, "rows_computed": T}
+        if blk:
+            counts.update(diff, diffusion_blocks_open=len(rows),
+                          diffusion_kv_tokens=int(kv_lengths.sum()))
         seq_start = np.append(np.arange(B) * R, base)
         if self._latent:
             counts["chunk_kv_len"] = int(kv_lengths[S - 1])
@@ -2490,7 +2693,8 @@ class ServingEngine:
                 * self._ssm_layers * self._ssm_state_bytes,
                 "ssm_scan_rows": n, "ssm_state_resets": starts})
         # what the step takes as `kv_lengths`: with a state table beside
-        kvl = (kv_lengths, tab) if self._ssm_layers else kv_lengths
+        kvl = (kv_lengths, tab) if self._ssm_layers \
+            else (kv_lengths, take) if blk else kv_lengths
         if eva:
             seen = num_tokens > 0
             ends = positions[(seq_start + num_tokens - 1)[seen]] + 1
@@ -2532,6 +2736,12 @@ class ServingEngine:
                  (tables, wtables), (tok_page, wtok_page), tok_off),
                 src, drafts, n, start, counts)
 
+    def _fresh_block(self, req: Request, given: int) -> List[int]:
+        """A block as it is opened: its given tokens (the prompt's last
+        `given`), then the mask token."""
+        return [int(t) for t in req.prompt[int(req.prompt.size) - given:]] \
+            + [self._mask_id] * (self._block - given)
+
     def _await_launch(self, fl: _Launch):
         """The wait for launch `fl` and the copy back: its greedy tokens
         ([S] int32; [T] with drafts), the routed layers' counts, and
@@ -2539,7 +2749,11 @@ class ServingEngine:
         (`on_logits`) — from the same launch of the same program."""
         tokens = np.asarray(fl.tokens)
         logits = np.asarray(fl.logits) if self.on_logits is not None \
-            else None
+            and not self._block else None
+        if self._block and self.on_block is not None:
+            # a live slot's rows only: [slots x B, vocab] is 0.3 GB
+            logits = {slot: np.asarray(self._jit_block_rows(
+                fl.logits, slot * self._block)) for slot, _ in fl.rows}
         if fl.moe is not None and self._family == "looped":
             # the mean exit distribution of the rows a request owned
             fl.counts["ut_exit_mass"] = tuple(
@@ -2562,7 +2776,7 @@ class ServingEngine:
         if fl is self._inflight:
             self._inflight = None
         B, K = self.max_slots, self.spec_k
-        R = 1 + K
+        R = self._slot_rows
         base = B * R
         done = self._retired
         counts = fl.counts
@@ -2575,25 +2789,36 @@ class ServingEngine:
         if preq is not None:
             preq.prefill_pos += n
             done["prefill_tokens"] += n
-            if preq.prefill_pos == int(preq.prompt.size):
+            if preq.prefill_pos == self._prompt_rows(preq):
                 preq.state = DECODE
-                # cache the full prompt pages BEFORE _emit can finish
-                # the request and return its pages — trie pins keep
-                # them warm for the next tenant
-                if self.prefix_cache is not None \
-                        and self.prefix_cache_admit:
-                    self.prefix_cache.insert(
-                        preq.prompt,
-                        self.allocator.seq_pages(preq.request_id))
-                i = base + n - 1 if K else B
-                fin = self._emit(preq, int(tokens[i]), row(i))
-                done["finished"] += fin
-                if not fin and self.role == "prefill":
-                    self._stage_handoff(preq)
+                # (a block family: no token comes of a prompt's last
+                # chunk — its logits are not read; its first block is
+                # next — and no page of it is shared)
+                if not self._block:
+                    # cache the full prompt pages BEFORE _emit can finish
+                    # the request and return its pages — trie pins keep
+                    # them warm for the next tenant
+                    if self.prefix_cache is not None \
+                            and self.prefix_cache_admit:
+                        self.prefix_cache.insert(
+                            preq.prompt,
+                            self.allocator.seq_pages(preq.request_id))
+                    i = base + n - 1 if K else B
+                    fin = self._emit(preq, int(tokens[i]), row(i))
+                    done["finished"] += fin
+                    if not fin and self.role == "prefill":
+                        self._stage_handoff(preq)
         decoded = 0
         for slot, req in fl.rows:
             if req.state != DECODE:
-                counts["rows_dropped"] += 1
+                counts["rows_dropped"] += self._block or 1
+                continue
+            if self._block:
+                n_out, fin = self._retire_block(
+                    req, tokens[slot * R:(slot + 1) * R], *fl.drafts[slot],
+                    None if logits is None else logits[slot])
+                decoded += n_out
+                done["finished"] += fin
                 continue
             d = fl.drafts[slot]
             r0 = slot * R
@@ -2621,6 +2846,12 @@ class ServingEngine:
             self.spec_accepted += m
             _TRACE.stamp(req.request_id, "verify_accept",
                          drafted=len(d), accepted=m)
+        if self._block:
+            counts["diffusion_tokens_committed"] = decoded
+            if _obs.enabled():
+                for kind in ("denoise", "commit"):
+                    _DIFF_PASSES.labels(kind=kind).inc(
+                        counts["diffusion_passes_" + kind])
         done["decoded"] += decoded
         if _obs.enabled() and decoded:
             _TOKENS.labels(phase="decode").inc(decoded)
@@ -2630,6 +2861,34 @@ class ServingEngine:
         for k, v in counts.items():
             self._retired_counts[k] = v + self._retired_counts.get(k, 0) \
                 if k in _ADDITIVE else v
+
+    def _retire_block(self, req: Request, after: np.ndarray, p: int,
+                      total: int, given: int, logits) -> Tuple[int, int]:
+        """Pass `p` of `total` of the request's open block has retired
+        with the block `after` it [B] (what the launch fed on, on the
+        device). The host keeps the block; a COMMIT pass — the block's
+        K/V is final — emits its tokens past the `given` ones, up to the
+        request's budget or an EOS, and counts the given ones as
+        prefilled. -> (tokens emitted, 1 if the request finished)."""
+        before = self._fresh_block(req, given) if p == 0 \
+            else req.block_tokens
+        req.block_tokens = [int(t) for t in after]
+        if self.on_block is not None:
+            self.on_block(req, p, total, np.asarray(before, np.int32),
+                          logits, np.asarray(after, np.int32))
+        if p < total - 1:
+            return 0, 0
+        req.prefill_pos += given
+        out = req.block_tokens[given:][:req.max_new_tokens
+                                       - len(req.tokens)]
+        if req.eos_token_id in out:
+            out = out[:out.index(req.eos_token_id) + 1]
+        _TRACE.stamp(req.request_id, "block_commit", tokens=len(out),
+                     passes=total)
+        fin = 0
+        for t in out:
+            fin = self._emit(req, t)
+        return len(out), fin
 
     def _emit(self, req: Request, tok: int,
               row: Optional[np.ndarray] = None) -> int:
@@ -2727,7 +2986,7 @@ class ServingEngine:
         a launch of the tables' row count can make: every decode row
         its own, the chunk's one for each tile it touches."""
         tile = self._append_tile
-        base = self.max_slots * (1 + self.spec_k)
+        base = self.max_slots * self._slot_rows
 
         def run_table(num_tokens, tok_page, tok_off):
             chunk = tok_page.shape[0] - base
@@ -3241,7 +3500,7 @@ class ServingEngine:
         d, cfg = self._chain, self._p["cfg"]
         eps, mu = d.eps, d.mults
         B, K = self.max_slots, self.spec_k
-        R = 1 + K
+        R = self._slot_rows
         T = B * R + C
         seq_start = _seq_starts(B, R)
         run_table = self._run_table(seq_start)
@@ -3340,11 +3599,24 @@ class ServingEngine:
             x = res.exit(x)
             with _scope("head"):
                 x = norm(x, "head", w, d.head_norm, d.head_once)
-                logits = _head_logits(
-                    w, _logit_rows(x, seq_start, num_tokens, K))
-                if mu:
-                    logits = logits * mu["lm_head"]
-                tokens = _greedy(logits)
+                if d.block:
+                    # every block row's logits, in float32 (the rule
+                    # compares confidences); the chunk's rows have none:
+                    # no token comes of a prompt's last chunk
+                    logits = jnp.dot(
+                        x[0, :B * R], w["head"] if w["head"] is not None
+                        else w["embed"].T, preferred_element_type=f32)
+                else:
+                    logits = _head_logits(
+                        w, _logit_rows(x, seq_start, num_tokens, K))
+                    if mu:
+                        logits = logits * mu["lm_head"]
+                    tokens = _greedy(logits)
+            if d.block:
+                # the blocks after this pass: the next launch's input
+                with _scope("unmask"):
+                    tokens = _unmask(tok[:B * R].reshape(B, R), logits,
+                                     tab, d.block[1]).reshape(-1)
             out = logits, d.join(new_kv, new_ssm), tokens
             # the counts taken on the device ride beside the logits as
             # ONE array, in the order of `_device_count_names`

@@ -55,7 +55,8 @@ _ids = itertools.count()
 
 
 class Request:
-    """One generation request. `tokens` accumulates greedy output ids;
+    """One generation request. `tokens` accumulates greedy output ids
+    (a block at a time where the model generates by blocks);
     after FINISHED, `result` is an int32 array padded to max_new_tokens
     with pad_token_id (the generate_cached row convention), a falsy
     `resilience.TimeoutResult` carrying the partial tokens on a deadline
@@ -68,7 +69,8 @@ class Request:
                  deadline_s: Optional[float] = None,
                  request_id=None,
                  priority: int = 0,
-                 tenant: Optional[str] = None):
+                 tenant: Optional[str] = None,
+                 block: int = 1):
         self.prompt = np.asarray(prompt, np.int32).reshape(-1)
         if self.prompt.size < 1:
             raise ValueError("empty prompt")
@@ -90,6 +92,17 @@ class Request:
         self.priority = int(priority)        # higher = more urgent
         self.tenant = tenant                 # fair-share accounting key
         self.preempted = False               # mid-decode, pages intact
+        # generation by diffusion over blocks (`block` > 1 or a config
+        # that says so; 1: a token a step): `tokens` then holds COMMITTED
+        # tokens only and grows by up to `block` at a commit;
+        # `prefill_pos` counts the prompt tokens whose K/V is final (the
+        # prompt's remainder when its block commits). What the engine has
+        # DISPATCHED: the passes of the open block, the blocks whose
+        # commit pass went out; what it holds of the open block as RETIRED
+        self.block = int(block)
+        self.block_pass = 0
+        self.blocks_sent = 0
+        self.block_tokens: Optional[List[int]] = None
         self._seq: int = 0                   # submit order (set by submit)
         self._share_source = None            # "cache" | "donor" | None
         self._share_meta: dict = {}
@@ -98,7 +111,10 @@ class Request:
 
     @property
     def total_tokens(self) -> int:
-        return int(self.prompt.size) + self.max_new_tokens
+        """Cache rows the request can come to hold: prompt + budget, in
+        whole blocks where it generates by blocks."""
+        n = int(self.prompt.size) + self.max_new_tokens
+        return -(-n // self.block) * self.block
 
     def start_deadline(self) -> None:
         if self.deadline_s:

@@ -324,6 +324,11 @@ def _phi4flash():
     return seeded(max_position_embeddings=64)[0]
 
 
+def _sdar():
+    from test_sdar import seeded
+    return seeded(max_position_embeddings=64)[0]
+
+
 #: family -> names its unified step must show
 FAMILIES = {
     "llama": {"ffn"}, "moe": {"routed_ffn", "shared_expert"},
@@ -337,6 +342,9 @@ FAMILIES = {
     # blocks that own no memory beside blocks that do, LayerNorm, window
     # pages: every name of a layer (through SCOPE_ALIASES)
     "hybrid_borrowed": {"ffn"},
+    # generation by diffusion over blocks: the q / k norms and the
+    # transfer rule under names of their own
+    "block_diffusion": {"routed_ffn", "qk_norm", "unmask"},
 }
 EVERY_STEP = {"embed", "attn_norm", "qkv_proj", "cache_write", "attention",
               "attn_out", "ffn_norm", "head"}
@@ -348,7 +356,8 @@ def test_every_op_of_a_serving_step_answers_to_a_name(family):
         else _ouro() if family == "looped" \
         else _nemotron() if family == "hybrid" \
         else _falcon() if family == "hybrid_two_mixers" \
-        else _phi4flash() if family == "hybrid_borrowed" else _tiny(family)
+        else _phi4flash() if family == "hybrid_borrowed" \
+        else _sdar() if family == "block_diffusion" else _tiny(family)
     kw = dict(max_slots=3, page_size=8, max_context=256, prefill_chunk=8,
               num_pages=64) if family == "eva" else \
         dict(max_slots=2, page_size=8, max_context=64, prefill_chunk=8)
@@ -366,8 +375,8 @@ def test_every_op_of_a_serving_step_answers_to_a_name(family):
     assert all(n == 1 for n in eng.program_cache_sizes().values())
     # the step at each of its row counts, lowered at its own shapes
     for name, rows in (
-            ("unified", kw["max_slots"] + kw["prefill_chunk"]),
-            ("unified_nochunk", kw["max_slots"])):
+            ("unified", eng._launch_rows(kw["prefill_chunk"])),
+            ("unified_nochunk", eng._launch_rows(0))):
         (_, tok, *_), _ = programs[name].args_info
         assert tok.shape == (rows,), (name, tok)
         step = list(at.op_scopes({name: programs[name]}).values())

@@ -730,3 +730,79 @@ class TestRaggedJit:
         f(*args1)
         f(*args2)
         assert f._cache_size() == 1
+
+
+# ------------------------------------------------- the block-causal rule
+#: generation by diffusion over blocks: slots of B rows (their block is
+#: the last of their sequence), a chunk of whole blocks; pages of 8 hold
+#: whole blocks of 4 or 8
+_BLOCK_LAYOUTS = {
+    "a_chunk_across_a_page_border": lambda B: dict(
+        kv_dec=[0, 0], runs=[0, 0], chunk=5 * B, kv_chunk=2 * B + 5 * B),
+    "slots_of_a_block": lambda B: dict(
+        kv_dec=[3 * B, 8 * B, B, 5 * B], runs=[B] * 4, chunk=0, kv_chunk=0),
+    "mixed_in_one_tile": lambda B: dict(
+        kv_dec=[4 * B, 0, 7 * B], runs=[B, 0, B], chunk=2 * B,
+        kv_chunk=6 * B),
+    "lengths_on_page_borders": lambda B: dict(
+        kv_dec=[16, 8, 32], runs=[B] * 3, chunk=16, kv_chunk=8 + 16),
+}
+
+
+def _dense_block_rule(q, kp, vp, ss, nt, kvl, tab, block):
+    """The rule written out in numpy: the row at position p of its
+    sequence sees keys 0 .. min(kv - 1, (p // B + 1) B - 1)."""
+    q, kp, vp = (np.asarray(a, np.float64) for a in (q, kp, vp))
+    T, H, D = q.shape
+    KV, psz = kp.shape[0], kp.shape[2]
+    out = np.zeros((T, H, D))
+    for i in range(len(ss)):
+        n, kv = int(nt[i]), int(kvl[i])
+        pages = np.asarray(tab[i])
+        k = kp[:, pages].reshape(KV, -1, D)[:, :kv]
+        v = vp[:, pages].reshape(KV, -1, D)[:, :kv]
+        for t in range(n):
+            p = kv - n + t
+            last = min(kv - 1, (p // block + 1) * block - 1)
+            for h in range(H):
+                g = h // (H // KV)
+                s = k[g, :last + 1] @ q[int(ss[i]) + t, h] * D ** -0.5
+                w = np.exp(s - s.max())
+                out[int(ss[i]) + t, h] = (w / w.sum()) @ v[g, :last + 1]
+    return out
+
+
+class TestBlockCausal:
+    @pytest.mark.parametrize("block", [4, 8])
+    @pytest.mark.parametrize("name", list(_BLOCK_LAYOUTS))
+    def test_kernel_reference_and_the_rule_by_hand(self, name, block):
+        q, kp, vp, ss, nt, kvl, tab = _engine_layout(
+            **_BLOCK_LAYOUTS[name](block))
+        out = ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab,
+                                     block=block)
+        ref = ragged_attention_reference(q, kp, vp, ss, nt, kvl, tab,
+                                         block=block)
+        want = _dense_block_rule(q, kp, vp, ss, nt, kvl, tab, block)
+        # kernel and reference change alike: BOTH against the rule
+        np.testing.assert_allclose(np.asarray(ref), want, atol=2e-5,
+                                   rtol=2e-5)
+        np.testing.assert_allclose(np.asarray(out), want, atol=2e-5,
+                                   rtol=2e-5)
+        # ... and the rule is not the causal one where a block is open
+        causal = ragged_attention_reference(q, kp, vp, ss, nt, kvl, tab)
+        assert not np.allclose(np.asarray(causal), want, atol=1e-3)
+
+    def test_a_block_of_one_is_the_causal_rule_bit_for_bit(self):
+        q, kp, vp, ss, nt, kvl, tab = _engine_layout(**_LAYOUTS["engine"])
+        for fn in (ragged_paged_attention, ragged_attention_reference):
+            np.testing.assert_array_equal(
+                np.asarray(fn(q, kp, vp, ss, nt, kvl, tab, block=1)),
+                np.asarray(fn(q, kp, vp, ss, nt, kvl, tab)))
+
+    def test_refused_with_a_window_or_pages_of_broken_blocks(self):
+        q, kp, vp, ss, nt, kvl, tab = _engine_layout(**_LAYOUTS["engine"])
+        with pytest.raises(ValueError, match="block 4"):
+            ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab, block=4,
+                                   window=16)
+        with pytest.raises(ValueError, match="block 3"):
+            ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab, block=3)

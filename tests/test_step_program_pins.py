@@ -2,7 +2,7 @@
 
 ONE place for what guards "another family's program did not move": the
 sha256 of `eng._programs[name].lower(...).as_text()` (no debug info) for
-twelve toy families times the two row counts the step body is compiled
+thirteen toy families times the two row counts the step body is compiled
 at (`unified`: the decode rows and a prefill chunk; `unified_nochunk`:
 the decode rows alone), at toy widths, on the CPU under the suite's
 matmul precision. The toy models and engines are the family files' own
@@ -18,8 +18,9 @@ did NOT change (PR 58: six step bodies became three) re-records none,
 or shows on the chip why a text moved and that the compiled program did
 not (ISSUE 58 says how). The nine `unified` hashes of llama, moe, mla,
 gpt, laguna, eva, looped, nemotron and ling are the ones PR 55 / PR 57
-/ PR 59 recorded; the other fifteen were recorded at PR 58's
-parent (404ac2e), before `engine.py` was touched.
+/ PR 59 recorded; fifteen were recorded at PR 58's parent (404ac2e),
+before `engine.py` was touched; sdar's two came with PR 60, which moved
+none of the other twenty-four.
 """
 
 import functools
@@ -35,6 +36,7 @@ import test_falcon_h1_serving as falcon_h1
 import test_nemotron_h_serving as nemotron
 import test_ouro_serving as looped
 import test_phi4flash_serving as phi4flash
+import test_sdar_serving as sdar
 import test_xing_serving as xing
 from test_engine_programs import _lowered_toy
 
@@ -49,6 +51,7 @@ ENGINES = {
     "xing": lambda: xing._engine(xing.seeded(experts_held=(4, 4))[0]),
     "falcon_h1": lambda: falcon_h1._engine(falcon_h1.seeded()[0]),
     "phi4flash": lambda: phi4flash._engine(phi4flash.seeded()[0]),
+    "sdar": lambda: sdar._engine(sdar.seeded()[0]),
 }
 
 PINS = {
@@ -102,6 +105,12 @@ PINS = {
         "6f13b59af144032a3a3add14309070c849b32d2ecf3085de29dd28147073a78a",
     ("phi4flash", "unified_nochunk"):
         "88832a20c2a221c8e5021f778738a2abdf1d0bed621969964f4010838742bdbe",
+    # generation by diffusion over blocks (PR 60, recorded with it: the
+    # moe family's chain + the q / k norms, the block rule, `unmask`)
+    ("sdar", "unified"):
+        "b8ad4a754d82a94f1ec9f68f5b31479b8772e29d1e93b63aaf2d98974dc605ab",
+    ("sdar", "unified_nochunk"):
+        "9a52a7ae501318723209a58ab6077c7039dba09590f0967b747e7df5c6669713",
 }
 
 
@@ -116,7 +125,8 @@ def lower_step(eng, program):
     positions, num_tokens, kv_lengths, tables, tok_page, tok_off). What
     is a pair for whom: a table and a page column a layer KIND where the
     model has window layers; the summary rows, pooling pages and offsets
-    of chunk-summary attention; the state table of a hybrid."""
+    of chunk-summary attention; the state table of a hybrid; the rows a
+    slot's pass unmasks where the model generates by blocks."""
     B = eng.max_slots
     C = eng._chunk_parts()[program[len("unified"):]]
     T = eng._launch_rows(C)
@@ -133,6 +143,8 @@ def lower_step(eng, program):
         lens, page, off = (lens, lens), (page, pooled), (off, pooled)
     if eng._ssm_layers:
         lens = (lens, i32(B + 3))
+    if eng._block:
+        lens = (lens, i32(B))
     return eng._programs[program].lower(
         eng._w, i32(T), eng._pools, i32(T), i32(B + 1), lens, table, page,
         off)

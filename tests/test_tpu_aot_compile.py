@@ -324,6 +324,29 @@ def test_ragged_paged_attention_compiles_at_the_ouro_cell_shapes(chip):
         chip.refusals.get(_ragged)
 
 
+def _ragged_blocks(q, kp, vp, ss, nt, kvl, tab):
+    from paddle_tpu.ops.pallas_ragged import ragged_paged_attention
+    return ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab, block=4)
+
+
+@pytest.mark.parametrize("t", [768, 512])
+def test_ragged_paged_attention_compiles_at_the_sdar_cell_shapes(chip, t):
+    """`sdar-30b-a3b-serve-pp8-d6` as its cell runs it: 128 slots of a
+    BLOCK of four rows (+ a 256-row chunk), the block-causal rule, 32
+    query heads over 4 KV heads x 128 (tiles of 16 tokens), pages of
+    256, a pool of 1,281, 129 sequences of 22 pages; and the per-head
+    RMSNorm of q as the step calls it."""
+    from paddle_tpu.ops.fused import fused_rms_norm
+    hq, kv, psz, n_pages, s, nj = 32, 4, 256, 1281, 129, 22
+    seq = chip.shape((s,), I32)
+    pool = chip.shape((kv, n_pages, psz, D))
+    assert chip.compiles(_ragged_blocks, chip.shape((t, hq, D)), pool, pool,
+                         seq, seq, seq, chip.shape((s, nj), I32)), \
+        chip.refusals.get(_ragged_blocks)
+    assert chip.compiles(fused_rms_norm, chip.shape((t, hq, D)),
+                         chip.shape((D,))), chip.refusals.get(fused_rms_norm)
+
+
 def _ragged_windowed(q, kp, vp, ss, nt, kvl, tab):
     from paddle_tpu.ops.pallas_ragged import ragged_paged_attention
     return ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab, window=512)
