@@ -202,14 +202,19 @@ STEP_COUNTS_SHARED: Tuple[str, ...] = ("shared_pool_readers",)
 #: ... and where the model generates by diffusion over blocks: of the
 #: launch the record retires, the slots whose rows were a DENOISE pass
 #: of their block (the transfer rule reads their logits) and a COMMIT
-#: pass (it writes the block's final K/V), the decode rows that were
+#: pass (it writes the block's final K/V) — a slot a launch counts ONE
+#: pass —, of the denoise ones those whose launch ALSO carried the
+#: commit of the block before, riding behind the block rows (FUSED: a
+#: commit that cost no launch), the decode rows that were
 #: still masked going in (the rows whose logits the rule reads), the
-#: tokens its commits emitted, the cache tokens its sequences held, open
-#: blocks and the chunk included — what ONE layer's attention has to
-#: read (the first five add up over a record's launches), and the slots
+#: tokens its commits emitted, the cache tokens its REQUESTS held, open
+#: blocks and the chunk included, once a request however many entries
+#: its rows take — what ONE layer's attention has to
+#: read (the first six add up over a record's launches), and the slots
 #: that held an open block
 STEP_COUNTS_DIFFUSION: Tuple[str, ...] = (
     "diffusion_passes_denoise", "diffusion_passes_commit",
+    "diffusion_passes_fused",
     "diffusion_rows_masked", "diffusion_tokens_committed",
     "diffusion_kv_tokens", "diffusion_blocks_open")
 #: step records kept for each slot of the request ring. A request lives

@@ -39,7 +39,9 @@ is neither 64 nor a multiple of 128) is refused at construction with a
 A launch computes the rows it carries. The body is compiled at two row
 counts: `max_slots x R + prefill_chunk` flat rows (R = `_slot_rows`: 1 +
 spec_k, or the block length), and the same tables' prefix of `max_slots
-x R` rows with no chunk part (`_step_programs`). `_unified_step` launches the second whenever no
+x R` rows with no chunk part (`_step_programs`; a block family's have
+the riding commits' region between the slots' rows and the chunk's,
+`_launch_rows`). `_unified_step` launches the second whenever no
 prompt is being dispatched in the launch it builds — which the host
 knows a launch ahead, like every other table — so a decode-only launch
 pays for no idle chunk row: at a few hundred rows the layers' matmuls
@@ -78,8 +80,26 @@ queued ahead survives — and ONE commit pass with the final tokens, after
 which its K/V is final and its tokens are emitted: `Request.tokens`
 grows by up to B at a commit, and only then. The schedule is static
 (`models.sdar.block_passes`), so the host knows a launch ahead which
-pass each slot is in; an EOS is seen a launch late, like any other. Its
-exactness contract is the reference rule's (`benchmarks/lib/
+pass each slot is in; an EOS is seen a launch late, like any other.
+
+A commit pass needs no launch of its own. Where the request has a next
+block, the commit RIDES in the launch of that block's first denoise
+pass: the block's B final rows go in as a block-sized prefill of the
+slot's own sequence — a sequence entry of their own (the slot's page
+table, the block's positions, a `kv_lengths` that ends at the block) in
+a fixed region behind the block rows, fed from the launch in flight by
+the same `feed` programs — written, attended, never sampled (no logits
+are formed for them); the slot's own rows open the next block, and read
+the riding block's FINAL K/V from the pages, because every layer appends
+all rows before any row attends. So a block costs `denoising_steps`
+launches, not one more, with the same passes, rule and tokens. The
+region holds `_ride_slots` blocks (derived from `max_slots` and
+`denoising_steps`; no argument sets it): a slot that finds it full and
+a request's last block commit alone, as before. A listener (`on_block`)
+and the step counts see a riding commit as the last PASS of its block,
+before the next block's first.
+
+Its exactness contract is the reference rule's (`benchmarks/lib/
 reference_sdar.py`): every pass's logits, the rule on them, the tokens
 committed. Drafting, the prefix cache, live-donor sharing, hand-off and
 preemption are refused at construction by name.
@@ -158,7 +178,9 @@ _DIFF_PASSES = _obs.registry().counter(
     "serving.engine.diffusion_passes",
     "passes of a block of a model that generates by diffusion over "
     "blocks, a slot a launch: denoise (the rule reads its logits) or "
-    "commit (it writes the block's final K/V)", labels=("kind",))
+    "commit (it writes the block's final K/V); fused: the denoise "
+    "passes whose launch also carried the commit of the block before",
+    labels=("kind",))
 _PREEMPTIONS = _obs.registry().counter(
     "serving.engine.preemptions",
     "low-priority decodes re-queued (pages intact) for a higher-"
@@ -297,13 +319,28 @@ def _once(fn, scope: str, *args, **static):
 
 
 # -- the step's entry and exit, shared by the jitted bodies ------------
-def _seq_starts(B: int, R: int):
-    """[B + 1] baked row starts of the unified step: decode slot s owns
-    rows [s*R, (s+1)*R), the prefill chunk the rows from B*R on. R == 1
-    reduces to arange(B + 1)."""
+def _seq_starts(B: int, R: int, riders: int = 0):
+    """[B + riders + 1] baked row starts of the unified step: decode
+    slot s owns rows [s*R, (s+1)*R), riding commit j (generation by
+    diffusion over blocks, `_ride_slots`) the R rows from B*R + j*R, the
+    prefill chunk the rows from (B + riders)*R on. R == 1 reduces to
+    arange(B + 1)."""
+    if riders:
+        return jnp.arange(B + riders + 1, dtype=jnp.int32) * R
     return jnp.concatenate(
         [jnp.arange(B, dtype=jnp.int32) * R,
          jnp.asarray([B * R], jnp.int32)])
+
+
+def _ride_slots(max_slots: int, steps: int) -> int:
+    """Commits ONE launch of a model that generates by diffusion over
+    blocks can carry riding behind the block rows: the slots that stand
+    at the end of a block in a launch when the `steps` launches a fused
+    block takes are spread evenly over the slots. A launch that finds
+    more takes their commits alone, which moves those slots a launch on
+    and evens the spread out; a longer region would cost EVERY launch
+    its rows (idle rows of the flat buffer are routed and computed)."""
+    return -(-max_slots // steps)
 
 
 def _logit_rows(x, seq_start, num_tokens, K: int):
@@ -448,7 +485,8 @@ def _latent_mixer(L, h, rope, pool, seq_start, num_tokens, kv_lengths,
 def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
                runs, *, heads: int, kv: int, d: int, mults=None,
                window=None, diff=None, borrowed: bool = False,
-               shared: bool = False, eps: float = 1e-5, block=None):
+               shared: bool = False, eps: float = 1e-5, block=None,
+               ride=None):
     """Grouped-query attention on the normed rows h [1, T, H] of a
     block's input, over the pages `pools` = (K, V): q / k / v (+ their
     biases, where the layer has them; ONE fused ``wqkv`` + ``bqkv``
@@ -465,7 +503,13 @@ def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
     `d` dims, one gain vector for all heads, BEFORE the rotary turn
     (`qk_norm`). `block`: the launch is block-causal — a row sees the
     keys up to the end of its block of `block` positions (generation by
-    diffusion over blocks). `mults` (Falcon-H1): the
+    diffusion over blocks). `ride` (static, with it): the flat rows [r0,
+    r1) hold riding commits — the final rows of blocks whose slots' own
+    rows, in this launch, are the NEXT block of the same sequence — and
+    `runs` is a pair, (the other rows' work list, theirs): two
+    neighbouring blocks lie in one cache tile more often than not, and
+    a run of the append owns its tile, so the riding rows are appended
+    by a call of their own, before any row attends. `mults` (Falcon-H1): the
     input's, the key's and the output's static multipliers. `window`:
     the layer's sliding window (its `tables` and `runs` are then the
     window kind's). -> (the mixer's output y [1, T, H], the pools).
@@ -517,6 +561,15 @@ def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
                       L["k_norm"], eps=eps)
     if not borrowed:
         with _scope("cache_write"):
+            if ride:
+                runs, rider_runs = runs
+                at = slice(*ride)
+                # (their q is turned with the launch's, below)
+                _, kp, vp = _once(
+                    fused_rope_append, "cache_write", q[at],
+                    k.reshape(T, kv, d)[at], v.reshape(T, kv, d)[at],
+                    cos[at], jnp.zeros_like(cos[at]) if sin is None
+                    else sin[at], kp, vp, rider_runs)
             q, kp, vp = _once(
                 fused_rope_append, "cache_write", q,
                 k.reshape(T, kv, d), v.reshape(T, kv, d), cos,
@@ -812,9 +865,10 @@ class _Launch:
         self.n = n              # that chunk's rows
         self.rows = rows        # [(slot, request)] of its decode rows
         self.drafts = drafts    # {slot: drafted tokens} (spec decoding);
-        #                         a block family: {slot: (the pass of its
-        #                         block these rows are, the block's passes,
-        #                         its given tokens)}
+        #                         a block family: {slot: [(the pass of a
+        #                         block, the block's passes, its given
+        #                         tokens)]}, a riding commit of the block
+        #                         before, then the slot's own rows'
         #: {id(request): row of `tokens`} of every request whose NEXT
         #: input token this launch produces: its decode rows, and the
         #: chunk's row when the chunk ends its prompt
@@ -833,7 +887,7 @@ _ADDITIVE = frozenset(
     + _tracing.STEP_COUNTS_BY_KIND[:4]
     + _tracing.STEP_COUNTS_EVA[:4] + _tracing.STEP_COUNTS_EVA[-1:]
     + ("ssm_state_bytes_moved", "ssm_scan_rows", "ssm_state_resets")
-    + _tracing.STEP_COUNTS_MHC[:1] + _tracing.STEP_COUNTS_DIFFUSION[:5])
+    + _tracing.STEP_COUNTS_MHC[:1] + _tracing.STEP_COUNTS_DIFFUSION[:6])
 
 
 class ServingEngine:
@@ -1034,9 +1088,13 @@ class ServingEngine:
         # model's config says so, never a switch. The block's K/V is
         # written every pass and final only after the commit pass
         self._block = int(getattr(cfg, "block_length", 0) or 0)
+        # ... and the commits of that family ONE launch can carry riding
+        # behind the block rows (`_build_unified`): none elsewhere
+        self._riders = 0
         if self._block:
             self._diff_steps = int(cfg.denoising_steps)
             self._mask_id = int(cfg.mask_token_id)
+            self._riders = _ride_slots(self.max_slots, self._diff_steps)
             _refuse_shared_cache(
                 f"this model generates by diffusion over blocks of "
                 f"{self._block} rows whose K/V is rewritten every pass "
@@ -1237,8 +1295,11 @@ class ServingEngine:
         #: a block family's twin: a callable (request, pass index, the
         #: block's passes, the block's tokens going in [B], its logits
         #: rows [B, vocab] float32, the block after the pass [B]) called
-        #: as each pass of a block RETIRES; `on_logits` is not called for
-        #: such a family (a token is not sampled from one row)
+        #: as each pass of a block RETIRES — a commit that rode in the
+        #: next block's first launch as the block's last pass, with no
+        #: logits (None), before that launch's own pass 0; `on_logits`
+        #: is not called for such a family (a token is not sampled from
+        #: one row)
         self.on_block = None
         # the open step's counts, taken where the work happens and
         # closed into the recorder's step record (tracing.STEP_COUNTS)
@@ -1400,8 +1461,10 @@ class ServingEngine:
         return self._block or 1 + self.spec_k
 
     def _launch_rows(self, chunk: int) -> int:
-        """Flat rows of a launch whose chunk part is `chunk` rows."""
-        return self.max_slots * self._slot_rows + chunk
+        """Flat rows of a launch whose chunk part is `chunk` rows: the
+        decode slots', the riding commits' region (a block family's:
+        `_riders` blocks), the chunk part."""
+        return (self.max_slots + self._riders) * self._slot_rows + chunk
 
     def _prompt_rows(self, req: Request) -> int:
         """Prompt tokens of `req` that are PREFILLED: all of them, or —
@@ -1463,7 +1526,7 @@ class ServingEngine:
         # device. Compiled and run ONCE here, on the tokens of no launch
         self._no_tokens = jax.jit(lambda: jnp.zeros(
             self._launch_rows(self.prefill_chunk) if self.spec_k
-            else self._launch_rows(0) if self._block
+            else self.max_slots * self._block if self._block
             else self.max_slots + 1, jnp.int32))()
         for sfx, chunk in self._chunk_parts().items():
             T = self._launch_rows(chunk)
@@ -1978,7 +2041,8 @@ class ServingEngine:
         reg.counter("serving.replica.rows_computed",
                     "flat rows the step launches computed: a launch with "
                     "a prompt's chunk max_slots x (1 + spec_k) + "
-                    "prefill_chunk, one without max_slots x (1 + spec_k)"
+                    "prefill_chunk, one without max_slots x (1 + spec_k) "
+                    "(a block family: blocks, and its riding region)"
                     ).inc(self.rows_computed)
         reg.counter("serving.replica.rows_owned",
                     "of the rows computed, those a sequence owned (decode "
@@ -2446,7 +2510,9 @@ class ServingEngine:
         request with a block left to dispatch — the host knows how many
         its prompt and budget make (`_blocks_of`) — and `src` the first
         row of its block after the pass in flight, None where the host
-        holds the block (`Request.block_tokens`) or opens a new one."""
+        holds the block (`Request.block_tokens`) or opens a new one; the
+        rows of a commit that rides with a new block (`_build_unified`)
+        are fed from the same `src`."""
         rows = []
         for slot, req in self.scheduler.active():
             src = fl.row_of.get(id(req)) if fl is not None else None
@@ -2471,7 +2537,8 @@ class ServingEngine:
                        fl: Optional[_Launch], chunk: int):
         """The host half of the unified launch: extend every sequence
         (applying copy-on-write copies) and fill the row tables, T =
-        max_slots x (1 + spec_k) + `chunk` flat rows long: `chunk` is
+        `_launch_rows(chunk)` = max_slots x (1 + spec_k) + `chunk` flat
+        rows long (a block family: + the riding region): `chunk` is
         `prefill_chunk` where the launch carries `preq`'s rows and 0
         where it carries none (`_unified_step`; `compiled_programs`
         asks for an idle launch's tables at either length).
@@ -2487,11 +2554,22 @@ class ServingEngine:
         three operands are pairs: (`kv_lengths`, the pooled rows
         visible), (`tok_page`, [2, P] pages: where each closing chunk's
         tokens lie and where its pooled row goes), (`tok_off`, [2, P]:
-        the chunk within that page, the row within that one)."""
+        the chunk within that page, the row within that one).
+
+        A block family: a slot's rows are pass `Request.block_pass` of
+        its open block. Where that pass is the COMMIT, the request has a
+        block after it and the region behind the block rows has room
+        (`_riders` blocks a launch), the launch carries both: the
+        commit's rows ride there as a sequence of their own, and the
+        slot's rows are pass 0 of the NEXT block. The slot counts one
+        pass (denoise, and `diffusion_passes_fused`); `drafts[slot]`
+        lists both for the retire, the commit first."""
         B, C, K = self.max_slots, chunk, self.spec_k
         R, blk = self._slot_rows, self._block
-        base = B * R
-        T, S = base + C, B + 1
+        # the chunk's rows start behind the decode slots' and, a block
+        # family, the riding commits' (sequences B .. B + riders - 1)
+        base = (B + self._riders) * R
+        T, S = base + C, B + self._riders + 1
         tiling = self._attn_tiling(T)   # the kernel's, at THIS row count
         ps, nj = self.page_size, self.pages_per_seq
         tok = np.zeros(T, np.int32)
@@ -2549,7 +2627,8 @@ class ServingEngine:
         if blk:
             # rows a slot's pass unmasks (0: a commit pass, an idle slot)
             take = np.zeros(B, np.int32)
-            diff = dict.fromkeys(_tracing.STEP_COUNTS_DIFFUSION[:3], 0)
+            diff = dict.fromkeys(_tracing.STEP_COUNTS_DIFFUSION[:4], 0)
+            riding = 0      # riding commits placed so far
         for slot, req, feed in rows:
             if blk:
                 # pass `p` of the request's open block, of `total`: the
@@ -2558,6 +2637,31 @@ class ServingEngine:
                 p, total = req.block_pass, block_passes(
                     blk, self._diff_steps, g)
                 r0, commit = slot * R, p == total - 1
+                drafts[slot] = []
+                if commit and riding < self._riders \
+                        and req.blocks_sent + 1 < self._blocks_of(req):
+                    # the commit RIDES: the block's final rows go in as
+                    # a block-sized prefill of the slot's own sequence,
+                    # in the region behind the block rows (written,
+                    # attended under a `kv_lengths` that ends at their
+                    # block, never sampled), and the slot's own rows
+                    # open the next block, whose pass 0 reads this
+                    # block's final K/V from the pages: every layer
+                    # appends before any row attends
+                    rr = (B + riding) * R
+                    if feed is None:
+                        tok[rr:rr + blk] = req.block_tokens
+                    else:
+                        src[rr:rr + blk] = feed + np.arange(blk)
+                    ln = self.allocator.seq_length(req.request_id)
+                    place(req.request_id, B + riding, rr,
+                          ln - blk + np.arange(blk), slot)
+                    drafts[slot].append((p, total, g))
+                    req.blocks_sent += 1
+                    riding += 1
+                    diff["diffusion_passes_fused"] += 1
+                    g, p, feed, commit = 0, 0, None, False
+                    total = block_passes(blk, self._diff_steps)
                 if p == 0:
                     # the block's rows join the sequence, and stay
                     self.allocator.extend(req.request_id, blk)
@@ -2571,7 +2675,7 @@ class ServingEngine:
                 ln = self.allocator.seq_length(req.request_id)
                 place(req.request_id, slot, r0, ln - blk + np.arange(blk),
                       slot)
-                drafts[slot] = (p, total, g)
+                drafts[slot].append((p, total, g))
                 req.block_pass = 0 if commit else p + 1
                 req.blocks_sent += commit
                 diff["diffusion_passes_commit" if commit
@@ -2612,12 +2716,16 @@ class ServingEngine:
                                preq)
             tok[base:base + n] = preq.prompt[start:start + n]
             place(preq.request_id, S - 1, base, start + np.arange(n), B)
-        counts = {"decode_rows": int(num_tokens[:B].sum()),
+        # (riding rows are decode rows: block rows that were computed)
+        counts = {"decode_rows": int(num_tokens[:S - 1].sum()),
                   "prefill_rows": n, "rows_computed": T}
+        # each REQUEST's cache tokens, once: a riding commit's entry
+        # reads pages its slot's own entry reads too
+        kv_once = np.delete(kv_lengths, slice(B, S - 1))
         if blk:
             counts.update(diff, diffusion_blocks_open=len(rows),
-                          diffusion_kv_tokens=int(kv_lengths.sum()))
-        seq_start = np.append(np.arange(B) * R, base)
+                          diffusion_kv_tokens=int(kv_once.sum()))
+        seq_start = np.arange(S) * R
         if self._latent:
             counts["chunk_kv_len"] = int(kv_lengths[S - 1])
             counts["latent_row_bytes"] = \
@@ -2667,7 +2775,7 @@ class ServingEngine:
                 "layer_applications": self._passes * n_layers,
                 "cache_row_bytes": self._passes * n_layers * 2
                 * self._kv_geom[0] * self._kv_geom[1] * self._kv_itemsize})
-        live = int(np.sum(-(-kv_lengths // ps)))
+        live = int(np.sum(-(-kv_once // ps)))
         counts["pages_live"] = live
         counts["pages_visited"] = visited(0)
         if self._ssm_layers:
@@ -2814,10 +2922,22 @@ class ServingEngine:
                 counts["rows_dropped"] += self._block or 1
                 continue
             if self._block:
-                n_out, fin = self._retire_block(
-                    req, tokens[slot * R:(slot + 1) * R], *fl.drafts[slot],
-                    None if logits is None else logits[slot])
-                decoded += n_out
+                *rode, own = fl.drafts[slot]
+                fin = 0
+                for commit in rode:
+                    # a riding commit retires BEFORE the slot's own
+                    # rows, the next block's pass 0: its tokens are the
+                    # block as the host holds it, its logits nobody's
+                    n_out, fin = self._retire_block(
+                        req, req.block_tokens, *commit, None)
+                    decoded += n_out
+                if fin:     # an EOS inside it: the opened block goes
+                    counts["rows_dropped"] += R
+                else:
+                    n_out, fin = self._retire_block(
+                        req, tokens[slot * R:(slot + 1) * R], *own,
+                        None if logits is None else logits[slot])
+                    decoded += n_out
                 done["finished"] += fin
                 continue
             d = fl.drafts[slot]
@@ -2849,7 +2969,7 @@ class ServingEngine:
         if self._block:
             counts["diffusion_tokens_committed"] = decoded
             if _obs.enabled():
-                for kind in ("denoise", "commit"):
+                for kind in ("denoise", "commit", "fused"):
                     _DIFF_PASSES.labels(kind=kind).inc(
                         counts["diffusion_passes_" + kind])
         done["decoded"] += decoded
@@ -2869,7 +2989,11 @@ class ServingEngine:
         device). The host keeps the block; a COMMIT pass — the block's
         K/V is final — emits its tokens past the `given` ones, up to the
         request's budget or an EOS, and counts the given ones as
-        prefilled. -> (tokens emitted, 1 if the request finished)."""
+        prefilled. A commit that rode in the next block's first launch
+        retires through here too, first, with the block the host holds
+        as `after` and no `logits`: the listener's and the timeline's
+        protocol does not know where a pass ran. -> (tokens emitted, 1
+        if the request finished)."""
         before = self._fresh_block(req, given) if p == 0 \
             else req.block_tokens
         req.block_tokens = [int(t) for t in after]
@@ -2958,7 +3082,9 @@ class ServingEngine:
     # the builders' one argument — and S = max_slots + 1 sequences with
     # BAKED seq_start [0..B-1, B] (decode slot i owns row i; the prefill
     # chunk owns rows B..B+n-1; with C = 0 the last sequence is empty
-    # and starts where the buffer ends). The ragged kernel cuts those
+    # and starts where the buffer ends; a block family has `_riders`
+    # block-sized sequences between the slots' and the chunk's,
+    # `_seq_starts`). The ragged kernel cuts those
     # rows into tiles of TQ
     # tokens and walks, for each tile, only the live pages of the
     # sequences with rows in it (decode slots share a tile; the chunk
@@ -2984,15 +3110,28 @@ class ServingEngine:
         step's `fused_rope_append` calls, made on the device from the
         row tables the step already takes. Its length is the most runs
         a launch of the tables' row count can make: every decode row
-        its own, the chunk's one for each tile it touches."""
+        its own, the chunk's one for each tile it touches. With riding
+        commits (`_riders`) a pair: the list without their rows, and the
+        list of their rows alone, for the append of their own
+        (`_gqa_mixer`): a block the tiles it crosses."""
         tile = self._append_tile
-        base = self.max_slots * self._slot_rows
+        B, R, riders = self.max_slots, self._slot_rows, self._riders
+        base = B * R
 
         def run_table(num_tokens, tok_page, tok_off):
-            chunk = tok_page.shape[0] - base
-            return append_run_table(
-                seq_start, num_tokens, tok_page, tok_off, tile=tile,
+            chunk = tok_page.shape[0] - base - riders * R
+            runs = append_run_table(
+                seq_start, num_tokens.at[B:B + riders].set(0)
+                if riders else num_tokens, tok_page, tok_off, tile=tile,
                 max_runs=base + -(-chunk // tile) + 1)
+            if not riders:
+                return runs
+            at = slice(base, base + riders * R)
+            return runs, append_run_table(
+                seq_start[B:B + riders] - base, num_tokens[B:B + riders],
+                tok_page[at], tok_off[at], tile=tile,
+                max_runs=riders * (-(-R // tile)
+                                   + bool(R % tile and tile % R)))
 
         return run_table
 
@@ -3501,10 +3640,13 @@ class ServingEngine:
         eps, mu = d.eps, d.mults
         B, K = self.max_slots, self.spec_k
         R = self._slot_rows
-        T = B * R + C
-        seq_start = _seq_starts(B, R)
+        T = self._launch_rows(C)
+        seq_start = _seq_starts(B, R, self._riders)
         run_table = self._run_table(seq_start)
         state = self._state_mixers(C) if self._ssm_layers else {}
+        # the rows of the riding commits, where the launch has them
+        ride = {"ride": (B * R, (B + self._riders) * R)} \
+            if self._riders else {}
         f32 = jnp.float32
 
         def norm(x, scope, w, keys, once=True):
@@ -3592,7 +3734,7 @@ class ServingEngine:
                         y, pool = _gqa_mixer(
                             L, a, trig[blk.rope], next(kv_pools), seq_start,
                             num_tokens, kv_lengths, tables[blk.pages],
-                            runs[blk.pages], **blk.attn)
+                            runs[blk.pages], **blk.attn, **ride)
                         new_kv.append(pool)
                         pages_of[i] = pool
                     x = res.leave(x, y, keep)

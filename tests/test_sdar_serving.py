@@ -1,12 +1,16 @@
 """SDAR-MoE through `ServingEngine` against the plain reference
 (`benchmarks/lib/reference_sdar.py`): generation by diffusion over
 blocks — a slot owns a block of four rows that see each other, five
-launches commit four tokens — as `benchmarks/systems/sdar_serving.py`'s
-`check()` holds it: every denoise pass's logits against the reference
-teacher-forced with the engine's ids, the transfer rule exact on the
-engine's own logits, committed tokens the last pass's block. Toy sizes
-as `test_sdar.py`'s; ONE engine a module (three slots), compiled once;
-the S = 2 case has a second."""
+PASSES commit four tokens, the commit pass riding in the launch of the
+next block's first pass wherever there is a next block and room — as
+`benchmarks/systems/sdar_serving.py`'s `check()` holds it: every denoise
+pass's logits against the reference teacher-forced with the engine's
+ids, the transfer rule exact on the engine's own logits, committed
+tokens the last pass's block; and against the schedule without riding
+commits (five LAUNCHES a block), pass for pass. Toy sizes as
+`test_sdar.py`'s; ONE engine a module (three slots, so ONE riding commit
+a launch), compiled once, and its twin without the region; the S = 2
+case has a third."""
 
 import numpy as np
 import pytest
@@ -42,6 +46,17 @@ def eng(tiny):
     return _engine(tiny[0])
 
 
+@pytest.fixture(scope="module")
+def alone(tiny):
+    """The engine whose every commit takes a launch of its own: the
+    region for riding commits, which the engine derives from its slots
+    and the model's steps, set to nothing (there is no switch)."""
+    e = _engine(tiny[0])
+    e._riders = 0
+    e._build_programs()
+    return e
+
+
 def _prompts(seed, lens):
     rng = np.random.default_rng(seed)
     return [rng.integers(0, 250, n).astype(np.int32) for n in lens]
@@ -74,6 +89,12 @@ def _run(eng, prompts, max_new, eos=None, late=(), between=None):
     eng.collect()
     eng.on_block = None
     return [handles[i] for i in range(len(prompts))], log
+
+
+def _passes(steps):
+    """The step records' passes by kind, summed."""
+    return {k: sum(s["diffusion_passes_" + k] for s in steps)
+            for k in ("denoise", "commit", "fused")}
 
 
 def _holds(tiny, h, log, prompt, max_new, eos=None):
@@ -115,12 +136,17 @@ def test_remainders_budgets_and_a_request_joining_midway(tiny, eng):
     assert set(eng.program_cache_sizes().values()) == {1}
     # the step records' counts of what the launches did
     steps = rec.steps()
-    den = sum(s["diffusion_passes_denoise"] for s in steps)
-    com = sum(s["diffusion_passes_commit"] for s in steps)
+    n = _passes(steps)
     blocks = sum(len(log.blocks(h.request_id)) for h in handles)
-    assert com == blocks
-    assert den == sum(len(b) - 1 for h in handles
-                      for b in log.blocks(h.request_id))
+    # a block's commit rode in a launch or took one; a request's last
+    # has no block to ride with
+    assert n["commit"] + n["fused"] == blocks
+    assert n["commit"] >= len(handles) and n["fused"] >= 3
+    assert n["denoise"] == sum(len(b) - 1 for h in handles
+                               for b in log.blocks(h.request_id))
+    # riding rows are decode rows: every pass of every block, computed
+    assert sum(s["decode_rows"] for s in steps) == 4 * sum(
+        len(b) for h in handles for b in log.blocks(h.request_id))
     assert sum(s["diffusion_tokens_committed"] for s in steps) == sum(news)
     assert sum(s["diffusion_rows_masked"] for s in steps) == sum(
         int((r["before"] == 255).sum()) for h in handles
@@ -128,6 +154,9 @@ def test_remainders_budgets_and_a_request_joining_midway(tiny, eng):
     assert max(s["diffusion_blocks_open"] for s in steps) == 3
     assert all(s["diffusion_kv_tokens"] >= 4 * s["diffusion_blocks_open"]
                for s in steps)
+    # a request's cache tokens count once a launch, riding or not
+    assert max(s["diffusion_kv_tokens"] for s in steps) <= sum(
+        len(p) + 4 + n for p, n in zip(prompts, news))
     # every block's commit is stamped on its request's timeline
     stamps = [e for e in rec.trace(handles[0].request_id).timeline()
               if e.name == "block_commit"]
@@ -138,12 +167,89 @@ def test_remainders_budgets_and_a_request_joining_midway(tiny, eng):
 def test_an_eos_inside_a_block_ends_the_request_at_it(tiny, eng):
     prompt = _prompts(3, [10])[0]
     want, _ = ref.generate(prompt, 12, tiny[1], tiny[2])
-    eos = want[4]           # inside the second block (two given tokens)
-    cut = want[:want.index(eos) + 1]
+    # the first new token value past the first block (two given tokens,
+    # two generated): an EOS inside the second block, of four
+    at = next(i for i in range(2, 6) if want[i] not in want[:i])
+    eos, cut = want[at], want[:at + 1]
+    rec = tracing.recorder()
+    rec.clear()
     (h,), log = _run(eng, [prompt], [12], eos=eos)
     assert h.tokens == cut and h.result[:len(cut)].tolist() == cut
     _holds(tiny, h, log, prompt, 12, eos=eos)
     assert eng.allocator.free_pages == PAGES - 1
+    # both commits rode, so the EOS was seen with the third block opened
+    # and a launch queued behind it: its rows in both are dropped, no
+    # pass of it is reported, the pages are back
+    assert _passes(rec.steps()) == {"denoise": 2 + 4 + 2, "commit": 0,
+                                    "fused": 2}
+    assert sum(s["rows_dropped"] for s in rec.steps()) == 8
+    assert [len(b) for b in log.blocks(h.request_id)] == [3, 5]
+
+
+# what the riding commits may not move: the schedule without them
+CASES = {
+    # remainders 0-3, budgets that are no whole blocks, a late joiner
+    "remainders": dict(lens=[13, 8, 2, 23], news=[9, 6, 5, 7], late=(3,)),
+    # three slots in step (no prompt rows: nothing is prefilled), so
+    # three stand at the end of a block in ONE launch and the region
+    # holds one: the others commit alone, which moves them a launch on
+    "region_full": dict(lens=[3, 3, 3], news=[9, 9, 9]),
+    # a budget that ends on the block: nothing to ride with
+    "one_block": dict(lens=[8, 5], news=[4, 3]),
+    # an EOS in the first block, seen with the second one opened
+    "eos": dict(lens=[10], news=[12], eos_at=1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_a_riding_commit_moves_no_token_and_no_pass(tiny, eng, alone, case):
+    c = CASES[case]
+    prompts = _prompts(11, c["lens"])
+    eos = None
+    if "eos_at" in c:
+        eos = ref.generate(prompts[0], c["news"][0], tiny[1],
+                           tiny[2])[0][c["eos_at"]]
+    rec = tracing.recorder()
+    runs = {}
+    for name, e in (("riding", eng), ("alone", alone)):
+        rec.clear()
+        handles, log = _run(e, prompts, c["news"], eos=eos,
+                            late=c.get("late", ()))
+        runs[name] = handles, log, _passes(rec.steps()), len(rec.steps())
+        assert e.allocator.free_pages == PAGES - 1
+        assert set(e.program_cache_sizes().values()) <= {0, 1}
+    (hr, lr, nr, steps_r), (ha, la, na, steps_a) = runs["riding"], \
+        runs["alone"]
+    for a, b in zip(hr, ha):
+        assert a.tokens == b.tokens
+        # `on_block` call for call: (p, total, before, after), a riding
+        # commit as the last pass of its block, before the next's first
+        mine, theirs = lr.passes[a.request_id], la.passes[b.request_id]
+        assert [(r["p"], r["total"]) for r in mine] == \
+            [(r["p"], r["total"]) for r in theirs]
+        for r, t in zip(mine, theirs):
+            np.testing.assert_array_equal(r["before"], t["before"])
+            np.testing.assert_array_equal(r["after"], t["after"])
+            if r["p"] < r["total"] - 1:
+                # a block's pass 0 read the block before's FINAL K/V,
+                # as after a commit that took a launch
+                np.testing.assert_allclose(r["logits"], t["logits"],
+                                           atol=ATOL, rtol=ATOL)
+    blocks = sum(len(lr.blocks(h.request_id)) for h in hr)
+    # (past an EOS the two schedules drop different launches)
+    assert na == {"denoise": nr["denoise"] - (eos is not None),
+                  "commit": blocks, "fused": 0}
+    assert nr["commit"] + nr["fused"] == blocks
+    if case == "region_full":
+        # every request's last block, and three the region was full for:
+        # two of three in step, then one of the two still in step
+        assert (nr["commit"], nr["fused"]) == (3 + 3, blocks - 6)
+    else:
+        assert nr["commit"] == (0 if eos is not None else len(hr))
+    # a riding commit is a launch less for its request (the run ends
+    # with the slowest: the one the region was full for, one that ended)
+    assert steps_r < steps_a if case == "remainders" \
+        else steps_r == steps_a
 
 
 def test_a_retire_between_two_passes_moves_nothing(tiny, eng):
@@ -172,10 +278,10 @@ def test_two_rows_a_pass():
 def test_the_registry_counts_the_passes(tiny, eng):
     fam = obs.registry().counter("serving.engine.diffusion_passes",
                                  labels=("kind",))
-    before = {k: fam.labels(kind=k).value for k in ("denoise", "commit")}
-    (h,), log = _run(eng, _prompts(9, [8]), [4])
-    assert fam.labels(kind="denoise").value - before["denoise"] == 4
-    assert fam.labels(kind="commit").value - before["commit"] == 1
+    kinds = ("denoise", "commit", "fused")
+    before = {k: fam.labels(kind=k).value for k in kinds}
+    (h,), log = _run(eng, _prompts(9, [8]), [8])
+    assert [fam.labels(kind=k).value - before[k] for k in kinds] == [8, 1, 1]
 
 
 # ----------------------------------------------------------- refusals

@@ -20,7 +20,9 @@ not (ISSUE 58 says how). The nine `unified` hashes of llama, moe, mla,
 gpt, laguna, eva, looped, nemotron and ling are the ones PR 55 / PR 57
 / PR 59 recorded; fifteen were recorded at PR 58's parent (404ac2e),
 before `engine.py` was touched; sdar's two came with PR 60, which moved
-none of the other twenty-four.
+none of the other twenty-four, and were re-recorded by PR 61 (a block's
+commit rides in the next block's first launch: a region of rows, its
+sequences, an append of its own), which moved none of them either.
 """
 
 import functools
@@ -105,12 +107,13 @@ PINS = {
         "6f13b59af144032a3a3add14309070c849b32d2ecf3085de29dd28147073a78a",
     ("phi4flash", "unified_nochunk"):
         "88832a20c2a221c8e5021f778738a2abdf1d0bed621969964f4010838742bdbe",
-    # generation by diffusion over blocks (PR 60, recorded with it: the
-    # moe family's chain + the q / k norms, the block rule, `unmask`)
+    # generation by diffusion over blocks (PR 60: the moe family's chain
+    # + the q / k norms, the block rule, `unmask`; re-recorded by PR 61:
+    # the riding commits' region, sequences and append)
     ("sdar", "unified"):
-        "b8ad4a754d82a94f1ec9f68f5b31479b8772e29d1e93b63aaf2d98974dc605ab",
+        "688479f75b13a3cf12d116dcddcb63a8c6033d5252b38b36487c0bc9a023f81b",
     ("sdar", "unified_nochunk"):
-        "9a52a7ae501318723209a58ab6077c7039dba09590f0967b747e7df5c6669713",
+        "3c28ec12190ab1eee96be4f9800d7268937dd43c7d2827ebcfc0044743646a18",
 }
 
 
@@ -126,15 +129,18 @@ def lower_step(eng, program):
     is a pair for whom: a table and a page column a layer KIND where the
     model has window layers; the summary rows, pooling pages and offsets
     of chunk-summary attention; the state table of a hybrid; the rows a
-    slot's pass unmasks where the model generates by blocks."""
+    slot's pass unmasks where the model generates by blocks — whose
+    riding commits are sequences of their own between the slots and the
+    chunk."""
     B = eng.max_slots
+    S = B + eng._riders + 1
     C = eng._chunk_parts()[program[len("unified"):]]
     T = eng._launch_rows(C)
 
     def i32(*d):
         return jax.ShapeDtypeStruct(d, jnp.int32)
 
-    lens, table, page, off = i32(B + 1), i32(B + 1, eng.pages_per_seq), \
+    lens, table, page, off = i32(S), i32(S, eng.pages_per_seq), \
         i32(T), i32(T)
     if eng.num_window_pages:
         table, page = (table, table), (page, page)
@@ -146,7 +152,7 @@ def lower_step(eng, program):
     if eng._block:
         lens = (lens, i32(B))
     return eng._programs[program].lower(
-        eng._w, i32(T), eng._pools, i32(T), i32(B + 1), lens, table, page,
+        eng._w, i32(T), eng._pools, i32(T), i32(S), lens, table, page,
         off)
 
 

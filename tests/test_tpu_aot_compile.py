@@ -189,13 +189,17 @@ def _rope_append_args(chip, t, hq, kv, s, n_pages, psz):
 @pytest.mark.parametrize("t,hq,kv,s,n_pages,psz", [
     (T, HQ, KV, S, NP, PSZ), (288, 32, 8, 33, 187, 256),
     (288, 32, 32, 33, 272, 256), (288, 72, 8, 33, 160, 256),
-    (288, 48, 8, 33, 1280, 256)],
-    ids=["smoke", "mistral", "evabyte_kv32", "laguna_hq72", "laguna_hq48"])
+    (288, 48, 8, 33, 1280, 256), (896, 32, 4, 161, 1281, 256),
+    (128, 32, 4, 33, 1281, 256)],
+    ids=["smoke", "mistral", "evabyte_kv32", "laguna_hq72", "laguna_hq48",
+         "sdar", "sdar_riding"])
 def test_fused_rope_append_compiles(chip, t, hq, kv, s, n_pages, psz):
     """The engine's front half: projections, then rope + append by
     cache-tile runs — one (KV, 1, 16, 128) block of K and of V a grid
     step, at the smoke's widths and at the serving cells' (Mistral,
-    EvaByte's 32 KV heads, Laguna's two head counts). The compiler's
+    EvaByte's 32 KV heads, Laguna's two head counts, SDAR's launch of
+    896 rows whose K and V stay resident, and the call of their own its
+    32 riding commits' 128 rows take). The compiler's
     default VMEM holds each: nothing asks for more."""
     import inspect
     from paddle_tpu.ops import fused
@@ -329,15 +333,16 @@ def _ragged_blocks(q, kp, vp, ss, nt, kvl, tab):
     return ragged_paged_attention(q, kp, vp, ss, nt, kvl, tab, block=4)
 
 
-@pytest.mark.parametrize("t", [768, 512])
+@pytest.mark.parametrize("t", [896, 640])
 def test_ragged_paged_attention_compiles_at_the_sdar_cell_shapes(chip, t):
     """`sdar-30b-a3b-serve-pp8-d6` as its cell runs it: 128 slots of a
-    BLOCK of four rows (+ a 256-row chunk), the block-causal rule, 32
-    query heads over 4 KV heads x 128 (tiles of 16 tokens), pages of
-    256, a pool of 1,281, 129 sequences of 22 pages; and the per-head
-    RMSNorm of q as the step calls it."""
+    BLOCK of four rows, 32 riding commits of four rows (PR 61) (+ a
+    256-row chunk), the block-causal rule, 32 query heads over 4 KV
+    heads x 128 (tiles of 16 tokens), pages of 256, a pool of 1,281,
+    161 sequences of 22 pages; and the per-head RMSNorm of q as the step
+    calls it."""
     from paddle_tpu.ops.fused import fused_rms_norm
-    hq, kv, psz, n_pages, s, nj = 32, 4, 256, 1281, 129, 22
+    hq, kv, psz, n_pages, s, nj = 32, 4, 256, 1281, 161, 22
     seq = chip.shape((s,), I32)
     pool = chip.shape((kv, n_pages, psz, D))
     assert chip.compiles(_ragged_blocks, chip.shape((t, hq, D)), pool, pool,
