@@ -27,7 +27,8 @@ from .. import nn
 from ..nn import functional as F
 from ..nn import initializer as I
 from ..distributed.parallel_layers import MP_AXIS
-from ..observability.attribution import scope as _scope
+from ..observability.attribution import keeps as _keeps, \
+    residual as _residual, scope as _scope
 
 __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM",
            "llama3_8b_config", "llama_tiny_config", "apply_rope",
@@ -228,7 +229,7 @@ class LlamaAttention(nn.Layer):
                 else:
                     o = sdpa_reference(q, k, v, mask=mask_arr, causal=True)
             with _scope("attn_out"):
-                return o.reshape(B, S, -1) @ wo
+                return _residual(o.reshape(B, S, -1) @ wo, "attn_out")
 
         if c.fuse_attention_qkv:
             g = self._qkv_groups
@@ -238,7 +239,8 @@ class LlamaAttention(nn.Layer):
                 # [B,S,g,(Hg+2KVg),D]: dim 2 is the shard (rank) dim, so
                 # the q|k|v slices below are shard-local under mp
                 with _scope("qkv_proj"):
-                    qkv = (h @ wqkv).reshape(B, S, g, Hg + 2 * KVg, D)
+                    qkv = _residual(h @ wqkv, "qkv").reshape(
+                        B, S, g, Hg + 2 * KVg, D)
                     q = qkv[:, :, :, :Hg].reshape(B, S, H, D)
                     k = qkv[:, :, :, Hg:Hg + KVg].reshape(B, S, KV, D)
                     v = qkv[:, :, :, Hg + KVg:].reshape(B, S, KV, D)
@@ -251,9 +253,9 @@ class LlamaAttention(nn.Layer):
 
         def impl(h, wq, wk, wv, wo):
             with _scope("qkv_proj"):
-                q = (h @ wq).reshape(B, S, H, D)
-                k = (h @ wk).reshape(B, S, KV, D)
-                v = (h @ wv).reshape(B, S, KV, D)
+                q = _residual(h @ wq, "qkv").reshape(B, S, H, D)
+                k = _residual(h @ wk, "qkv").reshape(B, S, KV, D)
+                v = _residual(h @ wv, "qkv").reshape(B, S, KV, D)
             return finish(q, k, v, wo)
         return _apply("llama_attention", impl,
                       [x, self.q_proj.weight, self.k_proj.weight,
@@ -293,10 +295,19 @@ class LlamaMLP(nn.Layer):
                                     P(MP_AXIS, None))
 
     def forward(self, x):
+        from ..core.dispatch import apply as _apply
+
+        def named(t):
+            """The raw gate / up product under its residual's name,
+            where a checkpoint around the layer keeps it."""
+            if not _keeps("gate_up"):
+                return t
+            return _apply("gate_up", lambda a: _residual(a, "gate_up"), [t])
+
         if self.c.fuse_attention_ffn:
             c = self.c
             g, Ig = self._ffn_groups, c.intermediate_size // self._ffn_groups
-            gu = self.gate_up_proj(x)
+            gu = named(self.gate_up_proj(x))
             if g == 1:
                 # single-arg swiglu splits [gate | up] internally
                 return self.down_proj(F.swiglu(gu))
@@ -306,7 +317,8 @@ class LlamaMLP(nn.Layer):
             gate = gu[..., :Ig].reshape(list(shp) + [c.intermediate_size])
             up = gu[..., Ig:].reshape(list(shp) + [c.intermediate_size])
             return self.down_proj(F.swiglu(gate, up))
-        return self.down_proj(F.swiglu(self.gate_proj(x), self.up_proj(x)))
+        return self.down_proj(F.swiglu(named(self.gate_proj(x)),
+                                       named(self.up_proj(x))))
 
 
 class LlamaDecoderLayer(nn.Layer):

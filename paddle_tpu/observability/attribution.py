@@ -24,6 +24,8 @@ the observatory artifact unchanged.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import re
 from typing import Any, Callable, Dict, List, Mapping, NamedTuple, \
     Optional, Sequence, Tuple, Union
@@ -32,8 +34,9 @@ from . import costmodel
 
 __all__ = ["attribute", "render_roofline_table",
            "train_step_attribution", "render_flagship_table",
-           "SCOPES", "SCOPE_ALIASES", "scope", "OpScope", "op_key",
-           "op_scopes", "compile_named"]
+           "SCOPES", "SCOPE_ALIASES", "scope", "RESIDUALS", "residual",
+           "keeping", "keeps",
+           "OpScope", "op_key", "op_scopes", "compile_named"]
 
 _TRAIN_PHASES = ("data", "fwd", "bwd", "opt")
 
@@ -264,6 +267,52 @@ def scope(name: str):
         raise ValueError(f"{name!r} is not one of the step scopes {SCOPES}")
     import jax
     return jax.named_scope(name)
+
+
+#: the values of a decoder layer that a checkpoint around it may KEEP for
+#: the backward where it would otherwise compute them again (the
+#: trainer's `remat` "full", `trainer/pretrain.py::choose_remat_plan`):
+#: the flash kernel's output and its row log-sum-exp (the residuals its
+#: backward reads), and the raw products of the output, the q / k / v and
+#: the gate / up projections.  A scope's own name where the value IS the
+#: scope's result.  The down projection is not here (nothing in the
+#: backward reads it), nor the swiglu product and the norms (the
+#: cheapest recomputation a byte).
+RESIDUALS = ("flash_o", "flash_lse", "attn_out", "qkv", "gate_up")
+
+
+_keeping: contextvars.ContextVar = contextvars.ContextVar(
+    "residuals_kept", default=())
+
+
+@contextlib.contextmanager
+def keeping(names: Sequence[str]):
+    """Code traced inside is under a checkpoint whose policy keeps the
+    residuals `names` (`jax.checkpoint_policies.save_only_these_names`):
+    `residual` names those, and only there."""
+    token = _keeping.set(tuple(names))
+    try:
+        yield
+    finally:
+        _keeping.reset(token)
+
+
+def keeps(name: str) -> bool:
+    if name not in RESIDUALS:
+        raise ValueError(f"{name!r} is not one of the residuals {RESIDUALS}")
+    return name in _keeping.get()
+
+
+def residual(x, name: str):
+    """`x` under a name of RESIDUALS
+    (`jax.ad_checkpoint.checkpoint_name`) where a checkpoint around the
+    caller keeps it (`keeping`); `x` itself everywhere else — eager,
+    serving, a checkpoint that keeps nothing: those programs have no
+    trace of the names."""
+    if not keeps(name):
+        return x
+    from jax.ad_checkpoint import checkpoint_name
+    return checkpoint_name(x, name)
 
 
 class OpScope(NamedTuple):

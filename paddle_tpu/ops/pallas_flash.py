@@ -44,9 +44,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..observability.attribution import keeps as _keeps
 from .flash_attention import count_block_pairs
 from .lane_stat import lanes as _lanes
 from .on_mesh import on_mesh
@@ -323,8 +325,9 @@ def _fwd_on_mesh(q, k, v, seg_q, seg_kv, scale, causal, bq, bk, use_seg):
     return on_mesh(fwd, (q, k, v, seg_q, seg_kv), _QKV, ("bhsd", "bh1s"))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash_core(q, k, v, seg_q, seg_kv, scale, causal, bq, bk, use_seg):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash_core(q, k, v, seg_q, seg_kv, scale, causal, bq, bk, use_seg,
+                kept=False):
     o, _ = _fwd_on_mesh(q, k, v, seg_q, seg_kv, scale, causal, bq, bk,
                         use_seg)
     return o
@@ -357,13 +360,23 @@ def _flash_fwd_impl(q, k, v, seg_q, seg_kv, scale, causal, bq, bk,
 
 
 def _flash_vjp_fwd(q, k, v, seg_q, seg_kv, scale, causal, bq, bk,
-                   use_seg):
+                   use_seg, kept):
     o, lse = _fwd_on_mesh(q, k, v, seg_q, seg_kv, scale, causal, bq, bk,
                           use_seg)
+    if kept:
+        # a checkpoint around the caller keeps the kernel's output and
+        # row log-sum-exp (`observability.attribution.RESIDUALS`), so
+        # that the kernel does not run again for the backward.  Named
+        # HERE, on the rule's own residuals: a name on the primal output
+        # outside the custom_vjp does not reach them; and `kept` is an
+        # argument because this rule is traced after the caller's
+        # `attribution.keeping` has ended
+        o = checkpoint_name(o, "flash_o")
+        lse = checkpoint_name(lse, "flash_lse")
     return o, (q, k, v, seg_q, seg_kv, o, lse)
 
 
-def _flash_vjp_bwd(scale, causal, bq, bk, use_seg, res, do):
+def _flash_vjp_bwd(scale, causal, bq, bk, use_seg, kept, res, do):
     bwd = functools.partial(_flash_bwd_impl, scale=scale, causal=causal,
                             bq=bq, bk=bk, use_seg=use_seg)
     dq, dk, dv = on_mesh(bwd, (*res, do),
@@ -464,7 +477,8 @@ def flash_sdpa(q, k, v, causal: bool = False, segment_ids_q=None,
     kh = jnp.swapaxes(k, 1, 2)
     vh = jnp.swapaxes(v, 1, 2)
     out = _flash_core(qh, kh, vh, seg_q, seg_kv, float(scale),
-                      bool(causal), block_q, block_k, use_seg)
+                      bool(causal), block_q, block_k, use_seg,
+                      _keeps("flash_o") and _keeps("flash_lse"))
     return jnp.swapaxes(out, 1, 2)
 
 

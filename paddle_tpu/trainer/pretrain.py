@@ -19,18 +19,23 @@ ClipGradByGlobalNorm semantics; bf16 compute params via amp.decorate_tree
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+import itertools
+from typing import Any, Dict, List, Mapping, NamedTuple, Optional, \
+    Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import observability as _obs
 from ..amp import decorate_tree
 from ..core.tensor import Tensor
+from ..device import memory_stats
 from ..distributed.mesh import (build_hybrid_mesh, global_device_put,
                                 mesh_context)
-from ..observability.attribution import compile_named, scope as _scope
+from ..observability.attribution import (compile_named, keeping,
+                                         scope as _scope)
 from ..ops.on_mesh import kernel_mesh
 from ..distributed.pipeline import (PP_AXIS, spmd_pipeline,
                                     spmd_pipeline_interleaved,
@@ -43,7 +48,22 @@ from ..optimizer.functional import FunctionalAdamW
 from ..jit import _StateSwap, bind_state, extract_state
 
 __all__ = ["PretrainConfig", "build_llama_pretrain_step",
-           "make_hybrid_mesh_for", "flops_per_token", "flops_per_token_hw"]
+           "make_hybrid_mesh_for", "flops_per_token", "flops_per_token_hw",
+           "choose_remat_plan", "remat_order"]
+
+_G_SAVED_BYTES = _obs.registry().gauge(
+    "trainer.remat.saved_bytes",
+    "bytes a chip of residuals the layers' checkpoints keep (remat full)")
+_G_SAVED_LAYERS = _obs.registry().gauge(
+    "trainer.remat.saved_layers",
+    "layers whose checkpoint keeps the named residual", labels=("name",))
+
+#: What `remat` "full" leaves free on a chip BESIDE the step program when
+#: it chooses the residuals to keep: a program's `memory_analysis()` is
+#: the compiler's own peak, and beside it live the batch, whatever the
+#: caller still holds on the first chip (a reference check's programs)
+#: and the allocator's fragments.
+REMAT_MARGIN_BYTES = 1 << 30
 
 
 class PretrainConfig:
@@ -72,7 +92,11 @@ class PretrainConfig:
         # round-trip, so unrolling 16 layers saves ~60ms/step fwd+bwd at
         # the price of longer compiles (ref parity: CINN-style tradeoff).
         self.scan_layers = scan_layers
-        # remat: "full" checkpoints every layer (fleet recompute parity),
+        # remat: "full" puts every layer under a checkpoint (fleet
+        # recompute parity) that keeps what the device has room for:
+        # named residuals chosen by bytes against the memory the device
+        # reports free beyond the program that keeps nothing
+        # (`choose_remat_plan`; nothing where it reports no limit),
         # "dots" saves matmul outputs (recompute only elementwise),
         # "none" stores all residuals.
         if remat not in ("full", "dots", "none"):
@@ -168,6 +192,75 @@ _WEIGHT_SCOPES = (("input_layernorm", "attn_norm"),
 
 def _weight_scope(key: str) -> str:
     return next(s for prefix, s in _WEIGHT_SCOPES if key.startswith(prefix))
+
+
+def remat_order(mp: int) -> Tuple[Tuple[str, ...], ...]:
+    """The residuals a layer's checkpoint may keep
+    (`observability.attribution.RESIDUALS`), most recomputation saved a
+    byte first.  That follows from shapes: the flash kernel is quadratic
+    in the sequence for an output linear in it (both of its residuals or
+    neither: its backward reads the two); a matmul's output saves its
+    contraction length in FLOPs a byte, the same hidden size for the
+    three products — but under tensor parallelism the recomputed
+    `attn_out` also repeats its all-reduce over `mp`, which nothing
+    hides, so there it goes before the other two."""
+    flash = ("flash_o", "flash_lse")
+    if mp > 1:
+        return (flash, ("attn_out",), ("qkv",), ("gate_up",))
+    return (flash, ("qkv",), ("attn_out",), ("gate_up",))
+
+
+def choose_remat_plan(nbytes: Mapping[str, int], n_layers: int,
+                      headroom: int, unrolled: bool,
+                      order: Sequence[Tuple[str, ...]]
+                      ) -> List[Tuple[str, ...]]:
+    """The names each layer's checkpoint keeps: `order` walked while the
+    bytes fit.  `nbytes` is a name's bytes a layer a chip and `headroom`
+    what the chip has free beyond the program that keeps nothing.  An
+    entry is taken for ALL layers while it fits; the first that does not
+    fit whole is taken for the first layers it fits in where the layers
+    are unrolled (under `lax.scan` one set serves every layer: for
+    none), and the walk ends there."""
+    plan: List[Tuple[str, ...]] = [()] * n_layers
+    left = headroom
+    for names in order:
+        each = sum(nbytes[n] for n in names)
+        fit = min(n_layers, max(left, 0) // each)
+        if fit < n_layers and not unrolled:
+            break
+        plan = [kept + names if i < fit else kept
+                for i, kept in enumerate(plan)]
+        left -= fit * each
+        if fit < n_layers:
+            break
+    return plan
+
+
+def _drop_last_taken(plan: List[Tuple[str, ...]],
+                     order: Sequence[Tuple[str, ...]]
+                     ) -> List[Tuple[str, ...]]:
+    """`plan` without the entry of `order` it took last."""
+    taken = {n for kept in plan for n in kept}
+    last = next(names for names in reversed(order) if taken & set(names))
+    return [tuple(n for n in kept if n not in last) for kept in plan]
+
+
+def _bytes_limit(mesh: Mesh) -> Optional[int]:
+    """The least `bytes_limit` this process's devices of the mesh report,
+    None where one reports none (the CPU; a described device)."""
+    limits = [memory_stats(d).get("bytes_limit")
+              for d in mesh.devices.flat
+              if d.process_index == jax.process_index()]
+    return int(min(limits)) if limits and all(limits) else None
+
+
+def _program_need(compiled) -> int:
+    """What a compiled program holds a device at its peak, by the
+    compiler's own account: arguments, outputs and scratch, less the
+    donated arguments the outputs take over."""
+    ma = compiled.memory_analysis()
+    return int(ma.argument_size_in_bytes + ma.output_size_in_bytes
+               + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
 
 
 class TrainState(NamedTuple):
@@ -281,26 +374,47 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
     # stage body: apply L/S decoder layers via scan over the local slice;
     # per-layer remat (ref: fleet recompute intervals) keeps scan residuals
     # at O(hidden) instead of O(attention-scores) per layer
-    if cfg.remat == "dots":
-        remat_wrap = functools.partial(
-            jax.checkpoint, policy=jax.checkpoint_policies.dots_saveable)
-    elif cfg.remat == "none":
-        remat_wrap = lambda f: f
-    else:
-        remat_wrap = jax.checkpoint
+    def remat_wrap(kept: Tuple[str, ...]):
+        if cfg.remat == "dots":
+            return functools.partial(
+                jax.checkpoint, policy=jax.checkpoint_policies.dots_saveable)
+        if cfg.remat == "none":
+            return lambda f: f
+        if not kept:
+            return jax.checkpoint
+        return functools.partial(
+            jax.checkpoint,
+            policy=jax.checkpoint_policies.save_only_these_names(*kept))
 
-    def stage_fn(params_slice, x, cos_, sin_):
-        def body(h, layer_params):
-            with _StateSwap([tmpl]):
-                bind_state(tmpl, layer_params)
-                from ..core import autograd as ag
-                with ag.no_grad():
-                    out = tmpl(Tensor(h), cos_, sin_)
-            return out._data, None
-        n_local = jax.tree.leaves(params_slice)[0].shape[0]
-        h, _ = jax.lax.scan(remat_wrap(body), x, params_slice,
-                            unroll=1 if cfg.scan_layers else n_local)
-        return h
+    def stage_body(saved):
+        """The stage function whose layer i keeps `saved[i]` under its
+        checkpoint (`saved` empty: every layer keeps nothing)."""
+        def stage_fn(params_slice, x, cos_, sin_):
+            def body(kept, h, layer_params):
+                with _StateSwap([tmpl]), keeping(kept):
+                    bind_state(tmpl, layer_params)
+                    from ..core import autograd as ag
+                    with ag.no_grad():
+                        out = tmpl(Tensor(h), cos_, sin_)
+                return out._data, None
+            n_local = jax.tree.leaves(params_slice)[0].shape[0]
+            # one scan a run of layers that keep the same: ONE where the
+            # plan is one set for all (the empty one included)
+            runs = [(kept, len(list(g))) for kept, g in
+                    itertools.groupby(saved or [()] * n_local)]
+            sizes = [n for _, n in runs]
+            assert sum(sizes) == n_local, (saved, n_local)
+            split = {k: jax.lax.split(p, sizes)
+                     for k, p in params_slice.items()} if len(runs) > 1 \
+                else {k: [p] for k, p in params_slice.items()}
+            h = x
+            for i, (kept, n) in enumerate(runs):
+                h, _ = jax.lax.scan(
+                    remat_wrap(kept)(functools.partial(body, kept)), h,
+                    {k: parts[i] for k, parts in split.items()},
+                    unroll=1 if cfg.scan_layers else n)
+            return h
+        return stage_fn
 
     embed_key = "llama.embed_tokens.weight"
     norm_key = "llama.norm.weight"
@@ -366,7 +480,8 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
                                        labels_h[..., lo:hi])
         return total
 
-    def loss_fn(compute_params, ids, labels):
+    def loss_fn(saved, compute_params, ids, labels):
+        stage_fn = stage_body(saved)
         if zdeg > 1:
             compute_params = dict(compute_params, stacked=zero_gather(
                 compute_params["stacked"]))
@@ -452,9 +567,9 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
         loss = total / (B * S)
         return loss
 
-    def train_step(state: TrainState, ids, labels):
+    def step_with(saved, state: TrainState, ids, labels):
         def cast_loss(master_params):
-            return loss_fn(decorate_tree(master_params, param_dtype),
+            return loss_fn(saved, decorate_tree(master_params, param_dtype),
                            ids, labels)
         # Pallas kernels in the step run per-shard on this mesh
         with kernel_mesh(mesh):
@@ -471,7 +586,86 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
     state = TrainState(compute, master, opt_state, jnp.zeros((), jnp.int32))
 
     data_spec = NamedSharding(mesh, P(("dp", "sharding"), None))
-    jstep = jax.jit(train_step, donate_argnums=(0,))
+    batch_shape = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=data_spec)
+
+    def step_for(saved):
+        """The jitted step whose layer i keeps `saved[i]`: a function
+        (and so a trace) of its own for each plan."""
+        saved = tuple(saved)
+
+        def train_step(state: TrainState, ids, labels):
+            return step_with(saved, state, ids, labels)
+        return jax.jit(train_step, donate_argnums=(0,))
+
+    def step_args(state: TrainState):
+        """The step's arguments as shapes with `state`'s shardings (an
+        uncommitted leaf, the step count before the first step, goes
+        where the program puts it, as in the `jax.jit` call)."""
+        def shape(a):
+            placed = getattr(a, "committed", True)
+            return jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=a.sharding if placed else None)
+        return jax.tree.map(shape, state), batch_shape, batch_shape
+
+    # remat "full": what each layer's checkpoint keeps, chosen HERE from
+    # what can be observed — the residuals' bytes a chip at these shapes
+    # on this mesh against what the device reports free beyond the
+    # program that keeps nothing (the FLOOR: today's program, and what a
+    # device that reports no limit gets).  Two compiles before the first
+    # step where it engages: the floor's, for its need, and the chosen
+    # program's, whose own need is held to the same limit (an entry too
+    # many is dropped and the floor always remains); the `jax.jit` call
+    # and `compiled_programs` find the second in the compile cache.
+    # Under pp > 1 (either pipeline, the timetable executor too) a
+    # stage-level checkpoint around the layers discards whatever a layer
+    # kept, so the plan is empty there; with FLAGS_flash_impl "bundled"
+    # the kernel's own custom_vjp carries no names, the rest applies.
+    mp = mesh.shape.get("mp", 1)
+    order = remat_order(mp)
+    item = jnp.dtype(param_dtype).itemsize
+    rows = -(-B // (mesh.shape.get("dp", 1) * zdeg)) \
+        * -(-S // mesh.shape.get("sep", 1))      # tokens a chip
+    heads = -(-mc.num_attention_heads // mp)
+    nbytes = {
+        "flash_o": rows * heads * mc.head_dim * item,
+        "flash_lse": rows * heads * 4,
+        "attn_out": rows * mc.hidden_size * item,
+        "qkv": rows * -(-(mc.num_attention_heads
+                         + 2 * mc.num_key_value_heads) * mc.head_dim
+                        // mp) * item,
+        "gate_up": rows * -(-2 * mc.intermediate_size // mp) * item}
+    plan: List[Tuple[str, ...]] = [()] * mc.num_hidden_layers
+    jstep = step_for(())
+    limit = _bytes_limit(mesh) \
+        if cfg.remat == "full" and n_stages == 1 else None
+    floor_need = chosen_need = None
+    if limit is not None:
+        fits = limit - REMAT_MARGIN_BYTES
+        floor_need = chosen_need = _program_need(
+            jstep.lower(*step_args(state)).compile())
+        plan = choose_remat_plan(nbytes, len(plan), fits - floor_need,
+                                 not cfg.scan_layers, order)
+        while any(plan):
+            chosen = step_for(plan)
+            try:
+                need = _program_need(
+                    chosen.lower(*step_args(state)).compile())
+            except jax.errors.JaxRuntimeError as e:
+                if "RESOURCE_EXHAUSTED" not in str(e):
+                    raise
+                need = None         # the compiler itself refuses it
+            if need is not None and need <= fits:
+                jstep, chosen_need = chosen, need
+                break
+            plan = _drop_last_taken(plan, order)
+    record = {"layers": plan,
+              "saved_bytes": sum(nbytes[n] for kept in plan for n in kept),
+              "limit": limit, "margin": REMAT_MARGIN_BYTES,
+              "headroom": None if limit is None else fits - floor_need,
+              "floor_need": floor_need, "need": chosen_need}
+    _G_SAVED_BYTES.set(record["saved_bytes"])
+    for n in nbytes:
+        _G_SAVED_LAYERS.labels(name=n).set(sum(n in kept for kept in plan))
 
     # the init model is NOT kept: its f32 parameters are a second copy
     # of the state on the device (1.3 GiB at the 8B shard), stale after
@@ -484,14 +678,11 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
         and answered by this process's own compile of the step or by
         the compile cache (`attribution.compile_named`); nothing runs
         on the devices and nothing here is called by the step."""
-        shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=a.sharding), state)
-        batch = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=data_spec)
         return {"train_step": compile_named(
-            jstep, (shapes, batch, batch),
-            lambda: jax.jit(lambda *a: train_step(*a), donate_argnums=(0,)))}
+            jstep, step_args(state), lambda: step_for(plan))}
 
     meta = {"mesh": mesh, "data_sharding": data_spec,
             "flops_per_token": flops_per_token(mc),
-            "compiled_programs": compiled_programs}
+            "compiled_programs": compiled_programs,
+            "remat_plan": record}
     return state, jstep, meta

@@ -244,6 +244,11 @@ def run(cfg: dict) -> int:
         ce_chunks=cfg["ce_chunks"], pp_schedule=cfg["pp_schedule"])
     mesh = make_hybrid_mesh_for(pcfg)
     state, jstep, meta = build_llama_pretrain_step(pcfg, mesh)
+    if is_coord:
+        # what each layer's checkpoint keeps under remat "full", and the
+        # bytes it was chosen against (null where the device says none)
+        print(f"[run_pretrain] remat plan "
+              f"{json.dumps(meta['remat_plan'])}", flush=True)
     fpt = flops_per_token(mc)
 
     # SPMD feeding contract: EVERY process draws the identical global

@@ -1,0 +1,288 @@
+"""`remat` "full" keeps what the chip has room for (ISSUE 63): a layer's
+checkpoint saves named residuals chosen by bytes against what the device
+reports free beyond the program that keeps nothing."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import observability as obs
+from paddle_tpu.distributed.mesh import global_device_put
+from paddle_tpu.models.llama import llama_tiny_config
+from paddle_tpu.observability import attribution as at
+from paddle_tpu.ops import flash_attention
+from paddle_tpu.trainer import pretrain
+from paddle_tpu.trainer.pretrain import (PretrainConfig,
+                                         build_llama_pretrain_step,
+                                         choose_remat_plan,
+                                         make_hybrid_mesh_for, remat_order)
+
+MB = 1 << 20
+#: the four-chip cell's bytes a layer a chip (ISSUE 63: 68 / 134 / 101 /
+#: 470 MB), eight layers
+CELL = {"flash_o": 64 * MB, "flash_lse": 1 * MB, "attn_out": 128 * MB,
+        "qkv": 96 * MB, "gate_up": 448 * MB}
+FLASH = ("flash_o", "flash_lse")
+ALL_BUT_GATE = FLASH + ("attn_out", "qkv")
+
+
+def _whole(plan, names):
+    """`names` taken for every layer."""
+    return all(set(names) <= set(kept) for kept in plan)
+
+
+@pytest.mark.parametrize("case, headroom, unrolled, mp, want", [
+    ("nothing free", 0, True, 2, [()] * 8),
+    ("less than nothing free", -5 * MB, True, 2, [()] * 8),
+    ("one flash pair fits: a layer unrolled", 70 * MB, True, 2,
+     [FLASH] + [()] * 7),
+    ("one flash pair fits: none under scan", 70 * MB, False, 2, [()] * 8),
+    ("flash whole, attn_out for three", (8 * 65 + 3 * 128 + 100) * MB, True,
+     2, [FLASH + ("attn_out",)] * 3 + [FLASH] * 5),
+    ("flash whole, attn_out not whole: scan stops", (8 * 65 + 900) * MB,
+     False, 2, [FLASH] * 8),
+    ("the cell: three whole, gate_up for one",
+     (8 * (65 + 128 + 96) + 448 + 300) * MB, True, 2,
+     [ALL_BUT_GATE + ("gate_up",)] + [ALL_BUT_GATE] * 7),
+    ("the cell under scan: three whole, no gate_up",
+     (8 * (65 + 128 + 96) + 448 + 300) * MB, False, 2, [ALL_BUT_GATE] * 8),
+    ("room for all", 8 * 800 * MB, True, 2,
+     [ALL_BUT_GATE + ("gate_up",)] * 8),
+    ("room for all under scan", 8 * 800 * MB, False, 2,
+     [ALL_BUT_GATE + ("gate_up",)] * 8),
+    # no tensor parallelism: attn_out repeats no all-reduce and ranks
+    # with the matmuls, behind qkv
+    ("mp 1: qkv before attn_out", (8 * (65 + 96) + 2 * 128 + 10) * MB, True,
+     1, [FLASH + ("qkv", "attn_out")] * 2 + [FLASH + ("qkv",)] * 6),
+    ("mp 2: attn_out before qkv", (8 * (65 + 128) + 2 * 96 + 10) * MB, True,
+     2, [FLASH + ("attn_out", "qkv")] * 2 + [FLASH + ("attn_out",)] * 6),
+])
+def test_the_choice(case, headroom, unrolled, mp, want):
+    plan = choose_remat_plan(CELL, 8, headroom, unrolled, remat_order(mp))
+    assert plan == want, case
+    # deterministic, and never beyond the headroom
+    assert plan == choose_remat_plan(CELL, 8, headroom, unrolled,
+                                     remat_order(mp))
+    assert sum(CELL[n] for kept in plan for n in kept) <= max(headroom, 0)
+    # the order is kept: a later entry is nowhere unless every earlier
+    # one is everywhere
+    order = remat_order(mp)
+    for before, after in zip(order, order[1:]):
+        if any(set(after) & set(kept) for kept in plan):
+            assert _whole(plan, before), (case, before, after)
+
+
+def test_the_order_names_the_vocabulary_once():
+    for mp in (1, 2, 8):
+        flat = [n for names in remat_order(mp) for n in names]
+        assert sorted(flat) == sorted(at.RESIDUALS)
+        assert flat[:2] == list(FLASH)
+    with pytest.raises(ValueError):
+        at.residual(jnp.zeros(2), "swiglu")
+
+
+def test_dropping_takes_the_last_entry_back_whole():
+    order = remat_order(2)
+    plan = [ALL_BUT_GATE + ("gate_up",)] + [ALL_BUT_GATE] * 3
+    plan = pretrain._drop_last_taken(plan, order)
+    assert plan == [ALL_BUT_GATE] * 4
+    plan = pretrain._drop_last_taken(plan, order)
+    assert plan == [FLASH + ("attn_out",)] * 4
+    plan = pretrain._drop_last_taken(
+        pretrain._drop_last_taken(plan, order), order)
+    assert plan == [()] * 4
+
+
+def test_a_name_is_an_identity_outside_a_checkpoint_that_keeps_it():
+    x = jnp.arange(4.0)
+    assert at.residual(x, "qkv") is x and not at.keeps("qkv")
+    with at.keeping(("qkv",)):
+        assert at.keeps("qkv") and not at.keeps("attn_out")
+        assert at.residual(x, "attn_out") is x
+        text = jax.make_jaxpr(lambda a: at.residual(a, "qkv"))(x)
+        assert "name=qkv" in str(text)
+    assert not at.keeps("qkv")
+
+
+# ---------------------------------------------------------------- the step
+needs_4 = pytest.mark.skipif(len(jax.devices()) < 4,
+                             reason="needs 4 (virtual) devices")
+
+
+def _build(monkeypatch, limit, plan=None, needs=None, **kw):
+    """The toy step of test_op_scopes.py at widths the flash kernel
+    takes (one 128-row block a head, interpreted here), float32 so that
+    two programs differ by nothing but what they keep."""
+    paddle.seed(5)
+    mc = llama_tiny_config(num_hidden_layers=2, max_position_embeddings=128,
+                           num_attention_heads=2, num_key_value_heads=2,
+                           head_dim=64, fuse_attention_qkv=True,
+                           fuse_attention_ffn=True, fuse_pack_groups=2)
+    base = dict(global_batch=4, seq_len=128, sharding=2, mp=2, remat="full",
+                scan_layers=False, ce_chunks=2, param_dtype="float32")
+    base.update(kw)
+    cfg = PretrainConfig(mc, **base)
+    n = cfg.dp * cfg.mp * cfg.pp * cfg.sharding * cfg.sep
+    mesh = make_hybrid_mesh_for(cfg, devices=jax.devices()[:n])
+    # the test steers what the program reads from the device
+    monkeypatch.setattr(flash_attention, "_tpu_flash_available",
+                        lambda: True)
+    monkeypatch.setattr(pretrain, "_bytes_limit", lambda mesh: limit)
+    if plan is not None:
+        monkeypatch.setattr(pretrain, "choose_remat_plan",
+                            lambda *a, **k: [tuple(p) for p in plan])
+    if needs is not None:
+        it = iter(needs)
+        monkeypatch.setattr(pretrain, "_program_need", lambda c: next(it))
+    state, step, meta = build_llama_pretrain_step(cfg, mesh)
+    ids = global_device_put(jnp.asarray(np.random.RandomState(0).randint(
+        0, mc.vocab_size, (4, 128)), jnp.int32), meta["data_sharding"])
+    return state, step, meta, ids
+
+
+def _one_step(built):
+    state, step, meta, ids = built
+    state, m = step(state, ids, ids)
+    table = at.op_scopes(meta["compiled_programs"](state))
+    return {"loss": float(m["loss"]), "gnorm": float(m["grad_norm"]),
+            "grad": jax.tree.map(np.asarray, state.opt_state.moment1),
+            "master": jax.tree.map(np.asarray, state.master),
+            "remat": {(r.scope, r.kind, r.opcode) for r in table.values()
+                      if r.direction == "remat"},
+            "plan": meta["remat_plan"]}
+
+
+@pytest.fixture(scope="module")
+def floor_step():
+    mp = pytest.MonkeyPatch()
+    out = _one_step(_build(mp, None))
+    mp.undo()
+    return out
+
+
+PARTIAL = [ALL_BUT_GATE + ("gate_up",), ALL_BUT_GATE]
+
+
+@needs_4
+@pytest.mark.parametrize("case", ["every name, every layer",
+                                  "gate_up in the first layer only",
+                                  "every name under scan"])
+def test_a_step_that_keeps_equals_the_step_that_keeps_nothing(
+        case, floor_step, monkeypatch):
+    kw = {"every name, every layer": dict(limit=1 << 40),
+          "gate_up in the first layer only": dict(limit=1 << 40,
+                                                  plan=PARTIAL),
+          "every name under scan": dict(limit=1 << 40, scan_layers=True)}
+    got = _one_step(_build(monkeypatch, **kw[case]))
+    want = floor_step
+    assert want["plan"]["layers"] == [(), ()]
+    layers = got["plan"]["layers"]
+    assert _whole(layers, ALL_BUT_GATE) and "gate_up" in layers[0]
+    # exact: a kept residual is the value the recomputation would have
+    # produced (the tolerance of test_zero_placement.py, where unrolled
+    # and scanned programs sum in different orders)
+    np.testing.assert_allclose(got["loss"], want["loss"], rtol=1e-6)
+    np.testing.assert_allclose(got["gnorm"], want["gnorm"], rtol=1e-5)
+    for tree in ("grad", "master"):
+        for a, b in zip(jax.tree.leaves(got[tree]),
+                        jax.tree.leaves(want[tree])):
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)
+    if case != "every name under scan":
+        # the floor recomputes the kernel, the three products and the
+        # output projection's all-reduce over mp ...
+        kernels = {(s, o) for s, k, o in want["remat"]
+                   if o in ("while", "custom-call")}
+        assert ("attention", "while") in kernels \
+            or ("attention", "custom-call") in kernels, want["remat"]
+        assert ("attn_out", "collective", "all-reduce") in want["remat"]
+        for scope in ("qkv_proj", "attn_out", "ffn"):
+            assert (scope, "compute", "dot") in want["remat"], scope
+        # ... and a layer that kept them does none of it again
+        assert not [r for r in got["remat"] if r[0] == "attention"
+                    and r[2] in ("while", "custom-call", "dot")]
+        assert ("attn_out", "collective", "all-reduce") not in got["remat"]
+        for scope in ("qkv_proj", "attn_out"):
+            assert (scope, "compute", "dot") not in got["remat"], scope
+        # the gate / up product is computed again exactly where it was
+        # not kept
+        assert (("ffn", "compute", "dot") in got["remat"]) \
+            == (case == "gate_up in the first layer only")
+
+
+@needs_4
+def test_the_plan_is_reported(monkeypatch):
+    reg = obs.registry()
+    _, _, meta, _ = _build(monkeypatch, 1 << 40, plan=PARTIAL)
+    plan = meta["remat_plan"]
+    assert plan["layers"] == PARTIAL
+    assert plan["limit"] == 1 << 40
+    assert plan["margin"] == pretrain.REMAT_MARGIN_BYTES
+    assert plan["headroom"] == plan["limit"] - plan["margin"] \
+        - plan["floor_need"]
+    assert plan["need"] >= plan["floor_need"] > 0
+    # bytes a chip by shapes: 4 x 128 tokens over sharding 2, heads and
+    # columns over mp 2, float32
+    rows = 2 * 128
+    per = {"flash_o": rows * 1 * 64 * 4, "flash_lse": rows * 1 * 4,
+           "attn_out": rows * 128 * 4, "qkv": rows * (6 * 64 // 2) * 4,
+           "gate_up": rows * (2 * 256 // 2) * 4}
+    assert plan["saved_bytes"] == 2 * sum(per.values()) - per["gate_up"]
+    snap = reg.snapshot()
+    assert snap["trainer.remat.saved_bytes"]["series"][0]["value"] \
+        == plan["saved_bytes"]
+    layers = {tuple(s["labels"].values())[0]: s["value"]
+              for s in snap["trainer.remat.saved_layers"]["series"]}
+    assert layers == {"flash_o": 2, "flash_lse": 2, "attn_out": 2,
+                      "qkv": 2, "gate_up": 1}
+
+
+@needs_4
+def test_a_program_that_does_not_fit_is_cut_back_to_one_that_does(
+        monkeypatch):
+    """The chosen program's own need is held to the limit: the last
+    taken entries go until it fits, and the floor always remains."""
+    GB = 1 << 30
+    # floor 10, then gate_up + all: 16 (no), all but gate_up: 15.5 (no),
+    # flash + attn_out: 14 (fits under 16 - 1)
+    _, _, meta, _ = _build(monkeypatch, 16 * GB,
+                           needs=[10 * GB, 16 * GB, int(15.5 * GB), 14 * GB])
+    plan = meta["remat_plan"]
+    assert plan["layers"] == [FLASH + ("attn_out",)] * 2
+    assert (plan["floor_need"], plan["need"]) == (10 * GB, 14 * GB)
+    # nothing fits: today's program
+    _, _, meta, _ = _build(monkeypatch, 16 * GB,
+                           needs=[10 * GB] + [17 * GB] * 4)
+    assert meta["remat_plan"]["layers"] == [(), ()]
+    assert meta["remat_plan"]["need"] == 10 * GB
+
+
+def _text(built):
+    state, step, meta, ids = built
+    return step.lower(state, ids, ids).as_text()
+
+
+@needs_4
+@pytest.mark.parametrize("case", ["no limit", "no room", "pp 2", "dots"])
+def test_where_nothing_is_kept_the_program_is_todays(case, monkeypatch):
+    """A device that reports no `bytes_limit` (tier-1's CPU, a described
+    device), one with nothing free, and the configurations the plan does
+    not reach build the program as if no residual had a name."""
+    kw = {"no limit": dict(limit=None),
+          "no room": dict(limit=1 << 40, needs=[1 << 40]),
+          "pp 2": dict(limit=1 << 40, pp=2, mp=1, n_microbatches=2),
+          "dots": dict(limit=1 << 40, remat="dots")}[case]
+    built = _build(monkeypatch, **kw)
+    assert built[2]["remat_plan"]["layers"] == [(), ()]
+    assert built[2]["remat_plan"]["saved_bytes"] == 0
+    got = _text(built)
+    # today's: no limit read, and every name an identity whoever asks
+    monkeypatch.setattr(at, "keeps", lambda name: False)
+    from paddle_tpu.models import llama
+    from paddle_tpu.ops import pallas_flash
+    monkeypatch.setattr(llama, "_keeps", lambda name: False)
+    monkeypatch.setattr(pallas_flash, "_keeps", lambda name: False)
+    kw.pop("needs", None)
+    want = _text(_build(monkeypatch, **dict(kw, limit=None)))
+    assert got == want
