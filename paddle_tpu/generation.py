@@ -749,6 +749,45 @@ def _bailing_decode_params(model):
         rope_fn=lambda n: dict(zip(("cos", "sin"), cfg.rope_table(n))))
 
 
+def _lfm2_decode_params(model):
+    """Lfm2MoeForCausalLM on the hybrid body: TWO blocks a layer, of the
+    kinds ``pattern`` spells (static, outside the tree): ``C`` a gated
+    short convolution (``w_in`` whose output splits B | C | z, the
+    depthwise ``conv_w`` [hidden, K], ``w_out``) whose memory of a
+    sequence is the last K - 1 rows of B * z and nothing else; ``*``
+    rotary GQA with ``q_norm`` / ``k_norm`` leaves (`_gqa_mixer`); ``D``
+    a dense SwiGLU FFN, ``E`` a routed FFN in `_ffn_apply`'s layout —
+    sigmoid scores, the ``bias`` that picks, EVERY expert held and said
+    so (``held`` None, ``scale``: the routed layers' counts are taken).
+    ``attn_static`` has one record for each ``*`` block (the only ones
+    with pages); ``moe_static`` one for each ``E`` block.  The head is
+    the embedding (``head`` None)."""
+    from .models.lfm2 import arrays
+    inner, cfg = model.model, model.config
+    layers, moe_static = [], []
+    for blk in inner.layers:
+        d = dict(norm=blk.norm.weight._data)
+        if blk.kind in "DE":
+            mlp_w, st = _mlp_params(blk)
+            d.update(mlp_w)
+            if st is not None:
+                st.update(held=None, scale=cfg.routed_scaling_factor)
+                moe_static.append(st)
+        else:
+            d.update(arrays(blk.mixer.weights()))
+        if blk.kind == "*":
+            _heads_w(d, cfg.head_dim, "wq", "wk", "wv")
+        layers.append(d)
+    return dict(
+        cfg=cfg, family="hybrid", pattern=cfg.pattern,
+        embed=inner.embed_tokens.weight._data, layers=layers,
+        norm=inner.embedding_norm.weight._data, head=None,
+        moe_static=tuple(moe_static),
+        attn_static=(dict(heads=cfg.num_attention_heads, window=None,
+                          rope=""),) * cfg.pattern.count("*"),
+        rope_fn=lambda n: dict(zip(("cos", "sin"), cfg.rope_table(n))))
+
+
 def _mla_decode_params(model, weight_only_int8: bool = False,
                        algo: str = "weight_only_int8"):
     """DeepSeekV2ForCausalLM: multi-head latent attention with the
@@ -846,17 +885,21 @@ def _decode_params(model, weight_only_int8: bool = False,
         from .models.moe_llm import MoEModel
         from .models.bailing_hybrid import BailingHybridModel
         from .models.falcon_h1 import FalconH1Model
+        from .models.lfm2 import Lfm2MoeModel
         from .models.nemotron_h import NemotronHModel
         from .models.ouro import OuroModel
         from .models.phi4flash import Phi4FlashModel
         from .models.sdar import SDARMoeModel
         if isinstance(inner, (NemotronHModel, BailingHybridModel,
-                              FalconH1Model, Phi4FlashModel)):
+                              FalconH1Model, Phi4FlashModel,
+                              Lfm2MoeModel)):
             if enabled:
                 raise NotImplementedError(
                     "weight-only quantisation is not wired for the "
-                    "Nemotron-H, Ling (bailing_hybrid), Falcon-H1 and "
-                    "Phi-4-flash families")
+                    "Nemotron-H, Ling (bailing_hybrid), Falcon-H1, "
+                    "Phi-4-flash and LFM2 families")
+            if isinstance(inner, Lfm2MoeModel):
+                return _lfm2_decode_params(model)
             if isinstance(inner, Phi4FlashModel):
                 return _phi4flash_decode_params(model)
             if isinstance(inner, BailingHybridModel):
@@ -1362,11 +1405,11 @@ def _cached_step_body(p, max_len: int):
             "one row a layer")
     if p["family"] == "hybrid":
         raise NotImplementedError(
-            "a hybrid family (Nemotron-H, Ling, Falcon-H1: mixers whose "
-            "memory of a sequence is a slot of recurrent state, beside the "
-            "attention mixers' pages) decodes through "
-            "serving.ServingEngine; the contiguous-cache bodies keep rows "
-            "only")
+            "a hybrid family (Nemotron-H, Ling, Falcon-H1, Phi-4-flash: "
+            "mixers whose memory of a sequence is a slot of recurrent "
+            "state; LFM2: a convolution's last rows) beside the attention "
+            "mixers' pages decodes through serving.ServingEngine; the "
+            "contiguous-cache bodies keep K/V rows only")
     if p["family"] == "gpt":
         return _gpt_cached_step_body(p["cfg"], max_len)
     if p["family"] == "mla" and p["cfg"].hc_mult > 1:

@@ -189,18 +189,19 @@ def ssm_split(zxbcdt, c: NemotronHConfig):
     return zxbcdt[..., :d], zxbcdt[..., d:d + w], zxbcdt[..., d + w:]
 
 
-def ssm_conv(u_ext, w, b):
-    """The depthwise causal convolution and its silu: ``u_ext`` [L + K
-    - 1, W] is the K - 1 rows before the L rows, then the rows; ``w``
-    [W, K], ``b`` [W] or None (no bias).  Float32 inside; returns [L, W]
-    in ``u_ext``'s type."""
+def ssm_conv(u_ext, w, b, act=jax.nn.silu):
+    """The depthwise causal convolution and its activation (silu; None:
+    none, LFM2's short convolution): ``u_ext`` [L + K - 1, W] is the K -
+    1 rows before the L rows, then the rows; ``w`` [W, K], ``b`` [W] or
+    None (no bias).  Float32 inside; returns [L, W] in ``u_ext``'s
+    type."""
     K = w.shape[-1]
     L = u_ext.shape[0] - (K - 1)
     f32 = jnp.float32
     acc = 0.0 if b is None else b.astype(f32)[None]
     for j in range(K):
         acc = acc + w[:, j].astype(f32)[None] * u_ext[j:j + L].astype(f32)
-    return jax.nn.silu(acc).astype(u_ext.dtype)
+    return (acc if act is None else act(acc)).astype(u_ext.dtype)
 
 
 def ssm_operands(u, dt, L, c: NemotronHConfig):

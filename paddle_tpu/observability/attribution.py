@@ -243,6 +243,9 @@ SCOPES = ("embed", "attn_norm", "qkv_proj", "cache_write", "attention",
 #: output) and the differential heads' combine after the launch
 #: (`diff_combine`) as `attn_out`; a launch over ANOTHER block's pages
 #: is `shared_attention`, told from `attention` over a block's own.
+#: A gated short convolution's parts (`lfm_*`, LFM2) answer as a
+#: state-space mixer's do; the copy of its tails at a page's last row
+#: into the page's snapshot (`tail_snapshot`) is a `cache_write`.
 SCOPE_ALIASES = {"mla_q": "qkv_proj", "mla_kv": "qkv_proj",
                  "mla_attention": "attention", "mla_out": "attn_out",
                  "eva_attention": "attention", "eva_pool": "cache_write",
@@ -257,7 +260,9 @@ SCOPE_ALIASES = {"mla_q": "qkv_proj", "mla_kv": "qkv_proj",
                  "ssm1_in_proj": "qkv_proj", "ssm1_conv": "cache_write",
                  "ssm1_scan": "attention", "ssm1_out": "attn_out",
                  "gmu": "attn_out", "shared_attention": "attention",
-                 "diff_combine": "attn_out"}
+                 "diff_combine": "attn_out",
+                 "lfm_in_proj": "qkv_proj", "lfm_conv": "cache_write",
+                 "tail_snapshot": "cache_write", "lfm_out": "attn_out"}
 
 
 def scope(name: str):

@@ -64,7 +64,8 @@ __all__ = ["TraceEvent", "RequestTrace", "TraceRecorder", "recorder",
            "STEP_COUNTS_BY_KIND", "STEP_COUNTS_MOE", "STEP_COUNTS_LATENT",
            "STEP_COUNTS_EVA", "STEP_COUNTS_LOOP", "STEP_COUNTS_SSM",
            "STEP_COUNTS_MHC", "STEP_COUNTS_SHARED",
-           "STEP_COUNTS_DIFFUSION"]
+           "STEP_COUNTS_DIFFUSION", "STEP_COUNTS_PREFIX",
+           "STEP_COUNTS_TAIL"]
 
 _FLAG = _flags._registry["FLAGS_request_tracing"]
 
@@ -182,6 +183,23 @@ STEP_COUNTS_SSM: Tuple[str, ...] = (
     "ssm_slots_live", "ssm_state_bytes", "ssm_state_bytes_moved",
     "ssm_scan_rows", "ssm_state_resets", "state_pool_slots_used",
     "state_pool_slots_total")
+#: ... and where the engine holds a prefix cache (`serving.prefix_cache`:
+#: every family that keeps it, dense ones by default). Of the step's
+#: admissions (`_reserve_pages`): the prompt tokens and the pages adopted
+#: from the trie, and the trie pages evicted to make room
+STEP_COUNTS_PREFIX: Tuple[str, ...] = (
+    "prefix_tokens_adopted", "prefix_pages_adopted", "prefix_pages_evicted")
+#: ... and where the state blocks' memory is a FINITE HISTORY and nothing
+#: else (a short convolution's last K - 1 rows: LFM2), which can be cut
+#: at a page's last row, so the family keeps the prefix cache — the
+#: `STEP_COUNTS_SSM` counts read 0 bytes of state held and moved. Of the
+#: launch the record retires: the pages whose last row its chunk wrote
+#: (each one's tails go to the snapshot plane of EVERY such block),
+#: whether the chunk continued an adopted prefix from a snapshot (these
+#: two add up over a record's launches), and the bytes a slot holds in
+#: ONE such block (its tail; a page's snapshot is as many)
+STEP_COUNTS_TAIL: Tuple[str, ...] = (
+    "tail_snapshots_written", "tail_restores", "tail_bytes")
 #: ... and where the residual is wider than one stream a token
 #: (hyper-connections: `serving.engine._HyperResidual`). Of the launch
 #: the record retires: the rows mixed (the flat buffer's, idle rows

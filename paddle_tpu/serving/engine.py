@@ -293,6 +293,67 @@ def _pad_lanes(x, width: int):
     return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
 
 
+def _pairs_heads(p) -> bool:
+    """Whether the pool's KV head is TWO published heads side by side
+    (`_pack_head_pairs`), read from the decode parameters' shapes, never
+    a switch: a published head of half a 128-lane register, an even
+    count of them, in a family whose attention is `_gqa_mixer` over
+    separate q / k / v projections (differential heads are paired
+    already). The constraint is the chip's (`_pack_head_pairs`: a 64-lane
+    row has no tiled layout there), but the pairing is the POOL's layout,
+    so it is decided from the model alone and holds on every backend: the
+    program the CPU tests and the pins read is the one the chip runs, and
+    the pools, the copy-on-write and hand-off programs and the off-chip
+    compile see one shape a model. What it costs where nothing tiles —
+    the zero half of a query's lanes in the scores and in PV — is
+    arithmetic in a kernel that the page reads bound."""
+    cfg = p["cfg"]
+    return (p["family"] in ("llama", "moe", "laguna", "hybrid")
+            and not p.get("diff") and getattr(cfg, "head_dim", 0) == 64
+            and cfg.num_key_value_heads % 2 == 0)
+
+
+def _pack_head_pairs(q, k, v, kv: int):
+    """TWO published heads of width w side by side in one stored row of 2
+    w lanes: q [T, H, w], k / v [T, 2 kv, w] (None: a mixer that appends
+    nothing) -> q [T, H, 2 w], k / v [T, kv, 2 w]. A 64-wide row has no
+    tiled layout on the chip but a padded one (the pool would hold, and
+    every page fetch move, twice its bytes, and Mosaic refuses the
+    64-lane slice of it), so heads 2 p and 2 p + 1 share row p. K's lanes
+    are [first halves of a | of b | second halves of a | of b]: the
+    append kernel's half-split rotary turn pairs lane i with lane i + w,
+    each head's own two halves, under the row's angles tiled twice
+    (`_pair_angles`). V's lanes are [a | b]. A query head of side s
+    holds zeros in the other side's lanes, so its 2 w-wide score IS its
+    own head's w-wide score; of its 2 w-wide output its side's w lanes
+    are its own (`_unpack_head_pairs`)."""
+    T, H, w = q.shape
+    if k is not None:
+        k = k.reshape(T, kv, 2, 2, w // 2).swapaxes(2, 3).reshape(
+            T, kv, 2 * w)
+        v = v.reshape(T, kv, 2 * w)
+    side = jnp.arange(2)
+    own = side[:, None, None, None, None] == side[None, None, None, :, None]
+    q = jnp.where(own, q.reshape(T, kv, 2, H // (2 * kv), 2, 1, w // 2), 0)
+    return q.reshape(T, H, 2 * w), k, v
+
+
+def _pair_angles(cos, sin):
+    """The rows' angles [T, w / 2] for rows of two heads (`_pack_head_
+    pairs`): each head's, side by side."""
+    return (jnp.concatenate([cos, cos], -1),
+            None if sin is None else jnp.concatenate([sin, sin], -1))
+
+
+def _unpack_head_pairs(o, kv: int):
+    """o [T, H, 2 w] over paired rows -> [T, H, w]: each query head's
+    own side of V's lanes."""
+    T, H, w2 = o.shape
+    o = o.reshape(T, kv, 2, H // (2 * kv), 2, w2 // 2)
+    return jnp.stack([o[:, :, 0, :, 0], o[:, :, 1, :, 1]], 2).reshape(
+        T, H, w2 // 2)
+
+
 @functools.lru_cache(maxsize=None)
 def _kernel_jit(fn, scope: str, static):
     def run(*args):
@@ -486,7 +547,7 @@ def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
                runs, *, heads: int, kv: int, d: int, mults=None,
                window=None, diff=None, borrowed: bool = False,
                shared: bool = False, eps: float = 1e-5, block=None,
-               ride=None):
+               ride=None, pack: int = 1):
     """Grouped-query attention on the normed rows h [1, T, H] of a
     block's input, over the pages `pools` = (K, V): q / k / v (+ their
     biases, where the layer has them; ONE fused ``wqkv`` + ``bqkv``
@@ -532,11 +593,18 @@ def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
 
     The chain's ``*`` mixer — a llama / MoE / Laguna / gpt layer's,
     alone in its block (Nemotron-H, Phi-4-flash) or beside a state-space
-    mixer on the same norm (Falcon-H1) — and its ``X`` mixer."""
+    mixer on the same norm (Falcon-H1) — and its ``X`` mixer.
+
+    `pack` 2 (a published head of 64 lanes: LFM2): the pool's KV head is
+    TWO published heads side by side, as the differential pair is, with
+    no combine — ``kv``, ``d`` are the pool's, q / k / v are projected
+    and normalised at the published width and packed before the append
+    (`_pack_head_pairs`), and each head's output is its side of the
+    row's."""
     T = h.shape[1]
     kp, vp = pools
     cos, sin = rope or _no_turn(T, d, h.dtype)
-    dq = d // 2 if diff is not None else d
+    dq = d // 2 if diff is not None or pack > 1 else d
     with _scope("qkv_proj"):
         if mults:
             h = h * mults["attention_in"]
@@ -557,8 +625,16 @@ def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
     if "q_norm" in L:
         with _scope("qk_norm"):
             q = _once(fused_rms_norm, "qk_norm", q, L["q_norm"], eps=eps)
-            k = _once(fused_rms_norm, "qk_norm", k.reshape(T, kv, d),
-                      L["k_norm"], eps=eps)
+            k = _once(fused_rms_norm, "qk_norm",
+                      k.reshape(T, kv * pack, d // pack), L["k_norm"],
+                      eps=eps)
+    if pack > 1:
+        with _scope("cache_write"):
+            q, k, v = _pack_head_pairs(
+                q, *((None, None) if borrowed else (
+                    k.reshape(T, kv * pack, dq), v.reshape(T, kv * pack,
+                                                           dq))), kv)
+            cos, sin = _pair_angles(cos, sin)
     if not borrowed:
         with _scope("cache_write"):
             if ride:
@@ -584,6 +660,8 @@ def _gqa_mixer(L, h, rope, pools, seq_start, num_tokens, kv_lengths, tables,
         with jax.named_scope("diff_combine"):
             o = diff_combine(o, L, diff, eps).astype(h.dtype)
     with _scope("attn_out"):
+        if pack > 1:
+            o = _unpack_head_pairs(o, kv)
         if "wgate" in L:
             g = jax.nn.sigmoid(_mm_w(h, L, "wgate"))[0]
             o = o * g[..., None].astype(o.dtype)
@@ -605,8 +683,8 @@ def _no_turn(T: int, d: int, dtype, zeros: bool = False):
 
 def _pattern_blocks(pattern: str) -> Tuple[str, ...]:
     """A hybrid's pattern as its blocks. A letter is a block of ONE
-    sublayer on its own norm — a mixer (``M`` ``K`` ``S`` ``*`` ``L``)
-    or an FFN (``E`` ``D``); ``[..]`` is a block whose one norm feeds
+    sublayer on its own norm — a mixer (``M`` ``K`` ``S`` ``C`` ``*``
+    ``L``) or an FFN (``E`` ``D``); ``[..]`` is a block whose one norm feeds
     SEVERAL mixers, their outputs summed into the residual (``[M*]D``: a
     Falcon-H1 layer). ``G<j>`` and ``X<j>`` are mixers that own NO
     memory and read block j's: a gated unit over the scan output of the
@@ -627,10 +705,10 @@ def _pattern_blocks(pattern: str) -> Tuple[str, ...]:
         if b[:1] in ("G", "X") and b[1:].isdigit() and int(b[1:]) < n and \
                 blocks[int(b[1:])] == "S*"[b[0] == "X"]:
             continue
-        if not b or set(b) - set("MKS*LED") or \
+        if not b or set(b) - set("MKSC*LED") or \
                 len(b) > 1 and set(b) - set("MK*L"):
             raise ValueError(
-                f"pattern {pattern!r}: a block is one letter of MKS*LED, "
+                f"pattern {pattern!r}: a block is one letter of MKSC*LED, "
                 f"several mixers (MK*L) in brackets, or G<j> / X<j> with j "
                 f"an earlier S / * block")
     return tuple(blocks)
@@ -722,6 +800,8 @@ def _chain_of(p, attn_static, layer_kind, pool_readers, kv_geom,
     if block:
         gqa["block"] = block
     diff = p.get("diff", {})
+    if _pairs_heads(p):
+        gqa["pack"] = 2     # two published heads a stored row
     owned = iter(zip(layer_kind, attn_static, pool_readers))
     for i, b in enumerate(blocks):
         if b.kind[0] == "X":    # attention over another block's pages
@@ -887,6 +967,7 @@ _ADDITIVE = frozenset(
     + _tracing.STEP_COUNTS_BY_KIND[:4]
     + _tracing.STEP_COUNTS_EVA[:4] + _tracing.STEP_COUNTS_EVA[-1:]
     + ("ssm_state_bytes_moved", "ssm_scan_rows", "ssm_state_resets")
+    + _tracing.STEP_COUNTS_TAIL[:2]
     + _tracing.STEP_COUNTS_MHC[:1] + _tracing.STEP_COUNTS_DIFFUSION[:6])
 
 
@@ -1040,23 +1121,37 @@ class ServingEngine:
                 enable_prefix_cache, spec_decode, role)
             # a forked page's copy-on-write would copy pass 0 only
             enable_prefix_cache = prefix_sharing = False
-        # a hybrid's state-space mixers keep a FIXED-SIZE state a
-        # sequence, in the slot the scheduler gave it, beside the pages
-        # of its attention mixers — in other blocks (Nemotron-H, Ling)
-        # or in the SAME block, on the same norm (Falcon-H1, where every
-        # layer holds a slot and pages): two kinds of cache, one
-        # engine. A state cannot be cut at a token: nothing can adopt a
-        # prefix of it, roll it back or move it, and a slot that is
-        # given away takes the state with it
+        # a hybrid's state blocks keep a FIXED-SIZE memory a sequence, in
+        # the slot the scheduler gave it, beside the pages of its
+        # attention mixers — in other blocks (Nemotron-H, Ling, LFM2) or
+        # in the SAME block, on the same norm (Falcon-H1, where every
+        # layer holds a slot and pages): two kinds of cache, one engine.
+        # Which memories can be cut at a token: K/V ROWS can (a page
+        # holds rows that depend on the tokens up to it and on nothing
+        # after), and so can a FINITE HISTORY (`C`: a short convolution
+        # remembers the last K - 1 rows of one stream — the rows before
+        # a token are all a continuation needs, so a copy of them taken
+        # at a page's last row, a SNAPSHOT, lets a later sequence adopt
+        # the pages up to there). A RECURRENT state (`M` `K` `S`) cannot:
+        # it is one summary of everything before, nothing can adopt a
+        # prefix of it without a copy of the whole state taken at that
+        # token, roll it back or move it. And a slot that is given away
+        # takes either kind of memory with it
         pattern = p["pattern"] if self._family == "hybrid" else ""
         self._blocks = _pattern_blocks(pattern)
-        self._ssm_layers = sum(pattern.count(k) for k in "MKS")
+        self._ssm_layers = sum(pattern.count(k) for k in "MKSC")
         # ... of ONE kind a model: Mamba-2's (`M`), the delta rule's
-        # (`K`, a KDA linear-attention block) or Mamba-1's (`S`); and
+        # (`K`, a KDA linear-attention block), Mamba-1's (`S`) or a
+        # short convolution's tail and NO state (`C`); and
         # the attention blocks' pages hold GQA rows (`*`) or latent rows
         # (`L`). A block that BORROWS (`G<j>`, `X<j>`) holds neither: it
         # reads block j's scan output / pages inside the launch
-        self._state_kind = next((k for k in "KS" if k in pattern), "M")
+        self._state_kind = next((k for k in "KSC" if k in pattern), "M")
+        # the state blocks' memory is a finite history and nothing else
+        self._tail_only = self._state_kind == "C"
+        # ... and a page id names, beside its K/V rows, the tails at its
+        # last row in every such block (the prefix cache is on)
+        self._tail_snapshots = False
         self._state_layout = HEADS_MINOR
         self._latent = self._family == "mla" or "L" in pattern
         # the blocks that read each page-holding block's pool in a
@@ -1067,21 +1162,46 @@ class ServingEngine:
             1 + sum(b == f"X{i}" for b in self._blocks) for i in owners] \
             if owners else [1] * len(self._attn_static)
         if self._ssm_layers:
-            if sum(k in pattern for k in "MKS") > 1 or \
+            if sum(k in pattern for k in "MKSC") > 1 or \
                     "L" in pattern and "*" in pattern:
+                # (so a model ALL of whose state blocks are finite
+                # histories is one whose kind is `C`; a mix would keep
+                # the whole refusal below, and is not built)
                 raise NotImplementedError(
                     f"pattern {pattern!r}: one kind of state block and "
                     f"one kind of attention block a model")
             what = "linear-attention (delta-rule)" \
-                if self._state_kind == "K" else "state-space"
+                if self._state_kind == "K" else "short-convolution" \
+                if self._tail_only else "state-space"
             _refuse_shared_cache(
                 f"this model has {self._ssm_layers} {what} blocks, "
-                f"whose memory of a sequence is one recurrent state in "
-                f"its slot, not rows that a snapshot could cut, so ",
-                enable_prefix_cache, spec_decode, role)
-            # neither is a preempted sequence's state kept once its slot
-            # is handed on (ROADMAP R4 b): the engine never preempts
-            enable_prefix_cache = prefix_sharing = preemption = False
+                + ("whose memory of a sequence is the last rows of a "
+                   "stream in its slot: a prefix is adopted at a PAGE "
+                   "border from a snapshot, never at a token, so "
+                   if self._tail_only else
+                   "whose memory of a sequence is one recurrent state in "
+                   "its slot, not rows that a snapshot could cut, so "),
+                enable_prefix_cache and not self._tail_only, spec_decode,
+                role)
+            if self._tail_only:
+                on = enable_prefix_cache if enable_prefix_cache is not None \
+                    else getattr(config, "_prefix_cache", None)
+                self._tail_snapshots = on in (None, True)
+                if self._tail_snapshots and \
+                        self.prefill_chunk % self.page_size:
+                    raise ValueError(
+                        f"prefill_chunk {self.prefill_chunk} must be whole "
+                        f"pages of {self.page_size} (page_size) while the "
+                        f"prefix cache is on: a page's snapshot of the "
+                        f"convolutions' tails is taken at a STATIC row of "
+                        f"the chunk that writes the page's last token, so "
+                        f"every chunk starts on a page border")
+            else:
+                enable_prefix_cache = False
+            # a live donor shares at a TOKEN, where no snapshot is; and a
+            # preempted sequence's state or tails are not kept once its
+            # slot is handed on (ROADMAP R4 b): the engine never preempts
+            prefix_sharing = preemption = False
         # generation by diffusion over blocks: a decode slot owns a
         # BLOCK of `block_length` flat rows that see each other, fed for
         # `denoising_steps` denoise passes and one commit pass; the
@@ -1192,6 +1312,10 @@ class ServingEngine:
                 # differential heads: the pool's KV head is a PAIR of
                 # published heads side by side (`_gqa_mixer`)
                 kv, d = kv // 2, 2 * d
+            elif _pairs_heads(p):
+                # a published head of half a 128-lane register: two of
+                # them a stored row, so that a row is whole registers
+                kv, d = kv // 2, 2 * d
         shape = (kv, self._passes * self.num_pages, self.page_size, d)
         wshape = (kv, self.num_window_pages, self.page_size, d)
         # each layer's kind: 0 keeps every page, 1 is the window kind
@@ -1231,7 +1355,12 @@ class ServingEngine:
             # (a KDA block's state is heads-major, a [K, V] tile a head
             # with V along the lanes: `ops.pallas_kda` says why; its
             # tail holds the q, k and v convolutions' rows side by side)
-            if self._state_kind == "K":
+            # (a `C` block has NO state: its pool entry is its tail and,
+            # with the prefix cache on, the snapshot plane — the tails at
+            # every page's last row, by page id)
+            if self._tail_only:
+                self._state_shape = None
+            elif self._state_kind == "K":
                 nh, hd = cfg.num_attention_heads, cfg.head_dim
                 if not _kda_step_eligible(nh, hd, self.prefill_chunk,
                                           cfg.kda_sub_chunk):
@@ -1263,7 +1392,12 @@ class ServingEngine:
                        else (jnp.zeros(sh, dt), jnp.zeros(sh, dt))
                        for sh in (wshape if k else shape
                                   for k in self._layer_kind)],
-                "ssm": [(jnp.zeros(self._state_shape, jnp.float32),
+                "ssm": [(jnp.zeros(self._tail_shape, dt),)
+                        + ((jnp.zeros((self.num_pages,)
+                                      + self._tail_shape[1:], dt),)
+                           if self._tail_snapshots else ())
+                        if self._tail_only else
+                        (jnp.zeros(self._state_shape, jnp.float32),
                          jnp.zeros(self._tail_shape, dt))
                         for _ in range(self._ssm_layers)]}
         else:
@@ -1316,6 +1450,10 @@ class ServingEngine:
             self._count_names += _tracing.STEP_COUNTS_LOOP
         if self._ssm_layers:
             self._count_names += _tracing.STEP_COUNTS_SSM
+        if self.prefix_cache is not None:
+            self._count_names += _tracing.STEP_COUNTS_PREFIX
+        if self._tail_only:
+            self._count_names += _tracing.STEP_COUNTS_TAIL
         if max(self._pool_readers) > 1:
             self._count_names += _tracing.STEP_COUNTS_SHARED
         if self._hc > 1:
@@ -1361,8 +1499,15 @@ class ServingEngine:
         # a state-space block's pool: every slot's state and tail
         self._ssm_state_bytes = self._ssm_slot_bytes = 0
         if self._ssm_layers:
-            self._ssm_state_bytes = 4 * int(np.prod(self._state_shape[1:]))
-            if self._state_kind == "K":
+            # the tail a slot holds in ONE state block
+            self._tail_bytes = int(np.prod(self._tail_shape[1:])) \
+                * self._kv_itemsize
+            # (tail only: no state held, none moved)
+            self._ssm_state_bytes = 0 if self._tail_only \
+                else 4 * int(np.prod(self._state_shape[1:]))
+            if self._tail_only:
+                self._ssm_slot_bytes = self._tail_bytes
+            elif self._state_kind == "K":
                 self._ssm_slot_bytes = \
                     _costmodel.kda_state_bytes_per_seq_layer(
                         heads=cfg.num_attention_heads,
@@ -1385,6 +1530,9 @@ class ServingEngine:
                         conv_dtype_bytes=self._kv_itemsize)
             self._hbm_pool_bytes += self._ssm_layers * (
                 self.max_slots + 1) * self._ssm_slot_bytes
+            if self._tail_snapshots:
+                self._hbm_pool_bytes += self._ssm_layers * self.num_pages \
+                    * self._tail_bytes
         # a wide residual: the bytes of one row of the stream as stored
         self._stream_row_bytes = (self._hc * cfg.hidden_size
                                   * self._kv_itemsize) if self._hc > 1 else 0
@@ -1547,7 +1695,10 @@ class ServingEngine:
             lambda pools, src, dst: jax.tree_util.tree_map(
                 lambda p: p.at[:, dst].set(p[:, src]), pools),
             donate_argnums=0)
-        if self.prefix_sharing or self.prefix_cache is not None:
+        # (nor where pages are adopted WHOLE and never forked — the
+        # finite-history family: an adopter's first write is a fresh page)
+        if (self.prefix_sharing or self.prefix_cache is not None) \
+                and not self._tail_snapshots:
             self._copy_pages(*np.zeros((2, self._copy_slots), np.int32))
         # a block's logits rows for `on_block`: [B, vocab] of [slots x B,
         # vocab], the slot a traced argument (one compile, at first use)
@@ -1651,6 +1802,11 @@ class ServingEngine:
             raise ValueError(
                 "a model with state-space blocks cannot roll a rejected "
                 "draft's state back: spec_decode stays 0")
+        if self._tail_snapshots and new_chunk % self.page_size:
+            raise ValueError(
+                f"prefill_chunk {new_chunk} must be whole pages of "
+                f"{self.page_size} while the prefix cache keeps a "
+                f"snapshot of the convolutions' tails a page")
         if self._block and (new_k or new_chunk % self._block):
             raise ValueError(
                 f"a model that generates by diffusion over blocks takes "
@@ -1943,6 +2099,11 @@ class ServingEngine:
                 self._ledger_model_bytes / self._ledger_tokens
                 if self._ledger_tokens else 0.0),
         }
+        if self._tail_snapshots:
+            # the tails at every page's last row, every `C` block
+            # (inside `page_pool_bytes`, as the state pool is)
+            acct["tail_snapshot_bytes"] = float(
+                self._ssm_layers * self.num_pages * self._tail_bytes)
         # KV heads and query tiles a page visit of the ragged kernel
         # serves, and the rows it computes for a sequence that owns a
         # few of a tile's (0: always the tile's), by layer kind (the
@@ -2152,9 +2313,9 @@ class ServingEngine:
                 f"a handoff at a block border is not implemented")
         if self._ssm_layers:
             raise NotImplementedError(
-                f"{what}: this model has state-space blocks; a handoff of "
-                f"a sequence's recurrent state beside its KV pages is not "
-                f"implemented")
+                f"{what}: this model has state blocks; a handoff of a "
+                f"sequence's recurrent state or convolution tails beside "
+                f"its KV pages is not implemented")
         if self._passes > 1:
             raise NotImplementedError(
                 f"{what}: this model runs its layers {self._passes} times "
@@ -2315,8 +2476,10 @@ class ServingEngine:
                     raise
                 eff = match.tokens if use_cache else share
                 need = self.allocator.pages_needed(req.total_tokens, eff)
-                if self.prefix_cache.evict(
-                        need - self.allocator.available_pages) <= 0:
+                freed = self.prefix_cache.evict(
+                    need - self.allocator.available_pages)
+                self._counts["prefix_pages_evicted"] += freed
+                if freed <= 0:
                     raise
                 take()
         except _res.Overloaded:
@@ -2328,6 +2491,8 @@ class ServingEngine:
             req._share_source = "cache"
             req._share_meta = {"pages": len(match.pages)}
             req.prefill_pos = req.shared_tokens = match.tokens
+            self._counts["prefix_tokens_adopted"] += match.tokens
+            self._counts["prefix_pages_adopted"] += len(match.pages)
         elif share > 0:
             req._share_source = "donor"
             req._share_meta = {"donor": donor.request_id}
@@ -2784,22 +2949,44 @@ class ServingEngine:
             # chunk's slot (the spare without a chunk) and whether this
             # launch STARTS its sequence — from zero state, by this
             # flag, on the device
+            # (a family whose pages carry snapshots of its tails: one
+            # entry more, the page whose snapshot the chunk CONTINUES —
+            # the last page its sequence adopted — or 0, the trash page,
+            # for none)
             named = [slot for slot, _, _ in rows]
-            tab = np.full(B + 3, B, np.int32)
+            tab = np.full(B + 3 + self._tail_snapshots, B, np.int32)
             tab[:len(named)] = named
             tab[B] = len(named)
-            starts = int(preq is not None and start == 0)
+            # (a sequence starts behind what it adopted: 0 where nothing
+            # can be)
+            starts = int(preq is not None and start == preq.shared_tokens)
             tab[B + 2] = starts
             if preq is not None:
                 tab[B + 1] = preq.slot
             slots = len(named) + (preq is not None)
             counts.update({
                 "ssm_slots_live": slots,
-                "ssm_state_bytes": self._ssm_slot_bytes,
+                "ssm_state_bytes": 0 if self._tail_only
+                else self._ssm_slot_bytes,
                 # (a Python int: 5.4e9 at the benchmark's sizes)
                 "ssm_state_bytes_moved": (2 * slots - starts)
                 * self._ssm_layers * self._ssm_state_bytes,
                 "ssm_scan_rows": n, "ssm_state_resets": starts})
+            if self._tail_only:
+                restore = starts and start > 0
+                if self._tail_snapshots:
+                    tab[B + 3] = self.allocator.table(
+                        preq.request_id)[start // ps - 1] if restore else 0
+                counts.update({
+                    # the pages whose last row the chunk writes (their
+                    # tails go to the planes, every `C` block's), whether
+                    # it continues a snapshot, and a slot's tail in ONE
+                    # such block
+                    "tail_snapshots_written":
+                    (start + n) // ps - start // ps
+                    if self._tail_snapshots else 0,
+                    "tail_restores": int(restore),
+                    "tail_bytes": self._tail_bytes})
         # what the step takes as `kv_lengths`: with a state table beside
         kvl = (kv_lengths, tab) if self._ssm_layers \
             else (kv_lengths, take) if blk else kv_lengths
@@ -3055,7 +3242,12 @@ class ServingEngine:
         if not copies:
             return
         # (a copied page is a shared page: never under a window, whose
-        # allocator refuses fork and adopt)
+        # allocator refuses fork and adopt; nor where pages are adopted
+        # whole and only then — the copy would leave the page's snapshot
+        # behind)
+        if self._tail_snapshots:
+            raise RuntimeError("a page was forked where prefixes are "
+                               "adopted at page borders only")
         self._counts["cow_pages"] += len(copies)
         if req is not None:
             _TRACE.stamp(req.request_id, "cow", pages=len(copies))
@@ -3339,6 +3531,26 @@ class ServingEngine:
         decode slots then the spare, their count, the chunk's slot,
         whether the launch starts it.
 
+        Which of these memories can be CUT at a token: the tail can — it
+        is the last K - 1 rows of a stream, and the rows before a token
+        are all its continuation needs — the recurrent state cannot (one
+        summary of everything before). So ``C``, whose memory is a tail
+        and NOTHING else, keeps the prefix cache, and ``M`` ``K`` ``S``
+        refuse it.
+
+        ``C``, a gated short convolution (LFM2): ``(L, a, the tail
+        pool[, the snapshot plane], num_tokens, the state table, the
+        chunk's page ids) -> (its output, the tail pool[, the plane])``.
+        In-projection to three streams B | C | z (`lfm_in_proj`) -> u =
+        B * z through `conv_tails` with NO activation (`lfm_conv`) ->
+        the gate C and the out-projection (`lfm_out`). With the prefix
+        cache on the block holds a second plane [num_pages, K - 1,
+        hidden], the tails at every page's LAST row by page id, and the
+        table one entry more: the chunk of a launch writes the plane at
+        the pages it fills (`tail_snapshot`) and, where it is the first
+        chunk of a sequence that ADOPTED a cached prefix, reads the last
+        adopted page's entry where a fresh sequence reads zeros.
+
         ``S``, a Mamba-1 state-space mixer (a decay a (channel, state
         column); the slot's state [1, N, C], channels along the lanes):
         in-projection (`ssm1_in_proj`) -> `_conv_tails` (`ssm1_conv`)
@@ -3394,31 +3606,56 @@ class ServingEngine:
                 return m
             return jnp.pad(m, ((0, 1),) + ((0, 0),) * (m.ndim - 1))
 
-        def conv_tails(u, t_pool, conv_w, conv_b, live, n_c, cslot, starts):
+        def conv_tails(u, t_pool, conv_w, conv_b, live, n_c, cslot, starts,
+                       conv=ssm_conv, snap=None):
             """The causal convolution of the launch's rows u [T, W], a
             decode row from its slot's tail, the chunk's rows from its
             slot's tail (zeros at a start) and from each other; the
             last K - 1 rows before each sequence's next one are written
-            back. -> (the convolved rows, the tail pool)."""
+            back. -> (the convolved rows, the tail pool). `conv`: the
+            convolution and its activation (`ssm_conv`: silu).
+
+            `snap` = (the snapshot plane [num_pages, K - 1, W], the page
+            whose entry a STARTING chunk continues or 0, the page ids of
+            the chunk's pages [C / page_size]): the chunk starts on a
+            page border (the engine refuses another chunk length), so
+            page j of the chunk ends at the STATIC row page_size * (j +
+            1) - 1 and the tails there are the page's last K - 1 rows of
+            u. Pages past the chunk's length are the trash page or not
+            yet full: what is written for them is garbage that nobody
+            reads — the trie holds FULL prompt pages only, and a page id
+            that is reused is overwritten, by the chunk that fills it,
+            before it is inserted again. -> (..., the plane)."""
             # decode row s is slot s: its tail, then its own row
             tails = t_pool[:B]
             ext = jnp.concatenate([tails, u[:B, None]], 1)
-            conv_d = jax.vmap(ssm_conv, (0, None, None))(
+            conv_d = jax.vmap(conv, (0, None, None))(
                 ext, conv_w, conv_b)[:, 0]
             if not C:       # no chunk part: the decode rows' tails alone
-                return conv_d, t_pool.at[:B].set(
-                    jnp.where(live[:, None, None], ext[:, 1:], tails))
+                return (conv_d, t_pool.at[:B].set(
+                    jnp.where(live[:, None, None], ext[:, 1:], tails))) \
+                    + (() if snap is None else snap[:1])
             # the chunk: its slot's tail (zeros at a start), its rows
             tail_c = jnp.where(starts, 0, t_pool[cslot])
+            if snap is not None:
+                plane, adopted, pages = snap
+                # ... or, behind an adopted prefix, the tails at its end
+                tail_c = jnp.where(starts & (adopted > 0), plane[adopted],
+                                   tail_c)
+                with jax.named_scope("tail_snapshot"):
+                    ps = self.page_size
+                    plane = plane.at[pages].set(
+                        u[B:].reshape(C // ps, ps, -1)[:, ps - (K - 1):])
             ext_c = jnp.concatenate([tail_c, u[B:]])
-            conv_c = ssm_conv(ext_c, conv_w, conv_b)
+            conv_c = conv(ext_c, conv_w, conv_b)
             t_pool = t_pool.at[:B].set(
                 jnp.where(live[:, None, None], ext[:, 1:], tails))
             t_pool = jax.lax.dynamic_update_slice(
                 t_pool, jax.lax.dynamic_slice(
                     ext_c, (n_c, 0), (K - 1, ext_c.shape[1]))[None],
                 (cslot, 0, 0))
-            return jnp.concatenate([conv_d, conv_c]), t_pool
+            return (jnp.concatenate([conv_d, conv_c]), t_pool) \
+                + (() if snap is None else (plane,))
 
         def chunk_state(z_pool, n_c, cslot, starts, scan, y_shape, scope):
             """The chunk's rows through ``scan(state it starts from)``
@@ -3591,7 +3828,24 @@ class ServingEngine:
                                    eps).astype(a.dtype)
                 return y @ L["wo"], z_pool, t_pool
 
-        return {"M": ssm, "K": kda, "S": ssm1}
+        def lfm(L, a, mem, num_tokens, tab, pages):
+            """The gated short convolution of a [T, hidden]; ``mem`` is
+            the block's pool entry, (the tail pool[, the snapshot
+            plane]) -> (its output [T, hidden], the entry after)."""
+            cslot, starts = tab[B + 1], tab[B + 2] > 0
+            with jax.named_scope("lfm_in_proj"):
+                gate_b, gate_c, z = jnp.split(a @ L["w_in"], 3, axis=-1)
+                u = gate_b * z
+            with jax.named_scope("lfm_conv"):
+                u, *mem = conv_tails(
+                    u, mem[0], L["conv_w"], None, num_tokens[:B] > 0,
+                    num_tokens[B], cslot, starts,
+                    conv=functools.partial(ssm_conv, act=None),
+                    snap=(mem[1], tab[B + 3], pages) if mem[1:] else None)
+            with jax.named_scope("lfm_out"):
+                return (gate_c * u) @ L["w_out"], tuple(mem)
+
+        return {"M": ssm, "K": kda, "S": ssm1, "C": lfm}
 
     def _chain_unified_body(self, C: int):
         """The step of every family whose layers are a list of BLOCKS
@@ -3718,7 +3972,13 @@ class ServingEngine:
                 # the block's mixers, each on the one normed input; the
                 # residual takes one after the other
                 for kind in blk.kind:
-                    if kind in "MKS":
+                    if kind == "C":     # a tail and no state
+                        y, mem = state[kind](
+                            L, a[0], next(ssm_pools), num_tokens, tab,
+                            tok_page[0][B * R:B * R + C:self.page_size])
+                        new_ssm.append(mem)
+                        y = y[None]
+                    elif kind in "MKS":
                         y, z_pool, t_pool, *scan = state[kind](
                             L, a[0], *next(ssm_pools), num_tokens, tab)
                         new_ssm.append((z_pool, t_pool))

@@ -13,6 +13,14 @@ Exactness discipline (why sharing is safe):
   - causal attention + absolute position embeddings mean a page's KV
     rows depend only on the token prefix up to and through that page —
     the trie path IS that prefix, so a path match is an exact KV match;
+  - where the model's state blocks are FINITE HISTORIES (a short
+    convolution's last K - 1 rows: LFM2), a page id names, beside its
+    K/V rows, the TAILS at its last row in every such block (the
+    engine's snapshot planes, written by the chunk that fills the page):
+    they too depend on the token prefix through that page and on nothing
+    else, so an adopter that continues from the last adopted page's
+    tails is exact; a RECURRENT state has no such cut, and the engine
+    builds no trie for those models;
   - only FULL pages are cached, so an adopter's first write lands on a
     page boundary (a fresh page) — trie pages are never written after
     insertion and need no COW;

@@ -329,6 +329,11 @@ def _sdar():
     return seeded(max_position_embeddings=64)[0]
 
 
+def _lfm2():
+    from test_lfm2 import seeded
+    return seeded(max_position_embeddings=64)[0]
+
+
 #: family -> names its unified step must show
 FAMILIES = {
     "llama": {"ffn"}, "moe": {"routed_ffn", "shared_expert"},
@@ -345,6 +350,10 @@ FAMILIES = {
     # generation by diffusion over blocks: the q / k norms and the
     # transfer rule under names of their own
     "block_diffusion": {"routed_ffn", "qk_norm", "unmask"},
+    # a mixer whose memory is a tail only, the prefix cache on: its
+    # parts and the snapshot's copy under names of their own (through
+    # SCOPE_ALIASES), a dense and a routed FFN, the q / k norms
+    "hybrid_tail_only": {"ffn", "routed_ffn", "qk_norm"},
 }
 EVERY_STEP = {"embed", "attn_norm", "qkv_proj", "cache_write", "attention",
               "attn_out", "ffn_norm", "head"}
@@ -357,7 +366,8 @@ def test_every_op_of_a_serving_step_answers_to_a_name(family):
         else _nemotron() if family == "hybrid" \
         else _falcon() if family == "hybrid_two_mixers" \
         else _phi4flash() if family == "hybrid_borrowed" \
-        else _sdar() if family == "block_diffusion" else _tiny(family)
+        else _sdar() if family == "block_diffusion" \
+        else _lfm2() if family == "hybrid_tail_only" else _tiny(family)
     kw = dict(max_slots=3, page_size=8, max_context=256, prefill_chunk=8,
               num_pages=64) if family == "eva" else \
         dict(max_slots=2, page_size=8, max_context=64, prefill_chunk=8)
@@ -408,6 +418,13 @@ def test_every_op_of_a_serving_step_answers_to_a_name(family):
             fills = {"f32[1,16,128]", "f32[8,128]"}
             assert not [r for r in step if r.scope is None
                         and r.kind == "compute" and r.shape not in fills]
+        if family == "hybrid_tail_only":
+            # the snapshot is taken where there is a chunk, and only there
+            own = {r.own for r in step}
+            assert {"lfm_in_proj", "lfm_conv", "lfm_out"} <= own
+            assert ("tail_snapshot" in own) == (name == "unified")
+            assert not [r for r in step if r.scope is None
+                        and r.kind == "compute"]
     # ... and read together, as a trace's reader does: a key the two
     # answer differently keeps neither scope, and few do
     table = at.op_scopes(programs)

@@ -224,6 +224,36 @@ class TestEnginePrefixCache:
         np.testing.assert_array_equal(out[r2.request_id],
                                       _solo(model, prompt, 3))
 
+    @pytest.mark.parametrize("cache", [True, False])
+    def test_step_record_counts_the_admissions(self, model, cache):
+        # a DENSE engine with the cache on counts what it adopted and
+        # evicted in its step records (tracing.STEP_COUNTS_PREFIX), as
+        # every family that keeps the cache does; without the cache the
+        # records have no such counts
+        from paddle_tpu.observability import tracing
+        V = model.config.vocab_size
+        rng = np.random.RandomState(5)
+        system = rng.randint(0, V, 12).astype(np.int32)   # 3 full pages
+        eng = ServingEngine(model, max_slots=1, page_size=4,
+                            prefill_chunk=4, num_pages=8, max_context=20,
+                            prefix_sharing=False, enable_prefix_cache=cache)
+        ev0 = _metric("serving.prefix_cache.evicted_pages")
+        at = eng.steps
+        for _ in range(4):
+            prompt = np.concatenate(
+                [system, rng.randint(0, V, 6).astype(np.int32)])
+            eng.add_request(prompt, max_new_tokens=2)
+            eng.run_to_completion()
+        recs = tracing.recorder().steps()[-(eng.steps - at):]
+        names = tracing.STEP_COUNTS_PREFIX
+        assert all((k in r) == cache for r in recs for k in names)
+        if cache:
+            tokens, pages, evicted = (sum(r[k] for r in recs)
+                                      for k in names)
+            assert (tokens, pages) == (3 * 12, 3 * 3)
+            assert evicted == _metric(
+                "serving.prefix_cache.evicted_pages") - ev0 > 0
+
     def test_config_set_prefix_cache(self, model):
         cfg = Config()
         cfg.set_prefix_cache(False)

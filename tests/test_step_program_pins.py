@@ -2,7 +2,7 @@
 
 ONE place for what guards "another family's program did not move": the
 sha256 of `eng._programs[name].lower(...).as_text()` (no debug info) for
-thirteen toy families times the two row counts the step body is compiled
+fourteen toy families times the two row counts the step body is compiled
 at (`unified`: the decode rows and a prefill chunk; `unified_nochunk`:
 the decode rows alone), at toy widths, on the CPU under the suite's
 matmul precision. The toy models and engines are the family files' own
@@ -22,7 +22,9 @@ gpt, laguna, eva, looped, nemotron and ling are the ones PR 55 / PR 57
 before `engine.py` was touched; sdar's two came with PR 60, which moved
 none of the other twenty-four, and were re-recorded by PR 61 (a block's
 commit rides in the next block's first launch: a region of rows, its
-sequences, an append of its own), which moved none of them either.
+sequences, an append of its own), which moved none of them either;
+lfm2's two came with PR 64 (a mixer whose memory is a tail only, a
+snapshot plane a block), which moved none of the twenty-six.
 """
 
 import functools
@@ -35,6 +37,7 @@ import pytest
 import test_bailing_hybrid_serving as ling
 import test_evabyte_serving as eva
 import test_falcon_h1_serving as falcon_h1
+import test_lfm2_serving as lfm2
 import test_nemotron_h_serving as nemotron
 import test_ouro_serving as looped
 import test_phi4flash_serving as phi4flash
@@ -54,6 +57,7 @@ ENGINES = {
     "falcon_h1": lambda: falcon_h1._engine(falcon_h1.seeded()[0]),
     "phi4flash": lambda: phi4flash._engine(phi4flash.seeded()[0]),
     "sdar": lambda: sdar._engine(sdar.seeded()[0]),
+    "lfm2": lambda: lfm2._engine(lfm2.seeded()[0]),
 }
 
 PINS = {
@@ -114,6 +118,13 @@ PINS = {
         "688479f75b13a3cf12d116dcddcb63a8c6033d5252b38b36487c0bc9a023f81b",
     ("sdar", "unified_nochunk"):
         "3c28ec12190ab1eee96be4f9800d7268937dd43c7d2827ebcfc0044743646a18",
+    # a hybrid whose state blocks hold a tail and no state, the prefix
+    # cache ON (PR 64: the hybrid chain + the `C` mixer, a snapshot plane
+    # a block, a state table of B + 4); it moved none of the twenty-six
+    ("lfm2", "unified"):
+        "d605330e1d7d990fd28043f9174e4b8171444b2a72d3f8b950aa5151ec17b297",
+    ("lfm2", "unified_nochunk"):
+        "7c6ce324a41f74a404b8368fbda13bceefff58cdd2d6c336b512163547f4e80e",
 }
 
 
@@ -148,7 +159,9 @@ def lower_step(eng, program):
         pooled = i32(2, B + C // eng._p["cfg"].chunk_size)
         lens, page, off = (lens, lens), (page, pooled), (off, pooled)
     if eng._ssm_layers:
-        lens = (lens, i32(B + 3))
+        # (a finite-history family with the prefix cache on: one entry
+        # more, the page whose snapshot the chunk continues)
+        lens = (lens, i32(B + 3 + eng._tail_snapshots))
     if eng._block:
         lens = (lens, i32(B))
     return eng._programs[program].lower(
