@@ -6,8 +6,10 @@ TPU-first design:
 - weights carry Megatron-pattern sharding specs (qkv/up: column on mp;
   o/down: row on mp; embeddings: vocab on mp) — GSPMD derives the per-layer
   collectives the reference's ColumnParallelLinear/RowParallelLinear issue.
-- activations get sequence-parallel constraints between blocks (P5) and a
-  dp/fsdp batch constraint at the top.
+- between a row-parallel product and the next column-parallel one the
+  activations are sequence-sharded on mp where the mesh and the length
+  allow (P5; `distributed.parallel_layers.seq_sharded_on` chooses, no
+  switch), and get a dp/fsdp batch constraint at the top.
 - attention is GQA through scaled_dot_product_attention (flash-routable);
   rope is fused-ready (paddle_tpu.ops).
 - fsdp (ZeRO-3) is a spec choice on the same weights (dim-0 on "sharding").
@@ -26,7 +28,11 @@ from ..core.tensor import Tensor
 from .. import nn
 from ..nn import functional as F
 from ..nn import initializer as I
-from ..distributed.parallel_layers import MP_AXIS
+from ..distributed.parallel_layers import (MP_AXIS,
+                                           annotate_column_parallel,
+                                           annotate_sequence_parallel,
+                                           seq_layout_engages, seq_sharded,
+                                           seq_whole)
 from ..observability.attribution import keeps as _keeps, \
     residual as _residual, scope as _scope
 
@@ -36,6 +42,14 @@ __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM",
 
 
 class LlamaConfig:
+    """`sequence_parallel` is INERT: the keyword is accepted and stored
+    because callers still pass it (`benchmarks/systems/llama_pretrain.py`
+    among them, which pinned it to False), and selects nothing — whether
+    the activations between a row-parallel and the next column-parallel
+    product are sequence-sharded on `mp` is chosen from the mesh and the
+    sequence length (`distributed.parallel_layers.seq_sharded_on`), two
+    layouts of the same mathematics not being a user's to pick."""
+
     def __init__(self, vocab_size=128256, hidden_size=4096,
                  intermediate_size=14336, num_hidden_layers=32,
                  num_attention_heads=32, num_key_value_heads=8,
@@ -229,7 +243,14 @@ class LlamaAttention(nn.Layer):
                 else:
                     o = sdpa_reference(q, k, v, mask=mask_arr, causal=True)
             with _scope("attn_out"):
-                return _residual(o.reshape(B, S, -1) @ wo, "attn_out")
+                # the row product's input is held to every row and this
+                # chip's heads (and so is its cotangent: the backward
+                # gathers the rows of d out, not the weight); its output
+                # is constrained, then named: the kept residual is the
+                # scattered [B, S/mp, H], not the replicated product
+                return _residual(
+                    seq_sharded(seq_whole(o.reshape(B, S, -1)) @ wo),
+                    "attn_out")
 
         if c.fuse_attention_qkv:
             g = self._qkv_groups
@@ -239,7 +260,7 @@ class LlamaAttention(nn.Layer):
                 # [B,S,g,(Hg+2KVg),D]: dim 2 is the shard (rank) dim, so
                 # the q|k|v slices below are shard-local under mp
                 with _scope("qkv_proj"):
-                    qkv = _residual(h @ wqkv, "qkv").reshape(
+                    qkv = _residual(seq_whole(h @ wqkv), "qkv").reshape(
                         B, S, g, Hg + 2 * KVg, D)
                     q = qkv[:, :, :, :Hg].reshape(B, S, H, D)
                     k = qkv[:, :, :, Hg:Hg + KVg].reshape(B, S, KV, D)
@@ -253,9 +274,9 @@ class LlamaAttention(nn.Layer):
 
         def impl(h, wq, wk, wv, wo):
             with _scope("qkv_proj"):
-                q = _residual(h @ wq, "qkv").reshape(B, S, H, D)
-                k = _residual(h @ wk, "qkv").reshape(B, S, KV, D)
-                v = _residual(h @ wv, "qkv").reshape(B, S, KV, D)
+                q = _residual(seq_whole(h @ wq), "qkv").reshape(B, S, H, D)
+                k = _residual(seq_whole(h @ wk), "qkv").reshape(B, S, KV, D)
+                v = _residual(seq_whole(h @ wv), "qkv").reshape(B, S, KV, D)
             return finish(q, k, v, wo)
         return _apply("llama_attention", impl,
                       [x, self.q_proj.weight, self.k_proj.weight,
@@ -298,11 +319,20 @@ class LlamaMLP(nn.Layer):
         from ..core.dispatch import apply as _apply
 
         def named(t):
-            """The raw gate / up product under its residual's name,
-            where a checkpoint around the layer keeps it."""
-            if not _keeps("gate_up"):
+            """The raw gate / up product, every row and this chip's
+            columns (`seq_whole`), under its residual's name where a
+            checkpoint around the layer keeps it."""
+            if not (_keeps("gate_up") or seq_layout_engages(t)):
                 return t
-            return _apply("gate_up", lambda a: _residual(a, "gate_up"), [t])
+            return _apply("gate_up",
+                          lambda a: _residual(seq_whole(a), "gate_up"), [t])
+
+        def down(t):
+            """The row product of the swiglu output, which is held (and
+            its cotangent with it) to every row and this chip's columns:
+            the backward gathers the rows of d out, not the weight."""
+            return annotate_sequence_parallel(
+                self.down_proj(annotate_column_parallel(t)))
 
         if self.c.fuse_attention_ffn:
             c = self.c
@@ -310,15 +340,15 @@ class LlamaMLP(nn.Layer):
             gu = named(self.gate_up_proj(x))
             if g == 1:
                 # single-arg swiglu splits [gate | up] internally
-                return self.down_proj(F.swiglu(gu))
+                return down(F.swiglu(gu))
             # grouped layout: split per block, then flatten back to [.., I]
             shp = gu.shape[:-1]
             gu = gu.reshape(list(shp) + [g, 2 * Ig])
             gate = gu[..., :Ig].reshape(list(shp) + [c.intermediate_size])
             up = gu[..., Ig:].reshape(list(shp) + [c.intermediate_size])
-            return self.down_proj(F.swiglu(gate, up))
-        return self.down_proj(F.swiglu(named(self.gate_proj(x)),
-                                       named(self.up_proj(x))))
+            return down(F.swiglu(gate, up))
+        return down(F.swiglu(named(self.gate_proj(x)),
+                             named(self.up_proj(x))))
 
 
 class LlamaDecoderLayer(nn.Layer):
@@ -332,20 +362,20 @@ class LlamaDecoderLayer(nn.Layer):
         self.mlp = LlamaMLP(c)
 
     def forward(self, x, cos, sin, attn_mask=None):
-        from ..distributed.parallel_layers import annotate_sequence_parallel
         # the step scopes of `observability.attribution`: the attention
-        # layer names its own three parts
+        # layer names its own three parts.  Where the sequence layout
+        # engages the norms and the residual adds run on S/mp rows; a
+        # norm's OUTPUT is held to those rows too, so that the gather
+        # sits between it and the column product (before the norm it
+        # would keep the duplicate work)
         with _scope("attn_norm"):
-            hn = self.input_layernorm(x)
-        h = x + self.self_attn(hn, cos, sin, attn_mask)
-        if self.c.sequence_parallel:
-            h = annotate_sequence_parallel(h)
+            hn = annotate_sequence_parallel(self.input_layernorm(x))
+        h = annotate_sequence_parallel(
+            x + self.self_attn(hn, cos, sin, attn_mask))
         with _scope("ffn_norm"):
-            hn = self.post_attention_layernorm(h)
+            hn = annotate_sequence_parallel(self.post_attention_layernorm(h))
         with _scope("ffn"):
-            out = h + self.mlp(hn)
-        if self.c.sequence_parallel:
-            out = annotate_sequence_parallel(out)
+            out = annotate_sequence_parallel(h + self.mlp(hn))
         return out
 
 
@@ -369,7 +399,7 @@ class LlamaModel(nn.Layer):
         self.register_buffer("rope_sin", Tensor(sin), persistable=False)
 
     def forward(self, input_ids, attn_mask=None):
-        x = self.embed_tokens(input_ids)
+        x = annotate_sequence_parallel(self.embed_tokens(input_ids))
         cos, sin = self.rope_cos._data, self.rope_sin._data
         for layer in self.layers:
             if self.config.recompute and self.training:
@@ -377,7 +407,7 @@ class LlamaModel(nn.Layer):
                 x = recompute(layer, x, cos, sin, attn_mask)
             else:
                 x = layer(x, cos, sin, attn_mask)
-        return self.norm(x)
+        return annotate_sequence_parallel(self.norm(x))
 
 
 class LlamaForCausalLM(nn.Layer):

@@ -77,14 +77,13 @@ class MoEDecoderLayer(nn.Layer):
                 z_loss_weight=c.router_z_loss_weight)
 
     def forward(self, x, cos, sin, attn_mask=None):
-        from ..distributed.parallel_layers import annotate_sequence_parallel
-        h = x + self.self_attn(self.input_layernorm(x), cos, sin, attn_mask)
-        if self.c.sequence_parallel:
-            h = annotate_sequence_parallel(h)
-        out = h + self.mlp(self.post_attention_layernorm(h))
-        if self.c.sequence_parallel:
-            out = annotate_sequence_parallel(out)
-        return out
+        # the layout of `LlamaDecoderLayer.forward`: S/mp rows a chip
+        # outside the two sublayers where the mesh and the length allow
+        from ..distributed.parallel_layers import \
+            annotate_sequence_parallel as held
+        h = held(x + self.self_attn(held(self.input_layernorm(x)),
+                                    cos, sin, attn_mask))
+        return held(h + self.mlp(held(self.post_attention_layernorm(h))))
 
 
 class MoEModel(nn.Layer):

@@ -21,7 +21,7 @@ import contextlib
 import jax
 from jax.sharding import PartitionSpec as P
 
-__all__ = ["kernel_mesh", "on_mesh"]
+__all__ = ["kernel_mesh", "step_mesh", "on_mesh"]
 
 _kernel_mesh = None
 
@@ -36,6 +36,11 @@ def kernel_mesh(mesh):
         yield
     finally:
         _kernel_mesh = prev
+
+
+def step_mesh():
+    """The mesh :func:`kernel_mesh` names, None outside it."""
+    return _kernel_mesh
 
 
 def on_mesh(kernel, args, layouts, out_layouts):

@@ -34,6 +34,7 @@ from ..core.tensor import Tensor
 from ..device import memory_stats
 from ..distributed.mesh import (build_hybrid_mesh, global_device_put,
                                 mesh_context)
+from ..distributed.parallel_layers import seq_sharded_on
 from ..observability.attribution import (compile_named, keeping,
                                          scope as _scope)
 from ..ops.on_mesh import kernel_mesh
@@ -57,6 +58,11 @@ _G_SAVED_BYTES = _obs.registry().gauge(
 _G_SAVED_LAYERS = _obs.registry().gauge(
     "trainer.remat.saved_layers",
     "layers whose checkpoint keeps the named residual", labels=("name",))
+_G_SEQ_SHARDED = _obs.registry().gauge(
+    "trainer.mp.seq_sharded",
+    "1 where the built step holds its [B, S, H] activations [B, S/mp, H] a "
+    "chip between a row-parallel and the next column-parallel product, "
+    "else 0")
 
 #: What `remat` "full" leaves free on a chip BESIDE the step program when
 #: it chooses the residuals to keep: a program's `memory_analysis()` is
@@ -200,10 +206,19 @@ def remat_order(mp: int) -> Tuple[Tuple[str, ...], ...]:
     byte first.  That follows from shapes: the flash kernel is quadratic
     in the sequence for an output linear in it (both of its residuals or
     neither: its backward reads the two); a matmul's output saves its
-    contraction length in FLOPs a byte, the same hidden size for the
-    three products — but under tensor parallelism the recomputed
-    `attn_out` also repeats its all-reduce over `mp`, which nothing
-    hides, so there it goes before the other two."""
+    contraction length in FLOPs a byte, and that is the same hidden size
+    for the three products a chip — the row product `attn_out` contracts
+    hidden / mp columns but, held `[B, S/mp, H]` under the sequence
+    layout, is 1 / mp of the bytes too.  What parts them under tensor
+    parallelism is the collective a recomputation repeats: `attn_out`'s
+    is the row product's own reduce-scatter (an all-reduce where the
+    layout does not engage), which stands alone in the compiled step with
+    nothing beside it, while the gathers into `qkv` and `gate_up` are
+    made again whatever is kept, for their weight gradients read the
+    gathered norm outputs (and they ride inside the backward's matmuls;
+    the step compiled for a v5e 2x2, `PERF.md` section 6, PR 65).  So
+    there `attn_out` goes before the other two; without `mp` it ranks
+    with them, behind the smaller `qkv`."""
     flash = ("flash_o", "flash_lse")
     if mp > 1:
         return (flash, ("attn_out",), ("qkv",), ("gate_up",))
@@ -236,13 +251,26 @@ def choose_remat_plan(nbytes: Mapping[str, int], n_layers: int,
     return plan
 
 
-def _drop_last_taken(plan: List[Tuple[str, ...]],
-                     order: Sequence[Tuple[str, ...]]
-                     ) -> List[Tuple[str, ...]]:
-    """`plan` without the entry of `order` it took last."""
+def _take_back(plan: List[Tuple[str, ...]],
+               order: Sequence[Tuple[str, ...]], nbytes: Mapping[str, int],
+               over: Optional[int], unrolled: bool
+               ) -> List[Tuple[str, ...]]:
+    """`plan` with less of the entry of `order` it took last, after the
+    compiled program needed `over` bytes more than fit (None: the
+    compiler refused it outright).  Where the layers are unrolled, the
+    LAST layers that keep the entry let go of it, as many as `over` is
+    worth by `nbytes` and at least one (a program's need is not its
+    floor's plus the bytes by shapes to the byte: the compiler places
+    what it keeps); under `lax.scan`, or with nothing to reckon from,
+    the whole entry."""
     taken = {n for kept in plan for n in kept}
     last = next(names for names in reversed(order) if taken & set(names))
-    return [tuple(n for n in kept if n not in last) for kept in plan]
+    holders = [i for i, kept in enumerate(plan) if set(last) & set(kept)]
+    n = len(holders)
+    if unrolled and over is not None:
+        n = min(n, max(1, -(-over // sum(nbytes[k] for k in last))))
+    return [tuple(k for k in kept if k not in last)
+            if i in holders[-n:] else kept for i, kept in enumerate(plan)]
 
 
 def _bytes_limit(mesh: Mesh) -> Optional[int]:
@@ -436,6 +464,11 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
         else:
             pp_timetable = generate_schedule(cfg.pp_schedule, n_stages, M)
         pp_timetable.validate()
+    # the sequence layout, as the layers traced on this mesh choose it
+    # (`parallel_layers.seq_sharded_on`; the timetable executor suppresses
+    # it in its branches): the embedding and the head follow the layers
+    seq_on_mp = not use_timetable and seq_sharded_on(mesh, S)
+    mp = mesh.shape.get("mp", 1)
 
     @_scope("head_loss")
     def _rms_head_loss(norm_w, w_head, h, labels_h, constrain=False,
@@ -453,6 +486,19 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
         hn = (h32 * jax.lax.rsqrt(
             jnp.mean(jnp.square(h32), -1, keepdims=True) + mc.rms_norm_eps)
         ).astype(h.dtype) * norm_w
+        # under the sequence layout `h` arrives on S/mp rows a chip: the
+        # norm runs on those, and a chunk is the SAME rows of every chip's
+        # share ([.., mp, S/mp, H], cut along S/mp), so that a chunk's
+        # gather into the vocabulary-parallel product and the
+        # reduce-scatter of its cotangent stay inside the chunk (a sum
+        # over tokens does not care which chunk holds which)
+        ways = mp if constrain and seq_on_mp else 1
+        span = S // ways            # the rows the chunks are cut along
+        if ways > 1:
+            rows = NamedSharding(mesh, P(("dp", "sharding"), "mp", None, None))
+            hn = jax.lax.with_sharding_constraint(
+                hn.reshape(hn.shape[:-2] + (ways, span, -1)), rows)
+            labels_h = labels_h.reshape(labels_h.shape[:-1] + (ways, span))
 
         @jax.checkpoint
         def chunk_loss(h_c, labels_c):
@@ -472,12 +518,15 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
                     logits32, labels_c[..., None], axis=-1)[..., 0]
             return (lse - picked).sum()
 
-        n_chunks = min(cfg.ce_chunks, S)
-        bounds = [i * S // n_chunks for i in range(n_chunks)] + [S]
+        n_chunks = min(cfg.ce_chunks, span)
+        bounds = [i * span // n_chunks for i in range(n_chunks)] + [span]
         total = jnp.zeros((), jnp.float32)
         for lo, hi in zip(bounds[:-1], bounds[1:]):
-            total = total + chunk_loss(hn[..., lo:hi, :],
-                                       labels_h[..., lo:hi])
+            h_c, labels_c = hn[..., lo:hi, :], labels_h[..., lo:hi]
+            if ways > 1:
+                h_c = h_c.reshape(h_c.shape[:-3] + (-1, h_c.shape[-1]))
+                labels_c = labels_c.reshape(labels_c.shape[:-2] + (-1,))
+            total = total + chunk_loss(h_c, labels_c)
         return total
 
     def loss_fn(saved, compute_params, ids, labels):
@@ -487,7 +536,7 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
                 compute_params["stacked"]))
         emb = compute_params["outer"][embed_key]
         with _scope("embed"):
-            if mesh.shape.get("mp", 1) > 1:
+            if mp > 1:
                 # vocab-parallel lookup as a one-hot CONTRACTION: a
                 # gather over the vocab-sharded table forces GSPMD into
                 # involuntary full rematerialization (replicate the
@@ -497,11 +546,22 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
                 # over mp, the GSPMD analog of Megatron's range-mask +
                 # allreduce) and rides the MXU
                 oh = jax.nn.one_hot(ids, emb.shape[0], dtype=emb.dtype)
+                if seq_on_mp:
+                    # every row, this chip's vocabulary: with the output
+                    # on S/mp rows the partitioner would otherwise gather
+                    # the TABLE to every chip and all-reduce its gradient
+                    oh = jax.lax.with_sharding_constraint(
+                        oh, NamedSharding(mesh, P(("dp", "sharding"),
+                                                  None, "mp")))
                 x = oh @ emb                # [B,S,H]
             else:
                 x = jnp.take(emb, ids, axis=0)  # [B,S,H]
+            # under the sequence layout the vocabulary-parallel product
+            # leaves as a reduce-scatter onto S/mp rows, as a layer's does
             x = jax.lax.with_sharding_constraint(
-                x, NamedSharding(mesh, P(("dp", "sharding"), "sep", None)))
+                x, NamedSharding(mesh, P(("dp", "sharding"),
+                                         "mp" if seq_on_mp else "sep",
+                                         None)))
         if use_timetable:
             # 1F1B/ZBH1/FThenB: the loss head runs ON the last stage
             # inside the executor (the cotangent seeds the interleaved
@@ -613,14 +673,14 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
     # program that keeps nothing (the FLOOR: today's program, and what a
     # device that reports no limit gets).  Two compiles before the first
     # step where it engages: the floor's, for its need, and the chosen
-    # program's, whose own need is held to the same limit (an entry too
-    # many is dropped and the floor always remains); the `jax.jit` call
+    # program's, whose own need is held to the same limit (what is too
+    # much is taken back, `_take_back`, at a compile a try, and the floor
+    # always remains); the `jax.jit` call
     # and `compiled_programs` find the second in the compile cache.
     # Under pp > 1 (either pipeline, the timetable executor too) a
     # stage-level checkpoint around the layers discards whatever a layer
     # kept, so the plan is empty there; with FLAGS_flash_impl "bundled"
     # the kernel's own custom_vjp carries no names, the rest applies.
-    mp = mesh.shape.get("mp", 1)
     order = remat_order(mp)
     item = jnp.dtype(param_dtype).itemsize
     rows = -(-B // (mesh.shape.get("dp", 1) * zdeg)) \
@@ -629,7 +689,9 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
     nbytes = {
         "flash_o": rows * heads * mc.head_dim * item,
         "flash_lse": rows * heads * 4,
-        "attn_out": rows * mc.hidden_size * item,
+        # the row product is kept as it is held: S/mp rows a chip under
+        # the sequence layout, every row without it
+        "attn_out": rows * mc.hidden_size * item // (mp if seq_on_mp else 1),
         "qkv": rows * -(-(mc.num_attention_heads
                          + 2 * mc.num_key_value_heads) * mc.head_dim
                         // mp) * item,
@@ -657,12 +719,16 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
             if need is not None and need <= fits:
                 jstep, chosen_need = chosen, need
                 break
-            plan = _drop_last_taken(plan, order)
-    record = {"layers": plan,
+            plan = _take_back(plan, order, nbytes,
+                              None if need is None else need - fits,
+                              not cfg.scan_layers)
+    record = {"layers": plan, "seq_sharded": seq_on_mp,
+              "nbytes": dict(nbytes),
               "saved_bytes": sum(nbytes[n] for kept in plan for n in kept),
               "limit": limit, "margin": REMAT_MARGIN_BYTES,
               "headroom": None if limit is None else fits - floor_need,
               "floor_need": floor_need, "need": chosen_need}
+    _G_SEQ_SHARDED.set(int(seq_on_mp))
     _G_SAVED_BYTES.set(record["saved_bytes"])
     for n in nbytes:
         _G_SAVED_LAYERS.labels(name=n).set(sum(n in kept for kept in plan))

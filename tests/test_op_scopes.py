@@ -465,21 +465,42 @@ def test_every_op_of_the_trainer_step_answers_to_a_name():
     assert got["update"] == {"-"}
     scoped = sum(r.scope is not None for r in table.values())
     assert scoped >= 0.9 * len(table), (scoped, len(table))
-    # the mp all-reduce of a row-parallel matmul's output takes the
-    # matmul's name, forward and recomputed
+    # the sequence layout (PR 65; mp 2 divides the 16 rows): a
+    # row-parallel matmul's partial sums leave under the matmul's name,
+    # forward and recomputed (a reduce-scatter; the CPU's partitioner
+    # spells it as an all-reduce and a slice) ...
     coll = {(r.scope, r.direction) for r in table.values()
             if r.kind == "collective"}
     assert {("attn_out", "fwd"), ("attn_out", "remat"),
             ("ffn", "fwd")} <= coll
+    act = "[2,16,128]"              # [B, S, H] a chip, whatever the type
+    scattered = {(r.scope, r.direction) for r in table.values()
+                 if r.kind == "collective" and r.opcode == "all-reduce"
+                 and r.shape.endswith(act)}
+    assert scattered == {("embed", "fwd"), ("attn_out", "fwd"),
+                         ("attn_out", "remat"), ("ffn", "fwd"),
+                         ("qkv_proj", "bwd"), ("ffn", "bwd")}, scattered
+    # ... and the gathers of [B, S/mp, H] rows answer to the scope that
+    # USES them — the column matmuls, whose weight gradients want them
+    # again, and the row matmuls' backward — never to a norm, never to
+    # no name
+    weights = {(r.scope, r.direction) for r in table.values()
+               if r.kind == "collective" and r.opcode == "all-gather"
+               and r.scope not in ("head_loss", "update")
+               and not r.shape.endswith(act)}
+    rows = {(r.scope, r.direction) for r in table.values()
+            if r.kind == "collective" and r.opcode == "all-gather"
+            and r.shape.endswith(act)}
+    assert rows == {("qkv_proj", "fwd"), ("qkv_proj", "remat"),
+                    ("ffn", "fwd"), ("ffn", "remat"), ("ffn", "bwd"),
+                    ("attn_out", "bwd"), ("embed", "bwd")}, rows
     # ZeRO's traffic: a layer's weights are gathered under the name of
     # the part that uses them, forward only (never recomputed, never in
     # the backward); a weight gradient's sum over the axis answers to
     # its matmul, backward; the update keeps the norm's scalars
-    gathers = {(r.scope, r.direction) for r in table.values()
-               if r.kind == "collective" and r.opcode == "all-gather"
-               and r.scope not in ("embed", "head_loss", "update")}
-    assert gathers == {(s, "fwd") for s in (
-        "attn_norm", "qkv_proj", "attn_out", "ffn_norm", "ffn")}, gathers
+    assert weights == {(s, "fwd") for s in (
+        "embed", "attn_norm", "qkv_proj", "attn_out", "ffn_norm", "ffn")}, \
+        weights
     assert {("qkv_proj", "bwd"), ("attn_out", "bwd"), ("ffn", "bwd")} <= coll
     assert {r.shape for r in table.values() if r.kind == "collective"
             and r.scope == "update"} == {"f32[]"}

@@ -83,16 +83,66 @@ def test_the_order_names_the_vocabulary_once():
         at.residual(jnp.zeros(2), "swiglu")
 
 
-def test_dropping_takes_the_last_entry_back_whole():
+@pytest.mark.parametrize("case, over, unrolled, want_gate, want_rest", [
+    # under scan, or with nothing to reckon from, the entry goes whole
+    ("scan: whole", 10 * MB, False, 0, ALL_BUT_GATE),
+    ("the compiler refused it: whole", None, True, 0, ALL_BUT_GATE),
+    # unrolled: the last holders let go, as many as the excess is worth
+    ("a little over: one layer", 10 * MB, True, 4, ALL_BUT_GATE),
+    ("one layer's worth: one", 448 * MB, True, 4, ALL_BUT_GATE),
+    ("a byte more: two", 448 * MB + 1, True, 3, ALL_BUT_GATE),
+    ("more than it holds: whole, and no more", 9000 * MB, True, 0,
+     ALL_BUT_GATE),
+])
+def test_what_does_not_fit_is_taken_back_from_the_last_entry(
+        case, over, unrolled, want_gate, want_rest):
     order = remat_order(2)
-    plan = [ALL_BUT_GATE + ("gate_up",)] + [ALL_BUT_GATE] * 3
-    plan = pretrain._drop_last_taken(plan, order)
-    assert plan == [ALL_BUT_GATE] * 4
-    plan = pretrain._drop_last_taken(plan, order)
-    assert plan == [FLASH + ("attn_out",)] * 4
-    plan = pretrain._drop_last_taken(
-        pretrain._drop_last_taken(plan, order), order)
-    assert plan == [()] * 4
+    plan = [ALL_BUT_GATE + ("gate_up",)] * 5 + [ALL_BUT_GATE] * 3
+    got = pretrain._take_back(plan, order, CELL, over, unrolled)
+    assert got == [want_rest + ("gate_up",)] * want_gate \
+        + [want_rest] * (8 - want_gate), case
+    # entry by entry down to the floor
+    plan = [ALL_BUT_GATE] * 4
+    for want in ([FLASH + ("attn_out",)] * 4, [FLASH] * 4, [()] * 4):
+        plan = pretrain._take_back(plan, order, CELL, None, unrolled)
+        assert plan == want
+
+
+def test_the_sequence_layout_halves_what_a_chip_keeps_of_the_row_product(
+        monkeypatch):
+    """`nbytes["attn_out"]` a chip under `mp` 2 is half of `mp` 1's (the
+    residual is named AFTER the constraint: `[B, S/mp, H]`), and at the
+    four-chip cell's shapes the memory that frees keeps the gate / up
+    product in two more layers — arithmetic on the cell's numbers (PR 65,
+    the step compiled for a described v5e 2x2), no device."""
+    plans = {}
+    for mp in (1, 2):
+        _, _, meta, _ = _build(monkeypatch, None, mp=mp, sharding=2)
+        plans[mp] = meta["remat_plan"]
+    assert plans[2]["seq_sharded"] and not plans[1]["seq_sharded"]
+    assert plans[2]["nbytes"]["attn_out"] * 2 \
+        == plans[1]["nbytes"]["attn_out"]
+    for name in ("flash_o", "flash_lse", "qkv", "gate_up"):
+        assert plans[2]["nbytes"][name] * 2 == plans[1]["nbytes"][name]
+    # the cell: 2 x 8192 rows a chip, hidden 4096, bf16, eight layers
+    rows, item = 2 * 8192, 2
+    cell = {"flash_o": rows * 16 * 128 * item, "flash_lse": rows * 16 * 4,
+            "attn_out": rows * 4096 * item, "qkv": rows * 3072 * item,
+            "gate_up": rows * 14336 * item}
+    sharded = dict(cell, attn_out=cell["attn_out"] // 2)
+    # the floor program's need fell by the sixteen [2, 8192, 4096]
+    # tensors a chip no longer holds whole (eight checkpoint inputs; the
+    # eight kept row products are the plan's own bytes) and more
+    limit, margin = 16_909_334_528, pretrain.REMAT_MARGIN_BYTES
+    parent = choose_remat_plan(cell, 8, limit - margin - 12_727_522_816,
+                               True, remat_order(2))
+    found = 8 * cell["attn_out"] // 2
+    change = choose_remat_plan(sharded, 8,
+                               limit - margin - 12_727_522_816 + found,
+                               True, remat_order(2))
+    gate = lambda plan: sum("gate_up" in kept for kept in plan)  # noqa: E731
+    assert gate(parent) == 1
+    assert gate(change) >= gate(parent) + 2
 
 
 def test_a_name_is_an_identity_outside_a_checkpoint_that_keeps_it():
@@ -222,13 +272,15 @@ def test_the_plan_is_reported(monkeypatch):
     assert plan["headroom"] == plan["limit"] - plan["margin"] \
         - plan["floor_need"]
     assert plan["need"] >= plan["floor_need"] > 0
-    # bytes a chip by shapes: 4 x 128 tokens over sharding 2, heads and
-    # columns over mp 2, float32
+    # bytes a chip by shapes: 4 x 128 tokens over sharding 2, heads,
+    # columns and (the sequence layout) the row product's rows over mp
+    # 2, float32
     rows = 2 * 128
     per = {"flash_o": rows * 1 * 64 * 4, "flash_lse": rows * 1 * 4,
-           "attn_out": rows * 128 * 4, "qkv": rows * (6 * 64 // 2) * 4,
+           "attn_out": rows * 128 * 4 // 2, "qkv": rows * (6 * 64 // 2) * 4,
            "gate_up": rows * (2 * 256 // 2) * 4}
     assert plan["saved_bytes"] == 2 * sum(per.values()) - per["gate_up"]
+    assert plan["seq_sharded"] is True and plan["nbytes"] == per
     snap = reg.snapshot()
     assert snap["trainer.remat.saved_bytes"]["series"][0]["value"] \
         == plan["saved_bytes"]

@@ -89,7 +89,8 @@ def chip():
         return verdicts[key]
 
     yield types.SimpleNamespace(shape=shape, compiles=compiles,
-                                refusals=refusals, texts=texts)
+                                refusals=refusals, texts=texts,
+                                devices=topo.devices)
     mp.undo()
     jax.config.update("jax_default_matmul_precision", precision_was)
     jax.config.update("jax_enable_compilation_cache", cache_was)
@@ -1121,3 +1122,47 @@ def test_train_norm_rope_swiglu_fwd_and_grad_compile(chip):
         f, chip.shape((B, Sq, H)), chip.shape((H,)),
         chip.shape((B, Sq, HQ, D)), chip.shape((B, Sq, KV, D)),
         trig, trig, act, act), chip.refusals.get(f)
+
+
+def test_trainer_step_scatters_its_row_products_over_mp(chip):
+    """The trainer's step at toy widths, `sharding 2 x mp 2` on the
+    described 2x2 (the four-chip cell's own build path, from shapes
+    only): the sequence layout (PR 65) in the TPU compiler's text.  Every
+    sum of a `[B, S, H]` activation over the mp pairs is a reduce-scatter
+    (the compiler's `%all-reduce-scatter` fusion), never a bare
+    all-reduce; the norms reduce S/mp rows a chip; the gathers of those
+    rows answer to the matmuls that use them."""
+    from benchmarks.systems import llama_pretrain
+    B, S, H = 4 // 2, 256, 256
+    config = {"vocab_size": 512, "hidden_size": H, "intermediate_size": 512,
+              "num_hidden_layers": 2, "num_attention_heads": 4,
+              "num_key_value_heads": 2, "head_dim": 64, "rope_theta": 1e4,
+              "rms_norm_eps": 1e-5, "tie_word_embeddings": False,
+              "trainer": {"parallel": {"sharding": 2, "mp": 2},
+                          "seq_len": S, "global_batch": 4,
+                          "fuse_pack_groups": 2, "remat": "full",
+                          "scan_layers": False, "ce_chunks": 2}}
+    text = llama_pretrain.compile_for(config, chip.devices).as_text()
+    act = f"bf16[{B},{S},{H}]"
+    comp, sums, gathers = None, [], []
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) \(", line)
+        if head:
+            comp = head.group(1)
+        op = re.search(r"= (\S+?)\{[^ ]* (all-reduce|all-gather)"
+                       r"(?:-start)?\(", line)
+        if op and op.group(1) == act:
+            name = re.search(r'op_name="([^"]*)"', line)
+            (sums if op.group(2) == "all-reduce" else gathers).append(
+                (comp, name.group(1) if name else ""))
+    # embed + (attn_out, ffn) x (fwd, bwd) + attn_out recomputed, a layer
+    assert len(sums) >= 1 + 2 * 5, sums
+    assert all(c.startswith("%all-reduce-scatter") for c, _ in sums), sums
+    assert gathers and all(
+        any(f"/{s}/" in n for s in ("qkv_proj", "attn_out", "ffn"))
+        or "(embed)" in n for _, n in gathers), gathers
+    for scope in ("/attn_norm/", "/ffn_norm/", "(head_loss)"):
+        rows = set(re.findall(
+            r"= f32\[(\d+),(\d+)\]\S* reduce\([^\n]*op_name=\"[^\"]*"
+            + re.escape(scope), text))
+        assert rows and rows <= {(str(B), str(S // 2))}, (scope, rows)

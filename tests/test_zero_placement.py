@@ -48,7 +48,8 @@ def _build(seed=5, layers=2, **kw):
     mesh = make_hybrid_mesh_for(cfg, devices=jax.devices()[:n])
     state, step, meta = build_llama_pretrain_step(cfg, mesh)
     ids = global_device_put(jnp.asarray(np.random.RandomState(0).randint(
-        0, mc.vocab_size, (4, 16)), jnp.int32), meta["data_sharding"])
+        0, mc.vocab_size, (4, base["seq_len"])), jnp.int32),
+        meta["data_sharding"])
     return state, step, meta, ids
 
 
@@ -251,23 +252,37 @@ def test_a_pipelines_stack_is_gathered_before_the_pipeline():
     assert weight_gathers(text[:cut]) == {}
 
 
-#: sha256 of the lowered step's text with the ZeRO axis at 1 (mp 2,
-#: toy widths, this file's `_build`), recorded at PR 38's parent
-LOWERED_AT_PARENT_WITHOUT_ZERO = \
-    "65f046752feb570514b4541d1b89f94de1adc53eb0aa90fadf7633aa19f71bb7"
+#: sha256 of the lowered step's text with the ZeRO axis at 1 (toy
+#: widths, this file's `_build`).  `mp 2, length 15` and `mp 1` are the
+#: PARENT's text (recorded at PR 65's parent, 5330949: the sequence
+#: layout does not engage there and ZeRO at 1 adds nothing).  `mp 2` was
+#: pinned at PR 38's parent (65f04675...) and is NOT that text since PR
+#: 65: at `mp` 2 the length 16 divides and the activations between a row
+#: and the next column product are sequence-sharded
+#: (tests/test_sequence_sharded.py); its hash is PR 65's own, kept so
+#: that the ZeRO axis at 1 still may not move the program unseen.
+LOWERED_WITHOUT_ZERO = {
+    "mp2": (dict(mp=2), 16,
+            "588b0c0cb56f2e1e3ec65d8b13f107020d67b820e12961955d878897210bdffc"),
+    "mp2-seq15": (dict(mp=2), 15,
+                  "81c63244f1d816f11c4fab3dbe1c20fdc355f0732f7c014c31c84f32d8"
+                  "e980b9"),
+    "mp1": (dict(), 16,
+            "ce1720ba7fe4dac27f1728e76a286fc4956755c3dfd7b6078c87f92de6ba116b")}
 
 
-def test_without_the_axis_the_step_lowers_to_the_parents_text():
+@pytest.mark.parametrize("case", sorted(LOWERED_WITHOUT_ZERO))
+def test_without_the_axis_the_step_lowers_to_the_pinned_text(case):
+    kw, seq, want = LOWERED_WITHOUT_ZERO[case]
     if len(jax.devices()) < 2:
         pytest.skip("needs 2 (virtual) devices")
-    state, step, meta, ids = _build(mp=2)
+    state, step, meta, ids = _build(seq_len=seq, **kw)
     shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
         a.shape, a.dtype, sharding=NamedSharding(
             meta["mesh"], a.sharding.spec if hasattr(a.sharding, "spec")
             else P())), state)
     text = step.lower(shapes, ids, ids).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == \
-        LOWERED_AT_PARENT_WITHOUT_ZERO
+    assert hashlib.sha256(text.encode()).hexdigest() == want
 
 
 @needs_4

@@ -8,10 +8,16 @@ default: what a v5e reports, 15.75 GiB).  The builder then does what it
 does on the chip — compiles the floor program (every layer's checkpoint
 keeps nothing), takes its need from the limit less the margin, walks
 `trainer/pretrain.py::choose_remat_plan`, compiles the chosen program
-and holds its need to the same limit — and this prints the plan, the
-floor's and the chosen program's need by the compiler's own account,
-and the chosen program's flash custom calls and all-reduces.  About
-90 s a compile (two where the first choice fits); the model's float32
+and holds its need to the same limit — and this prints the plan beside
+the layout it was priced under (`seq_sharded`: whether the activations
+between a row-parallel and the next column-parallel product are held
+S/mp rows a chip, and each name's bytes a layer a chip), the floor's
+and the chosen program's need by the compiler's own account, and the
+chosen program's flash custom calls, all-reduces and reduce-scatters
+(the TPU compiler's `%all-reduce-scatter` fusions, each of which holds
+one of the all-reduces).  About
+90 s a compile (two where the first choice fits, one more a try where
+it does not); the model's float32
 parameters are built on the host for real (7.6 GB at the cell's size).
 
     JAX_PLATFORMS=cpu python tools/remat_plan_aot.py
@@ -92,12 +98,17 @@ def main(argv=None) -> int:
         "headroom_GB": gb(seen["headroom"]),
         "chosen_need_GB": gb(pretrain._program_need(compiled)),
         "saved_GB_by_shapes": gb(seen["saved_bytes"]),
+        "seq_sharded": seen["seq_sharded"],
+        "MB_a_layer_a_chip": {k: round(v / 1e6, 1)
+                              for k, v in seen["nbytes"].items()},
         "layers": [list(k) for k in seen["layers"]],
         "flash_custom_calls":
             txt.count("custom_call_target=\"tpu_custom_call\""),
         "all_reduces": sum(ln.count(" all-reduce(")
                            + ln.count(" all-reduce-start(")
-                           for ln in txt.splitlines())}, indent=1))
+                           for ln in txt.splitlines()),
+        "reduce_scatters": sum(ln.startswith("%all-reduce-scatter")
+                               for ln in txt.splitlines())}, indent=1))
     return 0
 
 
