@@ -30,9 +30,11 @@ from ..core.tensor import Tensor
 from .. import nn
 from ..nn import initializer as I
 from ..distributed.mesh import get_mesh
+from ..observability.attribution import residual as _residual
 from ..ops.grouped_gemm import grouped_gemm, sort_by_group, unsort_by_group
 
-__all__ = ["top_k_gating", "load_balance_loss", "router_z_loss",
+__all__ = ["top_k_gating", "load_balance_loss",
+           "load_balance_loss_all_choices", "router_z_loss",
            "MoELayer", "SwitchMoELayer", "global_scatter", "global_gather",
            "ClipGradForMOEByGlobalNorm"]
 
@@ -220,6 +222,42 @@ def routing_stats(topi, held, num_experts: int, live=None):
                       jnp.sum(sizes > 0).astype(jnp.float32)])
 
 
+def load_balance_loss_all_choices(gates, topi):
+    """The Qwen-MoE / Mixtral families' `load_balancing_loss_func` on
+    one layer: E * sum_e P_e F_e with P_e the mean gate of expert e and
+    F_e its share of the tokens summed over ALL k choices (so a uniform
+    router reads k, not 1)."""
+    E = gates.shape[-1]
+    chosen = jnp.sum(jax.nn.one_hot(topi, E, dtype=gates.dtype), axis=1)
+    return E * jnp.sum(jnp.mean(gates, axis=0) * jnp.mean(chosen, axis=0))
+
+
+@jax.custom_vjp
+def _owned_rows(rows, n_owned):
+    """`rows` [M, ..] as they are; the COTANGENT of the rows from
+    `n_owned` on is zero.  A grouped GEMM owns no row past its last
+    group: forward they come back as whatever the kernel left there
+    (masked after the unsort), and so does their gradient — a backward
+    through the sorted rows has to drop it the same way, or the absent
+    experts' pair rows add noise to their tokens' gradient (ISSUE 66:
+    the first chip run's embedding gradient read NOTHING of the
+    reference's; the CPU's `ragged_dot` writes zeros there and hid it)."""
+    return rows
+
+
+def _owned_rows_fwd(rows, n_owned):
+    return rows, n_owned
+
+
+def _owned_rows_bwd(n_owned, ct):
+    keep = (jnp.arange(ct.shape[0]) < n_owned).reshape(
+        (-1,) + (1,) * (ct.ndim - 1))
+    return jnp.where(keep, ct, 0), None
+
+
+_owned_rows.defvjp(_owned_rows_fwd, _owned_rows_bwd)
+
+
 def _expert_act(activation: str, up, gate):
     """The experts' nonlinearity on the up-projection `up`: `swiglu`
     gates it with `gate()` (the third matrix's product, made only
@@ -282,25 +320,39 @@ def dropless_expert_ffn(xt, gates, wg, wu, wd, *, top_k: int,
     gives (a softmax, or sigmoids); `group` limits the choice to the
     best groups of experts and `bias` corrects it (`_route`).
     `activation` "relu2" is the non-gated expert of two matrices
-    (`wg` None): relu(x U)^2 V."""
+    (`wg` None): relu(x U)^2 V.
+
+    The choice runs under the scope `moe_route`, the sort and gather
+    under `moe_dispatch` and the unsort and weighted sum under
+    `moe_combine` (names only), so that a trace tells them from the
+    grouped GEMMs, which stay directly under the caller's scope
+    (`observability.attribution.SCOPE_ALIASES`); the sorted rows' gate /
+    up products are the residual `moe_gate_up` where a checkpoint around
+    the caller keeps it; with `held`, the gradient of the rows no held
+    expert owns is dropped (`_owned_rows`)."""
     E = wu.shape[0]
     T = xt.shape[0]
-    gv, topi, local, mine = _route(gates, top_k, renormalize, held, scale,
-                                   group, bias)
-    rows = jnp.repeat(xt, top_k, axis=0)                    # [T*k, H]
-    eids = local.reshape(-1)                                # [T*k]
-    srt, sizes, inv = sort_by_group(rows, eids,
-                                    E if mine is None else E + 1)
-    sizes = sizes[:E]
-    up = grouped_gemm(srt, wu, sizes)
-    act = _expert_act(activation, up,
-                      lambda: grouped_gemm(srt, wg, sizes))
+    with jax.named_scope("moe_route"):
+        gv, topi, local, mine = _route(gates, top_k, renormalize, held,
+                                       scale, group, bias)
+    with jax.named_scope("moe_dispatch"):
+        rows = jnp.repeat(xt, top_k, axis=0)                # [T*k, H]
+        eids = local.reshape(-1)                            # [T*k]
+        srt, sizes, inv = sort_by_group(rows, eids,
+                                        E if mine is None else E + 1)
+        sizes = sizes[:E]
+        if mine is not None:
+            srt = _owned_rows(srt, jnp.sum(sizes))
+    up = _residual(grouped_gemm(srt, wu, sizes), "moe_gate_up")
+    act = _expert_act(activation, up, lambda: _residual(
+        grouped_gemm(srt, wg, sizes), "moe_gate_up"))
     down = grouped_gemm(act, wd, sizes)
-    down = unsort_by_group(down, inv).reshape(T, top_k, -1)
-    if mine is not None:
-        # whatever a kernel leaves in the rows it does not own
-        down = jnp.where(mine[..., None], down, 0)
-    y = jnp.einsum("tk,tkh->th", gv.astype(down.dtype), down)
+    with jax.named_scope("moe_combine"):
+        down = unsort_by_group(down, inv).reshape(T, top_k, -1)
+        if mine is not None:
+            # whatever a kernel leaves in the rows it does not own
+            down = jnp.where(mine[..., None], down, 0)
+        y = jnp.einsum("tk,tkh->th", gv.astype(down.dtype), down)
     return y, topi
 
 
@@ -320,8 +372,17 @@ class MoELayer(nn.Layer):
                  shared_expert_hidden: int = 0, z_loss_weight: float = 0.0,
                  name=None, experts_held=None, routed_scale: float = 1.0,
                  score: str = "softmax", n_group: int = 1,
-                 topk_group: int = 1, correction_bias: bool = False):
+                 topk_group: int = 1, correction_bias: bool = False,
+                 aux_choices: str = "first"):
         super().__init__()
+        # which choices the load-balance term counts (`_dropless`)
+        if aux_choices not in ("first", "all"):
+            raise ValueError(f"aux_choices must be first|all, got "
+                             f"{aux_choices!r}")
+        if aux_choices == "all" and not dropless:
+            raise NotImplementedError("aux_choices='all' needs "
+                                      "dropless=True")
+        self.aux_choices = aux_choices
         if activation not in ("swiglu", "gelu"):
             raise ValueError(f"unsupported activation: {activation}")
         # the router's scores (a softmax over the experts, or one
@@ -363,6 +424,9 @@ class MoELayer(nn.Layer):
         self.expert_axis = expert_axis
         self.z_loss_weight = z_loss_weight
         self.l_aux = None
+        # the last forward's `routing_stats` (dropless only; an array
+        # of the trace it was made in, as `l_aux` is)
+        self.l_stats = None
 
         H, Iw = d_model, d_hidden
         Eg = num_experts                # the router's outputs
@@ -433,12 +497,15 @@ class MoELayer(nn.Layer):
                 if self.e_score_correction_bias is not None else None
             wg = rest[0] if rest else None
             xt = xa.reshape(T, shape[-1])
-            logits = (xt.astype(jnp.float32)
-                      @ gw.astype(jnp.float32))            # [T, E] f32 router
-            gates = jax.nn.sigmoid(logits) if self.score == "sigmoid" \
-                else jax.nn.softmax(logits, axis=-1)
+            with jax.named_scope("moe_route"):
+                logits = (xt.astype(jnp.float32)
+                          @ gw.astype(jnp.float32))       # [T, E] f32 router
+                gates = jax.nn.sigmoid(logits) if self.score == "sigmoid" \
+                    else jax.nn.softmax(logits, axis=-1)
+            stats = jnp.zeros((5,), jnp.float32)
             if self.dropless:
-                y, aux = self._dropless(xt, logits, gates, wg, wu, wd, bias)
+                y, aux, stats = self._dropless(xt, logits, gates, wg, wu,
+                                               wd, bias)
             else:
                 dispatch, combine, aux = top_k_gating(
                     gates, k, cap, renormalize=self.renormalize)
@@ -451,10 +518,12 @@ class MoELayer(nn.Layer):
                 y = jnp.einsum("tec,ech->th", combine, eout)
             if self.z_loss_weight:
                 aux = aux + self.z_loss_weight * router_z_loss(logits)
-            return y.reshape(shape).astype(xa.dtype), aux.astype(jnp.float32)
+            return (y.reshape(shape).astype(xa.dtype),
+                    aux.astype(jnp.float32), jax.lax.stop_gradient(stats))
 
-        out, aux = apply("moe_layer", impl, inputs)
+        out, aux, stats = apply("moe_layer", impl, inputs)
         self.l_aux = aux
+        self.l_stats = stats
         if self.shared_up is not None:
             from ..nn import functional as F
             s = F.silu(self.shared_gate(x)) * self.shared_up(x)
@@ -463,7 +532,17 @@ class MoELayer(nn.Layer):
 
     def _dropless(self, xt, logits, gates, wg, wu, wd, bias=None):
         """Megablocks pattern: flatten (token, choice) rows, sort by expert,
-        one ragged grouped GEMM, unsort, weighted-combine."""
+        one ragged grouped GEMM, unsort, weighted-combine.  Returns (y,
+        the load-balance term, `routing_stats`).
+
+        The load-balance term, E * sum_e P_e F_e over ALL the router's
+        outputs (P_e the mean gate), takes its F_e by family
+        (`aux_choices`): "first" — the share of tokens whose FIRST
+        choice is e (GShard eq. 13 / Switch; the capacity path's
+        `top_k_gating`, `moe_llm.MoEDecoderLayer`, Laguna, and every
+        family before ISSUE 66); "all" — that share summed over the k
+        choices (HF `load_balancing_loss_func`: Mixtral, Qwen-MoE,
+        Mellum2)."""
         k, E = self.top_k, self.num_experts
         y, topi = dropless_expert_ffn(xt, gates, wg, wu, wd, top_k=k,
                                       renormalize=self.renormalize,
@@ -471,8 +550,14 @@ class MoELayer(nn.Layer):
                                       held=self.experts_held,
                                       scale=self.routed_scale,
                                       group=self.route_group, bias=bias)
-        mask1 = jax.nn.one_hot(topi[:, 0], E, dtype=gates.dtype)
-        return y, load_balance_loss(gates, mask1)
+        with jax.named_scope("moe_route"):
+            if self.aux_choices == "all":
+                aux = load_balance_loss_all_choices(gates, topi)
+            else:
+                aux = load_balance_loss(
+                    gates, jax.nn.one_hot(topi[:, 0], E, dtype=gates.dtype))
+            stats = routing_stats(topi, self.experts_held, E)
+        return y, aux, stats
 
 
 class SwitchMoELayer(MoELayer):

@@ -207,7 +207,10 @@ class LlamaAttention(nn.Layer):
             self.v_proj = _mp_linear(c.hidden_size, KV * D, P(None, MP_AXIS))
         self.o_proj = _mp_linear(H * D, c.hidden_size, P(MP_AXIS, None))
 
-    def forward(self, x, cos, sin, attn_mask=None):
+    def forward(self, x, cos, sin, attn_mask=None, window=None):
+        """`window` W (static): a sliding-window layer — query i sees
+        keys j with i - W < j <= i — a band of the flash kernel's
+        block-pair table, run under the scope `window_attention`."""
         c = self.c
         B, S, _ = x.shape
         H, KV, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
@@ -221,10 +224,15 @@ class LlamaAttention(nn.Layer):
             raise NotImplementedError(
                 "attn_mask with context_parallel ring attention: pack "
                 "sequences via sdpa_segmented/flashmask instead")
+        if window is not None and c.context_parallel:
+            raise NotImplementedError(
+                "a sliding window with context_parallel ring attention")
+        banded = {} if window is None else {"window": int(window)}
 
         def finish(q, k, v, wo):
             """rope → attention → output projection (shared tail)."""
-            with _scope("attention"):
+            with _scope("attention") if window is None \
+                    else jax.named_scope("window_attention"):
                 q = apply_rope(q, cos, sin)
                 k = apply_rope(k, cos, sin)
                 rep = H // KV
@@ -239,9 +247,10 @@ class LlamaAttention(nn.Layer):
                         ring_attention_raw
                     o = ring_attention_raw(q, k, v, axis="sep", causal=True)
                 elif c.use_flash_attention:
-                    o = sdpa(q, k, v, mask=mask_arr, causal=True)
+                    o = sdpa(q, k, v, mask=mask_arr, causal=True, **banded)
                 else:
-                    o = sdpa_reference(q, k, v, mask=mask_arr, causal=True)
+                    o = sdpa_reference(q, k, v, mask=mask_arr, causal=True,
+                                       **banded)
             with _scope("attn_out"):
                 # the row product's input is held to every row and this
                 # chip's heads (and so is its cotangent: the backward

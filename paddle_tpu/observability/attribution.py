@@ -246,6 +246,12 @@ SCOPES = ("embed", "attn_norm", "qkv_proj", "cache_write", "attention",
 #: A gated short convolution's parts (`lfm_*`, LFM2) answer as a
 #: state-space mixer's do; the copy of its tails at a page's last row
 #: into the page's snapshot (`tail_snapshot`) is a `cache_write`.
+#: The trainer's layers (ISSUE 66) tell a flash launch under a sliding
+#: window's band (`window_attention`) from a full one (`attention`), and
+#: the parts of a routed FFN around its grouped GEMMs — the choice and
+#: its statistics (`moe_route`), the sort and gather of the pair rows
+#: (`moe_dispatch`), the unsort and weighted sum (`moe_combine`) — from
+#: the GEMMs, which stay directly under `routed_ffn`.
 SCOPE_ALIASES = {"mla_q": "qkv_proj", "mla_kv": "qkv_proj",
                  "mla_attention": "attention", "mla_out": "attn_out",
                  "eva_attention": "attention", "eva_pool": "cache_write",
@@ -262,7 +268,10 @@ SCOPE_ALIASES = {"mla_q": "qkv_proj", "mla_kv": "qkv_proj",
                  "gmu": "attn_out", "shared_attention": "attention",
                  "diff_combine": "attn_out",
                  "lfm_in_proj": "qkv_proj", "lfm_conv": "cache_write",
-                 "tail_snapshot": "cache_write", "lfm_out": "attn_out"}
+                 "tail_snapshot": "cache_write", "lfm_out": "attn_out",
+                 "window_attention": "attention",
+                 "moe_route": "routed_ffn", "moe_dispatch": "routed_ffn",
+                 "moe_combine": "routed_ffn"}
 
 
 def scope(name: str):
@@ -283,7 +292,10 @@ def scope(name: str):
 #: scope's result.  The down projection is not here (nothing in the
 #: backward reads it), nor the swiglu product and the norms (the
 #: cheapest recomputation a byte).
-RESIDUALS = ("flash_o", "flash_lse", "attn_out", "qkv", "gate_up")
+#: A routed FFN's `moe_gate_up` is the sorted pair rows' gate and up
+#: products (two [pairs, width] grouped GEMMs; ISSUE 66).
+RESIDUALS = ("flash_o", "flash_lse", "attn_out", "qkv", "gate_up",
+             "moe_gate_up")
 
 
 _keeping: contextvars.ContextVar = contextvars.ContextVar(

@@ -55,8 +55,10 @@ def count_block_pairs(kernel: str, pairs: dict) -> None:
 
 
 def sdpa_reference(q, k, v, mask=None, causal: bool = False,
-                   dropout_p: float = 0.0, scale: Optional[float] = None):
-    """[B,S,H,D] scaled-dot-product attention, bf16-safe (f32 softmax)."""
+                   dropout_p: float = 0.0, scale: Optional[float] = None,
+                   window: Optional[int] = None):
+    """[B,S,H,D] scaled-dot-product attention, bf16-safe (f32 softmax).
+    `window` W (with `causal`): query i sees keys j with i - W < j <= i."""
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     if scale is None:
@@ -68,6 +70,8 @@ def sdpa_reference(q, k, v, mask=None, causal: bool = False,
     logits = logits.astype(jnp.float32)
     if causal:
         cm = jnp.tril(jnp.ones((Sq, Sk), bool), k=Sk - Sq)
+        if window is not None:
+            cm &= ~jnp.tril(jnp.ones((Sq, Sk), bool), k=Sk - Sq - window)
         logits = jnp.where(cm, logits, jnp.asarray(-1e30, logits.dtype))
     if mask is not None:
         m = jnp.asarray(mask)
@@ -172,7 +176,7 @@ def sdpa_path(q, k, mask=None, causal: bool = False,
 
 
 def sdpa(q, k, v, mask=None, causal: bool = False, dropout_p: float = 0.0,
-         scale: Optional[float] = None):
+         scale: Optional[float] = None, window: Optional[int] = None):
     """Routing SDPA on raw [B,S,H,D] arrays: Pallas flash kernel on TPU
     (ref parity: FlashAttnKernel, paddle/phi/kernels/gpu/flash_attn_kernel.cu
     — here the fused device kernel is the in-tree Pallas TPU flash attention
@@ -182,19 +186,30 @@ def sdpa(q, k, v, mask=None, causal: bool = False, dropout_p: float = 0.0,
     Boolean key-padding masks route through the fused segment-id kernel
     (masked keys get segment 0, every query row segment 1) — NOT the
     composite; all query rows match the composite's semantics (masked
-    keys are excluded for everyone)."""
+    keys are excluded for everyone).
+
+    `window` W (static, with `causal`: query i sees keys j with
+    i - W < j <= i) is a band of the in-tree kernel's block-pair table;
+    the bundled and the segmented paths have none and give way to the
+    composite."""
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     if scale is None:
         scale = D ** -0.5
+    if window is not None and not causal:
+        raise ValueError("sdpa: a window needs causal=True")
     path = sdpa_path(q, k, mask=mask, causal=causal, dropout_p=dropout_p)
+    if window is not None and not (path == "flash"
+                                   and _flash_impl() == "intree"):
+        path = "composite"
     if path == "flash":
         if _flash_impl() == "intree":
             _count_kernel("flash_intree")
             from .pallas_flash import flash_sdpa
             return flash_sdpa(q, k, v, causal=causal, scale=scale,
                               block_q=_largest_dividing_block(Sq),
-                              block_k=_largest_dividing_block(Sk))
+                              block_k=_largest_dividing_block(Sk),
+                              window=window)
         _count_kernel("flash_bundled")
         from jax.experimental.pallas.ops.tpu.flash_attention import (
             flash_attention as _pallas_flash)
@@ -225,7 +240,7 @@ def sdpa(q, k, v, mask=None, causal: bool = False, dropout_p: float = 0.0,
         if pad is not None:  # normalize [B,Sk] forms for broadcasting
             mask = pad[:, None, None, :]
     return sdpa_reference(q, k, v, mask=mask, causal=causal,
-                          dropout_p=dropout_p, scale=scale)
+                          dropout_p=dropout_p, scale=scale, window=window)
 
 
 def sdpa_prefill(q, k, v, *, causal: bool = True,

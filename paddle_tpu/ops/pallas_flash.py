@@ -22,6 +22,11 @@ kernel refuses):
     whether the diagonal cuts it: an interior pair runs with no iota, no
     compare and no `where` (with segment ids, which are data, every
     visible pair stays masked);
+  - an optional static sliding WINDOW `W` on top of causal (query at
+    position i sees keys j with i - W < j <= i): one more bound of the
+    SAME table — a pair wholly left of the band is hidden (no grid step),
+    a pair the band's left edge cuts is masked, a pair between the two
+    edges stays interior — for the forward and both backward sweeps;
   - online-softmax forward emitting logsumexp; flash-style backward (dq
     sweep over a query block's key blocks; dk/dv sweep over a key block's
     query blocks on the TRANSPOSED scores); lse / di travel as dense
@@ -77,29 +82,38 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _pair_kind(qi, kj, bq, bk, off, causal, use_seg) -> str:
+def _pair_kind(qi, kj, bq, bk, off, causal, use_seg, window=None) -> str:
     """Static geometry of pair (qi, kj): row r sees column c iff
-    c <= r + off. Segment ids are data, so with them no pair is interior."""
+    c <= r + off and, under a window W, r + off - c < W. Segment ids are
+    data, so with them no pair is interior."""
     if causal and kj * bk > qi * bq + (bq - 1) + off:
         return _HIDDEN              # above the diagonal of its LAST row
+    if window is not None and \
+            qi * bq + off - (kj * bk + bk - 1) >= window:
+        return _HIDDEN              # left of the band of its FIRST row
     if use_seg or (causal and kj * bk + (bk - 1) > qi * bq + off):
         return _MASKED              # the diagonal of its FIRST row cuts it
+    if window is not None and \
+            qi * bq + (bq - 1) + off - kj * bk >= window:
+        return _MASKED              # the band's edge of its LAST row does
     return _INTERIOR
 
 
 @functools.lru_cache(maxsize=None)
-def _visit_table(nq, nk, bq, bk, off, causal, use_seg, order):
+def _visit_table(nq, nk, bq, bk, off, causal, use_seg, order, window=None):
     """The pairs a sweep visits, in its order, as (qi, kj, flags) columns
     and the sweep's {kind: pairs} counts. order 'qk': a run is a query
     block's visible key blocks (forward, dq); 'kq': a key block's visible
     query blocks (dk / dv). A run the mask leaves nothing of (the query
-    blocks above a causal diagonal with Sq > Sk) still visits one masked
-    pair, which writes its block: zeros and the lse sentinel."""
+    blocks above a causal diagonal with Sq > Sk, the key blocks no query
+    of a shorter Sq reaches back to under a window) still visits one
+    masked pair, which writes its block: zeros and the lse sentinel."""
     runs, inner = (nq, nk) if order == "qk" else (nk, nq)
     rows, counts = [], {_INTERIOR: 0, _MASKED: 0}
     for r in range(runs):
         pairs = [(r, c) if order == "qk" else (c, r) for c in range(inner)]
-        run = [(qi, kj, _pair_kind(qi, kj, bq, bk, off, causal, use_seg))
+        run = [(qi, kj, _pair_kind(qi, kj, bq, bk, off, causal, use_seg,
+                                   window))
                for qi, kj in pairs]
         run = [pair for pair in run if pair[2] != _HIDDEN] \
             or [(*pairs[0], _MASKED)]
@@ -113,7 +127,7 @@ def _visit_table(nq, nk, bq, bk, off, causal, use_seg, order):
 
 
 def _mask_for_block(qi, kj, bq, bk, causal, off, use_seg, sq_ref, sk_ref,
-                    transposed=False):
+                    transposed=False, window=None):
     """bool mask of HIDDEN entries for this block: [bq, bk], or [bk, bq]
     for the dk / dv sweep's transposed scores."""
     shape, qax = ((bk, bq), 1) if transposed else ((bq, bk), 0)
@@ -122,6 +136,8 @@ def _mask_for_block(qi, kj, bq, bk, causal, off, use_seg, sq_ref, sk_ref,
         rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, shape, qax)
         cols = kj * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - qax)
         masked = cols > rows + off
+        if window is not None:
+            masked = jnp.logical_or(masked, rows + off - cols >= window)
     if use_seg:
         sq, sk = sq_ref[0, 0], sk_ref[0, 0]
         seg = (sk[:, None] != sq[None, :] if transposed
@@ -131,7 +147,7 @@ def _mask_for_block(qi, kj, bq, bk, causal, off, use_seg, sq_ref, sk_ref,
 
 
 def _sweep(tabs, init, pair, emit, *, bq, bk, causal, off, use_seg,
-           transposed=False):
+           window=None, transposed=False):
     """One grid step = one visited pair of the table: `init` on the first
     of its run, `pair(masked)` with no mask at all on an interior pair and
     with the block's mask on a masked one, `emit` on the run's last."""
@@ -142,7 +158,7 @@ def _sweep(tabs, init, pair, emit, *, bq, bk, causal, off, use_seg,
     if causal or use_seg:
         pl.when(fl & _MASK != 0)(lambda: pair(_mask_for_block(
             qi, kj, bq, bk, causal, off, use_seg, sq_ref, sk_ref,
-            transposed)))
+            transposed, window)))
     if not use_seg:
         pl.when(fl & _MASK == 0)(lambda: pair(None))
     pl.when(fl & _LAST != 0)(emit)
@@ -319,26 +335,28 @@ def _pairs(kernel, order, q, k, *, bq, bk, **geometry):
 _QKV = ("bhsd", "bhsd", "bhsd", "b1s", "b1s")
 
 
-def _fwd_on_mesh(q, k, v, seg_q, seg_kv, scale, causal, bq, bk, use_seg):
+def _fwd_on_mesh(q, k, v, seg_q, seg_kv, scale, causal, bq, bk, use_seg,
+                 window):
     fwd = functools.partial(_flash_fwd_impl, scale=scale, causal=causal,
-                            bq=bq, bk=bk, use_seg=use_seg)
+                            bq=bq, bk=bk, use_seg=use_seg, window=window)
     return on_mesh(fwd, (q, k, v, seg_q, seg_kv), _QKV, ("bhsd", "bh1s"))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash_core(q, k, v, seg_q, seg_kv, scale, causal, bq, bk, use_seg,
-                kept=False):
+                kept=False, window=None):
     o, _ = _fwd_on_mesh(q, k, v, seg_q, seg_kv, scale, causal, bq, bk,
-                        use_seg)
+                        use_seg, window)
     return o
 
 
 def _flash_fwd_impl(q, k, v, seg_q, seg_kv, scale, causal, bq, bk,
-                    use_seg):
+                    use_seg, window=None):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     geometry = dict(bq=bq, bk=bk, off=Sk - Sq, causal=causal,
-                    use_seg=use_seg)
+                    use_seg=use_seg, window=window)
     table, n_pairs = _pairs("fwd", "qk", q, k, **geometry)
     in_specs, row_spec, qmap, _ = _specs(bq, bk, D)
     o, lse = pl.pallas_call(
@@ -360,9 +378,9 @@ def _flash_fwd_impl(q, k, v, seg_q, seg_kv, scale, causal, bq, bk,
 
 
 def _flash_vjp_fwd(q, k, v, seg_q, seg_kv, scale, causal, bq, bk,
-                   use_seg, kept):
+                   use_seg, kept, window):
     o, lse = _fwd_on_mesh(q, k, v, seg_q, seg_kv, scale, causal, bq, bk,
-                          use_seg)
+                          use_seg, window)
     if kept:
         # a checkpoint around the caller keeps the kernel's output and
         # row log-sum-exp (`observability.attribution.RESIDUALS`), so
@@ -376,20 +394,20 @@ def _flash_vjp_fwd(q, k, v, seg_q, seg_kv, scale, causal, bq, bk,
     return o, (q, k, v, seg_q, seg_kv, o, lse)
 
 
-def _flash_vjp_bwd(scale, causal, bq, bk, use_seg, kept, res, do):
+def _flash_vjp_bwd(scale, causal, bq, bk, use_seg, kept, window, res, do):
     bwd = functools.partial(_flash_bwd_impl, scale=scale, causal=causal,
-                            bq=bq, bk=bk, use_seg=use_seg)
+                            bq=bq, bk=bk, use_seg=use_seg, window=window)
     dq, dk, dv = on_mesh(bwd, (*res, do),
                          _QKV + ("bhsd", "bh1s", "bhsd"), ("bhsd",) * 3)
     return dq, dk, dv, None, None
 
 
 def _flash_bwd_impl(q, k, v, seg_q, seg_kv, o, lse, do, scale, causal,
-                    bq, bk, use_seg):
+                    bq, bk, use_seg, window=None):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     geometry = dict(bq=bq, bk=bk, off=Sk - Sq, causal=causal,
-                    use_seg=use_seg)
+                    use_seg=use_seg, window=window)
     di = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                  axis=-1)[:, :, None]                        # [B,H,1,Sq]
     in_specs, row_spec, qmap, kmap = _specs(bq, bk, D)
@@ -443,9 +461,12 @@ def flash_kernel_eligible(Sq: int, Sk: int, D: int, block_q: int = 128,
 
 def flash_sdpa(q, k, v, causal: bool = False, segment_ids_q=None,
                segment_ids_kv=None, scale: Optional[float] = None,
-               block_q: int = 512, block_k: int = 512):
+               block_q: int = 512, block_k: int = 512,
+               window: Optional[int] = None):
     """[B,S,H,D] flash attention through the in-tree kernel. Causal is
     bottom-right aligned for Sq != Sk (sdpa_reference convention).
+    `window` W (static, with `causal`): a query at position i sees the
+    keys j with i - W < j <= i, itself included.
     Differentiable (flash-style bwd kernels). Default 512x512 blocks
     (tools/flash_bench.py sweep on the v5e: 512-class blocks beat 128 by
     ~1.2-1.7x at seq >= 4096); blocks clamp to the sequence lengths so
@@ -460,6 +481,11 @@ def flash_sdpa(q, k, v, causal: bool = False, segment_ids_q=None,
             f"{block_q}x{block_k} (see flash_kernel_eligible)")
     if scale is None:
         scale = D ** -0.5
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("flash_sdpa: a window needs causal=True and "
+                             f"W >= 1 (got causal={causal}, W={window})")
+        window = int(window)
     use_seg = segment_ids_q is not None or segment_ids_kv is not None
     if use_seg:
         seg_q = (segment_ids_q if segment_ids_q is not None
@@ -478,7 +504,7 @@ def flash_sdpa(q, k, v, causal: bool = False, segment_ids_q=None,
     vh = jnp.swapaxes(v, 1, 2)
     out = _flash_core(qh, kh, vh, seg_q, seg_kv, float(scale),
                       bool(causal), block_q, block_k, use_seg,
-                      _keeps("flash_o") and _keeps("flash_lse"))
+                      _keeps("flash_o") and _keeps("flash_lse"), window)
     return jnp.swapaxes(out, 1, 2)
 
 

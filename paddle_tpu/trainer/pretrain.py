@@ -1,5 +1,7 @@
-"""Llama pretrain step — the flagship hybrid-parallel training program
-(ref: PaddleNLP llm/run_pretrain.py over fleet 4D; SURVEY §3.5).
+"""Pretrain step — the flagship hybrid-parallel training program
+(ref: PaddleNLP llm/run_pretrain.py over fleet 4D; SURVEY §3.5), for a
+dense Llama and for any decoder FAMILY that says what it is
+(`DecoderFamily`: Mellum's sliding / full layers with routed experts).
 
 One jitted SPMD program composes every axis:
   pp  — compiled microbatch pipeline (distributed.pipeline)
@@ -20,8 +22,8 @@ from __future__ import annotations
 
 import functools
 import itertools
-from typing import Any, Dict, List, Mapping, NamedTuple, Optional, \
-    Sequence, Tuple
+from typing import Any, Callable, Dict, List, Mapping, NamedTuple, \
+    Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,7 +37,7 @@ from ..device import memory_stats
 from ..distributed.mesh import (build_hybrid_mesh, global_device_put,
                                 mesh_context)
 from ..distributed.parallel_layers import seq_sharded_on
-from ..observability.attribution import (compile_named, keeping,
+from ..observability.attribution import (RESIDUALS, compile_named, keeping,
                                          scope as _scope)
 from ..ops.on_mesh import kernel_mesh
 from ..distributed.pipeline import (PP_AXIS, spmd_pipeline,
@@ -50,7 +52,8 @@ from ..jit import _StateSwap, bind_state, extract_state
 
 __all__ = ["PretrainConfig", "build_llama_pretrain_step",
            "make_hybrid_mesh_for", "flops_per_token", "flops_per_token_hw",
-           "choose_remat_plan", "remat_order"]
+           "choose_remat_plan", "remat_order", "DecoderFamily",
+           "decoder_family", "MOE_METRICS", "record_moe_metrics"]
 
 _G_SAVED_BYTES = _obs.registry().gauge(
     "trainer.remat.saved_bytes",
@@ -63,6 +66,33 @@ _G_SEQ_SHARDED = _obs.registry().gauge(
     "1 where the built step holds its [B, S, H] activations [B, S/mp, H] a "
     "chip between a row-parallel and the next column-parallel product, "
     "else 0")
+
+#: what a routed family's step says of its routing beside the loss
+#: (`incubate.moe.routing_stats`, combined over the layers as the serving
+#: engine's step counts are: sums, the fullest expert, the mean), and the
+#: gauges `run_pretrain.run` keeps of them
+MOE_METRICS = ("moe_pairs_routed", "moe_pairs_held", "moe_expert_rows_max",
+               "moe_expert_rows_mean", "aux_loss")
+_G_MOE = {name: _obs.registry().gauge(
+    "trainer.moe." + name.removeprefix("moe_"), text)
+    for name, text in zip(MOE_METRICS, (
+        "(token, expert) pairs the last logged step routed, summed over "
+        "the routed layers",
+        "of those, the pairs that met an expert this program holds",
+        "rows of the fullest held expert in any layer of that step",
+        "mean rows a held expert, mean over the layers",
+        "sum over the layers of the load-balance term (before its "
+        "coefficient)"))}
+
+
+def record_moe_metrics(metrics: Mapping[str, Any]) -> Dict[str, float]:
+    """The routing numbers of one step's `metrics` as floats, kept as
+    the gauges `trainer.moe.*`; {} for a dense family's step."""
+    out = {k: float(metrics[k]) for k in MOE_METRICS if k in metrics}
+    for k, v in out.items():
+        _G_MOE[k].set(v)
+    return out
+
 
 #: What `remat` "full" leaves free on a chip BESIDE the step program when
 #: it chooses the residuals to keep: a program's `memory_analysis()` is
@@ -150,19 +180,38 @@ def make_hybrid_mesh_for(cfg: PretrainConfig, devices=None) -> Mesh:
                              sep_degree=cfg.sep, devices=devices)
 
 
-def _n_params(c: LlamaConfig) -> float:
+def _ffn_params(c: LlamaConfig, pairs_held: Optional[float]) -> float:
+    """Parameters of one layer's FFN that a token multiplies: the dense
+    gate / up / down; for a routed layer (a config with
+    `num_experts_per_tok`) the router plus `pairs_held` experts — the
+    (token, expert) pairs a token that meet an expert HELD here, which
+    is the routing's to say; None reckons a uniform router, top-k times
+    the share of the experts held."""
+    k = getattr(c, "num_experts_per_tok", None)
+    if k is None:
+        return 3 * c.hidden_size * c.intermediate_size
+    held = getattr(c, "experts_held", None)
+    if pairs_held is None:
+        pairs_held = k * (held[1] / c.num_experts if held else 1.0)
+    return (c.hidden_size * c.num_experts
+            + pairs_held * 3 * c.hidden_size * c.moe_intermediate_size)
+
+
+def _n_params(c: LlamaConfig, pairs_held: Optional[float] = None) -> float:
     return (c.vocab_size * c.hidden_size * (1 if c.tie_word_embeddings else 2)
             + c.num_hidden_layers * (
                 c.hidden_size * c.head_dim
                 * (c.num_attention_heads + 2 * c.num_key_value_heads)
                 + c.num_attention_heads * c.head_dim * c.hidden_size
-                + 3 * c.hidden_size * c.intermediate_size
+                + _ffn_params(c, pairs_held)
                 + 2 * c.hidden_size)
             + c.hidden_size)
 
 
-def flops_per_token(c: LlamaConfig) -> float:
-    """6*N FLOPs/token — weight FLOPs only, NO attention term.
+def flops_per_token(c: LlamaConfig,
+                    pairs_held: Optional[float] = None) -> float:
+    """6*N FLOPs/token — weight FLOPs only, NO attention term.  For a
+    routed family N is the parameters a token MULTIPLIES (`_ffn_params`).
 
     This is the *model*-FLOPs MFU denominator (the conservative convention:
     attention score/value FLOPs the hardware actually performs are not
@@ -170,10 +219,11 @@ def flops_per_token(c: LlamaConfig) -> float:
     hardware-FLOPs variant that adds the 12*L*h*s attention term, use
     `flops_per_token_hw`; both are reported in docs/FLAGSHIP.md.
     """
-    return 6.0 * _n_params(c)
+    return 6.0 * _n_params(c, pairs_held)
 
 
-def flops_per_token_hw(c: LlamaConfig, seq_len: int) -> float:
+def flops_per_token_hw(c: LlamaConfig, seq_len: int,
+                       pairs_held: Optional[float] = None) -> float:
     """6*N + attention FLOPs/token: the hardware-FLOPs MFU denominator.
 
     Attention adds 2 matmuls (QK^T and PV) per head per layer, each
@@ -181,16 +231,30 @@ def flops_per_token_hw(c: LlamaConfig, seq_len: int) -> float:
     4*s*head_dim*n_heads*L forward FLOPs/token; the backward costs 2x the
     forward, so fwd+bwd = 3x -> 12 * L * n_heads * head_dim * seq_len per
     token (causal masking halves the realized work, but the dense
-    convention is standard for MFU).
+    convention is standard for MFU).  A config with `layer_types` (a
+    family of sliding and full layers) is counted by the keys VISIBLE to
+    a query instead: the causal mean (seq + 1) / 2 on a full layer, the
+    band's mean on a sliding one.
     """
-    attn = 12.0 * c.num_hidden_layers * c.num_attention_heads * c.head_dim * seq_len
-    return 6.0 * _n_params(c) + attn
+    kinds = getattr(c, "layer_types", None)
+    if kinds is None:
+        keys = float(c.num_hidden_layers * seq_len)
+    else:
+        def visible(w):
+            w = seq_len if w is None else min(w, seq_len)
+            # query i sees min(i + 1, w) keys
+            return (w * (w + 1) / 2 + (seq_len - w) * w) / seq_len
+        keys = sum(visible(c.window_of(kind)) for kind in kinds)
+    attn = 12.0 * c.num_attention_heads * c.head_dim * keys
+    return 6.0 * _n_params(c, pairs_held) + attn
 
 
 #: state-dict key prefix of a decoder layer's parameter -> the step scope
 #: (`observability.attribution.SCOPES`) of the part that uses it
 _WEIGHT_SCOPES = (("input_layernorm", "attn_norm"),
                   ("post_attention_layernorm", "ffn_norm"),
+                  ("mlp.gate_weight", "routed_ffn"),
+                  ("mlp.w_", "routed_ffn"),
                   ("mlp.", "ffn"),
                   ("self_attn.o_proj", "attn_out"),
                   ("self_attn.", "qkv_proj"))
@@ -200,7 +264,8 @@ def _weight_scope(key: str) -> str:
     return next(s for prefix, s in _WEIGHT_SCOPES if key.startswith(prefix))
 
 
-def remat_order(mp: int) -> Tuple[Tuple[str, ...], ...]:
+def remat_order(mp: int, routed: bool = False
+                ) -> Tuple[Tuple[str, ...], ...]:
     """The residuals a layer's checkpoint may keep
     (`observability.attribution.RESIDUALS`), most recomputation saved a
     byte first.  That follows from shapes: the flash kernel is quadratic
@@ -218,11 +283,14 @@ def remat_order(mp: int) -> Tuple[Tuple[str, ...], ...]:
     gathered norm outputs (and they ride inside the backward's matmuls;
     the step compiled for a v5e 2x2, `PERF.md` section 6, PR 65).  So
     there `attn_out` goes before the other two; without `mp` it ranks
-    with them, behind the smaller `qkv`."""
+    with them, behind the smaller `qkv`.  A `routed` layer's FFN keeps
+    `moe_gate_up` where a dense one keeps `gate_up`: the sorted pair
+    rows' gate and up products, the same contraction a byte."""
     flash = ("flash_o", "flash_lse")
+    ffn = ("moe_gate_up",) if routed else ("gate_up",)
     if mp > 1:
-        return (flash, ("attn_out",), ("qkv",), ("gate_up",))
-    return (flash, ("qkv",), ("attn_out",), ("gate_up",))
+        return (flash, ("attn_out",), ("qkv",), ffn)
+    return (flash, ("qkv",), ("attn_out",), ffn)
 
 
 def choose_remat_plan(nbytes: Mapping[str, int], n_layers: int,
@@ -291,6 +359,44 @@ def _program_need(compiled) -> int:
                + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
 
 
+class DecoderFamily(NamedTuple):
+    """What the step builder reads of a decoder family.  A model config
+    with a `pretrain_family()` method gives its own (`models/mellum.py`);
+    one without is a dense Llama."""
+    build: Callable         # config -> the causal LM (every parameter)
+    layer: Callable         # the built LM -> the layers' template
+    layer_prefix: str       # state-dict prefix of the decoder layers
+    embed_key: str
+    norm_key: str
+    head_key: str
+    #: a static kind a layer, handed to the template's call after the
+    #: rotary tables; () where the layers are all alike
+    kinds: Tuple[str, ...] = ()
+    #: seq_len -> (cos, sin) as the template reads them; None: Llama's
+    rope: Optional[Callable] = None
+    #: not None: the template's `mlp` leaves a load-balance term and
+    #: `routing_stats` (`l_aux`, `l_stats`) after each call, and the
+    #: loss adds this coefficient times the layers' sum
+    aux_coef: Optional[float] = None
+
+
+_LLAMA_FAMILY = DecoderFamily(
+    build=LlamaForCausalLM, layer=lambda lm: LlamaDecoderLayer(lm.config),
+    layer_prefix="llama.layers.", embed_key="llama.embed_tokens.weight",
+    norm_key="llama.norm.weight", head_key="lm_head.weight")
+
+
+def decoder_family(mc) -> DecoderFamily:
+    own = getattr(mc, "pretrain_family", None)
+    return own() if own is not None else _LLAMA_FAMILY
+
+
+def _period(kinds: Sequence[str]) -> int:
+    """The shortest p with kinds[i] == kinds[i % p] for every layer."""
+    return next((p for p in range(1, len(kinds) + 1)
+                 if all(k == kinds[i % p] for i, k in enumerate(kinds))), 1)
+
+
 class TrainState(NamedTuple):
     params: Any          # bf16 compute params
     master: Any          # f32 master weights
@@ -302,14 +408,16 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
     """Returns (state, train_step, meta). train_step(state, batch_ids,
     labels) -> (state, metrics) — one fully-sharded jitted step."""
     mc = cfg.model
+    family = decoder_family(mc)
+    routed = family.aux_coef is not None
     with mesh_context(mesh):
-        model = LlamaForCausalLM(mc)
+        model = family.build(mc)
     param_dtype = jnp.bfloat16 if cfg.param_dtype == "bfloat16" else jnp.float32
 
     full_state = extract_state(model)
 
     # split decoder-layer params (pipelined & stacked) from outer params
-    layer_prefix = "llama.layers."
+    layer_prefix = family.layer_prefix
     per_layer: list = [dict() for _ in range(mc.num_hidden_layers)]
     outer: Dict[str, jnp.ndarray] = {}
     for k, v in full_state.items():
@@ -321,13 +429,17 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
             outer[k] = v
 
     n_stages = mesh.shape[PP_AXIS]
+    if routed and (n_stages > 1 or cfg.vpp > 1):
+        raise NotImplementedError(
+            "a routed family's load-balance term does not cross the "
+            "pipeline's stages yet: pp and vpp must be 1")
     if cfg.vpp > 1:
         stacked = stack_layer_params_interleaved(per_layer, n_stages, cfg.vpp)
     else:
         stacked = stack_layer_params(per_layer, n_stages)
 
     # sharding specs
-    tmpl = LlamaDecoderLayer(mc)
+    tmpl = family.layer(model)
     tmpl_sd = tmpl.state_dict()
     stacked_specs = {}
     n_lead = 3 if cfg.vpp > 1 else 2  # [S, (v,) L/stage, ...param dims]
@@ -372,7 +484,12 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
                          moment_dtype=cfg.moment_dtype)
     opt_state = tx.init(master)
 
-    cos, sin = precompute_rope(mc.head_dim, cfg.seq_len, mc.rope_theta)
+    cos, sin = family.rope(cfg.seq_len) if family.rope is not None \
+        else precompute_rope(mc.head_dim, cfg.seq_len, mc.rope_theta)
+    # layers of one parameter shape and several static kinds: the unit
+    # of the layer scan is a PERIOD, its kinds applied in order
+    kinds = tuple(family.kinds)
+    period = _period(kinds)
 
     def zero_gather(stacked_bf16):
         """Each layer's weights brought together over the sharding axis
@@ -416,37 +533,73 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
 
     def stage_body(saved):
         """The stage function whose layer i keeps `saved[i]` under its
-        checkpoint (`saved` empty: every layer keeps nothing)."""
+        checkpoint (`saved` empty: every layer keeps nothing).  A routed
+        family's returns (h, {"aux": [layers], "stats": [layers, 5]})."""
         def stage_fn(params_slice, x, cos_, sin_):
-            def body(kept, h, layer_params):
+            def body(kept, kind, h, layer_params):
                 with _StateSwap([tmpl]), keeping(kept):
                     bind_state(tmpl, layer_params)
                     from ..core import autograd as ag
                     with ag.no_grad():
-                        out = tmpl(Tensor(h), cos_, sin_)
-                return out._data, None
+                        out = tmpl(Tensor(h), cos_, sin_, *kind)
+                if not routed:
+                    return out._data, None
+                raw = lambda t: getattr(t, "_data", t)  # noqa: E731
+                return out._data, {"aux": raw(tmpl.mlp.l_aux),
+                                   "stats": raw(tmpl.mlp.l_stats)}
+
+            def layer_fn(kept, j):
+                """Layer j of a period under its own checkpoint."""
+                return remat_wrap(kept)(functools.partial(
+                    body, kept, (kinds[j],) if kinds else ()))
+
+            def period_body(layers, h, period_params):
+                ys = []
+                for j, layer in enumerate(layers):
+                    h, y = layer(
+                        h, {k: v[j] for k, v in period_params.items()})
+                    ys.append(y)
+                return h, jax.tree.map(lambda *a: jnp.stack(a), *ys)
             n_local = jax.tree.leaves(params_slice)[0].shape[0]
-            # one scan a run of layers that keep the same: ONE where the
+            if n_local % period:
+                raise ValueError(
+                    f"{n_local} layers a stage do not hold whole periods "
+                    f"of {period} layer kinds")
+            # one scan a run of periods that keep the same: ONE where the
             # plan is one set for all (the empty one included)
-            runs = [(kept, len(list(g))) for kept, g in
-                    itertools.groupby(saved or [()] * n_local)]
-            sizes = [n for _, n in runs]
+            plan_ = list(saved) or [()] * n_local
+            units = [tuple(plan_[i:i + period])
+                     for i in range(0, n_local, period)]
+            runs = [(kepts, len(list(g))) for kepts, g in
+                    itertools.groupby(units)]
+            sizes = [n * period for _, n in runs]
             assert sum(sizes) == n_local, (saved, n_local)
             split = {k: jax.lax.split(p, sizes)
                      for k, p in params_slice.items()} if len(runs) > 1 \
                 else {k: [p] for k, p in params_slice.items()}
-            h = x
-            for i, (kept, n) in enumerate(runs):
-                h, _ = jax.lax.scan(
-                    remat_wrap(kept)(functools.partial(body, kept)), h,
-                    {k: parts[i] for k, parts in split.items()},
-                    unroll=1 if cfg.scan_layers else n)
-            return h
+            h, extras = x, []
+            for i, (kepts, n) in enumerate(runs):
+                xs = {k: parts[i] for k, parts in split.items()}
+                if period == 1:
+                    fn = layer_fn(kepts[0], 0)
+                else:
+                    fn = functools.partial(period_body, [
+                        layer_fn(kept, j) for j, kept in enumerate(kepts)])
+                    xs = {k: v.reshape((n, period) + v.shape[1:])
+                          for k, v in xs.items()}
+                h, ys = jax.lax.scan(fn, h, xs,
+                                     unroll=1 if cfg.scan_layers else n)
+                extras.append(ys)
+            if not routed:
+                return h
+            lead = 1 if period == 1 else 2      # [n(, period), ...]
+            return h, jax.tree.map(
+                lambda *a: jnp.concatenate(
+                    [v.reshape((-1,) + v.shape[lead:]) for v in a]), *extras)
         return stage_fn
 
-    embed_key = "llama.embed_tokens.weight"
-    norm_key = "llama.norm.weight"
-    head_key = "lm_head.weight"
+    embed_key, norm_key, head_key = (family.embed_key, family.norm_key,
+                                     family.head_key)
 
     M = cfg.n_microbatches
     B, S = cfg.global_batch, cfg.seq_len
@@ -610,6 +763,9 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
                                  extra_args=(cos.astype(x.dtype),
                                              sin.astype(x.dtype)),
                                  remat=(cfg.remat == "full"))
+        extras = None
+        if routed:          # (h, the layers' terms) of each microbatch
+            outs, extras = outs
         h = outs.reshape((B, S, -1))
         if head_key in compute_params["outer"]:
             w_head = compute_params["outer"][head_key]
@@ -625,15 +781,32 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
         total = _rms_head_loss(compute_params["outer"][norm_key], w_head,
                                h, labels, constrain=True)
         loss = total / (B * S)
-        return loss
+        if not routed:
+            return loss
+        # the load-balance term is a statistic of a microbatch's tokens:
+        # the layers' sum, mean over the microbatches; the routing
+        # numbers combine as the serving engine's step counts do
+        aux = extras["aux"].sum(-1).mean()
+        st = jax.lax.stop_gradient(extras["stats"])        # [M, L, 5]
+        said = {"moe_pairs_routed": st[..., 0].sum(),
+                "moe_pairs_held": st[..., 1].sum(),
+                "moe_expert_rows_max": st[..., 2].max(),
+                "moe_expert_rows_mean": st[..., 3].mean(),
+                "aux_loss": aux}
+        return loss + family.aux_coef * aux, said
 
     def step_with(saved, state: TrainState, ids, labels):
         def cast_loss(master_params):
             return loss_fn(saved, decorate_tree(master_params, param_dtype),
                            ids, labels)
         # Pallas kernels in the step run per-shard on this mesh
+        said = {}
         with kernel_mesh(mesh):
-            loss, grads = jax.value_and_grad(cast_loss)(state.master)
+            if routed:
+                (loss, said), grads = jax.value_and_grad(
+                    cast_loss, has_aux=True)(state.master)
+            else:
+                loss, grads = jax.value_and_grad(cast_loss)(state.master)
         # gradient clip, AdamW and the cast back, on each rank's shards
         with _scope("update"):
             new_master, new_opt, gnorm = tx.update(grads, state.opt_state,
@@ -641,7 +814,7 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
             new_params = decorate_tree(new_master, param_dtype)
         return TrainState(new_params, new_master, new_opt,
                           state.step + 1), {"loss": loss,
-                                            "grad_norm": gnorm}
+                                            "grad_norm": gnorm, **said}
 
     state = TrainState(compute, master, opt_state, jnp.zeros((), jnp.int32))
 
@@ -681,7 +854,7 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
     # stage-level checkpoint around the layers discards whatever a layer
     # kept, so the plan is empty there; with FLAGS_flash_impl "bundled"
     # the kernel's own custom_vjp carries no names, the rest applies.
-    order = remat_order(mp)
+    order = remat_order(mp, routed)
     item = jnp.dtype(param_dtype).itemsize
     rows = -(-B // (mesh.shape.get("dp", 1) * zdeg)) \
         * -(-S // mesh.shape.get("sep", 1))      # tokens a chip
@@ -694,8 +867,14 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
         "attn_out": rows * mc.hidden_size * item // (mp if seq_on_mp else 1),
         "qkv": rows * -(-(mc.num_attention_heads
                          + 2 * mc.num_key_value_heads) * mc.head_dim
-                        // mp) * item,
-        "gate_up": rows * -(-2 * mc.intermediate_size // mp) * item}
+                        // mp) * item}
+    if routed:
+        # the grouped GEMM's operand is ALL k pair rows a token, the
+        # absent experts' sorted behind the held ones (ROADMAP S12)
+        nbytes["moe_gate_up"] = (rows * mc.num_experts_per_tok
+                                 * 2 * mc.moe_intermediate_size * item)
+    else:
+        nbytes["gate_up"] = rows * -(-2 * mc.intermediate_size // mp) * item
     plan: List[Tuple[str, ...]] = [()] * mc.num_hidden_layers
     jstep = step_for(())
     limit = _bytes_limit(mesh) \
@@ -730,7 +909,7 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
               "floor_need": floor_need, "need": chosen_need}
     _G_SEQ_SHARDED.set(int(seq_on_mp))
     _G_SAVED_BYTES.set(record["saved_bytes"])
-    for n in nbytes:
+    for n in RESIDUALS:     # every name: 0 for one this family has not
         _G_SAVED_LAYERS.labels(name=n).set(sum(n in kept for kept in plan))
 
     # the init model is NOT kept: its f32 parameters are a second copy

@@ -85,10 +85,25 @@ def _load_config(path: str) -> dict:
 
 
 def _build_model_config(spec: dict, seq_len: int):
+    """The model configuration of a config's `model` table: a `preset`,
+    or the keys of the family `model_type` names ("llama", the default:
+    `LlamaConfig`; "mellum": `MellumConfig`).  An unknown `model_type`
+    is refused by name."""
     from ..models.llama import (LlamaConfig, llama3_8b_shard_config,
                                 llama_tiny_config)
     spec = dict(spec)
     preset = spec.pop("preset", None)
+    model_type = spec.pop("model_type", "llama")
+    if model_type == "mellum":
+        from ..models.mellum import MellumConfig, mellum_tiny_config
+        if preset not in (None, "tiny"):
+            raise SystemExit(f"unknown mellum preset {preset!r}")
+        return (mellum_tiny_config if preset == "tiny"
+                else MellumConfig)(**spec)
+    if model_type != "llama":
+        raise SystemExit(
+            f"unknown model_type {model_type!r}: run_pretrain builds "
+            f"'llama' and 'mellum'")
     if preset == "llama3_8b_shard":
         return llama3_8b_shard_config(mp=8, pp=4,
                                       max_position_embeddings=seq_len,
@@ -208,7 +223,8 @@ def run(cfg: dict) -> int:
     from ..distributed.mesh import global_device_put
     from ..io import DataLoader, DistributedBatchSampler
     from .pretrain import (PretrainConfig, build_llama_pretrain_step,
-                           flops_per_token, make_hybrid_mesh_for)
+                           flops_per_token, make_hybrid_mesh_for,
+                           record_moe_metrics)
 
     out_dir = cfg["output_dir"]
     os.makedirs(out_dir, exist_ok=True)
@@ -373,6 +389,10 @@ def run(cfg: dict) -> int:
                "tokens_per_s": round(tok_s, 1),
                "mfu_6N_est": (round(tok_s * fpt / pk.bf16_flops, 4)
                               if pk else None)}
+        # a routed family's step says what it routed (the gauges
+        # `trainer.moe.*`); a dense one adds nothing
+        rec.update({k: round(v, 6) for k, v in
+                    record_moe_metrics(jax.device_get(m)).items()})
         if logf is not None:
             logf.write(json.dumps(rec) + "\n")
             logf.flush()

@@ -161,7 +161,7 @@ def _segments(S, cut):
     return jnp.broadcast_to((jnp.arange(S) >= cut).astype(jnp.int32), (B, S))
 
 
-#: name -> (Sq, Sk, block_q, block_k, segment cut or None, dtype)
+#: name -> (Sq, Sk, block_q, block_k, segment cut or None, dtype[, window])
 GEOMETRIES = {
     # 3 x 3 blocks: hidden, interior and masked pairs all occur
     "causal_3x3": (192, 192, 64, 64, None, jnp.float32),
@@ -174,13 +174,25 @@ GEOMETRIES = {
     # sees whole by the causal geometry: the pair must still be masked
     "segment_in_interior_pair": (192, 192, 64, 64, 32, jnp.float32),
     "bf16": (192, 192, 64, 64, None, jnp.bfloat16),
+    # a sliding window (ISSUE 66): one more static bound of the SAME
+    # table — shorter than a key block, a block exactly, longer than the
+    # sequence (plain causal), across unequal blocks and lengths, and
+    # under segment ids
+    "window_lt_bk": (192, 192, 64, 64, None, jnp.float32, 24),
+    "window_eq_bk": (192, 192, 64, 64, None, jnp.float32, 64),
+    "window_gt_s": (192, 192, 64, 64, None, jnp.float32, 256),
+    "window_two_blocks": (256, 256, 32, 64, None, jnp.float32, 100),
+    "window_off_pos": (128, 240, 64, 48, None, jnp.float32, 70),
+    "window_segments": (192, 192, 64, 64, 32, jnp.float32, 48),
+    "window_bf16": (192, 192, 64, 64, None, jnp.bfloat16, 80),
 }
 
 
 def _case(name):
-    Sq, Sk, bq, bk, cut, dtype = GEOMETRIES[name]
+    Sq, Sk, bq, bk, cut, dtype, *window = GEOMETRIES[name]
+    window = window[0] if window else None
     q, k, v = _qkv(Sq, Sk, 64, seed=13, dtype=dtype)
-    kw = dict(causal=True, block_q=bq, block_k=bk)
+    kw = dict(causal=True, block_q=bq, block_k=bk, window=window)
     mask = None
     if cut is not None:
         sq, sk = _segments(Sq, cut), _segments(Sk, cut)
@@ -195,7 +207,8 @@ def _case(name):
         return flash_sdpa(q, k, v, **kw)
 
     def reference(q, k, v):
-        return sdpa_reference(q, k, v, mask=mask, causal=True)
+        return sdpa_reference(q, k, v, mask=mask, causal=True,
+                              window=window)
     return (q, k, v), kernel, reference, valid, tol
 
 
@@ -293,6 +306,58 @@ class TestVisitTable:
                     else:
                         assert bool(fl[n] & 4) == (kinds[pair] == "masked")
         assert checked == 94
+
+    @pytest.mark.parametrize("Sq,Sk,bq,bk,W", [
+        (96, 96, 32, 32, 8), (96, 96, 32, 32, 32), (96, 96, 32, 32, 33),
+        (96, 96, 16, 48, 40), (96, 96, 48, 16, 20), (64, 160, 32, 32, 50),
+        (176, 64, 16, 32, 30), (96, 96, 32, 32, 500)])
+    def test_window_kinds_follow_the_dense_band(self, Sq, Sk, bq, bk, W):
+        from paddle_tpu.ops.pallas_flash import _pair_kind, _visit_table
+        off, nq, nk = Sk - Sq, Sq // bq, Sk // bk
+        sees = np.tril(np.ones((Sq, Sk), bool), k=off) \
+            & ~np.tril(np.ones((Sq, Sk), bool), k=off - W)
+        kinds = {}
+        for qi in range(nq):
+            for kj in range(nk):
+                block = sees[qi * bq:(qi + 1) * bq, kj * bk:(kj + 1) * bk]
+                want = ("interior" if block.all() else
+                        "masked" if block.any() else "skipped")
+                kinds[qi, kj] = _pair_kind(qi, kj, bq, bk, off, True, False,
+                                           W)
+                assert kinds[qi, kj] == want, (qi, kj)
+                assert _pair_kind(qi, kj, bq, bk, off, True, True, W) == (
+                    "masked" if block.any() else "skipped")
+        visible = {p for p, kind in kinds.items() if kind != "skipped"}
+        for order in ("qk", "kq"):
+            (qi, kj, fl), counts = _visit_table(
+                nq, nk, bq, bk, off, True, False, order, W)
+            visited = set(zip(qi.tolist(), kj.tolist()))
+            assert sum(counts.values()) == nq * nk
+            assert len(visited) == len(fl) and visible <= visited
+            # a run the band leaves nothing of visits ONE forced pair
+            assert len(visited - visible) <= (nq if order == "qk" else nk)
+            for n, pair in enumerate(zip(qi.tolist(), kj.tolist())):
+                if pair not in visible:
+                    assert fl[n] == 1 | 2 | 4
+
+    def test_window_counts_at_the_training_shape(self):
+        """S = 8,192 in 512 x 512 blocks under W = 1,024 (Mellum2's
+        sliding layers): a query block visits its own key block (the
+        diagonal cuts it), the one before (whole) and the one before
+        that (the band's edge cuts it) — 45 of 256 pairs against the
+        causal table's 136, for both sweeps' orders."""
+        from paddle_tpu.ops.pallas_flash import _visit_table
+        for order in ("qk", "kq"):
+            _, band = _visit_table(16, 16, 512, 512, 0, True, False, order,
+                                   1024)
+            assert band == {"interior": 15, "masked": 30, "skipped": 211}
+            _, full = _visit_table(16, 16, 512, 512, 0, True, False, order)
+            assert full == {"interior": 120, "masked": 16, "skipped": 120}
+
+    def test_window_needs_causal(self):
+        q, k, v = _qkv(64, 64, 64)
+        with pytest.raises(ValueError, match="window"):
+            flash_sdpa(q, k, v, causal=False, window=16)
 
     def test_counter_counts_a_launch_by_kind(self):
         from paddle_tpu.observability import registry, sample_values

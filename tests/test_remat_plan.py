@@ -76,9 +76,15 @@ def test_the_choice(case, headroom, unrolled, mp, want):
 
 def test_the_order_names_the_vocabulary_once():
     for mp in (1, 2, 8):
+        # a dense layer's FFN keeps `gate_up`, a routed one's (ISSUE 66)
+        # the sorted pair rows' `moe_gate_up`: the two orders between
+        # them name every residual, each its own once
         flat = [n for names in remat_order(mp) for n in names]
-        assert sorted(flat) == sorted(at.RESIDUALS)
-        assert flat[:2] == list(FLASH)
+        routed = [n for names in remat_order(mp, routed=True) for n in names]
+        assert sorted(flat) == sorted(set(at.RESIDUALS) - {"moe_gate_up"})
+        assert sorted(routed) == sorted(set(at.RESIDUALS) - {"gate_up"})
+        assert flat[:2] == routed[:2] == list(FLASH)
+        assert routed[:-1] == flat[:-1]
     with pytest.raises(ValueError):
         at.residual(jnp.zeros(2), "swiglu")
 
@@ -286,8 +292,9 @@ def test_the_plan_is_reported(monkeypatch):
         == plan["saved_bytes"]
     layers = {tuple(s["labels"].values())[0]: s["value"]
               for s in snap["trainer.remat.saved_layers"]["series"]}
+    # every residual of the vocabulary, a routed family's at 0 here
     assert layers == {"flash_o": 2, "flash_lse": 2, "attn_out": 2,
-                      "qkv": 2, "gate_up": 1}
+                      "qkv": 2, "gate_up": 1, "moe_gate_up": 0}
 
 
 @needs_4

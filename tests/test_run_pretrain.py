@@ -115,3 +115,40 @@ class TestRunPretrainCLI:
             assert got[s] == pytest.approx(ref[s], abs=5e-4), \
                 (s, got[s], ref[s])
         assert max(got) == 60 and "done at step 60" in r2.stdout
+
+
+def test_a_mellum_model_file_trains_through_run(tmp_path, capsys):
+    """ISSUE 66: `model_type: "mellum"` through `run()` — the same step
+    builder as a Llama, a routed FFN in every layer, sliding and full
+    layers — for three steps, data-parallel over the suite's devices: the loss falls, the step's
+    routing numbers are logged and kept as the gauges `trainer.moe.*`;
+    an unknown `model_type` is refused by name."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.trainer import run_pretrain
+    cfg = dict(run_pretrain.DEFAULTS)
+    cfg.update(
+        model={"model_type": "mellum", "preset": "tiny",
+               "experts_held": [0, 4]},
+        data={"corpus": None, "synthetic_tokens": 64 * 8 * 4 + 1},
+        seq_len=64, global_batch=8, max_steps=3, lr=1e-2, save_interval=0,
+        parallel={"dp": len(__import__("jax").devices())},
+        scan_layers=False, output_dir=str(tmp_path / "mellum"))
+    assert run_pretrain.run(cfg) == 0
+    recs = [json.loads(line) for line in
+            open(os.path.join(cfg["output_dir"], "losses.jsonl"))]
+    assert [r["step"] for r in recs] == [1, 2, 3]
+    assert recs[-1]["loss"] < recs[0]["loss"]
+    for r in recs:
+        # 4 layers x 512 tokens x top-2, about half of them held
+        assert r["moe_pairs_routed"] == 4 * 512 * 2
+        assert 0 < r["moe_pairs_held"] < r["moe_pairs_routed"]
+        assert r["moe_expert_rows_max"] >= r["moe_expert_rows_mean"] > 0
+        assert r["aux_loss"] > 0
+    out = capsys.readouterr().out
+    assert "moe_pairs_held" in out
+    snap = obs.registry().snapshot()
+    assert snap["trainer.moe.pairs_held"]["series"][0]["value"] == \
+        recs[-1]["moe_pairs_held"]
+    assert snap["trainer.moe.aux_loss"]["series"][0]["value"] > 0
+    with pytest.raises(SystemExit, match="nosuch"):
+        run_pretrain._build_model_config({"model_type": "nosuch"}, 64)

@@ -175,3 +175,40 @@ def test_the_step_lowers_to_the_pinned_text(family, program):
     text = lower_step(_engine(family), program).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() \
         == PINS[family, program]
+
+
+# ---------------------------------------------------------------------------
+# the TRAINER's step (ISSUE 66): the Mistral training cell's program at its
+# rehearsal sizes on four simulated devices (ZeRO 2 x mp 2, remat "full",
+# fused qkv / ffn).  The step builder takes the decoder family from the
+# model since PR 66 (a routed family's layers hand a loss term out of the
+# layer scan, a period of layer kinds is the scan's unit); a dense family's
+# step must lower to the text it lowered to before.  Recorded at PR 66's
+# parent (aea953f) and unchanged by PR 66.
+# ---------------------------------------------------------------------------
+
+TRAIN_PIN = "8fcbacbffce859e8d2bf7f2f175734b09e438f14529c5b98f34a6d2a15f720ea"
+
+
+def test_the_mistral_train_step_lowers_to_the_pinned_text():
+    from paddle_tpu.models.llama import LlamaConfig
+    from paddle_tpu.trainer.pretrain import (PretrainConfig,
+                                             build_llama_pretrain_step,
+                                             make_hybrid_mesh_for)
+    import paddle_tpu as paddle
+    paddle.seed(7)
+    mc = LlamaConfig(vocab_size=512, hidden_size=128, intermediate_size=256,
+                     num_hidden_layers=2, num_attention_heads=4,
+                     num_key_value_heads=2, head_dim=32,
+                     max_position_embeddings=64, rope_theta=1000000.0,
+                     rms_norm_eps=1e-5, sequence_parallel=False,
+                     fuse_attention_qkv=True, fuse_attention_ffn=True,
+                     fuse_pack_groups=2)
+    cfg = PretrainConfig(mc, global_batch=4, seq_len=64, mp=2, sharding=2,
+                         remat="full", scan_layers=False, ce_chunks=2)
+    mesh = make_hybrid_mesh_for(cfg, devices=jax.devices()[:4])
+    state, jstep, meta = build_llama_pretrain_step(cfg, mesh)
+    spec = jax.ShapeDtypeStruct((4, 64), jnp.int32,
+                                sharding=meta["data_sharding"])
+    text = jstep.lower(state, spec, spec).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == TRAIN_PIN

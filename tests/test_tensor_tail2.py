@@ -247,6 +247,10 @@ class TestLinalgTail:
         np.testing.assert_allclose(got, qfull @ B[:4, :4], rtol=1e-4,
                                    atol=1e-4)
         big = R.standard_normal((20, 8)).astype(np.float32)
+        # the sketch comes from the global generator: one draw in ten
+        # loses the smallest direction to float32 (error over 1e-3), and
+        # which draw a worker makes here depends on the files before it
+        paddle.seed(7)
         u, s, v = L.svd_lowrank(paddle.to_tensor(big), q=8)
         np.testing.assert_allclose(
             u.numpy() @ np.diag(s.numpy()) @ v.numpy().T, big,
