@@ -31,7 +31,8 @@ from .. import nn
 from ..nn import initializer as I
 from ..distributed.mesh import get_mesh
 from ..observability.attribution import residual as _residual
-from ..ops.grouped_gemm import grouped_gemm, sort_by_group, unsort_by_group
+from ..ops.grouped_gemm import (combine_pair_rows, dispatch_pair_rows,
+                                grouped_gemm, pair_rows_visited)
 
 __all__ = ["top_k_gating", "load_balance_loss",
            "load_balance_loss_all_choices", "router_z_loss",
@@ -232,32 +233,6 @@ def load_balance_loss_all_choices(gates, topi):
     return E * jnp.sum(jnp.mean(gates, axis=0) * jnp.mean(chosen, axis=0))
 
 
-@jax.custom_vjp
-def _owned_rows(rows, n_owned):
-    """`rows` [M, ..] as they are; the COTANGENT of the rows from
-    `n_owned` on is zero.  A grouped GEMM owns no row past its last
-    group: forward they come back as whatever the kernel left there
-    (masked after the unsort), and so does their gradient — a backward
-    through the sorted rows has to drop it the same way, or the absent
-    experts' pair rows add noise to their tokens' gradient (ISSUE 66:
-    the first chip run's embedding gradient read NOTHING of the
-    reference's; the CPU's `ragged_dot` writes zeros there and hid it)."""
-    return rows
-
-
-def _owned_rows_fwd(rows, n_owned):
-    return rows, n_owned
-
-
-def _owned_rows_bwd(n_owned, ct):
-    keep = (jnp.arange(ct.shape[0]) < n_owned).reshape(
-        (-1,) + (1,) * (ct.ndim - 1))
-    return jnp.where(keep, ct, 0), None
-
-
-_owned_rows.defvjp(_owned_rows_fwd, _owned_rows_bwd)
-
-
 def _expert_act(activation: str, up, gate):
     """The experts' nonlinearity on the up-projection `up`: `swiglu`
     gates it with `gate()` (the third matrix's product, made only
@@ -314,7 +289,8 @@ def dropless_expert_ffn(xt, gates, wg, wu, wd, *, top_k: int,
     count)` contiguous ones. Routing (top-k, renormalise, `scale`) is
     that of the whole layer; the pairs of other chips' experts sort
     BEHIND the held groups, where the grouped GEMM owns no row of them
-    (rows past the last group cost no tile and come back zero), and
+    (rows past the last group cost no tile; on the chip they come back
+    as whatever the kernel found there, forward and backward), and
     weigh nothing in the combine. The result is this chip's addend of
     the layer's routed sum. `gates` are whatever scores the router
     gives (a softmax, or sigmoids); `group` limits the choice to the
@@ -328,31 +304,26 @@ def dropless_expert_ffn(xt, gates, wg, wu, wd, *, top_k: int,
     grouped GEMMs, which stay directly under the caller's scope
     (`observability.attribution.SCOPE_ALIASES`); the sorted rows' gate /
     up products are the residual `moe_gate_up` where a checkpoint around
-    the caller keeps it; with `held`, the gradient of the rows no held
-    expert owns is dropped (`_owned_rows`)."""
+    the caller keeps it.  Dispatch and combine carry their own
+    backward (`ops.grouped_gemm.dispatch_pair_rows` /
+    `combine_pair_rows`): gathers alone, the combine's in sorted space,
+    and neither the value nor the cotangent of a row no held expert
+    owns is used.  A call with `held` and more pair rows than
+    `PAIR_ROW_CHUNK` visits the owned prefix of the sorted rows only;
+    every other call's forward is one plain gather each way."""
     E = wu.shape[0]
-    T = xt.shape[0]
     with jax.named_scope("moe_route"):
         gv, topi, local, mine = _route(gates, top_k, renormalize, held,
                                        scale, group, bias)
     with jax.named_scope("moe_dispatch"):
-        rows = jnp.repeat(xt, top_k, axis=0)                # [T*k, H]
-        eids = local.reshape(-1)                            # [T*k]
-        srt, sizes, inv = sort_by_group(rows, eids,
-                                        E if mine is None else E + 1)
-        sizes = sizes[:E]
-        if mine is not None:
-            srt = _owned_rows(srt, jnp.sum(sizes))
+        srt, sizes, order, inv, n_owned = dispatch_pair_rows(
+            xt, local, mine, E)
     up = _residual(grouped_gemm(srt, wu, sizes), "moe_gate_up")
     act = _expert_act(activation, up, lambda: _residual(
         grouped_gemm(srt, wg, sizes), "moe_gate_up"))
     down = grouped_gemm(act, wd, sizes)
     with jax.named_scope("moe_combine"):
-        down = unsort_by_group(down, inv).reshape(T, top_k, -1)
-        if mine is not None:
-            # whatever a kernel leaves in the rows it does not own
-            down = jnp.where(mine[..., None], down, 0)
-        y = jnp.einsum("tk,tkh->th", gv.astype(down.dtype), down)
+        y = combine_pair_rows(down, gv, order, inv, mine, n_owned)
     return y, topi
 
 
@@ -424,8 +395,9 @@ class MoELayer(nn.Layer):
         self.expert_axis = expert_axis
         self.z_loss_weight = z_loss_weight
         self.l_aux = None
-        # the last forward's `routing_stats` (dropless only; an array
-        # of the trace it was made in, as `l_aux` is)
+        # the last forward's `routing_stats` and the pair rows its
+        # dispatch visited (dropless only; an array of the trace it was
+        # made in, as `l_aux` is)
         self.l_stats = None
 
         H, Iw = d_model, d_hidden
@@ -533,7 +505,8 @@ class MoELayer(nn.Layer):
     def _dropless(self, xt, logits, gates, wg, wu, wd, bias=None):
         """Megablocks pattern: flatten (token, choice) rows, sort by expert,
         one ragged grouped GEMM, unsort, weighted-combine.  Returns (y,
-        the load-balance term, `routing_stats`).
+        the load-balance term, `routing_stats` with the pair rows the
+        dispatch's forward visited behind them: [6]).
 
         The load-balance term, E * sum_e P_e F_e over ALL the router's
         outputs (P_e the mean gate), takes its F_e by family
@@ -557,7 +530,10 @@ class MoELayer(nn.Layer):
                 aux = load_balance_loss(
                     gates, jax.nn.one_hot(topi[:, 0], E, dtype=gates.dtype))
             stats = routing_stats(topi, self.experts_held, E)
-        return y, aux, stats
+            held = self.experts_held
+            moved = pair_rows_visited(topi.size, None if held is None else
+                                      jnp.sum(_held_ids(topi, held)[0]))
+        return y, aux, jnp.append(stats, moved.astype(stats.dtype))
 
 
 class SwitchMoELayer(MoELayer):

@@ -72,7 +72,7 @@ _G_SEQ_SHARDED = _obs.registry().gauge(
 #: engine's step counts are: sums, the fullest expert, the mean), and the
 #: gauges `run_pretrain.run` keeps of them
 MOE_METRICS = ("moe_pairs_routed", "moe_pairs_held", "moe_expert_rows_max",
-               "moe_expert_rows_mean", "aux_loss")
+               "moe_expert_rows_mean", "aux_loss", "moe_pair_rows_moved")
 _G_MOE = {name: _obs.registry().gauge(
     "trainer.moe." + name.removeprefix("moe_"), text)
     for name, text in zip(MOE_METRICS, (
@@ -82,7 +82,9 @@ _G_MOE = {name: _obs.registry().gauge(
         "rows of the fullest held expert in any layer of that step",
         "mean rows a held expert, mean over the layers",
         "sum over the layers of the load-balance term (before its "
-        "coefficient)"))}
+        "coefficient)",
+        "pair rows the dispatch's forward visited, summed over the routed "
+        "layers (`ops.grouped_gemm.pair_rows_visited`)"))}
 
 
 def record_moe_metrics(metrics: Mapping[str, Any]) -> Dict[str, float]:
@@ -787,12 +789,13 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
         # the layers' sum, mean over the microbatches; the routing
         # numbers combine as the serving engine's step counts do
         aux = extras["aux"].sum(-1).mean()
-        st = jax.lax.stop_gradient(extras["stats"])        # [M, L, 5]
+        st = jax.lax.stop_gradient(extras["stats"])        # [M, L, 6]
         said = {"moe_pairs_routed": st[..., 0].sum(),
                 "moe_pairs_held": st[..., 1].sum(),
                 "moe_expert_rows_max": st[..., 2].max(),
                 "moe_expert_rows_mean": st[..., 3].mean(),
-                "aux_loss": aux}
+                "aux_loss": aux,
+                "moe_pair_rows_moved": st[..., 5].sum()}
         return loss + family.aux_coef * aux, said
 
     def step_with(saved, state: TrainState, ids, labels):

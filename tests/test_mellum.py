@@ -6,6 +6,8 @@ share test the model-configs guide asks for — the shares of one
 expert-parallel layer add up to the uncut reference, forward and
 backward."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +17,7 @@ import paddle_tpu as paddle
 from benchmarks.lib import reference_mellum as ref
 from benchmarks.systems.mellum_pretrain import LAYER_WEIGHTS, _layers
 from paddle_tpu.incubate.moe import dropless_expert_ffn
+from paddle_tpu.ops import grouped_gemm
 from paddle_tpu.models.mellum import (KINDS, MellumForCausalLM,
                                       mellum_tiny_config)
 from paddle_tpu.trainer.pretrain import (PretrainConfig,
@@ -192,13 +195,19 @@ def test_flops_count_the_pairs_held_and_the_keys_visible():
         * (3 * band + full))
 
 
-def test_rows_no_held_expert_owns_give_no_gradient(monkeypatch):
+@pytest.mark.parametrize("chunk", [None, 32], ids=["one-chunk", "chunked"])
+def test_rows_no_held_expert_owns_give_no_gradient(monkeypatch, chunk):
     """On the chip the grouped GEMM leaves whatever it finds in the rows
     past its last group, forward AND backward (the CPU's writes zeros);
-    `dropless_expert_ffn` drops those rows' gradient as the forward
-    masks their output.  Through a grouped GEMM that fills them with
-    rubbish both ways, the default call's gradients are still the plain
-    reference's."""
+    `dropless_expert_ffn`'s dispatch and combine use neither those
+    rows' values nor their cotangents (`ops.grouped_gemm`'s two
+    hand-written rules; before ISSUE 67, `_owned_rows`).  Through a
+    grouped GEMM that fills them with rubbish both ways, the default
+    call's output and gradients are still the plain reference's — with
+    every pass walking all 128 pair rows, and with the passes in sorted
+    order walking chunks of 32 up to the last owned row."""
+    if chunk is not None:
+        monkeypatch.setattr(grouped_gemm, "PAIR_ROW_CHUNK", chunk)
     from paddle_tpu.incubate import moe
 
     @jax.custom_vjp
@@ -232,11 +241,126 @@ def test_rows_no_held_expert_owns_give_no_gradient(monkeypatch):
         return jnp.sum(jnp.sin(y))
 
     with ref.highest():
-        want = jax.grad(plain, (0, 1, 2, 3))(x, wg, wu, wd)
+        want = jax.value_and_grad(plain, (0, 1, 2, 3))(x, wg, wu, wd)
         monkeypatch.setattr(
             moe, "grouped_gemm", lambda lhs, rhs, sizes: rubbish(
                 real(rubbish(lhs, jnp.sum(sizes)), rhs, sizes),
                 jnp.sum(sizes)))
-        got = jax.grad(loss, (0, 1, 2, 3))(x, wg, wu, wd)
-    for a, b in zip(got, want):
+        got = jax.value_and_grad(loss, (0, 1, 2, 3))(x, wg, wu, wd)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+# ------------------------------------------- the permutations' backward
+@pytest.fixture(scope="module", params=[None, 64],
+                ids=["one-chunk", "chunked"])
+def toy_step(request):
+    """The toy trainer step (256 pair rows a layer, experts 2..5 of 8
+    held) with every pass walking all the pair rows, and with the passes
+    in sorted order walking chunks of 64: its compiled text, one step's
+    metrics, the chunk."""
+    mp = pytest.MonkeyPatch()
+    chunk = request.param or grouped_gemm.PAIR_ROW_CHUNK
+    mp.setattr(grouped_gemm, "PAIR_ROW_CHUNK", chunk)
+    try:
+        paddle.seed(11)
+        mc = mellum_tiny_config(experts_held=(2, 4))
+        cfg = PretrainConfig(mc, global_batch=B, seq_len=S, remat="full",
+                             param_dtype="float32", ce_chunks=2)
+        mesh = make_hybrid_mesh_for(cfg, devices=jax.devices()[:1])
+        state, jstep, _ = build_llama_pretrain_step(cfg, mesh)
+        rng = np.random.RandomState(5)
+        ids = jnp.asarray(rng.randint(0, mc.vocab_size, (B, S)), jnp.int32)
+        text = jstep.lower(state, ids, ids).compile().as_text()
+        _, m = jstep(state, ids, ids)
+        return mc, text, {k: float(v) for k, v in m.items()}, chunk
+    finally:
+        mp.undo()
+
+
+def _moe_ops(text):
+    """(scope as written, primitive, direction, result type) of every
+    instruction of a compiled text named under the dispatch or the
+    combine."""
+    from paddle_tpu.observability import attribution
+    out = []
+    for m in re.finditer(r'= (\S+) [\w-]+\(.*op_name="([^"]*)"', text):
+        own = attribution._path_own(m.group(2))
+        if own in ("moe_dispatch", "moe_combine"):
+            out.append((own, m.group(2).rsplit("/", 1)[-1],
+                        attribution._path_scope(m.group(2))[1],
+                        m.group(1)))
+    return out
+
+
+def test_the_permutations_backward_holds_no_scatter(toy_step):
+    """The backward of dispatch and combine is gathers: no scatter runs
+    under either name in the backward pass, and the only ones left at
+    all are the forward's (and its recomputation's) `bincount` of the
+    groups' sizes, five integers here."""
+    ops = _moe_ops(toy_step[1])
+    scatters = [o for o in ops if o[1].startswith("scatter")]
+    assert not [o for o in scatters if o[2] == "bwd"], scatters
+    assert scatters and all(
+        o[0] == "moe_dispatch" and o[3].startswith("s32[5]")
+        for o in scatters), scatters
+
+
+def test_the_permutations_gathers_carry_the_call_sites_names(toy_step):
+    """The hand-written rules open no scope of their own: their gathers
+    read `fwd` / `remat` / `bwd` under the call site's names
+    (`observability.attribution`), so the benchmark's readers find them.
+    The combine's forward is not recomputed: its backward reads the
+    sorted rows."""
+    gathers = {(o[0], o[2]) for o in _moe_ops(toy_step[1])
+               if o[1] == "gather"}
+    assert gathers == {("moe_dispatch", "fwd"), ("moe_dispatch", "remat"),
+                       ("moe_dispatch", "bwd"), ("moe_combine", "fwd"),
+                       ("moe_combine", "bwd")}
+
+
+def test_the_step_says_the_pair_rows_it_moved(toy_step):
+    """`moe_pair_rows_moved`: all the pair rows of a step whose calls
+    hold one chunk; whole chunks up to a layer's last owned row where
+    the passes in sorted order stop there."""
+    mc, _, m, chunk = toy_step
+    rows = B * S * mc.num_experts_per_tok
+    assert m["moe_pairs_routed"] == rows * mc.num_hidden_layers
+    if rows <= chunk:
+        assert m["moe_pair_rows_moved"] == m["moe_pairs_routed"]
+    else:
+        assert m["moe_pair_rows_moved"] % chunk == 0
+        assert m["moe_pairs_held"] <= m["moe_pair_rows_moved"] \
+            < m["moe_pairs_held"] + chunk * mc.num_hidden_layers
+        assert m["moe_pair_rows_moved"] < m["moe_pairs_routed"]
+
+
+@pytest.mark.parametrize("held, chunk", [
+    (None, 64), ((2, 4), None), ((2, 4), 64), ((0, 8), 64), ((6, 2), 48)])
+def test_a_layer_counts_the_rows_its_dispatch_visits(monkeypatch, held,
+                                                     chunk):
+    """`MoELayer` leaves, behind its `routing_stats`, the rows its
+    dispatch's forward visited: the sorted rows up to there hold the
+    owned pairs' tokens and zeros follow (256 pair rows; chunks of 64,
+    and of 48, which does not divide them)."""
+    from paddle_tpu.incubate.moe import MoELayer, _route
+    if chunk is not None:
+        monkeypatch.setattr(grouped_gemm, "PAIR_ROW_CHUNK", chunk)
+    paddle.seed(3)
+    T, H, E, k = 128, 16, 8, 2
+    layer = MoELayer(H, 8, E, top_k=k, dropless=True, experts_held=held)
+    x = jax.random.normal(jax.random.key(4), (1, T, H), jnp.float32)
+    layer(paddle.to_tensor(x))
+    stats = np.asarray(layer.l_stats._data)
+    assert stats.shape == (6,) and stats[0] == T * k
+    gates = jax.nn.softmax(x[0] @ layer.gate_weight._data, -1)
+    _, _, local, mine = _route(gates, k, layer.renormalize, held, 1.0)
+    srt, _, _, _, n_owned = grouped_gemm.dispatch_pair_rows(
+        x[0], local, mine, E if held is None else held[1])
+    if held is None or chunk is None:
+        assert stats[5] == T * k
+        return
+    assert int(n_owned) == stats[1]
+    assert stats[5] == min(-(-stats[1] // chunk) * chunk, T * k)
+    live = np.abs(np.asarray(srt)).sum(-1) > 0
+    assert live[:int(n_owned)].all() and not live[int(n_owned):].any()

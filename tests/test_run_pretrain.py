@@ -144,11 +144,15 @@ def test_a_mellum_model_file_trains_through_run(tmp_path, capsys):
         assert 0 < r["moe_pairs_held"] < r["moe_pairs_routed"]
         assert r["moe_expert_rows_max"] >= r["moe_expert_rows_mean"] > 0
         assert r["aux_loss"] > 0
+        # 1,024 pair rows a layer are one chunk: every pass walks all
+        assert r["moe_pair_rows_moved"] == r["moe_pairs_routed"]
     out = capsys.readouterr().out
     assert "moe_pairs_held" in out
     snap = obs.registry().snapshot()
     assert snap["trainer.moe.pairs_held"]["series"][0]["value"] == \
         recs[-1]["moe_pairs_held"]
     assert snap["trainer.moe.aux_loss"]["series"][0]["value"] > 0
+    assert snap["trainer.moe.pair_rows_moved"]["series"][0]["value"] == \
+        recs[-1]["moe_pair_rows_moved"]
     with pytest.raises(SystemExit, match="nosuch"):
         run_pretrain._build_model_config({"model_type": "nosuch"}, 64)

@@ -1166,3 +1166,46 @@ def test_trainer_step_scatters_its_row_products_over_mp(chip):
             r"= f32\[(\d+),(\d+)\]\S* reduce\([^\n]*op_name=\"[^\"]*"
             + re.escape(scope), text))
         assert rows and rows <= {(str(B), str(S // 2))}, (scope, rows)
+
+
+# -- the trainer's routed FFN (ISSUE 67): the permutations' own rules -----
+
+def _routed_ffn_grads(x, wr, wg, wu, wd):
+    from paddle_tpu.incubate.moe import dropless_expert_ffn
+
+    def loss(x, wr, wg, wu, wd):
+        gates = jax.nn.softmax(x.astype(F32) @ wr, -1)
+        with jax.named_scope("routed_ffn"):
+            y, _ = dropless_expert_ffn(x, gates, wg, wu, wd, top_k=8,
+                                       renormalize=True, held=(16, 16))
+        return jnp.sum(y.astype(F32))
+    return jax.grad(loss, (0, 1, 2, 3, 4))(x, wr, wg, wu, wd)
+
+
+def test_the_trained_routed_ffn_compiles_without_a_row_scatter(chip):
+    """A Mellum2 layer's routed FFN at the trained cell's sizes (16,384
+    tokens x top-8 = 131,072 pair rows of 2,304, 16 of 64 experts held),
+    forward and backward, for the described chip: the chunked walk of
+    the owned rows lowers (a `while` for the dispatch's forward, one for
+    the combine's backward), and no scatter of rows is left under the
+    dispatch or the combine — only `bincount`'s seventeen integers."""
+    from paddle_tpu.observability import attribution
+    T, H, W, E, held = 16384, 2304, 896, 64, 16
+    assert chip.compiles(
+        _routed_ffn_grads, chip.shape((T, H)), chip.shape((H, E), F32),
+        chip.shape((held, H, W)), chip.shape((held, H, W)),
+        chip.shape((held, W, H))), chip.refusals.get(_routed_ffn_grads)
+    text = chip.texts[_routed_ffn_grads]
+    scatters, whiles = [], []
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        if name is None or attribution._path_own(name.group(1)) not in (
+                "moe_dispatch", "moe_combine"):
+            continue
+        if " scatter(" in line:
+            scatters.append(line.split(" = ")[1].split(" ")[0])
+        if " while(" in line:
+            whiles.append(attribution._path_own(name.group(1)))
+    assert scatters and all(s.startswith("s32[17]") for s in scatters), \
+        scatters
+    assert sorted(whiles) == ["moe_combine", "moe_dispatch"], whiles
