@@ -8,6 +8,9 @@ fused kernels. See SURVEY.md for the blueprint and docs/ for design notes.
 
 from __future__ import annotations
 
+import time as _time
+_IMPORT_T0 = _time.perf_counter_ns()    # see the last line
+
 __version__ = "0.1.0"
 
 from . import _bootstrap  # noqa: F401  multi-host join BEFORE backend init
@@ -119,3 +122,7 @@ del annotations
 for _n in ("np", "tail", "tail2", "tail3"):
     globals().pop(_n, None)
 del _n
+
+# the import's two clock reads: `observability.tracing.recorder().setup()`
+# shows the pair as the set-up span `paddle_tpu.import`
+_IMPORT_NS = (_IMPORT_T0, _time.perf_counter_ns())

@@ -48,7 +48,7 @@ from .. import flags as _flags
 __all__ = ["Counter", "Gauge", "Histogram", "Registry", "registry",
            "enabled", "set_enabled", "snapshot", "to_prometheus",
            "parse_prometheus", "sample_values", "StepLogger", "span",
-           "DEFAULT_BUCKETS"]
+           "sections", "DEFAULT_BUCKETS"]
 
 # the flag is defined in paddle_tpu.flags (core flag set); grab the flag
 # OBJECT once so the hot-path enabled check is a plain attribute read
@@ -500,6 +500,9 @@ class _Span:
         if _TraceAnnotation is None:
             from jax.profiler import TraceAnnotation as _TraceAnnotation
             from ..native import RecordEvent as _RecordEvent
+            # the first span: the set-up ledger's listeners are on
+            # before what the span surrounds is traced
+            tracing._default_recorder._listen()
         self.name = name
         self.span_id = _new_span_id()
         self.step = args.get("step")
@@ -538,6 +541,43 @@ def span(name: str, **args: Any) -> _Span:
     """Open host span `name`; `args` (ids, counts — never pasted into
     the name) become the profiler event's arguments."""
     return _Span(name, args)
+
+
+class _Sections:
+    """The span `name` around a block, and one call to close the child
+    span that is open and open the next: disjoint children at the
+    borders of ONE long body (a constructor, a builder), whose locals
+    carry across them, without indenting it under a `with` a section."""
+
+    __slots__ = ("_parent", "_child")
+
+    def __init__(self, name: str):
+        self._parent = _Span(name, {})
+        self._child: Optional[_Span] = None
+
+    def __enter__(self):
+        self._parent.__enter__()
+        return self
+
+    def __call__(self, child: Optional[str] = None) -> None:
+        """End the open section; open `<name>.<child>` (None: none)."""
+        if self._child is not None:
+            self._child.__exit__(None, None, None)
+            self._child = None
+        if child is not None:
+            self._child = _Span(f"{self._parent.name}.{child}",
+                                {}).__enter__()
+
+    def __exit__(self, *exc):
+        self()
+        return self._parent.__exit__(*exc)
+
+
+def sections(name: str) -> _Sections:
+    """``with sections("trainer.build") as section:`` opens host span
+    `name`; ``section("model")`` then ends the section before and opens
+    the child span ``trainer.build.model`` (`_Sections`)."""
+    return _Sections(name)
 
 
 class StepLogger:

@@ -34,6 +34,14 @@ inside a step carries ``step=<seq>``, the step's spans carry it in
 memory, and its profiler event (`jax.profiler.TraceAnnotation`, on the
 device trace's clock) carries it as an argument.
 
+And it keeps the SET-UP LEDGER (`TraceRecorder.setup`): one PROGRAM
+RECORD for every program jax traces, lowers and compiles or reads from
+the compile cache — assembled from the `jax.monitoring` events of the
+recorder's one pair of listeners, each with the innermost open span of
+the thread that compiled and the open step — and the spans that belong
+to no step (`serving.engine.construct`, `trainer.build` and their
+children), in a store of their own that step traffic cannot evict.
+
 Overhead contract (same as the metrics layer): every entry point checks
 the cached ``FLAGS_request_tracing`` flag object FIRST, so with tracing
 off a stamp costs one function call + one attribute test. Gated
@@ -48,15 +56,19 @@ only under ``self._lock``; the optional background flush thread
 
 from __future__ import annotations
 
+import heapq
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
 from .. import flags as _flags
-from . import DEFAULT_BUCKETS, Histogram, _new_span_id, registry
+from . import (DEFAULT_BUCKETS, Histogram, _new_span_id, _open_spans,
+               registry)
 
 __all__ = ["TraceEvent", "RequestTrace", "TraceRecorder", "recorder",
            "enabled", "set_enabled", "percentile", "percentiles",
@@ -65,7 +77,7 @@ __all__ = ["TraceEvent", "RequestTrace", "TraceRecorder", "recorder",
            "STEP_COUNTS_EVA", "STEP_COUNTS_LOOP", "STEP_COUNTS_SSM",
            "STEP_COUNTS_MHC", "STEP_COUNTS_SHARED",
            "STEP_COUNTS_DIFFUSION", "STEP_COUNTS_PREFIX",
-           "STEP_COUNTS_TAIL"]
+           "STEP_COUNTS_TAIL", "PROGRAMS_KEPT", "SETUP_SPANS_KEPT"]
 
 _FLAG = _flags._registry["FLAGS_request_tracing"]
 
@@ -83,6 +95,11 @@ def _now_us() -> int:
     # same clock family as the host profiler's pure-python fallback
     # (perf_counter_ns // 1000), so exported timelines share an epoch
     return time.perf_counter_ns() // 1000
+
+
+# the spans' clock, where a `jax.monitoring` event is stamped as it
+# arrives (jax says how long a stage took, not when it ended)
+_now_ns = time.perf_counter_ns
 
 
 # the four serving SLO histograms; registered here so importing the
@@ -241,7 +258,70 @@ STEP_COUNTS_DIFFUSION: Tuple[str, ...] = (
 #: of steps plus the warm-up) needs every one: 2048 records stopped
 #: holding the chat cell's window once a step took under 27 ms (PR 29)
 STEPS_PER_SLOT = 4
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: the `jax.monitoring` duration events a program record is assembled
+#: from, by the record's field each one fills (jax reports a stage when
+#: it ENDS, with its length; the backend's event surrounds the compile
+#: cache's, so it fires on a hit too) ...
+_STAGES = {"/jax/core/compile/jaxpr_trace_duration": "trace_ns",
+           "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_ns",
+           "/jax/core/compile/backend_compile_duration": "compile_ns"}
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: ... and the plain events that say what the compile cache answered
+_CACHE_EVENTS = {"/jax/compilation_cache/compile_requests_use_cache": "miss",
+                 "/jax/compilation_cache/cache_misses": "miss",
+                 "/jax/compilation_cache/cache_hits": "hit"}
+#: program records kept one by one, the LONGEST (an eager float32 model
+#: compiles thousands of one-primitive programs; `program_totals` counts
+#: every one whatever is kept), and the spans of no step kept likewise
+PROGRAMS_KEPT = 4096
+SETUP_SPANS_KEPT = 256
+#: what one thread holds at most of stages nobody has claimed and of
+#: records still open (a step program's trace holds a jit a kernel a
+#: layer inside it; past this the oldest is let go and its time stays
+#: in the stage that surrounds it)
+_OPEN_KEPT = 4096
+#: the sums `program_totals` keeps for each span name
+_TOTAL_KEYS = ("programs", "hits", "misses", "trace_ns", "lower_ns",
+               "compile_ns", "cache_read_ns")
+
+
+def _keep_longest(heap: list, capacity: int, length: int, seq: int,
+                  entry: Dict[str, Any]) -> None:
+    """Push `entry` on a heap of (length, seq, entry) that keeps the
+    `capacity` longest (`seq` is unique: entries are never compared)."""
+    item = (length, seq, entry)
+    if len(heap) < capacity:
+        heapq.heappush(heap, item)
+    elif item > heap[0]:
+        heapq.heapreplace(heap, item)
+
+
+class _ThreadPrograms:
+    """What one thread's `jax.monitoring` events have said so far of the
+    programs it is still assembling. A stage is reported at its END, so
+    what ran inside it (a kernel's jit traced under the step program's
+    trace, an eager constant's whole compile) has been reported before
+    it: `stages` holds the finished stage intervals nobody has claimed
+    yet, and a new stage takes those that start inside it off its own
+    time. `open` holds the records that may still get a stage (traced
+    and not lowered, lowered and not compiled), oldest first."""
+
+    __slots__ = ("stages", "open", "cache", "cache_read_ns")
+
+    def __init__(self):
+        self.stages: deque = deque(maxlen=_OPEN_KEPT)  # (start, length)
+        self.open: List[Dict[str, Any]] = []
+        self.cache = "off"
+        self.cache_read_ns = 0
+
+    def inside(self, start_ns: int, end_ns: int) -> int:
+        """Nanoseconds of [start_ns, end_ns] that stages reported before
+        it took; they are this interval's from here on."""
+        took, stages = 0, self.stages
+        while stages and stages[-1][0] >= start_ns:
+            took += stages.pop()[1]
+        stages.append((start_ns, end_ns - start_ns))
+        return took
 
 
 class TraceEvent:
@@ -370,6 +450,9 @@ class TraceRecorder:
     (FLAGS_trace_ring_size, oldest evicted) so a long-lived serving
     process cannot grow without bound; host spans have a ring of the
     same capacity and step records one of `STEPS_PER_SLOT` times it.
+    The set-up ledger (program records, the spans of no step, the steps
+    in which a program was traced) is a store of its own, bounded by
+    keeping the longest, with exact totals beside it.
     An optional background exporter thread drains finished traces to
     JSONL; it shares the same lock as every other accessor (paddlelint
     PT006 discipline).
@@ -386,8 +469,15 @@ class TraceRecorder:
         self._spans: deque = deque(maxlen=int(capacity))
         self._steps: deque = deque(maxlen=STEPS_PER_SLOT * int(capacity))
         self._open_step: Optional[Dict[str, Any]] = None
-        self._compiles = 0
         self._listening = False
+        # the set-up ledger: heaps of (length, tie-break, entry) — the
+        # shortest goes first once one is full
+        self._threads: Dict[int, _ThreadPrograms] = {}
+        self._programs: List[Tuple[int, int, Dict[str, Any]]] = []
+        self._program_totals: Dict[Optional[str], Dict[str, int]] = {}
+        self._setup_spans: List[Tuple[int, int, Dict[str, Any]]] = []
+        self._setup_steps: List[Dict[str, Any]] = []
+        self._kept_seq = itertools.count()
         self._replica: Optional[str] = None
         self._export_f = None
         self._export_thread: Optional[threading.Thread] = None
@@ -459,11 +549,20 @@ class TraceRecorder:
     def _span_done(self, name: str, start_ns: int, end_ns: int,
                    parent: Optional[str], step: Optional[int]) -> None:
         """`observability.span`'s in-memory sink (the span checked the
-        flag when it opened). A span of the open step also lands in that
-        step's record: the step's own span gives its start and end, its
-        direct children are its phases."""
+        flag when it opened). A span of no step is kept in the set-up
+        ledger too, the longest, where the ring cannot evict it. A span of
+        the open step also lands in that step's record: the step's own
+        span gives its start and end, its direct children are its
+        phases."""
         with self._lock:
             self._spans.append((name, start_ns, end_ns, parent, step))
+            if step is None:    # a span of no step is a set-up span
+                _keep_longest(
+                    self._setup_spans, SETUP_SPANS_KEPT, end_ns - start_ns,
+                    next(self._kept_seq),
+                    {"name": name, "start_ns": start_ns, "end_ns": end_ns,
+                     "parent": parent, "step": None})
+                return
             st = self._open_step
             if st is not None and step == st["seq"]:
                 if parent == st["name"]:
@@ -471,38 +570,155 @@ class TraceRecorder:
                 elif name == st["name"]:
                     st["start_ns"], st["end_ns"] = start_ns, end_ns
 
-    def _on_compile(self, event: str, secs: float, **kw) -> None:
-        if event == _COMPILE_EVENT:
-            with self._lock:
-                self._compiles += 1
+    # --------------------------------------------------- program records
+    def _listen(self) -> None:
+        """Register the recorder's ONE pair of `jax.monitoring`
+        listeners (durations, plain events), once: when the first span
+        opens or `recorder()` is first asked, so before any program of
+        set-up is traced. Loads no profiler and starts no backend; with
+        the flag off each listener returns at its first line."""
+        if self._listening:
+            return
+        with self._lock:
+            if self._listening:
+                return
+            self._listening = True
+        import jax.monitoring as monitoring
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        monitoring.register_event_listener(self._on_event)
+
+    def _thread_programs(self) -> _ThreadPrograms:
+        # (under the lock; listeners run in the thread that compiles)
+        ident = threading.get_ident()
+        th = self._threads.get(ident)
+        if th is None:
+            th = self._threads[ident] = _ThreadPrograms()
+        return th
+
+    def _on_event(self, event: str, **kw) -> None:
+        """What the compile cache answered the program whose backend
+        stage is open in this thread (its duration event comes last)."""
+        answer = _CACHE_EVENTS.get(event)
+        if answer is None or not _FLAG.value:
+            return
+        with self._lock:
+            th = self._thread_programs()
+            if th.cache != "hit":
+                th.cache = answer
+
+    def _on_duration(self, event: str, secs: float, **kw) -> None:
+        """One stage of one program ended in this thread: its time less
+        what ran inside it goes to the record it continues, or opens
+        one. A lowering continues the trace that ended before it began,
+        a backend stage the lowering of its name; what started inside
+        the stage (a nested program) can get no more and is closed."""
+        if not _FLAG.value:
+            return
+        field = _STAGES.get(event)
+        if field is None and event != _CACHE_READ_EVENT:
+            return
+        end_ns = _now_ns()
+        length = round(secs * 1e9)
+        start_ns = end_ns - length
+        name = str(kw.get("fun_name", "?"))
+        stack = getattr(_open_spans, "stack", None)
+        span = stack[-1].name if stack else None
+        with self._lock:
+            th = self._thread_programs()
+            if field is None:       # the cache's read: inside the backend's
+                th.cache_read_ns = length
+                return
+            # (at least 1 ns: a stage that happened reads true)
+            own = max(length - th.inside(start_ns, end_ns), 1)
+            while th.open and th.open[-1]["start_ns"] >= start_ns:
+                self._close_program(th.open.pop())
+            rec = None
+            if field == "lower_ns":
+                top = th.open[-1] if th.open else None
+                if top is not None and not top["lower_ns"] \
+                        and name.endswith(f"({top['name']})"):
+                    rec = top
+            elif field == "compile_ns":
+                rec = next((r for r in reversed(th.open)
+                            if r["name"] == name and r["lower_ns"]), None)
+            st = self._open_step
+            if rec is None:
+                rec = {"name": name, "start_ns": start_ns, "end_ns": end_ns,
+                       "trace_ns": 0, "lower_ns": 0, "compile_ns": 0,
+                       "cache": None, "cache_read_ns": 0, "span": span,
+                       "step": st["seq"] if st is not None else None}
+                self._totals_of(span)["programs"] += 1
+                if field != "compile_ns":
+                    # (what is traced inside a stage waits here until
+                    # the stage is reported; a trace nobody lowers, for
+                    # good)
+                    th.open.append(rec)
+                    if len(th.open) > _OPEN_KEPT:
+                        self._close_program(th.open.pop(0))
+            elif field == "compile_ns":
+                th.open.remove(rec)
+            totals = self._totals_of(rec["span"])
+            rec["name"], rec["end_ns"] = name, end_ns
+            rec[field] += own
+            totals[field] += own
+            if st is not None:
+                st["programs"] += 1
+            if field == "compile_ns":   # the program reached the backend
+                rec["cache"], th.cache = th.cache, "off"
+                rec["cache_read_ns"], th.cache_read_ns = th.cache_read_ns, 0
+                if rec["cache"] != "off":
+                    totals["hits" if rec["cache"] == "hit"
+                           else "misses"] += 1
+                    totals["cache_read_ns"] += rec["cache_read_ns"]
+                if st is not None:
+                    st["compiles"] += 1
+                self._close_program(rec)
+
+    def _totals_of(self, span: Optional[str]) -> Dict[str, int]:
+        totals = self._program_totals.get(span)
+        if totals is None:
+            totals = self._program_totals[span] = dict.fromkeys(
+                _TOTAL_KEYS, 0)
+        return totals
+
+    def _close_program(self, rec: Dict[str, Any]) -> None:
+        """A record that gets no more stages: kept if it is among the
+        longest (its totals were counted stage by stage)."""
+        _keep_longest(
+            self._programs, PROGRAMS_KEPT,
+            rec["trace_ns"] + rec["lower_ns"] + rec["compile_ns"],
+            next(self._kept_seq), rec)
 
     def open_step(self, seq: int, name: str) -> None:
         """Open the record of engine step `seq`, whose span is `name`.
-        Until `close_step`, stamps carry ``step=seq`` and the step's
-        spans land in the record. The first call registers the one
-        `jax.monitoring` listener that counts compiles."""
+        Until `close_step`, stamps carry ``step=seq``, the step's spans
+        land in the record, and so does the count of the programs that
+        reach the backend (`compiles`)."""
         if not _FLAG.value:
             return
-        if not self._listening:
-            import jax.monitoring
-            jax.monitoring.register_event_duration_secs_listener(
-                self._on_compile)
-            self._listening = True
         with self._lock:
             self._open_step = {
                 "seq": int(seq), "name": name, "replica": self._replica,
                 "start_ns": None, "end_ns": None, "phases": [],
-                "compiles": self._compiles}
+                "compiles": 0, "programs": 0}
 
     def close_step(self, counts: Mapping[str, int]) -> None:
         """Close the open step record with the engine's `counts`
-        (`STEP_COUNTS`) and move it to the step ring."""
+        (`STEP_COUNTS`) and move it to the step ring. A step in which a
+        program was traced, lowered or compiled (a program's first
+        launch) is copied into the set-up ledger too: the ring may have
+        turned over by the time anyone asks."""
         with self._lock:
             st, self._open_step = self._open_step, None
             if st is None:
                 return
+            if st.pop("programs") and \
+                    len(self._setup_steps) < SETUP_SPANS_KEPT:
+                self._setup_steps.append({
+                    "name": st["name"], "start_ns": st["start_ns"],
+                    "end_ns": st["end_ns"], "parent": None,
+                    "step": st["seq"], "phases": list(st["phases"])})
             st.update(counts)
-            st["compiles"] = self._compiles - st["compiles"]
             self._steps.append(st)
 
     def set_replica_context(self, name: Optional[str]) -> None:
@@ -610,10 +826,54 @@ class TraceRecorder:
         """Copies of the newest step records, oldest first: ``seq``,
         ``name``, ``replica``, ``start_ns`` / ``end_ns``, ``phases``
         (``[(span name, start_ns, end_ns)]``, disjoint, inside the
-        step), ``compiles`` and the `STEP_COUNTS`."""
+        step), ``compiles`` (the program records that reached the
+        backend while the step was open) and the `STEP_COUNTS`."""
         with self._lock:
             return [dict(st, phases=list(st["phases"]))
                     for st in self._steps]
+
+    def programs(self) -> List[Dict[str, Any]]:
+        """Copies of the program records kept (the `PROGRAMS_KEPT`
+        longest, and those still being assembled), by their end:
+        ``name`` (jax's ``fun_name``), ``start_ns`` / ``end_ns``,
+        ``trace_ns`` (less what was traced inside it: a nested
+        program's time counts once, in its own record), ``lower_ns``,
+        ``compile_ns``, ``cache`` ("hit" / "miss" / "off"; None for a
+        program that never reached the backend), ``cache_read_ns``,
+        ``span`` (the innermost open span of the thread that compiled)
+        and ``step`` (the open step's ``seq``)."""
+        with self._lock:
+            recs = [dict(r) for _, _, r in self._programs]
+            recs += [dict(r) for th in self._threads.values()
+                     for r in th.open]
+        return sorted(recs, key=lambda r: r["end_ns"])
+
+    def program_totals(self) -> Dict[Optional[str], Dict[str, int]]:
+        """{span name (None: under no span): ``programs``, ``hits``,
+        ``misses`` and the four time sums} over EVERY program record,
+        whatever `programs` still holds."""
+        with self._lock:
+            return {k: dict(v) for k, v in self._program_totals.items()}
+
+    def setup(self) -> Dict[str, Any]:
+        """Where a start went (docs/OBSERVABILITY.md, "Set-up: where a
+        start goes"): ``spans`` — the spans of no step
+        (``paddle_tpu.import``, ``serving.engine.construct`` /
+        ``trainer.build`` and their children) and a copy of every step
+        in which a program was traced or compiled, with its ``phases``
+        — by their start, ``programs`` (`programs`) and ``totals``
+        (`program_totals`)."""
+        with self._lock:
+            spans = [dict(sp) for _, _, sp in self._setup_spans]
+            spans += [dict(st, phases=list(st["phases"]))
+                      for st in self._setup_steps]
+        pair = getattr(sys.modules.get("paddle_tpu"), "_IMPORT_NS", None)
+        if pair is not None:    # (None while the package still imports)
+            spans.append({"name": "paddle_tpu.import", "start_ns": pair[0],
+                          "end_ns": pair[1], "parent": None, "step": None})
+        return {"spans": sorted(spans, key=lambda sp: sp["start_ns"] or 0),
+                "programs": self.programs(),
+                "totals": self.program_totals()}
 
     def trace(self, request_id) -> Optional[RequestTrace]:
         """Most recent trace for `request_id`: live first, then the
@@ -650,6 +910,11 @@ class TraceRecorder:
             self._steps.clear()
             self._open_step = None
             self._replica = None
+            self._threads.clear()
+            self._programs.clear()
+            self._program_totals.clear()
+            self._setup_spans.clear()
+            self._setup_steps.clear()
 
     # ------------------------------------------------------- chrome export
     def export_chrome_trace(self, path: str,
@@ -798,7 +1063,10 @@ _default_recorder = TraceRecorder()
 def recorder() -> TraceRecorder:
     """The process-wide recorder the serving engine and trainer stamp
     into (module-level singleton, assigned once at import — readers
-    never mutate the binding)."""
+    never mutate the binding). Asking for it is what turns its
+    `jax.monitoring` listeners on (the modules that stamp ask at import:
+    before any program of set-up is traced)."""
+    _default_recorder._listen()
     return _default_recorder
 
 

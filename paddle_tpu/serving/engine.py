@@ -1019,6 +1019,26 @@ class ServingEngine:
                  replica: Optional[str] = None,
                  prefix_cache_admit: bool = True,
                  slo_targets=None):
+        # the set-up ledger (`observability.tracing.recorder().setup()`):
+        # one span over the whole constructor, its sections disjoint
+        # children; every program traced or compiled below says which
+        # section it was under
+        with _obs.sections("serving.engine.construct") as section:
+            self._construct(
+                section, model, max_slots, page_size, num_pages,
+                max_context, prefill_chunk, weight_only_int8,
+                weight_only_quant, config, prefix_sharing,
+                enable_prefix_cache, spec_decode, preemption,
+                tenant_budgets, role, replica, prefix_cache_admit,
+                slo_targets)
+
+    def _construct(self, section, model, max_slots, page_size, num_pages,
+                   max_context, prefill_chunk, weight_only_int8,
+                   weight_only_quant, config, prefix_sharing,
+                   enable_prefix_cache, spec_decode, preemption,
+                   tenant_budgets, role, replica, prefix_cache_admit,
+                   slo_targets) -> None:
+        """The constructor's body, `section` its borders (`__init__`)."""
         if role not in ("prefill", "decode", "colocated"):
             raise ValueError(
                 f"role must be prefill/decode/colocated, got {role!r}")
@@ -1036,6 +1056,8 @@ class ServingEngine:
         # share ONE default registry, so per-replica truth must come
         # from engine state, not the shared counters
         self._handoff_counts = {"export": 0, "import": 0}
+        # the stored leaves, transposed and stacked as the step reads them
+        section("weights")
         p = _decode_params(model, weight_only_int8, weight_only_quant)
         cfg = p["cfg"]
         self._family = p["family"]
@@ -1052,6 +1074,9 @@ class ServingEngine:
             p.update(p.pop("rope_fn")(self.max_context))
         self._p = p
         self._w = _llama_weights(p)
+        # what the family keeps of a sequence and may share of it: the
+        # allocator, the scheduler, the prefix cache
+        section("layout")
         self.prefill_chunk = int(prefill_chunk)
         if self.prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
@@ -1299,6 +1324,7 @@ class ServingEngine:
         self.preemption = bool(preemption)
 
         # family geometry + device page pools
+        section("pools")
         dt = p["embed"].dtype
         n_layers = len(p["layers"])
         if self._family == "gpt":
@@ -1405,6 +1431,8 @@ class ServingEngine:
                            for sh in (wshape if k else shape
                                       for k in self._layer_kind)]
 
+        # the step's counts and the HBM accounting
+        section("accounting")
         if spec_decode < 0:
             raise ValueError("spec_decode must be >= 0")
         if self._eva and jax.default_backend() == "tpu" and \
@@ -1548,6 +1576,9 @@ class ServingEngine:
             _G_HBM_POOL.set(self._hbm_pool_bytes)
             _G_HBM_DRAFT.set(0)
 
+        # the step programs, the feeds' compile-and-run, the copy
+        # program's warm run
+        section("programs")
         # what the one step builder reads of this family (eva and looped
         # have bodies of their own)
         self._chain = None if self._family in ("eva", "looped") \
@@ -2137,13 +2168,15 @@ class ServingEngine:
         compile cache answers where it answered the step; nothing is
         launched and no pool is taken. Called by no part of the engine:
         a run that does not ask pays nothing."""
-        args = {}
-        for sfx, chunk in self._chunk_parts().items():
-            args["unified" + sfx], args["feed" + sfx] = \
-                self._program_shapes(chunk)
-        return {name: compile_named(
-                    fn, args[name], lambda n=name: self._step_programs()[n])
-                for name, fn in self._programs.items()}
+        with _obs.span("serving.engine.compiled_programs"):
+            args = {}
+            for sfx, chunk in self._chunk_parts().items():
+                args["unified" + sfx], args["feed" + sfx] = \
+                    self._program_shapes(chunk)
+            return {name: compile_named(
+                        fn, args[name],
+                        lambda n=name: self._step_programs()[n])
+                    for name, fn in self._programs.items()}
 
     def _program_shapes(self, chunk: int):
         """(the step program's arguments, its token feed's) at a chunk

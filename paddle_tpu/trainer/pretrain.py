@@ -408,16 +408,32 @@ class TrainState(NamedTuple):
 
 def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
     """Returns (state, train_step, meta). train_step(state, batch_ids,
-    labels) -> (state, metrics) — one fully-sharded jitted step."""
+    labels) -> (state, metrics) — one fully-sharded jitted step.
+
+    The build is the span ``trainer.build`` of the set-up ledger
+    (`observability.tracing.recorder().setup()`), its sections disjoint
+    children: ``.model`` (the eager float32 model), ``.state`` (the
+    stacked and placed parameters, the optimizer state), ``.step`` (the
+    rope tables and the step's closures) and ``.plan`` (the remat plan:
+    ``.plan.floor`` and one ``.plan.try`` a compiled try). The chosen
+    program has no compile of its own here: it is the plan's last try,
+    or the `jax.jit` call's at the first step."""
+    with _obs.sections("trainer.build") as section:
+        return _build_step(section, cfg, mesh)
+
+
+def _build_step(section, cfg: PretrainConfig, mesh: Mesh):
     mc = cfg.model
     family = decoder_family(mc)
     routed = family.aux_coef is not None
+    section("model")
     with mesh_context(mesh):
         model = family.build(mc)
     param_dtype = jnp.bfloat16 if cfg.param_dtype == "bfloat16" else jnp.float32
 
     full_state = extract_state(model)
 
+    section("state")
     # split decoder-layer params (pipelined & stacked) from outer params
     layer_prefix = family.layer_prefix
     per_layer: list = [dict() for _ in range(mc.num_hidden_layers)]
@@ -486,6 +502,7 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
                          moment_dtype=cfg.moment_dtype)
     opt_state = tx.init(master)
 
+    section("step")
     cos, sin = family.rope(cfg.seq_len) if family.rope is not None \
         else precompute_rope(mc.head_dim, cfg.seq_len, mc.rope_theta)
     # layers of one parameter shape and several static kinds: the unit
@@ -857,6 +874,7 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
     # stage-level checkpoint around the layers discards whatever a layer
     # kept, so the plan is empty there; with FLAGS_flash_impl "bundled"
     # the kernel's own custom_vjp carries no names, the rest applies.
+    section("plan")
     order = remat_order(mp, routed)
     item = jnp.dtype(param_dtype).itemsize
     rows = -(-B // (mesh.shape.get("dp", 1) * zdeg)) \
@@ -885,15 +903,18 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
     floor_need = chosen_need = None
     if limit is not None:
         fits = limit - REMAT_MARGIN_BYTES
-        floor_need = chosen_need = _program_need(
-            jstep.lower(*step_args(state)).compile())
+        with _obs.span("trainer.build.plan.floor"):
+            floor_need = chosen_need = _program_need(
+                jstep.lower(*step_args(state)).compile())
         plan = choose_remat_plan(nbytes, len(plan), fits - floor_need,
                                  not cfg.scan_layers, order)
+        tries = 0
         while any(plan):
-            chosen = step_for(plan)
+            chosen, tries = step_for(plan), tries + 1
             try:
-                need = _program_need(
-                    chosen.lower(*step_args(state)).compile())
+                with _obs.span("trainer.build.plan.try", n=tries):
+                    need = _program_need(
+                        chosen.lower(*step_args(state)).compile())
             except jax.errors.JaxRuntimeError as e:
                 if "RESOURCE_EXHAUSTED" not in str(e):
                     raise
@@ -904,6 +925,7 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
             plan = _take_back(plan, order, nbytes,
                               None if need is None else need - fits,
                               not cfg.scan_layers)
+    section()
     record = {"layers": plan, "seq_sharded": seq_on_mp,
               "nbytes": dict(nbytes),
               "saved_bytes": sum(nbytes[n] for kept in plan for n in kept),
@@ -926,8 +948,9 @@ def build_llama_pretrain_step(cfg: PretrainConfig, mesh: Mesh):
         and answered by this process's own compile of the step or by
         the compile cache (`attribution.compile_named`); nothing runs
         on the devices and nothing here is called by the step."""
-        return {"train_step": compile_named(
-            jstep, step_args(state), lambda: step_for(plan))}
+        with _obs.span("trainer.compiled_programs"):
+            return {"train_step": compile_named(
+                jstep, step_args(state), lambda: step_for(plan))}
 
     meta = {"mesh": mesh, "data_sharding": data_spec,
             "flops_per_token": flops_per_token(mc),

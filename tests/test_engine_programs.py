@@ -117,15 +117,77 @@ class TestRaggedPath:
                     continue
                 assert eng.spec_k == 2
 
-    def test_launches_metric_series(self, model):
+    @pytest.fixture(scope="class")
+    def traced_run(self, model):
+        """ONE engine built and run to completion for the cases that
+        read what it left behind: the set-up ledger as it stood after
+        the run, and where the run began on the spans' clock."""
+        import time
+        from paddle_tpu.observability import tracing
+        t0 = time.perf_counter_ns()
+        _, _, eng = _run_trace(model, model.config.vocab_size, 3, seed=10,
+                               **self.ARGS)
+        ledger = tracing.recorder().setup()
+        return {"eng": eng, "t0": t0,
+                "spans": [sp for sp in ledger["spans"]
+                          if sp["start_ns"] >= t0],
+                "programs": [r for r in ledger["programs"]
+                             if r["start_ns"] >= t0]}
+
+    def test_launches_metric_series(self, traced_run):
         from paddle_tpu import serving as srv
-        V = model.config.vocab_size
-        _run_trace(model, V, 3, seed=10, **self.ARGS)
         m = srv.metrics()
         paths = {s["labels"]["path"]: s["value"]
                  for s in m["serving.engine.launches"]["series"]}
         assert paths.get("unified", 0) >= 1
         assert set(paths) == {"unified"}
+
+    def test_the_constructor_is_sections_of_the_set_up_ledger(
+            self, traced_run):
+        # ISSUE 68: `serving.engine.construct` over the constructor, its
+        # sections disjoint children that cover it
+        name = "serving.engine.construct"
+        (whole,) = [sp for sp in traced_run["spans"] if sp["name"] == name]
+        assert whole["parent"] is None and whole["step"] is None
+        kids = [sp for sp in traced_run["spans"] if sp["parent"] == name]
+        assert [sp["name"][len(name):] for sp in kids] == [
+            ".weights", ".layout", ".pools", ".accounting", ".programs"]
+        edges = [whole["start_ns"]] + [t for sp in kids for t in (
+            sp["start_ns"], sp["end_ns"])] + [whole["end_ns"]]
+        assert edges == sorted(edges)       # in order, disjoint, inside
+        covered = sum(sp["end_ns"] - sp["start_ns"] for sp in kids)
+        assert covered >= 0.95 * (whole["end_ns"] - whole["start_ns"])
+        # what the constructor compiled says which section it was under
+        under = {r["span"] for r in traced_run["programs"]
+                 if whole["start_ns"] <= r["start_ns"] < whole["end_ns"]}
+        assert name + ".programs" in under
+        assert under <= {sp["name"] for sp in kids}
+
+    def test_a_first_launch_is_found_from_its_program_record(
+            self, traced_run):
+        # no span and no test on the hot path: the record of a step
+        # program says the launch phase and the step's seq, and that
+        # step is in the ledger with its phases
+        recs = [r for r in traced_run["programs"] if r["step"] is not None]
+        assert recs and {r["span"] for r in recs} == {
+            "serving.engine.launch"}
+        firsts = [r for r in recs if r["cache"] is not None]
+        steps = sorted({r["step"] for r in firsts})
+        # the program with the chunk's rows, then the decode rows alone
+        assert len(steps) == 2 and steps[0] < steps[1]
+        copied = {sp["step"]: sp for sp in traced_run["spans"]
+                  if sp["step"] is not None}
+        assert sorted(copied) == steps
+        for r in firsts:
+            st = copied[r["step"]]
+            assert st["name"] == "serving.engine.step"
+            (launch,) = [ph for ph in st["phases"]
+                         if ph[0] == "serving.engine.launch"]
+            assert launch[1] <= r["start_ns"] < r["end_ns"] <= launch[2]
+        # every launch after them compiled nothing
+        assert traced_run["eng"].steps > steps[1]
+        assert all(n == 1 for n in
+                   traced_run["eng"].program_cache_sizes().values())
 
     @pytest.mark.parametrize("switch", ["ragged", "megafront",
                                         "megadecode"])

@@ -618,3 +618,213 @@ class TestReplicaPrefixMetrics:
         assert snap["serving.router.drains"]["labels"] == ["replica"]
         assert "serving.router.requeued" in snap
         assert "serving.router.replicas_up" in snap
+
+
+# ---------------------------------------------------------------------------
+# the set-up ledger (ISSUE 68): program records from jax.monitoring's
+# events, on a fresh recorder fed by hand — a stage is reported when it
+# ENDS, with its length, and the recorder stamps it on its own clock
+# ---------------------------------------------------------------------------
+
+_EV = "/jax/core/compile/"
+_TRACE, _LOWER, _BACKEND = (_EV + "jaxpr_trace_duration",
+                            _EV + "jaxpr_to_mlir_module_duration",
+                            _EV + "backend_compile_duration")
+_CACHE = "/jax/compilation_cache/"
+MS = 1_000_000
+
+
+class _Feed:
+    """A recorder, a clock the test sets, and the events as jax sends
+    them: `stage(kind, name, end in ms, length in ms)`."""
+
+    def __init__(self, monkeypatch, capacity=8):
+        from paddle_tpu.observability import tracing as tr
+        self.rec = tr.TraceRecorder(capacity=capacity)
+        self.now = 0
+        monkeypatch.setattr(tr, "_now_ns", lambda: self.now)
+
+    def stage(self, kind, name, end_ms, ms):
+        self.now = int(end_ms * MS)
+        self.rec._on_duration(kind, ms / 1e3, fun_name=name)
+
+    def program(self, fn, at_ms, cache=None, trace=3, lower=2, backend=1,
+                read=0.5):
+        """trace -> lower -> what the cache said -> backend, from
+        `at_ms` on; returns the end."""
+        t = at_ms + trace
+        self.stage(_TRACE, fn, t, trace)
+        t += lower
+        self.stage(_LOWER, f"jit({fn})", t, lower)
+        if cache is not None:
+            self.rec._on_event(_CACHE + "compile_requests_use_cache")
+            self.rec._on_event(_CACHE + "cache_" + cache)
+        if cache == "hits":
+            self.rec._on_duration(_CACHE + "cache_retrieval_time_sec",
+                                  read / 1e3)
+        t += backend
+        self.stage(_BACKEND, f"jit({fn})", t, backend)
+        return t
+
+
+class TestSetupLedger:
+    @pytest.mark.parametrize("said,cache,read", [
+        ("hits", "hit", 0.5), ("misses", "miss", 0), (None, "off", 0)])
+    def test_a_program_is_one_record(self, monkeypatch, said, cache, read):
+        f = _Feed(monkeypatch)
+        end = f.program("step", 10, cache=said, backend=40)
+        (r,) = f.rec.programs()
+        assert r == {"name": "jit(step)", "start_ns": 10 * MS,
+                     "end_ns": end * MS, "trace_ns": 3 * MS,
+                     "lower_ns": 2 * MS, "compile_ns": 40 * MS,
+                     "cache": cache, "cache_read_ns": int(read * MS),
+                     "span": None, "step": None}
+        want = dict.fromkeys(("hits", "misses"), 0)
+        if said:
+            want[said] = 1
+        assert f.rec.program_totals() == {None: dict(
+            want, programs=1, trace_ns=3 * MS, lower_ns=2 * MS,
+            compile_ns=40 * MS, cache_read_ns=int(read * MS))}
+
+    def test_a_nested_programs_time_counts_once(self, monkeypatch):
+        # the step program's trace, 0..10 ms, holds a kernel's jit
+        # traced 2..5 and an eager constant's whole compile 6..8
+        f = _Feed(monkeypatch)
+        f.stage(_TRACE, "kernel", 5, 3)
+        f.program("const", 6, trace=0.5, lower=0.5, backend=1)
+        f.stage(_TRACE, "step", 10, 10)
+        f.stage(_LOWER, "jit(step)", 12, 2)
+        f.stage(_BACKEND, "jit(step)", 15, 3)
+        by = {r["name"]: r for r in f.rec.programs()}
+        assert sorted(by) == ["jit(const)", "jit(step)", "kernel"]
+        assert by["kernel"]["trace_ns"] == 3 * MS
+        assert by["kernel"]["cache"] is None     # never reached a backend
+        assert by["jit(step)"]["trace_ns"] == (10 - 3 - 2) * MS
+        assert (by["jit(step)"]["start_ns"], by["jit(step)"]["end_ns"]) \
+            == (0, 15 * MS)
+        t = f.rec.program_totals()[None]
+        assert t["programs"] == 3
+        assert t["trace_ns"] + t["lower_ns"] + t["compile_ns"] == 15 * MS
+
+    def test_a_compile_finds_its_lowering_by_name(self, monkeypatch):
+        # `lowered = f.lower(...)`, other work, `lowered.compile()`
+        f = _Feed(monkeypatch)
+        f.stage(_TRACE, "step", 3, 3)
+        f.stage(_LOWER, "jit(step)", 5, 2)
+        f.program("other", 6)
+        f.stage(_BACKEND, "jit(step)", 20, 4)
+        by = {r["name"]: r for r in f.rec.programs()}
+        assert by["jit(step)"]["compile_ns"] == 4 * MS
+        assert by["jit(step)"]["lower_ns"] == 2 * MS
+        assert len(by) == 2
+
+    def test_span_and_step_are_the_open_ones(self, monkeypatch):
+        f = _Feed(monkeypatch)
+        with span("t.construct"):
+            with span("t.construct.programs"):
+                f.program("feed", 0, cache="misses")
+            f.program("pool", 10)
+        f.rec.open_step(7, "t.step")
+        with span("t.step", step=7):
+            with span("t.launch"):
+                f.program("step", 20, cache="hits")
+        f.rec._span_done("t.launch", 20 * MS, 27 * MS, "t.step", 7)
+        f.rec._span_done("t.step", 19 * MS, 28 * MS, None, 7)
+        f.rec.close_step({"decode_rows": 1})
+        got = {r["name"]: (r["span"], r["step"]) for r in f.rec.programs()}
+        assert got == {"jit(feed)": ("t.construct.programs", None),
+                       "jit(pool)": ("t.construct", None),
+                       "jit(step)": ("t.launch", 7)}
+        assert sorted(f.rec.program_totals()) == [
+            "t.construct", "t.construct.programs", "t.launch"]
+        # the step counts what reached the backend while it was open,
+        # and a step in which a program landed is copied to the ledger
+        (st,) = f.rec.steps()
+        assert st["compiles"] == 1 and "programs" not in st
+        copied = [sp for sp in f.rec.setup()["spans"] if sp["step"] == 7]
+        assert copied == [{
+            "name": "t.step", "start_ns": 19 * MS, "end_ns": 28 * MS,
+            "parent": None, "step": 7,
+            "phases": [("t.launch", 20 * MS, 27 * MS)]}]
+        # a step that compiled nothing is not
+        f.rec.open_step(8, "t.step")
+        f.rec.close_step({"decode_rows": 1})
+        assert f.rec.steps()[-1]["compiles"] == 0
+        assert len([sp for sp in f.rec.setup()["spans"]
+                    if sp["step"] is not None]) == 1
+
+    def test_totals_survive_ten_thousand_records(self, monkeypatch):
+        from paddle_tpu.observability import tracing as tr
+        monkeypatch.setattr(tr, "PROGRAMS_KEPT", 32)
+        f = _Feed(monkeypatch)
+        t = 0
+        for i in range(10_000):     # record i is i us of backend
+            t = f.program(f"op{i}", t, trace=0.001, lower=0.001,
+                          backend=(i + 1) / 1e3)
+        kept = f.rec.programs()
+        assert len(kept) == 32      # ... the longest
+        assert {r["name"] for r in kept} == {
+            f"jit(op{i})" for i in range(10_000 - 32, 10_000)}
+        tot = f.rec.program_totals()[None]
+        assert tot["programs"] == 10_000
+        assert tot["compile_ns"] == sum(range(1, 10_001)) * 1000
+        assert tot["trace_ns"] == tot["lower_ns"] == 10_000 * 1000
+
+    def test_set_up_survives_the_step_traffic(self, monkeypatch):
+        # a window makes thousands of step spans through the span ring
+        f = _Feed(monkeypatch, capacity=64)
+        f.rec._span_done("t.construct.pools", 1 * MS, 4 * MS,
+                         "t.construct", None)
+        f.rec._span_done("t.construct", 0, 5 * MS, None, None)
+        f.program("feed", 1)
+        for i in range(10_000):
+            f.rec._span_done("t.step", (10 + i) * MS, (11 + i) * MS,
+                             None, i)
+        assert len(f.rec.spans()) == 64
+        assert not [s for s in f.rec.spans() if s[0] == "t.construct"]
+        got = f.rec.setup()
+        assert [sp["name"] for sp in got["spans"]
+                if sp["name"].startswith("t.")] == [
+            "t.construct", "t.construct.pools"]
+        assert [r["name"] for r in got["programs"]] == ["jit(feed)"]
+        assert got["totals"][None]["programs"] == 1
+
+    def test_the_flag_off_keeps_nothing(self, monkeypatch):
+        from paddle_tpu.observability import tracing as tr
+        f = _Feed(monkeypatch)
+        tr.set_enabled(False)
+        try:
+            f.program("step", 0, cache="hits")
+            with span("t.construct"):
+                pass
+        finally:
+            tr.set_enabled(True)
+        got = f.rec.setup()
+        assert got["programs"] == [] and got["totals"] == {}
+        # (the package's own two clock reads are not the recorder's)
+        assert [sp["name"] for sp in got["spans"]] == ["paddle_tpu.import"]
+        a, b = paddle._IMPORT_NS
+        assert 0 < a < b
+
+    def test_a_real_jit_under_a_span_is_named(self):
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.observability import tracing as tr
+
+        def _only_traced_here(x):
+            return x * 3 + 1
+
+        rec = tr.recorder()
+        assert rec._listening       # on since the first import that asked
+        with span("t.construct"):
+            jax.jit(_only_traced_here)(jnp.ones(3)).block_until_ready()
+        mine = [r for r in rec.programs()
+                if r["name"] == "jit(_only_traced_here)"]
+        assert len(mine) == 1
+        r = mine[0]
+        assert r["span"] == "t.construct" and r["step"] is None
+        assert r["trace_ns"] > 0 and r["lower_ns"] > 0 \
+            and r["compile_ns"] > 0
+        assert r["cache"] in ("hit", "miss", "off")
+        assert r["start_ns"] < r["end_ns"] <= time.perf_counter_ns()
+        assert rec.program_totals()["t.construct"]["programs"] >= 1

@@ -2,6 +2,8 @@
 checkpoint saves named residuals chosen by bytes against what the device
 reports free beyond the program that keeps nothing."""
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -210,12 +212,48 @@ def _one_step(built):
             "plan": meta["remat_plan"]}
 
 
+def _ledger_since(t0):
+    """The set-up ledger's spans and program records from `t0` on."""
+    from paddle_tpu.observability import tracing
+    got = tracing.recorder().setup()
+    return {"spans": [sp for sp in got["spans"] if sp["start_ns"] >= t0],
+            "programs": [r for r in got["programs"]
+                         if r["start_ns"] >= t0]}
+
+
 @pytest.fixture(scope="module")
 def floor_step():
     mp = pytest.MonkeyPatch()
-    out = _one_step(_build(mp, None))
+    t0 = time.perf_counter_ns()
+    built = _build(mp, None)
+    ledger = _ledger_since(t0)      # the build's, before the first step
+    out = dict(_one_step(built), ledger=ledger)
     mp.undo()
     return out
+
+
+@needs_4
+def test_the_build_is_sections_of_the_set_up_ledger(floor_step):
+    # ISSUE 68: `trainer.build` over the builder, its sections disjoint
+    # children in the builder's own order
+    spans = floor_step["ledger"]["spans"]
+    (whole,) = [sp for sp in spans if sp["name"] == "trainer.build"]
+    assert whole["parent"] is None and whole["step"] is None
+    kids = [sp for sp in spans if sp["parent"] == "trainer.build"]
+    assert [sp["name"] for sp in kids] == [
+        "trainer.build" + s for s in (".model", ".state", ".step", ".plan")]
+    edges = [whole["start_ns"]] + [t for sp in kids for t in (
+        sp["start_ns"], sp["end_ns"])] + [whole["end_ns"]]
+    assert edges == sorted(edges)
+    # what the build traced says which section it was under (the eager
+    # float32 model is one-primitive programs: those this process has
+    # not met before); no device reports a limit here, so the plan
+    # compiled nothing and has no floor
+    under = {r["span"] for r in floor_step["ledger"]["programs"]
+             if whole["start_ns"] <= r["start_ns"] < whole["end_ns"]}
+    assert under and under <= {sp["name"] for sp in kids}
+    assert "trainer.build.plan" not in under
+    assert not [sp for sp in spans if sp["parent"] == "trainer.build.plan"]
 
 
 PARTIAL = [ALL_BUT_GATE + ("gate_up",), ALL_BUT_GATE]
@@ -270,7 +308,18 @@ def test_a_step_that_keeps_equals_the_step_that_keeps_nothing(
 @needs_4
 def test_the_plan_is_reported(monkeypatch):
     reg = obs.registry()
+    t0 = time.perf_counter_ns()
     _, _, meta, _ = _build(monkeypatch, 1 << 40, plan=PARTIAL)
+    # the set-up ledger (ISSUE 68): the floor program and the one try
+    # are spans under `.plan`, each with the step program it compiled
+    ledger = _ledger_since(t0)
+    tries = [sp["name"] for sp in ledger["spans"]
+             if sp["parent"] == "trainer.build.plan"]
+    assert tries == ["trainer.build.plan.floor", "trainer.build.plan.try"]
+    for name in tries:
+        compiled = [r for r in ledger["programs"]
+                    if r["span"] == name and r["cache"] is not None]
+        assert [r["name"] for r in compiled] == ["jit(train_step)"], name
     plan = meta["remat_plan"]
     assert plan["layers"] == PARTIAL
     assert plan["limit"] == 1 << 40
